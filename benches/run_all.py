@@ -8,6 +8,10 @@ bench, plus the observability sections (``slo`` / ``stage_latency_ms``,
 written by benches that boot real services — bench_faults) merged in, so
 BENCH_* files carry the stage decomposition, not just headline numbers.
 
+This runner never imports jax (nor ``common``): each bench is a child that
+holds the chip while it runs, one at a time — a parent that had touched JAX
+would hold it instead and every child would fail or hang.
+
 Usage: python benches/run_all.py [--quick]
 """
 

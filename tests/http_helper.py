@@ -2,64 +2,10 @@
 
 Mirrors the reference voice tests' style: boot the actual server on an
 ephemeral port and talk to it over TCP (apps/voice/test/server.test.ts:8-14).
+The runner itself lives in the package (``services.stack``): the one-process
+launcher and ``chip_smoke.py`` serve the stack the same way.
 """
 
-from __future__ import annotations
+from tpu_voice_agent.services.stack import AppServer
 
-import asyncio
-import threading
-
-from aiohttp import web
-
-
-class AppServer:
-    def __init__(self, app: web.Application):
-        self.app = app
-        self.port: int | None = None
-        self._loop: asyncio.AbstractEventLoop | None = None
-        self._thread: threading.Thread | None = None
-        self._started = threading.Event()
-
-    def __enter__(self) -> "AppServer":
-        self._thread = threading.Thread(target=self._run, daemon=True)
-        self._thread.start()
-        if not self._started.wait(timeout=30):
-            raise RuntimeError("server failed to start")
-        return self
-
-    def _run(self) -> None:
-        self._loop = asyncio.new_event_loop()
-        asyncio.set_event_loop(self._loop)
-
-        async def start():
-            # services that propagate client disconnects into in-flight
-            # work (brain/voice mid-decode cancellation, ISSUE 7) set this
-            # app flag; aiohttp >= 3.9 made handler cancellation opt-in
-            from tpu_voice_agent.services import HANDLER_CANCELLATION
-
-            runner = web.AppRunner(
-                self.app,
-                handler_cancellation=bool(
-                    self.app.get(HANDLER_CANCELLATION, False)))
-            await runner.setup()
-            site = web.TCPSite(runner, "127.0.0.1", 0)
-            await site.start()
-            self.port = runner.addresses[0][1]
-            self._runner = runner
-            self._started.set()
-
-        self._loop.run_until_complete(start())
-        self._loop.run_forever()
-
-    @property
-    def url(self) -> str:
-        return f"http://127.0.0.1:{self.port}"
-
-    def __exit__(self, *exc) -> None:
-        async def stop():
-            await self._runner.cleanup()
-
-        if self._loop is not None:
-            asyncio.run_coroutine_threadsafe(stop(), self._loop).result(timeout=10)
-            self._loop.call_soon_threadsafe(self._loop.stop)
-            self._thread.join(timeout=10)
+__all__ = ["AppServer"]

@@ -134,7 +134,27 @@ def test_device_peak_knob_override(monkeypatch):
     monkeypatch.delenv("COST_PEAK_GBPS")
     p = device_peak()  # CPU harness: the documented proxy, finite and > 0
     assert p["flops_per_s"] > 0 and p["bytes_per_s"] > 0
-    assert p["source"] in ("table", "cpu-proxy")
+    assert p["source"] == "cpu-proxy"
+
+
+def test_device_peak_matches_tpu_kind_exactly(monkeypatch):
+    """A TPU reads the published table by its exact device_kind; one the
+    table does not know is an error, never a default."""
+    import types
+
+    import jax
+
+    def fake(kind):
+        return lambda *a: [types.SimpleNamespace(platform="tpu", device_kind=kind)]
+
+    monkeypatch.setattr(jax, "devices", fake("TPU v5 lite"))
+    p = device_peak()
+    assert p == {"flops_per_s": 197e12, "bytes_per_s": 819e9,
+                 "device": "TPU v5 lite", "source": "table"}
+    for unknown in ("TPU v5", "TPU v9 lite", "tpu v5 lite"):
+        monkeypatch.setattr(jax, "devices", fake(unknown))
+        with pytest.raises(KeyError, match="no published peaks"):
+            device_peak()
 
 
 # ------------------------------------------------------- dense conservation

@@ -7,6 +7,8 @@ ckpts`` (~10 min CPU) and is scored by benches/bench_quality.py; these tests
 run scaled-down budgets that still prove each link of the chain.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -175,3 +177,33 @@ def test_intent_ckpt_roundtrip_preserves_parses(tmp_path, trained_intent):
         r1 = p1.parse(text, {})
         r2 = p2.parse(text, {})
         assert r1.model_dump() == r2.model_dump()
+
+
+def test_committed_ckpt_restores_onto_a_host_without_its_saving_device(tmp_path):
+    """The committed checkpoints were saved on a CPU and record that device
+    by name; orbax's bare restore() rebuilds the recorded sharding and fails
+    on a host whose devices carry other names (the TPU). ``restore_params``
+    places onto the restoring process's own default device instead."""
+    import json
+    import shutil
+
+    import jax
+
+    from tpu_voice_agent.ckpt.orbax_io import restore_params
+
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                       "checkpoints", distill.WHISPER_CKPT)
+    dst = tmp_path / "ckpt"
+    shutil.copytree(src, dst)
+    sharding_file = dst / "params" / "_sharding"
+    recorded = json.loads(sharding_file.read_text())
+    foreign = json.dumps({"sharding_type": "SingleDeviceSharding",
+                          "device_str": "TPU_0(process=0,(0,0,0,0))"})
+    sharding_file.write_text(json.dumps({k: foreign for k in recorded}))
+
+    params = restore_params(str(dst))
+    leaves = jax.tree.leaves(params)
+    assert leaves and all(
+        x.sharding.device_set == {jax.devices()[0]} for x in leaves)
+    want = jax.tree.leaves(restore_params(src))
+    assert all(np.array_equal(a, b) for a, b in zip(leaves, want))

@@ -174,7 +174,11 @@ def test_ring_thread_hammer():
     threads += [threading.Thread(target=reader) for _ in range(2)]
     for th in threads:
         th.start()
-    time.sleep(0.4)
+    # six spinning threads share the GIL with the sampler: give it until it
+    # has sampled enough, not a fixed 0.4 s it only sometimes gets 10 turns in
+    deadline = time.monotonic() + 10.0
+    while ring.state()["next_seq"] <= 10 and time.monotonic() < deadline:
+        time.sleep(0.05)
     stop.set()
     for th in threads:
         th.join(timeout=5)

@@ -1,0 +1,270 @@
+"""AOT-compile every public ``ops.*`` kernel for the TPU — without a TPU.
+
+libtpu is installed next to jax, and ``jax.experimental.topologies`` hands
+out abstract ``TPU v5 lite`` devices under ``JAX_PLATFORMS=cpu``; lowering a
+function whose arguments carry a sharding on one of them and calling
+``.compile()`` runs the real XLA:TPU and Mosaic compilers. Interpret mode
+(what every other kernel test here uses) accepts programs Mosaic refuses —
+``masked_argmax_advance`` shipped that way for nine PRs — so a kernel that
+has only ever been interpreted cannot land again.
+
+Compiling is not running: numerics on the chip are ``chip_smoke.py``'s job.
+
+Shapes are the published head geometries of the three model families the
+repo serves: TinyLlama-1.1B (32 q / 4 kv heads of 64, 22 layers), Llama-3-8B
+(32 q / 8 kv heads of 128, 32 layers) and Whisper-large-v3 (20 heads of 64,
+1500 encoder positions, 448 text positions). The kernels the voice->intent
+main path reaches run in the fast tier; the rest are ``slow``.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from tpu_voice_agent import ops
+
+BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
+
+# (n_q_heads, n_kv_heads, head_dim, n_layers)
+TINYLLAMA = (32, 4, 64, 22)
+LLAMA3_8B = (32, 8, 128, 32)
+WHISPER_V3 = (20, 20, 64, 32)
+# the in-tree intent grammar over the in-tree tokenizer (what every random-
+# weight engine decodes under): states x classes, vocab
+FSM_STATES, FSM_CLASSES, VOCAB = 35449, 310, 619
+BLOCK, MAX_BLOCKS = 128, 16  # serve.paged defaults: 128-token blocks, max_len 2048
+FF_T = 9  # a grammar fast-forward step: current token + BRAIN_FF=8 chain tokens
+
+
+@pytest.fixture(scope="module")
+def tpu_devices():
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+def _compile(devices, fn, *args, mesh_specs=None, mesh=None, **static):
+    """Lower + compile ``fn(*args)`` for the abstract TPU. ``args`` are
+    (shape, dtype) pairs; with ``mesh`` each takes the matching
+    PartitionSpec from ``mesh_specs``, otherwise all sit on device 0."""
+    if mesh is None:
+        shardings = [SingleDeviceSharding(devices[0])] * len(args)
+    else:
+        shardings = [NamedSharding(mesh, s) for s in mesh_specs]
+    sds = [jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+           for (shape, dt), sh in zip(args, shardings)]
+    return jax.jit(functools.partial(fn, **static)).lower(*sds).compile()
+
+
+def _dense_cache(B, S, geom, stacked):
+    _, nkv, hd, L = geom
+    shape = (L, B, S, nkv, hd) if stacked else (B, S, nkv, hd)
+    return (shape, BF16), (shape, BF16)
+
+
+def _pool(N, geom, hdp=None, dtype=BF16):
+    _, nkv, hd, L = geom
+    shape = (L, N, BLOCK, nkv, hdp or hd)
+    return (shape, dtype), (shape, dtype)
+
+
+def _scales(N, geom):
+    _, nkv, _, L = geom
+    shape = (L, N, BLOCK, nkv)
+    return (shape, BF16), (shape, BF16)
+
+
+# ------------------------------------------------------------- main path
+
+
+@pytest.mark.parametrize("T,causal,geom", [
+    # Whisper-large-v3 encoder: the full 30 s window, a 10 s final bucket,
+    # and the two incremental block widths (0.5 s anchor, 0.7 s with lookback)
+    (1500, False, WHISPER_V3), (500, False, WHISPER_V3),
+    (25, False, WHISPER_V3), (35, False, WHISPER_V3),
+    # TinyLlama prompt-prefix prefill at the 1024 bucket
+    (1024, True, TINYLLAMA),
+])
+def test_flash_attention_compiles(tpu_devices, T, causal, geom):
+    nq, nkv, hd, _ = geom
+    _compile(tpu_devices, ops.flash_attention,
+             ((1, T, nq, hd), BF16), ((1, T, nkv, hd), BF16),
+             ((1, T, nkv, hd), BF16), causal=causal, interpret=False)
+
+
+@pytest.mark.parametrize("S", [448, 1500, 500, 150])
+def test_decode_attention_compiles_whisper(tpu_devices, S):
+    """The Whisper decoder's T=1 step: self-attention over the 448-slot text
+    cache and cross-attention over an utterance's encoder frames."""
+    nq, _, hd, _ = WHISPER_V3
+    _compile(tpu_devices, ops.decode_attention, ((1, nq, hd), BF16),
+             *_dense_cache(1, S, WHISPER_V3, stacked=False), ((1,), I32),
+             interpret=False)
+
+
+def test_decode_attention_layer_compiles(tpu_devices):
+    nq, _, hd, _ = TINYLLAMA
+    _compile(tpu_devices, ops.decode_attention_layer, ((4, nq, hd), BF16),
+             *_dense_cache(4, 1024, TINYLLAMA, stacked=True), ((4,), I32),
+             ((), I32), interpret=False)
+
+
+def test_decode_block_attention_layer_compiles(tpu_devices):
+    nq, _, hd, _ = TINYLLAMA
+    _compile(tpu_devices, ops.decode_block_attention_layer,
+             ((4, FF_T, nq, hd), BF16),
+             *_dense_cache(4, 1024, TINYLLAMA, stacked=True),
+             ((4, FF_T), I32), ((), I32), interpret=False)
+
+
+def test_paged_attention_compiles(tpu_devices):
+    nq, _, hd, _ = TINYLLAMA
+    _compile(tpu_devices, ops.paged_attention, ((4, nq, hd), BF16),
+             *_pool(4 * MAX_BLOCKS + 1, TINYLLAMA), ((4, MAX_BLOCKS), I32),
+             ((4,), I32), ((), I32), interpret=False)
+
+
+def test_paged_block_attention_compiles(tpu_devices):
+    nq, _, hd, _ = TINYLLAMA
+    _compile(tpu_devices, ops.paged_block_attention, ((4, FF_T, nq, hd), BF16),
+             *_pool(4 * MAX_BLOCKS + 1, TINYLLAMA), ((4, MAX_BLOCKS), I32),
+             ((4, FF_T), I32), ((), I32), interpret=False)
+
+
+def test_masked_argmax_compiles(tpu_devices):
+    _compile(tpu_devices, ops.masked_argmax, ((4, VOCAB), F32), ((4,), I32),
+             ((FSM_STATES, VOCAB), jnp.bool_), interpret=False)
+
+
+def test_masked_argmax_advance_compiles(tpu_devices):
+    _compile(tpu_devices, ops.masked_argmax_advance, ((4, VOCAB), F32),
+             ((4,), I32), ((FSM_STATES, VOCAB), jnp.bool_),
+             ((FSM_STATES, FSM_CLASSES), I32), ((VOCAB,), I32), interpret=False)
+
+
+# ------------------------------------------------------- the rest (slow)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("geom", [TINYLLAMA, LLAMA3_8B])
+def test_llama_dense_kernels_compile(tpu_devices, geom):
+    nq, nkv, hd, _ = geom
+    B, S = 4, 2048
+    flat = _dense_cache(B, S, geom, stacked=False)
+    stacked = _dense_cache(B, S, geom, stacked=True)
+    _compile(tpu_devices, ops.flash_attention, ((1, 2048, nq, hd), BF16),
+             ((1, 2048, nkv, hd), BF16), ((1, 2048, nkv, hd), BF16),
+             causal=True, interpret=False)
+    _compile(tpu_devices, ops.decode_attention, ((B, nq, hd), BF16), *flat,
+             ((B,), I32), interpret=False)
+    _compile(tpu_devices, ops.decode_attention_layer, ((B, nq, hd), BF16),
+             *stacked, ((B,), I32), ((), I32), interpret=False)
+    _compile(tpu_devices, ops.decode_block_attention, ((B, FF_T, nq, hd), BF16),
+             *flat, ((B, FF_T), I32), interpret=False)
+    _compile(tpu_devices, ops.decode_block_attention_layer,
+             ((B, FF_T, nq, hd), BF16), *stacked, ((B, FF_T), I32), ((), I32),
+             interpret=False)
+    # the (B, 1+K) speculative verify block rides the same kernel at K=4
+    _compile(tpu_devices, ops.decode_block_attention_layer,
+             ((B, 5, nq, hd), BF16), *stacked, ((B, 5), I32), ((), I32),
+             interpret=False)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("geom", [TINYLLAMA, LLAMA3_8B])
+def test_llama_paged_kernels_compile(tpu_devices, geom):
+    nq, _, hd, _ = geom
+    B, N = 4, 4 * MAX_BLOCKS + 1
+    tables, lens, layer = ((B, MAX_BLOCKS), I32), ((B,), I32), ((), I32)
+    _compile(tpu_devices, ops.paged_attention, ((B, nq, hd), BF16),
+             *_pool(N, geom), tables, lens, layer, interpret=False)
+    _compile(tpu_devices, ops.paged_block_attention, ((B, FF_T, nq, hd), BF16),
+             *_pool(N, geom), tables, ((B, FF_T), I32), layer, interpret=False)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("geom", [TINYLLAMA, LLAMA3_8B])
+@pytest.mark.parametrize("bits", [8, 4])
+def test_quantized_kv_kernels_compile(tpu_devices, geom, bits):
+    nq, nkv, hd, _ = geom
+    B, N, S = 4, 4 * MAX_BLOCKS + 1, 2048
+    hdp = hd if bits == 8 else hd // 2
+    tables, lens, layer = ((B, MAX_BLOCKS), I32), ((B,), I32), ((), I32)
+    _compile(tpu_devices, ops.paged_attention_quant, ((B, nq, hd), BF16),
+             *_pool(N, geom, hdp, I8), *_scales(N, geom), tables, lens, layer,
+             bits=bits, interpret=False)
+    _compile(tpu_devices, ops.paged_block_attention_quant,
+             ((B, FF_T, nq, hd), BF16), *_pool(N, geom, hdp, I8),
+             *_scales(N, geom), tables, ((B, FF_T), I32), layer,
+             bits=bits, interpret=False)
+    _compile(tpu_devices, ops.decode_attention_quant, ((B, nq, hd), BF16),
+             ((B, S, nkv, hdp), I8), ((B, S, nkv, hdp), I8),
+             ((B, S, nkv), BF16), ((B, S, nkv), BF16), lens,
+             bits=bits, interpret=False)
+
+
+@pytest.mark.slow
+def test_masked_argmax_block_compiles(tpu_devices):
+    _compile(tpu_devices, ops.masked_argmax_block, ((4, 5, VOCAB), F32),
+             ((4, 5), I32), ((FSM_STATES, VOCAB), jnp.bool_), interpret=False)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("d,f", [(2048, 5632), (4096, 14336)])
+def test_grouped_matmul_compiles(tpu_devices, d, f):
+    """MoE expert dispatch at TinyLlama / Llama-3-8B (Mixtral) FFN widths,
+    8 experts, 1024 expert-sorted rows."""
+    M, E, tm = 1024, 8, 128
+    _compile(tpu_devices, ops.grouped_matmul, ((M, d), BF16), ((E, d, f), BF16),
+             ((M // tm,), I32), tm=tm, interpret=False)
+
+
+@pytest.mark.slow
+def test_sharded_kernels_compile_on_2x2(tpu_devices):
+    """The shard_map variants the dp x tp serving mesh traces (batch over
+    dp, heads over tp), compiled for all four abstract chips."""
+    mesh = Mesh(np.array(tpu_devices).reshape(2, 2), ("dp", "tp"))
+    nq, nkv, hd, L = TINYLLAMA
+    B, S, N = 4, 1024, 4 * MAX_BLOCKS + 2
+    heads = P("dp", None, "tp", None)
+    cache = P(None, "dp", None, "tp", None)
+    pool = P(None, "dp", None, "tp", None)
+    rep = P()
+
+    def go(fn, args, specs, **static):
+        _compile(tpu_devices, functools.partial(fn, mesh), *args, mesh=mesh,
+                 mesh_specs=specs, **static)
+
+    go(ops.sharded_flash_attention,
+       [((B, 1024, nq, hd), BF16), ((B, 1024, nkv, hd), BF16),
+        ((B, 1024, nkv, hd), BF16)], [heads] * 3, causal=True, interpret=False)
+    go(ops.sharded_decode_attention_layer,
+       [((B, nq, hd), BF16), *_dense_cache(B, S, TINYLLAMA, True), ((B,), I32),
+        ((), I32)], [P("dp", "tp", None), cache, cache, P("dp"), rep],
+       interpret=False)
+    go(ops.sharded_decode_block_attention_layer,
+       [((B, FF_T, nq, hd), BF16), *_dense_cache(B, S, TINYLLAMA, True),
+        ((B, FF_T), I32), ((), I32)],
+       [heads, cache, cache, P("dp", None), rep], interpret=False)
+    go(ops.sharded_paged_attention,
+       [((B, nq, hd), BF16), *_pool(N, TINYLLAMA), ((B, MAX_BLOCKS), I32),
+        ((B,), I32), ((), I32)],
+       [P("dp", "tp", None), pool, pool, P("dp", None), P("dp"), rep],
+       interpret=False)
+    go(ops.sharded_paged_block_attention,
+       [((B, FF_T, nq, hd), BF16), *_pool(N, TINYLLAMA),
+        ((B, MAX_BLOCKS), I32), ((B, FF_T), I32), ((), I32)],
+       [heads, pool, pool, P("dp", None), P("dp", None), rep], interpret=False)
+    go(ops.sharded_masked_argmax_advance,
+       [((B, VOCAB), F32), ((B,), I32), ((FSM_STATES, VOCAB), jnp.bool_),
+        ((FSM_STATES, FSM_CLASSES), I32), ((VOCAB,), I32)],
+       [P("dp", None), P("dp"), rep, rep, rep], interpret=False)
+    go(ops.sharded_masked_argmax_block,
+       [((B, 5, VOCAB), F32), ((B, 5), I32), ((FSM_STATES, VOCAB), jnp.bool_)],
+       [P("dp", None, None), P("dp", None), rep], interpret=False)

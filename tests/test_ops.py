@@ -151,3 +151,22 @@ def test_decode_block_attention_layer_matches_plain():
                                                jnp.int32(li), block_k=64)
         np.testing.assert_allclose(np.asarray(stacked), np.asarray(plain),
                                    rtol=1e-6, atol=1e-6)
+
+
+def test_engines_refuse_interpreted_pallas_unless_the_cpu_was_asked_for(monkeypatch):
+    """JAX lands on the CPU by itself when no accelerator initialises; that
+    must not turn kernels="pallas" into a silent interpret-mode run. Under
+    the suite's explicit JAX_PLATFORMS=cpu it is the supported test shape."""
+    from tpu_voice_agent.ops import backend
+
+    assert backend.on_cpu() and backend.cpu_requested()
+    assert backend.resolve_kernels("auto") == "xla"
+    assert backend.resolve_kernels("pallas") == "pallas"
+    monkeypatch.setattr(backend, "cpu_requested", lambda: False)  # a silent fallback
+    assert backend.resolve_kernels("auto") == "xla"
+    with pytest.raises(RuntimeError, match="interpret mode"):
+        backend.resolve_kernels("pallas")
+    with pytest.raises(SystemExit, match="no TPU found"):
+        backend.measurement_devices()
+    with pytest.raises(ValueError, match="unknown kernels"):
+        backend.resolve_kernels("mosaic")

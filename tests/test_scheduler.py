@@ -50,3 +50,24 @@ def test_more_requests_than_slots_queue_up(batcher):
     assert len(results) == 5
     for r in results:
         _assert_grammar_consistent(batcher, r)
+
+
+def test_warmup_compiles_ahead_and_leaves_no_trace(batcher):
+    """``warmup()`` is what the service mains run before they listen
+    (services.warm_up): afterwards a request compiles nothing in the
+    serving loop — a cold compile there runs under the stall watchdog —
+    and the batcher is as clean as a fresh one (same tokens, no slot, no
+    result, no queue left behind)."""
+    from tpu_voice_agent.utils.compilewatch import get_compile_watcher
+
+    batcher.warmup()
+    assert not batcher.pending and not batcher.results
+    assert all(sl.request_id < 0 for sl in batcher.slots)
+    assert not batcher._active_h.any()
+
+    compiles = get_compile_watcher().state()["compiles"]
+    warmed = batcher.generate_many([PROMPTS[0]])[0]
+    assert get_compile_watcher().state()["compiles"] == compiles
+    _assert_grammar_consistent(batcher, warmed)
+    fresh = ContinuousBatcher(batcher.engine, chunk_steps=16, max_new_tokens=300)
+    assert fresh.generate_many([PROMPTS[0]])[0].token_ids == warmed.token_ids

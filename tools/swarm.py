@@ -879,21 +879,16 @@ def build_local_stack(tmp_dir: str, *, brain_inflight: int = 8,
     benches/bench_router.py and tests."""
     import os
 
-    from tests.http_helper import AppServer
     from tpu_voice_agent.services.brain import RuleBasedParser
     from tpu_voice_agent.services.brain import build_app as build_brain
     from tpu_voice_agent.services.executor import SessionManager
-    from tpu_voice_agent.services.executor import build_app as build_executor
     from tpu_voice_agent.services.executor.page import FakePage
-    from tpu_voice_agent.services.voice import VoiceConfig
-    from tpu_voice_agent.services.voice import build_app as build_voice
+    from tpu_voice_agent.services.stack import AppServer, serve_stack
     from tpu_voice_agent.utils import chaos as chaos_mod
-
-    if chaos_spec is not None:
-        chaos_mod.configure(chaos_spec, seed=chaos_seed)
 
     servers: list = []
     urls: dict = {}
+    brain_url = None  # a routed tier's url; None = serve_stack hosts the brain
     if brain_replicas > 1:
         from tpu_voice_agent.services.router import BrainRouter
         from tpu_voice_agent.services.router import build_app as build_router
@@ -927,24 +922,23 @@ def build_local_stack(tmp_dir: str, *, brain_inflight: int = 8,
         if pf_replicas:
             urls["prefill_replicas"] = [b.url for b in pf_replicas]
         servers += [router] + replicas + pf_replicas
-    else:
-        brain = AppServer(build_brain(parser or RuleBasedParser(),
-                                      max_inflight=brain_inflight)).__enter__()
-        brain_url = brain.url
-        servers.append(brain)
-    urls["brain"] = brain_url
-    manager = SessionManager(page_factory=FakePage.demo,
-                             artifacts_root=os.path.join(tmp_dir, "art"),
-                             uploads_dir=os.path.join(tmp_dir, "up"))
-    executor = AppServer(build_executor(manager,
-                                        max_inflight=exec_inflight)).__enter__()
-    voice = AppServer(build_voice(VoiceConfig(
-        brain_url=brain_url, executor_url=executor.url,
-        stt_factory=lambda: ScriptedSTT(frames_per_final=frames_per_final),
-        parse_timeout_s=parse_timeout_s, retry_attempts=2,
-    ))).__enter__()
-    urls.update(voice=voice.url, executor=executor.url)
-    return urls, [voice, executor] + servers
+    stack = serve_stack(
+        None if brain_url else (parser or RuleBasedParser()),
+        brain_url=brain_url,
+        brain_kw={"max_inflight": brain_inflight},
+        executor_kw={"max_inflight": exec_inflight},
+        manager=SessionManager(page_factory=FakePage.demo,
+                               artifacts_root=os.path.join(tmp_dir, "art"),
+                               uploads_dir=os.path.join(tmp_dir, "up")),
+        voice_cfg=dict(
+            stt_factory=lambda: ScriptedSTT(frames_per_final=frames_per_final),
+            parse_timeout_s=parse_timeout_s, retry_attempts=2))
+    urls.update(stack.urls)
+    if chaos_spec is not None:
+        # armed only now: the stack's warm-up (serve_stack) must neither
+        # trip an injected fault nor consume the drill's seeded draws
+        chaos_mod.configure(chaos_spec, seed=chaos_seed)
+    return urls, stack.servers + servers
 
 
 # --------------------------------------------------------------- CLI

@@ -40,7 +40,16 @@ def restore_params(path: str | os.PathLike, shardings=None, params_like=None):
     path = os.path.join(os.path.abspath(path), "params")
     with ocp.StandardCheckpointer() as ckptr:
         if shardings is None:
-            return ckptr.restore(path)
+            # onto THIS process's default device, named explicitly: a bare
+            # restore() rebuilds the sharding recorded at save time, and a
+            # checkpoint saved on the CPU ("TFRT_CPU_0") then fails to
+            # restore on a TPU host, whose local devices hold no such name
+            here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+            is_array = lambda m: hasattr(m, "shape") and hasattr(m, "dtype")
+            abstract = jax.tree.map(
+                lambda m: jax.ShapeDtypeStruct(m.shape, m.dtype, sharding=here),
+                ckptr.metadata(path).item_metadata.tree, is_leaf=is_array)
+            return ckptr.restore(path, abstract)
         if params_like is None:
             raise ValueError("restore with shardings requires params_like (abstract pytree)")
         abstract = jax.tree.map(

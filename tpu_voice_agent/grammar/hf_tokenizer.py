@@ -1,9 +1,9 @@
 """Real-checkpoint tokenizers from HF ``tokenizer.json`` — true BPE merges.
 
-Round 1 approximated HF vocabs with greedy longest-match (VERDICT.md weak
-#3): prompts fed to a real checkpoint would segment differently from its
-training tokenizer and silently degrade quality. This module implements the
-actual BPE merge procedure for the two families every target checkpoint uses
+Round 1 approximated HF vocabs with greedy longest-match: prompts fed to a
+real checkpoint would segment differently from its training tokenizer and
+silently degrade quality. This module implements the actual BPE merge
+procedure for the two families every target checkpoint uses
 (zero network egress; pure-python over the checkpoint's own tokenizer.json):
 
 - **byte-level BPE** (GPT-2 lineage: Whisper, Qwen2, Llama-3): vocab keys

@@ -6,10 +6,10 @@ should not run in Python: audio decode/resample/RMS and the energy
 endpointer. The TPU compute path stays JAX/Pallas; this is the IO layer
 around it.
 
-Everything degrades gracefully: if the compiler or the .so is unavailable,
-``native_available()`` is False and the pure-numpy twins in ``audio/`` are
-used instead — same seam style as the reference's null-key STT fake
-(SURVEY.md §4).
+On a machine with no compiler ``native_available()`` is False and the
+pure-numpy twins in ``audio/`` are used instead — same seam style as the
+reference's null-key STT fake (SURVEY.md §4). With g++ present a failed
+build raises.
 """
 
 from . import frontend

@@ -1,14 +1,20 @@
 """ctypes binding for the C++ audio frontend, with numpy fallback.
 
-The shared library is built on first import with g++ (cached next to the
-source, keyed by source mtime). No pybind11 in this image, so the ABI is a
-small extern-C surface bound via ctypes.
+The shared library is built on first use with g++, next to the source and
+named by the source's content hash — a build of other source is never
+picked up, whatever a checkout or a copy did to the mtimes. No pybind11 in
+this image, so the ABI is a small extern-C surface bound via ctypes.
+
+The numpy fallback is for a machine WITHOUT a compiler. With g++ present a
+failed build raises: a broken frontend must not pass as a slow one.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
 import threading
 
@@ -16,7 +22,6 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "audio_frontend.cpp")
-_SO = os.path.join(_DIR, "_audio_frontend.so")
 
 _lock = threading.Lock()
 _lib = None
@@ -24,16 +29,28 @@ NATIVE_AVAILABLE = False
 
 
 def _build() -> str | None:
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
-    try:
-        subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", _SO, _SRC],
-            check=True, capture_output=True, timeout=120,
-        )
-        return _SO
-    except Exception:
+    """Path of the built library, or None when there is no g++ at all."""
+    with open(_SRC, "rb") as f:
+        key = hashlib.sha256(f.read()).hexdigest()[:12]
+    so = os.path.join(_DIR, f"_audio_frontend.{key}.so")
+    if os.path.exists(so):
+        return so
+    if shutil.which("g++") is None:
         return None
+    tmp = f"{so}.{os.getpid()}.tmp"  # concurrent first uses race safely
+    try:
+        proc = subprocess.run(
+            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC],
+            capture_output=True, text=True, timeout=120,
+        )
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"g++ failed to build {_SRC}:\n{proc.stderr[-2000:]}")
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    return so
 
 
 def _load():

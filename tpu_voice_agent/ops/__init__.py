@@ -12,16 +12,14 @@ Here the hot ops of the in-tree models get hand-written Pallas kernels:
 - ``masked_argmax``: fused grammar-mask + argmax over the vocab, the
   sampling half of grammar-constrained decoding
 
-Every kernel has a pure-jnp reference twin (``*_reference``) used for
-correctness tests and as the CPU fallback; kernels run under
-``interpret=True`` on CPU so the whole suite exercises kernel code paths
-without a chip.
+Every kernel has a pure-jnp reference twin (``*_reference``) that the
+correctness tests and ``chip_smoke.py`` compare it against; kernels run
+under ``interpret=True`` on CPU (``ops.backend.on_cpu``) so the whole suite
+exercises kernel code paths without a chip, and
+``tests/test_kernels_compile_tpu.py`` AOT-compiles each one for the TPU.
 """
 
-# import-time side effect: installs jax.shard_map on old jax (the kernels
-# below call it at runtime); same install point parallel.* relies on
-from ..utils import jaxcompat as _jaxcompat  # noqa: F401
-
+from .backend import on_cpu, resolve_kernels
 from .flash_attention import flash_attention, attention_reference, sharded_flash_attention
 from .decode_attention import (
     decode_attention,
@@ -72,6 +70,8 @@ from .paged_attention import (
 )
 
 __all__ = [
+    "on_cpu",
+    "resolve_kernels",
     "flash_attention",
     "attention_reference",
     "sharded_flash_attention",

@@ -21,11 +21,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import on_cpu
+
 _NEG_INF = -1e30
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def _decode_kernel(
@@ -106,7 +104,7 @@ def decode_attention(
     assert nq % nkv == 0
     group = nq // nkv
     scale = scale if scale is not None else hd**-0.5
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
 
     # Blocks DMA straight out of the cache's native (B, S, nkv, hd) layout —
     # no moveaxis/pad relayout of the full cache per step (the step's HBM
@@ -181,7 +179,7 @@ def decode_attention_layer(
     assert nq % nkv == 0
     group = nq // nkv
     scale = scale if scale is not None else hd**-0.5
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
     # this kernel runs once per LAYER per step: padding the stacked cache
     # here would copy the ENTIRE cache L times per token — the exact
     # traffic it exists to eliminate. Take a smaller block instead; oddly
@@ -437,7 +435,7 @@ def decode_block_attention(
     assert nq % nkv == 0
     group = nq // nkv
     scale = scale if scale is not None else hd**-0.5
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
 
     block_k = min(block_k, S)
     if S % block_k:
@@ -502,7 +500,7 @@ def decode_block_attention_layer(
     assert nq % nkv == 0
     group = nq // nkv
     scale = scale if scale is not None else hd**-0.5
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
     block_k = min(block_k, S)
     while S % block_k and block_k > 32:
         block_k //= 2
@@ -705,7 +703,7 @@ def decode_attention_quant(
     assert bits in (8, 4)
     group = nq // nkv
     scale = scale if scale is not None else hd**-0.5
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
     block_k = min(block_k, S)
     if S % block_k:
         raise ValueError(

@@ -23,11 +23,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import on_cpu
+
 _NEG_INF = -1e30
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def _flash_kernel(
@@ -127,7 +125,7 @@ def flash_attention(
     group = nq // nkv
     scale = scale if scale is not None else hd**-0.5
     kv_len = kv_len if kv_len is not None else S
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
 
     block_q = min(block_q, T)
     block_k = min(block_k, S)

@@ -25,13 +25,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import on_cpu
+
 _LANE = 128
 _SUB = 8
 _TILE = _SUB * _LANE  # vocab elements per grid cell
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def _argmax_kernel(
@@ -82,7 +80,7 @@ def masked_argmax(
     """Returns (B,) int32 = argmax_v(logits[b, v] where mask_table[state[b], v])."""
     B, V = logits.shape
     S = mask_table.shape[0]
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
     pad_v = (-V) % _TILE
     if pad_v:
         logits = jnp.pad(logits, ((0, 0), (0, pad_v)), constant_values=-jnp.inf)
@@ -156,11 +154,10 @@ def masked_argmax_reference(
 #
 # - ``masked_argmax_advance``: mask + argmax + FSM advance in ONE kernel.
 #   The col_id class tiles stream beside the logits tiles, the kernel
-#   tracks the argmax position's class, and the scalar-prefetched
-#   (1, C) row of the compressed transition table — indexed by the row's
-#   own state, the same trick as the mask tiles — yields the next state
-#   with one dynamic scalar load at finish. Nothing V-sized ever leaves
-#   the kernel.
+#   tracks the argmax position's class, and the (1, 1, C) row of the
+#   compressed transition table — fetched by the row's own state, the
+#   same scalar-prefetch trick as the mask tiles — yields the next state
+#   at finish. Nothing V-sized ever leaves the kernel.
 # - ``masked_argmax_block``: every verify position of a (B, 1+K) spec
 #   block masked at its OWN state and argmaxed in ONE pallas_call (the
 #   grid folds positions into rows), replacing the K+1-round XLA loop in
@@ -172,7 +169,7 @@ def _argmax_advance_kernel(
     logits_ref,  # (1, SUB, 128) f32 tile of row b
     mask_ref,  # (1, SUB, 128) bool tile of row state[b]
     col_ref,  # (SUB, 128) int32 col_id tile (token -> class)
-    trow_ref,  # (1, C) int32 — row state[b] of the compressed table
+    trow_ref,  # (1, 1, C) int32 — row state[b] of the compressed table
     idx_out_ref,  # SMEM (B,) int32
     next_out_ref,  # SMEM (B,) int32
     best_val_ref,  # SMEM (1,) f32
@@ -209,7 +206,12 @@ def _argmax_advance_kernel(
     @pl.when(j == nj - 1)
     def _finish():
         idx_out_ref[b] = best_idx_ref[0]
-        next_out_ref[b] = trow_ref[0, best_cls_ref[0]]
+        # Mosaic has no scalar load from VMEM at a dynamic lane index: pick
+        # the class lane with an iota compare and reduce (exactly one lane
+        # matches, so the sum IS that lane's value, -1 included)
+        trow = trow_ref[0]  # (1, C)
+        cls = jax.lax.broadcasted_iota(jnp.int32, trow.shape, 1)
+        next_out_ref[b] = jnp.sum(jnp.where(cls == best_cls_ref[0], trow, 0))
 
 
 # analyze: ok[jit-sentinel] -- kernel wrapper traced inline by the watched engine/stt loops, never a serving dispatch entry point
@@ -230,7 +232,7 @@ def masked_argmax_advance(
     engine's poison gate already fences (it keys on the ENTRY state)."""
     B, V = logits.shape
     S, C = table.shape
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
     state = jnp.maximum(fsm_state.astype(jnp.int32), 0)
     pad_v = (-V) % _TILE
     if pad_v:
@@ -253,7 +255,10 @@ def masked_argmax_advance(
             pl.BlockSpec((1, _SUB, _LANE), lambda b, j, state: (b, j, 0)),
             pl.BlockSpec((1, _SUB, _LANE), lambda b, j, state: (state[b], j, 0)),
             pl.BlockSpec((_SUB, _LANE), lambda b, j, state: (j, 0)),
-            pl.BlockSpec((1, Cp), lambda b, j, state: (state[b], 0)),
+            # (S, 1, C) view: a (1, C) block of an (S, C) array breaks the
+            # 8-sublane rule; as the full last two dims of a 3-D array it
+            # is legal
+            pl.BlockSpec((1, 1, Cp), lambda b, j, state: (state[b], 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((B,), lambda b, j, state: (0,), memory_space=pltpu.SMEM),
@@ -271,7 +276,7 @@ def masked_argmax_advance(
         out_shape=[jax.ShapeDtypeStruct((B,), jnp.int32),
                    jax.ShapeDtypeStruct((B,), jnp.int32)],
         interpret=interpret,
-    )(state, logits3, mask3, col2, table.astype(jnp.int32))
+    )(state, logits3, mask3, col2, table.astype(jnp.int32).reshape(S, 1, Cp))
     return tok, nxt
 
 

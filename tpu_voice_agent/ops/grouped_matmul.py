@@ -26,9 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+from .backend import on_cpu
 
 
 def _pick_tile(n: int, cap: int) -> int:
@@ -82,7 +80,7 @@ def grouped_matmul(
     tk = tk or _pick_tile(d, 512)
     assert M % tm == 0 and f % tn == 0 and d % tk == 0, (M, f, d, tm, tn, tk)
     assert tile_expert.shape == (M // tm,)
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,

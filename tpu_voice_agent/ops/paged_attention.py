@@ -26,11 +26,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import on_cpu
+
 _NEG_INF = -1e30
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def _paged_kernel(
@@ -112,7 +110,7 @@ def paged_attention(
     assert nq % nkv == 0
     group = nq // nkv
     scale = scale if scale is not None else hd**-0.5
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
     qg = q.reshape(B, nkv, group, hd)
 
     scalars = jnp.concatenate([
@@ -295,7 +293,7 @@ def paged_attention_quant(
     assert bits in (8, 4)
     group = nq // nkv
     scale = scale if scale is not None else hd**-0.5
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
     qg = q.reshape(B, nkv, group, hd)
 
     scalars = jnp.concatenate([
@@ -606,7 +604,7 @@ def paged_block_attention(
     assert nq % nkv == 0
     group = nq // nkv
     scale = scale if scale is not None else hd**-0.5
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
     qg = q.reshape(B, T, nkv, group, hd).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(B, nkv, T * group, hd)
 
@@ -793,7 +791,7 @@ def paged_block_attention_quant(
     assert bits in (8, 4)
     group = nq // nkv
     scale = scale if scale is not None else hd**-0.5
-    interpret = interpret if interpret is not None else _on_cpu()
+    interpret = interpret if interpret is not None else on_cpu()
     qg = q.reshape(B, T, nkv, group, hd).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(B, nkv, T * group, hd)
 

@@ -31,7 +31,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..utils.jaxcompat import shard_map  # jax.shard_map, gated for old jax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

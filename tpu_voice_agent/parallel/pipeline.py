@@ -20,9 +20,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
 from ..utils.compilewatch import watch_compiles
-from ..utils.jaxcompat import shard_map  # jax.shard_map, gated for old jax
 
 from ..models.llama import (
     LlamaConfig, _attend, _layer_out, _layer_qkv, _qe, rms_norm, rope_tables,

@@ -24,8 +24,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.jaxcompat import shard_map  # jax.shard_map, gated for old jax
 
 _NEG_INF = -1e30
 

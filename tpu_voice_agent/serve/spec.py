@@ -773,8 +773,8 @@ class SpecDecoder:
     verify steps, each ONE (B, 1+K) target forward that advances every
     active row by 1..K+1 tokens. The host pays one small readback per
     verify step (drafting needs cur/state) — the trade the chunk loop
-    exists to avoid, bought back K-fold in steps; over a high-latency
-    tunnel prefer fast-forward or raise SPEC_K.
+    exists to avoid, bought back K-fold in steps; where that readback
+    dominates a step, prefer fast-forward or raise SPEC_K.
 
     On a ``PagedDecodeEngine`` the verify step goes through
     ``paged_spec_verify_step`` (writes scatter through the slot's block

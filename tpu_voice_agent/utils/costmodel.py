@@ -201,16 +201,19 @@ def whisper_decoder_flops(cfg, n_tokens: int, enc_len: int) -> int:
 
 # ------------------------------------------------------------ device peak
 
-# bf16 peak FLOP/s and HBM bytes/s per TPU generation (per chip), from
-# published specs. Matched by substring against jax device_kind.
-_PEAK_TABLE = (
-    ("v6", (918e12, 1640e9)),   # Trillium
-    ("v5p", (459e12, 2765e9)),
-    ("v5", (197e12, 819e9)),    # v5e / "v5 lite"
-    ("v4", (275e12, 1228e9)),
-    ("v3", (123e12, 900e9)),
-    ("v2", (45e12, 700e9)),
-)
+# Published per-chip peaks, keyed by the EXACT ``jax`` ``device_kind``, each
+# with its source. THE one peaks table: the live MFU/MBU gauges, bench.py's
+# roofline line and chip_smoke.py's device check all read it. A TPU that is
+# not in it is an error, not a default.
+PEAK_TABLE = {
+    "TPU v5 lite": {
+        "flops_per_s": 197e12,      # bf16
+        "int8_ops_per_s": 393e12,
+        "bytes_per_s": 819e9,       # HBM
+        "hbm_bytes": 16e9,
+        "source": 'Google Cloud documentation, "TPU v5e"',
+    },
+}
 
 # Documented CPU proxy: NOT a hardware claim. A fixed reference point so
 # MFU/MBU are finite and comparable run-to-run on the CPU harness (the
@@ -220,26 +223,27 @@ _CPU_PROXY = (0.5e12, 50e9)
 
 def device_peak() -> dict:
     """(peak FLOP/s, peak bytes/s) for the local device: knob override >
-    per-generation table > CPU proxy."""
+    ``PEAK_TABLE`` by exact device_kind > the CPU proxy off-accelerator.
+    Raises ``KeyError`` for a TPU the table does not know."""
     tflops = knob_float("COST_PEAK_TFLOPS", 0.0)
     gbps = knob_float("COST_PEAK_GBPS", 0.0)
     if tflops > 0 and gbps > 0:
         return {"flops_per_s": tflops * 1e12, "bytes_per_s": gbps * 1e9,
                 "device": "knob", "source": "knob"}
-    kind, peaks, source = "cpu", _CPU_PROXY, "cpu-proxy"
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        kind = getattr(dev, "device_kind", dev.platform)
-        if dev.platform == "tpu":
-            low = kind.lower()
-            for key, p in _PEAK_TABLE:
-                if key in low:
-                    peaks, source = p, "table"
-                    break
-    except Exception:
-        pass
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        kind = dev.device_kind
+        if kind not in PEAK_TABLE:
+            raise KeyError(
+                f"no published peaks for device_kind {kind!r}: add it to "
+                "utils.costmodel.PEAK_TABLE with its source (or set BOTH "
+                "COST_PEAK_TFLOPS and COST_PEAK_GBPS)")
+        peaks = (PEAK_TABLE[kind]["flops_per_s"], PEAK_TABLE[kind]["bytes_per_s"])
+        source = "table"
+    else:
+        kind, peaks, source = dev.platform, _CPU_PROXY, "cpu-proxy"
     out = {"flops_per_s": peaks[0], "bytes_per_s": peaks[1],
            "device": kind, "source": source}
     if tflops > 0:

@@ -125,10 +125,7 @@ def measure_hbm(engine) -> dict:
     if live is not None:
         out["live_bytes"] = live
         out["other_bytes"] = max(0, live - weights - kv)
-    try:
-        stats = jax.devices()[0].memory_stats()
-    except Exception:
-        stats = None
+    stats = jax.devices()[0].memory_stats()  # None on backends that report none (CPU)
     if stats and "bytes_in_use" in stats:
         in_use = int(stats["bytes_in_use"])
         out["bytes_in_use"] = in_use

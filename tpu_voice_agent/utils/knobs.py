@@ -263,20 +263,18 @@ declare("FLEET_GRAY_HOLD_S", "300", "seconds a gray verdict survives WITHOUT sco
 # cost & efficiency observatory (ISSUE 17): analytic roofline metering,
 # live MFU/MBU, per-session resource attribution
 declare("COST_ENABLE", "1", "0 removes the analytic cost lanes (per-request ledger + MFU/MBU gauges; token-identical either way)", table=OBSERVABILITY)
-declare("COST_PEAK_TFLOPS", "0", "device peak TFLOP/s override for MFU (0 = per-device-kind table, documented CPU proxy off-TPU)", table=OBSERVABILITY)
-declare("COST_PEAK_GBPS", "0", "device peak HBM GB/s override for MBU (0 = per-device-kind table, documented CPU proxy off-TPU)", table=OBSERVABILITY)
+declare("COST_PEAK_TFLOPS", "0", "device peak TFLOP/s override for MFU (0 = costmodel.PEAK_TABLE by exact device_kind; an unknown TPU is an error; documented CPU proxy off-TPU)", table=OBSERVABILITY)
+declare("COST_PEAK_GBPS", "0", "device peak HBM GB/s override for MBU (0 = costmodel.PEAK_TABLE by exact device_kind; an unknown TPU is an error; documented CPU proxy off-TPU)", table=OBSERVABILITY)
 declare("COST_SESSIONS", "256", "per-session cost-rollup LRU size in the brain", table=OBSERVABILITY)
 
 # ========================================================= infrastructure
 # deliberately undocumented: JAX bootstrap + test/bench harness plumbing,
 # not operator tuning surface (the checker rejects doc rows for these)
 
-declare("JAX_PLATFORMS", None, "JAX platform selection (cpu forces the no-TPU path)")
+declare("JAX_COMPILATION_CACHE_DIR", None, "JAX's own persistent compile cache location; unset = <checkout>/.jax_cache (utils.compilecache)")
 declare("JAX_COORDINATOR_ADDRESS", None, "multihost coordinator address")
 declare("JAX_NUM_PROCESSES", None, "multihost process count")
 declare("JAX_PROCESS_ID", None, "multihost process index")
-declare("BENCH_INIT_TIMEOUT_S", "60", "bench harness device-init watchdog")
-declare("BENCH_NO_CPU_FALLBACK", None, "1 = fail fast instead of CPU fallback in benches")
 declare("TPU_VOICE_CACHE_DIR", None, "grammar FSM table cache dir override")
 declare("CKPT_HELDOUT", None, "0 skips the held-out eval ckpt in make_tiny_ckpts")
 declare("CKPT_GROUND", None, "0 skips the grounding ckpt in make_tiny_ckpts")
