@@ -1,0 +1,127 @@
+"""Plain reference: Whisper's encoder, cross-attention and decoder in
+straightforward ``jax.numpy``, float32, ``default_matmul_precision
+("highest")`` — no kernels, no cache, no buckets.
+
+Equations (Radford et al. 2022, "Robust Speech Recognition via Large-Scale
+Weak Supervision", and the released model code): encoder = conv1d(k3, s1)
+-> GELU -> conv1d(k3, s2) -> GELU -> + sinusoidal positions -> pre-LN
+transformer blocks (bidirectional) -> LayerNorm; decoder = token + learned
+position embeddings -> pre-LN blocks of causal self-attention,
+cross-attention over the encoder output and a GELU MLP -> LayerNorm ->
+logits tied to the token embedding. Keys carry no bias. GELU is the exact
+(erf) form, as published.
+
+Departure, deliberate: the second convolution pads (0, 1) — XLA's "SAME"
+for stride 2, which is what ``models/whisper.py`` runs — where the released
+model pads (1, 1). The two see the mel frames shifted by one; on seeded
+random weights that is the same model, on a real checkpoint it is not
+(PERF.md lists it for the program to repair). ``conv2_pad`` says which.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+F32 = jnp.float32
+
+
+def f32(tree, via=None):
+    """Weights as float32. ``via`` first rounds them to a lower-precision
+    type — only the comparison's negative control passes it."""
+    def cast(x):
+        return (x if via is None else x.astype(via)).astype(F32)
+
+    return jax.tree_util.tree_map(cast, tree)
+
+
+def layer_norm(x, p, eps):
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean((x - mu) ** 2, axis=-1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * p["g"] + p["b"]
+
+
+def gelu(x):
+    return jax.nn.gelu(x, approximate=False)
+
+
+def conv1d(x, w, b, stride, pad):
+    """x (T, Cin), w (3, Cin, Cout) -> (T', Cout), written as three shifted
+    matmuls so the arithmetic is in plain sight."""
+    T = x.shape[0]
+    xp = jnp.pad(x, (pad, (0, 0)))
+    n_out = (T + pad[0] + pad[1] - 3) // stride + 1
+    idx = jnp.arange(n_out) * stride
+    return sum(xp[idx + k] @ w[k] for k in range(3)) + b
+
+
+def sinusoids(n_pos, d):
+    log_timescale = np.log(10_000.0) / (d // 2 - 1)
+    inv = np.exp(-log_timescale * np.arange(d // 2))
+    scaled = np.arange(n_pos)[:, None] * inv[None, :]
+    return jnp.asarray(np.concatenate([np.sin(scaled), np.cos(scaled)], axis=1), F32)
+
+
+def mha(q, k, v, nh, mask=None):
+    Tq, Tk, hd = q.shape[0], k.shape[0], q.shape[1] // nh
+    s = jnp.einsum("qnh,knh->nqk", q.reshape(Tq, nh, hd), k.reshape(Tk, nh, hd)) * hd ** -0.5
+    if mask is not None:
+        s = jnp.where(mask[None], s, -jnp.inf)
+    return jnp.einsum("nqk,knh->qnh", jax.nn.softmax(s, axis=-1),
+                      v.reshape(Tk, nh, hd)).reshape(Tq, nh * hd)
+
+
+def attend(x_q, x_kv, a, nh, mask=None):
+    q = x_q @ a["wq"] + a["bq"]
+    k = x_kv @ a["wk"]  # keys carry no bias
+    v = x_kv @ a["wv"] + a["bv"]
+    return mha(q, k, v, nh, mask) @ a["wo"] + a["bo"]
+
+
+@partial(jax.jit, static_argnames=("nh", "eps", "conv2_pad", "via"))
+def encoder(p, mel, *, nh, eps, conv2_pad=(0, 1), via=None):
+    """mel (T, n_mels) -> (T // 2, d). Layers are converted to float32 one
+    at a time, so the full-width model fits beside the served one."""
+    with jax.default_matmul_precision("highest"):
+        c1, c2 = f32(p["conv1"], via), f32(p["conv2"], via)
+        x = gelu(conv1d(mel.astype(F32), c1["w"], c1["b"], 1, (1, 1)))
+        x = gelu(conv1d(x, c2["w"], c2["b"], 2, conv2_pad))
+        x = x + sinusoids(x.shape[0], x.shape[1])
+
+        def block(x, lp):
+            lp = f32(lp, via)
+            x = x + attend(layer_norm(x, lp["ln1"], eps), layer_norm(x, lp["ln1"], eps),
+                           lp["attn"], nh)
+            h = gelu(layer_norm(x, lp["ln2"], eps) @ lp["w1"] + lp["b1"])
+            return x + h @ lp["w2"] + lp["b2"], None
+
+        x, _ = jax.lax.scan(block, x, p["layers"])
+        return layer_norm(x, f32(p["ln_post"]), eps)
+
+
+@partial(jax.jit, static_argnames=("nh", "eps", "via"))
+def decoder(p, tokens, enc_out, n_valid, *, nh, eps, via=None):
+    """tokens (T,) teacher-forced, enc_out (Te, d) of which the first
+    ``n_valid`` frames are audio (the rest is bucket padding the served
+    engine masks too) -> logits (T, V)."""
+    with jax.default_matmul_precision("highest"):
+        T = tokens.shape[0]
+        tok_emb = f32(p["tok_emb"], via)
+        x = tok_emb[tokens] + f32(p["pos_emb"])[:T]
+        causal = jnp.arange(T)[:, None] >= jnp.arange(T)[None, :]
+        audible = jnp.broadcast_to(jnp.arange(enc_out.shape[0])[None, :] < n_valid,
+                                   (T, enc_out.shape[0]))
+
+        def block(x, lp):
+            lp = f32(lp, via)
+            h = layer_norm(x, lp["ln1"], eps)
+            x = x + attend(h, h, lp["self_attn"], nh, causal)
+            x = x + attend(layer_norm(x, lp["ln2"], eps), enc_out, lp["cross_attn"], nh, audible)
+            h = gelu(layer_norm(x, lp["ln3"], eps) @ lp["w1"] + lp["b1"])
+            return x + h @ lp["w2"] + lp["b2"], None
+
+        x, _ = jax.lax.scan(block, x, p["layers"])
+        return layer_norm(x, f32(p["ln_final"]), eps) @ tok_emb.T
