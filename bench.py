@@ -48,72 +48,6 @@ def int8_weight_bytes(cfg) -> float:
     return float(matmul_int8 + cfg.dim * 2)
 
 
-def diagnose_on_chip(engine, bench_prompt: str, base_ms_tok, preset: str) -> None:
-    """PERF.md's three levers, pulled automatically on a live chip:
-
-    1. HLO int8-fusion audit (hypothesis 1: a materialized dequant triples
-       that weight's HBM traffic) — findings to stderr + full HLO on disk.
-    2. jax.profiler trace around one constrained generation (falsifies the
-       small-op-latency and while-loop-overhead hypotheses).
-    3. decode_unroll sweep {1,2,4} — each unroll is a fresh engine compile;
-       the marginal slope decides if loop overhead is on the critical path.
-    """
-    import gc
-
-    from tpu_voice_agent.serve import DecodeEngine
-    from tpu_voice_agent.services.brain import install_prompt_prefix
-    from tpu_voice_agent.utils.perfdiag import (
-        audit_dequant,
-        capture_profile,
-        decode_step_hlo,
-        marginal_ms_per_token,
-    )
-
-    art = "bench_artifacts"
-    os.makedirs(art, exist_ok=True)
-
-    # (1) HLO audit
-    hlo = decode_step_hlo(engine)
-    with open(os.path.join(art, "decode_step_hlo.txt"), "w") as f:
-        f.write(hlo)
-    audit = audit_dequant(hlo)
-    if audit["findings"]:
-        print("[bench] DIAG hlo-audit: WASTEFUL DEQUANT LOWERING FOUND "
-              f"(PERF.md hypothesis 1; materialized buffer or scale fused "
-              f"into the dot chain): {audit['findings']}", file=sys.stderr)
-    else:
-        print(f"[bench] DIAG hlo-audit: clean — no materialized dequant and "
-              f"no scale-in-dot surplus in any computation "
-              f"({audit['scanned_instructions']} instructions scanned); see "
-              "profiler trace for hyp 2/3", file=sys.stderr)
-
-    # (2) profiler trace
-    trace_dir = capture_profile(engine, bench_prompt,
-                                os.path.join(art, "profile"))
-    print(f"[bench] DIAG profiler trace captured under {trace_dir}",
-          file=sys.stderr)
-
-    # (3) unroll sweep (fresh compile per unroll; drop each engine before
-    # the next so int8 weights don't stack up in HBM)
-    results = {1: base_ms_tok}
-    for u in (2, 4):
-        eng_u = DecodeEngine(preset=preset, max_len=1024,
-                             prefill_buckets=(1024,), quant="int8",
-                             decode_unroll=u)
-        install_prompt_prefix(eng_u)
-        eng_u.generate(bench_prompt, max_new_tokens=8)  # compile
-        results[u] = marginal_ms_per_token(eng_u, bench_prompt)
-        del eng_u
-        gc.collect()
-    line = ", ".join(
-        f"unroll={u}: {v:.2f} ms/tok" if v is not None else f"unroll={u}: n/a"
-        for u, v in results.items())
-    best = min((u for u, v in results.items() if v is not None),
-               key=lambda u: results[u], default=1)
-    print(f"[bench] DIAG unroll sweep: {line} -> best decode_unroll={best}",
-          file=sys.stderr)
-
-
 def main() -> None:
     from tpu_voice_agent.ops.backend import measurement_devices
     from tpu_voice_agent.utils.compilecache import place_compile_cache
@@ -411,10 +345,6 @@ def main() -> None:
         print(f"[bench] decode {ms_tok:.2f} ms/token marginal (CPU run; "
               "roofline n/a)", file=sys.stderr)
 
-    # ---- automatic roofline diagnosis: every chip run yields the
-    # DIAGNOSIS, not just the number. A diagnosis that fails fails the run.
-    if on_tpu and not neural and os.environ.get("BENCH_DIAG") != "0":
-        diagnose_on_chip(engine, bench_prompt, ms_tok, preset)
     # parse-only (round-1's metric, for continuity) — measured standalone
     # now that the e2e loop hides the parse inside the endpoint window
     po = []

@@ -113,6 +113,7 @@ class _NotingParser:
         note_stage("prefill_ms", 12.5)
         note_stage("decode_ms", 80.25)
         note_stage("cached_tokens", 896)
+        note_stage("queue_ms", 1305.25)
         return RuleBasedParser().parse(text, context)
 
 
@@ -127,11 +128,40 @@ def test_decode_split_rides_response_headers():
         assert r.headers["x-prefill-ms"] == "12.5"
         assert r.headers["x-decode-ms"] == "80.25"
         assert r.headers["x-cached-tokens"] == "896"
+        assert r.headers["x-queue-ms"] == "1305.25"
     with AppServer(build_app(RuleBasedParser())) as srv:
         r = httpx.post(srv.url + "/parse",
                        json={"text": "search for ants", "context": {}})
         assert r.status_code == 200
         assert "x-prefill-ms" not in r.headers
+
+
+def test_one_request_and_token_count_for_both_backends():
+    """``brain.parse_completed`` / ``brain.parse_tokens`` move in
+    ``_result_to_response``, which the serialized and the batched backend
+    share: a decode that ran to its end counts (a truncation too), a typed
+    failure does not; the queue wait rides out as a stage note."""
+    from tpu_voice_agent.serve.engine import GenerationResult
+    from tpu_voice_agent.services.brain import ParserError, _result_to_response
+    from tpu_voice_agent.utils import get_metrics
+    from tpu_voice_agent.utils.tracing import pop_stage_notes
+
+    def counts():
+        c, _ = get_metrics().counter_state()
+        return c.get("brain.parse_completed", 0.0), c.get("brain.parse_tokens", 0.0)
+
+    res = lambda **kw: GenerationResult(**{**dict(
+        text="{}", token_ids=[], prefill_ms=1.0, decode_ms=2.0, steps=33,
+        finished=False, queue_ms=12.3456), **kw})
+    n0, t0 = counts()
+    with pytest.raises(ParserError):  # truncated: counted, no plan
+        _result_to_response(res())
+    assert pop_stage_notes()["queue_ms"] == 12.346
+    assert counts() == (n0 + 1, t0 + 33)
+    with pytest.raises(ParserError):  # shed in the queue: no decode ran
+        _result_to_response(res(error="shed: deadline expired in queue", steps=0))
+    assert counts() == (n0 + 1, t0 + 33)
+    pop_stage_notes()
 
 
 def test_concurrent_parses_do_not_interleave(rule_server):

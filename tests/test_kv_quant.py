@@ -455,8 +455,10 @@ def test_hbm_plan_matches_measured_kv(tier):
 
 def test_pool_gauges_bytes_view(eng_int8):
     """record_pool_gauges with the engine exports the bytes-denominated
-    view (satellite: block counts stopped being a unit of HBM) and the
-    fused-tail dispatch gauge landed from the batcher runs above."""
+    view (satellite: block counts stopped being a unit of HBM); the one
+    host-dispatched instance of the fused tail is the
+    ``.first_token_call`` part of every admission the batcher runs above
+    recorded (it replaced the gauge that timed the same dispatch)."""
     from tpu_voice_agent.serve.paged import record_pool_gauges
 
     record_pool_gauges(eng_int8.allocator, engine=eng_int8)
@@ -472,7 +474,10 @@ def test_pool_gauges_bytes_view(eng_int8):
     # measured-thrash trigger (PoolExhausted -> RADIX_PRESSURE_S window)
     # needs no re-expression; the bytes gauges are the dashboard unit
     assert 0.0 <= g["paged.kv_utilization"] <= 1.0
-    assert "engine.step.fused_mask_sample_ms" in g
+    from tpu_voice_agent.utils import get_steplog
+
+    adm = [a for s in get_steplog().steps() for a in s.get("admissions", [])]
+    assert adm and all(a["first_token_call_ms"] > 0 for a in adm)
     assert get_metrics().collisions() == []
 
 

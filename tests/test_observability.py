@@ -455,6 +455,25 @@ def test_ttft_includes_queue_wait(tiny_batch_engine):
     b.run_until_done()
 
 
+def test_queue_wait_is_its_own_number_beside_ttft(tiny_batch_engine):
+    """The wait for a slot, which TTFT hides inside itself: a request that
+    sat in the queue reports it as ``scheduler.queue_wait`` and on its
+    result, and TTFT is that wait plus the admission."""
+    import time as _time
+
+    from tpu_voice_agent.serve.scheduler import ContinuousBatcher
+
+    b = ContinuousBatcher(tiny_batch_engine, chunk_steps=16, max_new_tokens=32)
+    rid = b.submit("scroll down")
+    _time.sleep(0.15)
+    b.step()
+    lat = get_metrics()._latencies
+    wait, ttft = lat["scheduler.queue_wait"][-1], lat["scheduler.ttft"][-1]
+    assert 150.0 <= wait < ttft
+    b.run_until_done()
+    assert b.results[rid].queue_ms == wait
+
+
 def test_kv_pool_utilization_gauges():
     from tpu_voice_agent.serve.paged import BlockAllocator, record_pool_gauges
 

@@ -75,35 +75,87 @@ def test_steptimer_stages_tile_the_wall():
     log = StepLog(max_steps=8, enabled=True)
     t = log.timer()
     time.sleep(0.002)
-    t.lap("admit")
+    t.stage("sched.admit")
     time.sleep(0.005)
-    t.lap("decode")
+    t.stage("sched.decode_dispatch")
     time.sleep(0.002)
-    t.lap("readback")
-    t.lap("release")
+    t.stage("sched.readback")
+    t.stage("sched.release")
     rec = t.finish(occupancy=2, tokens=5)
     assert rec["occupancy"] == 2 and rec["tokens"] == 5
-    assert set(rec["stages"]) <= set(STAGES)
-    # laps are contiguous segments of one perf_counter stream: they tile
-    # (each stage and the wall are rounded to 3 decimals independently, so
-    # allow half-ulp rounding slack per recorded stage)
+    assert set(rec["stages"]) == {"admit", "decode", "readback", "release"}
+    # the first stage runs from the step's start (the 2 ms before it was
+    # opened are admit, as the first lap's were)
+    assert rec["stages"]["admit"] >= 6.9 and rec["stages"]["decode"] >= 1.9
+    # stage spans are contiguous segments of one perf_counter_ns stream:
+    # they tile (each stage and the wall are rounded to 3 decimals
+    # independently, so allow half-ulp rounding slack per recorded stage)
     slack = 5e-4 * (len(rec["stages"]) + 1)
-    assert sum(rec["stages"].values()) <= rec["wall_ms"] + slack
-    assert sum(rec["stages"].values()) >= 0.95 * rec["wall_ms"]
+    assert abs(sum(rec["stages"].values()) - rec["wall_ms"]) <= slack
+    # the unrounded stamps bracket the wall on the wall clock
+    assert rec["t0_ns"] < rec["t1_ns"]
+    assert abs((rec["t1_ns"] - rec["t0_ns"]) / 1e6 - rec["wall_ms"]) < 1.0
+    assert rec["t_s"] == round(rec["t1_ns"] / 1e9, 3)
 
 
-def test_steptimer_carve_moves_subtime_between_stages():
+def test_nested_stage_spans_are_taken_out_of_the_stage_around_them():
+    """What ``carve`` did after the fact the spans do where it happens: a
+    prefill call inside admit is the prefill stage and NOT admit, the
+    drafter inside decode is draft — and the six still tile the wall."""
+    import time
+
+    from tpu_voice_agent.utils.steplog import (
+        ALLOC_SPAN,
+        PREFILL_CALL_SPAN,
+        PREFILL_STAGE_SPAN,
+        REQUEST_SPAN,
+        span,
+    )
+
     log = StepLog(max_steps=8, enabled=True)
     t = log.timer()
-    t.lap("admit")
-    t.stages["admit"] = 10.0
-    t.carve("admit", "prefill", 4.0)
-    assert t.stages["admit"] == pytest.approx(6.0)
-    assert t.stages["prefill"] == pytest.approx(4.0)
-    # carving more than the source stage holds clamps (tiling preserved)
-    t.carve("admit", "prefill", 100.0)
-    assert t.stages["admit"] == 0.0
-    assert t.stages["prefill"] == pytest.approx(10.0)
+    t.stage("sched.admit")
+    for rid in (7, 8):
+        with t.span(REQUEST_SPAN, rid=rid, queue_ms=1.5) as req:
+            with span(f"{REQUEST_SPAN}.tokenize"):  # the engine's entry point
+                time.sleep(0.001)
+            req.set(prompt_tokens=12)
+            with span(ALLOC_SPAN):  # the engine's prefill_slot
+                time.sleep(0.001)
+                with span(PREFILL_STAGE_SPAN), span(PREFILL_CALL_SPAN):
+                    time.sleep(0.003)
+    time.sleep(0.002)
+    t.stage("sched.decode_dispatch")
+    with span("sched.decode.draft"):
+        time.sleep(0.002)
+    time.sleep(0.001)
+    t.stage("sched.release")
+    rec = t.finish()
+    st = rec["stages"]
+    assert 5.9 <= st["prefill"] < 0.9 * (st["prefill"] + st["admit"])
+    assert st["admit"] >= 3.9 and st["draft"] >= 1.9 and st["decode"] >= 0.9
+    assert abs(sum(st.values()) - rec["wall_ms"]) <= 5e-4 * (len(st) + 1)
+    # one ledger entry per request span, attributes and parts together
+    assert [a["rid"] for a in rec["admissions"]] == [7, 8]
+    a = rec["admissions"][0]
+    assert a["queue_ms"] == 1.5 and a["prompt_tokens"] == 12
+    assert a["tokenize_ms"] >= 0.9 and a["prefill_call_ms"] >= 2.9
+    # a part inside another is taken out of it: alloc is not alloc + call
+    assert 0.9 <= a["alloc_ms"] < a["prefill_call_ms"]
+    parts = a["tokenize_ms"] + a["alloc_ms"] + a["prefill_call_ms"]
+    assert 0.98 * a["request_ms"] <= parts <= a["request_ms"]
+    # a request span that raises, or is dropped, is no admission
+    t = log.timer()
+    t.stage("sched.admit")
+    with pytest.raises(RuntimeError):
+        with t.span(REQUEST_SPAN, rid=9):
+            raise RuntimeError("pool exhausted")
+    with t.span(REQUEST_SPAN, rid=10) as req:
+        req.drop()
+    assert "admissions" not in t.finish()
+    # outside a step the primitive is a bare annotation, and costs nothing
+    with span(PREFILL_CALL_SPAN):
+        pass
 
 
 def test_steplog_ring_bounds_and_seq():
@@ -281,6 +333,233 @@ def test_steplog_off_is_token_identical(scope_engine):
         log.enabled = True
     assert [r.token_ids for r in on] == [r.token_ids for r in off]
     assert all(r.error is None for r in on)
+
+
+def test_admissions_tile_their_request_and_count_admitted(scope_engine):
+    """Each admission's parts (tokenize .. bookkeeping, the engine's
+    ``.alloc`` and ``.prefill_call`` among them) tile its
+    ``sched.admit.request`` span to within 2 %, one entry per admission,
+    and the ``prefill`` stage keeps its meaning: what ``prefill_ms`` times,
+    the layout kernel's whole call, of which the jitted call is a part."""
+    from tpu_voice_agent.utils.steplog import ADMISSION_PARTS
+
+    bat = _batcher(scope_engine)
+    res = bat.generate_many(["turn on the lights", "play some jazz",
+                             "what is the weather today"])
+    assert all(r.error is None for r in res)
+    steps = get_steplog().steps()
+    adm = [a for s in steps for a in s.get("admissions", [])]
+    assert len(adm) == 3 == sum(s.get("admitted", 0) for s in steps)
+    for s in steps:
+        assert len(s.get("admissions", [])) == s.get("admitted", 0)
+        calls = sum(a["prefill_call_ms"] for a in s.get("admissions", []))
+        assert calls <= s["stages"].get("prefill", 0.0) + 2e-3
+    assert sum(s["stages"].get("prefill", 0.0) for s in steps) == pytest.approx(
+        sum(r.prefill_ms for r in res), rel=0.05)
+    for a in adm:
+        assert {f"{p}_ms" for p in ADMISSION_PARTS if p != "bookkeeping"} <= set(a)
+        assert a["prompt_tokens"] > 0 and a["cached_tokens"] == 0 and a["rid"] >= 0
+    parts = sum(a.get(f"{p}_ms", 0.0) for a in adm for p in ADMISSION_PARTS)
+    whole = sum(a["request_ms"] for a in adm)
+    assert 0.98 * whole <= parts <= whole + 1e-3, (parts, whole)
+    # the result carries the request's own queue wait
+    assert sorted(round(r.queue_ms, 3) for r in res) == sorted(
+        round(a["queue_ms"], 3) for a in adm)
+
+
+def test_queue_wait_grows_when_slots_are_busy(scope_engine):
+    """``queue_ms`` is submit() -> popped from ``pending``: ~0 on an idle
+    batcher, a whole generation long for the requests that found both
+    slots taken; the histogram ``scheduler.queue_wait`` holds the same
+    waits the results and the ledger carry (its running sum and count are
+    what ``/metrics`` exports as ``_sum`` / ``_count``)."""
+    m = get_metrics()
+    s0, n0 = m.counter_state()[1].get("scheduler.queue_wait", (0.0, 0))
+    bat = _batcher(scope_engine)  # two slots
+    res = bat.generate_many(["turn on the lights", "play some jazz",
+                             "dim the bedroom lights", "what time is it"])
+    assert all(r.error is None for r in res)
+    waits = [r.queue_ms for r in res]
+    assert max(waits[:2]) < 50.0  # admitted by the first step
+    # the last two wait for a slot: at least one whole decode chunk
+    chunk_ms = min(s["wall_ms"] for s in get_steplog().steps() if s.get("tokens"))
+    assert min(waits[2:]) > max(waits[:2]) and min(waits[2:]) >= 0.5 * chunk_ms
+    s1, n1 = m.counter_state()[1]["scheduler.queue_wait"]
+    assert n1 - n0 == 4 and s1 - s0 == pytest.approx(sum(waits), rel=1e-6)
+    assert "scheduler.admissions" not in m.counter_state()[0]  # one copy, not two
+    # an idle batcher admits at once
+    bat2 = _batcher(scope_engine)
+    (r,) = bat2.generate_many(["stop"])
+    assert r.queue_ms < 50.0
+
+
+def test_profiler_capture_holds_the_step_and_its_admissions(scope_engine, tmp_path):
+    """A CPU ``jax.profiler`` capture of three requests' steps: ``sched.step`` on the
+    host plane with ``sched.admit.request`` and its parts nested inside,
+    on the clock the device's operations are on."""
+    from jax.profiler import ProfileData
+
+    bat = _batcher(scope_engine)
+    bat.generate_many(["stop"])  # compiled before the capture
+    get_steplog().clear()
+    for p in ["turn on the lights", "play some jazz", "what time is it"]:
+        bat.submit(p)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        bat.run_until_done()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    spans = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                spans += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                           dict(ev.stats)) for ev in line.events
+                          if ev.name.startswith("sched.")]
+    steps = [s for s in spans if s[0] == "sched.step"]
+    assert len(steps) >= 3
+    assert [s[3]["step_num"] for s in steps] == [r["seq"] for r in get_steplog().steps()]
+    reqs = [s for s in spans if s[0] == "sched.admit.request"]
+    assert len(reqs) == 3  # two slots: two admissions, then the third
+    inside = lambda a, b: b[1] <= a[1] and a[2] <= b[2]
+    for r in reqs:
+        assert sum(inside(r, s) for s in steps) == 1
+        assert {"rid", "queue_ms", "prompt_tokens", "cached_tokens"} <= set(r[3])
+        kids = {s[0].rsplit(".", 1)[1] for s in spans
+                if s[0].startswith("sched.admit.request.") and inside(s, r)}
+        assert {"tokenize", "alloc", "prefill_call", "first_token_call",
+                "slot_state", "bookkeeping"} <= kids
+    names = {s[0] for s in spans}
+    assert {"sched.admit", "sched.decode_dispatch", "sched.readback",
+            "sched.release"} <= names
+
+
+def _hlo_shape(text: str):
+    """(instruction count, the fusions' result types in order) of an
+    optimised HLO module, metadata left out."""
+    import re
+
+    instr = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", text, re.M)
+    return len(instr), [t for t, op in instr if op == "fusion"]
+
+
+def test_the_compile_cache_keys_on_names_and_not_on_paths(monkeypatch):
+    """Scope names are metadata, which JAX leaves out of the persistent
+    cache's key by default: an executable written by another commit would
+    come back with that commit's names, or none. ``place_compile_cache``
+    puts metadata into the key and takes the Python tracebacks out of it,
+    so what a lowered module carries besides the computation is its names
+    and no file path or line (two checkouts share a cache, two
+    vocabularies do not)."""
+    from tpu_voice_agent.utils.compilecache import place_compile_cache
+
+    names = ("jax_compilation_cache_include_metadata_in_key", "jax_traceback_in_locations_limit")
+    keep = {k: getattr(jax.config, k) for k in names}
+
+    def lowered():
+        def f(x):
+            with jax.named_scope("layer/ffn"):
+                return jnp.sin(x) @ x
+        return jax.jit(f).lower(jnp.ones((8, 8))).as_text(debug_info=True)
+
+    try:
+        monkeypatch.delenv("JAX_TRACEBACK_IN_LOCATIONS_LIMIT", raising=False)
+        place_compile_cache()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        text = lowered()
+        assert "layer/ffn/dot_general" in text and ".py" not in text
+        # an operator who asks JAX for source lines keeps them
+        jax.config.update("jax_traceback_in_locations_limit", 10)
+        monkeypatch.setenv("JAX_TRACEBACK_IN_LOCATIONS_LIMIT", "10")
+        place_compile_cache()
+        assert "test_steplog.py" in lowered()
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+
+
+@pytest.mark.parametrize("program", ["forward_paged", "paged_chunk_decode_loop"])
+def test_named_scopes_change_metadata_only(program, monkeypatch):
+    """Scopes name ops; they must not add one. The optimised HLO of the
+    prefill forward and of the paged chunk loop has the same instruction
+    count and the same fusions with ``jax.named_scope`` switched off."""
+    import contextlib
+
+    from tpu_voice_agent.models.llama import forward_paged
+    from tpu_voice_agent.serve import PagedDecodeEngine
+    from tpu_voice_agent.serve.paged import paged_chunk_decode_loop
+
+    eng = PagedDecodeEngine(preset="test-tiny", max_len=256, batch_slots=2,
+                            block_size=32, prefill_buckets=(32,), fast_forward=4)
+    B = eng.batch_slots
+    z = jnp.zeros((B,), jnp.int32)
+
+    def compiled() -> str:
+        jax.clear_caches()
+        if program == "forward_paged":
+            low = forward_paged.__wrapped__.lower(  # under watch_compiles
+                eng.params, eng.cfg, jnp.zeros((1, 32), jnp.int32),
+                jnp.arange(32, dtype=jnp.int32)[None] + 64, eng.k_pool, eng.v_pool,
+                eng.block_tables[0][None], attn_impl="xla", fresh_block=False,
+                gather_blocks=4)
+        else:
+            low = paged_chunk_decode_loop.__wrapped__.lower(
+                eng.params, eng.cfg, eng.k_pool, eng.v_pool, eng.block_tables,
+                z, z, z, jnp.ones((B,), bool), z, z + 8, eng.tables_ff,
+                eng.byte_len_table, jax.random.PRNGKey(0), jnp.float32(0.7),
+                jnp.int32(1000), trash_idx=eng._trash_idx,
+                logit_mask=eng.logit_mask, chunk_steps=4, kernels=eng.kernels,
+                eos_id=eng.eos_id, pad_id=eng.pad_id, max_len=eng.max_len)
+        return low.compile().as_text()
+
+    # the persistent compile cache keys on the computation and not on its
+    # metadata: left on, the second compile below could be served the first
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        scoped = compiled()
+        monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        bare = compiled()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+        jax.clear_caches()
+    assert "layer/ffn" in scoped and "lm_head" in scoped
+    if program == "paged_chunk_decode_loop":
+        assert "grammar_mask_sample" in scoped and "loop_carry" in scoped
+    assert "layer/ffn" not in bare
+    assert _hlo_shape(scoped) == _hlo_shape(bare)
+
+
+def _pallas_calls():
+    import ast
+
+    out = []
+    for path in sorted((ROOT / "tpu_voice_agent" / "ops").glob("*.py")):
+        tree = ast.parse(path.read_text())
+        for fn in tree.body:
+            if not isinstance(fn, ast.FunctionDef):
+                continue
+            for node in ast.walk(fn):
+                if (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+                        and node.func.attr == "pallas_call"):
+                    name = next((kw.value for kw in node.keywords if kw.arg == "name"), None)
+                    out.append(pytest.param(fn.name, name,
+                                            id=f"{path.stem}.{fn.name}"))
+    return out
+
+
+@pytest.mark.parametrize("entry_point,name", _pallas_calls())
+def test_every_pallas_call_is_named_after_its_entry_point(entry_point, name):
+    """The trace shows a custom call under its kernel's ``name``: each is
+    the Python function a reader of the trace would grep for."""
+    import ast
+
+    assert isinstance(name, ast.Constant) and name.value == entry_point
 
 
 def test_warm_restart_rearms_the_fence(scope_engine):

@@ -178,6 +178,32 @@ def test_chunked_prefill_token_identity_and_batchmate_isolation(
     assert eng_plain.allocator.blocks_in_use == 0  # radix off: all reclaimed
 
 
+def test_chunked_admissions_land_in_the_ledger_with_their_last_chunk(
+        eng_plain, monkeypatch):
+    """A chunked admission's start and middle chunks are spans on the trace
+    and no ledger entry; the chunk that lands it writes ONE, in the step it
+    lands in, carrying the queue wait stamped when the request was popped —
+    so ``admissions`` still counts ``admitted`` step by step."""
+    from tpu_voice_agent.utils import get_steplog
+
+    get_steplog().clear()
+    res = _run(eng_plain, PROMPTS, chunk_tokens=64, monkeypatch=monkeypatch)
+    assert all(r.error is None for r in res)
+    steps = get_steplog().steps()
+    get_steplog().clear()
+    for s in steps:
+        assert len(s.get("admissions", [])) == s.get("admitted", 0)
+    adm = [a for s in steps for a in s.get("admissions", [])]
+    assert len(adm) == len(PROMPTS)
+    chunked = [a for a in adm if "tokenize_ms" not in a]  # encoded steps earlier
+    assert len(chunked) >= 2
+    for a in chunked:
+        assert a["prefill_call_ms"] > 0 and a["first_token_call_ms"] > 0
+        assert a["prompt_tokens"] > 64 and a["queue_ms"] >= 0.0
+    assert sorted(round(a["queue_ms"], 3) for a in adm) == sorted(
+        round(r.queue_ms, 3) for r in res)
+
+
 def test_chunked_prefill_identity_with_radix(eng_off, eng_on, monkeypatch):
     """Chunked admissions against the radix plane: the first (cold) run
     seeds chains, the second admits warm through begin_chunked_prefill's

@@ -133,12 +133,16 @@ PINNED: dict[str, str] = {
     # fused decode tail"): kv_quant_bits is the active-tier dial the bench
     # kv_quant rows and the HBM-plan drift check key on, kv_bytes_per_block
     # the bytes-denominated capacity unit (block counts stopped being a
-    # unit of HBM when KV_QUANT halved them), fused_mask_sample_ms the
-    # dispatch-side wall of the one host-dispatched fused-tail instance —
-    # renaming any of these blinds the bench capacity/latency verdicts
+    # unit of HBM when KV_QUANT halved them) — renaming either blinds the
+    # bench capacity verdicts
     "paged.kv_quant_bits": "gauge",
     "paged.kv_bytes_per_block": "gauge",
-    "engine.step.fused_mask_sample_ms": "gauge",
+    # queue wait as its own number, and one request/token count for both
+    # brain backends (ISSUE 24): dashboards key on these names
+    "scheduler.queue_wait": "histogram",
+    "brain.parse_completed": "counter",
+    "brain.parse_tokens": "counter",
+    "voice.stt_feed_lag": "histogram",
     # replicated brain tier (ISSUE 10, services/router.py, docs/
     # RESILIENCE.md "Replica fault domain"): sessions_rehomed is the
     # observable failover cost (one cold re-prefill per forced move),

@@ -282,15 +282,16 @@ def _layer_qkv(p, x, cfg: LlamaConfig, cos, sin, cs=_identity_cs,
     B, T = x.shape[:2]
     nq = n_heads if n_heads is not None else cfg.n_heads
     nkv = n_kv_heads if n_kv_heads is not None else cfg.n_kv_heads
-    h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    h = cs(h, "act")
-    q = _qe("btd,dh->bth", h, p["wq"]).astype(x.dtype)
-    k = _qe("btd,dh->bth", h, p["wk"]).astype(x.dtype)
-    v = _qe("btd,dh->bth", h, p["wv"]).astype(x.dtype)
-    q = cs(q.reshape(B, T, nq, cfg.head_dim), "heads")
-    k = cs(k.reshape(B, T, nkv, cfg.head_dim), "kv_heads")
-    v = cs(v.reshape(B, T, nkv, cfg.head_dim), "kv_heads")
-    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+    with jax.named_scope("layer/attn_qkv"):
+        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        h = cs(h, "act")
+        q = _qe("btd,dh->bth", h, p["wq"]).astype(x.dtype)
+        k = _qe("btd,dh->bth", h, p["wk"]).astype(x.dtype)
+        v = _qe("btd,dh->bth", h, p["wv"]).astype(x.dtype)
+        q = cs(q.reshape(B, T, nq, cfg.head_dim), "heads")
+        k = cs(k.reshape(B, T, nkv, cfg.head_dim), "kv_heads")
+        v = cs(v.reshape(B, T, nkv, cfg.head_dim), "kv_heads")
+        return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
 def _moe_ffn_grouped(p, h, cfg: LlamaConfig):
@@ -397,17 +398,19 @@ def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs):
     """Shared decoder-layer back half: output projection + residual, then
     the MLP (dense SwiGLU, or routed MoE when cfg.n_experts > 0) +
     residual. ``attn`` is (B, T, n_heads * head_dim)."""
-    attn = _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
-    x = x + cs(attn, "act")
-    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-    if cfg.n_experts > 0:
-        return x + cs(_moe_ffn(p, h, cfg), "act")
-    gate = _qe("btd,df->btf", h, p["w_gate"])
-    up = _qe("btd,df->btf", h, p["w_up"])
-    act = (jax.nn.silu(gate) * up).astype(x.dtype)
-    act = cs(act, "ffn")
-    down = _qe("btf,fd->btd", act, p["w_down"]).astype(x.dtype)
-    return x + cs(down, "act")
+    with jax.named_scope("layer/attn_out"):
+        attn = _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
+        x = x + cs(attn, "act")
+    with jax.named_scope("layer/ffn"):
+        h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+        if cfg.n_experts > 0:
+            return x + cs(_moe_ffn(p, h, cfg), "act")
+        gate = _qe("btd,df->btf", h, p["w_gate"])
+        up = _qe("btd,df->btf", h, p["w_up"])
+        act = (jax.nn.silu(gate) * up).astype(x.dtype)
+        act = cs(act, "ffn")
+        down = _qe("btf,fd->btd", act, p["w_down"]).astype(x.dtype)
+        return x + cs(down, "act")
 
 
 # ---------------------------------------------------------------- forward
@@ -449,9 +452,10 @@ def forward(
     S = kv_cache["k"].shape[2]
     cs = lambda x, name: rules.constrain(x, name) if rules is not None else x
 
-    x = params["embed"][tokens]  # (B, T, D)
-    x = cs(x, "act")
-    cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]  # (B, T, D)
+        x = cs(x, "act")
+        cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
 
     # validity mask: slot s valid if s <= max written position for that seq.
     # caller guarantees contiguous writes, so max(positions) is the frontier.
@@ -469,60 +473,65 @@ def forward(
         p, li = layer_in
         q, k, v = _layer_qkv(p, x, cfg, cos, sin, cs)
 
-        kc = kc.at[li, batch_idx, positions].set(k.astype(kc.dtype))
-        vc = vc.at[li, batch_idx, positions].set(v.astype(vc.dtype))
+        with jax.named_scope("layer/kv_write"):
+            kc = kc.at[li, batch_idx, positions].set(k.astype(kc.dtype))
+            vc = vc.at[li, batch_idx, positions].set(v.astype(vc.dtype))
 
-        if attn_impl == "pallas" and T == 1:
-            from ..ops import sharded_decode_attention_layer
+        with jax.named_scope("layer/attn"):
+            if attn_impl == "pallas" and T == 1:
+                from ..ops import sharded_decode_attention_layer
 
-            # per-row frontiers; idle rows park writes at slot 0 so this
-            # stays proportional to real context (see chunk_decode_loop).
-            # The kernel indexes the layer's plane of the STACKED cache via
-            # scalar prefetch — slicing cache[li] for a per-layer kernel
-            # operand would materialize a full-plane HBM copy per layer per
-            # token. On a mesh it runs per-shard under shard_map.
-            mesh = rules.mesh if rules is not None else None
-            attn = sharded_decode_attention_layer(
-                mesh, q[:, 0], kc, vc, frontier + 1, li
-            ).reshape(B, T, -1)
-        elif (attn_impl == "pallas" and not fresh_block
-              and T <= MAX_BLOCK_DECODE_T):
-            from ..ops import sharded_decode_block_attention_layer
+                # per-row frontiers; idle rows park writes at slot 0 so this
+                # stays proportional to real context (see chunk_decode_loop).
+                # The kernel indexes the layer's plane of the STACKED cache via
+                # scalar prefetch — slicing cache[li] for a per-layer kernel
+                # operand would materialize a full-plane HBM copy per layer per
+                # token. On a mesh it runs per-shard under shard_map.
+                mesh = rules.mesh if rules is not None else None
+                attn = sharded_decode_attention_layer(
+                    mesh, q[:, 0], kc, vc, frontier + 1, li
+                ).reshape(B, T, -1)
+            elif (attn_impl == "pallas" and not fresh_block
+                  and T <= MAX_BLOCK_DECODE_T):
+                from ..ops import sharded_decode_block_attention_layer
 
-            # small mid-sequence block: the grammar fast-forward step is a
-            # (B, 1+W) forward, and the XLA cache fallback reads the cache
-            # at CAPACITY for every row (the round-3 reason ff was
-            # single-request only). This kernel reads each row's cache up
-            # to its own frontier, with intra-block causality from the
-            # queries' write positions — batched ff costs a T=1 step plus
-            # the riding chain tokens.
-            mesh = rules.mesh if rules is not None else None
-            attn = sharded_decode_block_attention_layer(
-                mesh, q, kc, vc, positions, li
-            ).reshape(B, T, -1)
-        elif attn_impl == "pallas" and fresh_block:
-            from ..ops import sharded_flash_attention
+                # small mid-sequence block: the grammar fast-forward step is a
+                # (B, 1+W) forward, and the XLA cache fallback reads the cache
+                # at CAPACITY for every row (the round-3 reason ff was
+                # single-request only). This kernel reads each row's cache up
+                # to its own frontier, with intra-block causality from the
+                # queries' write positions — batched ff costs a T=1 step plus
+                # the riding chain tokens.
+                mesh = rules.mesh if rules is not None else None
+                attn = sharded_decode_block_attention_layer(
+                    mesh, q, kc, vc, positions, li
+                ).reshape(B, T, -1)
+            elif attn_impl == "pallas" and fresh_block:
+                from ..ops import sharded_flash_attention
 
-            # fresh sequence starting at position 0: attention over the
-            # block's own k/v is exactly attention over the cache
-            mesh = rules.mesh if rules is not None else None
-            attn = sharded_flash_attention(mesh, q, k, v, causal=True).reshape(B, T, -1)
-        else:
-            attn = _attend(q, kc[li], vc[li], positions, kv_len_mask)
+                # fresh sequence starting at position 0: attention over the
+                # block's own k/v is exactly attention over the cache
+                mesh = rules.mesh if rules is not None else None
+                attn = sharded_flash_attention(mesh, q, k, v, causal=True).reshape(B, T, -1)
+            else:
+                attn = _attend(q, kc[li], vc[li], positions, kv_len_mask)
         x = _layer_out(p, x, attn, cfg, cs)
         return (x, kc, vc), None
 
     layer_fn = jax.checkpoint(layer) if remat else layer
-    (x, new_k, new_v), _ = jax.lax.scan(
-        lambda carry, inp: layer_fn(carry, inp),
-        (x, kv_cache["k"], kv_cache["v"]),
-        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
-        unroll=unroll,
-    )
+    with jax.named_scope("layers"):  # names the scan's own slices of the stacked weights
+        (x, new_k, new_v), _ = jax.lax.scan(
+            lambda carry, inp: layer_fn(carry, inp),
+            (x, kv_cache["k"], kv_cache["v"]),
+            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+            unroll=unroll,
+        )
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _qe("btd,dv->btv", x, params["lm_head"])
-    logits = cs(logits, "logits")
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        logits = _qe("btd,dv->btv", x, params["lm_head"])
+        logits = cs(logits, "logits")
     return logits, {"k": new_k, "v": new_v}
 
 
@@ -589,9 +598,10 @@ def forward_paged(
     bits = {None: 16, "int8": 8, "int4": 4}[kv_quant]
     hdp = k_pool.shape[4]  # stored last-axis width (hd, or hd/2 packed int4)
 
-    x = params["embed"][tokens]
-    x = cs(x, "act")
-    cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+        x = cs(x, "act")
+        cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
     frontier = jnp.max(positions, axis=1)  # (B,)
     kv_len_mask = jnp.arange(S)[None, :] <= frontier[:, None]
     # pool slot for each written token: table[b, pos//bs] * bs + pos%bs
@@ -607,122 +617,128 @@ def forward_paged(
         p, li = layer_in
         q, k, v = _layer_qkv(p, x, cfg, cos, sin, cs)
 
-        kp_flat = kp.reshape(L, N * bs, cfg.n_kv_heads, hdp)
-        vp_flat = vp.reshape(L, N * bs, cfg.n_kv_heads, hdp)
-        if kv_quant is None:
-            kp = kp_flat.at[li, flat_idx].set(k.astype(kp.dtype)).reshape(kp.shape)
-            vp = vp_flat.at[li, flat_idx].set(v.astype(vp.dtype)).reshape(vp.shape)
-        else:
-            from ..ops.kvquant import quantize_kv
-
-            # quantize-on-write: one deterministic rowwise quantization at
-            # the scatter, values and their scales landing at the SAME
-            # flat index (a shared/rolled-back/reserved block carries its
-            # scales by construction)
-            qk, sk = quantize_kv(k, kv_quant)
-            qv, sv = quantize_kv(v, kv_quant)
-            kp = kp_flat.at[li, flat_idx].set(qk).reshape(kp.shape)
-            vp = vp_flat.at[li, flat_idx].set(qv).reshape(vp.shape)
-            ksc_flat = ksc.reshape(L, N * bs, cfg.n_kv_heads)
-            vsc_flat = vsc.reshape(L, N * bs, cfg.n_kv_heads)
-            ksc = ksc_flat.at[li, flat_idx].set(sk).reshape(ksc.shape)
-            vsc = vsc_flat.at[li, flat_idx].set(sv).reshape(vsc.shape)
-
-        if attn_impl == "pallas" and T == 1:
-            mesh = rules.mesh if rules is not None else None
+        with jax.named_scope("layer/kv_write"):
+            kp_flat = kp.reshape(L, N * bs, cfg.n_kv_heads, hdp)
+            vp_flat = vp.reshape(L, N * bs, cfg.n_kv_heads, hdp)
             if kv_quant is None:
-                from ..ops import sharded_paged_attention
-
-                attn = sharded_paged_attention(
-                    mesh, q[:, 0], kp, vp, block_tables, frontier + 1, li
-                ).reshape(B, T, -1)
+                kp = kp_flat.at[li, flat_idx].set(k.astype(kp.dtype)).reshape(kp.shape)
+                vp = vp_flat.at[li, flat_idx].set(v.astype(vp.dtype)).reshape(vp.shape)
             else:
-                from ..ops import sharded_paged_attention_quant
+                from ..ops.kvquant import quantize_kv
 
-                # fused dequant: the kernel scales score/probability tiles
-                # by the per-position scales — half (a quarter) of the KV
-                # bytes cross HBM and fp KV never materializes
-                attn = sharded_paged_attention_quant(
-                    mesh, q[:, 0], kp, vp, ksc, vsc, block_tables,
-                    frontier + 1, li, bits=bits,
-                ).reshape(B, T, -1)
-        elif (attn_impl == "pallas" and not fresh_block
-              and T <= MAX_BLOCK_DECODE_T):
-            # small mid-sequence block (grammar fast-forward chain step):
-            # the paged twin of the dense frontier-read block kernel — T
-            # queries per row read the row's own pool blocks up to its own
-            # positions; no per-layer table gather
-            mesh = rules.mesh if rules is not None else None
-            if kv_quant is None:
-                from ..ops import sharded_paged_block_attention
+                # quantize-on-write: one deterministic rowwise quantization at
+                # the scatter, values and their scales landing at the SAME
+                # flat index (a shared/rolled-back/reserved block carries its
+                # scales by construction)
+                qk, sk = quantize_kv(k, kv_quant)
+                qv, sv = quantize_kv(v, kv_quant)
+                kp = kp_flat.at[li, flat_idx].set(qk).reshape(kp.shape)
+                vp = vp_flat.at[li, flat_idx].set(qv).reshape(vp.shape)
+                ksc_flat = ksc.reshape(L, N * bs, cfg.n_kv_heads)
+                vsc_flat = vsc.reshape(L, N * bs, cfg.n_kv_heads)
+                ksc = ksc_flat.at[li, flat_idx].set(sk).reshape(ksc.shape)
+                vsc = vsc_flat.at[li, flat_idx].set(sv).reshape(vsc.shape)
 
-                attn = sharded_paged_block_attention(
-                    mesh, q, kp, vp, block_tables, positions, li
-                ).reshape(B, T, -1)
-            else:
-                from ..ops import sharded_paged_block_attention_quant
-
-                attn = sharded_paged_block_attention_quant(
-                    mesh, q, kp, vp, ksc, vsc, block_tables, positions, li,
-                    bits=bits,
-                ).reshape(B, T, -1)
-        elif fresh_block and T > 1:
-            # fresh sequence starting at position 0: attention over the
-            # block's own k/v IS attention over the sequence — no pool
-            # gather at all (the scatter above still persists the KV).
-            # Under KV_QUANT the attended values are the quantize->dequant
-            # roundtrip of the block — exactly what the pool stores and a
-            # later decode read dequantizes, so prefill logits agree with
-            # the quantized serving plane, not the fp one.
-            if kv_quant is not None:
-                from ..ops.kvquant import dequantize_kv, quantize_kv
-
-                k_at = dequantize_kv(*quantize_kv(k, kv_quant), kv_quant)
-                v_at = dequantize_kv(*quantize_kv(v, kv_quant), kv_quant)
-            else:
-                k_at = k.astype(kp.dtype)
-                v_at = v.astype(vp.dtype)
-            if attn_impl == "pallas":
-                from ..ops import sharded_flash_attention
-
+        with jax.named_scope("layer/attn"):
+            if attn_impl == "pallas" and T == 1:
                 mesh = rules.mesh if rules is not None else None
-                attn = sharded_flash_attention(mesh, q, k_at, v_at,
-                                               causal=True).reshape(B, T, -1)
-            else:
-                # attend the POOL-dtype values (what the scatter persisted
-                # and decode later reads) — raw compute-dtype k/v would
-                # break prefill parity with the dense engine's bf16 cache
-                attn = _attend(q, k_at, v_at,
-                               positions, jnp.ones((B, T), dtype=bool))
-        else:
-            # mid-sequence prefill (prefix-cached suffix): gather the row's
-            # COVERED blocks to a contiguous view once per layer
-            tbl = block_tables[:, :nb]
-            if kv_quant is None:
-                kl = kp[li][tbl].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-                vl = vp[li][tbl].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-            else:
-                from ..ops.kvquant import dequantize_kv
+                if kv_quant is None:
+                    from ..ops import sharded_paged_attention
 
-                kl = dequantize_kv(
-                    kp[li][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
-                    ksc[li][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
-                vl = dequantize_kv(
-                    vp[li][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
-                    vsc[li][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
-            attn = _attend(q, kl, vl, positions, kv_len_mask)
+                    attn = sharded_paged_attention(
+                        mesh, q[:, 0], kp, vp, block_tables, frontier + 1, li
+                    ).reshape(B, T, -1)
+                else:
+                    from ..ops import sharded_paged_attention_quant
+
+                    # fused dequant: the kernel scales score/probability tiles
+                    # by the per-position scales — half (a quarter) of the KV
+                    # bytes cross HBM and fp KV never materializes
+                    attn = sharded_paged_attention_quant(
+                        mesh, q[:, 0], kp, vp, ksc, vsc, block_tables,
+                        frontier + 1, li, bits=bits,
+                    ).reshape(B, T, -1)
+            elif (attn_impl == "pallas" and not fresh_block
+                  and T <= MAX_BLOCK_DECODE_T):
+                # small mid-sequence block (grammar fast-forward chain step):
+                # the paged twin of the dense frontier-read block kernel — T
+                # queries per row read the row's own pool blocks up to its own
+                # positions; no per-layer table gather
+                mesh = rules.mesh if rules is not None else None
+                if kv_quant is None:
+                    from ..ops import sharded_paged_block_attention
+
+                    attn = sharded_paged_block_attention(
+                        mesh, q, kp, vp, block_tables, positions, li
+                    ).reshape(B, T, -1)
+                else:
+                    from ..ops import sharded_paged_block_attention_quant
+
+                    attn = sharded_paged_block_attention_quant(
+                        mesh, q, kp, vp, ksc, vsc, block_tables, positions, li,
+                        bits=bits,
+                    ).reshape(B, T, -1)
+            elif fresh_block and T > 1:
+                # fresh sequence starting at position 0: attention over the
+                # block's own k/v IS attention over the sequence — no pool
+                # gather at all (the scatter above still persists the KV).
+                # Under KV_QUANT the attended values are the quantize->dequant
+                # roundtrip of the block — exactly what the pool stores and a
+                # later decode read dequantizes, so prefill logits agree with
+                # the quantized serving plane, not the fp one.
+                if kv_quant is not None:
+                    from ..ops.kvquant import dequantize_kv, quantize_kv
+
+                    k_at = dequantize_kv(*quantize_kv(k, kv_quant), kv_quant)
+                    v_at = dequantize_kv(*quantize_kv(v, kv_quant), kv_quant)
+                else:
+                    k_at = k.astype(kp.dtype)
+                    v_at = v.astype(vp.dtype)
+                if attn_impl == "pallas":
+                    from ..ops import sharded_flash_attention
+
+                    mesh = rules.mesh if rules is not None else None
+                    attn = sharded_flash_attention(mesh, q, k_at, v_at,
+                                                   causal=True).reshape(B, T, -1)
+                else:
+                    # attend the POOL-dtype values (what the scatter persisted
+                    # and decode later reads) — raw compute-dtype k/v would
+                    # break prefill parity with the dense engine's bf16 cache
+                    attn = _attend(q, k_at, v_at,
+                                   positions, jnp.ones((B, T), dtype=bool))
+            else:
+                # mid-sequence prefill (prefix-cached suffix): gather the row's
+                # COVERED blocks to a contiguous view once per layer
+                with jax.named_scope("kv_gather"):
+                    tbl = block_tables[:, :nb]
+                    if kv_quant is None:
+                        kl = kp[li][tbl].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+                        vl = vp[li][tbl].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+                    else:
+                        from ..ops.kvquant import dequantize_kv
+
+                        kl = dequantize_kv(
+                            kp[li][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
+                            ksc[li][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
+                        vl = dequantize_kv(
+                            vp[li][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
+                            vsc[li][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
+                attn = _attend(q, kl, vl, positions, kv_len_mask)
         x = _layer_out(p, x, attn, cfg, cs)
         return (x, kp, vp, ksc, vsc), None
 
-    (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
-        layer,
-        (x, k_pool, v_pool, k_scale, v_scale),
-        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
-    )
+    with jax.named_scope("layers"):
+        (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
+            layer,
+            (x, k_pool, v_pool, k_scale, v_scale),
+            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        )
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _qe("btd,dv->btv", x, params["lm_head"])
-    logits = cs(logits, "logits")
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        logits = _qe("btd,dv->btv", x, params["lm_head"])
+        logits = cs(logits, "logits")
     return logits, k_pool, v_pool, k_scale, v_scale
 
 

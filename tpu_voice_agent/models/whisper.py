@@ -181,46 +181,48 @@ def encoder_forward(
     p = params["encoder"]
     cs = lambda x, name: rules.constrain(x, name) if rules is not None else x
     dn = ("NWC", "WIO", "NWC")
-    x = jax.lax.conv_general_dilated(
-        mel.astype(p["conv1"]["w"].dtype), p["conv1"]["w"], (1,), "SAME", dimension_numbers=dn
-    ) + p["conv1"]["b"]
-    x = jax.nn.gelu(x)
-    x = jax.lax.conv_general_dilated(
-        x, p["conv2"]["w"], (2,), "SAME", dimension_numbers=dn
-    ) + p["conv2"]["b"]
-    x = jax.nn.gelu(x)  # (B, T//2, d)
-    T2 = x.shape[1]
-    table = jnp.asarray(_sinusoid_pos(cfg.enc_positions, cfg.d_model))
-    if pos_offset is None:
-        pos = table[:T2]
-    else:
-        pos = jax.lax.dynamic_slice_in_dim(table, pos_offset, T2, axis=0)
-    x = (x + pos.astype(x.dtype)[None])
-    x = cs(x, "act")
+    with jax.named_scope("encoder/conv"):
+        x = jax.lax.conv_general_dilated(
+            mel.astype(p["conv1"]["w"].dtype), p["conv1"]["w"], (1,), "SAME", dimension_numbers=dn
+        ) + p["conv1"]["b"]
+        x = jax.nn.gelu(x)
+        x = jax.lax.conv_general_dilated(
+            x, p["conv2"]["w"], (2,), "SAME", dimension_numbers=dn
+        ) + p["conv2"]["b"]
+        x = jax.nn.gelu(x)  # (B, T//2, d)
+        T2 = x.shape[1]
+        table = jnp.asarray(_sinusoid_pos(cfg.enc_positions, cfg.d_model))
+        if pos_offset is None:
+            pos = table[:T2]
+        else:
+            pos = jax.lax.dynamic_slice_in_dim(table, pos_offset, T2, axis=0)
+        x = (x + pos.astype(x.dtype)[None])
+        x = cs(x, "act")
 
     nh, hd = cfg.n_heads, cfg.head_dim
 
     def layer(x, lp):
-        h = layer_norm(x, {"g": lp["ln1"]["g"], "b": lp["ln1"]["b"]}, cfg.norm_eps)
-        a = lp["attn"]
-        q = _proj(h, a["wq"], a["bq"])
-        k = _proj(h, a["wk"])
-        v = _proj(h, a["wv"], a["bv"])
-        if attn_impl == "pallas":
-            from ..ops import sharded_flash_attention
+        with jax.named_scope("encoder/layer"):
+            h = layer_norm(x, {"g": lp["ln1"]["g"], "b": lp["ln1"]["b"]}, cfg.norm_eps)
+            a = lp["attn"]
+            q = _proj(h, a["wq"], a["bq"])
+            k = _proj(h, a["wk"])
+            v = _proj(h, a["wv"], a["bv"])
+            if attn_impl == "pallas":
+                from ..ops import sharded_flash_attention
 
-            B, T2l, _ = q.shape
-            mesh = rules.mesh if rules is not None else None
-            attn = sharded_flash_attention(
-                mesh, q.reshape(B, T2l, nh, hd), k.reshape(B, T2l, nh, hd),
-                v.reshape(B, T2l, nh, hd), causal=False,
-            ).reshape(B, T2l, nh * hd)
-        else:
-            attn = _mha(q, k, v, None, nh, hd)
-        x = x + cs(_proj(attn, a["wo"], a["bo"]), "act")
-        h = layer_norm(x, {"g": lp["ln2"]["g"], "b": lp["ln2"]["b"]}, cfg.norm_eps)
-        h = jax.nn.gelu(_proj(h, lp["w1"], lp["b1"]))
-        x = x + cs(_proj(h, lp["w2"], lp["b2"]), "act")
+                B, T2l, _ = q.shape
+                mesh = rules.mesh if rules is not None else None
+                attn = sharded_flash_attention(
+                    mesh, q.reshape(B, T2l, nh, hd), k.reshape(B, T2l, nh, hd),
+                    v.reshape(B, T2l, nh, hd), causal=False,
+                ).reshape(B, T2l, nh * hd)
+            else:
+                attn = _mha(q, k, v, None, nh, hd)
+            x = x + cs(_proj(attn, a["wo"], a["bo"]), "act")
+            h = layer_norm(x, {"g": lp["ln2"]["g"], "b": lp["ln2"]["b"]}, cfg.norm_eps)
+            h = jax.nn.gelu(_proj(h, lp["w1"], lp["b1"]))
+            x = x + cs(_proj(h, lp["w2"], lp["b2"]), "act")
         return x, None
 
     x, _ = jax.lax.scan(lambda carry, lp: layer(carry, lp), x, p["layers"])
@@ -318,53 +320,56 @@ def decoder_forward(
 
     def layer(x, inp):
         lp, k_cache, v_cache, ck, cv = inp
-        # self attention with cache
-        h = layer_norm(x, lp["ln1"], cfg.norm_eps)
-        a = lp["self_attn"]
-        q = _proj(h, a["wq"], a["bq"]).reshape(B, T, nh, hd)
-        k = _proj(h, a["wk"]).reshape(B, T, nh, hd)
-        v = _proj(h, a["wv"], a["bv"]).reshape(B, T, nh, hd)
-        k_cache = k_cache.at[batch_idx, positions].set(k)
-        v_cache = v_cache.at[batch_idx, positions].set(v)
-        if use_pallas_step:
-            from ..ops import sharded_decode_attention
+        with jax.named_scope("decoder/self_attn"):
+            # self attention with cache
+            h = layer_norm(x, lp["ln1"], cfg.norm_eps)
+            a = lp["self_attn"]
+            q = _proj(h, a["wq"], a["bq"]).reshape(B, T, nh, hd)
+            k = _proj(h, a["wk"]).reshape(B, T, nh, hd)
+            v = _proj(h, a["wv"], a["bv"]).reshape(B, T, nh, hd)
+            k_cache = k_cache.at[batch_idx, positions].set(k)
+            v_cache = v_cache.at[batch_idx, positions].set(v)
+            if use_pallas_step:
+                from ..ops import sharded_decode_attention
 
-            mesh = rules.mesh if rules is not None else None
-            attn = sharded_decode_attention(mesh, q[:, 0], k_cache, v_cache, frontier + 1)
-            attn = attn.reshape(B, T, nh * hd).astype(x.dtype)
-        else:
-            scores = jnp.einsum("btnh,bsnh->bnts", q, k_cache, preferred_element_type=jnp.float32)
-            scores = scores * (hd**-0.5)
-            scores = jnp.where(self_mask[:, None, :, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            attn = jnp.einsum("bnts,bsnh->btnh", probs.astype(x.dtype), v_cache,
-                              preferred_element_type=jnp.float32)
-            attn = attn.reshape(B, T, nh * hd).astype(x.dtype)
-        x = x + cs(_proj(attn, a["wo"], a["bo"]), "act")
+                mesh = rules.mesh if rules is not None else None
+                attn = sharded_decode_attention(mesh, q[:, 0], k_cache, v_cache, frontier + 1)
+                attn = attn.reshape(B, T, nh * hd).astype(x.dtype)
+            else:
+                scores = jnp.einsum("btnh,bsnh->bnts", q, k_cache, preferred_element_type=jnp.float32)
+                scores = scores * (hd**-0.5)
+                scores = jnp.where(self_mask[:, None, :, :], scores, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1)
+                attn = jnp.einsum("bnts,bsnh->btnh", probs.astype(x.dtype), v_cache,
+                                  preferred_element_type=jnp.float32)
+                attn = attn.reshape(B, T, nh * hd).astype(x.dtype)
+            x = x + cs(_proj(attn, a["wo"], a["bo"]), "act")
 
-        # cross attention over precomputed encoder K/V
-        h = layer_norm(x, lp["ln2"], cfg.norm_eps)
-        ca = lp["cross_attn"]
-        qc = _proj(h, ca["wq"], ca["bq"]).reshape(B, T, nh, hd)
-        if use_pallas_step:
-            from ..ops import sharded_decode_attention
+        with jax.named_scope("decoder/cross_attn"):
+            # cross attention over precomputed encoder K/V
+            h = layer_norm(x, lp["ln2"], cfg.norm_eps)
+            ca = lp["cross_attn"]
+            qc = _proj(h, ca["wq"], ca["bq"]).reshape(B, T, nh, hd)
+            if use_pallas_step:
+                from ..ops import sharded_decode_attention
 
-            mesh = rules.mesh if rules is not None else None
-            attn = sharded_decode_attention(mesh, qc[:, 0], ck, cv, enc_len)
-            attn = attn.reshape(B, T, nh * hd).astype(x.dtype)
-        else:
-            scores = jnp.einsum("btnh,bsnh->bnts", qc, ck, preferred_element_type=jnp.float32)
-            scores = scores * (hd**-0.5)
-            scores = jnp.where(cross_mask[:, None, :, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            attn = jnp.einsum("bnts,bsnh->btnh", probs.astype(x.dtype), cv,
-                              preferred_element_type=jnp.float32)
-            attn = attn.reshape(B, T, nh * hd).astype(x.dtype)
-        x = x + cs(_proj(attn, ca["wo"], ca["bo"]), "act")
+                mesh = rules.mesh if rules is not None else None
+                attn = sharded_decode_attention(mesh, qc[:, 0], ck, cv, enc_len)
+                attn = attn.reshape(B, T, nh * hd).astype(x.dtype)
+            else:
+                scores = jnp.einsum("btnh,bsnh->bnts", qc, ck, preferred_element_type=jnp.float32)
+                scores = scores * (hd**-0.5)
+                scores = jnp.where(cross_mask[:, None, :, :], scores, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1)
+                attn = jnp.einsum("bnts,bsnh->btnh", probs.astype(x.dtype), cv,
+                                  preferred_element_type=jnp.float32)
+                attn = attn.reshape(B, T, nh * hd).astype(x.dtype)
+            x = x + cs(_proj(attn, ca["wo"], ca["bo"]), "act")
 
-        h = layer_norm(x, lp["ln3"], cfg.norm_eps)
-        h = jax.nn.gelu(_proj(h, lp["w1"], lp["b1"]))
-        x = x + cs(_proj(h, lp["w2"], lp["b2"]), "act")
+        with jax.named_scope("decoder/ffn"):
+            h = layer_norm(x, lp["ln3"], cfg.norm_eps)
+            h = jax.nn.gelu(_proj(h, lp["w1"], lp["b1"]))
+            x = x + cs(_proj(h, lp["w2"], lp["b2"]), "act")
         return x, (k_cache, v_cache)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -372,8 +377,9 @@ def decoder_forward(
         x,
         (p["layers"], self_cache["k"], self_cache["v"], cross_kv["k"], cross_kv["v"]),
     )
-    x = layer_norm(x, p["ln_final"], cfg.norm_eps)
-    logits = jnp.einsum("btd,vd->btv", x, p["tok_emb"], preferred_element_type=jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = layer_norm(x, p["ln_final"], cfg.norm_eps)
+        logits = jnp.einsum("btd,vd->btv", x, p["tok_emb"], preferred_element_type=jnp.float32)
     return logits, {"k": new_k, "v": new_v}
 
 

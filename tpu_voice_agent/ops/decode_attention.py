@@ -145,6 +145,7 @@ def decode_attention(
             pltpu.VMEM((nkv, group, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(kv_len.astype(jnp.int32), qg, k_cache, v_cache)
     return out.reshape(B, nq, hd)
 
@@ -223,6 +224,7 @@ def decode_attention_layer(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nkv, group, hd), q.dtype),
         interpret=interpret,
+        name="decode_attention_layer",
     )(scalars, qg, k_cache, v_cache)
     return out.reshape(B, nq, hd)
 
@@ -470,6 +472,7 @@ def decode_block_attention(
             pltpu.VMEM((nkv, T * group, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_block_attention",
     )(q_positions.reshape(-1).astype(jnp.int32), qg, k_cache, v_cache)
     return (out.reshape(B, nkv, T, group, hd)
                .transpose(0, 2, 1, 3, 4)
@@ -542,6 +545,7 @@ def decode_block_attention_layer(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nkv, T * group, hd), q.dtype),
         interpret=interpret,
+        name="decode_block_attention_layer",
     )(scalars, qg, k_cache, v_cache)
     return (out.reshape(B, nkv, T, group, hd)
                .transpose(0, 2, 1, 3, 4)
@@ -736,6 +740,7 @@ def decode_attention_quant(
             pltpu.VMEM((nkv, group, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention_quant",
     )(kv_len.astype(jnp.int32), qg, k_cache, v_cache, k_scale, v_scale)
     return out.reshape(B, nq, hd)
 

@@ -169,6 +169,7 @@ def flash_attention(
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return jnp.moveaxis(out[:, :, :T, :], 1, 2)
 

@@ -107,6 +107,7 @@ def masked_argmax(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B,), jnp.int32),
         interpret=interpret,
+        name="masked_argmax",
     )(fsm_state.astype(jnp.int32), logits3, mask3)
 
 
@@ -276,6 +277,7 @@ def masked_argmax_advance(
         out_shape=[jax.ShapeDtypeStruct((B,), jnp.int32),
                    jax.ShapeDtypeStruct((B,), jnp.int32)],
         interpret=interpret,
+        name="masked_argmax_advance",
     )(state, logits3, mask3, col2, table.astype(jnp.int32).reshape(S, 1, Cp))
     return tok, nxt
 

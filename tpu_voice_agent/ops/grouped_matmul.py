@@ -97,6 +97,7 @@ def grouped_matmul(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, f), x.dtype),
         interpret=interpret,
+        name="grouped_matmul",
     )(tile_expert.astype(jnp.int32), x, w)
 
 

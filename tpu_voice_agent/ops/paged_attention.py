@@ -147,6 +147,7 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nkv, group, hd), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(scalars, qg, k_pool, v_pool)
     return out.reshape(B, nq, hd)
 
@@ -333,6 +334,7 @@ def paged_attention_quant(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nkv, group, hd), q.dtype),
         interpret=interpret,
+        name="paged_attention_quant",
     )(scalars, qg, k_pool, v_pool, k_scale, v_scale)
     return out.reshape(B, nq, hd)
 
@@ -644,6 +646,7 @@ def paged_block_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nkv, T * group, hd), q.dtype),
         interpret=interpret,
+        name="paged_block_attention",
     )(scalars, qg, k_pool, v_pool)
     return (out.reshape(B, nkv, T, group, hd)
                .transpose(0, 2, 1, 3, 4)
@@ -834,6 +837,7 @@ def paged_block_attention_quant(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nkv, T * group, hd), q.dtype),
         interpret=interpret,
+        name="paged_block_attention_quant",
     )(scalars, qg, k_pool, v_pool, k_scale, v_scale)
     return (out.reshape(B, nkv, T, group, hd)
                .transpose(0, 2, 1, 3, 4)

@@ -32,6 +32,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from ..utils.steplog import span
 from .engine import GenerationResult
 from .scheduler import ContinuousBatcher
 from .stt import SpeechEngine, TranscribeResult
@@ -250,7 +251,8 @@ class ColocatedServing:
                 self.stats.decode_chunks += 1
                 self.stats.trace.append("chunk")
             did = True
-            self._harvest()
+            with span("sched.harvest"):
+                self._harvest()
         return did
 
     @staticmethod
@@ -511,4 +513,7 @@ class ColocatedServing:
                     return
                 if not did and not self._stt_q and not self._call_q \
                         and not self._has_decode_work():
-                    self._work.wait(timeout=0.05)
+                    # on the profiler's trace: the device idles here for
+                    # want of a request, not for want of a faster host
+                    with span("sched.wait_for_work"):
+                        self._work.wait(timeout=0.05)

@@ -27,6 +27,13 @@ from ..models.llama import LlamaConfig, PRESETS, forward, init_kv_cache, init_pa
 from ..ops.backend import resolve_kernels
 from ..parallel.mesh import default_rules, kv_cache_shardings, param_shardings
 from ..utils.compilewatch import get_compile_watcher, watch_compiles
+from ..utils.steplog import (
+    ALLOC_SPAN,
+    FIRST_TOKEN_SPAN,
+    PREFILL_CALL_SPAN,
+    PREFILL_STAGE_SPAN,
+    span,
+)
 
 
 def byte_len_table_for(tokenizer, vocab_size: int) -> jnp.ndarray:
@@ -62,6 +69,9 @@ class GenerationResult:
     prompt_tokens: int = 0  # prompt length in tokens — with cached_tokens
     # it yields the outstanding-prefill measurement the voice service's
     # endpoint gauge needs (ISSUE 15 satellite)
+    queue_ms: float = 0.0  # submit() -> popped from the batcher's queue by
+    # step(): the wait for a slot, which ``scheduler.ttft`` hides inside
+    # itself (0 outside the continuous batcher)
     quality: dict | None = None  # per-request confidence vector (ISSUE 15):
     # masked-logit margin mean/min, entropy mean, grammar-forced fraction,
     # decision count — None when the quality lanes are off or no decision
@@ -105,23 +115,27 @@ def _mask_sample_advance(logits, fsm_state, tables: DeviceFSM, key, temperature,
         # the result is exactly masked_argmax + fsm_advance (differential-
         # tested); dead states are fenced by the poison gate either way.
         mesh = rules.mesh if rules is not None else None
-        return sharded_masked_argmax_advance(
-            mesh, logits, fsm_state, tables.dense_mask, tables.table,
-            tables.col_id)
-    if logit_mask is not None:
-        # padded-vocab ids (mesh tp padding / checkpoint embed padding) have
-        # real logits (zero columns -> 0.0) but no tokenizer meaning: dead
-        # under the grammar, they must also be unsampleable unconstrained
-        logits = jnp.where(logit_mask[None, :], logits, -jnp.inf)
+        with jax.named_scope("grammar_mask_sample"):
+            return sharded_masked_argmax_advance(
+                mesh, logits, fsm_state, tables.dense_mask, tables.table,
+                tables.col_id)
+    with jax.named_scope("grammar_mask_sample"):
+        if logit_mask is not None:
+            # padded-vocab ids (mesh tp padding / checkpoint embed padding)
+            # have real logits (zero columns -> 0.0) but no tokenizer
+            # meaning: dead under the grammar, they must also be
+            # unsampleable unconstrained
+            logits = jnp.where(logit_mask[None, :], logits, -jnp.inf)
+        if constrained:
+            row = fsm_row(tables, fsm_state)  # (B, V) int32 next states; -1 dead
+            logits = jnp.where(row >= 0, logits, -jnp.inf)
+        if greedy:
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        else:
+            tok = jax.random.categorical(key, logits / jnp.maximum(temperature, 1e-4)).astype(jnp.int32)
     if constrained:
-        row = fsm_row(tables, fsm_state)  # (B, V) int32 next states; -1 dead
-        logits = jnp.where(row >= 0, logits, -jnp.inf)
-    if greedy:
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        tok = jax.random.categorical(key, logits / jnp.maximum(temperature, 1e-4)).astype(jnp.int32)
-    if constrained:
-        fsm_state = jnp.take_along_axis(row, tok[:, None], axis=-1)[:, 0]
+        with jax.named_scope("fsm_advance"):
+            fsm_state = jnp.take_along_axis(row, tok[:, None], axis=-1)[:, 0]
     return tok, fsm_state
 
 
@@ -130,6 +144,7 @@ def _mask_sample_advance(logits, fsm_state, tables: DeviceFSM, key, temperature,
 QUALITY_MARGIN_CAP = 30.0
 
 
+@jax.named_scope("quality_lanes")
 def _conf_stats(raw, state, tables: DeviceFSM, constrained: bool, logit_mask):
     """Masked-logit confidence of ONE sampling decision per row — the
     quality observatory's intent lanes (ISSUE 15): top1−top2 margin of the
@@ -172,6 +187,7 @@ def _masked_conf(lg, nlegal):
     return margin, ent, nlegal <= 1
 
 
+@jax.named_scope("quality_lanes")
 def _conf_accumulate(conf, ok, margin, ent, forced_one, forced_extra=None):
     """Fold one decision into the per-row conf lanes ``(margin_sum,
     margin_min, entropy_sum, forced, decisions)``. ``forced_extra`` adds
@@ -207,13 +223,14 @@ def _poison_gate(raw, state, state_next, active, poison, constrained: bool):
     poisoned rows must NOT commit the faulty sample, so batch-mates'
     carries stay untouched. Poison codes: 1 = NaN/inf, 2 = dead FSM
     (sticky via max across steps)."""
-    nanp = active & ~jnp.all(jnp.isfinite(raw), axis=-1)
-    if constrained:
-        deadp = active & ~nanp & ((state < 0) | (state_next < 0))
-    else:
-        deadp = jnp.zeros_like(active)
-    poison = jnp.maximum(poison, jnp.where(nanp, 1, jnp.where(deadp, 2, 0)))
-    return active & ~(nanp | deadp), poison
+    with jax.named_scope("poison_gate"):
+        nanp = active & ~jnp.all(jnp.isfinite(raw), axis=-1)
+        if constrained:
+            deadp = active & ~nanp & ((state < 0) | (state_next < 0))
+        else:
+            deadp = jnp.zeros_like(active)
+        poison = jnp.maximum(poison, jnp.where(nanp, 1, jnp.where(deadp, 2, 0)))
+        return active & ~(nanp | deadp), poison
 
 
 @watch_compiles("engine._decode_step")
@@ -457,17 +474,18 @@ def chunk_decode_loop(
     def body(c):
         (cache, cur, pos, state, active, eos, nbytes, left, out, n, key, step,
          poison, conf) = c
-        # record current token for active rows
-        out = out.at[jnp.arange(B), jnp.minimum(n, cap - 1)].set(
-            jnp.where(active, cur, out[jnp.arange(B), jnp.minimum(n, cap - 1)])
-        )
-        n = n + active.astype(jnp.int32)
-        nbytes = nbytes + jnp.where(active, byte_len_table[cur], 0)
-        left = left - active.astype(jnp.int32)
+        with jax.named_scope("loop_carry"):
+            # record current token for active rows
+            out = out.at[jnp.arange(B), jnp.minimum(n, cap - 1)].set(
+                jnp.where(active, cur, out[jnp.arange(B), jnp.minimum(n, cap - 1)])
+            )
+            n = n + active.astype(jnp.int32)
+            nbytes = nbytes + jnp.where(active, byte_len_table[cur], 0)
+            left = left - active.astype(jnp.int32)
 
-        # idle rows park their writes at slot 0 of their own (dead) line
-        write_pos = jnp.where(active, pos, 0)
-        step_tok = jnp.where(active, cur, pad_id)
+            # idle rows park their writes at slot 0 of their own (dead) line
+            write_pos = jnp.where(active, pos, 0)
+            step_tok = jnp.where(active, cur, pad_id)
         if fwd is not None:
             logits, cache = fwd(params, cache, step_tok[:, None], write_pos[:, None])
         else:
@@ -490,63 +508,66 @@ def chunk_decode_loop(
             mg, en, f1 = _conf_stats(raw, state, tables, constrained,
                                      logit_mask)
             conf = _conf_accumulate(conf, ok, mg, en, f1)
-        state = jnp.where(ok, state_next, state)
-        cur = jnp.where(ok, nxt, cur)
-        pos = jnp.where(ok, pos + 1, pos)
+        with jax.named_scope("loop_carry"):
+            state = jnp.where(ok, state_next, state)
+            cur = jnp.where(ok, nxt, cur)
+            pos = jnp.where(ok, pos + 1, pos)
 
-        eos = eos | (ok & (cur == eos_id))
-        stop = (cur == eos_id) | (nbytes >= byte_budget) | (pos >= max_len - 1) | (left <= 0)
-        active = ok & ~stop
+            eos = eos | (ok & (cur == eos_id))
+            stop = (cur == eos_id) | (nbytes >= byte_budget) | (pos >= max_len - 1) | (left <= 0)
+            active = ok & ~stop
         return (cache, cur, pos, state, active, eos, nbytes, left, out, n, key,
                 step + 1, poison, conf)
 
     def ff_body(c):
         (cache, cur, pos, state, active, eos, nbytes, left, out, n, key, step,
          poison, conf) = c
-        # dead-at-entry rows must not fast-forward: ff_tokens[state] with a
-        # negative state wraps to an arbitrary chain — fence them out of
-        # this step's emission entirely (their result is discarded anyway)
-        dead_in = active & (state < 0)
-        active = active & ~dead_in
-        poison = jnp.maximum(poison, jnp.where(dead_in, 2, 0))
-        iw = jnp.arange(1 + W)[None, :]  # (1, 1+W) block index
-        chain = tables.ff_tokens[state]  # (B, W); -1 pads
-        # chain length, capped so emission fits the token budget, the cache
-        # (writes land at pos .. pos+k <= max_len-1), and the byte budget
-        # (chain_byte_cap: the shared one-token-overshoot contract)
-        k = jnp.minimum(jnp.minimum(tables.ff_len[state], left - 1),
-                        max_len - 1 - pos)
-        k, _ = chain_byte_cap(k, chain, cur, nbytes, byte_len_table,
-                              byte_budget)
-        k = jnp.where(active, jnp.maximum(k, 0), 0)
+        with jax.named_scope("loop_carry"):
+            # dead-at-entry rows must not fast-forward: ff_tokens[state] with a
+            # negative state wraps to an arbitrary chain — fence them out of
+            # this step's emission entirely (their result is discarded anyway)
+            dead_in = active & (state < 0)
+            active = active & ~dead_in
+            poison = jnp.maximum(poison, jnp.where(dead_in, 2, 0))
+            iw = jnp.arange(1 + W)[None, :]  # (1, 1+W) block index
+            chain = tables.ff_tokens[state]  # (B, W); -1 pads
+            # chain length, capped so emission fits the token budget, the cache
+            # (writes land at pos .. pos+k <= max_len-1), and the byte budget
+            # (chain_byte_cap: the shared one-token-overshoot contract)
+            k = jnp.minimum(jnp.minimum(tables.ff_len[state], left - 1),
+                            max_len - 1 - pos)
+            k, _ = chain_byte_cap(k, chain, cur, nbytes, byte_len_table,
+                                  byte_budget)
+            k = jnp.where(active, jnp.maximum(k, 0), 0)
 
-        # [cur, chain_0..chain_{k-1}] with idempotent duplicate-tail padding
-        step_tok, blk_tok, blk_pos = chain_block(iw, cur, chain, k, active,
-                                                 pad_id, pos)
+            # [cur, chain_0..chain_{k-1}] with idempotent duplicate-tail padding
+            step_tok, blk_tok, blk_pos = chain_block(iw, cur, chain, k, active,
+                                                     pad_id, pos)
 
-        # emit cur + chain via the trash column
-        valid = (iw <= k[:, None]) & active[:, None]
-        tgt = jnp.where(valid, jnp.minimum(n[:, None] + iw, cap - 1), cap)
-        out = out.at[jnp.arange(B)[:, None], tgt].set(
-            jnp.where(valid, blk_tok, pad_id))
-        emitted = jnp.where(active, 1 + k, 0)
-        n = n + emitted
-        # taken chain bytes: inside chain_valid the block IS the chain
-        chain_valid = (iw >= 1) & (iw <= k[:, None]) & active[:, None]
-        nbytes = (nbytes + jnp.where(active, byte_len_table[cur], 0)
-                  + jnp.sum(jnp.where(chain_valid,
-                                      byte_len_table[jnp.maximum(blk_tok, 0)], 0),
-                            axis=1))
-        left = left - emitted
+            # emit cur + chain via the trash column
+            valid = (iw <= k[:, None]) & active[:, None]
+            tgt = jnp.where(valid, jnp.minimum(n[:, None] + iw, cap - 1), cap)
+            out = out.at[jnp.arange(B)[:, None], tgt].set(
+                jnp.where(valid, blk_tok, pad_id))
+            emitted = jnp.where(active, 1 + k, 0)
+            n = n + emitted
+            # taken chain bytes: inside chain_valid the block IS the chain
+            chain_valid = (iw >= 1) & (iw <= k[:, None]) & active[:, None]
+            nbytes = (nbytes + jnp.where(active, byte_len_table[cur], 0)
+                      + jnp.sum(jnp.where(chain_valid,
+                                          byte_len_table[jnp.maximum(blk_tok, 0)], 0),
+                                axis=1))
+            left = left - emitted
 
-        # FSM state after the taken chain tokens (walked stepwise so budget
-        # truncation of the chain keeps the state exact)
-        def cstep(s, xs):
-            t, i = xs
-            s2 = fsm_advance(tables, s, jnp.maximum(t, 0))
-            return jnp.where(i < k, s2, s), None
+        with jax.named_scope("fsm_advance"):
+            # FSM state after the taken chain tokens (walked stepwise so budget
+            # truncation of the chain keeps the state exact)
+            def cstep(s, xs):
+                t, i = xs
+                s2 = fsm_advance(tables, s, jnp.maximum(t, 0))
+                return jnp.where(i < k, s2, s), None
 
-        s_end, _ = jax.lax.scan(cstep, state, (chain.T, jnp.arange(W)))
+            s_end, _ = jax.lax.scan(cstep, state, (chain.T, jnp.arange(W)))
 
         if fwd is not None:
             logits, cache = fwd(params, cache, blk_tok, blk_pos)
@@ -572,13 +593,14 @@ def chunk_decode_loop(
                                      logit_mask)
             conf = _conf_accumulate(conf, ok, mg, en, f1,
                                     forced_extra=jnp.where(active, k, 0))
-        state = jnp.where(ok, state_next, state)
-        cur = jnp.where(ok, nxt, cur)
-        pos = jnp.where(ok, pos + 1 + k, pos)
+        with jax.named_scope("loop_carry"):
+            state = jnp.where(ok, state_next, state)
+            cur = jnp.where(ok, nxt, cur)
+            pos = jnp.where(ok, pos + 1 + k, pos)
 
-        eos = eos | (ok & (cur == eos_id))
-        stop = (cur == eos_id) | (nbytes >= byte_budget) | (pos >= max_len - 1) | (left <= 0)
-        active = ok & ~stop
+            eos = eos | (ok & (cur == eos_id))
+            stop = (cur == eos_id) | (nbytes >= byte_budget) | (pos >= max_len - 1) | (left <= 0)
+            active = ok & ~stop
         return (cache, cur, pos, state, active, eos, nbytes, left, out, n, key,
                 step + 1, poison, conf)
 
@@ -931,69 +953,81 @@ class DecodeEngine:
         single-request generate(), the continuous batcher's admission, and
         every engine layout (dense / paged / pp override only the
         ``_prefill_suffix`` / ``_prefill_full`` kernels) — the paths the
-        equivalence tests hold token-identical."""
+        equivalence tests hold token-identical.
+
+        On the trace and in the step ledger ``.alloc`` is this whole method
+        up to the slice of the last row (``.first_token_call``), less the
+        jitted forward, which the layout kernel alone wraps as
+        ``.prefill_call``. ``sched.admit.prefill`` — the ledger's prefill
+        stage — is what ``prefill_ms`` times: the layout kernel's whole
+        call."""
         from ..utils.chaos import ChaosError, chaos_fire
 
-        if chaos_fire("prefill_exc"):
-            # drill for the scheduler's per-request admission fence: fires
-            # BEFORE any engine state is touched, like a real tokenizer/
-            # shape fault at the top of admission
-            raise ChaosError("chaos: injected prefill exception")
-        self.release_slot(slot)  # a finished request may still own resources
-        if self.spec is not None:
-            # admission hook: the spec decoder keeps the host-side token
-            # context its drafters read (and the draft model prefills its
-            # own cache line for this slot)
-            self.spec.on_admit(slot, list(ids))
-        n = len(ids)
-        suffix = self._split_prefix(ids)
-        if suffix is not None:
-            bucket = self._suffix_bucket(len(suffix), self.max_len - len(self.prefix_ids))
-            if bucket is None:
-                suffix = None  # no suffix bucket fits; use full prefill below
-        if suffix is not None:
-            P, m = len(self.prefix_ids), len(suffix)
-            tokens = np.full((1, bucket), self.pad_id, dtype=np.int32)
-            tokens[0, :m] = suffix
-            positions = (P + np.arange(bucket, dtype=np.int32))[None, :]
-            t0 = time.perf_counter()
-            logits = self._prefill_suffix(
-                jnp.asarray(tokens), jnp.asarray(positions), slot, P, bucket, n)
-            # the prefill split (scheduler/_result_to_response read it):
-            # compute ms covers ONLY the suffix forward dispatch — the
-            # cached prefix contributes tokens, not compute
-            self._last_prefill_compute_ms = (time.perf_counter() - t0) * 1e3
+        with span(ALLOC_SPAN):
+            if chaos_fire("prefill_exc"):
+                # drill for the scheduler's per-request admission fence:
+                # fires BEFORE any engine state is touched, like a real
+                # tokenizer/shape fault at the top of admission
+                raise ChaosError("chaos: injected prefill exception")
+            self.release_slot(slot)  # a finished request may still own resources
+            if self.spec is not None:
+                # admission hook: the spec decoder keeps the host-side token
+                # context its drafters read (and the draft model prefills
+                # its own cache line for this slot)
+                self.spec.on_admit(slot, list(ids))
+            n = len(ids)
+            suffix = self._split_prefix(ids)
+            if suffix is not None:
+                bucket = self._suffix_bucket(len(suffix), self.max_len - len(self.prefix_ids))
+                if bucket is None:
+                    suffix = None  # no suffix bucket fits; use full prefill below
+            if suffix is not None:
+                P, m = len(self.prefix_ids), len(suffix)
+                tokens = np.full((1, bucket), self.pad_id, dtype=np.int32)
+                tokens[0, :m] = suffix
+                positions = (P + np.arange(bucket, dtype=np.int32))[None, :]
+            else:
+                P, m = 0, n
+                bucket = self._bucket(n)
+                tokens = np.full((1, bucket), self.pad_id, dtype=np.int32)
+                tokens[0, :n] = ids
+                positions = np.arange(bucket, dtype=np.int32)[None, :]
+            with span(PREFILL_STAGE_SPAN):
+                t0 = time.perf_counter()
+                tokens, positions = jnp.asarray(tokens), jnp.asarray(positions)
+                if suffix is not None:
+                    logits = self._prefill_suffix(tokens, positions, slot, P, bucket, n)
+                else:
+                    logits = self._prefill_full(tokens, positions, slot, bucket, n)
+                # the prefill split (scheduler/_result_to_response read it):
+                # compute ms covers ONLY the layout kernel's call, a dispatch
+                # (on the paged layout its block allocation too) — the cached
+                # prefix contributes tokens, not compute
+                self._last_prefill_compute_ms = (time.perf_counter() - t0) * 1e3
             self._last_cached_tokens = P
+        with span(FIRST_TOKEN_SPAN):
             return logits[:, m - 1, :]
-        bucket = self._bucket(n)
-        tokens = np.full((1, bucket), self.pad_id, dtype=np.int32)
-        tokens[0, :n] = ids
-        positions = np.arange(bucket, dtype=np.int32)[None, :]
-        t0 = time.perf_counter()
-        logits = self._prefill_full(
-            jnp.asarray(tokens), jnp.asarray(positions), slot, bucket, n)
-        self._last_prefill_compute_ms = (time.perf_counter() - t0) * 1e3
-        self._last_cached_tokens = 0
-        return logits[:, n - 1, :]
 
     def _prefill_suffix(self, tokens, positions, slot: int, P: int, bucket: int,
                         n: int):
         """Layout kernel: admit a prefix-cached suffix into ``slot``."""
-        logits, self.cache = prefill_row_with_prefix(
-            self.params, self.cfg, self.cache,
-            self.prefix_kv["k"], self.prefix_kv["v"],
-            tokens, positions, jnp.int32(slot),
-            rules=self.rules, kernels=self.kernels,
-        )
+        with span(PREFILL_CALL_SPAN):
+            logits, self.cache = prefill_row_with_prefix(
+                self.params, self.cfg, self.cache,
+                self.prefix_kv["k"], self.prefix_kv["v"],
+                tokens, positions, jnp.int32(slot),
+                rules=self.rules, kernels=self.kernels,
+            )
         return logits
 
     def _prefill_full(self, tokens, positions, slot: int, bucket: int, n: int):
         """Layout kernel: admit a fresh full prompt into ``slot``."""
-        logits, self.cache = prefill_row(
-            self.params, self.cfg, self.cache,
-            tokens, positions, jnp.int32(slot),
-            rules=self.rules, kernels=self.kernels, fresh=True,
-        )
+        with span(PREFILL_CALL_SPAN):
+            logits, self.cache = prefill_row(
+                self.params, self.cfg, self.cache,
+                tokens, positions, jnp.int32(slot),
+                rules=self.rules, kernels=self.kernels, fresh=True,
+            )
         return logits
 
     def decode_chunk(self, cur, pos, fsm, active, nbytes, tokens_left, key,

@@ -39,6 +39,13 @@ import numpy as np
 from ..grammar.fsm import fsm_advance
 from ..models.llama import forward_paged
 from ..utils.compilewatch import get_compile_watcher, watch_compiles
+from ..utils.steplog import (
+    ALLOC_SPAN,
+    FIRST_TOKEN_SPAN,
+    PREFILL_CALL_SPAN,
+    PREFILL_STAGE_SPAN,
+    span,
+)
 from .engine import (
     DecodeEngine,
     _conf_accumulate,
@@ -63,7 +70,7 @@ class _ChunkedPrefill:
     only the suffix forwards remain, one ``(1, C)`` dispatch per step."""
 
     __slots__ = ("slot", "ids", "suffix", "P", "C", "n_chunks", "j",
-                 "step_ms", "total_ms")
+                 "total_ms")
 
     def __init__(self, slot: int, ids: list[int], suffix: list[int],
                  P: int, C: int, n_chunks: int):
@@ -74,7 +81,6 @@ class _ChunkedPrefill:
         self.C = C              # PREFILL_CHUNK_TOKENS
         self.n_chunks = n_chunks
         self.j = 0              # chunks completed
-        self.step_ms = 0.0      # last chunk's compute wall (steplog carve)
         self.total_ms = 0.0     # accumulated compute (prefill_ms at finish)
 
 
@@ -356,15 +362,16 @@ def paged_chunk_decode_loop(
     def body(c):
         (kp, vp, ksc, vsc, cur, pos, state, active, eos, nbytes, left, out, n,
          key, step, poison, conf) = c
-        out = out.at[jnp.arange(B), jnp.minimum(n, chunk_steps - 1)].set(
-            jnp.where(active, cur, out[jnp.arange(B), jnp.minimum(n, chunk_steps - 1)])
-        )
-        n = n + active.astype(jnp.int32)
-        nbytes = nbytes + jnp.where(active, byte_len_table[cur], 0)
-        left = left - active.astype(jnp.int32)
+        with jax.named_scope("loop_carry"):
+            out = out.at[jnp.arange(B), jnp.minimum(n, chunk_steps - 1)].set(
+                jnp.where(active, cur, out[jnp.arange(B), jnp.minimum(n, chunk_steps - 1)])
+            )
+            n = n + active.astype(jnp.int32)
+            nbytes = nbytes + jnp.where(active, byte_len_table[cur], 0)
+            left = left - active.astype(jnp.int32)
 
-        step_tok = jnp.where(active, cur, pad_id)
-        write_pos = jnp.where(active, pos, 0)
+            step_tok = jnp.where(active, cur, pad_id)
+            write_pos = jnp.where(active, pos, 0)
         logits, kp, vp, ksc, vsc = forward_paged(
             params, cfg, step_tok[:, None], write_pos[:, None], kp, vp,
             block_tables, rules=rules, attn_impl=kernels, write_mask=active,
@@ -385,13 +392,14 @@ def paged_chunk_decode_loop(
             mg, en, f1 = _conf_stats(raw, state, tables, constrained,
                                      logit_mask)
             conf = _conf_accumulate(conf, ok, mg, en, f1)
-        state = jnp.where(ok, state_next, state)
-        cur = jnp.where(ok, nxt, cur)
-        pos = jnp.where(ok, pos + 1, pos)
+        with jax.named_scope("loop_carry"):
+            state = jnp.where(ok, state_next, state)
+            cur = jnp.where(ok, nxt, cur)
+            pos = jnp.where(ok, pos + 1, pos)
 
-        eos = eos | (ok & (cur == eos_id))
-        stop = (cur == eos_id) | (nbytes >= byte_budget) | (pos >= max_pos - 1) | (left <= 0)
-        active = ok & ~stop
+            eos = eos | (ok & (cur == eos_id))
+            stop = (cur == eos_id) | (nbytes >= byte_budget) | (pos >= max_pos - 1) | (left <= 0)
+            active = ok & ~stop
         return (kp, vp, ksc, vsc, cur, pos, state, active, eos, nbytes, left,
                 out, n, key, step + 1, poison, conf)
 
@@ -406,51 +414,53 @@ def paged_chunk_decode_loop(
         # full ff chunk before dispatch.
         (kp, vp, ksc, vsc, cur, pos, state, active, eos, nbytes, left, out, n,
          key, step, poison, conf) = c
-        # dead-at-entry fence (see the dense ff_body): a negative state
-        # wraps the ff_tokens gather — poison it out before it emits
-        dead_in = active & (state < 0)
-        active = active & ~dead_in
-        poison = jnp.maximum(poison, jnp.where(dead_in, 2, 0))
-        iw = jnp.arange(1 + W)[None, :]
-        chain = tables.ff_tokens[state]  # (B, W); -1 pads
-        k = jnp.minimum(jnp.minimum(tables.ff_len[state], left - 1),
-                        max_pos - 1 - pos)
-        chain_bytes = jnp.cumsum(
-            jnp.where(chain >= 0, byte_len_table[jnp.maximum(chain, 0)], 0), axis=1)
-        rem = (byte_budget - nbytes - byte_len_table[cur])[:, None]
-        k = jnp.minimum(k, jnp.sum(chain_bytes <= rem, axis=1))
-        k = jnp.where(active, jnp.maximum(k, 0), 0)
+        with jax.named_scope("loop_carry"):
+            # dead-at-entry fence (see the dense ff_body): a negative state
+            # wraps the ff_tokens gather — poison it out before it emits
+            dead_in = active & (state < 0)
+            active = active & ~dead_in
+            poison = jnp.maximum(poison, jnp.where(dead_in, 2, 0))
+            iw = jnp.arange(1 + W)[None, :]
+            chain = tables.ff_tokens[state]  # (B, W); -1 pads
+            k = jnp.minimum(jnp.minimum(tables.ff_len[state], left - 1),
+                            max_pos - 1 - pos)
+            chain_bytes = jnp.cumsum(
+                jnp.where(chain >= 0, byte_len_table[jnp.maximum(chain, 0)], 0), axis=1)
+            rem = (byte_budget - nbytes - byte_len_table[cur])[:, None]
+            k = jnp.minimum(k, jnp.sum(chain_bytes <= rem, axis=1))
+            k = jnp.where(active, jnp.maximum(k, 0), 0)
 
-        ci = jnp.clip(iw - 1, 0, jnp.maximum(k[:, None] - 1, 0))
-        chain_tok = jnp.take_along_axis(chain, ci, axis=1)
-        step_tok = jnp.where(active, cur, pad_id)
-        blk_tok = jnp.where(iw == 0, step_tok[:, None],
-                            jnp.where(k[:, None] > 0, chain_tok, step_tok[:, None]))
-        # idle rows park at position 0 (writes are parked via write_mask
-        # anyway): keeps their attention frontier at ONE tile instead of
-        # streaming a finished row's whole covered context every layer
-        write_pos = jnp.where(active, pos, 0)
-        blk_pos = write_pos[:, None] + jnp.minimum(iw, k[:, None])
+            ci = jnp.clip(iw - 1, 0, jnp.maximum(k[:, None] - 1, 0))
+            chain_tok = jnp.take_along_axis(chain, ci, axis=1)
+            step_tok = jnp.where(active, cur, pad_id)
+            blk_tok = jnp.where(iw == 0, step_tok[:, None],
+                                jnp.where(k[:, None] > 0, chain_tok, step_tok[:, None]))
+            # idle rows park at position 0 (writes are parked via write_mask
+            # anyway): keeps their attention frontier at ONE tile instead of
+            # streaming a finished row's whole covered context every layer
+            write_pos = jnp.where(active, pos, 0)
+            blk_pos = write_pos[:, None] + jnp.minimum(iw, k[:, None])
 
-        valid = (iw <= k[:, None]) & active[:, None]
-        tgt = jnp.where(valid, jnp.minimum(n[:, None] + iw, cap - 1), cap)
-        out = out.at[jnp.arange(B)[:, None], tgt].set(
-            jnp.where(valid, blk_tok, pad_id))
-        emitted = jnp.where(active, 1 + k, 0)
-        n = n + emitted
-        chain_valid = (iw >= 1) & (iw <= k[:, None]) & active[:, None]
-        nbytes = (nbytes + jnp.where(active, byte_len_table[cur], 0)
-                  + jnp.sum(jnp.where(chain_valid,
-                                      byte_len_table[jnp.maximum(chain_tok, 0)], 0),
-                            axis=1))
-        left = left - emitted
+            valid = (iw <= k[:, None]) & active[:, None]
+            tgt = jnp.where(valid, jnp.minimum(n[:, None] + iw, cap - 1), cap)
+            out = out.at[jnp.arange(B)[:, None], tgt].set(
+                jnp.where(valid, blk_tok, pad_id))
+            emitted = jnp.where(active, 1 + k, 0)
+            n = n + emitted
+            chain_valid = (iw >= 1) & (iw <= k[:, None]) & active[:, None]
+            nbytes = (nbytes + jnp.where(active, byte_len_table[cur], 0)
+                      + jnp.sum(jnp.where(chain_valid,
+                                          byte_len_table[jnp.maximum(chain_tok, 0)], 0),
+                                axis=1))
+            left = left - emitted
 
-        def cstep(s, xs):
-            t, i = xs
-            s2 = fsm_advance(tables, s, jnp.maximum(t, 0))
-            return jnp.where(i < k, s2, s), None
+        with jax.named_scope("fsm_advance"):
+            def cstep(s, xs):
+                t, i = xs
+                s2 = fsm_advance(tables, s, jnp.maximum(t, 0))
+                return jnp.where(i < k, s2, s), None
 
-        s_end, _ = jax.lax.scan(cstep, state, (chain.T, jnp.arange(W)))
+            s_end, _ = jax.lax.scan(cstep, state, (chain.T, jnp.arange(W)))
 
         logits, kp, vp, ksc, vsc = forward_paged(
             params, cfg, blk_tok, blk_pos, kp, vp,
@@ -473,13 +483,14 @@ def paged_chunk_decode_loop(
                                      logit_mask)
             conf = _conf_accumulate(conf, ok, mg, en, f1,
                                     forced_extra=jnp.where(active, k, 0))
-        state = jnp.where(ok, state_next, state)
-        cur = jnp.where(ok, nxt, cur)
-        pos = jnp.where(ok, pos + 1 + k, pos)
+        with jax.named_scope("loop_carry"):
+            state = jnp.where(ok, state_next, state)
+            cur = jnp.where(ok, nxt, cur)
+            pos = jnp.where(ok, pos + 1 + k, pos)
 
-        eos = eos | (ok & (cur == eos_id))
-        stop = (cur == eos_id) | (nbytes >= byte_budget) | (pos >= max_pos - 1) | (left <= 0)
-        active = ok & ~stop
+            eos = eos | (ok & (cur == eos_id))
+            stop = (cur == eos_id) | (nbytes >= byte_budget) | (pos >= max_pos - 1) | (left <= 0)
+            active = ok & ~stop
         return (kp, vp, ksc, vsc, cur, pos, state, active, eos, nbytes, left,
                 out, n, key, step + 1, poison, conf)
 
@@ -772,8 +783,9 @@ class PagedDecodeEngine(DecodeEngine):
             dst = jnp.asarray(owned[0] * bs + np.arange(R, dtype=np.int32))
             self._scatter_pool(tail["k"], tail["v"], dst)
         # gather only the COVERED blocks, bucketed to a power of two so
-        # compile count stays log-bounded (gathering the whole table width
-        # — max_len of context — per layer was round-2 verdict weak #6)
+        # compile count stays log-bounded (gathering the whole table
+        # width — max_len of context — per layer was round-2 verdict
+        # weak #6)
         need = -(-(P + bucket) // bs)
         gb = 1
         while gb < need:
@@ -790,15 +802,17 @@ class PagedDecodeEngine(DecodeEngine):
             gb = gb * 3 // 4
         gb = min(gb, self.max_blocks)
         self._next_pos[slot] = n
-        logits, self.k_pool, self.v_pool, self.k_scale, self.v_scale = \
-            forward_paged(
-                self.params, self.cfg, tokens, positions,
-                self.k_pool, self.v_pool, self.block_tables[slot][None],
-                rules=self.rules, attn_impl="xla",
-                fresh_block=False, gather_blocks=gb,
-                k_scale=self.k_scale, v_scale=self.v_scale,
-                kv_quant=self.kv_quant,
-            )
+        table_row = self.block_tables[slot][None]
+        with span(PREFILL_CALL_SPAN):
+            logits, self.k_pool, self.v_pool, self.k_scale, self.v_scale = \
+                forward_paged(
+                    self.params, self.cfg, tokens, positions,
+                    self.k_pool, self.v_pool, table_row,
+                    rules=self.rules, attn_impl="xla",
+                    fresh_block=False, gather_blocks=gb,
+                    k_scale=self.k_scale, v_scale=self.v_scale,
+                    kv_quant=self.kv_quant,
+                )
         return logits
 
     def prefill_slot(self, ids: list[int], slot: int):
@@ -808,35 +822,37 @@ class PagedDecodeEngine(DecodeEngine):
         None``) takes the parent path untouched."""
         if self.radix is None:
             return super().prefill_slot(ids, slot)
-        # capture the incoming tenant namespace across the release below
-        # (release pops it — it belongs to the PREVIOUS occupant there)
-        ns = self._slot_ns.get(slot)
-        self.release_slot(slot)
-        if ns is not None:
-            self._slot_ns[slot] = ns
-        ids = list(ids)
-        g = self._group(slot)
-        chain, matched = self.radix[g].match(ids, ns=ns)
-        bucket = None
-        P, tail = matched, None
-        if matched:
-            P0 = len(self.prefix_ids)
-            if (self._prefix_tail is not None and P0 > matched
-                    and len(ids) > P0
-                    and chain == self._prefix_blocks[g][: len(chain)]
-                    and ids[:P0] == self.prefix_ids):
-                # the match stopped exactly at the pinned root chain and the
-                # prompt extends the full static prefix: keep the sub-block
-                # tail scatter (byte-for-byte the _prefill_suffix layout)
-                # instead of recomputing the P % block_size remainder
-                P, tail = P0, self._prefix_tail
-            suffix = ids[P:]
-            bucket = self._suffix_bucket(len(suffix), self.max_len - P)
-            if bucket is None:
-                # no suffix bucket fits: release the chain refs and take
-                # the full-prompt path (which buckets independently)
-                self.allocator.free(chain)
-                matched = 0
+        with span(ALLOC_SPAN):
+            # capture the incoming tenant namespace across the release below
+            # (release pops it — it belongs to the PREVIOUS occupant there)
+            ns = self._slot_ns.get(slot)
+            self.release_slot(slot)
+            if ns is not None:
+                self._slot_ns[slot] = ns
+            ids = list(ids)
+            g = self._group(slot)
+            chain, matched = self.radix[g].match(ids, ns=ns)
+            bucket = None
+            P, tail = matched, None
+            if matched:
+                P0 = len(self.prefix_ids)
+                if (self._prefix_tail is not None and P0 > matched
+                        and len(ids) > P0
+                        and chain == self._prefix_blocks[g][: len(chain)]
+                        and ids[:P0] == self.prefix_ids):
+                    # the match stopped exactly at the pinned root chain and
+                    # the prompt extends the full static prefix: keep the
+                    # sub-block tail scatter (byte-for-byte the
+                    # _prefill_suffix layout) instead of recomputing the
+                    # P % block_size remainder
+                    P, tail = P0, self._prefix_tail
+                suffix = ids[P:]
+                bucket = self._suffix_bucket(len(suffix), self.max_len - P)
+                if bucket is None:
+                    # no suffix bucket fits: release the chain refs and take
+                    # the full-prompt path (which buckets independently)
+                    self.allocator.free(chain)
+                    matched = 0
         if not matched:
             logits = super().prefill_slot(ids, slot)
             # the parent prefill releases the slot once more on entry, which
@@ -848,26 +864,30 @@ class PagedDecodeEngine(DecodeEngine):
             return logits
         # the hit is accounted only HERE — a bucket fallback above must not
         # show up as served-from-cache in the radix gauges
-        self.radix[g].record_hit(P)
-        if self.spec is not None:
-            # drafter seeding on the warm path (the miss fallback hooks
-            # on_admit inside super().prefill_slot): the drafters get the
-            # FULL cached prompt ids, so prompt-lookup drafting sees the
-            # whole multi-turn transcript from a warm turn's first verify
-            # step — the radix admission feeds the drafter, not just the KV
-            self.spec.on_admit(slot, ids)
-        m = len(suffix)
-        tokens = np.full((1, bucket), self.pad_id, dtype=np.int32)
-        tokens[0, :m] = suffix
-        positions = (P + np.arange(bucket, dtype=np.int32))[None, :]
-        t0 = time.perf_counter()
-        logits = self._prefill_chain(
-            jnp.asarray(tokens), jnp.asarray(positions), slot, chain, P,
-            bucket, len(ids), tail=tail)
-        self._last_prefill_compute_ms = (time.perf_counter() - t0) * 1e3
-        self._last_cached_tokens = P
-        self._slot_ids[slot] = ids
-        return logits[:, m - 1, :]
+        with span(ALLOC_SPAN):
+            self.radix[g].record_hit(P)
+            if self.spec is not None:
+                # drafter seeding on the warm path (the miss fallback hooks
+                # on_admit inside super().prefill_slot): the drafters get
+                # the FULL cached prompt ids, so prompt-lookup drafting sees
+                # the whole multi-turn transcript from a warm turn's first
+                # verify step — the radix admission feeds the drafter, not
+                # just the KV
+                self.spec.on_admit(slot, ids)
+            m = len(suffix)
+            tokens = np.full((1, bucket), self.pad_id, dtype=np.int32)
+            tokens[0, :m] = suffix
+            positions = (P + np.arange(bucket, dtype=np.int32))[None, :]
+            with span(PREFILL_STAGE_SPAN):
+                t0 = time.perf_counter()
+                tokens, positions = jnp.asarray(tokens), jnp.asarray(positions)
+                logits = self._prefill_chain(tokens, positions, slot, chain, P,
+                                             bucket, len(ids), tail=tail)
+                self._last_prefill_compute_ms = (time.perf_counter() - t0) * 1e3
+            self._last_cached_tokens = P
+            self._slot_ids[slot] = ids
+        with span(FIRST_TOKEN_SPAN):
+            return logits[:, m - 1, :]
 
     def _prefill_full(self, tokens, positions, slot: int, bucket: int, n: int):
         bs = self.block_size
@@ -876,16 +896,18 @@ class PagedDecodeEngine(DecodeEngine):
         self._set_table_row(slot, owned)
         self._covered[slot] = len(owned) * bs
         self._next_pos[slot] = n
+        table_row = self.block_tables[slot][None]
         # position 0 start: block-local attention, no pool gather at all
-        logits, self.k_pool, self.v_pool, self.k_scale, self.v_scale = \
-            forward_paged(
-                self.params, self.cfg, tokens, positions,
-                self.k_pool, self.v_pool, self.block_tables[slot][None],
-                rules=self.rules, attn_impl=self.kernels,
-                fresh_block=True, gather_blocks=None,
-                k_scale=self.k_scale, v_scale=self.v_scale,
-                kv_quant=self.kv_quant,
-            )
+        with span(PREFILL_CALL_SPAN):
+            logits, self.k_pool, self.v_pool, self.k_scale, self.v_scale = \
+                forward_paged(
+                    self.params, self.cfg, tokens, positions,
+                    self.k_pool, self.v_pool, table_row,
+                    rules=self.rules, attn_impl=self.kernels,
+                    fresh_block=True, gather_blocks=None,
+                    k_scale=self.k_scale, v_scale=self.v_scale,
+                    kv_quant=self.kv_quant,
+                )
         return logits
 
     # ------------------------------------------------- chunked prefill
@@ -986,29 +1008,32 @@ class PagedDecodeEngine(DecodeEngine):
         block table with the same pow2-bucketed gather the chain admission
         uses, so compile count stays log-bounded at one token-dim (C)."""
         slot, C, bs = cur.slot, cur.C, self.block_size
-        start = cur.j * C
-        seg = cur.suffix[start:start + C]
-        tokens = np.full((1, C), self.pad_id, dtype=np.int32)
-        tokens[0, : len(seg)] = seg
-        positions = (cur.P + start + np.arange(C, dtype=np.int32))[None, :]
-        need = -(-(cur.P + start + C) // bs)
-        gb = 1
-        while gb < need:
-            gb *= 2
-        gb = min(gb, self.max_blocks)
-        t0 = time.perf_counter()
-        logits, self.k_pool, self.v_pool, self.k_scale, self.v_scale = \
-            forward_paged(
-                self.params, self.cfg, jnp.asarray(tokens),
-                jnp.asarray(positions),
-                self.k_pool, self.v_pool, self.block_tables[slot][None],
-                rules=self.rules, attn_impl="xla",
-                fresh_block=False, gather_blocks=gb,
-                k_scale=self.k_scale, v_scale=self.v_scale,
-                kv_quant=self.kv_quant,
-            )
-        cur.step_ms = (time.perf_counter() - t0) * 1e3
-        cur.total_ms += cur.step_ms
+        with span(ALLOC_SPAN):
+            start = cur.j * C
+            seg = cur.suffix[start:start + C]
+            tokens = np.full((1, C), self.pad_id, dtype=np.int32)
+            tokens[0, : len(seg)] = seg
+            positions = (cur.P + start + np.arange(C, dtype=np.int32))[None, :]
+            need = -(-(cur.P + start + C) // bs)
+            gb = 1
+            while gb < need:
+                gb *= 2
+            gb = min(gb, self.max_blocks)
+            with span(PREFILL_STAGE_SPAN):
+                t0 = time.perf_counter()
+                tokens, positions = jnp.asarray(tokens), jnp.asarray(positions)
+                table_row = self.block_tables[slot][None]
+                with span(PREFILL_CALL_SPAN):
+                    logits, self.k_pool, self.v_pool, self.k_scale, self.v_scale = \
+                        forward_paged(
+                            self.params, self.cfg, tokens, positions,
+                            self.k_pool, self.v_pool, table_row,
+                            rules=self.rules, attn_impl="xla",
+                            fresh_block=False, gather_blocks=gb,
+                            k_scale=self.k_scale, v_scale=self.v_scale,
+                            kv_quant=self.kv_quant,
+                        )
+                cur.total_ms += (time.perf_counter() - t0) * 1e3
         cur.j += 1
         if cur.j < cur.n_chunks:
             return None
@@ -1020,7 +1045,8 @@ class PagedDecodeEngine(DecodeEngine):
             # drafter seeding at admission, same hook as the one-shot paths
             self.spec.on_admit(slot, cur.ids)
         r = len(cur.suffix) - start
-        return logits[:, r - 1, :]
+        with span(FIRST_TOKEN_SPAN):
+            return logits[:, r - 1, :]
 
     # ------------------------------------------------------------ decode
 

@@ -36,6 +36,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.llama import LlamaConfig, init_params, quantize_leaf as _quant_leaf
 from ..utils.compilewatch import watch_compiles
+from ..utils.steplog import PREFILL_CALL_SPAN, span
 from ..parallel.pipeline import (
     init_pp_tp_cache,
     pp_tp_forward_cached,
@@ -262,18 +263,20 @@ class PPDecodeEngine(DecodeEngine):
 
     def _prefill_suffix(self, tokens, positions, slot: int, P: int, bucket: int,
                         n: int):
-        logits, self.cache = pp_prefill_row_with_prefix(
-            self.params, self.cache, self.cfg,
-            self.prefix_kv["k"], self.prefix_kv["v"],
-            tokens, positions, jnp.int32(slot), self.pmesh,
-        )
+        with span(PREFILL_CALL_SPAN):
+            logits, self.cache = pp_prefill_row_with_prefix(
+                self.params, self.cache, self.cfg,
+                self.prefix_kv["k"], self.prefix_kv["v"],
+                tokens, positions, jnp.int32(slot), self.pmesh,
+            )
         return logits
 
     def _prefill_full(self, tokens, positions, slot: int, bucket: int, n: int):
-        logits, self.cache = pp_prefill_row(
-            self.params, self.cache, self.cfg,
-            tokens, positions, jnp.int32(slot), self.pmesh,
-        )
+        with span(PREFILL_CALL_SPAN):
+            logits, self.cache = pp_prefill_row(
+                self.params, self.cache, self.cfg,
+                tokens, positions, jnp.int32(slot), self.pmesh,
+            )
         return logits
 
     def decode_chunk(self, cur, pos, fsm, active, nbytes, tokens_left, key,

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.steplog import ALLOC_SPAN, FIRST_TOKEN_SPAN, REQUEST_SPAN, span
 from .engine import DecodeEngine, GenerationResult, _first_token
 from .paged import PoolExhausted
 
@@ -65,6 +66,7 @@ class _Slot:
     prompt_len: int = 0
     cached_tokens: int = 0  # prompt tokens served from cached KV (static
     # prefix / radix chain) at admission
+    queue_ms: float = 0.0  # submit() -> popped from pending by step()
     forwards: int = 0  # decode forward dispatches this request rode (spec
     # engines report per-row participation; 0 = engine doesn't split it)
     spec_accepts: int = 0  # draft tokens accepted for this request (spec
@@ -471,7 +473,8 @@ class ContinuousBatcher:
                 return b
         return None
 
-    def _admit(self, slot: int, rid: int, prompt: str) -> bool:
+    def _admit(self, slot: int, rid: int, prompt: str, timer,
+               queue_ms: float) -> bool:
         """Prefill ONE slot's cache line (cost independent of batch width —
         round 1 prefilled the full (B, bucket) batch per admission, 32×
         wasted FLOPs at 32 slots) and reuse the engine's shared-prefix KV
@@ -482,74 +485,91 @@ class ContinuousBatcher:
         the slot is reserved — request_id set, active stays False — and
         ``_advance_admissions`` runs one prefill chunk per step until the
         final chunk lands, so a 1k-token cold prompt never head-of-line-
-        blocks batch-mates' decode chunks behind a barrier prefill."""
-        eng = self.engine
-        if self.tenancy is not None:
-            # tenant radix namespace (ISSUE 18): the slot's cache chains are
-            # salted with the resolved class name so one tenant's churn
-            # cannot evict another's warm chains (serve.radix)
-            setns = getattr(eng, "set_slot_ns", None)
-            if setns is not None:
-                setns(slot, self.tenancy.resolve(self._tenant.get(rid)))
-        t0 = time.perf_counter()
-        ids = (eng.tokenizer.encode(prompt, bos=True)
-               if isinstance(prompt, str) else [int(t) for t in prompt])
-        n = len(ids)
-        C = self._prefill_chunk
-        if C > 0 and n > C:
-            begin = getattr(eng, "begin_chunked_prefill", None)
-            if begin is not None:
-                cursor = begin(ids, slot, C)
-                if cursor is not None:
-                    sl = self.slots[slot]
-                    sl.request_id = rid
-                    sl.token_ids = []
-                    sl.start_s = t0
-                    sl.prompt_len = n
-                    sl.eos = False
-                    # the enqueue stamp travels with the cursor: TTFT still
-                    # covers queue wait + every interleaved prefill chunk
-                    self._admitting[slot] = (
-                        cursor, self._enqueued_at.pop(rid, t0))
-                    from ..utils import get_metrics as _gm
+        blocks batch-mates' decode chunks behind a barrier prefill.
 
-                    _gm().inc("prefill.chunked_admissions")
-                    return True
-        last_logits = eng.prefill_slot(ids, slot)
-        self._finish_admission(slot, rid, n, last_logits, t0,
-                               self._enqueued_at.pop(rid, t0))
+        The whole admission is ONE ``sched.admit.request`` span whose parts
+        (utils.steplog.ADMISSION_PARTS; the engine's ``prefill_slot`` writes
+        ``.alloc`` and ``.prefill_call``) tile it; a raise drops its ledger
+        entry with it."""
+        eng = self.engine
+        with timer.span(REQUEST_SPAN, rid=rid, queue_ms=round(queue_ms, 3)) as req:
+            if self.tenancy is not None:
+                # tenant radix namespace (ISSUE 18): the slot's cache chains
+                # are salted with the resolved class name so one tenant's
+                # churn cannot evict another's warm chains (serve.radix)
+                with span(f"{REQUEST_SPAN}.bookkeeping"):
+                    setns = getattr(eng, "set_slot_ns", None)
+                    if setns is not None:
+                        setns(slot, self.tenancy.resolve(self._tenant.get(rid)))
+            t0 = time.perf_counter()
+            with span(f"{REQUEST_SPAN}.tokenize"):
+                ids = (eng.tokenizer.encode(prompt, bos=True)
+                       if isinstance(prompt, str) else [int(t) for t in prompt])
+            n = len(ids)
+            req.set(prompt_tokens=n)
+            C = self._prefill_chunk
+            if C > 0 and n > C:
+                begin = getattr(eng, "begin_chunked_prefill", None)
+                if begin is not None:
+                    with span(ALLOC_SPAN):
+                        cursor = begin(ids, slot, C)
+                    if cursor is not None:
+                        sl = self.slots[slot]
+                        sl.request_id = rid
+                        sl.token_ids = []
+                        sl.start_s = t0
+                        sl.prompt_len = n
+                        sl.eos = False
+                        # the enqueue stamp travels with the cursor: TTFT
+                        # still covers queue wait + every interleaved
+                        # prefill chunk (and the queue wait its own number)
+                        self._admitting[slot] = (
+                            cursor, self._enqueued_at.pop(rid, t0), queue_ms)
+                        from ..utils import get_metrics as _gm
+
+                        _gm().inc("prefill.chunked_admissions")
+                        req.drop()  # the admission lands with its last chunk
+                        return True
+            last_logits = eng.prefill_slot(ids, slot)
+            self._finish_admission(slot, rid, n, last_logits, t0,
+                                   self._enqueued_at.pop(rid, t0), queue_ms)
+            req.set(cached_tokens=self.slots[slot].cached_tokens)
         return False
 
     def _finish_admission(self, slot: int, rid: int, n: int, last_logits,
-                          t0: float, t_enq: float) -> None:
+                          t0: float, t_enq: float, queue_ms: float) -> None:
         """The admission tail shared by one-shot and chunked prefills: the
         fused grammar-mask first-token sample, per-slot device state, slot
-        bookkeeping, TTFT, and the prefill cost fold."""
+        bookkeeping, TTFT and queue wait, and the prefill cost fold."""
         eng = self.engine
-        self._rng, k = jax.random.split(self._rng)
-        start_state = jnp.full((1,), self.engine.fsm.start, dtype=jnp.int32)
-        t_fm = time.perf_counter()
-        tok0, fsm0 = _first_token(
-            last_logits, start_state, eng.tables, k,
-            jnp.float32(self.temperature), greedy=self.greedy, constrained=True,
-            kernels=eng.kernels, rules=eng.rules, logit_mask=eng.logit_mask,
-        )
+        with span(f"{REQUEST_SPAN}.slot_state"):
+            self._rng, k = jax.random.split(self._rng)
+            start_state = jnp.full((1,), self.engine.fsm.start, dtype=jnp.int32)
         # the fused grammar-mask→sample tail's ONE host-dispatched instance
-        # (every in-chunk instance is jit-inlined inside the decode loops):
-        # dispatch-side wall of the standalone _first_token jit, the number
-        # that moves when the fused Pallas tail (ops.masked_argmax_advance)
-        # replaces the mask/argmax/advance op chain
-        from ..utils import get_metrics as _gm
+        # (every in-chunk instance is jit-inlined inside the decode loops,
+        # under the ``grammar_mask_sample`` scope): the span times the
+        # dispatch of the standalone _first_token jit, the device trace the
+        # work
+        with span(FIRST_TOKEN_SPAN):
+            tok0, fsm0 = _first_token(
+                last_logits, start_state, eng.tables, k,
+                jnp.float32(self.temperature), greedy=self.greedy, constrained=True,
+                kernels=eng.kernels, rules=eng.rules, logit_mask=eng.logit_mask,
+            )
+        with span(f"{REQUEST_SPAN}.slot_state"):
+            self.cur = self.cur.at[slot].set(tok0[0])
+            self.fsm = self.fsm.at[slot].set(fsm0[0])
+            self.pos = self.pos.at[slot].set(n)
+            self.nbytes = self.nbytes.at[slot].set(0)
+            self.tokens_left = self.tokens_left.at[slot].set(self.max_new_tokens)
+            self.active = self.active.at[slot].set(True)
+        with span(f"{REQUEST_SPAN}.bookkeeping"):
+            self._book_admission(slot, rid, n, t0, t_enq, queue_ms)
 
-        _gm().set_gauge("engine.step.fused_mask_sample_ms",
-                        (time.perf_counter() - t_fm) * 1e3)
-        self.cur = self.cur.at[slot].set(tok0[0])
-        self.fsm = self.fsm.at[slot].set(fsm0[0])
-        self.pos = self.pos.at[slot].set(n)
-        self.nbytes = self.nbytes.at[slot].set(0)
-        self.tokens_left = self.tokens_left.at[slot].set(self.max_new_tokens)
-        self.active = self.active.at[slot].set(True)
-
+    def _book_admission(self, slot: int, rid: int, n: int, t0: float,
+                        t_enq: float, queue_ms: float) -> None:
+        """The host-only end of an admission (``.bookkeeping``)."""
+        eng = self.engine
         sl = self.slots[slot]
         sl.request_id = rid
         sl.token_ids = []
@@ -561,6 +581,7 @@ class ContinuousBatcher:
         _pf = getattr(eng, "_last_prefill_compute_ms", None)
         sl.prefill_ms = _pf if _pf is not None else (time.perf_counter() - t0) * 1e3
         sl.cached_tokens = int(getattr(eng, "_last_cached_tokens", 0))
+        sl.queue_ms = queue_ms
         sl.eos = False
         # TTFT: ENQUEUE through the first sampled token — queue wait
         # included, because that is the component that degrades when all
@@ -569,8 +590,11 @@ class ContinuousBatcher:
         # headline metric (WhisperFlow/WhisperKit report it first-class).
         from ..utils import get_metrics
 
-        get_metrics().observe_ms("scheduler.ttft",
-                                 (time.perf_counter() - t_enq) * 1e3)
+        m = get_metrics()
+        m.observe_ms("scheduler.ttft", (time.perf_counter() - t_enq) * 1e3)
+        # queue wait as its own number: submit() -> popped from ``pending``
+        # (a pool-starved requeue keeps its first stamp)
+        m.observe_ms("scheduler.queue_wait", queue_ms)
         # prefill cost fold (ISSUE 17): an exact cached-vs-computed
         # partition of the cold-prompt cost — the same ints land in the
         # slot ledger and the meter totals, so conservation is exact
@@ -584,45 +608,49 @@ class ContinuousBatcher:
             sl.cost["prefill_cached_flops"] = cached
             self.costs.fold_prefill(computed, cached, sl.prefill_ms)
 
-    def _advance_admissions(self, act: np.ndarray) -> tuple[int, int, float]:
+    def _advance_admissions(self, act: np.ndarray, timer) -> tuple[int, int]:
         """Advance every in-flight chunked admission by ONE prefill chunk
         (ISSUE 19). A slot whose final chunk lands finishes admission and
         goes active for this step's decode chunk; earlier chunks cost one
         bounded ``(1, C)`` dispatch each, interleaved with batch-mates'
         decode chunks instead of stalling them behind a barrier prefill.
-        Returns (completed, chunks_stepped, compute_ms) for the step
-        ledger's admit/prefill accounting."""
+        Returns (completed, chunks_stepped) for the step ledger; the
+        engine's ``.prefill_call`` spans are its prefill stage."""
         if not self._admitting:
-            return 0, 0, 0.0
+            return 0, 0
         from ..utils import get_metrics
         from ..utils.chaos import chaos_fire
 
         m = get_metrics()
         eng = self.engine
-        done, stepped, pf_ms = 0, 0, 0.0
+        done, stepped = 0, 0
         for slot in sorted(self._admitting):
-            cursor, t_enq = self._admitting[slot]
+            cursor, t_enq, queue_ms = self._admitting[slot]
             rid = self.slots[slot].request_id
-            try:
-                last_logits = eng.chunked_prefill_step(cursor)
-            except Exception as e:
-                if isinstance(e, _DeviceFault):
-                    raise  # corrupted engine: never per-request (see step)
-                # per-request chunk fence: the admission fails alone, its
-                # blocks release through the ordinary eviction seam
-                if not isinstance(e, ValueError):
-                    self._record_offense(rid, f"prefill {type(e).__name__}")
-                self._evict_slot(slot, str(e), "scheduler.prefill_faults")
-                continue
-            stepped += 1
-            pf_ms += cursor.step_ms
-            m.inc("prefill.chunks")
-            if last_logits is None:
-                continue
-            self._admitting.pop(slot, None)
-            self._finish_admission(slot, rid, self.slots[slot].prompt_len,
-                                   last_logits, self.slots[slot].start_s,
-                                   t_enq)
+            with timer.span(REQUEST_SPAN, rid=rid, queue_ms=round(queue_ms, 3),
+                            prompt_tokens=self.slots[slot].prompt_len) as req:
+                try:
+                    last_logits = eng.chunked_prefill_step(cursor)
+                except Exception as e:
+                    if isinstance(e, _DeviceFault):
+                        raise  # corrupted engine: never per-request (see step)
+                    # per-request chunk fence: the admission fails alone, its
+                    # blocks release through the ordinary eviction seam
+                    if not isinstance(e, ValueError):
+                        self._record_offense(rid, f"prefill {type(e).__name__}")
+                    self._evict_slot(slot, str(e), "scheduler.prefill_faults")
+                    req.drop()
+                    continue
+                stepped += 1
+                m.inc("prefill.chunks")
+                if last_logits is None:
+                    req.drop()  # a middle chunk: on the trace, no admission
+                    continue
+                self._admitting.pop(slot, None)
+                self._finish_admission(slot, rid, self.slots[slot].prompt_len,
+                                       last_logits, self.slots[slot].start_s,
+                                       t_enq, queue_ms)
+                req.set(cached_tokens=self.slots[slot].cached_tokens)
             act[slot] = True
             done += 1
             # chaos drill arming matches the one-shot admission path
@@ -630,7 +658,7 @@ class ContinuousBatcher:
                 self._nan_slots.add(slot)
             if chaos_fire("dead_fsm"):
                 self.fsm = self.fsm.at[slot].set(-1)
-        return done, stepped, pf_ms
+        return done, stepped
 
     # ------------------------------------------------------------ feeds
 
@@ -815,11 +843,9 @@ class ContinuousBatcher:
         per-request (device faults still propagate); poisoned rows reported
         by the decode loop are quarantined (``scheduler.slots_quarantined``)
         — in every case batch-mates continue token-identically."""
-        from ..utils import get_metrics
         from ..utils.chaos import chaos_fire
         from ..utils.steplog import get_steplog
 
-        m = get_metrics()
         epoch = self._epoch
         if chaos_fire("stall_step"):
             # chaos drill for the stalled-step watchdog: sleep as if the
@@ -829,14 +855,25 @@ class ContinuousBatcher:
             if epoch != self._epoch:
                 return
 
-        # the step ledger (ISSUE 9): one StepTimer per scheduler step,
-        # lapped at each stage boundary so the segments tile the chunk wall.
-        # Host timing only — record() no-ops when STEPLOG_ENABLE=0, and the
-        # decode path is byte-identical either way.
+        # the step ledger (ISSUE 9): one StepTimer per scheduler step, a
+        # ``sched.step`` on the profiler's trace whose four contiguous
+        # stage spans tile the chunk wall. Host timing only — record()
+        # no-ops when STEPLOG_ENABLE=0, and the decode path is
+        # byte-identical either way.
         timer = get_steplog().timer()
+        try:
+            self._step(timer, epoch)
+        finally:
+            timer.close()  # a step that raised or returned early
+
+    def _step(self, timer, epoch: int) -> None:
+        from ..utils import get_metrics
+        from ..utils.chaos import chaos_fire
+
+        m = get_metrics()
+        timer.stage("sched.admit")
         n_admitted = 0    # successful admissions (slot went live)
         n_attempted = 0   # dequeued attempts, failures/sheds included
-        admit_prefill_ms = 0.0
 
         act = self._active_h  # host mirror — no device readback for admission
         # mid-decode cancellation: a slot whose deadline expired aborts at
@@ -878,6 +915,8 @@ class ContinuousBatcher:
                 if idx is None:
                     break  # every waiter's lane is at its slot cap
                 rid, prompt = self.pending.pop(idx)
+            now = time.perf_counter()
+            queue_ms = (now - self._enqueued_at.get(rid, now)) * 1e3
             n_attempted += 1
             dl = self._deadline.get(rid)
             if dl is not None and dl.expired:
@@ -892,7 +931,7 @@ class ContinuousBatcher:
                 self._cleanup(rid)
                 continue
             try:
-                chunked = self._admit(slot, rid, prompt)
+                chunked = self._admit(slot, rid, prompt, timer, queue_ms)
                 self._pool_wait.pop(rid, None)
                 self._requeues.pop(rid, None)
                 if plane is not None:
@@ -900,7 +939,6 @@ class ContinuousBatcher:
                 if not chunked:
                     act[slot] = True
                     n_admitted += 1
-                    admit_prefill_ms += self.slots[slot].prefill_ms
                     # chaos drill arming (no-ops with chaos off): NaN logits
                     # on this slot's next chunk / FSM state forced dead (a
                     # chunked admission arms at its final chunk instead)
@@ -981,17 +1019,10 @@ class ContinuousBatcher:
         # chunked admissions (ISSUE 19): one interleaved prefill chunk per
         # in-flight admission per step — the admit/prefill ledger stages
         # show the decode isolation directly (prefill time lands in the
-        # carved prefill stage, never inside batch-mates' decode segment)
-        adm_done, adm_stepped, adm_pf_ms = self._advance_admissions(act)
+        # prefill stage, never inside batch-mates' decode segment)
+        adm_done, adm_stepped = self._advance_admissions(act, timer)
         n_admitted += adm_done
         n_attempted += adm_stepped
-        admit_prefill_ms += adm_pf_ms
-
-        timer.lap("admit")
-        # prefill compute was measured INSIDE the admission segment
-        # (engine._last_prefill_compute_ms per admission) — report it as
-        # its own stage so admit is pure queue/bookkeeping
-        timer.carve("admit", "prefill", admit_prefill_ms)
 
         if not act.any():
             if n_attempted:
@@ -1002,6 +1033,13 @@ class ContinuousBatcher:
                 timer.finish(occupancy=0, tokens=0, admitted=n_admitted)
             return
 
+        # the engine's layout-kernel calls were stage spans of their own
+        # INSIDE the admission stage (``sched.admit.prefill``, what
+        # ``prefill_ms`` times) and are reported as the prefill stage, so
+        # admit is the queue, the bookkeeping and the rest of each
+        # admission; ``*.prefill_call`` is a PART of one admission (the
+        # jitted call alone), not a stage
+        timer.stage("sched.decode_dispatch")
         eng = self.engine
         if self._nan_slots:
             mask = np.zeros((self.B,), dtype=bool)
@@ -1017,7 +1055,6 @@ class ContinuousBatcher:
         eng._last_accepts = None
         eng._last_row_fwds = None
         eng._last_row_drafted = None
-        eng._last_draft_ms = 0.0  # the step ledger's drafter carve
         self._rng, k = jax.random.split(self._rng)
         (out, n, eos, cur, pos, fsm, active,
          nbytes, tokens_left) = eng.decode_chunk(
@@ -1025,7 +1062,7 @@ class ContinuousBatcher:
             self.tokens_left, k, self.temperature, self.byte_budget,
             self.chunk_steps, self.greedy,
         )
-        timer.lap("decode")
+        timer.stage("sched.readback")
         # one transfer for everything the host needs this chunk (a combined
         # device_get is ONE host<->device sync; separate gets pay one each).
         # _last_fwds (engines that report it) rides the same transfer: the
@@ -1048,7 +1085,7 @@ class ContinuousBatcher:
         out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h = (
             np.asarray(x) for x in (out_h, n_h, act_h, eos_h, pos_h, fwds_h,
                                     pois_h))
-        timer.lap("readback")
+        timer.stage("sched.release")
         if epoch != self._epoch:
             # the watchdog warm-restarted the engine while this step was
             # stalled in flight: its world is gone — committing the chunk's
@@ -1232,6 +1269,7 @@ class ContinuousBatcher:
                     forwards=sl.forwards,
                     spec_accepted=sl.spec_accepts,
                     prompt_tokens=sl.prompt_len,
+                    queue_ms=sl.queue_ms,
                     quality=conf_summary(
                         (sl.conf_msum, sl.conf_mmin, sl.conf_esum,
                          sl.conf_forced, sl.conf_cnt), len(sl.token_ids)),
@@ -1255,9 +1293,9 @@ class ContinuousBatcher:
 
         # close the ledger entry: everything after the readback (commit,
         # release/radix-insert, gauge exports, HBM tick) is "release"; the
-        # drafter's host share (spec engines report _last_draft_ms on the
-        # same readback) is carved out of the decode segment it was
-        # measured inside, so the six stages still tile the wall
+        # drafter's host share (``sched.decode.draft`` around the spec
+        # drafter) was taken out of the decode stage it ran inside, so the
+        # six stages still tile the wall
         # roofline reconciliation (ISSUE 17): the chunk's analytic FLOPs /
         # KV bytes against the measured chunk wall -> engine.mfu /
         # engine.mbu gauges + cost.* counters (weights stream per forward
@@ -1268,8 +1306,6 @@ class ContinuousBatcher:
                             int(fwds_h) if fwds is not None else 0, chunk_s)
             except Exception:
                 pass  # metering must never become a serving fault
-        timer.lap("release")
-        timer.carve("decode", "draft", float(getattr(eng, "_last_draft_ms", 0.0)))
         timer.finish(
             occupancy=occupancy,
             tokens=int(n_h.sum()),

@@ -77,6 +77,7 @@ from ..grammar.fsm import DeviceFSM, fsm_advance, fsm_row
 from ..models.llama import PRESETS, forward, forward_paged, init_kv_cache, init_params
 from ..utils.compilewatch import watch_compiles
 from ..utils.envcfg import env_bool, env_int, env_str
+from ..utils.steplog import span
 from .engine import (
     _conf_init,
     _conf_stats,
@@ -959,7 +960,8 @@ class SpecDecoder:
                 for b in range(B)
             ]
             t_d0 = time.perf_counter()
-            dtoks, dlen = self.drafter.draft_batch(ctxs, fsm_h, act_h, K)
+            with span("sched.decode.draft"):  # the step ledger's draft stage
+                dtoks, dlen = self.drafter.draft_batch(ctxs, fsm_h, act_h, K)
             draft_ms += (time.perf_counter() - t_d0) * 1e3
             dlen = np.minimum(np.asarray(dlen, np.int32), K)
             if self._gen != gen0:
@@ -1033,7 +1035,6 @@ class SpecDecoder:
         self.last_chunk_forwards = fwds
         self.last_chunk_draft_ms = draft_ms
         eng._last_fwds = fwds
-        eng._last_draft_ms = draft_ms  # the step ledger's drafter line
         # the widened readback (satellite 2): per-row fault codes for the
         # scheduler's quarantine (a poisoned verify row evicts alone), and
         # per-row accept/participation counts for per-request accounting

@@ -84,13 +84,14 @@ def _stt_decode_loop(
     B, P = bos.shape
 
     def pick(logits):
-        if suppress is not None:
-            logits = jnp.where(suppress[None, :], -jnp.inf, logits)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        if not quality_lanes:
-            return tok, jnp.zeros((B,), jnp.float32)
-        lsm = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        return tok, jnp.take_along_axis(lsm, tok[:, None], axis=-1)[:, 0]
+        with jax.named_scope("sample"):
+            if suppress is not None:
+                logits = jnp.where(suppress[None, :], -jnp.inf, logits)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if not quality_lanes:
+                return tok, jnp.zeros((B,), jnp.float32)
+            lsm = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            return tok, jnp.take_along_axis(lsm, tok[:, None], axis=-1)[:, 0]
 
     pos0 = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[None, :], (B, P))
     logits, self_cache = decoder_forward(
@@ -479,14 +480,18 @@ class SpeechEngine:
         ``stt_garble`` chaos point — see ``finalize_stt_ids``, the one
         post-decode tail both planes share."""
         t0 = time.perf_counter()
-        cache = init_self_cache(self.cfg, 1, dtype=self._param_dtype)
-        bos = jnp.asarray(list(self.bos_ids), dtype=jnp.int32)[None, :]
-        out, n, _, conf = _stt_decode_loop(
-            self.params, self.cfg, cache, cross_kv, enc_mask, bos, self.suppress,
-            max_new=self.max_new_tokens, eos_id=self.eos_id, pad_id=self.pad_id,
-            attn_impl=self.kernels, quality_lanes=self.quality_lanes,
-        )
-        out_h, n_a, conf_h = jax.device_get((out, n, conf))
+        # on the profiler's trace one partial or final pass is ``stt.pass``,
+        # from the decode loop's dispatch to the readback that ends it
+        with jax.profiler.TraceAnnotation("stt.pass", final=int(final),
+                                          frames=int(n_frames)):
+            cache = init_self_cache(self.cfg, 1, dtype=self._param_dtype)
+            bos = jnp.asarray(list(self.bos_ids), dtype=jnp.int32)[None, :]
+            out, n, _, conf = _stt_decode_loop(
+                self.params, self.cfg, cache, cross_kv, enc_mask, bos, self.suppress,
+                max_new=self.max_new_tokens, eos_id=self.eos_id, pad_id=self.pad_id,
+                attn_impl=self.kernels, quality_lanes=self.quality_lanes,
+            )
+            out_h, n_a, conf_h = jax.device_get((out, n, conf))
         n_h = int(n_a[0])
         ids = [int(t) for t in np.asarray(out_h)[0, :n_h]]
         decode_ms = (time.perf_counter() - t0) * 1e3

@@ -66,6 +66,9 @@ def _result_to_response(res) -> ParseResponse:
     note_stage("decode_ms", round(res.decode_ms, 3))
     note_stage("cached_tokens", int(getattr(res, "cached_tokens", 0)))
     note_stage("prompt_tokens", int(getattr(res, "prompt_tokens", 0)))
+    # the wait for a batch slot (0 on the serialized backend): its own
+    # number on the /parse span and the ``x-queue-ms`` header
+    note_stage("queue_ms", round(getattr(res, "queue_ms", 0.0), 3))
     # the ISSUE 15 confidence vector rides the same stage-note channel the
     # prefill/decode split uses — the quality monitor and the response
     # headers both read it off this thread
@@ -84,6 +87,13 @@ def _result_to_response(res) -> ParseResponse:
         if res.error.startswith("shed:"):
             raise ParserError("overloaded", res.error)
         raise ParserError("llm_error", res.error)
+    # ONE request and token count for both backends (BRAIN_BATCH 1 and >1):
+    # a decode that ran to its end, EOS or truncation — what
+    # ``scheduler.requests_completed`` counts on the batched one alone
+    from ..utils import get_metrics
+
+    get_metrics().inc("brain.parse_completed")
+    get_metrics().inc("brain.parse_tokens", float(res.steps))
     if not res.finished:
         raise ParserError(
             "schema_validation_failed",
@@ -1504,6 +1514,7 @@ def build_app(parser: IntentParser, tracer: Tracer | None = None,
         # endpoint measurement; intent_margin feeds the voice HUD badge.
         for note, header in (("prefill_ms", "x-prefill-ms"),
                              ("decode_ms", "x-decode-ms"),
+                             ("queue_ms", "x-queue-ms"),
                              ("cached_tokens", "x-cached-tokens"),
                              ("prompt_tokens", "x-prompt-tokens"),
                              ("intent_margin", "x-intent-margin")):

@@ -127,7 +127,7 @@ from .replicaset import rendezvous_weight as _weight  # noqa: F401 - test surfac
 # response headers forwarded back to the caller verbatim (the brain's
 # decode-split contract the voice service folds into latency_budget, plus
 # the two-phase speculation marker and the shed backoff hint)
-_PASS_HEADERS = ("x-trace-id", "x-prefill-ms", "x-decode-ms",
+_PASS_HEADERS = ("x-trace-id", "x-prefill-ms", "x-decode-ms", "x-queue-ms",
                  "x-cached-tokens", "x-prompt-tokens", "x-intent-margin",
                  "x-speculation-pending", "retry-after")
 

@@ -247,6 +247,15 @@ class ClientState:
             get_metrics().inc("voice.feeds_reaped")
 
 
+def _feed_now(feed, samples, t_recv: float):
+    """Runs where the STT's ``feed`` runs (an executor thread, or inline on
+    the batched plane): receipt of the frame -> here is the histogram
+    ``voice.stt_feed_lag``, the wait for a thread that ``stt.feed_lag_s``
+    (audio buffered behind the model) does not see."""
+    get_metrics().observe_ms("voice.stt_feed_lag", (time.perf_counter() - t_recv) * 1e3)
+    return feed(samples)
+
+
 def _reap(task: "asyncio.Task") -> None:
     """Cancel/abandon a speculative task without 'Task exception was never
     retrieved' ERROR-log spam on GC: a dropped speculation's failure is
@@ -925,10 +934,11 @@ def build_app(cfg: VoiceConfig | None = None, tracer: Tracer | None = None) -> w
                             # the event loop responsive via the executor
                             afeed = getattr(state.stt, "feed_async", None)
                             if afeed is not None:
-                                events = await afeed(samples)
+                                events = await _feed_now(afeed, samples, t_feed0)
                             else:
                                 events = await loop.run_in_executor(
-                                    None, state.stt.feed, samples)
+                                    None, _feed_now, state.stt.feed, samples,
+                                    t_feed0)
                         except Exception as e:
                             # a truncated PCM packet must not kill the session
                             await send(ws, "warn", message=f"bad audio frame: {e}")

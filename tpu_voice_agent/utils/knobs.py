@@ -272,6 +272,7 @@ declare("COST_SESSIONS", "256", "per-session cost-rollup LRU size in the brain",
 # not operator tuning surface (the checker rejects doc rows for these)
 
 declare("JAX_COMPILATION_CACHE_DIR", None, "JAX's own persistent compile cache location; unset = <checkout>/.jax_cache (utils.compilecache)")
+declare("JAX_TRACEBACK_IN_LOCATIONS_LIMIT", None, "JAX's own: Python frames kept in HLO locations; unset = 0, so the compile cache keys on names and not on paths and lines (utils.compilecache)")
 declare("JAX_COORDINATOR_ADDRESS", None, "multihost coordinator address")
 declare("JAX_NUM_PROCESSES", None, "multihost process count")
 declare("JAX_PROCESS_ID", None, "multihost process index")

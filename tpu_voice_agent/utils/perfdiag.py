@@ -1,8 +1,8 @@
 """Automatic decode-perf diagnosis (round-3 VERDICT next #1).
 
 Chip time is budgeted, so every chip run must yield the DIAGNOSIS, not just
-the headline number. Three probes, all scripted so ``bench.py`` runs them
-unattended:
+the headline number. Two probes (the profiler capture that was the third is
+``benchmark/run.py --trace 1``, which also reduces what it captures):
 
 - ``decode_step_hlo`` / ``audit_dequant``: lower the engine's T=1 decode
   forward at its real serving shapes, compile, and scan the optimized HLO's
@@ -10,11 +10,8 @@ unattended:
   ``multiply`` instructions with HBM-sized outputs. A mis-fused int8
   dequant triples that weight's traffic (int8 read + bf16 write + bf16
   read); docs/PERF.md hypothesis 1.
-- ``capture_profile``: one ``jax.profiler`` trace around a constrained
-  generation (PERF.md's falsifier for hypotheses 2/3).
-- the ``decode_unroll`` sweep lives in ``bench.py`` (it needs the bench's
-  engine-construction knobs); ``marginal_ms_per_token`` here is the shared
-  slope measurement.
+- ``marginal_ms_per_token``: the shared slope measurement ``bench.py`` and
+  ``benches/bench_batch.py`` report.
 """
 
 from __future__ import annotations
@@ -184,20 +181,6 @@ def audit_dequant(hlo_text: str, min_bytes: int = 8 << 20) -> dict:
                 if nm not in dot_operands:
                     record("fusion:scale-in-dot", m, size, name)
     return {"findings": findings, "scanned_instructions": n}
-
-
-def capture_profile(engine, prompt: str, out_dir: str,
-                    max_new_tokens: int = 64) -> str:
-    """One profiler trace around a constrained generation; returns the
-    trace directory (inspect with tensorboard / xprof)."""
-    import os
-
-    import jax
-
-    os.makedirs(out_dir, exist_ok=True)
-    with jax.profiler.trace(out_dir):
-        engine.generate(prompt, max_new_tokens=max_new_tokens, greedy=True)
-    return out_dir
 
 
 def marginal_ms_per_token(engine, prompt: str, lengths=(64, 192),
