@@ -335,10 +335,29 @@ def test_steplog_off_is_token_identical(scope_engine):
     assert all(r.error is None for r in on)
 
 
+def _assert_parts_tile(adm):
+    """The parts of an admission sum to its ``sched.admit.request`` span to
+    within 2 %, or to within what entering and leaving the spans themselves
+    costs (0.15 ms: on the CPU an admission of the tiny model is ~1 ms now
+    that its tail is one launch) — for the median admission, so that one
+    descheduled gap on a loaded machine does not read as untimed work."""
+    import statistics
+
+    from tpu_voice_agent.utils.steplog import ADMISSION_PARTS
+
+    gaps = []
+    for a in adm:
+        parts = sum(a.get(f"{p}_ms", 0.0) for p in ADMISSION_PARTS)
+        assert parts <= a["request_ms"] + 1e-3, a
+        gaps.append((a["request_ms"] - parts, a["request_ms"]))
+    gap, whole = statistics.median_low(gaps)
+    assert gap <= max(0.02 * whole, 0.15), gaps
+
+
 def test_admissions_tile_their_request_and_count_admitted(scope_engine):
     """Each admission's parts (tokenize .. bookkeeping, the engine's
     ``.alloc`` and ``.prefill_call`` among them) tile its
-    ``sched.admit.request`` span to within 2 %, one entry per admission,
+    ``sched.admit.request`` span (``_assert_parts_tile``), one entry per admission,
     and the ``prefill`` stage keeps its meaning: what ``prefill_ms`` times,
     the layout kernel's whole call, of which the jitted call is a part."""
     from tpu_voice_agent.utils.steplog import ADMISSION_PARTS
@@ -359,9 +378,7 @@ def test_admissions_tile_their_request_and_count_admitted(scope_engine):
     for a in adm:
         assert {f"{p}_ms" for p in ADMISSION_PARTS if p != "bookkeeping"} <= set(a)
         assert a["prompt_tokens"] > 0 and a["cached_tokens"] == 0 and a["rid"] >= 0
-    parts = sum(a.get(f"{p}_ms", 0.0) for a in adm for p in ADMISSION_PARTS)
-    whole = sum(a["request_ms"] for a in adm)
-    assert 0.98 * whole <= parts <= whole + 1e-3, (parts, whole)
+    _assert_parts_tile(adm)
     # the result carries the request's own queue wait
     assert sorted(round(r.queue_ms, 3) for r in res) == sorted(
         round(a["queue_ms"], 3) for a in adm)
@@ -433,6 +450,75 @@ def test_profiler_capture_holds_the_step_and_its_admissions(scope_engine, tmp_pa
     names = {s[0] for s in spans}
     assert {"sched.admit", "sched.decode_dispatch", "sched.readback",
             "sched.release"} <= names
+
+
+def test_admission_tail_is_one_launch_and_compiles_once(scope_engine, tmp_path):
+    """A warm one-shot admission under a CPU ``jax.profiler`` capture: the
+    ``.slot_state`` part holds exactly ONE executable launch, the jitted
+    ``_first_token_into_slot``, and no eager one-op program (the eager tail
+    it replaced launched 36 there). The counter is the runtime's own
+    ``…Executable::Execute`` event on the dispatching thread, which sees
+    every program, pjit's fast path included — shown by the last-row
+    slice's eager programs it counts under ``.first_token_call``. After
+    ``warmup()`` an admission at another slot and another length compiles
+    nothing, and its ledger entry still holds the six parts (that they tile
+    the request: ``test_admissions_tile_their_request_and_count_admitted``)."""
+    from jax.profiler import ProfileData
+
+    from tpu_voice_agent.utils.steplog import ADMISSION_PARTS
+
+    bat = _batcher(scope_engine)
+    bat.warmup()
+    bat.submit("stop")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        bat.step()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    launches, jitted, kids = {}, {}, set()
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                   for ev in line.events]
+            reqs = [e for e in evs if e[0] == "sched.admit.request"]
+            if not reqs:
+                continue
+            (req,) = reqs
+            for part in (e for e in evs if e[0].startswith(req[0] + ".")
+                         and req[1] <= e[1] and e[2] <= req[2]):
+                name = part[0].rsplit(".", 1)[1]
+                kids.add(name)
+                inside = [e[0] for e in evs if part[1] <= e[1] and e[2] <= part[2]]
+                launches[name] = launches.get(name, 0) + sum(
+                    n.endswith("Executable::Execute") for n in inside)
+                jitted.setdefault(name, set()).update(
+                    n for n in inside if n.startswith("PjitFunction("))
+    assert kids == set(ADMISSION_PARTS)
+    assert launches["first_token_call"] >= 2, launches  # the hook sees eager programs
+    assert launches["slot_state"] == 1, launches
+    assert jitted["slot_state"] == {"PjitFunction(_first_token_into_slot)"}
+
+    # slot 0 is live: the next admission lands in slot 1, in the other bucket
+    assert bat._active_h[0] and not bat._active_h[1]
+    watched = get_compile_watcher().state()["compiles"]
+    compiled = []
+    listener = lambda ev, _d, **_kw: compiled.append(ev) if ev.endswith(
+        "backend_compile_duration") else None
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    get_steplog().clear()
+    try:
+        bat.submit("open the settings page, " * 12 + "then turn on dark mode")
+        bat.step()
+    finally:
+        jax._src.monitoring.unregister_event_duration_listener(listener)
+    assert bat.slots[1].prompt_len > 96 and bat._active_h[1]
+    assert not compiled and get_compile_watcher().state()["compiles"] == watched
+    (entry,) = [a for s in get_steplog().steps() for a in s.get("admissions", [])]
+    assert {f"{p}_ms" for p in ADMISSION_PARTS} <= set(entry)
+    assert sum(entry[f"{p}_ms"] for p in ADMISSION_PARTS) <= entry["request_ms"] + 1e-3
 
 
 def _hlo_shape(text: str):

@@ -1003,10 +1003,11 @@ class PagedDecodeEngine(DecodeEngine):
     def chunked_prefill_step(self, cur: "_ChunkedPrefill"):
         """Run ONE ``(1, C)`` prefill chunk of an admission started by
         ``begin_chunked_prefill``. Returns the final-token logits row when
-        the last chunk lands (the scheduler's ``_first_token`` tail takes
-        over), else None. Earlier chunks' KV is read through the slot's
-        block table with the same pow2-bucketed gather the chain admission
-        uses, so compile count stays log-bounded at one token-dim (C)."""
+        the last chunk lands (the scheduler's ``_first_token_into_slot``
+        tail takes over), else None. Earlier chunks' KV is read through the
+        slot's block table with the same pow2-bucketed gather the chain
+        admission uses, so compile count stays log-bounded at one token-dim
+        (C)."""
         slot, C, bs = cur.slot, cur.C, self.block_size
         with span(ALLOC_SPAN):
             start = cur.j * C

@@ -28,13 +28,15 @@ import os
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.steplog import ALLOC_SPAN, FIRST_TOKEN_SPAN, REQUEST_SPAN, span
-from .engine import DecodeEngine, GenerationResult, _first_token
+from ..utils.compilewatch import watch_compiles
+from ..utils.steplog import ALLOC_SPAN, REQUEST_SPAN, span
+from .engine import DecodeEngine, GenerationResult, _mask_sample_advance
 from .paged import PoolExhausted
 
 try:  # device faults must PROPAGATE out of per-request fences (a corrupted
@@ -54,6 +56,29 @@ def _err_result(error: str, steps: int = 0,
                             decode_ms=0.0, steps=steps, finished=False,
                             error=error)
 
+
+@watch_compiles("scheduler._first_token_into_slot")
+@partial(jax.jit, static_argnames=("greedy", "constrained", "kernels", "rules"))
+def _first_token_into_slot(last_logits, state, rng, slot, n, start_state,
+                           temperature, max_new_tokens, tables,
+                           greedy: bool = True, constrained: bool = True,
+                           kernels: str = "xla", rules=None, logit_mask=None):
+    """The admission tail as ONE device program: split the batcher's key,
+    pick the first token from the prefill's last-row logits (the same
+    ``_mask_sample_advance`` the standalone ``engine._first_token`` runs),
+    and write the admitted slot's entry of the six per-slot state arrays
+    ``state`` = (cur, fsm, pos, nbytes, tokens_left, active). ``slot`` and
+    ``n`` (the prompt length) are traced scalars: one compile serves every
+    slot and length. Returns the six arrays and the batcher's next key."""
+    rng, k = jax.random.split(rng)
+    tok0, fsm0 = _mask_sample_advance(
+        last_logits, start_state, tables, k, temperature, greedy,
+        constrained, kernels, rules, logit_mask)
+    cur, fsm, pos, nbytes, tokens_left, active = state
+    return (cur.at[slot].set(tok0[0]), fsm.at[slot].set(fsm0[0]),
+            pos.at[slot].set(n), nbytes.at[slot].set(0),
+            tokens_left.at[slot].set(max_new_tokens),
+            active.at[slot].set(True)), rng
 
 
 @dataclass
@@ -115,6 +140,11 @@ class ContinuousBatcher:
         self.active = jnp.zeros((self.B,), dtype=bool)
         self.nbytes = jnp.zeros((self.B,), dtype=jnp.int32)
         self.tokens_left = jnp.zeros((self.B,), dtype=jnp.int32)
+        # what every admission writes, on the device once: the admission
+        # tail (_first_token_into_slot) takes no host value but slot and n
+        self._admit_consts = (
+            jnp.full((1,), engine.fsm.start, dtype=jnp.int32),
+            jnp.float32(temperature), jnp.int32(max_new_tokens))
 
         self.slots: list[_Slot] = [_Slot() for _ in range(self.B)]
         self.pending: list[tuple[int, str]] = []
@@ -199,10 +229,11 @@ class ContinuousBatcher:
         # with decode chunks (paged engines only — duck-typed on
         # begin_chunked_prefill); unset keeps the one-shot barrier prefill
         # byte-identical. _admitting maps a reserved slot (request_id set,
-        # active False — _free_slot skips it) to its (cursor, enqueue_ts).
+        # active False — _free_slot skips it) to its (cursor, enqueue_ts,
+        # queue_ms, staged (slot, n) for the admission tail).
         pc = os.environ.get("PREFILL_CHUNK_TOKENS")
         self._prefill_chunk = int(pc) if pc else 0
-        self._admitting: dict[int, tuple[object, float]] = {}
+        self._admitting: dict[int, tuple] = {}
         if self._prefill_chunk:
             m.inc("prefill.chunked_admissions", 0.0)
             m.inc("prefill.chunks", 0.0)
@@ -524,45 +555,49 @@ class ContinuousBatcher:
                         # still covers queue wait + every interleaved
                         # prefill chunk (and the queue wait its own number)
                         self._admitting[slot] = (
-                            cursor, self._enqueued_at.pop(rid, t0), queue_ms)
+                            cursor, self._enqueued_at.pop(rid, t0), queue_ms,
+                            self._stage_slot(slot, n))
                         from ..utils import get_metrics as _gm
 
                         _gm().inc("prefill.chunked_admissions")
                         req.drop()  # the admission lands with its last chunk
                         return True
+            slot_n = self._stage_slot(slot, n)
             last_logits = eng.prefill_slot(ids, slot)
-            self._finish_admission(slot, rid, n, last_logits, t0,
+            self._finish_admission(slot, rid, n, slot_n, last_logits, t0,
                                    self._enqueued_at.pop(rid, t0), queue_ms)
             req.set(cached_tokens=self.slots[slot].cached_tokens)
         return False
 
-    def _finish_admission(self, slot: int, rid: int, n: int, last_logits,
-                          t0: float, t_enq: float, queue_ms: float) -> None:
-        """The admission tail shared by one-shot and chunked prefills: the
-        fused grammar-mask first-token sample, per-slot device state, slot
-        bookkeeping, TTFT and queue wait, and the prefill cost fold."""
+    @staticmethod
+    def _stage_slot(slot: int, n: int):
+        """The two host values the admission tail needs, put on the device
+        BEFORE the prefill is dispatched: nothing after that launch then
+        waits for a host→device copy."""
+        with span(f"{REQUEST_SPAN}.slot_state"):
+            return jax.device_put((np.int32(slot), np.int32(n)))
+
+    def _finish_admission(self, slot: int, rid: int, n: int, slot_n,
+                          last_logits, t0: float, t_enq: float,
+                          queue_ms: float) -> None:
+        """The admission tail shared by one-shot and chunked prefills: ONE
+        launch (``_first_token_into_slot``: key split, fused grammar-mask
+        first-token sample, the slot's six state entries) on arrays already
+        on the device, then slot bookkeeping, TTFT and queue wait, and the
+        prefill cost fold. Every in-chunk instance of the mask→sample tail
+        is jit-inlined inside the decode loops; this is its one
+        host-dispatched instance, under the same ``grammar_mask_sample``
+        scope."""
         eng = self.engine
         with span(f"{REQUEST_SPAN}.slot_state"):
-            self._rng, k = jax.random.split(self._rng)
-            start_state = jnp.full((1,), self.engine.fsm.start, dtype=jnp.int32)
-        # the fused grammar-mask→sample tail's ONE host-dispatched instance
-        # (every in-chunk instance is jit-inlined inside the decode loops,
-        # under the ``grammar_mask_sample`` scope): the span times the
-        # dispatch of the standalone _first_token jit, the device trace the
-        # work
-        with span(FIRST_TOKEN_SPAN):
-            tok0, fsm0 = _first_token(
-                last_logits, start_state, eng.tables, k,
-                jnp.float32(self.temperature), greedy=self.greedy, constrained=True,
-                kernels=eng.kernels, rules=eng.rules, logit_mask=eng.logit_mask,
-            )
-        with span(f"{REQUEST_SPAN}.slot_state"):
-            self.cur = self.cur.at[slot].set(tok0[0])
-            self.fsm = self.fsm.at[slot].set(fsm0[0])
-            self.pos = self.pos.at[slot].set(n)
-            self.nbytes = self.nbytes.at[slot].set(0)
-            self.tokens_left = self.tokens_left.at[slot].set(self.max_new_tokens)
-            self.active = self.active.at[slot].set(True)
+            (self.cur, self.fsm, self.pos, self.nbytes, self.tokens_left,
+             self.active), self._rng = _first_token_into_slot(
+                last_logits,
+                (self.cur, self.fsm, self.pos, self.nbytes, self.tokens_left,
+                 self.active),
+                self._rng, *slot_n, *self._admit_consts, eng.tables,
+                greedy=self.greedy, constrained=True, kernels=eng.kernels,
+                rules=eng.rules, logit_mask=eng.logit_mask)
         with span(f"{REQUEST_SPAN}.bookkeeping"):
             self._book_admission(slot, rid, n, t0, t_enq, queue_ms)
 
@@ -625,7 +660,7 @@ class ContinuousBatcher:
         eng = self.engine
         done, stepped = 0, 0
         for slot in sorted(self._admitting):
-            cursor, t_enq, queue_ms = self._admitting[slot]
+            cursor, t_enq, queue_ms, slot_n = self._admitting[slot]
             rid = self.slots[slot].request_id
             with timer.span(REQUEST_SPAN, rid=rid, queue_ms=round(queue_ms, 3),
                             prompt_tokens=self.slots[slot].prompt_len) as req:
@@ -648,8 +683,8 @@ class ContinuousBatcher:
                     continue
                 self._admitting.pop(slot, None)
                 self._finish_admission(slot, rid, self.slots[slot].prompt_len,
-                                       last_logits, self.slots[slot].start_s,
-                                       t_enq, queue_ms)
+                                       slot_n, last_logits,
+                                       self.slots[slot].start_s, t_enq, queue_ms)
                 req.set(cached_tokens=self.slots[slot].cached_tokens)
             act[slot] = True
             done += 1
