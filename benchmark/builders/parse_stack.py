@@ -1,7 +1,16 @@
 """Builder ``parse_stack``: the brain alone on a real socket — a decoder
 configuration served as the repo serves any decoder: ``PagedDecodeEngine``
 behind ``brain._wrap_batched`` (cached prompt prefix, continuous batcher).
-Whisper is never built."""
+Whisper is never built.
+
+What differs between two decoders is two functions, and ``build`` /
+``build_parser`` take them: ``llama_config(model, serving)`` -> the
+program's ``LlamaConfig`` from the configuration's own keys, and
+``make_params(cfg, seed)`` -> the served parameter tree, made on the device.
+Their defaults are the dense ones below; a builder for another block type is
+a file with its two functions and ``def build(config, rehearsal, say):
+return parse_stack.build(config, rehearsal, say, llama_config=...,
+make_params=...)``."""
 
 from __future__ import annotations
 
@@ -35,6 +44,18 @@ def apply_env(serving: dict) -> None:
     for k in serving.get("env_unset", []):
         os.environ.pop(k, None)
     os.environ.update(serving.get("env", {}))
+
+
+def dense_llama_config(m: dict, s: dict):
+    """The program's configuration of a dense GQA decoder from the source's
+    keys ``m`` and the serving parameters ``s``."""
+    from tpu_voice_agent.models.llama import LlamaConfig
+
+    return LlamaConfig(vocab_size=m["vocab_size"], dim=m["hidden_size"],
+                       n_layers=m["num_hidden_layers"], n_heads=m["num_attention_heads"],
+                       n_kv_heads=m["num_key_value_heads"], ffn_dim=m["intermediate_size"],
+                       max_seq_len=s["max_len"], rope_theta=float(m["rope_theta"]),
+                       norm_eps=float(m["rms_norm_eps"]))
 
 
 def make_decoder_params(cfg, seed: int):
@@ -75,30 +96,25 @@ def make_decoder_params(cfg, seed: int):
     return make(jax.random.key(seed, impl="rbg"))
 
 
-def build_parser(config: dict, rehearsal: bool, say):
+def build_parser(config: dict, rehearsal: bool, say, llama_config=dense_llama_config,
+                 make_params=make_decoder_params):
     """The engine behind the batcher, prefix installed, runtime started."""
     import jax
 
     from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
-    from tpu_voice_agent.models.llama import LlamaConfig
     from tpu_voice_agent.serve import PagedDecodeEngine
     from tpu_voice_agent.services.brain import _wrap_batched
 
     dims = model_dims(config, rehearsal)
     m, s = dims["model"], dims["serving"]
-    cfg = LlamaConfig(vocab_size=m["vocab_size"], dim=m["hidden_size"],
-                      n_layers=m["num_hidden_layers"], n_heads=m["num_attention_heads"],
-                      n_kv_heads=m["num_key_value_heads"], ffn_dim=m["intermediate_size"],
-                      max_seq_len=s["max_len"], rope_theta=float(m["rope_theta"]),
-                      norm_eps=float(m["rms_norm_eps"]))
     t0 = time.perf_counter()
     engine = PagedDecodeEngine(
-        cfg=cfg, tokenizer=default_tokenizer(), quant=s["quant"], batch_slots=s["batch_slots"],
+        cfg=llama_config(m, s), tokenizer=default_tokenizer(), quant=s["quant"], batch_slots=s["batch_slots"],
         block_size=s["block_size"], pool_blocks=s["pool_blocks"], max_len=s["max_len"],
         prefill_buckets=tuple(s["prefill_buckets"]), fast_forward=s["fast_forward"],
         init_weights=False)
     t1 = time.perf_counter()
-    engine.load_params(make_decoder_params(engine.cfg, s["weights_seed"]))
+    engine.load_params(make_params(engine.cfg, s["weights_seed"]))
     jax.block_until_ready(engine.params)
     t2 = time.perf_counter()
     parser = _wrap_batched(engine)  # installs the prompt prefix, starts the serving loop
@@ -122,12 +138,13 @@ class Served:
             c()
 
 
-def build(config: dict, rehearsal: bool, say) -> Served:
+def build(config: dict, rehearsal: bool, say, **model_specific) -> Served:
+    """``model_specific``: ``build_parser``'s ``llama_config`` / ``make_params``."""
     from tpu_voice_agent.services import warm_up
     from tpu_voice_agent.services.brain import build_app
     from tpu_voice_agent.services.stack import AppServer
 
-    parser, dims = build_parser(config, rehearsal, say)
+    parser, dims = build_parser(config, rehearsal, say, **model_specific)
     t0 = time.perf_counter()
     warm_up(parser)
     say(f"decoder warm-up {time.perf_counter() - t0:.1f}s")

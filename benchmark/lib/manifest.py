@@ -59,12 +59,57 @@ def load_layer_metric(name: str) -> dict:
     return load_json(f"benchmark/layer_metrics/{name}.json")
 
 
+# what a module found by name owes the harness (README.md has the reference's
+# protocol in full); ``load_code`` refuses a module that lacks any of it
+OWES = {"builders": ("build",), "generators": ("warm", "run"), "readers": ("read",),
+        "reference": ("SAMPLE", "TOLERANCE", "CONTROL", "logits")}
+
+
 def load_code(kind: str, name: str):
     """``benchmark/<kind>/<name>.py`` (kind: builders, generators, readers,
     reference), by name."""
-    if not NAME_RE.match(name):
+    if not isinstance(name, str) or not NAME_RE.match(name):
         raise ValueError(f"bad {kind} name {name!r}")
-    return importlib.import_module(f"benchmark.{kind}.{name}")
+    mod = importlib.import_module(f"benchmark.{kind}.{name}")
+    lacks = [a for a in OWES[kind] if not hasattr(mod, a)]
+    if lacks:
+        raise AttributeError(f"benchmark/{kind}/{name}.py lacks {lacks}: a module of {kind!r} "
+                             f"owes {list(OWES[kind])}")
+    return mod
+
+
+def references_of(config: dict) -> list:
+    """The references a resolved configuration is compared with: the decoder
+    file's that it pulls in first, then its own."""
+    return [c.get("reference") for c in (config.get("decoder"), config) if c]
+
+
+def code_problems(cell: dict) -> list[str]:
+    """Every piece of code a resolved cell names — builder, references,
+    generator, its per-layer metrics' readers — imports and has what its
+    kind owes; ``run.py`` asks before it builds anything, so that a wrong
+    name costs no set-up and no window."""
+    from .refcheck import SAMPLERS
+
+    config = cell["config"]
+    named = [("builders", config.get("builder"), "builder")]
+    named += [("reference", r, "reference") for r in references_of(config)]
+    named.append(("generators", cell["traffic"].get("generator"), "generator"))
+    bad: list[str] = []
+    for m in cell["per_layer"]:
+        try:
+            named.append(("readers", load_layer_metric(m["name"]).get("reader"), f"reader of {m['name']}"))
+        except (OSError, ValueError) as e:
+            bad.append(f"per-layer metric {m['name']}: no readable layer_metrics file ({e})")
+    for kind, name, what in named:
+        try:
+            mod = load_code(kind, name)
+        except (ImportError, AttributeError, ValueError) as e:
+            bad.append(f"{what} {name!r}: {type(e).__name__}: {e}")
+            continue
+        if kind == "reference" and mod.SAMPLE not in SAMPLERS:
+            bad.append(f"{what} {name!r}: SAMPLE {mod.SAMPLE!r} is none of {sorted(SAMPLERS)}")
+    return bad
 
 
 def validate(manifest: dict) -> list[str]:

@@ -1,6 +1,7 @@
 """From a profiler trace (``.xplane.pb``) to numbers. Pure functions over
 plain tuples, so that the arithmetic is tested on a small recorded trace
-and on hand-made intervals; only ``load_xplane`` touches JAX.
+and on hand-made intervals; only ``load_xplane`` touches JAX and the file,
+and it is the run's one pass over it.
 
 Intervals are ``(start_ns, end_ns)``; events are ``(name, start_ns,
 dur_ns)``. Device lines nest (a ``while`` holds its body's ops), so busy
@@ -16,14 +17,22 @@ ANCHOR = "benchmark_anchor"       # the harness writes these two itself
 ANCHOR_END = "benchmark_anchor_end"
 
 
+SPAN_PREFIX = "sched."            # the program's own spans (``utils/steplog.py``)
+HOST_PREFIXES = (ANCHOR, SPAN_PREFIX)  # the host events ``load_xplane`` keeps
+SCOPE_STAT = "tf_op"  # where a TPU trace keeps an op's named_scope path
+
+
 def load_xplane(path: str) -> dict:
-    """``{"device": {plane: {line: [(name, start_ns, dur_ns)]}}, "host":
-    [(name, start_ns, dur_ns)]}`` — host events only where named like an
-    anchor (the rest of the host plane is large and unused)."""
+    """The run's ``.xplane.pb``, read ONCE for every reader of the run:
+    ``{"device": {plane: {line: [(name, start_ns, dur_ns)]}}, "host":
+    [(name, start_ns, dur_ns)], "scope": {op name: scope path}}`` — host
+    events only where named like an anchor or like the program's spans (the
+    rest of the host plane is large and unused); scope paths of the first
+    plane that holds operations, the one ``reduce`` reads by operation."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
-    out: dict = {"device": {}, "host": []}
+    out: dict = {"device": {}, "host": [], "scope": {}}
     for plane in data.planes:
         if plane.name.startswith("/device:"):
             lines = out["device"].setdefault(plane.name, {})
@@ -33,9 +42,101 @@ def load_xplane(path: str) -> dict:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith(ANCHOR):
+                    if ev.name.startswith(HOST_PREFIXES):
                         out["host"].append((ev.name, int(ev.start_ns), int(ev.duration_ns)))
+    with_ops = sorted(op_lines(out["device"]))
+    if with_ops:
+        out["scope"] = op_scopes(path, with_ops[0])
     return out
+
+
+def _varint(buf, i: int) -> tuple[int, int]:
+    n = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        n |= (b & 0x7F) << shift
+        shift += 7
+        if not b & 0x80:
+            return n, i
+
+
+def _fields(buf):
+    """(field number, value) of one protobuf message: a varint as an int, a
+    length-delimited field as a memoryview, a fixed-width one as None."""
+    i = 0
+    while i < len(buf):
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            n, i = _varint(buf, i)
+            yield key >> 3, n
+        elif wire == 2:
+            n, i = _varint(buf, i)
+            yield key >> 3, buf[i:i + n]
+            i += n
+        else:  # fixed 64 (1) or fixed 32 (5)
+            yield key >> 3, None
+            i += 8 if wire == 1 else 4
+
+
+def op_scopes(path: str, plane_name: str) -> dict[str, str]:
+    """``{an operation's trace name: its scope path}`` for one plane, from
+    the ``.xplane.pb`` itself. On a TPU v5e an XLA op's ``jax.named_scope``
+    path (HLO ``op_name`` metadata) is the stat ``tf_op`` of its EVENT
+    METADATA, which ``jax.profiler.ProfileData`` does not show (an event's
+    ``stats`` are its own three: offset, duration, time scale). The file is
+    an ``XSpace`` message; only the named plane's two metadata maps are
+    walked (XPlane: 2 name, 4 event_metadata, 5 stat_metadata; XEventMetadata:
+    2 name, 5 stats; XStat: 1 metadata_id, 5 str_value, 7 ref_value)."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    text = lambda v: bytes(v).decode("utf-8", "replace")
+    for field, plane in _fields(space):
+        if field != 1:
+            continue
+        parts = list(_fields(plane))
+        if not any(f == 2 and text(v) == plane_name for f, v in parts):
+            continue
+        stat_names = {}
+        for f, entry in parts:
+            if f == 5:  # map entry: 1 key, 2 XStatMetadata(1 id, 2 name)
+                meta = dict((k, v) for k, v in _fields(dict(_fields(entry))[2]) if k in (1, 2))
+                stat_names[meta.get(1, 0)] = text(meta.get(2, b""))
+        out = {}
+        for f, entry in parts:
+            if f != 4:  # map entry: 1 key, 2 XEventMetadata
+                continue
+            name, scope = None, None
+            for k, v in _fields(dict(_fields(entry))[2]):
+                if k == 2:
+                    name = text(v)
+                elif k == 5:
+                    st = dict(_fields(v))
+                    if stat_names.get(st.get(1)) == SCOPE_STAT:
+                        scope = text(st[5]) if 5 in st else stat_names.get(st.get(7), "")
+            if name and scope:
+                out[name] = scope.rstrip(":")
+        return out
+    return {}
+
+
+def first_plane(trace: dict) -> dict | None:
+    """What the readers of the program's spans and scopes read
+    (``readers/host_spans.py``, ``scopes.py``): ``{"spans": [(name, start_ns,
+    end_ns)], "anchors": {name: start_ns}, "ops": [(name, start_ns, dur_ns)],
+    "modules": [...], "scope": {op name: scope path}}`` of the first device
+    plane that holds operations and the host's kept events; None without one."""
+    with_ops = sorted(op_lines(trace["device"]))
+    if not with_ops:
+        return None
+    lines = trace["device"][with_ops[0]]
+    host = trace["host"]
+    return {"spans": sorted(((n, s, s + d) for n, s, d in host if not n.startswith(ANCHOR)),
+                            key=lambda e: e[1]),
+            "anchors": {n: s for n, s, _ in host if n.startswith(ANCHOR)},
+            "ops": lines["XLA Ops"], "modules": lines.get("XLA Modules", []),
+            "scope": trace.get("scope", {})}
 
 
 def find_xplane(trace_dir: str) -> str | None:
@@ -158,7 +259,7 @@ def reduce(trace: dict, steps: list[dict], anchor_wall_s: float, stages: tuple[s
            n_chips: int = 1) -> dict | None:
     """Everything the harness reports from one trace, or None when the trace
     holds no device operation inside the anchored window."""
-    anchors = {n: s for n, s, _ in trace["host"]}
+    anchors = {n: s for n, s, _ in trace["host"] if n.startswith(ANCHOR)}
     ops = op_lines(trace["device"])
     if not ops:
         return None
@@ -187,4 +288,5 @@ def reduce(trace: dict, steps: list[dict], anchor_wall_s: float, stages: tuple[s
     return {"busy_s": sum(busy) / len(busy) / 1e9, "window_s": (hi - lo) / 1e9,
             "device_ops": top(own), "idle_gaps": top(idle),
             "programs": {k: {"count": len(v), "total_s": sum(v) / 1e9} for k, v in progs.items()},
-            "anchored": ANCHOR in anchors and ANCHOR_END in anchors}
+            "anchored": ANCHOR in anchors and ANCHOR_END in anchors,
+            "plane": first_plane(trace)}

@@ -2,8 +2,8 @@
 spans (``lib.trace.gaps`` of the device's operations, cut by them). The
 batcher writes ``sched.*`` spans (``utils/steplog.py``) onto the profiler's
 trace as it works, so they lie on the clock the device's operations lie on: no ledger stamp rounded to a millisecond, no stages laid
-end to end after the fact (``lib/trace.stage_spans``). The run's
-``.xplane.pb`` is parsed once, for all metrics of the run.
+end to end after the fact (``lib/trace.stage_spans``). They come out of
+``lib.trace.load_xplane``'s one pass over the run's ``.xplane.pb``.
 
 ``idle_ms_per_span`` — idle nanoseconds of the anchored stretch lying under
 spans named ``span``, per such span that starts inside the stretch.
@@ -18,117 +18,12 @@ shifted by it, and the shift is printed."""
 
 from __future__ import annotations
 
-import os
-
 from ..lib import trace as tr
 
-PREFIX = "sched."
+PREFIX = tr.SPAN_PREFIX
 # a launching span (by the end of its name) -> the program it dispatches
 LAUNCHES = {".prefill_call": "forward_paged", "sched.decode_dispatch": "paged_chunk_decode_loop"}
-SCOPE_STAT = "tf_op"  # where a TPU trace keeps an op's named_scope path
 PAIR_SLACK_NS = 5_000_000  # a program may read as starting this long before its launch
-_parsed: dict = {}  # the run's trace, parsed once
-
-
-def load(path: str) -> dict:
-    """``{"spans": [(name, start_ns, end_ns)], "anchors": {name: start_ns},
-    "ops": [(name, start_ns, dur_ns)], "modules": [...], "scope": {op name:
-    scope path}}`` of the first device plane and the host's ``sched.*``
-    events; None without a device."""
-    from jax.profiler import ProfileData
-
-    data = ProfileData.from_file(path)
-    spans, anchors, device = [], {}, {}
-    for plane in data.planes:
-        if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name.startswith(PREFIX):
-                        s = int(ev.start_ns)
-                        spans.append((ev.name, s, s + int(ev.duration_ns)))
-                    elif ev.name.startswith(tr.ANCHOR):
-                        anchors[ev.name] = int(ev.start_ns)
-        elif plane.name.startswith("/device:") and any(ln.name == "XLA Ops" for ln in plane.lines):
-            device[plane.name] = plane  # a plane of operations, as lib.trace.op_lines picks them
-    if not device:
-        return None
-    first = sorted(device)[0]
-    lines = {ln.name: [(ev.name, int(ev.start_ns), int(ev.duration_ns)) for ev in ln.events]
-             for ln in device[first].lines if ln.name in ("XLA Ops", "XLA Modules")}
-    return {"spans": sorted(spans, key=lambda s: s[1]), "anchors": anchors,
-            "ops": lines.get("XLA Ops", []), "modules": lines.get("XLA Modules", []),
-            "scope": op_scopes(path, first)}
-
-
-def _varint(buf, i: int) -> tuple[int, int]:
-    n = shift = 0
-    while True:
-        b = buf[i]
-        i += 1
-        n |= (b & 0x7F) << shift
-        shift += 7
-        if not b & 0x80:
-            return n, i
-
-
-def _fields(buf):
-    """(field number, value) of one protobuf message: a varint as an int, a
-    length-delimited field as a memoryview, a fixed-width one as None."""
-    i = 0
-    while i < len(buf):
-        key, i = _varint(buf, i)
-        wire = key & 7
-        if wire == 0:
-            n, i = _varint(buf, i)
-            yield key >> 3, n
-        elif wire == 2:
-            n, i = _varint(buf, i)
-            yield key >> 3, buf[i:i + n]
-            i += n
-        else:  # fixed 64 (1) or fixed 32 (5)
-            yield key >> 3, None
-            i += 8 if wire == 1 else 4
-
-
-def op_scopes(path: str, plane_name: str) -> dict[str, str]:
-    """``{an operation's trace name: its scope path}`` for one plane, from
-    the ``.xplane.pb`` itself. On a TPU v5e an XLA op's ``jax.named_scope``
-    path (HLO ``op_name`` metadata) is the stat ``tf_op`` of its EVENT
-    METADATA, which ``jax.profiler.ProfileData`` does not show (an event's
-    ``stats`` are its own three: offset, duration, time scale). The file is
-    an ``XSpace`` message; only the named plane's two metadata maps are
-    walked (XPlane: 2 name, 4 event_metadata, 5 stat_metadata; XEventMetadata:
-    2 name, 5 stats; XStat: 1 metadata_id, 5 str_value, 7 ref_value)."""
-    with open(path, "rb") as f:
-        space = memoryview(f.read())
-    text = lambda v: bytes(v).decode("utf-8", "replace")
-    for field, plane in _fields(space):
-        if field != 1:
-            continue
-        parts = list(_fields(plane))
-        if not any(f == 2 and text(v) == plane_name for f, v in parts):
-            continue
-        stat_names = {}
-        for f, entry in parts:
-            if f == 5:  # map entry: 1 key, 2 XStatMetadata(1 id, 2 name)
-                meta = dict((k, v) for k, v in _fields(dict(_fields(entry))[2]) if k in (1, 2))
-                stat_names[meta.get(1, 0)] = text(meta.get(2, b""))
-        out = {}
-        for f, entry in parts:
-            if f != 4:  # map entry: 1 key, 2 XEventMetadata
-                continue
-            name, scope = None, None
-            for k, v in _fields(dict(_fields(entry))[2]):
-                if k == 2:
-                    name = text(v)
-                elif k == 5:
-                    st = dict(_fields(v))
-                    if stat_names.get(st.get(1)) == SCOPE_STAT:
-                        scope = text(st[5]) if 5 in st else stat_names.get(st.get(7), "")
-            if name and scope:
-                out[name] = scope.rstrip(":")
-        return out
-    return {}
 
 
 def clock_shift(spans, modules, launches=LAUNCHES) -> tuple[int, int]:
@@ -198,19 +93,11 @@ def reduce(trace: dict) -> dict | None:
 
 
 def run_trace(ctx: dict) -> dict | None:
-    """The run's own trace as ``load`` gives it, parsed once (``scopes``
-    reads it too); None without a traced stretch or a device operation."""
-    if not ctx.get("trace"):
-        return None
-    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    path = tr.find_xplane(os.path.join(root, ".bench_trace"))
-    if path is None:
-        return None
-    key = (path, os.path.getmtime(path))
-    if key not in _parsed:
-        _parsed.clear()
-        _parsed[key] = load(path)
-    return _parsed[key]
+    """The first device plane and the host's spans, as
+    ``lib.trace.first_plane`` gives them (``scopes`` and ``roofline`` read it
+    too) out of the run's one pass over its ``.xplane.pb``; None without a
+    traced stretch or a device operation."""
+    return (ctx.get("trace") or {}).get("plane")
 
 
 def _reduced(ctx: dict) -> dict | None:

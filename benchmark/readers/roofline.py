@@ -8,11 +8,17 @@ clock and counters; idle time counts against it.
 ``program_roofline`` — a device program's share of its roofline: the least
 time a decode forward can take on this chip (the larger of bytes / HBM
 bandwidth and FLOPs / bf16 peak, from shapes) over the device time per
-forward of the chunk-decode program in the trace."""
+forward of the chunk-decode program in the trace. The forwards are counted
+in the SAME traced executions, as ``readers/scopes.py`` counts them (the
+occurrences of the operation under ``lm_head``): the ledger's mean
+forwards a chunk over the whole window read 36.3 % for 27.8 % on a
+``parse_solo`` stretch of 16-forward and 1-forward chunks (PERF.md section 6)."""
 
 from __future__ import annotations
 
 from ..lib import peaks as pk
+from .host_spans import run_trace
+from .scopes import scope_ns
 
 
 def _shape(ctx: dict):
@@ -38,13 +44,11 @@ def read(ctx: dict, what: str, program: str = "paged_chunk_decode_loop"):
         return 100.0 * fwd_per_s * pk.forward_bytes(model, wbytes, round(rows), int(context)) \
             / peaks["bytes_per_s"]
     if what == "program_roofline":
-        tr = ctx.get("trace")
-        hit = [v for k, v in (tr or {}).get("programs", {}).items() if program in k]
-        if not hit:
+        plane = run_trace(ctx)
+        runs = scope_ns(plane, [], program) if plane else None  # counted by scopes' own ``per``
+        if not runs or not runs["forwards"]:  # no such program in the stretch, or one without scopes
             return None
-        # forwards inside the traced executions: the ledger's mean per chunk
-        per_chunk = sum(s["forwards"] for s in steps) / len(steps)
-        dev_s = sum(v["total_s"] for v in hit) / (sum(v["count"] for v in hit) * per_chunk)
+        dev_s = runs["program_ns"] / 1e9 / runs["forwards"]
         floor, _ = pk.forward_floor_s(model, peaks, wbytes, round(rows),
                                       1 + ctx["serving"]["fast_forward"], int(context))
         return 100.0 * floor / dev_s

@@ -38,9 +38,10 @@ def in_scope(path: str, scopes: list[str]) -> bool:
 
 
 def scope_ns(trace: dict, scopes: list[str], program: str, per: str = "lm_head") -> dict:
-    """``{"ns": self nanoseconds under scopes, "forwards", "runs"}`` over
-    the executions of ``program`` whole inside the anchored stretch."""
-    out = {"ns": 0, "forwards": 0, "runs": 0}
+    """``{"ns": self nanoseconds under scopes, "forwards", "runs",
+    "program_ns": device nanoseconds of those runs}`` over the executions of
+    ``program`` whole inside the anchored stretch."""
+    out = {"ns": 0, "forwards": 0, "runs": 0, "program_ns": 0}
     ops = trace["ops"]
     if not ops:
         return out
@@ -62,7 +63,8 @@ def scope_ns(trace: dict, scopes: list[str], program: str, per: str = "lm_head")
     once = [k for name, k in times.items() if in_scope(paths.get(name, ""), [per])]
     return {"ns": sum(ns for name, ns in own.items()
                       if in_scope(paths.get(name) or tr.short_name(name).split(" ")[0], scopes)),
-            "forwards": max(once, default=0), "runs": len(runs)}
+            "forwards": max(once, default=0), "runs": len(runs),
+            "program_ns": sum(b - a for a, b in runs)}
 
 
 def read(ctx: dict, scopes: list[str], program: str, per: str = "lm_head"):

@@ -16,6 +16,10 @@ for stride 2, which is what ``models/whisper.py`` runs — where the released
 model pads (1, 1). The two see the mel frames shifted by one; on seeded
 random weights that is the same model, on a real checkpoint it is not
 (PERF.md lists it for the program to repair). ``conv2_pad`` says which.
+
+What this module owes the comparison (``lib/refcheck.py``; README.md "What a
+reference module owes"): ``SAMPLE``, ``TOLERANCE``, ``CONTROL`` and
+``logits`` at the end of the file.
 """
 
 from __future__ import annotations
@@ -125,3 +129,29 @@ def decoder(p, tokens, enc_out, n_valid, *, nh, eps, via=None):
 
         x, _ = jax.lax.scan(block, x, p["layers"])
         return layer_norm(x, f32(p["ln_final"]), eps) @ tok_emb.T
+
+
+# ---- what the comparison reads (lib/refcheck.py) ----
+
+SAMPLE = "speech"    # the served rows: refcheck.SAMPLERS["speech"]
+CONTROL = "float8"   # e4m3, the precision below the configuration's bf16 weights
+# bf16 weights AND bf16 activations through 32 + 32 layers against float32,
+# the program's tanh GELU against the published erf form: the served path
+# measured 1.48-1.69 % of the logit range, the float8 control 10.7-12.6 %
+# (my chip runs, PR 23, TPU v5e, full width). 3 % is under twice the sound
+# runs' largest and under a third of the control's smallest.
+TOLERANCE = 0.03
+LAYER_NORM_EPS = 1e-5  # the released model's LayerNorm default; config.json has no key for it
+
+
+def logits(params: dict, model: dict, sample: dict, control: bool = False):
+    """The reference's rows for a served sample ``{"mel": (T, n_mels) as the
+    served encoder saw it, "tokens": the teacher-forced decoder input,
+    "n_valid": encoder frames that are audio, "first": the first position
+    the served side read}``; with ``control`` the weights rounded to float8."""
+    via = jnp.float8_e4m3fn if control else None
+    enc = encoder(params["encoder"], sample["mel"], nh=int(model["encoder_attention_heads"]),
+                  eps=LAYER_NORM_EPS, via=via)
+    return decoder(params["decoder"], jnp.asarray(sample["tokens"], jnp.int32), enc,
+                   sample["n_valid"], nh=int(model["decoder_attention_heads"]),
+                   eps=LAYER_NORM_EPS, via=via)[sample["first"]:]

@@ -379,6 +379,9 @@ def measure(args, cell: dict, rehearsal: bool) -> tuple[dict, int]:
             f"two {1e3 * max(waits, default=0.0):.0f} ms), "
             f"compiles in window {len(in_window)} (watched {len(watched)}); HBM peak "
             f"{peak_bytes / 1e9:.3f} GB")
+        if steps:  # a stall in an untraced run names its stage (PERF.md section 6, "the long step")
+            say("longest step of the window, its whole ledger record: "
+                + json.dumps(max(steps, key=lambda s: s["wall_ms"])))
         if done:
             chars = [len(json.dumps(r["body"], separators=(",", ":"))) for r in records
                      if r["outcome"] == "plan"]
@@ -417,12 +420,10 @@ def measure(args, cell: dict, rehearsal: bool) -> tuple[dict, int]:
             if not args.trace:
                 problems.append(f"end-to-end metrics missing: {missing}")
 
-        # the plain reference, outside the window and outside setup_s
-        seen = [refcheck.check_decoder(served, args.seed, say)]
-        if served.stt_engine is not None:
-            seen.append(refcheck.check_whisper(served, args.seed, say))
-        if not all(c["ok"] for c in seen):
-            problems.append("the served model disagrees with the plain reference")
+        # the plain references the configuration names, outside the window and outside setup_s
+        for c in refcheck.compare(served, config, args.seed, say):
+            if not c["ok"]:
+                problems.append(f"the served model disagrees with the plain reference {c['reference']!r}")
         for p in problems[:12]:
             say(f"NOT CORRECT: {p}")
         result = {"correct": not problems and not rehearsal, "attempted": attempted,
@@ -433,6 +434,20 @@ def measure(args, cell: dict, rehearsal: bool) -> tuple[dict, int]:
     finally:
         client.close()
         served.close()
+
+
+def program_env(config: dict) -> None:
+    """The program's caches, inside the checkout at fixed paths, and its
+    knobs as the configuration files state them — before the program is
+    imported (some are read at import)."""
+    os.environ["TPU_VOICE_CACHE_DIR"] = os.path.join(ROOT, ".jax_cache", "tpu_voice_cache")
+    os.makedirs(os.environ["TPU_VOICE_CACHE_DIR"], exist_ok=True)
+    from benchmark.builders.parse_stack import apply_env
+
+    for conf in (config.get("decoder"), config):
+        if conf:
+            apply_env(conf["serving"])
+    os.environ.pop("BENCH_RUN", None)  # the driver's own; nothing here may read it
 
 
 def main() -> int:
@@ -446,7 +461,7 @@ def main() -> int:
     sys.path.insert(0, ROOT)
     if not os.path.isdir(os.path.join(ROOT, "tpu_voice_agent")):
         return refuse("the program (tpu_voice_agent/) is not in this directory")
-    from benchmark.lib.manifest import load_cell, load_manifest, validate
+    from benchmark.lib.manifest import code_problems, load_cell, load_manifest, validate
 
     manifest = load_manifest()
     bad = validate(manifest)
@@ -454,16 +469,10 @@ def main() -> int:
         return refuse("BENCHMARK.json: " + "; ".join(bad[:5]))
     cell = load_cell(manifest, args.workload)
     rehearsal = os.environ.get("JAX_PLATFORMS", "") == "cpu"
-    # the program's caches, inside the checkout at fixed paths; its knobs as
-    # the configuration files state them — before the program is imported
-    os.environ["TPU_VOICE_CACHE_DIR"] = os.path.join(ROOT, ".jax_cache", "tpu_voice_cache")
-    os.makedirs(os.environ["TPU_VOICE_CACHE_DIR"], exist_ok=True)
-    from benchmark.builders.parse_stack import apply_env
-
-    for conf in (cell["config"].get("decoder"), cell["config"]):
-        if conf:
-            apply_env(conf["serving"])
-    os.environ.pop("BENCH_RUN", None)  # the driver's own; nothing here may read it
+    program_env(cell["config"])
+    bad = code_problems(cell)  # before any build: a wrong name costs no set-up and no window
+    if bad:
+        return refuse(f"cell {args.workload} names code that is not there: " + "; ".join(bad[:5]))
     result, rc = measure(args, cell, rehearsal)
     if rc == 0:
         print(json.dumps(result), flush=True)

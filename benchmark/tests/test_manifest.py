@@ -31,7 +31,7 @@ def test_cell_resolves_to_files_and_code(cell):
     assert hasattr(mf.load_code("builders", c["config"]["builder"]), "build")
     gen = mf.load_code("generators", c["traffic"]["generator"])
     assert hasattr(gen, "run") and hasattr(gen, "warm")
-    assert (BENCH / "reference" / f"{c['config']['reference']}.py").exists()
+    assert mf.code_problems(c) == []
     names = {m["name"] for m in c["end_to_end"]}
     assert "setup_s" in names and len(names) >= 2 and c["per_layer"]
 
@@ -55,6 +55,22 @@ def test_validate_refuses_a_metric_whose_cells_do_not_report_what_it_moves():
     bad = json.loads(json.dumps(M))
     bad["per_layer"][0]["unit"] = "tokens per second"
     assert any("bad unit" in p for p in mf.validate(bad))
+
+
+def test_code_problems_names_every_piece_of_code_that_is_not_there():
+    cell = mf.load_cell(M, "parse_solo")
+    assert mf.code_problems(cell) == []
+    bad = json.loads(json.dumps(cell))
+    bad["config"]["reference"] = "whisperr"
+    bad["config"]["builder"] = "client"  # no such file under builders/
+    bad["traffic"]["generator"] = "_http"  # a module there, without warm / run
+    bad["config"]["decoder"] = {"reference": "../decoder"}
+    got = mf.code_problems(bad)
+    assert len(got) == 4 and all("Error" in g for g in got)
+    assert any(g.startswith("generator '_http'") and "lacks" in g for g in got)
+    assert any(g.startswith("reference '../decoder'") and "bad reference name" in g for g in got)
+    del bad["config"]["builder"]
+    assert any("builder None" in g for g in mf.code_problems(bad))
 
 
 def test_the_voice_configuration_pulls_in_the_decoder_file_unchanged():
@@ -84,8 +100,7 @@ def test_every_data_file_names_code_that_exists_whether_or_not_the_manifest_name
     manifest (PERF.md section 7): a later PR adds entries, not files."""
     for path in sorted((BENCH / "configs").glob("*.json")):
         conf = json.loads(path.read_text())
-        assert hasattr(mf.load_code("builders", conf["builder"]), "build"), path
-        assert (BENCH / "reference" / f"{conf['reference']}.py").exists(), path
+        assert mf.load_code("builders", conf["builder"]) and mf.load_code("reference", conf["reference"])
     for path in sorted((BENCH / "traffic").glob("*.json")):
         gen = mf.load_code("generators", json.loads(path.read_text())["generator"])
         assert hasattr(gen, "run") and hasattr(gen, "warm"), path

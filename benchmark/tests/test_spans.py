@@ -173,6 +173,29 @@ def test_scopes_divides_by_the_forwards_of_the_stretch_and_warns_without_scopes(
     assert "WARNING: none of its operations is under 'lm_head'" in capsys.readouterr().out
 
 
+def test_program_roofline_divides_by_the_forwards_of_the_traced_executions():
+    """The same divisor as ``scopes``: the recorded chunk ran four forwards,
+    whatever the ledger's mean over the window says (16 here)."""
+    from benchmark.lib import peaks as pk
+    from benchmark.readers import roofline
+
+    data = recorded()
+    model = {"hidden_size": 4096, "intermediate_size": 14336, "num_hidden_layers": 32,
+             "num_attention_heads": 32, "num_key_value_heads": 8, "vocab_size": 32000}
+    peaks = pk.peaks_for("TPU v5 lite")
+    ctx = {"trace": {"plane": data}, "peaks": peaks, "model": model, "window_s": 1.0,
+           "serving": {"quant": "int8", "fast_forward": 8}, "prefix_tokens": 879,
+           "tokens_per_request": 34.0, "steps": [{"forwards": 16, "occupancy": 32}] * 3}
+    (program_ns,) = [d for n, _, d in data["modules"] if "paged_chunk_decode_loop" in n]
+    floor, _ = pk.forward_floor_s(model, peaks, 1, 32, 9, 879 + 17)
+    got = roofline.read(ctx, "program_roofline", "paged_chunk_decode_loop")
+    assert got == pytest.approx(100.0 * floor / (program_ns / 1e9 / 4))
+    # a program with no loop has no forwards to divide by; no trace, nothing to read
+    assert roofline.read(ctx, "program_roofline", "forward_paged") is None
+    assert roofline.read(dict(ctx, trace=None), "program_roofline", "paged_chunk_decode_loop") is None
+    assert roofline.read(dict(ctx, trace=None), "weight_read_util") > 0  # needs no trace
+
+
 def test_op_scopes_reads_the_metadata_stat_off_the_wire():
     """A hand-built XSpace: one plane, one stat name, two event metadata."""
     def varint(n):
@@ -202,6 +225,6 @@ def test_op_scopes_reads_the_metadata_stat_off_the_wire():
     with tempfile.NamedTemporaryFile(suffix=".xplane.pb") as f:
         f.write(decoy + plane)
         f.flush()
-        assert host_spans.op_scopes(f.name, "/device:TPU:0") == {
+        assert tr.op_scopes(f.name, "/device:TPU:0") == {
             "%fusion.1 = f32[] fusion()": "jit(f)/layer/ffn/dot_general"}
-        assert host_spans.op_scopes(f.name, "/device:TPU:1") == {}
+        assert tr.op_scopes(f.name, "/device:TPU:1") == {}

@@ -71,6 +71,29 @@ def test_trace_reader_times_a_program_on_the_device_per_execution():
     assert reader.read({"trace": None}, "program_ms", ["jit__lambda"]) is None
 
 
+def test_the_spans_readers_get_the_first_plane_out_of_the_same_pass():
+    """``reduce`` hands on what ``load_xplane`` read, in the form the
+    readers of the program's spans take: the file is not opened again."""
+    trace = {"device": {"/device:TPU:1": {"XLA Ops": [("b", 5, 1)]},
+                        "/device:TPU:0": {"XLA Ops": [("%f = f32[] fusion()", 100, 50)],
+                                          "XLA Modules": [("jit_f(1)", 90, 70)], "Steps": []}},
+             "host": [(tr.ANCHOR, 80, 1), ("sched.step", 85, 100), (tr.ANCHOR_END, 200, 1)],
+             "scope": {"%f = f32[] fusion()": "jit(f)/layer/ffn/dot_general"}}
+    plane = tr.reduce(trace, [], 0.0, STAGES)["plane"]
+    assert plane == tr.first_plane(trace) == {
+        "spans": [("sched.step", 85, 185)], "anchors": {tr.ANCHOR: 80, tr.ANCHOR_END: 200},
+        "ops": [("%f = f32[] fusion()", 100, 50)], "modules": [("jit_f(1)", 90, 70)],
+        "scope": {"%f = f32[] fusion()": "jit(f)/layer/ffn/dot_general"}}
+    from benchmark.readers import host_spans
+
+    assert host_spans.run_trace({"trace": {"plane": plane}}) is plane
+    assert host_spans.run_trace({"trace": None}) is None and host_spans.run_trace({}) is None
+    # a trace recorded before scopes were kept still reduces
+    old = json.loads(DATA.read_text())
+    old["host"] = [tuple(e) for e in old["host"]]
+    assert "scope" not in old and tr.first_plane(old)["scope"] == {}
+
+
 def test_a_trace_without_device_operations_reduces_to_nothing():
     assert tr.reduce({"device": {}, "host": []}, [], 0.0, STAGES) is None
     assert tr.reduce({"device": {"/device:TPU:0": {"XLA Ops": []}}, "host": []}, [], 0.0, STAGES) is None

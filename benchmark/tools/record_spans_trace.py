@@ -4,7 +4,7 @@
 four-forward "chunk" under the program's own span names, with idle gaps
 between them, the device programs named and scoped as the decoder's are.
 Run on the chip: ``python3 benchmark/tools/record_spans_trace.py
-<out.json>``; the output is ``readers/host_spans.load``'s plain form of the
+<out.json>``; the output is ``lib.trace.first_plane``'s plain form of the
 ``.xplane.pb``, so the test needs no profiler."""
 
 from __future__ import annotations
@@ -78,7 +78,7 @@ def main(out: str) -> int:
     with TraceAnnotation(tr.ANCHOR_END):
         pass
     jax.profiler.stop_trace()
-    data = host_spans.load(tr.find_xplane(d))
+    data = tr.first_plane(tr.load_xplane(tr.find_xplane(d)))
     if data is None:
         print("the trace holds no device plane: record it on the chip", file=sys.stderr)
         return 2
