@@ -225,6 +225,43 @@ def test_grouped_matmul_compiles(tpu_devices, d, f):
              ((M // tm,), I32), tm=tm, interpret=False)
 
 
+# OLMoE-1B-7B-0125 (the benchmark's olmoe_flood cell): 16 q / 16 kv heads of
+# 128 — group 1, so a fast-forward block is 9 query rows a head where
+# Mistral's is 36 — 16 layers, 64 experts of 2048 x 1024 in int8
+OLMOE = (16, 16, 128, 16)
+
+
+@pytest.mark.parametrize("rows,tm", [(288, 64), (32, 16), (1024, 128)])
+def test_grouped_matmul_int8_stacked_compiles_at_olmoe_widths(tpu_devices, rows, tm):
+    """The served expert dispatch: the STACKED int8 leaf and its scales go to
+    the kernel whole, with the layer and the tiles' experts in the scalar
+    prefetch, at the decode forward's, a suffix prefill's and the prefix
+    prefill's token counts and the row tile the program picks for each."""
+    from tpu_voice_agent.models.llama import moe_row_tile
+
+    L, E, d, f, K = 16, 64, 2048, 1024, 8
+    assert moe_row_tile(rows * K, E) == tm
+    n = -(-(rows * K + min(E, rows * K) * (tm - 1)) // tm)
+    sh = SingleDeviceSharding(tpu_devices[0])
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+    for din, dout in ((d, f), (f, d)):  # gate / up, then down
+        jax.jit(functools.partial(ops.grouped_matmul, tm=tm, interpret=False)).lower(
+            S((n * tm, din), BF16), {"q": S((L, E, din, dout), I8), "s": S((L, E, 1, dout), F32)},
+            S((n,), I32), S((), I32), S((), I32)).compile()
+
+
+def test_paged_attention_compiles_at_group_one(tpu_devices):
+    """Full multi-head attention (no grouping): 9 query rows a head in the
+    fast-forward block, 1 in the T = 1 step, over the benchmark's pool."""
+    nq, nkv, hd, L = OLMOE
+    B, N, blocks = 32, 200, 12
+    tables, layer = ((B, blocks), I32), ((), I32)
+    _compile(tpu_devices, ops.paged_block_attention, ((B, FF_T, nq, hd), BF16),
+             *_pool(N, OLMOE), tables, ((B, FF_T), I32), layer, interpret=False)
+    _compile(tpu_devices, ops.paged_attention, ((B, nq, hd), BF16),
+             *_pool(N, OLMOE), tables, ((B,), I32), layer, interpret=False)
+
+
 @pytest.mark.slow
 def test_sharded_kernels_compile_on_2x2(tpu_devices):
     """The shard_map variants the dp x tp serving mesh traces (batch over

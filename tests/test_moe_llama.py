@@ -40,7 +40,7 @@ def test_moe_layer_matches_standalone_ep_reference(params):
     B, T = 2, 8
     h = jnp.asarray(np.random.default_rng(0).standard_normal((B, T, CFG.dim)),
                     jnp.float32)
-    ours = _moe_ffn(p, h, CFG)
+    ours, _ = _moe_ffn(p, h, CFG)
 
     mcfg = MoEConfig(dim=CFG.dim, ffn_dim=CFG.ffn_dim, n_experts=CFG.n_experts,
                      top_k=CFG.top_k, capacity_factor=CFG.capacity_factor)
@@ -205,8 +205,8 @@ class TestGroupedMoE:
         params = init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
         p = jax.tree.map(lambda a: a[0], params["layers"])  # layer 0 slice
         h = jax.random.normal(jax.random.PRNGKey(4), (2, 24, cfg.dim), jnp.float32)
-        dense = _moe_ffn(p, h, cfg)
-        grouped = _moe_ffn(p, h, replace(cfg, moe_impl="grouped"))
+        dense, _ = _moe_ffn(p, h, cfg)
+        grouped, _ = _moe_ffn(p, h, replace(cfg, moe_impl="grouped"))
         np.testing.assert_allclose(np.asarray(dense), np.asarray(grouped),
                                    rtol=2e-4, atol=2e-4)
 
@@ -224,7 +224,7 @@ class TestGroupedMoE:
         h = jnp.zeros((1, 256, cfg.dim), jnp.float32)
 
         def flops(c):
-            fn = jax.jit(lambda p, h: _moe_ffn(p, h, c))
+            fn = jax.jit(lambda p, h: _moe_ffn(p, h, c)[0])
             an = fn.lower(p, h).compile().cost_analysis()
             return float(an["flops"]) if an and "flops" in an else None
 

@@ -38,7 +38,16 @@ def llama_config_from_hf(path: str) -> LlamaConfig:
         path = os.path.join(path, "config.json")
     with open(path) as f:
         cfg = json.load(f)
-    E = cfg.get("num_local_experts", 0)
+    # ``model_type: "olmoe"`` (OlmoeForCausalLM): ``num_experts`` experts of
+    # ``intermediate_size``, the chosen gates renormalised only where
+    # ``norm_topk_prob`` says so, and an RMSNorm over the whole q and k
+    # vector that the model definition applies (config.json has no key for
+    # it). Mixtral names its count ``num_local_experts`` and renormalises.
+    olmoe = cfg.get("model_type") == "olmoe"
+    if olmoe and cfg.get("clip_qkv") is not None:
+        raise ValueError("olmoe clip_qkv is not implemented (the published "
+                         "OLMoE-1B-7B configurations leave it null)")
+    E = cfg.get("num_experts" if olmoe else "num_local_experts", 0)
     K = cfg.get("num_experts_per_tok", 2)
     return LlamaConfig(
         vocab_size=cfg["vocab_size"],
@@ -57,6 +66,8 @@ def llama_config_from_hf(path: str) -> LlamaConfig:
         n_experts=E,
         top_k=K,
         capacity_factor=max(1.25, E / K) if E else 1.25,
+        norm_topk=bool(cfg.get("norm_topk_prob", False)) if olmoe else True,
+        qk_norm=olmoe,
     )
 
 
@@ -171,12 +182,16 @@ def llama_hf_check(shapes: dict[str, tuple[int, ...]], cfg: LlamaConfig) -> None
         "self_attn.o_proj.weight": (d, nq * hd),
         "post_attention_layernorm.weight": (d,),
     }
+    if cfg.qk_norm:
+        per_layer["self_attn.q_norm.weight"] = (nq * hd,)
+        per_layer["self_attn.k_norm.weight"] = (nkv * hd,)
     if cfg.n_experts > 0:
-        per_layer["block_sparse_moe.gate.weight"] = (cfg.n_experts, d)
+        block, names = moe_hf_names(cfg)
+        per_layer[f"{block}.gate.weight"] = (cfg.n_experts, d)
         for e in range(cfg.n_experts):
-            per_layer[f"block_sparse_moe.experts.{e}.w1.weight"] = (f, d)
-            per_layer[f"block_sparse_moe.experts.{e}.w3.weight"] = (f, d)
-            per_layer[f"block_sparse_moe.experts.{e}.w2.weight"] = (d, f)
+            per_layer[f"{block}.experts.{e}.{names['moe_gate']}.weight"] = (f, d)
+            per_layer[f"{block}.experts.{e}.{names['moe_up']}.weight"] = (f, d)
+            per_layer[f"{block}.experts.{e}.{names['moe_down']}.weight"] = (d, f)
     else:
         per_layer.update({
             "mlp.gate_proj.weight": (f, d),
@@ -201,11 +216,22 @@ def llama_hf_check(shapes: dict[str, tuple[int, ...]], cfg: LlamaConfig) -> None
         raise ValueError("HF checkpoint mismatch:\n" + "\n".join(problems[:20]))
 
 
-def llama_hf_key_map(layer: int, moe: bool = False) -> dict[str, str]:
+def moe_hf_names(cfg: LlamaConfig) -> tuple[str, dict[str, str]]:
+    """(block name, our stacked leaf -> the per-expert tensor's name) of a
+    routed checkpoint. OLMoE (recognised by its q/k norm, which Mixtral
+    lacks) keeps the dense MLP's names under ``mlp``: ``mlp.gate.weight`` is
+    the router, ``mlp.experts.{e}.{gate,up,down}_proj``; Mixtral has
+    ``block_sparse_moe.gate`` and ``experts.{e}.w1 / w3 / w2``."""
+    if cfg.qk_norm:
+        return "mlp", {"moe_gate": "gate_proj", "moe_up": "up_proj", "moe_down": "down_proj"}
+    return "block_sparse_moe", {"moe_gate": "w1", "moe_up": "w3", "moe_down": "w2"}
+
+
+def llama_hf_key_map(layer: int, moe: bool = False, qk_norm: bool = False) -> dict[str, str]:
     """Our per-layer leaf name -> HF tensor name, for layer ``layer``.
-    ``moe=True`` (Mixtral naming): the dense MLP keys are absent — the
-    router and per-expert tensors are handled by llama_from_hf_state's
-    expert stacking (they map E tensors onto one stacked leaf)."""
+    ``moe=True``: the dense MLP keys are absent — the router and per-expert
+    tensors are handled by llama_from_hf_state's expert stacking (they map
+    E tensors onto one stacked leaf). ``qk_norm`` adds OLMoE's two gains."""
     p = f"model.layers.{layer}."
     base = {
         "attn_norm": p + "input_layernorm.weight",
@@ -215,6 +241,9 @@ def llama_hf_key_map(layer: int, moe: bool = False) -> dict[str, str]:
         "wo": p + "self_attn.o_proj.weight",
         "mlp_norm": p + "post_attention_layernorm.weight",
     }
+    if qk_norm:
+        base.update({"q_norm": p + "self_attn.q_norm.weight",
+                     "k_norm": p + "self_attn.k_norm.weight"})
     if not moe:
         base.update({
             "w_gate": p + "mlp.gate_proj.weight",
@@ -272,26 +301,26 @@ def llama_from_hf_state(
         "wo": (nq * hd, d),
         "mlp_norm": (d,),
     }
+    if cfg.qk_norm:
+        want.update({"q_norm": (nq * hd,), "k_norm": (nkv * hd,)})
     if not moe:
         want.update({"w_gate": (d, f), "w_up": (d, f), "w_down": (f, d)})
     stacked: dict[str, list] = {k: [] for k in want}
     if moe:
         stacked.update({"router": [], "moe_gate": [], "moe_up": [], "moe_down": []})
     for layer in range(cfg.n_layers):
-        for ours, hf_name in llama_hf_key_map(layer, moe=moe).items():
+        for ours, hf_name in llama_hf_key_map(layer, moe=moe, qk_norm=cfg.qk_norm).items():
             stacked[ours].append(get(hf_name, want[ours], ours in _TRANSPOSED))
         if moe:
-            # Mixtral block_sparse_moe: gate (E, d) -> router (d, E);
-            # experts.{e}.w1/w3 (f, d) -> moe_gate/up (E, d, f);
-            # experts.{e}.w2 (d, f) -> moe_down (E, f, d)
-            p = f"model.layers.{layer}.block_sparse_moe."
+            # gate (E, d) -> router (d, E); the experts' gate/up (f, d) ->
+            # moe_gate/up (E, d, f); their down (d, f) -> moe_down (E, f, d)
+            block, names = moe_hf_names(cfg)
+            p = f"model.layers.{layer}.{block}."
             stacked["router"].append(
                 get(p + "gate.weight", (d, cfg.n_experts), transpose=True))
-            for ours, hf_w, shape in (("moe_gate", "w1", (d, f)),
-                                      ("moe_up", "w3", (d, f)),
-                                      ("moe_down", "w2", (f, d))):
+            for ours, shape in (("moe_gate", (d, f)), ("moe_up", (d, f)), ("moe_down", (f, d))):
                 stacked[ours].append(jnp.stack([
-                    get(f"{p}experts.{e}.{hf_w}.weight", shape, transpose=True)
+                    get(f"{p}experts.{e}.{names[ours]}.weight", shape, transpose=True)
                     for e in range(cfg.n_experts)
                 ]))
 

@@ -46,11 +46,22 @@ class LlamaConfig:
     n_experts: int = 0
     top_k: int = 2
     capacity_factor: float = 1.25
-    # "dense": one-hot dispatch/combine einsums (jit-simple; FLOPs ∝ E at
-    # drop-free capacity; the mesh/EP path). "grouped": expert-sorted rows
-    # through the ops.grouped_matmul Pallas kernel — FLOPs ∝ K + one row
-    # tile of padding per expert; single-device prefill optimization.
-    moe_impl: str = "dense"
+    # "dense": one-hot dispatch/combine einsums (jit-simple; FLOPs and weight
+    # bytes ∝ E at drop-free capacity; the mesh/EP path). "grouped":
+    # expert-grouped rows through the ops.grouped_matmul Pallas kernel — FLOPs
+    # ∝ K, weight bytes ∝ the experts touched; single-device. "auto": the
+    # engine decides ONCE, where it is built (single device -> grouped, mesh
+    # -> dense: serve.engine.DecodeEngine.__init__); a bare ``forward`` call
+    # reads "auto" as "dense", which is right everywhere.
+    moe_impl: str = "auto"
+    # two properties of the MODEL, not knobs. Mixtral divides the K chosen
+    # softmax weights by their sum; OLMoE (``norm_topk_prob: false``) uses
+    # them as they come out of the softmax over all experts
+    norm_topk: bool = True
+    # OLMoE: an RMSNorm with one learned gain over the WHOLE projected q
+    # (n_heads * head_dim wide) and k vector, before the split into heads
+    # and before RoPE
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -119,6 +130,8 @@ def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
         "wo": w_init(ks[3], L, nq * hd, d),
         "mlp_norm": norm_init(L, d),
     }
+    if cfg.qk_norm:
+        layers.update({"q_norm": norm_init(L, nq * hd), "k_norm": norm_init(L, nkv * hd)})
     if cfg.n_experts > 0:
         E = cfg.n_experts
         layers.update({
@@ -153,7 +166,9 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=jnp.bfloat16
 def _w(leaf):
     """Resolve a weight leaf to a dense array: raw array, or int8
     {"q", "s"} dequantized (materialized). Only for consumers that need a
-    dense tensor — the Pallas grouped matmul, leaf-wise re-quantization.
+    dense tensor — leaf-wise re-quantization (``ops.grouped_matmul`` takes
+    the int8 leaf itself since PR 28: this call wrote and re-read the whole
+    stacked expert tensor as bf16 before every kernel call).
     Matmul call sites must use :func:`_qe` instead: feeding a dequantized
     product into a dot makes the scale multiply the dot operand's producer
     and XLA lowers the whole matvec as a kLoop broadcast-multiply-reduce on
@@ -288,80 +303,152 @@ def _layer_qkv(p, x, cfg: LlamaConfig, cos, sin, cs=_identity_cs,
         q = _qe("btd,dh->bth", h, p["wq"]).astype(x.dtype)
         k = _qe("btd,dh->bth", h, p["wk"]).astype(x.dtype)
         v = _qe("btd,dh->bth", h, p["wv"]).astype(x.dtype)
+        if cfg.qk_norm:
+            if (nq, nkv) != (cfg.n_heads, cfg.n_kv_heads):
+                raise NotImplementedError(
+                    "qk_norm normalises the whole projected vector; a "
+                    "tensor-parallel shard of the heads holds only part of it")
+            with jax.named_scope("qk_norm"):
+                q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+                k = rms_norm(k, p["k_norm"], cfg.norm_eps)
         q = cs(q.reshape(B, T, nq, cfg.head_dim), "heads")
         k = cs(k.reshape(B, T, nkv, cfg.head_dim), "kv_heads")
         v = cs(v.reshape(B, T, nkv, cfg.head_dim), "kv_heads")
         return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
+# what a routed forward counts (summed over layers by the forwards, over
+# forwards by the chunk loops; ``scheduler`` publishes them as ``moe.<name>``)
+MOE_STATS = ("assigned_rows", "padded_rows", "experts_touched", "load_max")
+
+
+def _moe_stats(counts, computed_rows) -> jax.Array:
+    """(4,) int32 in ``MOE_STATS`` order from one layer's per-expert row
+    counts and the rows its dispatch computed (padding included)."""
+    return jnp.stack([jnp.sum(counts), jnp.asarray(computed_rows, jnp.int32),
+                      jnp.sum(counts > 0), jnp.max(counts)]).astype(jnp.int32)
+
+
+def moe_row_tile(assignments: int, n_experts: int) -> int:
+    """Row tile of the grouped dispatch from the (static) assignment count:
+    the smallest power of two ABOVE the mean run of an expert, between one
+    bf16 sublane tile (16) and the MXU's 128 rows — most experts then fill
+    one tile, and the kernel pays by the tile (each converts and latches
+    the whole weight plane) while every row of padding is written and read
+    back. On the chip at OLMoE's widths (one layer at 288 tokens, ms;
+    PERF.md section 6, PR 28): 1.16 / 0.95 / 0.76 / 0.92 at 16 / 32 / 64 / 128."""
+    mean = -(-max(assignments, 1) // n_experts)
+    return min(128, max(16, 1 << mean.bit_length()))
+
+
+_EXPERT_LEAVES = ("moe_gate", "moe_up", "moe_down")
+
+
+def _scan_and_whole(layers: dict, cfg: LlamaConfig) -> tuple[dict, dict]:
+    """(the stacked leaves a layer scan slices, those its body takes WHOLE).
+    The grouped dispatch's kernel picks a layer's expert planes out of the
+    stacked (L, E, d, f) leaves itself, by the layer index in its scalar
+    prefetch: a scan's slice of them, handed to a custom call, is a copy of
+    134 MB three times a layer (``ops.grouped_matmul``)."""
+    if cfg.n_experts == 0 or cfg.moe_impl != "grouped":
+        return layers, {}
+    return ({k: v for k, v in layers.items() if k not in _EXPERT_LEAVES},
+            {k: layers[k] for k in _EXPERT_LEAVES})
+
+
+def _running_count(hot: jax.Array) -> jax.Array:
+    """(A, E) bool -> (A, E) int32: how many of rows 0..i hold True, column
+    by column. A prefix sum down 2304 rows is a 100 us reduce-window on the
+    TPU (as much as two expert planes); in blocks of 128 rows it is one
+    lower-triangular matmul on the MXU (0/1 in bf16, float32 sums: exact)
+    and a prefix sum over the few block totals."""
+    A, E = hot.shape
+    blk = 128
+    h = jnp.pad(hot, ((0, -A % blk), (0, 0))).astype(jnp.bfloat16).reshape(-1, blk, E)
+    tril = jnp.tril(jnp.ones((blk, blk), jnp.bfloat16))
+    within = jnp.einsum("ij,bje->bie", tril, h, preferred_element_type=jnp.float32).astype(jnp.int32)
+    totals = within[:, -1, :]  # (blocks, E)
+    before = jnp.cumsum(totals, axis=0) - totals
+    return (within + before[:, None, :]).reshape(-1, E)[:A]
+
+
 def _moe_ffn_grouped(p, h, cfg: LlamaConfig):
-    """Grouped-matmul MoE FFN (round-2 VERDICT weak #5): tokens sort by
-    expert, each expert's run pads to a row-tile multiple, and the Pallas
-    grouped matmul streams one weight plane per tile — FFN FLOPs ∝ T·K
-    (plus one tile of padding per expert) instead of the dense dispatch's
-    T·E. Single-device path (a bare pallas_call under GSPMD would
-    replicate its operands); the mesh/EP layout keeps dense dispatch."""
+    """Grouped-matmul MoE FFN: assignments group by expert, each expert's run
+    pads to a row-tile multiple, and ``ops.grouped_matmul`` streams one
+    weight plane per expert that has rows — int8 as served — so FFN FLOPs
+    are ∝ T·K (plus under one tile of padding per expert) and weight bytes
+    ∝ the experts TOUCHED, against the dense dispatch's T·E and E. The
+    single-device path (a bare pallas_call under GSPMD would replicate its
+    operands); a mesh keeps the dense dispatch (``cfg.moe_impl``). ``p`` holds one layer's
+    expert leaves, or — from the layer scans — the stacked ones and the
+    layer's index under ``"layer"``. -> (out, ``_moe_stats``)."""
     from ..ops.grouped_matmul import grouped_matmul
     from .moe import route_topk_flat
 
     B, T, d = h.shape
-    E, K, f = cfg.n_experts, cfg.top_k, cfg.ffn_dim
+    E, K = cfg.n_experts, cfg.top_k
     Tt = B * T
+    A = Tt * K
     x2 = h.reshape(Tt, d)
-    eids, gates = route_topk_flat(p["router"], x2, E, K)  # (Tt, K)
+    with jax.named_scope("router"):
+        eids, gates = route_topk_flat(p["router"], x2, E, K, cfg.norm_topk)  # (Tt, K)
 
-    flat_e = eids.reshape(-1)  # assignment j = t*K + k
-    flat_t = jnp.arange(Tt * K, dtype=jnp.int32) // K
-    flat_g = gates.reshape(-1)
-    order = jnp.argsort(flat_e, stable=True)  # expert-major, token-stable
-    sorted_e = flat_e[order]
+    with jax.named_scope("dispatch"):
+        # compares and running counts over an (A, E) one-hot, no sort and
+        # no per-element gather: on the TPU a 2304-element gather or scatter
+        # costs as much as a whole expert plane (PERF.md section 6, PR 28)
+        flat_e = eids.reshape(-1)  # assignment j = t*K + k
+        hot = flat_e[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :]  # (A, E)
+        tm = moe_row_tile(A, E)
+        counts = jnp.sum(hot, axis=0, dtype=jnp.int32)
+        padded = ((counts + tm - 1) // tm) * tm
+        ends = jnp.cumsum(padded)
+        offsets = ends - padded
+        # row of assignment j: its expert's padded offset plus its rank in
+        # the expert's run (assignment order: expert-major, token-stable)
+        rank = _running_count(hot) - 1  # (A, E)
+        dest = jnp.sum(jnp.where(hot, rank + offsets[None, :], 0), axis=1)  # (A,)
+        # static bound on sum(padded): under one tile of padding for each
+        # expert that can hold a row; the tiles past the real ones are
+        # skipped by the kernel and never gathered back
+        n_static = -(-(A + min(E, A) * (tm - 1)) // tm)
+        # rows move by GATHER (an int32 scatter builds the index): row r of
+        # the padded layout reads token row_tok[r], padding reads a zero row
+        row_tok = jnp.full((n_static * tm,), Tt, jnp.int32).at[dest].set(
+            jnp.arange(A, dtype=jnp.int32) // K)
+        xs = jnp.concatenate([x2, jnp.zeros((1, d), x2.dtype)])[row_tok]
+        n_tiles = ends[-1] // tm
+        tile_start = jnp.arange(n_static, dtype=jnp.int32) * tm
+        tile_expert = jnp.minimum(
+            jnp.sum(tile_start[:, None] >= ends[None, :], axis=1, dtype=jnp.int32), E - 1)
+        # skipped tiles name the last real tile's expert: no weight fetch
+        last = jnp.sum(jnp.where(jnp.arange(n_static) == n_tiles - 1, tile_expert, 0))
+        tile_expert = jnp.where(jnp.arange(n_static) < n_tiles, tile_expert, last)
 
-    # fixed power-of-two row tile >= 8: tm need NOT divide Tt*K (rows are
-    # zero-padded to a tile multiple below), and Mosaic's f32 sublane
-    # tiling rejects blocks narrower than 8 rows on real TPU — a divisor-
-    # derived tm of 1-2 (odd batch x top_k) would fail to compile there
-    # while CPU interpret mode hid it (round-3 reviewer finding)
-    tm = min(128, max(8, 1 << (max(Tt * K, 1) - 1).bit_length()))
-    counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
-    padded = ((counts + tm - 1) // tm) * tm
-    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(padded)[:-1]])
-    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)[:-1]])
-    # destination row for sorted assignment i: its expert's padded offset
-    # plus its rank within the expert's run
-    rank = jnp.arange(Tt * K, dtype=jnp.int32) - starts[sorted_e]
-    pos = offsets[sorted_e] + rank
+    with jax.named_scope("experts"):
+        li = p.get("layer")
+        gate_s = grouped_matmul(xs, p["moe_gate"], tile_expert, n_tiles, li, tm=tm)
+        up_s = grouped_matmul(xs, p["moe_up"], tile_expert, n_tiles, li, tm=tm)
+        act = (jax.nn.silu(gate_s.astype(jnp.float32)) * up_s.astype(jnp.float32)).astype(h.dtype)
+        down = grouped_matmul(act, p["moe_down"], tile_expert, n_tiles, li, tm=tm)  # (M_pad, d)
 
-    # static bound >= sum(padded), rounded to a tile multiple (Tt*K itself
-    # need not divide tm); tail tiles are garbage and never gathered back
-    M_pad = -(-(Tt * K) // tm) * tm + E * tm
-    xs = jnp.zeros((M_pad, d), h.dtype).at[pos].set(x2[flat_t[order]])
-    ends = jnp.cumsum(padded)
-    tile_expert = jnp.clip(
-        jnp.searchsorted(ends, jnp.arange(M_pad // tm, dtype=jnp.int32) * tm,
-                         side="right"),
-        0, E - 1).astype(jnp.int32)
-
-    gate_s = grouped_matmul(xs, _w(p["moe_gate"]), tile_expert, tm=tm)
-    up_s = grouped_matmul(xs, _w(p["moe_up"]), tile_expert, tm=tm)
-    act = (jax.nn.silu(gate_s.astype(jnp.float32)) * up_s.astype(jnp.float32)).astype(h.dtype)
-    down = grouped_matmul(act, _w(p["moe_down"]), tile_expert, tm=tm)  # (M_pad, d)
-
-    out = jnp.zeros((Tt, d), jnp.float32).at[flat_t[order]].add(
-        flat_g[order][:, None] * down[pos].astype(jnp.float32))
-    return out.astype(h.dtype).reshape(B, T, d)
+    with jax.named_scope("combine"):
+        # assignment j sits at row dest[j]; a token's K rows are gathered
+        # and summed under its gates (no scatter-add of (A, d) rows)
+        rows = down[dest].reshape(Tt, K, d).astype(jnp.float32)
+        out = jnp.sum(rows * gates[:, :, None], axis=1)
+    return out.astype(h.dtype).reshape(B, T, d), _moe_stats(counts, ends[-1])
 
 
-def _moe_ffn(p, h, cfg: LlamaConfig):
-    """Top-k routed expert FFN over (B, T, d) hidden states. Dense-dispatch
-    einsums (models.moe.route_topk): expert choice becomes MXU matmuls with
-    static shapes, so the MoE decode step jits exactly like the dense one.
-    EP sharding happens declaratively: the stacked (E, ...) expert weights
-    shard E over the mesh's tp axis (parallel.mesh.param_shardings) and XLA
-    partitions the dispatch/combine einsums, inserting one psum.
-    ``cfg.moe_impl="grouped"`` swaps in the Pallas grouped-matmul dispatch
-    (FLOPs ∝ K, not E)."""
-    if cfg.moe_impl == "grouped":
-        return _moe_ffn_grouped(p, h, cfg)
+def _moe_ffn_dense(p, h, cfg: LlamaConfig):
+    """Dense-dispatch MoE FFN (models.moe.route_topk): expert choice becomes
+    one-hot einsums with static shapes. EP sharding happens declaratively:
+    the stacked (E, ...) expert weights shard E over the mesh's tp axis
+    (parallel.mesh.param_shardings) and XLA partitions the dispatch/combine
+    einsums, inserting one psum. Every expert computes over the full
+    capacity, so FLOPs and weight bytes are ∝ E whatever was routed: the
+    meshed path, and the exact twin the grouped path is tested against.
+    -> (out, ``_moe_stats``)."""
     from .moe import moe_capacity, route_topk
 
     B, T, d = h.shape
@@ -385,26 +472,48 @@ def _moe_ffn(p, h, cfg: LlamaConfig):
             stacklevel=2,
         )
     C = moe_capacity(B * T, cfg.n_experts, cfg.top_k, cf)
-    dispatch, combine = route_topk(p["router"], x2, cfg.n_experts, cfg.top_k, C)
-    xe = jnp.einsum("tec,td->ecd", dispatch.astype(h.dtype), x2)  # (E, C, d)
-    gate = _qe("ecd,edf->ecf", xe, p["moe_gate"])
-    up = _qe("ecd,edf->ecf", xe, p["moe_up"])
-    a = (jax.nn.silu(gate) * up).astype(h.dtype)
-    down = _qe("ecf,efd->ecd", a, p["moe_down"]).astype(h.dtype)
-    return jnp.einsum("tec,ecd->td", combine.astype(h.dtype), down).reshape(B, T, d)
+    with jax.named_scope("router"):
+        dispatch, combine = route_topk(p["router"], x2, cfg.n_experts, cfg.top_k, C,
+                                       cfg.norm_topk)
+    with jax.named_scope("dispatch"):
+        xe = jnp.einsum("tec,td->ecd", dispatch.astype(h.dtype), x2)  # (E, C, d)
+    with jax.named_scope("experts"):
+        gate = _qe("ecd,edf->ecf", xe, p["moe_gate"])
+        up = _qe("ecd,edf->ecf", xe, p["moe_up"])
+        a = (jax.nn.silu(gate) * up).astype(h.dtype)
+        down = _qe("ecf,efd->ecd", a, p["moe_down"]).astype(h.dtype)
+    with jax.named_scope("combine"):
+        out = jnp.einsum("tec,ecd->td", combine.astype(h.dtype), down).reshape(B, T, d)
+    counts = jnp.sum(dispatch, axis=(0, 2)).astype(jnp.int32)
+    return out, _moe_stats(counts, cfg.n_experts * C)
 
 
-def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs):
+def _moe_ffn(p, h, cfg: LlamaConfig):
+    """Top-k routed expert FFN over (B, T, d) hidden states -> (out, stats).
+    ``cfg.moe_impl`` names the dispatch; the engine that serves the model
+    resolved "auto" where it was built (a single device takes the grouped
+    kernel at every token count — PERF.md section 6, PR 28: it wins from 128
+    tokens up and ties at a suffix prefill's 32-64 rows — a mesh the dense
+    einsums, its experts sharded over tp)."""
+    if cfg.moe_impl == "grouped":
+        return _moe_ffn_grouped(p, h, cfg)
+    return _moe_ffn_dense(p, h, cfg)
+
+
+def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = False):
     """Shared decoder-layer back half: output projection + residual, then
     the MLP (dense SwiGLU, or routed MoE when cfg.n_experts > 0) +
-    residual. ``attn`` is (B, T, n_heads * head_dim)."""
+    residual. ``attn`` is (B, T, n_heads * head_dim). With ``moe_stats``
+    (routed models only) -> (x, the layer's ``_moe_stats``)."""
     with jax.named_scope("layer/attn_out"):
         attn = _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
         x = x + cs(attn, "act")
     with jax.named_scope("layer/ffn"):
         h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
         if cfg.n_experts > 0:
-            return x + cs(_moe_ffn(p, h, cfg), "act")
+            y, stats = _moe_ffn(p, h, cfg)
+            x = x + cs(y, "act")
+            return (x, stats) if moe_stats else x
         gate = _qe("btd,df->btf", h, p["w_gate"])
         up = _qe("btd,df->btf", h, p["w_up"])
         act = (jax.nn.silu(gate) * up).astype(x.dtype)
@@ -468,9 +577,13 @@ def forward(
     # (li,) plane in place. Passing per-layer cache planes as scan xs/ys
     # instead (round 1) forced XLA to copy every layer's whole cache line
     # per step — ~35% of the decode step's device time at tinyllama scale.
+    scanned, whole = _scan_and_whole(params["layers"], cfg)
+
     def layer(carry, layer_in):
         x, kc, vc = carry
         p, li = layer_in
+        if whole:
+            p = {**p, **whole, "layer": li}
         q, k, v = _layer_qkv(p, x, cfg, cos, sin, cs)
 
         with jax.named_scope("layer/kv_write"):
@@ -523,7 +636,7 @@ def forward(
         (x, new_k, new_v), _ = jax.lax.scan(
             lambda carry, inp: layer_fn(carry, inp),
             (x, kv_cache["k"], kv_cache["v"]),
-            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+            (scanned, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
             unroll=unroll,
         )
 
@@ -537,7 +650,7 @@ def forward(
 
 @watch_compiles("llama.forward_paged")
 @partial(jax.jit, static_argnames=("cfg", "rules", "attn_impl", "fresh_block",
-                                   "gather_blocks", "kv_quant"),
+                                   "gather_blocks", "kv_quant", "moe_stats"),
          donate_argnames=("k_pool", "v_pool", "k_scale", "v_scale"))
 def forward_paged(
     params: dict,
@@ -569,6 +682,9 @@ def forward_paged(
     # byte-identical — the scale leaves are empty pytree nodes)
     v_scale: jax.Array | None = None,
     kv_quant: str | None = None,  # None | "int8" | "int4" (static)
+    moe_stats: bool = False,  # routed models: also return the forward's
+    # ``MOE_STATS`` summed over layers, (4,) int32 (the chunk loops carry them
+    # out with their readback; no callback on the hot path)
 ):
     """The paged twin of ``forward`` (parity-tested): sequences own
     non-contiguous pool blocks via per-row block tables (SURVEY.md §7
@@ -589,9 +705,11 @@ def forward_paged(
     what decode later reads.
 
     Returns (logits, k_pool, v_pool, k_scale, v_scale) — the scale slots
-    are None when ``kv_quant`` is None."""
+    are None when ``kv_quant`` is None — and, with ``moe_stats``, the
+    forward's routed-expert counts as a sixth."""
     B, T = tokens.shape
     L, N, bs = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    moe_stats = moe_stats and cfg.n_experts > 0  # a dense model has none
     nb = gather_blocks if gather_blocks is not None else block_tables.shape[1]
     S = nb * bs  # gathered context capacity
     cs = lambda x, name: rules.constrain(x, name) if rules is not None else x
@@ -612,9 +730,13 @@ def forward_paged(
                 else trash_idx.astype(jnp.int32))
         flat_idx = jnp.where(write_mask[:, None], flat_idx, park[:, None])
 
+    scanned, whole = _scan_and_whole(params["layers"], cfg)
+
     def layer(carry, layer_in):
         x, kp, vp, ksc, vsc = carry
         p, li = layer_in
+        if whole:
+            p = {**p, **whole, "layer": li}
         q, k, v = _layer_qkv(p, x, cfg, cos, sin, cs)
 
         with jax.named_scope("layer/kv_write"):
@@ -724,14 +846,15 @@ def forward_paged(
                             vp[li][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
                             vsc[li][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
                 attn = _attend(q, kl, vl, positions, kv_len_mask)
-        x = _layer_out(p, x, attn, cfg, cs)
-        return (x, kp, vp, ksc, vsc), None
+        out = _layer_out(p, x, attn, cfg, cs, moe_stats=moe_stats)
+        x, stats = out if moe_stats else (out, None)
+        return (x, kp, vp, ksc, vsc), stats
 
     with jax.named_scope("layers"):
-        (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
+        (x, k_pool, v_pool, k_scale, v_scale), stats = jax.lax.scan(
             layer,
             (x, k_pool, v_pool, k_scale, v_scale),
-            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+            (scanned, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
         )
 
     with jax.named_scope("final_norm"):
@@ -739,6 +862,8 @@ def forward_paged(
     with jax.named_scope("lm_head"):
         logits = _qe("btd,dv->btv", x, params["lm_head"])
         logits = cs(logits, "logits")
+    if moe_stats:
+        return logits, k_pool, v_pool, k_scale, v_scale, jnp.sum(stats, axis=0)
     return logits, k_pool, v_pool, k_scale, v_scale
 
 
@@ -749,4 +874,6 @@ def param_count(cfg: LlamaConfig) -> int:
         per_layer += cfg.n_experts * 3 * d * f + d * cfg.n_experts + 2 * d
     else:
         per_layer += 3 * d * f + 2 * d
+    if cfg.qk_norm:
+        per_layer += (cfg.n_heads + cfg.n_kv_heads) * hd
     return cfg.vocab_size * d * 2 + cfg.n_layers * per_layer + d

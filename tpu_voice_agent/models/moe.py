@@ -27,41 +27,48 @@ def moe_capacity(n_tokens: int, n_experts: int, top_k: int,
 
 
 def _select_topk(router_w: jax.Array, x: jax.Array, n_experts: int,
-                 top_k: int) -> tuple[jax.Array, jax.Array]:
+                 top_k: int) -> tuple[jax.Array, jax.Array, jax.Array]:
     """THE expert-selection rule, in one place: x (T, d), router_w (d, E)
-    -> (probs (T, E) f32 softmax, eids (T, K) int32 iterative-argmax picks).
-    Both dispatch layouts (dense one-hot and flat/grouped) derive from
-    this, so expert choice and tie behavior can never drift apart."""
+    -> (probs (T, E) f32 softmax, eids (T, K) int32 iterative-argmax picks,
+    their probabilities (T, K)). Both dispatch layouts (dense one-hot and
+    flat/grouped) derive from this, so expert choice and tie behavior can
+    never drift apart."""
     E, K = n_experts, top_k
     logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)  # (T, E)
-    ids = []
+    ids, vals = [], []
     masked = probs
     for _ in range(K):
         idx = jnp.argmax(masked, axis=-1)  # (T,)
         ids.append(idx.astype(jnp.int32))
+        vals.append(jnp.max(masked, axis=-1))  # the pick's own probability: no gather afterwards
         masked = masked * (1.0 - jax.nn.one_hot(idx, E, dtype=probs.dtype))
-    return probs, jnp.stack(ids, axis=1)
+    return probs, jnp.stack(ids, axis=1), jnp.stack(vals, axis=1)
 
 
 def route_topk_flat(router_w: jax.Array, x: jax.Array, n_experts: int,
-                    top_k: int) -> tuple[jax.Array, jax.Array]:
-    """x (T, d), router_w (d, E) -> (eids (T, K) int32, gates (T, K) f32
-    renormalized over the K chosen experts). The flat (assignment-list)
-    layout for the grouped-matmul dispatch path; selection comes from
-    ``_select_topk`` so it is identical to the dense path by construction."""
-    probs, eids = _select_topk(router_w, x, n_experts, top_k)
-    gates = jnp.take_along_axis(probs, eids, axis=-1)  # (T, K)
+                    top_k: int, renormalize: bool = True) -> tuple[jax.Array, jax.Array]:
+    """x (T, d), router_w (d, E) -> (eids (T, K) int32, gates (T, K) f32).
+    ``renormalize`` is a property of the MODEL: Mixtral divides the K chosen
+    softmax weights by their sum, OLMoE (``norm_topk_prob: false``) keeps
+    them as they are. The flat (assignment-list) layout for the
+    grouped-matmul dispatch path; selection comes from ``_select_topk`` so
+    it is identical to the dense path by construction."""
+    _, eids, gates = _select_topk(router_w, x, n_experts, top_k)
+    if not renormalize:
+        return eids, gates
     denom = jnp.sum(gates, axis=1, keepdims=True)
     return eids, gates / jnp.where(denom == 0.0, 1.0, denom)
 
 
 def route_topk(router_w: jax.Array, x: jax.Array, n_experts: int, top_k: int,
-               capacity: int) -> tuple[jax.Array, jax.Array]:
+               capacity: int, renormalize: bool = True) -> tuple[jax.Array, jax.Array]:
     """x (T, d), router_w (d, E) -> (dispatch (T, E, C) one-hot,
-    combine (T, E, C) gate-weighted). Pure function of static E/K/C."""
+    combine (T, E, C) gate-weighted). Pure function of static E/K/C.
+    ``renormalize`` as in ``route_topk_flat`` (over the experts that KEPT
+    the token: an overflow changes the sum)."""
     E, K, C = n_experts, top_k, capacity
-    probs, eids = _select_topk(router_w, x, E, K)
+    probs, eids, _ = _select_topk(router_w, x, E, K)
     # (T, E) gate matrix from the selected ids
     gates = jnp.sum(
         jax.nn.one_hot(eids, E, dtype=probs.dtype, axis=-1) * probs[:, None, :],
@@ -72,10 +79,10 @@ def route_topk(router_w: jax.Array, x: jax.Array, n_experts: int, top_k: int,
     # slot position of each token within its expert's queue, in token order
     pos = jnp.cumsum(chosen.astype(jnp.int32), axis=0) - 1  # (T, E)
     keep = chosen & (pos < C)
-    # renormalize gates over experts that kept the token
     kept_gate = jnp.where(keep, gates, 0.0)
-    denom = jnp.sum(kept_gate, axis=-1, keepdims=True)
-    kept_gate = kept_gate / jnp.where(denom == 0.0, 1.0, denom)
+    if renormalize:  # over the experts that kept the token
+        denom = jnp.sum(kept_gate, axis=-1, keepdims=True)
+        kept_gate = kept_gate / jnp.where(denom == 0.0, 1.0, denom)
 
     slot_onehot = jax.nn.one_hot(jnp.where(keep, pos, C), C, dtype=probs.dtype)  # (T,E,C)
     dispatch = slot_onehot * keep[..., None]

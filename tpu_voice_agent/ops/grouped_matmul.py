@@ -1,20 +1,41 @@
 """Grouped matmul: expert-sorted rows × per-group weight, Pallas TPU.
 
-The MoE dispatch optimization (round-2 VERDICT weak #5): drop-free
-dense-dispatch routing turns expert choice into (T, E, C) one-hot einsums —
-jit-friendly, but the expert FFN then burns FLOPs ∝ E (every expert's
-matmul runs over the full capacity C == T). Here tokens are SORTED by
-expert on the host side of the op (jnp argsort; static shapes), each
+The MoE dispatch (round-2 VERDICT weak #5; decode and int8 since PR 28):
+drop-free dense-dispatch routing turns expert choice into (T, E, C) one-hot
+einsums — jit-friendly, but the expert FFN then burns FLOPs ∝ E (every
+expert's matmul runs over the full capacity C == T). Here the caller GROUPS
+the rows by expert (``models.llama._moe_ffn_grouped``; static shapes), each
 expert's run padded to a row-tile multiple, and one kernel walks the row
 tiles with the expert id in scalar prefetch — the BlockSpec index map picks
 the expert's weight plane per tile (the same indirection trick as
-paged_attention's block tables). FLOPs become ∝ T·K plus one tile of
-padding per expert.
+paged_attention's block tables). FLOPs become ∝ T·K plus at most one tile
+of padding per expert.
 
-Standard (m, n, k) matmul tiling: f32 accumulation scratch across the k
-grid axis, output written on the last k step. Like every kernel in ops/,
-a pure-jnp reference twin and interpret=True on CPU keep it testable
-without a chip.
+Weights are a raw (E, d, f) array or the served int8 leaf ``{"q": (E, d, f)
+int8, "s": (E, 1, f) f32}`` — or, with ``layer``, the model's STACKED
+(L, E, d, f) leaf and the layer's index: the index rides the scalar prefetch
+and the BlockSpec picks the layer's plane, because slicing ``w[layer]`` for
+a per-layer operand makes XLA copy the layer's whole expert tensor (134 MB
+at OLMoE's widths, three times a layer: 0.30 s of a 2 s trace before this,
+PERF.md section 6, PR 28 — the lesson ``paged_attention`` already holds
+for the stacked KV pool). The int8 plane goes to the kernel AS int8: a
+weight TILE is converted to bf16 in VMEM, multiplied bf16 × bf16 into the
+float32 accumulator, and the per-output-channel scale multiplies the output
+tile (``(x @ q) * s == x @ (q * s)``, the identity ``models.llama._qe``
+uses). Nothing the size of the stacked weights is ever written to HBM.
+
+Tiling. At decode the weights bound the kernel (36 rows an expert at
+OLMoE's shapes), so a weight plane must cross HBM once per EXPERT, not once
+per row tile: where a whole (d, f) plane fits the VMEM budget the grid is
+one step a row tile and the plane's block index repeats over an expert's
+consecutive tiles, which Pallas does not fetch again. A plane too large for
+that (Mixtral's 4096 × 14336) is walked in (tk, tn) tiles with a float32
+accumulator across k. Row tiles past ``n_tiles`` (the static row bound is
+one tile an expert more than the routing needs) are skipped: no compute,
+and — their block index being the last real tile's — no weight fetch.
+
+Like every kernel in ops/, a pure-jnp reference twin and interpret=True on
+CPU keep it testable without a chip.
 """
 
 from __future__ import annotations
@@ -28,6 +49,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .backend import on_cpu
 
+# one weight block as stored (int8: 2 MiB for OLMoE's 2048 x 1024); it is
+# double-buffered and converted to bf16 beside the activations' tiles
+_PLANE_BYTES = 4 << 20
+_VMEM_LIMIT = 48 << 20  # of v5e's 128 MiB; the default scoped limit is 16
+
 
 def _pick_tile(n: int, cap: int) -> int:
     """Largest power-of-two divisor of n, at most cap."""
@@ -37,29 +63,48 @@ def _pick_tile(n: int, cap: int) -> int:
     return t
 
 
-def _gmm_kernel(gid_ref, x_ref, w_ref, o_ref, acc_ref):
-    k = pl.program_id(2)
+def plane_tiles(d: int, f: int, itemsize: int) -> tuple[int, int]:
+    """(tk, tn): the whole (d, f) plane where it fits ``_PLANE_BYTES``, else
+    the largest power-of-two tiles (lane dimension first) that do."""
+    if d * f * itemsize <= _PLANE_BYTES:
+        return d, f
+    tn = _pick_tile(f, 512)
+    return _pick_tile(d, max(128, _PLANE_BYTES // (tn * itemsize))), tn
 
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...].astype(jnp.float32), w_ref[0].astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    )
+def _gmm_kernel(sc_ref, x_ref, w_ref, *rest, scaled: bool):
+    s_ref = rest[0] if scaled else None
+    o_ref, acc_ref = rest[-2], rest[-1]
+    m, k = pl.program_id(0), pl.program_id(2)
 
-    @pl.when(k == pl.num_programs(2) - 1)
-    def _finish():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+    @pl.when(m < sc_ref[sc_ref.shape[0] - 2])  # a tile the routing filled
+    def _tile():
+        @pl.when(k == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        acc_ref[...] += jax.lax.dot_general(
+            x_ref[...], w_ref[0, 0].astype(x_ref.dtype),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+
+        @pl.when(k == pl.num_programs(2) - 1)
+        def _finish():
+            acc = acc_ref[...]
+            if scaled:
+                acc = acc * s_ref[0, 0]
+            o_ref[...] = acc.astype(o_ref.dtype)
 
 
 # analyze: ok[jit-sentinel] -- kernel wrapper traced inline by the watched engine/stt loops, never a serving dispatch entry point
 @functools.partial(jax.jit, static_argnames=("tm", "tn", "tk", "interpret"))
 def grouped_matmul(
     x: jax.Array,  # (M, d) rows, expert-sorted and tile-padded
-    w: jax.Array,  # (E, d, f) stacked expert weights
+    w,  # (E, d, f) stacked expert weights, or {"q": int8 (E, d, f), "s": f32 (E, 1, f)};
+    # with ``layer``: the same with a leading layer axis, (L, E, d, f) / (L, E, 1, f)
     tile_expert: jax.Array,  # (M // tm,) int32 expert id per row tile
+    n_tiles: jax.Array | None = None,  # () int32: row tiles that hold rows (default: all)
+    layer: jax.Array | None = None,  # () int32 index into w's leading layer axis
     *,
     tm: int | None = None,
     tn: int | None = None,
@@ -69,41 +114,64 @@ def grouped_matmul(
     """out[i] = x[i] @ w[tile_expert[i // tm]]  — (M, f).
 
     Every row tile belongs to exactly ONE expert (the caller pads each
-    expert's run to a tile multiple); the weight plane streams from HBM
-    once per (row-tile, n-tile) pair regardless of E.
+    expert's run to a tile multiple, and gives tiles past ``n_tiles`` the
+    last real tile's expert). Rows of skipped tiles are left unwritten.
     """
+    scaled = isinstance(w, dict)
+    q, s = (w["q"], w["s"]) if scaled else (w, None)
+    if layer is None:  # one layer's weights: a leading axis of one
+        q, s, layer = q[None], None if s is None else s[None], jnp.int32(0)
     M, d = x.shape
-    E, d2, f = w.shape
+    _, _, d2, f = q.shape
     assert d == d2, (d, d2)
     tm = tm or _pick_tile(M, 128)
-    tn = tn or _pick_tile(f, 128)
-    tk = tk or _pick_tile(d, 512)
+    ptk, ptn = plane_tiles(d, f, q.dtype.itemsize)
+    tn, tk = tn or ptn, tk or ptk
     assert M % tm == 0 and f % tn == 0 and d % tk == 0, (M, f, d, tm, tn, tk)
     assert tile_expert.shape == (M // tm,)
     interpret = interpret if interpret is not None else on_cpu()
+    if n_tiles is None:
+        n_tiles = jnp.int32(M // tm)
+    # scalar prefetch: the tiles' experts, how many tiles are real, the layer
+    nt = M // tm
+    sc = jnp.concatenate([tile_expert.astype(jnp.int32), jnp.reshape(n_tiles, (1,)).astype(jnp.int32),
+                          jnp.reshape(layer, (1,)).astype(jnp.int32)])
 
+    in_specs = [
+        pl.BlockSpec((tm, tk), lambda m, n, k, sc: (m, k)),
+        pl.BlockSpec((1, 1, tk, tn), lambda m, n, k, sc: (sc[nt + 1], sc[m], k, n)),
+    ]
+    operands = [x, q]
+    if scaled:
+        in_specs.append(pl.BlockSpec((1, 1, 1, tn), lambda m, n, k, sc: (sc[nt + 1], sc[m], 0, n)))
+        operands.append(s.astype(jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(M // tm, f // tn, d // tk),
-        in_specs=[
-            pl.BlockSpec((tm, tk), lambda m, n, k, sc: (m, k)),
-            pl.BlockSpec((1, tk, tn), lambda m, n, k, sc: (sc[m], k, n)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((tm, tn), lambda m, n, k, sc: (m, n)),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
     )
     return pl.pallas_call(
-        _gmm_kernel,
+        functools.partial(_gmm_kernel, scaled=scaled),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, f), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="grouped_matmul",
-    )(tile_expert.astype(jnp.int32), x, w)
+    )(sc, *operands)
 
 
 def grouped_matmul_reference(x, w, tile_expert, tm: int) -> jax.Array:
-    """Pure-jnp twin: per-row expert gather + batched matmul."""
+    """Pure-jnp twin: per-row expert gather + batched matmul, the int8 leaf
+    resolved as the kernel resolves it (bf16 plane, scale on the output)."""
     row_expert = jnp.repeat(tile_expert, tm)  # (M,)
+    if isinstance(w, dict):
+        out = jnp.einsum("md,mdf->mf", x, w["q"][row_expert].astype(x.dtype),
+                         preferred_element_type=jnp.float32)
+        return (out * w["s"][row_expert][:, 0, :].astype(jnp.float32)).astype(x.dtype)
     return jnp.einsum(
         "md,mdf->mf", x.astype(jnp.float32), w[row_expert].astype(jnp.float32)
     ).astype(x.dtype)

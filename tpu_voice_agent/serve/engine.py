@@ -671,6 +671,12 @@ class DecodeEngine:
                 raise ValueError(
                     f"model vocab {vocab} < tokenizer vocab {tokenizer.vocab_size}"
                 )
+        if base.n_experts > 0 and base.moe_impl == "auto":
+            # THE dispatch choice of a routed model, made once, here, from
+            # where the engine runs: the grouped-matmul kernel on a single
+            # device (weight bytes ∝ the experts touched), the dense einsum
+            # dispatch on a mesh (its experts shard over tp)
+            base = replace(base, moe_impl="dense" if mesh is not None else "grouped")
         if mesh is not None:
             if getattr(base, "moe_impl", "dense") == "grouped":
                 # the grouped-matmul dispatch is a bare pallas_call: under

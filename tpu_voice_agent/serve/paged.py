@@ -325,7 +325,10 @@ def paged_chunk_decode_loop(
     blocks). Returns the dense loop's tuple shape including the per-row
     ``poison`` fault codes (0 ok / 1 non-finite logits / 2 dead FSM); a
     poisoned row deactivates without committing the faulty sample, so
-    batch-mates decode token-identically to an undisturbed run.
+    batch-mates decode token-identically to an undisturbed run. A ROUTED
+    model (``cfg.n_experts > 0``, static) compiles a variant with one more
+    carry and one more output: ``llama.MOE_STATS`` summed over the chunk's
+    forwards and layers, (4,) int32. A dense model's program is untouched.
 
     The batched VERIFY mode of this chunk path (speculative decoding,
     ISSUE 8) lives in serve.spec.paged_spec_verify_step: drafting is
@@ -349,11 +352,18 @@ def paged_chunk_decode_loop(
                    dtype=jnp.int32)
     eos0 = (~active) & (cur == eos_id)
 
+    # the routed variant: forwards count their expert rows, the carry sums
+    # them. For a dense model every ``*moe`` / ``*stats`` below is empty and
+    # the traced program is the one it always was (tests/test_olmoe.py)
+    routed = cfg.n_experts > 0
+    moe_kw = {"moe_stats": True} if routed else {}
+    moe0 = (jnp.zeros((4,), jnp.int32),) if routed else ()
+
     carry0 = (k_pool, v_pool, k_scale, v_scale, cur, pos, fsm_state, active,
               eos0, nbytes,
               tokens_left, out, jnp.zeros((B,), jnp.int32), key,
               jnp.zeros((), jnp.int32), jnp.zeros((B,), jnp.int32),
-              _conf_init(B))
+              _conf_init(B), *moe0)
 
     def cond(c):
         active, step = c[7], c[14]
@@ -361,7 +371,7 @@ def paged_chunk_decode_loop(
 
     def body(c):
         (kp, vp, ksc, vsc, cur, pos, state, active, eos, nbytes, left, out, n,
-         key, step, poison, conf) = c
+         key, step, poison, conf, *moe) = c
         with jax.named_scope("loop_carry"):
             out = out.at[jnp.arange(B), jnp.minimum(n, chunk_steps - 1)].set(
                 jnp.where(active, cur, out[jnp.arange(B), jnp.minimum(n, chunk_steps - 1)])
@@ -372,10 +382,11 @@ def paged_chunk_decode_loop(
 
             step_tok = jnp.where(active, cur, pad_id)
             write_pos = jnp.where(active, pos, 0)
-        logits, kp, vp, ksc, vsc = forward_paged(
+        logits, kp, vp, ksc, vsc, *stats = forward_paged(
             params, cfg, step_tok[:, None], write_pos[:, None], kp, vp,
             block_tables, rules=rules, attn_impl=kernels, write_mask=active,
             trash_idx=trash_idx, k_scale=ksc, v_scale=vsc, kv_quant=kv_quant,
+            **moe_kw,
         )
         raw = logits[:, 0, :]
         if nan_inject is not None:
@@ -401,7 +412,8 @@ def paged_chunk_decode_loop(
             stop = (cur == eos_id) | (nbytes >= byte_budget) | (pos >= max_pos - 1) | (left <= 0)
             active = ok & ~stop
         return (kp, vp, ksc, vsc, cur, pos, state, active, eos, nbytes, left,
-                out, n, key, step + 1, poison, conf)
+                out, n, key, step + 1, poison, conf,
+                *(m + s for m, s in zip(moe, stats)))
 
     def ff_body(c):
         # the dense ff_body's paged twin: cur + its state's forced chain in
@@ -413,7 +425,7 @@ def paged_chunk_decode_loop(
         # the engine's decode_chunk grew every live row's table to cover a
         # full ff chunk before dispatch.
         (kp, vp, ksc, vsc, cur, pos, state, active, eos, nbytes, left, out, n,
-         key, step, poison, conf) = c
+         key, step, poison, conf, *moe) = c
         with jax.named_scope("loop_carry"):
             # dead-at-entry fence (see the dense ff_body): a negative state
             # wraps the ff_tokens gather — poison it out before it emits
@@ -462,10 +474,11 @@ def paged_chunk_decode_loop(
 
             s_end, _ = jax.lax.scan(cstep, state, (chain.T, jnp.arange(W)))
 
-        logits, kp, vp, ksc, vsc = forward_paged(
+        logits, kp, vp, ksc, vsc, *stats = forward_paged(
             params, cfg, blk_tok, blk_pos, kp, vp,
             block_tables, rules=rules, attn_impl=kernels, write_mask=active,
             trash_idx=trash_idx, k_scale=ksc, v_scale=vsc, kv_quant=kv_quant,
+            **moe_kw,
         )
         logits_k = jnp.take_along_axis(logits, k[:, None, None], axis=1)[:, 0, :]
         if nan_inject is not None:
@@ -492,15 +505,16 @@ def paged_chunk_decode_loop(
             stop = (cur == eos_id) | (nbytes >= byte_budget) | (pos >= max_pos - 1) | (left <= 0)
             active = ok & ~stop
         return (kp, vp, ksc, vsc, cur, pos, state, active, eos, nbytes, left,
-                out, n, key, step + 1, poison, conf)
+                out, n, key, step + 1, poison, conf,
+                *(m + s for m, s in zip(moe, stats)))
 
     (k_pool, v_pool, k_scale, v_scale, cur, pos, state, active, eos, nbytes,
-     left, out, n, _, fwds, poison, conf) = (
+     left, out, n, _, fwds, poison, conf, *moe) = (
         jax.lax.while_loop(cond, ff_body if use_ff else body, carry0)
     )
     return (out[:, : cap if use_ff else chunk_steps], n, eos, k_pool, v_pool,
             k_scale, v_scale, cur, pos, state, active, nbytes, left, fwds,
-            poison, conf)
+            poison, conf, *moe)
 
 
 class PagedDecodeEngine(DecodeEngine):
@@ -520,6 +534,8 @@ class PagedDecodeEngine(DecodeEngine):
 
     _alloc_dense_cache = False  # startup must never peak at the dense
     # worst-case footprint this engine exists to avoid
+    _last_moe = None  # a ROUTED engine's decode_chunk sets it: llama.MOE_STATS
+    # summed over the chunk, read back with the chunk's one readback
 
     def __init__(self, *args, block_size: int = 128, pool_blocks: int | None = None,
                  radix_enable: bool | None = None,
@@ -1096,6 +1112,7 @@ class PagedDecodeEngine(DecodeEngine):
             # claims block coverage per verify step via spec_grow (growth
             # here would over-claim chunk_steps*(1+K) positions at once);
             # reconcile_coverage still clamps after the chunk.
+            self._last_moe = None  # only the plain chunk loop counts expert rows
             return self.spec.decode_chunk(
                 cur, pos, fsm, active, nbytes, tokens_left, key,
                 temperature, byte_budget, chunk_steps)
@@ -1127,7 +1144,7 @@ class PagedDecodeEngine(DecodeEngine):
                     continue
                 self._next_pos[b] = min(self._next_pos[b] + span, self.max_len)
         out, n, eos, self.k_pool, self.v_pool, self.k_scale, self.v_scale, \
-            cur, pos, fsm, active, nbytes, left, fwds, pois, conf = (
+            cur, pos, fsm, active, nbytes, left, fwds, pois, conf, *moe = (
                 paged_chunk_decode_loop(
                     self.params, self.cfg, self.k_pool, self.v_pool, self.block_tables,
                     cur, pos, fsm, active, nbytes, tokens_left,
@@ -1153,6 +1170,8 @@ class PagedDecodeEngine(DecodeEngine):
         self._last_fwds = fwds
         self._last_poison = pois
         self._last_conf = conf if self.quality_lanes else None
+        if moe:  # a routed model's expert-row counts ride the same readback
+            self._last_moe = moe[0]
         return out, n, eos, cur, pos, fsm, active, nbytes, left
 
     def spec_grow(self, span: int, active=None) -> list[int]:

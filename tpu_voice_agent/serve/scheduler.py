@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.llama import MOE_STATS
 from ..utils.compilewatch import watch_compiles
 from ..utils.steplog import ALLOC_SPAN, REQUEST_SPAN, span
 from .engine import DecodeEngine, GenerationResult, _mask_sample_advance
@@ -1110,12 +1111,14 @@ class ContinuousBatcher:
         fwds = getattr(eng, "_last_fwds", None)
         pois = getattr(eng, "_last_poison", None)
         conf = getattr(eng, "_last_conf", None)
-        out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h = (
+        moe = getattr(eng, "_last_moe", None)  # a routed model's expert-row counts
+        out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h, moe_h = (
             jax.device_get(
                 (out, n, active, eos, pos,
                  0 if fwds is None else fwds,
                  0 if pois is None else pois,
-                 0 if conf is None else conf))
+                 0 if conf is None else conf,
+                 0 if moe is None else moe))
         )
         out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h = (
             np.asarray(x) for x in (out_h, n_h, act_h, eos_h, pos_h, fwds_h,
@@ -1145,6 +1148,11 @@ class ContinuousBatcher:
             m.inc("scheduler.forwards", float(fwds_h))
             m.set_gauge("scheduler.tokens_per_forward",
                         float(n_h.sum()) / float(fwds_h))
+        if moe is not None:
+            # summed over the chunk's forwards and layers: per forward they
+            # are these over scheduler.forwards (docs/OBSERVABILITY.md)
+            for name, v in zip(MOE_STATS, np.asarray(moe_h)):
+                m.inc(f"moe.{name}", float(v))
         # saturation gauges: the signals continuous batching is tuned by —
         # backlog (queue_depth), batch occupancy (slots used / total), KV
         # page pressure (paged engines), and rolling throughput
