@@ -262,6 +262,56 @@ def test_paged_attention_compiles_at_group_one(tpu_devices):
              *_pool(N, OLMOE), tables, ((B,), I32), layer, interpret=False)
 
 
+@pytest.mark.parametrize("config", ["mistral-7b-v0.1-int8", "olmoe-1b-7b-0125-int8"])
+def test_the_compacted_chunk_program_compiles_at_published_widths(tpu_devices, monkeypatch, config):
+    """The whole decode chunk at its COMPACTED width (ISSUE 29: 8 of 32 slots'
+    rows, gathered and scattered back inside the program), as the benchmark's
+    configuration serves it — published widths, int8 weights, the 200-block
+    pool, fast-forward 8, chunk 16, the real grammar tables — lowered on
+    shapes and compiled by XLA:TPU and Mosaic. The dense model reaches
+    ``paged_block_attention`` at 8 rows; the routed one also the grouped
+    matmul at 72 tokens = 576 assignments and its row tile. No chip has run
+    the routed variant at this width (PERF.md section 7, ``olmoe_solo``)."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from benchmark.builders import olmoe_stack, parse_stack
+    from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
+    from tpu_voice_agent.serve import PagedDecodeEngine, paged
+
+    for mod in ("paged_attention", "grouped_matmul"):  # the kernels ask where they run: not interpreted here
+        monkeypatch.setattr(sys.modules[f"tpu_voice_agent.ops.{mod}"], "on_cpu", lambda: False)
+    conf = json.loads((Path(__file__).parents[1] / "benchmark" / "configs" / f"{config}.json").read_text())
+    dims = parse_stack.model_dims(conf, False)
+    m, s = dims["model"], dims["serving"]
+    routed = "num_experts" in m
+    llama_config, make_params = ((olmoe_stack.llama_config, olmoe_stack.make_params) if routed else
+                                 (parse_stack.dense_llama_config, parse_stack.make_decoder_params))
+    eng = PagedDecodeEngine(  # the builder's engine, but for a two-block pool: the real one is a shape below
+        cfg=llama_config(m, s), tokenizer=default_tokenizer(), quant=s["quant"], batch_slots=s["batch_slots"],
+        block_size=s["block_size"], pool_blocks=2, max_len=s["max_len"],
+        prefill_buckets=tuple(s["prefill_buckets"]), fast_forward=s["fast_forward"], init_weights=False)
+    B, R, cfg = eng.batch_slots, eng.compact_rows, eng.cfg
+    assert (B, R) == (32, 8) and (cfg.moe_impl == "grouped") == routed
+
+    chip = SingleDeviceSharding(tpu_devices[0])
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    shapes = lambda tree: jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), tree)
+    pool = S((cfg.n_layers, s["pool_blocks"], eng.block_size, cfg.n_kv_heads, cfg.head_dim), BF16)
+    compiled = paged.paged_chunk_decode_loop.__wrapped__.lower(
+        shapes(jax.eval_shape(lambda: make_params(cfg, s["weights_seed"]))), cfg, pool, pool,
+        S((B, eng.max_blocks), I32), S((B,), I32), S((B,), I32), S((B,), I32), S((B,), jnp.bool_),
+        S((B,), I32), S((B,), I32), shapes(eng.tables_ff), shapes(eng.byte_len_table),
+        shapes(jax.random.PRNGKey(0)), S((), F32), S((), I32), trash_idx=S((B,), I32), rules=None,
+        logit_mask=None if eng.logit_mask is None else shapes(eng.logit_mask), rows_idx=S((R,), I32),
+        chunk_steps=16, greedy=True, constrained=True, kernels="pallas", eos_id=eng.eos_id,
+        pad_id=eng.pad_id, max_len=eng.max_len, kv_quant=None, quality_lanes=eng.quality_lanes).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (4 if routed else 1)  # block attention (+ gate, up, down)
+    assert f"bf16[{R},9," in text and f"bf16[{B},9," not in text  # the forwards run at R rows
+
+
 @pytest.mark.slow
 def test_sharded_kernels_compile_on_2x2(tpu_devices):
     """The shard_map variants the dp x tp serving mesh traces (batch over

@@ -178,7 +178,7 @@ class _Inline:
         return None
 
 
-def _served(cfg: LlamaConfig, kernels: str):
+def _served(cfg: LlamaConfig, kernels: str, batch_slots: int = 2):
     """A ``PagedDecodeEngine`` over the in-tree tokenizer with the cached
     prompt prefix, int8 weights made by the benchmark's own builder, in the
     shape ``refcheck.sample_paged_decoder`` drives."""
@@ -189,7 +189,7 @@ def _served(cfg: LlamaConfig, kernels: str):
     from tpu_voice_agent.serve import PagedDecodeEngine
     from tpu_voice_agent.services.brain import install_prompt_prefix
 
-    eng = PagedDecodeEngine(cfg=cfg, tokenizer=default_tokenizer(), quant="int8", batch_slots=2,
+    eng = PagedDecodeEngine(cfg=cfg, tokenizer=default_tokenizer(), quant="int8", batch_slots=batch_slots,
                             block_size=128, pool_blocks=32, max_len=1536, kernels=kernels,
                             prefill_buckets=(128, 256, 1024), fast_forward=8, init_weights=False)
     eng.load_params(olmoe_stack.make_params(eng.cfg, 23))
@@ -221,6 +221,56 @@ def test_prefill_then_decode_through_the_paged_pool_matches_the_full_forward(ker
     want = ref.logits(params, model, sample)
     assert rel(rows, want) < 0.03
     assert rel(ref.logits(params, model, sample, control=True), want) > 0.03
+
+
+def test_a_compacted_routed_chunk_picks_what_the_plain_forward_picks():
+    """One request alone in an 8-slot batcher: every chunk runs at the
+    compacted width (2 rows × 9 positions = 18 tokens, 36 assignments: the
+    grouped kernel's smallest row tile), int8 weights, fast-forward on. Each
+    token it emitted, teacher-forced through the reference's full forward:
+    every PICK (the token behind each forced chain) is the reference's best
+    legal token or within the comparison's 3 % of the logit range of it, and
+    every chain is the grammar's; the expert-row counters counted 2 rows a
+    forward, not 8."""
+    from tpu_voice_agent.serve import ContinuousBatcher
+    from tpu_voice_agent.services.prompts import render_prompt
+    from tpu_voice_agent.utils import get_metrics
+
+    cfg = dataclasses.replace(olmoe_cfg(8, 2), vocab_size=1024, max_seq_len=1536)
+    served = _served(cfg, "xla", batch_slots=8)
+    eng = served.engine
+    assert eng.compact_rows == 2 and eng.cfg.moe_impl == "grouped"
+    ids = eng.tokenizer.encode(render_prompt("search for laptops under 1000", {}), bos=True)
+    before = dict(get_metrics().counter_state()[0])
+    bat = ContinuousBatcher(eng, chunk_steps=4, max_new_tokens=24)
+    rid = bat.submit(ids)
+    bat.run_until_done()
+    after = get_metrics().counter_state()[0]
+    d = {k: after.get(k, 0.0) - before.get(k, 0.0) for k in after}
+    gen = bat.results[rid].token_ids
+    assert bat.results[rid].error is None and len(gen) == 24 and eng._last_rows == 2
+    assert d["scheduler.forward_rows"] == 2 * d["scheduler.forwards"] > 0
+    assert d["moe.assigned_rows"] == d["scheduler.forward_rows"] * cfg.n_layers * 9 * cfg.top_k
+
+    sample = {"tokens": ids + gen[:-1], "rows": len(gen)}
+    want = np.asarray(ref.logits(eng.params, served.dims["model"], sample))
+    chains, chain_len = eng.fsm.forced_tables(eng.fast_forward)
+    live, state, i, choices = eng.tokenizer.vocab_size, eng.fsm.start, 0, 0
+    while i < len(gen):
+        row, tok = want[i], gen[i]  # a pick: the first token, or the one behind a chain
+        legal = eng.fsm.allowed(state)[:live]
+        assert legal[tok]
+        choices += int(legal.sum() > 1)
+        assert row[:live][legal].max() - row[tok] <= ref.TOLERANCE * np.abs(row).max(), (i, tok)
+        state = eng.fsm.step(state, tok)
+        chain = [int(t) for t in chains[state][: chain_len[state]]]
+        if len(gen) - i - 1 <= len(chain):
+            break  # the token budget may cut this chain short: nothing to judge behind it
+        assert gen[i + 1: i + 1 + len(chain)] == chain  # forced, as the grammar spells it
+        for t in chain:
+            state = eng.fsm.step(state, t)
+        i += 1 + len(chain)
+    assert choices >= 5  # real choices were judged, not forced chains alone
 
 
 def test_both_dispatches_are_token_identical_through_the_batcher():
@@ -287,25 +337,49 @@ def test_the_batcher_publishes_the_expert_row_counters():
     after = get_metrics().counter_state()[0]
     d = {k: after.get(k, 0.0) - before.get(k, 0.0) for k in after}
     fwds, layers, rows, K = d["scheduler.forwards"], cfg.n_layers, 2, cfg.top_k
-    assert d["moe.assigned_rows"] == fwds * layers * rows * K  # T = 1: two rows a forward, idle or not
+    # T = 1: the rows COMPUTED, idle or not — Σ forwards × the width each chunk was dispatched at.
+    # One request in two slots rides the compacted width of one row (ISSUE 29)
+    assert d["scheduler.forward_rows"] == fwds * eng.compact_rows == fwds
+    assert d["moe.assigned_rows"] == d["scheduler.forward_rows"] * layers * K
     assert d["moe.padded_rows"] >= d["moe.assigned_rows"]
     assert 0 < d["moe.experts_touched"] <= fwds * layers * min(cfg.n_experts, rows * K)
     assert fwds * layers <= d["moe.load_max"] <= fwds * layers * rows
 
 
-def _chunk_arity(monkeypatch) -> list[int]:
-    """Record how many outputs each chunk program hands ``decode_chunk``."""
+def _chunk_spy(monkeypatch) -> tuple[list[int], list[str]]:
+    """Record how many outputs each chunk program hands ``decode_chunk``, and
+    the lowered text of the first FULL-WIDTH dispatch (scope names included,
+    no Python frames: what the entry points' compile cache keys on)."""
     from tpu_voice_agent.serve import paged
 
-    seen, loop = [], paged.paged_chunk_decode_loop
+    seen, texts, loop = [], [], paged.paged_chunk_decode_loop
 
     def spy(*a, **kw):
+        if not texts and "rows_idx" not in kw:
+            frames = jax.config.jax_traceback_in_locations_limit
+            jax.config.update("jax_traceback_in_locations_limit", 0)
+            try:
+                texts.append(loop.__wrapped__.lower(*a, **kw).as_text(debug_info=True))
+            finally:
+                jax.config.update("jax_traceback_in_locations_limit", frames)
         out = loop(*a, **kw)
         seen.append(len(out))
         return out
 
     monkeypatch.setattr(paged, "paged_chunk_decode_loop", spy)
-    return seen
+    return seen, texts
+
+
+# sha256 of the full-width chunk program's StableHLO text as commit 884eedd
+# (PR 28, before the compacted width existed) lowers it for the two engines
+# of the fence test. ISSUE 29's fence for the flood cells: ``rows_idx`` absent
+# is an empty pytree leaf and the program is the parent's byte for byte. A
+# PR that changes the loop on purpose re-derives these (lower the first
+# ``decode_chunk`` dispatch as ``_chunk_spy`` does and hash it) and says so.
+FULL_WIDTH_SHA256 = {
+    "dense": "3f3e27ae17b72ad070239e5e55f2e5060102b8188b2375b6140001d57edb9921",
+    "routed": "ba34716538dbd9cb700b275de0c818804f4faa7e0cacae3a9773ef49fec12a64",
+}
 
 
 @pytest.mark.parametrize("model", ["dense", "routed"])
@@ -317,14 +391,18 @@ def test_the_fence_around_the_dense_path(model, monkeypatch):
     16 values it always did, and the tokens are those of the un-paged
     ``DecodeEngine`` (whose loop this block never touched). The routed
     variant of the same program returns one more, and the four counters
-    rise."""
+    rise. Since ISSUE 29 the fence holds the compacted width out too: at the
+    full width (``rows_idx`` absent) both variants lower to the text PR 28's
+    tree lowers, and nothing of the row gather or scatter is in it."""
+    import hashlib
+
     from tpu_voice_agent.serve import ContinuousBatcher, DecodeEngine, PagedDecodeEngine
     from tpu_voice_agent.services.prompts import render_prompt
     from tpu_voice_agent.utils import tracing
 
     fresh = tracing.Metrics()
     monkeypatch.setattr(tracing, "_GLOBAL_METRICS", fresh)
-    arity = _chunk_arity(monkeypatch)
+    arity, texts = _chunk_spy(monkeypatch)
     prompts = [render_prompt(t, {}) for t in ("go back", "scroll down")]
     kw = dict(max_len=1536, batch_slots=2, prefill_buckets=(128, 256, 1024))
     if model == "dense":
@@ -333,6 +411,8 @@ def test_the_fence_around_the_dense_path(model, monkeypatch):
         eng = PagedDecodeEngine(cfg=olmoe_cfg(8, 2), **kw)
     out = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=24).generate_many(prompts)
     assert all(r.error is None for r in out) and len(arity) >= 3
+    assert "rows_gather" not in texts[0] and "rows_scatter" not in texts[0] and "lm_head" in texts[0]
+    assert hashlib.sha256(texts[0].encode()).hexdigest() == FULL_WIDTH_SHA256[model]
     snap = fresh.snapshot()
     moe_names = sorted(k for part in snap.values() if isinstance(part, dict) for k in part
                        if str(k).startswith("moe."))

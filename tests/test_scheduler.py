@@ -1,6 +1,8 @@
 """Continuous batching: concurrent slots must be isolated and all outputs
 grammar-valid; batch composition must not change a greedy request's tokens."""
 
+import functools
+
 import pytest
 
 from tpu_voice_agent.schemas import parse_response_from_json
@@ -151,3 +153,185 @@ def test_fused_admission_tail_equals_the_eager_writes(layout, greedy, request):
     for r in (bat.results[rids[0]], bat.results[rid], bat.results[rids[2]]):
         assert r.error is None
         _assert_grammar_consistent(bat, r)
+
+
+# ------------------------------------------------- the chunk program's width
+#
+# ISSUE 29: a paged engine's chunk program has two widths — ``batch_slots``
+# and the compacted ``compact_rows`` (a quarter of the slots: 2 of these 8) —
+# and ``decode_chunk`` takes the one the batcher's live count allows.
+
+@functools.lru_cache(maxsize=None)
+def _wide(ff: int):
+    """One 8-slot paged engine per fast-forward setting, built once."""
+    from tpu_voice_agent.serve.paged import PagedDecodeEngine
+
+    eng = PagedDecodeEngine(preset="test-tiny", max_len=1024, batch_slots=8, prefill_buckets=(64, 128),
+                            radix_enable=False, fast_forward=ff)
+    assert eng.compact_rows == 2
+    return eng
+
+
+@functools.lru_cache(maxsize=None)
+def _plain(ff: int):
+    """The un-paged engine with the same weights and fast-forward setting (a
+    forced chain is emitted as the grammar spells it, so the setting is part
+    of which tokens a greedy decode gives)."""
+    from tpu_voice_agent.serve import DecodeEngine
+
+    return DecodeEngine(preset="test-tiny", max_len=1024, prefill_buckets=(64, 128), fast_forward=ff)
+
+
+WIDE_PROMPTS = PROMPTS + ["scroll down", "go back", "open the settings page", "sort by price"]
+FF = pytest.mark.parametrize("ff", [0, 8], ids=["ff-off", "ff-on"])
+
+
+@pytest.mark.parametrize("live,rows", [
+    ([], None), ([5], [5, 0]), ([3, 6], [3, 6]), ([0, 1], [0, 1]), ([1, 3, 6], None),
+    (list(range(8)), None)],
+    ids=["none-live", "one-live", "R-live", "first-two", "R-plus-one", "all-live"])
+def test_the_width_follows_the_live_count(live, rows):
+    """``live <= R`` rides ``R`` rows — the live slots first, idle slots
+    after them, none twice (the program scatters the rows back) — and one
+    more live row takes the full width (``None``: the call as it always was)."""
+    import numpy as np
+    from types import SimpleNamespace
+
+    from tpu_voice_agent.serve.paged import PagedDecodeEngine
+
+    mirror = np.zeros((8,), dtype=bool)
+    mirror[live] = True
+    got = PagedDecodeEngine._rows_of(SimpleNamespace(compact_rows=2), mirror)
+    assert (got is None) if rows is None else (got.dtype == np.int32 and got.tolist() == rows)
+    assert PagedDecodeEngine._rows_of(SimpleNamespace(compact_rows=0), mirror) is None
+
+
+@FF
+def test_a_compacted_chunk_is_the_full_width_chunk_on_its_rows(ff):
+    """ONE chunk from ONE state, dispatched at both widths: slots 3 and 6
+    live, slot 5 idle but still owning its blocks (what a slot mid-way through
+    a chunked admission is to the program), the rest released. Everything the
+    scheduler reads back is equal, the idle slots' state and slot 5's pool
+    blocks are bit-for-bit what they were, and the compacted program left
+    them so by never touching them."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    eng = _wide(ff)
+    bat = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=64)
+    rids = [bat.submit(p) for p in WIDE_PROMPTS]
+    bat.step()  # slots 0-6 admitted, one full-width chunk in
+    assert bat._active_h[:7].all() and eng._last_rows == 8
+    for slot in (0, 1, 2, 4):
+        bat.cancel(rids[slot])
+    live = np.zeros((8,), dtype=bool)
+    live[[3, 6]] = True
+    state = dict(cur=bat.cur, pos=bat.pos, fsm=bat.fsm, active=bat.active & jnp.asarray(live),
+                 nbytes=bat.nbytes, tokens_left=bat.tokens_left)
+    idle = [b for b in range(8) if not live[b]]
+    pools = (np.asarray(eng.k_pool), np.asarray(eng.v_pool))
+    book = (list(eng._next_pos), list(eng._covered))
+    names = ("out", "n", "eos", "cur", "pos", "fsm", "active", "nbytes", "tokens_left")
+
+    def chunk(**width):
+        eng.k_pool, eng.v_pool = jnp.asarray(pools[0]), jnp.asarray(pools[1])
+        eng._next_pos[:] = book[0]
+        got = eng.decode_chunk(*state.values(), jax.random.PRNGKey(0), 0.7, 3900, 8, True, **width)
+        got = dict(zip(names, (np.asarray(x) for x in got)),
+                   fwds=int(eng._last_fwds), poison=np.asarray(eng._last_poison),
+                   k=np.asarray(eng.k_pool), v=np.asarray(eng.v_pool))
+        return got, eng._last_rows
+
+    full, rows_full = chunk()
+    compact, rows_compact = chunk(live=live)
+    assert (rows_full, rows_compact) == (8, 2) and full["fwds"] == compact["fwds"] > 0
+    assert compact["n"][[3, 6]].min() > 0 and not compact["n"][idle].any()
+    for key in names + ("poison",):
+        assert np.array_equal(full[key], compact[key]), key
+    for key in ("cur", "pos", "fsm", "nbytes", "tokens_left"):
+        assert np.array_equal(compact[key][idle], np.asarray(state[key])[idle]), key
+    parked = eng._slot_owned[5]
+    assert parked and eng._covered == book[1]  # the second dispatch grew no table
+    for got, was in ((compact["k"], pools[0]), (compact["v"], pools[1])):
+        assert np.array_equal(got[:, parked], was[:, parked])
+    written = eng._slot_owned[3] + eng._slot_owned[6]
+    assert np.allclose(compact["k"][:, written], full["k"][:, written], atol=1e-5)
+    assert not np.array_equal(compact["k"][:, written], pools[0][:, written])
+    bat.reset()
+
+
+@FF
+def test_a_run_through_both_widths_compiles_nothing_and_keeps_its_tokens(ff):
+    """``warmup()`` RUNS both widths (its lone request rides the compacted
+    one, then the full one for a forward), so staggered arrivals that take the batcher R → B → R compile
+    nothing; ``scheduler.forward_rows`` over ``scheduler.forwards`` and the
+    step ledger's ``rows`` read the width of each chunk; and the tokens are
+    the un-paged ``DecodeEngine``'s."""
+    from tpu_voice_agent.utils import get_metrics
+    from tpu_voice_agent.utils.compilewatch import get_compile_watcher
+    from tpu_voice_agent.utils.steplog import get_steplog
+
+    eng = _wide(ff)
+    bat = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=40)
+    bat.warmup()
+    # its last dispatch ran the FULL width for one real forward, and it left nothing behind
+    assert (eng._last_rows, int(eng._last_fwds)) == (8, 1)
+    assert not bat._active_h.any() and not any(eng._slot_owned) and not bat.results
+    compiles = get_compile_watcher().state()["compiles"]
+    widths = []
+
+    def step():
+        before = dict(get_metrics().counter_state()[0])
+        bat.step()
+        after = get_metrics().counter_state()[0]
+        fwds, rows = (after.get(k, 0.0) - before.get(k, 0.0)
+                      for k in ("scheduler.forwards", "scheduler.forward_rows"))
+        assert fwds > 0 and get_steplog().last()["rows"] == rows / fwds == eng._last_rows
+        widths.append(int(rows / fwds))
+
+    rids = [bat.submit(p) for p in PROMPTS[:2]]
+    step()  # two live = R
+    rids.append(bat.submit(PROMPTS[2]))
+    step()  # three live = R + 1
+    while any(sl.request_id >= 0 for sl in bat.slots):
+        step()
+    assert widths[:2] == [2, 8] and widths[-1] == 2 and set(widths) == {2, 8}
+    assert get_compile_watcher().state()["compiles"] == compiles
+    for rid, prompt in zip(rids, PROMPTS):
+        got = bat.results[rid]
+        assert got.error is None
+        assert got.token_ids == _plain(ff).generate(prompt, max_new_tokens=40, greedy=True).token_ids
+
+
+def test_a_sampled_chunk_keeps_the_full_width():
+    """Non-greedy decode draws a row's noise at the batch's shape, so a row's
+    place in the batch is part of its tokens: only greedy chunks compact."""
+    eng = _wide(8)
+    from tpu_voice_agent.utils.steplog import get_steplog
+
+    bat = ContinuousBatcher(eng, chunk_steps=4, greedy=False, max_new_tokens=64)
+    bat.submit(PROMPTS[0])
+    bat.step()
+    rec = get_steplog().last()
+    assert (rec["occupancy"], rec["rows"], eng._last_rows) == (1, 8, 8)
+    bat.reset()
+
+
+def test_slots_of_two_dp_groups_never_share_a_compacted_program():
+    """On a mesh with ``dp > 1`` a slot's pool blocks live in its group's
+    shard: the compacted batch axis would mix groups, so such an engine has
+    no compacted width and every chunk computes ``batch_slots`` rows."""
+    from tpu_voice_agent.parallel.mesh import make_mesh
+    from tpu_voice_agent.serve.paged import PagedDecodeEngine
+    from tpu_voice_agent.utils import get_metrics
+
+    eng = PagedDecodeEngine(preset="test-tiny", max_len=1024, batch_slots=8, prefill_buckets=(64, 128),
+                            radix_enable=False, mesh=make_mesh(dp=2, tp=1))
+    assert eng.dp == 2 and eng.compact_rows == 0
+    before = dict(get_metrics().counter_state()[0])
+    out = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=16).generate_many([PROMPTS[0]])
+    after = get_metrics().counter_state()[0]
+    fwds, rows = (after.get(k, 0.0) - before.get(k, 0.0)
+                  for k in ("scheduler.forwards", "scheduler.forward_rows"))
+    assert out[0].error is None and eng._last_rows == 8 and rows == 8 * fwds > 0

@@ -105,6 +105,7 @@ PINNED: dict[str, str] = {
     "spec.trace_records": "counter",
     "scheduler.tokens_per_forward": "gauge",
     "scheduler.forwards": "counter",
+    "scheduler.forward_rows": "counter",  # ISSUE 29: forwards x the chunk's width
     # engine microscope (ISSUE 9, utils/steplog.py + utils/compilewatch.py
     # + utils/hbmledger.py, docs/OBSERVABILITY.md "Engine microscope"):
     # the step ledger's wall histogram + per-chunk occupancy/token gauges

@@ -309,6 +309,8 @@ def paged_chunk_decode_loop(
     k_scale=None,  # (L, N, bs, nkv) bf16 KV_QUANT scale planes (None = off:
     # empty pytree leaves, the traced loop is byte-identical to pre-quant)
     v_scale=None,
+    rows_idx=None,  # (R,) int32 distinct slots, or None = every slot (an empty
+    # pytree leaf too: the full-width program is the one it was without it)
     chunk_steps: int = 32,
     greedy: bool = True,
     constrained: bool = True,
@@ -330,12 +332,32 @@ def paged_chunk_decode_loop(
     carry and one more output: ``llama.MOE_STATS`` summed over the chunk's
     forwards and layers, (4,) int32. A dense model's program is untouched.
 
+    The COMPACTED width (ISSUE 29): with ``rows_idx`` the same loop runs over
+    those R slots' rows alone — their state and block-table rows gathered on
+    entry, scattered back on exit into the (B,) arrays it was handed — and
+    every slot that did not ride gets what the full width gives an idle row
+    (pad, 0 emitted, ``eos0``, no poison, ``_conf_init``), so the caller sees
+    the full width's shapes. The pool is shared and addressed through the
+    tables, so nothing of it is gathered. The engine picks the width from
+    the batcher's live count (``PagedDecodeEngine.decode_chunk``).
+
     The batched VERIFY mode of this chunk path (speculative decoding,
     ISSUE 8) lives in serve.spec.paged_spec_verify_step: drafting is
     host-side so verify steps cannot run inside this lax.while_loop — the
     SpecDecoder substitutes for the whole loop behind decode_chunk, one
     (B, 1+K) forward_paged per step with the same write_mask/trash-block
     discipline, per-row accept lengths, and the same per-row poison codes."""
+    if rows_idx is not None:
+        with jax.named_scope("rows_gather"):
+            full = (cur, pos, fsm_state, active, nbytes, tokens_left)
+            eos_full = (~active) & (cur == eos_id)
+            cur, pos, fsm_state, active, nbytes, tokens_left = (
+                x[rows_idx] for x in full)
+            block_tables = block_tables[rows_idx]
+            if trash_idx is not None:
+                trash_idx = trash_idx[rows_idx]
+            if nan_inject is not None:
+                nan_inject = nan_inject[rows_idx]
     B = cur.shape[0]
     # the engine's max_len, NOT the block-rounded table capacity — with a
     # non-multiple max_len the dense loop stops at max_len-1 and the paged
@@ -512,7 +534,23 @@ def paged_chunk_decode_loop(
      left, out, n, _, fwds, poison, conf, *moe) = (
         jax.lax.while_loop(cond, ff_body if use_ff else body, carry0)
     )
-    return (out[:, : cap if use_ff else chunk_steps], n, eos, k_pool, v_pool,
+    out = out[:, : cap if use_ff else chunk_steps]
+    if rows_idx is not None:
+        with jax.named_scope("rows_scatter"):
+            Bf = eos_full.shape[0]
+
+            def put(base, rows):
+                return base.at[rows_idx].set(rows)
+
+            cur, pos, state, active, nbytes, left = (
+                put(f, x) for f, x in
+                zip(full, (cur, pos, state, active, nbytes, left)))
+            out = put(jnp.full((Bf, out.shape[1]), pad_id, jnp.int32), out)
+            n, poison = (put(jnp.zeros((Bf,), jnp.int32), x)
+                         for x in (n, poison))
+            eos = put(eos_full, eos)
+            conf = tuple(put(c0, c) for c0, c in zip(_conf_init(Bf), conf))
+    return (out, n, eos, k_pool, v_pool,
             k_scale, v_scale, cur, pos, state, active, nbytes, left, fwds,
             poison, conf, *moe)
 
@@ -536,6 +574,7 @@ class PagedDecodeEngine(DecodeEngine):
     # worst-case footprint this engine exists to avoid
     _last_moe = None  # a ROUTED engine's decode_chunk sets it: llama.MOE_STATS
     # summed over the chunk, read back with the chunk's one readback
+    _last_rows = None  # rows the last chunk's forwards computed (its width)
 
     def __init__(self, *args, block_size: int = 128, pool_blocks: int | None = None,
                  radix_enable: bool | None = None,
@@ -546,6 +585,13 @@ class PagedDecodeEngine(DecodeEngine):
         self.block_size = bs
         self.max_blocks = -(-self.max_len // bs)
         self.dp = self.mesh.shape.get("dp", 1) if self.mesh is not None else 1
+        # the chunk program's compacted width (ISSUE 29): a chunk with at
+        # most this many live rows computes this many, not batch_slots. One
+        # width, derived here: each one is an executable every start-up
+        # compiles. 0 = never: slots of different dp groups may not share a
+        # program's batch axis
+        R = max(1, self.batch_slots // 4)
+        self.compact_rows = R if self.dp == 1 and R < self.batch_slots else 0
         # quantized KV storage tier (ISSUE 12): KV_QUANT=int8|int4 stores
         # per-(position, head) scaled values (ops.kvquant) — half/quarter
         # the HBM bytes per block, so a fixed pool budget holds ~2x/~4x the
@@ -1090,10 +1136,34 @@ class PagedDecodeEngine(DecodeEngine):
         self._set_table_row(slot, self._slot_shared[slot] + self._slot_owned[slot])
         self._covered[slot] += len(extra) * bs
 
+    def _rows_of(self, live) -> "np.ndarray | None":
+        """The (R,) slot index a compacted chunk rides, or None for the full
+        width: the live slots, padded with the first idle ones so no index
+        repeats (the program scatters the rows back)."""
+        R = self.compact_rows
+        if live is None or not R:
+            return None
+        live = np.asarray(live, dtype=bool)
+        k = int(live.sum())
+        if not 1 <= k <= R:
+            return None
+        return np.concatenate(
+            [np.flatnonzero(live), np.flatnonzero(~live)[: R - k]]
+        ).astype(np.int32)
+
     def decode_chunk(self, cur, pos, fsm, active, nbytes, tokens_left, key,
                      temperature: float, byte_budget: int, chunk_steps: int,
-                     greedy: bool):
+                     greedy: bool, live=None):
         """One dispatch of up to ``chunk_steps`` constrained decode steps.
+
+        ``live`` is the batcher's host mirror of ``active`` (a superset of
+        it). With 1 to ``compact_rows`` slots live the greedy chunk runs at
+        ``compact_rows`` rows (``paged_chunk_decode_loop``'s ``rows_idx``)
+        and is token-identical; otherwise, and without ``live``, at
+        ``batch_slots`` through the call this always made. A sampled
+        (non-greedy) chunk keeps the full width: its per-row noise is drawn
+        at the batch's shape, so a row's place would change its tokens.
+        ``_last_rows`` says which width ran.
 
         CALLER OBLIGATION: after consuming the chunk's results, pass the
         returned ``pos`` (host-fetched) to ``reconcile_coverage``. The
@@ -1113,6 +1183,7 @@ class PagedDecodeEngine(DecodeEngine):
             # here would over-claim chunk_steps*(1+K) positions at once);
             # reconcile_coverage still clamps after the chunk.
             self._last_moe = None  # only the plain chunk loop counts expert rows
+            self._last_rows = self.batch_slots
             return self.spec.decode_chunk(
                 cur, pos, fsm, active, nbytes, tokens_left, key,
                 temperature, byte_budget, chunk_steps)
@@ -1143,6 +1214,10 @@ class PagedDecodeEngine(DecodeEngine):
                     tokens_left = tokens_left.at[b].set(0)
                     continue
                 self._next_pos[b] = min(self._next_pos[b] + span, self.max_len)
+        rows = self._rows_of(live) if greedy else None
+        self._last_rows = self.batch_slots if rows is None else len(rows)
+        # absent at the full width, so that call is the one it always was
+        compact = {} if rows is None else {"rows_idx": jnp.asarray(rows)}
         out, n, eos, self.k_pool, self.v_pool, self.k_scale, self.v_scale, \
             cur, pos, fsm, active, nbytes, left, fwds, pois, conf, *moe = (
                 paged_chunk_decode_loop(
@@ -1160,6 +1235,7 @@ class PagedDecodeEngine(DecodeEngine):
                     eos_id=self.eos_id, pad_id=self.pad_id, max_len=self.max_len,
                     kv_quant=self.kv_quant,
                     quality_lanes=self.quality_lanes,
+                    **compact,
                 )
             )
         # forward-dispatch count for the scheduler's tokens-per-forward

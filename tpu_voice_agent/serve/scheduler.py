@@ -260,8 +260,8 @@ class ContinuousBatcher:
         can make it dispatch: the admission prefill at every suffix bucket
         behind the installed prompt prefix (every full-prompt bucket when
         there is none), the first-token pick, and one decode chunk with its
-        readback. A cold compile inside ``step()`` stalls every batch-mate
-        and runs under the colocate stall watchdog (``ENGINE_STALL_S``):
+        readback, at every width the chunk program has. A cold compile
+        inside ``step()`` stalls every batch-mate and runs under the colocate stall watchdog (``ENGINE_STALL_S``):
         a burst landing in several uncompiled buckets at once would outlast
         it and get the healthy engine warm-restarted. MUST run on the thread
         that drives ``step()``, with nothing in flight."""
@@ -278,6 +278,17 @@ class ContinuousBatcher:
                 eng.release_slot(0, ok=False)
         rid = self.submit(prefix + [eng.pad_id] * 8)
         self.step()
+        if (getattr(eng, "_last_rows", None) or self.B) != self.B:
+            # that lone request rode the compacted width: RUN the full one
+            # too, on the same row, for the one forward a token budget of 1
+            # allows (a program entered with nothing live runs no forward,
+            # and the first one it then runs in traffic is slower: 30-80 ms
+            # on a v5e, PERF.md section 6, PR 29). What it returns is dropped;
+            # the request is cancelled next, its blocks with it
+            eng.decode_chunk(
+                self.cur, self.pos, self.fsm, self.active, self.nbytes,
+                jnp.minimum(self.tokens_left, 1), self._rng, self.temperature,
+                self.byte_budget, self.chunk_steps, self.greedy)
         self.cancel(rid, "warm-up")
         self.results.pop(rid, None)
 
@@ -1092,12 +1103,16 @@ class ContinuousBatcher:
         eng._last_row_fwds = None
         eng._last_row_drafted = None
         self._rng, k = jax.random.split(self._rng)
+        # a paged engine takes the chunk program's width from the live rows
+        # (ISSUE 29): few enough of them ride a compacted program
+        width = {"live": act} if getattr(eng, "compact_rows", 0) else {}
         (out, n, eos, cur, pos, fsm, active,
          nbytes, tokens_left) = eng.decode_chunk(
             self.cur, self.pos, self.fsm, self.active, self.nbytes,
             self.tokens_left, k, self.temperature, self.byte_budget,
-            self.chunk_steps, self.greedy,
+            self.chunk_steps, self.greedy, **width,
         )
+        rows = getattr(eng, "_last_rows", None) or self.B
         timer.stage("sched.readback")
         # one transfer for everything the host needs this chunk (a combined
         # device_get is ONE host<->device sync; separate gets pay one each).
@@ -1146,6 +1161,8 @@ class ContinuousBatcher:
         m.inc("scheduler.chunks")
         if fwds is not None and fwds_h > 0:
             m.inc("scheduler.forwards", float(fwds_h))
+            # rows COMPUTED: forwards at the width this chunk was dispatched
+            m.inc("scheduler.forward_rows", float(fwds_h) * rows)
             m.set_gauge("scheduler.tokens_per_forward",
                         float(n_h.sum()) / float(fwds_h))
         if moe is not None:
@@ -1351,6 +1368,7 @@ class ContinuousBatcher:
                 pass  # metering must never become a serving fault
         timer.finish(
             occupancy=occupancy,
+            rows=rows,
             tokens=int(n_h.sum()),
             admitted=n_admitted or None,
             forwards=int(fwds_h) if fwds is not None else None,
