@@ -89,3 +89,17 @@ def tiny_batch_engine():
     return DecodeEngine(
         preset="test-tiny", max_len=1024, batch_slots=3, prefill_buckets=(64, 128, 256, 512)
     )
+
+
+@pytest.fixture(scope="session")
+def distilled_intent():
+    """(cfg, params) of the in-tree DISTILLED intent checkpoint, for tests that
+    need a parse to be an ANSWER: ``_result_to_response`` refuses a decode
+    that does not reach EOS, and random weights never reach it."""
+    from tpu_voice_agent.models.llama import LlamaConfig
+    from tpu_voice_agent.train import distill
+
+    loaded = distill.load_ckpt("checkpoints", distill.INTENT_CKPT, LlamaConfig)
+    if loaded is None:
+        pytest.skip("trained checkpoints not present (run python -m tpu_voice_agent.train.make_tiny_ckpts)")
+    return loaded

@@ -244,11 +244,13 @@ def test_a_compacted_routed_chunk_picks_what_the_plain_forward_picks():
     before = dict(get_metrics().counter_state()[0])
     bat = ContinuousBatcher(eng, chunk_steps=4, max_new_tokens=24)
     rid = bat.submit(ids)
-    bat.run_until_done()
+    widths = set()
+    while rid not in bat.results:
+        widths.add(bat.step().rows)
     after = get_metrics().counter_state()[0]
     d = {k: after.get(k, 0.0) - before.get(k, 0.0) for k in after}
     gen = bat.results[rid].token_ids
-    assert bat.results[rid].error is None and len(gen) == 24 and eng._last_rows == 2
+    assert bat.results[rid].error is None and len(gen) == 24 and widths == {2}
     assert d["scheduler.forward_rows"] == 2 * d["scheduler.forwards"] > 0
     assert d["moe.assigned_rows"] == d["scheduler.forward_rows"] * cfg.n_layers * 9 * cfg.top_k
 
@@ -286,16 +288,22 @@ def test_both_dispatches_are_token_identical_through_the_batcher():
         eng = PagedDecodeEngine(cfg=cfg, max_len=1536, batch_slots=3, fast_forward=8,
                                 prefill_buckets=(128, 256, 1024), init_weights=False)
         eng.load_params(seeded_params(eng.cfg, seed=11))
-        out = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=48).generate_many(prompts)
+        bat = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=48)
+        rids = [bat.submit(p) for p in prompts]
+        chunks = []
+        while not all(rid in bat.results for rid in rids):
+            chunks.append(bat.step())
+        out = [bat.results[rid] for rid in rids]
         assert all(r.error is None for r in out)
+        # every chunk's record carried the expert-row counts out of the loop
+        assert all(c.moe is not None and c.moe.shape == (len(llama.MOE_STATS),) for c in chunks)
         return [r.token_ids for r in out], eng
 
     cfg = olmoe_cfg(8, 2)
     grouped, eng = run(cfg)
     assert eng.cfg.moe_impl == "grouped"  # chosen by the engine, no knob
-    assert eng._last_moe is not None  # the chunk loop carried the expert-row counts out
     dense, eng = run(dataclasses.replace(cfg, moe_impl="dense"))
-    assert eng.cfg.moe_impl == "dense" and eng._last_moe is not None
+    assert eng.cfg.moe_impl == "dense"
     assert grouped == dense and all(len(t) > 4 for t in grouped)
 
 
@@ -387,7 +395,7 @@ def test_the_fence_around_the_dense_path(model, monkeypatch):
     """What a configuration with ``n_experts == 0`` may not see of this
     block (ISSUE 28: PR 27 was refused for a slower DENSE cell). A dense
     ``test-tiny`` engine behind the batcher: no ``moe.*`` metric of any kind
-    is registered, ``_last_moe`` stays None, the chunk program returns the
+    is registered, every chunk's record has ``moe`` None, the chunk program returns the
     16 values it always did, and the tokens are those of the un-paged
     ``DecodeEngine`` (whose loop this block never touched). The routed
     variant of the same program returns one more, and the four counters
@@ -409,20 +417,23 @@ def test_the_fence_around_the_dense_path(model, monkeypatch):
         eng = PagedDecodeEngine(preset="test-tiny", **kw)
     else:
         eng = PagedDecodeEngine(cfg=olmoe_cfg(8, 2), **kw)
+    chunks, decode_chunk = [], eng.decode_chunk
+    monkeypatch.setattr(eng, "decode_chunk", lambda *a, **kw: chunks.append(decode_chunk(*a, **kw)) or chunks[-1])
     out = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=24).generate_many(prompts)
-    assert all(r.error is None for r in out) and len(arity) >= 3
+    assert all(r.error is None for r in out) and len(arity) == len(chunks) >= 3
     assert "rows_gather" not in texts[0] and "rows_scatter" not in texts[0] and "lm_head" in texts[0]
     assert hashlib.sha256(texts[0].encode()).hexdigest() == FULL_WIDTH_SHA256[model]
     snap = fresh.snapshot()
     moe_names = sorted(k for part in snap.values() if isinstance(part, dict) for k in part
                        if str(k).startswith("moe."))
     if model == "routed":
-        assert set(arity) == {17} and eng._last_moe.shape == (4,)
+        assert set(arity) == {17} and all(c.moe.shape == (4,) for c in chunks)
         assert moe_names == sorted(f"moe.{n}" for n in llama.MOE_STATS)
         assert all(snap["counters"][k] > 0 for k in moe_names)
         return
     assert set(arity) == {16} and moe_names == []
-    assert eng._last_moe is None and "_last_moe" not in vars(eng)
+    assert all(c.moe is None for c in chunks)
+    assert {k for k in vars(eng) if k.startswith("_last_")} <= {"_last_prefill_compute_ms", "_last_cached_tokens"}
     plain = DecodeEngine(preset="test-tiny", max_len=1536, prefill_buckets=(128, 256, 1024))
     assert [r.token_ids for r in out] == [
         plain.generate(p, max_new_tokens=24, greedy=True).token_ids for p in prompts]

@@ -225,12 +225,17 @@ def test_stt_confidence_lanes(stt_engine):
 
 
 def test_stt_garble_chaos_flags_repetition(stt_engine):
-    clean = stt_engine.transcribe(_tone(400, 0.8))
+    # seeded noise, not the 400 Hz tone: on the tone the random-init Whisper
+    # loops ONE token to its budget by itself (repetition 15/16, the ceiling
+    # the garble pins), and nothing can rise above that; on this noise it
+    # emits five distinct tokens
+    audio = (0.3 * np.random.default_rng(0).standard_normal(16_000)).astype(np.float32)
+    clean = stt_engine.transcribe(audio)
     if not clean.text:
         pytest.skip("random-init whisper emitted nothing to garble")
     chaos_mod.configure("stt_garble:1", seed=3)
     try:
-        garbled = stt_engine.transcribe(_tone(400, 0.8))
+        garbled = stt_engine.transcribe(audio)
     finally:
         chaos_mod.reset()
     # post-decode corruption: one token looped — latency identical,
@@ -274,15 +279,18 @@ def test_intent_downgrade_latches_brain_replica():
         chaos_mod.reset()
 
 
-def test_brain_parse_reports_quality_headers(tiny_engine):
+def test_brain_parse_reports_quality_headers(distilled_intent):
     """An engine-backed /parse answers with the confidence headers the
     voice service folds into its gauges (x-prompt-tokens powers the
-    prefill-remaining-at-endpoint measurement)."""
+    prefill-remaining-at-endpoint measurement). The engine carries the
+    in-tree DISTILLED intent checkpoint (what ``BRAIN_BACKEND=distilled``
+    serves): a /parse is a 200 only if its plan reaches EOS, which random
+    weights never do."""
     from tests.http_helper import AppServer
-    from tpu_voice_agent.services.brain import EngineParser, build_app
+    from tpu_voice_agent.services.brain import build_app
+    from tpu_voice_agent.train import distill
 
-    with AppServer(build_app(EngineParser(tiny_engine,
-                                          max_new_tokens=48))) as srv:
+    with AppServer(build_app(distill.intent_engine_from(*distilled_intent))) as srv:
         req = urllib.request.Request(
             srv.url + "/parse",
             data=json.dumps({"text": "scroll down", "context": {}}).encode(),

@@ -207,6 +207,27 @@ def eng_on():
     return eng
 
 
+@pytest.fixture(scope="module")
+def trained(distilled_intent):
+    """Engines carrying the distilled checkpoint, for the tests that go
+    through ``BatchedEngineParser``. Its plans take up to ~70 tokens behind
+    the brain prompt: PARSE_TOKENS leaves them room."""
+    import jax
+
+    cfg, params = distilled_intent
+
+    def build(radix: bool):
+        eng = _paged(radix, cfg=cfg, init_weights=False)
+        eng.load_params(jax.device_put(params))
+        install_prompt_prefix(eng)
+        return eng
+
+    return build
+
+
+PARSE_TOKENS = 160
+
+
 def _run(eng, prompts, max_new=48):
     return ContinuousBatcher(eng, chunk_steps=16,
                              max_new_tokens=max_new).generate_many(prompts)
@@ -406,7 +427,7 @@ def test_session_transcripts_strict_token_extension(eng_on):
     assert t.prompt_for("s1", "x", {}) == render_prompt("x", {})
 
 
-def test_session_parser_radix_reuse_and_two_phase(eng_on):
+def test_session_parser_radix_reuse_and_two_phase(trained):
     """Service integration: the session-aware BatchedEngineParser renders
     strict-extension prompts, warm turns report more cached tokens, and a
     speculative turn commits (cached plan, zero decode) on the matching
@@ -414,7 +435,7 @@ def test_session_parser_radix_reuse_and_two_phase(eng_on):
     from tpu_voice_agent.services.brain import BatchedEngineParser
     from tpu_voice_agent.utils.tracing import pop_stage_notes
 
-    p = BatchedEngineParser(eng_on, chunk_steps=16, max_new_tokens=48,
+    p = BatchedEngineParser(trained(True), chunk_steps=16, max_new_tokens=PARSE_TOKENS,
                             session_aware=True)
     try:
         pop_stage_notes()
@@ -442,12 +463,12 @@ def test_session_parser_radix_reuse_and_two_phase(eng_on):
         p.close()
 
 
-def test_stateless_parser_contract_unchanged(eng_off):
+def test_stateless_parser_contract_unchanged(trained):
     """session_aware off: parse(text, context) works positionally (the
     pre-radix contract build_app relies on when wants_session is False)."""
     from tpu_voice_agent.services.brain import BatchedEngineParser
 
-    p = BatchedEngineParser(eng_off, chunk_steps=16, max_new_tokens=48)
+    p = BatchedEngineParser(trained(False), chunk_steps=16, max_new_tokens=PARSE_TOKENS)
     try:
         assert p.wants_session is False
         r = p.parse("take a screenshot", {})

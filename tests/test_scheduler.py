@@ -221,8 +221,8 @@ def test_a_compacted_chunk_is_the_full_width_chunk_on_its_rows(ff):
     eng = _wide(ff)
     bat = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=64)
     rids = [bat.submit(p) for p in WIDE_PROMPTS]
-    bat.step()  # slots 0-6 admitted, one full-width chunk in
-    assert bat._active_h[:7].all() and eng._last_rows == 8
+    first = bat.step()  # slots 0-6 admitted, one full-width chunk in
+    assert bat._active_h[:7].all() and first.rows == 8
     for slot in (0, 1, 2, 4):
         bat.cancel(rids[slot])
     live = np.zeros((8,), dtype=bool)
@@ -237,11 +237,10 @@ def test_a_compacted_chunk_is_the_full_width_chunk_on_its_rows(ff):
     def chunk(**width):
         eng.k_pool, eng.v_pool = jnp.asarray(pools[0]), jnp.asarray(pools[1])
         eng._next_pos[:] = book[0]
-        got = eng.decode_chunk(*state.values(), jax.random.PRNGKey(0), 0.7, 3900, 8, True, **width)
-        got = dict(zip(names, (np.asarray(x) for x in got)),
-                   fwds=int(eng._last_fwds), poison=np.asarray(eng._last_poison),
-                   k=np.asarray(eng.k_pool), v=np.asarray(eng.v_pool))
-        return got, eng._last_rows
+        res = eng.decode_chunk(*state.values(), jax.random.PRNGKey(0), 0.7, 3900, 8, True, **width)
+        got = dict({name: np.asarray(getattr(res, name)) for name in names + ("poison",)},
+                   fwds=int(res.fwds), k=np.asarray(eng.k_pool), v=np.asarray(eng.v_pool))
+        return got, res.rows
 
     full, rows_full = chunk()
     compact, rows_compact = chunk(live=live)
@@ -262,7 +261,7 @@ def test_a_compacted_chunk_is_the_full_width_chunk_on_its_rows(ff):
 
 
 @FF
-def test_a_run_through_both_widths_compiles_nothing_and_keeps_its_tokens(ff):
+def test_a_run_through_both_widths_compiles_nothing_and_keeps_its_tokens(ff, monkeypatch):
     """``warmup()`` RUNS both widths (its lone request rides the compacted
     one, then the full one for a forward), so staggered arrivals that take the batcher R → B → R compile
     nothing; ``scheduler.forward_rows`` over ``scheduler.forwards`` and the
@@ -274,20 +273,22 @@ def test_a_run_through_both_widths_compiles_nothing_and_keeps_its_tokens(ff):
 
     eng = _wide(ff)
     bat = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=40)
+    chunks, decode_chunk = [], eng.decode_chunk
+    monkeypatch.setattr(eng, "decode_chunk", lambda *a, **kw: chunks.append(decode_chunk(*a, **kw)) or chunks[-1])
     bat.warmup()
     # its last dispatch ran the FULL width for one real forward, and it left nothing behind
-    assert (eng._last_rows, int(eng._last_fwds)) == (8, 1)
+    assert [c.rows for c in chunks] == [2, 8] and int(chunks[-1].fwds) == 1
     assert not bat._active_h.any() and not any(eng._slot_owned) and not bat.results
     compiles = get_compile_watcher().state()["compiles"]
     widths = []
 
     def step():
         before = dict(get_metrics().counter_state()[0])
-        bat.step()
+        res = bat.step()
         after = get_metrics().counter_state()[0]
         fwds, rows = (after.get(k, 0.0) - before.get(k, 0.0)
                       for k in ("scheduler.forwards", "scheduler.forward_rows"))
-        assert fwds > 0 and get_steplog().last()["rows"] == rows / fwds == eng._last_rows
+        assert fwds > 0 and get_steplog().last()["rows"] == rows / fwds == res.rows
         widths.append(int(rows / fwds))
 
     rids = [bat.submit(p) for p in PROMPTS[:2]]
@@ -312,9 +313,9 @@ def test_a_sampled_chunk_keeps_the_full_width():
 
     bat = ContinuousBatcher(eng, chunk_steps=4, greedy=False, max_new_tokens=64)
     bat.submit(PROMPTS[0])
-    bat.step()
+    res = bat.step()
     rec = get_steplog().last()
-    assert (rec["occupancy"], rec["rows"], eng._last_rows) == (1, 8, 8)
+    assert (rec["occupancy"], rec["rows"], res.rows) == (1, 8, 8)
     bat.reset()
 
 
@@ -330,8 +331,120 @@ def test_slots_of_two_dp_groups_never_share_a_compacted_program():
                             radix_enable=False, mesh=make_mesh(dp=2, tp=1))
     assert eng.dp == 2 and eng.compact_rows == 0
     before = dict(get_metrics().counter_state()[0])
-    out = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=16).generate_many([PROMPTS[0]])
+    bat = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=16)
+    rid = bat.submit(PROMPTS[0])
+    widths = set()
+    while rid not in bat.results:
+        widths.add(bat.step().rows)
     after = get_metrics().counter_state()[0]
     fwds, rows = (after.get(k, 0.0) - before.get(k, 0.0)
                   for k in ("scheduler.forwards", "scheduler.forward_rows"))
-    assert out[0].error is None and eng._last_rows == 8 and rows == 8 * fwds > 0
+    assert bat.results[rid].error is None and widths == {8} and rows == 8 * fwds > 0
+
+
+# ------------------------------------------------- the seam (ISSUE 30)
+#
+# ``decode_chunk`` hands its chunk back as ONE value, a ``ChunkResult``, under
+# one signature in all four implementations; nothing of a chunk is left on
+# the engine, and the batcher writes nothing there.
+
+@functools.lru_cache(maxsize=None)
+def _implementer(kind: str):
+    """A 2-slot engine of each ``decode_chunk`` implementation; the
+    confidence lanes off on one of them, so both kinds of ``conf`` are met."""
+    from tpu_voice_agent.parallel.pipeline import pp_tp_mesh
+    from tpu_voice_agent.serve import DecodeEngine, PagedDecodeEngine, PPDecodeEngine, SpecConfig
+
+    kw = dict(preset="test-tiny", max_len=512, batch_slots=2, prefill_buckets=(64, 128))
+    if kind == "dense":
+        return DecodeEngine(quality_lanes=False, **kw)
+    if kind == "pp":
+        return PPDecodeEngine(mesh=pp_tp_mesh(2, 1), **kw)
+    spec = SpecConfig(k=4, drafter="fsm") if kind == "spec-over-paged" else None
+    return PagedDecodeEngine(radix_enable=False, spec=spec, **kw)
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged", "pp", "spec-over-paged"])
+def test_every_decode_chunk_returns_one_record_under_one_signature(kind, monkeypatch):
+    import inspect
+
+    import jax
+    import numpy as np
+
+    from tpu_voice_agent.serve import DecodeEngine
+    from tpu_voice_agent.serve.engine import ChunkResult
+
+    eng = _implementer(kind)
+    B = eng.batch_slots
+    chunker = eng.spec if kind == "spec-over-paged" else eng
+    assert inspect.signature(type(chunker).decode_chunk) == inspect.signature(DecodeEngine.decode_chunk)
+
+    # the batcher reads a chunk back with ONE device_get, counted from the
+    # moment ``decode_chunk`` returned (the spec decoder pays its own inside)
+    gets, device_get, decode_chunk = [], jax.device_get, eng.decode_chunk
+    monkeypatch.setattr(jax, "device_get", lambda x: gets.append(1) or device_get(x))
+    monkeypatch.setattr(eng, "decode_chunk", lambda *a, **kw: (decode_chunk(*a, **kw), gets.clear())[0])
+    bat = ContinuousBatcher(eng, chunk_steps=4, max_new_tokens=64)
+    bat.submit(PROMPTS[0])
+    res = bat.step()
+    assert len(gets) == 1 and bat._active_h.tolist() == [True, False]
+    monkeypatch.undo()
+    held = set(vars(eng))
+    assert {k for k in held if k.startswith("_last_")} == {"_last_prefill_compute_ms", "_last_cached_tokens"}
+
+    def check(res, rows):
+        assert type(res) is ChunkResult and res.rows == rows
+        for name in ("n", "eos", "cur", "pos", "fsm", "active", "nbytes", "tokens_left", "poison"):
+            assert np.asarray(getattr(res, name)).shape == (B,), name
+        assert np.asarray(res.out).shape[0] == B and int(res.fwds) > 0 and int(np.asarray(res.n)[0]) > 0
+        if eng.quality_lanes:
+            assert len(res.conf) == 5 and all(np.asarray(lane).shape == (B,) for lane in res.conf)
+        else:
+            assert res.conf is None
+        assert res.moe is None  # a dense model's chunk program has no such output
+        counts = (res.row_fwds, res.row_accepts, res.row_drafted)
+        if kind == "spec-over-paged":  # host counts a row: the live one rode every verify step
+            assert all(c.shape == (B,) and c.dtype == np.int64 for c in counts) and res.row_fwds[0] == res.fwds
+        else:
+            assert counts == (None, None, None)
+
+    check(res, eng.compact_rows if kind == "paged" else B)  # one live row of two: the compacted width
+    # the same call by hand, with both keywords: the chaos mask is THIS chunk's
+    # (row 0 poisoned, its idle neighbour untouched), and no width without ``live``
+    mask = np.array([True, False])
+    res = eng.decode_chunk(bat.cur, bat.pos, bat.fsm, bat.active, bat.nbytes, bat.tokens_left,
+                           jax.random.PRNGKey(0), 0.7, 3900, 4, True, live=None, nan_inject=mask)
+    assert np.asarray(res.poison).tolist() == [1, 0] and res.rows == B
+    assert set(vars(eng)) == held  # a chunk leaves nothing new on the engine
+    bat.reset()
+    eng.release_slot(0, ok=False)
+
+
+@FF
+def test_two_chunks_in_flight_are_independent_values(ff):
+    """What overlapping chunk N's readback with chunk N+1's dispatch needs
+    (ROADMAP S4c): the second ``decode_chunk`` — another width, a poisoned
+    row — is dispatched BEFORE the first one's record is read, and the first
+    record still reads what its own chunk produced."""
+    import jax
+    import numpy as np
+
+    eng = _wide(ff)
+    bat = ContinuousBatcher(eng, chunk_steps=4, max_new_tokens=64)
+    for p in PROMPTS[:2]:
+        bat.submit(p)
+    bat.step()
+    args = (jax.random.PRNGKey(0), 0.7, 3900, 4, True)
+    first = eng.decode_chunk(bat.cur, bat.pos, bat.fsm, bat.active, bat.nbytes, bat.tokens_left, *args,
+                             live=bat._active_h)
+    mask = np.zeros((8,), dtype=bool)
+    mask[1] = True
+    second = eng.decode_chunk(first.cur, first.pos, first.fsm, first.active, first.nbytes, first.tokens_left,
+                              *args, nan_inject=mask)
+    a, b = jax.device_get(((first.n, first.fwds, first.poison), (second.n, second.fwds, second.poison)))
+    assert (first.rows, second.rows) == (2, 8) and first is not second
+    assert int(a[1]) > 0 and a[0][:2].min() > 0 and not a[2].any()
+    assert b[2].tolist() == [0, 1] + [0] * 6 and b[0][0] > 0
+    bat.reset()
+    for slot in (0, 1):
+        eng.release_slot(slot, ok=False)

@@ -14,6 +14,7 @@ Replaces the reference's OpenAI chat.completions call (apps/brain/src/llm.ts:
 from __future__ import annotations
 
 import time
+from typing import Any
 from dataclasses import dataclass, replace, field
 from functools import partial
 
@@ -89,6 +90,43 @@ class GenerationResult:
         # speculation-saturated generation can finish inside timer
         # resolution — report 0 rather than raise/inf
         return self.steps / (self.decode_ms / 1e3) if self.decode_ms > 0 else 0.0
+
+
+@dataclass(frozen=True)
+class ChunkResult:
+    """What one ``decode_chunk`` hands back, as a VALUE: a caller may hold
+    one chunk's record while it dispatches the next, and nothing of a chunk
+    is left on the engine. A field that is None is an empty pytree leaf, so
+    ONE ``jax.device_get`` over the fields a caller wants reads them all in
+    one transfer, with no placeholder for what this engine does not report.
+    The spec decoder's ``out``/``n``/``eos``/``fwds``/``poison``/``conf``
+    are host values already (its per-step readbacks paid for them)."""
+
+    # the batcher's per-slot state after the chunk, and what it emitted
+    out: Any  # (B, cap) emitted token ids, pad-filled
+    n: Any  # (B,) EMITTED tokens per row — never forwards
+    eos: Any  # (B,) the row reached EOS (truncation leaves it False)
+    cur: Any
+    pos: Any
+    fsm: Any
+    active: Any
+    nbytes: Any
+    tokens_left: Any
+    fwds: Any  # forward dispatches of the chunk: the denominator that keeps
+    # tokens-per-forward truthful when one forward emits several tokens
+    # (grammar fast-forward, speculative decoding)
+    poison: Any  # (B,) per-row fault code the quarantine evicts on:
+    # 0 ok / 1 non-finite logits / 2 grammar dead state
+    rows: int  # the width the chunk was dispatched at: ``batch_slots``,
+    # or the paged engine's ``compact_rows``
+    conf: tuple | None = None  # the ISSUE 15 per-row confidence lanes
+    # (margin sum/min, entropy sum, forced, decisions); None with them off
+    moe: Any = None  # a ROUTED model's llama.MOE_STATS summed over the
+    # chunk; None for a dense model (its chunk program has no such output)
+    # the spec decoder's per-row host counts; None on the plain loops
+    row_fwds: Any = None  # verify steps the row took part in
+    row_accepts: Any = None  # draft tokens accepted
+    row_drafted: Any = None  # draft tokens proposed
 
 
 def _mask_sample_advance(logits, fsm_state, tables: DeviceFSM, key, temperature,
@@ -620,6 +658,17 @@ class DecodeEngine:
     # cache they exist to avoid
     _alloc_dense_cache = True
 
+    # what a KV layout MAY offer the batcher, declared once so the batcher
+    # reads and calls, never probes (methods: set_slot_ns,
+    # begin_chunked_prefill, reconcile_coverage, slot_block_count)
+    radix = None  # per-dp-group radix trees (serve.radix), RADIX_ENABLE
+    allocator = None  # the KV pool's BlockAllocator
+    compact_rows = 0  # the chunk program's compacted width; 0 = it has none
+    # prefill_slot's report of its last admission (its return value is the
+    # logits alone: ROADMAP D13)
+    _last_prefill_compute_ms = None
+    _last_cached_tokens = 0
+
     def __init__(
         self,
         preset: str = "test-tiny",
@@ -1038,7 +1087,7 @@ class DecodeEngine:
 
     def decode_chunk(self, cur, pos, fsm, active, nbytes, tokens_left, key,
                      temperature: float, byte_budget: int, chunk_steps: int,
-                     greedy: bool):
+                     greedy: bool, live=None, nan_inject=None) -> ChunkResult:
         """Advance all slots by one decode chunk (the batcher's device-work
         entry point — the KV layout stays the engine's business, so the
         paged engine can substitute its pool/table loop). With fast_forward
@@ -1049,13 +1098,26 @@ class DecodeEngine:
         (serve.spec) greedy chunks route through the SpecDecoder —
         draft-K-verify-once steps, token-identical to this loop by
         construction; non-greedy chunks keep the plain path (temperature
-        speculation would need rejection sampling)."""
+        speculation would need rejection sampling).
+
+        THE CONTRACT of every layout's ``decode_chunk`` (dense, paged, pp,
+        and the SpecDecoder behind them): one signature, one ``ChunkResult``
+        back, nothing of the chunk left on the engine. ``live`` is the
+        caller's host mirror of ``active`` (a superset of it), from which a
+        layout with a compacted width chooses the chunk program's width;
+        this one has none and ignores it. ``nan_inject`` is the chaos
+        drill's (B,) bool mask for THIS chunk (None in production, and None
+        keeps the traced loop byte-identical). After consuming the record
+        the caller passes the host-fetched ``pos`` to ``reconcile_coverage``:
+        a layout that claims KV blocks for a chunk's worst case before its
+        dispatch is only clamped back to each row's actual frontier there
+        (the clamp cannot live in here: ``pos`` is a device array
+        mid-async-dispatch, and a host read would stall the chain)."""
         if self.spec is not None and greedy:
-            # the spec decoder sets _last_fwds/_last_poison itself, plus the
-            # widened per-row accept/participation readbacks (ISSUE 8)
             return self.spec.decode_chunk(
                 cur, pos, fsm, active, nbytes, tokens_left, key,
-                temperature, byte_budget, chunk_steps)
+                temperature, byte_budget, chunk_steps, greedy,
+                nan_inject=nan_inject)
         out, n, eos, self.cache, cur, pos, fsm, active, nbytes, left, fwds, \
             pois, conf = (
                 chunk_decode_loop(
@@ -1065,7 +1127,7 @@ class DecodeEngine:
                     self.byte_len_table,
                     key, jnp.float32(temperature), jnp.int32(byte_budget),
                     rules=self.rules, logit_mask=self.logit_mask,
-                    nan_inject=self._take_nan_inject(),
+                    nan_inject=nan_inject,
                     chunk_steps=chunk_steps,
                     greedy=greedy, constrained=True, kernels=self.kernels,
                     eos_id=self.eos_id, pad_id=self.pad_id,
@@ -1073,27 +1135,30 @@ class DecodeEngine:
                     quality_lanes=self.quality_lanes,
                 )
             )
-        # forward-dispatch count for the chunk (device scalar; the batcher
-        # folds it into its one combined readback): the denominator that
-        # keeps tokens-per-forward gauges truthful under multi-token steps.
-        # _last_poison rides the same transfer: per-row fault codes the
-        # scheduler's quarantine evicts on (0 ok / 1 NaN / 2 dead FSM).
-        # _last_conf: the ISSUE 15 per-row confidence lanes (margin/entropy/
-        # forced/decisions), same readback contract — None when off.
-        self._last_fwds = fwds
-        self._last_poison = pois
-        self._last_conf = conf if self.quality_lanes else None
-        return out, n, eos, cur, pos, fsm, active, nbytes, left
+        return ChunkResult(out, n, eos, cur, pos, fsm, active, nbytes, left,
+                           fwds=fwds, poison=pois, rows=self.batch_slots,
+                           conf=conf if self.quality_lanes else None)
 
-    def _take_nan_inject(self):
-        """Consume the one-shot chaos NaN mask (scheduler sets it per
-        admission under an active drill; None in production — and None
-        keeps the traced loop byte-identical)."""
-        ni = getattr(self, "_nan_inject", None)
-        if ni is None:
-            return None
-        self._nan_inject = None
-        return jnp.asarray(np.asarray(ni, dtype=bool))
+    def set_slot_ns(self, slot: int, ns: str | None) -> None:
+        """Tenant radix namespace of the slot's NEXT admission (the batcher
+        calls this right before ``prefill_slot``). Nothing to salt without a
+        radix tree."""
+
+    def begin_chunked_prefill(self, ids: list[int], slot: int,
+                              chunk_tokens: int):
+        """Start a chunked admission and return its cursor, or None when
+        this layout (or this prompt) cannot be chunked: the caller then
+        takes the one-shot ``prefill_slot``."""
+        return None
+
+    def reconcile_coverage(self, pos_h) -> None:
+        """Post-chunk hook, see ``decode_chunk``. A dense line claims
+        nothing per chunk."""
+
+    def slot_block_count(self, slot: int) -> int:
+        """KV blocks the slot holds (the cost ledger's block-time): a dense
+        row holds one, its whole KV line."""
+        return 1
 
     def release_slot(self, slot: int, generated_ids: list[int] | None = None,
                      ok: bool = True) -> None:
@@ -1122,7 +1187,6 @@ class DecodeEngine:
                     out_shardings=kv_sh)()
             else:
                 self.cache = init_kv_cache(self.cfg, self.batch_slots, self.max_len)
-        self._nan_inject = None
         if self.spec is not None:
             # drop per-slot host contexts + drafter state and bump the
             # generation fence: a decode_chunk wedged mid-flight must stop
@@ -1275,26 +1339,27 @@ class DecodeEngine:
         pois = 0
         conf_acc = None
         while True:
-            (out, n_c, eos, cur, pos, fsm, active, nbytes, left) = \
-                self.decode_chunk(cur, pos, fsm, active, nbytes, left, None,
-                                  0.0, byte_budget, chunk_steps=32,
-                                  greedy=True)
-            out_h, n_h, act_h, eos_h = jax.device_get((out, n_c, active, eos))
+            res = self.decode_chunk(cur, pos, fsm, active, nbytes, left, None,
+                                    0.0, byte_budget, chunk_steps=32,
+                                    greedy=True)
+            cur, pos, fsm, active, nbytes, left = (
+                res.cur, res.pos, res.fsm, res.active, res.nbytes,
+                res.tokens_left)
+            out_h, n_h, act_h, eos_h = jax.device_get(
+                (res.out, res.n, active, res.eos))
             out_ids.extend(int(t) for t in np.asarray(out_h)[0, : int(n_h[0])])
             finished = finished or bool(eos_h[0])
-            forwards += self.spec.last_chunk_forwards
-            lc = getattr(self, "_last_conf", None)
-            if lc is not None:
-                # per-chunk conf lanes (the spec decoder publishes host
-                # arrays): one fold rule, utils.quality.conf_fold
+            forwards += res.fwds
+            if res.conf is not None:
+                # per-chunk conf lanes (host arrays from the spec decoder):
+                # one fold rule, utils.quality.conf_fold
                 from ..utils.quality import conf_fold
 
-                conf_acc = conf_fold(conf_acc, lc)
+                conf_acc = conf_fold(conf_acc, res.conf)
             # the verify step carries the same per-row fault codes as the
             # chunk loops — surface them as the typed error generate() does
-            lp = getattr(self, "_last_poison", None)
-            if lp is not None and int(np.asarray(lp)[0]) > 0:
-                pois = int(np.asarray(lp)[0])
+            if int(res.poison[0]) > 0:
+                pois = int(res.poison[0])
                 break
             if not bool(np.asarray(act_h)[0]):
                 break

@@ -47,6 +47,7 @@ from ..utils.steplog import (
     span,
 )
 from .engine import (
+    ChunkResult,
     DecodeEngine,
     _conf_accumulate,
     _conf_init,
@@ -572,9 +573,6 @@ class PagedDecodeEngine(DecodeEngine):
 
     _alloc_dense_cache = False  # startup must never peak at the dense
     # worst-case footprint this engine exists to avoid
-    _last_moe = None  # a ROUTED engine's decode_chunk sets it: llama.MOE_STATS
-    # summed over the chunk, read back with the chunk's one readback
-    _last_rows = None  # rows the last chunk's forwards computed (its width)
 
     def __init__(self, *args, block_size: int = 128, pool_blocks: int | None = None,
                  radix_enable: bool | None = None,
@@ -1153,27 +1151,22 @@ class PagedDecodeEngine(DecodeEngine):
 
     def decode_chunk(self, cur, pos, fsm, active, nbytes, tokens_left, key,
                      temperature: float, byte_budget: int, chunk_steps: int,
-                     greedy: bool, live=None):
-        """One dispatch of up to ``chunk_steps`` constrained decode steps.
+                     greedy: bool, live=None, nan_inject=None) -> ChunkResult:
+        """One dispatch of up to ``chunk_steps`` constrained decode steps
+        (the contract: ``DecodeEngine.decode_chunk``).
 
-        ``live`` is the batcher's host mirror of ``active`` (a superset of
-        it). With 1 to ``compact_rows`` slots live the greedy chunk runs at
+        With 1 to ``compact_rows`` slots ``live`` the greedy chunk runs at
         ``compact_rows`` rows (``paged_chunk_decode_loop``'s ``rows_idx``)
         and is token-identical; otherwise, and without ``live``, at
         ``batch_slots`` through the call this always made. A sampled
         (non-greedy) chunk keeps the full width: its per-row noise is drawn
         at the batch's shape, so a row's place would change its tokens.
-        ``_last_rows`` says which width ran.
+        The record's ``rows`` says which width ran.
 
-        CALLER OBLIGATION: after consuming the chunk's results, pass the
-        returned ``pos`` (host-fetched) to ``reconcile_coverage``. The
-        worst-case (1+W)x-per-step block claim below is only clamped back
-        to the actual frontier by that hook; a driver that skips it
+        The worst-case (1+W)x-per-step block claim below is what the
+        caller's ``reconcile_coverage`` clamps back: a driver that skips it
         compounds the claim toward max_len per slot — recreating the dense
-        footprint this engine exists to avoid. (The clamp cannot live here:
-        ``pos`` is a device array mid-async-dispatch, and a host read at
-        this point would stall the chain — ContinuousBatcher reconciles
-        from the host copy it fetches anyway.)"""
+        footprint this engine exists to avoid."""
         if self.spec is not None and greedy:
             # speculative batched verify mode (ISSUE 8): chunks become
             # draft-K/verify-once steps through the SpecDecoder, each ONE
@@ -1181,12 +1174,12 @@ class PagedDecodeEngine(DecodeEngine):
             # construction, stacking on radix warm prefills. The decoder
             # claims block coverage per verify step via spec_grow (growth
             # here would over-claim chunk_steps*(1+K) positions at once);
-            # reconcile_coverage still clamps after the chunk.
-            self._last_moe = None  # only the plain chunk loop counts expert rows
-            self._last_rows = self.batch_slots
+            # reconcile_coverage still clamps after the chunk. Only the
+            # plain chunk loop counts expert rows: its ``moe`` stays None
             return self.spec.decode_chunk(
                 cur, pos, fsm, active, nbytes, tokens_left, key,
-                temperature, byte_budget, chunk_steps)
+                temperature, byte_budget, chunk_steps, greedy,
+                nan_inject=nan_inject)
         # a fast-forward chunk can emit up to (1+W) tokens per step — the
         # table must cover the worst case BEFORE dispatch (a mid-chunk
         # write past the covered blocks would scribble on the pool). The
@@ -1215,7 +1208,6 @@ class PagedDecodeEngine(DecodeEngine):
                     continue
                 self._next_pos[b] = min(self._next_pos[b] + span, self.max_len)
         rows = self._rows_of(live) if greedy else None
-        self._last_rows = self.batch_slots if rows is None else len(rows)
         # absent at the full width, so that call is the one it always was
         compact = {} if rows is None else {"rows_idx": jnp.asarray(rows)}
         out, n, eos, self.k_pool, self.v_pool, self.k_scale, self.v_scale, \
@@ -1228,7 +1220,7 @@ class PagedDecodeEngine(DecodeEngine):
                     key, jnp.float32(temperature), jnp.int32(byte_budget),
                     trash_idx=self._trash_idx, rules=self.rules,
                     logit_mask=self.logit_mask,
-                    nan_inject=self._take_nan_inject(),
+                    nan_inject=nan_inject,
                     k_scale=self.k_scale, v_scale=self.v_scale,
                     chunk_steps=chunk_steps,
                     greedy=greedy, constrained=True, kernels=self.kernels,
@@ -1238,17 +1230,12 @@ class PagedDecodeEngine(DecodeEngine):
                     **compact,
                 )
             )
-        # forward-dispatch count for the scheduler's tokens-per-forward
-        # gauge (rides its combined readback) — without it the gauge is
-        # silently absent on the paged layout while ff multi-emits there too.
-        # _last_poison rides the same readback (quarantine fault codes);
-        # _last_conf the ISSUE 15 confidence lanes (None when off).
-        self._last_fwds = fwds
-        self._last_poison = pois
-        self._last_conf = conf if self.quality_lanes else None
-        if moe:  # a routed model's expert-row counts ride the same readback
-            self._last_moe = moe[0]
-        return out, n, eos, cur, pos, fsm, active, nbytes, left
+        return ChunkResult(
+            out, n, eos, cur, pos, fsm, active, nbytes, left,
+            fwds=fwds, poison=pois,
+            rows=self.batch_slots if rows is None else len(rows),
+            conf=conf if self.quality_lanes else None,
+            moe=moe[0] if moe else None)  # a routed model's expert-row counts
 
     def spec_grow(self, span: int, active=None) -> list[int]:
         """Claim block coverage for one speculative verify step (cur + K
@@ -1338,6 +1325,9 @@ class PagedDecodeEngine(DecodeEngine):
         return False
 
     # ------------------------------------------------------------ handoff
+
+    def slot_block_count(self, slot: int) -> int:
+        return len(self._slot_shared[slot]) + len(self._slot_owned[slot])
 
     def slot_chain_blocks(self, slot: int) -> list[int]:
         """The in-order pool block chain covering ``slot``'s context —
@@ -1440,7 +1430,6 @@ class PagedDecodeEngine(DecodeEngine):
         self.block_tables = jnp.zeros(
             (self.batch_slots, self.max_blocks), jnp.int32)
         self._pressure_until = 0.0
-        self._nan_inject = None
         if self.spec is not None:
             # per-slot host contexts + drafter state are slot bookkeeping
             # too; the generation fence stops a wedged decode_chunk from

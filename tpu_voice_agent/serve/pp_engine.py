@@ -43,7 +43,7 @@ from ..parallel.pipeline import (
     stage_params,
     staged_tp_shardings,
 )
-from .engine import DecodeEngine
+from .engine import ChunkResult, DecodeEngine
 
 
 def _pp_fwd(params, cache, tokens, positions, *, cfg, mesh):
@@ -281,7 +281,7 @@ class PPDecodeEngine(DecodeEngine):
 
     def decode_chunk(self, cur, pos, fsm, active, nbytes, tokens_left, key,
                      temperature: float, byte_budget: int, chunk_steps: int,
-                     greedy: bool):
+                     greedy: bool, live=None, nan_inject=None) -> ChunkResult:
         from .engine import chunk_decode_loop
 
         # fast-forward tables when enabled: the forced-chain (B, 1+W) step
@@ -296,20 +296,16 @@ class PPDecodeEngine(DecodeEngine):
                 tables, self.byte_len_table,
                 key, jnp.float32(temperature), jnp.int32(byte_budget),
                 rules=None, logit_mask=self.logit_mask,
+                nan_inject=nan_inject,
                 chunk_steps=chunk_steps,
                 greedy=greedy, constrained=True, kernels="xla",
                 eos_id=self.eos_id, pad_id=self.pad_id,
                 fwd=self._fwd, max_len=self.max_len,
                 quality_lanes=self.quality_lanes,
             )
-        # forward-dispatch count: the scheduler's tokens-per-forward gauge
-        # reads this off the chunk's combined device_get; _last_poison
-        # carries the per-row quarantine fault codes on the same transfer
-        # (_last_conf: the ISSUE 15 confidence lanes ride it too)
-        self._last_fwds = fwds
-        self._last_poison = pois
-        self._last_conf = conf if self.quality_lanes else None
-        return out, n, eos, cur, pos, fsm, active, nbytes, left
+        return ChunkResult(out, n, eos, cur, pos, fsm, active, nbytes, left,
+                           fwds=fwds, poison=pois, rows=self.batch_slots,
+                           conf=conf if self.quality_lanes else None)
 
     def generate(self, *a, **kw):
         # the parent's generate() drives chunk_decode_loop with the dense

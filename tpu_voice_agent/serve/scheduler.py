@@ -37,7 +37,12 @@ import numpy as np
 from ..models.llama import MOE_STATS
 from ..utils.compilewatch import watch_compiles
 from ..utils.steplog import ALLOC_SPAN, REQUEST_SPAN, span
-from .engine import DecodeEngine, GenerationResult, _mask_sample_advance
+from .engine import (
+    ChunkResult,
+    DecodeEngine,
+    GenerationResult,
+    _mask_sample_advance,
+)
 from .paged import PoolExhausted
 
 try:  # device faults must PROPAGATE out of per-request fences (a corrupted
@@ -248,10 +253,8 @@ class ContinuousBatcher:
             m.inc("tenant.preemptions", 0.0)
             # per-tenant radix namespaces: the trees charge over-quota
             # inserts to the owning tenant's own leaves (serve.radix)
-            radix = getattr(engine, "radix", None)
-            if radix is not None:
-                for rc in radix:
-                    rc.ns_quota = self.tenancy.block_quota
+            for rc in engine.radix or ():
+                rc.ns_quota = self.tenancy.block_quota
 
     # ------------------------------------------------------------ warm-up
 
@@ -277,8 +280,8 @@ class ContinuousBatcher:
             finally:
                 eng.release_slot(0, ok=False)
         rid = self.submit(prefix + [eng.pad_id] * 8)
-        self.step()
-        if (getattr(eng, "_last_rows", None) or self.B) != self.B:
+        res = self.step()
+        if res is not None and res.rows != self.B:
             # that lone request rode the compacted width: RUN the full one
             # too, on the same row, for the one forward a token budget of 1
             # allows (a program entered with nothing live runs no forward,
@@ -541,9 +544,8 @@ class ContinuousBatcher:
                 # are salted with the resolved class name so one tenant's
                 # churn cannot evict another's warm chains (serve.radix)
                 with span(f"{REQUEST_SPAN}.bookkeeping"):
-                    setns = getattr(eng, "set_slot_ns", None)
-                    if setns is not None:
-                        setns(slot, self.tenancy.resolve(self._tenant.get(rid)))
+                    eng.set_slot_ns(
+                        slot, self.tenancy.resolve(self._tenant.get(rid)))
             t0 = time.perf_counter()
             with span(f"{REQUEST_SPAN}.tokenize"):
                 ids = (eng.tokenizer.encode(prompt, bos=True)
@@ -552,28 +554,26 @@ class ContinuousBatcher:
             req.set(prompt_tokens=n)
             C = self._prefill_chunk
             if C > 0 and n > C:
-                begin = getattr(eng, "begin_chunked_prefill", None)
-                if begin is not None:
-                    with span(ALLOC_SPAN):
-                        cursor = begin(ids, slot, C)
-                    if cursor is not None:
-                        sl = self.slots[slot]
-                        sl.request_id = rid
-                        sl.token_ids = []
-                        sl.start_s = t0
-                        sl.prompt_len = n
-                        sl.eos = False
-                        # the enqueue stamp travels with the cursor: TTFT
-                        # still covers queue wait + every interleaved
-                        # prefill chunk (and the queue wait its own number)
-                        self._admitting[slot] = (
-                            cursor, self._enqueued_at.pop(rid, t0), queue_ms,
-                            self._stage_slot(slot, n))
-                        from ..utils import get_metrics as _gm
+                with span(ALLOC_SPAN):
+                    cursor = eng.begin_chunked_prefill(ids, slot, C)
+                if cursor is not None:
+                    sl = self.slots[slot]
+                    sl.request_id = rid
+                    sl.token_ids = []
+                    sl.start_s = t0
+                    sl.prompt_len = n
+                    sl.eos = False
+                    # the enqueue stamp travels with the cursor: TTFT
+                    # still covers queue wait + every interleaved
+                    # prefill chunk (and the queue wait its own number)
+                    self._admitting[slot] = (
+                        cursor, self._enqueued_at.pop(rid, t0), queue_ms,
+                        self._stage_slot(slot, n))
+                    from ..utils import get_metrics as _gm
 
-                        _gm().inc("prefill.chunked_admissions")
-                        req.drop()  # the admission lands with its last chunk
-                        return True
+                    _gm().inc("prefill.chunked_admissions")
+                    req.drop()  # the admission lands with its last chunk
+                    return True
             slot_n = self._stage_slot(slot, n)
             last_logits = eng.prefill_slot(ids, slot)
             self._finish_admission(slot, rid, n, slot_n, last_logits, t0,
@@ -625,9 +625,8 @@ class ContinuousBatcher:
         # prefill_ms = COMPUTED suffix dispatch only (the old wall-clock
         # number conflated cached-prefix bookkeeping with real forward
         # time); cached_tokens carries the part the cache absorbed
-        _pf = getattr(eng, "_last_prefill_compute_ms", None)
-        sl.prefill_ms = _pf if _pf is not None else (time.perf_counter() - t0) * 1e3
-        sl.cached_tokens = int(getattr(eng, "_last_cached_tokens", 0))
+        sl.prefill_ms = eng._last_prefill_compute_ms
+        sl.cached_tokens = int(eng._last_cached_tokens)
         sl.queue_ms = queue_ms
         sl.eos = False
         # TTFT: ENQUEUE through the first sampled token — queue wait
@@ -730,7 +729,7 @@ class ContinuousBatcher:
         m = get_metrics()
         m.inc("prefill.feeds")
         eng = self.engine
-        if getattr(eng, "radix", None) is None:
+        if eng.radix is None:
             return {"ok": False, "reason": "radix_off"}
         if self.pending:
             m.inc("prefill.feeds_shed")
@@ -740,9 +739,7 @@ class ContinuousBatcher:
             m.inc("prefill.feeds_shed")
             return {"ok": False, "reason": "no_slot"}
         if self.tenancy is not None:
-            setns = getattr(eng, "set_slot_ns", None)
-            if setns is not None:
-                setns(slot, self.tenancy.resolve(tenant))
+            eng.set_slot_ns(slot, self.tenancy.resolve(tenant))
         ids = (eng.tokenizer.encode(prompt, bos=True)
                if isinstance(prompt, str) else [int(t) for t in prompt])
         try:
@@ -762,7 +759,7 @@ class ContinuousBatcher:
             except Exception:
                 pass
             return {"ok": False, "reason": f"{type(e).__name__}: {e}"}
-        cached = int(getattr(eng, "_last_cached_tokens", 0))
+        cached = int(eng._last_cached_tokens)
         # generated_ids=[] (not None): release's ok-path radix insert fires
         # with the fed prompt alone — the tree adopts its full blocks, so
         # everything is either cached or freed before this call returns
@@ -800,7 +797,7 @@ class ContinuousBatcher:
         m = get_metrics()
         m.inc("disagg.exports")
         eng = self.engine
-        if getattr(eng, "radix", None) is None:
+        if eng.radix is None:
             m.inc("disagg.exports_shed")
             return {"ok": False, "reason": "radix_off"}
         if self.pending:
@@ -811,9 +808,7 @@ class ContinuousBatcher:
             m.inc("disagg.exports_shed")
             return {"ok": False, "reason": "no_slot"}
         if self.tenancy is not None:
-            setns = getattr(eng, "set_slot_ns", None)
-            if setns is not None:
-                setns(slot, self.tenancy.resolve(tenant))
+            eng.set_slot_ns(slot, self.tenancy.resolve(tenant))
         ids = (eng.tokenizer.encode(prompt, bos=True)
                if isinstance(prompt, str) else [int(t) for t in prompt])
         bs = eng.block_size
@@ -867,7 +862,7 @@ class ContinuousBatcher:
             m.inc("disagg.exports_shed")
             return {"ok": False, "reason": f"{type(e).__name__}: {e}",
                     "segments": segments}
-        cached = int(getattr(eng, "_last_cached_tokens", 0))
+        cached = int(eng._last_cached_tokens)
         try:
             _ship(ship_cap, final=True)
         except Exception:
@@ -881,8 +876,10 @@ class ContinuousBatcher:
 
     # ------------------------------------------------------------ step
 
-    def step(self) -> None:
-        """Admit pending requests into free slots, then run one chunk.
+    def step(self) -> ChunkResult | None:
+        """Admit pending requests into free slots, then run one chunk, and
+        return that chunk's record (None: nothing was live, or the watchdog
+        restarted the world under the step).
 
         Containment happens at the chunk boundaries: expired requests are
         shed at dequeue (``scheduler.shed_expired``) and cancelled between
@@ -900,7 +897,7 @@ class ContinuousBatcher:
             # already warm-restarted the world — this step must vanish.
             time.sleep(float(os.environ.get("CHAOS_STALL_S", "2.0")))
             if epoch != self._epoch:
-                return
+                return None
 
         # the step ledger (ISSUE 9): one StepTimer per scheduler step, a
         # ``sched.step`` on the profiler's trace whose four contiguous
@@ -909,11 +906,11 @@ class ContinuousBatcher:
         # byte-identical either way.
         timer = get_steplog().timer()
         try:
-            self._step(timer, epoch)
+            return self._step(timer, epoch)
         finally:
             timer.close()  # a step that raised or returned early
 
-    def _step(self, timer, epoch: int) -> None:
+    def _step(self, timer, epoch: int) -> ChunkResult | None:
         from ..utils import get_metrics
         from ..utils.chaos import chaos_fire
 
@@ -1078,7 +1075,7 @@ class ContinuousBatcher:
                 # faults): still a step that spent wall time, during
                 # exactly the overload churn an autopsy needs — record it
                 timer.finish(occupancy=0, tokens=0, admitted=n_admitted)
-            return
+            return None
 
         # the engine's layout-kernel calls were stage spans of their own
         # INSIDE the admission stage (``sched.admit.prefill``, what
@@ -1088,70 +1085,46 @@ class ContinuousBatcher:
         # jitted call alone), not a stage
         timer.stage("sched.decode_dispatch")
         eng = self.engine
+        nan_inject = None  # the chaos drill's one-shot mask, THIS chunk's
         if self._nan_slots:
-            mask = np.zeros((self.B,), dtype=bool)
-            for b in self._nan_slots:
-                mask[b] = True
-            eng._nan_inject = mask
+            nan_inject = np.zeros((self.B,), dtype=bool)
+            nan_inject[list(self._nan_slots)] = True
             self._nan_slots.clear()
         t_chunk0 = time.perf_counter()
         occupancy = int(act.sum())  # slots riding THIS chunk's dispatches
-        # stale-readback fence: the spec decoder publishes per-row accept/
-        # participation arrays; a chunk that takes the plain loop instead
-        # (non-greedy, spec off) must not re-serve the previous chunk's
-        eng._last_accepts = None
-        eng._last_row_fwds = None
-        eng._last_row_drafted = None
         self._rng, k = jax.random.split(self._rng)
-        # a paged engine takes the chunk program's width from the live rows
-        # (ISSUE 29): few enough of them ride a compacted program
-        width = {"live": act} if getattr(eng, "compact_rows", 0) else {}
-        (out, n, eos, cur, pos, fsm, active,
-         nbytes, tokens_left) = eng.decode_chunk(
+        # ``live``: few enough live rows ride a compacted program (ISSUE 29)
+        res = eng.decode_chunk(
             self.cur, self.pos, self.fsm, self.active, self.nbytes,
             self.tokens_left, k, self.temperature, self.byte_budget,
-            self.chunk_steps, self.greedy, **width,
+            self.chunk_steps, self.greedy, live=act, nan_inject=nan_inject,
         )
-        rows = getattr(eng, "_last_rows", None) or self.B
         timer.stage("sched.readback")
         # one transfer for everything the host needs this chunk (a combined
-        # device_get is ONE host<->device sync; separate gets pay one each).
-        # _last_fwds (engines that report it) rides the same transfer: the
-        # chunk's forward-dispatch count, the denominator that keeps
-        # tokens-per-forward truthful under multi-token steps (grammar
-        # fast-forward / speculative decoding emit several accepted tokens
-        # per forward — counting dispatches as tokens would inflate every
-        # throughput gauge). _last_poison rides it too: per-row fault codes
-        # for the quarantine below.
-        fwds = getattr(eng, "_last_fwds", None)
-        pois = getattr(eng, "_last_poison", None)
-        conf = getattr(eng, "_last_conf", None)
-        moe = getattr(eng, "_last_moe", None)  # a routed model's expert-row counts
+        # device_get is ONE host<->device sync; separate gets pay one each;
+        # a field the engine does not report is None, an empty leaf).
+        # ``fwds`` keeps tokens-per-forward truthful under multi-token steps
+        # (counting dispatches as tokens would inflate every throughput
+        # gauge); ``poison`` is the quarantine's per-row fault codes below.
         out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h, moe_h = (
-            jax.device_get(
-                (out, n, active, eos, pos,
-                 0 if fwds is None else fwds,
-                 0 if pois is None else pois,
-                 0 if conf is None else conf,
-                 0 if moe is None else moe))
-        )
-        out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h = (
-            np.asarray(x) for x in (out_h, n_h, act_h, eos_h, pos_h, fwds_h,
-                                    pois_h))
+            jax.device_get((res.out, res.n, res.active, res.eos, res.pos,
+                            res.fwds, res.poison, res.conf, res.moe)))
+        out_h, n_h, act_h, eos_h, pos_h, pois_h = (
+            np.asarray(x) for x in (out_h, n_h, act_h, eos_h, pos_h, pois_h))
+        fwds_h, rows = int(fwds_h), res.rows
         timer.stage("sched.release")
         if epoch != self._epoch:
             # the watchdog warm-restarted the engine while this step was
             # stalled in flight: its world is gone — committing the chunk's
             # state would scribble stale arrays over the fresh one
-            return
+            return None
         (self.cur, self.pos, self.fsm, self.active, self.nbytes,
-         self.tokens_left) = cur, pos, fsm, active, nbytes, tokens_left
+         self.tokens_left) = (res.cur, res.pos, res.fsm, res.active,
+                              res.nbytes, res.tokens_left)
         self._active_h = np.array(act_h)
         # paged engines clamp their block-growth targets to the actual
         # frontier (the ff worst-case claim must not compound per chunk)
-        reconcile = getattr(eng, "reconcile_coverage", None)
-        if reconcile is not None:
-            reconcile(pos_h)
+        eng.reconcile_coverage(pos_h)
 
         # ACCEPTED/emitted tokens, never verify steps or forward dispatches:
         # `n` is the per-row emitted count in every engine layout (plain,
@@ -1159,13 +1132,13 @@ class ContinuousBatcher:
         # one forward emits several tokens
         m.inc("scheduler.tokens_generated", float(n_h.sum()))
         m.inc("scheduler.chunks")
-        if fwds is not None and fwds_h > 0:
+        if fwds_h > 0:
             m.inc("scheduler.forwards", float(fwds_h))
             # rows COMPUTED: forwards at the width this chunk was dispatched
             m.inc("scheduler.forward_rows", float(fwds_h) * rows)
             m.set_gauge("scheduler.tokens_per_forward",
                         float(n_h.sum()) / float(fwds_h))
-        if moe is not None:
+        if moe_h is not None:
             # summed over the chunk's forwards and layers: per forward they
             # are these over scheduler.forwards (docs/OBSERVABILITY.md)
             for name, v in zip(MOE_STATS, np.asarray(moe_h)):
@@ -1183,16 +1156,14 @@ class ContinuousBatcher:
             self._tps_ema = inst if self._tps_ema == 0.0 \
                 else 0.8 * self._tps_ema + 0.2 * inst
             m.set_gauge("scheduler.tokens_per_s", self._tps_ema)
-        alloc = getattr(eng, "allocator", None)
-        if alloc is not None:
+        if eng.allocator is not None:
             from .paged import record_pool_gauges
 
-            record_pool_gauges(alloc, engine=eng)
-        radix = getattr(eng, "radix", None)
-        if radix is not None:
+            record_pool_gauges(eng.allocator, engine=eng)
+        if eng.radix is not None:
             from .radix import record_radix_gauges
 
-            record_radix_gauges(radix)
+            record_radix_gauges(eng.radix)
         if plane is not None:
             # tenant.* occupancy/share/SLO gauges ride the TS rings and the
             # fleet plane automatically once set here (ISSUE 18)
@@ -1212,12 +1183,11 @@ class ContinuousBatcher:
         # transfer for, folded into per-REQUEST accounting so batched
         # results carry an honest ``forwards`` (steps/forwards IS the
         # request's speculation multiplier) and ``spec_accepted``
-        row_fwds = getattr(eng, "_last_row_fwds", None)
-        row_accepts = getattr(eng, "_last_accepts", None)
+        row_fwds, row_accepts = res.row_fwds, res.row_accepts
         # ISSUE 15 conf lanes: per-row (margin_sum, margin_min, entropy_sum,
         # forced, decisions) folded into per-request accounting so finished
         # results carry an honest quality vector
-        conf_arr = None if conf is None else [np.asarray(x) for x in conf_h]
+        conf_arr = None if conf_h is None else [np.asarray(x) for x in conf_h]
 
         # cost fold (ISSUE 17): one per-row ledger dict per chunk, computed
         # from readbacks already paid for. Positions computed: spec rows
@@ -1228,14 +1198,11 @@ class ContinuousBatcher:
         # KV block-time: paged rows hold owned + shared blocks for the
         # chunk wall; dense rows hold 1 "block" (their whole KV line).
         costs = self.costs
-        row_drafted = getattr(eng, "_last_row_drafted", None)
-        owned = getattr(eng, "_slot_owned", None)
-        shared = getattr(eng, "_slot_shared", None)
+        row_drafted = res.row_drafted
         chunk_us = int(round(chunk_s * 1e6))
         chunk_flops = 0
         chunk_kv_bytes = 0
 
-        pois_arr = None if pois is None else pois_h
         for b in range(self.B):
             sl = self.slots[b]
             if sl.request_id < 0:
@@ -1266,11 +1233,7 @@ class ContinuousBatcher:
                     if w_pos:
                         wasted = costs.model.decode_row(
                             w_pos, int(pos_h[b]))[0]
-                if owned is not None and shared is not None:
-                    blocks = len(owned[b]) + len(shared[b])
-                else:
-                    blocks = 1
-                kv_us = chunk_us * blocks
+                kv_us = chunk_us * eng.slot_block_count(b)
                 sl.cost["decode_flops"] += fl
                 sl.cost["decode_bytes"] += by
                 sl.cost["wasted_draft_flops"] += wasted
@@ -1280,14 +1243,14 @@ class ContinuousBatcher:
                                 "kv_block_us": kv_us})
                 chunk_flops += fl
                 chunk_kv_bytes += by
-            if pois_arr is not None and int(pois_arr[b]) > 0:
+            if int(pois_h[b]) > 0:
                 # poison-request quarantine: the loop fenced this row off
                 # mid-chunk (non-finite logits / dead FSM state) without
                 # touching batch-mates. Evict the slot with a typed error,
                 # free its KV refs WITHOUT radix insertion, count the
                 # offense against the prompt, and freeze a flight-recorder
                 # dump — every contained incident leaves evidence.
-                reason = ("non-finite logits" if int(pois_arr[b]) == 1
+                reason = ("non-finite logits" if int(pois_h[b]) == 1
                           else "grammar dead state")
                 self._record_offense(sl.request_id, reason)
                 self._evict_slot(b, f"poisoned: {reason}",
@@ -1362,8 +1325,7 @@ class ContinuousBatcher:
         # dispatch, batch-shared, metered engine-side)
         if costs is not None:
             try:
-                costs.chunk(chunk_flops, chunk_kv_bytes,
-                            int(fwds_h) if fwds is not None else 0, chunk_s)
+                costs.chunk(chunk_flops, chunk_kv_bytes, fwds_h, chunk_s)
             except Exception:
                 pass  # metering must never become a serving fault
         timer.finish(
@@ -1371,10 +1333,11 @@ class ContinuousBatcher:
             rows=rows,
             tokens=int(n_h.sum()),
             admitted=n_admitted or None,
-            forwards=int(fwds_h) if fwds is not None else None,
+            forwards=fwds_h,
             accepted=(int(np.sum(row_accepts)) if row_accepts is not None
                       else None),
         )
+        return res
 
     # ------------------------------------------------------------ drain
 

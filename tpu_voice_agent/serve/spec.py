@@ -65,7 +65,6 @@ from __future__ import annotations
 
 import json
 import threading
-import time
 from dataclasses import dataclass, replace
 from functools import partial
 
@@ -79,6 +78,7 @@ from ..utils.compilewatch import watch_compiles
 from ..utils.envcfg import env_bool, env_int, env_str
 from ..utils.steplog import span
 from .engine import (
+    ChunkResult,
     _conf_init,
     _conf_stats,
     _masked_conf,
@@ -264,7 +264,7 @@ def _verify_commit(logits, cur, pos, fsm_state, active, nbytes, tokens_left,
         # one verified decision (accepted drafts ARE the target's masked
         # greedy pick; position a is the bonus), scored at its own FSM
         # state — the dense/paged chunk loops and this verify path share
-        # one readback contract like ``_last_fwds``. ``conf_pos`` was
+        # one readback contract (``ChunkResult.conf``). ``conf_pos`` was
         # computed above on the masked logits the greedy pick already
         # built; rejected positions (i > a) mask out of the fold here.
         msum, mmin, esum, forced, cnt = conf
@@ -789,7 +789,7 @@ class SpecDecoder:
     """
 
     def __init__(self, engine, cfg: SpecConfig, drafter: Drafter | None = None):
-        self.paged = getattr(engine, "k_pool", None) is not None
+        self.paged = engine.allocator is not None
         if not engine._alloc_dense_cache and not self.paged:
             raise ValueError(
                 "speculative decoding needs per-position KV rollback: the "
@@ -802,11 +802,10 @@ class SpecDecoder:
         self.K = max(1, int(cfg.k))
         # ISSUE 15: the verify steps carry the same conf lanes as the
         # chunk loops (one readback contract across planes)
-        self.quality_lanes = bool(getattr(engine, "quality_lanes", False))
+        self.quality_lanes = engine.quality_lanes
         self.drafter = drafter if drafter is not None else build_drafter(cfg, engine)
         self._ctx: list[list[int] | None] = [None] * engine.batch_slots
         self._prompt_len = [0] * engine.batch_slots
-        self.last_chunk_forwards = 0
         # cumulative accounting behind the spec.* gauges
         self._drafted = 0
         self._accepted = 0
@@ -918,30 +917,30 @@ class SpecDecoder:
                 a, dl, pois, conf)
 
     def decode_chunk(self, cur, pos, fsm, active, nbytes, tokens_left, key,
-                     temperature: float, byte_budget: int, chunk_steps: int):
-        """Drop-in for the engine's decode_chunk (greedy constrained only;
-        the engine gates). Returns the same 9-tuple; ``out``/``n``/``eos``
-        come back as host arrays (the per-step readbacks already paid).
-        Besides ``_last_fwds``/``_last_poison`` the readback widens to
-        per-row accept counts (``_last_accepts``) and per-row verify
-        participation (``_last_row_fwds``) — the scheduler folds them into
-        per-request ``GenerationResult.forwards`` and the spec gauges
-        reflect paged-plane traffic through the same counters."""
+                     temperature: float, byte_budget: int, chunk_steps: int,
+                     greedy: bool, live=None, nan_inject=None) -> ChunkResult:
+        """Drop-in for the engine's decode_chunk, under its contract and
+        signature; greedy constrained only (the engine gates: ``key``,
+        ``temperature`` and ``live`` go unused). In the record ``out``/
+        ``n``/``eos``/``fwds``/``poison``/``conf`` are host values (the
+        per-step readbacks already paid), and it widens to per-row verify
+        participation, accept and draft counts (``row_fwds``,
+        ``row_accepts``, ``row_drafted``) — the scheduler folds them into
+        per-request ``GenerationResult.forwards`` and the cost ledger, and
+        the spec gauges reflect paged-plane traffic through the same
+        counters. The chaos mask injects at the chunk's first verify step,
+        exactly like the plain loops' one-shot mask."""
+        if not greedy:
+            raise ValueError("the spec decoder verifies greedy picks only")
         eng = self.engine
         B = eng.batch_slots
         K = self.K
         gen0 = self._gen
-        nan_inject = eng._take_nan_inject()  # chaos drill parity: the
-        # scheduler arms the mask per admission; the first verify step of
-        # the chunk injects, exactly like the plain loops' one-shot mask
         cur_h, fsm_h, act_h = (np.asarray(x) for x in
                                jax.device_get((cur, fsm, active)))
         eos_total = (~act_h) & (cur_h == eng.eos_id)
         outs: list[list[int]] = [[] for _ in range(B)]
         fwds = 0
-        draft_ms = 0.0  # host drafter share of the chunk wall (the step
-        # ledger's "drafter time" — drafting is the host-side cost the
-        # verify speedup pays for, so it gets its own ledger line)
         drafted = accepted = 0
         row_fwds = np.zeros((B,), np.int64)
         row_accepts = np.zeros((B,), np.int64)
@@ -959,10 +958,10 @@ class SpecDecoder:
                 if act_h[b] and self._ctx[b] is not None else None
                 for b in range(B)
             ]
-            t_d0 = time.perf_counter()
-            with span("sched.decode.draft"):  # the step ledger's draft stage
+            # the step ledger's draft stage: drafting is the host-side cost
+            # the verify speedup pays for, so it gets its own ledger line
+            with span("sched.decode.draft"):
                 dtoks, dlen = self.drafter.draft_batch(ctxs, fsm_h, act_h, K)
-            draft_ms += (time.perf_counter() - t_d0) * 1e3
             dlen = np.minimum(np.asarray(dlen, np.int32), K)
             if self._gen != gen0:
                 # draft_batch is a host-blocking point (draft-model feeds
@@ -1032,28 +1031,6 @@ class SpecDecoder:
             out_arr[b, : len(o)] = o
             n_arr[b] = len(o)
 
-        self.last_chunk_forwards = fwds
-        self.last_chunk_draft_ms = draft_ms
-        eng._last_fwds = fwds
-        # the widened readback (satellite 2): per-row fault codes for the
-        # scheduler's quarantine (a poisoned verify row evicts alone), and
-        # per-row accept/participation counts for per-request accounting
-        eng._last_poison = poison_h
-        eng._last_accepts = row_accepts
-        eng._last_row_fwds = row_fwds
-        # per-row drafted counts (ISSUE 17): the cost ledger's
-        # wasted-draft lane is (drafted - accepted) x per-token FLOPs
-        eng._last_row_drafted = row_drafted
-        # the ISSUE 15 conf readback contract, spec plane: same tuple shape
-        # as the chunk loops publish, already host-side here (a chunk that
-        # ran zero verify steps publishes fresh zero lanes)
-        if self.quality_lanes:
-            eng._last_conf = tuple(
-                conf_acc if conf_acc is not None else
-                (np.zeros((B,)), np.full((B,), np.inf), np.zeros((B,)),
-                 np.zeros((B,), np.int64), np.zeros((B,), np.int64)))
-        else:
-            eng._last_conf = None
         self._steps += fwds
         self._drafted += drafted
         self._accepted += accepted
@@ -1069,8 +1046,16 @@ class SpecDecoder:
                 m.set_gauge("spec.accept_rate", self._accepted / self._drafted)
             if self._steps > 0:
                 m.set_gauge("spec.tokens_per_step", self._emitted / self._steps)
-        return (out_arr, n_arr, eos_total, cur, pos, fsm, active, nbytes,
-                tokens_left)
+        # a chunk that ran zero verify steps reports fresh zero conf lanes
+        conf = None if not self.quality_lanes else tuple(
+            conf_acc if conf_acc is not None else
+            (np.zeros((B,)), np.full((B,), np.inf), np.zeros((B,)),
+             np.zeros((B,), np.int64), np.zeros((B,), np.int64)))
+        return ChunkResult(
+            out_arr, n_arr, eos_total, cur, pos, fsm, active, nbytes,
+            tokens_left, fwds=fwds, poison=poison_h, rows=B, conf=conf,
+            row_fwds=row_fwds, row_accepts=row_accepts,
+            row_drafted=row_drafted)
 
     # ------------------------------------------------------------ stats
 

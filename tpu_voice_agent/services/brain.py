@@ -533,10 +533,10 @@ class BatchedEngineParser:
         b = self.batcher
         out = {"scheduler.batch_occupancy":
                sum(1 for s in b.slots if s.request_id >= 0) / max(1, b.B)}
-        alloc = getattr(self.engine, "allocator", None)
+        alloc = self.engine.allocator
         if alloc is not None:
             used = alloc.blocks_in_use
-            radix = getattr(self.engine, "radix", None)
+            radix = self.engine.radix
             if radix:
                 # a warm radix cache drifts raw utilization toward 1.0 BY
                 # DESIGN (released chains keep tree refs; _alloc reclaims
@@ -1764,7 +1764,7 @@ def _wrap_batched(engine) -> "BatchedEngineParser":
         install_prompt_prefix(engine)
     return BatchedEngineParser(engine,
                                chunk_steps=int(os.environ.get("BRAIN_CHUNK", "16")),
-                               session_aware=getattr(engine, "radix", None) is not None)
+                               session_aware=engine.radix is not None)
 
 
 def _wrap_engine(engine) -> IntentParser:

@@ -16,8 +16,8 @@ SLO-gated signal on every utterance:
   ``stt.confidence_repetition`` and fed here by the voice service per
   final transcript.
 - **Intent confidence** — the grammar-constrained decode tail (dense,
-  paged, and spec-verify planes share one readback contract like
-  ``_last_fwds``) reports masked-logit margin and entropy per accepted
+  paged, and spec-verify planes share one readback contract,
+  ``ChunkResult.conf``) reports masked-logit margin and entropy per accepted
   decision plus the grammar-forced-token fraction; the brain feeds them
   here per parse, with degraded/downgraded parses counted structurally.
 - **Execution feedback** — executor action verdicts become weak labels
