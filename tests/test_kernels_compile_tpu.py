@@ -262,6 +262,23 @@ def test_paged_attention_compiles_at_group_one(tpu_devices):
              *_pool(N, OLMOE), tables, ((B,), I32), layer, interpret=False)
 
 
+@pytest.mark.parametrize("B,geom", [(32, LLAMA3_8B), (32, OLMOE), (8, LLAMA3_8B)],
+                         ids=["parse_flood", "olmoe_flood", "parse_solo"])
+def test_paged_block_attention_common_pass_compiles_at_the_cells_shapes(tpu_devices, B, geom):
+    """The block kernel with its common pass (ISSUE 31) as the three cells run
+    it: (rows, 9 queries, q / kv heads of 128) = (32, 9, 32 / 8) Mistral's
+    full width, (32, 9, 16 / 16) OLMoE's, (8, 9, 32 / 8) the compacted width,
+    over the benchmark's 200-block pool and 12-column tables, with the write
+    mask handed down. Its dynamic grid, the VMEM it asks for beyond the
+    default (every row's statistics stay resident) and the dynamic sublane
+    slices are what interpret mode cannot refuse and Mosaic can."""
+    nq, nkv, hd, L = geom
+    N, blocks = 200, 12
+    _compile(tpu_devices, ops.paged_block_attention, ((B, FF_T, nq, hd), BF16),
+             *_pool(N, geom), ((B, blocks), I32), ((B, FF_T), I32), ((), I32),
+             ((B,), jnp.bool_), interpret=False)
+
+
 @pytest.mark.parametrize("config", ["mistral-7b-v0.1-int8", "olmoe-1b-7b-0125-int8"])
 def test_the_compacted_chunk_program_compiles_at_published_widths(tpu_devices, monkeypatch, config):
     """The whole decode chunk at its COMPACTED width (ISSUE 29: 8 of 32 slots'

@@ -378,15 +378,17 @@ def _chunk_spy(monkeypatch) -> tuple[list[int], list[str]]:
     return seen, texts
 
 
-# sha256 of the full-width chunk program's StableHLO text as commit 884eedd
-# (PR 28, before the compacted width existed) lowers it for the two engines
+# sha256 of the full-width chunk program's StableHLO text for the two engines
 # of the fence test. ISSUE 29's fence for the flood cells: ``rows_idx`` absent
-# is an empty pytree leaf and the program is the parent's byte for byte. A
-# PR that changes the loop on purpose re-derives these (lower the first
+# is an empty pytree leaf, so the compacted width adds nothing to this program.
+# Until PR 31 they were the values commit 884eedd (PR 28) lowers; ISSUE 31
+# changed the loop on purpose — one more carry and output in BOTH variants,
+# the attention row-block counts — and re-derived them as prescribed here:
+# a PR that changes the loop on purpose re-derives these (lower the first
 # ``decode_chunk`` dispatch as ``_chunk_spy`` does and hash it) and says so.
 FULL_WIDTH_SHA256 = {
-    "dense": "3f3e27ae17b72ad070239e5e55f2e5060102b8188b2375b6140001d57edb9921",
-    "routed": "ba34716538dbd9cb700b275de0c818804f4faa7e0cacae3a9773ef49fec12a64",
+    "dense": "89e0f7a309f21f7b47c61d7f072874fca4192bc8a79f9f25ccfced98da7fe588",
+    "routed": "2b0ba7a92de12af9e351cc831b4e550ea1420c0e95445fcd4e976ac32adb1580",
 }
 
 
@@ -395,8 +397,9 @@ def test_the_fence_around_the_dense_path(model, monkeypatch):
     """What a configuration with ``n_experts == 0`` may not see of this
     block (ISSUE 28: PR 27 was refused for a slower DENSE cell). A dense
     ``test-tiny`` engine behind the batcher: no ``moe.*`` metric of any kind
-    is registered, every chunk's record has ``moe`` None, the chunk program returns the
-    16 values it always did, and the tokens are those of the un-paged
+    is registered, every chunk's record has ``moe`` None, the chunk program returns
+    17 values (16 until ISSUE 31 added the attention row-block counts to both
+    variants), and the tokens are those of the un-paged
     ``DecodeEngine`` (whose loop this block never touched). The routed
     variant of the same program returns one more, and the four counters
     rise. Since ISSUE 29 the fence holds the compacted width out too: at the
@@ -427,11 +430,11 @@ def test_the_fence_around_the_dense_path(model, monkeypatch):
     moe_names = sorted(k for part in snap.values() if isinstance(part, dict) for k in part
                        if str(k).startswith("moe."))
     if model == "routed":
-        assert set(arity) == {17} and all(c.moe.shape == (4,) for c in chunks)
+        assert set(arity) == {18} and all(c.moe.shape == (4,) for c in chunks)
         assert moe_names == sorted(f"moe.{n}" for n in llama.MOE_STATS)
         assert all(snap["counters"][k] > 0 for k in moe_names)
         return
-    assert set(arity) == {16} and moe_names == []
+    assert set(arity) == {17} and moe_names == []
     assert all(c.moe is None for c in chunks)
     assert {k for k in vars(eng) if k.startswith("_last_")} <= {"_last_prefill_compute_ms", "_last_cached_tokens"}
     plain = DecodeEngine(preset="test-tiny", max_len=1536, prefill_buckets=(128, 256, 1024))

@@ -170,3 +170,80 @@ def test_engines_refuse_interpreted_pallas_unless_the_cpu_was_asked_for(monkeypa
         backend.measurement_devices()
     with pytest.raises(ValueError, match="unknown kernels"):
         backend.resolve_kernels("mosaic")
+
+
+# ------------------------------------------------- the paged block kernel
+
+# the common pass of the block kernel (ISSUE 31). Tables of 6 columns over
+# 16-token blocks; three rows behind a shared prefix of blocks 1, 2, 3 unless
+# the case says otherwise; T = 9 queries a row starting at ``pos``.
+_PREFIX = [1, 2, 3]
+_TWO_PASS_CASES = {
+    # name: (tables, first query position a row, live rows or None, S, riders)
+    "all rows ride": ([_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0], _PREFIX + [7, 8, 0]],
+                      [60, 50, 70], None, 3, [1, 1, 1]),
+    "one row with a different first block": (
+        [_PREFIX + [4, 5, 0], [9, 10, 11, 6, 0, 0], _PREFIX + [7, 8, 0]],
+        [60, 50, 70], None, 3, [1, 0, 1]),
+    "an idle row among live ones": (
+        [_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0], _PREFIX + [7, 8, 0]],
+        [60, 0, 70], [True, False, True], 3, [1, 0, 1]),
+    "smallest position on a block edge": (
+        [_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0], _PREFIX + [7, 8, 0]],
+        [48, 50, 70], None, 3, [1, 1, 1]),
+    "smallest position one under a block edge": (
+        [_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0], _PREFIX + [7, 8, 0]],
+        [47, 50, 70], None, 2, [1, 1, 1]),
+    "one live row of eight": ([_PREFIX + [4, 5, 0]] + [[0] * 6] * 7, [60] + [0] * 7,
+                              [True] + [False] * 7, 3, [1] + [0] * 7),
+    "no two rows agree": ([[1, 2, 3, 0, 0, 0], [4, 5, 6, 0, 0, 0], [7, 8, 9, 0, 0, 0]],
+                          [30, 20, 40], None, 0, [0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("group", [4, 1])
+@pytest.mark.parametrize("case", list(_TWO_PASS_CASES))
+def test_paged_block_attention_common_pass_matches_the_plain_reference(case, group, monkeypatch):
+    """Common pass + own pass + merge against ``paged_attention_reference``'s
+    arithmetic at T queries a row (interpret mode): the split that was
+    derived, the rows that ride, the counts the counters carry, the outputs
+    of every live row, and zeros for a row that is not live."""
+    from tpu_voice_agent.ops import (
+        common_block_split,
+        paged_block_attention,
+        paged_block_attention_reference,
+    )
+
+    tables, pos, live, want_s, want_rides = _TWO_PASS_CASES[case]
+    L, N, bs, T, nkv, hd = 2, 12, 16, 9, 2, 32
+    B = len(tables)
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (B, T, nkv * group, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (L, N, bs, nkv, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (L, N, bs, nkv, hd), jnp.float32)
+    tables = jnp.asarray(tables, jnp.int32)
+    q_pos = jnp.asarray(pos, jnp.int32)[:, None] + jnp.arange(T)[None, :]
+    live = None if live is None else jnp.asarray(live)
+    rows = np.ones(B, bool) if live is None else np.asarray(live)
+
+    split = common_block_split(tables, q_pos, live, bs)
+    assert int(split.n_common) == want_s
+    assert (np.asarray(split.slot) < int(split.n_riders)).astype(int).tolist() == want_rides
+    blocks = (np.asarray(q_pos).max(axis=1) // bs + 1)[rows]  # a live row attends these
+    assert np.asarray(split.counts).tolist() == [want_s * sum(want_rides), int(blocks.sum())]
+    assert int(split.n_items) == want_s + int(blocks.sum()) - want_s * sum(want_rides)
+
+    out = np.asarray(paged_block_attention(q, kp, vp, tables, q_pos, jnp.int32(1), live))
+    ref = np.asarray(paged_block_attention_reference(q, kp, vp, tables, q_pos, 1))
+    np.testing.assert_allclose(out[rows], ref[rows], rtol=1e-5, atol=1e-5)
+    assert (out[~rows] == 0).all()
+    if case == "all rows ride":
+        # a batch wider than the kernel's VMEM budget goes through in groups
+        # of rows, each with its own split: here one row a group
+        import sys
+
+        monkeypatch.setattr(sys.modules["tpu_voice_agent.ops.paged_attention"], "_STATE_BYTES", 1)
+        jax.clear_caches()
+        one_by_one = paged_block_attention(q, kp, vp, tables, q_pos, jnp.int32(1), live)
+        np.testing.assert_allclose(np.asarray(one_by_one), ref, rtol=1e-5, atol=1e-5)
+        jax.clear_caches()  # the budget is read when the wrapper is traced

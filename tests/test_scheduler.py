@@ -448,3 +448,56 @@ def test_two_chunks_in_flight_are_independent_values(ff):
     bat.reset()
     for slot in (0, 1):
         eng.release_slot(slot, ok=False)
+
+
+def test_common_pass_counters_behind_the_batcher_match_the_tables(monkeypatch):
+    """A ``test-tiny`` paged engine WITH its pinned prompt prefix, three rows
+    behind the batcher through the Pallas block kernel: the tokens are the
+    un-paged ``DecodeEngine``'s, and ``attn.common_row_blocks`` /
+    ``attn.row_blocks`` are what the tables and positions say, worked out
+    here by hand. Chunks of ONE forward, so a forward's positions are the
+    chunk's: a live row's queries run from ``pos`` to the position before
+    the one it is left at. Two or more live rows hold the prefix's full
+    blocks in common and nothing after them; one live row alone holds every
+    block under its first query 'in common' with itself."""
+    import numpy as np
+
+    from tpu_voice_agent.utils import tracing
+
+    fresh = tracing.Metrics()
+    monkeypatch.setattr(tracing, "_GLOBAL_METRICS", fresh)
+    from tpu_voice_agent.serve import DecodeEngine, PagedDecodeEngine
+    from tpu_voice_agent.services.brain import install_prompt_prefix
+    from tpu_voice_agent.services.prompts import render_prompt
+
+    kw = dict(preset="test-tiny", max_len=2048, batch_slots=3, fast_forward=8,
+              prefill_buckets=(128, 256, 512, 1024))
+    dense = DecodeEngine(**kw)
+    paged = PagedDecodeEngine(kernels="pallas", **kw)
+    install_prompt_prefix(dense)
+    bs = paged.block_size
+    shared = install_prompt_prefix(paged) // bs
+    assert shared >= 2
+
+    want, decode_chunk = np.zeros(2, np.int64), paged.decode_chunk
+
+    def spy(cur, pos, fsm, active, *a, **kw):
+        tables = np.asarray(paged.block_tables)
+        res = decode_chunk(cur, pos, fsm, active, *a, **kw)
+        live, first, last = np.asarray(active), np.asarray(pos), np.asarray(res.pos) - 1
+        assert int(res.fwds) == 1 and (tables[live, :shared] == tables[live][0, :shared]).all()
+        common = shared if live.sum() > 1 else first[live][0] // bs
+        by_hand = [common * live.sum(), (last[live] // bs + 1).sum()]
+        assert np.asarray(res.attn).tolist() == by_hand
+        want[:] += by_hand
+        return res
+
+    monkeypatch.setattr(paged, "decode_chunk", spy)
+    prompts = [render_prompt(t, {}) for t in PROMPTS]
+    rd = ContinuousBatcher(dense, chunk_steps=1, max_new_tokens=24).generate_many(prompts)
+    rp = ContinuousBatcher(paged, chunk_steps=1, max_new_tokens=24).generate_many(prompts)
+    assert [r.token_ids for r in rp] == [r.token_ids for r in rd]
+    assert all(r.error is None for r in rp)
+    counters = fresh.snapshot()["counters"]
+    assert [counters["attn.common_row_blocks"], counters["attn.row_blocks"]] == want.tolist()
+    assert want[0] / want[1] > 0.6  # the prefix is most of what a row attends

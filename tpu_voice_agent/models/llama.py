@@ -650,7 +650,8 @@ def forward(
 
 @watch_compiles("llama.forward_paged")
 @partial(jax.jit, static_argnames=("cfg", "rules", "attn_impl", "fresh_block",
-                                   "gather_blocks", "kv_quant", "moe_stats"),
+                                   "gather_blocks", "kv_quant", "moe_stats",
+                                   "attn_stats"),
          donate_argnames=("k_pool", "v_pool", "k_scale", "v_scale"))
 def forward_paged(
     params: dict,
@@ -685,6 +686,9 @@ def forward_paged(
     moe_stats: bool = False,  # routed models: also return the forward's
     # ``MOE_STATS`` summed over layers, (4,) int32 (the chunk loops carry them
     # out with their readback; no callback on the hot path)
+    attn_stats: bool = False,  # also return ``ops.ATTN_STATS``, (2,) int32:
+    # the row-blocks the block kernel's common pass took this forward and the
+    # row-blocks live rows attend in all (the chunk loops carry them likewise)
 ):
     """The paged twin of ``forward`` (parity-tested): sequences own
     non-contiguous pool blocks via per-row block tables (SURVEY.md §7
@@ -705,8 +709,9 @@ def forward_paged(
     what decode later reads.
 
     Returns (logits, k_pool, v_pool, k_scale, v_scale) — the scale slots
-    are None when ``kv_quant`` is None — and, with ``moe_stats``, the
-    forward's routed-expert counts as a sixth."""
+    are None when ``kv_quant`` is None — then, with ``moe_stats``, the
+    forward's routed-expert counts, then, with ``attn_stats``, its attention
+    row-block counts."""
     B, T = tokens.shape
     L, N, bs = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     moe_stats = moe_stats and cfg.n_experts > 0  # a dense model has none
@@ -729,6 +734,22 @@ def forward_paged(
         park = (jnp.zeros((B,), jnp.int32) if trash_idx is None
                 else trash_idx.astype(jnp.int32))
         flat_idx = jnp.where(write_mask[:, None], flat_idx, park[:, None])
+
+    # the small mid-sequence block (a grammar fast-forward chain step, a
+    # speculative verify step) attends through the paged block kernel. Which
+    # leading blocks the live rows hold in common is read HERE, once a
+    # forward, from the tables, the positions and the write mask: tables do
+    # not move between layers. Under a mesh each dp group pins its own prefix
+    # blocks, so the kernel's wrapper derives it shard-locally instead.
+    mesh = rules.mesh if rules is not None else None
+    block_decode = (attn_impl == "pallas" and not fresh_block
+                    and 1 < T <= MAX_BLOCK_DECODE_T)
+    split = None
+    if block_decode and kv_quant is None and mesh is None:
+        from ..ops import common_block_split
+
+        with jax.named_scope("layer/attn/split"):
+            split = common_block_split(block_tables, positions, write_mask, bs)
 
     scanned, whole = _scan_and_whole(params["layers"], cfg)
 
@@ -763,7 +784,6 @@ def forward_paged(
 
         with jax.named_scope("layer/attn"):
             if attn_impl == "pallas" and T == 1:
-                mesh = rules.mesh if rules is not None else None
                 if kv_quant is None:
                     from ..ops import sharded_paged_attention
 
@@ -780,18 +800,17 @@ def forward_paged(
                         mesh, q[:, 0], kp, vp, ksc, vsc, block_tables,
                         frontier + 1, li, bits=bits,
                     ).reshape(B, T, -1)
-            elif (attn_impl == "pallas" and not fresh_block
-                  and T <= MAX_BLOCK_DECODE_T):
-                # small mid-sequence block (grammar fast-forward chain step):
+            elif block_decode:
                 # the paged twin of the dense frontier-read block kernel — T
-                # queries per row read the row's own pool blocks up to its own
-                # positions; no per-layer table gather
-                mesh = rules.mesh if rules is not None else None
+                # queries per row read the blocks live rows hold in common
+                # once for all of them, then the row's own pool blocks up to
+                # its own positions; no per-layer table gather
                 if kv_quant is None:
                     from ..ops import sharded_paged_block_attention
 
                     attn = sharded_paged_block_attention(
-                        mesh, q, kp, vp, block_tables, positions, li
+                        mesh, q, kp, vp, block_tables, positions, li,
+                        write_mask, split,
                     ).reshape(B, T, -1)
                 else:
                     from ..ops import sharded_paged_block_attention_quant
@@ -819,7 +838,6 @@ def forward_paged(
                 if attn_impl == "pallas":
                     from ..ops import sharded_flash_attention
 
-                    mesh = rules.mesh if rules is not None else None
                     attn = sharded_flash_attention(mesh, q, k_at, v_at,
                                                    causal=True).reshape(B, T, -1)
                 else:
@@ -862,9 +880,31 @@ def forward_paged(
     with jax.named_scope("lm_head"):
         logits = _qe("btd,dv->btv", x, params["lm_head"])
         logits = cs(logits, "logits")
-    if moe_stats:
-        return logits, k_pool, v_pool, k_scale, v_scale, jnp.sum(stats, axis=0)
-    return logits, k_pool, v_pool, k_scale, v_scale
+    extra = (jnp.sum(stats, axis=0),) if moe_stats else ()
+    if attn_stats:
+        extra += (_attn_stats(split, block_decode and kv_quant is None, mesh,
+                              block_tables, positions, write_mask, bs),)
+    return (logits, k_pool, v_pool, k_scale, v_scale, *extra)
+
+
+def _attn_stats(split, two_pass: bool, mesh, block_tables, positions, live, bs: int):
+    """``ops.ATTN_STATS`` of one forward, (2,) int32. Through the two-pass
+    block kernel they are its split's own; under a mesh that is a split per
+    dp group, as the kernel's wrapper derives it; on every other path no
+    block is common and live rows attend the blocks up to their frontier."""
+    from ..ops import common_block_split
+
+    if split is not None:
+        return split.counts
+    B = positions.shape[0]
+    live = jnp.ones((B,), bool) if live is None else live
+    if two_pass:
+        dp = mesh.shape.get("dp", 1)
+        groups = lambda x: x.reshape(dp, B // dp, *x.shape[1:])
+        return jnp.sum(jax.vmap(lambda t, p, l: common_block_split(t, p, l, bs).counts)(
+            groups(block_tables), groups(positions), groups(live)), axis=0)
+    blocks = jnp.where(live, jnp.max(positions, axis=1) // bs + 1, 0)
+    return jnp.stack([jnp.zeros((), jnp.int32), jnp.sum(blocks).astype(jnp.int32)])
 
 
 def param_count(cfg: LlamaConfig) -> int:

@@ -56,6 +56,10 @@ from .kvquant import (
     quantize_kv,
 )
 from .paged_attention import (
+    ATTN_STATS,
+    BlockSplit,
+    common_block_split,
+    paged_block_attention_reference,
     paged_attention,
     paged_attention_quant,
     paged_attention_quant_reference,
@@ -106,6 +110,10 @@ __all__ = [
     "paged_attention_quant",
     "paged_attention_quant_reference",
     "paged_block_attention",
+    "paged_block_attention_reference",
+    "common_block_split",
+    "BlockSplit",
+    "ATTN_STATS",
     "paged_block_attention_quant",
     "paged_block_attention_quant_reference",
     "sharded_paged_block_attention",

@@ -15,11 +15,18 @@ decode_attention_layer's stacked-cache plane).
 
 The pool is layer-stacked (L, N, bs, nkv, hd) with the layer index in the
 scalars, so the decode loop's scan body never slices a per-layer pool.
+
+The (B, T > 1) BLOCK kernel (grammar fast-forward, speculative verify) does
+not let each row walk its own table: the leading blocks that live rows hold
+in common — the shared prompt prefix — are read ONCE, against every row's
+queries, and only a row's own blocks are read per row; see "block decode"
+below. The T = 1 kernel and the ``*_quant`` twins walk row by row.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -510,77 +517,246 @@ def paged_attention_reference(
 # under the batcher takes (B, 1+W) steps, and the paged pool must serve them
 # without gathering each row's whole table to a contiguous cache (the T>1
 # XLA fallback's cost). T queries fold into the row dimension; per-query
-# write positions give intra-block causality; tile gating skips pool blocks
-# beyond the row's last query.
+# write positions give intra-block causality.
+#
+# Two passes in one kernel (ISSUE 31). Every row of this system sits behind
+# the same prompt prefix, held ONCE in the pool and named by the first
+# columns of every row's table, so a kernel in which each row walks its own
+# table fetches those blocks once per row. ``common_block_split`` reads, from
+# the tables, the positions and which rows are live, how many leading blocks
+# S the live rows hold in common and see whole, and writes ONE list of work
+# items, walked by a dynamic grid (a dead tile costs nothing):
+#   * the first S items are the COMMON pass: one pool block each, multiplied
+#     against the queries of every row that rides, as one (rows, hd) matrix
+#     per kv head — a K block is fetched, and its heads picked apart, once
+#     for all of them;
+#   * the rest are the OWN pass: (row, tile) pairs, each live row's tiles
+#     from its first own block (S if it rides, 0 if not) to its last query's.
+# The statistics (m, l, acc: float32) of every row stay in VMEM between the
+# two, so a riding row's own tiles go on from what the common pass left
+# instead of from (-inf, 0, 0): the online softmax across tiles, carried
+# across the passes. That IS the log-sum-exp merge (m = max(m1, m2),
+# l = e^(m1-m) l1 + e^(m2-m) l2, acc alike) with nothing written out between
+# them, in the order and arithmetic of the one-pass walk: block by block,
+# ascending. The division is a row's last act. S = 0 (under half of the live
+# rows agree, or a query inside the first block) is the old walk. A row that
+# is not live has no item and its output is zero.
+#
+# Operands: q, k, v and p go to both dots cast up to float32, as the one-pass
+# walk cast them: on the chip a float32 dot at the default precision is one
+# bf16 pass of the MXU, so that is what the pool's bf16 values cost, and bf16
+# operands measured 5-12 % SLOWER here (my chip runs, PR 31). With the order
+# unchanged the outputs equal the one-pass kernel's bit for bit, on the chip
+# and in interpret mode alike.
+
+
+class BlockSplit(NamedTuple):
+    """What ``common_block_split`` derives for one forward (every layer of
+    it: tables do not move inside a forward, positions do between them)."""
+
+    n_common: jax.Array  # () int32 — S, leading table columns of the common pass
+    n_items: jax.Array  # () int32 — S + the own pass's (row, tile) pairs
+    n_riders: jax.Array  # () int32
+    item_block: jax.Array  # (max_blocks + B*max_blocks,) int32 — pool block
+    item_row: jax.Array  # ... row (own items; rows in order)
+    item_tile: jax.Array  # ... and table column of each item
+    slot: jax.Array  # (B,) int32 — the row's place in the kernel's query
+    # layout: riders first (the common pass multiplies those places alone),
+    # so a row rides — starts from the common pass — iff slot < n_riders
+    order: jax.Array  # (B,) int32 — its inverse: the row at each place
+    attended: jax.Array  # (B,) bool — the row has an own tile (it is live)
+    counts: jax.Array  # (2,) int32 — ATTN_STATS: row-blocks the common pass
+    # took, row-blocks live rows attend in all
+
+
+# what a block forward counts (``forward_paged(attn_stats=True)``; the chunk
+# loops sum them, ``scheduler`` publishes them as ``attn.<name>``)
+ATTN_STATS = ("common_row_blocks", "row_blocks")
+
+
+def common_block_split(
+    block_tables: jax.Array,  # (B, max_blocks) int32
+    q_positions: jax.Array,  # (B, T) int32
+    live: jax.Array | None,  # (B,) bool — None: every row
+    bs: int,
+) -> BlockSplit:
+    """The split, from what the kernel is handed and nothing else.
+
+    The rows that RIDE the common pass are the largest set of live rows
+    that hold the same first block (ties: the set of the lowest row), so a
+    row with another first block walks its whole table as before and
+    switches nothing off for the others; fewer riders than half the live
+    rows take no common pass at all (it pays only for blocks most rows
+    read). Column j is common when every rider holds the leader's id there
+    and (j+1)*bs <= the smallest query position of any rider: every query
+    of every rider sees the whole block (no mask), and it is never a block
+    this forward writes (a row writes at its query positions, all of them at
+    or past S*bs)."""
+    bt = block_tables.astype(jnp.int32)
+    B, M = bt.shape
+    qp = q_positions.astype(jnp.int32)
+    live = jnp.ones((B,), bool) if live is None else live.astype(bool)
+    agree = (bt[:, :1] == bt[:, 0][None, :]) & live[:, None] & live[None, :]
+    leader = jnp.argmax(jnp.sum(agree, axis=1))
+    lead = bt[leader]
+    cand = agree[leader]  # none when nothing is live
+    same = jnp.all((bt == lead[None, :]) | ~cand[:, None], axis=0)  # (M,)
+    s_agree = jnp.sum(jnp.cumprod(same.astype(jnp.int32)))
+    s_pos = jnp.min(jnp.where(cand, jnp.min(qp, axis=1) // bs, M))
+    S = jnp.where(2 * jnp.sum(cand) >= jnp.maximum(jnp.sum(live), 1),
+                  jnp.minimum(s_agree, s_pos), 0)
+    rides = cand & (S > 0)
+    first = jnp.where(rides, S, 0)
+    last = jnp.minimum(jnp.max(qp, axis=1) // bs, M - 1)
+    n = jnp.where(live, last - first + 1, 0)
+    ends = jnp.cumsum(n)
+    # items: S common blocks, then each row's (row, tile) pairs, rows in order
+    w = jnp.arange(M + B * M, dtype=jnp.int32)
+    own = w - S
+    rows = jnp.clip(jnp.sum(own[:, None] >= ends[None, :], axis=1), 0, B - 1)
+    tiles = jnp.clip(first[rows] + own - (ends - n)[rows], 0, M - 1)
+    blocks = jnp.where(own < 0, lead[jnp.minimum(w, M - 1)], bt[rows, tiles])
+    order = jnp.argsort(~rides, stable=True).astype(jnp.int32)
+    slot = jnp.zeros((B,), jnp.int32).at[order].set(jnp.arange(B, dtype=jnp.int32))
+    counts = jnp.stack([S * jnp.sum(rides), jnp.sum(jnp.where(live, last + 1, 0))])
+    i32 = lambda x: x.astype(jnp.int32)
+    return BlockSplit(i32(S), i32(S + ends[-1]), i32(jnp.sum(rides)), i32(blocks),
+                      i32(rows), i32(tiles), slot, order, n > 0, i32(counts))
+
+
+def _softmax_tile(q, k, v, valid, m_prev, l_prev, acc_prev, scale: float):
+    """One online-softmax step of (rows, hd) queries over one block's (bs, hd)
+    float32 k and v: new m, l, acc. ``valid`` None: every key is seen."""
+    s = jax.lax.dot_general(
+        q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    if valid is not None:
+        s = jnp.where(valid, s, _NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return m_new, l_new, acc_prev * alpha + pv
 
 
 def _paged_block_kernel(
-    scalars_ref,  # SMEM: [q_pos (B*T,) | layer (1,) | table (B*max_blocks,)]
-    q_ref,  # (1, nkv, T*group, hd)
-    k_ref,  # (1, 1, bs, nkv, hd) — pool block picked by the index map
+    qpos_ref,  # SMEM (B*T,)
+    meta_ref,  # SMEM (5,): [layer, S, items, riders, sub-chunks that hold one]
+    block_ref,  # SMEM (max_blocks + B*max_blocks,): each item's pool block ...
+    row_ref,  # ... row ...
+    tile_ref,  # ... and table column
+    slot_ref,  # SMEM (B,): riders first
+    q_ref,  # (nkv, B*Rp, hd) — every row's queries, riders first
+    k_ref,  # (1, 1, bs, nkv, hd) — pool block block[w]
     v_ref,
-    o_ref,  # (1, nkv, T*group, hd)
-    acc_ref,  # VMEM (nkv, T*group, hd) f32
-    m_ref,  # VMEM (nkv, T*group, 128) f32
+    o_ref,  # (nkv, B*Rp, hd) — rows in their own order
+    acc_ref,  # VMEM (nkv, B*Rp, hd) f32
+    m_ref,  # VMEM (nkv, B*Rp, 128) f32, a value across its lanes
     l_ref,
+    kh_ref,  # VMEM (nkv, bs, hd) f32 — a common block's heads, picked apart once
+    vh_ref,
     *,
     scale: float,
     nkv: int,
     group: int,
     T: int,
     bs: int,
+    Rp: int,  # query rows a batch row holds in the layout (T*group, padded)
+    sub: int,  # query rows a sub-chunk of the common pass: whole batch rows
 ):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-    rows = T * group
+    w = pl.program_id(0)
+    S, n, n_riders, n_sub = meta_ref[1], meta_ref[2], meta_ref[3], meta_ref[4]
+    hd = acc_ref.shape[2]
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def start(at, size):  # state from nothing
+        for h in range(nkv):
+            acc_ref[h, at, :] = jnp.zeros((size, hd), jnp.float32)
+            m_ref[h, at, :] = jnp.full((size, 128), _NEG_INF, jnp.float32)
+            l_ref[h, at, :] = jnp.zeros((size, 128), jnp.float32)
 
-    # true block max over all T query positions (no ordering assumption)
-    max_pos = scalars_ref[b * T]
-    for _i in range(1, T):
-        max_pos = jnp.maximum(max_pos, scalars_ref[b * T + _i])
+    def advance(h, at, size, k, v, valid):  # one head's rows ``at`` over one block
+        m, l, acc = _softmax_tile(q_ref[h, at, :], k, v, valid, m_ref[h, at, :1],
+                                  l_ref[h, at, :1], acc_ref[h, at, :], scale)
+        acc_ref[h, at, :] = acc
+        m_ref[h, at, :] = jnp.broadcast_to(m, (size, 128))
+        l_ref[h, at, :] = jnp.broadcast_to(l, (size, 128))
 
-    @pl.when(j * bs <= max_pos)
-    def _tile():
-        k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
-        qpos_rows = jnp.zeros((rows, 1), jnp.int32)
+    chunk = lambda i: pl.ds(pl.multiple_of(i * sub, sub), sub)
+
+    @pl.when(w == 0)
+    def _riders_start():
+        jax.lax.fori_loop(0, n_sub, lambda i, c: (start(chunk(i), sub), c)[1], 0)
+
+    @pl.when(w < S)
+    def _common():  # every rider sees the whole block: no mask
+        for h in range(nkv):
+            kh_ref[h] = k_ref[0, 0, :, h].astype(jnp.float32)
+            vh_ref[h] = v_ref[0, 0, :, h].astype(jnp.float32)
+
+        def riders(i, c):  # the heads in line, so that one's dots hide under another's softmax
+            for h in range(nkv):
+                advance(h, chunk(i), sub, kh_ref[h], vh_ref[h], None)
+            return c
+
+        jax.lax.fori_loop(0, n_sub, riders, 0)
+
+    @pl.when(jnp.logical_and(w >= S, w < n))
+    def _own():
+        b, j = row_ref[w], tile_ref[w]
+        at = pl.ds(pl.multiple_of(slot_ref[b] * Rp, Rp), Rp)
+        first = jnp.logical_or(w == S, row_ref[jnp.maximum(w - 1, 0)] != b)
+        last = jnp.logical_or(w == n - 1, row_ref[jnp.minimum(w + 1, row_ref.shape[0] - 1)] != b)
+
+        @pl.when(jnp.logical_and(first, slot_ref[b] >= n_riders))
+        def _row_start():  # a rider goes on from the common pass: the merge
+            start(at, Rp)
+
+        k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (Rp, bs), 1)
+        qpos_rows = jnp.zeros((Rp, 1), jnp.int32)  # padding rows stay at 0
         for i in range(T):
             qpos_rows = jnp.where(
-                (jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // group) == i,
-                scalars_ref[b * T + i], qpos_rows)
+                (jax.lax.broadcasted_iota(jnp.int32, (Rp, 1), 0) // group) == i,
+                qpos_ref[b * T + i], qpos_rows)
         valid = k_pos <= qpos_rows  # causal + frontier in one mask
         for h in range(nkv):
-            q = q_ref[0, h].astype(jnp.float32)  # (rows, hd)
-            k = k_ref[0, 0, :, h].astype(jnp.float32)  # (bs, hd)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * scale
-            s = jnp.where(valid, s, _NEG_INF)
+            advance(h, at, Rp, k_ref[0, 0, :, h].astype(jnp.float32),
+                    v_ref[0, 0, :, h].astype(jnp.float32), valid)
 
-            m_prev = m_ref[h, :, :1]
-            l_prev = l_ref[h, :, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p, v_ref[0, 0, :, h].astype(jnp.float32), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            acc_ref[h] = acc_ref[h] * alpha + pv
-            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        @pl.when(last)
+        def _row_finish():
+            to = pl.ds(pl.multiple_of(b * Rp, Rp), Rp)
+            for h in range(nkv):
+                l = l_ref[h, at, :1]
+                o_ref[h, to, :] = (acc_ref[h, at, :] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
-    @pl.when(j == nj - 1)
-    def _finish():
-        l = l_ref[:, :, :1]
-        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+# the kernel keeps every row's queries, outputs and float32 statistics in
+# VMEM for the whole walk, which at (32 rows, 36 query rows, 8 kv heads) is
+# 32 MB: more than the 16 MB a kernel gets unasked, a quarter of a v5e's.
+# Wider batches than fit ``_STATE_BYTES`` go through the kernel in groups of
+# rows, each with a split of its own.
+_VMEM_LIMIT = 64 << 20
+_STATE_BYTES = 48 << 20
+
+
+def _rows_that_fit(B: int, Rp: int, nkv: int, hd: int, itemsize: int) -> int:
+    """The largest divisor of B whose rows' resident state (queries and
+    outputs, double-buffered; acc, m, l) stays inside ``_STATE_BYTES``."""
+    per_row = nkv * Rp * (4 * hd * itemsize + 4 * hd + 2 * 4 * 128)
+    return max([c for c in range(1, B + 1) if B % c == 0 and c * per_row <= _STATE_BYTES],
+               default=1)
+
+
+def _sub_rows(B: int) -> int:
+    """Batch rows a sub-chunk of the common pass holds: a quarter of the rows
+    (the largest divisor of B at or under it). Its dots stream that many
+    rows' queries past one head's K block, and only sub-chunks that hold a
+    rider run: larger is faster when every row rides (at 32 rows a quarter
+    is within 3 % of the whole), smaller when one of eight does (my chip
+    runs, PR 31)."""
+    return max(c for c in range(1, max(B // 4, 1) + 1) if B % c == 0)
 
 
 # analyze: ok[jit-sentinel] -- kernel wrapper traced inline by the watched engine/stt loops, never a serving dispatch entry point
@@ -592,6 +768,9 @@ def paged_block_attention(
     block_tables: jax.Array,  # (B, max_blocks) int32
     q_positions: jax.Array,  # (B, T) int32 — each query's sequence position
     layer: jax.Array,  # scalar int32
+    live: jax.Array | None = None,  # (B,) bool — rows whose output is read
+    split: BlockSplit | None = None,  # common_block_split of the three
+    # above, when the caller has it already (one forward, many layers)
     *,
     scale: float | None = None,
     interpret: bool | None = None,
@@ -599,58 +778,86 @@ def paged_block_attention(
     """Returns (B, T, nq, hd). Query i attends positions [0, q_positions
     [b, i]] of its row's paged sequence (the caller has already scattered
     the block's k/v at those positions). Unused table entries must hold a
-    valid block id — tiles beyond the row's last query are skipped."""
+    valid block id. A row that is not ``live`` is not attended: zeros."""
     B, T, nq, hd = q.shape
     bs, nkv = k_pool.shape[2], k_pool.shape[3]
-    max_blocks = block_tables.shape[1]
     assert nq % nkv == 0
     group = nq // nkv
     scale = scale if scale is not None else hd**-0.5
     interpret = interpret if interpret is not None else on_cpu()
-    qg = q.reshape(B, T, nkv, group, hd).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(B, nkv, T * group, hd)
-
-    scalars = jnp.concatenate([
-        q_positions.astype(jnp.int32).reshape(-1),
-        jnp.reshape(layer, (1,)).astype(jnp.int32),
-        block_tables.astype(jnp.int32).reshape(-1),
-    ])
-    kernel = functools.partial(
-        _paged_block_kernel, scale=scale, nkv=nkv, group=group, T=T, bs=bs
-    )
-    BT = B * T
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, nkv, T * group, hd), lambda b, j, sc: (b, 0, 0, 0)),
-            pl.BlockSpec(
-                (1, 1, bs, nkv, hd),
-                lambda b, j, sc, M=max_blocks: (sc[BT], sc[BT + 1 + b * M + j], 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, bs, nkv, hd),
-                lambda b, j, sc, M=max_blocks: (sc[BT], sc[BT + 1 + b * M + j], 0, 0, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, nkv, T * group, hd),
-                               lambda b, j, sc: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nkv, T * group, hd), jnp.float32),
-            pltpu.VMEM((nkv, T * group, 128), jnp.float32),
-            pltpu.VMEM((nkv, T * group, 128), jnp.float32),
-        ],
-    )
+    # the layout: (nkv, B * Rp, hd), riders first, a row's T*group query rows
+    # padded to whole sublane tiles so that a row is an aligned slice
+    R = T * group
+    Rp = -(-R // 16) * 16
+    Bg = _rows_that_fit(B, Rp, nkv, hd, q.dtype.itemsize)
+    if Bg < B:
+        return jnp.concatenate([
+            paged_block_attention(
+                q[g:g + Bg], k_pool, v_pool, block_tables[g:g + Bg], q_positions[g:g + Bg],
+                layer, None if live is None else live[g:g + Bg], scale=scale, interpret=interpret)
+            for g in range(0, B, Bg)])
+    if split is None:
+        split = common_block_split(block_tables, q_positions, live, bs)
+    Bc = _sub_rows(B)
+    qg = q.reshape(B, T, nkv, group, hd).transpose(2, 0, 1, 3, 4).reshape(nkv, B, R, hd)
+    qg = jnp.pad(qg[:, split.order], ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
+    qg = qg.reshape(nkv, B * Rp, hd)
+    whole = pl.BlockSpec((nkv, B * Rp, hd), lambda w, *_: (0, 0, 0))
+    pool_spec = pl.BlockSpec(
+        (1, 1, bs, nkv, hd), lambda w, qpos, meta, block, *_: (meta[0], block[w], 0, 0, 0))
     out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nkv, T * group, hd), q.dtype),
+        functools.partial(_paged_block_kernel, scale=scale, nkv=nkv, group=group, T=T,
+                          bs=bs, Rp=Rp, sub=Bc * Rp),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(jnp.maximum(split.n_items, 1),),
+            in_specs=[whole, pool_spec, pool_spec],
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((nkv, B * Rp, hd), jnp.float32),
+                pltpu.VMEM((nkv, B * Rp, 128), jnp.float32),
+                pltpu.VMEM((nkv, B * Rp, 128), jnp.float32),
+                pltpu.VMEM((nkv, bs, hd), jnp.float32),
+                pltpu.VMEM((nkv, bs, hd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((nkv, B * Rp, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="paged_block_attention",
-    )(scalars, qg, k_pool, v_pool)
-    return (out.reshape(B, nkv, T, group, hd)
-               .transpose(0, 2, 1, 3, 4)
+    )(q_positions.astype(jnp.int32).reshape(-1),
+      jnp.stack([jnp.reshape(layer, ()).astype(jnp.int32), split.n_common, split.n_items,
+                 split.n_riders, -(-split.n_riders // Bc)]),
+      split.item_block, split.item_row, split.item_tile, split.slot,
+      qg, k_pool, v_pool)
+    # a row without an item was never written: zeros, not what the buffer held
+    out = jnp.where(split.attended[None, :, None, None],
+                    out.reshape(nkv, B, Rp, hd)[:, :, :R], 0)
+    return (out.reshape(nkv, B, T, group, hd)
+               .transpose(1, 2, 0, 3, 4)
                .reshape(B, T, nq, hd))
+
+
+def paged_block_attention_reference(
+    q: jax.Array,  # (B, T, nq, hd)
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    block_tables: jax.Array,
+    q_positions: jax.Array,  # (B, T)
+    layer,
+    *,
+    scale: float | None = None,
+) -> jax.Array:
+    """Pure-jnp twin: ``paged_attention_reference``'s gather, T queries a row
+    under the dense block reference's mask."""
+    from .decode_attention import decode_block_attention_reference
+
+    B = q.shape[0]
+    bs, nkv, hd = k_pool.shape[2], k_pool.shape[3], k_pool.shape[4]
+    S = block_tables.shape[1] * bs
+    kc = k_pool[layer][block_tables].reshape(B, S, nkv, hd)
+    vc = v_pool[layer][block_tables].reshape(B, S, nkv, hd)
+    return decode_block_attention_reference(q, kc, vc, q_positions, scale=scale)
 
 
 def sharded_paged_block_attention(
@@ -661,14 +868,19 @@ def sharded_paged_block_attention(
     block_tables: jax.Array,  # (B, max_blocks) GLOBAL block ids
     q_positions: jax.Array,  # (B, T)
     layer: jax.Array,
+    live: jax.Array | None = None,  # (B,) bool
+    split: BlockSplit | None = None,  # mesh=None only
     **kw,
 ) -> jax.Array:
     """paged_block_attention over a (dp, tp) mesh — same layout contract as
     sharded_paged_attention (pool blocks over dp, kv heads over tp, each dp
-    group's rows reference only its own block range)."""
+    group's rows reference only its own block range). Each dp group pins its
+    own prefix blocks, so under a mesh the common-block split is derived
+    shard-locally, from the shard's rows of the tables, positions and
+    ``live``; ``split`` is the unmeshed caller's."""
     if mesh is None:
         return paged_block_attention(q, k_pool, v_pool, block_tables,
-                                     q_positions, layer, **kw)
+                                     q_positions, layer, live, split, **kw)
     from jax.sharding import PartitionSpec as P
 
     tp = mesh.shape.get("tp", 1)
@@ -683,22 +895,23 @@ def sharded_paged_block_attention(
     dp_ax = "dp" if dp > 1 else None
     local_blocks = N // dp if dp_ax else N
 
-    def local(q, kp, vp, bt, qp, layer):
+    def local(q, kp, vp, bt, qp, live, layer):
         if dp_ax is not None:
             bt = bt - jax.lax.axis_index("dp") * local_blocks
-        return paged_block_attention(q, kp, vp, bt, qp, layer, **kw)
+        return paged_block_attention(q, kp, vp, bt, qp, layer, live, **kw)
 
     qs = P(dp_ax, None, tp_ax, None)
     ps = P(None, dp_ax, None, tp_ax, None)
     fn = jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(qs, ps, ps, P(dp_ax, None), P(dp_ax, None), P()),
+        in_specs=(qs, ps, ps, P(dp_ax, None), P(dp_ax, None), P(dp_ax), P()),
         out_specs=qs,
         check_vma=False,
     )
     return fn(q, k_pool, v_pool, block_tables.astype(jnp.int32),
-              q_positions.astype(jnp.int32), layer)
+              q_positions.astype(jnp.int32),
+              jnp.ones((B,), bool) if live is None else live.astype(bool), layer)
 
 
 def _paged_block_kernel_quant(

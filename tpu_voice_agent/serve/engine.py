@@ -123,6 +123,9 @@ class ChunkResult:
     # (margin sum/min, entropy sum, forced, decisions); None with them off
     moe: Any = None  # a ROUTED model's llama.MOE_STATS summed over the
     # chunk; None for a dense model (its chunk program has no such output)
+    attn: Any = None  # the paged chunk loop's ops.ATTN_STATS summed over the
+    # chunk, (2,) int32: row-blocks the block kernel's common pass took, and
+    # row-blocks live rows attended in all; None from every other engine
     # the spec decoder's per-row host counts; None on the plain loops
     row_fwds: Any = None  # verify steps the row took part in
     row_accepts: Any = None  # draft tokens accepted

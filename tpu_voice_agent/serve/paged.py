@@ -331,7 +331,9 @@ def paged_chunk_decode_loop(
     batch-mates decode token-identically to an undisturbed run. A ROUTED
     model (``cfg.n_experts > 0``, static) compiles a variant with one more
     carry and one more output: ``llama.MOE_STATS`` summed over the chunk's
-    forwards and layers, (4,) int32. A dense model's program is untouched.
+    forwards and layers, (4,) int32. Every variant's LAST output is
+    ``ops.ATTN_STATS`` summed over the chunk's forwards, (2,) int32 (ISSUE 31:
+    how often the block kernel's common pass engages).
 
     The COMPACTED width (ISSUE 29): with ``rows_idx`` the same loop runs over
     those R slots' rows alone — their state and block-table rows gathered on
@@ -375,18 +377,18 @@ def paged_chunk_decode_loop(
                    dtype=jnp.int32)
     eos0 = (~active) & (cur == eos_id)
 
-    # the routed variant: forwards count their expert rows, the carry sums
-    # them. For a dense model every ``*moe`` / ``*stats`` below is empty and
-    # the traced program is the one it always was (tests/test_olmoe.py)
+    # what the forwards count and the carry sums: attention row-blocks
+    # always, last; in the routed variant the expert rows before them (for a
+    # dense model that carry and output do not exist: tests/test_olmoe.py)
     routed = cfg.n_experts > 0
-    moe_kw = {"moe_stats": True} if routed else {}
-    moe0 = (jnp.zeros((4,), jnp.int32),) if routed else ()
+    count_kw = {"attn_stats": True, **({"moe_stats": True} if routed else {})}
+    counts0 = ((jnp.zeros((4,), jnp.int32),) if routed else ()) + (jnp.zeros((2,), jnp.int32),)
 
     carry0 = (k_pool, v_pool, k_scale, v_scale, cur, pos, fsm_state, active,
               eos0, nbytes,
               tokens_left, out, jnp.zeros((B,), jnp.int32), key,
               jnp.zeros((), jnp.int32), jnp.zeros((B,), jnp.int32),
-              _conf_init(B), *moe0)
+              _conf_init(B), *counts0)
 
     def cond(c):
         active, step = c[7], c[14]
@@ -394,7 +396,7 @@ def paged_chunk_decode_loop(
 
     def body(c):
         (kp, vp, ksc, vsc, cur, pos, state, active, eos, nbytes, left, out, n,
-         key, step, poison, conf, *moe) = c
+         key, step, poison, conf, *counts) = c
         with jax.named_scope("loop_carry"):
             out = out.at[jnp.arange(B), jnp.minimum(n, chunk_steps - 1)].set(
                 jnp.where(active, cur, out[jnp.arange(B), jnp.minimum(n, chunk_steps - 1)])
@@ -409,7 +411,7 @@ def paged_chunk_decode_loop(
             params, cfg, step_tok[:, None], write_pos[:, None], kp, vp,
             block_tables, rules=rules, attn_impl=kernels, write_mask=active,
             trash_idx=trash_idx, k_scale=ksc, v_scale=vsc, kv_quant=kv_quant,
-            **moe_kw,
+            **count_kw,
         )
         raw = logits[:, 0, :]
         if nan_inject is not None:
@@ -436,7 +438,7 @@ def paged_chunk_decode_loop(
             active = ok & ~stop
         return (kp, vp, ksc, vsc, cur, pos, state, active, eos, nbytes, left,
                 out, n, key, step + 1, poison, conf,
-                *(m + s for m, s in zip(moe, stats)))
+                *(c + s for c, s in zip(counts, stats)))
 
     def ff_body(c):
         # the dense ff_body's paged twin: cur + its state's forced chain in
@@ -448,7 +450,7 @@ def paged_chunk_decode_loop(
         # the engine's decode_chunk grew every live row's table to cover a
         # full ff chunk before dispatch.
         (kp, vp, ksc, vsc, cur, pos, state, active, eos, nbytes, left, out, n,
-         key, step, poison, conf, *moe) = c
+         key, step, poison, conf, *counts) = c
         with jax.named_scope("loop_carry"):
             # dead-at-entry fence (see the dense ff_body): a negative state
             # wraps the ff_tokens gather — poison it out before it emits
@@ -501,7 +503,7 @@ def paged_chunk_decode_loop(
             params, cfg, blk_tok, blk_pos, kp, vp,
             block_tables, rules=rules, attn_impl=kernels, write_mask=active,
             trash_idx=trash_idx, k_scale=ksc, v_scale=vsc, kv_quant=kv_quant,
-            **moe_kw,
+            **count_kw,
         )
         logits_k = jnp.take_along_axis(logits, k[:, None, None], axis=1)[:, 0, :]
         if nan_inject is not None:
@@ -529,10 +531,10 @@ def paged_chunk_decode_loop(
             active = ok & ~stop
         return (kp, vp, ksc, vsc, cur, pos, state, active, eos, nbytes, left,
                 out, n, key, step + 1, poison, conf,
-                *(m + s for m, s in zip(moe, stats)))
+                *(c + s for c, s in zip(counts, stats)))
 
     (k_pool, v_pool, k_scale, v_scale, cur, pos, state, active, eos, nbytes,
-     left, out, n, _, fwds, poison, conf, *moe) = (
+     left, out, n, _, fwds, poison, conf, *counts) = (
         jax.lax.while_loop(cond, ff_body if use_ff else body, carry0)
     )
     out = out[:, : cap if use_ff else chunk_steps]
@@ -553,7 +555,7 @@ def paged_chunk_decode_loop(
             conf = tuple(put(c0, c) for c0, c in zip(_conf_init(Bf), conf))
     return (out, n, eos, k_pool, v_pool,
             k_scale, v_scale, cur, pos, state, active, nbytes, left, fwds,
-            poison, conf, *moe)
+            poison, conf, *counts)
 
 
 class PagedDecodeEngine(DecodeEngine):
@@ -1211,7 +1213,7 @@ class PagedDecodeEngine(DecodeEngine):
         # absent at the full width, so that call is the one it always was
         compact = {} if rows is None else {"rows_idx": jnp.asarray(rows)}
         out, n, eos, self.k_pool, self.v_pool, self.k_scale, self.v_scale, \
-            cur, pos, fsm, active, nbytes, left, fwds, pois, conf, *moe = (
+            cur, pos, fsm, active, nbytes, left, fwds, pois, conf, *counts = (
                 paged_chunk_decode_loop(
                     self.params, self.cfg, self.k_pool, self.v_pool, self.block_tables,
                     cur, pos, fsm, active, nbytes, tokens_left,
@@ -1235,7 +1237,8 @@ class PagedDecodeEngine(DecodeEngine):
             fwds=fwds, poison=pois,
             rows=self.batch_slots if rows is None else len(rows),
             conf=conf if self.quality_lanes else None,
-            moe=moe[0] if moe else None)  # a routed model's expert-row counts
+            moe=counts[0] if len(counts) > 1 else None,  # a routed model's expert-row counts
+            attn=counts[-1])  # the attention row-blocks, common and all
 
     def spec_grow(self, span: int, active=None) -> list[int]:
         """Claim block coverage for one speculative verify step (cur + K

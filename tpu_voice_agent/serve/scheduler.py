@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.llama import MOE_STATS
+from ..ops import ATTN_STATS
 from ..utils.compilewatch import watch_compiles
 from ..utils.steplog import ALLOC_SPAN, REQUEST_SPAN, span
 from .engine import (
@@ -1106,9 +1107,9 @@ class ContinuousBatcher:
         # ``fwds`` keeps tokens-per-forward truthful under multi-token steps
         # (counting dispatches as tokens would inflate every throughput
         # gauge); ``poison`` is the quarantine's per-row fault codes below.
-        out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h, moe_h = (
+        out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h, moe_h, attn_h = (
             jax.device_get((res.out, res.n, res.active, res.eos, res.pos,
-                            res.fwds, res.poison, res.conf, res.moe)))
+                            res.fwds, res.poison, res.conf, res.moe, res.attn)))
         out_h, n_h, act_h, eos_h, pos_h, pois_h = (
             np.asarray(x) for x in (out_h, n_h, act_h, eos_h, pos_h, pois_h))
         fwds_h, rows = int(fwds_h), res.rows
@@ -1143,6 +1144,11 @@ class ContinuousBatcher:
             # are these over scheduler.forwards (docs/OBSERVABILITY.md)
             for name, v in zip(MOE_STATS, np.asarray(moe_h)):
                 m.inc(f"moe.{name}", float(v))
+        if attn_h is not None:
+            # likewise: the share of attended row-blocks the block kernel's
+            # common pass took is the first over the second
+            for name, v in zip(ATTN_STATS, np.asarray(attn_h)):
+                m.inc(f"attn.{name}", float(v))
         # saturation gauges: the signals continuous batching is tuned by —
         # backlog (queue_depth), batch occupancy (slots used / total), KV
         # page pressure (paged engines), and rolling throughput
