@@ -84,7 +84,7 @@ def test_the_olmoe_cells_cpu_rehearsal_reaches_ok():
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "benchmark/run.py", "--workload", "olmoe_flood", "--seed", "2147483651",
-         "--seconds", "3", "--trace", "0"],
+         "--seconds", "8", "--trace", "0"],  # 3 until PR 32: beside five busy workers no plan ended inside 3 s
         cwd=mf.ROOT, env=env, capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
     ref_line = next(ln for ln in out.stdout.splitlines() if "reference olmoe_decoder:" in ln)
@@ -93,3 +93,49 @@ def test_the_olmoe_cells_cpu_rehearsal_reaches_ok():
     assert result["failed"] == 0 and result["attempted"] > 0
     assert result["correct"] is False and result["device"]["platform"] == "cpu"  # a rehearsal is never a result
     assert {"setup_s", "out_tokens_per_s"} <= set(result["metrics"])
+
+
+def test_the_phi4flash_configuration_keeps_every_published_number():
+    """The catalog's ``config`` for Phi-4-mini-flash-reasoning, key for key;
+    ``reduced`` is empty, so none may differ; the sizes the source does not
+    carry are keys of their own and listed under ``assumed``."""
+    published = {
+        "embd_pdrop": 0, "hidden_act": "silu", "hidden_size": 2560, "intermediate_size": 10240,
+        "layer_norm_eps": 1e-05, "max_position_embeddings": 262144, "mb_per_layer": 2,
+        "model_type": "phi4flash", "num_attention_heads": 40, "num_hidden_layers": 32,
+        "num_key_value_heads": 20, "resid_pdrop": 0, "sliding_window": 512,
+        "tie_word_embeddings": True, "mlp_bias": False, "lm_head_bias": False, "vocab_size": 200064}
+    entry = next(c for c in M["configs"] if c["name"] == "phi-4-mini-flash-reasoning-int8")
+    conf = mf.load_json(entry["file"])
+    assert entry["reduced"] == [] and entry["source"] == conf["source"]
+    assert {k: conf[k] for k in published} == published
+    assumed = " ".join(conf["assumed"])
+    assert all(k in assumed and k in conf for k in ("ssm_d_inner", "ssm_d_state", "ssm_d_conv", "ssm_dt_rank"))
+    mistral = mf.load_json("benchmark/configs/mistral-7b-v0.1-int8.json")
+    same = lambda c: {k: v for k, v in c["serving"].items() if k != "weights_seed"}
+    assert same(conf) == same(mistral)  # the same pool, slots, buckets and knobs
+    cell = next(w for w in M["workloads"] if w["name"] == "phi4flash_flood")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (entry["name"], "parse_flood", 1)
+    rate = next(m for m in M["end_to_end"] if m["name"] == "out_tokens_per_s")
+    assert rate["workloads"][-1] == "phi4flash_flood" and rate["bound"] == 0.015
+
+
+def test_the_phi4flash_cells_cpu_rehearsal_reaches_ok():
+    """``benchmark/run.py`` on the CPU at the rehearsal's widths: the builder
+    serves the hybrid decoder through ``brain._wrap_batched`` ->
+    ``ContinuousBatcher`` -> ``PagedDecodeEngine`` with no entry point, knob
+    or environment variable of its own, no ``/parse`` fails, and the
+    comparison with the plain reference ends ``-> ok``."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload", "phi4flash_flood", "--seed", "3000000017",
+         "--seconds", "4", "--trace", "0"],
+        cwd=mf.ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    ref_line = next(ln for ln in out.stdout.splitlines() if "reference sambay_decoder:" in ln)
+    assert ref_line.endswith("-> ok"), ref_line
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["failed"] == 0  # a plan of these seeded weights runs ~240 tokens: none need end inside 4 s
+    assert result["correct"] is False and result["device"]["platform"] == "cpu"  # a rehearsal is never a result
+    assert {"setup_s", "out_tokens_per_s"} <= set(result["metrics"])  # the layers' counters: tests/test_hybrid_decoder.py

@@ -329,6 +329,93 @@ def test_the_compacted_chunk_program_compiles_at_published_widths(tpu_devices, m
     assert f"bf16[{R},9," in text and f"bf16[{B},9," not in text  # the forwards run at R rows
 
 
+def _hybrid_engine(monkeypatch):
+    """The ``phi4flash_flood`` cell's engine (published widths, a two-block
+    pool: the real one is a shape below), its configuration file's sizes and
+    abstract weights, with the kernels told they are not interpreted."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from benchmark.builders import sambay_stack
+    from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
+    from tpu_voice_agent.serve import PagedDecodeEngine
+
+    for mod in ("paged_attention", "selective_scan"):
+        monkeypatch.setattr(sys.modules[f"tpu_voice_agent.ops.{mod}"], "on_cpu", lambda: False)
+    conf = json.loads((Path(__file__).parents[1] / "benchmark" / "configs"
+                       / "phi-4-mini-flash-reasoning-int8.json").read_text())
+    dims = sambay_stack.model_dims(conf, False)
+    m, s = dims["model"], dims["serving"]
+    eng = PagedDecodeEngine(
+        cfg=sambay_stack.sambay_config(m, s), tokenizer=default_tokenizer(), quant=s["quant"],
+        batch_slots=s["batch_slots"], block_size=s["block_size"], pool_blocks=2, max_len=s["max_len"],
+        prefill_buckets=tuple(s["prefill_buckets"]), fast_forward=s["fast_forward"], init_weights=False)
+    params = jax.eval_shape(lambda: sambay_stack.make_params(eng.cfg, s["weights_seed"]))
+    return eng, s, params
+
+
+def _hybrid_pools(eng, s, S):
+    from tpu_voice_agent.models.sambay import cache_spec
+
+    spec = cache_spec(eng.cfg, eng.batch_slots)
+    kv = S((spec["kv_layers"], s["pool_blocks"], eng.block_size, spec["kv_heads"], spec["kv_head_dim"]), BF16)
+    return {"kv": kv, "conv": S(*spec["conv"])}, {"kv": kv, "ssm": S(*spec["ssm"])}
+
+
+@pytest.mark.parametrize("width", [pytest.param("full", marks=pytest.mark.slow), "compact"])  # the chip runs "full" in every check
+def test_the_hybrid_chunk_program_compiles_at_published_widths(tpu_devices, monkeypatch, width):
+    """Phi-4-mini-flash-reasoning's decode chunk as ``phi4flash_flood`` serves
+    it — 32 layers at published widths, int8 weights, 9 K/V planes of 10 packed
+    heads of 128 over the 200-block pool, the per-slot convolution tails and
+    float32 states riding the pools, the 200 064-wide head on one position a
+    row — at the full width and at the compacted one (8 rows; no chip has run
+    that one: PERF.md section 7, ``phi4flash_solo``). Mosaic compiles the
+    block kernel with and without the window and the selective scan."""
+    from tpu_voice_agent.serve import paged
+
+    eng, s, params = _hybrid_engine(monkeypatch)
+    B, R, cfg = eng.batch_slots, eng.compact_rows, eng.cfg
+    assert (B, R) == (32, 8) and eng.hybrid
+    chip = SingleDeviceSharding(tpu_devices[0])
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    shapes = lambda tree: jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), tree)
+    k_pool, v_pool = _hybrid_pools(eng, s, S)
+    rows = {"rows_idx": S((R,), I32)} if width == "compact" else {}
+    compiled = paged.paged_chunk_decode_loop.__wrapped__.lower(
+        shapes(params), cfg, k_pool, v_pool,
+        S((B, eng.max_blocks + 1), I32), S((B,), I32), S((B,), I32), S((B,), I32), S((B,), jnp.bool_),
+        S((B,), I32), S((B,), I32), shapes(eng.tables_ff), shapes(eng.byte_len_table),
+        shapes(jax.random.PRNGKey(0)), S((), F32), S((), I32), trash_idx=S((B,), I32), rules=None,
+        logit_mask=None if eng.logit_mask is None else shapes(eng.logit_mask), **rows,
+        chunk_steps=16, greedy=True, constrained=True, kernels="pallas", eos_id=eng.eos_id,
+        pad_id=eng.pad_id, max_len=eng.max_len, kv_quant=None, quality_lanes=eng.quality_lanes).compile()
+    text = compiled.as_text()
+    # windowed and full attention and a scan in the front scan's body and again in
+    # the full pair's, the cross-attention in the back scan's
+    assert text.count("tpu_custom_call") == 5
+    n = R if width == "compact" else B
+    # the head runs on one position a row: no (rows, 9, vocabulary) or (rows * 9, vocabulary) logits
+    assert f"f32[{n},200064]" in text and f"{n},9,200064]" not in text and f"[{9 * n},200064]" not in text
+
+
+@pytest.mark.parametrize("bucket", [64])
+def test_the_hybrid_suffix_prefill_compiles_at_published_widths(tpu_devices, monkeypatch, bucket):
+    """An admission's forward: one row, a suffix bucket behind the cached
+    prefix, the covered blocks gathered, the scan masked to the real tokens."""
+    from tpu_voice_agent.models import llama
+
+    eng, s, params = _hybrid_engine(monkeypatch)
+    chip = SingleDeviceSharding(tpu_devices[0])
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    shapes = lambda tree: jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), tree)
+    k_pool, v_pool = _hybrid_pools(eng, s, S)
+    llama.forward_paged.__wrapped__.lower(
+        shapes(params), eng.cfg, S((1, bucket), I32), S((1, bucket), I32), k_pool, v_pool,
+        S((1, eng.max_blocks + 1), I32), attn_impl="pallas", gather_blocks=8,
+        n_real=S((1,), I32)).compile()
+
+
 @pytest.mark.slow
 def test_sharded_kernels_compile_on_2x2(tpu_devices):
     """The shard_map variants the dp x tp serving mesh traces (batch over

@@ -123,7 +123,9 @@ def test_nested_stage_spans_are_taken_out_of_the_stage_around_them():
             with span(ALLOC_SPAN):  # the engine's prefill_slot
                 time.sleep(0.001)
                 with span(PREFILL_STAGE_SPAN), span(PREFILL_CALL_SPAN):
-                    time.sleep(0.003)
+                    # 3 ms until PR 32: beside busy workers a 1 ms sleep ran 6 ms
+                    # and "alloc < call" failed on the scheduler, not on the spans
+                    time.sleep(0.015)
     time.sleep(0.002)
     t.stage("sched.decode_dispatch")
     with span("sched.decode.draft"):
@@ -132,14 +134,14 @@ def test_nested_stage_spans_are_taken_out_of_the_stage_around_them():
     t.stage("sched.release")
     rec = t.finish()
     st = rec["stages"]
-    assert 5.9 <= st["prefill"] < 0.9 * (st["prefill"] + st["admit"])
+    assert 29.9 <= st["prefill"] < 0.95 * (st["prefill"] + st["admit"])
     assert st["admit"] >= 3.9 and st["draft"] >= 1.9 and st["decode"] >= 0.9
     assert abs(sum(st.values()) - rec["wall_ms"]) <= 5e-4 * (len(st) + 1)
     # one ledger entry per request span, attributes and parts together
     assert [a["rid"] for a in rec["admissions"]] == [7, 8]
     a = rec["admissions"][0]
     assert a["queue_ms"] == 1.5 and a["prompt_tokens"] == 12
-    assert a["tokenize_ms"] >= 0.9 and a["prefill_call_ms"] >= 2.9
+    assert a["tokenize_ms"] >= 0.9 and a["prefill_call_ms"] >= 14.9
     # a part inside another is taken out of it: alloc is not alloc + call
     assert 0.9 <= a["alloc_ms"] < a["prefill_call_ms"]
     parts = a["tokenize_ms"] + a["alloc_ms"] + a["prefill_call_ms"]

@@ -108,8 +108,20 @@ PRESETS: dict[str, LlamaConfig] = {
 # ---------------------------------------------------------------- params
 
 
+def _hybrid(cfg) -> bool:
+    """``cfg`` is a ``models.sambay.SambaYConfig``: the one other decoder
+    family the paged engine serves. This module's entry points the engine
+    calls (``init_params``, ``quantize_params``, ``forward_paged``) hand on to
+    that module's, so the engine has ONE path for both."""
+    return not isinstance(cfg, LlamaConfig)
+
+
 def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     """Random init. Layer weights are stacked on a leading n_layers axis."""
+    if _hybrid(cfg):
+        from . import sambay
+
+        return sambay.init_params(cfg, key, dtype)
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     d, f, hd = cfg.dim, cfg.ffn_dim, cfg.head_dim
     nq, nkv, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
@@ -215,6 +227,10 @@ def quantize_params(params: dict) -> dict:
     """Weight-only symmetric int8, per-output-channel scales. Norms and the
     embedding table (a gather, already cheap) stay in their original dtype;
     every matmul weight becomes {"q": int8, "s": f32} resolved by _w()."""
+    if "layers" not in params:  # a models.sambay tree
+        from . import sambay
+
+        return sambay.quantize_params(params)
 
     quant = quantize_leaf
 
@@ -651,7 +667,7 @@ def forward(
 @watch_compiles("llama.forward_paged")
 @partial(jax.jit, static_argnames=("cfg", "rules", "attn_impl", "fresh_block",
                                    "gather_blocks", "kv_quant", "moe_stats",
-                                   "attn_stats"),
+                                   "attn_stats", "hybrid_stats"),
          donate_argnames=("k_pool", "v_pool", "k_scale", "v_scale"))
 def forward_paged(
     params: dict,
@@ -689,6 +705,13 @@ def forward_paged(
     attn_stats: bool = False,  # also return ``ops.ATTN_STATS``, (2,) int32:
     # the row-blocks the block kernel's common pass took this forward and the
     # row-blocks live rows attend in all (the chunk loops carry them likewise)
+    n_real: jax.Array | None = None,  # (B,) int32 — a model with a RECURRENT
+    # state (models.sambay) advances it over a row's first n_real positions
+    # and no others (None: all T of a live row); a decoder whose state is K/V
+    # alone never looks: absent, the traced program is the one it was
+    logit_pos: jax.Array | None = None,  # (B,) int32, that model only: the
+    # head runs on this one position of each row
+    hybrid_stats: bool = False,  # that model only: also ``sambay.HYBRID_STATS``
 ):
     """The paged twin of ``forward`` (parity-tested): sequences own
     non-contiguous pool blocks via per-row block tables (SURVEY.md §7
@@ -712,6 +735,18 @@ def forward_paged(
     are None when ``kv_quant`` is None — then, with ``moe_stats``, the
     forward's routed-expert counts, then, with ``attn_stats``, its attention
     row-block counts."""
+    if _hybrid(cfg):
+        from . import sambay
+
+        if rules is not None or kv_quant is not None:
+            raise sambay.StateNotCarried(
+                "a mesh shards, and KV_QUANT re-stores, K/V blocks alone: neither "
+                "carries the recurrent state of a SambaYConfig's requests")
+        return sambay.forward_paged(
+            params, cfg, tokens, positions, k_pool, v_pool, block_tables,
+            attn_impl=attn_impl, write_mask=write_mask, trash_idx=trash_idx,
+            gather_blocks=gather_blocks, n_real=n_real, logit_pos=logit_pos,
+            hybrid_stats=hybrid_stats, attn_stats=attn_stats)
     B, T = tokens.shape
     L, N, bs = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     moe_stats = moe_stats and cfg.n_experts > 0  # a dense model has none

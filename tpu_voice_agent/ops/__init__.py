@@ -11,6 +11,8 @@ Here the hot ops of the in-tree models get hand-written Pallas kernels:
   cache, the per-step hot op of the decode loop
 - ``masked_argmax``: fused grammar-mask + argmax over the vocab, the
   sampling half of grammar-constrained decoding
+- ``selective_scan``: the state-space recurrence over per-slot float32 state
+  planes, advanced in place (``models.sambay``'s recurrent layers)
 
 Every kernel has a pure-jnp reference twin (``*_reference``) that the
 correctness tests and ``chip_smoke.py`` compare it against; kernels run
@@ -55,6 +57,7 @@ from .kvquant import (
     kv_store_dtype,
     quantize_kv,
 )
+from .selective_scan import selective_scan, selective_scan_reference
 from .paged_attention import (
     ATTN_STATS,
     BlockSplit,
@@ -121,4 +124,6 @@ __all__ = [
     "paged_attention_reference",
     "sharded_paged_attention",
     "sharded_paged_attention_quant",
+    "selective_scan",
+    "selective_scan_reference",
 ]

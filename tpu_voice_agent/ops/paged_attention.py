@@ -579,6 +579,8 @@ def common_block_split(
     q_positions: jax.Array,  # (B, T) int32
     live: jax.Array | None,  # (B,) bool — None: every row
     bs: int,
+    window: int | None = None,  # a windowed layer: a query sees its last
+    # ``window`` positions, its own among them
 ) -> BlockSplit:
     """The split, from what the kernel is handed and nothing else.
 
@@ -591,7 +593,12 @@ def common_block_split(
     and (j+1)*bs <= the smallest query position of any rider: every query
     of every rider sees the whole block (no mask), and it is never a block
     this forward writes (a row writes at its query positions, all of them at
-    or past S*bs)."""
+    or past S*bs).
+
+    Under a ``window`` no row rides (a block most rows hold in common lies
+    before most rows' windows, or is cut by one: the common pass is the full
+    layers') and a row's walk starts at the block that holds the first
+    position any of its queries sees."""
     bt = block_tables.astype(jnp.int32)
     B, M = bt.shape
     qp = q_positions.astype(jnp.int32)
@@ -605,8 +612,12 @@ def common_block_split(
     s_pos = jnp.min(jnp.where(cand, jnp.min(qp, axis=1) // bs, M))
     S = jnp.where(2 * jnp.sum(cand) >= jnp.maximum(jnp.sum(live), 1),
                   jnp.minimum(s_agree, s_pos), 0)
+    if window is not None:
+        S = jnp.zeros_like(S)
     rides = cand & (S > 0)
     first = jnp.where(rides, S, 0)
+    if window is not None:
+        first = jnp.maximum(jnp.min(qp, axis=1) - (window - 1), 0) // bs
     last = jnp.minimum(jnp.max(qp, axis=1) // bs, M - 1)
     n = jnp.where(live, last - first + 1, 0)
     ends = jnp.cumsum(n)
@@ -644,6 +655,7 @@ def _softmax_tile(q, k, v, valid, m_prev, l_prev, acc_prev, scale: float):
 def _paged_block_kernel(
     qpos_ref,  # SMEM (B*T,)
     meta_ref,  # SMEM (5,): [layer, S, items, riders, sub-chunks that hold one]
+    # (``windowed``: (6,), then the window — a query sees that many positions)
     block_ref,  # SMEM (max_blocks + B*max_blocks,): each item's pool block ...
     row_ref,  # ... row ...
     tile_ref,  # ... and table column
@@ -665,6 +677,7 @@ def _paged_block_kernel(
     bs: int,
     Rp: int,  # query rows a batch row holds in the layout (T*group, padded)
     sub: int,  # query rows a sub-chunk of the common pass: whole batch rows
+    windowed: bool = False,
 ):
     w = pl.program_id(0)
     S, n, n_riders, n_sub = meta_ref[1], meta_ref[2], meta_ref[3], meta_ref[4]
@@ -720,6 +733,8 @@ def _paged_block_kernel(
                 (jax.lax.broadcasted_iota(jnp.int32, (Rp, 1), 0) // group) == i,
                 qpos_ref[b * T + i], qpos_rows)
         valid = k_pos <= qpos_rows  # causal + frontier in one mask
+        if windowed:  # the boundary block, masked per query position
+            valid = jnp.logical_and(valid, k_pos > qpos_rows - meta_ref[5])
         for h in range(nkv):
             advance(h, at, Rp, k_ref[0, 0, :, h].astype(jnp.float32),
                     v_ref[0, 0, :, h].astype(jnp.float32), valid)
@@ -741,10 +756,12 @@ _VMEM_LIMIT = 64 << 20
 _STATE_BYTES = 48 << 20
 
 
-def _rows_that_fit(B: int, Rp: int, nkv: int, hd: int, itemsize: int) -> int:
+def _rows_that_fit(B: int, Rp: int, nkv: int, hd: int, itemsize: int,
+                   out_itemsize: int | None = None) -> int:
     """The largest divisor of B whose rows' resident state (queries and
     outputs, double-buffered; acc, m, l) stays inside ``_STATE_BYTES``."""
-    per_row = nkv * Rp * (4 * hd * itemsize + 4 * hd + 2 * 4 * 128)
+    out_itemsize = itemsize if out_itemsize is None else out_itemsize
+    per_row = nkv * Rp * (2 * hd * (itemsize + out_itemsize) + 4 * hd + 2 * 4 * 128)
     return max([c for c in range(1, B + 1) if B % c == 0 and c * per_row <= _STATE_BYTES],
                default=1)
 
@@ -760,7 +777,7 @@ def _sub_rows(B: int) -> int:
 
 
 # analyze: ok[jit-sentinel] -- kernel wrapper traced inline by the watched engine/stt loops, never a serving dispatch entry point
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "out_dtype"))
 def paged_block_attention(
     q: jax.Array,  # (B, T, nq, hd) — a small block of queries per row
     k_pool: jax.Array,  # (L, N, bs, nkv, hd)
@@ -771,9 +788,14 @@ def paged_block_attention(
     live: jax.Array | None = None,  # (B,) bool — rows whose output is read
     split: BlockSplit | None = None,  # common_block_split of the three
     # above, when the caller has it already (one forward, many layers)
+    window: jax.Array | None = None,  # scalar int32: query i attends its last
+    # ``window`` positions alone (a traced value, so that layers of one scan
+    # may differ; ``split`` is then the caller's, made with that window)
     *,
     scale: float | None = None,
     interpret: bool | None = None,
+    out_dtype=None,  # None: q's. float32 where what follows takes a
+    # DIFFERENCE of outputs (models.sambay): the accumulator is float32 anyway
 ) -> jax.Array:
     """Returns (B, T, nq, hd). Query i attends positions [0, q_positions
     [b, i]] of its row's paged sequence (the caller has already scattered
@@ -789,12 +811,17 @@ def paged_block_attention(
     # padded to whole sublane tiles so that a row is an aligned slice
     R = T * group
     Rp = -(-R // 16) * 16
-    Bg = _rows_that_fit(B, Rp, nkv, hd, q.dtype.itemsize)
+    out_dtype = q.dtype if out_dtype is None else jnp.dtype(out_dtype)
+    Bg = _rows_that_fit(B, Rp, nkv, hd, q.dtype.itemsize, out_dtype.itemsize)
     if Bg < B:
+        if window is not None:
+            raise NotImplementedError(
+                "a windowed layer's split is its caller's, made for one group of rows")
         return jnp.concatenate([
             paged_block_attention(
                 q[g:g + Bg], k_pool, v_pool, block_tables[g:g + Bg], q_positions[g:g + Bg],
-                layer, None if live is None else live[g:g + Bg], scale=scale, interpret=interpret)
+                layer, None if live is None else live[g:g + Bg], scale=scale, interpret=interpret,
+                out_dtype=out_dtype)
             for g in range(0, B, Bg)])
     if split is None:
         split = common_block_split(block_tables, q_positions, live, bs)
@@ -807,7 +834,8 @@ def paged_block_attention(
         (1, 1, bs, nkv, hd), lambda w, qpos, meta, block, *_: (meta[0], block[w], 0, 0, 0))
     out = pl.pallas_call(
         functools.partial(_paged_block_kernel, scale=scale, nkv=nkv, group=group, T=T,
-                          bs=bs, Rp=Rp, sub=Bc * Rp),
+                          bs=bs, Rp=Rp, sub=Bc * Rp,
+                          **({} if window is None else {"windowed": True})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=(jnp.maximum(split.n_items, 1),),
@@ -821,13 +849,14 @@ def paged_block_attention(
                 pltpu.VMEM((nkv, bs, hd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((nkv, B * Rp, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((nkv, B * Rp, hd), out_dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="paged_block_attention",
     )(q_positions.astype(jnp.int32).reshape(-1),
       jnp.stack([jnp.reshape(layer, ()).astype(jnp.int32), split.n_common, split.n_items,
-                 split.n_riders, -(-split.n_riders // Bc)]),
+                 split.n_riders, -(-split.n_riders // Bc),
+                 *(() if window is None else (jnp.reshape(window, ()).astype(jnp.int32),))]),
       split.item_block, split.item_row, split.item_tile, split.slot,
       qg, k_pool, v_pool)
     # a row without an item was never written: zeros, not what the buffer held

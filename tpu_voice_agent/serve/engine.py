@@ -123,6 +123,8 @@ class ChunkResult:
     # (margin sum/min, entropy sum, forced, decisions); None with them off
     moe: Any = None  # a ROUTED model's llama.MOE_STATS summed over the
     # chunk; None for a dense model (its chunk program has no such output)
+    hybrid: Any = None  # a model with a recurrent state: sambay.HYBRID_STATS
+    # summed over the chunk, (4,) int32; None for every other model
     attn: Any = None  # the paged chunk loop's ops.ATTN_STATS summed over the
     # chunk, (2,) int32: row-blocks the block kernel's common pass took, and
     # row-blocks live rows attended in all; None from every other engine
@@ -723,6 +725,17 @@ class DecodeEngine:
                 raise ValueError(
                     f"model vocab {vocab} < tokenizer vocab {tokenizer.vocab_size}"
                 )
+        if not isinstance(base, LlamaConfig) and self._alloc_dense_cache:
+            raise ValueError(f"a {type(base).__name__} is served by PagedDecodeEngine alone: its "
+                             "requests' state lives in the paged pool's per-slot planes")
+        if not isinstance(base, LlamaConfig) and (
+                mesh is not None or (spec is not None and getattr(spec, "k", 0))):
+            from ..models.sambay import StateNotCarried
+
+            raise StateNotCarried(
+                "a mesh shards K/V blocks and weights of a LlamaConfig's layout, and "
+                "speculative decoding rolls back K/V alone (overwrite-before-attend): "
+                f"neither carries the recurrent state of a {type(base).__name__}'s requests")
         if base.n_experts > 0 and base.moe_impl == "auto":
             # THE dispatch choice of a routed model, made once, here, from
             # where the engine runs: the grouped-matmul kernel on a single

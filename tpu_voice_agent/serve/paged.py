@@ -37,13 +37,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..grammar.fsm import fsm_advance
-from ..models.llama import forward_paged
+from ..models.llama import _hybrid, forward_paged
 from ..utils.compilewatch import get_compile_watcher, watch_compiles
 from ..utils.steplog import (
     ALLOC_SPAN,
     FIRST_TOKEN_SPAN,
     PREFILL_CALL_SPAN,
     PREFILL_STAGE_SPAN,
+    STATE_RESTORE_SPAN,
     span,
 )
 from .engine import (
@@ -233,17 +234,42 @@ def record_pool_gauges(alloc: "BlockAllocator", engine=None) -> None:
         m.set_gauge("paged.kv_bytes_total", float(alloc.usable_blocks * bpb))
 
 
+def kv_planes(pool):
+    """The (L, N, bs, nkv, hd) K or V planes of a pool. A decoder whose
+    requests hold K/V alone has nothing else there; one with a recurrent
+    state (``models.sambay``) keeps its per-slot planes beside them in a
+    pytree, under other keys, and everything that moves BLOCKS reads and
+    writes ``["kv"]``."""
+    return pool["kv"] if isinstance(pool, dict) else pool
+
+
 @watch_compiles("paged._scatter_blocks")
 @partial(jax.jit, donate_argnames=("k_pool", "v_pool"))
 def _scatter_blocks(k_pool, v_pool, src_k, src_v, dst_idx):
     """Write (L, n, nkv, hd) rows into the flat pool at dst_idx (n,)."""
-    L, N, bs = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
-    shp = k_pool.shape
-    kf = k_pool.reshape(L, N * bs, *shp[3:])
-    vf = v_pool.reshape(L, N * bs, *shp[3:])
+    kp, vp = kv_planes(k_pool), kv_planes(v_pool)
+    L, N, bs = kp.shape[0], kp.shape[1], kp.shape[2]
+    shp = kp.shape
+    if isinstance(k_pool, dict):
+        # a hybrid model's planes are written as they are shaped, (block,
+        # offset): XLA relays their flat view out around a scatter (see
+        # models.sambay.forward_paged), 13 ms a call (my chip run, PR 32)
+        at = (slice(None), dst_idx // bs, dst_idx % bs)
+        return ({**k_pool, "kv": kp.at[at].set(src_k)}, {**v_pool, "kv": vp.at[at].set(src_v)})
+    kf = kp.reshape(L, N * bs, *shp[3:])
+    vf = vp.reshape(L, N * bs, *shp[3:])
     kf = kf.at[:, dst_idx].set(src_k)
     vf = vf.at[:, dst_idx].set(src_v)
     return kf.reshape(shp), vf.reshape(shp)
+
+
+@watch_compiles("paged._restore_state")
+@partial(jax.jit, donate_argnames=("k_pool", "v_pool"))
+def _restore_state(k_pool, v_pool, conv, ssm, slot):
+    """A slot's recurrent state <- a snapshot (n_layers, ...) of it: the
+    convolution tails in ``k_pool``, the float32 states in ``v_pool``."""
+    return ({**k_pool, "conv": k_pool["conv"].at[:, slot].set(conv)},
+            {**v_pool, "ssm": v_pool["ssm"].at[:, slot].set(ssm)})
 
 
 @watch_compiles("paged._scatter_blocks_quant")
@@ -362,10 +388,17 @@ def paged_chunk_decode_loop(
             if nan_inject is not None:
                 nan_inject = nan_inject[rows_idx]
     B = cur.shape[0]
+    # a model whose requests hold a recurrent state beside their blocks
+    # (``models.sambay``; static, on the configuration's type) compiles a
+    # variant: a table row's last column is its slot's state index, not a
+    # block; every forward is told how many of a row's positions are real;
+    # the head runs on the one position a row reads; and one more carry and
+    # output, ``sambay.HYBRID_STATS`` summed over the chunk, (4,) int32
+    hybrid = _hybrid(cfg)
     # the engine's max_len, NOT the block-rounded table capacity — with a
     # non-multiple max_len the dense loop stops at max_len-1 and the paged
     # loop must match it token for token
-    max_pos = block_tables.shape[1] * k_pool.shape[2]
+    max_pos = (block_tables.shape[1] - hybrid) * kv_planes(k_pool).shape[2]
     if max_len is not None:
         max_pos = min(max_pos, max_len)
     use_ff = constrained and tables.ff_tokens is not None
@@ -381,8 +414,10 @@ def paged_chunk_decode_loop(
     # always, last; in the routed variant the expert rows before them (for a
     # dense model that carry and output do not exist: tests/test_olmoe.py)
     routed = cfg.n_experts > 0
-    count_kw = {"attn_stats": True, **({"moe_stats": True} if routed else {})}
-    counts0 = ((jnp.zeros((4,), jnp.int32),) if routed else ()) + (jnp.zeros((2,), jnp.int32),)
+    count_kw = {"attn_stats": True, **({"moe_stats": True} if routed else {}),
+                **({"hybrid_stats": True} if hybrid else {})}
+    counts0 = (((jnp.zeros((4,), jnp.int32),) if routed or hybrid else ())
+               + (jnp.zeros((2,), jnp.int32),))
 
     carry0 = (k_pool, v_pool, k_scale, v_scale, cur, pos, fsm_state, active,
               eos0, nbytes,
@@ -411,7 +446,7 @@ def paged_chunk_decode_loop(
             params, cfg, step_tok[:, None], write_pos[:, None], kp, vp,
             block_tables, rules=rules, attn_impl=kernels, write_mask=active,
             trash_idx=trash_idx, k_scale=ksc, v_scale=vsc, kv_quant=kv_quant,
-            **count_kw,
+            **count_kw, **({"n_real": active.astype(jnp.int32)} if hybrid else {}),
         )
         raw = logits[:, 0, :]
         if nan_inject is not None:
@@ -503,9 +538,10 @@ def paged_chunk_decode_loop(
             params, cfg, blk_tok, blk_pos, kp, vp,
             block_tables, rules=rules, attn_impl=kernels, write_mask=active,
             trash_idx=trash_idx, k_scale=ksc, v_scale=vsc, kv_quant=kv_quant,
-            **count_kw,
+            **count_kw, **({"n_real": emitted, "logit_pos": k} if hybrid else {}),
         )
-        logits_k = jnp.take_along_axis(logits, k[:, None, None], axis=1)[:, 0, :]
+        logits_k = (logits[:, 0, :] if hybrid else
+                    jnp.take_along_axis(logits, k[:, None, None], axis=1)[:, 0, :])
         if nan_inject is not None:
             logits_k = jnp.where(nan_inject[:, None] & active[:, None],
                                  jnp.float32(jnp.nan), logits_k)
@@ -604,6 +640,25 @@ class PagedDecodeEngine(DecodeEngine):
         if kv_quant not in (None, "int8", "int4"):
             raise ValueError(f"KV_QUANT must be int8 or int4, got {kv_quant!r}")
         self.kv_quant = kv_quant
+        # THE cache spec, by layer kind, from the model's configuration: which
+        # layers own K/V planes (a dense or routed decoder: all of them, at its
+        # kv heads; models.sambay: the h/2 + 1 that write K/V, at its packed
+        # heads) and what a SLOT holds beside its blocks (there: a convolution
+        # tail and a float32 state for each recurrent layer; here: nothing)
+        self.hybrid = _hybrid(self.cfg)
+        if self.hybrid:
+            from ..models.sambay import cache_spec
+
+            if radix_enable is None:
+                radix_enable = os.environ.get("RADIX_ENABLE") == "1"
+            if kv_quant:
+                self._refuse_blocks_alone("KV_QUANT re-stores K/V blocks")
+            if radix_enable:
+                self._refuse_blocks_alone("radix reuse hands a slot cached K/V blocks")
+            self._cache_spec = cache_spec(self.cfg, self.batch_slots)
+        else:
+            self._cache_spec = {"kv_layers": self.cfg.n_layers, "kv_heads": self.cfg.n_kv_heads,
+                                "kv_head_dim": self.cfg.head_dim}
         if pool_blocks is None:
             # default: same worst case as dense, plus each group's trash block
             pool_blocks = self.batch_slots * self.max_blocks + self.dp
@@ -613,7 +668,7 @@ class PagedDecodeEngine(DecodeEngine):
                 f"axis ({self.dp}): each dp group owns its own block range")
         from ..ops.kvquant import kv_store_dim, kv_store_dtype
 
-        L, nkv, hd = self.cfg.n_layers, self.cfg.n_kv_heads, self.cfg.head_dim
+        L, nkv, hd = (self._cache_spec[k] for k in ("kv_layers", "kv_heads", "kv_head_dim"))
         hdp = kv_store_dim(hd, kv_quant)
         dtype = kv_store_dtype(kv_quant)
         shape = (L, pool_blocks, bs, nkv, hdp)
@@ -641,8 +696,14 @@ class PagedDecodeEngine(DecodeEngine):
                 self.v_scale = jnp.zeros(sshape, jnp.bfloat16)
             else:
                 self.k_scale = self.v_scale = None
+        if self.hybrid:
+            # the per-slot planes ride the pools as pytrees, so that whatever
+            # is handed (k_pool, v_pool, a table row) reaches a slot's state
+            self.k_pool = {"kv": self.k_pool, "conv": jnp.zeros(*self._cache_spec["conv"])}
+            self.v_pool = {"kv": self.v_pool, "ssm": jnp.zeros(*self._cache_spec["ssm"])}
+        self._prefix_state: dict | None = None  # hybrid: the state after the cached prefix
         self.allocator = BlockAllocator(pool_blocks, n_groups=self.dp)
-        self.block_tables = jnp.zeros((self.batch_slots, self.max_blocks), jnp.int32)
+        self.block_tables = self._fresh_tables()
         self._slot_shared: list[list[int]] = [[] for _ in range(self.batch_slots)]
         self._slot_owned: list[list[int]] = [[] for _ in range(self.batch_slots)]
         self._covered: list[int] = [0] * self.batch_slots  # positions with blocks
@@ -696,6 +757,15 @@ class PagedDecodeEngine(DecodeEngine):
         if self._spec_cfg is not None:
             self._build_spec()
 
+    def _fresh_tables(self):
+        """(batch_slots, max_blocks) zeros; a hybrid model's rows carry their
+        slot's state index in one more column, which no attention walk
+        reaches (``models.sambay.forward_paged`` splits it off)."""
+        tables = np.zeros((self.batch_slots, self.max_blocks + self.hybrid), np.int32)
+        if self.hybrid:
+            tables[:, -1] = np.arange(self.batch_slots)
+        return jnp.asarray(tables)
+
     def _group(self, slot: int) -> int:
         """dp group of a batch slot (slots shard over dp like the dense
         cache's batch axis: contiguous runs of batch_slots/dp)."""
@@ -716,9 +786,9 @@ class PagedDecodeEngine(DecodeEngine):
         source the HBM ledger plan and the bench capacity rows share)."""
         from ..ops.kvquant import kv_block_bytes
 
-        return kv_block_bytes(self.cfg.n_layers, self.block_size,
-                              self.cfg.n_kv_heads, self.cfg.head_dim,
-                              self.kv_quant)
+        spec = self._cache_spec
+        return kv_block_bytes(spec["kv_layers"], self.block_size, spec["kv_heads"],
+                              spec["kv_head_dim"], self.kv_quant)
 
     def _scatter_pool(self, src_k, src_v, dst_idx) -> None:
         """Pool scatter dispatch: plain bf16 write, or quantize-on-write
@@ -735,7 +805,57 @@ class PagedDecodeEngine(DecodeEngine):
 
     # ------------------------------------------------------------ prefix
 
+    def _compute_prefix_kv(self, tokens, positions, P: int, bucket: int) -> dict:
+        """A hybrid model's prefix: prefilled through ``forward_paged`` into a
+        scratch pool of the bucket's blocks and ONE scratch slot, whose state
+        after the P real positions is the snapshot every admission behind the
+        prefix starts from (``_prefix_state``). Its K/V comes back in the
+        dense layout ``set_prompt_prefix`` scatters from."""
+        if not self.hybrid:
+            return super()._compute_prefix_kv(tokens, positions, P, bucket)
+        from ..models.sambay import cache_spec
+
+        bs, spec = self.block_size, cache_spec(self.cfg, 1)
+        nb = -(-bucket // bs)
+        shape = (spec["kv_layers"], nb + 1, bs, spec["kv_heads"], spec["kv_head_dim"])
+        planes = lambda: jnp.zeros(shape, kv_planes(self.k_pool).dtype)
+        table = jnp.asarray([list(range(1, nb + 1)) + [0]], jnp.int32)  # block 0: parked writes
+        _, k, v, _, _ = forward_paged(
+            self.params, self.cfg, tokens, positions,
+            {"kv": planes(), "conv": jnp.zeros(*spec["conv"])},
+            {"kv": planes(), "ssm": jnp.zeros(*spec["ssm"])}, table,
+            attn_impl=self.kernels, fresh_block=True, n_real=jnp.asarray([P], jnp.int32))
+        self._prefix_state = {"conv": k["conv"][:, 0], "ssm": v["ssm"][:, 0]}
+        dense = lambda pool: pool["kv"][:, 1:].reshape(shape[0], 1, nb * bs, *shape[3:])[:, :, :P]
+        return {"k": dense(k), "v": dense(v)}
+
+    def _restore_slot_state(self, slot: int, snapshot: dict | None) -> None:
+        """Before a hybrid model's admission chain runs: the slot's recurrent
+        state <- the prefix's snapshot (None: zeros, a prompt from position 0).
+        One device copy; ``release_slot`` needs nothing."""
+        from ..utils import get_metrics
+
+        with span(STATE_RESTORE_SPAN):
+            if snapshot is None:
+                snapshot = {"conv": jnp.zeros_like(self.k_pool["conv"][:, 0]),
+                            "ssm": jnp.zeros_like(self.v_pool["ssm"][:, 0])}
+            self.k_pool, self.v_pool = _restore_state(
+                self.k_pool, self.v_pool, snapshot["conv"], snapshot["ssm"], jnp.int32(slot))
+        get_metrics().inc("ssm.state_restores")
+
+    def _prefill_kw(self, attn_impl: str, n_real: int) -> dict:
+        """A prefill forward's arguments that follow the model's kind: a
+        decoder whose state is K/V alone takes the layout kernel's attention
+        path; a hybrid model is told the engine's kernels (its forward picks
+        the attention path by T, its scan by this) and how many of the bucket's
+        positions are real (its states advance over those alone)."""
+        if not self.hybrid:
+            return {"rules": self.rules, "attn_impl": attn_impl}
+        return {"rules": self.rules, "attn_impl": self.kernels,
+                "n_real": jnp.asarray([n_real], jnp.int32)}
+
     def set_prompt_prefix(self, *sample_prompts: str) -> int:
+        self._prefix_state = None
         P = super().set_prompt_prefix(*sample_prompts)
         if self.radix is not None:
             # drop the whole tree BEFORE freeing the old prefix blocks: the
@@ -785,6 +905,8 @@ class PagedDecodeEngine(DecodeEngine):
         # empty table rows must still point INSIDE the slot's dp shard
         # (the sharded kernel localizes ids by subtracting the group base)
         row[len(blocks):] = self._group(slot) * self.allocator.blocks_per_group
+        if self.hybrid:
+            row = np.append(row, np.int32(slot))  # the state index, never a block
         self.block_tables = self.block_tables.at[slot].set(jnp.asarray(row))
 
     def _alloc(self, k: int, group: int) -> list[int]:
@@ -865,12 +987,16 @@ class PagedDecodeEngine(DecodeEngine):
         gb = min(gb, self.max_blocks)
         self._next_pos[slot] = n
         table_row = self.block_tables[slot][None]
+        if self.hybrid:
+            # the chain is the static prefix (radix is refused): its K/V is the
+            # shared blocks and the tail just scattered, its state the snapshot
+            self._restore_slot_state(slot, self._prefix_state)
         with span(PREFILL_CALL_SPAN):
             logits, self.k_pool, self.v_pool, self.k_scale, self.v_scale = \
                 forward_paged(
                     self.params, self.cfg, tokens, positions,
                     self.k_pool, self.v_pool, table_row,
-                    rules=self.rules, attn_impl="xla",
+                    **self._prefill_kw("xla", n - P),
                     fresh_block=False, gather_blocks=gb,
                     k_scale=self.k_scale, v_scale=self.v_scale,
                     kv_quant=self.kv_quant,
@@ -959,13 +1085,15 @@ class PagedDecodeEngine(DecodeEngine):
         self._covered[slot] = len(owned) * bs
         self._next_pos[slot] = n
         table_row = self.block_tables[slot][None]
+        if self.hybrid:
+            self._restore_slot_state(slot, None)
         # position 0 start: block-local attention, no pool gather at all
         with span(PREFILL_CALL_SPAN):
             logits, self.k_pool, self.v_pool, self.k_scale, self.v_scale = \
                 forward_paged(
                     self.params, self.cfg, tokens, positions,
                     self.k_pool, self.v_pool, table_row,
-                    rules=self.rules, attn_impl=self.kernels,
+                    **self._prefill_kw(self.kernels, n),
                     fresh_block=True, gather_blocks=None,
                     k_scale=self.k_scale, v_scale=self.v_scale,
                     kv_quant=self.kv_quant,
@@ -990,7 +1118,10 @@ class PagedDecodeEngine(DecodeEngine):
         Returns None when chunking cannot represent the prompt (padded
         span past max_len, or nothing left to compute) — the caller falls
         back to the one-shot ``prefill_slot`` path, which buckets (and
-        errors) independently."""
+        errors) independently. A hybrid model's admission is never chunked
+        (the cursor carries no count of real positions for its state): None."""
+        if self.hybrid:
+            return None
         ns = self._slot_ns.get(slot)
         self.release_slot(slot)
         if ns is not None:
@@ -1237,7 +1368,9 @@ class PagedDecodeEngine(DecodeEngine):
             fwds=fwds, poison=pois,
             rows=self.batch_slots if rows is None else len(rows),
             conf=conf if self.quality_lanes else None,
-            moe=counts[0] if len(counts) > 1 else None,  # a routed model's expert-row counts
+            # a routed model's expert-row counts, a hybrid one's state and window counts
+            moe=counts[0] if len(counts) > 1 and not self.hybrid else None,
+            hybrid=counts[0] if self.hybrid else None,
             attn=counts[-1])  # the attention row-blocks, common and all
 
     def spec_grow(self, span: int, active=None) -> list[int]:
@@ -1349,6 +1482,7 @@ class PagedDecodeEngine(DecodeEngine):
         Returns ``(k, v, k_scale | None, v_scale | None)`` shaped
         ``(L, n, bs, nkv, hd_store)`` / ``(L, n, bs, nkv)``. Serving-loop
         thread only (reads race the decode loop's pool rebinds otherwise)."""
+        self._refuse_blocks_alone("a handoff ships K/V frames")
         idx = jnp.asarray(blocks, jnp.int32)
         k = np.asarray(jax.device_get(self.k_pool[:, idx]))
         v = np.asarray(jax.device_get(self.v_pool[:, idx]))
@@ -1367,6 +1501,7 @@ class PagedDecodeEngine(DecodeEngine):
         planes ride their own scatter. ``PoolExhausted`` propagates (after
         the radix-eviction retry in ``_alloc``): the caller counts the
         clean cold fallback. Serving-loop thread only."""
+        self._refuse_blocks_alone("a handoff adopts K/V frames")
         n = int(k.shape[1])
         if self.kv_quant is not None and (k_scale is None or v_scale is None):
             raise ValueError("quantized pool adoption needs scale planes")
@@ -1396,6 +1531,14 @@ class PagedDecodeEngine(DecodeEngine):
             self.allocator.free(blocks)
             raise
         return blocks
+
+    def _refuse_blocks_alone(self, what: str) -> None:
+        if self.hybrid:
+            from ..models.sambay import StateNotCarried
+
+            raise StateNotCarried(
+                f"{what}, without the recurrent state that goes with them: "
+                f"not with a {type(self.cfg).__name__}")
 
     def warm_restart(self) -> None:
         """Paged warm restart: throw away every slot's mutable state and the
@@ -1430,8 +1573,7 @@ class PagedDecodeEngine(DecodeEngine):
         self._slot_ids = [None] * self.batch_slots
         self._slot_ns.clear()
         self._mid_prefill.clear()
-        self.block_tables = jnp.zeros(
-            (self.batch_slots, self.max_blocks), jnp.int32)
+        self.block_tables = self._fresh_tables()
         self._pressure_until = 0.0
         if self.spec is not None:
             # per-slot host contexts + drafter state are slot bookkeeping

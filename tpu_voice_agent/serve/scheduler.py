@@ -1107,9 +1107,9 @@ class ContinuousBatcher:
         # ``fwds`` keeps tokens-per-forward truthful under multi-token steps
         # (counting dispatches as tokens would inflate every throughput
         # gauge); ``poison`` is the quarantine's per-row fault codes below.
-        out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h, moe_h, attn_h = (
+        out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h, moe_h, attn_h, hybrid_h = (
             jax.device_get((res.out, res.n, res.active, res.eos, res.pos,
-                            res.fwds, res.poison, res.conf, res.moe, res.attn)))
+                            res.fwds, res.poison, res.conf, res.moe, res.attn, res.hybrid)))
         out_h, n_h, act_h, eos_h, pos_h, pois_h = (
             np.asarray(x) for x in (out_h, n_h, act_h, eos_h, pos_h, pois_h))
         fwds_h, rows = int(fwds_h), res.rows
@@ -1149,6 +1149,16 @@ class ContinuousBatcher:
             # common pass took is the first over the second
             for name, v in zip(ATTN_STATS, np.asarray(attn_h)):
                 m.inc(f"attn.{name}", float(v))
+        if hybrid_h is not None:
+            # a model with a recurrent state: positions its states advanced
+            # over / positions computed, window blocks walked / held
+            # (``sambay.HYBRID_STATS``, in its order; the names spelt out so
+            # that the metric catalog's lint finds them registered)
+            advanced, positions, walked, held = (float(v) for v in np.asarray(hybrid_h))
+            m.inc("ssm.positions_advanced", advanced)
+            m.inc("ssm.positions", positions)
+            m.inc("attn.window_blocks_walked", walked)
+            m.inc("attn.window_blocks_held", held)
         # saturation gauges: the signals continuous batching is tuned by —
         # backlog (queue_depth), batch occupancy (slots used / total), KV
         # page pressure (paged engines), and rolling throughput

@@ -102,7 +102,7 @@ def measure_hbm(engine) -> dict:
 
     weights = _tree_bytes(getattr(engine, "params", None))
     if getattr(engine, "allocator", None) is not None:
-        kv = int(engine.k_pool.nbytes + engine.v_pool.nbytes)
+        kv = _tree_bytes(engine.k_pool) + _tree_bytes(engine.v_pool)  # a hybrid model's are pytrees
         # quantized pools carry their bf16 scale planes beside the values
         for sc in (getattr(engine, "k_scale", None),
                    getattr(engine, "v_scale", None)):

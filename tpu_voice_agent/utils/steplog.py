@@ -47,6 +47,8 @@ duration into the step's record. The step itself is a
               .prefill_call          the part inside both: the jitted call
                                      alone (``.alloc`` is the engine's
                                      ``prefill_slot`` less this call)
+              .state_restore         a recurrent model's admissions only: the
+                                     prefix's state snapshot copied into the slot
       sched.decode_dispatch          stage ``decode``
         sched.decode.draft           stage ``draft``
       sched.readback                 stage ``readback``
@@ -87,6 +89,11 @@ REQUEST_SPAN = "sched.admit.request"
 ALLOC_SPAN = REQUEST_SPAN + ".alloc"
 PREFILL_CALL_SPAN = REQUEST_SPAN + ".prefill_call"
 FIRST_TOKEN_SPAN = REQUEST_SPAN + ".first_token_call"
+# a model with a recurrent state (models.sambay) alone: the copy of the
+# prefix's state snapshot into the slot, inside ``.alloc`` and taken out of
+# it like ``.prefill_call`` (``state_restore_ms`` in the admission's entry;
+# no other model's admission has the key, so it is no ``ADMISSION_PARTS``)
+STATE_RESTORE_SPAN = REQUEST_SPAN + ".state_restore"
 # one admission in code order; each is ``<part>_ms`` in its ledger entry
 ADMISSION_PARTS = ("tokenize", "alloc", "prefill_call", "first_token_call",
                    "slot_state", "bookkeeping")
