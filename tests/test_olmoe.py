@@ -386,9 +386,11 @@ def _chunk_spy(monkeypatch) -> tuple[list[int], list[str]]:
 # the attention row-block counts — and re-derived them as prescribed here:
 # a PR that changes the loop on purpose re-derives these (lower the first
 # ``decode_chunk`` dispatch as ``_chunk_spy`` does and hash it) and says so.
+# ISSUE 33 did: the confidence lanes' ``top_k`` over the vocabulary became
+# reductions (``engine._masked_conf``), in both variants.
 FULL_WIDTH_SHA256 = {
-    "dense": "89e0f7a309f21f7b47c61d7f072874fca4192bc8a79f9f25ccfced98da7fe588",
-    "routed": "2b0ba7a92de12af9e351cc831b4e550ea1420c0e95445fcd4e976ac32adb1580",
+    "dense": "401512baa13caf19f6fd9a666bd0901571ca3bc251b7120ffde235bae878d11a",
+    "routed": "ca00f47003dc18393fe2d3aacc8c0c0d25337d627fa32ca414a49faf9911f750",
 }
 
 
@@ -404,8 +406,10 @@ def test_the_fence_around_the_dense_path(model, monkeypatch):
     variant of the same program returns one more, and the four counters
     rise. Since ISSUE 29 the fence holds the compacted width out too: at the
     full width (``rows_idx`` absent) both variants lower to the text PR 28's
-    tree lowers, and nothing of the row gather or scatter is in it."""
+    tree lowers, and nothing of the row gather or scatter is in it. Since
+    ISSUE 33 neither holds a sort or a top-k over the vocabulary."""
     import hashlib
+    import re
 
     from tpu_voice_agent.serve import ContinuousBatcher, DecodeEngine, PagedDecodeEngine
     from tpu_voice_agent.services.prompts import render_prompt
@@ -426,6 +430,13 @@ def test_the_fence_around_the_dense_path(model, monkeypatch):
     assert all(r.error is None for r in out) and len(arity) == len(chunks) >= 3
     assert "rows_gather" not in texts[0] and "rows_scatter" not in texts[0] and "lm_head" in texts[0]
     assert hashlib.sha256(texts[0].encode()).hexdigest() == FULL_WIDTH_SHA256[model]
+    # ISSUE 33: with the lanes on, nothing in the program sorts a row of the
+    # vocabulary (a router may keep a top-k over its experts)
+    assert eng.quality_lanes and "quality_lanes" in texts[0] and "stablehlo.sort" not in texts[0]
+    # ``chlo.top_k(%x, k = 2) : tensor<2x619xf32> -> ...``: the width each one selects from
+    widths = re.findall(r"chlo\.top_k\([^)]*\)\s*:\s*tensor<(?:\d+x)*(\d+)x\w+>", texts[0])
+    assert str(eng.cfg.vocab_size) not in widths and (model == "routed" or not widths)
+    assert texts[0].count("chlo.top_k") == len(widths)
     snap = fresh.snapshot()
     moe_names = sorted(k for part in snap.values() if isinstance(part, dict) for k in part
                        if str(k).startswith("moe."))

@@ -106,6 +106,128 @@ def test_zero_postfence_recompiles_with_lanes_on():
     assert w.state()["post_fence_compiles"] == before
 
 
+# ------------------------------------------------------------ the lanes' top-2
+
+
+def _sorted_conf(lg, nlegal):
+    """The parent's ``_masked_conf``, kept HERE as the reference: the top-2
+    by ``jax.lax.top_k``, which the TPU lowers to a sort of the whole row
+    (ISSUE 33 took it out of the served program)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_voice_agent.serve.engine import QUALITY_MARGIN_CAP
+
+    top2 = jax.lax.top_k(lg, 2)[0]
+    margin = jnp.where(jnp.isfinite(top2[:, 1]),
+                       jnp.minimum(top2[:, 0] - top2[:, 1], QUALITY_MARGIN_CAP),
+                       QUALITY_MARGIN_CAP)
+    margin = jnp.where(jnp.isfinite(top2[:, 0]), margin, 0.0)
+    p = jax.nn.softmax(lg, axis=-1)
+    ent = -jnp.sum(jnp.where(p > 0, p * jnp.log(jnp.maximum(p, 1e-30)), 0.0),
+                   axis=-1)
+    ent = jnp.where(jnp.isfinite(top2[:, 0]), ent, 0.0)
+    return margin, ent, nlegal <= 1
+
+
+def _masked_rows(case: str, V: int):
+    """Seeded float32 rows as ``_conf_stats`` hands them on: -inf where the
+    token is illegal. Four rows a case, legal shares 0.3 % to 30 %."""
+    rng = np.random.default_rng(V * 31 + sum(map(ord, case)))
+    B = 4
+    raw = (rng.standard_normal((B, V)) * 3).astype(np.float32)
+    legal = rng.random((B, V)) < np.array([0.003, 0.01, 0.1, 0.3])[:, None]
+    a, b, c = rng.permutation(V)[:3]
+    legal[:, [a, b, c]] = True
+    top = np.where(legal, raw, -np.inf).max(axis=-1)
+    if case == "tie_at_max":  # the maximum twice: margin 0
+        raw[:, a] = raw[:, b] = top + 1.0
+    elif case == "tie_below_max":  # one maximum, the runner-up twice
+        raw[:, a] = top + 2.5
+        raw[:, b] = raw[:, c] = top + 1.0
+    elif case == "one_legal":  # the grammar forces the token
+        legal[:] = False
+        legal[:, a] = True
+    elif case == "none_legal":  # a dead row
+        legal[:] = False
+    elif case == "above_cap":  # a maximum further ahead than the cap
+        raw[:, a] = top + 100.0
+    elif case == "nan_row":  # what the poison gate fences: the lanes stay finite
+        raw[:, a] = np.nan
+    else:
+        assert case == "random"
+    return np.where(legal, raw, -np.inf).astype(np.float32), legal.sum(axis=-1).astype(np.int32)
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.uint32) if x.dtype == np.float32 else x
+
+
+@pytest.mark.parametrize("V", [619, 32000, 200064])
+@pytest.mark.parametrize("case", ["random", "tie_at_max", "tie_below_max", "one_legal",
+                                  "none_legal", "above_cap", "nan_row"])
+def test_conf_lanes_select_what_the_sort_gave(case, V):
+    """ISSUE 33: ``_masked_conf`` takes the top-2 by reductions over the row;
+    margin, entropy and the forced flag are, bit for bit, those of the
+    parent's ``top_k`` — at the tokenizer's, Mistral's and Phi-4's vocabulary."""
+    import jax
+
+    from tpu_voice_agent.serve.engine import QUALITY_MARGIN_CAP, _masked_conf
+
+    lg, nlegal = _masked_rows(case, V)
+    got = jax.jit(_masked_conf)(lg, nlegal)
+    want = jax.jit(_sorted_conf)(lg, nlegal)
+    for g, w, name in zip(got, want, ("margin", "entropy", "forced")):
+        assert np.array_equal(_bits(g), _bits(w)), (name, np.asarray(g), np.asarray(w))
+    margin, ent, forced = (np.asarray(x) for x in got)
+    assert np.all(np.isfinite(margin)) and np.all(np.isfinite(ent))
+    assert forced.all() == (case in ("one_legal", "none_legal")) and forced.any() == forced.all()
+    if case == "random":
+        assert np.all((margin > 0) & (margin < QUALITY_MARGIN_CAP)) and np.all(ent > 0)
+    elif case == "tie_below_max":
+        assert np.all(margin == 1.5)
+    elif case in ("one_legal", "above_cap"):
+        assert np.all(margin == QUALITY_MARGIN_CAP)
+    else:  # a tie at the maximum, a dead row, a poisoned row
+        assert np.all(margin == 0.0) and (case == "tie_at_max" or np.all(ent == 0.0))
+
+
+def test_conf_lanes_of_the_spec_verify_tail_are_the_sorted_ones(monkeypatch):
+    """The verify tail calls ``_masked_conf`` directly, once a verified
+    position, on the masked logits its greedy pick built: the block's lanes
+    are those the parent's sort folds, bit for bit."""
+    import jax.numpy as jnp
+
+    from tpu_voice_agent.serve import spec as spec_mod
+
+    eng = _dense(True, spec=SpecConfig(k=3))
+    B, K, V = 2, 3, eng.cfg.vocab_size
+    rng = np.random.default_rng(33)
+    logits = jnp.asarray((rng.standard_normal((B, 1 + K, V)) * 3).astype(np.float32))
+    start = jnp.full((B,), eng.fsm.start, jnp.int32)
+    first = np.asarray(spec_mod.fsm_row(eng.tables, start))[0]
+    cur = jnp.full((B,), int(np.flatnonzero(first >= 0)[0]), jnp.int32)
+    state = jnp.asarray(np.full((B,), first[int(cur[0])]), jnp.int32)
+
+    def lanes():
+        none = jnp.full((B, K), -1, jnp.int32)
+        out = spec_mod._verify_commit(
+            logits, cur, jnp.full((B,), 8, jnp.int32), state, jnp.ones((B,), bool),
+            jnp.zeros((B,), jnp.int32), jnp.full((B,), 16, jnp.int32), none,
+            jnp.zeros((B,), jnp.int32), cur, jnp.tile(cur[:, None], (1, 1 + K)), eng.tables,
+            eng.byte_len_table, jnp.int32(4096), eng.logit_mask, K, eng.eos_id, eng.pad_id, 256,
+            quality_lanes=True)
+        return out[-1]
+
+    got = lanes()
+    monkeypatch.setattr(spec_mod, "_masked_conf", _sorted_conf)
+    want = lanes()
+    assert int(np.asarray(got[4]).sum()) == B  # one verified decision a row: the bonus
+    for g, w in zip(got, want):
+        assert np.array_equal(_bits(g), _bits(w)), (np.asarray(g), np.asarray(w))
+
+
 # ------------------------------------------------------------ quality SLO
 
 

@@ -215,18 +215,29 @@ def _masked_conf(lg, nlegal):
     """The reduction half of ``_conf_stats`` over ALREADY-masked f32
     logits — the spec verify tail calls this directly on the per-position
     masked logits it builds anyway (re-deriving the mask per position
-    would double the verify tail's vocab work)."""
-    top2 = jax.lax.top_k(lg, 2)[0]
-    margin = jnp.where(jnp.isfinite(top2[:, 1]),
-                       jnp.minimum(top2[:, 0] - top2[:, 1], QUALITY_MARGIN_CAP),
+    would double the verify tail's vocab work).
+
+    The margin's top-2 is SELECTED by reductions, never sorted:
+    ``lax.top_k`` lowers on the TPU to a sort of the whole row (7.9 ms a
+    forward at 200064 logits, for two numbers). The runner-up is the
+    maximum again if it occurs twice, else the largest value under it —
+    elements of ``lg`` both, so the lanes are the sort's bit for bit
+    (tests/test_quality.py): a tie at the maximum gives 0, one legal token
+    the cap. A row holding a NaN (fenced by the poison gate, its lanes
+    never accumulated) reads margin 0 and entropy 0."""
+    m1 = jnp.max(lg, axis=-1)
+    m2 = jnp.where(jnp.sum(lg == m1[:, None], axis=-1) > 1, m1,
+                   jnp.max(jnp.where(lg < m1[:, None], lg, -jnp.inf), axis=-1))
+    margin = jnp.where(jnp.isfinite(m2),
+                       jnp.minimum(m1 - m2, QUALITY_MARGIN_CAP),
                        QUALITY_MARGIN_CAP)
     # a dead row (no legal token at all) carries no signal; it is fenced
     # by the poison gate anyway — zero keeps the lane NaN-free
-    margin = jnp.where(jnp.isfinite(top2[:, 0]), margin, 0.0)
+    margin = jnp.where(jnp.isfinite(m1), margin, 0.0)
     p = jax.nn.softmax(lg, axis=-1)
     ent = -jnp.sum(jnp.where(p > 0, p * jnp.log(jnp.maximum(p, 1e-30)), 0.0),
                    axis=-1)
-    ent = jnp.where(jnp.isfinite(top2[:, 0]), ent, 0.0)
+    ent = jnp.where(jnp.isfinite(m1), ent, 0.0)
     return margin, ent, nlegal <= 1
 
 
