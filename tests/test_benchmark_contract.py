@@ -117,7 +117,7 @@ def test_the_phi4flash_configuration_keeps_every_published_number():
     cell = next(w for w in M["workloads"] if w["name"] == "phi4flash_flood")
     assert (cell["config"], cell["traffic"], cell["chips"]) == (entry["name"], "parse_flood", 1)
     rate = next(m for m in M["end_to_end"] if m["name"] == "out_tokens_per_s")
-    assert rate["workloads"][-1] == "phi4flash_flood" and rate["bound"] == 0.015
+    assert "phi4flash_flood" in rate["workloads"] and rate["bound"] == 0.015  # later cells append
 
 
 def test_the_phi4flash_cells_cpu_rehearsal_reaches_ok():

@@ -416,6 +416,148 @@ def test_the_hybrid_suffix_prefill_compiles_at_published_widths(tpu_devices, mon
         n_real=S((1,), I32)).compile()
 
 
+# Command A+ (the benchmark's cmdaplus_flood cell): 128 q heads of 128 over 8 kv
+# heads on a 4096-wide residual (group 16: 144 query rows a K/V head in a
+# fast-forward block), 8 layers, 16 held experts of 4096 x 4096 in int8
+CMDAPLUS = (128, 8, 128, 8)
+
+
+@pytest.mark.parametrize("rows", [288, 32])
+def test_grouped_matmul_tiled_int8_stacked_compiles_at_command_a_plus_widths(tpu_devices, rows):
+    """A (4096, 4096) int8 plane is 16 MiB, over ``_PLANE_BYTES``: the kernel's
+    (tk, tn)-tiled path with the float32 accumulator, the STACKED leaf of the
+    16 experts HELD with the layer in the scalar prefetch, at the decode
+    forward's and a suffix prefill's token counts; the row tile follows the
+    ROUTER's width (18 rows an expert of 128 at 288 tokens: 32)."""
+    from tpu_voice_agent.models.llama import moe_row_tile
+    from tpu_voice_agent.ops.grouped_matmul import _PLANE_BYTES, plane_tiles
+
+    L, H, E, d, K = 8, 16, 128, 4096, 8
+    tm = moe_row_tile(rows * K, E)
+    assert tm == (32 if rows == 288 else 16) and plane_tiles(d, d, 1) == (4096, 512)
+    assert d * d > _PLANE_BYTES
+    n = -(-(rows * K + min(H, rows * K) * (tm - 1)) // tm)
+    sh = SingleDeviceSharding(tpu_devices[0])
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+    jax.jit(functools.partial(ops.grouped_matmul, tm=tm, interpret=False)).lower(
+        S((n * tm, d), BF16), {"q": S((L, H, d, d), I8), "s": S((L, H, 1, d), F32)},
+        S((n,), I32), S((), I32), S((), I32)).compile()
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["unbound", "window"])
+def test_paged_block_attention_compiles_at_144_query_rows_a_head(tpu_devices, window):
+    """(32 rows, 9 queries, 128 / 8 heads of 128): the rows' resident state is
+    94 MB, so the kernel walks two groups of 16, each with the split its
+    caller made (``row_group_splits``) — also behind a window, where a lone
+    split made the wrapper refuse."""
+    nq, nkv, hd, L = CMDAPLUS
+    B, N, blocks, bs = 32, 200, 12, 128
+
+    def attend(q, kp, vp, tables, pos, layer, live):
+        splits = ops.row_group_splits((B, FF_T, nq, nkv, hd), tables, pos, live, bs, window=window)
+        assert len(splits) == 2
+        return ops.paged_block_attention(q, kp, vp, tables, pos, layer, live, splits,
+                                         None if window is None else jnp.int32(window),
+                                         interpret=False)
+
+    _compile(tpu_devices, attend, ((B, FF_T, nq, hd), BF16), *_pool(N, CMDAPLUS),
+             ((B, blocks), I32), ((B, FF_T), I32), ((), I32), ((B,), jnp.bool_))
+
+
+def _cmdaplus_engine(monkeypatch, **serving):
+    """The ``cmdaplus_flood`` cell's engine (published widths, a two-block
+    pool: the real one is a shape below) and abstract weights, with the
+    kernels told they are not interpreted; ``serving`` overrides the file's."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from benchmark.builders import cohere2moe_stack, parse_stack
+    from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
+    from tpu_voice_agent.serve import PagedDecodeEngine
+
+    for mod in ("paged_attention", "grouped_matmul", "flash_attention"):
+        monkeypatch.setattr(sys.modules[f"tpu_voice_agent.ops.{mod}"], "on_cpu", lambda: False)
+    conf = json.loads((Path(__file__).parents[1] / "benchmark" / "configs"
+                       / "command-a-plus-05-2026-int8.json").read_text())
+    m, s = parse_stack.as_run(conf, False)
+    s = {**s, **serving}
+    eng = PagedDecodeEngine(
+        cfg=cohere2moe_stack.llama_config(m, s), tokenizer=default_tokenizer(), quant=s["quant"],
+        batch_slots=s["batch_slots"], block_size=s["block_size"], pool_blocks=2, max_len=s["max_len"],
+        prefill_buckets=tuple(s["prefill_buckets"]), fast_forward=s["fast_forward"], init_weights=False)
+    params = jax.eval_shape(lambda: cohere2moe_stack.make_params(eng.cfg, s["weights_seed"]))
+    return eng, s, params
+
+
+@pytest.mark.parametrize("width", [pytest.param("full", marks=pytest.mark.slow), "compact", "window"])  # the chip runs "full" in every check
+def test_the_command_a_plus_chunk_program_compiles_at_published_widths(tpu_devices, monkeypatch, width):
+    """Command A+'s decode chunk as ``cmdaplus_flood`` serves it — 8 parallel
+    blocks at published widths, int8 weights, 16 held experts through the
+    grouped kernel's tiled path, 128 query heads over 8 K/V planes in the
+    200-block pool, the 32768-wide tied head on one position a row — at the
+    full width, at the compacted one, and ("window") with ``max_len`` 5120 so
+    that the 4096 window BINDS: two slots, as the published-window check on
+    the chip runs it, the sliding layers through the block kernel's windowed
+    variant with splits of their own."""
+    from tpu_voice_agent.models import llama
+    from tpu_voice_agent.serve import paged
+
+    bound = width == "window"
+    eng, s, params = _cmdaplus_engine(monkeypatch, **({"max_len": 5120, "batch_slots": 2} if bound else {}))
+    B, R, cfg = eng.batch_slots, eng.compact_rows, eng.cfg
+    assert cfg.moe_impl == "grouped" and llama.bound_window(cfg) == (4096 if bound else None)
+    chip = SingleDeviceSharding(tpu_devices[0])
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    shapes = lambda tree: jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), tree)
+    pool = S((cfg.n_layers, s["pool_blocks"], eng.block_size, cfg.n_kv_heads, cfg.head_dim), BF16)
+    rows = {"rows_idx": S((R,), I32)} if width == "compact" else {}
+    compiled = paged.paged_chunk_decode_loop.__wrapped__.lower(
+        shapes(params), cfg, pool, pool,
+        S((B, eng.max_blocks), I32), S((B,), I32), S((B,), I32), S((B,), I32), S((B,), jnp.bool_),
+        S((B,), I32), S((B,), I32), shapes(eng.tables_ff), shapes(eng.byte_len_table),
+        shapes(jax.random.PRNGKey(0)), S((), F32), S((), I32), trash_idx=S((B,), I32), rules=None,
+        logit_mask=None if eng.logit_mask is None else shapes(eng.logit_mask), **rows,
+        chunk_steps=16, greedy=True, constrained=True, kernels="pallas", eos_id=eng.eos_id,
+        pad_id=eng.pad_id, max_len=eng.max_len, kv_quant=None, quality_lanes=eng.quality_lanes).compile()
+    text = compiled.as_text()
+    n = R if width == "compact" else B
+    # the eight layers unrolled: attention (two groups of
+    # 16 rows at the full width) and gate, up, down for each
+    assert text.count("tpu_custom_call") == 8 * (3 + (2 if width == "full" else 1))
+    # the head runs on one position a row
+    assert f"f32[{n},32768]" in text and f"{n},9,32768]" not in text and f"[{9 * n},32768]" not in text
+
+
+@pytest.mark.parametrize("bucket,fresh", [(64, False), (1024, True), (1, False), (9, False)],
+                         ids=["suffix", "prefix", "one-step", "one-block"])
+def test_the_command_a_plus_prefills_compile_at_published_widths(tpu_devices, monkeypatch, bucket, fresh):
+    """An admission's forward (one row, a suffix bucket behind the cached
+    prefix, the covered blocks gathered), the prefix's own prefill through
+    the scratch pool (a fresh 1024-token block: the flash kernel at 128 / 8
+    heads, the grouped kernel at 8192 assignments), and the comparison's
+    one-row T = 1 step and 1 + 8 block over the whole pool — whose K/V write
+    through the flat view of the pool made XLA pad a copy of it sixteenfold
+    (6.25 GB; my chip run, PR 34): the temporaries stay under 3 GB."""
+    from tpu_voice_agent.models import llama
+
+    eng, s, params = _cmdaplus_engine(monkeypatch)
+    cfg = eng.cfg
+    chip = SingleDeviceSharding(tpu_devices[0])
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    shapes = lambda tree: jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), tree)
+    blocks = 9 if fresh else s["pool_blocks"]
+    pool = S((cfg.n_layers, blocks, eng.block_size, cfg.n_kv_heads, cfg.head_dim), BF16)
+    prefill = bucket > 9
+    compiled = llama.forward_paged.__wrapped__.lower(
+        shapes(params), cfg, S((1, bucket), I32), S((1, bucket), I32), pool, pool,
+        S((1, 8 if fresh else eng.max_blocks), I32),
+        attn_impl="pallas" if fresh or not prefill else "xla",
+        fresh_block=fresh, gather_blocks=8 if prefill and not fresh else None).compile()
+    # the pools are not donated through ``__wrapped__``: two copies of 0.84 GB are in it
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
 @pytest.mark.slow
 def test_sharded_kernels_compile_on_2x2(tpu_devices):
     """The shard_map variants the dp x tp serving mesh traces (batch over

@@ -62,10 +62,61 @@ class LlamaConfig:
     # (n_heads * head_dim wide) and k vector, before the split into heads
     # and before RoPE
     qk_norm: bool = False
+    # ---- sizes and rules of the MODEL a Llama-family file does not have
+    # (``cohere2_moe``, Command A+). Every default is the Llama family's, and
+    # with the defaults every program traces as it did before they existed.
+    # A head's width where it is not dim / n_heads (128 query heads of 128 on
+    # a 4096-wide residual); 0 = dim // n_heads
+    head_size: int = 0
+    # the kind of every layer by index, "sliding" | "full": a sliding layer
+    # rotates q and k and a query sees its own and the ``sliding_window`` - 1
+    # positions before it; a full layer sees every earlier position and
+    # carries NO positions (no rotation). () = every layer full WITH rotation
+    layer_types: tuple[str, ...] = ()
+    sliding_window: int = 0
+    # rotary pairs: (x[i], x[i + hd/2]) as Llama splits the head in halves,
+    # or (x[2i], x[2i+1]) interleaved (``rope_gptj``)
+    rope_interleaved: bool = False
+    # "rms" | "layer" (a LayerNorm with a gain and no bias)
+    norm: str = "rms"
+    # ONE norm feeds attention and the expert layer, both added to the
+    # residual: x' = x + Attn(N(x)) + FFN(N(x))
+    parallel_block: bool = False
+    # the router's score: "softmax" over the experts | "sigmoid" of each
+    router_fn: str = "softmax"
+    # experts every token passes through beside the routed ones; their MEAN is
+    # added to the routed sum (``shared_expert_combination_strategy: average``)
+    n_shared_experts: int = 0
+    # THE CHIP'S SHARE of an expert-parallel group: the router stays
+    # ``n_experts`` wide and picks ``top_k`` of them, this chip's expert
+    # planes hold ``experts_held`` of them, ids ``first_expert`` onward;
+    # 0 = all of them. A pick that falls elsewhere takes no row here
+    experts_held: int = 0
+    first_expert: int = 0
+    # logits = logit_scale * N(x) embed^T (the head is the embedding)
+    tie_embeddings: bool = False
+    logit_scale: float = 1.0
+
+    def __post_init__(self):
+        if self.layer_types and (len(self.layer_types) != self.n_layers or
+                                 set(self.layer_types) - {"sliding", "full"}):
+            raise ValueError(f"layer_types: one of 'sliding' | 'full' for each of "
+                             f"{self.n_layers} layers, got {self.layer_types}")
+        if "sliding" in self.layer_types and self.sliding_window <= 0:
+            raise ValueError("sliding layers need a sliding_window")
+        held = self.experts_held
+        if held and not self.first_expert + held <= self.n_experts:
+            raise ValueError(f"experts held {self.first_expert}..{self.first_expert + held} "
+                             f"of {self.n_experts}")
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_size or self.dim // self.n_heads
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose planes this chip holds."""
+        return self.experts_held or self.n_experts
 
 
 # Parameter-count-faithful presets; vocab_size is overridden from the
@@ -116,6 +167,14 @@ def _hybrid(cfg) -> bool:
     return not isinstance(cfg, LlamaConfig)
 
 
+def paged_only(cfg) -> bool:
+    """A LlamaConfig whose layers ``forward_paged`` alone runs (layers of
+    more than one kind, a parallel block, a tied head): ``forward`` and its
+    dense cache refuse it, and the paged engine prefills its prompt prefix
+    through a scratch pool, as it does a hybrid model's."""
+    return bool(cfg.layer_types or cfg.parallel_block or cfg.tie_embeddings)
+
+
 def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     """Random init. Layer weights are stacked on a leading n_layers axis."""
     if _hybrid(cfg):
@@ -140,18 +199,27 @@ def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
         "wk": w_init(ks[1], L, d, nkv * hd),
         "wv": w_init(ks[2], L, d, nkv * hd),
         "wo": w_init(ks[3], L, nq * hd, d),
-        "mlp_norm": norm_init(L, d),
     }
+    if not cfg.parallel_block:  # there ``attn_norm`` feeds both halves
+        layers["mlp_norm"] = norm_init(L, d)
     if cfg.qk_norm:
         layers.update({"q_norm": norm_init(L, nq * hd), "k_norm": norm_init(L, nkv * hd)})
+    if cfg.n_shared_experts:
+        # the shared experts side by side: ONE SwiGLU of n_shared * f columns
+        # IS the sum of theirs (the mean is taken where it is added)
+        ss = jax.random.split(jax.random.fold_in(k_layers, 1), 3)
+        sf = cfg.n_shared_experts * f
+        layers.update({"shared_gate": w_init(ss[0], L, d, sf), "shared_up": w_init(ss[1], L, d, sf),
+                       "shared_down": w_init(ss[2], L, sf, d, scale=f ** -0.5)})
     if cfg.n_experts > 0:
-        E = cfg.n_experts
+        E, H = cfg.n_experts, cfg.n_held
         layers.update({
-            # router stays small + unquantized; expert weights stack on E
+            # router stays small + unquantized; expert weights stack on the
+            # experts HELD (all of them unless this is a chip's share)
             "router": w_init(ks[7], L, d, E),
-            "moe_gate": w_init(ks[4], L, E, d, f),
-            "moe_up": w_init(ks[5], L, E, d, f),
-            "moe_down": w_init(ks[6], L, E, f, d),
+            "moe_gate": w_init(ks[4], L, H, d, f),
+            "moe_up": w_init(ks[5], L, H, d, f),
+            "moe_down": w_init(ks[6], L, H, f, d),
         })
     else:
         layers.update({
@@ -159,12 +227,14 @@ def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
             "w_up": w_init(ks[5], L, d, f),
             "w_down": w_init(ks[6], L, f, d),
         })
-    return {
+    params = {
         "embed": w_init(k_embed, cfg.vocab_size, d, scale=d**-0.5),
         "layers": layers,
         "final_norm": norm_init(d),
-        "lm_head": w_init(k_head, d, cfg.vocab_size),
     }
+    if not cfg.tie_embeddings:  # tied: the head IS the embedding
+        params["lm_head"] = w_init(k_head, d, cfg.vocab_size)
+    return params
 
 
 def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=jnp.bfloat16) -> dict:
@@ -240,11 +310,12 @@ def quantize_params(params: dict) -> dict:
         "layers": {
             # matmul weights (dense w_* and stacked-expert moe_*) quantize;
             # norms and the tiny router stay full precision
-            k: (quant(v) if k.startswith(("w", "moe_")) else v)
+            k: (quant(v) if k.startswith(("w", "moe_", "shared_")) else v)
             for k, v in L.items()
         },
         "final_norm": params["final_norm"],
-        "lm_head": quant(_w(params["lm_head"])),
+        # a tied head: an int8 copy of the embedding, a scale a vocabulary row
+        "lm_head": quant(_w(params["lm_head"]) if "lm_head" in params else params["embed"].T),
     }
 
 
@@ -255,6 +326,19 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     xf = x.astype(jnp.float32)
     scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (xf * scale).astype(x.dtype) * w
+
+
+def layer_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    """LayerNorm with a gain and no bias (cohere2)."""
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+    return ((xf - mu) * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w
+
+
+def _norm(x: jax.Array, w: jax.Array, cfg: "LlamaConfig") -> jax.Array:
+    """The model's norm (``cfg.norm``)."""
+    return (layer_norm if cfg.norm == "layer" else rms_norm)(x, w, cfg.norm_eps)
 
 
 def rope_tables(positions: jax.Array, head_dim: int, theta: float) -> tuple[jax.Array, jax.Array]:
@@ -271,12 +355,46 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
 
 
-def _attend(q, k_cache, v_cache, q_positions, kv_len_mask):
+def apply_rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """x: (B, T, H, hd); rotate the pairs (x[2i], x[2i+1]) by angle i
+    (``rope_gptj``): the same tables, another pairing of the lanes. Written
+    on whole 128-lane rows — each lane times its pair's cosine, plus its
+    PARTNER (the neighbour lane, by two lane rotations and a select) times
+    the signed sine — because a (..., hd/2, 2) view puts 2 elements in a
+    128-lane tile."""
+    xf = x.astype(jnp.float32)
+    c = jnp.repeat(cos, 2, axis=-1)[:, :, None, :]
+    s = jnp.repeat(sin, 2, axis=-1)[:, :, None, :]
+    even = jnp.arange(x.shape[-1]) % 2 == 0
+    partner = jnp.where(even, -jnp.roll(xf, -1, axis=-1), jnp.roll(xf, 1, axis=-1))
+    return (xf * c + partner * s).astype(x.dtype)
+
+
+def bound_window(cfg: "LlamaConfig") -> int | None:
+    """The window a sliding layer's mask is given, or None where the mask
+    can never be false: no position of a sequence of at most ``max_seq_len``
+    <= ``sliding_window`` tokens is a window away from a query after it, so
+    the layer IS a full causal one there (an identity, not a tolerance). The
+    engine fixes ``max_seq_len`` to its ``max_len`` once, where it is built,
+    and serves such a model's sliding layers through the unbounded paths —
+    the block kernel's common pass among them."""
+    return cfg.sliding_window if 0 < cfg.sliding_window < cfg.max_seq_len else None
+
+
+def layer_kinds(cfg: "LlamaConfig") -> tuple[tuple[bool, int | None], ...]:
+    """(rotates, window) of every layer by index. A Llama-family model:
+    every layer rotates and sees everything."""
+    kinds = {"sliding": (True, bound_window(cfg)), "full": (False, None)}
+    return tuple(kinds[t] for t in cfg.layer_types) or ((True, None),) * cfg.n_layers
+
+
+def _attend(q, k_cache, v_cache, q_positions, kv_len_mask, window: int | None = None):
     """GQA attention of q (B,T,nq,hd) against the full cache (B,S,nkv,hd).
 
     kv_len_mask: (B, S) bool — which cache slots hold valid keys.
     Causality: key_position <= query_position, tracked via positions stored
     implicitly by slot index (slot i holds the token at position i).
+    ``window``: a query sees its last ``window`` positions, its own among them.
     """
     B, T, nq, hd = q.shape
     S = k_cache.shape[1]
@@ -289,6 +407,8 @@ def _attend(q, k_cache, v_cache, q_positions, kv_len_mask):
 
     slot_pos = jnp.arange(S)[None, None, :]  # (1, 1, S)
     causal = slot_pos <= q_positions[:, :, None]  # (B, T, S)
+    if window is not None:
+        causal = causal & (slot_pos > q_positions[:, :, None] - window)
     mask = causal & kv_len_mask[:, None, :]
     scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -302,19 +422,22 @@ def _identity_cs(x, name):
 
 
 def _layer_qkv(p, x, cfg: LlamaConfig, cos, sin, cs=_identity_cs,
-               n_heads: int | None = None, n_kv_heads: int | None = None):
+               n_heads: int | None = None, n_kv_heads: int | None = None,
+               rotate: bool = True, u=None):
     """Shared decoder-layer front half: attn-norm -> q/k/v projections ->
     head reshape -> RoPE. The ONE copy of this math for forward /
     forward_paged / pipeline / longctx (they differ only in how KV is
     written and attended, never in the projections). ``n_heads`` /
     ``n_kv_heads`` override the config's counts for tensor-parallel LOCAL
     shards inside shard_map (pipeline.pp_tp_forward_cached passes
-    cfg.n_heads // tp etc; head_dim is unchanged)."""
+    cfg.n_heads // tp etc; head_dim is unchanged). ``rotate`` False: a layer
+    that carries no positions. ``u``: the layer's input already normed (a
+    parallel block's one norm feeds the expert layer too)."""
     B, T = x.shape[:2]
     nq = n_heads if n_heads is not None else cfg.n_heads
     nkv = n_kv_heads if n_kv_heads is not None else cfg.n_kv_heads
     with jax.named_scope("layer/attn_qkv"):
-        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        h = _norm(x, p["attn_norm"], cfg) if u is None else u
         h = cs(h, "act")
         q = _qe("btd,dh->bth", h, p["wq"]).astype(x.dtype)
         k = _qe("btd,dh->bth", h, p["wk"]).astype(x.dtype)
@@ -330,23 +453,41 @@ def _layer_qkv(p, x, cfg: LlamaConfig, cos, sin, cs=_identity_cs,
         q = cs(q.reshape(B, T, nq, cfg.head_dim), "heads")
         k = cs(k.reshape(B, T, nkv, cfg.head_dim), "kv_heads")
         v = cs(v.reshape(B, T, nkv, cfg.head_dim), "kv_heads")
-        return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+        if not rotate:
+            return q, k, v
+        rope = apply_rope_interleaved if cfg.rope_interleaved else apply_rope
+        return rope(q, cos, sin), rope(k, cos, sin), v
 
 
 # what a routed forward counts (summed over layers by the forwards, over
 # forwards by the chunk loops; ``scheduler`` publishes them as ``moe.<name>``)
 MOE_STATS = ("assigned_rows", "padded_rows", "experts_touched", "load_max")
+# a chip's SHARE of the experts counts one more: of the rows the router
+# assigned, those that fell on an expert held here (the other four are then
+# over the held experts: rows computed, held experts with a row, the busiest)
+MOE_SHARE_STATS = MOE_STATS + ("local_rows",)
 
 
-def _moe_stats(counts, computed_rows) -> jax.Array:
+def moe_stat_names(cfg) -> tuple[str, ...]:
+    """What a routed forward of this model counts, in order."""
+    return MOE_SHARE_STATS if cfg.experts_held else MOE_STATS
+
+
+def _moe_stats(counts, computed_rows, assigned=None) -> jax.Array:
     """(4,) int32 in ``MOE_STATS`` order from one layer's per-expert row
-    counts and the rows its dispatch computed (padding included)."""
-    return jnp.stack([jnp.sum(counts), jnp.asarray(computed_rows, jnp.int32),
-                      jnp.sum(counts > 0), jnp.max(counts)]).astype(jnp.int32)
+    counts and the rows its dispatch computed (padding included); with
+    ``assigned`` (a share: every row the router assigned, wherever its expert
+    lives) (5,) in ``MOE_SHARE_STATS`` order."""
+    local = jnp.sum(counts)
+    return jnp.stack([local if assigned is None else jnp.asarray(assigned, jnp.int32),
+                      jnp.asarray(computed_rows, jnp.int32), jnp.sum(counts > 0),
+                      jnp.max(counts), *(() if assigned is None else (local,))]).astype(jnp.int32)
 
 
 def moe_row_tile(assignments: int, n_experts: int) -> int:
-    """Row tile of the grouped dispatch from the (static) assignment count:
+    """Row tile of the grouped dispatch from the (static) assignment count
+    and the ROUTER's width (an expert's mean run is the same on a chip that
+    holds a share of them):
     the smallest power of two ABOVE the mean run of an expert, between one
     bf16 sublane tile (16) and the MXU's 128 rows — most experts then fill
     one tile, and the kernel pays by the tile (each converts and latches
@@ -403,18 +544,27 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig):
 
     B, T, d = h.shape
     E, K = cfg.n_experts, cfg.top_k
+    # the experts held: all E, or a chip's share of them — ids ``first_expert``
+    # onward. An assignment to an expert held elsewhere matches no column of
+    # the one-hot below: it counts in no run, pads nothing, names no tile
+    # (no weight fetch), takes no row, and adds nothing in the combine. Its
+    # gate stays what the router gave it (normalised over all K chosen)
+    H, share = cfg.n_held, cfg.n_held < E
     Tt = B * T
     A = Tt * K
     x2 = h.reshape(Tt, d)
     with jax.named_scope("router"):
-        eids, gates = route_topk_flat(p["router"], x2, E, K, cfg.norm_topk)  # (Tt, K)
+        eids, gates = route_topk_flat(p["router"], x2, E, K, cfg.norm_topk,
+                                      cfg.router_fn)  # (Tt, K)
 
     with jax.named_scope("dispatch"):
         # compares and running counts over an (A, E) one-hot, no sort and
         # no per-element gather: on the TPU a 2304-element gather or scatter
         # costs as much as a whole expert plane (PERF.md section 6, PR 28)
         flat_e = eids.reshape(-1)  # assignment j = t*K + k
-        hot = flat_e[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :]  # (A, E)
+        if share:
+            flat_e = flat_e - cfg.first_expert
+        hot = flat_e[:, None] == jnp.arange(H, dtype=jnp.int32)[None, :]  # (A, H)
         tm = moe_row_tile(A, E)
         counts = jnp.sum(hot, axis=0, dtype=jnp.int32)
         padded = ((counts + tm - 1) // tm) * tm
@@ -422,21 +572,26 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig):
         offsets = ends - padded
         # row of assignment j: its expert's padded offset plus its rank in
         # the expert's run (assignment order: expert-major, token-stable)
-        rank = _running_count(hot) - 1  # (A, E)
+        rank = _running_count(hot) - 1  # (A, H)
         dest = jnp.sum(jnp.where(hot, rank + offsets[None, :], 0), axis=1)  # (A,)
         # static bound on sum(padded): under one tile of padding for each
         # expert that can hold a row; the tiles past the real ones are
         # skipped by the kernel and never gathered back
-        n_static = -(-(A + min(E, A) * (tm - 1)) // tm)
+        n_static = -(-(A + min(H, A) * (tm - 1)) // tm)
         # rows move by GATHER (an int32 scatter builds the index): row r of
         # the padded layout reads token row_tok[r], padding reads a zero row
-        row_tok = jnp.full((n_static * tm,), Tt, jnp.int32).at[dest].set(
-            jnp.arange(A, dtype=jnp.int32) // K)
+        row_tok = jnp.full((n_static * tm,), Tt, jnp.int32)
+        if share:
+            local = jnp.any(hot, axis=1)  # (A,) the expert is held here
+            row_tok = row_tok.at[jnp.where(local, dest, n_static * tm)].set(
+                jnp.arange(A, dtype=jnp.int32) // K, mode="drop")
+        else:
+            row_tok = row_tok.at[dest].set(jnp.arange(A, dtype=jnp.int32) // K)
         xs = jnp.concatenate([x2, jnp.zeros((1, d), x2.dtype)])[row_tok]
         n_tiles = ends[-1] // tm
         tile_start = jnp.arange(n_static, dtype=jnp.int32) * tm
         tile_expert = jnp.minimum(
-            jnp.sum(tile_start[:, None] >= ends[None, :], axis=1, dtype=jnp.int32), E - 1)
+            jnp.sum(tile_start[:, None] >= ends[None, :], axis=1, dtype=jnp.int32), H - 1)
         # skipped tiles name the last real tile's expert: no weight fetch
         last = jnp.sum(jnp.where(jnp.arange(n_static) == n_tiles - 1, tile_expert, 0))
         tile_expert = jnp.where(jnp.arange(n_static) < n_tiles, tile_expert, last)
@@ -452,8 +607,11 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig):
         # assignment j sits at row dest[j]; a token's K rows are gathered
         # and summed under its gates (no scatter-add of (A, d) rows)
         rows = down[dest].reshape(Tt, K, d).astype(jnp.float32)
+        if share:  # an absent pick's ``dest`` is 0: some other row, or one never written
+            rows = jnp.where(local.reshape(Tt, K, 1), rows, 0.0)
         out = jnp.sum(rows * gates[:, :, None], axis=1)
-    return out.astype(h.dtype).reshape(B, T, d), _moe_stats(counts, ends[-1])
+    return (out.astype(h.dtype).reshape(B, T, d),
+            _moe_stats(counts, ends[-1], A if share else None))
 
 
 def _moe_ffn_dense(p, h, cfg: LlamaConfig):
@@ -490,7 +648,11 @@ def _moe_ffn_dense(p, h, cfg: LlamaConfig):
     C = moe_capacity(B * T, cfg.n_experts, cfg.top_k, cf)
     with jax.named_scope("router"):
         dispatch, combine = route_topk(p["router"], x2, cfg.n_experts, cfg.top_k, C,
-                                       cfg.norm_topk)
+                                       cfg.norm_topk, cfg.router_fn)
+        if cfg.experts_held:  # a chip's share: the held experts' columns alone
+            held = slice(cfg.first_expert, cfg.first_expert + cfg.experts_held)
+            assigned = jnp.sum(dispatch).astype(jnp.int32)
+            dispatch, combine = dispatch[:, held], combine[:, held]
     with jax.named_scope("dispatch"):
         xe = jnp.einsum("tec,td->ecd", dispatch.astype(h.dtype), x2)  # (E, C, d)
     with jax.named_scope("experts"):
@@ -501,7 +663,7 @@ def _moe_ffn_dense(p, h, cfg: LlamaConfig):
     with jax.named_scope("combine"):
         out = jnp.einsum("tec,ecd->td", combine.astype(h.dtype), down).reshape(B, T, d)
     counts = jnp.sum(dispatch, axis=(0, 2)).astype(jnp.int32)
-    return out, _moe_stats(counts, cfg.n_experts * C)
+    return out, _moe_stats(counts, cfg.n_held * C, assigned if cfg.experts_held else None)
 
 
 def _moe_ffn(p, h, cfg: LlamaConfig):
@@ -516,20 +678,38 @@ def _moe_ffn(p, h, cfg: LlamaConfig):
     return _moe_ffn_dense(p, h, cfg)
 
 
-def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = False):
+def _swiglu(p, h, names, cs=_identity_cs):
+    """down(silu(gate h) * up h) over the three leaves ``names``, float32."""
+    gate = _qe("btd,df->btf", h, p[names[0]])
+    up = _qe("btd,df->btf", h, p[names[1]])
+    act = cs((jax.nn.silu(gate) * up).astype(h.dtype), "ffn")
+    return _qe("btf,fd->btd", act, p[names[2]])
+
+
+def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = False, u=None):
     """Shared decoder-layer back half: output projection + residual, then
     the MLP (dense SwiGLU, or routed MoE when cfg.n_experts > 0) +
     residual. ``attn`` is (B, T, n_heads * head_dim). With ``moe_stats``
-    (routed models only) -> (x, the layer's ``_moe_stats``)."""
+    (routed models only) -> (x, the layer's ``_moe_stats``). A PARALLEL
+    block (``u``: the layer's one normed input, which fed q/k/v too) adds
+    both halves to the same residual: x + W_o attn + FFN(u)."""
     with jax.named_scope("layer/attn_out"):
         attn = _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
-        x = x + cs(attn, "act")
+        attn = cs(attn, "act")
+        if u is None:
+            x = x + attn
     with jax.named_scope("layer/ffn"):
-        h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+        h = _norm(x, p["mlp_norm"], cfg) if u is None else u
         if cfg.n_experts > 0:
             y, stats = _moe_ffn(p, h, cfg)
-            x = x + cs(y, "act")
+            if cfg.n_shared_experts:
+                with jax.named_scope("shared"):  # layer/ffn/shared
+                    shared = _swiglu(p, h, ("shared_gate", "shared_up", "shared_down"), cs)
+                    y = y + (shared * (1.0 / cfg.n_shared_experts)).astype(y.dtype)
+            x = x + cs(y, "act") if u is None else x + attn + cs(y, "act")
             return (x, stats) if moe_stats else x
+        if u is not None or cfg.n_shared_experts:
+            raise NotImplementedError("a parallel block or shared experts around a dense MLP")
         gate = _qe("btd,df->btf", h, p["w_gate"])
         up = _qe("btd,df->btf", h, p["w_up"])
         act = (jax.nn.silu(gate) * up).astype(x.dtype)
@@ -573,6 +753,10 @@ def forward(
     T > 1 block without the flag takes the exact XLA cache path instead of
     silently computing block-local attention.
     """
+    if paged_only(cfg):
+        raise NotImplementedError(
+            "layers of more than one kind, a parallel block and a tied head are "
+            "forward_paged's: PagedDecodeEngine serves this model, the dense cache does not")
     B, T = tokens.shape
     S = kv_cache["k"].shape[2]
     cs = lambda x, name: rules.constrain(x, name) if rules is not None else x
@@ -657,7 +841,7 @@ def forward(
         )
 
     with jax.named_scope("final_norm"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = _norm(x, params["final_norm"], cfg)
     with jax.named_scope("lm_head"):
         logits = _qe("btd,dv->btv", x, params["lm_head"])
         logits = cs(logits, "logits")
@@ -709,8 +893,9 @@ def forward_paged(
     # state (models.sambay) advances it over a row's first n_real positions
     # and no others (None: all T of a live row); a decoder whose state is K/V
     # alone never looks: absent, the traced program is the one it was
-    logit_pos: jax.Array | None = None,  # (B,) int32, that model only: the
-    # head runs on this one position of each row
+    logit_pos: jax.Array | None = None,  # (B,) int32: the head runs on this
+    # one position of each row, logits (B, 1, V) (that model, and one with
+    # layers of more than one kind: the chunk loop's ``one_head``)
     hybrid_stats: bool = False,  # that model only: also ``sambay.HYBRID_STATS``
 ):
     """The paged twin of ``forward`` (parity-tested): sequences own
@@ -777,28 +962,60 @@ def forward_paged(
     # not move between layers. Under a mesh each dp group pins its own prefix
     # blocks, so the kernel's wrapper derives it shard-locally instead.
     mesh = rules.mesh if rules is not None else None
+    # the layers' kinds (static): whether each rotates, and the window its
+    # mask is given. A layer whose window binds attends through the block
+    # kernel at T = 1 too (the T = 1 kernel has no window), with a split of
+    # its own: behind a window no row rides the common pass
+    kinds = layer_kinds(cfg)
+    windows = sorted({w for _, w in kinds if w is not None})
+    if windows and (mesh is not None or kv_quant is not None):
+        raise NotImplementedError("a sliding window that binds, under a mesh or KV_QUANT: "
+                                  "their kernels' wrappers take no window")
     block_decode = (attn_impl == "pallas" and not fresh_block
-                    and 1 < T <= MAX_BLOCK_DECODE_T)
-    split = None
+                    and (1 < T or bool(windows)) and T <= MAX_BLOCK_DECODE_T)
+    split, win_split = None, {}
     if block_decode and kv_quant is None and mesh is None:
-        from ..ops import common_block_split
+        from ..ops import common_block_split, row_group_splits
 
         with jax.named_scope("layer/attn/split"):
-            split = common_block_split(block_tables, positions, write_mask, bs)
+            if cfg.layer_types:
+                # this model's 144 query rows a K/V head pass what the kernel
+                # keeps resident for 32 rows: it walks groups of rows, each
+                # with its split, all made here, once a forward
+                shape = (B, T, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
+                size = params["embed"].dtype.itemsize  # the activations'
+                split = row_group_splits(shape, block_tables, positions, write_mask, bs,
+                                         itemsize=size)
+                win_split = {w: row_group_splits(shape, block_tables, positions, write_mask,
+                                                 bs, window=w, itemsize=size) for w in windows}
+            else:
+                split = common_block_split(block_tables, positions, write_mask, bs)
 
     scanned, whole = _scan_and_whole(params["layers"], cfg)
 
-    def layer(carry, layer_in):
+    def layer(carry, layer_in, kind=(True, None)):
         x, kp, vp, ksc, vsc = carry
         p, li = layer_in
+        rotate, window = kind
         if whole:
             p = {**p, **whole, "layer": li}
-        q, k, v = _layer_qkv(p, x, cfg, cos, sin, cs)
+        u = None
+        if cfg.parallel_block:
+            with jax.named_scope("layer/attn_qkv"):
+                u = _norm(x, p["attn_norm"], cfg)
+        q, k, v = _layer_qkv(p, x, cfg, cos, sin, cs, rotate=rotate, u=u)
 
         with jax.named_scope("layer/kv_write"):
             kp_flat = kp.reshape(L, N * bs, cfg.n_kv_heads, hdp)
             vp_flat = vp.reshape(L, N * bs, cfg.n_kv_heads, hdp)
-            if kv_quant is None:
+            if cfg.layer_types and kv_quant is None:
+                # the pool indexed AS IT IS SHAPED, (block, offset): through the
+                # flat view XLA relaid the whole pool out around a one-row
+                # scatter in the unrolled layers — a 16x padded copy, 6.25 GB
+                # at these widths (my chip run, PR 34; PR 32 met the same)
+                kp = kp.at[li, flat_idx // bs, flat_idx % bs].set(k.astype(kp.dtype))
+                vp = vp.at[li, flat_idx // bs, flat_idx % bs].set(v.astype(vp.dtype))
+            elif kv_quant is None:
                 kp = kp_flat.at[li, flat_idx].set(k.astype(kp.dtype)).reshape(kp.shape)
                 vp = vp_flat.at[li, flat_idx].set(v.astype(vp.dtype)).reshape(vp.shape)
             else:
@@ -817,8 +1034,10 @@ def forward_paged(
                 ksc = ksc_flat.at[li, flat_idx].set(sk).reshape(ksc.shape)
                 vsc = vsc_flat.at[li, flat_idx].set(sv).reshape(vsc.shape)
 
-        with jax.named_scope("layer/attn"):
-            if attn_impl == "pallas" and T == 1:
+        # this model's layers say their kind: layer/attn/{window,full}
+        with jax.named_scope("layer/attn" + ("" if not cfg.layer_types else
+                                             "/full" if not rotate else "/window")):
+            if attn_impl == "pallas" and T == 1 and not block_decode:
                 if kv_quant is None:
                     from ..ops import sharded_paged_attention
 
@@ -844,8 +1063,9 @@ def forward_paged(
                     from ..ops import sharded_paged_block_attention
 
                     attn = sharded_paged_block_attention(
-                        mesh, q, kp, vp, block_tables, positions, li,
-                        write_mask, split,
+                        mesh, q, kp, vp, block_tables, positions, li, write_mask,
+                        **({"split": split} if window is None else
+                           {"split": win_split[window], "window": jnp.int32(window)}),
                     ).reshape(B, T, -1)
                 else:
                     from ..ops import sharded_paged_block_attention_quant
@@ -854,7 +1074,7 @@ def forward_paged(
                         mesh, q, kp, vp, ksc, vsc, block_tables, positions, li,
                         bits=bits,
                     ).reshape(B, T, -1)
-            elif fresh_block and T > 1:
+            elif fresh_block and T > 1 and window is None:
                 # fresh sequence starting at position 0: attention over the
                 # block's own k/v IS attention over the sequence — no pool
                 # gather at all (the scatter above still persists the KV).
@@ -898,27 +1118,52 @@ def forward_paged(
                         vl = dequantize_kv(
                             vp[li][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
                             vsc[li][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
-                attn = _attend(q, kl, vl, positions, kv_len_mask)
-        out = _layer_out(p, x, attn, cfg, cs, moe_stats=moe_stats)
+                attn = _attend(q, kl, vl, positions, kv_len_mask, window)
+        out = _layer_out(p, x, attn, cfg, cs, moe_stats=moe_stats, u=u)
         x, stats = out if moe_stats else (out, None)
         return (x, kp, vp, ksc, vsc), stats
 
+    # layers of ONE kind are a scan over the stacked weights. Layers of more
+    # than one kind (three sliding, one full) run UNROLLED, each one's kind
+    # and index static, its weights a static slice of the stacked leaves: a
+    # scan over periods of the pattern, the period's four layers unrolled in
+    # its body, made XLA copy every period's slice of every leaf out — 13.6 ms
+    # of a 32.6 ms forward (my chip run, PR 34)
     with jax.named_scope("layers"):
-        (x, k_pool, v_pool, k_scale, v_scale), stats = jax.lax.scan(
-            layer,
-            (x, k_pool, v_pool, k_scale, v_scale),
-            (scanned, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
-        )
+        if len(set(kinds)) == 1:
+            (x, k_pool, v_pool, k_scale, v_scale), stats = jax.lax.scan(
+                partial(layer, kind=kinds[0]),
+                (x, k_pool, v_pool, k_scale, v_scale),
+                (scanned, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+            )
+        else:
+            carry, per_layer = (x, k_pool, v_pool, k_scale, v_scale), []
+            for i, kind in enumerate(kinds):
+                carry, st = layer(carry, (jax.tree.map(lambda a: a[i], scanned), jnp.int32(i)), kind)
+                per_layer.append(st)
+            x, k_pool, v_pool, k_scale, v_scale = carry
+            stats = jnp.stack(per_layer) if moe_stats else None
 
     with jax.named_scope("final_norm"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if logit_pos is not None:  # the head on the one position a row reads
+            x = jnp.take_along_axis(x, logit_pos[:, None, None], axis=1)
+        x = _norm(x, params["final_norm"], cfg)
     with jax.named_scope("lm_head"):
-        logits = _qe("btd,dv->btv", x, params["lm_head"])
+        if "lm_head" in params:
+            logits = _qe("btd,dv->btv", x, params["lm_head"])
+        else:  # a tied head, unquantised
+            logits = jnp.einsum("btd,vd->btv", x, params["embed"],
+                                preferred_element_type=jnp.float32)
+        if cfg.logit_scale != 1.0:
+            logits = logits * cfg.logit_scale
         logits = cs(logits, "logits")
     extra = (jnp.sum(stats, axis=0),) if moe_stats else ()
     if attn_stats:
-        extra += (_attn_stats(split, block_decode and kv_quant is None, mesh,
-                              block_tables, positions, write_mask, bs),)
+        stats_of = lambda sp: _attn_stats(sp, block_decode and kv_quant is None, mesh,
+                                          block_tables, positions, write_mask, bs)
+        # layers behind a window that binds read other blocks: every layer's read
+        extra += (sum(stats_of(split if w is None else win_split.get(w)) for _, w in kinds)
+                  if windows else stats_of(split),)
     return (logits, k_pool, v_pool, k_scale, v_scale, *extra)
 
 
@@ -929,8 +1174,8 @@ def _attn_stats(split, two_pass: bool, mesh, block_tables, positions, live, bs: 
     block is common and live rows attend the blocks up to their frontier."""
     from ..ops import common_block_split
 
-    if split is not None:
-        return split.counts
+    if split is not None:  # one split, or one for each group of rows
+        return split.counts if hasattr(split, "counts") else sum(s.counts for s in split)
     B = positions.shape[0]
     live = jnp.ones((B,), bool) if live is None else live
     if two_pass:
@@ -943,12 +1188,15 @@ def _attn_stats(split, two_pass: bool, mesh, block_tables, positions, live, bs: 
 
 
 def param_count(cfg: LlamaConfig) -> int:
+    """Parameters THIS chip holds: the experts held (all, or a share), the
+    shared experts, a tied head once."""
     d, f, hd = cfg.dim, cfg.ffn_dim, cfg.head_dim
     per_layer = d * (cfg.n_heads * hd) + 2 * d * (cfg.n_kv_heads * hd) + (cfg.n_heads * hd) * d
+    norms = d if cfg.parallel_block else 2 * d
     if cfg.n_experts > 0:
-        per_layer += cfg.n_experts * 3 * d * f + d * cfg.n_experts + 2 * d
+        per_layer += (cfg.n_held + cfg.n_shared_experts) * 3 * d * f + d * cfg.n_experts + norms
     else:
-        per_layer += 3 * d * f + 2 * d
+        per_layer += 3 * d * f + norms
     if cfg.qk_norm:
         per_layer += (cfg.n_heads + cfg.n_kv_heads) * hd
-    return cfg.vocab_size * d * 2 + cfg.n_layers * per_layer + d
+    return cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2) + cfg.n_layers * per_layer + d

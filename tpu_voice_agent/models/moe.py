@@ -27,15 +27,23 @@ def moe_capacity(n_tokens: int, n_experts: int, top_k: int,
 
 
 def _select_topk(router_w: jax.Array, x: jax.Array, n_experts: int,
-                 top_k: int) -> tuple[jax.Array, jax.Array, jax.Array]:
+                 top_k: int, score_fn: str = "softmax") -> tuple[jax.Array, jax.Array, jax.Array]:
     """THE expert-selection rule, in one place: x (T, d), router_w (d, E)
-    -> (probs (T, E) f32 softmax, eids (T, K) int32 iterative-argmax picks,
-    their probabilities (T, K)). Both dispatch layouts (dense one-hot and
+    -> (probs (T, E) f32 scores, eids (T, K) int32 iterative-argmax picks,
+    their scores (T, K)). Both dispatch layouts (dense one-hot and
     flat/grouped) derive from this, so expert choice and tie behavior can
-    never drift apart."""
+    never drift apart. ``score_fn`` is a property of the MODEL: "softmax"
+    over all experts (Mixtral, OLMoE) or "sigmoid" of each expert's logit
+    alone (``expert_selection_fn: sigmoid``, cohere2_moe) — positive either
+    way, which the masked argmax below relies on."""
     E, K = n_experts, top_k
     logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)  # (T, E)
+    if score_fn == "sigmoid":
+        probs = jax.nn.sigmoid(logits)  # (T, E)
+    elif score_fn == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)  # (T, E)
+    else:
+        raise ValueError(f"router score function {score_fn!r}: softmax or sigmoid")
     ids, vals = [], []
     masked = probs
     for _ in range(K):
@@ -47,14 +55,15 @@ def _select_topk(router_w: jax.Array, x: jax.Array, n_experts: int,
 
 
 def route_topk_flat(router_w: jax.Array, x: jax.Array, n_experts: int,
-                    top_k: int, renormalize: bool = True) -> tuple[jax.Array, jax.Array]:
+                    top_k: int, renormalize: bool = True,
+                    score_fn: str = "softmax") -> tuple[jax.Array, jax.Array]:
     """x (T, d), router_w (d, E) -> (eids (T, K) int32, gates (T, K) f32).
     ``renormalize`` is a property of the MODEL: Mixtral divides the K chosen
     softmax weights by their sum, OLMoE (``norm_topk_prob: false``) keeps
     them as they are. The flat (assignment-list) layout for the
     grouped-matmul dispatch path; selection comes from ``_select_topk`` so
     it is identical to the dense path by construction."""
-    _, eids, gates = _select_topk(router_w, x, n_experts, top_k)
+    _, eids, gates = _select_topk(router_w, x, n_experts, top_k, score_fn)
     if not renormalize:
         return eids, gates
     denom = jnp.sum(gates, axis=1, keepdims=True)
@@ -62,13 +71,14 @@ def route_topk_flat(router_w: jax.Array, x: jax.Array, n_experts: int,
 
 
 def route_topk(router_w: jax.Array, x: jax.Array, n_experts: int, top_k: int,
-               capacity: int, renormalize: bool = True) -> tuple[jax.Array, jax.Array]:
+               capacity: int, renormalize: bool = True,
+               score_fn: str = "softmax") -> tuple[jax.Array, jax.Array]:
     """x (T, d), router_w (d, E) -> (dispatch (T, E, C) one-hot,
     combine (T, E, C) gate-weighted). Pure function of static E/K/C.
     ``renormalize`` as in ``route_topk_flat`` (over the experts that KEPT
     the token: an overflow changes the sum)."""
     E, K, C = n_experts, top_k, capacity
-    probs, eids, _ = _select_topk(router_w, x, E, K)
+    probs, eids, _ = _select_topk(router_w, x, E, K, score_fn)
     # (T, E) gate matrix from the selected ids
     gates = jnp.sum(
         jax.nn.one_hot(eids, E, dtype=probs.dtype, axis=-1) * probs[:, None, :],

@@ -62,6 +62,7 @@ from .paged_attention import (
     ATTN_STATS,
     BlockSplit,
     common_block_split,
+    row_group_splits,
     paged_block_attention_reference,
     paged_attention,
     paged_attention_quant,
@@ -115,6 +116,7 @@ __all__ = [
     "paged_block_attention",
     "paged_block_attention_reference",
     "common_block_split",
+    "row_group_splits",
     "BlockSplit",
     "ATTN_STATS",
     "paged_block_attention_quant",

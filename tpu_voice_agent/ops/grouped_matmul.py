@@ -30,9 +30,15 @@ per row tile: where a whole (d, f) plane fits the VMEM budget the grid is
 one step a row tile and the plane's block index repeats over an expert's
 consecutive tiles, which Pallas does not fetch again. A plane too large for
 that (Mixtral's 4096 × 14336) is walked in (tk, tn) tiles with a float32
-accumulator across k. Row tiles past ``n_tiles`` (the static row bound is
-one tile an expert more than the routing needs) are skipped: no compute,
-and — their block index being the last real tile's — no weight fetch.
+accumulator across k (Command A+'s 4096 × 4096, 16 MiB: (4096, 512) tiles,
+eight column steps a row tile; PR 34 ran it on a chip first). Row tiles past
+``n_tiles`` (the static row bound is one tile an expert more than the routing
+needs — and on a chip that holds a SHARE of the experts, where most
+assignments fall elsewhere, several times the tiles that hold rows) are
+skipped: on the whole-plane path no compute, and — their block index being
+the last real tile's — no weight fetch; on the tiled path the grid's row axis
+ENDS at ``n_tiles`` and lies inside the column tiles', so a skipped tile costs
+no step and a busy expert's plane still crosses HBM once.
 
 Like every kernel in ops/, a pure-jnp reference twin and interpret=True on
 CPU keep it testable without a chip.
@@ -72,10 +78,10 @@ def plane_tiles(d: int, f: int, itemsize: int) -> tuple[int, int]:
     return _pick_tile(d, max(128, _PLANE_BYTES // (tn * itemsize))), tn
 
 
-def _gmm_kernel(sc_ref, x_ref, w_ref, *rest, scaled: bool):
+def _gmm_kernel(sc_ref, x_ref, w_ref, *rest, scaled: bool, m_axis: int = 0):
     s_ref = rest[0] if scaled else None
     o_ref, acc_ref = rest[-2], rest[-1]
-    m, k = pl.program_id(0), pl.program_id(2)
+    m, k = pl.program_id(m_axis), pl.program_id(2)
 
     @pl.when(m < sc_ref[sc_ref.shape[0] - 2])  # a tile the routing filled
     def _tile():
@@ -137,23 +143,41 @@ def grouped_matmul(
     sc = jnp.concatenate([tile_expert.astype(jnp.int32), jnp.reshape(n_tiles, (1,)).astype(jnp.int32),
                           jnp.reshape(layer, (1,)).astype(jnp.int32)])
 
-    in_specs = [
-        pl.BlockSpec((tm, tk), lambda m, n, k, sc: (m, k)),
-        pl.BlockSpec((1, 1, tk, tn), lambda m, n, k, sc: (sc[nt + 1], sc[m], k, n)),
-    ]
+    x_map = lambda m, n, k, sc: (m, k)
+    w_map = lambda m, n, k, sc: (sc[nt + 1], sc[m], k, n)
+    s_map = lambda m, n, k, sc: (sc[nt + 1], sc[m], 0, n)
+    o_map = lambda m, n, k, sc: (m, n)
+    tiled = (tk, tn) != (d, f)
+    if not tiled:
+        # a whole plane a step: a skipped tile names the last real tile's
+        # expert, so its block index repeats and nothing is fetched
+        grid = (M // tm, f // tn, d // tk)
+    else:
+        # a plane walked in (tk, tn) tiles: the COLUMN tiles outermost, so
+        # that an expert's consecutive row tiles meet the same weight tile
+        # and it is fetched once (row tiles outermost read a plane again for
+        # every row tile of a busy expert: 1.6 times the planes at Command
+        # A+'s routing, my chip run, PR 34), and the row axis ENDS at the
+        # last tile that holds rows — a skipped one would walk the column
+        # tiles for nothing, 2 MiB a step
+        rows = jnp.maximum(jnp.reshape(n_tiles, ()).astype(jnp.int32), 1)
+        grid = (f // tn, rows, d // tk)
+        x_map, w_map, s_map, o_map = (
+            (lambda n, m, k, sc, fn=fn: fn(m, n, k, sc)) for fn in (x_map, w_map, s_map, o_map))
+    in_specs = [pl.BlockSpec((tm, tk), x_map), pl.BlockSpec((1, 1, tk, tn), w_map)]
     operands = [x, q]
     if scaled:
-        in_specs.append(pl.BlockSpec((1, 1, 1, tn), lambda m, n, k, sc: (sc[nt + 1], sc[m], 0, n)))
+        in_specs.append(pl.BlockSpec((1, 1, 1, tn), s_map))
         operands.append(s.astype(jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(M // tm, f // tn, d // tk),
+        grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((tm, tn), lambda m, n, k, sc: (m, n)),
+        out_specs=pl.BlockSpec((tm, tn), o_map),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
     )
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, scaled=scaled),
+        functools.partial(_gmm_kernel, scaled=scaled, m_axis=1 if tiled else 0),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, f), x.dtype),
         compiler_params=pltpu.CompilerParams(

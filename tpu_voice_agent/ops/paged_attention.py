@@ -776,6 +776,27 @@ def _sub_rows(B: int) -> int:
     return max(c for c in range(1, max(B // 4, 1) + 1) if B % c == 0)
 
 
+def _padded_query_rows(T: int, group: int) -> int:
+    """Query rows a batch row holds in the kernel's layout: T * group, padded
+    to whole sublane tiles so that a row is an aligned slice."""
+    return -(-T * group // 16) * 16
+
+
+def row_group_splits(shape: tuple[int, int, int, int, int], block_tables, q_positions, live,
+                     bs: int, window: int | None = None, itemsize: int = 2,
+                     out_itemsize: int | None = None) -> tuple[BlockSplit, ...]:
+    """``common_block_split`` of each group of rows ``paged_block_attention``
+    walks for queries of ``shape`` (B, T, nq, nkv, hd): one split where the
+    rows' resident state fits the kernel whole, else one for each group — made
+    by the caller once a forward, for all its layers (and, with ``window``,
+    for the layers behind that window)."""
+    B, T, nq, nkv, hd = shape
+    Bg = _rows_that_fit(B, _padded_query_rows(T, nq // nkv), nkv, hd, itemsize, out_itemsize)
+    return tuple(common_block_split(block_tables[g:g + Bg], q_positions[g:g + Bg],
+                                    None if live is None else live[g:g + Bg], bs, window)
+                 for g in range(0, B, Bg))
+
+
 # analyze: ok[jit-sentinel] -- kernel wrapper traced inline by the watched engine/stt loops, never a serving dispatch entry point
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "out_dtype"))
 def paged_block_attention(
@@ -786,8 +807,9 @@ def paged_block_attention(
     q_positions: jax.Array,  # (B, T) int32 — each query's sequence position
     layer: jax.Array,  # scalar int32
     live: jax.Array | None = None,  # (B,) bool — rows whose output is read
-    split: BlockSplit | None = None,  # common_block_split of the three
-    # above, when the caller has it already (one forward, many layers)
+    split: BlockSplit | tuple | None = None,  # common_block_split of the
+    # three above, when the caller has it already (one forward, many layers);
+    # or ``row_group_splits``' tuple, one for each group of rows
     window: jax.Array | None = None,  # scalar int32: query i attends its last
     # ``window`` positions alone (a traced value, so that layers of one scan
     # may differ; ``split`` is then the caller's, made with that window)
@@ -810,19 +832,26 @@ def paged_block_attention(
     # the layout: (nkv, B * Rp, hd), riders first, a row's T*group query rows
     # padded to whole sublane tiles so that a row is an aligned slice
     R = T * group
-    Rp = -(-R // 16) * 16
+    Rp = _padded_query_rows(T, group)
     out_dtype = q.dtype if out_dtype is None else jnp.dtype(out_dtype)
     Bg = _rows_that_fit(B, Rp, nkv, hd, q.dtype.itemsize, out_dtype.itemsize)
+    # ``row_group_splits``' tuple, or nothing (one BlockSplit is a whole batch's)
+    groups = None if split is None or isinstance(split, BlockSplit) else split
     if Bg < B:
-        if window is not None:
+        # groups of rows, each with a split of its own: ``row_group_splits``'
+        # where the caller made them once a forward, else made here
+        if groups is None and window is not None:
             raise NotImplementedError(
                 "a windowed layer's split is its caller's, made for one group of rows")
         return jnp.concatenate([
             paged_block_attention(
                 q[g:g + Bg], k_pool, v_pool, block_tables[g:g + Bg], q_positions[g:g + Bg],
-                layer, None if live is None else live[g:g + Bg], scale=scale, interpret=interpret,
-                out_dtype=out_dtype)
+                layer, None if live is None else live[g:g + Bg],
+                None if groups is None else groups[g // Bg], window, scale=scale,
+                interpret=interpret, out_dtype=out_dtype)
             for g in range(0, B, Bg)])
+    if groups is not None:
+        (split,) = groups  # rows that fit whole: one group
     if split is None:
         split = common_block_split(block_tables, q_positions, live, bs)
     Bc = _sub_rows(B)

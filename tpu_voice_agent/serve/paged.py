@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..grammar.fsm import fsm_advance
-from ..models.llama import _hybrid, forward_paged
+from ..models.llama import _hybrid, forward_paged, moe_stat_names, paged_only
 from ..utils.compilewatch import get_compile_watcher, watch_compiles
 from ..utils.steplog import (
     ALLOC_SPAN,
@@ -416,8 +416,12 @@ def paged_chunk_decode_loop(
     routed = cfg.n_experts > 0
     count_kw = {"attn_stats": True, **({"moe_stats": True} if routed else {}),
                 **({"hybrid_stats": True} if hybrid else {})}
-    counts0 = (((jnp.zeros((4,), jnp.int32),) if routed or hybrid else ())
-               + (jnp.zeros((2,), jnp.int32),))
+    counts0 = (((jnp.zeros((len(moe_stat_names(cfg)) if routed else 4,), jnp.int32),)
+                if routed or hybrid else ()) + (jnp.zeros((2,), jnp.int32),))
+    # the head on the ONE position a row of a 1 + W block reads: the hybrid
+    # model, and a LlamaConfig with layers of more than one kind (the others'
+    # programs compute it on all 1 + W, as they always have)
+    one_head = hybrid or bool(cfg.layer_types)
 
     carry0 = (k_pool, v_pool, k_scale, v_scale, cur, pos, fsm_state, active,
               eos0, nbytes,
@@ -538,9 +542,10 @@ def paged_chunk_decode_loop(
             params, cfg, blk_tok, blk_pos, kp, vp,
             block_tables, rules=rules, attn_impl=kernels, write_mask=active,
             trash_idx=trash_idx, k_scale=ksc, v_scale=vsc, kv_quant=kv_quant,
-            **count_kw, **({"n_real": emitted, "logit_pos": k} if hybrid else {}),
+            **count_kw, **({"n_real": emitted} if hybrid else {}),
+            **({"logit_pos": k} if one_head else {}),
         )
-        logits_k = (logits[:, 0, :] if hybrid else
+        logits_k = (logits[:, 0, :] if one_head else
                     jnp.take_along_axis(logits, k[:, None, None], axis=1)[:, 0, :])
         if nan_inject is not None:
             logits_k = jnp.where(nan_inject[:, None] & active[:, None],
@@ -806,27 +811,35 @@ class PagedDecodeEngine(DecodeEngine):
     # ------------------------------------------------------------ prefix
 
     def _compute_prefix_kv(self, tokens, positions, P: int, bucket: int) -> dict:
-        """A hybrid model's prefix: prefilled through ``forward_paged`` into a
-        scratch pool of the bucket's blocks and ONE scratch slot, whose state
-        after the P real positions is the snapshot every admission behind the
-        prefix starts from (``_prefix_state``). Its K/V comes back in the
-        dense layout ``set_prompt_prefix`` scatters from."""
-        if not self.hybrid:
+        """The prefix of a model ``forward_paged`` alone runs (a hybrid one,
+        or a ``llama.paged_only`` one): prefilled through it into a scratch
+        pool of the bucket's blocks — and, for a hybrid model, ONE scratch
+        slot, whose state after the P real positions is the snapshot every
+        admission behind the prefix starts from (``_prefix_state``). Its K/V
+        comes back in the dense layout ``set_prompt_prefix`` scatters from."""
+        if not self.hybrid and not paged_only(self.cfg):
             return super()._compute_prefix_kv(tokens, positions, P, bucket)
-        from ..models.sambay import cache_spec
+        bs, spec = self.block_size, self._cache_spec
+        if self.hybrid:
+            from ..models.sambay import cache_spec
 
-        bs, spec = self.block_size, cache_spec(self.cfg, 1)
+            spec = cache_spec(self.cfg, 1)
         nb = -(-bucket // bs)
         shape = (spec["kv_layers"], nb + 1, bs, spec["kv_heads"], spec["kv_head_dim"])
         planes = lambda: jnp.zeros(shape, kv_planes(self.k_pool).dtype)
-        table = jnp.asarray([list(range(1, nb + 1)) + [0]], jnp.int32)  # block 0: parked writes
-        _, k, v, _, _ = forward_paged(
-            self.params, self.cfg, tokens, positions,
-            {"kv": planes(), "conv": jnp.zeros(*spec["conv"])},
-            {"kv": planes(), "ssm": jnp.zeros(*spec["ssm"])}, table,
-            attn_impl=self.kernels, fresh_block=True, n_real=jnp.asarray([P], jnp.int32))
-        self._prefix_state = {"conv": k["conv"][:, 0], "ssm": v["ssm"][:, 0]}
-        dense = lambda pool: pool["kv"][:, 1:].reshape(shape[0], 1, nb * bs, *shape[3:])[:, :, :P]
+        table = jnp.asarray([list(range(1, nb + 1)) + [0] * self.hybrid], jnp.int32)
+        if self.hybrid:  # block 0: parked writes; the table's last column: the slot
+            pools = ({"kv": planes(), "conv": jnp.zeros(*spec["conv"])},
+                     {"kv": planes(), "ssm": jnp.zeros(*spec["ssm"])})
+            kw = {"n_real": jnp.asarray([P], jnp.int32)}
+        else:
+            pools, kw = (planes(), planes()), {}
+        _, k, v, _, _ = forward_paged(self.params, self.cfg, tokens, positions, *pools, table,
+                                      attn_impl=self.kernels, fresh_block=True, **kw)
+        if self.hybrid:
+            self._prefix_state = {"conv": k["conv"][:, 0], "ssm": v["ssm"][:, 0]}
+        dense = lambda pool: kv_planes(pool)[:, 1:].reshape(
+            shape[0], 1, nb * bs, *shape[3:])[:, :, :P]
         return {"k": dense(k), "v": dense(v)}
 
     def _restore_slot_state(self, slot: int, snapshot: dict | None) -> None:

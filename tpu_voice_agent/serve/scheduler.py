@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.llama import MOE_STATS
+from ..models.llama import MOE_SHARE_STATS
 from ..ops import ATTN_STATS
 from ..utils.compilewatch import watch_compiles
 from ..utils.steplog import ALLOC_SPAN, REQUEST_SPAN, span
@@ -1141,8 +1141,10 @@ class ContinuousBatcher:
                         float(n_h.sum()) / float(fwds_h))
         if moe_h is not None:
             # summed over the chunk's forwards and layers: per forward they
-            # are these over scheduler.forwards (docs/OBSERVABILITY.md)
-            for name, v in zip(MOE_STATS, np.asarray(moe_h)):
+            # are these over scheduler.forwards (docs/OBSERVABILITY.md). Four
+            # of them, and ``moe.local_rows`` after them from a model that
+            # holds a share of its experts (``llama.moe_stat_names``)
+            for name, v in zip(MOE_SHARE_STATS, np.asarray(moe_h)):
                 m.inc(f"moe.{name}", float(v))
         if attn_h is not None:
             # likewise: the share of attended row-blocks the block kernel's

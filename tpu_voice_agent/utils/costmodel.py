@@ -91,11 +91,23 @@ def decode_step_bytes(cfg, batch: int, context_tokens: int,
     nq, nkv, L, V = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers, cfg.vocab_size
     wbytes = 1 if weight_quant == "int8" else 2
     attn = d * nq * hd + 2 * d * nkv * hd + nq * hd * d
-    weights = (L * (attn + 3 * d * f) + V * d) * wbytes
+    weights = (L * (attn + _ffn_planes(cfg) * 3 * d * f) + V * d) * wbytes
     per_pos_head = hd * KV_QUANT_VBYTES[kv_quant] + KV_SCALE_BYTES[kv_quant]
     kv = int(2 * L * context_tokens * nkv * per_pos_head) * batch
     return {"weights_bytes": int(weights), "kv_read_bytes": int(kv),
             "total_bytes": int(weights + kv)}
+
+
+def _ffn_planes(cfg) -> int:
+    """SwiGLU planes (gate, up, down of ``ffn_dim``) a layer holds on THIS
+    chip: one for a dense MLP; for a routed model the experts HELD here
+    (``LlamaConfig.n_held``: all of them, or a chip's share) beside its shared
+    experts — a saturated decode step reads nearly all of them. A model that is
+    no ``LlamaConfig`` (``models.sambay``) is still counted as a dense decoder
+    of its ``ffn_dim`` (PERF.md section 7)."""
+    if not getattr(cfg, "n_experts", 0):
+        return 1
+    return getattr(cfg, "n_held", cfg.n_experts) + getattr(cfg, "n_shared_experts", 0)
 
 
 def kv_position_bytes(cfg, kv_quant: str | None = None) -> int:
@@ -125,7 +137,12 @@ def llm_token_flops(cfg) -> int:
     E = getattr(cfg, "n_experts", 0)
     attn = d * nq * hd + 2 * d * nkv * hd + nq * hd * d
     if E > 0:
-        ffn = getattr(cfg, "top_k", 2) * 3 * d * f + d * E  # active experts + router
+        # active experts + router; on a chip that holds a share of the experts
+        # a token's picks land here in that proportion, and every token passes
+        # through the shared experts
+        held = getattr(cfg, "n_held", E)
+        ffn = ((getattr(cfg, "top_k", 2) * held // E + getattr(cfg, "n_shared_experts", 0))
+               * 3 * d * f + d * E)
     else:
         ffn = 3 * d * f
     return int(2 * (L * (attn + ffn) + V * d))

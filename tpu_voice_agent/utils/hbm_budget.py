@@ -63,7 +63,16 @@ def pp_tp_hbm_per_chip(
     quant: str | None = "int8",
     prefill_bucket: int = 2048,
 ) -> HBMBreakdown:
-    """Per-chip steady-state bytes for PPDecodeEngine at this config."""
+    """Per-chip steady-state bytes for PPDecodeEngine at this config: a dense
+    decoder's. A routed or hybrid model is refused by name rather than
+    reported as a dense decoder of its ``ffn_dim`` (its planes are counted by
+    ``costmodel.decode_step_bytes`` and, from the live tree, ``hbmledger``)."""
+    from ..models.llama import LlamaConfig
+
+    if cfg.n_experts or not isinstance(cfg, LlamaConfig):
+        raise ValueError(f"hbm_budget sizes dense decoders on the pp x tp layout; a "
+                         f"{type(cfg).__name__} with {cfg.n_experts} experts "
+                         f"is not one (utils.hbmledger plans from the engine's own tree)")
     d, f, hd = cfg.dim, cfg.ffn_dim, cfg.head_dim
     nq, nkv, L, V = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers, cfg.vocab_size
     wbytes = 1 if quant == "int8" else 2
