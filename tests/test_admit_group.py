@@ -1,0 +1,258 @@
+"""Grouped admission (ISSUE 35): requests that wait together behind the static
+prefix share ONE ``forward_paged`` at (``admit_rows``, bucket).
+
+The engine's half: ``PagedDecodeEngine.prepare_admission`` (host, a request)
+and ``admit_group`` (device, a group) leave what ``prefill_slot`` a request
+leaves. (In a module of its own: ``tests/test_paged.py`` is one of
+``conftest.SLOW_MODULES``, which tier-1 leaves out.)"""
+
+import dataclasses
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_voice_agent.models.llama import LlamaConfig
+from tpu_voice_agent.serve import ContinuousBatcher, PagedDecodeEngine
+from tpu_voice_agent.serve import scheduler as sched
+from tpu_voice_agent.serve.paged import kv_planes
+from tpu_voice_agent.services.brain import install_prompt_prefix
+from tpu_voice_agent.services.prompts import render_prompt
+from tpu_voice_agent.utils import get_metrics
+from tpu_voice_agent.utils.compilewatch import get_compile_watcher
+
+TEXTS = ["search for laptops under 1000",
+         "open the settings page, then turn on dark mode and go back to the start",  # bucket 64
+         "go back", "take a screenshot of this page", "scroll down", "play some jazz",
+         "upload my resume and submit"]
+SLOTS = 32  # admit_rows = 4
+
+
+def _engine(model: str) -> PagedDecodeEngine:
+    kw = dict(max_len=1536, batch_slots=SLOTS, prefill_buckets=(128, 256, 1024), block_size=128,
+              pool_blocks=96, fast_forward=8)
+    if model == "dense":
+        eng = PagedDecodeEngine(preset="test-tiny", **kw)
+    elif model == "routed":
+        eng = PagedDecodeEngine(cfg=LlamaConfig(
+            vocab_size=1024, dim=128, n_layers=2, n_heads=4, n_kv_heads=4, ffn_dim=64,
+            max_seq_len=1536, n_experts=8, top_k=2, capacity_factor=4.0, norm_topk=False,
+            qk_norm=True), **kw)
+    elif model == "hybrid":
+        from benchmark.builders import sambay_stack
+        from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
+        from tpu_voice_agent.models import sambay
+
+        cfg = dataclasses.replace(sambay.PRESETS["sambay-test"], vocab_size=1024,
+                                  max_seq_len=1536, window=384)
+        eng = PagedDecodeEngine(cfg=cfg, tokenizer=default_tokenizer(), quant="int8",
+                                init_weights=False, **kw)
+        eng.load_params(sambay_stack.make_params(eng.cfg, 23))
+    else:  # "share": layers of two kinds, a parallel block, held experts, a tied head
+        import json
+        from pathlib import Path
+
+        from benchmark.builders import cohere2moe_stack, parse_stack
+
+        conf = json.loads((Path(__file__).parents[1]
+                           / "benchmark/configs/command-a-plus-05-2026-int8.json").read_text())
+        cfg = cohere2moe_stack.llama_config(*parse_stack.as_run(conf, True))
+        eng = PagedDecodeEngine(cfg=dataclasses.replace(cfg, max_seq_len=1536), quant=None, **kw)
+    install_prompt_prefix(eng)
+    return eng
+
+
+@pytest.fixture(scope="module", params=["dense", "routed", "hybrid", "share"])
+def pair(request):
+    """Two engines of one model on the same weights: what the per-slot path
+    leaves on one is compared with what the grouped path leaves on the other.
+    Both see the same allocations and releases in the same order, case after
+    case, so their allocators hand out the same blocks."""
+    return request.param, _engine(request.param), _engine(request.param)
+
+
+def _pool_bytes(eng):
+    return np.asarray(kv_planes(eng.k_pool), np.float32), np.asarray(kv_planes(eng.v_pool), np.float32)
+
+
+def _books(eng, n):
+    return (np.asarray(eng.block_tables)[:n].tolist(), eng._covered[:n], eng._next_pos[:n],
+            eng._slot_owned[:n], eng._slot_shared[:n], dict(eng.allocator._refs))
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-9))
+
+
+def _logits_and_first_tokens(logits, *a, **kw):
+    """The batcher's pick, and the logits it picked from beside it."""
+    return logits, sched._first_tokens_into_slots(logits, *a, **kw)
+
+
+@pytest.mark.parametrize("n", [2, 3, 4])
+def test_a_group_leaves_what_n_per_slot_admissions_leave(pair, n):
+    """n in {2, A - 1, A} static-prefix admissions through
+    ``prepare_admission`` + ``admit_group``: the same table rows, coverage,
+    frontier, refcounts and owned / shared lists as n ``prefill_slot`` calls;
+    K/V at the prompts' positions and the last-position logits equal to
+    within rounding (a row's matmuls are tiled for another row count — on
+    the CPU they come out bit-equal or a last bit apart); the first tokens
+    identical; and rows the group does not fill write nothing: every pool
+    block but the members' own and the trash block keeps its bytes, every
+    slot outside the group its recurrent state."""
+    model, one, grp = pair
+    A = grp.admit_rows
+    assert A == 4 == one.admit_rows and n <= A
+    ids = [one.tokenizer.encode(render_prompt(t, {}), bos=True) for t in TEXTS[:n]]
+    # a short and a long suffix: one bucket where admissions are grouped
+    assert min(len(i) for i in ids) - 879 <= 32 < max(len(i) for i in ids) - 879
+    assert one.suffix_buckets == (64,) and one._suffix_bucket(8, 64) == 64
+    state = lambda: (jnp.full((SLOTS,), one.pad_id, jnp.int32), jnp.zeros((SLOTS,), jnp.int32),
+                     jnp.full((SLOTS,), 7, jnp.int32), jnp.zeros((SLOTS,), jnp.int32),
+                     jnp.zeros((SLOTS,), jnp.int32), jnp.zeros((SLOTS,), bool))
+    consts = (jnp.full((1,), one.fsm.start, jnp.int32), jnp.float32(0.7), jnp.int32(24))
+    kw = dict(greedy=True, constrained=True, kernels=one.kernels, rules=None,
+              logit_mask=one.logit_mask)
+    # the per-slot path, on ``one``
+    logits_one, st, rng = [], state(), jax.random.PRNGKey(0)
+    for slot, i in enumerate(ids):
+        lg = one.prefill_slot(i, slot)
+        logits_one.append(np.asarray(lg[0], np.float32))
+        st, rng = sched._first_token_into_slot(lg, st, rng, jnp.int32(slot), jnp.int32(len(i)),
+                                               *consts, one.tables, **kw)
+    # the grouped path, on ``grp``
+    before = _pool_bytes(grp)
+    hybrid_before = (np.asarray(grp.k_pool["conv"]), np.asarray(grp.v_pool["ssm"])) if grp.hybrid else None
+    preps = [grp.prepare_admission(i, slot) for slot, i in enumerate(ids)]
+    assert all(p is not None and p.cached == len(grp.prefix_ids) == 879 for p in preps)
+    out = grp.admit_group(preps, pick=_logits_and_first_tokens, state=state(),
+                          pick_args=(jax.random.PRNGKey(0), *consts, grp.tables, grp.logit_mask),
+                          pick_kw=tuple((k, v) for k, v in kw.items() if k != "logit_mask"))
+    logits_grp, (st_g, _) = out.picked
+    assert logits_grp.shape[:2] == (A, 1) and out.logits is None
+    assert [(r.slot, r.rows, r.width, r.cached_tokens, r.bucket) for r in out.records] == [
+        (s, n, A, 879, 64) for s in range(n)]
+    assert _books(one, n) == _books(grp, n)
+    for a, b in zip(st, st_g):  # cur, fsm, pos, nbytes, tokens_left, active: all 32 slots
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert np.asarray(st_g[5]).sum() == n and np.asarray(st_g[2])[:n].tolist() == [len(i) for i in ids]
+    for slot in range(n):
+        assert _rel(logits_grp[slot, 0], logits_one[slot]) < 2e-2
+    bs, after, ref = grp.block_size, _pool_bytes(grp), _pool_bytes(one)
+    mine = {b for s in range(n) for b in grp._slot_owned[s]}
+    for plane in (0, 1):
+        for slot, i in enumerate(ids):
+            (first, *_), R = grp._slot_owned[slot], len(grp.prefix_ids) % bs
+            got, want = (p[plane][:, first, : R + len(i) - 879] for p in (after, ref))
+            assert np.abs(want).max() > 0 and _rel(got, want) < 2e-2
+            # the prefix's sub-block tail is a copy: bit-equal
+            assert np.array_equal(got[:, :R], want[:, :R])
+        others = [b for b in range(after[plane].shape[1]) if b not in mine and b != 0]
+        assert np.array_equal(after[plane][:, others], before[plane][:, others])
+    if grp.hybrid:
+        conv, ssm = np.asarray(grp.k_pool["conv"]), np.asarray(grp.v_pool["ssm"])
+        assert np.array_equal(conv[:, n:], hybrid_before[0][:, n:])
+        assert np.array_equal(ssm[:, n:], hybrid_before[1][:, n:])
+        assert _rel(conv[:, :n], np.asarray(one.k_pool["conv"])[:, :n]) < 2e-2
+        assert _rel(ssm[:, :n], np.asarray(one.v_pool["ssm"])[:, :n]) < 2e-2
+        assert np.abs(ssm[:, :n]).max() > 0
+    for eng in (one, grp):
+        for slot in range(n):
+            eng.release_slot(slot, ok=False)
+    assert _books(one, n) == _books(grp, n)
+
+
+def _one_row_prefill_text(eng) -> str:
+    """The lowered text (scope names in, Python frames out: what the compile
+    cache keys on) of the (1, 64) suffix prefill ``prefill_slot`` dispatches
+    for a short and for a long suffix: one program."""
+    from tpu_voice_agent.serve import paged
+
+    texts, forward = [], paged.forward_paged
+
+    def spy(*a, **kw):
+        frames = jax.config.jax_traceback_in_locations_limit
+        jax.config.update("jax_traceback_in_locations_limit", 0)
+        try:
+            texts.append(forward.__wrapped__.lower(*a, **kw).as_text(debug_info=True))
+        finally:
+            jax.config.update("jax_traceback_in_locations_limit", frames)
+        return forward(*a, **kw)
+
+    paged.forward_paged = spy
+    try:
+        for slot, t in enumerate(TEXTS[:2]):
+            eng.prefill_slot(eng.tokenizer.encode(render_prompt(t, {}), bos=True), slot)
+    finally:
+        paged.forward_paged = forward
+        for slot in (0, 1):
+            eng.release_slot(slot, ok=False)
+    assert len(texts) == 2 and texts[0] == texts[1] and "tensor<1x64xi32>" in texts[0]
+    return texts[0]
+
+
+# sha256 of the lowered text of the one-row suffix prefill at bucket 64 that
+# ``prefill_slot`` dispatches on this module's four engines, as the PARENT of
+# ISSUE 35 (commit 857ed88) lowers it (its (1, 32) program is not dispatched
+# where admissions are grouped: ``suffix_buckets``): ``parse_solo`` and every
+# lone admission still run these programs (what the entry points' compile
+# cache keys on, so a chip run LOADS the parent's executables). A PR that
+# changes ``forward_paged`` on purpose re-derives them (``_one_row_prefill_text``
+# on its parent's tree) and says so.
+ONE_ROW_SHA256 = {
+    "dense": "8a98dfcdaf06a51af0d8ba6e251b3d4bbef0d08663e2d0d18b7e14998a2e0dad",
+    "routed": "473ec8f7c6f0a6f79571e5feac550de838d34c0c77a4a69d892a5146a369e069",
+    "hybrid": "e832eabb33a04f121e95d85f69364d2ec5755eaabcb989af3041fce3347c14e2",
+    "share": "9e53bc71334fa837c8d1f8821e84b6f39cff8594a6516bdb4ca4183f888fe2a0",
+}
+
+
+def test_the_one_row_prefill_programs_are_the_parents(pair):
+    """The (1, bucket) prefill program lowers to the parent's text, through
+    ``prefill_slot`` and through a group of ONE (``admit_group`` runs
+    ``prefill_slot``'s launches for it): the grouped path added arguments to
+    no call of the per-slot one."""
+    model, one, grp = pair
+    assert hashlib.sha256(_one_row_prefill_text(one).encode()).hexdigest() == ONE_ROW_SHA256[model]
+
+    def lone(ids, slot):
+        return grp.admit_group([grp.prepare_admission(ids, slot)]).logits
+
+    real, grp.prefill_slot = grp.prefill_slot, lone
+    try:
+        text = _one_row_prefill_text(grp)
+    finally:
+        grp.prefill_slot = real
+    assert hashlib.sha256(text.encode()).hexdigest() == ONE_ROW_SHA256[model]
+
+
+@pytest.mark.parametrize("model", ["dense", "hybrid"])
+def test_warmup_leaves_no_grouped_shape_uncompiled(model):
+    """After ``warmup()`` — which RUNS the grouped admission and leaves
+    nothing behind — steps that admit 2, A and A + 1 requests
+    together compile nothing, by the compile watcher's count and by JAX's own
+    ``backend_compile`` events."""
+    eng = _engine(model)
+    bat = ContinuousBatcher(eng, chunk_steps=4, max_new_tokens=8)
+    calls0 = get_metrics().counter_state()[0].get("admit.calls", 0.0)
+    bat.warmup()
+    # one grouped call beside the per-slot walk and the lone request's
+    assert get_metrics().counter_state()[0]["admit.batched_rows"] >= 2
+    assert get_metrics().counter_state()[0]["admit.calls"] - calls0 >= 2
+    assert not bat._active_h.any() and not any(eng._slot_owned) and not bat.results and not bat.pending
+    assert eng.allocator.blocks_in_use == len(eng._prefix_blocks[0])
+    watched, compiled = get_compile_watcher().state()["compiles"], []
+    listener = lambda ev, _d, **_kw: compiled.append(ev) if ev.endswith(
+        "backend_compile_duration") else None
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        for n, texts in ((2, TEXTS[2:4]), (4, TEXTS[:4]), (5, TEXTS[:5])):  # short suffixes, a long one, a lone one left over
+            rids = [bat.submit(render_prompt(t, {})) for t in texts]
+            bat.run_until_done()
+            assert all(bat.results.pop(r).error is None for r in rids)
+    finally:
+        jax._src.monitoring.unregister_event_duration_listener(listener)
+    assert not compiled and get_compile_watcher().state()["compiles"] == watched

@@ -558,6 +558,79 @@ def test_the_command_a_plus_prefills_compile_at_published_widths(tpu_devices, mo
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
 
 
+@pytest.mark.parametrize("config", ["mistral-7b-v0.1-int8", "olmoe-1b-7b-0125-int8",
+                                    "phi-4-mini-flash-reasoning-int8", "command-a-plus-05-2026-int8"])
+def test_the_grouped_admission_compiles_at_published_widths(tpu_devices, monkeypatch, config):
+    """ISSUE 35's one new program: a group's table rows, prefix tails and
+    state snapshots, ``forward_paged`` over the waiting suffixes of a step —
+    (``admit_rows``, 64) rows behind the cached prefix, a table row, a write
+    mask and a head position PER ROW, the covered blocks gathered — and the
+    batcher's first tokens, as each of the benchmark's four configurations serves it
+    (published widths, int8 weights, the 200-block pool). The head runs on one
+    position a row, and the program's temporaries stay far under the chip's
+    memory beside the weights."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from benchmark.builders import olmoe_stack, parse_stack
+    from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
+    from tpu_voice_agent.models import llama
+    from tpu_voice_agent.serve import PagedDecodeEngine
+
+    if config.startswith("phi-4"):
+        eng, s, params = _hybrid_engine(monkeypatch)
+    elif config.startswith("command-a"):
+        eng, s, params = _cmdaplus_engine(monkeypatch)
+    else:
+        monkeypatch.setattr(sys.modules["tpu_voice_agent.ops.grouped_matmul"], "on_cpu", lambda: False)
+        conf = json.loads((Path(__file__).parents[1] / "benchmark" / "configs" / f"{config}.json").read_text())
+        dims = parse_stack.model_dims(conf, False)
+        m, s = dims["model"], dims["serving"]
+        llama_config, make_params = ((olmoe_stack.llama_config, olmoe_stack.make_params) if "num_experts" in m
+                                     else (parse_stack.dense_llama_config, parse_stack.make_decoder_params))
+        eng = PagedDecodeEngine(
+            cfg=llama_config(m, s), tokenizer=default_tokenizer(), quant=s["quant"], batch_slots=s["batch_slots"],
+            block_size=s["block_size"], pool_blocks=2, max_len=s["max_len"],
+            prefill_buckets=tuple(s["prefill_buckets"]), fast_forward=s["fast_forward"], init_weights=False)
+        params = jax.eval_shape(lambda: make_params(eng.cfg, s["weights_seed"]))
+    eng.prefix_ids = [0] * 879  # behind a prefix: what turns the grouped path on
+    A, cfg = eng.admit_rows, eng.cfg
+    assert A == 4 and eng.GROUP_BUCKET == 64
+    chip = SingleDeviceSharding(tpu_devices[0])
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    shapes = lambda tree: jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), tree)
+    if eng.hybrid:
+        k_pool, v_pool = _hybrid_pools(eng, s, S)
+    else:
+        k_pool = v_pool = S((cfg.n_layers, s["pool_blocks"], eng.block_size, cfg.n_kv_heads, cfg.head_dim), BF16)
+    from tpu_voice_agent.serve import paged, scheduler
+
+    tail = S((k_pool["kv"] if eng.hybrid else k_pool).shape[:1] + (879 % eng.block_size,)
+             + (k_pool["kv"] if eng.hybrid else k_pool).shape[3:], BF16)
+    snapshot = ({"conv": S(k_pool["conv"].shape[:1] + k_pool["conv"].shape[2:], k_pool["conv"].dtype),
+                 "ssm": S(v_pool["ssm"].shape[:1] + v_pool["ssm"].shape[2:], v_pool["ssm"].dtype)}
+                if eng.hybrid else None)
+    B = eng.batch_slots
+    state = (S((B,), I32), S((B,), I32), S((B,), I32), S((B,), I32), S((B,), I32), S((B,), jnp.bool_))
+    pick_args = (shapes(jax.random.PRNGKey(0)), S((1,), I32), S((), F32), S((), I32), shapes(eng.tables),
+                 None if eng.logit_mask is None else shapes(eng.logit_mask))
+    compiled = paged.forward_paged_first_tokens.__wrapped__.lower(
+        shapes(params), cfg, S((A, 64), I32), S((A, 64), I32), k_pool, v_pool,
+        S((B, eng.max_blocks + eng.hybrid), I32), S((A, eng.max_blocks + eng.hybrid), I32),
+        S((A,), I32), S((A,), I32), S((A,), jnp.bool_), S((A,), I32),
+        S((A,), I32) if eng.hybrid else None, {"k": tail, "v": tail}, S((A * (879 % eng.block_size),), I32),
+        snapshot, S((A,), I32), state, pick_args, rules=None,
+        attn_impl="pallas" if eng.hybrid else "xla", gather_blocks=eng._gather_bucket(879, 64),
+        pick=scheduler._first_tokens_into_slots,
+        pick_kw=(("greedy", True), ("constrained", True), ("kernels", "pallas"), ("rules", None))).compile()
+    text = compiled.as_text()  # one program, the head on one position a row
+    assert "jit_forward_paged_first_tokens" in text and f"f32[{A},{cfg.vocab_size}]" in text
+    assert f"[{A},64,{cfg.vocab_size}]" not in text and f"[{A * 64},{cfg.vocab_size}]" not in text
+    # the pools are not donated through ``__wrapped__``: two copies of them are in it
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
 @pytest.mark.slow
 def test_sharded_kernels_compile_on_2x2(tpu_devices):
     """The shard_map variants the dp x tp serving mesh traces (batch over

@@ -501,3 +501,160 @@ def test_common_pass_counters_behind_the_batcher_match_the_tables(monkeypatch):
     counters = fresh.snapshot()["counters"]
     assert [counters["attn.common_row_blocks"], counters["attn.row_blocks"]] == want.tolist()
     assert want[0] / want[1] > 0.6  # the prefix is most of what a row attends
+
+
+# ---------------------------------------------------------------- grouped admission (ISSUE 35)
+
+GROUP_TEXTS = ["search for laptops under 1000",
+               "open the settings page, then turn on dark mode and go back to the start",
+               "go back", "take a screenshot of this page", "scroll down", "play some jazz",
+               "upload my resume and submit"]
+
+
+@functools.lru_cache(maxsize=None)
+def _grouping(**kw):
+    """A paged engine that groups admissions: 32 slots (``admit_rows`` 4)
+    behind the 879-token prompt prefix; with ``kw`` (radix, spec) one that
+    does not. Shared: every test leaves it with no slot held."""
+    from tpu_voice_agent.serve.paged import PagedDecodeEngine
+    from tpu_voice_agent.services.brain import install_prompt_prefix
+
+    eng = PagedDecodeEngine(preset="test-tiny", max_len=1536, batch_slots=32, block_size=128,
+                            pool_blocks=96, prefill_buckets=(128, 256, 1024), fast_forward=8,
+                            **{"radix_enable": False, **kw})
+    install_prompt_prefix(eng)
+    return eng
+
+
+def _group_prompts(n):
+    from tpu_voice_agent.services.prompts import render_prompt
+
+    return [render_prompt(t, {}) for t in GROUP_TEXTS[:n]]
+
+
+def _admit_counts(fn):
+    """What ``fn()`` moved of (admit.calls, admit.rows, admit.batched_rows)."""
+    from tpu_voice_agent.utils import get_metrics
+
+    names = ("admit.calls", "admit.rows", "admit.batched_rows")
+    before = dict(get_metrics().counter_state()[0])
+    out = fn()
+    after = get_metrics().counter_state()[0]
+    return out, tuple(int(after.get(k, 0.0) - before.get(k, 0.0)) for k in names)
+
+
+@functools.lru_cache(maxsize=None)
+def _per_slot_plans(n):
+    """The n plans through ``prefill_slot`` alone, one step's admissions."""
+    eng = _grouping()
+    mp = pytest.MonkeyPatch()
+    mp.setattr(type(eng), "admit_rows", 0)
+    try:
+        out, counts = _admit_counts(lambda: ContinuousBatcher(
+            eng, chunk_steps=8, max_new_tokens=96).generate_many(_group_prompts(n)))
+    finally:
+        mp.undo()
+    assert counts == (n, n, 0) and all(r.error is None for r in out)
+    return [r.token_ids for r in out]
+
+
+@pytest.mark.parametrize("n", [2, 4, 5, 7])
+def test_requests_that_wait_together_are_admitted_together(n):
+    """A step that opens with n requests waiting beside free slots makes
+    ceil(n / A) prefill calls, the last of them at one row when one request
+    is left over; the requests take the slots in the order they came; and
+    every plan is the per-slot path's, token for token."""
+    eng = _grouping()
+    A = eng.admit_rows
+    assert A == 4
+    bat = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=96)
+    rids = [bat.submit(p) for p in _group_prompts(n)]
+    _, (calls, rows, batched) = _admit_counts(bat.step)
+    assert (calls, rows) == (-(-n // A), n) and batched == n - (n % A == 1)
+    assert [bat.slots[b].request_id for b in range(n)] == rids  # FIFO, slot by slot
+    assert bat._active_h[:n].all() and not bat._active_h[n:].any()
+    assert all(bat.slots[b].cached_tokens == 879 and bat.slots[b].prefill_ms > 0 for b in range(n))
+    bat.run_until_done()
+    got = [bat.results.pop(r) for r in rids]
+    assert all(r.error is None and r.cached_tokens == 879 for r in got)
+    assert [r.token_ids for r in got] == _per_slot_plans(n)
+    assert eng.allocator.blocks_in_use == len(eng._prefix_blocks[0])
+    assert {k for k in vars(eng) if k.startswith("_last_")} <= {
+        "_last_prefill_compute_ms", "_last_cached_tokens"}
+
+
+@pytest.mark.parametrize("fault", ["oversized", "prefill_exc", "pool_exhausted"])
+def test_a_member_that_fails_in_its_host_half_fails_alone(fault):
+    """Four requests wait; the SECOND one's host half raises — a prompt past
+    every bucket (``ValueError``), a chaos fault at the top of admission, the
+    allocator out of blocks. It alone fails (typed) or, for the pool, is put
+    back at the head and admitted by the next step; the others are admitted
+    in this step, as a group, and decode the tokens they decode undisturbed."""
+    from tpu_voice_agent.utils import chaos
+
+    eng = _grouping()
+    prompts = _group_prompts(4)
+    if fault == "oversized":
+        prompts[1] = prompts[1] + " and then scroll down" * 200
+    bat = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=96)
+    rids = [bat.submit(p) for p in prompts]
+    if fault != "oversized":
+        chaos.configure({"prefill_exc": "prefill_exc@2", "pool_exhausted": "alloc_fail@2"}[fault], seed=0)
+    try:
+        _, (calls, rows, batched) = _admit_counts(bat.step)
+    finally:
+        chaos.reset()
+    want = _per_slot_plans(4)
+    if fault == "pool_exhausted":
+        # the first is launched alone when the second breaks the loop off
+        assert (calls, rows, batched) == (1, 1, 0) and [r for r, _ in bat.pending] == rids[1:]
+        _, (calls, rows, batched) = _admit_counts(bat.step)
+        assert (calls, rows, batched) == (1, 3, 3)
+    else:
+        assert (calls, rows, batched) == (1, 3, 3) and not bat.pending
+        assert bat.results[rids[1]].error and not bat.results[rids[1]].token_ids
+    bat.run_until_done()
+    got = [bat.results.pop(r) for r in rids]
+    for i, r in enumerate(got):
+        if i == 1 and fault != "pool_exhausted":
+            assert r.error.startswith("chaos: injected" if fault == "prefill_exc" else "prompt length")
+        else:
+            assert r.error is None and r.token_ids == want[i]
+    assert eng.allocator.blocks_in_use == len(eng._prefix_blocks[0])
+
+
+@pytest.mark.parametrize("case", ["lone-waiter", "no-prefix-match", "radix", "spec", "chunked"])
+def test_what_the_grouped_path_does_not_take_goes_through_prefill_slot(case, monkeypatch):
+    """One request waiting; prompts that do not start with the cached prefix;
+    an engine with radix reuse or spec decode on (``admit_rows`` 0); and
+    admissions that PREFILL_CHUNK_TOKENS chunks: none reaches ``admit_group``,
+    all are admitted, a slot at a time."""
+    from tpu_voice_agent.serve.spec import SpecConfig
+
+    if case == "chunked":
+        monkeypatch.setenv("PREFILL_CHUNK_TOKENS", "512")
+    eng = _grouping(**{"radix": {"radix_enable": True},
+                       "spec": {"spec": SpecConfig(k=4, drafter="fsm")}}.get(case, {}))
+    assert eng.admit_rows == (0 if case in ("radix", "spec") else 4)
+    grouped, per_slot, chunked = [], [], []
+    for name, seen in (("admit_group", grouped), ("prefill_slot", per_slot),
+                       ("begin_chunked_prefill", chunked)):
+        fn = getattr(eng, name)
+        monkeypatch.setattr(eng, name, lambda *a, _f=fn, _s=seen, **kw: _s.append(1) or _f(*a, **kw))
+    prompts = _group_prompts(1 if case == "lone-waiter" else 3)
+    if case == "no-prefix-match":
+        prompts = ["turn on the lights", "play some jazz", "what time is it"]
+    bat = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=16)
+    for p in prompts:
+        bat.submit(p)
+    _, (calls, rows, batched) = _admit_counts(bat.step)
+    assert not grouped and batched == 0
+    if case == "chunked":
+        assert len(chunked) == len(prompts) and not per_slot
+    else:
+        assert len(per_slot) == len(prompts) == calls == rows
+    bat.run_until_done()
+    assert all(r.error is None for r in bat.results.values()) and len(bat.results) == len(prompts)
+    bat.reset()
+    if case != "radix":  # whose tree keeps the finished requests' chains
+        assert eng.allocator.blocks_in_use == len(eng._prefix_blocks[0])

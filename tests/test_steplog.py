@@ -386,6 +386,106 @@ def test_admissions_tile_their_request_and_count_admitted(scope_engine):
         round(a["queue_ms"], 3) for a in adm)
 
 
+def test_a_group_span_shares_its_parts_out_over_its_members():
+    """``StepTimer.group``: the launches several admissions share are ONE
+    span; its parts and its own time land in every member's entry in even
+    shares, the entries gain ``rows``, and a group that raises takes its
+    members' entries out of the ledger (nobody was admitted)."""
+    import time
+
+    from tpu_voice_agent.utils.steplog import (
+        ALLOC_SPAN,
+        PREFILL_CALL_SPAN,
+        PREFILL_STAGE_SPAN,
+        REQUEST_SPAN,
+        span,
+    )
+
+    log = StepLog(max_steps=8, enabled=True)
+    t = log.timer()
+    t.stage("sched.admit")
+    entries = []
+    for rid in (1, 2, 3):
+        with t.span(REQUEST_SPAN, rid=rid, queue_ms=0.5) as req:
+            with span(ALLOC_SPAN):  # the host half: ``prepare_admission``
+                time.sleep(0.002)
+        entries.append(req.entry)
+    host = [dict(e) for e in entries]
+    with t.group(entries):
+        with span(ALLOC_SPAN):
+            time.sleep(0.003)
+            with span(PREFILL_STAGE_SPAN), span(PREFILL_CALL_SPAN):
+                time.sleep(0.015)
+        with span(f"{REQUEST_SPAN}.slot_state"):
+            time.sleep(0.003)
+    t.stage("sched.decode_dispatch")
+    rec = t.finish()
+    assert [a["rid"] for a in rec["admissions"]] == [1, 2, 3]
+    for a, h in zip(rec["admissions"], host):
+        assert a["rows"] == 3 and "rows" not in h
+        assert a["prefill_call_ms"] >= 4.9 and a["slot_state_ms"] >= 0.9
+        assert a["alloc_ms"] >= h["alloc_ms"] + 0.9  # its own host half + a third of the group's
+        assert a["request_ms"] >= h["request_ms"] + 6.9
+        parts = sum(a.get(f"{p}_ms", 0.0) for p in ("alloc", "prefill_call", "slot_state"))
+        assert 0.95 * a["request_ms"] <= parts <= a["request_ms"] + 1e-3
+    # the entries sum to the admit and prefill stages (the loop around them is the rest)
+    whole = sum(a["request_ms"] for a in rec["admissions"])
+    assert whole <= rec["stages"]["admit"] + rec["stages"]["prefill"] + 1e-3
+    assert whole >= 0.9 * (rec["stages"]["admit"] + rec["stages"]["prefill"])
+    assert rec["stages"]["prefill"] >= 14.9  # the one call's stage, once
+    # a launch that raises admitted nobody
+    t = log.timer()
+    t.stage("sched.admit")
+    with t.span(REQUEST_SPAN, rid=9) as req:
+        pass
+    with t.span(REQUEST_SPAN, rid=10) as other:
+        pass
+    with pytest.raises(RuntimeError):
+        with t.group([req.entry]):
+            raise RuntimeError("the device half failed")
+    assert [a["rid"] for a in t.finish()["admissions"]] == [10] and other.entry["rid"] == 10
+
+
+@pytest.mark.parametrize("n", [3, 5])
+def test_a_groups_ledger_entries_carry_rows_and_sum_to_the_admit_stage(n):
+    """Behind the batcher: n requests admitted in one step — one grouped call
+    of 3, or one of 4 and a lone one — leave n entries with ``rows`` (members
+    of their call), the six parts less ``first_token_call`` (a group's head
+    runs on the last position alone), equal shares of the call within a
+    group, and together the step's admit and prefill stages."""
+    from tpu_voice_agent.serve.paged import PagedDecodeEngine
+    from tpu_voice_agent.services.brain import install_prompt_prefix
+    from tpu_voice_agent.services.prompts import render_prompt
+
+    eng = PagedDecodeEngine(preset="test-tiny", max_len=1536, batch_slots=32, block_size=128,
+                            pool_blocks=96, prefill_buckets=(128, 256, 1024), radix_enable=False)
+    install_prompt_prefix(eng)
+    bat = _batcher(eng)
+    bat.warmup()
+    get_steplog().clear()
+    texts = ["go back", "scroll down", "play some jazz", "what time is it", "stop"][:n]
+    for t in texts:
+        bat.submit(render_prompt(t, {}))
+    bat.step()
+    (rec,) = get_steplog().steps()
+    adm = rec["admissions"]
+    assert rec["admitted"] == n == len(adm)
+    assert [a["rows"] for a in adm] == ([3, 3, 3] if n == 3 else [4, 4, 4, 4, 1])
+    grouped = [a for a in adm if a["rows"] > 1]
+    for a in grouped:
+        assert {"tokenize_ms", "alloc_ms", "prefill_call_ms", "slot_state_ms", "bookkeeping_ms",
+                "queue_ms", "request_ms", "prompt_tokens"} <= set(a)
+        assert a["cached_tokens"] == 879 and "first_token_call_ms" not in a
+    assert len({a["prefill_call_ms"] for a in grouped}) == 1  # even shares of ONE call
+    from tpu_voice_agent.utils.steplog import ADMISSION_PARTS
+
+    for a in adm:  # the parts never pass the request (how closely they tile it: the unit test above)
+        assert sum(a.get(f"{p}_ms", 0.0) for p in ADMISSION_PARTS) <= a["request_ms"] + 1e-3, a
+    whole, stages = sum(a["request_ms"] for a in adm), rec["stages"]["admit"] + rec["stages"]["prefill"]
+    assert 0.5 * stages <= whole <= stages + 1e-3  # the rest: the loop around them, on a busy machine
+    bat.reset()
+
+
 def test_queue_wait_grows_when_slots_are_busy(scope_engine):
     """``queue_ms`` is submit() -> popped from ``pending``: ~0 on an idle
     batcher, a whole generation long for the requests that found both

@@ -106,6 +106,10 @@ PINNED: dict[str, str] = {
     "scheduler.tokens_per_forward": "gauge",
     "scheduler.forwards": "counter",
     "scheduler.forward_rows": "counter",  # ISSUE 29: forwards x the chunk's width
+    # ISSUE 35: prefill calls / admissions / admissions that rode a grouped call
+    "admit.calls": "counter",
+    "admit.rows": "counter",
+    "admit.batched_rows": "counter",
     # engine microscope (ISSUE 9, utils/steplog.py + utils/compilewatch.py
     # + utils/hbmledger.py, docs/OBSERVABILITY.md "Engine microscope"):
     # the step ledger's wall histogram + per-chunk occupancy/token gauges

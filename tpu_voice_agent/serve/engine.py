@@ -684,6 +684,9 @@ class DecodeEngine:
     # logits alone: ROADMAP D13)
     _last_prefill_compute_ms = None
     _last_cached_tokens = 0
+    # rows of a grouped admission call; 0: this layout admits a slot at a time
+    # (``PagedDecodeEngine.admit_rows`` has the one layout that groups)
+    admit_rows = 0
 
     def __init__(
         self,
@@ -966,10 +969,16 @@ class DecodeEngine:
         prefix + bucket fits the cache. None = no bucket fits; the caller
         falls back to full prefill (which may still fit, since the full
         prompt buckets independently)."""
-        for b in (32, 64) + self.prefill_buckets:
+        for b in self.suffix_buckets + self.prefill_buckets:
             if n <= b <= limit:
                 return b
         return None
+
+    @property
+    def suffix_buckets(self) -> tuple:
+        """The buckets tried for a suffix before the full-prompt ones: every
+        one is a forward executable a start-up compiles or loads."""
+        return (32, 64)
 
     # ------------------------------------------------------------ prefix
 

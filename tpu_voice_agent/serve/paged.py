@@ -30,7 +30,9 @@ from __future__ import annotations
 
 import os
 import time
+from dataclasses import dataclass
 from functools import partial
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +46,7 @@ from ..utils.steplog import (
     FIRST_TOKEN_SPAN,
     PREFILL_CALL_SPAN,
     PREFILL_STAGE_SPAN,
+    REQUEST_SPAN,
     STATE_RESTORE_SPAN,
     span,
 )
@@ -57,6 +60,8 @@ from .engine import (
     _poison_gate,
 )
 from .radix import RadixCache
+
+SLOT_STATE_SPAN = REQUEST_SPAN + ".slot_state"
 
 
 class PoolExhausted(RuntimeError):
@@ -84,6 +89,49 @@ class _ChunkedPrefill:
         self.n_chunks = n_chunks
         self.j = 0              # chunks completed
         self.total_ms = 0.0     # accumulated compute (prefill_ms at finish)
+
+
+@dataclass(frozen=True)
+class PreparedAdmission:
+    """The HOST half of one static-prefix admission (ISSUE 35), from
+    ``PagedDecodeEngine.prepare_admission``: the slot is released and holds
+    its blocks, nothing has been launched. Whatever can fail for ONE request
+    (a chaos fault, ``PoolExhausted``) has failed by now; what is left,
+    ``admit_group``, is device work the requests of a step share."""
+
+    slot: int
+    n: int  # prompt tokens
+    cached: int  # of them behind the static prefix (P)
+    suffix: tuple  # the n - P token ids the forward computes
+    bucket: int  # the suffix bucket this admission alone would run at
+    blocks: tuple  # the slot's table: the prefix's shared blocks, then its own
+    tail_at: int  # flat pool index where the prefix's sub-block tail lands
+
+
+@dataclass(frozen=True)
+class AdmissionRecord:
+    """What ``admit_group`` says of one admission, as a value (the way
+    ``decode_chunk`` returns ``ChunkResult``; the per-slot ``prefill_slot``
+    still leaves its two ``_last_prefill_*`` attributes)."""
+
+    slot: int
+    cached_tokens: int
+    compute_ms: float  # this admission's share of the call's wall
+    rows: int  # admissions that rode the call
+    width: int  # rows the call computed: 1, or ``admit_rows``
+    bucket: int  # positions a row of the call computed
+
+
+@dataclass(frozen=True)
+class GroupAdmission:
+    """One ``admit_group`` call. From a call of one row: ``logits`` (1, V),
+    what ``prefill_slot`` returns (the first-token launch is the caller's).
+    From a grouped one: ``picked``, what the caller's ``pick`` made of the
+    (A, 1, V) logits inside the group's one program."""
+
+    records: tuple
+    logits: Any = None
+    picked: Any = None
 
 
 class BlockAllocator:
@@ -270,6 +318,52 @@ def _restore_state(k_pool, v_pool, conv, ssm, slot):
     convolution tails in ``k_pool``, the float32 states in ``v_pool``."""
     return ({**k_pool, "conv": k_pool["conv"].at[:, slot].set(conv)},
             {**v_pool, "ssm": v_pool["ssm"].at[:, slot].set(ssm)})
+
+
+@watch_compiles("paged._set_table_rows")
+@partial(jax.jit, donate_argnames=("tables",))
+def _set_table_rows(tables, slots, rows):
+    """Several slots' table rows in one launch (a group's admissions; the
+    slots a chunk's claim grew): ``rows`` (n, width) land at ``slots`` (n,);
+    an entry of ``slots`` past the table (a row not used) is dropped."""
+    return tables.at[slots].set(rows, mode="drop")
+
+
+@watch_compiles("paged.forward_paged_first_tokens")
+@partial(jax.jit,
+         static_argnames=("cfg", "rules", "attn_impl", "gather_blocks", "pick", "pick_kw"),
+         donate_argnames=("k_pool", "v_pool", "tables"))
+def forward_paged_first_tokens(params, cfg, tokens, positions, k_pool, v_pool, tables, rows,
+                               slots, ns, live, last, n_real, tail, dst, snapshot, restore_at,
+                               state, pick_args, *, rules, attn_impl: str, gather_blocks: int,
+                               pick, pick_kw: tuple = ()):
+    """A GROUP's admission as ONE device program (ISSUE 35): the members'
+    table rows into ``tables`` (an entry of ``slots`` past the table — a row
+    the group does not fill — is dropped), the prefix's sub-block ``tail``
+    into each member's first own block (``dst`` (A * R,) flat pool indices;
+    a row not filled writes the trash block), a hybrid model's ``snapshot``
+    into the members' state (``restore_at``: a row not filled names member
+    0's slot again and writes the same bytes), ``forward_paged`` over the
+    (A, bucket) suffixes with a write mask, a head position and, for that
+    model, a count of real positions a row, and the caller's ``pick`` on the
+    (A, 1, V) logits — the batcher's first tokens into its slot state.
+    One program and not five: a start-up then loads ONE more executable
+    (``setup_s`` may not grow a tenth), and a group is one launch.
+    -> (``pick``'s result, k_pool, v_pool, tables)."""
+    A = tokens.shape[0]
+    tables = tables.at[slots].set(rows, mode="drop")
+    if tail is not None:
+        tile = lambda x: jnp.tile(x, (1, A) + (1,) * (x.ndim - 2))
+        k_pool, v_pool = _scatter_blocks(k_pool, v_pool, tile(tail["k"]), tile(tail["v"]), dst)
+    if snapshot is not None:
+        rep = lambda x: jnp.repeat(x[:, None], A, axis=1)
+        k_pool, v_pool = _restore_state(k_pool, v_pool, rep(snapshot["conv"]),
+                                        rep(snapshot["ssm"]), restore_at)
+    logits, k_pool, v_pool, _, _ = forward_paged(
+        params, cfg, tokens, positions, k_pool, v_pool, rows, rules=rules,
+        attn_impl=attn_impl, write_mask=live, logit_pos=last, n_real=n_real,
+        fresh_block=False, gather_blocks=gather_blocks)
+    return pick(logits, state, slots, ns, *pick_args, **dict(pick_kw)), k_pool, v_pool, tables
 
 
 @watch_compiles("paged._scatter_blocks_quant")
@@ -856,7 +950,7 @@ class PagedDecodeEngine(DecodeEngine):
                 self.k_pool, self.v_pool, snapshot["conv"], snapshot["ssm"], jnp.int32(slot))
         get_metrics().inc("ssm.state_restores")
 
-    def _prefill_kw(self, attn_impl: str, n_real: int) -> dict:
+    def _prefill_kw(self, attn_impl: str, n_real) -> dict:
         """A prefill forward's arguments that follow the model's kind: a
         decoder whose state is K/V alone takes the layout kernel's attention
         path; a hybrid model is told the engine's kernels (its forward picks
@@ -864,8 +958,9 @@ class PagedDecodeEngine(DecodeEngine):
         positions are real (its states advance over those alone)."""
         if not self.hybrid:
             return {"rules": self.rules, "attn_impl": attn_impl}
-        return {"rules": self.rules, "attn_impl": self.kernels,
-                "n_real": jnp.asarray([n_real], jnp.int32)}
+        if isinstance(n_real, int):  # one row; a group hands its (A,) array
+            n_real = jnp.asarray([n_real], jnp.int32)
+        return {"rules": self.rules, "attn_impl": self.kernels, "n_real": n_real}
 
     def set_prompt_prefix(self, *sample_prompts: str) -> int:
         self._prefix_state = None
@@ -912,7 +1007,7 @@ class PagedDecodeEngine(DecodeEngine):
 
     # ------------------------------------------------------------ admission
 
-    def _set_table_row(self, slot: int, blocks: list[int]) -> None:
+    def _table_row(self, slot: int, blocks) -> np.ndarray:
         row = np.zeros(self.max_blocks, np.int32)
         row[: len(blocks)] = blocks
         # empty table rows must still point INSIDE the slot's dp shard
@@ -920,7 +1015,11 @@ class PagedDecodeEngine(DecodeEngine):
         row[len(blocks):] = self._group(slot) * self.allocator.blocks_per_group
         if self.hybrid:
             row = np.append(row, np.int32(slot))  # the state index, never a block
-        self.block_tables = self.block_tables.at[slot].set(jnp.asarray(row))
+        return row
+
+    def _set_table_row(self, slot: int, blocks: list[int]) -> None:
+        self.block_tables = self.block_tables.at[slot].set(
+            jnp.asarray(self._table_row(slot, blocks)))
 
     def _alloc(self, k: int, group: int) -> list[int]:
         """allocator.alloc with radix backpressure: when the pool is out,
@@ -961,7 +1060,18 @@ class PagedDecodeEngine(DecodeEngine):
         [0, len(chain)*bs) read-only; ``tail`` optionally supplies dense KV
         for [len(chain)*bs, P); the (1, bucket) suffix forward computes
         [P, n). New tokens only ever land in the freshly allocated owned
-        blocks (copy-on-write: suffix writes start at P >= len(chain)*bs)."""
+        blocks (copy-on-write: suffix writes start at P >= len(chain)*bs).
+        The host half (``_claim_chain``), then the launches
+        (``_run_chain_row``): ``admit_group`` runs the same two, apart."""
+        owned = self._claim_chain(slot, chain, P, bucket, n)
+        return self._run_chain_row(tokens, positions, slot, list(chain) + owned,
+                                   owned[0], P, bucket, n, tail)
+
+    def _claim_chain(self, slot: int, chain: list[int], P: int, bucket: int,
+                     n: int) -> list[int]:
+        """The HOST half of a chain admission: the slot's own blocks behind
+        ``chain`` for [len(chain)*bs, P + bucket), and its books. Launches
+        nothing; ``PoolExhausted`` gives the chain's refs back."""
         bs = self.block_size
         full = len(chain)
         n_owned = -(-(P + bucket) // bs) - full
@@ -971,19 +1081,16 @@ class PagedDecodeEngine(DecodeEngine):
             self.allocator.free(chain)  # don't leak the chain refs
             raise
         self._slot_shared[slot], self._slot_owned[slot] = list(chain), owned
-        self._set_table_row(slot, list(chain) + owned)
         self._covered[slot] = (full + n_owned) * bs
-        if tail is not None:
-            # sub-block chain remainder goes into the slot's first
-            # owned block (shared blocks stay read-only)
-            R = P - full * bs
-            dst = jnp.asarray(owned[0] * bs + np.arange(R, dtype=np.int32))
-            self._scatter_pool(tail["k"], tail["v"], dst)
-        # gather only the COVERED blocks, bucketed to a power of two so
-        # compile count stays log-bounded (gathering the whole table
-        # width — max_len of context — per layer was round-2 verdict
-        # weak #6)
-        need = -(-(P + bucket) // bs)
+        self._next_pos[slot] = n
+        return owned
+
+    def _gather_bucket(self, P: int, bucket: int) -> int:
+        """Table entries a mid-sequence prefill gathers a layer: the COVERED
+        blocks, bucketed to a power of two so compile count stays
+        log-bounded (gathering the whole table width — max_len of context —
+        per layer was round-2 verdict weak #6)."""
+        need = -(-(P + bucket) // self.block_size)
         gb = 1
         while gb < need:
             gb *= 2
@@ -997,8 +1104,23 @@ class PagedDecodeEngine(DecodeEngine):
             # keep the pre-radix gather shapes (and therefore programs)
             # byte-identical.
             gb = gb * 3 // 4
-        gb = min(gb, self.max_blocks)
-        self._next_pos[slot] = n
+        return min(gb, self.max_blocks)
+
+    def _run_chain_row(self, tokens, positions, slot: int, blocks: list[int],
+                       first_owned: int, P: int, bucket: int, n: int,
+                       tail: dict | None = None):
+        """The launches of ONE chain admission, behind ``_claim_chain``: the
+        slot's table row, the chain's sub-block tail into its first own
+        block, a hybrid model's state, the (1, bucket) forward."""
+        bs = self.block_size
+        self._set_table_row(slot, blocks)
+        if tail is not None:
+            # sub-block chain remainder goes into the slot's first
+            # owned block (shared blocks stay read-only)
+            R = tail["k"].shape[1]  # P less the chain's whole blocks
+            dst = jnp.asarray(first_owned * bs + np.arange(R, dtype=np.int32))
+            self._scatter_pool(tail["k"], tail["v"], dst)
+        gb = self._gather_bucket(P, bucket)
         table_row = self.block_tables[slot][None]
         if self.hybrid:
             # the chain is the static prefix (radix is refused): its K/V is the
@@ -1089,6 +1211,151 @@ class PagedDecodeEngine(DecodeEngine):
             self._slot_ids[slot] = ids
         with span(FIRST_TOKEN_SPAN):
             return logits[:, m - 1, :]
+
+    # ------------------------------------------------- grouped admission
+
+    # positions a row of a grouped call computes: the wider of the two suffix
+    # buckets ``_suffix_bucket`` tries first (short payloads' own). ONE, so
+    # that grouping adds one forward executable to a start-up and not two: a
+    # warm start loads each one in 0.5 (a scanned dense 7B) to 3 s (unrolled
+    # layers around Pallas calls), and ``setup_s`` may not grow a tenth. A
+    # group whose suffixes all fit 32 pays 64 (PERF.md section 6, PR 35)
+    GROUP_BUCKET = 64
+
+    @property
+    def admit_rows(self) -> int:
+        """The ONE width A of a grouped admission call (ISSUE 35): requests
+        that wait together behind the static prefix share one
+        ``forward_paged`` at (A, ``GROUP_BUCKET``) — one read of the weights — where
+        ``prefill_slot`` reads them once a request. An eighth of the slots
+        (and not the quarter ``compact_rows`` takes): past ~128 rows a dense
+        7B forward is compute-bound, so a call's device time grows with A
+        while a group of TWO must still cost less than two one-row calls
+        (PERF.md section 6, PR 35, has the chip's numbers at 4 and at 8).
+        0 = never: a mesh (slots of different dp groups share no batch
+        axis), radix reuse (an admission's chain is its own), spec decode (a
+        drafter is seeded per admission), ``KV_QUANT`` — all of which
+        ``prefill_slot`` serves as it did."""
+        A = self.batch_slots // 8
+        on = (A >= 2 and self.dp == 1 and self.radix is None and self.spec is None
+              and self.kv_quant is None and bool(self.prefix_ids))
+        return A if on else 0
+
+    @property
+    def suffix_buckets(self) -> tuple:
+        """Where admissions are grouped, ONE suffix bucket before the
+        full-prompt ones, the group's: the (1, 32) program would serve the
+        odd lone short suffix alone, for half a millisecond of a 24 ms call
+        at a dense 7B's width (my chip runs, PR 35), and every forward
+        executable is 0.5 to 4 s of a warm start. So grouping leaves the
+        number of forward programs a start-up loads where it was."""
+        return (self.GROUP_BUCKET,) if self.admit_rows else super().suffix_buckets
+
+    def prepare_admission(self, ids: list[int], slot: int) -> PreparedAdmission | None:
+        """The HOST half of a static-prefix admission into ``slot``: the
+        prefix match, the slot's release, its blocks and books — everything
+        of ``prefill_slot`` that can fail for this request alone
+        (``PoolExhausted``, a chaos fault), and no launch. None, with nothing
+        touched: this prompt is ``prefill_slot``'s (no prefix match, a suffix
+        past ``GROUP_BUCKET``, the grouped path off)."""
+        from ..utils.chaos import ChaosError, chaos_fire
+
+        if not self.admit_rows:
+            return None
+        suffix = self._split_prefix(ids)
+        if suffix is None:
+            return None
+        P = len(self.prefix_ids)
+        bucket = self._suffix_bucket(len(suffix), self.max_len - P)
+        if bucket is None or bucket > self.GROUP_BUCKET:
+            return None
+        with span(ALLOC_SPAN):
+            if chaos_fire("prefill_exc"):
+                raise ChaosError("chaos: injected prefill exception")  # as prefill_slot's
+            self.release_slot(slot)  # a finished request may still own resources
+            bs = self.block_size
+            shared = list(self._prefix_blocks[self._group(slot)][: P // bs])
+            self.allocator.ref(shared)
+            owned = self._claim_chain(slot, shared, P, bucket, len(ids))
+            return PreparedAdmission(slot=slot, n=len(ids), cached=P, suffix=tuple(suffix),
+                                     bucket=bucket, blocks=tuple(shared + owned),
+                                     tail_at=owned[0] * bs)
+
+    def admit_group(self, group: list[PreparedAdmission], pick=None, state=None,
+                    pick_args: tuple = (), pick_kw: tuple = ()) -> GroupAdmission:
+        """The DEVICE half of the admissions ``prepare_admission`` prepared,
+        ONE program for the group (``forward_paged_first_tokens``): their
+        table rows, their prefix tails, for a model with a recurrent state
+        their snapshots, ONE ``forward_paged`` at (``admit_rows``,
+        ``GROUP_BUCKET``) whose head runs on each row's last real position,
+        and the caller's ``pick(logits, state, slots, ns, *pick_args,
+        **dict(pick_kw))`` on its logits (a module-level function and a tuple
+        of pairs: they are static). Rows the group does not fill park their
+        writes. A group of one runs ``prefill_slot``'s launches at (1,
+        bucket), the programs it always ran, and hands its logits back.
+        One record an admission; nothing is left on the engine."""
+        t0 = time.perf_counter()
+        if len(group) == 1:
+            (p,) = group
+            P, m = p.cached, len(p.suffix)
+            tokens = np.full((1, p.bucket), self.pad_id, dtype=np.int32)
+            tokens[0, :m] = p.suffix
+            positions = (P + np.arange(p.bucket, dtype=np.int32))[None, :]
+            with span(ALLOC_SPAN), span(PREFILL_STAGE_SPAN):
+                logits = self._run_chain_row(
+                    jnp.asarray(tokens), jnp.asarray(positions), p.slot, list(p.blocks),
+                    p.tail_at // self.block_size, P, p.bucket, p.n, tail=self._prefix_tail)
+                ms = (time.perf_counter() - t0) * 1e3
+            with span(FIRST_TOKEN_SPAN):
+                logits = logits[:, m - 1, :]
+            return GroupAdmission((AdmissionRecord(p.slot, P, ms, 1, 1, p.bucket),), logits=logits)
+        A, n, P, bucket = self.admit_rows, len(group), group[0].cached, self.GROUP_BUCKET
+        slots = np.full((A,), self.batch_slots, np.int32)  # past the table: dropped
+        slots[:n] = [p.slot for p in group]
+        with span(ALLOC_SPAN):
+            tokens = np.full((A, bucket), self.pad_id, dtype=np.int32)
+            positions = np.broadcast_to(P + np.arange(bucket, dtype=np.int32), (A, bucket))
+            # a row the group does not fill: a table of trash blocks, no real
+            # position, a masked write. For a hybrid model its state index is
+            # a slot OUTSIDE the group, whose state it rewrites as it found it
+            # (what an idle row of the chunk program does to its own)
+            rows = np.zeros((A, self.max_blocks + self.hybrid), np.int32)
+            if self.hybrid:
+                rows[n:, -1] = next(b for b in range(self.batch_slots) if b not in slots)
+            m_real = np.zeros((A,), np.int32)
+            R = 0 if self._prefix_tail is None else self._prefix_tail["k"].shape[1]
+            dst = np.broadcast_to(np.arange(R, dtype=np.int32), (A, R)).copy()  # trash block
+            for i, p in enumerate(group):
+                tokens[i, : len(p.suffix)] = p.suffix
+                rows[i] = self._table_row(p.slot, p.blocks)
+                m_real[i] = len(p.suffix)
+                dst[i] += p.tail_at
+            ns = np.asarray([p.n for p in group] + [0] * (A - n), np.int32)
+            restore_at = np.where(m_real > 0, slots, slots[0])
+            with span(PREFILL_STAGE_SPAN):
+                with span(SLOT_STATE_SPAN):  # the one host→device copy, before the launch
+                    staged = jax.device_put((
+                        tokens, positions, rows, slots, ns, m_real > 0,
+                        np.maximum(m_real - 1, 0), m_real if self.hybrid else None,
+                        dst.reshape(-1), restore_at))
+                tokens, positions, rows, slots, ns, live, last, n_real, dst, restore_at = staged
+                with span(PREFILL_CALL_SPAN):
+                    picked, self.k_pool, self.v_pool, self.block_tables = \
+                        forward_paged_first_tokens(
+                            self.params, self.cfg, tokens, positions, self.k_pool, self.v_pool,
+                            self.block_tables, rows, slots, ns, live, last, n_real,
+                            self._prefix_tail, dst, self._prefix_state if self.hybrid else None,
+                            restore_at, state, pick_args, rules=self.rules,
+                            attn_impl=self.kernels if self.hybrid else "xla",
+                            gather_blocks=self._gather_bucket(P, bucket),
+                            pick=pick, pick_kw=pick_kw)
+                ms = (time.perf_counter() - t0) * 1e3 / n
+        if self.hybrid:
+            from ..utils import get_metrics
+
+            get_metrics().inc("ssm.state_restores", float(n))
+        return GroupAdmission(
+            tuple(AdmissionRecord(p.slot, P, ms, n, A, bucket) for p in group), picked=picked)
 
     def _prefill_full(self, tokens, positions, slot: int, bucket: int, n: int):
         bs = self.block_size
@@ -1268,8 +1535,12 @@ class PagedDecodeEngine(DecodeEngine):
             if self._slot_owned[b] and b not in self._mid_prefill:
                 self._next_pos[b] = min(self._next_pos[b], int(pos_h[b]))
 
-    def _grow(self, slot: int, upto: int) -> None:
-        """Extend a slot's table so positions < upto have blocks."""
+    def _grow(self, slot: int, upto: int, grown: list | None = None) -> None:
+        """Extend a slot's table so positions < upto have blocks. With
+        ``grown`` the slot is noted there and its table row left to the
+        caller's ONE ``_put_table_rows`` (a chunk's claim: every request
+        admitted this step needs a block more, and a launch a slot made the
+        device wait for the chunk program behind them)."""
         bs = self.block_size
         upto = min(upto, self.max_len)
         if upto <= self._covered[slot]:
@@ -1277,8 +1548,23 @@ class PagedDecodeEngine(DecodeEngine):
         extra = self._alloc(
             -(-(upto - self._covered[slot]) // bs), self._group(slot))
         self._slot_owned[slot].extend(extra)
-        self._set_table_row(slot, self._slot_shared[slot] + self._slot_owned[slot])
         self._covered[slot] += len(extra) * bs
+        if grown is None:
+            self._set_table_row(slot, self._slot_shared[slot] + self._slot_owned[slot])
+        else:
+            grown.append(slot)
+
+    def _put_table_rows(self, slots: list[int]) -> None:
+        """The table rows of ``slots`` from the host's books, in ONE launch
+        at one shape (``batch_slots`` rows; the unused ones name a slot past
+        the table and are dropped)."""
+        B = self.batch_slots
+        at = np.full((B,), B, np.int32)
+        at[: len(slots)] = slots
+        rows = np.zeros((B, self.max_blocks + self.hybrid), np.int32)
+        for i, b in enumerate(slots):
+            rows[i] = self._table_row(b, self._slot_shared[b] + self._slot_owned[b])
+        self.block_tables = _set_table_rows(self.block_tables, *jax.device_put((at, rows)))
 
     def _rows_of(self, live) -> "np.ndarray | None":
         """The (R,) slot index a compacted chunk rides, or None for the full
@@ -1336,6 +1622,9 @@ class PagedDecodeEngine(DecodeEngine):
         W = (self.tables_ff.ff_tokens.shape[1]
              if self.tables_ff is not None else 0)
         span = chunk_steps * (1 + W)
+        # slots whose claim took blocks: one launch for all of them (a slot at
+        # a time under a mesh, whose tables keep their placement that way)
+        grown: list[int] | None = [] if self.mesh is None else None
         for b in range(self.batch_slots):
             if b in self._mid_prefill:
                 # chunked admission underway (ISSUE 19): the row is not
@@ -1345,7 +1634,7 @@ class PagedDecodeEngine(DecodeEngine):
                 continue
             if self._slot_owned[b]:  # request in flight on this slot
                 try:
-                    self._grow(b, self._next_pos[b] + span + 1)
+                    self._grow(b, self._next_pos[b] + span + 1, grown)
                 except PoolExhausted:
                     # per-request isolation at decode time too: the slot
                     # that cannot grow truncates cleanly (finished=False)
@@ -1353,6 +1642,8 @@ class PagedDecodeEngine(DecodeEngine):
                     tokens_left = tokens_left.at[b].set(0)
                     continue
                 self._next_pos[b] = min(self._next_pos[b] + span, self.max_len)
+        if grown:
+            self._put_table_rows(grown)
         rows = self._rows_of(live) if greedy else None
         # absent at the full width, so that call is the one it always was
         compact = {} if rows is None else {"rows_idx": jnp.asarray(rows)}

@@ -9,7 +9,12 @@ equivalent is slot-based continuous batching on one device mesh:
   (engine.prefill_row slices that row out, runs a (1, bucket) forward, and
   writes it back in place) — admission cost is independent of batch width,
   and other slots' cache lines are never touched; the shared prompt prefix
-  is copied from the engine's prefix KV instead of recomputed
+  is copied from the engine's prefix KV instead of recomputed. Requests that
+  WAIT TOGETHER behind that prefix are admitted together where the engine
+  groups admissions (the paged one: ``admit_rows``): each does its host half
+  alone, then one forward over the waiting suffixes' rows admits the group
+  (``_admit_pending``, ``_launch_group``) — one read of the weights, not one
+  a request
 - decode advances ALL active slots together in chunked on-device loops
   (`chunk_steps` per dispatch): one host round-trip per chunk, not per token
   — a readback stalls the dispatch pipeline and idles the device while the
@@ -44,7 +49,7 @@ from .engine import (
     GenerationResult,
     _mask_sample_advance,
 )
-from .paged import PoolExhausted
+from .paged import PoolExhausted, PreparedAdmission
 
 try:  # device faults must PROPAGATE out of per-request fences (a corrupted
     # engine must not be dispatched again); everything else fails alone
@@ -86,6 +91,45 @@ def _first_token_into_slot(last_logits, state, rng, slot, n, start_state,
             pos.at[slot].set(n), nbytes.at[slot].set(0),
             tokens_left.at[slot].set(max_new_tokens),
             active.at[slot].set(True)), rng
+
+
+def _first_tokens_into_slots(last_logits, state, slots, ns, rng, start_state,
+                             temperature, max_new_tokens, tables, logit_mask=None, *,
+                             greedy: bool = True, constrained: bool = True,
+                             kernels: str = "xla", rules=None):
+    """``_first_token_into_slot`` for a GROUP of admissions (ISSUE 35): the
+    ``pick`` the engine's one group program (``paged.forward_paged_first_tokens``)
+    runs on its logits — traced inside it, no launch of its own.
+    ``last_logits`` (A, 1, V) is each row's last real position, ``slots`` /
+    ``ns`` (A,) where and how long each admitted prompt is. A row the group
+    does not fill names slot ``batch_slots``, past the state arrays: its pick
+    is dropped. One key split a group (one an admission on the per-slot
+    path: a sampled, non-greedy stream differs between the two, a greedy one
+    does not). -> (the six state arrays, the next key)."""
+    rng, k = jax.random.split(rng)
+    A = last_logits.shape[0]
+    tok0, fsm0 = _mask_sample_advance(
+        last_logits.reshape(A, -1), jnp.broadcast_to(start_state, (A,)), tables, k,
+        temperature, greedy, constrained, kernels, rules, logit_mask)
+    cur, fsm, pos, nbytes, tokens_left, active = state
+    put = lambda arr, v: arr.at[slots].set(v, mode="drop")
+    return (put(cur, tok0), put(fsm, fsm0), put(pos, ns), put(nbytes, 0),
+            put(tokens_left, max_new_tokens), put(active, True)), rng
+
+
+@dataclass
+class _Waiting:
+    """A request whose HOST half of admission is done (``_admit`` with a
+    group open): its slot is taken and holds its blocks, its ledger entry
+    holds the host half; the launches are its group's."""
+
+    rid: int
+    slot: int
+    prep: PreparedAdmission
+    entry: dict | None  # its ``admissions`` entry in the step ledger
+    t0: float
+    t_enq: float
+    queue_ms: float
 
 
 @dataclass
@@ -263,15 +307,18 @@ class ContinuousBatcher:
         """Compile, BEFORE the serving loop takes traffic, what a request
         can make it dispatch: the admission prefill at every suffix bucket
         behind the installed prompt prefix (every full-prompt bucket when
-        there is none), the first-token pick, and one decode chunk with its
-        readback, at every width the chunk program has. A cold compile
+        there is none), the first-token pick, the grouped admission,
+        and one decode chunk with its readback, at every width the
+        chunk program has. A cold compile
         inside ``step()`` stalls every batch-mate and runs under the colocate stall watchdog (``ENGINE_STALL_S``):
         a burst landing in several uncompiled buckets at once would outlast
         it and get the healthy engine warm-restarted. MUST run on the thread
         that drives ``step()``, with nothing in flight."""
+        from ..utils.steplog import get_steplog
+
         eng = self.engine
         prefix = list(eng.prefix_ids)
-        for b in (32, 64) + tuple(eng.prefill_buckets):
+        for b in eng.suffix_buckets + tuple(eng.prefill_buckets):
             if len(prefix) + b > eng.max_len:
                 break
             try:
@@ -280,6 +327,19 @@ class ContinuousBatcher:
                 break  # a pool this small sheds such a prompt in serving too
             finally:
                 eng.release_slot(0, ok=False)
+        # the grouped admission (ISSUE 35), RUN and not only compiled: two
+        # requests waiting beside two free slots are a group; its launches
+        # alone, no chunk behind them
+        if eng.admit_rows and len(prefix) + eng.GROUP_BUCKET <= eng.max_len:
+            rids = [self.submit(prefix + [eng.pad_id] * 8) for _ in range(2)]
+            timer = get_steplog().timer()
+            try:
+                self._admit_pending(timer, self._active_h)
+            finally:
+                timer.close()
+            for rid in rids:
+                self.cancel(rid, "warm-up")
+                self.results.pop(rid, None)
         rid = self.submit(prefix + [eng.pad_id] * 8)
         res = self.step()
         if res is not None and res.rows != self.B:
@@ -521,23 +581,38 @@ class ContinuousBatcher:
         return None
 
     def _admit(self, slot: int, rid: int, prompt: str, timer,
-               queue_ms: float) -> bool:
-        """Prefill ONE slot's cache line (cost independent of batch width —
-        round 1 prefilled the full (B, bucket) batch per admission, 32×
-        wasted FLOPs at 32 slots) and reuse the engine's shared-prefix KV
-        when the prompt starts with it.
+               queue_ms: float, group: list | None = None) -> str:
+        """One request's admission into ``slot``, or its HOST half.
 
-        Returns True when a CHUNKED admission was started instead (ISSUE
+        With no ``group`` (one request waiting, or the engine groups none):
+        prefill ONE slot's cache line through ``engine.prefill_slot`` — a
+        (1, bucket) forward, whatever the batch width — reusing the engine's
+        shared-prefix KV when the prompt starts with it; "live" back.
+
+        With a ``group`` open (ISSUE 35: several requests wait beside as many
+        free slots), the request does here what can fail for it ALONE —
+        tokenize, ``engine.prepare_admission``: the slot's release, the
+        prefix match, its blocks — and joins the group as a ``_Waiting``;
+        "waiting" back. ``_launch_group`` then admits the group with one
+        forward over its members' rows (not every SLOT's row, which round 1
+        computed for each admission: 32x wasted FLOPs at 32 slots). A prompt
+        the engine does not group (no prefix match, a long suffix) takes the
+        per-slot path as above.
+
+        "chunked" back when a CHUNKED admission was started instead (ISSUE
         19, PREFILL_CHUNK_TOKENS set, long prompt, engine supports it):
         the slot is reserved — request_id set, active stays False — and
         ``_advance_admissions`` runs one prefill chunk per step until the
         final chunk lands, so a 1k-token cold prompt never head-of-line-
         blocks batch-mates' decode chunks behind a barrier prefill.
 
-        The whole admission is ONE ``sched.admit.request`` span whose parts
-        (utils.steplog.ADMISSION_PARTS; the engine's ``prefill_slot`` writes
-        ``.alloc`` and ``.prefill_call``) tile it; a raise drops its ledger
-        entry with it."""
+        Each request is ONE ``sched.admit.request`` span whose parts
+        (utils.steplog.ADMISSION_PARTS; the engine writes ``.alloc`` and
+        ``.prefill_call``) tile it; a raise drops its ledger entry with it.
+        A waiting request's span is its host half, and its entry gains its
+        share of the group's launches when they run."""
+        from ..utils import get_metrics
+
         eng = self.engine
         with timer.span(REQUEST_SPAN, rid=rid, queue_ms=round(queue_ms, 3)) as req:
             if self.tenancy is not None:
@@ -570,17 +645,84 @@ class ContinuousBatcher:
                     self._admitting[slot] = (
                         cursor, self._enqueued_at.pop(rid, t0), queue_ms,
                         self._stage_slot(slot, n))
-                    from ..utils import get_metrics as _gm
-
-                    _gm().inc("prefill.chunked_admissions")
+                    get_metrics().inc("prefill.chunked_admissions")
                     req.drop()  # the admission lands with its last chunk
-                    return True
+                    return "chunked"
+            prep = eng.prepare_admission(ids, slot) if group is not None else None
+            if prep is not None:
+                with span(f"{REQUEST_SPAN}.bookkeeping"):
+                    # taken: ``_free_slot`` passes it by until the launch
+                    self.slots[slot].request_id = rid
+                    req.set(cached_tokens=prep.cached)
+                    group.append(_Waiting(rid, slot, prep, req.entry, t0,
+                                          self._enqueued_at.pop(rid, t0), queue_ms))
+                return "waiting"
             slot_n = self._stage_slot(slot, n)
             last_logits = eng.prefill_slot(ids, slot)
             self._finish_admission(slot, rid, n, slot_n, last_logits, t0,
-                                   self._enqueued_at.pop(rid, t0), queue_ms)
-            req.set(cached_tokens=self.slots[slot].cached_tokens)
-        return False
+                                   self._enqueued_at.pop(rid, t0), queue_ms,
+                                   eng._last_prefill_compute_ms, eng._last_cached_tokens)
+            req.set(cached_tokens=self.slots[slot].cached_tokens, rows=1)
+            self._count_call(1)
+        return "live"
+
+    @staticmethod
+    def _count_call(rows: int) -> None:
+        """One device prefill call that admitted ``rows`` requests."""
+        from ..utils import get_metrics
+
+        m = get_metrics()
+        m.inc("admit.calls")
+        m.inc("admit.rows", float(rows))
+        if rows > 1:
+            m.inc("admit.batched_rows", float(rows))
+
+    def _launch_group(self, group: list, timer) -> None:
+        """The DEVICE half of the waiting requests' admissions, once for the
+        group: ``engine.admit_group`` — ONE program: table rows, prefix tails,
+        the forward, and ``_first_tokens_into_slots`` on its logits — then
+        each member's books. On the trace ``sched.admit.group`` holds the
+        launch's spans (``.alloc``, ``.slot_state`` for its one host→device
+        copy, ``.prefill_call`` once a call);
+        in the ledger what it took is shared out evenly over the members'
+        entries, which gain ``rows``, so a step's entries still sum to its
+        admit and prefill stages. A group of one runs the per-slot programs
+        (``admit_group`` says how). A fault here is the device's or the
+        program's, never one member's prompt: a device fault propagates, as
+        from ``_admit``; anything else fails every member, typed."""
+        eng = self.engine
+        lone = group[0] if len(group) == 1 else None
+        try:
+            with timer.group([w.entry for w in group]):
+                if lone is not None:
+                    slot_n = self._stage_slot(lone.slot, lone.prep.n)
+                    out = eng.admit_group([lone.prep])
+                    (rec,) = out.records
+                    self._finish_admission(lone.slot, lone.rid, lone.prep.n, slot_n,
+                                           out.logits, lone.t0, lone.t_enq, lone.queue_ms,
+                                           rec.compute_ms, rec.cached_tokens)
+                    self._count_call(1)
+                    return
+                out = eng.admit_group(
+                    [w.prep for w in group], pick=_first_tokens_into_slots,
+                    state=(self.cur, self.fsm, self.pos, self.nbytes, self.tokens_left,
+                           self.active),
+                    pick_args=(self._rng, *self._admit_consts, eng.tables, eng.logit_mask),
+                    pick_kw=(("greedy", self.greedy), ("constrained", True),
+                             ("kernels", eng.kernels), ("rules", eng.rules)))
+                (self.cur, self.fsm, self.pos, self.nbytes, self.tokens_left,
+                 self.active), self._rng = out.picked
+                with span(f"{REQUEST_SPAN}.bookkeeping"):
+                    for w, rec in zip(group, out.records):
+                        self._book_admission(w.slot, w.rid, w.prep.n, w.t0, w.t_enq,
+                                             w.queue_ms, rec.compute_ms, rec.cached_tokens)
+                    self._count_call(len(group))
+        except Exception as e:
+            if isinstance(e, _DeviceFault):
+                raise
+            for w in group:
+                self._record_offense(w.rid, f"prefill {type(e).__name__}")
+                self._evict_slot(w.slot, str(e), "scheduler.prefill_faults")
 
     @staticmethod
     def _stage_slot(slot: int, n: int):
@@ -591,8 +733,8 @@ class ContinuousBatcher:
             return jax.device_put((np.int32(slot), np.int32(n)))
 
     def _finish_admission(self, slot: int, rid: int, n: int, slot_n,
-                          last_logits, t0: float, t_enq: float,
-                          queue_ms: float) -> None:
+                          last_logits, t0: float, t_enq: float, queue_ms: float,
+                          prefill_ms: float, cached_tokens: int) -> None:
         """The admission tail shared by one-shot and chunked prefills: ONE
         launch (``_first_token_into_slot``: key split, fused grammar-mask
         first-token sample, the slot's six state entries) on arrays already
@@ -600,7 +742,7 @@ class ContinuousBatcher:
         prefill cost fold. Every in-chunk instance of the mask→sample tail
         is jit-inlined inside the decode loops; this is its one
         host-dispatched instance, under the same ``grammar_mask_sample``
-        scope."""
+        scope (a group's is ``_first_tokens_into_slots``)."""
         eng = self.engine
         with span(f"{REQUEST_SPAN}.slot_state"):
             (self.cur, self.fsm, self.pos, self.nbytes, self.tokens_left,
@@ -612,12 +754,13 @@ class ContinuousBatcher:
                 greedy=self.greedy, constrained=True, kernels=eng.kernels,
                 rules=eng.rules, logit_mask=eng.logit_mask)
         with span(f"{REQUEST_SPAN}.bookkeeping"):
-            self._book_admission(slot, rid, n, t0, t_enq, queue_ms)
+            self._book_admission(slot, rid, n, t0, t_enq, queue_ms, prefill_ms,
+                                 cached_tokens)
 
     def _book_admission(self, slot: int, rid: int, n: int, t0: float,
-                        t_enq: float, queue_ms: float) -> None:
+                        t_enq: float, queue_ms: float, prefill_ms: float,
+                        cached_tokens: int) -> None:
         """The host-only end of an admission (``.bookkeeping``)."""
-        eng = self.engine
         sl = self.slots[slot]
         sl.request_id = rid
         sl.token_ids = []
@@ -625,9 +768,11 @@ class ContinuousBatcher:
         sl.prompt_len = n
         # prefill_ms = COMPUTED suffix dispatch only (the old wall-clock
         # number conflated cached-prefix bookkeeping with real forward
-        # time); cached_tokens carries the part the cache absorbed
-        sl.prefill_ms = eng._last_prefill_compute_ms
-        sl.cached_tokens = int(eng._last_cached_tokens)
+        # time); cached_tokens carries the part the cache absorbed. Both are
+        # the engine's to say: ``prefill_slot`` leaves them on itself,
+        # ``admit_group`` returns them
+        sl.prefill_ms = prefill_ms
+        sl.cached_tokens = int(cached_tokens)
         sl.queue_ms = queue_ms
         sl.eos = False
         # TTFT: ENQUEUE through the first sampled token — queue wait
@@ -690,14 +835,18 @@ class ContinuousBatcher:
                     continue
                 stepped += 1
                 m.inc("prefill.chunks")
+                m.inc("admit.calls")
                 if last_logits is None:
                     req.drop()  # a middle chunk: on the trace, no admission
                     continue
                 self._admitting.pop(slot, None)
                 self._finish_admission(slot, rid, self.slots[slot].prompt_len,
                                        slot_n, last_logits,
-                                       self.slots[slot].start_s, t_enq, queue_ms)
-                req.set(cached_tokens=self.slots[slot].cached_tokens)
+                                       self.slots[slot].start_s, t_enq, queue_ms,
+                                       eng._last_prefill_compute_ms,
+                                       eng._last_cached_tokens)
+                m.inc("admit.rows")
+                req.set(cached_tokens=self.slots[slot].cached_tokens, rows=1)
             act[slot] = True
             done += 1
             # chaos drill arming matches the one-shot admission path
@@ -911,41 +1060,39 @@ class ContinuousBatcher:
         finally:
             timer.close()  # a step that raised or returned early
 
-    def _step(self, timer, epoch: int) -> ChunkResult | None:
+    def _admit_pending(self, timer, act: np.ndarray) -> tuple[int, int]:
+        """The admission loop of a step: waiting requests into free slots,
+        FIFO (or by ``tenancy.pick``), each fenced alone — deadline shed at
+        dequeue, ``PoolExhausted`` requeued or shed, any other fault of its
+        host half failed typed — and, where several wait beside as many free
+        slots and the engine groups admissions (``admit_rows``), launched
+        together: up to that many requests a ``_launch_group``. Returns
+        (admitted, attempted) for the step ledger."""
         from ..utils import get_metrics
         from ..utils.chaos import chaos_fire
 
         m = get_metrics()
-        timer.stage("sched.admit")
+        plane = self.tenancy
         n_admitted = 0    # successful admissions (slot went live)
         n_attempted = 0   # dequeued attempts, failures/sheds included
+        A = self.engine.admit_rows
+        waiting: list[_Waiting] = []  # host halves done, launch to come
 
-        act = self._active_h  # host mirror — no device readback for admission
-        # mid-decode cancellation: a slot whose deadline expired aborts at
-        # the chunk boundary, releasing slot + blocks instead of burning
-        # decode steps for a response nobody will read
-        for b in range(self.B):
-            rid = self.slots[b].request_id
-            if rid >= 0:
-                dl = self._deadline.get(rid)
-                if dl is not None and dl.expired:
-                    self._evict_slot(b, "cancelled: deadline expired mid-decode",
-                                     "scheduler.cancelled")
-        plane = self.tenancy
-        if (plane is not None and self._preempt_on and self.pending
-                and self._free_slot(act) is None):
-            # over-budget preemption (ISSUE 18): all slots busy while a
-            # poorer lane starves — vacate the richest lane's slot at this
-            # chunk boundary (at most one per step; see _preempt_slot)
-            victim = plane.over_budget_victim(
-                [(b, self._tenant.get(self.slots[b].request_id))
-                 for b in range(self.B)
-                 if self.slots[b].request_id >= 0 and act[b]
-                 and self.slots[b].token_ids
-                 and self._preempted.get(self.slots[b].request_id, 0) < 1],
-                [self._tenant.get(r) for r, _ in self.pending])
-            if victim is not None:
-                self._preempt_slot(victim)
+        def launch() -> None:
+            nonlocal n_admitted
+            self._launch_group(waiting, timer)
+            for w in waiting:
+                if self.slots[w.slot].request_id != w.rid:
+                    continue  # the launch failed it (typed, in ``results``)
+                act[w.slot] = True
+                n_admitted += 1
+                # chaos drill arming, as for a per-slot admission below
+                if chaos_fire("nan_logits"):
+                    self._nan_slots.add(w.slot)
+                if chaos_fire("dead_fsm"):
+                    self.fsm = self.fsm.at[w.slot].set(-1)
+            waiting.clear()
+
         while self.pending:
             slot = self._free_slot(act)
             if slot is None:
@@ -975,13 +1122,20 @@ class ContinuousBatcher:
                     plane.on_dequeue(self._tenant.get(rid), admitted=False)
                 self._cleanup(rid)
                 continue
+            # a group is open while one waits for its launch, and opens when
+            # another request waits behind this one beside another free slot
+            grouped = A > 0 and (bool(waiting) or (
+                bool(self.pending) and any(
+                    not act[b] and self.slots[b].request_id < 0
+                    for b in range(slot + 1, self.B))))
             try:
-                chunked = self._admit(slot, rid, prompt, timer, queue_ms)
+                how = self._admit(slot, rid, prompt, timer, queue_ms,
+                                  waiting if grouped else None)
                 self._pool_wait.pop(rid, None)
                 self._requeues.pop(rid, None)
                 if plane is not None:
                     plane.on_dequeue(self._tenant.get(rid), admitted=True)
-                if not chunked:
+                if how == "live":
                     act[slot] = True
                     n_admitted += 1
                     # chaos drill arming (no-ops with chaos off): NaN logits
@@ -991,6 +1145,8 @@ class ContinuousBatcher:
                         self._nan_slots.add(slot)
                     if chaos_fire("dead_fsm"):
                         self.fsm = self.fsm.at[slot].set(-1)
+                elif len(waiting) == A:
+                    launch()
             except PoolExhausted as e:
                 # pool-pressure degradation ladder (stage 3; stages 1-2 —
                 # radix cold-leaf eviction and session-cache admission
@@ -1004,7 +1160,7 @@ class ContinuousBatcher:
                     pass  # partial admission state is best-effort cleanup
                 first = self._pool_wait.setdefault(rid, time.perf_counter())
                 waited = time.perf_counter() - first
-                if (not act.any() or waited >= self._pool_wait_s
+                if ((not act.any() and not waiting) or waited >= self._pool_wait_s
                         or (dl is not None and dl.expired)):
                     self.results[rid] = _err_result(f"shed: {e}")
                     m.inc("scheduler.shed_pool")
@@ -1047,6 +1203,44 @@ class ContinuousBatcher:
                 if plane is not None:
                     plane.on_dequeue(self._tenant.get(rid), admitted=False)
                 self._cleanup(rid)
+
+        if waiting:
+            launch()
+        return n_admitted, n_attempted
+
+    def _step(self, timer, epoch: int) -> ChunkResult | None:
+        from ..utils import get_metrics
+
+        m = get_metrics()
+        timer.stage("sched.admit")
+
+        act = self._active_h  # host mirror — no device readback for admission
+        # mid-decode cancellation: a slot whose deadline expired aborts at
+        # the chunk boundary, releasing slot + blocks instead of burning
+        # decode steps for a response nobody will read
+        for b in range(self.B):
+            rid = self.slots[b].request_id
+            if rid >= 0:
+                dl = self._deadline.get(rid)
+                if dl is not None and dl.expired:
+                    self._evict_slot(b, "cancelled: deadline expired mid-decode",
+                                     "scheduler.cancelled")
+        plane = self.tenancy
+        if (plane is not None and self._preempt_on and self.pending
+                and self._free_slot(act) is None):
+            # over-budget preemption (ISSUE 18): all slots busy while a
+            # poorer lane starves — vacate the richest lane's slot at this
+            # chunk boundary (at most one per step; see _preempt_slot)
+            victim = plane.over_budget_victim(
+                [(b, self._tenant.get(self.slots[b].request_id))
+                 for b in range(self.B)
+                 if self.slots[b].request_id >= 0 and act[b]
+                 and self.slots[b].token_ids
+                 and self._preempted.get(self.slots[b].request_id, 0) < 1],
+                [self._tenant.get(r) for r, _ in self.pending])
+            if victim is not None:
+                self._preempt_slot(victim)
+        n_admitted, n_attempted = self._admit_pending(timer, act)
 
         # drop enqueue stamps with no pending entry left (requests admitted
         # above pop their own; these are abandons — colocate tombstoning

@@ -49,6 +49,14 @@ duration into the step's record. The step itself is a
                                      ``prefill_slot`` less this call)
               .state_restore         a recurrent model's admissions only: the
                                      prefix's state snapshot copied into the slot
+        sched.admit.group            what a group of admissions shares (attr
+                                     rows): each member's request span above
+                                     is its HOST half alone; the parts in here
+                                     (.alloc, .slot_state: one host→device
+                                     copy, .prefill_call: the group's ONE
+                                     launch, .bookkeeping) are shared out
+                                     evenly over the members' ledger entries,
+                                     which gain ``rows``
       sched.decode_dispatch          stage ``decode``
         sched.decode.draft           stage ``draft``
       sched.readback                 stage ``readback``
@@ -94,6 +102,12 @@ FIRST_TOKEN_SPAN = REQUEST_SPAN + ".first_token_call"
 # it like ``.prefill_call`` (``state_restore_ms`` in the admission's entry;
 # no other model's admission has the key, so it is no ``ADMISSION_PARTS``)
 STATE_RESTORE_SPAN = REQUEST_SPAN + ".state_restore"
+# what a GROUP of admissions shares (ISSUE 35): each member's
+# ``sched.admit.request`` span is its host half, this span holds the group's
+# ``.alloc`` / ``.slot_state`` / ``.prefill_call`` (its one launch) /
+# ``.bookkeeping`` parts, once a call, and each member's entry gets an even
+# share of them
+GROUP_SPAN = "sched.admit.group"
 # one admission in code order; each is ``<part>_ms`` in its ledger entry
 ADMISSION_PARTS = ("tokenize", "alloc", "prefill_call", "first_token_call",
                    "slot_state", "bookkeeping")
@@ -176,7 +190,8 @@ class _Span:
     """One open span: a TraceAnnotation on the profiler's clock, and a
     ``perf_counter_ns`` duration folded into the step's record at exit."""
 
-    __slots__ = ("timer", "name", "stage", "part", "entry", "ann", "t0", "carved_ns")
+    __slots__ = ("timer", "name", "stage", "part", "entry", "members", "ann", "t0",
+                 "carved_ns")
 
     def __init__(self, timer: "StepTimer", name: str, attrs: dict):
         self.timer, self.name = timer, name
@@ -184,6 +199,7 @@ class _Span:
         # a request span carries the admission's ledger entry, a span named
         # under it is one of the admission's parts
         self.entry = dict(attrs) if name == REQUEST_SPAN else None
+        self.members = None  # a ``_Group``'s: the entries its parts are shared over
         self.part = (name[len(REQUEST_SPAN) + 1:] + "_ms"
                      if name.startswith(REQUEST_SPAN + ".") else None)
         self.ann = _annotation(name, **attrs)
@@ -230,12 +246,40 @@ class _Span:
             # its own time and not its parent's: the parts tile the request
             own, nearest = dur - self.carved_ns, True
             for up in reversed(timer._open):
-                if up.entry is not None:
-                    up.entry[self.part] = round(up.entry.get(self.part, 0.0) + own / 1e6, 4)
+                into = [up.entry] if up.entry is not None else up.members
+                if into is not None:  # a request, or the group that shares it out
+                    for e in into:
+                        e[self.part] = round(e.get(self.part, 0.0) + own / 1e6 / len(into), 4)
                     break
                 if up.part is not None and nearest:
                     up.carved_ns += dur
                     nearest = False
+        return False
+
+
+class _Group(_Span):
+    """The launches several admissions share (``GROUP_SPAN``): one span on
+    the trace; in the ledger its time and its parts' are shared out evenly
+    over the members' entries, which gain ``rows``. A launch that raises
+    admitted nobody: the members' entries leave the ledger."""
+
+    __slots__ = ()
+
+    def __init__(self, timer: "StepTimer", members: list):
+        super().__init__(timer, GROUP_SPAN, {"rows": len(members)})
+        self.members = [e for e in members if e is not None]
+
+    def __exit__(self, exc_type=None, exc=None, tb=None, now: int | None = None):
+        dur = (time.perf_counter_ns() if now is None else now) - self.t0
+        super().__exit__(exc_type, exc, tb, now=now)  # neither a stage nor a part
+        n = len(self.members)
+        if exc_type is not None:
+            self.timer.admissions[:] = [a for a in self.timer.admissions
+                                        if all(a is not e for e in self.members)]
+            return False
+        for e in self.members:
+            e["request_ms"] = round(e["request_ms"] + dur / 1e6 / n, 4)
+            e["rows"] = n
         return False
 
 
@@ -267,6 +311,11 @@ class StepTimer:
 
     def span(self, name: str, **attrs) -> _Span:
         return _Span(self, name, attrs)
+
+    def group(self, members: list) -> _Group:
+        """The span of what ``members`` — entries of request spans already
+        closed — share: see ``_Group``."""
+        return _Group(self, members)
 
     def stage(self, name: str) -> None:
         now = time.perf_counter_ns()
