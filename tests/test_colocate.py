@@ -67,8 +67,9 @@ def test_stt_preempts_between_decode_chunks(stt_engine, tiny_batch_engine):
     assert stt_fut.done()
     co.drain(timeout_s=300)
     assert parse_fut.result(timeout=1).error is None
-    first_stt = co.stats.trace.index("stt")
-    last_chunk = len(co.stats.trace) - 1 - co.stats.trace[::-1].index("chunk")
+    order = list(co.stats.trace)  # a bounded deque: the last entries
+    first_stt = order.index("stt")
+    last_chunk = len(order) - 1 - order[::-1].index("chunk")
     assert first_stt < last_chunk  # interleaved, not appended at the end
 
 
@@ -107,3 +108,4 @@ def test_stt_less_runtime_rejects_stt_jobs(tiny_batch_engine):
     co = ColocatedServing(None, ContinuousBatcher(tiny_batch_engine, chunk_steps=8))
     with pytest.raises(RuntimeError):
         co.submit_stt(_audio())
+

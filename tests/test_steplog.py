@@ -66,6 +66,20 @@ def _batcher(engine, **kw):
     return ContinuousBatcher(engine, **kw)
 
 
+# what every record holds since ISSUE 36, 0.0 where nothing happened
+EVERY_RECORD = {"cpu_ms", "others_cpu_ms", "gap_ms", "gap_cpu_ms", "gap_others_cpu_ms",
+                "lock_wait_ms", "gc_ms", "gc_max_ms", "gc_n", "watchdog_late_ms"}
+
+
+def _spin(seconds: float) -> None:
+    """Pure-Python work that holds the interpreter for ``seconds``."""
+    import time
+
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
 # ------------------------------------------------------------ StepLog units
 
 
@@ -158,6 +172,196 @@ def test_nested_stage_spans_are_taken_out_of_the_stage_around_them():
     # outside a step the primitive is a bare annotation, and costs nothing
     with span(PREFILL_CALL_SPAN):
         pass
+
+
+def test_cpu_clocks_tile_as_the_wall_does():
+    """``cpu_ms`` and ``others_cpu_ms`` are read on the boundaries the wall is
+    read on and tiled the same way: a nested staged span's CPU is its own
+    stage's and is carved out of its parent ONCE, and a thread cannot burn more
+    CPU than wall (the three clocks are read in turn: half a millisecond)."""
+    import time
+
+    from tpu_voice_agent.utils.steplog import PREFILL_STAGE_SPAN, span
+
+    log = StepLog(max_steps=8, enabled=True)
+    t = log.timer()
+    t.stage("sched.admit")
+    _spin(0.02)
+    with span(PREFILL_STAGE_SPAN):
+        _spin(0.03)
+    t.stage("sched.decode_dispatch")
+    time.sleep(0.02)
+    t.stage("sched.readback")
+    _spin(0.01)
+    rec = t.finish()
+    st, cpu, others = rec["stages"], rec["cpu_ms"], rec["others_cpu_ms"]
+    assert set(st) == set(cpu) == set(others) == {"admit", "prefill", "decode", "readback"}
+    assert sum(cpu.values()) <= rec["wall_ms"] + 0.5
+    for k in st:
+        assert cpu[k] <= st[k] + 0.5, (k, cpu, st)
+    # carved once: the 30 ms of the nested span are ``prefill`` on every clock
+    # and NOT ``admit`` (uncarved it would read 50: the margin is for a thread
+    # the machine ran late, which only lowers its CPU)
+    assert 19.9 <= st["admit"] and cpu["admit"] <= st["admit"] + 0.5 < 45.0
+    assert cpu["prefill"] <= st["prefill"] + 0.5 and cpu["prefill"] >= 15.0
+    assert cpu["decode"] <= 5.0  # asleep: no CPU of its own
+    assert EVERY_RECORD <= set(rec)
+
+
+@pytest.mark.parametrize("held_by", ["another_thread", "a_sleep", "its_own_work"])
+def test_off_cpu_and_others_cpu_tell_three_causes_apart(held_by):
+    """The discriminator of ISSUE 36 on a fake step of one host stage. Another
+    thread busy in pure Python: the batcher's thread is off the CPU about as
+    long as the others burn it. A sleep (the device, a lock): off the CPU,
+    and nobody burns any. Its own busy loop: on the CPU."""
+    import threading
+    import time
+
+    log = StepLog(max_steps=8, enabled=True)
+    stop = threading.Event()
+
+    def busy():  # pure Python: it holds the interpreter whenever it runs
+        while not stop.is_set():
+            pass
+
+    other = threading.Thread(target=busy)
+    if held_by == "another_thread":
+        other.start()
+    try:
+        t = log.timer()
+        t.stage("sched.admit")
+        if held_by == "a_sleep":
+            time.sleep(0.3)
+        else:
+            _spin(0.3)
+        rec = t.finish()
+    finally:
+        stop.set()
+        if other.is_alive():
+            other.join(timeout=10)
+    assert not other.is_alive()
+    wall, cpu, others = rec["stages"]["admit"], rec["cpu_ms"]["admit"], rec["others_cpu_ms"]["admit"]
+    off = wall - cpu
+    if held_by == "another_thread":
+        # two threads share one interpreter: each runs about half the time (a
+        # loaded machine gives both less; the other thread's share stays a
+        # third of what this one lost or more)
+        assert off >= 0.2 * wall and others >= 0.3 * off, rec
+    elif held_by == "a_sleep":
+        assert off >= 0.9 * wall and others <= 0.15 * off, rec
+    else:
+        assert off <= 0.35 * wall and others <= 0.35 * wall, rec
+
+
+@pytest.mark.parametrize("where", ["another_thread", "own_thread", "the_gap"])
+def test_a_collection_lands_in_the_step_it_fell_in(where):
+    """``gc.callbacks`` → the event ring → the record of the step that closes
+    next: a full collection from another thread inside a step is that step's
+    with ``own`` false, one from the batcher's thread ``own`` true, one between
+    two steps has ``at_ms`` < 0; the scalars count every collection."""
+    import gc
+    import threading
+
+    from tpu_voice_agent.utils import steplog
+
+    log = StepLog(max_steps=8, enabled=True)
+    log.timer().finish()  # installs the callback; takes what the ring held
+    assert steplog._on_gc in gc.callbacks
+    if where == "the_gap":
+        gc.collect()
+    t = log.timer()
+    t.stage("sched.admit")
+    if where == "another_thread":
+        th = threading.Thread(target=gc.collect)
+        th.start()
+        th.join(timeout=30)
+        assert not th.is_alive()
+    elif where == "own_thread":
+        gc.collect()
+    rec = t.finish()
+    full = [g for g in rec["gc"] if g["gen"] == 2]
+    assert len(full) == 1 and rec["gc_n"] >= 1
+    (g,) = full
+    assert g["own"] is (where != "another_thread")
+    assert (g["at_ms"] < 0) is (where == "the_gap")
+    assert 0 < g["ms"] <= rec["gc_max_ms"] <= rec["gc_ms"]
+    if where != "the_gap":
+        assert g["at_ms"] + g["ms"] <= rec["wall_ms"] + 0.5
+    # counted for a scrape where the record is folded (never in the callback)
+    assert get_metrics().counter_state()[0]["host.gc_collections"] >= 1
+    assert "gc" not in log.timer().finish()  # taken once: the next record is quiet
+
+
+def test_a_ledger_that_is_off_registers_no_gc_callback():
+    import gc
+
+    from tpu_voice_agent.utils import steplog
+
+    had = steplog._on_gc in gc.callbacks
+    if had:
+        gc.callbacks.remove(steplog._on_gc)
+    try:
+        rec = StepLog(max_steps=8, enabled=False).timer().finish()
+        assert steplog._on_gc not in gc.callbacks
+        assert rec["gc_n"] == 0 and "gc" not in rec  # and it takes nothing off the ring
+        StepLog(max_steps=8, enabled=True).timer().close()
+        StepLog(max_steps=8, enabled=True).timer().close()
+        assert gc.callbacks.count(steplog._on_gc) == 1  # ONE a process
+    finally:
+        if not had and steplog._on_gc in gc.callbacks:
+            gc.callbacks.remove(steplog._on_gc)
+
+
+@pytest.mark.parametrize("first_launch", ["per_slot", "group", "decode_dispatch"])
+def test_the_head_of_a_step_closes_at_its_first_launch(first_launch):
+    """``sched.admit.head``: from the admit stage's start to the first
+    ``.prefill_call`` to ENTER — a per-slot admission's, a group's — and, in a
+    step that admits nobody, to ``sched.decode_dispatch``; later launches do
+    not move it."""
+    import time
+
+    from tpu_voice_agent.utils.steplog import (
+        ALLOC_SPAN,
+        HEAD_SPAN,
+        PREFILL_CALL_SPAN,
+        PREFILL_STAGE_SPAN,
+        REQUEST_SPAN,
+        span,
+    )
+
+    log = StepLog(max_steps=8, enabled=True)
+    t = log.timer()
+    t.stage("sched.admit")
+    time.sleep(0.01)  # the head: tokenize, prepare, whoever else holds the thread
+    assert t.open_spans() == ["sched.admit", HEAD_SPAN]
+    if first_launch == "per_slot":
+        for rid in (1, 2):
+            with t.span(REQUEST_SPAN, rid=rid), span(ALLOC_SPAN):
+                time.sleep(0.004)
+                with span(PREFILL_STAGE_SPAN), span(PREFILL_CALL_SPAN):
+                    assert HEAD_SPAN not in t.open_spans()
+                    time.sleep(0.01)
+    elif first_launch == "group":
+        entries = []
+        for rid in (1, 2):
+            with t.span(REQUEST_SPAN, rid=rid) as req:
+                time.sleep(0.002)
+            entries.append(req.entry)
+        with t.group(entries), span(ALLOC_SPAN):
+            with span(PREFILL_STAGE_SPAN), span(PREFILL_CALL_SPAN):
+                time.sleep(0.01)
+    t.stage("sched.decode_dispatch")
+    assert HEAD_SPAN not in t.open_spans()
+    time.sleep(0.005)
+    rec = t.finish(forwards=1)
+    lo = {"per_slot": 13.9, "group": 13.9, "decode_dispatch": 9.9}[first_launch]
+    assert lo <= rec["head_ms"] <= lo + 8.0, rec  # not the later launch, not the chunk
+    assert rec["head_ms"] <= rec["stages"]["admit"] + rec["stages"].get("prefill", 0.0) + 1e-3
+    assert rec["head_cpu_ms"] <= rec["head_ms"] + 0.5 and rec["head_others_cpu_ms"] >= 0.0
+    # a step that launched nothing (every admission shed) has no head
+    t = log.timer()
+    t.stage("sched.admit")
+    assert "head_ms" not in t.finish()
 
 
 def test_steplog_ring_bounds_and_seq():
@@ -335,6 +539,16 @@ def test_steplog_off_is_token_identical(scope_engine):
         log.enabled = True
     assert [r.token_ids for r in on] == [r.token_ids for r in off]
     assert all(r.error is None for r in on)
+    # the ledger that was on holds ISSUE 36's keys on every record (zeros where
+    # nothing happened), tiled as the stages are; the one that was off nothing
+    steps = log.steps()
+    assert steps and all(s["seq"] < len(steps) for s in steps)
+    for rec in steps:
+        assert EVERY_RECORD <= set(rec), EVERY_RECORD - set(rec)
+        assert set(rec["cpu_ms"]) == set(rec["others_cpu_ms"]) == set(rec["stages"])
+        assert sum(rec["cpu_ms"].values()) <= rec["wall_ms"] + 0.5
+        if rec.get("forwards"):
+            assert rec["head_ms"] <= rec["stages"]["admit"] + rec["stages"].get("prefill", 0.0) + 1e-3
 
 
 def _assert_parts_tile(adm):
@@ -922,3 +1136,139 @@ def test_brain_debug_steplog_endpoint():
     assert body["service"] == "brain"
     assert len(body["steps"]) == 2 and body["recorded"] == 5
     assert body["steps"][-1]["occupancy"] == 4
+
+
+# ---------------------------------------------------- what holds the batcher's thread (ISSUE 36)
+
+
+def test_gap_and_lock_wait_of_a_step_behind_a_held_lock(scope_engine):
+    """The stretch between two steps is the NEXT record's ``gap_ms``, and what
+    the serving loop waited for its own lock in it ``lock_wait_ms``: a thread
+    that holds ``ColocatedServing._lock`` for 60 ms between two ticks shows
+    in both, and in neither stage of either step."""
+    import threading
+    import time
+
+    from tpu_voice_agent.serve.colocate import ColocatedServing
+
+    log = get_steplog()
+    co = ColocatedServing(None, _batcher(scope_engine, max_new_tokens=64))
+    co.submit_parse("sort by price low to high")
+    assert co.step()
+    n0 = len(log.steps())
+    first = log.steps()[-1]
+    assert first["lock_wait_ms"] < 30.0
+    held, go = threading.Event(), threading.Event()
+
+    def hold():
+        with co._lock:
+            held.set()
+            go.wait(timeout=30)
+            time.sleep(0.06)
+
+    th = threading.Thread(target=hold)
+    th.start()
+    assert held.wait(timeout=30)
+    go.set()
+    assert co.step()  # its first acquisition waits out the holder
+    th.join(timeout=30)
+    assert not th.is_alive()
+    rec = log.steps()[n0]
+    assert rec["seq"] == first["seq"] + 1
+    assert rec["lock_wait_ms"] >= 55.0 and rec["gap_ms"] >= rec["lock_wait_ms"]
+    assert rec["gap_cpu_ms"] <= rec["gap_ms"] - 50.0  # it slept on the lock
+    assert sum(rec["stages"].values()) == pytest.approx(rec["wall_ms"], abs=0.01)
+    co.drain(timeout_s=300)
+    assert all(s["lock_wait_ms"] < 30.0 for s in log.steps()[n0 + 1:])
+
+
+def test_a_long_step_is_photographed_once_and_nothing_restarts(scope_engine, monkeypatch):
+    """The chaos drill ``stall_step`` (2 s of sleep at the top of a step) under
+    the watchdog at its default threshold (30 s: no restart): ONE ``stall``
+    snapshot, taken when the step was a second old, whose batcher thread
+    stands in the drill's ``sleep``, on the record of the step that follows;
+    the watchdog's own lateness rides the same records."""
+    from tpu_voice_agent.serve.colocate import ColocatedServing
+    from tpu_voice_agent.utils import chaos
+
+    monkeypatch.setenv("CHAOS_STALL_S", "2.0")
+    log = get_steplog()
+    bat = _batcher(scope_engine, max_new_tokens=32)
+    assert bat.generate_many(["go back"])[0].token_ids  # compiled before the drill
+    log.clear()
+    co = ColocatedServing(None, bat)
+    restarts = get_metrics().counter_state()[0].get("engine.restarts", 0.0)
+    chaos.configure("stall_step@1")
+    co.start()
+    co.start_watchdog(interval_s=0.1)
+    try:
+        res = co.submit_parse("scroll down").result(timeout=120)
+    finally:
+        chaos.reset()
+        co.stop()
+    assert res.error is None and co.stats.restarts == 0
+    assert get_metrics().counter_state()[0].get("engine.restarts", 0.0) == restarts
+    steps = log.steps()
+    stalls = [s["stall"] for s in steps if "stall" in s]
+    assert len(stalls) == 1 and "stall" in steps[0]  # the record that follows the sleep
+    (snap,) = stalls
+    assert 1000.0 <= snap["age_ms"] < 2000.0 and snap["gc_open_ms"] is None
+    assert snap["batcher"] == "colocate" and snap["late_ms"] < 500.0
+    (batcher,) = [t for t in snap["threads"] if t["name"] == "colocate"]
+    assert batcher["frames"][0].startswith("scheduler.py:") and batcher["frames"][0].endswith(" step")
+    assert any(f.endswith(" _tick") for f in batcher["frames"]) and len(batcher["frames"]) <= 6
+    assert {t["name"] for t in snap["threads"]} >= {"colocate", "colocate-watchdog", "MainThread"}
+    assert snap["open_spans"] == []  # the drill sleeps before the step's timer opens
+    assert "stall_pending" not in log.dump()  # folded: it is the record's now
+    assert steps[0]["gap_ms"] == 0.0 and all("watchdog_late_ms" in s for s in steps)
+    assert "host.watchdog_late" in get_metrics().counter_state()[1]
+
+
+def test_one_rid_from_submit_through_admission_to_delivery(scope_engine, tmp_path):
+    """A request's three spans on the profiler's trace — ``brain.submit`` on
+    the caller's thread, ``sched.admit.request`` on the batcher's,
+    ``brain.deliver`` on the caller's again — carry ONE ``rid``; the wake
+    latency is counted and noted; ``sched.tick`` holds every ``sched.step``."""
+    from jax.profiler import ProfileData
+
+    from tpu_voice_agent.services.brain import BatchedEngineParser, ParserError
+    from tpu_voice_agent.utils.tracing import pop_stage_notes
+
+    parser = BatchedEngineParser(scope_engine, chunk_steps=7, max_new_tokens=16)
+    m = get_metrics()
+    try:
+        with pytest.raises(ParserError):  # random weights: truncated, typed
+            parser._answer("go back", None)  # compiled before the capture
+        d0 = m.counter_state()[0].get("brain.parse_deliver_ms", 0.0)
+        pop_stage_notes()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for text in ("scroll down", "stop"):
+                with pytest.raises(ParserError):
+                    parser._answer(text, None)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        parser.runtime.stop()
+    assert m.counter_state()[0]["brain.parse_deliver_ms"] > d0
+    assert pop_stage_notes()["deliver_ms"] >= 0.0
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    spans: dict[str, list] = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for at, line in enumerate(plane.lines):  # one line a thread (all named alike)
+                for ev in line.events:
+                    if ev.name.startswith(("brain.", "sched.")):
+                        spans.setdefault(ev.name, []).append(
+                            (at, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    rids = {name: sorted(int(s[3]["rid"]) for s in spans[name])
+            for name in ("brain.submit", "sched.admit.request", "brain.deliver")}
+    assert rids["brain.submit"] == rids["sched.admit.request"] == rids["brain.deliver"]
+    assert len(rids["brain.submit"]) == 2
+    # the request's own spans are on ITS thread, not on the batcher's
+    batcher_lines = {s[0] for s in spans["sched.step"]}
+    assert not batcher_lines & {s[0] for s in spans["brain.submit"] + spans["brain.deliver"]}
+    inside = lambda a, b: b[1] <= a[1] and a[2] <= b[2]
+    for step in spans["sched.step"]:
+        assert sum(inside(step, tick) for tick in spans["sched.tick"]) == 1
+    assert len(spans["sched.admit.head"]) >= 2 and spans["sched.harvest"]

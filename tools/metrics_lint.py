@@ -291,6 +291,15 @@ PINNED: dict[str, str] = {
     "disagg.decode_replicas": "gauge",
     "disagg.prefill_queue": "gauge",
     "autopilot.prefill_target_replicas": "gauge",
+    # what holds the batcher's thread (ISSUE 36, utils/steplog.py's event
+    # ring): the collections and the watchdog's lateness behind the step
+    # record's gc_* / watchdog_late_ms keys, and the wake latency the
+    # benchmark's deliver_ms_mean.* divides by brain.parse_completed
+    "host.gc_collections": "counter",
+    "host.gc_pause": "histogram",
+    "host.watchdog_late": "histogram",
+    "brain.parse_deliver_ms": "counter",
+    "brain.parse_completed": "counter",
 }
 
 

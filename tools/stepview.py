@@ -6,7 +6,9 @@ ring (utils/steplog.py) served at ``GET /debug/steplog`` on the brain and
 folded into flight-recorder freezes. This tool renders that ring as a text
 timeline: one gantt row per step, the six tiling stages (admit / prefill /
 draft / decode / readback / release) as proportional bar segments, batch
-occupancy + token counts in the margin, and any compile-sentinel events
+occupancy + token counts — and, where the record holds them, the head of
+the step, the gap before it, time off the CPU and collections — in the
+margin, a stall snapshot's batcher frames, and any compile-sentinel events
 flagged inline on the step that paid the trace — the "why did THIS chunk
 take 400 ms" view the per-utterance waterfall (traceview) cannot answer.
 
@@ -93,8 +95,26 @@ def render_step(rec: dict, width: int = 48, max_wall_ms: float | None = None) ->
         meta.append(f"fwd {rec['forwards']}")
     if rec.get("accepted"):
         meta.append(f"acc {rec['accepted']}")
+    # what held the thread (ISSUE 36), where the record says: the head of the
+    # step, the gap before it, time off the CPU beside others' CPU, collections
+    if "head_ms" in rec:
+        meta.append(f"head {rec['head_ms']:.1f}")
+    if rec.get("gap_ms"):
+        meta.append(f"gap {rec['gap_ms']:.1f}")
+    if "cpu_ms" in rec:
+        off = sum(stages.values()) - sum(rec["cpu_ms"].values())
+        meta.append(f"off-cpu {max(off, 0.0):.1f} (others {sum(rec.get('others_cpu_ms', {}).values()):.1f})")
+    if rec.get("gc_n"):
+        meta.append(f"gc {rec['gc_n']}x {rec.get('gc_ms', 0.0):.1f} max {rec.get('gc_max_ms', 0.0):.1f}")
     line = (f"#{rec.get('seq', '?'):>5} {rec.get('wall_ms', 0.0):>9.2f} ms "
             f"|{bar}| {' '.join(meta)}")
+    stall = rec.get("stall")
+    if stall:
+        frames = [f for t in stall.get("threads", []) if t.get("name") == stall.get("batcher")
+                  for f in t.get("frames", [])]
+        line += (f"\n       ⏸ stall snapshot at {stall.get('age_ms', 0.0):.0f} ms "
+                 f"(late {stall.get('late_ms', 0.0):.1f}, gc open {stall.get('gc_open_ms')}): "
+                 f"spans {stall.get('open_spans')}; batcher {' < '.join(frames[:3])}")
     for ev in rec.get("events") or []:
         flag = "POST-FENCE " if ev.get("post_fence") else ""
         line += (f"\n       ⚡ {flag}compile {ev.get('site')} "
@@ -132,11 +152,26 @@ def _synthetic_ring() -> dict:
         {"seq": 1, "wall_ms": 101.0, "occupancy": 3, "tokens": 24,
          "forwards": 8, "accepted": 16,
          "stages": {"admit": 0.5, "draft": 12.0, "decode": 80.0,
-                    "readback": 6.0, "release": 2.5}},
+                    "readback": 6.0, "release": 2.5},
+         "cpu_ms": {"admit": 0.4, "draft": 11.0, "decode": 3.0,
+                    "readback": 0.1, "release": 2.0},
+         "others_cpu_ms": {"admit": 0.1, "draft": 0.5, "decode": 2.0,
+                           "readback": 0.3, "release": 0.4},
+         "head_ms": 0.4, "gap_ms": 3.2, "lock_wait_ms": 0.0,
+         "gc_n": 2, "gc_ms": 1.5, "gc_max_ms": 1.2, "watchdog_late_ms": 0.1,
+         "gc": [{"gen": 1, "ms": 1.2, "own": False, "at_ms": -2.0}],
+         "stall": {"batcher": "colocate", "age_ms": 1030.0, "late_ms": 0.2,
+                   "gc_open_ms": None, "open_spans": ["sched.readback"],
+                   "threads": [{"name": "colocate",
+                                "frames": ["scheduler.py:1297 _step", "scheduler.py:1060 step"]}]}},
         {"seq": 2, "wall_ms": 96.0, "occupancy": 3, "tokens": 24,
          "stages": {"decode": 88.0, "readback": 6.0, "release": 2.0}},
     ]
     return {"enabled": True, "max_steps": 256, "recorded": 3, "steps": steps}
+
+
+def rows_of(txt: str) -> list[str]:
+    return [ln for ln in txt.splitlines() if ln.lstrip().startswith("#")]
 
 
 def self_test() -> int:
@@ -146,9 +181,12 @@ def self_test() -> int:
     assert "POST-FENCE compile engine.chunk_decode_loop" in txt, txt
     assert "⚡" in txt and "1 compile stall(s)" in txt, txt
     assert "occ 3" in txt and "tok 24" in txt and "fwd 8" in txt, txt
+    assert "head 0.4 gap 3.2 off-cpu 84.5 (others 3.3) gc 2x 1.5 max 1.2" in txt, txt
+    assert "stall snapshot at 1030 ms" in txt and "scheduler.py:1297 _step <" in txt, txt
+    assert "off-cpu" not in rows_of(txt)[2]  # a record of an older ledger renders as before
     # the bar scales against the window's longest step: the 412 ms step's
     # bar must be strictly longer than the 96 ms step's
-    rows = [ln for ln in txt.splitlines() if ln.lstrip().startswith("#")]
+    rows = rows_of(txt)
     assert len(rows) == 3, rows
     w0 = rows[0].split("|")[1]
     w2 = rows[2].split("|")[1]

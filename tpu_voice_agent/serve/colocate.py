@@ -27,12 +27,21 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import deque
 from concurrent.futures import Future
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import numpy as np
 
-from ..utils.steplog import span
+from ..utils.steplog import (
+    TICK_SPAN,
+    annotation,
+    get_steplog,
+    note_lock_wait,
+    note_watchdog_late,
+    span,
+)
 from .engine import GenerationResult
 from .scheduler import ContinuousBatcher
 from .stt import SpeechEngine, TranscribeResult
@@ -49,8 +58,9 @@ class ColocationStats:
     restarts: int = 0  # dead workers revived by the watchdog
     max_stt_queue: int = 0
     max_parse_inflight: int = 0
-    # dispatch-order trace: "stt" / "chunk" entries, for fairness asserts
-    trace: list = field(default_factory=list)
+    # dispatch-order trace: the last "stt" / "chunk" entries, for fairness
+    # asserts (as many as the step ring holds: the process lives longer)
+    trace: deque = field(default_factory=lambda: deque(maxlen=get_steplog().max_steps))
 
 
 class ColocatedServing:
@@ -114,8 +124,11 @@ class ColocatedServing:
         # the tenant kwarg is only forwarded when set: duck-typed batchers
         # that predate the QoS plane keep working untagged
         kw = {"tenant": tenant} if tenant is not None else {}
-        with self._work:
+        # on the trace, on the CALLER's thread: the wait for the lock and the
+        # submit; ``rid`` is the one ``sched.admit.request`` / ``brain.deliver`` carry
+        with annotation("brain.submit") as on_trace, self._work:
             rid = self.batcher.submit(prompt, deadline=deadline, **kw)
+            on_trace.set_metadata(rid=rid)
             fut.request_id = rid  # lets abandon_parse find the request again
             if rid in self.batcher.results:
                 # refused at submit (quarantined prompt / throttled tenant):
@@ -171,12 +184,31 @@ class ColocatedServing:
             sl.request_id >= 0 for sl in self.batcher.slots
         )
 
+    @contextmanager
+    def _own_lock(self):
+        """``_lock`` as the SERVING LOOP's thread takes it (``_loop``, ``step``,
+        ``_harvest``): what it waited goes into its next step's record
+        (``lock_wait_ms``), so a slow lock and a slow step can be told apart."""
+        t0 = time.perf_counter_ns()
+        self._lock.acquire()
+        note_lock_wait(time.perf_counter_ns() - t0)
+        try:
+            yield
+        finally:
+            self._lock.release()
+
     def step(self) -> bool:
         """One scheduling decision: drain STT queue, else one decode chunk.
-        Returns True if any device work was dispatched."""
+        Returns True if any device work was dispatched. On the trace one
+        ``sched.tick`` (the batcher's ``sched.step`` nests inside), so the
+        stretch between two steps lies under a span."""
+        with span(TICK_SPAN):
+            return self._tick()
+
+    def _tick(self) -> bool:
         from ..utils import get_metrics
 
-        with self._lock:
+        with self._own_lock():
             stt_jobs = list(self._stt_q)
             self._stt_q.clear()
             calls = list(self._call_q)
@@ -207,7 +239,7 @@ class ColocatedServing:
                 self._set_future(fut, exc=e)
             if result is not None:
                 self._set_future(fut, value=result)
-            with self._lock:
+            with self._own_lock():
                 self.stats.stt_busy_ms += (time.perf_counter() - t0) * 1e3
                 self.stats.stt_jobs += 1
                 self.stats.trace.append("stt")
@@ -226,7 +258,7 @@ class ColocatedServing:
 
         if self._has_decode_work():
             t0 = time.perf_counter()
-            with self._lock:
+            with self._own_lock():
                 self._step_t0 = t0  # stall watchdog arms on this
             try:
                 self.batcher.step()
@@ -238,7 +270,7 @@ class ColocatedServing:
                 self._fail_inflight(e)
                 return True
             finally:
-                with self._lock:
+                with self._own_lock():
                     # an abandoned (stall-restarted) worker waking here must
                     # not clear the REPLACEMENT worker's armed timestamp —
                     # that would silently blind the watchdog to a second
@@ -246,7 +278,7 @@ class ColocatedServing:
                     if (self._thread is None
                             or threading.current_thread() is self._thread):
                         self._step_t0 = None
-            with self._lock:
+            with self._own_lock():
                 self.stats.decode_busy_ms += (time.perf_counter() - t0) * 1e3
                 self.stats.decode_chunks += 1
                 self.stats.trace.append("chunk")
@@ -277,12 +309,14 @@ class ColocatedServing:
             self._set_future(fut, exc=exc)
 
     def _harvest(self) -> None:
-        with self._lock:
+        with self._own_lock():
             done = [rid for rid in self._parse_futs if rid in self.batcher.results]
             for rid in done:
                 fut = self._parse_futs.pop(rid)
                 res = self.batcher.results.pop(rid)
                 self.stats.parse_jobs += 1
+                # the waiter reads its wake latency off this (``brain.parse_deliver_ms``)
+                fut.resolved_ns = time.perf_counter_ns()
                 self._set_future(fut, value=res)
             # purge results whose futures were abandoned (submit and future
             # registration share one lock, so no still-wanted rid lacks one)
@@ -368,6 +402,13 @@ class ColocatedServing:
           dump (``engine.restarts``). The abandoned thread exits at its
           next loop check — a genuinely hung device call may never wake,
           which is exactly why the replacement loop must not wait for it.
+
+        Long before that, and with no restart (ISSUE 36): its own LATENESS —
+        how far each ``sleep(interval_s)`` overslept, histogram
+        ``host.watchdog_late`` and ``watchdog_late_ms`` on the next step
+        record — and, once a step, a SNAPSHOT of what holds the batcher's
+        thread when the step is older than three median steps and a second
+        (``StepLog.stall_snapshot``: the record's ``stall``).
         """
         if self._watchdog is not None:
             return
@@ -411,16 +452,31 @@ class ColocatedServing:
         import logging
 
         from ..utils import get_metrics
+        from ..utils.tracing import log_event
 
         log = logging.getLogger("tpu_voice_agent.colocate")
+        steplog = get_steplog()
+        late_ms, snapped = 0.0, None  # the last sleep's lateness; the step photographed
         while True:
             with self._work:
                 if self._stop:
                     return
                 dead = self._thread is not None and not self._thread.is_alive()
-                t0 = self._step_t0
-                stalled = (not dead and t0 is not None
-                           and time.perf_counter() - t0 >= stall_s)
+                t0, worker = self._step_t0, self._thread
+                age_s = time.perf_counter() - t0 if t0 is not None else 0.0
+                stalled = not dead and t0 is not None and age_s >= stall_s
+            if (steplog.enabled and not dead and not stalled and t0 != snapped
+                    and age_s >= 1.0 and age_s >= steplog.stall_after_s()):
+                # a long step, far short of a stall: say what holds the thread
+                # (once a step), restart nothing
+                snapped = t0
+                snap = steplog.stall_snapshot(worker.name if worker else "", age_s, late_ms)
+                log_event("colocate", "step.stall_snapshot", age_ms=snap["age_ms"],
+                          late_ms=snap["late_ms"], gc_open_ms=snap["gc_open_ms"],
+                          open_spans=" > ".join(snap["open_spans"]),
+                          batcher_frames=" < ".join(
+                              f for t in snap["threads"] if t["name"] == snap["batcher"]
+                              for f in t["frames"]))
             if dead:
                 log.error("colocate worker died; failing inflight work and "
                           "restarting the serving loop")
@@ -467,7 +523,13 @@ class ColocatedServing:
                 for fut in futs:
                     self._set_future(fut, exc=exc)
                 self._restart_worker(exc, reset_batcher=False)
+            t_sleep = time.perf_counter_ns()
             time.sleep(interval_s)
+            late_ns = max(0, time.perf_counter_ns() - t_sleep - int(interval_s * 1e9))
+            late_ms = late_ns / 1e6
+            get_metrics().observe_ms("host.watchdog_late", late_ms)
+            if steplog.enabled:
+                note_watchdog_late(late_ns)
 
     def stop(self) -> None:
         with self._work:
@@ -490,7 +552,7 @@ class ColocatedServing:
 
         log = logging.getLogger("tpu_voice_agent.colocate")
         while True:
-            with self._work:
+            with self._own_lock():
                 # a stall-watchdog restart replaced this loop while it was
                 # wedged inside a step: the impostor must exit, never touch
                 # the (warm-restarted) batcher again
@@ -505,7 +567,7 @@ class ColocatedServing:
                 self.stats.errors += 1
                 log.exception("colocate step failed; worker continues")
                 did = False
-            with self._work:
+            with self._own_lock():  # ``_work``'s lock: the wait below releases it
                 if self._stop:
                     return
                 if self._thread is not None and \

@@ -298,9 +298,19 @@ class BatchedEngineParser:
         self.runtime.start_watchdog()
 
     def _decode(self, prompt: str):
+        """Submit, wait, and hand back ``(result, deliver)``: ``deliver`` is the
+        request's ``brain.deliver`` annotation, open since this thread WOKE
+        with the result (attr ``rid``, as ``brain.submit`` and the batcher's
+        ``sched.admit.request`` carry it); the caller leaves it when the
+        answer is converted. How long the wake took after the serving loop
+        resolved the future is counted (``brain.parse_deliver_ms``) and noted
+        on the request (``deliver_ms``)."""
         from concurrent.futures import CancelledError
 
+        from ..utils import get_metrics
         from ..utils.resilience import current_request_context
+        from ..utils.steplog import annotation
+        from ..utils.tracing import note_stage
 
         # the request context (set by build_app on this worker thread)
         # carries the propagated deadline INTO the scheduler — expired
@@ -314,7 +324,15 @@ class BatchedEngineParser:
         if ctx is not None:
             ctx.on_cancel(lambda: self.runtime.cancel_parse(fut))
         try:
-            return fut.result(timeout=self.timeout_s)
+            res = fut.result(timeout=self.timeout_s)
+            woke = time.perf_counter_ns()
+            deliver = annotation("brain.deliver", rid=getattr(fut, "request_id", -1))
+            resolved = getattr(fut, "resolved_ns", None)  # none: refused at submit
+            if resolved is not None:
+                ms = (woke - resolved) / 1e6
+                get_metrics().inc("brain.parse_deliver_ms", ms)
+                note_stage("deliver_ms", round(ms, 3))
+            return res, deliver
         except CancelledError as e:  # BaseException: the broad catch misses it
             raise ParserError("llm_error", "cancelled: client disconnected") from e
         except TimeoutError as e:
@@ -326,12 +344,18 @@ class BatchedEngineParser:
         except Exception as e:
             raise ParserError("llm_error", str(e)) from e
 
+    def _answer(self, prompt, session_id: str | None):
+        """One decode and its conversion, ``(result, response)``; the wake and
+        the conversion lie under the request's ``brain.deliver``."""
+        res, deliver = self._decode(prompt)
+        with deliver:
+            self._fold_cost(session_id, res)
+            return res, _result_to_response(res)
+
     def parse(self, text: str, context: dict, session_id: str | None = None,
               speculative: bool = False) -> ParseResponse:
         if self.transcripts is None or not session_id:
-            res = self._decode(render_prompt(text, context))
-            self._fold_cost(session_id, res)
-            return _result_to_response(res)
+            return self._answer(render_prompt(text, context), session_id)[1]
         user = SessionTranscripts.user_frame(text, context)
         with self._plock:
             pend = self._pending.pop(session_id, None)
@@ -356,10 +380,8 @@ class BatchedEngineParser:
             # bound model context by the engine's real capacity)
             self.transcripts.forget(session_id)
             prompt = self.transcripts.prompt_for(session_id, text, context)
-        res = self._decode(prompt)
-        self._fold_cost(session_id, res)
-        resp = _result_to_response(res)  # raises on truncation: transcript
-        # stays at the last committed turn (the session survives)
+        res, resp = self._answer(prompt, session_id)  # raises on truncation:
+        # transcript stays at the last committed turn (the session survives)
         if speculative:
             from ..utils.tracing import peek_stage_notes
 

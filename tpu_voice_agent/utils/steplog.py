@@ -35,8 +35,14 @@ the device's operations while one is — and folds its ``perf_counter_ns``
 duration into the step's record. The step itself is a
 ``StepTraceAnnotation("sched.step", step_num=seq)``. Span names:
 
+    sched.tick                       one ColocatedServing.step(): the queue
+                                     drains, the step below, the harvest — so
+                                     the stretch between two steps has a span
     sched.step                       one ContinuousBatcher.step()
       sched.admit                    stage ``admit`` (``stage()``: contiguous)
+        sched.admit.head             from the stage's start to the step's FIRST
+                                     launch (the first ``.prefill_call`` to
+                                     enter, else ``sched.decode_dispatch``)
         sched.admit.request          one admission; attrs rid, queue_ms,
                                      prompt_tokens, cached_tokens
           .tokenize .alloc .first_token_call .slot_state .bookkeeping
@@ -62,11 +68,34 @@ duration into the step's record. The step itself is a
       sched.readback                 stage ``readback``
       sched.release                  stage ``release``
     sched.wait_for_work, sched.harvest   serve/colocate.py, between steps
+    host.gc                          one collection of the garbage collector, on
+                                     whichever thread it ran (``gc.callbacks``)
+    brain.submit, brain.deliver      a request's way in and out, on ITS threads,
+                                     attr rid (serve/colocate.py, services/brain.py)
 
 The four ``stage()`` spans are contiguous (one clock reading closes one and
 opens the next), and a staged span nested in another is subtracted from it,
 so the six stages TILE the step wall by construction: ``sum(stages) ≈
 wall``.
+
+WHY the thread was slow there (ISSUE 36). Every stage boundary is read on
+three clocks — ``perf_counter_ns``, ``thread_time_ns`` (this thread's CPU)
+and ``process_time_ns`` (the process's) — and the record carries, tiled as
+``stages`` is, ``cpu_ms`` (CPU the batcher's thread burned in the stage) and
+``others_cpu_ms`` (process CPU less the thread's: every OTHER thread's).
+``stages[s] - cpu_ms[s]`` is the time the thread did not run: off-CPU with
+others' CPU about equal to it → another thread held the interpreter (or the
+core); off-CPU with others' CPU near the runtime's floor → the thread slept
+on the device or a lock; neither → its own work. Beside them: ``head_ms`` /
+``head_cpu_ms`` / ``head_others_cpu_ms`` (``sched.admit.head``), ``gap_ms`` /
+``gap_cpu_ms`` / ``gap_others_cpu_ms`` (the previous step's last boundary →
+this step's start), ``lock_wait_ms`` (the serving loop's waits for its own
+lock since the previous step: ``note_lock_wait``), and what the process's
+ONE event ring held when the step closed: collections (``gc_ms``,
+``gc_max_ms``, ``gc_n``; ``gc`` lists those of generation ≥ 1 or ≥ 1 ms),
+the watchdog's lateness (``watchdog_late_ms``) and its ``stall`` snapshot
+(``StepLog.stall_snapshot``). The scalar keys are on every record, 0.0 where
+nothing happened; ``gc`` and ``stall`` only where they hold something.
 
 Surfaces: ``engine.step.*`` histograms/gauges in the metrics registry,
 ``GET /debug/steplog`` on the brain, a ``steplog`` section folded into
@@ -81,9 +110,12 @@ differentially). ``STEPLOG_STEPS`` sizes the ring (default 256).
 
 from __future__ import annotations
 
+import gc
 import os
+import sys
 import threading
 import time
+from collections import deque
 
 # the tiling stage order (stepview renders bars in this order)
 STAGES = ("admit", "prefill", "draft", "decode", "readback", "release")
@@ -108,11 +140,114 @@ STATE_RESTORE_SPAN = REQUEST_SPAN + ".state_restore"
 # ``.bookkeeping`` parts, once a call, and each member's entry gets an even
 # share of them
 GROUP_SPAN = "sched.admit.group"
+# the head of a step (ISSUE 36): opened with ``sched.admit``, closed by the
+# step's first launching span — a span whose name ends in ``LAUNCH_TAIL``,
+# else the ``sched.decode_dispatch`` stage
+HEAD_SPAN = "sched.admit.head"
+LAUNCH_TAIL = ".prefill_call"
+TICK_SPAN = "sched.tick"  # serve/colocate.py, around one scheduling decision
+GC_SPAN = "host.gc"
 # one admission in code order; each is ``<part>_ms`` in its ledger entry
 ADMISSION_PARTS = ("tokenize", "alloc", "prefill_call", "first_token_call",
                    "slot_state", "bookkeeping")
-# the thread's open step, so that engine code finds it without plumbing
+# the thread's open step, so that engine code finds it without plumbing; and
+# what the serving loop's thread waited for its own lock since its last step
 _ACTIVE = threading.local()
+
+# The process's ONE event ring (ISSUE 36): what happens on other threads, or
+# between steps, and belongs in a step's record — a collection
+# ("gc", t0_ns, dur_ns, generation, collected, thread ident), the watchdog's
+# lateness ("late", late_ns), its stall snapshot ("stall", dict).
+# Fed under the interpreter's lock alone (``deque.append``); the step that
+# closes next takes what is there (``_fold_events``).
+_EVENTS: deque = deque(maxlen=4096)
+_gc_t0 = 0  # ``perf_counter_ns`` at the open collection's "start"; 0: none open
+_gc_ann = None
+_TRACE = None  # jax.profiler.TraceAnnotation, bound where the callback is installed
+
+
+def _clocks() -> tuple[int, int, int]:
+    """One boundary on the three clocks: the wall, this thread's CPU, the
+    process's."""
+    return time.perf_counter_ns(), time.thread_time_ns(), time.process_time_ns()
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """The process's ``gc.callbacks`` entry: a stamp and an open ``host.gc``
+    annotation at "start" (no container is built there), the event at "stop".
+    Runs on whichever thread the collection runs on; takes no lock (a metric's
+    lock may be held by the very thread that allocated into a collection)."""
+    global _gc_t0, _gc_ann
+    if phase == "start":
+        _gc_t0 = time.perf_counter_ns()
+        _gc_ann = _TRACE(GC_SPAN)
+    elif _gc_t0:
+        t0, ann, _gc_t0, _gc_ann = _gc_t0, _gc_ann, 0, None
+        dur = time.perf_counter_ns() - t0
+        ann.set_metadata(generation=info["generation"], collected=info["collected"])
+        ann.__exit__(None, None, None)
+        _EVENTS.append(("gc", t0, dur, info["generation"], info["collected"],
+                        threading.get_ident()))
+
+
+def _install_gc() -> None:
+    global _TRACE
+    if _on_gc not in gc.callbacks:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE = TraceAnnotation
+        gc.callbacks.append(_on_gc)
+
+
+def note_lock_wait(ns: int) -> None:
+    """The calling thread waited ``ns`` for a lock of the serving loop's; its
+    next step's record carries the sum as ``lock_wait_ms``."""
+    _ACTIVE.lock_wait_ns = getattr(_ACTIVE, "lock_wait_ns", 0) + ns
+
+
+def note_watchdog_late(ns: int) -> None:
+    """A watchdog's ``sleep`` overslept by ``ns``: a thread that only sleeps
+    and cannot get the interpreter back is the cheapest starvation probe."""
+    _EVENTS.append(("late", ns))
+
+
+def _take_events() -> list[tuple]:
+    """Everything the event ring holds, taken off it (each event lands in ONE
+    record: the step's that closes next)."""
+    taken = []
+    while True:
+        try:
+            taken.append(_EVENTS.popleft())
+        except IndexError:
+            return taken
+
+
+def _fold_events(rec: dict, events, t0: int, ident: int) -> None:
+    """``events`` into the record of the step that started at ``t0`` on thread
+    ``ident`` — the scalar keys always, 0.0 where nothing happened — and
+    counted for an operator's scrape."""
+    from . import get_metrics
+
+    m = get_metrics()
+    kept, total, longest, n, late = [], 0, 0, 0, 0
+    for ev in events:
+        if ev[0] == "gc":
+            _, at, dur, gen, collected, who = ev
+            n, total, longest = n + 1, total + dur, max(longest, dur)
+            m.observe_ms("host.gc_pause", dur / 1e6)
+            if gen >= 1 or dur >= 1_000_000:
+                kept.append({"gen": gen, "ms": round(dur / 1e6, 3), "own": who == ident,
+                             "at_ms": round((at - t0) / 1e6, 3), "collected": collected})
+        elif ev[0] == "late":
+            late = max(late, ev[1])
+        else:
+            rec["stall"] = ev[1]
+    if n:
+        m.inc("host.gc_collections", float(n))
+    rec.update(gc_ms=round(total / 1e6, 3), gc_max_ms=round(longest / 1e6, 3), gc_n=n,
+               watchdog_late_ms=round(late / 1e6, 3))
+    if kept:
+        rec["gc"] = kept
 
 
 class StepLog:
@@ -128,15 +263,20 @@ class StepLog:
         self._lock = threading.Lock()
         self._steps: list[dict] = []
         self._seq = 0
+        self._current: StepTimer | None = None  # the open step, for the watchdog
+        # where the last recorded step ended: (wall, thread CPU, process CPU, thread)
+        self._last_end: tuple[int, int, int, int] | None = None
 
     # ------------------------------------------------------------ feeding
 
     def timer(self) -> "StepTimer":
+        if self.enabled:
+            _install_gc()  # ONE callback a process, and none while the ledger is off
         return StepTimer(self)
 
     def record(self, rec: dict) -> None:
         """Append one step record and export its metrics. No-op when
-        disabled — the scheduler's timing calls still happen (perf_counter
+        disabled — the scheduler's timing calls still happen (clock
         noise), but nothing is stored or exported."""
         if not self.enabled:
             return
@@ -175,23 +315,66 @@ class StepLog:
 
     def dump(self) -> dict:
         """The /debug/steplog body; also folded into flight-recorder
-        freezes so an overload autopsy carries the device-plane timeline."""
+        freezes so an overload autopsy carries the device-plane timeline.
+        A stall snapshot whose step has not closed yet (it may never) rides
+        along as ``stall_pending``."""
         with self._lock:
-            return {"enabled": self.enabled, "max_steps": self.max_steps,
+            body = {"enabled": self.enabled, "max_steps": self.max_steps,
                     "recorded": self._seq, "steps": [dict(s) for s in self._steps]}
+        pending = [ev[1] for ev in list(_EVENTS) if ev[0] == "stall"]
+        if pending:
+            body["stall_pending"] = pending
+        return body
 
     def clear(self) -> None:
         with self._lock:
             self._steps.clear()
             self._seq = 0
+            self._last_end = None
+
+    # ------------------------------------------------------------ stalls
+
+    def stall_after_s(self) -> float:
+        """How old an open step must be before the watchdog photographs it:
+        three times the ring's median step, and at least a second."""
+        with self._lock:
+            walls = sorted(s["wall_ms"] for s in self._steps)
+        return max(1.0, 3e-3 * walls[len(walls) // 2]) if walls else 1.0
+
+    def stall_snapshot(self, batcher: str, age_s: float, late_ms: float) -> dict:
+        """What holds the batcher's thread, photographed from another thread
+        (the watchdog's) while a step is ``age_s`` old: every thread's name and
+        top six frames, whether a collection is open and since when, the names
+        of the step's open spans, and how late the photograph itself came.
+        Pushed onto the event ring, so that the step's record carries it as
+        ``stall`` when (if) the step closes; ``dump`` shows it until then."""
+        names = {t.ident: t.name for t in threading.enumerate()}
+        threads = []
+        for ident, frame in sys._current_frames().items():
+            frames = []
+            while frame is not None and len(frames) < 6:
+                code = frame.f_code
+                frames.append(f"{os.path.basename(code.co_filename)}:{frame.f_lineno} "
+                              f"{code.co_name}")
+                frame = frame.f_back
+            threads.append({"name": names.get(ident, str(ident)), "frames": frames})
+        gc_t0, timer = _gc_t0, self._current
+        snap = {"batcher": batcher, "age_ms": round(age_s * 1e3, 1),
+                "late_ms": round(late_ms, 3),
+                "gc_open_ms": round((time.perf_counter_ns() - gc_t0) / 1e6, 3) if gc_t0 else None,
+                "open_spans": timer.open_spans() if timer is not None else [],
+                "threads": threads}
+        _EVENTS.append(("stall", snap))
+        return snap
 
 
 class _Span:
     """One open span: a TraceAnnotation on the profiler's clock, and a
-    ``perf_counter_ns`` duration folded into the step's record at exit."""
+    ``perf_counter_ns`` duration folded into the step's record at exit. A
+    span that is a STAGE is read on the two CPU clocks too (``_clocks``)."""
 
-    __slots__ = ("timer", "name", "stage", "part", "entry", "members", "ann", "t0",
-                 "carved_ns")
+    __slots__ = ("timer", "name", "stage", "part", "entry", "members", "ann", "t0", "c0", "p0",
+                 "carved_ns", "carved_cpu", "carved_proc")
 
     def __init__(self, timer: "StepTimer", name: str, attrs: dict):
         self.timer, self.name = timer, name
@@ -202,8 +385,8 @@ class _Span:
         self.members = None  # a ``_Group``'s: the entries its parts are shared over
         self.part = (name[len(REQUEST_SPAN) + 1:] + "_ms"
                      if name.startswith(REQUEST_SPAN + ".") else None)
-        self.ann = _annotation(name, **attrs)
-        self.carved_ns = 0
+        self.ann = annotation(name, **attrs)
+        self.carved_ns = self.carved_cpu = self.carved_proc = 0
 
     def set(self, **attrs) -> None:
         """Attributes learned inside the span (a prompt's token count)."""
@@ -218,25 +401,40 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         self.ann.__enter__()
-        self.t0 = time.perf_counter_ns()
+        if self.stage is not None:
+            self.t0, self.c0, self.p0 = _clocks()
+        else:
+            self.t0 = time.perf_counter_ns()
         self.timer._open.append(self)
         return self
 
-    def __exit__(self, exc_type=None, exc=None, tb=None, now: int | None = None):
-        dur = (time.perf_counter_ns() if now is None else now) - self.t0
+    def __exit__(self, exc_type=None, exc=None, tb=None,
+                 now: tuple[int, int, int] | None = None):
+        """``now``: the boundary's clocks, where ``stage()`` already read them."""
+        if self.stage is not None:
+            t, c, p = now or _clocks()
+            dur = t - self.t0
+        else:
+            dur = time.perf_counter_ns() - self.t0
         self.ann.__exit__(exc_type, exc, tb)
         timer = self.timer
         timer._open.remove(self)
         if self.stage is not None:
             # a staged span inside another (a prefill call inside admit, the
             # drafter inside decode) is that stage's time and not its
-            # parent's: the stages tile the wall
+            # parent's: the stages tile the wall, on all three clocks
+            cpu, proc = c - self.c0, p - self.p0
             for up in reversed(timer._open):
                 if up.stage is not None:
                     up.carved_ns += dur
+                    up.carved_cpu += cpu
+                    up.carved_proc += proc
                     break
-            timer.stages[self.stage] = (timer.stages.get(self.stage, 0.0)
-                                        + (dur - self.carved_ns) / 1e6)
+            own_cpu = cpu - self.carved_cpu
+            s = self.stage
+            timer.stages[s] = timer.stages.get(s, 0.0) + (dur - self.carved_ns) / 1e6
+            timer.cpu[s] = timer.cpu.get(s, 0) + own_cpu
+            timer.others[s] = timer.others.get(s, 0) + proc - self.carved_proc - own_cpu
         if self.entry is not None:
             if exc_type is None:
                 self.entry["request_ms"] = round(dur / 1e6, 4)
@@ -269,9 +467,9 @@ class _Group(_Span):
         super().__init__(timer, GROUP_SPAN, {"rows": len(members)})
         self.members = [e for e in members if e is not None]
 
-    def __exit__(self, exc_type=None, exc=None, tb=None, now: int | None = None):
-        dur = (time.perf_counter_ns() if now is None else now) - self.t0
-        super().__exit__(exc_type, exc, tb, now=now)  # neither a stage nor a part
+    def __exit__(self, exc_type=None, exc=None, tb=None):
+        dur = time.perf_counter_ns() - self.t0
+        super().__exit__(exc_type, exc, tb)  # neither a stage nor a part
         n = len(self.members)
         if exc_type is not None:
             self.timer.admissions[:] = [a for a in self.timer.admissions
@@ -283,33 +481,49 @@ class _Group(_Span):
         return False
 
 
+def _ms(ns: int) -> float:
+    """Nanoseconds of a clock difference as a record's milliseconds; the two
+    CPU clocks tick apart, so a difference of differences may dip under 0."""
+    return max(0.0, round(ns / 1e6, 3))
+
+
 class StepTimer:
     """Measures one scheduler step as spans.
 
     ``stage(name)`` closes the open stage span and opens ``name`` on ONE
-    clock reading — stage spans are contiguous, which is what makes the
+    reading of the clocks — stage spans are contiguous, which is what makes the
     ≥95%-accounted property hold by construction. ``span(name)`` is a
     ``with`` block inside them; one whose name maps to a stage
     (``SPAN_STAGE``) is reported as that stage and taken out of the stage
     around it. ``finish`` drains the compile
-    sentinel's pending events and records; ``close`` (idempotent) ends
-    whatever is still open, for a step that raised or was abandoned."""
+    sentinel's pending events and the event ring and records; ``close``
+    (idempotent) ends whatever is still open, for a step that raised or was
+    abandoned."""
 
     def __init__(self, log: StepLog):
         self._log = log
         self.stages: dict[str, float] = {}
+        self.cpu: dict[str, int] = {}  # this thread's CPU by stage, ns
+        self.others: dict[str, int] = {}  # the process's CPU less this thread's, ns
         self.admissions: list[dict] = []
         self._open: list[_Span] = []
         self._stage: _Span | None = None
+        self._head: tuple | None = None  # (annotation, wall, cpu, process) while open
+        self.head: tuple[int, int, int] | None = None  # its three durations, once closed
         self._prev = getattr(_ACTIVE, "timer", None)
         _ACTIVE.timer = self
-        self._step = _annotation("sched.step", step_num=log.next_seq(), step=True)
+        self.lock_wait_ns, _ACTIVE.lock_wait_ns = getattr(_ACTIVE, "lock_wait_ns", 0), 0
+        self.ident = threading.get_ident()
+        log._current = self
+        self._step = annotation("sched.step", step_num=log.next_seq(), step=True)
         self._step.__enter__()
         self.t0_ns = time.time_ns()
-        self.t0 = time.perf_counter_ns()
-        self._t_end: int | None = None  # where the last stage closed
+        self.t0, self.c0, self.p0 = _clocks()
+        self._t_end: tuple[int, int, int] | None = None  # where the last stage closed
 
     def span(self, name: str, **attrs) -> _Span:
+        if self._head is not None and name.endswith(LAUNCH_TAIL):
+            self._close_head(_clocks())  # the step's first launch
         return _Span(self, name, attrs)
 
     def group(self, members: list) -> _Group:
@@ -318,19 +532,35 @@ class StepTimer:
         return _Group(self, members)
 
     def stage(self, name: str) -> None:
-        now = time.perf_counter_ns()
+        now = _clocks()
         first = self._t_end is None
         self._close_stage(now)
+        if self._head is not None and name == "sched.decode_dispatch":
+            self._close_head(now)  # a step that admitted nobody: the chunk is its first launch
         self._stage = self.span(name).__enter__()
         # the first stage runs from the step's start, the others from the
         # reading that closed the one before
-        self._stage.t0 = self.t0 if first else now
+        self._stage.t0, self._stage.c0, self._stage.p0 = \
+            (self.t0, self.c0, self.p0) if first else now
+        if name == "sched.admit":
+            self._head = (annotation(HEAD_SPAN), *now)
 
-    def _close_stage(self, now: int) -> None:
+    def _close_head(self, now: tuple[int, int, int]) -> None:
+        ann, t, c, p = self._head
+        ann.__exit__(None, None, None)
+        self._head = None
+        self.head = (now[0] - t, now[1] - c, now[2] - p)
+
+    def _close_stage(self, now: tuple[int, int, int]) -> None:
         if self._stage is not None:
             self._stage.__exit__(now=now)
             self._stage = None
         self._t_end = now
+
+    def open_spans(self) -> list[str]:
+        """The names of the spans open now, outermost first; read from the
+        watchdog's thread (a copy of the list, under the interpreter's lock)."""
+        return [sp.name for sp in list(self._open)] + ([HEAD_SPAN] if self._head else [])
 
     def close(self) -> None:
         if self._step is None:
@@ -338,8 +568,13 @@ class StepTimer:
         for sp in reversed(list(self._open)):
             sp.__exit__()
         self._stage = None
+        if self._head is not None:  # a step that launched nothing has no head
+            self._head[0].__exit__(None, None, None)
+            self._head = None
         self._step.__exit__(None, None, None)
         self._step = None
+        if self._log._current is self:
+            self._log._current = None
         if getattr(_ACTIVE, "timer", None) is self:
             _ACTIVE.timer = self._prev
 
@@ -350,23 +585,41 @@ class StepTimer:
         # recorder's own overhead (pending-drain, dict assembly), which
         # must not show up as unaccounted step time — with it excluded the
         # stages tile the wall by construction
-        now, t1_ns = time.perf_counter_ns(), time.time_ns()
+        now, t1_ns = _clocks(), time.time_ns()
         if self._stage is not None:
             self._close_stage(now)
         end = self._t_end if self.stages else now
         self.close()
+        log = self._log
         rec = {
             "t_s": round(t1_ns / 1e9, 3),
             "t0_ns": self.t0_ns,
             "t1_ns": t1_ns,
-            "wall_ms": round((end - self.t0) / 1e6, 3),
+            "wall_ms": round((end[0] - self.t0) / 1e6, 3),
             "stages": {k: round(v, 3) for k, v in self.stages.items()},
+            "cpu_ms": {k: _ms(v) for k, v in self.cpu.items()},
+            "others_cpu_ms": {k: _ms(v) for k, v in self.others.items()},
             "events": get_compile_watcher().take_pending(),
         }
+        if self.head is not None:
+            dur, cpu, proc = self.head
+            rec.update(head_ms=_ms(dur), head_cpu_ms=_ms(cpu), head_others_cpu_ms=_ms(proc - cpu))
+        # the gap before this step: from where the last recorded step's last
+        # stage closed; the CPU clocks only where that was this thread
+        last = log._last_end
+        gap = (self.t0 - last[0], self.c0 - last[1], self.p0 - last[2]) \
+            if last is not None and last[3] == self.ident else (0, 0, 0)
+        rec.update(gap_ms=_ms(gap[0]), gap_cpu_ms=_ms(gap[1]),
+                   gap_others_cpu_ms=_ms(gap[2] - gap[1]),
+                   lock_wait_ms=_ms(self.lock_wait_ns))
+        # a ledger that is off takes nothing off the ring (and records nothing)
+        _fold_events(rec, _take_events() if log.enabled else (), self.t0, self.ident)
+        if log.enabled:
+            log._last_end = (*end, self.ident)
         if self.admissions:
             rec["admissions"] = self.admissions
         rec.update({k: v for k, v in meta.items() if v is not None})
-        self._log.record(rec)
+        log.record(rec)
         return rec
 
 
@@ -375,10 +628,12 @@ def span(name: str, **attrs):
     part of the thread's open step when there is one, a bare
     TraceAnnotation when there is none (a direct ``engine.generate``)."""
     timer = getattr(_ACTIVE, "timer", None)
-    return timer.span(name, **attrs) if timer is not None else _annotation(name, **attrs)
+    return timer.span(name, **attrs) if timer is not None else annotation(name, **attrs)
 
 
-def _annotation(name: str, step: bool = False, **attrs):
+def annotation(name: str, step: bool = False, **attrs):
+    """A bare annotation on the profiler's trace, part of no step (a request's
+    ``brain.*`` spans on its own threads; ``span`` is the one for a step's)."""
     # jax is imported on first use: ``utils`` is imported by processes that
     # never touch it (the rule-parser brain, the tools)
     from jax.profiler import StepTraceAnnotation, TraceAnnotation
