@@ -1,0 +1,278 @@
+"""The MLPs of a fast-forward block run on its real positions (ISSUE 37).
+
+A (B, 1 + W) block holds ``1 + k_b`` real positions a live row and copies of
+the last one behind them; ``forward_paged`` told ``n_real`` and a packed
+width P gathers the real ones into (1, P, d), runs every layer's MLP on
+those, and hands each position its slot back (``llama.packed_ffn``). What it
+leaves — logits at the real positions, the K/V pool — is what the full-width MLP leaves; more real positions than P take the
+full-width branch of the same program; a call without ``n_real`` is the
+parent's program, text for text."""
+
+import dataclasses
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_voice_agent.models import llama
+from tpu_voice_agent.models.llama import LlamaConfig, forward_paged
+from tpu_voice_agent.serve import ContinuousBatcher, PagedDecodeEngine
+from tpu_voice_agent.serve.paged import FFN_PACK_ROWS
+from tpu_voice_agent.services.brain import install_prompt_prefix
+from tpu_voice_agent.services.prompts import render_prompt
+from tpu_voice_agent.utils import get_metrics
+
+MODELS = ["dense", "routed", "share"]
+SLOTS, W = 8, 8  # a block of 72 positions: no wider than the engine's ``FFN_PACK_ROWS``, so
+PACK = 24  # the packed width is put on it here, a third of the block as 96 is of the cells' 288
+TEXTS = ["search for laptops under 1000", "go back", "take a screenshot of this page",
+         "scroll down", "play some jazz", "upload my resume and submit",
+         "open the settings page, then turn on dark mode and go back to the start"]
+
+
+def _engine(model: str, prefix: bool = False) -> PagedDecodeEngine:
+    kw = dict(max_len=1536, batch_slots=SLOTS, prefill_buckets=(128, 256, 1024), block_size=128,
+              pool_blocks=8 * SLOTS + 8, fast_forward=W)
+    if model == "dense":
+        eng = PagedDecodeEngine(preset="test-tiny", **kw)
+    elif model == "routed":
+        eng = PagedDecodeEngine(cfg=LlamaConfig(
+            vocab_size=1024, dim=128, n_layers=2, n_heads=4, n_kv_heads=4, ffn_dim=64,
+            max_seq_len=1536, n_experts=8, top_k=2, capacity_factor=4.0, norm_topk=False,
+            qk_norm=True), **kw)
+    else:  # "share": layers of two kinds, a parallel block, shared + held experts, a tied head
+        import json
+        from pathlib import Path
+
+        from benchmark.builders import cohere2moe_stack, parse_stack
+
+        conf = json.loads((Path(__file__).parents[1]
+                           / "benchmark/configs/command-a-plus-05-2026-int8.json").read_text())
+        cfg = cohere2moe_stack.llama_config(*parse_stack.as_run(conf, True))
+        eng = PagedDecodeEngine(cfg=dataclasses.replace(cfg, max_seq_len=1536), quant=None, **kw)
+    if prefix:
+        install_prompt_prefix(eng)
+    return eng
+
+
+def _rel(got, want) -> float:
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.max(np.abs(got - want)) / max(float(np.max(np.abs(want))), 1e-30))
+
+
+def _block(eng, n_real, seed: int = 3):
+    """A (SLOTS, 1 + W) block as ``ff_body`` builds it — row b's positions
+    past its ``n_real[b]`` hold copies of its last real one; a row of 0 is
+    idle (write mask off, parked at position 0) — over pools of seeded K/V,
+    each row behind 200 positions of its own blocks. -> the call's
+    arguments, the pools as fresh copies (they are donated)."""
+    rng = np.random.default_rng(seed)
+    n_real = np.asarray(n_real, np.int32)
+    B, T, bs = SLOTS, 1 + W, eng.block_size
+    live = n_real > 0
+    iw = np.minimum(np.arange(T)[None, :], np.maximum(n_real[:, None] - 1, 0))
+    tokens = np.take_along_axis(rng.integers(3, 600, size=(B, T)), iw, axis=1)
+    positions = np.where(live[:, None], 200 + 7 * np.arange(B)[:, None] + iw, 0)
+    tables = np.zeros((B, eng.max_blocks), np.int32)
+    tables[:, :3] = 1 + 3 * np.arange(B)[:, None] + np.arange(3)[None, :]
+
+    def seeded(pool, k):
+        return jax.tree.map(lambda a: (jax.random.normal(jax.random.PRNGKey(k), a.shape, jnp.float32)
+                                       * 0.3).astype(a.dtype), pool)
+
+    pools = lambda: (seeded(eng.k_pool, 11), seeded(eng.v_pool, 12))
+    one_head = bool(eng.cfg.layer_types)
+    kw = dict(attn_impl="xla", write_mask=jnp.asarray(live),
+              **({"logit_pos": jnp.asarray(np.maximum(n_real - 1, 0))} if one_head else {}))
+    args = (eng.params, eng.cfg, jnp.asarray(tokens, jnp.int32), jnp.asarray(positions, jnp.int32))
+    return args, pools, jnp.asarray(tables), kw, one_head
+
+
+def _flat(pool):
+    return [np.asarray(x, np.float32) for x in jax.tree.leaves(pool)]
+
+
+@pytest.fixture(scope="module", params=MODELS)
+def engine(request):
+    return request.param, _engine(request.param)
+
+
+# rows of k = 0, of k = W, idle rows, and chains between: 1+1+9+0+3+2+0+1 = 17 of 72
+FITS = [1, 1, 1 + W, 0, 3, 2, 0, 1]
+OVERFLOWS = [1 + W, 1 + W, 1 + W, 0, 1 + W, 3, 2, 1]  # 42 > PACK
+
+
+@pytest.mark.parametrize("case", ["fits", "overflows"])
+def test_the_packed_mlp_leaves_what_the_full_one_leaves(engine, case):
+    model, eng = engine
+    n_real = FITS if case == "fits" else OVERFLOWS
+    P = PACK
+    assert P < SLOTS * (1 + W) <= eng.ffn_pack_rows == FFN_PACK_ROWS
+    args, pools, tables, kw, one_head = _block(eng, n_real)
+    n = jnp.asarray(n_real, jnp.int32)
+    want = forward_paged(*args, *pools(), tables, **kw)
+    got = forward_paged(*args, *pools(), tables, **kw, n_real=n, ffn_pack=P)
+    assert len(got) == len(want) + 1
+    fits = sum(n_real) <= P
+    assert np.asarray(got[-1]).tolist() == [int(fits), P if fits else SLOTS * (1 + W)]
+    real = np.arange(1 + W)[None, :] < np.asarray(n_real)[:, None]
+    lw, lg = np.asarray(want[0], np.float32), np.asarray(got[0], np.float32)
+    pick = (lambda x: x[np.asarray(n_real) > 0, 0]) if one_head else (lambda x: x[real])
+    assert np.abs(pick(lw)).max() > 0
+    tol = 2e-2 if fits else 1e-6  # the full branch is the computation it was
+    assert _rel(pick(lg), pick(lw)) < tol
+    assert np.array_equal(np.argmax(pick(lg), -1), np.argmax(pick(lw), -1))
+    # the pools whole but the trash block (0: idle rows park there whatever
+    # they hold): the real positions' K/V, the duplicates of a row's last real
+    # position (equal values under one index)
+    for pool_w, pool_g in zip(want[1:3], got[1:3]):
+        for w_, g_ in zip(_flat(pool_w), _flat(pool_g)):
+            if w_.ndim == 5:  # (planes, blocks, block_size, heads, width)
+                w_, g_ = w_[:, 1:], g_[:, 1:]
+            assert _rel(g_, w_) < tol
+
+
+def test_a_padded_position_reads_its_rows_last_real_slot():
+    n = jnp.asarray(FITS, jnp.int32)
+    pack = llama.ffn_pack_index(n, 1 + W, 32)
+    idx, inv = np.asarray(pack.idx), np.asarray(pack.inv)
+    assert bool(pack.fits) and np.asarray(pack.stats).tolist() == [1, 32]
+    total, T = sum(FITS), 1 + W
+    # the real positions in row order, each once; a slot reads itself back
+    want = [b * T + t for b, k in enumerate(FITS) for t in range(k)]
+    assert idx[:total].tolist() == want
+    for b, k in enumerate(FITS):
+        for t in range(T):
+            if k:
+                assert idx[inv[b, t]] == b * T + min(t, k - 1)
+            assert 0 <= inv[b, t] < 32
+    assert ((0 <= idx) & (idx < SLOTS * T)).all()
+    over = llama.ffn_pack_index(jnp.asarray(OVERFLOWS, jnp.int32), T, 32)
+    assert not bool(over.fits) and np.asarray(over.stats).tolist() == [0, SLOTS * T]
+    assert ((0 <= np.asarray(over.inv)) & (np.asarray(over.inv) < 32)).all()
+
+
+# sha256 of the lowered text (scope names in, Python frames out: what the
+# compile cache keys on) of ``forward_paged`` over ``_block`` WITHOUT
+# ``n_real`` as the PARENT of ISSUE 37 (commit 0cf90c6) lowers it: admission,
+# refcheck, spec decode's verify block, the T = 1 body and the compacted
+# chunk trace the program they traced.
+PARENT_SHA256 = {
+    "dense": "f5ccf0b0e2a3d3d0cd3dbbfdde9f981abb9078119a1d186e4932a61d258e446f",
+    "routed": "e0474155d53199451149d5d107c38a607d23bae4d56c4be9b0852d02eae5432a",
+    "share": "d933de84b9bc016d6f2f94db045ca85cf193000d15de3cd8482a05dbeeab82fc",
+}
+
+
+def _lowered_sha(eng) -> str:
+    args, pools, tables, kw, _ = _block(eng, FITS)
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        text = forward_paged.__wrapped__.lower(*args, *pools(), tables, **kw).as_text(debug_info=True)
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def test_without_a_packed_width_the_program_is_the_parents(engine):
+    model, eng = engine
+    assert _lowered_sha(eng) == PARENT_SHA256[model]
+
+
+@pytest.fixture(scope="module", params=MODELS)
+def served(request):
+    """One engine's plans at its derived packed width and with none in reach."""
+    eng = _engine(request.param, prefix=True)
+    derived, P = eng.ffn_pack_rows, PACK
+    prompts = [render_prompt(t, {}) for t in TEXTS]
+    runs = {}
+    for name, rows in (("packed", P), ("full", SLOTS * (1 + W))):
+        eng.ffn_pack_rows = rows
+        before = dict(get_metrics().counter_state()[0])
+        outs = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=48).generate_many(prompts)
+        after = get_metrics().counter_state()[0]
+        runs[name] = (outs, {k: after.get(k, 0.0) - before.get(k, 0.0) for k in after})
+    eng.ffn_pack_rows = derived
+    return request.param, eng, runs
+
+
+def test_the_chunk_loop_gives_the_same_plans_packed_and_full(served):
+    _, eng, runs = served
+    (packed, _), (full, _) = runs["packed"], runs["full"]
+    assert len(packed) == len(TEXTS)
+    for a, b in zip(packed, full):
+        assert a.token_ids == b.token_ids and a.text == b.text
+        assert a.finished == b.finished
+
+
+def test_the_batcher_publishes_the_packed_forwards_and_the_rows(served):
+    _, eng, runs = served
+    P, block = PACK, SLOTS * (1 + W)
+    for name, (_, d) in runs.items():
+        fwds, rows = d["scheduler.forwards"], d["scheduler.forward_rows"]
+        packed = d.get("ffn.forwards_packed", 0.0)
+        assert 0 <= packed <= fwds
+        # a full-width chunk's forwards: P rows packed, the block otherwise; a
+        # compacted chunk's (2 rows x 9 <= P) always its own block
+        assert d["ffn.rows"] == P * packed + (1 + W) * rows - block * packed
+        if name == "full":
+            assert packed == 0
+    assert runs["packed"][1]["ffn.forwards_packed"] > 0
+    assert runs["packed"][1]["ffn.rows"] < runs["full"][1]["ffn.rows"]
+
+
+def test_an_engine_of_the_cells_width_packs_on_its_own():
+    """32 slots x 9 positions are wider than ``FFN_PACK_ROWS``: the engine's
+    own chunk program has the branch and takes it, nothing put on it by hand;
+    at 8 slots (every other engine of this file) it derives the same rows and
+    its programs hold no branch."""
+    eng = PagedDecodeEngine(preset="test-tiny", max_len=1536, batch_slots=32, block_size=128,
+                            prefill_buckets=(128, 256, 1024), pool_blocks=8 * 32 + 8, fast_forward=W)
+    install_prompt_prefix(eng)
+    assert eng.compact_rows * (1 + W) <= eng.ffn_pack_rows == FFN_PACK_ROWS < 32 * (1 + W)
+    before = dict(get_metrics().counter_state()[0])
+    outs = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=24).generate_many(
+        [render_prompt(t, {}) for t in TEXTS * 3])
+    after = get_metrics().counter_state()[0]
+    d = {k: after.get(k, 0.0) - before.get(k, 0.0) for k in after}
+    assert all(o.error is None for o in outs)
+    assert 0 < d["ffn.forwards_packed"] <= d["scheduler.forwards"]
+    assert d["ffn.rows"] < (1 + W) * d["scheduler.forward_rows"]
+
+
+def test_a_model_with_a_recurrent_state_is_asked_for_no_packed_width():
+    """``models.sambay``'s MLPs have no packed branch: its engine derives no
+    width (its programs are the ones they were) and its forward refuses one."""
+    from benchmark.builders import sambay_stack
+    from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
+    from tpu_voice_agent.models import sambay
+
+    cfg = dataclasses.replace(sambay.PRESETS["sambay-test"], vocab_size=1024, max_seq_len=1536,
+                              window=384)
+    eng = PagedDecodeEngine(cfg=cfg, tokenizer=default_tokenizer(), quant="int8", init_weights=False,
+                            max_len=1536, batch_slots=SLOTS, prefill_buckets=(128, 256, 1024),
+                            block_size=128, pool_blocks=8 * SLOTS + 8, fast_forward=W)
+    assert eng.hybrid and eng.ffn_pack_rows == 0
+    params = jax.eval_shape(lambda: sambay_stack.make_params(eng.cfg, 23))
+    tok = jax.ShapeDtypeStruct((SLOTS, 1 + W), jnp.int32)
+    with pytest.raises(NotImplementedError, match="no packed branch"):
+        jax.eval_shape(lambda p, t, k, v: forward_paged(
+            p, eng.cfg, t, t, k, v, jnp.zeros((SLOTS, eng.max_blocks + 1), jnp.int32),
+            n_real=jnp.ones((SLOTS,), jnp.int32), ffn_pack=PACK), params, tok, eng.k_pool, eng.v_pool)
+
+
+def test_the_metric_catalog_knows_the_counters():
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).parents[1]
+    sys.path.insert(0, str(root / "tools"))
+    import metrics_lint
+
+    reg = metrics_lint.scan_source(root / "tpu_voice_agent")
+    assert list(reg["ffn.forwards_packed"]) == list(reg["ffn.rows"]) == ["counter"]
+    # registered AND in the operator's catalog, under their type
+    assert metrics_lint.main([str(root / "tpu_voice_agent"), str(root / "docs/OBSERVABILITY.md")]) == 0

@@ -279,10 +279,10 @@ def test_paged_block_attention_common_pass_compiles_at_the_cells_shapes(tpu_devi
              ((B,), jnp.bool_), interpret=False)
 
 
-@pytest.mark.parametrize("config", ["mistral-7b-v0.1-int8", "olmoe-1b-7b-0125-int8"])
-def test_the_compacted_chunk_program_compiles_at_published_widths(tpu_devices, monkeypatch, config):
+def _chunk_program_at_published_widths(tpu_devices, monkeypatch, config, compacted: bool):
     """The whole decode chunk at its COMPACTED width (ISSUE 29: 8 of 32 slots'
-    rows, gathered and scattered back inside the program), as the benchmark's
+    rows, gathered and scattered back inside the program) or at its full one
+    with the packed MLP (ISSUE 37), as the benchmark's
     configuration serves it — published widths, int8 weights, the 200-block
     pool, fast-forward 8, chunk 16, the real grammar tables — lowered on
     shapes and compiled by XLA:TPU and Mosaic. The dense model reaches
@@ -321,12 +321,34 @@ def test_the_compacted_chunk_program_compiles_at_published_widths(tpu_devices, m
         S((B, eng.max_blocks), I32), S((B,), I32), S((B,), I32), S((B,), I32), S((B,), jnp.bool_),
         S((B,), I32), S((B,), I32), shapes(eng.tables_ff), shapes(eng.byte_len_table),
         shapes(jax.random.PRNGKey(0)), S((), F32), S((), I32), trash_idx=S((B,), I32), rules=None,
-        logit_mask=None if eng.logit_mask is None else shapes(eng.logit_mask), rows_idx=S((R,), I32),
+        logit_mask=None if eng.logit_mask is None else shapes(eng.logit_mask),
+        **({"rows_idx": S((R,), I32)} if compacted else {"ffn_pack": eng.ffn_pack_rows}),
         chunk_steps=16, greedy=True, constrained=True, kernels="pallas", eos_id=eng.eos_id,
         pad_id=eng.pad_id, max_len=eng.max_len, kv_quant=None, quality_lanes=eng.quality_lanes).compile()
-    text = compiled.as_text()
+    return compiled.as_text(), eng, routed
+
+
+@pytest.mark.parametrize("config", ["mistral-7b-v0.1-int8", "olmoe-1b-7b-0125-int8"])
+def test_the_compacted_chunk_program_compiles_at_published_widths(tpu_devices, monkeypatch, config):
+    text, eng, routed = _chunk_program_at_published_widths(tpu_devices, monkeypatch, config, True)
+    B, R = eng.batch_slots, eng.compact_rows
     assert text.count("tpu_custom_call") == (4 if routed else 1)  # block attention (+ gate, up, down)
     assert f"bf16[{R},9," in text and f"bf16[{B},9," not in text  # the forwards run at R rows
+    assert R * 9 <= eng.ffn_pack_rows and "conditional" not in text  # nothing to pack at this width
+
+
+@pytest.mark.parametrize("config", ["mistral-7b-v0.1-int8",
+                                    pytest.param("olmoe-1b-7b-0125-int8", marks=pytest.mark.slow)])
+def test_the_packed_chunk_program_compiles_at_published_widths(tpu_devices, monkeypatch, config):
+    """The FULL-width chunk program with its MLPs on the real positions
+    (ISSUE 37): 32 x 9 positions packed into ``ffn_pack_rows`` = 96 rows, one
+    conditional a layer between the packed MLP and the whole one — for the
+    routed model the grouped kernel in BOTH branches, at both row tiles."""
+    text, eng, routed = _chunk_program_at_published_widths(tpu_devices, monkeypatch, config, False)
+    B, P = eng.batch_slots, eng.ffn_pack_rows
+    assert (B, P) == (32, 96) and "conditional" in text
+    assert text.count("tpu_custom_call") == (7 if routed else 1)  # block attention (+ 3 a branch)
+    assert f"bf16[{B},9," in text and f"bf16[{P}," in text  # both branches
 
 
 def _hybrid_engine(monkeypatch):
@@ -490,7 +512,8 @@ def _cmdaplus_engine(monkeypatch, **serving):
     return eng, s, params
 
 
-@pytest.mark.parametrize("width", [pytest.param("full", marks=pytest.mark.slow), "compact", "window"])  # the chip runs "full" in every check
+@pytest.mark.parametrize("width", [pytest.param("full", marks=pytest.mark.slow), "compact", "window",  # the chip runs "full" in every check
+                                   pytest.param("packed", marks=pytest.mark.slow)])  # ... since ISSUE 37 "packed"
 def test_the_command_a_plus_chunk_program_compiles_at_published_widths(tpu_devices, monkeypatch, width):
     """Command A+'s decode chunk as ``cmdaplus_flood`` serves it — 8 parallel
     blocks at published widths, int8 weights, 16 held experts through the
@@ -512,6 +535,8 @@ def test_the_command_a_plus_chunk_program_compiles_at_published_widths(tpu_devic
     shapes = lambda tree: jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), tree)
     pool = S((cfg.n_layers, s["pool_blocks"], eng.block_size, cfg.n_kv_heads, cfg.head_dim), BF16)
     rows = {"rows_idx": S((R,), I32)} if width == "compact" else {}
+    if width == "packed":  # shared and routed experts on the real positions, 96 rows (ISSUE 37)
+        rows = {"ffn_pack": eng.ffn_pack_rows}
     compiled = paged.paged_chunk_decode_loop.__wrapped__.lower(
         shapes(params), cfg, pool, pool,
         S((B, eng.max_blocks), I32), S((B,), I32), S((B,), I32), S((B,), I32), S((B,), jnp.bool_),
@@ -524,7 +549,9 @@ def test_the_command_a_plus_chunk_program_compiles_at_published_widths(tpu_devic
     n = R if width == "compact" else B
     # the eight layers unrolled: attention (two groups of
     # 16 rows at the full width) and gate, up, down for each
-    assert text.count("tpu_custom_call") == 8 * (3 + (2 if width == "full" else 1))
+    # ("packed": the three expert calls in each branch of a layer's conditional)
+    assert text.count("tpu_custom_call") == 8 * ({"packed": 6}.get(width, 3) + (1 if n == R or bound else 2))
+    assert ("conditional" in text) == (width == "packed")
     # the head runs on one position a row
     assert f"f32[{n},32768]" in text and f"{n},9,32768]" not in text and f"[{9 * n},32768]" not in text
 

@@ -1,0 +1,348 @@
+#!/usr/bin/env python3
+"""The packed MLP of a fast-forward block against the whole one, on the chip
+at full width: the numbers, what each costs, and how often it would engage.
+
+``correct``'s comparison with the plain references reaches ``forward_paged``
+without ``n_real`` (``benchmark/lib/refcheck.py``), so it never samples the
+branch ISSUE 37 adds. This does, two ways.
+
+``--config NAME``: one configuration of the benchmark built as its builder
+builds it (published widths, its seeded int8 weights, the 200-block pool), and
+``--seeds`` (batch_slots, 1 + fast_forward) blocks as the chunk program's
+``ff_body`` makes them — a seeded ``n_real`` a row (idle rows, rows of k = 0,
+chains up to W), the positions behind it copies of the row's last real one,
+over a pool of seeded K/V — through ``forward_paged`` whole and with
+``ffn_pack`` = the engine's ``ffn_pack_rows``: the largest difference of the
+real positions' logits and of the K/V they wrote, each as a share of the
+whole path's largest value (``refcheck._rel_err``'s measure), the rows whose
+top-1 agrees, held against ``LIMIT`` (exit code 1 over it). Two readings
+bracket the limit. BELOW it, the packed branch itself, and what it is made of:
+the whole MLP over the block FLATTENED to (1, B x T, d) rows (no index, no
+gather, no conditional: the packed branch's operand shape alone) against the
+whole MLP as served, and the same with its input and output HELD in buffers
+(``optimization_barrier``: no fusion across them, as around a gather). On the
+chip the first equals the served MLP and the second equals the packed branch,
+bit for bit (PERF.md section 6, PR 37): what the packed branch differs by is
+the fusion of the down projection's rounding with the residual add, which the
+served MLP has and a buffer forbids. ABOVE it, a control the limit must
+refuse: the whole MLP with its weights rounded to int4.
+With ``--rows``, the wall of the forward (first launch to the pools' last
+write, median) whole and packed at each of them (every one holds the same real
+positions: the differences are the MLPs'), and of the layers' MLPs ALONE
+(``llama._ffn`` scanned over the stacked weights) at each of those row counts
+and at the block's: the microbenchmark ``ffn_pack_rows`` was picked from.
+
+``--workload CELL --sweep 0 64 96 128 160``: serves the cell as
+``benchmark/run.py`` does, and for each packed width in turn (0 = none) its
+traffic for ``--seconds``: tokens a second, ``ffn.forwards_packed`` /
+``scheduler.forwards`` — the share of forwards whose real positions number no
+more than that width: the cumulative histogram of sum(n_real) at those points —
+and ``ffn.rows`` a forward. A width the engine does not derive is put on it
+here, for the measurement alone (the batcher's warm-up runs its chunk program first).
+
+    python3 tools/ffn_pack_check.py --config mistral-7b-v0.1-int8 [--seed 7 --seeds 4] [--rows 72 128 144]
+    python3 tools/ffn_pack_check.py --workload parse_flood --sweep 0 64 96 [--seconds 45]
+
+One configuration a process (each fills most of the chip). A line of JSON a
+run, on stdout and appended to ``chiprun_out/ffn_pack_check.jsonl``. With
+JAX_PLATFORMS=cpu at the configuration's rehearsal widths (no timing is a
+device's there)."""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+from functools import partial
+from unittest import mock
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def seeded_n_real(rng, B: int, T: int, budget: int):
+    """A chunk's forward as the flood cells meet it: an eighth of the rows
+    idle, three quarters of the others at k = 0, chains of 1..W on the rest,
+    one row of k = W; trimmed from the longest chain down to ``budget``."""
+    import numpy as np
+
+    n = np.where(rng.random(B) < 0.75, 1, 1 + rng.integers(1, T, size=B))
+    n[rng.random(B) < 0.125] = 0
+    n[0], n[1] = T, 1
+    while n.sum() > budget:
+        n[int(np.argmax(n[1:])) + 1] -= 1
+    return n.astype(np.int32)
+
+
+def block(eng, n_real, rng, pool_blocks: int, layout_seed: int):
+    """``ff_body``'s block for ``n_real``: tokens, positions, a table a row
+    (own blocks, ~600 positions behind it in the cells' pool), the write mask.
+    Where a row starts comes from ``layout_seed`` alone: blocks that share it
+    write the same stretch of each row and read nothing another one wrote."""
+    import numpy as np
+
+    B, T, bs = len(n_real), 1 + eng.tables_ff.ff_tokens.shape[1], eng.block_size
+    live = n_real > 0
+    iw = np.minimum(np.arange(T)[None, :], np.maximum(n_real[:, None] - 1, 0))
+    tokens = np.take_along_axis(rng.integers(3, eng.tokenizer.vocab_size, size=(B, T)), iw, axis=1)
+    per_row = min(5, (pool_blocks - 1) // B)  # own blocks a row, block 0 the trash
+    assert per_row >= 1, "the pool holds a block a row"
+    start = (per_row - 1) * bs + np.random.default_rng(layout_seed).integers(
+        0, bs - T, size=B)  # inside each row's last block
+    positions = np.where(live[:, None], start[:, None] + iw, 0)
+    tables = np.zeros((B, eng.max_blocks), np.int32)
+    tables[:, :per_row] = 1 + per_row * np.arange(B)[:, None] + np.arange(per_row)[None, :]
+    return tokens.astype(np.int32), positions.astype(np.int32), tables, live
+
+
+# What the packed branch may differ from the whole MLP by, as a share of a
+# row's largest logit (``refcheck._rel_err``). Between two readings (PERF.md
+# section 6, PR 37, has them by configuration): the packed branch itself, which
+# reads 3.9-4.0 % (Mistral), 0.7-5.3 % (Command A+), 0 (OLMoE) over four seeds —
+# bit for bit what the whole MLP reads once its output is a buffer and not fused
+# into the residual add — and the whole MLP with its weights rounded to int4,
+# the nearest precision below, which reads 75-186 %
+LIMIT = 0.12
+
+
+def to_int4(layers: dict) -> dict:
+    """The MLPs' int8 planes rounded to 16 levels IN PLACE (donated), their
+    scales kept: the control ``LIMIT`` has to refuse."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_voice_agent.models import llama
+
+    round4 = jax.jit(lambda q: (jnp.clip((q.astype(jnp.int16) + 8) >> 4, -8, 7) << 4).astype(jnp.int8),
+                     donate_argnums=0)
+    return {k: {**v, "q": round4(v["q"])} if k in llama._FFN_LEAVES and isinstance(v, dict) and "q" in v
+            else v for k, v in layers.items()}
+
+
+def check_config(args) -> int:
+    from benchmark.lib import refcheck
+    from benchmark.lib.manifest import load_json
+    from benchmark.run import program_env, say
+
+    conf = load_json(f"benchmark/configs/{args.config}.json")
+    program_env(conf)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tools.admit_batch_check import build_engine
+    from tpu_voice_agent.models import llama
+    from tpu_voice_agent.utils.compilecache import place_compile_cache
+
+    place_compile_cache()
+    rehearsal = os.environ.get("JAX_PLATFORMS", "") == "cpu"
+    t0 = time.perf_counter()
+    eng, _ = build_engine(conf, rehearsal)
+    B, T = eng.batch_slots, 1 + eng.tables_ff.ff_tokens.shape[1]
+    P = eng.ffn_pack_rows
+    dev = jax.devices()[0]
+    say(f"{args.config}: engine built in {time.perf_counter() - t0:.1f}s on {dev.platform} "
+        f"{dev.device_kind}; kernels {eng.kernels}, block ({B}, {T}), ffn_pack_rows {P}")
+    if not 0 < P < B * T:
+        print("this engine packs nothing", file=sys.stderr)
+        return 2
+    widths = sorted({r for r in args.rows if r < B * T} | {P})
+    one_head = bool(eng.cfg.layer_types)
+
+    def seeded(pool, k):
+        return (jax.random.normal(jax.random.PRNGKey(k), pool.shape, jnp.bfloat16) * 0.3).astype(pool.dtype)
+
+    pools = [seeded(eng.k_pool, 11), seeded(eng.v_pool, 12)]
+    eng.k_pool = eng.v_pool = None  # donated below, from ``pools``
+    params = eng.params
+
+    class Block:
+        """One seeded block: what ``forward_paged`` is handed, and where its real positions are."""
+
+        def __init__(self, seed: int):
+            rng = np.random.default_rng(seed)
+            self.n_real = seeded_n_real(rng, B, T, min(widths))
+            self.tokens, self.positions, self.tables, self.live = block(eng, self.n_real, rng, pools[0].shape[1], args.seed)
+            self.real = np.arange(T)[None, :] < self.n_real[:, None]
+            self.kw = dict(attn_impl=eng.kernels, write_mask=jnp.asarray(self.live), **(
+                {"logit_pos": jnp.asarray(np.maximum(self.n_real - 1, 0))} if one_head else {}))
+            self.packed_kw = {**self.kw, "n_real": jnp.asarray(self.n_real)}
+            rows, _ = np.nonzero(self.real)
+            where = self.positions[self.real]
+            self.at = (self.tables[rows, where // eng.block_size], where % eng.block_size)
+
+        def forward(self, **more):
+            out = llama.forward_paged(params, eng.cfg, jnp.asarray(self.tokens), jnp.asarray(self.positions),
+                                      *pools, jnp.asarray(self.tables), **more)
+            pools[:] = out[1:3]
+            return out
+
+        def left(self, out):
+            """The real positions' logits, and the K/V the block wrote."""
+            logits = np.asarray(out[0], np.float32)
+            logits = logits[self.live, 0] if one_head else logits[self.real]
+            return logits, [np.asarray(p[:, self.at[0], self.at[1]], np.float32) for p in pools]
+
+    def flat_ffn(ffn, h, pack, held: bool = False):
+        """In ``llama.packed_ffn``'s place: the WHOLE block as (1, B * T, d)
+        rows — no index, no gather, no conditional, the packed branch's 2-D
+        operand shape alone. ``held``: the MLP's input and output are BUFFERS,
+        as a gather's operand and result are — no fusion reaches across them,
+        so the residual is added to a y rounded to bfloat16 as written."""
+        if held:
+            h = jax.lax.optimization_barrier(h)
+        y, stats = ffn(h.reshape(1, -1, h.shape[-1]))
+        y = y.reshape(h.shape)
+        return (jax.lax.optimization_barrier(y) if held else y), stats
+
+    rel_of = lambda got, want: refcheck._rel_err(got, want)[0]
+    blocks, readings = [Block(args.seed + i) for i in range(args.seeds)], []
+    for blk in blocks:
+        blk.want, want_kv = blk.left(blk.forward(**blk.kw))
+        out = blk.forward(**blk.packed_kw, ffn_pack=P)
+        assert np.asarray(out[-1]).tolist() == [1, P], "the seeded block fits: the packed branch ran"
+        got, got_kv = blk.left(out)
+        with mock.patch.object(llama, "packed_ffn", flat_ffn):  # a width no call traced: this trace takes the patch
+            flat, _ = blk.left(blk.forward(**blk.packed_kw, ffn_pack=B * T - 1))
+        with mock.patch.object(llama, "packed_ffn", partial(flat_ffn, held=True)):
+            held, _ = blk.left(blk.forward(**blk.packed_kw, ffn_pack=B * T - 2))
+        rel, top1 = refcheck._rel_err(got, blk.want)
+        readings.append({
+            "seed": args.seed + len(readings), "real_positions": int(blk.n_real.sum()),
+            "packed_vs_whole": rel, "top1_agree": [top1, len(blk.want)],
+            "kv_written": max(float(np.max(np.abs(g - w)) / np.max(np.abs(w))) for g, w in zip(got_kv, want_kv)),
+            "whole_flat_vs_whole": rel_of(flat, blk.want), "packed_vs_whole_flat": rel_of(got, flat),
+            "whole_flat_held_vs_whole": rel_of(held, blk.want), "packed_vs_whole_flat_held": rel_of(got, held)})
+        say(f"PACKED vs WHOLE, ({B}, {T}) block, sum(n_real) {int(blk.n_real.sum())} into {P} rows: {readings[-1]}")
+
+    def wall(fn) -> float:
+        fn()  # compiled outside the timing
+        times = []
+        for _ in range(args.repeat):
+            jax.block_until_ready(pools)
+            t = time.perf_counter()
+            jax.block_until_ready(fn())
+            times.append((time.perf_counter() - t) * 1e3)
+        return statistics.median(times)
+
+    timings, vs_whole, blk = {}, {}, blocks[0]
+    if len(widths) > 1:
+        # every width holds the same real positions: the differences are the MLPs'
+        timings["forward_whole_ms"] = wall(lambda: blk.forward(**blk.kw))
+        for rows in widths:
+            timings[f"forward_packed_{rows}_ms"] = wall(lambda: blk.forward(**blk.packed_kw, ffn_pack=rows))
+            vs_whole[rows] = rel_of(blk.left(blk.forward(**blk.packed_kw, ffn_pack=rows))[0], blk.want)
+        cfg = eng.cfg
+
+        @partial(jax.jit, static_argnames=("rows",))
+        def mlps(layers, seed, rows: int):  # the layers' MLPs alone, by rows
+            scanned, held = llama._scan_and_whole(layers, cfg)
+            scanned = {k: v for k, v in scanned.items() if k in llama._FFN_LEAVES}
+            x = jax.random.normal(seed, (1, rows, cfg.dim), jnp.bfloat16)
+
+            def layer(x, xs):
+                p, li = xs
+                y, _ = llama._ffn({**p, **held, "layer": li} if held else p, x, cfg)
+                return (x + 0.01 * y).astype(x.dtype), None
+
+            return jax.lax.scan(layer, x, (scanned, jnp.arange(cfg.n_layers, dtype=jnp.int32)))[0]
+
+        for rows in widths + [B * T]:
+            timings[f"mlps_alone_{rows}_rows_ms"] = wall(
+                lambda: mlps(params["layers"], jax.random.PRNGKey(rows), rows))
+        say("ms, median of %d: %s" % (args.repeat, ", ".join(f"{k[:-3]} {v:.2f}" for k, v in timings.items())))
+    # the control, last (it rewrites the weights): the whole MLP at int4
+    params = eng.params = {**params, "layers": to_int4(params["layers"])}
+    control = [rel_of(blk.left(blk.forward(**blk.kw))[0], blk.want) for blk in blocks]
+    worst = max(r["packed_vs_whole"] for r in readings)
+    ok = worst < LIMIT < min(control)
+    say(f"packed against whole, worst of {len(readings)} seeds {worst:.6f}; the whole MLP at int4 against "
+        f"itself at int8 {[round(c, 6) for c in control]}; LIMIT {LIMIT}: {'PASS' if ok else 'FAIL'}")
+    line = {"config": args.config, "block": [B, T], "ffn_pack_rows": P, "limit": LIMIT, "pass": ok,
+            "readings": readings, "int4_control_vs_whole": control, "widths_vs_whole": vs_whole,
+            "timings_ms": None if rehearsal else timings,
+            "device": {"platform": dev.platform, "kind": dev.device_kind}}
+    return report(line, 0 if ok else 1)
+
+
+def sweep_workload(args) -> int:
+    from benchmark.lib.manifest import load_cell, load_code, load_manifest
+    from benchmark.run import Client, program_env, say
+
+    cell = load_cell(load_manifest(), args.workload)
+    config, traffic = cell["config"], cell["traffic"]
+    program_env(config)
+    import jax
+
+    from tpu_voice_agent.utils import get_metrics
+    from tpu_voice_agent.utils.compilecache import place_compile_cache
+
+    place_compile_cache()
+    rehearsal = os.environ.get("JAX_PLATFORMS", "") == "cpu"
+    client = Client()
+    served = load_code("builders", config["builder"]).build(config, rehearsal, say)
+    eng, points = served.engine, []
+    derived = eng.ffn_pack_rows
+    try:
+        for i, rows in enumerate(args.sweep):
+            eng.ffn_pack_rows = rows
+            served.parser.warmup()  # this width's full chunk program is compiled and RUN here
+            gen = {"generator": traffic["generator"], "traffic": traffic, "urls": served.urls,
+                   "seed": args.seed, "seconds": args.seconds}
+            client.command(dict(gen, cmd="warm"))
+            edges: dict = {}
+            client.command(dict(gen, cmd="run"), lambda msg: edges.__setitem__(
+                msg["ev"], (msg["t"], get_metrics().counter_state()[0])))
+            (t0, c0), (t1, c1) = edges["window_start"], edges["window_end"]
+            d = lambda k: c1.get(k, 0.0) - c0.get(k, 0.0)
+            fwds = d("scheduler.forwards")
+            points.append({
+                "ffn_pack_rows": rows, "seed": args.seed, "forwards": fwds,
+                "tokens_per_s": round(d("scheduler.tokens_generated") / (t1 - t0), 2),
+                "tokens_per_forward": round(d("scheduler.tokens_generated") / fwds, 3) if fwds else None,
+                "packed_share": round(d("ffn.forwards_packed") / fwds, 4) if fwds else None,
+                "ffn_rows_per_forward": round(d("ffn.rows") / fwds, 2) if fwds else None})
+            say(f"ffn_pack_rows {rows}: {points[-1]}")
+    finally:
+        eng.ffn_pack_rows = derived
+        client.close()
+        served.close()
+    dev = jax.devices()[0]
+    return report({"workload": args.workload, "seconds": args.seconds, "derived": derived,
+                   "sweep": points if not rehearsal else [
+                       {**p, "tokens_per_s": None} for p in points],
+                   "device": {"platform": dev.platform, "kind": dev.device_kind}}, 0)
+
+
+def report(line: dict, code: int) -> int:
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/ffn_pack_check.jsonl", "a") as f:
+        f.write(json.dumps(line) + "\n")
+    print(json.dumps(line), flush=True)
+    return code
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    what = ap.add_mutually_exclusive_group(required=True)
+    what.add_argument("--config", help="a name under benchmark/configs/: the block check")
+    what.add_argument("--workload", help="a cell of BENCHMARK.json: the sweep under its traffic")
+    ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--seeds", type=int, default=4, help="blocks checked, seeded --seed, --seed + 1, ...")
+    ap.add_argument("--rows", type=int, nargs="*", default=[],
+                    help="other packed widths: time the forward and the MLPs alone at each")
+    ap.add_argument("--sweep", type=int, nargs="*", default=[0, 64, 96], help="packed widths to serve at")
+    ap.add_argument("--seconds", type=float, default=45.0)
+    ap.add_argument("--repeat", type=int, default=9)
+    args = ap.parse_args()
+    os.chdir(ROOT)
+    sys.path.insert(0, ROOT)
+    return check_config(args) if args.config else sweep_workload(args)
+
+
+if __name__ == "__main__":
+    code = main()
+    sys.stdout.flush()
+    os._exit(code)

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -501,16 +502,24 @@ def moe_row_tile(assignments: int, n_experts: int) -> int:
 _EXPERT_LEAVES = ("moe_gate", "moe_up", "moe_down")
 
 
-def _scan_and_whole(layers: dict, cfg: LlamaConfig) -> tuple[dict, dict]:
+# the leaves a layer's MLP reads: everything under ``layer/ffn`` but its norm
+_FFN_LEAVES = ("w_gate", "w_up", "w_down", "router", "shared_gate", "shared_up", "shared_down",
+               *_EXPERT_LEAVES)
+
+
+def _scan_and_whole(layers: dict, cfg: LlamaConfig, packed: bool = False) -> tuple[dict, dict]:
     """(the stacked leaves a layer scan slices, those its body takes WHOLE).
     The grouped dispatch's kernel picks a layer's expert planes out of the
     stacked (L, E, d, f) leaves itself, by the layer index in its scalar
     prefetch: a scan's slice of them, handed to a custom call, is a copy of
-    134 MB three times a layer (``ops.grouped_matmul``)."""
-    if cfg.n_experts == 0 or cfg.moe_impl != "grouped":
-        return layers, {}
-    return ({k: v for k, v in layers.items() if k not in _EXPERT_LEAVES},
-            {k: layers[k] for k in _EXPERT_LEAVES})
+    134 MB three times a layer (``ops.grouped_matmul``). Where the MLP runs
+    ``packed`` every leaf it reads stays whole likewise and is sliced INSIDE
+    the branch that reads it (``_ffn``): a slice made before a conditional
+    is an operand of it, written out and read back, 176 MB a Mistral layer."""
+    grouped = cfg.n_experts > 0 and cfg.moe_impl == "grouped"
+    held = tuple(k for k in (_FFN_LEAVES if packed else _EXPERT_LEAVES if grouped else ())
+                 if k in layers)
+    return ({k: v for k, v in layers.items() if k not in held}, {k: layers[k] for k in held})
 
 
 def _running_count(hot: jax.Array) -> jax.Array:
@@ -686,36 +695,122 @@ def _swiglu(p, h, names, cs=_identity_cs):
     return _qe("btf,fd->btd", act, p[names[2]])
 
 
-def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = False, u=None):
-    """Shared decoder-layer back half: output projection + residual, then
-    the MLP (dense SwiGLU, or routed MoE when cfg.n_experts > 0) +
-    residual. ``attn`` is (B, T, n_heads * head_dim). With ``moe_stats``
-    (routed models only) -> (x, the layer's ``_moe_stats``). A PARALLEL
-    block (``u``: the layer's one normed input, which fed q/k/v too) adds
-    both halves to the same residual: x + W_o attn + FFN(u)."""
-    with jax.named_scope("layer/attn_out"):
-        attn = _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
-        attn = cs(attn, "act")
-        if u is None:
-            x = x + attn
+# what a forward whose FFN may run PACKED counts (ISSUE 37; summed over a
+# chunk's forwards by ``paged_chunk_decode_loop``, published as ``ffn.<name>``):
+# whether it took the packed branch, and the rows its FFN computed
+FFN_STATS = ("forwards_packed", "rows")
+
+
+class FfnPack(NamedTuple):
+    """The real positions of a (B, T) block, packed: built ONCE a forward
+    from ``n_real`` (row b's real positions are ``t < n_real[b]``), used by
+    every layer's FFN. ``idx`` (P,) names the position (into the B * T) each
+    packed slot holds; ``inv`` (B, T) the slot each position reads back: its
+    own if it is real, else the slot of ITS ROW's last real position — a
+    padded position of a fast-forward block is a copy of that one and writes
+    the same K/V index, so the values must stay equal — and any slot for a
+    row with none (its writes are parked, its logits unread). ``fits``: all
+    real positions have a slot; where they do not, the forward's FFN runs
+    at its full width, as it always did."""
+
+    idx: jax.Array
+    inv: jax.Array
+    fits: jax.Array
+
+    @property
+    def stats(self) -> jax.Array:
+        """``FFN_STATS`` of this forward, (2,) int32."""
+        full = self.inv.size
+        return jnp.stack([self.fits.astype(jnp.int32),
+                          jnp.where(self.fits, self.idx.shape[0], full).astype(jnp.int32)])
+
+
+def ffn_pack_index(n_real: jax.Array, T: int, P: int) -> FfnPack:
+    """``FfnPack`` of a (B, T) block at P slots, rows in order. No scatter and
+    no prefix sum over positions: a row's slots start where the rows before
+    it end, and a slot finds its row by counting the rows that end at or
+    before it (B and P are small)."""
+    n = jnp.clip(n_real.astype(jnp.int32), 0, T)
+    B = n.shape[0]
+    ends = jnp.cumsum(n)
+    starts = ends - n
+    t = jnp.minimum(jnp.arange(T, dtype=jnp.int32)[None, :], jnp.maximum(n[:, None] - 1, 0))
+    inv = jnp.minimum(starts[:, None] + t, P - 1)
+    slot = jnp.arange(P, dtype=jnp.int32)
+    row = jnp.minimum(jnp.sum(ends[None, :] <= slot[:, None], axis=1, dtype=jnp.int32), B - 1)
+    idx = row * T + jnp.clip(slot - starts[row], 0, T - 1)  # past the last real slot: unread
+    return FfnPack(idx, inv, ends[-1] <= P)
+
+
+def packed_ffn(ffn, h: jax.Array, pack: FfnPack | None):
+    """``ffn(h)`` -> (y, stats) over the (B, T, d) block ``h``, or — with a
+    ``pack`` — over its real positions alone where they fit: gather them to
+    (1, P, d), the same ``ffn``, and every position reads its slot back. One
+    branch a forward (``pack.fits`` is made once, before the layers)."""
+    if pack is None:
+        return ffn(h)
+    B, T, d = h.shape
+
+    def packed(h):
+        with jax.named_scope("layer/ffn/pack"):
+            hp = h.reshape(B * T, d)[pack.idx][None]
+        y, stats = ffn(hp)
+        with jax.named_scope("layer/ffn/unpack"):
+            return y[0][pack.inv], stats
+
+    # the conditional's own time (its operands' copies) is the MLP's: read
+    # under ``layer/ffn`` with the branches it chooses between
     with jax.named_scope("layer/ffn"):
-        h = _norm(x, p["mlp_norm"], cfg) if u is None else u
+        return jax.lax.cond(pack.fits, packed, ffn, h)
+
+
+def _ffn(p, h, cfg: LlamaConfig, cs=_identity_cs):
+    """A layer's MLP over its normed input (B, T, d) -> (y, the layer's
+    ``_moe_stats`` or None): the dense SwiGLU, or the routed experts and the
+    shared ones beside them. Position-wise: it asks nothing of B and T. Opens
+    ``layer/ffn`` itself: a branch of ``packed_ffn`` puts its own names
+    before it, and the scope paths the trace is read by stay whole. ``p``
+    may hold leaves still STACKED over the layers, named in ``p["stacked"]``,
+    beside the layer's index: sliced here, inside whichever branch runs."""
+    with jax.named_scope("layer/ffn"):
+        if p.get("stacked"):
+            p = {**p, **{k: jax.tree.map(lambda a: a[p["layer"]], p[k]) for k in p["stacked"]}}
         if cfg.n_experts > 0:
             y, stats = _moe_ffn(p, h, cfg)
             if cfg.n_shared_experts:
                 with jax.named_scope("shared"):  # layer/ffn/shared
                     shared = _swiglu(p, h, ("shared_gate", "shared_up", "shared_down"), cs)
                     y = y + (shared * (1.0 / cfg.n_shared_experts)).astype(y.dtype)
-            x = x + cs(y, "act") if u is None else x + attn + cs(y, "act")
-            return (x, stats) if moe_stats else x
-        if u is not None or cfg.n_shared_experts:
-            raise NotImplementedError("a parallel block or shared experts around a dense MLP")
+            return y, stats
         gate = _qe("btd,df->btf", h, p["w_gate"])
         up = _qe("btd,df->btf", h, p["w_up"])
-        act = (jax.nn.silu(gate) * up).astype(x.dtype)
+        act = (jax.nn.silu(gate) * up).astype(h.dtype)
         act = cs(act, "ffn")
-        down = _qe("btf,fd->btd", act, p["w_down"]).astype(x.dtype)
-        return x + cs(down, "act")
+        return _qe("btf,fd->btd", act, p["w_down"]).astype(h.dtype), None
+
+
+def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = False, u=None,
+               pack: FfnPack | None = None):
+    """Shared decoder-layer back half: output projection + residual, then
+    the MLP (dense SwiGLU, or routed MoE when cfg.n_experts > 0) +
+    residual. ``attn`` is (B, T, n_heads * head_dim). With ``moe_stats``
+    (routed models only) -> (x, the layer's ``_moe_stats``). A PARALLEL
+    block (``u``: the layer's one normed input, which fed q/k/v too) adds
+    both halves to the same residual: x + W_o attn + FFN(u). With ``pack``
+    the MLP runs on the block's real positions (``packed_ffn``)."""
+    with jax.named_scope("layer/attn_out"):
+        attn = _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
+        attn = cs(attn, "act")
+        if u is None:
+            x = x + attn
+    if cfg.n_experts == 0 and (u is not None or cfg.n_shared_experts):
+        raise NotImplementedError("a parallel block or shared experts around a dense MLP")
+    with jax.named_scope("layer/ffn"):
+        h = _norm(x, p["mlp_norm"], cfg) if u is None else u
+    y, stats = packed_ffn(partial(_ffn, p, cfg=cfg, cs=cs), h, pack)
+    with jax.named_scope("layer/ffn"):
+        x = x + cs(y, "act") if u is None else x + attn + cs(y, "act")
+    return (x, stats) if moe_stats else x
 
 
 # ---------------------------------------------------------------- forward
@@ -851,7 +946,7 @@ def forward(
 @watch_compiles("llama.forward_paged")
 @partial(jax.jit, static_argnames=("cfg", "rules", "attn_impl", "fresh_block",
                                    "gather_blocks", "kv_quant", "moe_stats",
-                                   "attn_stats", "hybrid_stats"),
+                                   "attn_stats", "hybrid_stats", "ffn_pack"),
          donate_argnames=("k_pool", "v_pool", "k_scale", "v_scale"))
 def forward_paged(
     params: dict,
@@ -889,14 +984,19 @@ def forward_paged(
     attn_stats: bool = False,  # also return ``ops.ATTN_STATS``, (2,) int32:
     # the row-blocks the block kernel's common pass took this forward and the
     # row-blocks live rows attend in all (the chunk loops carry them likewise)
-    n_real: jax.Array | None = None,  # (B,) int32 — a model with a RECURRENT
-    # state (models.sambay) advances it over a row's first n_real positions
-    # and no others (None: all T of a live row); a decoder whose state is K/V
-    # alone never looks: absent, the traced program is the one it was
+    n_real: jax.Array | None = None,  # (B,) int32: row b's real positions are
+    # t < n_real[b] (None: all T of a live row). A model with a RECURRENT
+    # state (models.sambay) advances it over those and no others; with
+    # ``ffn_pack`` a LlamaConfig's MLPs compute those and no others. Absent,
+    # the traced program is the one it was
     logit_pos: jax.Array | None = None,  # (B,) int32: the head runs on this
     # one position of each row, logits (B, 1, V) (that model, and one with
     # layers of more than one kind: the chunk loop's ``one_head``)
     hybrid_stats: bool = False,  # that model only: also ``sambay.HYBRID_STATS``
+    ffn_pack: int = 0,  # P > 0 with ``n_real``, off a mesh, where B * T > P: the
+    # MLPs run on the block's real positions packed into P rows while they fit
+    # (``packed_ffn``; a fast-forward block of 1 + W positions a row holds few
+    # real ones), and ``FFN_STATS`` (2,) int32 is returned LAST
 ):
     """The paged twin of ``forward`` (parity-tested): sequences own
     non-contiguous pool blocks via per-row block tables (SURVEY.md §7
@@ -927,6 +1027,8 @@ def forward_paged(
             raise sambay.StateNotCarried(
                 "a mesh shards, and KV_QUANT re-stores, K/V blocks alone: neither "
                 "carries the recurrent state of a SambaYConfig's requests")
+        if ffn_pack:
+            raise NotImplementedError("models.sambay's MLPs have no packed branch (ROADMAP S3 (e))")
         return sambay.forward_paged(
             params, cfg, tokens, positions, k_pool, v_pool, block_tables,
             attn_impl=attn_impl, write_mask=write_mask, trash_idx=trash_idx,
@@ -991,14 +1093,25 @@ def forward_paged(
             else:
                 split = common_block_split(block_tables, positions, write_mask, bs)
 
-    scanned, whole = _scan_and_whole(params["layers"], cfg)
+    # rows of different dp groups may not share a packed axis: not under a mesh
+    pack = None
+    if ffn_pack and n_real is not None and rules is None and B * T > ffn_pack:
+        with jax.named_scope("layer/ffn/pack"):
+            live = n_real if write_mask is None else jnp.where(write_mask, n_real, 0)
+            pack = ffn_pack_index(live, T, ffn_pack)
+
+    scanned, whole = _scan_and_whole(params["layers"], cfg, packed=pack is not None)
+    # of the whole leaves, those the MLP slices itself (the grouped kernel
+    # takes its planes stacked, and the layer's index)
+    stacked = () if pack is None else tuple(
+        k for k in whole if not (cfg.moe_impl == "grouped" and k in _EXPERT_LEAVES))
 
     def layer(carry, layer_in, kind=(True, None)):
         x, kp, vp, ksc, vsc = carry
         p, li = layer_in
         rotate, window = kind
         if whole:
-            p = {**p, **whole, "layer": li}
+            p = {**p, **whole, "layer": li, **({"stacked": stacked} if stacked else {})}
         u = None
         if cfg.parallel_block:
             with jax.named_scope("layer/attn_qkv"):
@@ -1119,7 +1232,7 @@ def forward_paged(
                             vp[li][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
                             vsc[li][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
                 attn = _attend(q, kl, vl, positions, kv_len_mask, window)
-        out = _layer_out(p, x, attn, cfg, cs, moe_stats=moe_stats, u=u)
+        out = _layer_out(p, x, attn, cfg, cs, moe_stats=moe_stats, u=u, pack=pack)
         x, stats = out if moe_stats else (out, None)
         return (x, kp, vp, ksc, vsc), stats
 
@@ -1164,6 +1277,8 @@ def forward_paged(
         # layers behind a window that binds read other blocks: every layer's read
         extra += (sum(stats_of(split if w is None else win_split.get(w)) for _, w in kinds)
                   if windows else stats_of(split),)
+    if pack is not None:
+        extra += (pack.stats,)
     return (logits, k_pool, v_pool, k_scale, v_scale, *extra)
 
 

@@ -128,6 +128,10 @@ class ChunkResult:
     attn: Any = None  # the paged chunk loop's ops.ATTN_STATS summed over the
     # chunk, (2,) int32: row-blocks the block kernel's common pass took, and
     # row-blocks live rows attended in all; None from every other engine
+    ffn: Any = None  # llama.FFN_STATS summed over the chunk, (2,) int32, from
+    # a paged chunk program whose MLPs may run packed; None from every other
+    ffn_rows: int = 0  # the rows an UNPACKED forward's MLPs compute (width x
+    # positions a row); 0: this engine does not say
     # the spec decoder's per-row host counts; None on the plain loops
     row_fwds: Any = None  # verify steps the row took part in
     row_accepts: Any = None  # draft tokens accepted

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..grammar.fsm import fsm_advance
-from ..models.llama import _hybrid, forward_paged, moe_stat_names, paged_only
+from ..models.llama import FFN_STATS, _hybrid, forward_paged, moe_stat_names, paged_only
 from ..utils.compilewatch import get_compile_watcher, watch_compiles
 from ..utils.steplog import (
     ALLOC_SPAN,
@@ -62,6 +62,13 @@ from .engine import (
 from .radix import RadixCache
 
 SLOT_STATE_SPAN = REQUEST_SPAN + ".slot_state"
+
+# the rows the MLPs of a fast-forward block are packed into (ISSUE 37): under
+# the ridge of int8 weights on this chip (~120 rows: an MLP costs the same
+# from 72 to 96 and more from 128 on), over what a chunk's forwards hold but
+# its first (PERF.md section 5 item 1 has both measurements). Rows, not rows
+# a slot: the ridge is the chip's, and a block no wider than this packs nothing
+FFN_PACK_ROWS = 96
 
 
 class PoolExhausted(RuntimeError):
@@ -408,7 +415,7 @@ def _scatter_scale_planes(k_scale, v_scale, src_k, src_v, dst_idx):
     jax.jit,
     static_argnames=("cfg", "rules", "chunk_steps", "greedy", "constrained",
                      "kernels", "eos_id", "pad_id", "max_len", "kv_quant",
-                     "quality_lanes"),
+                     "quality_lanes", "ffn_pack"),
     donate_argnames=("k_pool", "v_pool", "k_scale", "v_scale"),
 )
 def paged_chunk_decode_loop(
@@ -441,6 +448,9 @@ def paged_chunk_decode_loop(
     max_len: int | None = None,
     kv_quant: str | None = None,
     quality_lanes: bool = False,  # ISSUE 15 conf lanes (see the dense twin)
+    ffn_pack: int = 0,  # P: the MLPs of a fast-forward block run on its real
+    # positions packed into P rows (``PagedDecodeEngine.ffn_pack_rows``; 0, or
+    # a block of no more than P positions: the program is the one it was)
 ):
     """chunk_decode_loop's paged twin: forward_paged per step, idle rows'
     writes parked in their group's reserved trash block via write_mask (they
@@ -453,7 +463,10 @@ def paged_chunk_decode_loop(
     carry and one more output: ``llama.MOE_STATS`` summed over the chunk's
     forwards and layers, (4,) int32. Every variant's LAST output is
     ``ops.ATTN_STATS`` summed over the chunk's forwards, (2,) int32 (ISSUE 31:
-    how often the block kernel's common pass engages).
+    how often the block kernel's common pass engages). A program whose MLPs
+    may run PACKED (ISSUE 37: ``ffn_pack`` under a fast-forward block wider
+    than it, off a mesh) has one more carry and output after them:
+    ``llama.FFN_STATS`` summed over the chunk's forwards, (2,) int32.
 
     The COMPACTED width (ISSUE 29): with ``rows_idx`` the same loop runs over
     those R slots' rows alone — their state and block-table rows gathered on
@@ -516,6 +529,12 @@ def paged_chunk_decode_loop(
     # model, and a LlamaConfig with layers of more than one kind (the others'
     # programs compute it on all 1 + W, as they always have)
     one_head = hybrid or bool(cfg.layer_types)
+    # a fast-forward block holds 1 + k real positions a live row and copies
+    # of the last one behind them: the forward is told, and its MLPs compute
+    # the real ones packed into ``ffn_pack`` rows while they fit
+    packs = bool(use_ff and ffn_pack and rules is None and B * (1 + W) > ffn_pack)
+    if packs:
+        counts0 += (jnp.zeros((len(FFN_STATS),), jnp.int32),)
 
     carry0 = (k_pool, v_pool, k_scale, v_scale, cur, pos, fsm_state, active,
               eos0, nbytes,
@@ -636,7 +655,8 @@ def paged_chunk_decode_loop(
             params, cfg, blk_tok, blk_pos, kp, vp,
             block_tables, rules=rules, attn_impl=kernels, write_mask=active,
             trash_idx=trash_idx, k_scale=ksc, v_scale=vsc, kv_quant=kv_quant,
-            **count_kw, **({"n_real": emitted} if hybrid else {}),
+            **count_kw, **({"n_real": emitted} if hybrid or packs else {}),
+            **({"ffn_pack": ffn_pack} if packs else {}),
             **({"logit_pos": k} if one_head else {}),
         )
         logits_k = (logits[:, 0, :] if one_head else
@@ -727,6 +747,12 @@ class PagedDecodeEngine(DecodeEngine):
         # program's batch axis
         R = max(1, self.batch_slots // 4)
         self.compact_rows = R if self.dp == 1 and R < self.batch_slots else 0
+        # the rows the MLPs of a fast-forward block compute (ISSUE 37): a
+        # block of batch_slots x (1 + W) positions holds ~1.4 real ones a
+        # row, the rest are copies. A dispatch whose block is no wider runs
+        # the program it always ran, and so does a mesh (rows of different dp
+        # groups may not share a packed axis)
+        self.ffn_pack_rows = FFN_PACK_ROWS if self.dp == 1 else 0
         # quantized KV storage tier (ISSUE 12): KV_QUANT=int8|int4 stores
         # per-(position, head) scaled values (ops.kvquant) — half/quarter
         # the HBM bytes per block, so a fixed pool budget holds ~2x/~4x the
@@ -746,6 +772,9 @@ class PagedDecodeEngine(DecodeEngine):
         # tail and a float32 state for each recurrent layer; here: nothing)
         self.hybrid = _hybrid(self.cfg)
         if self.hybrid:
+            # ``sambay.forward_paged`` has no packed MLP (ROADMAP S3 (e) has
+            # what it waits for)
+            self.ffn_pack_rows = 0
             from ..models.sambay import cache_spec
 
             if radix_enable is None:
@@ -1647,6 +1676,11 @@ class PagedDecodeEngine(DecodeEngine):
         rows = self._rows_of(live) if greedy else None
         # absent at the full width, so that call is the one it always was
         compact = {} if rows is None else {"rows_idx": jnp.asarray(rows)}
+        width = (self.batch_slots if rows is None else len(rows)) * (1 + W)
+        # likewise absent where no block is wider than the packed rows
+        packs = bool(W and self.mesh is None and width > self.ffn_pack_rows > 0)
+        if packs:
+            compact["ffn_pack"] = self.ffn_pack_rows
         out, n, eos, self.k_pool, self.v_pool, self.k_scale, self.v_scale, \
             cur, pos, fsm, active, nbytes, left, fwds, pois, conf, *counts = (
                 paged_chunk_decode_loop(
@@ -1673,9 +1707,10 @@ class PagedDecodeEngine(DecodeEngine):
             rows=self.batch_slots if rows is None else len(rows),
             conf=conf if self.quality_lanes else None,
             # a routed model's expert-row counts, a hybrid one's state and window counts
-            moe=counts[0] if len(counts) > 1 and not self.hybrid else None,
+            moe=counts[0] if len(counts) > 1 + packs and not self.hybrid else None,
             hybrid=counts[0] if self.hybrid else None,
-            attn=counts[-1])  # the attention row-blocks, common and all
+            attn=counts[-1 - packs],  # the attention row-blocks, common and all
+            ffn=counts[-1] if packs else None, ffn_rows=width)
 
     def spec_grow(self, span: int, active=None) -> list[int]:
         """Claim block coverage for one speculative verify step (cur + K

@@ -1301,9 +1301,9 @@ class ContinuousBatcher:
         # ``fwds`` keeps tokens-per-forward truthful under multi-token steps
         # (counting dispatches as tokens would inflate every throughput
         # gauge); ``poison`` is the quarantine's per-row fault codes below.
-        out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h, moe_h, attn_h, hybrid_h = (
-            jax.device_get((res.out, res.n, res.active, res.eos, res.pos,
-                            res.fwds, res.poison, res.conf, res.moe, res.attn, res.hybrid)))
+        out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h, moe_h, attn_h, hybrid_h, ffn_h = (
+            jax.device_get((res.out, res.n, res.active, res.eos, res.pos, res.fwds,
+                            res.poison, res.conf, res.moe, res.attn, res.hybrid, res.ffn)))
         out_h, n_h, act_h, eos_h, pos_h, pois_h = (
             np.asarray(x) for x in (out_h, n_h, act_h, eos_h, pos_h, pois_h))
         fwds_h, rows = int(fwds_h), res.rows
@@ -1345,6 +1345,15 @@ class ContinuousBatcher:
             # common pass took is the first over the second
             for name, v in zip(ATTN_STATS, np.asarray(attn_h)):
                 m.inc(f"attn.{name}", float(v))
+        if ffn_h is not None:
+            # forwards whose MLPs ran on the real positions packed, and the
+            # rows the MLPs computed (``llama.FFN_STATS``, in its order)
+            packed, ffn_rows = (float(v) for v in np.asarray(ffn_h))
+            m.inc("ffn.forwards_packed", packed)
+            m.inc("ffn.rows", ffn_rows)
+        elif res.ffn_rows:  # a program that packs nothing: every forward whole
+            m.inc("ffn.forwards_packed", 0.0)
+            m.inc("ffn.rows", float(fwds_h) * res.ffn_rows)
         if hybrid_h is not None:
             # a model with a recurrent state: positions its states advanced
             # over / positions computed, window blocks walked / held
