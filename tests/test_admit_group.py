@@ -229,6 +229,61 @@ def test_the_one_row_prefill_programs_are_the_parents(pair):
     assert hashlib.sha256(text.encode()).hexdigest() == ONE_ROW_SHA256[model]
 
 
+def _chunk_program_shas(eng) -> list[str]:
+    """sha256 of the lowered text (scope names in, Python frames out) of every
+    chunk program a batcher dispatches while it serves more requests than the
+    compacted width holds, then one alone: the full width's, the compacted one's."""
+    from tpu_voice_agent.serve import paged
+
+    texts, loop = {}, paged.paged_chunk_decode_loop
+
+    def spy(*a, **kw):
+        rows = len(kw["rows_idx"]) if "rows_idx" in kw else a[4].shape[0]  # the program's width
+        if rows not in texts:
+            frames = jax.config.jax_traceback_in_locations_limit
+            jax.config.update("jax_traceback_in_locations_limit", 0)
+            try:
+                texts[rows] = loop.__wrapped__.lower(*a, **kw).as_text(debug_info=True)
+            finally:
+                jax.config.update("jax_traceback_in_locations_limit", frames)
+        return loop(*a, **kw)
+
+    paged.paged_chunk_decode_loop = spy
+    try:
+        bat = ContinuousBatcher(eng, chunk_steps=4, max_new_tokens=12)
+        outs = [o for texts_ in (TEXTS + TEXTS[:5], TEXTS[:1])
+                for o in bat.generate_many([render_prompt(t, {}) for t in texts_])]
+    finally:
+        paged.paged_chunk_decode_loop = loop
+    assert all(o.error is None for o in outs)
+    assert sorted(texts) == [eng.compact_rows, eng.batch_slots]
+    return [hashlib.sha256(texts[rows].encode()).hexdigest() for rows in sorted(texts, reverse=True)]
+
+
+# the chunk programs of this module's four engines — the kinds of the four
+# configurations the benchmark held before ISSUE 38 — at the full and at the
+# compacted width, as the PARENT of ISSUE 38 (commit e3ab964) lowers them: a
+# model with a LATENT cache compiles a variant of its own
+# (``paged_chunk_decode_loop``'s ``lat``), and every other model's program
+# is the text it was. A PR that changes the chunk loop on purpose re-derives
+# them (``_chunk_program_shas`` on its parent's tree) and says so.
+CHUNK_SHA256 = {
+    "dense": ["e2c0fe672ca2f731ec63ce973b1e92dd275d3e064e6981e53cd5592fea4394a7",
+              "a83a1246db842dd9278de4755325c77eab35845297b5216c5ef486791938abaa"],
+    "routed": ["96e6b871f414bb4e3b946652d053b70cc8919c3c963b40ac2d72c51ea1529ab5",
+               "09487a9d4027ee5490ba7d0e2544442000c57ee25acce4c9fc2fa995ff695c73"],
+    "hybrid": ["8a84063c101aa0386efdc89fb1599cba13fb411edfce7fe4b42fda5421b4c500",
+               "234168ec7de640450f7aa42f1701430ed236e1539a16d62dc2046323d7e095fb"],
+    "share": ["8f8d98e57314b281309e0314141f852e071346bc942d3c1529f5f1fcc90956e0",
+              "3aa59c4590c50e4b16c1693e05a42ecc9cc7c564820b6d869e1761f558e9c495"],
+}
+
+
+def test_the_chunk_programs_are_the_parents(pair):
+    model, one, _ = pair
+    assert _chunk_program_shas(one) == CHUNK_SHA256[model]
+
+
 @pytest.mark.parametrize("model", ["dense", "hybrid"])
 def test_warmup_leaves_no_grouped_shape_uncompiled(model):
     """After ``warmup()`` — which RUNS the grouped admission and leaves

@@ -658,6 +658,107 @@ def test_the_grouped_admission_compiles_at_published_widths(tpu_devices, monkeyp
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
 
 
+def _moonlight_engine(monkeypatch):
+    """The ``moonlight_flood`` cell's engine (published widths, a two-block
+    pool: the real one is a shape below) and abstract weights, with the
+    kernels told they are not interpreted."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from benchmark.builders import moonlight_stack, parse_stack
+    from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
+    from tpu_voice_agent.serve import PagedDecodeEngine
+
+    for mod in ("latent_attention", "grouped_matmul"):
+        monkeypatch.setattr(sys.modules[f"tpu_voice_agent.ops.{mod}"], "on_cpu", lambda: False)
+    conf = json.loads((Path(__file__).parents[1] / "benchmark" / "configs"
+                       / "moonlight-16b-a3b-int8.json").read_text())
+    m, s = parse_stack.as_run(conf, False)
+    eng = PagedDecodeEngine(
+        cfg=moonlight_stack.llama_config(m, s), tokenizer=default_tokenizer(), quant=s["quant"],
+        batch_slots=s["batch_slots"], block_size=s["block_size"], pool_blocks=2, max_len=s["max_len"],
+        prefill_buckets=tuple(s["prefill_buckets"]), fast_forward=s["fast_forward"], init_weights=False)
+    params = jax.eval_shape(lambda: moonlight_stack.make_params(eng.cfg, s["weights_seed"]))
+    return eng, s, params
+
+
+def _latent_pools(eng, s, S):
+    cfg = eng.cfg
+    planes = lambda width, blocks=s["pool_blocks"]: S((cfg.n_layers, blocks, eng.block_size, width), BF16)
+    return planes, planes(cfg.kv_lora_rank), planes(cfg.qk_rope_dim)
+
+
+@pytest.mark.parametrize("width", [pytest.param("full", marks=pytest.mark.slow), "compact", "packed"])  # the chip runs "full" in every check
+def test_the_moonlight_chunk_program_compiles_at_published_widths(tpu_devices, monkeypatch, width):
+    """Moonlight's decode chunk as ``moonlight_flood`` serves it — layer 0
+    dense at 11264, 16 routed layers in a scan (64 experts of 1408 through
+    the grouped kernel, the layer in its scalar prefetch, the shared SwiGLU
+    beside them), int8 weights, the LATENT pool of 512 + 64 values a token a
+    layer behind the latent kernel at 144 query rows a batch row, the
+    163840-wide head on one position a row — at the compacted width, with the
+    MLPs packed into 96 rows (what the cell runs under load) and whole."""
+    from tpu_voice_agent.serve import paged
+
+    eng, s, params = _moonlight_engine(monkeypatch)
+    B, R, cfg = eng.batch_slots, eng.compact_rows, eng.cfg
+    assert cfg.moe_impl == "grouped" and eng.latent and eng.ffn_pack_rows == 96
+    chip = SingleDeviceSharding(tpu_devices[0])
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    shapes = lambda tree: jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), tree)
+    _, c_pool, r_pool = _latent_pools(eng, s, S)
+    rows = {"rows_idx": S((R,), I32)} if width == "compact" else {}
+    if width == "packed":
+        rows = {"ffn_pack": eng.ffn_pack_rows}
+    compiled = paged.paged_chunk_decode_loop.__wrapped__.lower(
+        shapes(params), cfg, c_pool, r_pool,
+        S((B, eng.max_blocks), I32), S((B,), I32), S((B,), I32), S((B,), I32), S((B,), jnp.bool_),
+        S((B,), I32), S((B,), I32), shapes(eng.tables_ff), shapes(eng.byte_len_table),
+        shapes(jax.random.PRNGKey(0)), S((), F32), S((), I32), trash_idx=S((B,), I32), rules=None,
+        logit_mask=None if eng.logit_mask is None else shapes(eng.logit_mask), **rows,
+        chunk_steps=16, greedy=True, constrained=True, kernels="pallas", eos_id=eng.eos_id,
+        pad_id=eng.pad_id, max_len=eng.max_len, kv_quant=None, quality_lanes=eng.quality_lanes).compile()
+    text = compiled.as_text()
+    n = R if width == "compact" else B
+    # layer 0's latent kernel, and in the scan's body one more beside the three
+    # expert calls ("packed": those in each branch of the layer's conditional)
+    assert text.count("tpu_custom_call") == 1 + 1 + (6 if width == "packed" else 3)
+    assert ("conditional" in text) == (width == "packed")
+    # the head runs on one position a row, and no K or V of a cached position
+    # is ever decompressed: nothing of (pool positions) x (heads x 128) exists
+    assert f"f32[{n},163840]" in text and f"{n},9,163840]" not in text
+    assert not any(f"[{blocks},128,16,{w}]" in text for blocks in (s["pool_blocks"], eng.max_blocks)
+                   for w in (128, 192, 256))
+    # the pools are not donated through ``__wrapped__``: two copies of 0.50 GB are in it
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+@pytest.mark.parametrize("rows,bucket,fresh", [(4, 64, False), (1, 64, False), (1, 1024, True), (1, 9, False)],
+                         ids=["group", "suffix", "prefix", "one-block"])
+def test_the_moonlight_prefills_compile_at_published_widths(tpu_devices, monkeypatch, rows, bucket, fresh):
+    """A group's admission forward ((4, 64) suffixes behind the cached prefix,
+    the covered blocks of BOTH planes gathered, absorbed attention in XLA), the
+    per-slot one, the prefix's own prefill through the scratch pool (a fresh
+    1024-token block) and the comparison's one-row 1 + 8 block over the whole
+    pool through the latent kernel."""
+    from tpu_voice_agent.models import llama
+
+    eng, s, params = _moonlight_engine(monkeypatch)
+    chip = SingleDeviceSharding(tpu_devices[0])
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    shapes = lambda tree: jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), tree)
+    planes, _, _ = _latent_pools(eng, s, S)
+    blocks = 9 if fresh else s["pool_blocks"]
+    prefill = bucket > 9
+    compiled = llama.forward_paged.__wrapped__.lower(
+        shapes(params), eng.cfg, S((rows, bucket), I32), S((rows, bucket), I32),
+        planes(eng.cfg.kv_lora_rank, blocks), planes(eng.cfg.qk_rope_dim, blocks),
+        S((rows, 8 if fresh else eng.max_blocks), I32),
+        attn_impl="pallas" if fresh or not prefill else "xla",
+        fresh_block=fresh, gather_blocks=8 if prefill and not fresh else None).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
 @pytest.mark.slow
 def test_sharded_kernels_compile_on_2x2(tpu_devices):
     """The shard_map variants the dp x tp serving mesh traces (batch over

@@ -354,9 +354,23 @@ def test_the_head_of_a_step_closes_at_its_first_launch(first_launch):
     assert HEAD_SPAN not in t.open_spans()
     time.sleep(0.005)
     rec = t.finish(forwards=1)
+    # a sleep never returns early: the head holds at least what was slept
+    # before the first launch entered ...
     lo = {"per_slot": 13.9, "group": 13.9, "decode_dispatch": 9.9}[first_launch]
-    assert lo <= rec["head_ms"] <= lo + 8.0, rec  # not the later launch, not the chunk
-    assert rec["head_ms"] <= rec["stages"]["admit"] + rec["stages"].get("prefill", 0.0) + 1e-3
+    assert lo <= rec["head_ms"], rec
+    # ... and at most — by the ledger's OWN sums, a bound far under the
+    # parent's ``lo + 8`` ms, which a loaded machine's longer sleeps failed one
+    # run in three — the two stages less everything that follows the first
+    # launch's entry: the launches themselves, the second request's host half,
+    # the chunk. What is left over is entering and leaving the spans: 0.04-0.18
+    # ms in 60 readings under load (4.9 once: a gap between two spans)
+    adm = rec.get("admissions", [])
+    later = sum(a["prefill_call_ms"] for a in adm)
+    if first_launch == "per_slot":
+        later += adm[1]["request_ms"] - adm[1]["prefill_call_ms"]
+    stages = rec["stages"]["admit"] + rec["stages"].get("prefill", 0.0)
+    assert rec["head_ms"] <= stages - later + 1e-3, rec
+    assert rec["head_ms"] <= stages + 1e-3
     assert rec["head_cpu_ms"] <= rec["head_ms"] + 0.5 and rec["head_others_cpu_ms"] >= 0.0
     # a step that launched nothing (every admission shed) has no head
     t = log.timer()
@@ -554,11 +568,15 @@ def test_steplog_off_is_token_identical(scope_engine):
 def _assert_parts_tile(adm):
     """The parts of an admission sum to its ``sched.admit.request`` span to
     within 2 %, or to within what entering and leaving the spans themselves
-    costs (0.15 ms: on the CPU an admission of the tiny model is ~1 ms now
-    that its tail is one launch) — for the median admission, so that one
-    descheduled gap on a loaded machine does not read as untimed work."""
-    import statistics
-
+    costs (on the CPU an admission of the tiny model is ~1.5 ms now that its
+    tail is one launch) — for the admission that tiles BEST: work that no
+    part times shows in EVERY admission, a descheduled gap on a loaded machine
+    in some. Both are the ledger's own sums. What was read (three admissions a
+    run, gap of request in ms, 12 runs beside this suite under six workers):
+    the best 0.085-0.195 of 1.4-2.6, the median 0.097-0.259 of 1.4-20 (the
+    parent's median-within-0.15 fails there one run in three), the worst
+    0.18-4.3. 0.3 ms is 1.5 times the best admission's largest reading; the
+    2 % is the parent's."""
     from tpu_voice_agent.utils.steplog import ADMISSION_PARTS
 
     gaps = []
@@ -566,8 +584,8 @@ def _assert_parts_tile(adm):
         parts = sum(a.get(f"{p}_ms", 0.0) for p in ADMISSION_PARTS)
         assert parts <= a["request_ms"] + 1e-3, a
         gaps.append((a["request_ms"] - parts, a["request_ms"]))
-    gap, whole = statistics.median_low(gaps)
-    assert gap <= max(0.02 * whole, 0.15), gaps
+    gap, whole = min(gaps)
+    assert gap <= max(0.02 * whole, 0.3), gaps
 
 
 def test_admissions_tile_their_request_and_count_admitted(scope_engine):
@@ -589,8 +607,11 @@ def test_admissions_tile_their_request_and_count_admitted(scope_engine):
         assert len(s.get("admissions", [])) == s.get("admitted", 0)
         calls = sum(a["prefill_call_ms"] for a in s.get("admissions", []))
         assert calls <= s["stages"].get("prefill", 0.0) + 2e-3
-    assert sum(s["stages"].get("prefill", 0.0) for s in steps) == pytest.approx(
-        sum(r.prefill_ms for r in res), rel=0.05)
+    # ``prefill_ms`` is timed INSIDE the stage's span: the stage holds it, and
+    # it is most of the stage (0.975-0.9999 of it in 12 runs beside this suite
+    # under six workers, where "within 5 %" either way failed)
+    staged = sum(s["stages"].get("prefill", 0.0) for s in steps)
+    assert 0.8 * staged <= sum(r.prefill_ms for r in res) <= staged + 1e-3
     for a in adm:
         assert {f"{p}_ms" for p in ADMISSION_PARTS if p != "bookkeeping"} <= set(a)
         assert a["prompt_tokens"] > 0 and a["cached_tokens"] == 0 and a["rid"] >= 0
@@ -695,8 +716,16 @@ def test_a_groups_ledger_entries_carry_rows_and_sum_to_the_admit_stage(n):
 
     for a in adm:  # the parts never pass the request (how closely they tile it: the unit test above)
         assert sum(a.get(f"{p}_ms", 0.0) for p in ADMISSION_PARTS) <= a["request_ms"] + 1e-3, a
+    # by the ledger's own sums: the entries lie inside the two stages and are
+    # most of them — the rest is the loop around them and, on a busy machine,
+    # whatever gap fell between two spans: 0.94-0.97 of the stages alone,
+    # 0.66-0.99 in 48 readings beside this suite under six workers and under
+    # twelve spinning processes, where the parent's one half failed one run in
+    # three under the driver's; a quarter keeps the entries from going missing.
+    # The shares of the one or two calls lie inside the prefill stage
     whole, stages = sum(a["request_ms"] for a in adm), rec["stages"]["admit"] + rec["stages"]["prefill"]
-    assert 0.5 * stages <= whole <= stages + 1e-3  # the rest: the loop around them, on a busy machine
+    assert 0.25 * stages <= whole <= stages + 1e-3
+    assert 0.0 < sum(a["prefill_call_ms"] for a in adm) <= rec["stages"]["prefill"] + 1e-3
     bat.reset()
 
 

@@ -97,6 +97,31 @@ class LlamaConfig:
     # logits = logit_scale * N(x) embed^T (the head is the embedding)
     tie_embeddings: bool = False
     logit_scale: float = 1.0
+    # ---- LATENT attention, leading dense layers and a biased router
+    # (``deepseek_v3``: Moonlight; ``models.mla`` has the equations and the
+    # forward). ``kv_lora_rank`` > 0: a token's cache is ONE normed latent of
+    # that width and ONE rotated key of ``qk_rope_dim`` shared by every head —
+    # no K and V planes — behind absorbed attention; a query head is
+    # [``qk_nope_dim`` | ``qk_rope_dim``] wide (``head_size`` = their sum), a
+    # value head ``v_head_dim``
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # the latent's own RMSNorm is built with its default in the published
+    # implementation, not with ``rms_norm_eps``
+    latent_norm_eps: float = 1e-6
+    # the first layers run a dense SwiGLU of ``dense_ffn_dim`` where the rest
+    # run ``n_experts`` routed experts of ``ffn_dim``
+    first_dense_layers: int = 0
+    dense_ffn_dim: int = 0
+    # the experts are CHOSEN by score + a learned bias a expert and WEIGHTED
+    # by the score alone (``topk_method: noaux_tc``); the renormalised gates
+    # times ``router_scale``
+    router_bias: bool = False
+    router_scale: float = 1.0
+    # the shared experts' outputs are ADDED to the routed sum, not averaged
+    shared_sum: bool = False
 
     def __post_init__(self):
         if self.layer_types and (len(self.layer_types) != self.n_layers or
@@ -109,6 +134,16 @@ class LlamaConfig:
         if held and not self.first_expert + held <= self.n_experts:
             raise ValueError(f"experts held {self.first_expert}..{self.first_expert + held} "
                              f"of {self.n_experts}")
+        if self.kv_lora_rank and not (self.qk_nope_dim and self.qk_rope_dim and self.v_head_dim
+                                      and self.head_dim == self.qk_nope_dim + self.qk_rope_dim):
+            raise ValueError("latent attention: qk_nope_dim, qk_rope_dim, v_head_dim and a "
+                             "head_size of qk_nope_dim + qk_rope_dim")
+        if self.first_dense_layers and not (self.n_experts and self.dense_ffn_dim
+                                            and self.first_dense_layers <= self.n_layers):
+            raise ValueError("leading dense layers: a dense_ffn_dim, before routed layers")
+        if (self.first_dense_layers or self.router_bias) and not self.kv_lora_rank:
+            raise NotImplementedError("leading dense layers and a biased router are "
+                                      "models.mla's forward's: a latent model's alone")
 
     @property
     def head_dim(self) -> int:
@@ -170,10 +205,16 @@ def _hybrid(cfg) -> bool:
 
 def paged_only(cfg) -> bool:
     """A LlamaConfig whose layers ``forward_paged`` alone runs (layers of
-    more than one kind, a parallel block, a tied head): ``forward`` and its
-    dense cache refuse it, and the paged engine prefills its prompt prefix
-    through a scratch pool, as it does a hybrid model's."""
-    return bool(cfg.layer_types or cfg.parallel_block or cfg.tie_embeddings)
+    more than one kind, a parallel block, a tied head, a latent cache):
+    ``forward`` and its dense cache refuse it, and the paged engine prefills
+    its prompt prefix through a scratch pool, as it does a hybrid model's."""
+    return bool(cfg.layer_types or cfg.parallel_block or cfg.tie_embeddings or cfg.kv_lora_rank)
+
+
+def latent(cfg) -> bool:
+    """A LlamaConfig whose requests cache a latent and a shared rotated key
+    where the others cache K and V (``models.mla``)."""
+    return bool(getattr(cfg, "kv_lora_rank", 0))
 
 
 def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
@@ -182,6 +223,10 @@ def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
         from . import sambay
 
         return sambay.init_params(cfg, key, dtype)
+    if cfg.kv_lora_rank:
+        from . import mla
+
+        return mla.init_params(cfg, key, dtype)
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     d, f, hd = cfg.dim, cfg.ffn_dim, cfg.head_dim
     nq, nkv, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
@@ -305,15 +350,15 @@ def quantize_params(params: dict) -> dict:
 
     quant = quantize_leaf
 
-    L = params["layers"]
+    # matmul weights (dense w_* and stacked-expert moe_*) quantize;
+    # norms and the tiny router stay full precision
+    layers = lambda L: {k: (quant(v) if k.startswith(("w", "moe_", "shared_")) else v)
+                        for k, v in L.items()}
     return {
         "embed": params["embed"],
-        "layers": {
-            # matmul weights (dense w_* and stacked-expert moe_*) quantize;
-            # norms and the tiny router stay full precision
-            k: (quant(v) if k.startswith(("w", "moe_", "shared_")) else v)
-            for k, v in L.items()
-        },
+        "layers": layers(params["layers"]),
+        # a latent model's leading dense layers, stacked apart (models.mla)
+        **({"dense_layers": layers(params["dense_layers"])} if "dense_layers" in params else {}),
         "final_norm": params["final_norm"],
         # a tied head: an int8 copy of the embedding, a scale a vocabulary row
         "lm_head": quant(_w(params["lm_head"]) if "lm_head" in params else params["embed"].T),
@@ -434,6 +479,11 @@ def _layer_qkv(p, x, cfg: LlamaConfig, cos, sin, cs=_identity_cs,
     cfg.n_heads // tp etc; head_dim is unchanged). ``rotate`` False: a layer
     that carries no positions. ``u``: the layer's input already normed (a
     parallel block's one norm feeds the expert layer too)."""
+    if cfg.kv_lora_rank:
+        from .mla import LatentCacheOnly
+
+        raise LatentCacheOnly("q, k and v of n_heads x head_dim: a latent model projects to a "
+                              "latent and a shared key (models.mla), for forward_paged alone")
     B, T = x.shape[:2]
     nq = n_heads if n_heads is not None else cfg.n_heads
     nkv = n_kv_heads if n_kv_heads is not None else cfg.n_kv_heads
@@ -502,6 +552,18 @@ def moe_row_tile(assignments: int, n_experts: int) -> int:
 _EXPERT_LEAVES = ("moe_gate", "moe_up", "moe_down")
 
 
+def _router_kw(p, cfg) -> dict:
+    """What a router that selects by score + bias and scales its gates adds
+    to the routing call; nothing for every other model (their programs'
+    text is what it was)."""
+    kw = {}
+    if cfg.router_bias:
+        kw["bias"] = p["router_bias"]
+    if cfg.router_scale != 1.0:
+        kw["scale"] = cfg.router_scale
+    return kw
+
+
 # the leaves a layer's MLP reads: everything under ``layer/ffn`` but its norm
 _FFN_LEAVES = ("w_gate", "w_up", "w_down", "router", "shared_gate", "shared_up", "shared_down",
                *_EXPERT_LEAVES)
@@ -564,7 +626,7 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig):
     x2 = h.reshape(Tt, d)
     with jax.named_scope("router"):
         eids, gates = route_topk_flat(p["router"], x2, E, K, cfg.norm_topk,
-                                      cfg.router_fn)  # (Tt, K)
+                                      cfg.router_fn, **_router_kw(p, cfg))  # (Tt, K)
 
     with jax.named_scope("dispatch"):
         # compares and running counts over an (A, E) one-hot, no sort and
@@ -657,7 +719,7 @@ def _moe_ffn_dense(p, h, cfg: LlamaConfig):
     C = moe_capacity(B * T, cfg.n_experts, cfg.top_k, cf)
     with jax.named_scope("router"):
         dispatch, combine = route_topk(p["router"], x2, cfg.n_experts, cfg.top_k, C,
-                                       cfg.norm_topk, cfg.router_fn)
+                                       cfg.norm_topk, cfg.router_fn, **_router_kw(p, cfg))
         if cfg.experts_held:  # a chip's share: the held experts' columns alone
             held = slice(cfg.first_expert, cfg.first_expert + cfg.experts_held)
             assigned = jnp.sum(dispatch).astype(jnp.int32)
@@ -780,7 +842,10 @@ def _ffn(p, h, cfg: LlamaConfig, cs=_identity_cs):
             if cfg.n_shared_experts:
                 with jax.named_scope("shared"):  # layer/ffn/shared
                     shared = _swiglu(p, h, ("shared_gate", "shared_up", "shared_down"), cs)
-                    y = y + (shared * (1.0 / cfg.n_shared_experts)).astype(y.dtype)
+                    if cfg.shared_sum:  # the stacked SwiGLU IS their sum
+                        y = y + shared.astype(y.dtype)
+                    else:
+                        y = y + (shared * (1.0 / cfg.n_shared_experts)).astype(y.dtype)
             return y, stats
         gate = _qe("btd,df->btf", h, p["w_gate"])
         up = _qe("btd,df->btf", h, p["w_up"])
@@ -946,7 +1011,7 @@ def forward(
 @watch_compiles("llama.forward_paged")
 @partial(jax.jit, static_argnames=("cfg", "rules", "attn_impl", "fresh_block",
                                    "gather_blocks", "kv_quant", "moe_stats",
-                                   "attn_stats", "hybrid_stats", "ffn_pack"),
+                                   "attn_stats", "hybrid_stats", "ffn_pack", "latent_stats"),
          donate_argnames=("k_pool", "v_pool", "k_scale", "v_scale"))
 def forward_paged(
     params: dict,
@@ -997,6 +1062,8 @@ def forward_paged(
     # MLPs run on the block's real positions packed into P rows while they fit
     # (``packed_ffn``; a fast-forward block of 1 + W positions a row holds few
     # real ones), and ``FFN_STATS`` (2,) int32 is returned LAST
+    latent_stats: bool = False,  # a latent model only: also ``mla.LATENT_STATS``,
+    # (2,) int32, after the attention row-blocks
 ):
     """The paged twin of ``forward`` (parity-tested): sequences own
     non-contiguous pool blocks via per-row block tables (SURVEY.md §7
@@ -1034,6 +1101,19 @@ def forward_paged(
             attn_impl=attn_impl, write_mask=write_mask, trash_idx=trash_idx,
             gather_blocks=gather_blocks, n_real=n_real, logit_pos=logit_pos,
             hybrid_stats=hybrid_stats, attn_stats=attn_stats)
+    if cfg.kv_lora_rank:
+        from . import mla
+
+        if rules is not None or kv_quant is not None:
+            raise mla.LatentCacheOnly(
+                "a mesh shards, and KV_QUANT re-stores, K and V planes by head: a latent "
+                "cache has neither planes nor heads")
+        return mla.forward_paged(
+            params, cfg, tokens, positions, k_pool, v_pool, block_tables,
+            attn_impl=attn_impl, write_mask=write_mask, trash_idx=trash_idx,
+            fresh_block=fresh_block, gather_blocks=gather_blocks, n_real=n_real,
+            logit_pos=logit_pos, moe_stats=moe_stats, attn_stats=attn_stats,
+            latent_stats=latent_stats, ffn_pack=ffn_pack)
     B, T = tokens.shape
     L, N, bs = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     moe_stats = moe_stats and cfg.n_experts > 0  # a dense model has none

@@ -13,6 +13,9 @@ Here the hot ops of the in-tree models get hand-written Pallas kernels:
   sampling half of grammar-constrained decoding
 - ``selective_scan``: the state-space recurrence over per-slot float32 state
   planes, advanced in place (``models.sambay``'s recurrent layers)
+- ``paged_latent_attention``: absorbed attention over a paged LATENT cache —
+  one (block, kv_lora_rank) tile serves scores and values, every head a query
+  row (``models.mla``)
 
 Every kernel has a pure-jnp reference twin (``*_reference``) that the
 correctness tests and ``chip_smoke.py`` compare it against; kernels run
@@ -58,6 +61,12 @@ from .kvquant import (
     quantize_kv,
 )
 from .selective_scan import selective_scan, selective_scan_reference
+from .latent_attention import (
+    latent_attention_reference,
+    latent_row_splits,
+    paged_latent_attention,
+    paged_latent_attention_reference,
+)
 from .paged_attention import (
     ATTN_STATS,
     BlockSplit,
@@ -119,6 +128,10 @@ __all__ = [
     "row_group_splits",
     "BlockSplit",
     "ATTN_STATS",
+    "latent_attention_reference",
+    "latent_row_splits",
+    "paged_latent_attention",
+    "paged_latent_attention_reference",
     "paged_block_attention_quant",
     "paged_block_attention_quant_reference",
     "sharded_paged_block_attention",

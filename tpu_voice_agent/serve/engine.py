@@ -130,6 +130,8 @@ class ChunkResult:
     # row-blocks live rows attended in all; None from every other engine
     ffn: Any = None  # llama.FFN_STATS summed over the chunk, (2,) int32, from
     # a paged chunk program whose MLPs may run packed; None from every other
+    latent: Any = None  # a model with a latent cache: mla.LATENT_STATS summed
+    # over the chunk, (2,) int32; None for every other model
     ffn_rows: int = 0  # the rows an UNPACKED forward's MLPs compute (width x
     # positions a row); 0: this engine does not say
     # the spec decoder's per-row host counts; None on the plain loops
@@ -754,6 +756,15 @@ class DecodeEngine:
                 "a mesh shards K/V blocks and weights of a LlamaConfig's layout, and "
                 "speculative decoding rolls back K/V alone (overwrite-before-attend): "
                 f"neither carries the recurrent state of a {type(base).__name__}'s requests")
+        if getattr(base, "kv_lora_rank", 0) and (
+                self._alloc_dense_cache or mesh is not None
+                or (spec is not None and getattr(spec, "k", 0))):
+            from ..models.mla import LatentCacheOnly
+
+            raise LatentCacheOnly(
+                "a dense cache holds, a mesh shards and speculative decoding rolls back K and V "
+                "planes by head: a latent cache (kv_lora_rank "
+                f"{base.kv_lora_rank}) has none; PagedDecodeEngine on one device serves it")
         if base.n_experts > 0 and base.moe_impl == "auto":
             # THE dispatch choice of a routed model, made once, here, from
             # where the engine runs: the grouped-matmul kernel on a single

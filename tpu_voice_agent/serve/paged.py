@@ -39,7 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..grammar.fsm import fsm_advance
-from ..models.llama import FFN_STATS, _hybrid, forward_paged, moe_stat_names, paged_only
+from ..models.llama import (FFN_STATS, _hybrid, forward_paged, latent, moe_stat_names,
+                            paged_only)
 from ..utils.compilewatch import get_compile_watcher, watch_compiles
 from ..utils.steplog import (
     ALLOC_SPAN,
@@ -285,6 +286,7 @@ def record_pool_gauges(alloc: "BlockAllocator", engine=None) -> None:
         bpb = engine.kv_bytes_per_block
         m.set_gauge("paged.kv_quant_bits", float(engine.kv_quant_bits))
         m.set_gauge("paged.kv_bytes_per_block", float(bpb))
+        m.set_gauge("paged.kv_bytes_per_token", float(bpb // engine.block_size))
         m.set_gauge("paged.kv_bytes_used", float(alloc.blocks_in_use * bpb))
         m.set_gauge("paged.kv_bytes_total", float(alloc.usable_blocks * bpb))
 
@@ -311,6 +313,11 @@ def _scatter_blocks(k_pool, v_pool, src_k, src_v, dst_idx):
         # models.sambay.forward_paged), 13 ms a call (my chip run, PR 32)
         at = (slice(None), dst_idx // bs, dst_idx % bs)
         return ({**k_pool, "kv": kp.at[at].set(src_k)}, {**v_pool, "kv": vp.at[at].set(src_v)})
+    if kp.ndim == 4:
+        # a latent cache's two planes, (L, N, bs, width) each of its own
+        # width (models.mla): written as they are shaped, likewise
+        at = (slice(None), dst_idx // bs, dst_idx % bs)
+        return kp.at[at].set(src_k), vp.at[at].set(src_v)
     kf = kp.reshape(L, N * bs, *shp[3:])
     vf = vp.reshape(L, N * bs, *shp[3:])
     kf = kf.at[:, dst_idx].set(src_k)
@@ -521,14 +528,21 @@ def paged_chunk_decode_loop(
     # always, last; in the routed variant the expert rows before them (for a
     # dense model that carry and output do not exist: tests/test_olmoe.py)
     routed = cfg.n_experts > 0
+    # a model with a LATENT cache (``models.mla``; static, on its
+    # configuration) compiles a variant too: one more carry and output after
+    # the attention row-blocks, ``mla.LATENT_STATS`` summed over the chunk
+    lat = latent(cfg)
     count_kw = {"attn_stats": True, **({"moe_stats": True} if routed else {}),
-                **({"hybrid_stats": True} if hybrid else {})}
+                **({"hybrid_stats": True} if hybrid else {}),
+                **({"latent_stats": True} if lat else {})}
     counts0 = (((jnp.zeros((len(moe_stat_names(cfg)) if routed else 4,), jnp.int32),)
-                if routed or hybrid else ()) + (jnp.zeros((2,), jnp.int32),))
+                if routed or hybrid else ()) + (jnp.zeros((2,), jnp.int32),)
+               + ((jnp.zeros((2,), jnp.int32),) if lat else ()))
     # the head on the ONE position a row of a 1 + W block reads: the hybrid
-    # model, and a LlamaConfig with layers of more than one kind (the others'
-    # programs compute it on all 1 + W, as they always have)
-    one_head = hybrid or bool(cfg.layer_types)
+    # model, a LlamaConfig with layers of more than one kind and one with a
+    # latent cache (the others' programs compute it on all 1 + W, as they
+    # always have)
+    one_head = hybrid or bool(cfg.layer_types) or lat
     # a fast-forward block holds 1 + k real positions a live row and copies
     # of the last one behind them: the forward is told, and its MLPs compute
     # the real ones packed into ``ffn_pack`` rows while they fit
@@ -771,6 +785,7 @@ class PagedDecodeEngine(DecodeEngine):
         # heads) and what a SLOT holds beside its blocks (there: a convolution
         # tail and a float32 state for each recurrent layer; here: nothing)
         self.hybrid = _hybrid(self.cfg)
+        self.latent = latent(self.cfg)
         if self.hybrid:
             # ``sambay.forward_paged`` has no packed MLP (ROADMAP S3 (e) has
             # what it waits for)
@@ -787,6 +802,20 @@ class PagedDecodeEngine(DecodeEngine):
         else:
             self._cache_spec = {"kv_layers": self.cfg.n_layers, "kv_heads": self.cfg.n_kv_heads,
                                 "kv_head_dim": self.cfg.head_dim}
+        # a LATENT cache (models.mla): no K and V planes, no heads — a latent
+        # and ONE rotated key a token a layer, two planes of their own widths
+        if self.latent:
+            from ..models.mla import cache_spec
+
+            if radix_enable is None:
+                radix_enable = os.environ.get("RADIX_ENABLE") == "1"
+            if kv_quant:
+                self._refuse_blocks_alone("KV_QUANT re-stores K/V blocks")
+            if radix_enable:
+                self._refuse_blocks_alone("radix reuse hands a slot cached K/V blocks")
+            if self.mesh is not None or self._spec_cfg is not None:
+                self._refuse_blocks_alone("a mesh shards K/V heads, a verify step rolls K/V back")
+            self._cache_spec = cache_spec(self.cfg)
         if pool_blocks is None:
             # default: same worst case as dense, plus each group's trash block
             pool_blocks = self.batch_slots * self.max_blocks + self.dp
@@ -796,12 +825,17 @@ class PagedDecodeEngine(DecodeEngine):
                 f"axis ({self.dp}): each dp group owns its own block range")
         from ..ops.kvquant import kv_store_dim, kv_store_dtype
 
-        L, nkv, hd = (self._cache_spec[k] for k in ("kv_layers", "kv_heads", "kv_head_dim"))
+        L, nkv, hd = (self._cache_spec.get(k, 0) for k in ("kv_layers", "kv_heads", "kv_head_dim"))
         hdp = kv_store_dim(hd, kv_quant)
         dtype = kv_store_dtype(kv_quant)
         shape = (L, pool_blocks, bs, nkv, hdp)
         sshape = (L, pool_blocks, bs, nkv)
-        if self.mesh is not None:
+        if self.latent:  # ``bs`` second-minor: a heads axis of one would pad every position sixteenfold
+            self.k_pool, self.v_pool = (
+                jnp.zeros((L, pool_blocks, bs, self._cache_spec[w]), jnp.bfloat16)
+                for w in ("latent_dim", "rope_dim"))
+            self.k_scale = self.v_scale = None
+        elif self.mesh is not None:
             from ..parallel.mesh import paged_pool_shardings, paged_scale_shardings
 
             sh = paged_pool_shardings(self.mesh, nkv)
@@ -915,6 +949,9 @@ class PagedDecodeEngine(DecodeEngine):
         from ..ops.kvquant import kv_block_bytes
 
         spec = self._cache_spec
+        if self.latent:  # the two planes' widths, bf16
+            return (spec["kv_layers"] * self.block_size
+                    * (spec["latent_dim"] + spec["rope_dim"]) * 2)
         return kv_block_bytes(spec["kv_layers"], self.block_size, spec["kv_heads"],
                               spec["kv_head_dim"], self.kv_quant)
 
@@ -948,21 +985,22 @@ class PagedDecodeEngine(DecodeEngine):
 
             spec = cache_spec(self.cfg, 1)
         nb = -(-bucket // bs)
-        shape = (spec["kv_layers"], nb + 1, bs, spec["kv_heads"], spec["kv_head_dim"])
-        planes = lambda: jnp.zeros(shape, kv_planes(self.k_pool).dtype)
+        # the scratch planes: the pool's own, at the bucket's blocks and one
+        planes = lambda pool: jnp.zeros(
+            (spec["kv_layers"], nb + 1, *kv_planes(pool).shape[2:]), kv_planes(pool).dtype)
         table = jnp.asarray([list(range(1, nb + 1)) + [0] * self.hybrid], jnp.int32)
         if self.hybrid:  # block 0: parked writes; the table's last column: the slot
-            pools = ({"kv": planes(), "conv": jnp.zeros(*spec["conv"])},
-                     {"kv": planes(), "ssm": jnp.zeros(*spec["ssm"])})
+            pools = ({"kv": planes(self.k_pool), "conv": jnp.zeros(*spec["conv"])},
+                     {"kv": planes(self.v_pool), "ssm": jnp.zeros(*spec["ssm"])})
             kw = {"n_real": jnp.asarray([P], jnp.int32)}
-        else:
-            pools, kw = (planes(), planes()), {}
+        else:  # (a latent cache's second plane has a width of its own)
+            pools, kw = (planes(self.k_pool), planes(self.v_pool)), {}
         _, k, v, _, _ = forward_paged(self.params, self.cfg, tokens, positions, *pools, table,
                                       attn_impl=self.kernels, fresh_block=True, **kw)
         if self.hybrid:
             self._prefix_state = {"conv": k["conv"][:, 0], "ssm": v["ssm"][:, 0]}
         dense = lambda pool: kv_planes(pool)[:, 1:].reshape(
-            shape[0], 1, nb * bs, *shape[3:])[:, :, :P]
+            spec["kv_layers"], 1, nb * bs, *kv_planes(pool).shape[3:])[:, :, :P]
         return {"k": dense(k), "v": dense(v)}
 
     def _restore_slot_state(self, slot: int, snapshot: dict | None) -> None:
@@ -1701,16 +1739,21 @@ class PagedDecodeEngine(DecodeEngine):
                     **compact,
                 )
             )
+        # what the program counted, from the back: a packed MLP's rows, a
+        # latent cache's reads, the attention row-blocks (always), and before
+        # them a routed model's expert rows or a hybrid one's state counts
+        counts = list(counts)
+        ffn = counts.pop() if packs else None
+        latent_counts = counts.pop() if self.latent else None
+        attn = counts.pop()
+        first = counts.pop() if counts else None
         return ChunkResult(
             out, n, eos, cur, pos, fsm, active, nbytes, left,
             fwds=fwds, poison=pois,
             rows=self.batch_slots if rows is None else len(rows),
             conf=conf if self.quality_lanes else None,
-            # a routed model's expert-row counts, a hybrid one's state and window counts
-            moe=counts[0] if len(counts) > 1 + packs and not self.hybrid else None,
-            hybrid=counts[0] if self.hybrid else None,
-            attn=counts[-1 - packs],  # the attention row-blocks, common and all
-            ffn=counts[-1] if packs else None, ffn_rows=width)
+            moe=None if self.hybrid else first, hybrid=first if self.hybrid else None,
+            attn=attn, latent=latent_counts, ffn=ffn, ffn_rows=width)
 
     def spec_grow(self, span: int, active=None) -> list[int]:
         """Claim block coverage for one speculative verify step (cur + K
@@ -1872,6 +1915,12 @@ class PagedDecodeEngine(DecodeEngine):
         return blocks
 
     def _refuse_blocks_alone(self, what: str) -> None:
+        if self.latent:
+            from ..models.mla import LatentCacheOnly
+
+            raise LatentCacheOnly(
+                f"{what}, K and V planes by head: a latent cache has none "
+                f"(kv_lora_rank {self.cfg.kv_lora_rank})")
         if self.hybrid:
             from ..models.sambay import StateNotCarried
 

@@ -1301,9 +1301,11 @@ class ContinuousBatcher:
         # ``fwds`` keeps tokens-per-forward truthful under multi-token steps
         # (counting dispatches as tokens would inflate every throughput
         # gauge); ``poison`` is the quarantine's per-row fault codes below.
-        out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h, moe_h, attn_h, hybrid_h, ffn_h = (
+        (out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h, moe_h, attn_h, hybrid_h, ffn_h,
+         latent_h) = (
             jax.device_get((res.out, res.n, res.active, res.eos, res.pos, res.fwds,
-                            res.poison, res.conf, res.moe, res.attn, res.hybrid, res.ffn)))
+                            res.poison, res.conf, res.moe, res.attn, res.hybrid, res.ffn,
+                            res.latent)))
         out_h, n_h, act_h, eos_h, pos_h, pois_h = (
             np.asarray(x) for x in (out_h, n_h, act_h, eos_h, pos_h, pois_h))
         fwds_h, rows = int(fwds_h), res.rows
@@ -1345,6 +1347,12 @@ class ContinuousBatcher:
             # common pass took is the first over the second
             for name, v in zip(ATTN_STATS, np.asarray(attn_h)):
                 m.inc(f"attn.{name}", float(v))
+        if latent_h is not None:
+            # a latent cache: cached positions attention read (a common block
+            # once) and query rows x heads it served (``mla.LATENT_STATS``)
+            keys, qrows = (float(v) for v in np.asarray(latent_h))
+            m.inc("attn.latent_keys_read", keys)
+            m.inc("attn.latent_query_rows", qrows)
         if ffn_h is not None:
             # forwards whose MLPs ran on the real positions packed, and the
             # rows the MLPs computed (``llama.FFN_STATS``, in its order)
