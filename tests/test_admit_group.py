@@ -50,15 +50,17 @@ def _engine(model: str) -> PagedDecodeEngine:
         eng = PagedDecodeEngine(cfg=cfg, tokenizer=default_tokenizer(), quant="int8",
                                 init_weights=False, **kw)
         eng.load_params(sambay_stack.make_params(eng.cfg, 23))
-    else:  # "share": layers of two kinds, a parallel block, held experts, a tied head
+    else:  # "share": layers of two kinds, a parallel block, held experts, a tied head;
+        # "latent": a latent cache, leading dense layers — each at its file's rehearsal widths
         import json
         from pathlib import Path
 
-        from benchmark.builders import cohere2moe_stack, parse_stack
+        from benchmark.builders import cohere2moe_stack, moonlight_stack, parse_stack
 
-        conf = json.loads((Path(__file__).parents[1]
-                           / "benchmark/configs/command-a-plus-05-2026-int8.json").read_text())
-        cfg = cohere2moe_stack.llama_config(*parse_stack.as_run(conf, True))
+        name, stack = {"share": ("command-a-plus-05-2026-int8", cohere2moe_stack),
+                       "latent": ("moonlight-16b-a3b-int8", moonlight_stack)}[model]
+        conf = json.loads((Path(__file__).parents[1] / f"benchmark/configs/{name}.json").read_text())
+        cfg = stack.llama_config(*parse_stack.as_run(conf, True))
         eng = PagedDecodeEngine(cfg=dataclasses.replace(cfg, max_seq_len=1536), quant=None, **kw)
     install_prompt_prefix(eng)
     return eng
@@ -266,22 +268,41 @@ def _chunk_program_shas(eng) -> list[str]:
 # model with a LATENT cache compiles a variant of its own
 # (``paged_chunk_decode_loop``'s ``lat``), and every other model's program
 # is the text it was. A PR that changes the chunk loop on purpose re-derives
-# them (``_chunk_program_shas`` on its parent's tree) and says so.
+# them (``_chunk_program_shas`` on its parent's tree) and says so. ISSUE 41
+# did, for the FULL width of the dense, routed and parallel-block kinds alone
+# (both position-wise regions of a layer run packed there, two conditionals a
+# layer in ``llama.forward_paged``); the compacted width (72 positions <= 96), the
+# hybrid's both widths and the latent model's both (taken on ISSUE 41's
+# parent, commit 4349cdf: it packs its MLPs alone, ``llama.packed_ffn`` on the
+# ``FfnPack`` the other models' two regions share) are unedited.
 CHUNK_SHA256 = {
-    "dense": ["e2c0fe672ca2f731ec63ce973b1e92dd275d3e064e6981e53cd5592fea4394a7",
+    "dense": ["8951780868d2d09f00e8f4257810c6d572d269e5bdee3f275b18d326bba3efd7",
               "a83a1246db842dd9278de4755325c77eab35845297b5216c5ef486791938abaa"],
-    "routed": ["96e6b871f414bb4e3b946652d053b70cc8919c3c963b40ac2d72c51ea1529ab5",
+    "routed": ["e5ea58bb8ee327cb1d68896070945d339e380e1f332944e244204bb5403944bc",
                "09487a9d4027ee5490ba7d0e2544442000c57ee25acce4c9fc2fa995ff695c73"],
     "hybrid": ["8a84063c101aa0386efdc89fb1599cba13fb411edfce7fe4b42fda5421b4c500",
                "234168ec7de640450f7aa42f1701430ed236e1539a16d62dc2046323d7e095fb"],
-    "share": ["8f8d98e57314b281309e0314141f852e071346bc942d3c1529f5f1fcc90956e0",
+    "share": ["4aea2b264d78c130ac91ab51b91ab8d1abdc2b5312848381596fd56d592761d4",
               "3aa59c4590c50e4b16c1693e05a42ecc9cc7c564820b6d869e1761f558e9c495"],
+    "latent": ["64a86c324bda9f11af6988b7b6cbee575cff285b76427c46600ee44a1c3be96f",
+               "e95bd6aebfe18613acd90f0196c29b679462a7fb2ed33089c7942d76cfefa65a"],
 }
 
 
 def test_the_chunk_programs_are_the_parents(pair):
     model, one, _ = pair
     assert _chunk_program_shas(one) == CHUNK_SHA256[model]
+
+
+def test_the_latent_chunk_programs_are_the_parents():
+    """A model with a latent cache (``models/mla.py``: the benchmark's
+    ``moonlight-16b-a3b-int8`` at its rehearsal widths) packs its MLPs alone
+    (``llama.packed_ffn``), on the ``FfnPack`` the other models pack both
+    regions with: at 32 slots its full-width chunk program holds that branch,
+    and both widths lower to the text ISSUE 41's parent lowers."""
+    eng = _engine("latent")
+    assert eng.latent and eng.compact_rows * 9 <= eng.ffn_pack_rows < SLOTS * 9
+    assert _chunk_program_shas(eng) == CHUNK_SHA256["latent"]
 
 
 @pytest.mark.parametrize("model", ["dense", "hybrid"])

@@ -279,6 +279,11 @@ def test_paged_block_attention_common_pass_compiles_at_the_cells_shapes(tpu_devi
              ((B,), jnp.bool_), interpret=False)
 
 
+def _conditionals(hlo: str) -> int:
+    """``conditional`` instructions of a compiled program's text."""
+    return hlo.count(" conditional(")
+
+
 def _chunk_program_at_published_widths(tpu_devices, monkeypatch, config, compacted: bool):
     """The whole decode chunk at its COMPACTED width (ISSUE 29: 8 of 32 slots'
     rows, gathered and scattered back inside the program) or at its full one
@@ -340,13 +345,14 @@ def test_the_compacted_chunk_program_compiles_at_published_widths(tpu_devices, m
 @pytest.mark.parametrize("config", ["mistral-7b-v0.1-int8",
                                     pytest.param("olmoe-1b-7b-0125-int8", marks=pytest.mark.slow)])
 def test_the_packed_chunk_program_compiles_at_published_widths(tpu_devices, monkeypatch, config):
-    """The FULL-width chunk program with its MLPs on the real positions
-    (ISSUE 37): 32 x 9 positions packed into ``ffn_pack_rows`` = 96 rows, one
-    conditional a layer between the packed MLP and the whole one — for the
-    routed model the grouped kernel in BOTH branches, at both row tiles."""
+    """The FULL-width chunk program with its position-wise regions on the
+    real positions (ISSUES 37, 41): 32 x 9 positions packed into
+    ``ffn_pack_rows`` = 96 rows, two conditionals in the layer scan's body —
+    q/k/v, and the output projection with the MLP — for the routed model the
+    grouped kernel in BOTH branches of the second, at both row tiles."""
     text, eng, routed = _chunk_program_at_published_widths(tpu_devices, monkeypatch, config, False)
     B, P = eng.batch_slots, eng.ffn_pack_rows
-    assert (B, P) == (32, 96) and "conditional" in text
+    assert (B, P) == (32, 96) and _conditionals(text) == 2
     assert text.count("tpu_custom_call") == (7 if routed else 1)  # block attention (+ 3 a branch)
     assert f"bf16[{B},9," in text and f"bf16[{P}," in text  # both branches
 
@@ -513,7 +519,7 @@ def _cmdaplus_engine(monkeypatch, **serving):
 
 
 @pytest.mark.parametrize("width", [pytest.param("full", marks=pytest.mark.slow), "compact", "window",  # the chip runs "full" in every check
-                                   pytest.param("packed", marks=pytest.mark.slow)])  # ... since ISSUE 37 "packed"
+                                   "packed"])  # ... since ISSUE 37 "packed"; since ISSUE 41 it holds the count of conditionals
 def test_the_command_a_plus_chunk_program_compiles_at_published_widths(tpu_devices, monkeypatch, width):
     """Command A+'s decode chunk as ``cmdaplus_flood`` serves it — 8 parallel
     blocks at published widths, int8 weights, 16 held experts through the
@@ -535,7 +541,7 @@ def test_the_command_a_plus_chunk_program_compiles_at_published_widths(tpu_devic
     shapes = lambda tree: jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), tree)
     pool = S((cfg.n_layers, s["pool_blocks"], eng.block_size, cfg.n_kv_heads, cfg.head_dim), BF16)
     rows = {"rows_idx": S((R,), I32)} if width == "compact" else {}
-    if width == "packed":  # shared and routed experts on the real positions, 96 rows (ISSUE 37)
+    if width == "packed":  # both regions of a layer on the real positions, 96 rows (ISSUES 37, 41)
         rows = {"ffn_pack": eng.ffn_pack_rows}
     compiled = paged.paged_chunk_decode_loop.__wrapped__.lower(
         shapes(params), cfg, pool, pool,
@@ -551,7 +557,9 @@ def test_the_command_a_plus_chunk_program_compiles_at_published_widths(tpu_devic
     # 16 rows at the full width) and gate, up, down for each
     # ("packed": the three expert calls in each branch of a layer's conditional)
     assert text.count("tpu_custom_call") == 8 * ({"packed": 6}.get(width, 3) + (1 if n == R or bound else 2))
-    assert ("conditional" in text) == (width == "packed")
+    # the layers run UNROLLED: a conditional a layer is 8 x its text in every
+    # executable that holds it, and a warm start loads them all (ROADMAP S11)
+    assert _conditionals(text) == (8 * 2 if width == "packed" else 0)
     # the head runs on one position a row
     assert f"f32[{n},32768]" in text and f"{n},9,32768]" not in text and f"[{9 * n},32768]" not in text
 

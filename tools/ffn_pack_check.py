@@ -1,10 +1,10 @@
 #!/usr/bin/env python3
-"""The packed MLP of a fast-forward block against the whole one, on the chip
-at full width: the numbers, what each costs, and how often it would engage.
+"""The packed regions of a fast-forward block against the whole ones, on the
+chip at full width: the numbers, what each costs, and how often it would engage.
 
 ``correct``'s comparison with the plain references reaches ``forward_paged``
 without ``n_real`` (``benchmark/lib/refcheck.py``), so it never samples the
-branch ISSUE 37 adds. This does, two ways.
+branches ISSUES 37 and 41 add. This does, two ways.
 
 ``--config NAME``: one configuration of the benchmark built as its builder
 builds it (published widths, its seeded int8 weights, the 200-block pool), and
@@ -12,23 +12,22 @@ builds it (published widths, its seeded int8 weights, the 200-block pool), and
 ``ff_body`` makes them — a seeded ``n_real`` a row (idle rows, rows of k = 0,
 chains up to W), the positions behind it copies of the row's last real one,
 over a pool of seeded K/V — through ``forward_paged`` whole and with
-``ffn_pack`` = the engine's ``ffn_pack_rows``: the largest difference of the
-real positions' logits and of the K/V they wrote, each as a share of the
-whole path's largest value (``refcheck._rel_err``'s measure), the rows whose
-top-1 agrees, held against ``LIMIT`` (exit code 1 over it). Two readings
-bracket the limit. BELOW it, the packed branch itself, and what it is made of:
-the whole MLP over the block FLATTENED to (1, B x T, d) rows (no index, no
-gather, no conditional: the packed branch's operand shape alone) against the
-whole MLP as served, and the same with its input and output HELD in buffers
-(``optimization_barrier``: no fusion across them, as around a gather). On the
-chip the first equals the served MLP and the second equals the packed branch,
-bit for bit (PERF.md section 6, PR 37): what the packed branch differs by is
-the fusion of the down projection's rounding with the residual add, which the
-served MLP has and a buffer forbids. ABOVE it, a control the limit must
-refuse: the whole MLP with its weights rounded to int4.
+``ffn_pack`` = the engine's ``ffn_pack_rows``, which packs BOTH position-wise
+regions of every layer (norm, q/k/v; output projection, residuals, MLP:
+``llama.FfnPack``): the largest difference of the real positions'
+logits and of the K/V they wrote, each as a share of the whole path's largest
+value (``refcheck._rel_err``'s measure), the rows whose top-1 agrees, held
+against ``LIMIT`` (exit code 1 over it). Two readings bracket the limit. BELOW
+it, the packed branches themselves: what they differ by is what XLA may fuse
+across a region's edge — a projection's rounding with the residual add, a
+norm with the layer before it — which the served whole path has and a packed
+branch's buffers forbid, and the dots' tiling at another row count (PERF.md
+section 6, PRs 37 and 41). ABOVE it, a control the limit must refuse: the whole
+path with the layers' planes — q/k/v, the output projection, the MLPs —
+rounded to int4.
 With ``--rows``, the wall of the forward (first launch to the pools' last
 write, median) whole and packed at each of them (every one holds the same real
-positions: the differences are the MLPs'), and of the layers' MLPs ALONE
+positions: the differences are the regions'), and of the layers' MLPs ALONE
 (``llama._ffn`` scanned over the stacked weights) at each of those row counts
 and at the block's: the microbenchmark ``ffn_pack_rows`` was picked from.
 
@@ -57,7 +56,6 @@ import statistics
 import sys
 import time
 from functools import partial
-from unittest import mock
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -97,28 +95,26 @@ def block(eng, n_real, rng, pool_blocks: int, layout_seed: int):
     return tokens.astype(np.int32), positions.astype(np.int32), tables, live
 
 
-# What the packed branch may differ from the whole MLP by, as a share of a
+# What the packed branches may differ from the whole regions by, as a share of a
 # row's largest logit (``refcheck._rel_err``). Between two readings (PERF.md
-# section 6, PR 37, has them by configuration): the packed branch itself, which
-# reads 3.9-4.0 % (Mistral), 0.7-5.3 % (Command A+), 0 (OLMoE) over four seeds —
-# bit for bit what the whole MLP reads once its output is a buffer and not fused
-# into the residual add — and the whole MLP with its weights rounded to int4,
-# the nearest precision below, which reads 75-186 %
+# section 6, PRs 37 and 41, have them by configuration): the packed branches
+# themselves — the MLP alone read 3.9-4.0 % (Mistral), 0.7-5.3 % (Command A+), 0
+# (OLMoE) over four seeds, bit for bit what the whole MLP reads once its output
+# is a buffer and not fused into the residual add — and the whole path with its
+# planes rounded to int4, the nearest precision below (the MLPs' alone: 75-186 %)
 LIMIT = 0.12
 
 
 def to_int4(layers: dict) -> dict:
-    """The MLPs' int8 planes rounded to 16 levels IN PLACE (donated), their
+    """The layers' int8 planes rounded to 16 levels IN PLACE (donated), their
     scales kept: the control ``LIMIT`` has to refuse."""
     import jax
     import jax.numpy as jnp
 
-    from tpu_voice_agent.models import llama
-
     round4 = jax.jit(lambda q: (jnp.clip((q.astype(jnp.int16) + 8) >> 4, -8, 7) << 4).astype(jnp.int8),
                      donate_argnums=0)
-    return {k: {**v, "q": round4(v["q"])} if k in llama._FFN_LEAVES and isinstance(v, dict) and "q" in v
-            else v for k, v in layers.items()}
+    return {k: {**v, "q": round4(v["q"])} if isinstance(v, dict) and "q" in v else v
+            for k, v in layers.items()}
 
 
 def check_config(args) -> int:
@@ -185,18 +181,6 @@ def check_config(args) -> int:
             logits = logits[self.live, 0] if one_head else logits[self.real]
             return logits, [np.asarray(p[:, self.at[0], self.at[1]], np.float32) for p in pools]
 
-    def flat_ffn(ffn, h, pack, held: bool = False):
-        """In ``llama.packed_ffn``'s place: the WHOLE block as (1, B * T, d)
-        rows — no index, no gather, no conditional, the packed branch's 2-D
-        operand shape alone. ``held``: the MLP's input and output are BUFFERS,
-        as a gather's operand and result are — no fusion reaches across them,
-        so the residual is added to a y rounded to bfloat16 as written."""
-        if held:
-            h = jax.lax.optimization_barrier(h)
-        y, stats = ffn(h.reshape(1, -1, h.shape[-1]))
-        y = y.reshape(h.shape)
-        return (jax.lax.optimization_barrier(y) if held else y), stats
-
     rel_of = lambda got, want: refcheck._rel_err(got, want)[0]
     blocks, readings = [Block(args.seed + i) for i in range(args.seeds)], []
     for blk in blocks:
@@ -204,17 +188,11 @@ def check_config(args) -> int:
         out = blk.forward(**blk.packed_kw, ffn_pack=P)
         assert np.asarray(out[-1]).tolist() == [1, P], "the seeded block fits: the packed branch ran"
         got, got_kv = blk.left(out)
-        with mock.patch.object(llama, "packed_ffn", flat_ffn):  # a width no call traced: this trace takes the patch
-            flat, _ = blk.left(blk.forward(**blk.packed_kw, ffn_pack=B * T - 1))
-        with mock.patch.object(llama, "packed_ffn", partial(flat_ffn, held=True)):
-            held, _ = blk.left(blk.forward(**blk.packed_kw, ffn_pack=B * T - 2))
         rel, top1 = refcheck._rel_err(got, blk.want)
         readings.append({
             "seed": args.seed + len(readings), "real_positions": int(blk.n_real.sum()),
             "packed_vs_whole": rel, "top1_agree": [top1, len(blk.want)],
-            "kv_written": max(float(np.max(np.abs(g - w)) / np.max(np.abs(w))) for g, w in zip(got_kv, want_kv)),
-            "whole_flat_vs_whole": rel_of(flat, blk.want), "packed_vs_whole_flat": rel_of(got, flat),
-            "whole_flat_held_vs_whole": rel_of(held, blk.want), "packed_vs_whole_flat_held": rel_of(got, held)})
+            "kv_written": max(float(np.max(np.abs(g - w)) / np.max(np.abs(w))) for g, w in zip(got_kv, want_kv))})
         say(f"PACKED vs WHOLE, ({B}, {T}) block, sum(n_real) {int(blk.n_real.sum())} into {P} rows: {readings[-1]}")
 
     def wall(fn) -> float:
@@ -229,7 +207,7 @@ def check_config(args) -> int:
 
     timings, vs_whole, blk = {}, {}, blocks[0]
     if len(widths) > 1:
-        # every width holds the same real positions: the differences are the MLPs'
+        # every width holds the same real positions: the differences are the regions'
         timings["forward_whole_ms"] = wall(lambda: blk.forward(**blk.kw))
         for rows in widths:
             timings[f"forward_packed_{rows}_ms"] = wall(lambda: blk.forward(**blk.packed_kw, ffn_pack=rows))
@@ -253,12 +231,12 @@ def check_config(args) -> int:
             timings[f"mlps_alone_{rows}_rows_ms"] = wall(
                 lambda: mlps(params["layers"], jax.random.PRNGKey(rows), rows))
         say("ms, median of %d: %s" % (args.repeat, ", ".join(f"{k[:-3]} {v:.2f}" for k, v in timings.items())))
-    # the control, last (it rewrites the weights): the whole MLP at int4
+    # the control, last (it rewrites the weights): the whole path, the layers' planes at int4
     params = eng.params = {**params, "layers": to_int4(params["layers"])}
     control = [rel_of(blk.left(blk.forward(**blk.kw))[0], blk.want) for blk in blocks]
     worst = max(r["packed_vs_whole"] for r in readings)
     ok = worst < LIMIT < min(control)
-    say(f"packed against whole, worst of {len(readings)} seeds {worst:.6f}; the whole MLP at int4 against "
+    say(f"packed against whole, worst of {len(readings)} seeds {worst:.6f}; the whole path at int4 against "
         f"itself at int8 {[round(c, 6) for c in control]}; LIMIT {LIMIT}: {'PASS' if ok else 'FAIL'}")
     line = {"config": args.config, "block": [B, T], "ffn_pack_rows": P, "limit": LIMIT, "pass": ok,
             "readings": readings, "int4_control_vs_whole": control, "widths_vs_whole": vs_whole,

@@ -467,24 +467,17 @@ def _identity_cs(x, name):
     return x
 
 
-def _layer_qkv(p, x, cfg: LlamaConfig, cos, sin, cs=_identity_cs,
-               n_heads: int | None = None, n_kv_heads: int | None = None,
-               rotate: bool = True, u=None):
-    """Shared decoder-layer front half: attn-norm -> q/k/v projections ->
-    head reshape -> RoPE. The ONE copy of this math for forward /
-    forward_paged / pipeline / longctx (they differ only in how KV is
-    written and attended, never in the projections). ``n_heads`` /
-    ``n_kv_heads`` override the config's counts for tensor-parallel LOCAL
-    shards inside shard_map (pipeline.pp_tp_forward_cached passes
-    cfg.n_heads // tp etc; head_dim is unchanged). ``rotate`` False: a layer
-    that carries no positions. ``u``: the layer's input already normed (a
-    parallel block's one norm feeds the expert layer too)."""
+def _project_qkv(p, x, cfg: LlamaConfig, cs=_identity_cs, n_heads: int | None = None,
+                 n_kv_heads: int | None = None, u=None):
+    """A layer's attn-norm -> q/k/v projections (-> q/k norm), each still
+    (B, T, heads * head_dim): the position-wise part of the front half, which
+    asks nothing of B and T (``forward_paged`` runs it on a block's real
+    positions, packed). ``_layer_qkv`` has the arguments."""
     if cfg.kv_lora_rank:
         from .mla import LatentCacheOnly
 
         raise LatentCacheOnly("q, k and v of n_heads x head_dim: a latent model projects to a "
                               "latent and a shared key (models.mla), for forward_paged alone")
-    B, T = x.shape[:2]
     nq = n_heads if n_heads is not None else cfg.n_heads
     nkv = n_kv_heads if n_kv_heads is not None else cfg.n_kv_heads
     with jax.named_scope("layer/attn_qkv"):
@@ -501,13 +494,37 @@ def _layer_qkv(p, x, cfg: LlamaConfig, cos, sin, cs=_identity_cs,
             with jax.named_scope("qk_norm"):
                 q = rms_norm(q, p["q_norm"], cfg.norm_eps)
                 k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-        q = cs(q.reshape(B, T, nq, cfg.head_dim), "heads")
-        k = cs(k.reshape(B, T, nkv, cfg.head_dim), "kv_heads")
-        v = cs(v.reshape(B, T, nkv, cfg.head_dim), "kv_heads")
+    return q, k, v
+
+
+def _rotate_heads(q, k, v, cfg: LlamaConfig, cos, sin, cs=_identity_cs, rotate: bool = True):
+    """Flat q/k/v (B, T, heads * head_dim) -> the (B, T, heads, head_dim) the
+    attention calls and the K/V write keep, q and k rotated."""
+    B, T = q.shape[:2]
+    with jax.named_scope("layer/attn_qkv"):
+        q = cs(q.reshape(B, T, -1, cfg.head_dim), "heads")
+        k = cs(k.reshape(B, T, -1, cfg.head_dim), "kv_heads")
+        v = cs(v.reshape(B, T, -1, cfg.head_dim), "kv_heads")
         if not rotate:
             return q, k, v
         rope = apply_rope_interleaved if cfg.rope_interleaved else apply_rope
         return rope(q, cos, sin), rope(k, cos, sin), v
+
+
+def _layer_qkv(p, x, cfg: LlamaConfig, cos, sin, cs=_identity_cs,
+               n_heads: int | None = None, n_kv_heads: int | None = None,
+               rotate: bool = True, u=None):
+    """Shared decoder-layer front half: attn-norm -> q/k/v projections ->
+    head reshape -> RoPE. The ONE copy of this math for forward /
+    forward_paged / pipeline / longctx (they differ only in how KV is
+    written and attended, never in the projections). ``n_heads`` /
+    ``n_kv_heads`` override the config's counts for tensor-parallel LOCAL
+    shards inside shard_map (pipeline.pp_tp_forward_cached passes
+    cfg.n_heads // tp etc; head_dim is unchanged). ``rotate`` False: a layer
+    that carries no positions. ``u``: the layer's input already normed (a
+    parallel block's one norm feeds the expert layer too)."""
+    q, k, v = _project_qkv(p, x, cfg, cs, n_heads, n_kv_heads, u)
+    return _rotate_heads(q, k, v, cfg, cos, sin, cs, rotate)
 
 
 # what a routed forward counts (summed over layers by the forwards, over
@@ -569,7 +586,8 @@ _FFN_LEAVES = ("w_gate", "w_up", "w_down", "router", "shared_gate", "shared_up",
                *_EXPERT_LEAVES)
 
 
-def _scan_and_whole(layers: dict, cfg: LlamaConfig, packed: bool = False) -> tuple[dict, dict]:
+def _scan_and_whole(layers: dict, cfg: LlamaConfig, packed: bool = False,
+                    regions: bool = False) -> tuple[dict, dict]:
     """(the stacked leaves a layer scan slices, those its body takes WHOLE).
     The grouped dispatch's kernel picks a layer's expert planes out of the
     stacked (L, E, d, f) leaves itself, by the layer index in its scalar
@@ -577,10 +595,12 @@ def _scan_and_whole(layers: dict, cfg: LlamaConfig, packed: bool = False) -> tup
     134 MB three times a layer (``ops.grouped_matmul``). Where the MLP runs
     ``packed`` every leaf it reads stays whole likewise and is sliced INSIDE
     the branch that reads it (``_ffn``): a slice made before a conditional
-    is an operand of it, written out and read back, 176 MB a Mistral layer."""
+    is an operand of it, written out and read back, 176 MB a Mistral layer.
+    Where both position-wise ``regions`` of a layer run packed
+    (``forward_paged``) that is every leaf: the scan slices none."""
     grouped = cfg.n_experts > 0 and cfg.moe_impl == "grouped"
-    held = tuple(k for k in (_FFN_LEAVES if packed else _EXPERT_LEAVES if grouped else ())
-                 if k in layers)
+    held = tuple(k for k in (layers if regions else _FFN_LEAVES if packed else
+                             _EXPERT_LEAVES if grouped else ()) if k in layers)
     return ({k: v for k, v in layers.items() if k not in held}, {k: layers[k] for k in held})
 
 
@@ -757,23 +777,24 @@ def _swiglu(p, h, names, cs=_identity_cs):
     return _qe("btf,fd->btd", act, p[names[2]])
 
 
-# what a forward whose FFN may run PACKED counts (ISSUE 37; summed over a
-# chunk's forwards by ``paged_chunk_decode_loop``, published as ``ffn.<name>``):
-# whether it took the packed branch, and the rows its FFN computed
+# what a forward whose position-wise work may run PACKED counts (ISSUE 37, 41;
+# summed over a chunk's forwards by ``paged_chunk_decode_loop``, published as
+# ``ffn.<name>``): whether it took the packed branches — one predicate decides
+# every region of every layer — and the rows those regions computed
 FFN_STATS = ("forwards_packed", "rows")
 
 
 class FfnPack(NamedTuple):
     """The real positions of a (B, T) block, packed: built ONCE a forward
     from ``n_real`` (row b's real positions are ``t < n_real[b]``), used by
-    every layer's FFN. ``idx`` (P,) names the position (into the B * T) each
-    packed slot holds; ``inv`` (B, T) the slot each position reads back: its
-    own if it is real, else the slot of ITS ROW's last real position — a
-    padded position of a fast-forward block is a copy of that one and writes
-    the same K/V index, so the values must stay equal — and any slot for a
-    row with none (its writes are parked, its logits unread). ``fits``: all
-    real positions have a slot; where they do not, the forward's FFN runs
-    at its full width, as it always did."""
+    every packed region of every layer. ``idx`` (P,) names the position (into
+    the B * T) each packed slot holds; ``inv`` (B, T) the slot each position
+    reads back: its own if it is real, else the slot of ITS ROW's last real
+    position — a padded position of a fast-forward block is a copy of that
+    one and writes the same K/V index, so the values must stay equal — and
+    any slot for a row with none (its writes are parked, its logits unread).
+    ``fits``: all real positions have a slot; where they do not, the forward
+    runs at its full width, as it always did."""
 
     idx: jax.Array
     inv: jax.Array
@@ -785,6 +806,14 @@ class FfnPack(NamedTuple):
         full = self.inv.size
         return jnp.stack([self.fits.astype(jnp.int32),
                           jnp.where(self.fits, self.idx.shape[0], full).astype(jnp.int32)])
+
+    def rows(self, block: jax.Array) -> jax.Array:
+        """(B, T, ...) -> (1, P, ...): each slot's position."""
+        return block.reshape(-1, *block.shape[2:])[self.idx][None]
+
+    def block(self, rows: jax.Array) -> jax.Array:
+        """(1, P, ...) -> (B, T, ...): each position's slot."""
+        return rows[0][self.inv]
 
 
 def ffn_pack_index(n_real: jax.Array, T: int, P: int) -> FfnPack:
@@ -808,17 +837,18 @@ def packed_ffn(ffn, h: jax.Array, pack: FfnPack | None):
     """``ffn(h)`` -> (y, stats) over the (B, T, d) block ``h``, or — with a
     ``pack`` — over its real positions alone where they fit: gather them to
     (1, P, d), the same ``ffn``, and every position reads its slot back. One
-    branch a forward (``pack.fits`` is made once, before the layers)."""
+    branch a forward (``pack.fits`` is made once, before the layers). A
+    latent model's (``models.mla``), which packs its MLPs alone;
+    ``forward_paged`` here packs both position-wise regions of a layer."""
     if pack is None:
         return ffn(h)
-    B, T, d = h.shape
 
     def packed(h):
         with jax.named_scope("layer/ffn/pack"):
-            hp = h.reshape(B * T, d)[pack.idx][None]
+            hp = pack.rows(h)
         y, stats = ffn(hp)
         with jax.named_scope("layer/ffn/unpack"):
-            return y[0][pack.inv], stats
+            return pack.block(y), stats
 
     # the conditional's own time (its operands' copies) is the MLP's: read
     # under ``layer/ffn`` with the branches it chooses between
@@ -826,17 +856,26 @@ def packed_ffn(ffn, h: jax.Array, pack: FfnPack | None):
         return jax.lax.cond(pack.fits, packed, ffn, h)
 
 
+def _layer_leaves(p: dict) -> dict:
+    """``p`` with the leaves ``p["stacked"]`` names — still STACKED over the
+    layers, beside the layer's index — sliced: called inside whichever
+    branch reads them (a slice made before a conditional is its operand)."""
+    if not p.get("stacked"):
+        return p
+    return {**p, "stacked": (),
+            **{k: jax.tree.map(lambda a: a[p["layer"]], p[k]) for k in p["stacked"]}}
+
+
 def _ffn(p, h, cfg: LlamaConfig, cs=_identity_cs):
     """A layer's MLP over its normed input (B, T, d) -> (y, the layer's
     ``_moe_stats`` or None): the dense SwiGLU, or the routed experts and the
     shared ones beside them. Position-wise: it asks nothing of B and T. Opens
-    ``layer/ffn`` itself: a branch of ``packed_ffn`` puts its own names
+    ``layer/ffn`` itself: a branch of a packed region puts its own names
     before it, and the scope paths the trace is read by stay whole. ``p``
-    may hold leaves still STACKED over the layers, named in ``p["stacked"]``,
-    beside the layer's index: sliced here, inside whichever branch runs."""
+    may hold leaves still STACKED over the layers (``_layer_leaves``): sliced
+    here, inside whichever branch runs."""
     with jax.named_scope("layer/ffn"):
-        if p.get("stacked"):
-            p = {**p, **{k: jax.tree.map(lambda a: a[p["layer"]], p[k]) for k in p["stacked"]}}
+        p = _layer_leaves(p)
         if cfg.n_experts > 0:
             y, stats = _moe_ffn(p, h, cfg)
             if cfg.n_shared_experts:
@@ -854,15 +893,15 @@ def _ffn(p, h, cfg: LlamaConfig, cs=_identity_cs):
         return _qe("btf,fd->btd", act, p["w_down"]).astype(h.dtype), None
 
 
-def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = False, u=None,
-               pack: FfnPack | None = None):
+def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = False, u=None):
     """Shared decoder-layer back half: output projection + residual, then
     the MLP (dense SwiGLU, or routed MoE when cfg.n_experts > 0) +
     residual. ``attn`` is (B, T, n_heads * head_dim). With ``moe_stats``
     (routed models only) -> (x, the layer's ``_moe_stats``). A PARALLEL
     block (``u``: the layer's one normed input, which fed q/k/v too) adds
-    both halves to the same residual: x + W_o attn + FFN(u). With ``pack``
-    the MLP runs on the block's real positions (``packed_ffn``)."""
+    both halves to the same residual: x + W_o attn + FFN(u). Position-wise:
+    it asks nothing of B and T (``forward_paged`` runs it on a block's real
+    positions, packed)."""
     with jax.named_scope("layer/attn_out"):
         attn = _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
         attn = cs(attn, "act")
@@ -872,7 +911,7 @@ def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = 
         raise NotImplementedError("a parallel block or shared experts around a dense MLP")
     with jax.named_scope("layer/ffn"):
         h = _norm(x, p["mlp_norm"], cfg) if u is None else u
-    y, stats = packed_ffn(partial(_ffn, p, cfg=cfg, cs=cs), h, pack)
+    y, stats = _ffn(p, h, cfg, cs)
     with jax.named_scope("layer/ffn"):
         x = x + cs(y, "act") if u is None else x + attn + cs(y, "act")
     return (x, stats) if moe_stats else x
@@ -1052,16 +1091,18 @@ def forward_paged(
     n_real: jax.Array | None = None,  # (B,) int32: row b's real positions are
     # t < n_real[b] (None: all T of a live row). A model with a RECURRENT
     # state (models.sambay) advances it over those and no others; with
-    # ``ffn_pack`` a LlamaConfig's MLPs compute those and no others. Absent,
-    # the traced program is the one it was
+    # ``ffn_pack`` a LlamaConfig's position-wise work computes those and no
+    # others. Absent, the traced program is the one it was
     logit_pos: jax.Array | None = None,  # (B,) int32: the head runs on this
     # one position of each row, logits (B, 1, V) (that model, and one with
     # layers of more than one kind: the chunk loop's ``one_head``)
     hybrid_stats: bool = False,  # that model only: also ``sambay.HYBRID_STATS``
-    ffn_pack: int = 0,  # P > 0 with ``n_real``, off a mesh, where B * T > P: the
-    # MLPs run on the block's real positions packed into P rows while they fit
-    # (``packed_ffn``; a fast-forward block of 1 + W positions a row holds few
-    # real ones), and ``FFN_STATS`` (2,) int32 is returned LAST
+    ffn_pack: int = 0,  # P > 0 with ``n_real``, off a mesh, where B * T > P:
+    # everything position-wise in a layer — norms, q/k/v, rotary; the output
+    # projection, the residuals, the MLP — runs on the block's real positions
+    # packed into P rows while they fit (``FfnPack``; a fast-forward block
+    # of 1 + W positions a row holds few real ones; a latent model packs its
+    # MLPs alone), and ``FFN_STATS`` (2,) int32 is returned LAST
     latent_stats: bool = False,  # a latent model only: also ``mla.LATENT_STATS``,
     # (2,) int32, after the attention row-blocks
 ):
@@ -1179,12 +1220,25 @@ def forward_paged(
         with jax.named_scope("layer/ffn/pack"):
             live = n_real if write_mask is None else jnp.where(write_mask, n_real, 0)
             pack = ffn_pack_index(live, T, ffn_pack)
+        # once a forward: the packed slots' angles, and the residual — which
+        # then STAYS packed from layer to layer where the positions fit (the
+        # block beside it is the whole branches' and goes stale meanwhile)
+        with jax.named_scope("layer/attn_qkv/pack"):
+            rope_packed = (pack.rows(cos), pack.rows(sin))
+            x = (x, pack.rows(x))
 
-    scanned, whole = _scan_and_whole(params["layers"], cfg, packed=pack is not None)
-    # of the whole leaves, those the MLP slices itself (the grouped kernel
-    # takes its planes stacked, and the layer's index)
+    scanned, whole = _scan_and_whole(params["layers"], cfg, regions=pack is not None)
+    # of the whole leaves, those a region slices itself, inside its branch (the
+    # grouped kernel takes its planes stacked, and the layer's index)
     stacked = () if pack is None else tuple(
         k for k in whole if not (cfg.moe_impl == "grouped" and k in _EXPERT_LEAVES))
+
+    def own_norm(p, x):
+        """A parallel block's ONE normed input: q/k/v's and the MLP's."""
+        if not cfg.parallel_block:
+            return None
+        with jax.named_scope("layer/attn_qkv"):
+            return _norm(x, p["attn_norm"], cfg)
 
     def layer(carry, layer_in, kind=(True, None)):
         x, kp, vp, ksc, vsc = carry
@@ -1192,11 +1246,42 @@ def forward_paged(
         rotate, window = kind
         if whole:
             p = {**p, **whole, "layer": li, **({"stacked": stacked} if stacked else {})}
-        u = None
-        if cfg.parallel_block:
+
+        if pack is None:
+            u = own_norm(p, x)
+            q, k, v = _layer_qkv(p, x, cfg, cos, sin, cs, rotate=rotate, u=u)
+        else:
+            # a layer is position-wise but for its attention call and its K/V
+            # write: the region before them and the one after run on the
+            # block's real positions where they fit, each its own branch of
+            # one predicate — the output projection beside the MLP, not apart.
+            # Only q/k/v are read back into the block, and only the attention
+            # output is gathered
+            def front(x, rope, hold=False):
+                pl = _layer_leaves(p)
+                q, k, v = _project_qkv(pl, x, cfg, cs, u=own_norm(pl, x))
+                if hold:
+                    # buffers before they open into heads: a projection fused
+                    # with that reshape wants every stacked plane transposed,
+                    # and the whole branch then copies them all back, a layer
+                    q, k, v = jax.lax.optimization_barrier((q, k, v))
+                return _rotate_heads(q, k, v, cfg, *rope, cs, rotate)
+
+            def front_rows(x, xp):
+                qkv = front(xp, rope_packed, hold=True)
+                with jax.named_scope("layer/attn_qkv/unpack"):
+                    return jax.tree.map(pack.block, qkv)
+
+            def back(x, attn):
+                pl = _layer_leaves(p)
+                out = _layer_out(pl, x, attn, cfg, cs, moe_stats=moe_stats, u=own_norm(pl, x))
+                return out if moe_stats else (out, None)
+
+            x, xp = x
+            # the conditional's own time (its operands' copies) is read with
+            # the branches it chooses between
             with jax.named_scope("layer/attn_qkv"):
-                u = _norm(x, p["attn_norm"], cfg)
-        q, k, v = _layer_qkv(p, x, cfg, cos, sin, cs, rotate=rotate, u=u)
+                q, k, v = jax.lax.cond(pack.fits, front_rows, lambda x, xp: front(x, (cos, sin)), x, xp)
 
         with jax.named_scope("layer/kv_write"):
             kp_flat = kp.reshape(L, N * bs, cfg.n_kv_heads, hdp)
@@ -1312,8 +1397,24 @@ def forward_paged(
                             vp[li][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
                             vsc[li][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
                 attn = _attend(q, kl, vl, positions, kv_len_mask, window)
-        out = _layer_out(p, x, attn, cfg, cs, moe_stats=moe_stats, u=u, pack=pack)
-        x, stats = out if moe_stats else (out, None)
+        if pack is None:
+            out = _layer_out(p, x, attn, cfg, cs, moe_stats=moe_stats, u=u)
+            x, stats = out if moe_stats else (out, None)
+        else:
+            def back_rows(x, xp, attn):  # the new residual stays packed
+                with jax.named_scope("layer/ffn/pack"):
+                    rows = pack.rows(attn)
+                xp, st = back(xp, rows)
+                return (x, xp), st
+
+            def back_block(x, xp, attn):
+                x, st = back(x, attn)
+                return (x, xp), st
+
+            # under a name no reader matches: ``layer/attn_out`` inside it
+            # stays out of the MLP's time
+            with jax.named_scope("layer/out"):
+                x, stats = jax.lax.cond(pack.fits, back_rows, back_block, x, xp, attn)
         return (x, kp, vp, ksc, vsc), stats
 
     # layers of ONE kind are a scan over the stacked weights. Layers of more
@@ -1337,6 +1438,9 @@ def forward_paged(
             x, k_pool, v_pool, k_scale, v_scale = carry
             stats = jnp.stack(per_layer) if moe_stats else None
 
+    if pack is not None:
+        with jax.named_scope("layer/out/unpack"):  # once a forward: every position its slot
+            x = jnp.where(pack.fits, pack.block(x[1]), x[0])
     with jax.named_scope("final_norm"):
         if logit_pos is not None:  # the head on the one position a row reads
             x = jnp.take_along_axis(x, logit_pos[:, None, None], axis=1)

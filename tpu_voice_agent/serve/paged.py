@@ -64,10 +64,11 @@ from .radix import RadixCache
 
 SLOT_STATE_SPAN = REQUEST_SPAN + ".slot_state"
 
-# the rows the MLPs of a fast-forward block are packed into (ISSUE 37): under
-# the ridge of int8 weights on this chip (~120 rows: an MLP costs the same
-# from 72 to 96 and more from 128 on), over what a chunk's forwards hold but
-# its first (PERF.md section 5 item 1 has both measurements). Rows, not rows
+# the rows the position-wise regions of a fast-forward block are packed into
+# (ISSUE 37: the MLPs; ISSUE 41: q/k/v and the output projection with them):
+# under the ridge of int8 weights on this chip (~120 rows: an MLP costs the
+# same from 72 to 96 and more from 128 on), over what a chunk's forwards hold
+# but its first (PERF.md section 5 item 1 has both measurements). Rows, not rows
 # a slot: the ridge is the chip's, and a block no wider than this packs nothing
 FFN_PACK_ROWS = 96
 
@@ -455,9 +456,10 @@ def paged_chunk_decode_loop(
     max_len: int | None = None,
     kv_quant: str | None = None,
     quality_lanes: bool = False,  # ISSUE 15 conf lanes (see the dense twin)
-    ffn_pack: int = 0,  # P: the MLPs of a fast-forward block run on its real
-    # positions packed into P rows (``PagedDecodeEngine.ffn_pack_rows``; 0, or
-    # a block of no more than P positions: the program is the one it was)
+    ffn_pack: int = 0,  # P: the position-wise work of a fast-forward block —
+    # q/k/v, the output projection, the MLPs — runs on its real positions
+    # packed into P rows (``PagedDecodeEngine.ffn_pack_rows``; 0, or a block
+    # of no more than P positions: the program is the one it was)
 ):
     """chunk_decode_loop's paged twin: forward_paged per step, idle rows'
     writes parked in their group's reserved trash block via write_mask (they
@@ -470,9 +472,9 @@ def paged_chunk_decode_loop(
     carry and one more output: ``llama.MOE_STATS`` summed over the chunk's
     forwards and layers, (4,) int32. Every variant's LAST output is
     ``ops.ATTN_STATS`` summed over the chunk's forwards, (2,) int32 (ISSUE 31:
-    how often the block kernel's common pass engages). A program whose MLPs
-    may run PACKED (ISSUE 37: ``ffn_pack`` under a fast-forward block wider
-    than it, off a mesh) has one more carry and output after them:
+    how often the block kernel's common pass engages). A program whose
+    position-wise regions may run PACKED (ISSUES 37, 41: ``ffn_pack`` under a
+    fast-forward block wider than it, off a mesh) has one more carry and output after them:
     ``llama.FFN_STATS`` summed over the chunk's forwards, (2,) int32.
 
     The COMPACTED width (ISSUE 29): with ``rows_idx`` the same loop runs over
@@ -544,8 +546,9 @@ def paged_chunk_decode_loop(
     # always have)
     one_head = hybrid or bool(cfg.layer_types) or lat
     # a fast-forward block holds 1 + k real positions a live row and copies
-    # of the last one behind them: the forward is told, and its MLPs compute
-    # the real ones packed into ``ffn_pack`` rows while they fit
+    # of the last one behind them: the forward is told, and everything
+    # position-wise in its layers computes the real ones packed into
+    # ``ffn_pack`` rows while they fit
     packs = bool(use_ff and ffn_pack and rules is None and B * (1 + W) > ffn_pack)
     if packs:
         counts0 += (jnp.zeros((len(FFN_STATS),), jnp.int32),)
@@ -761,9 +764,9 @@ class PagedDecodeEngine(DecodeEngine):
         # program's batch axis
         R = max(1, self.batch_slots // 4)
         self.compact_rows = R if self.dp == 1 and R < self.batch_slots else 0
-        # the rows the MLPs of a fast-forward block compute (ISSUE 37): a
-        # block of batch_slots x (1 + W) positions holds ~1.4 real ones a
-        # row, the rest are copies. A dispatch whose block is no wider runs
+        # the rows a fast-forward block's projections and MLPs compute (ISSUES
+        # 37, 41): a block of batch_slots x (1 + W) positions holds ~1.4 real
+        # ones a row, the rest are copies. A dispatch whose block is no wider runs
         # the program it always ran, and so does a mesh (rows of different dp
         # groups may not share a packed axis)
         self.ffn_pack_rows = FFN_PACK_ROWS if self.dp == 1 else 0
@@ -787,7 +790,7 @@ class PagedDecodeEngine(DecodeEngine):
         self.hybrid = _hybrid(self.cfg)
         self.latent = latent(self.cfg)
         if self.hybrid:
-            # ``sambay.forward_paged`` has no packed MLP (ROADMAP S3 (e) has
+            # ``sambay.forward_paged`` has no packed branch (ROADMAP S3 (e) has
             # what it waits for)
             self.ffn_pack_rows = 0
             from ..models.sambay import cache_spec
