@@ -32,10 +32,23 @@ def as_run(conf: dict, rehearsal: bool) -> tuple[dict, dict]:
 
 def model_dims(config: dict, rehearsal: bool) -> dict:
     model, serving = as_run(config.get("decoder", config), rehearsal)
-    if serving["max_len"] >= model.get("sliding_window", 1 << 30) and not rehearsal:
-        raise ValueError("max_len reaches the sliding window, which models/llama.py "
-                         "does not implement: the served model would not be the published one")
     return {"model": model, "serving": serving}
+
+
+def refuse_unserved_window(model: dict, serving: dict, cfg) -> None:
+    """A published ``sliding_window`` that ``max_len`` passes BINDS (at
+    ``max_len`` <= window no mask can be false: ``llama.bound_window``'s
+    identity). The program serves a binding window since PR 34 — where its
+    configuration carries it. So ask the program's configuration, as the
+    builder's ``llama_config`` made it: one that dropped the window (the dense
+    ``dense_llama_config`` passes none) would serve another model than the
+    published one, and is refused here as before."""
+    window = model.get("sliding_window")
+    if window and serving["max_len"] > window and window not in (
+            getattr(cfg, "sliding_window", None), getattr(cfg, "window", None)):
+        raise ValueError(f"max_len {serving['max_len']} passes the published sliding window {window} and "
+                         f"the program's configuration ({type(cfg).__name__}) does not carry it: the "
+                         f"served model would not be the published one")
 
 
 def apply_env(serving: dict) -> None:
@@ -108,8 +121,11 @@ def build_parser(config: dict, rehearsal: bool, say, llama_config=dense_llama_co
     dims = model_dims(config, rehearsal)
     m, s = dims["model"], dims["serving"]
     t0 = time.perf_counter()
+    cfg = llama_config(m, s)
+    if not rehearsal:
+        refuse_unserved_window(m, s, cfg)
     engine = PagedDecodeEngine(
-        cfg=llama_config(m, s), tokenizer=default_tokenizer(), quant=s["quant"], batch_slots=s["batch_slots"],
+        cfg=cfg, tokenizer=default_tokenizer(), quant=s["quant"], batch_slots=s["batch_slots"],
         block_size=s["block_size"], pool_blocks=s["pool_blocks"], max_len=s["max_len"],
         prefill_buckets=tuple(s["prefill_buckets"]), fast_forward=s["fast_forward"],
         init_weights=False)

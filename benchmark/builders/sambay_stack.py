@@ -3,17 +3,18 @@ decoder-hybrid-decoder (``tpu_voice_agent.models.sambay``) behind it, served
 as the repo serves any decoder: ``PagedDecodeEngine`` behind
 ``brain._wrap_batched`` (cached prompt prefix, continuous batcher).
 
-WHY THIS FILE DOES NOT CALL ``parse_stack.build``: ``parse_stack.model_dims``
-REFUSES a configuration whose ``sliding_window`` is at or under its
-``max_len`` — rightly for ``models/llama.py``, which has no window. This
-model's 512-token window binds from the first decoded token (the cached
-prefix alone is 879) and ``models/sambay.py`` implements it, so the refusal
-does not apply; ``parse_stack.py`` may not be edited by the PR that adds a
-configuration, so ``build`` below is ``parse_stack.build`` /
-``build_parser`` again without that check, on ``parse_stack``'s own
-``as_run`` and ``Served`` (``apply_env`` is run.py's own call). The next ``benchmark`` issue can
-make ``model_dims``'s refusal ask the builder (a ``windowed=True`` argument,
-say) and fold this file's ``build`` back into a one-line call.
+WHY THIS FILE DOES NOT CALL ``parse_stack.build``: until PR 42
+``parse_stack.model_dims`` REFUSED a configuration whose ``sliding_window``
+is at or under its ``max_len`` (written when ``models/llama.py`` had no
+window); this model's 512-token window binds from the first decoded token
+(the cached prefix alone is 879) and ``models/sambay.py`` implements it, so
+``build`` below is ``parse_stack.build`` / ``build_parser`` again without
+that check, on ``parse_stack``'s own ``as_run`` and ``Served``
+(``apply_env`` is run.py's own call). Since PR 42 the refusal asks the
+program's configuration (``parse_stack.refuse_unserved_window``: this
+model's carries its ``window``), so this ``build`` could be a one-line call
+of ``parse_stack.build``; it was left as it is so that no accepted cell's
+path changed in the PR that moved the refusal.
 """
 
 from __future__ import annotations

@@ -1,7 +1,9 @@
 """BENCHMARK.json and the data files it names. Everything that belongs to
 one configuration, one traffic mix or one per-layer metric is a file found
 by NAME; no registry lists them, so a later PR adds files and entries and
-edits nothing that is here."""
+edits nothing that is here — but for its cell's name appended to the
+``workloads`` lists it joins in BENCHMARK.json, which is why no file under
+``benchmark/`` repeats such a list."""
 
 from __future__ import annotations
 
@@ -55,8 +57,30 @@ def metrics_of(manifest: dict, kind: str, workload: str) -> list[dict]:
             if "workloads" not in m or workload in m["workloads"]]
 
 
-def load_layer_metric(name: str) -> dict:
-    return load_json(f"benchmark/layer_metrics/{name}.json")
+VARIANT_KEYS = {"reader", "args", "note"}
+
+
+def load_layer_metric(name: str, cell: str | None = None) -> dict:
+    """One per-layer metric resolved for ``cell``: ``layer_metrics/<name>.json``
+    holds the entry's fields and the default ``reader`` / ``args``; where this
+    cell reads the quantity otherwise, ``layer_metrics/<name>/<cell>.json``
+    — found from the two names alone — holds its own ``reader``, ``args`` and
+    ``note`` and nothing else (unit, ``better``, ``source``, ``layer`` and
+    ``moves`` are the entry's, the same in every cell). The cells are the
+    manifest's alone: ``workloads`` is the entry's list in BENCHMARK.json (a
+    file names cells only while no manifest does: the held-back voice cell's)."""
+    spec = load_json(f"benchmark/layer_metrics/{name}.json")
+    if cell is not None and NAME_RE.match(cell) and \
+            (ROOT / f"benchmark/layer_metrics/{name}/{cell}.json").is_file():
+        own = load_json(f"benchmark/layer_metrics/{name}/{cell}.json")
+        if not {"reader", "args"} <= set(own) <= VARIANT_KEYS:
+            raise ValueError(f"layer_metrics/{name}/{cell}.json holds {sorted(own)}: a cell's own file "
+                             f"holds reader and args, a note if it likes, and nothing else")
+        spec.update(own)
+    entry = next((m for m in load_manifest()["per_layer"] if m["name"] == name), None)
+    if entry is not None and "workloads" in entry:
+        spec["workloads"] = entry["workloads"]
+    return spec
 
 
 # what a module found by name owes the harness (README.md has the reference's
@@ -98,7 +122,8 @@ def code_problems(cell: dict) -> list[str]:
     bad: list[str] = []
     for m in cell["per_layer"]:
         try:
-            named.append(("readers", load_layer_metric(m["name"]).get("reader"), f"reader of {m['name']}"))
+            named.append(("readers", load_layer_metric(m["name"], cell["cell"]["name"]).get("reader"),
+                          f"reader of {m['name']}"))
         except (OSError, ValueError) as e:
             bad.append(f"per-layer metric {m['name']}: no readable layer_metrics file ({e})")
     for kind, name, what in named:
@@ -156,6 +181,8 @@ def validate(manifest: dict) -> list[str]:
         if not 0 < m["bound"] <= 0.1:
             bad.append(f"end-to-end {m['name']}: bound {m['bound']}")
     for m in manifest["per_layer"]:
+        if not m.get("workloads"):  # an entry without one would be owed by every cell a later PR adds
+            bad.append(f"per-layer {m['name']}: no workloads list")
         moved = e2e.get(m["moves"])
         if moved is None:
             bad.append(f"per-layer {m['name']}: moves unknown metric {m['moves']}")

@@ -12,21 +12,27 @@ memory units; every layer has the (d -> 2f -> d) MLP.
   and the int8 head (the copy of the tied embedding; the embedding itself is
   a gather of a few rows), the small bf16 ones (x_proj, dt_proj, the
   convolution) beside them.
-- K/V a live row reads, bf16: a windowed layer at most ``window`` positions
-  (and never more than the context), the full layer the context, each
-  cross-attention layer the full layer's context AGAIN (it is a read of its
-  own) — 2 (K and V) x n_kv_heads x head_dim a position, the published width
+- K/V a forward reads, bf16: a windowed layer at most ``window`` positions a
+  row (and never more than the context; no row rides a common pass there),
+  the full layer the context, each cross-attention layer the full layer's
+  context AGAIN (it is a read of its own) — in those 1 + 7 reads the
+  positions live rows hold in common ONCE, each row's own a row — 2 (K and
+  V) x n_kv_heads x head_dim a position, the published width
   (the served layout packs pairs of half-heads, the same bytes).
 - STATE of a live row: each state-space layer's float32 (d_inner x d_state)
   read once and written once a forward, whatever the block's length.
-- FLOPs: 2 a MAC over the per-position matmuls, the head on ONE position a
-  row, 4 x n_heads x head_dim an attended position (two softmaxes over half
+- FLOPs: 2 a MAC over the per-position matmuls on the forward's REAL
+  positions (never rows x (1 + W): this model packs nothing and its floor
+  still counts what is needed), the head on ONE position a row, 4 x n_heads
+  x head_dim an attended position (two softmaxes over half
   the heads each, values twice as wide: the same count as plain attention
   at these head sizes), and ~9 a state element a position in the scan.
 
 Exact Python integers where the inputs are."""
 
 from __future__ import annotations
+
+from . import peaks as pk
 
 
 def dims(model: dict) -> dict:
@@ -54,10 +60,13 @@ def layer_params(model: dict) -> tuple[int, int]:
     return int8, small
 
 
-def kv_positions(model: dict, ctx: int) -> int:
-    """Positions of K (and of V) a live row reads a forward, over the layers."""
+def kv_positions(model: dict, rows: float, ctx: float, common: float = 0.0) -> float:
+    """Positions of K (and of V) ONE forward reads, over the layers and the
+    live rows: the full layer's and the cross-attention reads take their
+    ``common`` leading positions once (``peaks.kv_positions``)."""
     s = dims(model)
-    return s["n_window"] * min(ctx, s["window"]) + (s["n_full"] + s["n_cross"]) * ctx
+    return (s["n_window"] * rows * min(ctx, s["window"])
+            + (s["n_full"] + s["n_cross"]) * pk.kv_positions(rows, ctx, common))
 
 
 def state_bytes(model: dict, rows: float) -> float:
@@ -67,28 +76,29 @@ def state_bytes(model: dict, rows: float) -> float:
     return rows * s["n_ssm"] * s["di"] * s["ds"] * 4 * 2
 
 
-def forward_bytes(model: dict, weight_bytes: int, rows: float, ctx: int, kv_bytes: int = 2) -> float:
+def forward_bytes(model: dict, weight_bytes: int, rows: float, ctx: float, kv_bytes: int = 2,
+                  common: float = 0.0) -> float:
     s = dims(model)
     int8, small = layer_params(model)
-    kv = rows * kv_positions(model, ctx) * 2 * s["nkv"] * s["hd"] * kv_bytes
+    kv = kv_positions(model, rows, ctx, common) * 2 * s["nkv"] * s["hd"] * kv_bytes
     return (int8 + s["V"] * s["d"]) * weight_bytes + small * 2 + kv + state_bytes(model, rows)
 
 
-def forward_flops(model: dict, rows: float, positions_per_row: float, ctx: int) -> float:
+def forward_flops(model: dict, rows: float, positions: float, ctx: float) -> float:
+    """``positions``: the forward's REAL positions, all rows together."""
     s = dims(model)
     int8, small = layer_params(model)
-    positions = rows * positions_per_row
-    attn = kv_positions(model, ctx) * 4 * s["nq"] * s["hd"]
+    attn = kv_positions(model, 1, ctx) * 4 * s["nq"] * s["hd"]
     scan = s["n_ssm"] * s["di"] * s["ds"] * 9
     return positions * (2 * (int8 + small) + attn + scan) + rows * 2 * s["V"] * s["d"]
 
 
 def forward_floor_s(model: dict, peaks: dict, weight_bytes: int, rows: float,
-                    positions_per_row: float, ctx: int) -> tuple[float, str]:
+                    positions: float, ctx: float, common: float = 0.0) -> tuple[float, str]:
     """Least seconds one hybrid decode forward can take on this chip, and
     which roof sets it."""
-    t_b = forward_bytes(model, weight_bytes, rows, ctx) / peaks["bytes_per_s"]
-    t_f = forward_flops(model, rows, positions_per_row, ctx) / peaks["flops_per_s"]
+    t_b = forward_bytes(model, weight_bytes, rows, ctx, common=common) / peaks["bytes_per_s"]
+    t_f = forward_flops(model, rows, positions, ctx) / peaks["flops_per_s"]
     return (t_b, "bytes") if t_b >= t_f else (t_f, "flops")
 
 

@@ -17,11 +17,13 @@ and from what the routing and the latent cache really did. Beside
 - the LATENT CACHE by what attention read: ``attn.latent_keys_read`` cached
   positions (whole blocks, one the live rows hold in common ONCE) x (C + dr)
   x 2 B — never rows x context by assumption, and never decompressed K/V.
-- ATTENTION FLOPs = ``attn.latent_query_rows`` (positions x heads of live
-  rows, summed over layers) x the keys each may see x 2 x ((C + dr) + C): a
-  score against the latent and the rotated key, a value from the latent.
+- ATTENTION FLOPs = query rows (REAL positions x heads, summed over layers:
+  ``query_rows``) x the keys each may see x 2 x ((C + dr) + C): a score
+  against the latent and the rotated key, a value from the latent. The
+  program's ``attn.latent_query_rows`` counts all 1 + W positions of a live
+  row, padding with them, so it says that the program counts and no more.
 - the HEAD on ONE position a row (the chunk program runs it there alone);
-  every other matmul on all 1 + W positions.
+  every other matmul on the forward's REAL positions, never on rows x (1 + W).
 
 Exact Python integers where the inputs are."""
 
@@ -92,8 +94,14 @@ def cache_read_bytes(model: dict, keys_read: float, cache_bytes: int = 2) -> flo
     return keys_read * (s["C"] + s["dr"]) * cache_bytes
 
 
+def query_rows(model: dict, positions: float) -> float:
+    """Query rows of ``positions`` real positions: x heads, over all layers."""
+    s = dims(model)
+    return positions * s["H"] * s["L"]
+
+
 def attention_flops(model: dict, query_rows: float, ctx: float) -> float:
-    """``query_rows``: positions x heads of live rows, summed over layers."""
+    """``query_rows``: REAL positions x heads, summed over layers."""
     s = dims(model)
     return query_rows * ctx * 2 * ((s["C"] + s["dr"]) + s["C"])
 
@@ -104,32 +112,43 @@ def forward_bytes(model: dict, weight_bytes: int, touched: float, keys_read: flo
             + cache_read_bytes(model, keys_read))
 
 
-def forward_flops(model: dict, rows: int, positions: int, ctx: float, assigned_rows: float,
-                  query_rows: float) -> float:
-    """``positions`` token positions through the layers, the head on one
-    position of each of ``rows`` rows."""
+def forward_flops(model: dict, rows: float, positions: float, ctx: float, assigned_rows: float) -> float:
+    """``positions`` REAL token positions through the layers and attention,
+    the head on one position of each of ``rows`` rows."""
     s = dims(model)
     quant, plain = streamed_params(model)
     through_layers = quant - s["V"] * s["d"] + plain
     return (positions * 2 * through_layers + rows * 2 * s["V"] * s["d"]
-            + expert_flops(model, assigned_rows) + attention_flops(model, query_rows, ctx))
+            + expert_flops(model, assigned_rows)
+            + attention_flops(model, query_rows(model, positions), ctx))
 
 
-def forward_floor_s(model: dict, peaks: dict, weight_bytes: int, rows: int,
-                    positions_per_row: float, ctx: float, touched: float, assigned_rows: float,
-                    keys_read: float, query_rows: float) -> tuple[float, str]:
+def forward_floor_s(model: dict, peaks: dict, weight_bytes: int, rows: float,
+                    positions: float, ctx: float, touched: float, assigned_rows: float,
+                    keys_read: float) -> tuple[float, str]:
     """Least seconds one decode forward can take on this chip, and which roof
-    sets it."""
+    sets it. ``positions``: the forward's real positions, all rows together."""
     t_b = forward_bytes(model, weight_bytes, touched, keys_read) / peaks["bytes_per_s"]
-    t_f = forward_flops(model, rows, int(round(rows * positions_per_row)), ctx, assigned_rows,
-                        query_rows) / peaks["flops_per_s"]
+    t_f = forward_flops(model, rows, positions, ctx, assigned_rows) / peaks["flops_per_s"]
     return (t_b, "bytes") if t_b >= t_f else (t_f, "flops")
 
 
-def latent_attention_floor_s(model: dict, peaks: dict, keys_read: float, query_rows: float,
+def grouped_matmul_floor_s(model: dict, peaks: dict, weight_bytes: int, touched: float,
+                           assigned_rows: float) -> tuple[float, str]:
+    """Least seconds the three ``grouped_matmul`` calls of every routed layer
+    of one forward can take: the touched experts' planes over HBM bandwidth,
+    or the assigned rows' FLOPs over the bf16 peak — rows ASSIGNED and planes
+    TOUCHED, never the row tiles the dispatch padded to."""
+    t_b = expert_bytes(model, weight_bytes, touched) / peaks["bytes_per_s"]
+    t_f = expert_flops(model, assigned_rows) / peaks["flops_per_s"]
+    return (t_b, "bytes") if t_b >= t_f else (t_f, "flops")
+
+
+def latent_attention_floor_s(model: dict, peaks: dict, keys_read: float, positions: float,
                              ctx: float) -> tuple[float, str]:
     """Least seconds the latent-attention kernel's calls of one forward can
-    take: the cache it read over HBM bandwidth, or its dots over the bf16 peak."""
+    take: the cache it read over HBM bandwidth, or the dots of its REAL
+    positions' query rows over the bf16 peak."""
     t_b = cache_read_bytes(model, keys_read) / peaks["bytes_per_s"]
-    t_f = attention_flops(model, query_rows, ctx) / peaks["flops_per_s"]
+    t_f = attention_flops(model, query_rows(model, positions), ctx) / peaks["flops_per_s"]
     return (t_b, "bytes") if t_b >= t_f else (t_f, "flops")

@@ -6,7 +6,8 @@ construction (one gate/up/down a layer), and a changed dense floor would
 move every dense cell's roofline share.
 
 Attention, the head and the KV bytes are counted as ``peaks.py`` counts
-them. The experts are counted from the program's counters (``moe.*``,
+them: on the forward's REAL positions, the K/V live rows hold in common
+once. The experts are counted from the program's counters (``moe.*``,
 ``serve/scheduler.py``; summed over layers and forwards, so per forward they
 are those over ``scheduler.forwards``):
 
@@ -49,32 +50,33 @@ def expert_flops(model: dict, assigned: float) -> float:
     return assigned * 3 * 2 * s["d"] * s["f"]
 
 
-def forward_bytes(model: dict, weight_bytes: int, rows: int, ctx: int, touched: float,
-                  kv_bytes: int = 2) -> float:
+def forward_bytes(model: dict, weight_bytes: int, rows: float, ctx: float, touched: float,
+                  kv_bytes: int = 2, common: float = 0.0) -> float:
     """HBM bytes ONE decode forward must read: the shared weights once, the
-    planes of the experts it touched, each live row's attended K and V."""
+    planes of the experts it touched, the attended K and V (the ``common``
+    positions once, each live row's own a row: ``peaks.kv_positions``)."""
     s = routed_dims(model)
     quant, plain = shared_params(model)
-    kv = 2 * s["L"] * ctx * s["nkv"] * s["hd"] * kv_bytes * rows
+    kv = 2 * s["L"] * pk.kv_positions(rows, ctx, common) * s["nkv"] * s["hd"] * kv_bytes
     return quant * weight_bytes + plain * 2 + expert_bytes(model, weight_bytes, touched) + kv
 
 
-def forward_flops(model: dict, positions: int, ctx: int, assigned: float) -> float:
-    """FLOPs of ``positions`` token positions at attended context ``ctx``:
-    2 per MAC over the shared matmuls and the router, 4*nq*hd per attended
-    position, and the expert rows that were routed."""
+def forward_flops(model: dict, positions: float, ctx: float, assigned: float) -> float:
+    """FLOPs of ``positions`` REAL token positions at attended context
+    ``ctx``: 2 per MAC over the shared matmuls and the router, 4*nq*hd per
+    attended position, and the expert rows that were routed."""
     s = routed_dims(model)
     quant, plain = shared_params(model)
     return positions * (2 * (quant + plain) + ctx * 4 * s["nq"] * s["hd"]) + expert_flops(model, assigned)
 
 
-def forward_floor_s(model: dict, peaks: dict, weight_bytes: int, rows: int,
-                    positions_per_row: float, ctx: int, touched: float,
-                    assigned: float) -> tuple[float, str]:
+def forward_floor_s(model: dict, peaks: dict, weight_bytes: int, rows: float,
+                    positions: float, ctx: float, touched: float,
+                    assigned: float, common: float = 0.0) -> tuple[float, str]:
     """Least seconds one routed decode forward can take on this chip, and
-    which roof sets it."""
-    t_b = forward_bytes(model, weight_bytes, rows, ctx, touched) / peaks["bytes_per_s"]
-    t_f = forward_flops(model, int(round(rows * positions_per_row)), ctx, assigned) / peaks["flops_per_s"]
+    which roof sets it. ``positions``: the forward's real positions."""
+    t_b = forward_bytes(model, weight_bytes, rows, ctx, touched, common=common) / peaks["bytes_per_s"]
+    t_f = forward_flops(model, positions, ctx, assigned) / peaks["flops_per_s"]
     return (t_b, "bytes") if t_b >= t_f else (t_f, "flops")
 
 
@@ -82,7 +84,9 @@ def grouped_matmul_floor_s(model: dict, peaks: dict, weight_bytes: int, touched:
                            assigned: float) -> tuple[float, str]:
     """Least seconds the three ``grouped_matmul`` calls of every layer of one
     forward can take: the touched experts' planes over HBM bandwidth, or the
-    routed rows' FLOPs over the bf16 peak (the kernel multiplies bf16 x bf16)."""
+    routed rows' FLOPs over the bf16 peak (the kernel multiplies bf16 x bf16).
+    Rows ASSIGNED and planes TOUCHED, never the row tiles the dispatch padded
+    to: the tile follows the packed width x top-k (PR 37), the floor does not."""
     t_b = expert_bytes(model, weight_bytes, touched) / peaks["bytes_per_s"]
     t_f = expert_flops(model, assigned) / peaks["flops_per_s"]
     return (t_b, "bytes") if t_b >= t_f else (t_f, "flops")

@@ -12,8 +12,8 @@ counted in the SAME traced executions (as ``readers/roofline.py``).
 can take (touched held experts' planes / HBM bandwidth, or the local rows'
 FLOPs / bf16 peak) over their device SELF time per forward: the operations
 whose scope path holds the kernel's name.
-``padding_share`` — ``moe.padded_rows`` / ``moe.local_rows`` - 1: the rows
-the dispatch computed beyond the rows that fell on held experts.
+``padding_share`` — 1 - ``moe.local_rows`` / ``moe.padded_rows``: of the rows
+the dispatch computed, the share that holds no row of a held expert.
 
 A program without ``moe.local_rows`` (the parent of PR 34; a model that holds
 all its experts) gives nothing to read: every quantity returns None and
@@ -22,9 +22,8 @@ never raises."""
 from __future__ import annotations
 
 from ..lib import peaks_cohere2moe as pkc
-from .host_spans import run_trace
-from .roofline import _shape
-from .scopes import scope_ns
+from .roofline import kernel_share, needed, program_share, step_mfu, weight_bytes
+from .roofline_routed import padding_share
 
 PROGRAM = "paged_chunk_decode_loop"
 
@@ -40,31 +39,20 @@ def _per_forward(ctx: dict) -> tuple[float, float] | None:
 
 def read(ctx: dict, what: str, program: str = PROGRAM):
     if what == "padding_share":
-        c = ctx.get("counters", {})
-        if not c.get("moe.local_rows") or "moe.padded_rows" not in c:
-            return None
-        return 100.0 * (c["moe.padded_rows"] / c["moe.local_rows"] - 1.0)
-    plane = run_trace(ctx)
-    routed, shape = _per_forward(ctx), _shape(ctx)
-    if (plane is None or routed is None or shape is None or ctx["peaks"] is None
+        return padding_share(ctx, "moe.local_rows")
+    routed, n = _per_forward(ctx), needed(ctx)
+    if (routed is None or n is None or ctx["peaks"] is None
             or "num_experts_published" not in ctx["model"]):
         return None
     touched, local = routed
-    _, rows, context = shape
     model, peaks = ctx["model"], ctx["peaks"]
-    wbytes = 1 if ctx["serving"]["quant"] == "int8" else 2
+    if what == "step_mfu":
+        return step_mfu(ctx, n, pkc.forward_flops(model, n["live"], n["positions"], n["context"], local))
     if what == "program_roofline":
-        runs = scope_ns(plane, [], program)
-        if not runs["forwards"]:
-            return None
-        floor, _ = pkc.forward_floor_s(model, peaks, wbytes, round(rows),
-                                       1 + ctx["serving"]["fast_forward"], int(context),
-                                       touched, local)
-        return 100.0 * floor / (runs["program_ns"] / 1e9 / runs["forwards"])
+        floor, _ = pkc.forward_floor_s(model, peaks, weight_bytes(ctx), n["live"], n["positions"],
+                                       n["context"], touched, local, n["common"])
+        return program_share(ctx, program, floor)
     if what == "kernel_roofline":
-        r = scope_ns(plane, ["grouped_matmul"], program)
-        if not r["forwards"] or not r["ns"]:
-            return None
-        floor, _ = pkc.grouped_matmul_floor_s(model, peaks, wbytes, touched, local)
-        return 100.0 * floor / (r["ns"] / 1e9 / r["forwards"])
+        floor, _ = pkc.grouped_matmul_floor_s(model, peaks, weight_bytes(ctx), touched, local)
+        return kernel_share(ctx, program, "grouped_matmul", floor)
     raise ValueError(f"roofline_cohere2moe reader: unknown quantity {what!r}")

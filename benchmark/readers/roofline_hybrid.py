@@ -1,7 +1,8 @@
 """Reader ``roofline_hybrid``: a SambaY decoder-hybrid-decoder's device
 programs against the chip's published peaks, with the floor of
-``lib/peaks_hybrid.py`` (weights once, K/V by layer kind with the window, the
-recurrent state twice, the head on one position a row).
+``lib/peaks_hybrid.py`` (weights once, K/V by layer kind with the window and
+the common positions once, the recurrent state twice, the per-position work
+on the forward's real positions, the head on one position a row).
 ``readers/roofline.py`` and ``lib/peaks.py`` stay the dense ones, untouched.
 
 ``program_roofline`` — the least time a hybrid decode forward can take on
@@ -18,34 +19,31 @@ and never raises."""
 
 from __future__ import annotations
 
+from ..lib import peaks as pk
 from ..lib import peaks_hybrid as pkh
-from .host_spans import run_trace
-from .roofline import _shape
-from .scopes import scope_ns
+from .roofline import kernel_share, needed, program_share, step_mfu, weight_bytes
 
 PROGRAM = "paged_chunk_decode_loop"
 
 
 def read(ctx: dict, what: str, program: str = PROGRAM):
-    shape = _shape(ctx)
-    if shape is None or ctx["peaks"] is None or "ssm_d_state" not in ctx["model"]:
+    n = needed(ctx)
+    if n is None or ctx["peaks"] is None or "ssm_d_state" not in ctx["model"]:
         return None
-    plane = run_trace(ctx)
-    if plane is None:
-        return None
-    _, rows, context = shape
     model, peaks = ctx["model"], ctx["peaks"]
-    if what == "program_roofline":
-        runs = scope_ns(plane, [], program)
-        if not runs["forwards"]:
-            return None
-        wbytes = 1 if ctx["serving"]["quant"] == "int8" else 2
-        floor, _ = pkh.forward_floor_s(model, peaks, wbytes, rows,
-                                       1 + ctx["serving"]["fast_forward"], int(context))
-        return 100.0 * floor / (runs["program_ns"] / 1e9 / runs["forwards"])
+    if what in ("step_mfu", "program_roofline"):
+        # this program sums ``attn.*`` over ALL its attention reads: the blocks live rows hold are its
+        # ``attn.window_blocks_held`` a windowed layer, the common row-blocks those of the reads that ride
+        s, c = pkh.dims(model), ctx.get("counters", {})
+        held = c.get("attn.window_blocks_held", 0.0) / (c.get("scheduler.forwards") or 1.0) / s["n_window"]
+        live = pk.live_rows(held, n["context"], n["block_size"], n["rows"])
+        if what == "step_mfu":
+            return step_mfu(ctx, n, pkh.forward_flops(model, live, n["positions"], n["context"]))
+        common = pk.common_positions(n["common_row_blocks"], live, n["block_size"],
+                                     reads=s["n_full"] + s["n_cross"])
+        floor, _ = pkh.forward_floor_s(model, peaks, weight_bytes(ctx), live, n["positions"],
+                                       n["context"], common)
+        return program_share(ctx, program, floor)
     if what == "scan_roofline":
-        r = scope_ns(plane, ["selective_scan"], program)
-        if not r["forwards"] or not r["ns"]:
-            return None
-        return 100.0 * pkh.scan_floor_s(model, peaks, rows) / (r["ns"] / 1e9 / r["forwards"])
+        return kernel_share(ctx, program, "selective_scan", pkh.scan_floor_s(model, peaks, n["rows"]))
     raise ValueError(f"roofline_hybrid reader: unknown quantity {what!r}")

@@ -14,8 +14,8 @@ bf16 peak) over their device SELF time per forward: the operations whose
 scope path holds the kernel's name (``.../layer/ffn/experts/grouped_matmul/...``).
 ``scope_share`` — device self time under ``scopes`` as a share of the device
 time of ``program``'s executions in the stretch.
-``padding_share`` — ``moe.padded_rows`` / ``moe.assigned_rows`` - 1: the rows
-the dispatch computed beyond the rows that were routed.
+``padding_share`` — 1 - ``moe.assigned_rows`` / ``moe.padded_rows``: of the
+rows the dispatch computed, the share that holds no routed row.
 
 A program without the counters or the scopes (the parent of PR 27, a dense
 model) gives nothing to read: every quantity returns None and never raises."""
@@ -24,7 +24,7 @@ from __future__ import annotations
 
 from ..lib import peaks_routed as pkr
 from .host_spans import run_trace
-from .roofline import _shape
+from .roofline import kernel_share, needed, program_share, step_mfu, weight_bytes
 from .scopes import scope_ns
 
 PROGRAM = "paged_chunk_decode_loop"
@@ -40,37 +40,36 @@ def _per_forward(ctx: dict) -> tuple[float, float] | None:
     return c["moe.experts_touched"] / fwds, c["moe.assigned_rows"] / fwds
 
 
+def padding_share(ctx: dict, filled: str):
+    """Of the rows the dispatch COMPUTED (``moe.padded_rows``), the share
+    that holds no assignment: 1 - ``filled`` / padded, in %. A share of what
+    ran, so it cannot pass 100 (until PR 42: padded / filled - 1, which read
+    106.9 % where half the computed rows were padding)."""
+    c = ctx.get("counters", {})
+    if not c.get("moe.padded_rows") or filled not in c:
+        return None
+    return 100.0 * (1.0 - c[filled] / c["moe.padded_rows"])
+
+
 def read(ctx: dict, what: str, program: str = PROGRAM, scopes: list[str] | None = None):
     if what == "padding_share":
-        c = ctx.get("counters", {})
-        if not c.get("moe.assigned_rows") or "moe.padded_rows" not in c:
-            return None
-        return 100.0 * (c["moe.padded_rows"] / c["moe.assigned_rows"] - 1.0)
-    plane = run_trace(ctx)
-    if plane is None:
-        return None
+        return padding_share(ctx, "moe.assigned_rows")
     if what == "scope_share":
-        r = scope_ns(plane, scopes, program)
-        return 100.0 * r["ns"] / r["program_ns"] if r["forwards"] and r["ns"] else None
-    routed, shape = _per_forward(ctx), _shape(ctx)
-    if routed is None or shape is None or ctx["peaks"] is None or "num_experts" not in ctx["model"]:
+        plane = run_trace(ctx)
+        r = scope_ns(plane, scopes, program) if plane else None
+        return 100.0 * r["ns"] / r["program_ns"] if r and r["forwards"] and r["ns"] else None
+    routed, n = _per_forward(ctx), needed(ctx)
+    if routed is None or n is None or ctx["peaks"] is None or "num_experts" not in ctx["model"]:
         return None
     touched, assigned = routed
-    _, rows, context = shape
     model, peaks = ctx["model"], ctx["peaks"]
-    wbytes = 1 if ctx["serving"]["quant"] == "int8" else 2
+    if what == "step_mfu":
+        return step_mfu(ctx, n, pkr.forward_flops(model, n["positions"], n["context"], assigned))
     if what == "program_roofline":
-        runs = scope_ns(plane, [], program)
-        if not runs["forwards"]:
-            return None
-        floor, _ = pkr.forward_floor_s(model, peaks, wbytes, round(rows),
-                                       1 + ctx["serving"]["fast_forward"], int(context),
-                                       touched, assigned)
-        return 100.0 * floor / (runs["program_ns"] / 1e9 / runs["forwards"])
+        floor, _ = pkr.forward_floor_s(model, peaks, weight_bytes(ctx), n["live"], n["positions"],
+                                       n["context"], touched, assigned, n["common"])
+        return program_share(ctx, program, floor)
     if what == "kernel_roofline":
-        r = scope_ns(plane, ["grouped_matmul"], program)
-        if not r["forwards"] or not r["ns"]:
-            return None
-        floor, _ = pkr.grouped_matmul_floor_s(model, peaks, wbytes, touched, assigned)
-        return 100.0 * floor / (r["ns"] / 1e9 / r["forwards"])
+        floor, _ = pkr.grouped_matmul_floor_s(model, peaks, weight_bytes(ctx), touched, assigned)
+        return kernel_share(ctx, program, "grouped_matmul", floor)
     raise ValueError(f"roofline_routed reader: unknown quantity {what!r}")

@@ -408,7 +408,7 @@ def measure(args, cell: dict, rehearsal: bool) -> tuple[dict, int]:
         values: dict = {}
         for m in wanted:
             if args.trace:
-                spec = load_layer_metric(m["name"])
+                spec = load_layer_metric(m["name"], args.workload)
                 v = load_code("readers", spec["reader"]).read(ctx, **spec.get("args", {}))
             else:
                 v = e2e.get(m["name"])

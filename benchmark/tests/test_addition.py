@@ -44,8 +44,9 @@ def _add(copy: Path, files: Path, entries: dict) -> None:
     manifest = json.loads((copy / "BENCHMARK.json").read_text())
     for kind in ("configs", "workloads", "per_layer"):
         manifest[kind] += entries.get(kind, [])
-    for metric, cells in entries.get("append_workloads", {}).items():
-        next(m for m in manifest["end_to_end"] if m["name"] == metric)["workloads"] += cells
+    for kind, key in (("end_to_end", "append_workloads"), ("per_layer", "append_per_layer")):
+        for metric, cells in entries.get(key, {}).items():  # the one touch of an entry that is there
+            next(m for m in manifest[kind] if m["name"] == metric)["workloads"] += cells
     (copy / "BENCHMARK.json").write_text(json.dumps(manifest, indent=1))
 
 
@@ -76,7 +77,19 @@ def test_a_routed_expert_cell_is_added_as_files_and_entries_only(copy):
     assert "NOT CORRECT: the served model disagrees" not in p.stdout
     # and nothing the benchmark had was edited to get there
     after = _hashes(copy / "benchmark")
-    assert {k: after[k] for k in before} == before and len(after) == len(before) + 5
+    assert {k: after[k] for k in before} == before and len(after) == len(before) + 6
+    # the cell joined two lists the manifest had and reads one of them through a file of its own,
+    # found from the entry's and the cell's name; the cell that was there reads what it read
+    probe = ("import json; from benchmark.lib import manifest as mf; m = mf.load_manifest(); "
+             "c = mf.load_cell(m, 'routed_solo'); print(json.dumps([mf.validate(m), mf.code_problems(c), "
+             "sorted(e['name'] for e in c['per_layer']), "
+             "[mf.load_layer_metric('decode_program_roofline.solo', w)['reader'] for w in ('routed_solo', 'parse_solo')], "
+             "mf.load_layer_metric('rows_per_forward.solo', 'routed_solo')['reader']]))")
+    out = subprocess.run([sys.executable, "-c", probe], cwd=copy, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert json.loads(out.stdout.strip().splitlines()[-1]) == [
+        [], [], ["decode_program_roofline.solo", "out_tokens_per_req.routed", "rows_per_forward.solo"],
+        ["roofline_routed", "roofline"], "counters"]
 
 
 @pytest.mark.parametrize("key", ["reference", "builder"])

@@ -98,8 +98,10 @@ def test_the_floor_counts_held_experts_touched_local_rows_and_the_head_once_a_ro
     all_ = pkc.forward_bytes(MODEL, 1, rows=32, ctx=950, touched=L * held)
     assert all_ - few == L * 12 * 3 * PLANE
     assert all_ == int8 + 32768 * 4096 + 2 * bf16 + L * held * 3 * PLANE + 32 * 950 * L * 4096
-    # K/V: a sliding layer reads min(context, window); the head's FLOPs on ONE position a row
-    assert pkc.kv_positions(MODEL, 950) == 8 * 950 and pkc.kv_positions(MODEL, 6000) == 2 * 6000 + 6 * 4096
+    # K/V: a sliding layer reads min(context, window), the positions live rows hold in common once (PR 42);
+    # the head's FLOPs on ONE position a row
+    assert pkc.kv_positions(MODEL, 1, 950) == 8 * 950 and pkc.kv_positions(MODEL, 1, 6000) == 2 * 6000 + 6 * 4096
+    assert pkc.kv_positions(MODEL, 32, 950, common=768) == 8 * (768 + 32 * 182)
     base = pkc.forward_flops(MODEL, rows=32, positions=288, ctx=950, local_rows=0)
     assert pkc.forward_flops(MODEL, rows=33, positions=288, ctx=950, local_rows=0) - base == 2 * 32768 * 4096
 
@@ -109,9 +111,12 @@ def test_a_perfect_kernel_reads_100_percent_and_a_program_without_the_counter_re
 
     L, fwds, touched = 8, 16, 12
     perfect_ns = L * touched * 3 * PLANE / 819e9 * 1e9 * fwds
-    monkeypatch.setattr(rc, "run_trace", lambda ctx: object())
-    monkeypatch.setattr(rc, "_shape", lambda ctx: (9, 32.0, 950.0))
-    monkeypatch.setattr(rc, "scope_ns", lambda plane, scopes, program: {
+    from benchmark.readers import roofline
+
+    monkeypatch.setattr(roofline, "run_trace", lambda ctx: object())
+    monkeypatch.setattr(rc, "needed", lambda ctx: {"steps": [], "rows": 32.0, "context": 950.0, "positions": 45.0,
+                                                 "common_row_blocks": 192.0, "block_size": 128, "live": 32.0, "common": 768.0})
+    monkeypatch.setattr(roofline, "scope_ns", lambda plane, scopes, program: {
         "ns": perfect_ns if scopes else 0, "program_ns": 4 * perfect_ns, "forwards": fwds})
     counters = {"scheduler.forwards": 100.0, "moe.experts_touched": 100.0 * L * touched,
                 "moe.assigned_rows": 100.0 * L * 2304, "moe.local_rows": 100.0 * L * 288,
@@ -120,7 +125,7 @@ def test_a_perfect_kernel_reads_100_percent_and_a_program_without_the_counter_re
            "serving": {"quant": "int8", "fast_forward": 8}}
     assert abs(rc.read(ctx, "kernel_roofline") - 100.0) < 1e-9
     assert 0 < rc.read(ctx, "program_roofline") < 100.0
-    assert abs(rc.read(ctx, "padding_share") - 100.0 * (400 / 288 - 1)) < 1e-9
+    assert abs(rc.read(ctx, "padding_share") - 100.0 * (1 - 288 / 400)) < 1e-9  # of the rows computed
     # the parent of PR 34, or a model that holds all its experts: no ``moe.local_rows``
     parent = dict(ctx, counters={k: v for k, v in counters.items() if k != "moe.local_rows"})
     assert [rc.read(parent, w) for w in ("kernel_roofline", "program_roofline", "padding_share")] == [None] * 3

@@ -73,10 +73,16 @@ def test_the_existing_readers_give_nothing_where_the_program_wrote_nothing():
                          den="brain.parse_completed") == 1.5
 
 
-def test_every_new_metric_is_a_file_and_an_appended_entry():
+def test_every_new_metric_is_a_file_and_an_entry_behind_what_the_manifest_had():
+    """PR 36 appended these sixteen; PRs 37-42 appended behind them (until
+    PR 42 this asserted they were the manifest's LAST and failed from PR 37
+    on), so: every one is an entry, in one run, behind every entry the
+    manifest had before them."""
     assert len(NEW) == 16
     names = [m["name"] for m in M["per_layer"]]
-    assert names[-len(NEW):] and set(names[-len(NEW):]) == set(NEW)  # appended, at the end
+    at = sorted(names.index(n) for n in NEW)
+    assert at == list(range(at[0], at[0] + len(NEW)))  # appended together
+    assert names[at[0] - 1] == "admit_rows_per_call.solo"  # PR 35's last, folded or not
     assert len(names) <= 128 and mf.validate(M) == []
 
 

@@ -5,7 +5,7 @@ through ``lib/refcheck.compare`` on the rehearsal's served stack (two leading
 dense layers, a latent rank that is no head width, a nonzero bias), where its
 int4 control has to land above its tolerance; then the latent cache's and the
 routed block's roofline arithmetic against hand counts at the PUBLISHED
-widths, and the manifest at its 128 per-layer entries."""
+widths, and the cell among the manifest's per-layer lists."""
 
 import dataclasses
 
@@ -42,15 +42,26 @@ def test_the_file_holds_the_catalog_s_numbers_but_for_the_depth():
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("moonlight-16b-a3b-int8", "parse_flood", 1)
 
 
-def test_the_manifest_is_valid_at_128_per_layer_entries_and_the_cell_reads_four_of_its_own():
+def test_the_manifest_is_valid_with_room_left_and_the_cell_reads_what_its_siblings_read():
+    """Until PR 42 the manifest was full (128 of 128) and this cell had four
+    entries; folded to one entry a (metric, metric moved) it reads its
+    siblings' lists, its own floors through its own files, and four of its own."""
     manifest = mf.load_manifest()
-    assert mf.validate(manifest) == [] and len(manifest["per_layer"]) == 128
+    assert mf.validate(manifest) == [] and len(manifest["per_layer"]) <= 80
     cell = mf.load_cell(manifest, "moonlight_flood")
     assert [m["name"] for m in cell["end_to_end"]] == ["setup_s", "out_tokens_per_s"]
-    own = [m["name"] for m in cell["per_layer"]]
-    assert own == ["decode_program_roofline.moonlight_flood", "latent_attn_roofline.moonlight_flood",
-                   "attn_device_ms_per_forward.moonlight_flood", "ffn_device_ms_per_forward.moonlight_flood"]
-    assert all(m["workloads"] == ["moonlight_flood"] and m["moves"] == "out_tokens_per_s" for m in cell["per_layer"])
+    names = [m["name"] for m in cell["per_layer"]]
+    assert len(names) == 35 and {
+        "expert_matmul_device_ms_per_forward.floods", "grouped_matmul_roofline.floods", "tokens_per_forward.floods",
+        "step_ms.floods", "device_idle_share.floods", "decode_program_roofline.floods", "step_mfu.floods"} <= set(names)
+    own = [m["name"] for m in cell["per_layer"] if m["workloads"] == ["moonlight_flood"]]
+    assert own == ["latent_attn_roofline.moonlight_flood", "latent_write_device_ms_per_forward.moonlight_flood",
+                   "latent_absorb_device_ms_per_forward.moonlight_flood", "dense_ffn_device_ms_per_forward.moonlight_flood"]
+    assert all(m["moves"] == "out_tokens_per_s" for m in cell["per_layer"])
+    floors = {n: mf.load_layer_metric(n, "moonlight_flood") for n in names if n.endswith("_roofline.floods")}
+    assert {n: (s["reader"], s["args"]["what"]) for n, s in floors.items()} == {
+        "decode_program_roofline.floods": ("roofline_mla_moe", "program_roofline"),
+        "grouped_matmul_roofline.floods": ("roofline_mla_moe", "grouped_matmul_roofline")}
     assert mf.code_problems(cell) == []
     rate = next(m for m in manifest["end_to_end"] if m["name"] == "out_tokens_per_s")
     assert rate["workloads"][-1] == "moonlight_flood" and rate["bound"] == 0.015
@@ -151,33 +162,43 @@ def test_the_floor_counts_experts_touched_rows_assigned_and_the_cache_as_it_was_
     all_ = pkm.forward_bytes(MODEL, 1, touched=L * 64, keys_read=0)
     assert all_ - few == L * 54 * EXPERT
     # the head's FLOPs on ONE position a row
-    base = pkm.forward_flops(MODEL, rows=32, positions=288, ctx=950, assigned_rows=0, query_rows=0)
-    more = pkm.forward_flops(MODEL, rows=33, positions=288, ctx=950, assigned_rows=0, query_rows=0)
+    base = pkm.forward_flops(MODEL, rows=32, positions=288, ctx=950, assigned_rows=0)
+    more = pkm.forward_flops(MODEL, rows=33, positions=288, ctx=950, assigned_rows=0)
     assert more - base == 2 * 163840 * 2048
-    # a 1 + 8 block of 32 rows over ~950 positions: the kernel's dots bound it, not its bytes
-    floor, roof = pkm.latent_attention_floor_s(MODEL, V5E, keys_read=17 * 48 * 128, query_rows=17 * 4608, ctx=950)
+    # were all 288 positions of a 1 + 8 block of 32 rows real, the kernel's dots would bound it, not its bytes
+    floor, roof = pkm.latent_attention_floor_s(MODEL, V5E, keys_read=17 * 48 * 128, positions=288, ctx=950)
     assert roof == "flops" and floor == 17 * 4608 * 950 * 2 * 1088 / 197e12
+    # 29 of 64 experts a layer touched at 576 assignments: the planes bound the grouped matmul
+    floor, roof = pkm.grouped_matmul_floor_s(MODEL, V5E, 1, touched=L * 29, assigned_rows=L * 576)
+    assert roof == "bytes" and floor == L * 29 * EXPERT / 819e9
 
 
 def test_a_perfect_kernel_reads_100_percent_and_a_program_without_the_counters_reads_nothing(monkeypatch):
     from benchmark.readers import roofline_mla_moe as rm
 
+    from benchmark.readers import roofline
+
     fwds, keys, qrows = 16, 17 * 48 * 128, 17 * 4608
-    perfect_ns = 17 * 4608 * 950 * 2 * 1088 / 197e12 * 1e9 * fwds
-    monkeypatch.setattr(rm, "run_trace", lambda ctx: object())
-    monkeypatch.setattr(rm, "_shape", lambda ctx: (9, 32.0, 950.0))
-    monkeypatch.setattr(rm, "scope_ns", lambda plane, scopes, program: {
-        "ns": perfect_ns if scopes == [rm.KERNEL] else 0, "program_ns": 40 * perfect_ns, "forwards": fwds})
+    perfect_ns = 17 * 48 * 128 * 1152 / 819e9 * 1e9 * fwds  # 45 real positions: the cache's read bounds the kernel
+    experts_ns = 16 * 50 * EXPERT / 819e9 * 1e9 * fwds
+    monkeypatch.setattr(roofline, "run_trace", lambda ctx: object())
+    monkeypatch.setattr(rm, "needed", lambda ctx: {"steps": [], "rows": 32.0, "context": 950.0, "positions": 45.0,
+                                                 "common_row_blocks": 192.0, "block_size": 128, "live": 32.0, "common": 768.0})
+    monkeypatch.setattr(roofline, "scope_ns", lambda plane, scopes, program: {
+        "ns": {rm.KERNEL: perfect_ns, "grouped_matmul": experts_ns}.get((scopes or [None])[0], 0),
+        "program_ns": 400 * perfect_ns, "forwards": fwds})
     counters = {"scheduler.forwards": 100.0, "moe.experts_touched": 100.0 * 16 * 50,
                 "moe.assigned_rows": 100.0 * 16 * 576, "attn.latent_keys_read": 100.0 * keys,
                 "attn.latent_query_rows": 100.0 * qrows}
     ctx = {"counters": counters, "peaks": V5E, "model": MODEL,
            "serving": {"quant": "int8", "fast_forward": 8}}
     assert abs(rm.read(ctx, "kernel_roofline") - 100.0) < 1e-9
+    assert abs(rm.read(ctx, "grouped_matmul_roofline") - 100.0) < 1e-9  # the planes of the 50 touched, at the roof
     assert 0 < rm.read(ctx, "program_roofline") < 100.0
     # the parent of PR 38, every model whose cache is K and V, a CPU rehearsal: nothing, and no raise
-    for lacking in ("attn.latent_keys_read", "attn.latent_query_rows", "moe.experts_touched"):
+    for lacking in ("attn.latent_keys_read", "moe.experts_touched"):
         parent = dict(ctx, counters={k: v for k, v in counters.items() if k != lacking})
-        assert [rm.read(parent, w) for w in ("kernel_roofline", "program_roofline")] == [None, None]
+        assert [rm.read(parent, w) for w in ("kernel_roofline", "program_roofline", "grouped_matmul_roofline")] \
+            == [None] * 3
     assert rm.read(dict(ctx, peaks=None), "kernel_roofline") is None
     assert rm.read(dict(ctx, model={"hidden_size": 4096}), "program_roofline") is None

@@ -95,9 +95,13 @@ def test_a_perfect_kernel_reads_100_percent_when_fewer_than_64_experts_are_touch
 
     L, fwds, touched = 16, 16, 40
     perfect_ns = L * touched * 3 * PLANE / 819e9 * 1e9 * fwds
+    from benchmark.readers import roofline
+
     monkeypatch.setattr(rr, "run_trace", lambda ctx: object())
-    monkeypatch.setattr(rr, "_shape", lambda ctx: (9, 32.0, 950.0))
-    monkeypatch.setattr(rr, "scope_ns", lambda plane, scopes, program: {
+    monkeypatch.setattr(roofline, "run_trace", lambda ctx: object())
+    monkeypatch.setattr(rr, "needed", lambda ctx: {"steps": [], "rows": 32.0, "context": 950.0, "positions": 45.0,
+                                                 "common_row_blocks": 192.0, "block_size": 128, "live": 32.0, "common": 768.0})
+    monkeypatch.setattr(roofline, "scope_ns", lambda plane, scopes, program: {
         "ns": perfect_ns if scopes else 0, "program_ns": 4 * perfect_ns, "forwards": fwds})
     counters = {"scheduler.forwards": 100.0, "moe.experts_touched": 100.0 * L * touched,
                 "moe.assigned_rows": 100.0 * L * 2304, "moe.padded_rows": 100.0 * L * 4000}
@@ -105,7 +109,7 @@ def test_a_perfect_kernel_reads_100_percent_when_fewer_than_64_experts_are_touch
            "serving": {"quant": "int8", "fast_forward": 8}}
     assert abs(rr.read(ctx, "kernel_roofline") - 100.0) < 1e-9
     assert 0 < rr.read(ctx, "program_roofline") < 100.0
-    assert abs(rr.read(ctx, "padding_share") - 100.0 * (4000 / 2304 - 1)) < 1e-9
+    assert abs(rr.read(ctx, "padding_share") - 100.0 * (1 - 2304 / 4000)) < 1e-9  # of the rows computed
     # a program without the counters (the parent, a dense model) gives nothing to read and never raises
     dense = dict(ctx, counters={"scheduler.forwards": 100.0})
     assert [rr.read(dense, w) for w in ("kernel_roofline", "program_roofline", "padding_share")] == [None] * 3

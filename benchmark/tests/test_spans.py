@@ -185,9 +185,9 @@ def test_program_roofline_divides_by_the_forwards_of_the_traced_executions():
     peaks = pk.peaks_for("TPU v5 lite")
     ctx = {"trace": {"plane": data}, "peaks": peaks, "model": model, "window_s": 1.0,
            "serving": {"quant": "int8", "fast_forward": 8}, "prefix_tokens": 879,
-           "tokens_per_request": 34.0, "steps": [{"forwards": 16, "occupancy": 32}] * 3}
+           "tokens_per_request": 34.0, "steps": [{"forwards": 16, "occupancy": 32, "tokens": 720}] * 3}
     (program_ns,) = [d for n, _, d in data["modules"] if "paged_chunk_decode_loop" in n]
-    floor, _ = pk.forward_floor_s(model, peaks, 1, 32, 9, 879 + 17)
+    floor, _ = pk.forward_floor_s(model, peaks, 1, 32, 45, 879 + 17)  # 720 tokens in 16 forwards: 45 real positions
     got = roofline.read(ctx, "program_roofline", "paged_chunk_decode_loop")
     assert got == pytest.approx(100.0 * floor / (program_ns / 1e9 / 4))
     # a program with no loop has no forwards to divide by; no trace, nothing to read

@@ -12,9 +12,10 @@ plain reference's ONE full forward, with its int4 control.
 
     python3 benchmark/tools/window_check.py [--seed 7] [--prompt 4700]
 
-``builders/parse_stack.model_dims`` refuses a ``max_len`` that reaches a
-window (written when the program had none), so this builds the engine itself
-with ``cohere2moe_stack``'s two functions. With JAX_PLATFORMS=cpu at the
+Until PR 42 ``builders/parse_stack.model_dims`` refused a ``max_len`` that
+reaches a window (written when the program had none; since then
+``parse_stack.refuse_unserved_window`` asks the program's configuration), so
+this builds the engine itself with ``cohere2moe_stack``'s two functions. With JAX_PLATFORMS=cpu at the
 rehearsal's widths (window 16, a 200-token prompt)."""
 
 from __future__ import annotations
