@@ -1,0 +1,535 @@
+"""dots3-note-prev (``dots3_note``; the benchmark's ``dots3-note-prev-int8``) at
+test widths on the CPU: the served path — LEARNED SPARSE attention over a
+latent cache in the full layers (an indexer choosing ``index_topk`` keys),
+WINDOWED latent attention of its own sizes in the sliding ones, a compressed
+query, a gate a head, planes by layer KIND on one block table — against its
+plain reference (``benchmark/reference/dots3_decoder.py``, which decompresses
+keys and values a head and applies selection and window as masks), with
+selection AND window binding; each ``assumed`` reading flipped; the kernels
+against their twins; the share test of the model-configs guide's section 4;
+what the pool holds; every refusal by type; and what the engine asks of the
+model (a prefix longer than the largest bucket through the scratch pool in
+chunks, grouped admission, both chunk widths, the counters). The AOT compile
+for the TPU at the published widths is ``tests/test_kernels_compile_tpu.py``'s.
+"""
+
+import dataclasses
+import functools
+import json
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.builders import dots3_stack, parse_stack
+from benchmark.reference import dots3_decoder as ref
+from tpu_voice_agent.models import dots3, llama, mla
+from tpu_voice_agent.models.llama import forward_paged, init_params, quantize_params
+from tpu_voice_agent.ops import sparse_latent as sl
+
+F32 = jnp.float32
+CONF = json.loads((Path(__file__).parents[1] / "benchmark/configs/dots3-note-prev-int8.json").read_text())
+# the file's rehearsal widths (F S F S S: two leading dense layers of different
+# kinds, three routed ones; 4 full heads, 2 sliding ones; ranks 48 / 40), with
+# an ``index_topk`` and a window small enough to BIND inside 50 tokens
+MODEL, SERVING = parse_stack.as_run(CONF, True)
+MODEL = {**MODEL, "index_topk": 16, "sliding_window_size": 9}
+CFG = dataclasses.replace(dots3_stack.llama_config(MODEL, {**SERVING, "site_context_tokens": 0}),
+                          max_seq_len=256)
+BS, N = 8, 12
+TABLE = jnp.asarray([[1, 2, 3, 4, 5, 6, 7]], jnp.int32)
+TOKS = jax.random.randint(jax.random.key(1), (1, 50), 0, CFG.vocab_size)
+SAMPLE = {"tokens": [int(t) for t in TOKS[0]], "rows": 50}
+
+
+def rel(got, want) -> float:
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float((np.abs(got - want).max(-1) / np.abs(want).max(-1)).max())
+
+
+def pools(cfg, dtype, n=N, bs=BS):
+    planes = dots3.cache_spec(cfg)["planes"]
+    return tuple({name: jnp.zeros((L, n, bs, w), dtype) for name, (L, w) in planes[p].items()}
+                 for p in ("k", "v"))
+
+
+def through_the_pool(params, cfg, impl, dtype, steps=(37, 1, 1, 1, 9, 1), toks=TOKS, **kw):
+    """50 tokens as the engine feeds them: a prefill of 37, three T = 1 steps,
+    one 1 + 8 block, one more step — through the paged planes of both kinds.
+    -> (50, V) logits."""
+    kp, vp = pools(cfg, dtype)
+    rows, pos = [], 0
+    for T in steps:
+        out = forward_paged(params, cfg, toks[:, pos:pos + T], (pos + jnp.arange(T))[None], kp, vp,
+                            TABLE, attn_impl=impl, **kw)
+        rows.append(np.asarray(out[0][0]))
+        kp, vp, pos = out[1], out[2], pos + T
+    return np.concatenate(rows)
+
+
+def test_the_configuration_keeps_the_published_widths_and_names_its_cut():
+    """The file's top level is the catalog's ``config`` but for depth, experts
+    HELD and vocabulary rows; the program's configuration reads every size
+    from it, and the pool answers by layer kind."""
+    published = {"hidden_size": 5120, "intermediate_size": 13824, "moe_intermediate_size": 1536,
+                 "num_attention_heads": 128, "kv_lora_rank": 512, "q_lora_rank": 1024,
+                 "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+                 "index_n_heads": 64, "index_head_dim": 128, "index_topk": 2048,
+                 "swa_num_attention_heads": 64, "swa_kv_lora_rank": 1024, "swa_q_lora_rank": 1024,
+                 "swa_qk_nope_head_dim": 192, "swa_qk_rope_head_dim": 64, "swa_v_head_dim": 128,
+                 "swa_rope_theta": 50000, "rope_theta": 80000000, "sliding_window_size": 513,
+                 "num_experts_per_tok": 8, "n_shared_experts": 1, "first_k_dense_replace": 1,
+                 "routed_scaling_factor": 1, "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+                 "norm_topk_prob": True, "apply_mla_qkv_lora_rescale": True,
+                 "attention_gate_type": "headwise", "max_position_embeddings": 524288}
+    assert {k: CONF[k] for k in published} == published
+    assert [(CONF[k], CONF[k + "_published"]) for k in ("num_hidden_layers", "n_routed_experts", "vocab_size")] \
+        == [(9, 46), (32, 256), (19008, 152064)]
+    assert sorted(CONF["reduced_why"]) == ["n_routed_experts", "num_hidden_layers", "vocab_size"]
+    assert CONF["vocab_size"] * CONF["chips_sharing_a_layer"] == CONF["vocab_size_published"]
+    assert CONF["n_routed_experts"] * CONF["chips_sharing_a_layer"] == CONF["n_routed_experts_published"]
+    # the letters builder and reference read are the published list's first nine
+    assert len(CONF["layer_types"]) == 46 and CONF["layer_types"].count("full_attention") == 13
+    assert CONF["layer_kinds"] == "".join(t[0].upper() for t in CONF["layer_types"][:9]) == "FFSSSFSSS"
+    for part in ("vision", "audio", "multi-token"):
+        assert part in CONF["left_out"]
+    s = CONF["serving"]
+    assert s["max_len"] == 8192 + 128 + 512 and s["pool_blocks"] == 64 + 32 * 6 + 8
+    assert 879 + s["site_context_tokens"] == 8192 == 64 * s["block_size"]
+    m, s = parse_stack.as_run(CONF, False)
+    full = dots3_stack.llama_config(m, {**s, "site_context_tokens": 0})
+    assert (full.n_heads, full.kv_lora_rank, full.q_lora_rank, full.qk_nope_dim, full.qk_rope_dim,
+            full.v_head_dim, full.index_n_heads, full.index_head_dim, full.index_topk) == \
+        (128, 512, 1024, 128, 64, 128, 64, 128, 2048)
+    assert (full.swa_n_heads, full.swa_kv_lora_rank, full.swa_q_lora_rank, full.swa_qk_nope_dim,
+            full.swa_qk_rope_dim, full.swa_v_head_dim, full.swa_rope_theta, full.sliding_window) == \
+        (64, 1024, 1024, 192, 64, 128, 50000.0, 513)
+    assert (full.n_experts, full.n_held, full.first_expert, full.top_k, full.first_dense_layers,
+            full.dense_ffn_dim, full.ffn_dim, full.vocab_size) == (256, 32, 0, 8, 1, 13824, 1536, 19008)
+    assert full.attn_gate and full.lora_rescale and full.router_bias and full.shared_sum
+    assert full.layer_types == ("full", "full") + ("sliding",) * 3 + ("full",) + ("sliding",) * 3
+    assert llama.paged_only(full) and llama.latent(full)
+    spec = mla.cache_spec(full)
+    assert spec["planes"] == {"k": {"kv": (3, 512), "idx": (3, 128), "swa": (6, 1024)},
+                              "v": {"kv": (3, 64), "swa": (6, 64)}}
+    # 1408 B a token a full layer, 2176 B a sliding one (the file's deployment)
+    assert spec["token_bytes"] == 3 * 1408 + 6 * 2176 == 17280
+    assert dots3.layer_plan(full)[:3] == (("full", 0), ("full", 1), ("sliding", 0))
+    assert mla.latent_stat_names(full) == mla.LATENT_STATS + dots3.SPARSE_STATS
+    assert mla.latent_stat_names(llama.PRESETS["test-tiny"]) == mla.LATENT_STATS
+    # the rehearsal: every mechanism present, selection and window binding
+    assert (CFG.layer_types, CFG.first_dense_layers) == (("full", "sliding", "full", "sliding", "sliding"), 2)
+    assert (CFG.n_heads, CFG.swa_n_heads, CFG.kv_lora_rank, CFG.swa_kv_lora_rank) == (4, 2, 48, 40)
+    assert (CFG.n_experts, CFG.n_held, CFG.first_expert, CFG.top_k) == (16, 4, 4, 3)
+    reh = parse_stack.as_run(CONF, True)[0]
+    assert reh["index_topk"] < 1024 and reh["sliding_window_size"] < 1024  # both bind behind the rehearsal's head
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_prefill_then_decode_through_the_pool_is_the_reference_full_forward(impl):
+    """Float32 weights and activations: a prefill of 37 (past ``index_topk``
+    16 and the window of 9, so selection and window bind INSIDE it), T = 1
+    steps and a 1 + 8 block through the planes of both kinds — absorbed
+    attention over gathered keys everywhere — against the reference's ONE
+    full forward, which decompresses keys and values a head and masks. Under
+    "pallas" the indexer and the gathered kernel run (interpreted), and the
+    grouped kernel the experts."""
+    params = init_params(CFG, jax.random.key(0), F32)
+    assert float(jnp.abs(params["layers"]["router_bias"]).min()) > 0
+    cfg = dataclasses.replace(CFG, moe_impl="grouped" if impl == "pallas" else "dense")
+    want = ref.logits(params, MODEL, SAMPLE)
+    with jax.default_matmul_precision("highest"):
+        assert rel(through_the_pool(params, cfg, impl, F32), want) < 1e-4
+
+
+def test_a_ragged_block_of_two_rows_behind_chunks_is_the_reference():
+    """Two rows on tables of their own: the head in two chunks of 16 (the
+    second attends the first through the pool, selected and windowed), then a
+    1 + 2 block where one row holds 3 real positions and the other 2
+    (``n_real``: the real positions go first through the full layers'
+    tiles) — each real position's logits are the reference's."""
+    params = init_params(CFG, jax.random.key(0), F32)
+    toks = jax.random.randint(jax.random.key(3), (2, 36), 0, CFG.vocab_size)
+    tables = jnp.asarray([[1, 2, 3, 4, 5], [6, 7, 8, 9, 10]], jnp.int32)
+    kp, vp = pools(CFG, F32)
+    got = []
+    with jax.default_matmul_precision("highest"):
+        for at, T, kw in ((0, 16, {}), (16, 16, {}), (32, 1, {}),
+                          (33, 3, {"n_real": jnp.asarray([3, 2]), "latent_stats": True})):
+            out = forward_paged(params, CFG, toks[:, at:at + T], jnp.tile(at + jnp.arange(T)[None], (2, 1)),
+                                kp, vp, tables, attn_impl="xla", **kw)
+            kp, vp = out[1], out[2]
+            got.append(np.asarray(out[0]))
+        stats = dict(zip(dots3.latent_stat_names(), np.asarray(out[-1]).tolist()))
+    got = np.concatenate(got, axis=1)
+    for b, real in ((0, 36), (1, 35)):
+        want = ref.forward(params, [int(t) for t in toks[b]], MODEL, last=36)
+        assert rel(got[b, :real], np.asarray(want)[:real]) < 1e-4
+    # 5 real positions at 33, 34, 35 | 33, 34: two full layers see pos + 1 keys, attend 16
+    assert stats["keys_visible"] == 2 * (34 + 35 + 36 + 34 + 35) and stats["keys_selected"] == 2 * 5 * 16
+    assert stats["index_keys_scored"] == 2 * 6 * N * BS  # one tile of the block's 6 slots, the whole plane
+    assert stats["window_keys_read"] > 0
+
+
+FLIPS = {
+    "no_rescale": ({"rescale": False}, {}),
+    "no_gate": ({"gated": False}, {}),
+    "a_window_of_8": ({}, {"sliding_window_size": 8}),
+    "a_window_of_10": ({}, {"sliding_window_size": 10}),
+    "fifteen_keys": ({}, {"index_topk": 15}),
+    "every_key": ({}, {"index_topk": 64}),
+    "sixteen_index_heads_of_8": ({}, {"index_n_heads": 8, "index_head_dim": 16}),
+    "the_sliding_theta_everywhere": ({}, {"rope_theta": 50000.0}),
+    "one_theta_for_both": ({}, {"swa_rope_theta": 8e7}),
+    "latent_norm_eps": ({}, {"latent_norm_eps": 1e-2}),
+    "top_2_experts": ({}, {"num_experts_per_tok": 2}),
+    "held_from_expert_0": ({}, {"first_expert": 0}),
+    "scaled_gates": ({}, {"routed_scaling_factor": 2.5}),
+    "layer_kinds_in_another_order": ({}, {"layer_kinds": "FSSFS"}),
+}
+
+
+@pytest.fixture(scope="module")
+def served_f32():
+    params = init_params(CFG, jax.random.key(0), F32)
+    with jax.default_matmul_precision("highest"):
+        return params, through_the_pool(params, CFG, "xla", F32)
+
+
+@pytest.mark.parametrize("flip", sorted(FLIPS))
+def test_each_assumed_reading_flipped_is_another_model(served_f32, flip):
+    """The reference under ONE other reading of the block — no rank rescale,
+    no gate, a window one wider or narrower, another ``index_topk``, the
+    indexer's heads cut otherwise, one theta for both kinds, another latent
+    eps, another expert rule, another share — is no longer what is served."""
+    params, got = served_f32
+    departures, keys = FLIPS[flip]
+    if flip == "sixteen_index_heads_of_8":  # the same W_qI read as other heads
+        keys = {"index_n_heads": MODEL["index_n_heads"] * 2, "index_head_dim": MODEL["index_head_dim"] // 2}
+        with pytest.raises(Exception):  # one key of 32 values a token: 16-wide heads cannot read it
+            ref.forward(params, SAMPLE["tokens"], {**MODEL, **keys}, last=50)
+        return
+    want = ref.forward(params, SAMPLE["tokens"], {**MODEL, **keys}, last=50, **departures)
+    assert rel(got, want) > 1e-3, flip
+
+
+def test_the_bias_selects_and_the_index_key_is_layer_normed(served_f32):
+    params, got = served_f32
+    zero = {**params, "layers": {**params["layers"],
+                                 "router_bias": jnp.zeros_like(params["layers"]["router_bias"])}}
+    assert rel(got, ref.logits(zero, MODEL, SAMPLE)) > 1e-3
+    # the index key's LayerNorm gain: scaled, the chosen sets stay (a score is
+    # linear in kI up to the relu) — but shifted keys choose otherwise
+    full = params["attn_full"]
+    moved = {**params, "attn_full": {**full, "w_ik": full["w_ik"][:, :, ::-1]}}
+    assert rel(got, ref.logits(moved, MODEL, SAMPLE)) > 1e-3
+
+
+@pytest.mark.parametrize("fault", dots3.FAULTS)
+def test_each_planted_fault_moves_the_served_logits(served_f32, fault):
+    """``dots3.forward_paged(fault=...)``: what the comparison's limit is set
+    against on the chip — planted in the SERVED program, each departs from
+    the sound one at float32 by far more than rounding."""
+    params, sound = served_f32
+    kp, vp = pools(CFG, F32)
+    prefill = lambda fault: jax.jit(functools.partial(dots3.forward_paged, attn_impl="xla", fault=fault),
+                                    static_argnums=1)(params, CFG, TOKS, jnp.arange(50)[None], kp, vp, TABLE)
+    with jax.default_matmul_precision("highest"):
+        out = prefill(fault)
+        if fault == dots3.FAULTS[0]:
+            assert rel(prefill(None)[0][0], sound) < 1e-4  # one prefill of 50 is the six steps
+    assert rel(out[0][0], sound) > 1e-3
+    with pytest.raises(ValueError, match="one of"):
+        prefill("none")
+
+
+def test_the_indexer_kernel_matches_its_twin_and_scores_by_hand():
+    """Interpret mode against the jnp twin, and one score by hand: sum over
+    index heads of w relu(q . k)."""
+    ks = jax.random.split(jax.random.key(7), 3)
+    q = jax.random.normal(ks[0], (11, 4, 32), F32)  # 11 positions: padded to 16 inside
+    w = jax.random.normal(ks[1], (11, 4), F32)
+    plane = jax.random.normal(ks[2], (3, 12, 8, 32), F32)
+    with jax.default_matmul_precision("highest"):
+        got = sl.indexer_scores(q, w, plane, jnp.int32(2))
+        want = sl.indexer_scores_reference(q, w, plane, 2)
+    assert got.shape == (11, 96) and rel(got, want) < 1e-5
+    k = np.asarray(plane[2]).reshape(96, 32)
+    by_hand = sum(float(w[5, j]) * max(float(np.asarray(q[5, j]) @ k[70]), 0.0) for j in range(4))
+    assert abs(float(got[5, 70]) - by_hand) < 1e-4
+
+
+@pytest.mark.parametrize("Q,K", [(8, 24), (18, 40)])
+def test_the_gathered_kernel_matches_its_twin_with_bounds_a_query_and_padding_keys(Q, K):
+    """Each query row its own [lo, hi]; keys out of order, some at -1 (padding:
+    never seen, lo >= 0); Q and K no multiple of the kernel's tiles."""
+    G, C, R = 3, 48, 16
+    ks = jax.random.split(jax.random.key(8), 5)
+    q_c, q_r = jax.random.normal(ks[0], (G, Q, C), F32), jax.random.normal(ks[1], (G, Q, R), F32)
+    c, r = jax.random.normal(ks[2], (G, K, C), F32), jax.random.normal(ks[3], (G, K, R), F32)
+    kpos = jnp.stack([jax.random.permutation(k_, K) for k_ in jax.random.split(ks[4], G)]).astype(jnp.int32)
+    kpos = kpos.at[:, -3:].set(-1)
+    hi = jnp.tile(jnp.arange(Q, dtype=jnp.int32)[None] + 5, (G, 1))
+    lo = jnp.maximum(hi - 6, 0)
+    with jax.default_matmul_precision("highest"):
+        got = sl.window_latent_attention(q_c, q_r, c, r, kpos, lo, hi, scale=0.125)
+        assert np.array_equal(got, sl.sparse_latent_attention(q_c, q_r, c, r, kpos, lo, hi, scale=0.125))
+        want = sl.gathered_latent_attention_reference(q_c, q_r, c, r, kpos, lo, hi, scale=0.125)
+    assert got.shape == (G, Q, C) and rel(got, want) < 1e-4
+    # by hand, one query: softmax over the keys inside its bounds alone
+    g, qi = 1, 4
+    seen = (np.asarray(kpos[g]) >= int(lo[g, qi])) & (np.asarray(kpos[g]) <= int(hi[g, qi]))
+    s = (np.asarray(q_c[g, qi]) @ np.asarray(c[g]).T + np.asarray(q_r[g, qi]) @ np.asarray(r[g]).T) * 0.125
+    p = np.where(seen, np.exp(s - s[seen].max()), 0.0)
+    np.testing.assert_allclose(got[g, qi], (p / p.sum()) @ np.asarray(c[g]), rtol=2e-4, atol=2e-5)
+
+
+def test_the_shares_of_all_chips_add_up_to_the_uncut_layer():
+    """The model-configs guide's section 4: the routed parts the FOUR shares
+    of the rehearsal's 16 experts give (4 held from ids 0, 4, 8, 12), with
+    the shared expert and attention counted ONCE, are the uncut layer — in
+    the reference and in the served ``_ffn``."""
+    from benchmark.reference import decoder as dense_ref
+
+    uncut_cfg = dataclasses.replace(CFG, experts_held=0, first_expert=0)
+    up = init_params(uncut_cfg, jax.random.key(4), F32)
+    layer = jax.tree.map(lambda a: a[0], up["layers"])
+    u = jax.random.normal(jax.random.key(5), (1, 12, CFG.dim), F32)
+    kw = dict(top_k=CFG.top_k, scale=1.0)
+    with jax.default_matmul_precision("highest"):
+        whole = ref.routed_part(u[0], layer, dense_ref.dense, first=0, **kw)
+        served_whole, _ = llama._ffn(layer, u, uncut_cfg)
+        parts, served_parts = [], []
+        for first in (0, 4, 8, 12):
+            share = {**layer, **{k: layer[k][first:first + 4] for k in ("moe_gate", "moe_up", "moe_down")}}
+            parts.append(ref.routed_part(u[0], share, dense_ref.dense, first=first, **kw))
+            cfg = dataclasses.replace(CFG, experts_held=4, first_expert=first)
+            served_parts.append(llama._ffn(share, u, cfg)[0][0])
+        shared = ref.shared_part(u[0], layer, dense_ref.dense, n_shared=1)
+    assert rel(sum(parts), whole) < 1e-5
+    assert float(jnp.abs(parts[1]).max()) > 1e-3  # a share is not nothing
+    # each served share carries the shared expert: counted once, three of them come off
+    assert rel(sum(served_parts) - 3 * shared, served_whole[0]) < 1e-4
+    assert rel(served_whole[0], whole + shared) < 1e-4
+
+
+def test_the_served_precision_reads_inside_the_limit_and_int4_outside():
+    """int8 weights, bf16 activations and bf16 planes against the float32
+    reference on the same weights, and the int4 control; the chip's limit at
+    published widths is the reference module's own. With the selection VOID
+    (``index_topk`` past the context; the window of 9 binds): sixteen keys of
+    fifty chosen in bfloat16 are other keys than float32 chooses, and at
+    these widths one key is a sixteenth of a softmax — the rehearsal's 256 of
+    1060 and the cell's 2048 of 8.3 k are held by the comparison itself
+    (``benchmark/tests/test_dots3_reference.py``, the chip)."""
+    model = {**MODEL, "index_topk": 64}
+    cfg = dataclasses.replace(CFG, index_topk=64)
+    params = quantize_params(init_params(cfg, jax.random.key(0)))
+    assert params["attn_full"]["w_kvb"]["q"].dtype == jnp.int8 and params["attn_swa"]["w_qb"]["q"].dtype == jnp.int8
+    assert params["attn_full"]["ik_norm"].dtype == jnp.bfloat16
+    assert params["layers"]["router_bias"].dtype == F32 and params["layers"]["router"].dtype == jnp.bfloat16
+    want = np.asarray(ref.logits(params, model, SAMPLE))
+    rows = lambda got: np.abs(np.asarray(got, np.float32) - want).max(-1) / np.abs(want).max(-1)
+    served = rows(through_the_pool(params, cfg, "xla", jnp.bfloat16))
+    control = rows(ref.logits(params, model, SAMPLE, control=True))
+    # by the typical row: at these widths one of a token's three experts that
+    # flips on a near tie (4 of 16 held) moves a row by a fifth of its range
+    assert 1e-3 < np.median(served) < 0.03 and np.mean(served < 0.08) > 0.85
+    assert np.median(control) > 0.08 > np.quantile(served, 0.85)
+
+
+def test_the_pool_holds_planes_by_layer_kind_at_the_published_widths():
+    """Three full layers of 512 + 64 + a 128-wide index key, six sliding ones
+    of 1024 + 64: read from the pool's own shapes, the engine's gauge and the
+    byte plan."""
+    from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
+    from tpu_voice_agent.serve import PagedDecodeEngine, paged
+    from tpu_voice_agent.utils import hbmledger, tracing
+
+    m, s = parse_stack.as_run(CONF, False)
+    full = dots3_stack.llama_config(m, {**s, "site_context_tokens": 0})
+    eng = PagedDecodeEngine(cfg=full, tokenizer=default_tokenizer(), quant="int8", batch_slots=2,
+                            block_size=128, pool_blocks=4, max_len=256, prefill_buckets=(128,),
+                            init_weights=False)
+    assert eng.sparse and eng.latent and not eng.hybrid
+    assert {n: a.shape for n, a in eng.k_pool.items()} == {
+        "kv": (3, 4, 128, 512), "idx": (3, 4, 128, 128), "swa": (6, 4, 128, 1024)}
+    assert {n: a.shape for n, a in eng.v_pool.items()} == {"kv": (3, 4, 128, 64), "swa": (6, 4, 128, 64)}
+    pool_bytes = sum(a.nbytes for pool in (eng.k_pool, eng.v_pool) for a in pool.values())
+    assert pool_bytes == 4 * eng.kv_bytes_per_block == 4 * 128 * 17280
+    assert hbmledger.engine_hbm_plan(eng)["kv_pool_bytes"] == pool_bytes
+    fresh = tracing.Metrics()
+    orig, tracing._GLOBAL_METRICS = tracing._GLOBAL_METRICS, fresh
+    try:
+        paged.record_pool_gauges(eng.allocator, engine=eng)
+    finally:
+        tracing._GLOBAL_METRICS = orig
+    assert fresh.snapshot()["gauges"]["paged.kv_bytes_per_token"] == 17280
+
+
+def test_the_configuration_refuses_what_only_this_forward_has_without_it():
+    base = dict(kv_lora_rank=48, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=12, n_layers=2,
+                n_heads=2, n_kv_heads=2, dim=32)
+    with pytest.raises(NotImplementedError, match="compressed query"):
+        llama.LlamaConfig(**base, q_lora_rank=16)
+    with pytest.raises(NotImplementedError, match="compressed query"):
+        llama.LlamaConfig(**base, attn_gate=True)
+    with pytest.raises(NotImplementedError, match="layer kinds inside a latent model"):
+        llama.LlamaConfig(**base, layer_types=("full", "sliding"), sliding_window=4)
+    with pytest.raises(ValueError, match="over a latent cache"):
+        llama.LlamaConfig(n_layers=2, index_topk=8, index_n_heads=2, index_head_dim=16)
+    sparse = dict(base, layer_types=("full", "sliding"), sliding_window=4, index_topk=8,
+                  index_n_heads=2, index_head_dim=16)
+    with pytest.raises(NotImplementedError, match="q_lora_rank"):
+        llama.LlamaConfig(**sparse)
+    with pytest.raises(ValueError, match="swa_"):
+        llama.LlamaConfig(**sparse, q_lora_rank=16)
+
+
+REHEARSAL = parse_stack.as_run(CONF, True)
+
+
+def _float32(tree):
+    return jax.tree.map(lambda a: a.astype(F32) if a.dtype == jnp.bfloat16 else a, tree)
+
+
+def _engine(float32=False, **kw):
+    from tpu_voice_agent.serve import PagedDecodeEngine
+
+    # the rehearsal's OWN selection and window (256 and 129: both bind behind
+    # its head of 1024 tokens), buckets the head is LONGER than
+    cfg = dataclasses.replace(dots3_stack.llama_config(*REHEARSAL), max_seq_len=1536)
+    args = dict(cfg=cfg, max_len=1536, batch_slots=8, prefill_buckets=(128, 256),
+                fast_forward=8, block_size=128, pool_blocks=80, quant=None)
+    eng = PagedDecodeEngine(**{**args, **kw})
+    if float32:  # weights and planes: no rounding for a selection to turn on
+        eng.params, eng.k_pool, eng.v_pool = _float32(eng.params), _float32(eng.k_pool), _float32(eng.v_pool)
+    return eng
+
+
+@pytest.fixture(scope="module")
+def prompts():
+    """Prompts behind the rehearsal's SITE CONTEXT (``llama_config`` puts its
+    145 tokens into the prompt head: 879 + 145 = 1024, eight whole blocks);
+    taken away again behind the module's tests."""
+    from tpu_voice_agent.services import prompts as P
+
+    dots3_stack.llama_config(*REHEARSAL)
+    assert P.site_context()
+    yield [P.render_prompt(t, {}) for t in ("go back", "scroll down to the bottom of the page",
+                                            "open the settings page", "search for red shoes")]
+    P.set_site_context("")
+
+
+def test_the_engine_serves_it_behind_the_batcher_at_both_chunk_widths(monkeypatch, prompts):
+    """The normal path: the prompt head — LONGER than the largest bucket —
+    prefilled in chunks through ONE scratch pool, whole blocks of it cached;
+    admissions behind it; chunks at the compacted and the full width; the
+    routed counters, the latent reads and the selection's counters published."""
+    from tpu_voice_agent.serve import ContinuousBatcher
+    from tpu_voice_agent.utils import tracing
+
+    fresh = tracing.Metrics()
+    monkeypatch.setattr(tracing, "_GLOBAL_METRICS", fresh)
+    eng = _engine(kernels="pallas")
+    assert eng.cfg.moe_impl == "grouped" and eng.compact_rows == 2 and eng.sparse
+    P = eng.set_prompt_prefix(*prompts[:2])
+    assert P == 1024 > eng.prefill_buckets[-1] and not eng._prefix_tail and len(eng._prefix_blocks[0]) == 8
+    held = np.asarray(eng._prefix_blocks[0])  # every plane of both kinds holds the head
+    assert all(float(jnp.abs(a[:, held]).min(axis=(0, 2, 3)).max()) > 0
+               for pool in (eng.k_pool, eng.v_pool) for a in pool.values())
+    chunks, decode_chunk = [], eng.decode_chunk
+    monkeypatch.setattr(eng, "decode_chunk", lambda *a, **k: chunks.append(decode_chunk(*a, **k)) or chunks[-1])
+    batcher = ContinuousBatcher(eng, chunk_steps=4, max_new_tokens=12)
+    solo = batcher.generate_many(prompts[:1])
+    many = batcher.generate_many(prompts)
+    assert all(r.error is None for r in solo + many)
+    assert {c.rows for c in chunks} == {2, 8}
+    assert all(c.moe.shape == (len(llama.moe_stat_names(eng.cfg)),) and c.latent.shape == (6,) for c in chunks)
+    assert many[0].token_ids == solo[0].token_ids  # the same plan at either width
+    counters = fresh.snapshot()["counters"]
+    assert counters["moe.assigned_rows"] > counters["moe.local_rows"] > 0
+    visible, chosen = counters["attn.keys_visible"], counters["attn.keys_selected"]
+    assert 0 < chosen < 0.3 * visible  # 256 of ~1050 keys a position
+    assert counters["attn.index_keys_scored"] >= visible and counters["attn.window_keys_read"] > 0
+    assert counters["attn.latent_query_rows"] > 0 and counters["attn.latent_keys_read"] > 0
+
+
+def test_the_chunked_head_and_a_suffix_behind_it_are_the_reference(prompts):
+    """What the cell's comparison holds at published widths, here in float32:
+    the head through the scratch pool in four chunks of 256 (selection from
+    position 256 on, the window from 129), a suffix admitted behind it — the
+    reference's one full forward over the same tokens."""
+    eng = _engine(float32=True)
+    assert eng.set_prompt_prefix(*prompts[:2]) == 1024
+    ids = eng.tokenizer.encode(prompts[1], bos=True)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(eng.prefill_slot(ids, 0))
+        want = ref.forward(eng.params, ids, REHEARSAL[0], last=1)
+    assert len(ids) > 1024 + 20 and rel(got.reshape(1, -1), want) < 2e-4
+
+
+def _pick_logits(logits, state, slots, ns):
+    return logits[:, 0, :]
+
+
+def test_a_group_s_admission_is_the_per_slot_admissions(prompts):
+    """Grouped admission (16 slots: ``admit_rows`` 2) behind the cached head
+    writes the planes of every kind and picks the logits the per-slot path does."""
+    def admitted(grouped: bool):
+        eng = _engine(float32=True, batch_slots=16, pool_blocks=140)
+        eng.set_prompt_prefix(*prompts[:2])
+        ids = [eng.tokenizer.encode(p, bos=True) for p in prompts[:2]]
+        assert eng.admit_rows == 2
+        if grouped:
+            out = eng.admit_group([eng.prepare_admission(i, s) for s, i in enumerate(ids)], pick=_pick_logits)
+            logits = np.asarray(out.picked)
+        else:
+            logits = np.concatenate([np.asarray(eng.prefill_slot(i, s)) for s, i in enumerate(ids)])
+        owned = [eng._slot_owned[s][0] for s in range(2)]
+        planes = [[np.asarray(a[:, b], np.float32) for pool in (eng.k_pool, eng.v_pool) for a in pool.values()]
+                  for b in owned]
+        return logits, planes, [len(i) for i in ids], eng
+
+    one, planes_one, lens, eng = admitted(False)
+    grp, planes_grp, _, _ = admitted(True)
+    assert rel(grp, one) < 1e-4
+    P = len(eng.prefix_ids)
+    assert P == 1024
+    for a, b, n in zip(planes_one, planes_grp, lens):  # the suffix's cache, position by position
+        assert len(a) == 5  # kv, idx, swa | kv, swa
+        for x, y in zip(a, b):
+            assert float(np.abs(x[:, :n - P] - y[:, :n - P]).max()) < 1e-4
+
+
+@pytest.mark.parametrize("what", ["radix", "spec", "kv_quant", "handoff", "mesh", "dense_engine", "dense_forward"])
+def test_what_moves_k_and_v_planes_refuses_the_planes_by_kind_by_type(what):
+    """ONE typed error, where each is built or called — its message names the
+    index plane."""
+    from tpu_voice_agent.serve import DecodeEngine
+    from tpu_voice_agent.serve.spec import SpecConfig
+
+    if what == "dense_forward":
+        params = init_params(CFG, jax.random.key(0), F32)
+        with pytest.raises(NotImplementedError, match="forward_paged"):
+            llama.forward(params, CFG, jnp.zeros((1, 4), jnp.int32), jnp.arange(4)[None],
+                          llama.init_kv_cache(CFG, 1, 8))
+        return
+    with pytest.raises(mla.LatentCacheOnly):
+        if what == "radix":
+            _engine(radix_enable=True)
+        elif what == "spec":
+            _engine(spec=SpecConfig(k=2))
+        elif what == "kv_quant":
+            _engine(kv_quant="int8")
+        elif what == "handoff":
+            _engine().gather_chain_kv([1])
+        elif what == "mesh":
+            from tpu_voice_agent.parallel import make_mesh
+
+            _engine(mesh=make_mesh(dp=2, tp=1, devices=jax.devices()[:2]))
+        else:
+            DecodeEngine(cfg=CFG, max_len=256, batch_slots=2, quant=None)
+    assert "index key" in mla.LatentCacheOnly.__doc__

@@ -767,6 +767,41 @@ def test_the_moonlight_prefills_compile_at_published_widths(tpu_devices, monkeyp
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
 
 
+@pytest.mark.parametrize("P,blocks", [(16, 264), (12, 264), (9, 264), (16, 65)],
+                         ids=["block", "compact", "one-block", "scratch"])
+def test_the_indexer_kernel_compiles_at_published_widths(tpu_devices, P, blocks):
+    """dots3-note-prev's indexer (64 index heads of 128 a position) over the
+    WHOLE index-key plane of a full layer — the cell's 264-block pool and the
+    prefix's 65-block scratch pool — for a tile of 16 position slots (a
+    fast-forward block, a suffix group, a chunk of the head), the compacted
+    width's 12 and the comparison's 1 + 8 block (both padded to 16 inside)."""
+    from tpu_voice_agent.ops import sparse_latent as sl
+
+    compiled = _compile(tpu_devices, sl.indexer_scores, ((P, 64, 128), BF16), ((P, 64), F32),
+                        ((3, blocks, 128, 128), BF16), ((), I32), interpret=False)
+    assert "indexer_scores" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name,G,Q,K,C", [("sparse_latent_attention", 16, 128, 2048, 512),
+                                          ("sparse_latent_attention", 9, 128, 2048, 512),
+                                          ("window_latent_attention", 32, 576, 768, 1024),
+                                          ("window_latent_attention", 128, 512, 768, 1024),
+                                          ("window_latent_attention", 32, 512, 640, 1024)],
+                         ids=["selected", "selected-one-block", "window-block", "window-prefix", "window-suffix"])
+def test_the_gathered_latent_kernel_compiles_at_published_widths(tpu_devices, name, G, Q, K, C):
+    """ONE kernel under two names: a position's 128 heads over its 2048 selected
+    (c, r) rows of 512 + 64 (a tile of 16 slots; the comparison's 9), and a
+    sliding layer's row — 9 x 64 queries of a fast-forward block, 8 x 64 of a
+    prefill's rows of eight — over the 5-6 blocks of 1024 + 64 that hold its
+    window; the whole key set one tile."""
+    from tpu_voice_agent.ops import sparse_latent as sl
+
+    compiled = _compile(tpu_devices, getattr(sl, name), ((G, Q, C), BF16), ((G, Q, 64), BF16),
+                        ((G, K, C), BF16), ((G, K, 64), BF16), ((G, K), I32), ((G, Q), I32), ((G, Q), I32),
+                        scale=0.07, interpret=False)
+    assert name in compiled.as_text()
+
+
 @pytest.mark.slow
 def test_sharded_kernels_compile_on_2x2(tpu_devices):
     """The shard_map variants the dp x tp serving mesh traces (batch over

@@ -1,0 +1,520 @@
+"""A ``dots3_note`` block (dots3-note-prev), the served forward: LEARNED SPARSE
+attention over a latent cache in its full layers, WINDOWED latent attention
+of its own sizes in its sliding ones, a compressed query and a gate a head in
+both, Moonlight's expert rule behind them. A ``LlamaConfig`` with
+``kv_lora_rank`` AND ``index_topk``; ``llama.forward_paged`` hands its
+arguments on to ``forward_paged`` here on that, as it does a plain latent
+model's to ``models.mla``.
+
+d the hidden size, h = RMSNorm(x; ``norm_eps``). A layer of either kind, at
+ITS OWN sizes (``kinds``: a full layer ``n_heads`` / ``kv_lora_rank`` /
+``q_lora_rank`` / ``qk_*`` / ``v_head_dim`` / ``rope_theta``, a sliding layer
+the ``swa_*`` ones):
+
+    cq = RMSNorm(h W_qa; g_q) rho_q        rho_q  = (d / Cq)^0.5 (``lora_rescale``)
+    q  = cq W_qb                            H heads of [q_n (dn) | q_r (dr)]
+    [c' | r'] = h W_kva;  c = RMSNorm(c'; g_kv) rho_kv,  rho_kv = (d / C)^0.5
+    r = RoPE(r'), q_r = RoPE(q_r)           interleaved pairs, the kind's theta
+    [k_n | v]_head = c W_kvb
+    score[t, s] = (q_n . k_n + q_r . r)(dn + dr)^-0.5 over the keys s the
+    query MAY SEE;  o_head = sum softmax v;  g = sigmoid(h W_g) (H wide);
+    x += concat(g_head o_head) W_o
+
+A SLIDING layer sees t - (``sliding_window`` - 1) <= s <= t. A FULL layer sees
+the ``index_topk`` keys s <= t its indexer scores highest (all of them while
+t < ``index_topk``): with Hi = ``index_n_heads`` heads of di =
+``index_head_dim``,
+
+    qI = cq W_qI;  kI = LayerNorm(h W_kI)   ONE key of di a token
+    RoPE on the first dr values of each qI head and of kI (the layer's theta)
+    w = h W_w Hi^-0.5 di^-0.5               Hi a token
+    I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])
+
+SERVED, every path attends ABSORBED as ``models.mla`` does (rho_kv is folded
+into the cached c), through ONE kernel, ``ops.sparse_latent`` (``sparse_latent_attention`` / ``window_latent_attention``),
+over a key set gathered for its queries:
+
+- a full layer's positions, the REAL ones first (``llama.ffn_pack_index``),
+  go tile by tile (16 slots) — as many tiles as hold real positions — through
+  ``ops.indexer_scores`` (every pool block scored once for all of them), a
+  read of each position's own blocks out of that by its table, ``top_k``, a
+  gather of the chosen (c, r) rows, and the kernel with a position's H heads
+  as a group;
+- a sliding layer's rows gather the few blocks that hold their window and
+  the kernel takes a row's T x H queries as a group (a prefill wider than
+  ``MAX_BLOCK_DECODE_T`` is cut into rows of 8 positions first).
+
+One path whatever T is: a decode step, a fast-forward block, a suffix behind
+the cached prefix and a chunk of the prefix itself.
+
+The pool is a pytree a kind: ``k_pool`` {"kv": the full layers' latents (Lf,
+N, bs, C), "swa": the sliding layers' (Ls, N, bs, Cs), "idx": the full
+layers' index keys (Lf, N, bs, di)}, ``v_pool`` {"kv", "swa"}: the rotated
+keys. All ride ONE block table. Parameters: ``attn_full`` / ``attn_swa``
+stack the attention leaves by kind, ``dense_layers`` / ``layers`` the
+feed-forward ones and the norms (``models.mla``'s).
+"""
+
+from __future__ import annotations
+
+from dataclasses import replace
+from functools import partial
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from .llama import (MAX_BLOCK_DECODE_T, LlamaConfig, _attn_stats, _ffn, _qe, _scan_and_whole,
+                    _EXPERT_LEAVES, apply_rope_interleaved, ffn_pack_index, layer_norm,
+                    packed_ffn, rms_norm, rope_tables)
+from .mla import LATENT_STATS, _dense_ffn
+
+F32 = jnp.float32
+
+# what this forward counts behind ``mla.LATENT_STATS`` (one vector, summed
+# and published like them, ``attn.<name>``): pool positions the indexer
+# scored (positions x the plane), keys the real positions of full layers
+# could see and the keys they attended, cached positions the sliding layers'
+# window gathers read
+SPARSE_STATS = ("index_keys_scored", "keys_visible", "keys_selected", "window_keys_read")
+INDEX_NORM_EPS = 1e-6
+
+
+class Kind(NamedTuple):
+    """One kind of layer's attention sizes."""
+
+    H: int
+    dn: int
+    dr: int
+    dv: int
+    Cq: int
+    C: int
+    theta: float
+    window: int | None
+    indexed: bool
+
+
+def kinds(cfg: LlamaConfig) -> dict[str, Kind]:
+    return {"full": Kind(cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim,
+                         cfg.q_lora_rank, cfg.kv_lora_rank, cfg.rope_theta, None, True),
+            "sliding": Kind(cfg.swa_n_heads, cfg.swa_qk_nope_dim, cfg.swa_qk_rope_dim,
+                            cfg.swa_v_head_dim, cfg.swa_q_lora_rank or cfg.q_lora_rank,
+                            cfg.swa_kv_lora_rank, cfg.swa_rope_theta, cfg.sliding_window, False)}
+
+
+def layer_plan(cfg: LlamaConfig) -> tuple[tuple[str, int], ...]:
+    """(kind, index among the layers of that kind) of every layer."""
+    seen = {"full": 0, "sliding": 0}
+    plan = []
+    for t in cfg.layer_types:
+        plan.append((t, seen[t]))
+        seen[t] += 1
+    return tuple(plan)
+
+
+def latent_stat_names() -> tuple[str, ...]:
+    return LATENT_STATS + SPARSE_STATS
+
+
+def cache_spec(cfg: LlamaConfig) -> dict:
+    """What a token holds in the pool, by layer KIND: ``planes`` names each
+    pool's planes as (layers of the kind, width); ``kv_layers`` /
+    ``latent_dim`` / ``rope_dim`` are the full layers'."""
+    k = kinds(cfg)
+    n = {t: cfg.layer_types.count(t) for t in ("full", "sliding")}
+    planes = {"k": {"kv": (n["full"], k["full"].C), "idx": (n["full"], cfg.index_head_dim)},
+              "v": {"kv": (n["full"], k["full"].dr)}}
+    if n["sliding"]:
+        planes["k"]["swa"] = (n["sliding"], k["sliding"].C)
+        planes["v"]["swa"] = (n["sliding"], k["sliding"].dr)
+    return {"kv_layers": n["full"], "latent_dim": k["full"].C, "rope_dim": k["full"].dr,
+            "planes": planes,
+            "token_bytes": 2 * sum(L * w for pool in planes.values() for L, w in pool.values())}
+
+
+# ---------------------------------------------------------------- params
+
+
+def attn_shapes(cfg: LlamaConfig, kind: str) -> dict:
+    """The attention matrices of one layer of ``kind``, (fan_in, fan_out)."""
+    d, k = cfg.dim, kinds(cfg)[kind]
+    out = {"w_qa": (d, k.Cq), "w_qb": (k.Cq, k.H * (k.dn + k.dr)), "w_kva": (d, k.C + k.dr),
+           "w_kvb": (k.C, k.H * (k.dn + k.dv)), "wo": (k.H * k.dv, d)}
+    if cfg.attn_gate:
+        out["w_hgate"] = (d, k.H)
+    if k.indexed:
+        out.update(w_iq=(k.Cq, cfg.index_n_heads * cfg.index_head_dim),
+                   w_ik=(d, cfg.index_head_dim), w_iw=(d, cfg.index_n_heads))
+    return out
+
+
+def attn_norms(cfg: LlamaConfig, kind: str, L: int, dtype=jnp.bfloat16) -> dict:
+    k = kinds(cfg)[kind]
+    out = {"q_norm": jnp.ones((L, k.Cq), dtype), "kv_norm": jnp.ones((L, k.C), dtype)}
+    if k.indexed:
+        out["ik_norm"] = jnp.ones((L, cfg.index_head_dim), dtype)
+    return out
+
+
+def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
+    """Random init in ``mla.init_params``' recipe (normal, fan_in^-0.5; gains
+    1; a NONZERO router bias), the matrices behind a rescaled rank at d^-0.5."""
+    k_embed, k_dense, k_routed, k_head, k_full, k_swa = jax.random.split(key, 6)
+    d, f, E = cfg.dim, cfg.ffn_dim, cfg.n_experts
+    n_dense, n_routed = cfg.first_dense_layers, cfg.n_layers - cfg.first_dense_layers
+
+    def w(key, *shape, fan=None):
+        return (jax.random.normal(key, shape, F32) * (fan or shape[-2]) ** -0.5).astype(dtype)
+
+    # a matrix that reads a RESCALED rank has the hidden size's fan-in: its
+    # input's mean square is d / rank, not 1
+    rescaled = ("w_qb", "w_iq", "w_kvb") if cfg.lora_rescale else ()
+
+    def stack(key, L, names: dict) -> dict:
+        ks = jax.random.split(key, len(names))
+        return {n: w(k, L, *s, fan=d if n in rescaled else None) for (n, s), k in zip(names.items(), ks)}
+
+    norms = lambda L: {"attn_norm": jnp.ones((L, d), dtype), "mlp_norm": jnp.ones((L, d), dtype)}
+    fd, sf = cfg.dense_ffn_dim, cfg.n_shared_experts * f
+    routed = {**stack(k_routed, n_routed, {
+        "router": (d, E), "moe_gate": (cfg.n_held, d, f), "moe_up": (cfg.n_held, d, f),
+        "moe_down": (cfg.n_held, f, d),
+        **({"shared_gate": (d, sf), "shared_up": (d, sf), "shared_down": (sf, d)} if sf else {})}),
+        **norms(n_routed)}
+    if cfg.router_bias:
+        routed["router_bias"] = 0.1 * jax.random.normal(
+            jax.random.fold_in(k_routed, 1), (n_routed, E), F32)
+    params = {"embed": w(k_embed, cfg.vocab_size, d, fan=d), "layers": routed,
+              "final_norm": jnp.ones((d,), dtype), "lm_head": w(k_head, d, cfg.vocab_size)}
+    if n_dense:
+        params["dense_layers"] = {**stack(k_dense, n_dense, {
+            "w_gate": (d, fd), "w_up": (d, fd), "w_down": (fd, d)}), **norms(n_dense)}
+    for kind, kk in (("full", k_full), ("sliding", k_swa)):
+        L = cfg.layer_types.count(kind)
+        if L:
+            params["attn_" + ("swa" if kind == "sliding" else kind)] = {
+                **stack(kk, L, attn_shapes(cfg, kind)), **attn_norms(cfg, kind, L, dtype)}
+    return params
+
+
+# ---------------------------------------------------------------- layers
+
+
+def _split_kvb(leaf, k: Kind):
+    """W_kvb (C, H x (dn + dv)), int8 {"q", "s"} or plain -> (W_UK (C, H, dn),
+    its per-column scale (H, dn) or None, W_UV (C, H, dv), its scale)."""
+    heads = lambda a: a.reshape(*a.shape[:-1], k.H, k.dn + k.dv)
+    if isinstance(leaf, dict):
+        q, s = heads(leaf["q"]), heads(leaf["s"])[0]
+        return q[..., :k.dn], s[:, :k.dn], q[..., k.dn:], s[:, k.dn:]
+    w = heads(leaf)
+    return w[..., :k.dn], None, w[..., k.dn:], None
+
+
+def _rope_head(x, cos, sin, dr: int):
+    """RoPE on the first ``dr`` values of the last axis of x (B, T, H, w)."""
+    return jnp.concatenate([apply_rope_interleaved(x[..., :dr], cos, sin), x[..., dr:]], axis=-1)
+
+
+def latent_qkv(p, x, cfg: LlamaConfig, k: Kind, cos, sin):
+    """The front half of a layer -> (q_c (B, T, H, C) with W_UK absorbed, q_r
+    (B, T, H, dr) rotated, c (B, T, C) normed and rescaled, r (B, T, dr)
+    rotated, gate (B, T, H) float32 or None, the indexer's (qI (B, T, Hi,
+    di), kI (B, T, di), w (B, T, Hi) float32) or None)."""
+    B, T = x.shape[:2]
+    rescale = lambda rank: (cfg.dim / rank) ** 0.5 if cfg.lora_rescale else 1.0
+    with jax.named_scope("layer/attn_qkv"):
+        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+        with jax.named_scope("layer/attn/q_lora"):
+            cq = rms_norm(_qe("btd,dc->btc", h, p["w_qa"]).astype(x.dtype), p["q_norm"],
+                          cfg.latent_norm_eps)
+            cq = (cq * rescale(k.Cq)).astype(x.dtype)
+            q = _qe("btc,ch->bth", cq, p["w_qb"]).astype(x.dtype).reshape(B, T, k.H, k.dn + k.dr)
+        with jax.named_scope("kv_a"):
+            cr = _qe("btd,dh->bth", h, p["w_kva"]).astype(x.dtype)
+            c = rms_norm(cr[..., :k.C], p["kv_norm"], cfg.latent_norm_eps)
+            c = (c * rescale(k.C)).astype(x.dtype)
+            r = apply_rope_interleaved(cr[..., None, k.C:], cos, sin)[:, :, 0]
+        q_r = apply_rope_interleaved(q[..., k.dn:], cos, sin)
+        with jax.named_scope("q_absorb"):
+            w_uk, s_k, _, _ = _split_kvb(p["w_kvb"], k)
+            q_n = q[..., :k.dn]
+            if s_k is not None:  # a scale a column of W_UK: on the query, which contracts it
+                q_n = (q_n.astype(F32) * s_k).astype(x.dtype)
+            q_c = jnp.einsum("bthn,chn->bthc", q_n, w_uk.astype(x.dtype),
+                             preferred_element_type=F32).astype(x.dtype)
+    gate = index = None
+    if cfg.attn_gate:
+        with jax.named_scope("layer/attn/gate"):
+            gate = jax.nn.sigmoid(_qe("btd,dh->bth", h, p["w_hgate"]))
+    if k.indexed:
+        Hi, di = cfg.index_n_heads, cfg.index_head_dim
+        with jax.named_scope("layer/attn/index"), jax.named_scope("project"):
+            qi = _qe("btc,ch->bth", cq, p["w_iq"]).astype(x.dtype).reshape(B, T, Hi, di)
+            qi = _rope_head(qi, cos, sin, k.dr)
+            ki = layer_norm(_qe("btd,dh->bth", h, p["w_ik"]).astype(x.dtype), p["ik_norm"],
+                            INDEX_NORM_EPS)
+            ki = _rope_head(ki[:, :, None, :], cos, sin, k.dr)[:, :, 0]
+            wi = _qe("btd,dh->bth", h, p["w_iw"]) * (Hi ** -0.5 * di ** -0.5)
+        index = (qi, ki, wi)
+    return q_c, q_r, c, r, gate, index
+
+
+def latent_out(p, a, k: Kind, gate, dtype):
+    """(B, T, H, C) attended latents -> (B, T, H * dv): W_UV a head, then the
+    head's gate."""
+    with jax.named_scope("layer/attn_out"), jax.named_scope("v_up"):
+        _, _, w_uv, s_v = _split_kvb(p["w_kvb"], k)
+        o = jnp.einsum("bthc,chv->bthv", a.astype(dtype), w_uv.astype(dtype),
+                       preferred_element_type=F32)
+        if s_v is not None:
+            o = o * s_v
+    if gate is not None:
+        with jax.named_scope("layer/attn/gate"):
+            o = o * gate[..., None]
+    return o.astype(dtype).reshape(*a.shape[:2], -1)
+
+
+def _position_tile(P: int) -> int:
+    """Positions a pass of a full layer's attention takes: the largest
+    divisor of P up to 16 (a fast-forward block of 32 rows, a suffix group
+    and the prefix's chunks: 16; the compacted width's 72: 12). Small,
+    because a pass GATHERS ``index_topk`` rows of the cache for every slot
+    of its tile, real or not, and the gather is most of a full layer's
+    attention: ~45 real positions of a block's 288 take three tiles."""
+    return max(t for t in range(1, min(P, 16) + 1) if P % t == 0)
+
+
+# ---------------------------------------------------------------- forward
+
+
+def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, block_tables, *,
+                  attn_impl: str = "pallas", write_mask=None, trash_idx=None,
+                  gather_blocks: int | None = None, n_real=None, logit_pos=None,
+                  moe_stats: bool = False, attn_stats: bool = False,
+                  latent_stats: bool = False, ffn_pack: int = 0, fault: str | None = None):
+    """``llama.forward_paged`` for this model: ``k_pool`` / ``v_pool`` the
+    pytrees the module's text names. -> (logits, k_pool, v_pool, None, None),
+    then with ``moe_stats`` the routed layers' counts, with ``attn_stats``
+    ``ops.ATTN_STATS``, with ``latent_stats`` ``latent_stat_names()`` over all
+    layers, with a packed MLP ``llama.FFN_STATS``. ``fault`` PLANTS one, for
+    the comparison's limit to be set against (``FAULTS``); None everywhere
+    else."""
+    from ..ops import sparse_latent as sl
+
+    pallas = attn_impl == "pallas"
+    index_fn = sl.indexer_scores if pallas else sl.indexer_scores_reference
+    twin = sl.gathered_latent_attention_reference
+    attend_full = sl.sparse_latent_attention if pallas else twin
+    attend_window = sl.window_latent_attention if pallas else twin
+    if fault is not None and fault not in FAULTS:
+        raise ValueError(f"fault {fault!r}: one of {FAULTS}")
+
+    B, T = tokens.shape
+    cp, rp, ip = k_pool["kv"], v_pool["kv"], k_pool["idx"]
+    cps, rps = k_pool.get("swa"), v_pool.get("swa")
+    N, bs = cp.shape[1], cp.shape[2]
+    ncols = block_tables.shape[1]
+    nb = min(gather_blocks, ncols) if gather_blocks is not None else ncols
+    kd = kinds(cfg)
+    if fault == "no_rescale":
+        cfg = replace(cfg, lora_rescale=False)
+    n_dense = cfg.first_dense_layers
+    P = B * T
+    alive = jnp.ones((B,), bool) if write_mask is None else write_mask
+
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+        rope = {t: rope_tables(positions, k.dr, k.theta) for t, k in kd.items()}
+    blk = jnp.take_along_axis(block_tables, positions // bs, axis=1)
+    off = positions % bs
+    if write_mask is not None:
+        park = jnp.zeros((B,), jnp.int32) if trash_idx is None else trash_idx.astype(jnp.int32)
+        blk = jnp.where(write_mask[:, None], blk, park[:, None] // bs)
+        off = jnp.where(write_mask[:, None], off, park[:, None] % bs)
+
+    pack = None
+    live_n = None
+    if n_real is not None:
+        live_n = n_real if write_mask is None else jnp.where(write_mask, n_real, 0)
+        if ffn_pack and P > ffn_pack:
+            with jax.named_scope("layer/ffn/pack"):
+                pack = ffn_pack_index(live_n, T, ffn_pack)
+
+    # ---- a full layer's geometry, once a forward: the real positions first
+    K = min(cfg.index_topk, nb * bs)
+    tile = _position_tile(P)
+    with jax.named_scope("layer/attn/split"):
+        if live_n is not None:
+            order = ffn_pack_index(live_n, T, P)  # every position has a slot: it always fits
+            idx, inv = order.idx, order.inv.reshape(-1)
+            n_pos = jnp.sum(jnp.clip(live_n, 0, T)).astype(jnp.int32)
+            n_tiles = jnp.maximum(-(-n_pos // tile), 1)
+        else:
+            idx = inv = jnp.arange(P, dtype=jnp.int32)
+            n_pos = jnp.sum(alive).astype(jnp.int32) * T
+            n_tiles = P // tile
+        pos_of = positions.reshape(-1)[idx]  # (P,)
+        tbl_of = block_tables[idx // T, :nb]  # (P, nb)
+        real = jnp.arange(P) < n_pos if live_n is not None else jnp.repeat(alive, T)[idx]
+
+        # ---- a sliding layer's: rows of at most MAX_BLOCK_DECODE_T positions
+        # and the blocks that hold their window
+        Tq = 8 if T > MAX_BLOCK_DECODE_T and T % 8 == 0 else T
+        G = P // Tq
+        pos_g = positions.reshape(G, Tq)
+        tbl_g = jnp.repeat(block_tables, T // Tq, axis=0)
+        window = kd["sliding"].window if fault != "no_window" else None
+        reach = (window - 1) if window else ncols * bs
+        WB = min(-(-(reach + Tq - 1) // bs) + 1, ncols)
+        first = jnp.maximum(jnp.min(pos_g, axis=1) - reach, 0) // bs  # (G,)
+        cols = first[:, None] + jnp.arange(WB, dtype=jnp.int32)[None, :]
+        wblk = jnp.take_along_axis(tbl_g, jnp.minimum(cols, ncols - 1), axis=1)  # (G, WB)
+        kpos_w = jnp.where(cols[:, :, None] < ncols,
+                           cols[:, :, None] * bs + jnp.arange(bs, dtype=jnp.int32), -1)
+        kpos_w = kpos_w.reshape(G, WB * bs)
+        hi_w = jnp.repeat(pos_g, kd["sliding"].H, axis=1)  # (G, Tq * Hs), position-major
+        lo_w = jnp.maximum(hi_w - reach, 0)
+
+    def full_attention(li, q_c, q_r, index, cp, rp, ip):
+        k = kd["full"]
+        qi, _, wi = index
+        order_of = lambda a: a.reshape(P, *a.shape[2:])[idx]
+        qc_f, qr_f, qi_f, wi_f = (order_of(a) for a in (q_c, q_r, qi, wi))
+        scale = (k.dn + k.dr) ** -0.5
+
+        def one_tile(i, out):
+            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, i * tile, tile)
+            ps, tb = cut(pos_of), cut(tbl_of)
+            with jax.named_scope("layer/attn/index"):
+                with jax.named_scope("scores"):
+                    scores = index_fn(cut(qi_f), cut(wi_f), ip, li)  # (tile, N * bs)
+                    mine = jnp.take_along_axis(scores.reshape(tile, N, bs), tb[:, :, None],
+                                               axis=1).reshape(tile, nb * bs)
+                    seq = jnp.arange(nb * bs, dtype=jnp.int32)[None, :]
+                    if fault == "first_keys":  # the first K keys, whatever their score
+                        mine = -seq.astype(F32) + jnp.zeros_like(mine)
+                    mine = jnp.where(seq <= ps[:, None], mine, -jnp.inf)
+                with jax.named_scope("top_k"):
+                    _, sel = jax.lax.top_k(mine, K)  # (tile, K) sequence positions
+            with jax.named_scope("layer/attn/select"):
+                # the block of each chosen key out of the slot's table: a compare
+                # and a sum over its few columns (a gather of scalars costs more
+                # than the rows it names)
+                col = (sel // bs)[:, :, None] == jnp.arange(nb, dtype=jnp.int32)
+                sblk = jnp.sum(jnp.where(col, tb[:, None, :], 0), axis=-1)
+                c_sel, r_sel = cp[li][sblk, sel % bs], rp[li][sblk, sel % bs]
+            with jax.named_scope("layer/attn/full"):
+                a = attend_full(cut(qc_f), cut(qr_f), c_sel, r_sel, sel,
+                                jnp.zeros((tile, k.H), jnp.int32),
+                                jnp.broadcast_to(ps[:, None], (tile, k.H)), scale=scale)
+            return jax.lax.dynamic_update_slice_in_dim(out, a, i * tile, 0)
+
+        out = jax.lax.fori_loop(0, n_tiles, one_tile, jnp.zeros((P, k.H, k.C), q_c.dtype))
+        return out[inv].reshape(B, T, k.H, k.C)
+
+    def dense_attention(li, q_c, q_r, cp, rp):
+        """The planted fault ``no_selection``: a full layer over every key."""
+        k = kd["full"]
+        g = lambda a: a.reshape(B, T * k.H, a.shape[-1])
+        c_all = cp[li][block_tables[:, :nb]].reshape(B, nb * bs, k.C)
+        r_all = rp[li][block_tables[:, :nb]].reshape(B, nb * bs, k.dr)
+        hi = jnp.repeat(positions, k.H, axis=1)
+        a = twin(
+            g(q_c), g(q_r), c_all, r_all,
+            jnp.broadcast_to(jnp.arange(nb * bs, dtype=jnp.int32), (B, nb * bs)),
+            jnp.zeros_like(hi), hi, scale=(k.dn + k.dr) ** -0.5)
+        return a.reshape(B, T, k.H, k.C)
+
+    def window_attention(li, q_c, q_r, cps, rps):
+        k = kd["sliding"]
+        g = lambda a: a.reshape(G, Tq * k.H, a.shape[-1])
+        with jax.named_scope("layer/attn/window"):
+            with jax.named_scope("gather"):
+                c_w = cps[li][wblk].reshape(G, WB * bs, k.C)
+                r_w = rps[li][wblk].reshape(G, WB * bs, k.dr)
+            # (with the window planted away a row's whole context is one tile: XLA)
+            a = (attend_window if window else twin)(
+                g(q_c), g(q_r), c_w, r_w, kpos_w, lo_w, hi_w, scale=(k.dn + k.dr) ** -0.5)
+        return a.reshape(B, T, k.H, k.C)
+
+    scanned, whole = _scan_and_whole(params["layers"], cfg, packed=pack is not None)
+    stacked = () if pack is None else tuple(
+        k for k in whole if not (cfg.moe_impl == "grouped" and k in _EXPERT_LEAVES))
+    stats = []
+    for L, (kind, ki) in enumerate(layer_plan(cfg)):
+        k = kd[kind]
+        li = jnp.int32(ki)
+        attn_p = jax.tree.map(lambda a: a[ki], params["attn_full" if k.indexed else "attn_swa"])
+        if L < n_dense:
+            ffn_p = jax.tree.map(lambda a: a[L], params["dense_layers"])
+            ffn = partial(_dense_ffn, cfg=cfg)
+        else:
+            j = L - n_dense
+            ffn_p = {**jax.tree.map(lambda a: a[j], scanned), **whole, "layer": jnp.int32(j),
+                     **({"stacked": stacked} if stacked else {})}
+            ffn = partial(_ffn, cfg=cfg)
+        p = {**attn_p, **ffn_p}
+        q_c, q_r, c, r, gate, index = latent_qkv(p, x, cfg, k, *rope[kind])
+        if fault == "no_gate":
+            gate = None
+        with jax.named_scope("layer/kv_write"):
+            if k.indexed:
+                cp = cp.at[li, blk, off].set(c.astype(cp.dtype))
+                rp = rp.at[li, blk, off].set(r.astype(rp.dtype))
+                ip = ip.at[li, blk, off].set(index[1].astype(ip.dtype))
+            else:
+                cps = cps.at[li, blk, off].set(c.astype(cps.dtype))
+                rps = rps.at[li, blk, off].set(r.astype(rps.dtype))
+        with jax.named_scope("layer/attn"):
+            if not k.indexed:
+                a = window_attention(li, q_c, q_r, cps, rps)
+            elif fault == "no_selection":
+                a = dense_attention(li, q_c, q_r, cp, rp)
+            else:
+                a = full_attention(li, q_c, q_r, index, cp, rp, ip)
+        attn = latent_out(p, a, k, gate, x.dtype)
+        with jax.named_scope("layer/attn_out"):
+            x = x + _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
+        with jax.named_scope("layer/ffn"):
+            h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+        y, st = packed_ffn(partial(ffn, p), h, pack)
+        with jax.named_scope("layer/ffn"):
+            x = x + y
+        if st is not None:
+            stats.append(st)
+
+    with jax.named_scope("final_norm"):
+        if logit_pos is not None:  # the head on the one position a row reads
+            x = jnp.take_along_axis(x, logit_pos[:, None, None], axis=1)
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        logits = _qe("btd,dv->btv", x, params["lm_head"])
+    k_pool = {**k_pool, "kv": cp, "idx": ip, **({} if cps is None else {"swa": cps})}
+    v_pool = {**v_pool, "kv": rp, **({} if rps is None else {"swa": rps})}
+    extra = (sum(stats),) if moe_stats else ()
+    if attn_stats or latent_stats:
+        if attn_stats:
+            extra += (_attn_stats(None, False, None, block_tables, positions, write_mask, bs),)
+        if latent_stats:
+            n_full, n_swa = (cfg.layer_types.count(t) for t in ("full", "sliding"))
+            n_alive = jnp.sum(alive).astype(jnp.int32)
+            scored = n_tiles * tile
+            seen = jnp.sum(jnp.where(real, pos_of + 1, 0))
+            chosen = jnp.sum(jnp.where(real, jnp.minimum(pos_of + 1, K), 0))
+            win = n_alive * (T // Tq) * WB * bs
+            extra += (jnp.stack([
+                n_full * scored * K + n_swa * win,
+                n_alive * T * (n_full * kd["full"].H + n_swa * kd["sliding"].H),
+                n_full * scored * (N * bs), n_full * seen, n_full * chosen,
+                n_swa * win]).astype(jnp.int32),)
+    if pack is not None:
+        extra += (pack.stats,)
+    return (logits, k_pool, v_pool, None, None, *extra)
+
+
+# faults of this block's own mechanisms, planted in the served program for
+# the comparison's limit to be set against (``benchmark/tools``): dense
+# attention over every key, the first ``index_topk`` keys instead of the
+# best, no window, no gate, no rescale
+FAULTS = ("no_selection", "first_keys", "no_window", "no_gate", "no_rescale")

@@ -122,6 +122,33 @@ class LlamaConfig:
     router_scale: float = 1.0
     # the shared experts' outputs are ADDED to the routed sum, not averaged
     shared_sum: bool = False
+    # ---- LEARNED SPARSE attention over a latent cache, beside WINDOWED latent
+    # attention of its own sizes (``dots3_note``; ``models.dots3`` has the
+    # equations and the forward: ``index_topk`` > 0 names that forward). With
+    # ``kv_lora_rank`` the two ``layer_types`` take ANOTHER rule than a K/V
+    # model's: BOTH kinds rotate, each at its own theta; a "full" layer is the
+    # latent attention above behind an indexer — ``index_n_heads`` heads of
+    # ``index_head_dim`` score every visible key from ONE cached index key a
+    # token, and a query attends the ``index_topk`` best — and a "sliding"
+    # layer is a second latent attention with the ``swa_*`` sizes under
+    # ``sliding_window`` (the query's own position and the window - 1 before)
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # the query is compressed too: h W_qa -> RMSNorm -> W_qb (0: one matrix)
+    q_lora_rank: int = 0
+    # the normed compressed query and latent are rescaled by (dim / rank)^0.5
+    lora_rescale: bool = False
+    # a sigmoid gate a head, from the layer's normed input, on the head's
+    # output before the output projection
+    attn_gate: bool = False
+    swa_n_heads: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_q_lora_rank: int = 0
+    swa_qk_nope_dim: int = 0
+    swa_qk_rope_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
 
     def __post_init__(self):
         if self.layer_types and (len(self.layer_types) != self.n_layers or
@@ -130,6 +157,24 @@ class LlamaConfig:
                              f"{self.n_layers} layers, got {self.layer_types}")
         if "sliding" in self.layer_types and self.sliding_window <= 0:
             raise ValueError("sliding layers need a sliding_window")
+        if self.index_topk and not (self.kv_lora_rank and self.layer_types and self.index_n_heads
+                                    and self.index_head_dim >= self.qk_rope_dim):
+            raise ValueError("learned sparse attention: over a latent cache (kv_lora_rank), with "
+                             "layer_types, index_n_heads and an index_head_dim that holds the "
+                             "rotated width")
+        if self.kv_lora_rank and self.layer_types and not self.index_topk:
+            raise NotImplementedError("layer kinds inside a latent model are models.dots3's "
+                                      "forward's: one with an indexer (index_topk)")
+        if (self.q_lora_rank or self.attn_gate or self.lora_rescale) and not self.index_topk:
+            raise NotImplementedError("a compressed query, a gate a head and the rank rescale "
+                                      "are models.dots3's forward's (index_topk)")
+        if self.index_topk and not self.q_lora_rank:
+            raise NotImplementedError("models.dots3's indexer reads the compressed query: "
+                                      "a q_lora_rank")
+        if self.index_topk and "sliding" in self.layer_types and not (
+                self.swa_n_heads and self.swa_kv_lora_rank and self.swa_qk_nope_dim
+                and self.swa_qk_rope_dim and self.swa_v_head_dim and self.swa_rope_theta):
+            raise ValueError("sliding latent layers: the swa_* sizes and swa_rope_theta")
         held = self.experts_held
         if held and not self.first_expert + held <= self.n_experts:
             raise ValueError(f"experts held {self.first_expert}..{self.first_expert + held} "
@@ -224,9 +269,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
 
         return sambay.init_params(cfg, key, dtype)
     if cfg.kv_lora_rank:
-        from . import mla
+        from . import dots3, mla
 
-        return mla.init_params(cfg, key, dtype)
+        return (dots3 if cfg.index_topk else mla).init_params(cfg, key, dtype)
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     d, f, hd = cfg.dim, cfg.ffn_dim, cfg.head_dim
     nq, nkv, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
@@ -359,6 +404,8 @@ def quantize_params(params: dict) -> dict:
         "layers": layers(params["layers"]),
         # a latent model's leading dense layers, stacked apart (models.mla)
         **({"dense_layers": layers(params["dense_layers"])} if "dense_layers" in params else {}),
+        # attention leaves stacked by layer KIND (models.dots3)
+        **{k: layers(params[k]) for k in ("attn_full", "attn_swa") if k in params},
         "final_norm": params["final_norm"],
         # a tied head: an int8 copy of the embedding, a scale a vocabulary row
         "lm_head": quant(_w(params["lm_head"]) if "lm_head" in params else params["embed"].T),
@@ -1143,12 +1190,19 @@ def forward_paged(
             gather_blocks=gather_blocks, n_real=n_real, logit_pos=logit_pos,
             hybrid_stats=hybrid_stats, attn_stats=attn_stats)
     if cfg.kv_lora_rank:
-        from . import mla
+        from . import dots3, mla
 
         if rules is not None or kv_quant is not None:
             raise mla.LatentCacheOnly(
                 "a mesh shards, and KV_QUANT re-stores, K and V planes by head: a latent "
                 "cache has neither planes nor heads")
+        if cfg.index_topk:  # learned sparse + windowed latent attention: its own forward
+            return dots3.forward_paged(
+                params, cfg, tokens, positions, k_pool, v_pool, block_tables,
+                attn_impl=attn_impl, write_mask=write_mask, trash_idx=trash_idx,
+                gather_blocks=gather_blocks, n_real=n_real, logit_pos=logit_pos,
+                moe_stats=moe_stats, attn_stats=attn_stats, latent_stats=latent_stats,
+                ffn_pack=ffn_pack)
         return mla.forward_paged(
             params, cfg, tokens, positions, k_pool, v_pool, block_tables,
             attn_impl=attn_impl, write_mask=write_mask, trash_idx=trash_idx,

@@ -59,12 +59,27 @@ LATENT_STATS = ("latent_keys_read", "latent_query_rows")
 class LatentCacheOnly(ValueError):
     """A serving feature that reads, moves, shares, re-stores or shards K and
     V planes by head was asked of a model whose cache is a latent and a shared
-    rotated key: it has no such planes."""
+    rotated key (and, behind an indexer, an index key): it has no such planes."""
 
 
 def cache_spec(cfg: LlamaConfig) -> dict:
-    """What a token holds in the pool a layer: the two planes' widths."""
+    """What a token holds in the pool a layer: the two planes' widths. A model
+    with layers of more than one KIND (``models.dots3``) answers by kind,
+    under ``planes``."""
+    if cfg.index_topk:
+        from . import dots3
+
+        return dots3.cache_spec(cfg)
     return {"kv_layers": cfg.n_layers, "latent_dim": cfg.kv_lora_rank, "rope_dim": cfg.qk_rope_dim}
+
+
+def latent_stat_names(cfg) -> tuple[str, ...]:
+    """What a latent forward of this model counts, in order."""
+    if getattr(cfg, "index_topk", 0):
+        from . import dots3
+
+        return dots3.latent_stat_names()
+    return LATENT_STATS
 
 
 # ---------------------------------------------------------------- params

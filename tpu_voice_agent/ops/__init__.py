@@ -16,6 +16,11 @@ Here the hot ops of the in-tree models get hand-written Pallas kernels:
 - ``paged_latent_attention``: absorbed attention over a paged LATENT cache —
   one (block, kv_lora_rank) tile serves scores and values, every head a query
   row (``models.mla``)
+- ``indexer_scores`` / ``sparse_latent_attention`` / ``window_latent_attention``: learned sparse
+  attention over a latent cache — the indexer that scores every pool
+  position of a layer's index-key plane, and absorbed attention over a key
+  set GATHERED for its queries (a position's selected keys, or the blocks
+  that hold a row's window; ``models.dots3``)
 
 Every kernel has a pure-jnp reference twin (``*_reference``) that the
 correctness tests and ``chip_smoke.py`` compare it against; kernels run
@@ -66,6 +71,13 @@ from .latent_attention import (
     latent_row_splits,
     paged_latent_attention,
     paged_latent_attention_reference,
+)
+from .sparse_latent import (
+    gathered_latent_attention_reference,
+    indexer_scores,
+    indexer_scores_reference,
+    sparse_latent_attention,
+    window_latent_attention,
 )
 from .paged_attention import (
     ATTN_STATS,
@@ -132,6 +144,11 @@ __all__ = [
     "latent_row_splits",
     "paged_latent_attention",
     "paged_latent_attention_reference",
+    "indexer_scores",
+    "indexer_scores_reference",
+    "sparse_latent_attention",
+    "window_latent_attention",
+    "gathered_latent_attention_reference",
     "paged_block_attention_quant",
     "paged_block_attention_quant_reference",
     "sharded_paged_block_attention",

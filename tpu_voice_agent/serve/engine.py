@@ -1015,11 +1015,12 @@ class DecodeEngine:
         shortest = min(len(e) for e in encs)
         while P < shortest and all(e[P] == encs[0][P] for e in encs):
             P += 1
+        P = self._cached_prefix_len(P)
         if P == 0:
             self.prefix_ids, self.prefix_kv = [], None
             return 0
         ids = list(encs[0][:P])
-        bucket = self._bucket(P)
+        bucket = self._prefix_bucket(P)
         tokens = np.full((1, bucket), self.pad_id, dtype=np.int32)
         tokens[0, :P] = ids
         positions = np.arange(bucket, dtype=np.int32)[None, :]
@@ -1027,6 +1028,13 @@ class DecodeEngine:
             jnp.asarray(tokens), jnp.asarray(positions), P, bucket)
         self.prefix_ids = ids
         return P
+
+    def _cached_prefix_len(self, P: int) -> int:
+        """How much of the common token prefix is cached (all of it)."""
+        return P
+
+    def _prefix_bucket(self, P: int) -> int:
+        return self._bucket(P)
 
     def _compute_prefix_kv(self, tokens, positions, P: int, bucket: int) -> dict:
         """Prefill the prefix into a scratch cache and return its KV in

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..grammar.fsm import fsm_advance
+from ..models.mla import latent_stat_names
 from ..models.llama import (FFN_STATS, _hybrid, forward_paged, latent, moe_stat_names,
                             paged_only)
 from ..utils.compilewatch import get_compile_watcher, watch_compiles
@@ -308,6 +309,13 @@ def _scatter_blocks(k_pool, v_pool, src_k, src_v, dst_idx):
     kp, vp = kv_planes(k_pool), kv_planes(v_pool)
     L, N, bs = kp.shape[0], kp.shape[1], kp.shape[2]
     shp = kp.shape
+    if isinstance(src_k, dict):
+        # a latent cache with planes by layer KIND (models.dots3): every plane
+        # the source names, each of its own layers and width, at the same
+        # (block, offset) — they ride one table
+        at = (slice(None), dst_idx // bs, dst_idx % bs)
+        put = lambda pool, src: {**pool, **{n: pool[n].at[at].set(v) for n, v in src.items()}}
+        return put(k_pool, src_k), put(v_pool, src_v)
     if isinstance(k_pool, dict):
         # a hybrid model's planes are written as they are shaped, (block,
         # offset): XLA relays their flat view out around a scatter (see
@@ -534,12 +542,13 @@ def paged_chunk_decode_loop(
     # configuration) compiles a variant too: one more carry and output after
     # the attention row-blocks, ``mla.LATENT_STATS`` summed over the chunk
     lat = latent(cfg)
+    n_lat = len(latent_stat_names(cfg))  # two; behind an indexer its counts too
     count_kw = {"attn_stats": True, **({"moe_stats": True} if routed else {}),
                 **({"hybrid_stats": True} if hybrid else {}),
                 **({"latent_stats": True} if lat else {})}
     counts0 = (((jnp.zeros((len(moe_stat_names(cfg)) if routed else 4,), jnp.int32),)
                 if routed or hybrid else ()) + (jnp.zeros((2,), jnp.int32),)
-               + ((jnp.zeros((2,), jnp.int32),) if lat else ()))
+               + ((jnp.zeros((n_lat,), jnp.int32),) if lat else ()))
     # the head on the ONE position a row of a 1 + W block reads: the hybrid
     # model, a LlamaConfig with layers of more than one kind and one with a
     # latent cache (the others' programs compute it on all 1 + W, as they
@@ -789,6 +798,9 @@ class PagedDecodeEngine(DecodeEngine):
         # tail and a float32 state for each recurrent layer; here: nothing)
         self.hybrid = _hybrid(self.cfg)
         self.latent = latent(self.cfg)
+        # learned sparse attention over that cache (models.dots3): planes by
+        # layer KIND and an index-key plane, one attention path whatever T is
+        self.sparse = bool(getattr(self.cfg, "index_topk", 0))
         if self.hybrid:
             # ``sambay.forward_paged`` has no packed branch (ROADMAP S3 (e) has
             # what it waits for)
@@ -833,7 +845,12 @@ class PagedDecodeEngine(DecodeEngine):
         dtype = kv_store_dtype(kv_quant)
         shape = (L, pool_blocks, bs, nkv, hdp)
         sshape = (L, pool_blocks, bs, nkv)
-        if self.latent:  # ``bs`` second-minor: a heads axis of one would pad every position sixteenfold
+        if self.sparse:  # planes by layer kind, each (layers of the kind, N, bs, width)
+            self.k_pool, self.v_pool = (
+                {n: jnp.zeros((Lk, pool_blocks, bs, w), jnp.bfloat16) for n, (Lk, w) in planes.items()}
+                for planes in (self._cache_spec["planes"]["k"], self._cache_spec["planes"]["v"]))
+            self.k_scale = self.v_scale = None
+        elif self.latent:  # ``bs`` second-minor: a heads axis of one would pad every position sixteenfold
             self.k_pool, self.v_pool = (
                 jnp.zeros((L, pool_blocks, bs, self._cache_spec[w]), jnp.bfloat16)
                 for w in ("latent_dim", "rope_dim"))
@@ -952,6 +969,8 @@ class PagedDecodeEngine(DecodeEngine):
         from ..ops.kvquant import kv_block_bytes
 
         spec = self._cache_spec
+        if self.sparse:  # every plane of every kind, bf16
+            return self.block_size * spec["token_bytes"]
         if self.latent:  # the two planes' widths, bf16
             return (spec["kv_layers"] * self.block_size
                     * (spec["latent_dim"] + spec["rope_dim"]) * 2)
@@ -983,6 +1002,24 @@ class PagedDecodeEngine(DecodeEngine):
         if not self.hybrid and not paged_only(self.cfg):
             return super()._compute_prefix_kv(tokens, positions, P, bucket)
         bs, spec = self.block_size, self._cache_spec
+        if self.sparse:
+            # a prefix longer than the largest bucket, in chunks of it through
+            # ONE scratch pool: each chunk attends the earlier ones through the
+            # model's own attention path (selection and window from the first
+            # position they bind at), one program for all of them
+            nb = -(-bucket // bs)
+            scratch = lambda pool: {n: jnp.zeros((a.shape[0], nb + 1, *a.shape[2:]), a.dtype)
+                                    for n, a in pool.items()}
+            k, v = scratch(self.k_pool), scratch(self.v_pool)
+            table = jnp.asarray([list(range(1, nb + 1))], jnp.int32)
+            chunk = min(bucket, self.prefill_buckets[-1])
+            for at in range(0, bucket, chunk):
+                _, k, v, _, _ = forward_paged(
+                    self.params, self.cfg, tokens[:, at:at + chunk], positions[:, at:at + chunk],
+                    k, v, table, attn_impl=self.kernels)
+            dense = lambda pool: {n: a[:, 1:].reshape(a.shape[0], 1, nb * bs, a.shape[-1])[:, :, :P]
+                                  for n, a in pool.items()}
+            return {"k": dense(k), "v": dense(v)}
         if self.hybrid:
             from ..models.sambay import cache_spec
 
@@ -1020,13 +1057,24 @@ class PagedDecodeEngine(DecodeEngine):
                 self.k_pool, self.v_pool, snapshot["conv"], snapshot["ssm"], jnp.int32(slot))
         get_metrics().inc("ssm.state_restores")
 
+    def _cached_prefix_len(self, P: int) -> int:
+        """A model with planes by layer kind caches WHOLE blocks of the common
+        prefix (no sub-block tail to scatter plane by plane into every
+        admission's first block): the rest of it is prefilled with the suffix."""
+        return P // self.block_size * self.block_size if self.sparse else P
+
+    def _prefix_bucket(self, P: int) -> int:
+        """Such a model's prefix may pass the largest bucket: whole chunks of it."""
+        top = self.prefill_buckets[-1]
+        return -(-P // top) * top if self.sparse and P > top else self._bucket(P)
+
     def _prefill_kw(self, attn_impl: str, n_real) -> dict:
         """A prefill forward's arguments that follow the model's kind: a
         decoder whose state is K/V alone takes the layout kernel's attention
         path; a hybrid model is told the engine's kernels (its forward picks
         the attention path by T, its scan by this) and how many of the bucket's
         positions are real (its states advance over those alone)."""
-        if not self.hybrid:
+        if not self.hybrid and not self.sparse:
             return {"rules": self.rules, "attn_impl": attn_impl}
         if isinstance(n_real, int):  # one row; a group hands its (A,) array
             n_real = jnp.asarray([n_real], jnp.int32)
@@ -1051,15 +1099,16 @@ class PagedDecodeEngine(DecodeEngine):
             return 0
         bs = self.block_size
         full = P // bs
-        pk = self.prefix_kv["k"][:, 0]  # (L, P, nkv, hd)
-        pv = self.prefix_kv["v"][:, 0]
+        # (L, P, nkv, hd); a model with planes by layer kind: a tree of them
+        pk = jax.tree.map(lambda a: a[:, 0], self.prefix_kv["k"])
+        pv = jax.tree.map(lambda a: a[:, 0], self.prefix_kv["v"])
+        head = lambda planes, n: jax.tree.map(lambda a: a[:, :n], planes)
         if full:
             for g in range(self.dp):
                 self._prefix_blocks[g] = self.allocator.alloc(full, group=g)
                 blocks = np.asarray(self._prefix_blocks[g], np.int32)
                 dst = (blocks[:, None] * bs + np.arange(bs)[None, :]).reshape(-1)
-                self._scatter_pool(pk[:, : full * bs], pv[:, : full * bs],
-                                   jnp.asarray(dst))
+                self._scatter_pool(head(pk, full * bs), head(pv, full * bs), jnp.asarray(dst))
         if P % bs:
             self._prefix_tail = {"k": pk[:, full * bs:], "v": pv[:, full * bs:]}
         if full and self.radix is not None:
@@ -1406,7 +1455,8 @@ class PagedDecodeEngine(DecodeEngine):
                 with span(SLOT_STATE_SPAN):  # the one host→device copy, before the launch
                     staged = jax.device_put((
                         tokens, positions, rows, slots, ns, m_real > 0,
-                        np.maximum(m_real - 1, 0), m_real if self.hybrid else None,
+                        np.maximum(m_real - 1, 0),
+                        m_real if self.hybrid or self.sparse else None,
                         dst.reshape(-1), restore_at))
                 tokens, positions, rows, slots, ns, live, last, n_real, dst, restore_at = staged
                 with span(PREFILL_CALL_SPAN):
@@ -1416,7 +1466,7 @@ class PagedDecodeEngine(DecodeEngine):
                             self.block_tables, rows, slots, ns, live, last, n_real,
                             self._prefix_tail, dst, self._prefix_state if self.hybrid else None,
                             restore_at, state, pick_args, rules=self.rules,
-                            attn_impl=self.kernels if self.hybrid else "xla",
+                            attn_impl=self.kernels if self.hybrid or self.sparse else "xla",
                             gather_blocks=self._gather_bucket(P, bucket),
                             pick=pick, pick_kw=pick_kw)
                 ms = (time.perf_counter() - t0) * 1e3 / n

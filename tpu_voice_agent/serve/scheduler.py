@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.llama import MOE_SHARE_STATS
+from ..models.mla import latent_stat_names
 from ..ops import ATTN_STATS
 from ..utils.compilewatch import watch_compiles
 from ..utils.steplog import ALLOC_SPAN, REQUEST_SPAN, span
@@ -1350,9 +1351,10 @@ class ContinuousBatcher:
         if latent_h is not None:
             # a latent cache: cached positions attention read (a common block
             # once) and query rows x heads it served (``mla.LATENT_STATS``)
-            keys, qrows = (float(v) for v in np.asarray(latent_h))
-            m.inc("attn.latent_keys_read", keys)
-            m.inc("attn.latent_query_rows", qrows)
+            # (behind an indexer ``dots3.SPARSE_STATS`` too: what it scored,
+            # what positions could see and attended, what the windows read)
+            for name, v in zip(latent_stat_names(self.engine.cfg), np.asarray(latent_h)):
+                m.inc(f"attn.{name}", float(v))
         if ffn_h is not None:
             # forwards whose MLPs ran on the real positions packed, and the
             # rows the MLPs computed (``llama.FFN_STATS``, in its order)

@@ -132,9 +132,30 @@ TOKENIZER_EXTRA_CORPUS = [
 ]
 
 
+# A request-invariant SITE CONTEXT an operator puts behind the system prompt
+# and before the exemplars — the site's page map and tool catalog, the same
+# for every tab — so that it is part of the cached prompt head. Empty by
+# default: every prompt is then, token for token, what it was before this
+# existed. Set once, before the engine installs its prompt prefix.
+_SITE_CONTEXT = ""
+
+
+def set_site_context(text: str) -> None:
+    """The deployment's site context (``""`` takes it away). Whoever builds
+    the service calls this BEFORE ``install_prompt_prefix``: the cached head
+    is located from rendered prompts."""
+    global _SITE_CONTEXT
+    _SITE_CONTEXT = text
+
+
+def site_context() -> str:
+    return _SITE_CONTEXT
+
+
 def fewshot_messages() -> list[dict]:
     """Chat messages for the parse prompt (system + user/assistant pairs)."""
-    msgs = [{"role": "system", "content": SYSTEM_PROMPT}]
+    system = SYSTEM_PROMPT + (f"Site context:\n{_SITE_CONTEXT}\n" if _SITE_CONTEXT else "")
+    msgs = [{"role": "system", "content": system}]
     for req, resp in FEWSHOTS:
         msgs.append({"role": "user", "content": json.dumps(req, separators=(",", ":"))})
         msgs.append({"role": "assistant", "content": json.dumps(resp, separators=(",", ":"))})
