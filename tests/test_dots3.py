@@ -173,6 +173,94 @@ def test_a_ragged_block_of_two_rows_behind_chunks_is_the_reference():
     assert stats["window_keys_read"] > 0
 
 
+@functools.lru_cache(maxsize=None)
+def _rows_behind_a_head(B: int):
+    """B rows on tables of their own behind 20 cached positions each
+    (selection and window bind), the pool they left, float32."""
+    params = init_params(CFG, jax.random.key(0), F32)
+    toks = jax.random.randint(jax.random.key(5), (B, 29), 0, CFG.vocab_size)
+    tables = jnp.arange(1, 4 * B + 1, dtype=jnp.int32).reshape(B, 4)
+    kp, vp = pools(CFG, F32, n=4 * B + 2)
+    with jax.default_matmul_precision("highest"):
+        out = forward_paged(params, CFG, toks[:, :20], jnp.tile(jnp.arange(20)[None], (B, 1)), kp, vp,
+                            tables, attn_impl="xla")
+    return params, toks, tables, out[1], out[2]
+
+
+# (n_real a row, the packed tile, a parked row or None): the tiles the real positions take. Four rows'
+# 36 positions go through the full layers' attention in tiles of 12, eight rows' 72 in tiles of 16
+# (``_position_tile``): in the ``filler`` cases a packed tile holds slots past the last real position
+# that NO attention tile filled — they are the last real position again, and write its cache index
+PACKED = {
+    "one_tile": ([2, 1, 3, 2], 12, None),
+    "several_tiles": ([9, 5, 7, 4], 12, None),
+    "a_row_with_none": ([3, 0, 2, 1], 12, None),
+    "a_parked_row": ([3, 2, 2, 1], 12, 1),
+    "every_position_real": ([9, 9, 9, 9], 12, None),
+    "a_last_tile_that_overlaps": ([9, 9, 9, 9], 16, None),
+    "filler_slots_no_attention_tile_fills": ([2, 1, 0, 3, 1, 2, 0, 1], 32, None),
+    "filler_slots_in_the_last_of_two_tiles": ([5, 4, 6, 3, 5, 4, 6, 4], 32, None),
+    "filler_slots_a_parked_row_and_an_overlap": ([3, 2, 9, 1, 4, 0, 2, 3], 40, 2),
+    "filler_slots_in_a_block_of_four_rows": ([2, 1, 3, 2], 30, None),
+}
+
+
+@pytest.mark.parametrize("one_head", [False, True], ids=["every_position", "one_head"])
+@pytest.mark.parametrize("case", sorted(PACKED))
+def test_the_packed_walk_is_the_whole_block(case, one_head):
+    """A 1 + 8 block of four or eight rows as the chunk loop builds it (a
+    padded position is a copy of its row's last real one), float32: with
+    ``ffn_pack`` under the block's positions everything position-wise runs on
+    the real positions in tiles of packed rows — the logits of every real
+    position, BOTH pools' planes and the index keys of EVERY layer at every
+    index a real position writes are the whole block's, nothing else in the
+    pool moves, and the counts keep their meaning (``FFN_STATS``: one tile or
+    not, rows computed)."""
+    n, tile, parked = PACKED[case]
+    B = len(n)
+    params, toks, tables, kp, vp = _rows_behind_a_head(B)
+    trash = 4 * B + 1
+    n = jnp.asarray(n, jnp.int32)
+    t = jnp.minimum(jnp.arange(9)[None], jnp.maximum(n[:, None] - 1, 0))  # (B, 9)
+    kw = {"n_real": n, "attn_impl": "xla", "moe_stats": True, "attn_stats": True, "latent_stats": True}
+    if parked is not None:
+        kw.update(write_mask=jnp.arange(B) != parked, trash_idx=jnp.full((B,), trash * BS, jnp.int32))
+    if one_head:
+        kw["logit_pos"] = jnp.maximum(n - 1, 0)
+    block = lambda: (jnp.take_along_axis(toks[:, 20:], t, axis=1), 20 + t,  # the pools are donated
+                     *jax.tree.map(jnp.copy, (kp, vp)), tables)
+    with jax.default_matmul_precision("highest"):
+        whole = forward_paged(params, CFG, *block(), **kw)
+        packed = forward_paged(params, CFG, *block(), ffn_pack=tile, **kw)
+    assert len(packed) == len(whole) + 1
+    live = np.asarray(n) * (np.arange(B) != parked)
+    n_pos = int(live.sum())
+    n_tiles = max(-(-n_pos // tile), 1)
+    assert np.asarray(packed[-1]).tolist() == [int(n_tiles == 1), n_tiles * tile]
+    # the routed layers' assignments, summed over the tiles; the attention's and the selection's counts
+    assert int(packed[5][0]) == 3 * n_tiles * tile * CFG.top_k and int(whole[5][0]) == 3 * B * 9 * CFG.top_k
+    for a, b in zip(packed[6:8], whole[6:8]):
+        assert np.asarray(a).tolist() == np.asarray(b).tolist()
+    for b in range(B):
+        if live[b]:
+            rows = slice(0, 1) if one_head else slice(0, int(live[b]))
+            assert rel(packed[0][b, rows], whole[0][b, rows]) < 1e-4, b
+    written = np.zeros((trash + 1, BS), bool)
+    for b in range(B):
+        for p in range(20, 20 + int(live[b])):
+            written[int(tables[b, p // BS]), p % BS] = True
+    for before, got, want in zip(jax.tree.leaves((kp, vp)), jax.tree.leaves(packed[1:3]),
+                                 jax.tree.leaves(whole[1:3])):
+        before, got, want = (np.asarray(a) for a in (before, got, want))
+        for layer in range(want.shape[0]):
+            assert (np.abs(got[layer][written] - want[layer][written]).max()
+                    < 1e-4 * np.abs(want[layer]).max()), layer
+        assert np.abs(want[:, written] - before[:, written]).max(axis=-1).min() > 0  # they WERE written
+        still = ~written
+        still[trash] = False  # the trash block: a parked row's writes
+        assert np.array_equal(got[:, still], before[:, still])
+
+
 FLIPS = {
     "no_rescale": ({"rescale": False}, {}),
     "no_gate": ({"gated": False}, {}),
@@ -457,6 +545,38 @@ def test_the_engine_serves_it_behind_the_batcher_at_both_chunk_widths(monkeypatc
     assert counters["attn.latent_query_rows"] > 0 and counters["attn.latent_keys_read"] > 0
 
 
+def test_the_chunk_loop_gives_the_same_plans_walked_and_whole(monkeypatch, prompts):
+    """Behind the pool the model's OWN prefill wrote (keys that weigh: the
+    selection and the window bind), float32: four requests on eight slots
+    with the block walked in tiles of 24 packed rows — a tile's slots behind
+    the last real position are ones no attention tile (12 here) fills — end
+    in the plans the whole block gives and leave its cache, and the counters
+    say it walked."""
+    from tpu_voice_agent.serve import ContinuousBatcher
+    from tpu_voice_agent.utils import tracing
+
+    plans = {}
+    for name, width in (("whole", 0), ("walked", 24)):
+        fresh = tracing.Metrics()
+        monkeypatch.setattr(tracing, "_GLOBAL_METRICS", fresh)
+        eng = _engine(float32=True)  # an engine each: the same blocks to the same requests
+        eng.set_prompt_prefix(*prompts[:2])
+        eng.ffn_pack_rows = width
+        with jax.default_matmul_precision("highest"):
+            outs = ContinuousBatcher(eng, chunk_steps=4, max_new_tokens=16).generate_many(prompts)
+        assert all(r.error is None for r in outs)
+        planes = [np.asarray(a[:, 1:]) for a in jax.tree.leaves((eng.k_pool, eng.v_pool))]  # (0: trash)
+        plans[name] = ([r.token_ids for r in outs], fresh.snapshot()["counters"], planes)
+    assert plans["walked"][0] == plans["whole"][0]
+    # what the requests left in the cache, every layer's planes of both kinds and the index keys
+    for got, want in zip(plans["walked"][2], plans["whole"][2]):
+        assert np.abs(got - want).max() < 1e-4 * np.abs(want).max()
+    assert plans["whole"][1].get("ffn.forwards_packed", 0) == 0
+    walked = plans["walked"][1]
+    assert 0 < walked["ffn.forwards_packed"] <= walked["scheduler.forwards"]
+    assert walked["ffn.rows"] < plans["whole"][1]["ffn.rows"]
+
+
 def test_the_chunked_head_and_a_suffix_behind_it_are_the_reference(prompts):
     """What the cell's comparison holds at published widths, here in float32:
     the head through the scratch pool in four chunks of 256 (selection from
@@ -502,6 +622,70 @@ def test_a_group_s_admission_is_the_per_slot_admissions(prompts):
         assert len(a) == 5  # kv, idx, swa | kv, swa
         for x, y in zip(a, b):
             assert float(np.abs(x[:, :n - P] - y[:, :n - P]).max()) < 1e-4
+
+
+# this model's programs at the rehearsal widths and 32 slots as the PARENT of
+# ISSUE 44 (commit ee06ed6) lowers them (``tests/test_older_programs_pinned._texts``,
+# ``tests/test_admit_group._chunk_program_shas`` on that tree): ISSUE 44 changed
+# the FULL-width chunk program alone
+PARENT_SHA256 = {
+    "group": "40e7eb5c4d43b180927204831d7bcdcbe5d96e16a582f6cee183f465dfcec310",
+    "block": "fa375e5aa7978e30cd174009e602af6d31e40b80250206518bfd77084d564591",
+    "chunk": ["250cbdb724897d097ec8fcc8c015c35a6b831a0157490cbcc95e936c8569de5c",
+              "0fe78ce2d14d9022037b4fbbd9cf7a204b8246dc8889b0674f9a8b4f2689ae64"],
+}
+
+
+@pytest.fixture(scope="module")
+def engine_of_32_slots(prompts):
+    eng = _engine(batch_slots=32, pool_blocks=240)
+    eng.set_prompt_prefix(*prompts[:2])
+    assert eng.admit_rows == 4 and eng.compact_rows * 9 <= eng.ffn_pack_rows < 32 * 9
+    return eng
+
+
+@pytest.fixture(scope="module")
+def lowered_at_32_slots(engine_of_32_slots):
+    from test_admit_group import _chunk_program_shas
+    from test_older_programs_pinned import _sha, _texts
+
+    eng = engine_of_32_slots
+    return {**{k: _sha(v) for k, v in _texts(eng).items()}, "chunk": _chunk_program_shas(eng)}
+
+
+@pytest.mark.parametrize("program", ["group", "block", "compact"])
+def test_the_programs_that_pack_nothing_are_the_parents(lowered_at_32_slots, program):
+    """The grouped admission ((4, 64) with ``n_real`` and no ``ffn_pack``: the
+    real positions first through the full layers' tiles, block-shaped
+    everywhere else), the comparison's one-row 1 + 8 block and the compacted
+    chunk width (72 positions <= 96) lower to the parent's text: a chip run
+    loads the parent's executables."""
+    got = lowered_at_32_slots
+    if program == "compact":
+        assert got["chunk"][1] == PARENT_SHA256["chunk"][1]
+    else:
+        assert got[program] == PARENT_SHA256[program]
+
+
+def test_the_full_width_chunk_program_walks_tiles_and_branches_nowhere(engine_of_32_slots):
+    """ISSUE 44: at 32 rows x 9 positions the chunk program packs — with NO
+    conditional from the packing (as many as the same program lowered without
+    ``ffn_pack``; the parent's held 11 more at these widths, ``llama.packed_ffn``'s
+    one a layer and what its two branches each repeat) and two tile walks a layer."""
+    from tpu_voice_agent.serve import paged
+
+    eng = engine_of_32_slots
+    z = lambda dt=jnp.int32: jnp.zeros((eng.batch_slots,), dt)
+    lowered = lambda **kw: paged.paged_chunk_decode_loop.__wrapped__.lower(
+        eng.params, eng.cfg, eng.k_pool, eng.v_pool, eng.block_tables, z(), z(), z(), z(jnp.bool_), z(), z(),
+        eng.tables_ff, eng.byte_len_table, jax.random.PRNGKey(0), jnp.float32(0), jnp.int32(0),
+        trash_idx=z(), rules=None, logit_mask=eng.logit_mask, chunk_steps=4, greedy=True, constrained=True,
+        kernels=eng.kernels, eos_id=eng.eos_id, pad_id=eng.pad_id, max_len=eng.max_len, kv_quant=None,
+        quality_lanes=eng.quality_lanes, **kw).as_text()
+    packed, whole = lowered(ffn_pack=eng.ffn_pack_rows), lowered()
+    count = lambda text, op: text.count(f"stablehlo.{op}")
+    assert count(packed, "case") + count(packed, "if") == count(whole, "case") + count(whole, "if")
+    assert count(packed, "while") == count(whole, "while") + 2 * eng.cfg.n_layers
 
 
 @pytest.mark.parametrize("what", ["radix", "spec", "kv_quant", "handoff", "mesh", "dense_engine", "dense_forward"])

@@ -40,11 +40,13 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def build_engine(conf: dict, rehearsal: bool):
-    """The configuration's engine as its builder makes it, less the server."""
+def build_engine(conf: dict, rehearsal: bool, prefix: bool = True):
+    """The configuration's engine as its builder makes it, less the server
+    (and, for a check that seeds the pool itself, less the cached ``prefix``)."""
     import jax
 
-    from benchmark.builders import cohere2moe_stack, olmoe_stack, parse_stack, sambay_stack
+    from benchmark.builders import (cohere2moe_stack, dots3_stack, moonlight_stack, olmoe_stack,
+                                    parse_stack, sambay_stack)
     from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
     from tpu_voice_agent.serve import PagedDecodeEngine
     from tpu_voice_agent.services.brain import install_prompt_prefix
@@ -56,6 +58,8 @@ def build_engine(conf: dict, rehearsal: bool):
         "olmoe_stack": (olmoe_stack.llama_config, olmoe_stack.make_params),
         "sambay_stack": (sambay_stack.sambay_config, sambay_stack.make_params),
         "cohere2moe_stack": (cohere2moe_stack.llama_config, cohere2moe_stack.make_params),
+        "moonlight_stack": (moonlight_stack.llama_config, moonlight_stack.make_params),
+        "dots3_stack": (dots3_stack.llama_config, dots3_stack.make_params),
     }[conf["builder"]]
     eng = PagedDecodeEngine(
         cfg=llama_config(m, s), tokenizer=default_tokenizer(), quant=s["quant"],
@@ -63,7 +67,8 @@ def build_engine(conf: dict, rehearsal: bool):
         max_len=s["max_len"], prefill_buckets=tuple(s["prefill_buckets"]),
         fast_forward=s["fast_forward"], init_weights=False)
     eng.load_params(make_params(eng.cfg, s["weights_seed"]))
-    install_prompt_prefix(eng)
+    if prefix:
+        install_prompt_prefix(eng)
     jax.block_until_ready((eng.params, eng.k_pool))
     return eng, m
 

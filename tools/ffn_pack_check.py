@@ -14,7 +14,12 @@ chains up to W), the positions behind it copies of the row's last real one,
 over a pool of seeded K/V â€” through ``forward_paged`` whole and with
 ``ffn_pack`` = the engine's ``ffn_pack_rows``, which packs BOTH position-wise
 regions of every layer (norm, q/k/v; output projection, residuals, MLP:
-``llama.FfnPack``): the largest difference of the real positions'
+``llama.FfnPack``; for ``dots3-note-prev-int8`` the walk in tiles of packed
+rows, ``llama.RowTiles``, ISSUE 44 â€” its rows sit behind what the model's OWN
+prefill wrote, the cached head all rows share and a seeded suffix admitted a
+slot (``admitted``): keys the attention weighs, and a selection that binds;
+its planes by layer kind are all compared and its control rounds the
+attention planes of both kinds too): the largest difference of the real positions'
 logits and of the K/V they wrote, each as a share of the whole path's largest
 value (``refcheck._rel_err``'s measure), the rows whose top-1 agrees, held
 against ``LIMIT`` (exit code 1 over it). Two readings bracket the limit. BELOW
@@ -39,8 +44,17 @@ more than that width: the cumulative histogram of sum(n_real) at those points â€
 and ``ffn.rows`` a forward. A width the engine does not derive is put on it
 here, for the measurement alone (the batcher's warm-up runs its chunk program first).
 
+``--workload CELL --plans 0 96``: the corpus's 64 plans decoded once through
+the batcher at each packed width (0 = the whole block), no traffic: how many
+end, how long they run, tokens a forward â€” and, against the FIRST width's,
+token for token: the plans that are the same, and where the others part
+(the token index; a plan of random weights follows a near tie that a
+rounding turns). The plans' tokens go to ``chiprun_out/plans_<width>.json``,
+for two trees to be held against each other.
+
     python3 tools/ffn_pack_check.py --config mistral-7b-v0.1-int8 [--seed 7 --seeds 4] [--rows 72 128 144]
     python3 tools/ffn_pack_check.py --workload parse_flood --sweep 0 64 96 [--seconds 45]
+    python3 tools/ffn_pack_check.py --workload dots3note_sitemap_flood --plans 0 96
 
 One configuration a process (each fills most of the chip). A line of JSON a
 run, on stdout and appended to ``chiprun_out/ffn_pack_check.jsonl``. With
@@ -74,25 +88,47 @@ def seeded_n_real(rng, B: int, T: int, budget: int):
     return n.astype(np.int32)
 
 
-def block(eng, n_real, rng, pool_blocks: int, layout_seed: int):
+def block(eng, n_real, rng, pool_blocks: int, layout_seed: int, behind=None):
     """``ff_body``'s block for ``n_real``: tokens, positions, a table a row
     (own blocks, ~600 positions behind it in the cells' pool), the write mask.
     Where a row starts comes from ``layout_seed`` alone: blocks that share it
-    write the same stretch of each row and read nothing another one wrote."""
+    write the same stretch of each row and read nothing another one wrote.
+    ``behind`` (``admitted``): the tables and the starts of rows the engine
+    admitted itself."""
     import numpy as np
 
     B, T, bs = len(n_real), 1 + eng.tables_ff.ff_tokens.shape[1], eng.block_size
     live = n_real > 0
     iw = np.minimum(np.arange(T)[None, :], np.maximum(n_real[:, None] - 1, 0))
     tokens = np.take_along_axis(rng.integers(3, eng.tokenizer.vocab_size, size=(B, T)), iw, axis=1)
-    per_row = min(5, (pool_blocks - 1) // B)  # own blocks a row, block 0 the trash
-    assert per_row >= 1, "the pool holds a block a row"
-    start = (per_row - 1) * bs + np.random.default_rng(layout_seed).integers(
-        0, bs - T, size=B)  # inside each row's last block
+    if behind is not None:
+        tables, start = behind
+    else:
+        per_row = min(5, (pool_blocks - 1) // B)  # own blocks a row, block 0 the trash
+        assert per_row >= 1, "the pool holds a block a row"
+        start = (per_row - 1) * bs + np.random.default_rng(layout_seed).integers(
+            0, bs - T, size=B)  # inside each row's last block
+        tables = np.zeros((B, eng.max_blocks), np.int32)
+        tables[:, :per_row] = 1 + per_row * np.arange(B)[:, None] + np.arange(per_row)[None, :]
     positions = np.where(live[:, None], start[:, None] + iw, 0)
-    tables = np.zeros((B, eng.max_blocks), np.int32)
-    tables[:, :per_row] = 1 + per_row * np.arange(B)[:, None] + np.arange(per_row)[None, :]
     return tokens.astype(np.int32), positions.astype(np.int32), tables, live
+
+
+def admitted(eng, layout_seed: int):
+    """Every slot admitted by the engine's own prefill behind its cached head:
+    a seeded suffix of 20 to 60 tokens a slot, blocks for a block more.
+    -> (the slots' tables, where each slot's next position is)."""
+    import numpy as np
+
+    rng = np.random.default_rng(layout_seed)
+    B, T = eng.batch_slots, 1 + eng.tables_ff.ff_tokens.shape[1]
+    start = np.zeros((B,), np.int64)
+    for b in range(B):
+        ids = eng.prefix_ids + rng.integers(3, eng.tokenizer.vocab_size, size=rng.integers(20, 61)).tolist()
+        eng.prefill_slot(ids, b)
+        eng._grow(b, len(ids) + T)
+        start[b] = len(ids)
+    return np.asarray(eng.block_tables)[:, :eng.max_blocks], start
 
 
 # What the packed branches may differ from the whole regions by, as a share of a
@@ -105,7 +141,12 @@ def block(eng, n_real, rng, pool_blocks: int, layout_seed: int):
 LIMIT = 0.12
 
 
-def to_int4(layers: dict) -> dict:
+# the groups of a parameter tree that hold a layer's planes (a latent model
+# keeps its attention's, by layer kind, and its leading dense layers' apart)
+LAYER_GROUPS = ("layers", "dense_layers", "attn_full", "attn_swa")
+
+
+def to_int4(params: dict) -> dict:
     """The layers' int8 planes rounded to 16 levels IN PLACE (donated), their
     scales kept: the control ``LIMIT`` has to refuse."""
     import jax
@@ -113,8 +154,9 @@ def to_int4(layers: dict) -> dict:
 
     round4 = jax.jit(lambda q: (jnp.clip((q.astype(jnp.int16) + 8) >> 4, -8, 7) << 4).astype(jnp.int8),
                      donate_argnums=0)
-    return {k: {**v, "q": round4(v["q"])} if isinstance(v, dict) and "q" in v else v
-            for k, v in layers.items()}
+    group = lambda layers: {k: {**v, "q": round4(v["q"])} if isinstance(v, dict) and "q" in v else v
+                            for k, v in layers.items()}
+    return {**params, **{g: group(params[g]) for g in LAYER_GROUPS if g in params}}
 
 
 def check_config(args) -> int:
@@ -135,7 +177,10 @@ def check_config(args) -> int:
     place_compile_cache()
     rehearsal = os.environ.get("JAX_PLATFORMS", "") == "cpu"
     t0 = time.perf_counter()
-    eng, _ = build_engine(conf, rehearsal)
+    # a pool of seeded K/V â€” or, where the model weighs and SELECTS its keys (a pool of noise
+    # under a residual of the embedding's size would move no logit), what its own prefill wrote
+    own_prefill = conf["builder"] == "dots3_stack"
+    eng, _ = build_engine(conf, rehearsal, prefix=own_prefill)
     B, T = eng.batch_slots, 1 + eng.tables_ff.ff_tokens.shape[1]
     P = eng.ffn_pack_rows
     dev = jax.devices()[0]
@@ -147,10 +192,15 @@ def check_config(args) -> int:
     widths = sorted({r for r in args.rows if r < B * T} | {P})
     one_head = bool(eng.cfg.layer_types)
 
-    def seeded(pool, k):
-        return (jax.random.normal(jax.random.PRNGKey(k), pool.shape, jnp.bfloat16) * 0.3).astype(pool.dtype)
+    def seeded(pool, k):  # an array (its values what they were: key k itself), or planes by layer kind
+        leaves, tree = jax.tree.flatten(pool)
+        return jax.tree.unflatten(tree, [
+            (jax.random.normal(jax.random.fold_in(jax.random.PRNGKey(k), i) if i else jax.random.PRNGKey(k),
+                               a.shape, jnp.bfloat16) * 0.3).astype(a.dtype) for i, a in enumerate(leaves)])
 
-    pools = [seeded(eng.k_pool, 11), seeded(eng.v_pool, 12)]
+    behind = admitted(eng, args.seed) if own_prefill else None
+    pools = [eng.k_pool, eng.v_pool] if own_prefill else [seeded(eng.k_pool, 11), seeded(eng.v_pool, 12)]
+    pool_blocks = jax.tree.leaves(pools[0])[0].shape[1]
     eng.k_pool = eng.v_pool = None  # donated below, from ``pools``
     params = eng.params
 
@@ -160,7 +210,8 @@ def check_config(args) -> int:
         def __init__(self, seed: int):
             rng = np.random.default_rng(seed)
             self.n_real = seeded_n_real(rng, B, T, min(widths))
-            self.tokens, self.positions, self.tables, self.live = block(eng, self.n_real, rng, pools[0].shape[1], args.seed)
+            self.tokens, self.positions, self.tables, self.live = block(
+                eng, self.n_real, rng, pool_blocks, args.seed, behind)
             self.real = np.arange(T)[None, :] < self.n_real[:, None]
             self.kw = dict(attn_impl=eng.kernels, write_mask=jnp.asarray(self.live), **(
                 {"logit_pos": jnp.asarray(np.maximum(self.n_real - 1, 0))} if one_head else {}))
@@ -179,14 +230,14 @@ def check_config(args) -> int:
             """The real positions' logits, and the K/V the block wrote."""
             logits = np.asarray(out[0], np.float32)
             logits = logits[self.live, 0] if one_head else logits[self.real]
-            return logits, [np.asarray(p[:, self.at[0], self.at[1]], np.float32) for p in pools]
+            return logits, [np.asarray(p[:, self.at[0], self.at[1]], np.float32) for p in jax.tree.leaves(pools)]
 
     rel_of = lambda got, want: refcheck._rel_err(got, want)[0]
     blocks, readings = [Block(args.seed + i) for i in range(args.seeds)], []
     for blk in blocks:
         blk.want, want_kv = blk.left(blk.forward(**blk.kw))
         out = blk.forward(**blk.packed_kw, ffn_pack=P)
-        assert np.asarray(out[-1]).tolist() == [1, P], "the seeded block fits: the packed branch ran"
+        assert np.asarray(out[-1]).tolist() == [1, P], "the seeded block fits: ONE packed tile held it"
         got, got_kv = blk.left(out)
         rel, top1 = refcheck._rel_err(got, blk.want)
         readings.append({
@@ -232,7 +283,7 @@ def check_config(args) -> int:
                 lambda: mlps(params["layers"], jax.random.PRNGKey(rows), rows))
         say("ms, median of %d: %s" % (args.repeat, ", ".join(f"{k[:-3]} {v:.2f}" for k, v in timings.items())))
     # the control, last (it rewrites the weights): the whole path, the layers' planes at int4
-    params = eng.params = {**params, "layers": to_int4(params["layers"])}
+    params = eng.params = to_int4(params)
     control = [rel_of(blk.left(blk.forward(**blk.kw))[0], blk.want) for blk in blocks]
     worst = max(r["packed_vs_whole"] for r in readings)
     ok = worst < LIMIT < min(control)
@@ -294,6 +345,66 @@ def sweep_workload(args) -> int:
                    "device": {"platform": dev.platform, "kind": dev.device_kind}}, 0)
 
 
+def parted(plan: list, other: list) -> int | None:
+    """The first token index at which two plans differ, None where they are one."""
+    if plan == other:
+        return None
+    return next((i for i, (a, b) in enumerate(zip(plan, other)) if a != b), min(len(plan), len(other)))
+
+
+def decode_plans(args) -> int:
+    from benchmark.builders import parse_stack
+    from benchmark.lib.corpus import texts
+    from benchmark.lib.manifest import load_cell, load_manifest
+    from benchmark.run import program_env, say
+
+    config = load_cell(load_manifest(), args.workload)["config"]
+    program_env(config)
+    import jax
+
+    from tools.admit_batch_check import build_engine
+    from tpu_voice_agent.serve import ContinuousBatcher
+    from tpu_voice_agent.services.prompts import render_prompt
+    from tpu_voice_agent.utils import get_metrics
+    from tpu_voice_agent.utils.compilecache import place_compile_cache
+
+    place_compile_cache()
+    rehearsal = os.environ.get("JAX_PLATFORMS", "") == "cpu"
+    eng, _ = build_engine(config, rehearsal)
+    chunk = int(parse_stack.as_run(config, rehearsal)[1]["env"].get("BRAIN_CHUNK", 16))
+    prompts = [render_prompt(t, {}) for t in texts(64)]
+    derived, first, points = eng.ffn_pack_rows, None, []
+    os.makedirs("chiprun_out", exist_ok=True)
+    for rows in args.plans:
+        eng.ffn_pack_rows = rows
+        batcher = ContinuousBatcher(eng, chunk_steps=chunk, max_new_tokens=512)
+        before = dict(get_metrics().counter_state()[0])
+        res = batcher.generate_many(prompts)
+        after = get_metrics().counter_state()[0]
+        batcher.reset()
+        fwds = after.get("scheduler.forwards", 0.0) - before.get("scheduler.forwards", 0.0)
+        plans = [list(map(int, r.token_ids)) for r in res]
+        with open(f"chiprun_out/plans_{rows}.json", "w") as f:
+            json.dump(plans, f)
+        first = first if first is not None else plans
+        lens = sorted(map(len, plans))
+        at = [parted(a, b) for a, b in zip(plans, first)]
+        points.append({
+            "ffn_pack_rows": rows, "plans": len(plans),
+            "ended": sum(bool(r.finished) and r.error is None for r in res),
+            "tokens_a_plan": {"min": lens[0], "median": lens[len(lens) // 2], "max": lens[-1],
+                              "mean": round(sum(lens) / len(lens), 2)},
+            "distinct": len({tuple(p) for p in plans}),
+            "tokens_per_forward": round(sum(lens) / fwds, 2) if fwds else None,
+            "same_as_first_width": sum(a is None for a in at),
+            "parted_at": sorted(a for a in at if a is not None)})
+        say(f"ffn_pack_rows {rows}: {points[-1]}")
+    eng.ffn_pack_rows = derived
+    dev = jax.devices()[0]
+    return report({"workload": args.workload, "derived": derived, "plans": points,
+                   "device": {"platform": dev.platform, "kind": dev.device_kind}}, 0)
+
+
 def report(line: dict, code: int) -> int:
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/ffn_pack_check.jsonl", "a") as f:
@@ -312,12 +423,15 @@ def main() -> int:
     ap.add_argument("--rows", type=int, nargs="*", default=[],
                     help="other packed widths: time the forward and the MLPs alone at each")
     ap.add_argument("--sweep", type=int, nargs="*", default=[0, 64, 96], help="packed widths to serve at")
+    ap.add_argument("--plans", type=int, nargs="+", help="with --workload: packed widths to decode the 64 plans at")
     ap.add_argument("--seconds", type=float, default=45.0)
     ap.add_argument("--repeat", type=int, default=9)
     args = ap.parse_args()
     os.chdir(ROOT)
     sys.path.insert(0, ROOT)
-    return check_config(args) if args.config else sweep_workload(args)
+    if args.config:
+        return check_config(args)
+    return decode_plans(args) if args.plans else sweep_workload(args)
 
 
 if __name__ == "__main__":

@@ -47,6 +47,18 @@ over a key set gathered for its queries:
 One path whatever T is: a decode step, a fast-forward block, a suffix behind
 the cached prefix and a chunk of the prefix itself.
 
+A fast-forward block WIDER than ``ffn_pack`` rows whose real positions are
+told (``n_real``: the full-width chunk program, 32 rows x 9 positions against
+96) runs everything position-wise on the real positions alone (ISSUE 44):
+the residual is packed once behind the embedding (``llama.RowTiles``: every
+position has a slot, so there is no predicate and no whole-width twin) and
+stays packed from layer to layer; a layer is two walks over tiles of
+``ffn_pack`` packed rows — norm, q/k/v, the indexer's projections, the gate
+and the cache writes of the tile's rows; then W_UV, the gates, W_o, the
+residual and the MLP — around its attention. A full layer attends the packed
+queries as they are; a sliding layer's tile rows are scattered to their
+positions for the window kernel and its output gathered a tile.
+
 The pool is a pytree a kind: ``k_pool`` {"kv": the full layers' latents (Lf,
 N, bs, C), "swa": the sliding layers' (Ls, N, bs, Cs), "idx": the full
 layers' index keys (Lf, N, bs, di)}, ``v_pool`` {"kv", "swa"}: the rotated
@@ -57,6 +69,7 @@ feed-forward ones and the norms (``models.mla``'s).
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from dataclasses import replace
 from functools import partial
 from typing import NamedTuple
@@ -65,8 +78,8 @@ import jax
 import jax.numpy as jnp
 
 from .llama import (MAX_BLOCK_DECODE_T, LlamaConfig, _attn_stats, _ffn, _qe, _scan_and_whole,
-                    _EXPERT_LEAVES, apply_rope_interleaved, ffn_pack_index, layer_norm,
-                    packed_ffn, rms_norm, rope_tables)
+                    apply_rope_interleaved, ffn_pack_index, layer_norm, moe_stat_names, rms_norm,
+                    rope_tables, row_tiles)
 from .mla import LATENT_STATS, _dense_ffn
 
 F32 = jnp.float32
@@ -216,12 +229,16 @@ def _rope_head(x, cos, sin, dr: int):
     return jnp.concatenate([apply_rope_interleaved(x[..., :dr], cos, sin), x[..., dr:]], axis=-1)
 
 
-def latent_qkv(p, x, cfg: LlamaConfig, k: Kind, cos, sin):
+def latent_qkv(p, x, cfg: LlamaConfig, k: Kind, cos, sin, hold: bool = False):
     """The front half of a layer -> (q_c (B, T, H, C) with W_UK absorbed, q_r
     (B, T, H, dr) rotated, c (B, T, C) normed and rescaled, r (B, T, dr)
     rotated, gate (B, T, H) float32 or None, the indexer's (qI (B, T, Hi,
-    di), kI (B, T, di), w (B, T, Hi) float32) or None)."""
+    di), kI (B, T, di), w (B, T, Hi) float32) or None). ``hold`` (the packed
+    walk): a projection's output is a buffer before it opens into heads —
+    fused with that reshape, XLA:TPU wants the stacked plane transposed and
+    copies it whole (PERF.md section 6, PRs 41 and 44)."""
     B, T = x.shape[:2]
+    buffered = jax.lax.optimization_barrier if hold else (lambda a: a)
     rescale = lambda rank: (cfg.dim / rank) ** 0.5 if cfg.lora_rescale else 1.0
     with jax.named_scope("layer/attn_qkv"):
         h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
@@ -229,9 +246,9 @@ def latent_qkv(p, x, cfg: LlamaConfig, k: Kind, cos, sin):
             cq = rms_norm(_qe("btd,dc->btc", h, p["w_qa"]).astype(x.dtype), p["q_norm"],
                           cfg.latent_norm_eps)
             cq = (cq * rescale(k.Cq)).astype(x.dtype)
-            q = _qe("btc,ch->bth", cq, p["w_qb"]).astype(x.dtype).reshape(B, T, k.H, k.dn + k.dr)
+            q = buffered(_qe("btc,ch->bth", cq, p["w_qb"]).astype(x.dtype)).reshape(B, T, k.H, k.dn + k.dr)
         with jax.named_scope("kv_a"):
-            cr = _qe("btd,dh->bth", h, p["w_kva"]).astype(x.dtype)
+            cr = buffered(_qe("btd,dh->bth", h, p["w_kva"]).astype(x.dtype))
             c = rms_norm(cr[..., :k.C], p["kv_norm"], cfg.latent_norm_eps)
             c = (c * rescale(k.C)).astype(x.dtype)
             r = apply_rope_interleaved(cr[..., None, k.C:], cos, sin)[:, :, 0]
@@ -250,7 +267,7 @@ def latent_qkv(p, x, cfg: LlamaConfig, k: Kind, cos, sin):
     if k.indexed:
         Hi, di = cfg.index_n_heads, cfg.index_head_dim
         with jax.named_scope("layer/attn/index"), jax.named_scope("project"):
-            qi = _qe("btc,ch->bth", cq, p["w_iq"]).astype(x.dtype).reshape(B, T, Hi, di)
+            qi = buffered(_qe("btc,ch->bth", cq, p["w_iq"]).astype(x.dtype)).reshape(B, T, Hi, di)
             qi = _rope_head(qi, cos, sin, k.dr)
             ki = layer_norm(_qe("btd,dh->bth", h, p["w_ik"]).astype(x.dtype), p["ik_norm"],
                             INDEX_NORM_EPS)
@@ -297,7 +314,8 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
     pytrees the module's text names. -> (logits, k_pool, v_pool, None, None),
     then with ``moe_stats`` the routed layers' counts, with ``attn_stats``
     ``ops.ATTN_STATS``, with ``latent_stats`` ``latent_stat_names()`` over all
-    layers, with a packed MLP ``llama.FFN_STATS``. ``fault`` PLANTS one, for
+    layers, where the block is walked packed (``ffn_pack`` under its B * T
+    positions, with ``n_real``) ``llama.FFN_STATS``. ``fault`` PLANTS one, for
     the comparison's limit to be set against (``FAULTS``); None everywhere
     else."""
     from ..ops import sparse_latent as sl
@@ -333,20 +351,23 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         blk = jnp.where(write_mask[:, None], blk, park[:, None] // bs)
         off = jnp.where(write_mask[:, None], off, park[:, None] % bs)
 
-    pack = None
+    # a block wider than ``ffn_pack`` rows whose real positions are told: the
+    # walk of its position-wise work, in tiles of that many packed rows
+    rows = None
     live_n = None
     if n_real is not None:
         live_n = n_real if write_mask is None else jnp.where(write_mask, n_real, 0)
         if ffn_pack and P > ffn_pack:
             with jax.named_scope("layer/ffn/pack"):
-                pack = ffn_pack_index(live_n, T, ffn_pack)
+                rows = row_tiles(live_n, T, ffn_pack)
 
     # ---- a full layer's geometry, once a forward: the real positions first
     K = min(cfg.index_topk, nb * bs)
     tile = _position_tile(P)
     with jax.named_scope("layer/attn/split"):
         if live_n is not None:
-            order = ffn_pack_index(live_n, T, P)  # every position has a slot: it always fits
+            # every position has a slot: it always fits
+            order = rows if rows is not None else ffn_pack_index(live_n, T, P)
             idx, inv = order.idx, order.inv.reshape(-1)
             n_pos = jnp.sum(jnp.clip(live_n, 0, T)).astype(jnp.int32)
             n_tiles = jnp.maximum(-(-n_pos // tile), 1)
@@ -376,11 +397,10 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         hi_w = jnp.repeat(pos_g, kd["sliding"].H, axis=1)  # (G, Tq * Hs), position-major
         lo_w = jnp.maximum(hi_w - reach, 0)
 
-    def full_attention(li, q_c, q_r, index, cp, rp, ip):
+    def full_attention(li, qc_f, qr_f, qi_f, wi_f, cp, rp, ip, out):
+        """The full layers' attention over (P, ...) queries in ``idx``'s
+        order, the real positions first, into ``out`` (P, H, C), tile by tile."""
         k = kd["full"]
-        qi, _, wi = index
-        order_of = lambda a: a.reshape(P, *a.shape[2:])[idx]
-        qc_f, qr_f, qi_f, wi_f = (order_of(a) for a in (q_c, q_r, qi, wi))
         scale = (k.dn + k.dr) ** -0.5
 
         def one_tile(i, out):
@@ -410,8 +430,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
                                 jnp.broadcast_to(ps[:, None], (tile, k.H)), scale=scale)
             return jax.lax.dynamic_update_slice_in_dim(out, a, i * tile, 0)
 
-        out = jax.lax.fori_loop(0, n_tiles, one_tile, jnp.zeros((P, k.H, k.C), q_c.dtype))
-        return out[inv].reshape(B, T, k.H, k.C)
+        return jax.lax.fori_loop(0, n_tiles, one_tile, out)
 
     def dense_attention(li, q_c, q_r, cp, rp):
         """The planted fault ``no_selection``: a full layer over every key."""
@@ -431,59 +450,181 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         g = lambda a: a.reshape(G, Tq * k.H, a.shape[-1])
         with jax.named_scope("layer/attn/window"):
             with jax.named_scope("gather"):
-                c_w = cps[li][wblk].reshape(G, WB * bs, k.C)
-                r_w = rps[li][wblk].reshape(G, WB * bs, k.dr)
+                # (behind the walk's ``while`` the window's blocks come straight out of the pool:
+                # the layer's plane no longer fits the fast memory, and ``pool[li]`` is a 69 MB copy)
+                blocks = (lambda pool: pool[li, wblk]) if rows is not None else (lambda pool: pool[li][wblk])
+                c_w = blocks(cps).reshape(G, WB * bs, k.C)
+                r_w = blocks(rps).reshape(G, WB * bs, k.dr)
             # (with the window planted away a row's whole context is one tile: XLA)
             a = (attend_window if window else twin)(
                 g(q_c), g(q_r), c_w, r_w, kpos_w, lo_w, hi_w, scale=(k.dn + k.dr) ** -0.5)
         return a.reshape(B, T, k.H, k.C)
 
-    scanned, whole = _scan_and_whole(params["layers"], cfg, packed=pack is not None)
-    stacked = () if pack is None else tuple(
-        k for k in whole if not (cfg.moe_impl == "grouped" and k in _EXPERT_LEAVES))
-    stats = []
-    for L, (kind, ki) in enumerate(layer_plan(cfg)):
-        k = kd[kind]
-        li = jnp.int32(ki)
-        attn_p = jax.tree.map(lambda a: a[ki], params["attn_full" if k.indexed else "attn_swa"])
-        if L < n_dense:
-            ffn_p = jax.tree.map(lambda a: a[L], params["dense_layers"])
-            ffn = partial(_dense_ffn, cfg=cfg)
-        else:
+    scanned, whole = _scan_and_whole(params["layers"], cfg)
+
+    def leaves(L, k: Kind, ki: int, scope=None):
+        """(layer L's parameters, its MLP), every stacked leaf sliced by the
+        layer's STATIC index — called inside whichever loop body reads them:
+        a slice made before a loop is its operand, written out and read back.
+        ``scope`` (the walk): the name a slice that is an op of its own runs
+        under — the attention's planes under it, the MLP's under
+        ``layer/ffn/pack`` — so that a reader of the region counts it."""
+        named = jax.named_scope if scope else (lambda name: nullcontext())
+        with named(scope):
+            attn_p = jax.tree.map(lambda a: a[ki], params["attn_full" if k.indexed else "attn_swa"])
+        with named("layer/ffn/pack"):
+            if L < n_dense:
+                ffn_p = jax.tree.map(lambda a: a[L], params["dense_layers"])
+                return {**attn_p, **ffn_p}, partial(_dense_ffn, cfg=cfg)
             j = L - n_dense
-            ffn_p = {**jax.tree.map(lambda a: a[j], scanned), **whole, "layer": jnp.int32(j),
-                     **({"stacked": stacked} if stacked else {})}
-            ffn = partial(_ffn, cfg=cfg)
-        p = {**attn_p, **ffn_p}
-        q_c, q_r, c, r, gate, index = latent_qkv(p, x, cfg, k, *rope[kind])
+            ffn_p = {**jax.tree.map(lambda a: a[j], scanned), **whole, "layer": jnp.int32(j)}
+            return {**attn_p, **ffn_p}, partial(_ffn, cfg=cfg)
+
+    def front(p, k: Kind, li, x, cos, sin, blk, off, planes):
+        """A layer up to its attention, position-wise over (b, t, d): the
+        queries (q_c, q_r, the gate, the indexer's qI and w) and the kind's
+        ``planes`` with these positions' rows written."""
+        q_c, q_r, c, r, gate, index = latent_qkv(p, x, cfg, k, cos, sin, hold=rows is not None)
         if fault == "no_gate":
             gate = None
+        qi, ki, wi = index or (None, None, None)
         with jax.named_scope("layer/kv_write"):
-            if k.indexed:
-                cp = cp.at[li, blk, off].set(c.astype(cp.dtype))
-                rp = rp.at[li, blk, off].set(r.astype(rp.dtype))
-                ip = ip.at[li, blk, off].set(index[1].astype(ip.dtype))
-            else:
-                cps = cps.at[li, blk, off].set(c.astype(cps.dtype))
-                rps = rps.at[li, blk, off].set(r.astype(rps.dtype))
-        with jax.named_scope("layer/attn"):
-            if not k.indexed:
-                a = window_attention(li, q_c, q_r, cps, rps)
-            elif fault == "no_selection":
-                a = dense_attention(li, q_c, q_r, cp, rp)
-            else:
-                a = full_attention(li, q_c, q_r, index, cp, rp, ip)
+            planes = tuple(pl.at[li, blk, off].set(v.astype(pl.dtype))
+                           for pl, v in zip(planes, (c, r, ki)))
+        return {"c": q_c, "r": q_r, "gate": gate, "i": qi, "w": wi}, planes
+
+    def attend_block(k: Kind, li, q_c, q_r, planes):
+        """What takes a row's (b, t) block of queries: a sliding layer's
+        window, and the planted fault ``no_selection``."""
+        if not k.indexed:
+            return window_attention(li, q_c, q_r, *planes)
+        return dense_attention(li, q_c, q_r, *planes[:2])
+
+    def back(p, ffn, k: Kind, x, a, gate):
+        """A layer behind its attention, position-wise over (b, t, d) ->
+        (the new residual, the MLP's routed counts or None)."""
         attn = latent_out(p, a, k, gate, x.dtype)
         with jax.named_scope("layer/attn_out"):
             x = x + _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
         with jax.named_scope("layer/ffn"):
             h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-        y, st = packed_ffn(partial(ffn, p), h, pack)
+        y, st = ffn(p, h)
         with jax.named_scope("layer/ffn"):
-            x = x + y
+            return x + y, st
+
+    pools = {"full": (cp, rp, ip), "sliding": (cps, rps)}
+
+    def block_layer(L, kind, ki, x):
+        """A layer over the (B, T) block as it stands."""
+        k, li = kd[kind], jnp.int32(ki)
+        p, ffn = leaves(L, k, ki)
+        q, pools[kind] = front(p, k, li, x, *rope[kind], blk, off, pools[kind])
+        with jax.named_scope("layer/attn"):
+            if k.indexed and fault != "no_selection":
+                order_of = lambda a: a.reshape(P, *a.shape[2:])[idx]
+                a = full_attention(li, *(order_of(q[n]) for n in "criw"), *pools[kind],
+                                   jnp.zeros((P, k.H, k.C), x.dtype))
+                a = a[inv].reshape(B, T, k.H, k.C)
+            else:
+                a = attend_block(k, li, q["c"], q["r"], pools[kind])
+        return back(p, ffn, k, x, a, q["gate"])
+
+    def walked_layer(L, kind, ki, x, held):
+        """A layer over the PACKED residual x (P, d): ONE copy of its
+        position-wise code in two walks over tiles of ``ffn_pack`` packed rows
+        (one tile in ~99 % of a flood's forwards) — no predicate, no
+        whole-width twin. Every stacked leaf is sliced inside the walk that
+        reads it (``leaves``), and every move of a walk — a tile cut out of
+        the packed arrays, a plane sliced, rows put back — runs under the
+        region's own name (``layer/attn_qkv/pack`` and ``/unpack``,
+        ``layer/attn_out/pack``, ``layer/ffn/pack`` and ``/unpack``).
+        ``held``: the walks' buffers by slot, a kind's queries and the full
+        layers' output, handed on from layer to layer (none zeroed a layer).
+        -> (x, the MLP's routed counts or None, ``held``)."""
+        k, li = kd[kind], jnp.int32(ki)
+        blockwise = not k.indexed or fault == "no_selection"  # attention takes a row's (t, head) group
+
+        def front_rows(i, planes):
+            p = leaves(L, k, ki, "layer/attn_qkv/pack")[0]
+            with jax.named_scope("layer/attn_qkv/pack"):
+                cut = lambda a: rows.cut(a, i)[None]
+                of_tile = (cut(x), *map(cut, rope[kind]), cut(blk), cut(off))
+            q, planes = front(p, k, li, *of_tile, planes)
+            with jax.named_scope("layer/attn_qkv/unpack"):
+                return jax.tree.map(lambda v: v[0], q), planes
+
+        def front_tile(i, carry):
+            q, planes = front_rows(i, carry[1])
+            # a sliding layer's window wants its queries by POSITION: its tile's q_c / q_r rows
+            # land in the block at once (a padded position keeps what an earlier layer left
+            # there: its output is never read); everything else stays by slot
+            place = lambda n, buf, v: (buf.at[rows.cut(rows.idx, i)].set(v) if blockwise and n in "cr"
+                                       else rows.put(buf, v, i))
+            with jax.named_scope("layer/attn_qkv/unpack"):
+                return {n: v if v is None else place(n, carry[0][n], v) for n, v in q.items()}, planes
+
+        if kind not in held:
+            like = jax.eval_shape(lambda: front_rows(0, pools[kind])[0])
+            held = {**held, kind: jax.tree.map(lambda v: jnp.zeros((P, *v.shape[1:]), v.dtype), like)}
+            if k.indexed:
+                held["out"] = jnp.zeros((P, k.H, k.C), x.dtype)
+        with jax.named_scope("layer/front"):  # (the walk's ``while`` itself: a name no reader matches)
+            q, pools[kind] = jax.lax.fori_loop(0, rows.n_tiles, front_tile, (held[kind], pools[kind]))
+        held = {**held, kind: q}
+        with jax.named_scope("layer/attn"):
+            if blockwise:
+                by_row = lambda a: a.reshape(B, T, *a.shape[1:])
+                a = attend_block(k, li, by_row(q["c"]), by_row(q["r"]), pools[kind]).reshape(P, k.H, k.C)
+                a_rows = lambda i: a[rows.cut(rows.idx, i)]
+            else:
+                # the packed queries as they are, the output handed on packed. Its tiles (of 16) end
+                # before a walk's tile (of ``ffn_pack``) does: a slot behind the last real one reads
+                # THAT one's output, or its residual — and from the next layer on the latent it
+                # writes to that position's cache index — would be another's
+                a = full_attention(li, *(q[n] for n in "criw"), *pools[kind], held["out"])
+                held = {**held, "out": a}
+                a_rows = lambda i: a[rows.slots(i)]
+
+        def back_tile(i, carry):
+            out, st = carry
+            p, ffn = leaves(L, k, ki, "layer/attn_out/pack")
+            with jax.named_scope("layer/attn_out/pack"):
+                a_i = a_rows(i)[None]
+                gate = None if q["gate"] is None else rows.cut(q["gate"], i)[None]
+                x_i = rows.cut(x, i)[None]
+            x_i, s = back(p, ffn, k, x_i, a_i, gate)
+            with jax.named_scope("layer/ffn/unpack"):
+                return rows.put(out, x_i[0], i), (None if s is None else st + s)
+
+        routed = L >= n_dense and cfg.n_experts > 0
+        st0 = jnp.zeros((len(moe_stat_names(cfg)),), jnp.int32) if routed else None
+        with jax.named_scope("layer/out"):  # (as ``layer/front``)
+            x, st = jax.lax.fori_loop(0, rows.n_tiles, back_tile, (x, st0))
+        return x, st, held
+
+    if rows is not None:
+        # once a forward: the residual, its angles and its cache indices by
+        # packed slot. The residual STAYS packed from layer to layer
+        with jax.named_scope("layer/attn_qkv/pack"):
+            slots = lambda a: a.reshape(P, *a.shape[2:])[rows.idx]
+            x, blk, off = slots(x), slots(blk), slots(off)
+            rope = {t: tuple(slots(a) for a in cs) for t, cs in rope.items()}
+    stats, held = [], {}
+    for L, (kind, ki) in enumerate(layer_plan(cfg)):
+        if rows is None:
+            x, st = block_layer(L, kind, ki, x)
+        else:
+            x, st, held = walked_layer(L, kind, ki, x, held)
         if st is not None:
             stats.append(st)
 
+    cp, rp, ip = pools["full"]
+    cps, rps = pools["sliding"]
+    if rows is not None:
+        with jax.named_scope("layer/out/unpack"):  # once a forward: the positions the head reads
+            x = x[rows.inv if logit_pos is None else
+                  jnp.take_along_axis(rows.inv, logit_pos[:, None], axis=1)]
+            logit_pos = None
     with jax.named_scope("final_norm"):
         if logit_pos is not None:  # the head on the one position a row reads
             x = jnp.take_along_axis(x, logit_pos[:, None, None], axis=1)
@@ -508,8 +649,8 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
                 n_alive * T * (n_full * kd["full"].H + n_swa * kd["sliding"].H),
                 n_full * scored * (N * bs), n_full * seen, n_full * chosen,
                 n_swa * win]).astype(jnp.int32),)
-    if pack is not None:
-        extra += (pack.stats,)
+    if rows is not None:
+        extra += (rows.stats,)
     return (logits, k_pool, v_pool, None, None, *extra)
 
 
