@@ -112,8 +112,9 @@ def test_the_configuration_keeps_the_published_widths_and_names_its_cut():
     assert full.layer_types == ("full", "full") + ("sliding",) * 3 + ("full",) + ("sliding",) * 3
     assert llama.paged_only(full) and llama.latent(full)
     spec = mla.cache_spec(full)
-    assert spec["planes"] == {"k": {"kv": (3, 512), "idx": (3, 128), "swa": (6, 1024)},
-                              "v": {"kv": (3, 64), "swa": (6, 64)}}
+    # a full layer's latent and rotated key share ONE row of 512 + 64; no plane of full-layer r's beside it
+    assert spec["planes"] == {"k": {"kv": (3, 576), "idx": (3, 128), "swa": (6, 1024)}, "v": {"swa": (6, 64)}}
+    assert (spec["latent_dim"], spec["rope_dim"], spec["kv_layers"]) == (512, 64, 3)  # as published
     # 1408 B a token a full layer, 2176 B a sliding one (the file's deployment)
     assert spec["token_bytes"] == 3 * 1408 + 6 * 2176 == 17280
     assert dots3.layer_plan(full)[:3] == (("full", 0), ("full", 1), ("sliding", 0))
@@ -349,29 +350,104 @@ def test_the_indexer_kernel_matches_its_twin_and_scores_by_hand():
     assert abs(float(got[5, 70]) - by_hand) < 1e-4
 
 
-@pytest.mark.parametrize("Q,K", [(8, 24), (18, 40)])
-def test_the_gathered_kernel_matches_its_twin_with_bounds_a_query_and_padding_keys(Q, K):
-    """Each query row its own [lo, hi]; keys out of order, some at -1 (padding:
-    never seen, lo >= 0); Q and K no multiple of the kernel's tiles."""
+def _gathered_case(Q: int, K: int, reach: int, seed: int):
+    """G = 3 groups of Q query rows over K keys (C = 48, R = 16): each query row
+    its own [lo, hi] (``reach`` wide), keys out of order, the last three at -1
+    (padding: never seen, lo >= 0). -> (q_c, q_r, c, r, kpos, lo, hi)."""
     G, C, R = 3, 48, 16
-    ks = jax.random.split(jax.random.key(8), 5)
+    ks = jax.random.split(jax.random.key(seed), 5)
     q_c, q_r = jax.random.normal(ks[0], (G, Q, C), F32), jax.random.normal(ks[1], (G, Q, R), F32)
     c, r = jax.random.normal(ks[2], (G, K, C), F32), jax.random.normal(ks[3], (G, K, R), F32)
     kpos = jnp.stack([jax.random.permutation(k_, K) for k_ in jax.random.split(ks[4], G)]).astype(jnp.int32)
     kpos = kpos.at[:, -3:].set(-1)
-    hi = jnp.tile(jnp.arange(Q, dtype=jnp.int32)[None] + 5, (G, 1))
-    lo = jnp.maximum(hi - 6, 0)
+    hi = jnp.tile(jnp.arange(Q, dtype=jnp.int32)[None] + reach - 1, (G, 1))
+    return q_c, q_r, c, r, kpos, jnp.maximum(hi - reach, 0), hi
+
+
+@pytest.mark.parametrize("Q,K", [(8, 24), (18, 40)])
+def test_the_gathered_kernel_matches_its_twin_with_bounds_a_query_and_padding_keys(Q, K):
+    """Each query row its own [lo, hi]; keys out of order, some at -1 (padding:
+    never seen, lo >= 0); Q and K no multiple of the kernel's tiles."""
+    q_c, q_r, c, r, kpos, lo, hi = case = _gathered_case(Q, K, 6, seed=8)
     with jax.default_matmul_precision("highest"):
-        got = sl.window_latent_attention(q_c, q_r, c, r, kpos, lo, hi, scale=0.125)
-        assert np.array_equal(got, sl.sparse_latent_attention(q_c, q_r, c, r, kpos, lo, hi, scale=0.125))
-        want = sl.gathered_latent_attention_reference(q_c, q_r, c, r, kpos, lo, hi, scale=0.125)
-    assert got.shape == (G, Q, C) and rel(got, want) < 1e-4
+        got = sl.window_latent_attention(*case, scale=0.125)
+        want = sl.gathered_latent_attention_reference(*case, scale=0.125)
+    assert got.shape == (3, Q, 48) and rel(got, want) < 1e-4
     # by hand, one query: softmax over the keys inside its bounds alone
     g, qi = 1, 4
     seen = (np.asarray(kpos[g]) >= int(lo[g, qi])) & (np.asarray(kpos[g]) <= int(hi[g, qi]))
     s = (np.asarray(q_c[g, qi]) @ np.asarray(c[g]).T + np.asarray(q_r[g, qi]) @ np.asarray(r[g]).T) * 0.125
     p = np.where(seen, np.exp(s - s[seen].max()), 0.0)
     np.testing.assert_allclose(got[g, qi], (p / p.sum()) @ np.asarray(c[g]), rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("Q,K", [(8, 24), (18, 40), (4, 130)])
+def test_the_gathered_kernel_on_one_key_tile_matches_its_twin_on_the_split_rows(Q, K):
+    """A full layer's form of the same body: the keys as ONE tile of rows [c |
+    r] as the plane holds them, against the twin on the split (c, r) — the
+    cases above again, and K one past a lane tile. A tile of any other width —
+    filler columns, a column short — is refused: the plane holds none, so
+    nothing is ever read against a query value it was not written for."""
+    q_c, q_r, c, r, kpos, lo, hi = case = _gathered_case(Q, K, K // 3 + 1, seed=9)
+    rows = sl.key_row(c, r)
+    assert rows.shape == (3, K, 48 + 16)
+    assert np.array_equal(rows[..., :48], c) and np.array_equal(rows[..., 48:], r)
+    with jax.default_matmul_precision("highest"):
+        want = sl.gathered_latent_attention_reference(*case, scale=0.125)
+        got = sl.sparse_latent_attention(q_c, q_r, rows, kpos, lo, hi, scale=0.125)
+        assert rel(sl.sparse_latent_attention_reference(q_c, q_r, rows, kpos, lo, hi, scale=0.125), want) < 1e-6
+        # the two names' one body: the window's entry on the same keys handed as (c, r)
+        assert rel(got, sl.window_latent_attention(*case, scale=0.125)) < 1e-5
+    assert got.shape == (3, Q, 48) and rel(got, want) < 1e-4
+    for other in (rows[..., :-1], jnp.pad(rows, ((0, 0), (0, 0), (0, 8)))):
+        with pytest.raises(ValueError, match="no rows"):
+            sl.sparse_latent_attention(q_c, q_r, other, kpos, lo, hi, scale=0.125)
+
+
+def test_a_full_layer_s_row_in_the_pool_is_the_latent_beside_its_rotated_key():
+    """Float32, a prefill of 37 then a T = 1 step and a 1 + 8 block: at every
+    position the full layers' plane ``kv`` reads ``latent_qkv``'s c in its first
+    C columns and its r in the next dr — nothing behind —, the sliding layers'
+    two planes c and r apart; no plane holds a rotated key of a full layer
+    anywhere else."""
+    params = init_params(CFG, jax.random.key(0), F32)
+    kd = dots3.kinds(CFG)
+    seen = {"full": [], "sliding": []}
+    front = dots3.latent_qkv
+
+    def recorded(p, x, cfg, k, cos, sin, hold=False):
+        out = front(p, x, cfg, k, cos, sin, hold)
+        seen["full" if k.indexed else "sliding"].append((np.asarray(out[2][0]), np.asarray(out[3][0])))
+        return out
+
+    kp, vp = pools(CFG, F32)
+    assert set(kp) == {"kv", "idx", "swa"} and set(vp) == {"swa"}
+    pos = 0
+    try:
+        dots3.latent_qkv = recorded
+        with jax.default_matmul_precision("highest"):
+            for T in (37, 1, 9):  # eagerly: a block-shaped layer calls the front half outside any loop
+                out = dots3.forward_paged(params, CFG, TOKS[:, pos:pos + T], (pos + jnp.arange(T))[None], kp, vp,
+                                          TABLE, attn_impl="xla")
+                kp, vp, pos = out[1], out[2], pos + T
+    finally:
+        dots3.latent_qkv = front
+    at = (np.asarray(TABLE[0])[np.arange(pos) // BS], np.arange(pos) % BS)
+    for kind, n_layers in (("full", 2), ("sliding", 3)):
+        k = kd[kind]
+        calls = seen[kind]
+        assert len(calls) == 3 * n_layers
+        for layer in range(n_layers):
+            c = np.concatenate([calls[step * n_layers + layer][0] for step in range(3)])
+            r = np.concatenate([calls[step * n_layers + layer][1] for step in range(3)])
+            assert c.shape == (pos, k.C) and r.shape == (pos, k.dr) and np.abs(r).min(axis=-1).max() > 0
+            if kind == "full":
+                row = np.asarray(kp["kv"][layer])[at]
+                assert row.shape == (pos, k.C + k.dr)
+                assert np.array_equal(row[:, :k.C], c) and np.array_equal(row[:, k.C:], r)
+            else:
+                assert np.array_equal(np.asarray(kp["swa"][layer])[at], c)
+                assert np.array_equal(np.asarray(vp["swa"][layer])[at], r)
 
 
 def test_the_shares_of_all_chips_add_up_to_the_uncut_layer():
@@ -429,9 +505,10 @@ def test_the_served_precision_reads_inside_the_limit_and_int4_outside():
 
 
 def test_the_pool_holds_planes_by_layer_kind_at_the_published_widths():
-    """Three full layers of 512 + 64 + a 128-wide index key, six sliding ones
-    of 1024 + 64: read from the pool's own shapes, the engine's gauge and the
-    byte plan."""
+    """Three full layers of ONE row of 512 + 64 and a 128-wide index key, six
+    sliding ones of 1024 + 64 in two planes: read from the pool's own shapes
+    (no plane where the full layers' rotated keys lived), the engine's gauge
+    and the byte plan."""
     from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
     from tpu_voice_agent.serve import PagedDecodeEngine, paged
     from tpu_voice_agent.utils import hbmledger, tracing
@@ -443,8 +520,8 @@ def test_the_pool_holds_planes_by_layer_kind_at_the_published_widths():
                             init_weights=False)
     assert eng.sparse and eng.latent and not eng.hybrid
     assert {n: a.shape for n, a in eng.k_pool.items()} == {
-        "kv": (3, 4, 128, 512), "idx": (3, 4, 128, 128), "swa": (6, 4, 128, 1024)}
-    assert {n: a.shape for n, a in eng.v_pool.items()} == {"kv": (3, 4, 128, 64), "swa": (6, 4, 128, 64)}
+        "kv": (3, 4, 128, 576), "idx": (3, 4, 128, 128), "swa": (6, 4, 128, 1024)}
+    assert {n: a.shape for n, a in eng.v_pool.items()} == {"swa": (6, 4, 128, 64)}
     pool_bytes = sum(a.nbytes for pool in (eng.k_pool, eng.v_pool) for a in pool.values())
     assert pool_bytes == 4 * eng.kv_bytes_per_block == 4 * 128 * 17280
     assert hbmledger.engine_hbm_plan(eng)["kv_pool_bytes"] == pool_bytes
@@ -619,20 +696,22 @@ def test_a_group_s_admission_is_the_per_slot_admissions(prompts):
     P = len(eng.prefix_ids)
     assert P == 1024
     for a, b, n in zip(planes_one, planes_grp, lens):  # the suffix's cache, position by position
-        assert len(a) == 5  # kv, idx, swa | kv, swa
+        assert len(a) == 4  # kv (a full layer's rows [c | r]), idx, swa | swa
         for x, y in zip(a, b):
             assert float(np.abs(x[:, :n - P] - y[:, :n - P]).max()) < 1e-4
 
 
-# this model's programs at the rehearsal widths and 32 slots as the PARENT of
-# ISSUE 44 (commit ee06ed6) lowers them (``tests/test_older_programs_pinned._texts``,
-# ``tests/test_admit_group._chunk_program_shas`` on that tree): ISSUE 44 changed
-# the FULL-width chunk program alone
+# this model's programs at the rehearsal widths and 32 slots as ISSUE 45's tree lowers them
+# (``tests/test_older_programs_pinned._texts``, ``tests/test_admit_group._chunk_program_shas``).
+# ISSUE 44 changed the FULL-width chunk program alone and pinned the other three to ITS parent
+# (ee06ed6); ISSUE 45 moved all four — every program of the model writes a full layer's ONE row
+# [c | r] and gathers it once, straight out of the pool — and re-derived them: what a later PR
+# that leaves this model's programs alone must reproduce
 PARENT_SHA256 = {
-    "group": "40e7eb5c4d43b180927204831d7bcdcbe5d96e16a582f6cee183f465dfcec310",
-    "block": "fa375e5aa7978e30cd174009e602af6d31e40b80250206518bfd77084d564591",
-    "chunk": ["250cbdb724897d097ec8fcc8c015c35a6b831a0157490cbcc95e936c8569de5c",
-              "0fe78ce2d14d9022037b4fbbd9cf7a204b8246dc8889b0674f9a8b4f2689ae64"],
+    "group": "2df8668ef87d708bc17833bc3db3e38c8026e97d01e88443924dbbe67d3347fd",
+    "block": "d2108056ef37eebcfa596cff0819a46729ac83034a677683d25e2f093b137089",
+    "chunk": ["d78d902077560881fd9b398c6898fbcfc29a260620a640cf731741faa8a344c3",
+              "779d2ae47f3bf863c157a919f8a6265cb60ae7a07db6694352703e5634bf408c"],
 }
 
 
@@ -658,8 +737,9 @@ def test_the_programs_that_pack_nothing_are_the_parents(lowered_at_32_slots, pro
     """The grouped admission ((4, 64) with ``n_real`` and no ``ffn_pack``: the
     real positions first through the full layers' tiles, block-shaped
     everywhere else), the comparison's one-row 1 + 8 block and the compacted
-    chunk width (72 positions <= 96) lower to the parent's text: a chip run
-    loads the parent's executables."""
+    chunk width (72 positions <= 96) lower to the pinned text (ISSUE 45's: the
+    merged plane moved them all): a change that means to leave them alone
+    loads the parent's executables on the chip."""
     got = lowered_at_32_slots
     if program == "compact":
         assert got["chunk"][1] == PARENT_SHA256["chunk"][1]

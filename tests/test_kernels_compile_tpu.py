@@ -790,15 +790,16 @@ def test_the_indexer_kernel_compiles_at_published_widths(tpu_devices, P, blocks)
                          ids=["selected", "selected-one-block", "window-block", "window-prefix", "window-suffix"])
 def test_the_gathered_latent_kernel_compiles_at_published_widths(tpu_devices, name, G, Q, K, C):
     """ONE kernel under two names: a position's 128 heads over its 2048 selected
-    (c, r) rows of 512 + 64 (a tile of 16 slots; the comparison's 9), and a
-    sliding layer's row — 9 x 64 queries of a fast-forward block, 8 x 64 of a
-    prefill's rows of eight — over the 5-6 blocks of 1024 + 64 that hold its
-    window; the whole key set one tile."""
+    rows [c | r] of 512 + 64 as the full layers' plane holds them (a tile of 16
+    slots; the comparison's 9), and a sliding layer's row — 9 x 64 queries of a
+    fast-forward block, 8 x 64 of a prefill's rows of eight — over the 5-6
+    blocks of 1024 + 64, two planes' rows, that hold its window; the whole key
+    set one tile."""
     from tpu_voice_agent.ops import sparse_latent as sl
 
+    keys = [((G, K, C + 64), BF16)] if name == "sparse_latent_attention" else [((G, K, C), BF16), ((G, K, 64), BF16)]
     compiled = _compile(tpu_devices, getattr(sl, name), ((G, Q, C), BF16), ((G, Q, 64), BF16),
-                        ((G, K, C), BF16), ((G, K, 64), BF16), ((G, K), I32), ((G, Q), I32), ((G, Q), I32),
-                        scale=0.07, interpret=False)
+                        *keys, ((G, K), I32), ((G, Q), I32), ((G, Q), I32), scale=0.07, interpret=False)
     assert name in compiled.as_text()
 
 

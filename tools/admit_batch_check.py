@@ -81,15 +81,18 @@ def the_logits(logits, state, slots, ns):
 
 def written(eng, slot: int, n: int):
     """K and V of the slot's first own block at the prefix's tail and the
-    prompt's own positions, float32 on the host."""
+    prompt's own positions (a pool by layer kind: every plane it holds),
+    float32 on the host."""
+    import jax
     import numpy as np
 
     from tpu_voice_agent.serve.paged import kv_planes
 
     P = len(eng.prefix_ids)
     first, upto = eng._slot_owned[slot][0], n - P // eng.block_size * eng.block_size
-    return [np.asarray(kv_planes(pool)[:, first, :upto], np.float32)
-            for pool in (eng.k_pool, eng.v_pool)]
+    planes = jax.tree.leaves if eng.sparse else (lambda pool: [kv_planes(pool)])
+    return [np.asarray(a[:, first, :upto], np.float32)
+            for pool in (eng.k_pool, eng.v_pool) for a in planes(pool)]
 
 
 def main() -> int:

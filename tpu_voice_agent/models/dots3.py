@@ -37,9 +37,11 @@ over a key set gathered for its queries:
 - a full layer's positions, the REAL ones first (``llama.ffn_pack_index``),
   go tile by tile (16 slots) — as many tiles as hold real positions — through
   ``ops.indexer_scores`` (every pool block scored once for all of them), a
-  read of each position's own blocks out of that by its table, ``top_k``, a
-  gather of the chosen (c, r) rows, and the kernel with a position's H heads
-  as a group;
+  read of each position's own blocks out of that by its table, ``top_k``, ONE
+  gather of the chosen keys' rows [c | r] straight out of the pool (one row a
+  key where two planes paid for two; what a gathered row costs on the chip,
+  and the step it takes past 1 KB, is in PERF.md section 6, PR 45), and the
+  kernel with a position's H heads as a group over that one tile of keys;
 - a sliding layer's rows gather the few blocks that hold their window and
   the kernel takes a row's T x H queries as a group (a prefill wider than
   ``MAX_BLOCK_DECODE_T`` is cut into rows of 8 positions first).
@@ -59,12 +61,17 @@ residual and the MLP — around its attention. A full layer attends the packed
 queries as they are; a sliding layer's tile rows are scattered to their
 positions for the window kernel and its output gathered a tile.
 
-The pool is a pytree a kind: ``k_pool`` {"kv": the full layers' latents (Lf,
-N, bs, C), "swa": the sliding layers' (Ls, N, bs, Cs), "idx": the full
-layers' index keys (Lf, N, bs, di)}, ``v_pool`` {"kv", "swa"}: the rotated
-keys. All ride ONE block table. Parameters: ``attn_full`` / ``attn_swa``
-stack the attention leaves by kind, ``dense_layers`` / ``layers`` the
-feed-forward ones and the norms (``models.mla``'s).
+The pool is a pytree a kind (``cache_spec``): ``k_pool`` {"kv": the full
+layers' rows (Lf, N, bs, C + dr) — a token's latent c and its rotated key r
+side by side, ONE row a token a layer, written by one scatter and gathered by
+one —, "idx": the full layers' index keys (Lf, N, bs, di), a plane of their
+own (the indexer reads it alone, whole), "swa": the sliding layers' latents
+(Ls, N, bs, Cs)}, ``v_pool`` {"swa": the sliding layers' rotated keys (Ls, N,
+bs, drs)} (their gather is by whole blocks: two planes cost it nothing) — no
+plane where a full layer's r would live apart. All ride ONE block table.
+Parameters: ``attn_full`` / ``attn_swa`` stack the attention leaves by kind,
+``dense_layers`` / ``layers`` the feed-forward ones and the norms
+(``models.mla``'s).
 """
 
 from __future__ import annotations
@@ -131,12 +138,14 @@ def latent_stat_names() -> tuple[str, ...]:
 
 def cache_spec(cfg: LlamaConfig) -> dict:
     """What a token holds in the pool, by layer KIND: ``planes`` names each
-    pool's planes as (layers of the kind, width); ``kv_layers`` /
-    ``latent_dim`` / ``rope_dim`` are the full layers'."""
+    pool's planes as (layers of the kind, width) — a full layer's ONE row
+    [c | r] and its index key, a sliding layer's c and r apart; ``kv_layers``
+    / ``latent_dim`` / ``rope_dim`` are the full layers' published sizes,
+    ``token_bytes`` what the planes hold."""
     k = kinds(cfg)
     n = {t: cfg.layer_types.count(t) for t in ("full", "sliding")}
-    planes = {"k": {"kv": (n["full"], k["full"].C), "idx": (n["full"], cfg.index_head_dim)},
-              "v": {"kv": (n["full"], k["full"].dr)}}
+    planes = {"k": {"kv": (n["full"], k["full"].C + k["full"].dr), "idx": (n["full"], cfg.index_head_dim)},
+              "v": {}}
     if n["sliding"]:
         planes["k"]["swa"] = (n["sliding"], k["sliding"].C)
         planes["v"]["swa"] = (n["sliding"], k["sliding"].dr)
@@ -323,15 +332,15 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
     pallas = attn_impl == "pallas"
     index_fn = sl.indexer_scores if pallas else sl.indexer_scores_reference
     twin = sl.gathered_latent_attention_reference
-    attend_full = sl.sparse_latent_attention if pallas else twin
+    attend_full = sl.sparse_latent_attention if pallas else sl.sparse_latent_attention_reference
     attend_window = sl.window_latent_attention if pallas else twin
     if fault is not None and fault not in FAULTS:
         raise ValueError(f"fault {fault!r}: one of {FAULTS}")
 
     B, T = tokens.shape
-    cp, rp, ip = k_pool["kv"], v_pool["kv"], k_pool["idx"]
+    kvp, ip = k_pool["kv"], k_pool["idx"]
     cps, rps = k_pool.get("swa"), v_pool.get("swa")
-    N, bs = cp.shape[1], cp.shape[2]
+    N, bs = kvp.shape[1], kvp.shape[2]
     ncols = block_tables.shape[1]
     nb = min(gather_blocks, ncols) if gather_blocks is not None else ncols
     kd = kinds(cfg)
@@ -397,7 +406,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         hi_w = jnp.repeat(pos_g, kd["sliding"].H, axis=1)  # (G, Tq * Hs), position-major
         lo_w = jnp.maximum(hi_w - reach, 0)
 
-    def full_attention(li, qc_f, qr_f, qi_f, wi_f, cp, rp, ip, out):
+    def full_attention(li, qc_f, qr_f, qi_f, wi_f, kvp, ip, out):
         """The full layers' attention over (P, ...) queries in ``idx``'s
         order, the real positions first, into ``out`` (P, H, C), tile by tile."""
         k = kd["full"]
@@ -423,24 +432,25 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
                 # than the rows it names)
                 col = (sel // bs)[:, :, None] == jnp.arange(nb, dtype=jnp.int32)
                 sblk = jnp.sum(jnp.where(col, tb[:, None, :], 0), axis=-1)
-                c_sel, r_sel = cp[li][sblk, sel % bs], rp[li][sblk, sel % bs]
+                # ONE row a chosen key, [c | r], straight out of the pool (a layer's plane
+                # sliced first is an HBM copy a tile behind a ``while`` that carries the pools)
+                kv_sel = kvp[li, sblk, sel % bs]
             with jax.named_scope("layer/attn/full"):
-                a = attend_full(cut(qc_f), cut(qr_f), c_sel, r_sel, sel,
+                a = attend_full(cut(qc_f), cut(qr_f), kv_sel, sel,
                                 jnp.zeros((tile, k.H), jnp.int32),
                                 jnp.broadcast_to(ps[:, None], (tile, k.H)), scale=scale)
             return jax.lax.dynamic_update_slice_in_dim(out, a, i * tile, 0)
 
         return jax.lax.fori_loop(0, n_tiles, one_tile, out)
 
-    def dense_attention(li, q_c, q_r, cp, rp):
+    def dense_attention(li, q_c, q_r, kvp):
         """The planted fault ``no_selection``: a full layer over every key."""
         k = kd["full"]
         g = lambda a: a.reshape(B, T * k.H, a.shape[-1])
-        c_all = cp[li][block_tables[:, :nb]].reshape(B, nb * bs, k.C)
-        r_all = rp[li][block_tables[:, :nb]].reshape(B, nb * bs, k.dr)
+        kv_all = kvp[li][block_tables[:, :nb]].reshape(B, nb * bs, k.C + k.dr)
         hi = jnp.repeat(positions, k.H, axis=1)
-        a = twin(
-            g(q_c), g(q_r), c_all, r_all,
+        a = sl.sparse_latent_attention_reference(
+            g(q_c), g(q_r), kv_all,
             jnp.broadcast_to(jnp.arange(nb * bs, dtype=jnp.int32), (B, nb * bs)),
             jnp.zeros_like(hi), hi, scale=(k.dn + k.dr) ** -0.5)
         return a.reshape(B, T, k.H, k.C)
@@ -489,8 +499,10 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
             gate = None
         qi, ki, wi = index or (None, None, None)
         with jax.named_scope("layer/kv_write"):
+            # a full layer's position writes ONE row [c | r] and its index key, a sliding one's c and r
+            written = (sl.key_row(c, r), ki) if k.indexed else (c, r)
             planes = tuple(pl.at[li, blk, off].set(v.astype(pl.dtype))
-                           for pl, v in zip(planes, (c, r, ki)))
+                           for pl, v in zip(planes, written))
         return {"c": q_c, "r": q_r, "gate": gate, "i": qi, "w": wi}, planes
 
     def attend_block(k: Kind, li, q_c, q_r, planes):
@@ -498,7 +510,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         window, and the planted fault ``no_selection``."""
         if not k.indexed:
             return window_attention(li, q_c, q_r, *planes)
-        return dense_attention(li, q_c, q_r, *planes[:2])
+        return dense_attention(li, q_c, q_r, planes[0])
 
     def back(p, ffn, k: Kind, x, a, gate):
         """A layer behind its attention, position-wise over (b, t, d) ->
@@ -512,7 +524,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         with jax.named_scope("layer/ffn"):
             return x + y, st
 
-    pools = {"full": (cp, rp, ip), "sliding": (cps, rps)}
+    pools = {"full": (kvp, ip), "sliding": (cps, rps)}
 
     def block_layer(L, kind, ki, x):
         """A layer over the (B, T) block as it stands."""
@@ -618,7 +630,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         if st is not None:
             stats.append(st)
 
-    cp, rp, ip = pools["full"]
+    kvp, ip = pools["full"]
     cps, rps = pools["sliding"]
     if rows is not None:
         with jax.named_scope("layer/out/unpack"):  # once a forward: the positions the head reads
@@ -631,8 +643,8 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     with jax.named_scope("lm_head"):
         logits = _qe("btd,dv->btv", x, params["lm_head"])
-    k_pool = {**k_pool, "kv": cp, "idx": ip, **({} if cps is None else {"swa": cps})}
-    v_pool = {**v_pool, "kv": rp, **({} if rps is None else {"swa": rps})}
+    k_pool = {**k_pool, "kv": kvp, "idx": ip, **({} if cps is None else {"swa": cps})}
+    v_pool = {**v_pool, **({} if rps is None else {"swa": rps})}
     extra = (sum(stats),) if moe_stats else ()
     if attn_stats or latent_stats:
         if attn_stats:

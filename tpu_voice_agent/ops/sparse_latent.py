@@ -21,8 +21,13 @@ sequence position and a query row the bounds [lo, hi] it may see, so one
 kernel serves the selected keys of a full layer (a group = a position's H
 heads over its ``index_topk`` keys, valid while they last) and the window of
 a sliding layer (a group = a row's T x H queries over the blocks that hold
-its window, each query its own causal and window edge). Dots take the pool's
-dtype and accumulate in float32.
+its window, each query its own causal and window edge). The keys come in the
+form their planes hold them — by the arguments' shapes, no flag: a full
+layer's as ONE tile of rows [c | r] (``key_row``; one gather out of one
+plane), scored by one dot against [q_c | q_r] and attended through the tile's
+first C columns (a tile of any other width is refused); a sliding layer's as
+(c, r) out of two planes, two dots summed. Dots take the pool's dtype and
+accumulate in float32.
 """
 
 from __future__ import annotations
@@ -110,58 +115,73 @@ def indexer_scores_reference(q, w, k_plane, layer) -> jax.Array:
     return jnp.sum(jnp.maximum(s, 0.0) * w.astype(F32)[:, :, None], axis=1)
 
 
-def _gathered_kernel(qc_ref, qr_ref, lo_ref, hi_ref, c_ref, r_ref, kpos_ref, o_ref, *,
-                     scale: float):
-    c, r = c_ref[0], r_ref[0]
-    s = (_dot(qc_ref[0], c, ((1,), (1,))) + _dot(qr_ref[0], r, ((1,), (1,)))) * scale  # (Q, K)
+def key_row(c: jax.Array, r: jax.Array) -> jax.Array:
+    """(..., C) latents and (..., R) rotated keys -> the (..., C + R) rows a
+    full layer's plane holds, [c | r]; a query's [q_c | q_r] likewise."""
+    return jnp.concatenate([c, r], axis=-1)
+
+
+def _gathered_kernel(*refs, scale: float, n: int):
+    """``n`` query operands, lo, hi, their ``n`` key operands, kpos -> o (Q,
+    C). A score is the sum of the pairs' dots — (q_c, c) and (q_r, r) where
+    the keys come as two planes' rows, ([q_c | q_r], [c | r]) where they come
+    as one — and the values are the first key operand's first C columns."""
+    qs, (lo_ref, hi_ref), ks, (kpos_ref, o_ref) = (
+        refs[:n], refs[n:n + 2], refs[n + 2:2 * n + 2], refs[2 * n + 2:])
+    s = functools.reduce(jnp.add, (_dot(q[0], k[0], ((1,), (1,))) for q, k in zip(qs, ks))) * scale  # (Q, K)
     kpos = kpos_ref[0]  # (1, K)
     s = jnp.where(jnp.logical_and(kpos >= lo_ref[0], kpos <= hi_ref[0]), s, _NEG_INF)
     p = jnp.exp(s - jnp.max(s, axis=1, keepdims=True))
     l = jnp.sum(p, axis=1, keepdims=True)
+    c = ks[0][0, :, :o_ref.shape[-1]]
     o_ref[0] = (_dot(p.astype(c.dtype), c, ((1,), (0,))) / l).astype(o_ref.dtype)
 
 
-def _gathered(q_c, q_r, c, r, kpos, lo, hi, interpret):
-    """What both entry points hand ``pallas_call``: the grid (one step a
-    group, the whole key set one tile), the specs, the padded arguments."""
+def _gathered(q_c, q_r, keys, kpos, lo, hi, scale, interpret):
+    """What both entry points hand ``pallas_call``: the kernel, the grid (one
+    step a group, the whole key set one tile), the specs, the padded
+    arguments. ``keys``: (c, r), or (kv,) whose rows are [c | r] — the queries
+    then go in as ONE operand, [q_c | q_r]."""
     G, Q, C = q_c.shape
-    K, R = c.shape[1], r.shape[-1]
+    K = keys[0].shape[1]
+    qs = (q_c, q_r) if len(keys) == 2 else (key_row(q_c, q_r),)
+    if qs[0].shape[-1] != keys[0].shape[-1]:
+        raise ValueError(f"keys of {keys[0].shape[-1]} columns are no rows [c | r] of {C} + {q_r.shape[-1]}")
     Qp, Kp = -(-Q // 16) * 16, -(-K // 128) * 128
     padq = lambda a: jnp.pad(a, ((0, 0), (0, Qp - Q)) + ((0, 0),) * (a.ndim - 2))
     padk = lambda a, v=0: jnp.pad(a, ((0, 0), (0, Kp - K)) + ((0, 0),) * (a.ndim - 2),
                                   constant_values=v)
     group = lambda *tail: pl.BlockSpec((1, *tail), lambda g: (g,) + (0,) * len(tail))
     spec = dict(grid=(G,),
-                in_specs=[group(Qp, C), group(Qp, R), group(Qp, 1), group(Qp, 1),
-                          group(Kp, C), group(Kp, R), group(1, Kp)],
+                in_specs=[*(group(Qp, q.shape[-1]) for q in qs), group(Qp, 1), group(Qp, 1),
+                          *(group(Kp, k.shape[-1]) for k in keys), group(1, Kp)],
                 out_specs=group(Qp, C),
                 out_shape=jax.ShapeDtypeStruct((G, Qp, C), q_c.dtype),
                 compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
                 interpret=interpret if interpret is not None else on_cpu())
-    args = (padq(q_c), padq(q_r), padq(lo.astype(jnp.int32))[..., None],
-            padq(hi.astype(jnp.int32))[..., None], padk(c), padk(r),
-            padk(kpos.astype(jnp.int32), -1)[:, None, :])
-    return spec, args
+    args = (*map(padq, qs), padq(lo.astype(jnp.int32))[..., None], padq(hi.astype(jnp.int32))[..., None],
+            *map(padk, keys), padk(kpos.astype(jnp.int32), -1)[:, None, :])
+    return functools.partial(_gathered_kernel, scale=scale, n=len(keys)), spec, args
 
 
 # The two entry points below are ONE kernel under the two names a reader of
-# the device trace greps for. q_c (G, Q, C), q_r (G, Q, R) over c (G, K, C),
-# r (G, K, R) whose key k of group g sits at sequence position kpos[g, k];
-# query row q of the group sees the keys with lo[g, q] <= kpos <= hi[g, q]
-# (lo >= 0: a key at a negative position is padding) -> (G, Q, C), softmax
-# over those keys of ``(q_c . c + q_r . r) * scale`` times the latents.
+# the device trace greps for. q_c (G, Q, C), q_r (G, Q, R) over G groups of K
+# keys whose key k of group g sits at sequence position kpos[g, k]; query row
+# q of the group sees the keys with lo[g, q] <= kpos <= hi[g, q] (lo >= 0: a
+# key at a negative position is padding) -> (G, Q, C), softmax over those keys
+# of ``(q_c . c + q_r . r) * scale`` times the latents.
 
 
 # analyze: ok[jit-sentinel] -- kernel wrapper traced inline by the watched engine loops, never a serving dispatch entry point
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def sparse_latent_attention(q_c: jax.Array, q_r: jax.Array, c: jax.Array, r: jax.Array,
-                            kpos: jax.Array, lo: jax.Array, hi: jax.Array, *, scale: float,
+def sparse_latent_attention(q_c: jax.Array, q_r: jax.Array, kv: jax.Array, kpos: jax.Array,
+                            lo: jax.Array, hi: jax.Array, *, scale: float,
                             interpret: bool | None = None) -> jax.Array:
-    """A full layer's: a group = a position's H heads over its selected keys."""
-    spec, args = _gathered(q_c, q_r, c, r, kpos, lo, hi, interpret)
+    """A full layer's: a group = a position's H heads over its selected keys,
+    the rows ``kv`` (G, K, C + R) as its plane holds them, [c | r]."""
+    kernel, spec, args = _gathered(q_c, q_r, (kv,), kpos, lo, hi, scale, interpret)
     with jax.named_scope("sparse_latent_attention"):  # the kernel alone, by its name, in the device trace
-        out = pl.pallas_call(functools.partial(_gathered_kernel, scale=scale),
-                             name="sparse_latent_attention", **spec)(*args)
+        out = pl.pallas_call(kernel, name="sparse_latent_attention", **spec)(*args)
     return out[:, :q_c.shape[1]]
 
 
@@ -171,16 +191,23 @@ def window_latent_attention(q_c: jax.Array, q_r: jax.Array, c: jax.Array, r: jax
                             kpos: jax.Array, lo: jax.Array, hi: jax.Array, *, scale: float,
                             interpret: bool | None = None) -> jax.Array:
     """A sliding layer's: a group = a row's T x H queries over the blocks
-    that hold its window, each query its own causal and window edge."""
-    spec, args = _gathered(q_c, q_r, c, r, kpos, lo, hi, interpret)
+    that hold its window, c (G, K, C) and r (G, K, R) out of their two
+    planes, each query its own causal and window edge."""
+    kernel, spec, args = _gathered(q_c, q_r, (c, r), kpos, lo, hi, scale, interpret)
     with jax.named_scope("window_latent_attention"):  # the kernel alone, by its name, in the device trace
-        out = pl.pallas_call(functools.partial(_gathered_kernel, scale=scale),
-                             name="window_latent_attention", **spec)(*args)
+        out = pl.pallas_call(kernel, name="window_latent_attention", **spec)(*args)
     return out[:, :q_c.shape[1]]
 
 
+def sparse_latent_attention_reference(q_c, q_r, kv, kpos, lo, hi, *, scale: float) -> jax.Array:
+    """Pure-jnp twin of ``sparse_latent_attention``: the rows split again."""
+    C, R = q_c.shape[-1], q_r.shape[-1]
+    return gathered_latent_attention_reference(q_c, q_r, kv[..., :C], kv[..., C:C + R], kpos, lo, hi,
+                                               scale=scale)
+
+
 def gathered_latent_attention_reference(q_c, q_r, c, r, kpos, lo, hi, *, scale: float) -> jax.Array:
-    """Pure-jnp twin of both entry points, float32 softmax."""
+    """Pure-jnp twin of the kernel on keys that come as (c, r), float32 softmax."""
     s = (jnp.einsum("gqc,gkc->gqk", q_c, c, preferred_element_type=F32)
          + jnp.einsum("gqr,gkr->gqk", q_r, r, preferred_element_type=F32)) * scale
     seen = (kpos[:, None, :] >= lo[:, :, None]) & (kpos[:, None, :] <= hi[:, :, None])

@@ -298,7 +298,9 @@ def kv_planes(pool):
     requests hold K/V alone has nothing else there; one with a recurrent
     state (``models.sambay``) keeps its per-slot planes beside them in a
     pytree, under other keys, and everything that moves BLOCKS reads and
-    writes ``["kv"]``."""
+    writes ``["kv"]``. (A pool by layer KIND, ``models.dots3``, holds the
+    planes its ``cache_spec`` names — its V side no ``"kv"`` at all — and is
+    moved plane by plane as a tree, never through here.)"""
     return pool["kv"] if isinstance(pool, dict) else pool
 
 
@@ -306,16 +308,18 @@ def kv_planes(pool):
 @partial(jax.jit, donate_argnames=("k_pool", "v_pool"))
 def _scatter_blocks(k_pool, v_pool, src_k, src_v, dst_idx):
     """Write (L, n, nkv, hd) rows into the flat pool at dst_idx (n,)."""
-    kp, vp = kv_planes(k_pool), kv_planes(v_pool)
-    L, N, bs = kp.shape[0], kp.shape[1], kp.shape[2]
-    shp = kp.shape
     if isinstance(src_k, dict):
         # a latent cache with planes by layer KIND (models.dots3): every plane
         # the source names, each of its own layers and width, at the same
-        # (block, offset) — they ride one table
+        # (block, offset) — they ride one table, and a pool holds the planes
+        # its model's ``cache_spec`` names and no other
+        bs = jax.tree.leaves(k_pool)[0].shape[2]
         at = (slice(None), dst_idx // bs, dst_idx % bs)
         put = lambda pool, src: {**pool, **{n: pool[n].at[at].set(v) for n, v in src.items()}}
         return put(k_pool, src_k), put(v_pool, src_v)
+    kp, vp = kv_planes(k_pool), kv_planes(v_pool)
+    L, N, bs = kp.shape[0], kp.shape[1], kp.shape[2]
+    shp = kp.shape
     if isinstance(k_pool, dict):
         # a hybrid model's planes are written as they are shaped, (block,
         # offset): XLA relays their flat view out around a scatter (see
