@@ -30,38 +30,50 @@ TEXTS = ["search for laptops under 1000",
 SLOTS = 32  # admit_rows = 4
 
 
-def _engine(model: str) -> PagedDecodeEngine:
-    kw = dict(max_len=1536, batch_slots=SLOTS, prefill_buckets=(128, 256, 1024), block_size=128,
-              pool_blocks=96, fast_forward=8)
+def _model(model: str) -> dict:
+    """The engine arguments that name a test-size model of each family
+    (``tests/test_family_contract.py`` builds its engines from them too)."""
     if model == "dense":
-        eng = PagedDecodeEngine(preset="test-tiny", **kw)
-    elif model == "routed":
-        eng = PagedDecodeEngine(cfg=LlamaConfig(
+        return dict(preset="test-tiny")
+    if model == "routed":
+        return dict(cfg=LlamaConfig(
             vocab_size=1024, dim=128, n_layers=2, n_heads=4, n_kv_heads=4, ffn_dim=64,
             max_seq_len=1536, n_experts=8, top_k=2, capacity_factor=4.0, norm_topk=False,
-            qk_norm=True), **kw)
-    elif model == "hybrid":
-        from benchmark.builders import sambay_stack
+            qk_norm=True))
+    if model == "hybrid":
         from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
         from tpu_voice_agent.models import sambay
 
-        cfg = dataclasses.replace(sambay.PRESETS["sambay-test"], vocab_size=1024,
-                                  max_seq_len=1536, window=384)
-        eng = PagedDecodeEngine(cfg=cfg, tokenizer=default_tokenizer(), quant="int8",
-                                init_weights=False, **kw)
+        return dict(cfg=dataclasses.replace(sambay.PRESETS["sambay-test"], vocab_size=1024,
+                                            max_seq_len=1536, window=384),
+                    tokenizer=default_tokenizer())
+    # "share": layers of two kinds, a parallel block, held experts, a tied head; "latent": a
+    # latent cache, leading dense layers; "sparse": an indexer over planes by layer kind —
+    # each at its file's rehearsal widths
+    import json
+    from pathlib import Path
+
+    from benchmark.builders import cohere2moe_stack, dots3_stack, moonlight_stack, parse_stack
+
+    name, stack = {"share": ("command-a-plus-05-2026-int8", cohere2moe_stack),
+                   "latent": ("moonlight-16b-a3b-int8", moonlight_stack),
+                   "sparse": ("dots3-note-prev-int8", dots3_stack)}[model]
+    conf = json.loads((Path(__file__).parents[1] / f"benchmark/configs/{name}.json").read_text())
+    run, serving = parse_stack.as_run(conf, True)
+    cfg = stack.llama_config(run, {**serving, "site_context_tokens": 0})
+    return dict(cfg=dataclasses.replace(cfg, max_seq_len=1536), quant=None)
+
+
+def _engine(model: str) -> PagedDecodeEngine:
+    kw = dict(max_len=1536, batch_slots=SLOTS, prefill_buckets=(128, 256, 1024), block_size=128,
+              pool_blocks=96, fast_forward=8, **_model(model))
+    if model == "hybrid":
+        from benchmark.builders import sambay_stack
+
+        eng = PagedDecodeEngine(quant="int8", init_weights=False, **kw)
         eng.load_params(sambay_stack.make_params(eng.cfg, 23))
-    else:  # "share": layers of two kinds, a parallel block, held experts, a tied head;
-        # "latent": a latent cache, leading dense layers — each at its file's rehearsal widths
-        import json
-        from pathlib import Path
-
-        from benchmark.builders import cohere2moe_stack, moonlight_stack, parse_stack
-
-        name, stack = {"share": ("command-a-plus-05-2026-int8", cohere2moe_stack),
-                       "latent": ("moonlight-16b-a3b-int8", moonlight_stack)}[model]
-        conf = json.loads((Path(__file__).parents[1] / f"benchmark/configs/{name}.json").read_text())
-        cfg = stack.llama_config(*parse_stack.as_run(conf, True))
-        eng = PagedDecodeEngine(cfg=dataclasses.replace(cfg, max_seq_len=1536), quant=None, **kw)
+    else:
+        eng = PagedDecodeEngine(**kw)
     install_prompt_prefix(eng)
     return eng
 

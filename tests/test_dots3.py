@@ -26,6 +26,7 @@ import pytest
 from benchmark.builders import dots3_stack, parse_stack
 from benchmark.reference import dots3_decoder as ref
 from tpu_voice_agent.models import dots3, llama, mla
+from tpu_voice_agent.models.family import family
 from tpu_voice_agent.models.llama import forward_paged, init_params, quantize_params
 from tpu_voice_agent.ops import sparse_latent as sl
 
@@ -110,16 +111,18 @@ def test_the_configuration_keeps_the_published_widths_and_names_its_cut():
             full.dense_ffn_dim, full.ffn_dim, full.vocab_size) == (256, 32, 0, 8, 1, 13824, 1536, 19008)
     assert full.attn_gate and full.lora_rescale and full.router_bias and full.shared_sum
     assert full.layer_types == ("full", "full") + ("sliding",) * 3 + ("full",) + ("sliding",) * 3
-    assert llama.paged_only(full) and llama.latent(full)
-    spec = mla.cache_spec(full)
+    fam = family(full)  # the record the serving side reads
+    assert (fam.name, fam.module, fam.scratch_prefix, fam.prefix_whole_blocks) == ("sparse", dots3, True, True)
+    spec = fam.cache
     # a full layer's latent and rotated key share ONE row of 512 + 64; no plane of full-layer r's beside it
     assert spec["planes"] == {"k": {"kv": (3, 576), "idx": (3, 128), "swa": (6, 1024)}, "v": {"swa": (6, 64)}}
-    assert (spec["latent_dim"], spec["rope_dim"], spec["kv_layers"]) == (512, 64, 3)  # as published
+    assert spec["by_name"] and not spec["state_column"] and spec["slot_planes"] == {"k": {}, "v": {}}
+    assert (full.kv_lora_rank, full.qk_rope_dim, full.layer_types.count("full")) == (512, 64, 3)  # as published
     # 1408 B a token a full layer, 2176 B a sliding one (the file's deployment)
-    assert spec["token_bytes"] == 3 * 1408 + 6 * 2176 == 17280
+    assert fam.token_bytes == 3 * 1408 + 6 * 2176 == 17280
     assert dots3.layer_plan(full)[:3] == (("full", 0), ("full", 1), ("sliding", 0))
-    assert mla.latent_stat_names(full) == mla.LATENT_STATS + dots3.SPARSE_STATS
-    assert mla.latent_stat_names(llama.PRESETS["test-tiny"]) == mla.LATENT_STATS
+    assert fam.count("latent").metrics == tuple(f"attn.{n}" for n in mla.LATENT_STATS + dots3.SPARSE_STATS)
+    assert [c.name for c in family(llama.PRESETS["test-tiny"]).counts] == ["attn"]
     # the rehearsal: every mechanism present, selection and window binding
     assert (CFG.layer_types, CFG.first_dense_layers) == (("full", "sliding", "full", "sliding", "sliding"), 2)
     assert (CFG.n_heads, CFG.swa_n_heads, CFG.kv_lora_rank, CFG.swa_kv_lora_rank) == (4, 2, 48, 40)
@@ -163,7 +166,7 @@ def test_a_ragged_block_of_two_rows_behind_chunks_is_the_reference():
                                 kp, vp, tables, attn_impl="xla", **kw)
             kp, vp = out[1], out[2]
             got.append(np.asarray(out[0]))
-        stats = dict(zip(dots3.latent_stat_names(), np.asarray(out[-1]).tolist()))
+        stats = dict(zip(mla.LATENT_STATS + dots3.SPARSE_STATS, np.asarray(out[-1]).tolist()))
     got = np.concatenate(got, axis=1)
     for b, real in ((0, 36), (1, 35)):
         want = ref.forward(params, [int(t) for t in toks[b]], MODEL, last=36)
@@ -612,7 +615,7 @@ def test_the_engine_serves_it_behind_the_batcher_at_both_chunk_widths(monkeypatc
     many = batcher.generate_many(prompts)
     assert all(r.error is None for r in solo + many)
     assert {c.rows for c in chunks} == {2, 8}
-    assert all(c.moe.shape == (len(llama.moe_stat_names(eng.cfg)),) and c.latent.shape == (6,) for c in chunks)
+    assert all(c.counts["moe"].shape == (len(llama.moe_stat_names(eng.cfg)),) and c.counts["latent"].shape == (6,) for c in chunks)
     assert many[0].token_ids == solo[0].token_ids  # the same plan at either width
     counters = fresh.snapshot()["counters"]
     assert counters["moe.assigned_rows"] > counters["moe.local_rows"] > 0

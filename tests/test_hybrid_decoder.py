@@ -38,10 +38,11 @@ def rel(got, want) -> float:
 
 
 def pools(cfg, dtype=jnp.bfloat16, slots=SLOTS):
-    spec = sambay.cache_spec(cfg, slots)
-    kv = (spec["kv_layers"], N, BS, spec["kv_heads"], spec["kv_head_dim"])
-    return ({"kv": jnp.zeros(kv, dtype), "conv": jnp.zeros(spec["conv"][0], dtype)},
-            {"kv": jnp.zeros(kv, dtype), "ssm": jnp.zeros(*spec["ssm"])})
+    from tpu_voice_agent.serve.paged import build_pools
+
+    # the float32 states keep their dtype, every other plane takes ``dtype``
+    return build_pools(sambay.cache_spec(cfg), N, BS, slots,
+                       zeros=lambda shape, dt: jnp.zeros(shape, dt if dt == jnp.float32 else dtype))
 
 
 TABLE = jnp.asarray([[1, 2, 3, 4, 1]], jnp.int32)  # four blocks, then the slot's state index
@@ -293,7 +294,7 @@ def test_the_compacted_width_is_the_full_width_state_included(engine):
     the leftover state."""
     alone, chunks = _generate(engine, TEXTS[:1])
     assert {c.rows for c in chunks} == {engine.compact_rows} == {1}
-    assert all(c.hybrid is not None and c.hybrid.shape == (len(sambay.HYBRID_STATS),) for c in chunks)
+    assert all(c.counts["hybrid"].shape == (len(sambay.HYBRID_STATS),) for c in chunks)
     together, chunks = _generate(engine, TEXTS)
     assert engine.batch_slots in {c.rows for c in chunks}
     assert together[0] == alone[0] and len(alone[0]) == 40

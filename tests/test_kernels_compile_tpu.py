@@ -384,11 +384,9 @@ def _hybrid_engine(monkeypatch):
 
 
 def _hybrid_pools(eng, s, S):
-    from tpu_voice_agent.models.sambay import cache_spec
+    from tpu_voice_agent.serve.paged import build_pools
 
-    spec = cache_spec(eng.cfg, eng.batch_slots)
-    kv = S((spec["kv_layers"], s["pool_blocks"], eng.block_size, spec["kv_heads"], spec["kv_head_dim"]), BF16)
-    return {"kv": kv, "conv": S(*spec["conv"])}, {"kv": kv, "ssm": S(*spec["ssm"])}
+    return build_pools(eng._cache_spec, s["pool_blocks"], eng.block_size, eng.batch_slots, zeros=S)
 
 
 @pytest.mark.parametrize("width", [pytest.param("full", marks=pytest.mark.slow), "compact"])  # the chip runs "full" in every check

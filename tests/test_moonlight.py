@@ -23,6 +23,7 @@ from benchmark.builders import moonlight_stack, parse_stack
 from benchmark.reference import decoder as dense_ref
 from benchmark.reference import moonlight_decoder as ref
 from tpu_voice_agent.models import llama, mla
+from tpu_voice_agent.models.family import family
 from tpu_voice_agent.models.llama import forward_paged, init_params, quantize_params
 
 F32 = jnp.float32
@@ -76,9 +77,11 @@ def test_the_configuration_keeps_the_published_widths_and_names_its_cut():
             full.n_held, full.top_k, full.first_dense_layers, full.dense_ffn_dim, full.ffn_dim) == \
         (192, 512, 64, 128, 64, 64, 6, 1, 11264, 1408)
     assert full.router_bias and full.shared_sum and full.router_scale == 2.446
-    assert llama.paged_only(full) and llama.latent(full) and not llama.latent(llama.PRESETS["test-tiny"])
-    spec = mla.cache_spec(full)
-    assert spec["kv_layers"] * (spec["latent_dim"] + spec["rope_dim"]) * 2 == 17 * 1152 == 19584
+    fam = family(full)  # the record the serving side reads
+    assert (fam.name, fam.module, fam.scratch_prefix) == ("latent", mla, True)
+    assert family(llama.PRESETS["test-tiny"]).name == "plain"
+    assert fam.cache["planes"] == {"k": {"kv": (17, 512)}, "v": {"kv": (17, 64)}} and not fam.cache["by_name"]
+    assert fam.token_bytes == 17 * 1152 == 19584
     assert (CFG.first_dense_layers, CFG.kv_lora_rank, CFG.qk_rope_dim) == (2, 48, 16)
 
 
@@ -280,7 +283,7 @@ def test_the_engine_serves_it_behind_the_batcher_at_both_chunk_widths(monkeypatc
     many = batcher.generate_many([render_prompt(t, {}) for t in texts])
     assert all(r.error is None for r in solo + many)
     assert {c.rows for c in chunks} == {2, 8}  # one live row rides the compacted width, four the full one
-    assert all(c.moe.shape == (4,) and c.attn.shape == (2,) and c.latent.shape == (2,) for c in chunks)
+    assert all([(k, v.shape) for k, v in c.counts.items()] == [("moe", (4,)), ("attn", (2,)), ("latent", (2,))] for c in chunks)
     assert many[0].token_ids == solo[0].token_ids  # the same plan at either width
     counters = fresh.snapshot()["counters"]
     assert counters["moe.assigned_rows"] > 0 and counters["attn.row_blocks"] > 0

@@ -296,7 +296,7 @@ def test_both_dispatches_are_token_identical_through_the_batcher():
         out = [bat.results[rid] for rid in rids]
         assert all(r.error is None for r in out)
         # every chunk's record carried the expert-row counts out of the loop
-        assert all(c.moe is not None and c.moe.shape == (len(llama.MOE_STATS),) for c in chunks)
+        assert all(c.counts["moe"].shape == (len(llama.MOE_STATS),) for c in chunks)
         return [r.token_ids for r in out], eng
 
     cfg = olmoe_cfg(8, 2)
@@ -441,12 +441,12 @@ def test_the_fence_around_the_dense_path(model, monkeypatch):
     moe_names = sorted(k for part in snap.values() if isinstance(part, dict) for k in part
                        if str(k).startswith("moe."))
     if model == "routed":
-        assert set(arity) == {18} and all(c.moe.shape == (4,) for c in chunks)
+        assert set(arity) == {18} and all(c.counts["moe"].shape == (4,) for c in chunks)
         assert moe_names == sorted(f"moe.{n}" for n in llama.MOE_STATS)
         assert all(snap["counters"][k] > 0 for k in moe_names)
         return
     assert set(arity) == {17} and moe_names == []
-    assert all(c.moe is None for c in chunks)
+    assert all("moe" not in c.counts for c in chunks)
     assert {k for k in vars(eng) if k.startswith("_last_")} <= {"_last_prefill_compute_ms", "_last_cached_tokens"}
     plain = DecodeEngine(preset="test-tiny", max_len=1536, prefill_buckets=(128, 256, 1024))
     assert [r.token_ids for r in out] == [

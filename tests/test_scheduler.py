@@ -401,7 +401,7 @@ def test_every_decode_chunk_returns_one_record_under_one_signature(kind, monkeyp
             assert len(res.conf) == 5 and all(np.asarray(lane).shape == (B,) for lane in res.conf)
         else:
             assert res.conf is None
-        assert res.moe is None  # a dense model's chunk program has no such output
+        assert "moe" not in res.counts  # a dense model's chunk program has no such output
         counts = (res.row_fwds, res.row_accepts, res.row_drafted)
         if kind == "spec-over-paged":  # host counts a row: the live one rode every verify step
             assert all(c.shape == (B,) and c.dtype == np.int64 for c in counts) and res.row_fwds[0] == res.fwds
@@ -488,7 +488,7 @@ def test_common_pass_counters_behind_the_batcher_match_the_tables(monkeypatch):
         assert int(res.fwds) == 1 and (tables[live, :shared] == tables[live][0, :shared]).all()
         common = shared if live.sum() > 1 else first[live][0] // bs
         by_hand = [common * live.sum(), (last[live] // bs + 1).sum()]
-        assert np.asarray(res.attn).tolist() == by_hand
+        assert np.asarray(res.counts["attn"]).tolist() == by_hand
         want[:] += by_hand
         return res
 

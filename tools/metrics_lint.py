@@ -457,6 +457,14 @@ def check_catalog(reg: dict[str, dict[str, list[str]]],
     return problems
 
 
+# What a chunk program counts (``models/family.py`` ``Count``): ONE loop of the
+# batcher adds each vector to the counters its record names, built from the
+# modules' own tuples (``llama.moe_stat_names``, ``ops.ATTN_STATS``,
+# ``mla.LATENT_STATS``, ``sambay.HYBRID_STATS``, ``llama.FFN_STATS``) — no
+# ``inc("...")`` a name, so their families are registered where ``Count`` is defined
+COUNTED = ("moe.*", "attn.*", "ssm.*", "ffn.*")
+
+
 def scan_source(root: pathlib.Path) -> dict[str, dict[str, list[str]]]:
     """name -> kind -> [file:line, ...] over every .py under root."""
     reg: dict[str, dict[str, list[str]]] = {}
@@ -473,6 +481,10 @@ def scan_source(root: pathlib.Path) -> dict[str, dict[str, list[str]]]:
                 kind = _KIND[m.group("kind")]
                 reg.setdefault(name, {}).setdefault(kind, []).append(
                     f"{path.relative_to(root)}:{i}")
+        at = text.find("\nclass Count(")
+        for name in COUNTED if at >= 0 else ():
+            reg.setdefault(name, {}).setdefault("counter", []).append(
+                f"{path.relative_to(root)}:{text.count(chr(10), 0, at) + 2}")
     return reg
 
 

@@ -85,9 +85,9 @@ import jax
 import jax.numpy as jnp
 
 from .llama import (MAX_BLOCK_DECODE_T, LlamaConfig, _attn_stats, _ffn, _qe, _scan_and_whole,
-                    apply_rope_interleaved, ffn_pack_index, layer_norm, moe_stat_names, rms_norm,
-                    rope_tables, row_tiles)
-from .mla import LATENT_STATS, _dense_ffn
+                    apply_rope_interleaved, cache_planes, ffn_pack_index, layer_norm, moe_stat_names,
+                    rms_norm, rope_tables, row_tiles)
+from .mla import _dense_ffn
 
 F32 = jnp.float32
 
@@ -132,16 +132,10 @@ def layer_plan(cfg: LlamaConfig) -> tuple[tuple[str, int], ...]:
     return tuple(plan)
 
 
-def latent_stat_names() -> tuple[str, ...]:
-    return LATENT_STATS + SPARSE_STATS
-
-
 def cache_spec(cfg: LlamaConfig) -> dict:
-    """What a token holds in the pool, by layer KIND: ``planes`` names each
-    pool's planes as (layers of the kind, width) — a full layer's ONE row
-    [c | r] and its index key, a sliding layer's c and r apart; ``kv_layers``
-    / ``latent_dim`` / ``rope_dim`` are the full layers' published sizes,
-    ``token_bytes`` what the planes hold."""
+    """What a token holds in the pool, by layer KIND: each pool's planes as
+    (layers of the kind, width) — a full layer's ONE row [c | r] and its index
+    key, a sliding layer's c and r apart."""
     k = kinds(cfg)
     n = {t: cfg.layer_types.count(t) for t in ("full", "sliding")}
     planes = {"k": {"kv": (n["full"], k["full"].C + k["full"].dr), "idx": (n["full"], cfg.index_head_dim)},
@@ -149,9 +143,7 @@ def cache_spec(cfg: LlamaConfig) -> dict:
     if n["sliding"]:
         planes["k"]["swa"] = (n["sliding"], k["sliding"].C)
         planes["v"]["swa"] = (n["sliding"], k["sliding"].dr)
-    return {"kv_layers": n["full"], "latent_dim": k["full"].C, "rope_dim": k["full"].dr,
-            "planes": planes,
-            "token_bytes": 2 * sum(L * w for pool in planes.values() for L, w in pool.values())}
+    return cache_planes(planes["k"], planes["v"], by_name=True)
 
 
 # ---------------------------------------------------------------- params
@@ -316,13 +308,14 @@ def _position_tile(P: int) -> int:
 
 def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, block_tables, *,
                   attn_impl: str = "pallas", write_mask=None, trash_idx=None,
-                  gather_blocks: int | None = None, n_real=None, logit_pos=None,
-                  moe_stats: bool = False, attn_stats: bool = False,
+                  fresh_block: bool = False, gather_blocks: int | None = None, n_real=None,
+                  logit_pos=None, moe_stats: bool = False, attn_stats: bool = False,
                   latent_stats: bool = False, ffn_pack: int = 0, fault: str | None = None):
     """``llama.forward_paged`` for this model: ``k_pool`` / ``v_pool`` the
-    pytrees the module's text names. -> (logits, k_pool, v_pool, None, None),
+    pytrees the module's text names (``fresh_block`` is a promise this forward
+    does not need: ONE attention path whatever T is). -> (logits, k_pool, v_pool, None, None),
     then with ``moe_stats`` the routed layers' counts, with ``attn_stats``
-    ``ops.ATTN_STATS``, with ``latent_stats`` ``latent_stat_names()`` over all
+    ``ops.ATTN_STATS``, with ``latent_stats`` ``LATENT_STATS + SPARSE_STATS`` over all
     layers, where the block is walked packed (``ffn_pack`` under its B * T
     positions, with ``n_real``) ``llama.FFN_STATS``. ``fault`` PLANTS one, for
     the comparison's limit to be set against (``FAULTS``); None everywhere

@@ -1,0 +1,155 @@
+"""The serving contract of a model family: ONE record the model side owns and
+the serving side (``serve.paged`` / ``engine`` / ``scheduler``, ``utils.hbm*``)
+reads — what a request keeps on the device, which variant of the chunk
+program the model compiles, what its forwards count, and what the engine may
+not do with it. ``family`` is the only place that asks which model a
+configuration is; a new family is its own module, its configuration's fields
+and one more return there.
+
+The cache, one shape for every family (each module's ``cache_spec(cfg)``):
+
+    {"planes": {"k": {name: (layers, *trailing)}, "v": {...}},
+     "slot_planes": {"k": {name: ((layers, *trailing), dtype)}, "v": {...}},
+     "by_name": bool, "state_column": bool}
+
+A block plane is (layers, N, block_size, *trailing) bfloat16 in the pool, a
+per-SLOT plane (layers, slots, *trailing); a pool is the dict of its planes
+``by_name``, or else its one plane ``"kv"`` itself; with ``state_column`` a
+block-table row carries its slot's index in one more column.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from functools import lru_cache
+from math import prod
+from types import ModuleType
+from typing import Mapping, NamedTuple
+
+from ..ops import ATTN_STATS
+from . import dots3, llama, mla, sambay
+
+# the rows the position-wise regions of a fast-forward block are packed into
+# (ISSUE 37: the MLPs; ISSUE 41: q/k/v and the output projection with them):
+# under the ridge of int8 weights on this chip (~120 rows: an MLP costs the
+# same from 72 to 96 and more from 128 on), over what a chunk's forwards hold
+# but its first (PERF.md section 5 item 1 has both measurements). Rows, not rows
+# a slot: the ridge is the chip's, and a block no wider than this packs nothing
+FFN_PACK_ROWS = 96
+
+
+class Count(NamedTuple):
+    """One thing a chunk program counts: a carry of ``len(metrics)`` int32
+    summed over the chunk's forwards."""
+
+    name: str  # its key in ``ChunkResult.counts``
+    keyword: str  # the keyword of ``forward_paged`` that asks a forward for it
+    metrics: tuple[str, ...]  # the counter each element is added to, in the forward's order
+
+
+ATTN = Count("attn", "attn_stats", tuple(f"attn.{n}" for n in ATTN_STATS))
+# a program whose position-wise regions may run packed, LAST (``ffn_pack`` = the rows)
+FFN = Count("ffn", "ffn_pack", tuple(f"ffn.{n}" for n in llama.FFN_STATS))
+
+
+def _counts(cfg, latent: tuple[str, ...] = ()) -> tuple[Count, ...]:
+    """A LlamaConfig's, in the order its chunk program carries them: the
+    routed layers' expert rows, the attention row-blocks, a latent cache's reads."""
+    routed = (Count("moe", "moe_stats", tuple(f"moe.{n}" for n in llama.moe_stat_names(cfg))),)
+    return (routed if cfg.n_experts else ()) + (ATTN,) + (
+        (Count("latent", "latent_stats", tuple(f"attn.{n}" for n in latent)),) if latent else ())
+
+
+@dataclass(frozen=True)
+class Family:
+    name: str
+    module: ModuleType  # ``forward_paged`` / ``init_params`` / ``quantize_params`` are looked up
+    # on it at CALL time (a check plants faults by rebinding a module's forward)
+    cache: Mapping  # the module's ``cache_spec(cfg)``: the shape in this module's text
+    counts: tuple[Count, ...]  # what every chunk program carries, in carry order (``FFN`` behind)
+    error: type  # what ``refuse`` raises
+    refuses: Mapping[str, str]  # serving feature -> why this family cannot honour it
+    # the program variant
+    n_real: str = ""  # where a forward is told its rows' real positions: "admit" (a prefill
+    # behind the prefix), "always" (the prefix and every decode forward too), "" (a packed block
+    # alone). One that is told picks an admission's attention path itself, by T, from the engine's
+    # kernels; for the others the caller names the layout's ("xla" behind a prefix)
+    one_head: bool = False  # the head runs on the ONE position a row of a 1 + W block reads
+    pack_rows: int = FFN_PACK_ROWS  # the packed width of a fast-forward block; 0: no packed branch
+    scratch_prefix: bool = True  # the prompt prefix is prefilled through a scratch POOL
+    # (``forward_paged`` alone runs the model), not through the dense cache
+    prefix_whole_blocks: bool = False  # the cached prefix ends on a block (no sub-block tail to
+    # scatter plane by plane; the rest rides every suffix) and may pass the largest bucket, in chunks
+
+    def refuse(self, feature: str, error: type | None = None) -> None:
+        """Raise where this family cannot honour ``feature``: its own class, or
+        ``error`` from an entry point that states something else (a function
+        that does not implement it: ``NotImplementedError``)."""
+        why = self.refuses.get(feature)
+        if why is not None:
+            raise (error or self.error)(f"{feature}: {why}")
+
+    def count(self, name: str) -> Count:
+        return next(c for c in self.counts + (FFN,) if c.name == name)
+
+    @property
+    def kv_by_head(self) -> bool:
+        """The block planes are K and V by head, (layers, heads, head_dim): what a
+        dense decoder's arithmetic sizes (``utils.hbmledger``)."""
+        return all(len(p) == 3 for side in self.cache["planes"].values() for p in side.values())
+
+    @property
+    def token_bytes(self) -> int:
+        """Bytes a token holds in the pool's block planes (bfloat16)."""
+        return 2 * sum(prod(p) for side in self.cache["planes"].values() for p in side.values())
+
+
+_STATE = ("K/V blocks alone, without the recurrent state that goes with them: not with a "
+          "SambaYConfig")
+_HYBRID_REFUSES = {
+    "kv_quant": f"KV_QUANT re-stores {_STATE}",
+    "radix": f"radix reuse hands a slot cached {_STATE}",
+    "mesh": f"a mesh shards weights of a LlamaConfig's layout and {_STATE}",
+    "spec": f"speculative decoding rolls back (overwrite-before-attend) {_STATE}",
+    "handoff": f"a handoff ships and adopts {_STATE}",
+    "chunked_prefill": "the cursor of a chunked admission carries no count of real positions "
+                       "for the recurrent state: the one-shot prefill_slot serves it",
+    "dense_cache": "the state of a SambaYConfig's requests lives in the paged pool's per-slot "
+                   "planes, forward_paged's: PagedDecodeEngine alone serves it",
+    "ffn_pack": "models.sambay's MLPs have no packed branch (ROADMAP S3 (e))",
+}
+_PLANES = "K and V planes by head: a latent cache has none"
+_LATENT_REFUSES = {
+    "kv_quant": f"KV_QUANT re-stores {_PLANES}",
+    "radix": f"radix reuse hands a slot cached {_PLANES}",
+    "mesh": f"a mesh shards {_PLANES}",
+    "spec": f"a verify step rolls back {_PLANES}",
+    "handoff": f"a handoff ships and adopts {_PLANES}",
+    "dense_cache": f"a dense cache holds {_PLANES} (forward_paged alone runs it, "
+                   "PagedDecodeEngine on one device serves it)",
+}
+_PAGED_ONLY = {"dense_cache": "layers of more than one kind, a parallel block and a tied head are "
+                              "forward_paged's: PagedDecodeEngine serves this model, the dense "
+                              "cache does not"}
+
+
+@lru_cache(maxsize=256)  # configurations are few, frozen and hashable; the record is read-only
+def family(cfg) -> Family:
+    """The record of ``cfg``'s family."""
+    if not isinstance(cfg, llama.LlamaConfig):  # a ``sambay.SambaYConfig``: K/V and a recurrent state
+        hybrid = Count("hybrid", "hybrid_stats", sambay.HYBRID_STATS)
+        return Family("hybrid", sambay, sambay.cache_spec(cfg), (hybrid, ATTN),
+                      sambay.StateNotCarried, _HYBRID_REFUSES, n_real="always",
+                      one_head=True, pack_rows=0)
+    if cfg.index_topk:  # learned sparse attention over a latent cache, planes by layer kind
+        return Family("sparse", dots3, dots3.cache_spec(cfg),
+                      _counts(cfg, mla.LATENT_STATS + dots3.SPARSE_STATS), mla.LatentCacheOnly,
+                      _LATENT_REFUSES, n_real="admit", one_head=True, prefix_whole_blocks=True)
+    if cfg.kv_lora_rank:  # a latent and ONE rotated key a token a layer
+        return Family("latent", mla, mla.cache_spec(cfg), _counts(cfg, mla.LATENT_STATS),
+                      mla.LatentCacheOnly, _LATENT_REFUSES, one_head=True)
+    paged_only = bool(cfg.layer_types or cfg.parallel_block or cfg.tie_embeddings)
+    return Family("plain", llama, llama.cache_spec(cfg), _counts(cfg), NotImplementedError,
+                  _PAGED_ONLY if paged_only else {}, one_head=bool(cfg.layer_types),
+                  scratch_prefix=paged_only)
+

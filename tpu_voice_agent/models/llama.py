@@ -240,38 +240,28 @@ PRESETS: dict[str, LlamaConfig] = {
 # ---------------------------------------------------------------- params
 
 
-def _hybrid(cfg) -> bool:
-    """``cfg`` is a ``models.sambay.SambaYConfig``: the one other decoder
-    family the paged engine serves. This module's entry points the engine
-    calls (``init_params``, ``quantize_params``, ``forward_paged``) hand on to
-    that module's, so the engine has ONE path for both."""
-    return not isinstance(cfg, LlamaConfig)
+def cache_planes(k: dict, v: dict, *, by_name: bool = False, slot_k=None, slot_v=None) -> dict:
+    """A ``cache_spec`` in the one shape every family answers in (``models.family``
+    has it): the block planes of the k and v pools as name -> (layers, *trailing),
+    the per-slot planes beside them as name -> ((layers, *trailing), dtype)."""
+    slot = {"k": slot_k or {}, "v": slot_v or {}}
+    return {"planes": {"k": k, "v": v}, "slot_planes": slot, "by_name": by_name,
+            "state_column": bool(slot["k"] or slot["v"])}
 
 
-def paged_only(cfg) -> bool:
-    """A LlamaConfig whose layers ``forward_paged`` alone runs (layers of
-    more than one kind, a parallel block, a tied head, a latent cache):
-    ``forward`` and its dense cache refuse it, and the paged engine prefills
-    its prompt prefix through a scratch pool, as it does a hybrid model's."""
-    return bool(cfg.layer_types or cfg.parallel_block or cfg.tie_embeddings or cfg.kv_lora_rank)
-
-
-def latent(cfg) -> bool:
-    """A LlamaConfig whose requests cache a latent and a shared rotated key
-    where the others cache K and V (``models.mla``)."""
-    return bool(getattr(cfg, "kv_lora_rank", 0))
+def cache_spec(cfg: LlamaConfig) -> dict:
+    """K and V planes by head, every layer's."""
+    kv = {"kv": (cfg.n_layers, cfg.n_kv_heads, cfg.head_dim)}
+    return cache_planes(kv, kv)
 
 
 def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     """Random init. Layer weights are stacked on a leading n_layers axis."""
-    if _hybrid(cfg):
-        from . import sambay
+    from .family import family  # the ONE hand-off to the sibling families (they import this module)
 
-        return sambay.init_params(cfg, key, dtype)
-    if cfg.kv_lora_rank:
-        from . import dots3, mla
-
-        return (dots3 if cfg.index_topk else mla).init_params(cfg, key, dtype)
+    owner = family(cfg).module
+    if owner.__name__ != __name__:
+        return owner.init_params(cfg, key, dtype)
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     d, f, hd = cfg.dim, cfg.ffn_dim, cfg.head_dim
     nq, nkv, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
@@ -520,11 +510,11 @@ def _project_qkv(p, x, cfg: LlamaConfig, cs=_identity_cs, n_heads: int | None = 
     (B, T, heads * head_dim): the position-wise part of the front half, which
     asks nothing of B and T (``forward_paged`` runs it on a block's real
     positions, packed). ``_layer_qkv`` has the arguments."""
-    if cfg.kv_lora_rank:
-        from .mla import LatentCacheOnly
+    if cfg.kv_lora_rank:  # q, k and v of n_heads x head_dim: a latent model projects to a
+        # latent and a shared key, for forward_paged alone
+        from .family import family
 
-        raise LatentCacheOnly("q, k and v of n_heads x head_dim: a latent model projects to a "
-                              "latent and a shared key (models.mla), for forward_paged alone")
+        family(cfg).refuse("dense_cache")
     nq = n_heads if n_heads is not None else cfg.n_heads
     nkv = n_kv_heads if n_kv_heads is not None else cfg.n_kv_heads
     with jax.named_scope("layer/attn_qkv"):
@@ -1051,10 +1041,9 @@ def forward(
     T > 1 block without the flag takes the exact XLA cache path instead of
     silently computing block-local attention.
     """
-    if paged_only(cfg):
-        raise NotImplementedError(
-            "layers of more than one kind, a parallel block and a tied head are "
-            "forward_paged's: PagedDecodeEngine serves this model, the dense cache does not")
+    from .family import family
+
+    family(cfg).refuse("dense_cache", NotImplementedError)
     B, T = tokens.shape
     S = kv_cache["k"].shape[2]
     cs = lambda x, name: rules.constrain(x, name) if rules is not None else x
@@ -1227,43 +1216,30 @@ def forward_paged(
     are None when ``kv_quant`` is None — then, with ``moe_stats``, the
     forward's routed-expert counts, then, with ``attn_stats``, its attention
     row-block counts."""
-    if _hybrid(cfg):
-        from . import sambay
+    from .family import family
 
-        if rules is not None or kv_quant is not None:
-            raise sambay.StateNotCarried(
-                "a mesh shards, and KV_QUANT re-stores, K/V blocks alone: neither "
-                "carries the recurrent state of a SambaYConfig's requests")
-        if ffn_pack:
-            raise NotImplementedError("models.sambay's MLPs have no packed branch (ROADMAP S3 (e))")
-        return sambay.forward_paged(
-            params, cfg, tokens, positions, k_pool, v_pool, block_tables,
-            attn_impl=attn_impl, write_mask=write_mask, trash_idx=trash_idx,
-            gather_blocks=gather_blocks, n_real=n_real, logit_pos=logit_pos,
-            hybrid_stats=hybrid_stats, attn_stats=attn_stats)
-    if cfg.kv_lora_rank:
-        from . import dots3, mla
-
-        if rules is not None or kv_quant is not None:
-            raise mla.LatentCacheOnly(
-                "a mesh shards, and KV_QUANT re-stores, K and V planes by head: a latent "
-                "cache has neither planes nor heads")
-        if cfg.index_topk:  # learned sparse + windowed latent attention: its own forward
-            return dots3.forward_paged(
-                params, cfg, tokens, positions, k_pool, v_pool, block_tables,
-                attn_impl=attn_impl, write_mask=write_mask, trash_idx=trash_idx,
-                gather_blocks=gather_blocks, n_real=n_real, logit_pos=logit_pos,
-                moe_stats=moe_stats, attn_stats=attn_stats, latent_stats=latent_stats,
-                ffn_pack=ffn_pack)
-        return mla.forward_paged(
+    fam = family(cfg)
+    # what the family's table refuses is refused HERE, for every family alike,
+    # and a count its record does not name with it: nothing is dropped in silence
+    if rules is not None:
+        fam.refuse("mesh")
+    if kv_quant is not None:
+        fam.refuse("kv_quant")
+    if ffn_pack:
+        fam.refuse("ffn_pack", NotImplementedError)
+    asked = {"moe_stats": moe_stats, "attn_stats": attn_stats, "hybrid_stats": hybrid_stats,
+             "latent_stats": latent_stats}
+    counted = {c.keyword for c in fam.counts}
+    if any(on and kw not in counted for kw, on in asked.items()):
+        raise ValueError(f"a {fam.name} model's forward counts {sorted(counted)}: asked {asked}")
+    if fam.module.__name__ != __name__:
+        return fam.module.forward_paged(
             params, cfg, tokens, positions, k_pool, v_pool, block_tables,
             attn_impl=attn_impl, write_mask=write_mask, trash_idx=trash_idx,
             fresh_block=fresh_block, gather_blocks=gather_blocks, n_real=n_real,
-            logit_pos=logit_pos, moe_stats=moe_stats, attn_stats=attn_stats,
-            latent_stats=latent_stats, ffn_pack=ffn_pack)
+            logit_pos=logit_pos, ffn_pack=ffn_pack, **{kw: asked[kw] for kw in counted})
     B, T = tokens.shape
     L, N, bs = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
-    moe_stats = moe_stats and cfg.n_experts > 0  # a dense model has none
     nb = gather_blocks if gather_blocks is not None else block_tables.shape[1]
     S = nb * bs  # gathered context capacity
     cs = lambda x, name: rules.constrain(x, name) if rules is not None else x

@@ -43,8 +43,8 @@ import jax
 import jax.numpy as jnp
 
 from .llama import (MAX_BLOCK_DECODE_T, LlamaConfig, _attn_stats, _ffn, _qe, _scan_and_whole,
-                    _swiglu, _EXPERT_LEAVES, apply_rope_interleaved, ffn_pack_index, packed_ffn,
-                    rms_norm, rope_tables)
+                    _swiglu, _EXPERT_LEAVES, apply_rope_interleaved, cache_planes, ffn_pack_index,
+                    packed_ffn, rms_norm, rope_tables)
 
 F32 = jnp.float32
 
@@ -63,23 +63,10 @@ class LatentCacheOnly(ValueError):
 
 
 def cache_spec(cfg: LlamaConfig) -> dict:
-    """What a token holds in the pool a layer: the two planes' widths. A model
-    with layers of more than one KIND (``models.dots3``) answers by kind,
-    under ``planes``."""
-    if cfg.index_topk:
-        from . import dots3
-
-        return dots3.cache_spec(cfg)
-    return {"kv_layers": cfg.n_layers, "latent_dim": cfg.kv_lora_rank, "rope_dim": cfg.qk_rope_dim}
-
-
-def latent_stat_names(cfg) -> tuple[str, ...]:
-    """What a latent forward of this model counts, in order."""
-    if getattr(cfg, "index_topk", 0):
-        from . import dots3
-
-        return dots3.latent_stat_names()
-    return LATENT_STATS
+    """What a token holds in the pool a layer: a latent in the k pool's one
+    plane, ONE rotated key in the v pool's, each of its own width (no heads
+    axis: of one, it would pad every position sixteenfold)."""
+    return cache_planes({"kv": (cfg.n_layers, cfg.kv_lora_rank)}, {"kv": (cfg.n_layers, cfg.qk_rope_dim)})
 
 
 # ---------------------------------------------------------------- params

@@ -64,7 +64,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from .llama import MAX_BLOCK_DECODE_T, _qe, quantize_leaf
+from .llama import MAX_BLOCK_DECODE_T, _qe, cache_planes, quantize_leaf
 
 F32 = jnp.float32
 _NO_WINDOW = 1 << 30
@@ -138,13 +138,15 @@ def layer_kinds(cfg: SambaYConfig) -> list[str]:
             for l in range(cfg.n_layers)]
 
 
-def cache_spec(cfg: SambaYConfig, slots: int) -> dict:
-    """Shapes of what the engine keeps between forwards, less the pool's
-    block axes: K/V planes (layers, heads, width) and the per-slot planes."""
-    return {"kv_layers": cfg.n_front, "kv_heads": cfg.n_kv_heads // 2,
-            "kv_head_dim": 2 * cfg.head_dim,
-            "conv": ((cfg.n_front, slots, cfg.d_conv - 1, cfg.d_inner), jnp.bfloat16),
-            "ssm": ((cfg.n_front, slots, cfg.d_state, cfg.d_inner), F32)}
+def cache_spec(cfg: SambaYConfig) -> dict:
+    """What the engine keeps between forwards: K/V planes of packed heads for
+    the layers that write them, and a SLOT's convolution tail and float32
+    state for each recurrent layer (``models.family`` has the shape)."""
+    kv = {"kv": (cfg.n_front, cfg.n_kv_heads // 2, 2 * cfg.head_dim)}
+    return cache_planes(
+        kv, kv, by_name=True,
+        slot_k={"conv": ((cfg.n_front, cfg.d_conv - 1, cfg.d_inner), jnp.bfloat16)},
+        slot_v={"ssm": ((cfg.n_front, cfg.d_state, cfg.d_inner), F32)})
 
 
 # ---------------------------------------------------------------- params
@@ -363,10 +365,13 @@ def _attend(q, kl, vl, positions, window, scale: float):
 
 def forward_paged(params, cfg: SambaYConfig, tokens, positions, k_pool, v_pool, block_tables, *,
                   attn_impl: str = "pallas", write_mask=None, trash_idx=None,
-                  gather_blocks: int | None = None, n_real=None, logit_pos=None,
-                  hybrid_stats: bool = False, attn_stats: bool = False):
-    """``models.llama.forward_paged`` for this model (it dispatches here on
-    the configuration's type and passes its own arguments on): ``k_pool`` /
+                  fresh_block: bool = False, gather_blocks: int | None = None, n_real=None,
+                  logit_pos=None, ffn_pack: int = 0, hybrid_stats: bool = False,
+                  attn_stats: bool = False):
+    """``models.llama.forward_paged`` for this model (it hands on to the
+    family's module, with the keywords every family takes: ``fresh_block`` is
+    a promise this forward does not need, ``ffn_pack`` one its family's table
+    refuses before it gets here): ``k_pool`` /
     ``v_pool`` are the pytrees of the module docstring, ``block_tables`` (B,
     max_blocks + 1) with the state index last. ``logit_pos`` (B,): the head
     runs on that one position of each row, logits (B, 1, V) — the chunk

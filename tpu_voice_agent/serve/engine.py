@@ -24,6 +24,7 @@ import numpy as np
 
 from ..grammar.fsm import DeviceFSM, fsm_advance, fsm_row
 from ..grammar.intent_grammar import build_fsm_for, build_intent_fsm
+from ..models.family import family
 from ..models.llama import LlamaConfig, PRESETS, forward, init_kv_cache, init_params
 from ..ops.backend import resolve_kernels
 from ..parallel.mesh import default_rules, kv_cache_shardings, param_shardings
@@ -121,17 +122,11 @@ class ChunkResult:
     # or the paged engine's ``compact_rows``
     conf: tuple | None = None  # the ISSUE 15 per-row confidence lanes
     # (margin sum/min, entropy sum, forced, decisions); None with them off
-    moe: Any = None  # a ROUTED model's llama.MOE_STATS summed over the
-    # chunk; None for a dense model (its chunk program has no such output)
-    hybrid: Any = None  # a model with a recurrent state: sambay.HYBRID_STATS
-    # summed over the chunk, (4,) int32; None for every other model
-    attn: Any = None  # the paged chunk loop's ops.ATTN_STATS summed over the
-    # chunk, (2,) int32: row-blocks the block kernel's common pass took, and
-    # row-blocks live rows attended in all; None from every other engine
-    ffn: Any = None  # llama.FFN_STATS summed over the chunk, (2,) int32, from
-    # a paged chunk program whose MLPs may run packed; None from every other
-    latent: Any = None  # a model with a latent cache: mla.LATENT_STATS summed
-    # over the chunk, (2,) int32; None for every other model
+    counts: dict = field(default_factory=dict)  # what the chunk program counted, by the names
+    # of the model's record (``models.family.Family.counts``, and ``ffn`` from a
+    # program whose position-wise regions may run packed): each an int32 vector
+    # summed over the chunk's forwards, ONE leaf of the caller's readback; empty
+    # from an engine whose loop counts nothing
     ffn_rows: int = 0  # the rows an UNPACKED forward's MLPs compute (width x
     # positions a row); 0: this engine does not say
     # the spec decoder's per-row host counts; None on the plain loops
@@ -745,26 +740,15 @@ class DecodeEngine:
                 raise ValueError(
                     f"model vocab {vocab} < tokenizer vocab {tokenizer.vocab_size}"
                 )
-        if not isinstance(base, LlamaConfig) and self._alloc_dense_cache:
-            raise ValueError(f"a {type(base).__name__} is served by PagedDecodeEngine alone: its "
-                             "requests' state lives in the paged pool's per-slot planes")
-        if not isinstance(base, LlamaConfig) and (
-                mesh is not None or (spec is not None and getattr(spec, "k", 0))):
-            from ..models.sambay import StateNotCarried
-
-            raise StateNotCarried(
-                "a mesh shards K/V blocks and weights of a LlamaConfig's layout, and "
-                "speculative decoding rolls back K/V alone (overwrite-before-attend): "
-                f"neither carries the recurrent state of a {type(base).__name__}'s requests")
-        if getattr(base, "kv_lora_rank", 0) and (
-                self._alloc_dense_cache or mesh is not None
-                or (spec is not None and getattr(spec, "k", 0))):
-            from ..models.mla import LatentCacheOnly
-
-            raise LatentCacheOnly(
-                "a dense cache holds, a mesh shards and speculative decoding rolls back K and V "
-                "planes by head: a latent cache (kv_lora_rank "
-                f"{base.kv_lora_rank}) has none; PagedDecodeEngine on one device serves it")
+        # what this kind of model refuses of THIS engine (``models.family``), here,
+        # before the grammar is built
+        fam = family(base)
+        if self._alloc_dense_cache:
+            fam.refuse("dense_cache")
+        if mesh is not None:
+            fam.refuse("mesh")
+        if spec is not None and getattr(spec, "k", 0):
+            fam.refuse("spec")
         if base.n_experts > 0 and base.moe_impl == "auto":
             # THE dispatch choice of a routed model, made once, here, from
             # where the engine runs: the grouped-matmul kernel on a single
@@ -798,6 +782,9 @@ class DecodeEngine:
         else:
             self.fsm = build_fsm_for(self.tokenizer, vocab_size=vocab)
         self.cfg = replace(base, vocab_size=vocab, max_seq_len=max_len)
+        # what this kind of model keeps on the device, compiles, counts and
+        # refuses: the record the serving side reads in place of asking which it is
+        self.family = family(self.cfg)
         self.eos_id = int(self.tokenizer.eos_id)
         self.pad_id = int(self.tokenizer.pad_id)
         self.mesh = mesh

@@ -31,7 +31,7 @@ from __future__ import annotations
 import os
 import time
 from dataclasses import dataclass
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any
 
 import jax
@@ -39,9 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..grammar.fsm import fsm_advance
-from ..models.mla import latent_stat_names
-from ..models.llama import (FFN_STATS, _hybrid, forward_paged, latent, moe_stat_names,
-                            paged_only)
+from ..models.family import FFN, FFN_PACK_ROWS, family
+from ..models.llama import forward_paged
 from ..utils.compilewatch import get_compile_watcher, watch_compiles
 from ..utils.steplog import (
     ALLOC_SPAN,
@@ -64,14 +63,6 @@ from .engine import (
 from .radix import RadixCache
 
 SLOT_STATE_SPAN = REQUEST_SPAN + ".slot_state"
-
-# the rows the position-wise regions of a fast-forward block are packed into
-# (ISSUE 37: the MLPs; ISSUE 41: q/k/v and the output projection with them):
-# under the ridge of int8 weights on this chip (~120 rows: an MLP costs the
-# same from 72 to 96 and more from 128 on), over what a chunk's forwards hold
-# but its first (PERF.md section 5 item 1 has both measurements). Rows, not rows
-# a slot: the ridge is the chip's, and a block no wider than this packs nothing
-FFN_PACK_ROWS = 96
 
 
 class PoolExhausted(RuntimeError):
@@ -304,6 +295,32 @@ def kv_planes(pool):
     return pool["kv"] if isinstance(pool, dict) else pool
 
 
+def build_pools(spec: dict, blocks: int, block_size: int, slots: int, zeros=jnp.zeros):
+    """THE pool constructor: the (k_pool, v_pool) pytrees a model's cache spec
+    names (``models.family`` has its shape) — a block plane (layers, blocks,
+    block_size, *trailing) bfloat16, a per-slot plane (layers, slots,
+    *trailing) of its own dtype; a pool is the dict of its planes by name, or
+    its one plane itself. The tree's structure is part of every program's text.
+    ``zeros(shape, dtype)`` makes a plane (an engine places its own; a scratch
+    pool and a compile check hand theirs)."""
+    def pool(side: str):
+        planes = {n: zeros((p[0], blocks, block_size, *p[1:]), jnp.bfloat16)
+                  for n, p in spec["planes"][side].items()}
+        planes.update({n: zeros((p[0], slots, *p[1:]), dtype)
+                       for n, (p, dtype) in spec["slot_planes"][side].items()})
+        return planes if spec["by_name"] else planes["kv"]
+
+    return pool("k"), pool("v")
+
+
+@lru_cache(maxsize=8)
+def _sharded_zeros(shape, dtype, sharding):
+    """ONE program a (shape, dtype, sharding): the k and the v plane of a pool
+    under a mesh are one trace, lowered once."""
+    # analyze: ok[jit-sentinel] -- one-shot cache-init compile at construction time, not a serving dispatch the fence could catch
+    return jax.jit(partial(jnp.zeros, shape, dtype), out_shardings=sharding)
+
+
 @watch_compiles("paged._scatter_blocks")
 @partial(jax.jit, donate_argnames=("k_pool", "v_pool"))
 def _scatter_blocks(k_pool, v_pool, src_k, src_v, dst_idx):
@@ -479,15 +496,12 @@ def paged_chunk_decode_loop(
     blocks). Returns the dense loop's tuple shape including the per-row
     ``poison`` fault codes (0 ok / 1 non-finite logits / 2 dead FSM); a
     poisoned row deactivates without committing the faulty sample, so
-    batch-mates decode token-identically to an undisturbed run. A ROUTED
-    model (``cfg.n_experts > 0``, static) compiles a variant with one more
-    carry and one more output: ``llama.MOE_STATS`` summed over the chunk's
-    forwards and layers, (4,) int32. Every variant's LAST output is
-    ``ops.ATTN_STATS`` summed over the chunk's forwards, (2,) int32 (ISSUE 31:
-    how often the block kernel's common pass engages). A program whose
+    batch-mates decode token-identically to an undisturbed run. Behind them
+    one more carry and output for each thing the model's record says its
+    forwards count (``models.family.Family.counts``, in that order; static),
+    each summed over the chunk's forwards — and LAST, from a program whose
     position-wise regions may run PACKED (ISSUES 37, 41: ``ffn_pack`` under a
-    fast-forward block wider than it, off a mesh) has one more carry and output after them:
-    ``llama.FFN_STATS`` summed over the chunk's forwards, (2,) int32.
+    fast-forward block wider than it, off a mesh), ``family.FFN``'s.
 
     The COMPACTED width (ISSUE 29): with ``rows_idx`` the same loop runs over
     those R slots' rows alone — their state and block-table rows gathered on
@@ -516,17 +530,18 @@ def paged_chunk_decode_loop(
             if nan_inject is not None:
                 nan_inject = nan_inject[rows_idx]
     B = cur.shape[0]
-    # a model whose requests hold a recurrent state beside their blocks
-    # (``models.sambay``; static, on the configuration's type) compiles a
-    # variant: a table row's last column is its slot's state index, not a
-    # block; every forward is told how many of a row's positions are real;
-    # the head runs on the one position a row reads; and one more carry and
-    # output, ``sambay.HYBRID_STATS`` summed over the chunk, (4,) int32
-    hybrid = _hybrid(cfg)
+    # the variant this kind of model compiles is its record's (``models.family``;
+    # static, from the configuration): whether a table row's last column is its
+    # slot's state index, not a block; whether every forward is told how many of
+    # a row's positions are real; whether the head runs on the one position a
+    # row reads; and what the forwards count, each one more carry and output
+    fam = family(cfg)
+    told = fam.n_real == "always"
     # the engine's max_len, NOT the block-rounded table capacity — with a
     # non-multiple max_len the dense loop stops at max_len-1 and the paged
     # loop must match it token for token
-    max_pos = (block_tables.shape[1] - hybrid) * kv_planes(k_pool).shape[2]
+    max_pos = ((block_tables.shape[1] - fam.cache["state_column"])
+               * kv_planes(k_pool).shape[2])
     if max_len is not None:
         max_pos = min(max_pos, max_len)
     use_ff = constrained and tables.ff_tokens is not None
@@ -538,33 +553,17 @@ def paged_chunk_decode_loop(
                    dtype=jnp.int32)
     eos0 = (~active) & (cur == eos_id)
 
-    # what the forwards count and the carry sums: attention row-blocks
-    # always, last; in the routed variant the expert rows before them (for a
-    # dense model that carry and output do not exist: tests/test_olmoe.py)
-    routed = cfg.n_experts > 0
-    # a model with a LATENT cache (``models.mla``; static, on its
-    # configuration) compiles a variant too: one more carry and output after
-    # the attention row-blocks, ``mla.LATENT_STATS`` summed over the chunk
-    lat = latent(cfg)
-    n_lat = len(latent_stat_names(cfg))  # two; behind an indexer its counts too
-    count_kw = {"attn_stats": True, **({"moe_stats": True} if routed else {}),
-                **({"hybrid_stats": True} if hybrid else {}),
-                **({"latent_stats": True} if lat else {})}
-    counts0 = (((jnp.zeros((len(moe_stat_names(cfg)) if routed else 4,), jnp.int32),)
-                if routed or hybrid else ()) + (jnp.zeros((2,), jnp.int32),)
-               + ((jnp.zeros((n_lat,), jnp.int32),) if lat else ()))
-    # the head on the ONE position a row of a 1 + W block reads: the hybrid
-    # model, a LlamaConfig with layers of more than one kind and one with a
-    # latent cache (the others' programs compute it on all 1 + W, as they
-    # always have)
-    one_head = hybrid or bool(cfg.layer_types) or lat
     # a fast-forward block holds 1 + k real positions a live row and copies
     # of the last one behind them: the forward is told, and everything
     # position-wise in its layers computes the real ones packed into
     # ``ffn_pack`` rows while they fit
     packs = bool(use_ff and ffn_pack and rules is None and B * (1 + W) > ffn_pack)
-    if packs:
-        counts0 += (jnp.zeros((len(FFN_STATS),), jnp.int32),)
+    # what the forwards count and the carry sums, in the record's order (the
+    # carry order is program text): a dense model's program has no carry of
+    # expert rows (tests/test_olmoe.py); a packing program one more, last
+    count_kw = {c.keyword: True for c in fam.counts}
+    counts0 = tuple(jnp.zeros((len(c.metrics),), jnp.int32)
+                    for c in fam.counts + (FFN,) * packs)
 
     carry0 = (k_pool, v_pool, k_scale, v_scale, cur, pos, fsm_state, active,
               eos0, nbytes,
@@ -593,7 +592,7 @@ def paged_chunk_decode_loop(
             params, cfg, step_tok[:, None], write_pos[:, None], kp, vp,
             block_tables, rules=rules, attn_impl=kernels, write_mask=active,
             trash_idx=trash_idx, k_scale=ksc, v_scale=vsc, kv_quant=kv_quant,
-            **count_kw, **({"n_real": active.astype(jnp.int32)} if hybrid else {}),
+            **count_kw, **({"n_real": active.astype(jnp.int32)} if told else {}),
         )
         raw = logits[:, 0, :]
         if nan_inject is not None:
@@ -685,11 +684,11 @@ def paged_chunk_decode_loop(
             params, cfg, blk_tok, blk_pos, kp, vp,
             block_tables, rules=rules, attn_impl=kernels, write_mask=active,
             trash_idx=trash_idx, k_scale=ksc, v_scale=vsc, kv_quant=kv_quant,
-            **count_kw, **({"n_real": emitted} if hybrid or packs else {}),
+            **count_kw, **({"n_real": emitted} if told or packs else {}),
             **({"ffn_pack": ffn_pack} if packs else {}),
-            **({"logit_pos": k} if one_head else {}),
+            **({"logit_pos": k} if fam.one_head else {}),
         )
-        logits_k = (logits[:, 0, :] if one_head else
+        logits_k = (logits[:, 0, :] if fam.one_head else
                     jnp.take_along_axis(logits, k[:, None, None], axis=1)[:, 0, :])
         if nan_inject is not None:
             logits_k = jnp.where(nan_inject[:, None] & active[:, None],
@@ -782,7 +781,8 @@ class PagedDecodeEngine(DecodeEngine):
         # ones a row, the rest are copies. A dispatch whose block is no wider runs
         # the program it always ran, and so does a mesh (rows of different dp
         # groups may not share a packed axis)
-        self.ffn_pack_rows = FFN_PACK_ROWS if self.dp == 1 else 0
+        fam = self.family
+        self.ffn_pack_rows = fam.pack_rows if self.dp == 1 else 0
         # quantized KV storage tier (ISSUE 12): KV_QUANT=int8|int4 stores
         # per-(position, head) scaled values (ops.kvquant) — half/quarter
         # the HBM bytes per block, so a fixed pool budget holds ~2x/~4x the
@@ -795,46 +795,26 @@ class PagedDecodeEngine(DecodeEngine):
         if kv_quant not in (None, "int8", "int4"):
             raise ValueError(f"KV_QUANT must be int8 or int4, got {kv_quant!r}")
         self.kv_quant = kv_quant
-        # THE cache spec, by layer kind, from the model's configuration: which
-        # layers own K/V planes (a dense or routed decoder: all of them, at its
-        # kv heads; models.sambay: the h/2 + 1 that write K/V, at its packed
-        # heads) and what a SLOT holds beside its blocks (there: a convolution
-        # tail and a float32 state for each recurrent layer; here: nothing)
-        self.hybrid = _hybrid(self.cfg)
-        self.latent = latent(self.cfg)
-        # learned sparse attention over that cache (models.dots3): planes by
-        # layer KIND and an index-key plane, one attention path whatever T is
-        self.sparse = bool(getattr(self.cfg, "index_topk", 0))
-        if self.hybrid:
-            # ``sambay.forward_paged`` has no packed branch (ROADMAP S3 (e) has
-            # what it waits for)
-            self.ffn_pack_rows = 0
-            from ..models.sambay import cache_spec
-
-            if radix_enable is None:
-                radix_enable = os.environ.get("RADIX_ENABLE") == "1"
-            if kv_quant:
-                self._refuse_blocks_alone("KV_QUANT re-stores K/V blocks")
-            if radix_enable:
-                self._refuse_blocks_alone("radix reuse hands a slot cached K/V blocks")
-            self._cache_spec = cache_spec(self.cfg, self.batch_slots)
-        else:
-            self._cache_spec = {"kv_layers": self.cfg.n_layers, "kv_heads": self.cfg.n_kv_heads,
-                                "kv_head_dim": self.cfg.head_dim}
-        # a LATENT cache (models.mla): no K and V planes, no heads — a latent
-        # and ONE rotated key a token a layer, two planes of their own widths
-        if self.latent:
-            from ..models.mla import cache_spec
-
-            if radix_enable is None:
-                radix_enable = os.environ.get("RADIX_ENABLE") == "1"
-            if kv_quant:
-                self._refuse_blocks_alone("KV_QUANT re-stores K/V blocks")
-            if radix_enable:
-                self._refuse_blocks_alone("radix reuse hands a slot cached K/V blocks")
-            if self.mesh is not None or self._spec_cfg is not None:
-                self._refuse_blocks_alone("a mesh shards K/V heads, a verify step rolls K/V back")
-            self._cache_spec = cache_spec(self.cfg)
+        if radix_enable is None:
+            radix_enable = os.environ.get("RADIX_ENABLE") == "1"
+        # what the model's kind refuses of a paged engine (a mesh and a verify
+        # step: the parent's constructor, from the same table)
+        if kv_quant:
+            fam.refuse("kv_quant")
+        if radix_enable:
+            fam.refuse("radix")
+        # THE cache spec, from the model's record: the planes of the k and v
+        # pools by name (a dense or routed decoder: every layer's K/V at its kv
+        # heads; models.sambay: the h/2 + 1 that write K/V, at its packed heads;
+        # a latent cache: a latent and ONE rotated key, two widths; models.dots3:
+        # planes by layer KIND) and what a SLOT holds beside its blocks (sambay:
+        # a convolution tail and a float32 state for each recurrent layer)
+        self._cache_spec = spec = fam.cache
+        self._state_col = int(spec["state_column"])  # a table row's last column: its slot
+        # facts of the record, for whoever asks from outside (tests, tools/*_check.py)
+        self.hybrid = fam.name == "hybrid"
+        self.sparse = fam.name == "sparse"
+        self.latent = self.sparse or fam.name == "latent"
         if pool_blocks is None:
             # default: same worst case as dense, plus each group's trash block
             pool_blocks = self.batch_slots * self.max_blocks + self.dp
@@ -842,52 +822,15 @@ class PagedDecodeEngine(DecodeEngine):
             raise ValueError(
                 f"pool_blocks ({pool_blocks}) must divide into the mesh dp "
                 f"axis ({self.dp}): each dp group owns its own block range")
-        from ..ops.kvquant import kv_store_dim, kv_store_dtype
-
-        L, nkv, hd = (self._cache_spec.get(k, 0) for k in ("kv_layers", "kv_heads", "kv_head_dim"))
-        hdp = kv_store_dim(hd, kv_quant)
-        dtype = kv_store_dtype(kv_quant)
-        shape = (L, pool_blocks, bs, nkv, hdp)
-        sshape = (L, pool_blocks, bs, nkv)
-        if self.sparse:  # planes by layer kind, each (layers of the kind, N, bs, width)
-            self.k_pool, self.v_pool = (
-                {n: jnp.zeros((Lk, pool_blocks, bs, w), jnp.bfloat16) for n, (Lk, w) in planes.items()}
-                for planes in (self._cache_spec["planes"]["k"], self._cache_spec["planes"]["v"]))
-            self.k_scale = self.v_scale = None
-        elif self.latent:  # ``bs`` second-minor: a heads axis of one would pad every position sixteenfold
-            self.k_pool, self.v_pool = (
-                jnp.zeros((L, pool_blocks, bs, self._cache_spec[w]), jnp.bfloat16)
-                for w in ("latent_dim", "rope_dim"))
-            self.k_scale = self.v_scale = None
-        elif self.mesh is not None:
-            from ..parallel.mesh import paged_pool_shardings, paged_scale_shardings
-
-            sh = paged_pool_shardings(self.mesh, nkv)
-            # analyze: ok[jit-sentinel] -- one-shot cache-init compile at construction time, not a serving dispatch the fence could catch
-            z = jax.jit(partial(jnp.zeros, shape, dtype), out_shardings=sh)
-            self.k_pool, self.v_pool = z(), z()
-            if kv_quant is not None:
-                ssh = paged_scale_shardings(self.mesh, nkv)
-                # analyze: ok[jit-sentinel] -- one-shot cache-init compile at construction time, not a serving dispatch the fence could catch
-                zs = jax.jit(partial(jnp.zeros, sshape, jnp.bfloat16),
-                             out_shardings=ssh)
-                self.k_scale, self.v_scale = zs(), zs()
-            else:
-                self.k_scale = self.v_scale = None
-        else:
-            self.k_pool = jnp.zeros(shape, dtype)
-            self.v_pool = jnp.zeros(shape, dtype)
-            if kv_quant is not None:
-                self.k_scale = jnp.zeros(sshape, jnp.bfloat16)
-                self.v_scale = jnp.zeros(sshape, jnp.bfloat16)
-            else:
-                self.k_scale = self.v_scale = None
-        if self.hybrid:
-            # the per-slot planes ride the pools as pytrees, so that whatever
-            # is handed (k_pool, v_pool, a table row) reaches a slot's state
-            self.k_pool = {"kv": self.k_pool, "conv": jnp.zeros(*self._cache_spec["conv"])}
-            self.v_pool = {"kv": self.v_pool, "ssm": jnp.zeros(*self._cache_spec["ssm"])}
-        self._prefix_state: dict | None = None  # hybrid: the state after the cached prefix
+        self.k_pool, self.v_pool = build_pools(spec, pool_blocks, bs, self.batch_slots,
+                                               self._placed_zeros)
+        self.k_scale = self.v_scale = None
+        if kv_quant is not None:  # scales by (position, head), beside K/V planes by head
+            L, nkv, _ = spec["planes"]["k"]["kv"]
+            self.k_scale, self.v_scale = (
+                self._placed_zeros((L, pool_blocks, bs, nkv), jnp.bfloat16, scale=True)
+                for _ in "kv")
+        self._prefix_state: dict | None = None  # a slot's own planes after the cached prefix
         self.allocator = BlockAllocator(pool_blocks, n_groups=self.dp)
         self.block_tables = self._fresh_tables()
         self._slot_shared: list[list[int]] = [[] for _ in range(self.batch_slots)]
@@ -906,8 +849,6 @@ class PagedDecodeEngine(DecodeEngine):
         # radix KV reuse (serve.radix): one tree per dp group, gated by
         # RADIX_ENABLE — unset keeps the pre-radix paged path byte-identical
         # (admission never consults a tree, release never inserts)
-        if radix_enable is None:
-            radix_enable = os.environ.get("RADIX_ENABLE") == "1"
         if radix_max_nodes is None:
             radix_max_nodes = int(os.environ.get("RADIX_MAX_NODES", "4096"))
         self.radix: list[RadixCache] | None = (
@@ -943,12 +884,29 @@ class PagedDecodeEngine(DecodeEngine):
         if self._spec_cfg is not None:
             self._build_spec()
 
+    def _placed_zeros(self, shape, dtype, scale: bool = False):
+        """A plane of zeros where this engine keeps it: under KV_QUANT a block
+        plane at its stored width and dtype (``scale``: the scales beside it),
+        under a mesh sharded — both the family's whose planes are K/V by head,
+        every other refuses them."""
+        from ..ops.kvquant import kv_store_dim, kv_store_dtype
+
+        if self.kv_quant and not scale:
+            shape = (*shape[:-1], kv_store_dim(shape[-1], self.kv_quant))
+            dtype = kv_store_dtype(self.kv_quant)
+        if self.mesh is None:
+            return jnp.zeros(shape, dtype)
+        from ..parallel.mesh import paged_pool_shardings, paged_scale_shardings
+
+        sh = (paged_scale_shardings if scale else paged_pool_shardings)(self.mesh, shape[3])
+        return _sharded_zeros(shape, dtype, sh)()
+
     def _fresh_tables(self):
-        """(batch_slots, max_blocks) zeros; a hybrid model's rows carry their
-        slot's state index in one more column, which no attention walk
-        reaches (``models.sambay.forward_paged`` splits it off)."""
-        tables = np.zeros((self.batch_slots, self.max_blocks + self.hybrid), np.int32)
-        if self.hybrid:
+        """(batch_slots, max_blocks) zeros; where the record says so, rows
+        carry their slot's state index in one more column, which no attention
+        walk reaches (``models.sambay.forward_paged`` splits it off)."""
+        tables = np.zeros((self.batch_slots, self.max_blocks + self._state_col), np.int32)
+        if self._state_col:
             tables[:, -1] = np.arange(self.batch_slots)
         return jnp.asarray(tables)
 
@@ -970,16 +928,12 @@ class PagedDecodeEngine(DecodeEngine):
         """HBM bytes one pool block occupies under the active KV tier
         (values + scale planes; ops.kvquant.kv_block_bytes is the single
         source the HBM ledger plan and the bench capacity rows share)."""
+        if self.kv_quant is None:  # every block plane of both pools, bf16
+            return self.block_size * self.family.token_bytes
         from ..ops.kvquant import kv_block_bytes
 
-        spec = self._cache_spec
-        if self.sparse:  # every plane of every kind, bf16
-            return self.block_size * spec["token_bytes"]
-        if self.latent:  # the two planes' widths, bf16
-            return (spec["kv_layers"] * self.block_size
-                    * (spec["latent_dim"] + spec["rope_dim"]) * 2)
-        return kv_block_bytes(spec["kv_layers"], self.block_size, spec["kv_heads"],
-                              spec["kv_head_dim"], self.kv_quant)
+        L, nkv, hd = self._cache_spec["planes"]["k"]["kv"]  # K/V by head: what KV_QUANT re-stores
+        return kv_block_bytes(L, self.block_size, nkv, hd, self.kv_quant)
 
     def _scatter_pool(self, src_k, src_v, dst_idx) -> None:
         """Pool scatter dispatch: plain bf16 write, or quantize-on-write
@@ -997,60 +951,49 @@ class PagedDecodeEngine(DecodeEngine):
     # ------------------------------------------------------------ prefix
 
     def _compute_prefix_kv(self, tokens, positions, P: int, bucket: int) -> dict:
-        """The prefix of a model ``forward_paged`` alone runs (a hybrid one,
-        or a ``llama.paged_only`` one): prefilled through it into a scratch
-        pool of the bucket's blocks — and, for a hybrid model, ONE scratch
-        slot, whose state after the P real positions is the snapshot every
-        admission behind the prefix starts from (``_prefix_state``). Its K/V
-        comes back in the dense layout ``set_prompt_prefix`` scatters from."""
-        if not self.hybrid and not paged_only(self.cfg):
+        """The prefix of a model ``forward_paged`` alone runs (its record's
+        ``scratch_prefix``): prefilled through it into a scratch pool of the
+        bucket's blocks — and, where a slot holds planes of its own, ONE
+        scratch slot, whose state after the P real positions is the snapshot
+        every admission behind the prefix starts from (``_prefix_state``). Its
+        K/V comes back in the dense layout ``set_prompt_prefix`` scatters from."""
+        fam, bs = self.family, self.block_size
+        if not fam.scratch_prefix:
             return super()._compute_prefix_kv(tokens, positions, P, bucket)
-        bs, spec = self.block_size, self._cache_spec
-        if self.sparse:
-            # a prefix longer than the largest bucket, in chunks of it through
-            # ONE scratch pool: each chunk attends the earlier ones through the
-            # model's own attention path (selection and window from the first
-            # position they bind at), one program for all of them
-            nb = -(-bucket // bs)
-            scratch = lambda pool: {n: jnp.zeros((a.shape[0], nb + 1, *a.shape[2:]), a.dtype)
-                                    for n, a in pool.items()}
-            k, v = scratch(self.k_pool), scratch(self.v_pool)
-            table = jnp.asarray([list(range(1, nb + 1))], jnp.int32)
-            chunk = min(bucket, self.prefill_buckets[-1])
-            for at in range(0, bucket, chunk):
-                _, k, v, _, _ = forward_paged(
-                    self.params, self.cfg, tokens[:, at:at + chunk], positions[:, at:at + chunk],
-                    k, v, table, attn_impl=self.kernels)
-            dense = lambda pool: {n: a[:, 1:].reshape(a.shape[0], 1, nb * bs, a.shape[-1])[:, :, :P]
-                                  for n, a in pool.items()}
-            return {"k": dense(k), "v": dense(v)}
-        if self.hybrid:
-            from ..models.sambay import cache_spec
-
-            spec = cache_spec(self.cfg, 1)
         nb = -(-bucket // bs)
+        slot = self._cache_spec["slot_planes"]
         # the scratch planes: the pool's own, at the bucket's blocks and one
-        planes = lambda pool: jnp.zeros(
-            (spec["kv_layers"], nb + 1, *kv_planes(pool).shape[2:]), kv_planes(pool).dtype)
-        table = jnp.asarray([list(range(1, nb + 1)) + [0] * self.hybrid], jnp.int32)
-        if self.hybrid:  # block 0: parked writes; the table's last column: the slot
-            pools = ({"kv": planes(self.k_pool), "conv": jnp.zeros(*spec["conv"])},
-                     {"kv": planes(self.v_pool), "ssm": jnp.zeros(*spec["ssm"])})
-            kw = {"n_real": jnp.asarray([P], jnp.int32)}
-        else:  # (a latent cache's second plane has a width of its own)
-            pools, kw = (planes(self.k_pool), planes(self.v_pool)), {}
-        _, k, v, _, _ = forward_paged(self.params, self.cfg, tokens, positions, *pools, table,
-                                      attn_impl=self.kernels, fresh_block=True, **kw)
-        if self.hybrid:
-            self._prefix_state = {"conv": k["conv"][:, 0], "ssm": v["ssm"][:, 0]}
-        dense = lambda pool: kv_planes(pool)[:, 1:].reshape(
-            spec["kv_layers"], 1, nb * bs, *kv_planes(pool).shape[3:])[:, :, :P]
-        return {"k": dense(k), "v": dense(v)}
+        # (block 0: parked writes), a slot's at ONE slot
+        plane = lambda a, n=nb + 1: jnp.zeros((a.shape[0], n, *a.shape[2:]), a.dtype)
+        scratch = lambda pool, side: (
+            {n: plane(a, 1) if n in slot[side] else plane(a) for n, a in pool.items()}
+            if isinstance(pool, dict) else plane(pool))
+        k, v = scratch(self.k_pool, "k"), scratch(self.v_pool, "v")
+        # the table's last column, where it has one: the scratch slot
+        table = jnp.asarray([list(range(1, nb + 1)) + [0] * self._state_col], jnp.int32)
+        kw = {"n_real": jnp.asarray([P], jnp.int32)} if fam.n_real == "always" else {}
+        # a prefix longer than the largest bucket (``prefix_whole_blocks``), in
+        # chunks of it through the ONE scratch pool: each chunk attends the
+        # earlier ones through the model's own attention path, one program for
+        # all of them
+        chunk = min(bucket, self.prefill_buckets[-1]) if fam.prefix_whole_blocks else bucket
+        for at in range(0, bucket, chunk):
+            part = (tokens, positions) if chunk == bucket else (
+                tokens[:, at:at + chunk], positions[:, at:at + chunk])
+            _, k, v, _, _ = forward_paged(self.params, self.cfg, *part, k, v, table,
+                                          attn_impl=self.kernels,
+                                          fresh_block=not fam.prefix_whole_blocks, **kw)
+        dense = lambda a: a[:, 1:].reshape(a.shape[0], 1, nb * bs, *a.shape[3:])[:, :, :P]
+        if slot["k"] or slot["v"]:  # the slot's planes: the snapshot; the blocks: the K/V planes
+            self._prefix_state = {n: pool[n][:, 0] for pool, side in ((k, "k"), (v, "v"))
+                                  for n in slot[side]}
+            k, v = k["kv"], v["kv"]
+        return {"k": jax.tree.map(dense, k), "v": jax.tree.map(dense, v)}
 
     def _restore_slot_state(self, slot: int, snapshot: dict | None) -> None:
-        """Before a hybrid model's admission chain runs: the slot's recurrent
-        state <- the prefix's snapshot (None: zeros, a prompt from position 0).
-        One device copy; ``release_slot`` needs nothing."""
+        """Before the admission chain of a model whose slots hold planes of
+        their own runs: the slot's state <- the prefix's snapshot (None: zeros,
+        a prompt from position 0). One device copy; ``release_slot`` needs nothing."""
         from ..utils import get_metrics
 
         with span(STATE_RESTORE_SPAN):
@@ -1065,20 +1008,21 @@ class PagedDecodeEngine(DecodeEngine):
         """A model with planes by layer kind caches WHOLE blocks of the common
         prefix (no sub-block tail to scatter plane by plane into every
         admission's first block): the rest of it is prefilled with the suffix."""
-        return P // self.block_size * self.block_size if self.sparse else P
+        return P // self.block_size * self.block_size if self.family.prefix_whole_blocks else P
 
     def _prefix_bucket(self, P: int) -> int:
         """Such a model's prefix may pass the largest bucket: whole chunks of it."""
         top = self.prefill_buckets[-1]
-        return -(-P // top) * top if self.sparse and P > top else self._bucket(P)
+        whole = self.family.prefix_whole_blocks and P > top
+        return -(-P // top) * top if whole else self._bucket(P)
 
     def _prefill_kw(self, attn_impl: str, n_real) -> dict:
-        """A prefill forward's arguments that follow the model's kind: a
-        decoder whose state is K/V alone takes the layout kernel's attention
-        path; a hybrid model is told the engine's kernels (its forward picks
-        the attention path by T, its scan by this) and how many of the bucket's
-        positions are real (its states advance over those alone)."""
-        if not self.hybrid and not self.sparse:
+        """A prefill forward's arguments that follow the model's record: most
+        take the layout kernel's attention path; one told its real positions
+        (``n_real``) is told how many of the bucket's are (its states advance
+        over those alone) and the engine's kernels (its forward picks the
+        attention path by T, a scan by this)."""
+        if not self.family.n_real:
             return {"rules": self.rules, "attn_impl": attn_impl}
         if isinstance(n_real, int):  # one row; a group hands its (A,) array
             n_real = jnp.asarray([n_real], jnp.int32)
@@ -1136,7 +1080,7 @@ class PagedDecodeEngine(DecodeEngine):
         # empty table rows must still point INSIDE the slot's dp shard
         # (the sharded kernel localizes ids by subtracting the group base)
         row[len(blocks):] = self._group(slot) * self.allocator.blocks_per_group
-        if self.hybrid:
+        if self._state_col:
             row = np.append(row, np.int32(slot))  # the state index, never a block
         return row
 
@@ -1245,7 +1189,7 @@ class PagedDecodeEngine(DecodeEngine):
             self._scatter_pool(tail["k"], tail["v"], dst)
         gb = self._gather_bucket(P, bucket)
         table_row = self.block_tables[slot][None]
-        if self.hybrid:
+        if self._state_col:
             # the chain is the static prefix (radix is refused): its K/V is the
             # shared blocks and the tail just scattered, its state the snapshot
             self._restore_slot_state(slot, self._prefix_state)
@@ -1439,11 +1383,11 @@ class PagedDecodeEngine(DecodeEngine):
             tokens = np.full((A, bucket), self.pad_id, dtype=np.int32)
             positions = np.broadcast_to(P + np.arange(bucket, dtype=np.int32), (A, bucket))
             # a row the group does not fill: a table of trash blocks, no real
-            # position, a masked write. For a hybrid model its state index is
+            # position, a masked write. Where a row carries a state index, its is
             # a slot OUTSIDE the group, whose state it rewrites as it found it
             # (what an idle row of the chunk program does to its own)
-            rows = np.zeros((A, self.max_blocks + self.hybrid), np.int32)
-            if self.hybrid:
+            rows = np.zeros((A, self.max_blocks + self._state_col), np.int32)
+            if self._state_col:
                 rows[n:, -1] = next(b for b in range(self.batch_slots) if b not in slots)
             m_real = np.zeros((A,), np.int32)
             R = 0 if self._prefix_tail is None else self._prefix_tail["k"].shape[1]
@@ -1460,7 +1404,7 @@ class PagedDecodeEngine(DecodeEngine):
                     staged = jax.device_put((
                         tokens, positions, rows, slots, ns, m_real > 0,
                         np.maximum(m_real - 1, 0),
-                        m_real if self.hybrid or self.sparse else None,
+                        m_real if self.family.n_real else None,
                         dst.reshape(-1), restore_at))
                 tokens, positions, rows, slots, ns, live, last, n_real, dst, restore_at = staged
                 with span(PREFILL_CALL_SPAN):
@@ -1468,13 +1412,13 @@ class PagedDecodeEngine(DecodeEngine):
                         forward_paged_first_tokens(
                             self.params, self.cfg, tokens, positions, self.k_pool, self.v_pool,
                             self.block_tables, rows, slots, ns, live, last, n_real,
-                            self._prefix_tail, dst, self._prefix_state if self.hybrid else None,
+                            self._prefix_tail, dst, self._prefix_state if self._state_col else None,
                             restore_at, state, pick_args, rules=self.rules,
-                            attn_impl=self.kernels if self.hybrid or self.sparse else "xla",
+                            attn_impl=self.kernels if self.family.n_real else "xla",
                             gather_blocks=self._gather_bucket(P, bucket),
                             pick=pick, pick_kw=pick_kw)
                 ms = (time.perf_counter() - t0) * 1e3 / n
-        if self.hybrid:
+        if self._state_col:
             from ..utils import get_metrics
 
             get_metrics().inc("ssm.state_restores", float(n))
@@ -1489,7 +1433,7 @@ class PagedDecodeEngine(DecodeEngine):
         self._covered[slot] = len(owned) * bs
         self._next_pos[slot] = n
         table_row = self.block_tables[slot][None]
-        if self.hybrid:
+        if self._state_col:
             self._restore_slot_state(slot, None)
         # position 0 start: block-local attention, no pool gather at all
         with span(PREFILL_CALL_SPAN):
@@ -1522,9 +1466,10 @@ class PagedDecodeEngine(DecodeEngine):
         Returns None when chunking cannot represent the prompt (padded
         span past max_len, or nothing left to compute) — the caller falls
         back to the one-shot ``prefill_slot`` path, which buckets (and
-        errors) independently. A hybrid model's admission is never chunked
-        (the cursor carries no count of real positions for its state): None."""
-        if self.hybrid:
+        errors) independently. Nor when the model's record refuses
+        ``chunked_prefill`` (the cursor carries no count of real positions for
+        a recurrent state): declined, not raised — the caller's fallback serves it."""
+        if "chunked_prefill" in self.family.refuses:
             return None
         ns = self._slot_ns.get(slot)
         self.release_slot(slot)
@@ -1685,7 +1630,7 @@ class PagedDecodeEngine(DecodeEngine):
         B = self.batch_slots
         at = np.full((B,), B, np.int32)
         at[: len(slots)] = slots
-        rows = np.zeros((B, self.max_blocks + self.hybrid), np.int32)
+        rows = np.zeros((B, self.max_blocks + self._state_col), np.int32)
         for i, b in enumerate(slots):
             rows[i] = self._table_row(b, self._slot_shared[b] + self._slot_owned[b])
         self.block_tables = _set_table_rows(self.block_tables, *jax.device_put((at, rows)))
@@ -1731,7 +1676,7 @@ class PagedDecodeEngine(DecodeEngine):
             # claims block coverage per verify step via spec_grow (growth
             # here would over-claim chunk_steps*(1+K) positions at once);
             # reconcile_coverage still clamps after the chunk. Only the
-            # plain chunk loop counts expert rows: its ``moe`` stays None
+            # plain chunk loop counts anything: its ``counts`` stay empty
             return self.spec.decode_chunk(
                 cur, pos, fsm, active, nbytes, tokens_left, key,
                 temperature, byte_budget, chunk_steps, greedy,
@@ -1796,21 +1741,15 @@ class PagedDecodeEngine(DecodeEngine):
                     **compact,
                 )
             )
-        # what the program counted, from the back: a packed MLP's rows, a
-        # latent cache's reads, the attention row-blocks (always), and before
-        # them a routed model's expert rows or a hybrid one's state counts
-        counts = list(counts)
-        ffn = counts.pop() if packs else None
-        latent_counts = counts.pop() if self.latent else None
-        attn = counts.pop()
-        first = counts.pop() if counts else None
+        # what the program counted, under the record's names in carry order
+        # (a packing program's ``ffn`` last)
+        names = [c.name for c in self.family.counts] + [FFN.name] * packs
         return ChunkResult(
             out, n, eos, cur, pos, fsm, active, nbytes, left,
             fwds=fwds, poison=pois,
             rows=self.batch_slots if rows is None else len(rows),
             conf=conf if self.quality_lanes else None,
-            moe=None if self.hybrid else first, hybrid=first if self.hybrid else None,
-            attn=attn, latent=latent_counts, ffn=ffn, ffn_rows=width)
+            counts=dict(zip(names, counts, strict=True)), ffn_rows=width)
 
     def spec_grow(self, span: int, active=None) -> list[int]:
         """Claim block coverage for one speculative verify step (cur + K
@@ -1921,7 +1860,7 @@ class PagedDecodeEngine(DecodeEngine):
         Returns ``(k, v, k_scale | None, v_scale | None)`` shaped
         ``(L, n, bs, nkv, hd_store)`` / ``(L, n, bs, nkv)``. Serving-loop
         thread only (reads race the decode loop's pool rebinds otherwise)."""
-        self._refuse_blocks_alone("a handoff ships K/V frames")
+        self.family.refuse("handoff")
         idx = jnp.asarray(blocks, jnp.int32)
         k = np.asarray(jax.device_get(self.k_pool[:, idx]))
         v = np.asarray(jax.device_get(self.v_pool[:, idx]))
@@ -1940,7 +1879,7 @@ class PagedDecodeEngine(DecodeEngine):
         planes ride their own scatter. ``PoolExhausted`` propagates (after
         the radix-eviction retry in ``_alloc``): the caller counts the
         clean cold fallback. Serving-loop thread only."""
-        self._refuse_blocks_alone("a handoff adopts K/V frames")
+        self.family.refuse("handoff")
         n = int(k.shape[1])
         if self.kv_quant is not None and (k_scale is None or v_scale is None):
             raise ValueError("quantized pool adoption needs scale planes")
@@ -1970,20 +1909,6 @@ class PagedDecodeEngine(DecodeEngine):
             self.allocator.free(blocks)
             raise
         return blocks
-
-    def _refuse_blocks_alone(self, what: str) -> None:
-        if self.latent:
-            from ..models.mla import LatentCacheOnly
-
-            raise LatentCacheOnly(
-                f"{what}, K and V planes by head: a latent cache has none "
-                f"(kv_lora_rank {self.cfg.kv_lora_rank})")
-        if self.hybrid:
-            from ..models.sambay import StateNotCarried
-
-            raise StateNotCarried(
-                f"{what}, without the recurrent state that goes with them: "
-                f"not with a {type(self.cfg).__name__}")
 
     def warm_restart(self) -> None:
         """Paged warm restart: throw away every slot's mutable state and the

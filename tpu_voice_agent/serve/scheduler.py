@@ -39,9 +39,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.llama import MOE_SHARE_STATS
-from ..models.mla import latent_stat_names
-from ..ops import ATTN_STATS
 from ..utils.compilewatch import watch_compiles
 from ..utils.steplog import ALLOC_SPAN, REQUEST_SPAN, span
 from .engine import (
@@ -1302,11 +1299,9 @@ class ContinuousBatcher:
         # ``fwds`` keeps tokens-per-forward truthful under multi-token steps
         # (counting dispatches as tokens would inflate every throughput
         # gauge); ``poison`` is the quarantine's per-row fault codes below.
-        (out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h, moe_h, attn_h, hybrid_h, ffn_h,
-         latent_h) = (
+        (out_h, n_h, act_h, eos_h, pos_h, fwds_h, pois_h, conf_h, counts_h) = (
             jax.device_get((res.out, res.n, res.active, res.eos, res.pos, res.fwds,
-                            res.poison, res.conf, res.moe, res.attn, res.hybrid, res.ffn,
-                            res.latent)))
+                            res.poison, res.conf, res.counts)))
         out_h, n_h, act_h, eos_h, pos_h, pois_h = (
             np.asarray(x) for x in (out_h, n_h, act_h, eos_h, pos_h, pois_h))
         fwds_h, rows = int(fwds_h), res.rows
@@ -1336,44 +1331,17 @@ class ContinuousBatcher:
             m.inc("scheduler.forward_rows", float(fwds_h) * rows)
             m.set_gauge("scheduler.tokens_per_forward",
                         float(n_h.sum()) / float(fwds_h))
-        if moe_h is not None:
-            # summed over the chunk's forwards and layers: per forward they
-            # are these over scheduler.forwards (docs/OBSERVABILITY.md). Four
-            # of them, and ``moe.local_rows`` after them from a model that
-            # holds a share of its experts (``llama.moe_stat_names``)
-            for name, v in zip(MOE_SHARE_STATS, np.asarray(moe_h)):
-                m.inc(f"moe.{name}", float(v))
-        if attn_h is not None:
-            # likewise: the share of attended row-blocks the block kernel's
-            # common pass took is the first over the second
-            for name, v in zip(ATTN_STATS, np.asarray(attn_h)):
-                m.inc(f"attn.{name}", float(v))
-        if latent_h is not None:
-            # a latent cache: cached positions attention read (a common block
-            # once) and query rows x heads it served (``mla.LATENT_STATS``)
-            # (behind an indexer ``dots3.SPARSE_STATS`` too: what it scored,
-            # what positions could see and attended, what the windows read)
-            for name, v in zip(latent_stat_names(self.engine.cfg), np.asarray(latent_h)):
-                m.inc(f"attn.{name}", float(v))
-        if ffn_h is not None:
-            # forwards whose MLPs ran on the real positions packed, and the
-            # rows the MLPs computed (``llama.FFN_STATS``, in its order)
-            packed, ffn_rows = (float(v) for v in np.asarray(ffn_h))
-            m.inc("ffn.forwards_packed", packed)
-            m.inc("ffn.rows", ffn_rows)
-        elif res.ffn_rows:  # a program that packs nothing: every forward whole
+        # what the chunk program counted, summed over its forwards (and layers):
+        # each vector into the counters its model's record names, in the
+        # forward's order (``models.family.Count.metrics``: the metric catalog's
+        # lint reads the names there). Per forward they are these over
+        # ``scheduler.forwards`` (docs/OBSERVABILITY.md)
+        for name, values in counts_h.items():
+            for metric, v in zip(eng.family.count(name).metrics, np.asarray(values), strict=True):
+                m.inc(metric, float(v))
+        if "ffn" not in counts_h and res.ffn_rows:  # a program that packs nothing: every forward whole
             m.inc("ffn.forwards_packed", 0.0)
             m.inc("ffn.rows", float(fwds_h) * res.ffn_rows)
-        if hybrid_h is not None:
-            # a model with a recurrent state: positions its states advanced
-            # over / positions computed, window blocks walked / held
-            # (``sambay.HYBRID_STATS``, in its order; the names spelt out so
-            # that the metric catalog's lint finds them registered)
-            advanced, positions, walked, held = (float(v) for v in np.asarray(hybrid_h))
-            m.inc("ssm.positions_advanced", advanced)
-            m.inc("ssm.positions", positions)
-            m.inc("attn.window_blocks_walked", walked)
-            m.inc("attn.window_blocks_held", held)
         # saturation gauges: the signals continuous batching is tuned by —
         # backlog (queue_depth), batch occupancy (slots used / total), KV
         # page pressure (paged engines), and rolling throughput

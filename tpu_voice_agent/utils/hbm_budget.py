@@ -67,9 +67,9 @@ def pp_tp_hbm_per_chip(
     decoder's. A routed or hybrid model is refused by name rather than
     reported as a dense decoder of its ``ffn_dim`` (its planes are counted by
     ``costmodel.decode_step_bytes`` and, from the live tree, ``hbmledger``)."""
-    from ..models.llama import LlamaConfig
+    from ..models.family import family
 
-    if cfg.n_experts or not isinstance(cfg, LlamaConfig):
+    if cfg.n_experts or family(cfg).cache["state_column"]:  # a request's per-slot state: not sized here
         raise ValueError(f"hbm_budget sizes dense decoders on the pp x tp layout; a "
                          f"{type(cfg).__name__} with {cfg.n_experts} experts "
                          f"is not one (utils.hbmledger plans from the engine's own tree)")

@@ -81,7 +81,7 @@ def engine_hbm_plan(engine) -> dict:
 
         kv = pool_blocks * kv_block_bytes(
             L, engine.block_size, nkv, hd, getattr(engine, "kv_quant", None))
-        if getattr(cfg, "kv_lora_rank", 0):  # a latent cache: no K/V planes (models.mla)
+        if not engine.family.kv_by_head:  # a latent cache: the planes its record names
             kv = pool_blocks * engine.kv_bytes_per_block
     else:
         kv = 2 * L * engine.batch_slots * engine.max_len * nkv * hd * 2
