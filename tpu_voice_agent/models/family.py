@@ -27,7 +27,7 @@ from types import ModuleType
 from typing import Mapping, NamedTuple
 
 from ..ops import ATTN_STATS
-from . import dots3, llama, mla, sambay
+from . import dots3, llama, mla, nemotron_h, sambay
 
 # the rows the position-wise regions of a fast-forward block are packed into
 # (ISSUE 37: the MLPs; ISSUE 41: q/k/v and the output projection with them):
@@ -118,6 +118,21 @@ _HYBRID_REFUSES = {
                    "planes, forward_paged's: PagedDecodeEngine alone serves it",
     "ffn_pack": "models.sambay's MLPs have no packed branch (ROADMAP S3 (e))",
 }
+_SSD = ("K/V blocks alone, without the Mamba-2 state (4 MB a layer a request) and the convolution "
+        "tail that go with them: not with a NemotronHConfig")
+_SSD_REFUSES = {
+    "kv_quant": f"KV_QUANT re-stores {_SSD}",
+    "radix": f"radix reuse hands a slot cached {_SSD}",
+    "mesh": "a mesh shards a LlamaConfig's weights and would exchange latent rows between the chips "
+            f"that share an expert layer, which nothing here does; it moves {_SSD}",
+    "spec": "a rejected draft cannot be un-advanced: a verify step rolls back (overwrite-before-"
+            f"attend) {_SSD}",
+    "handoff": f"a handoff ships and adopts {_SSD}",
+    "chunked_prefill": "the cursor of a chunked admission carries no count of real positions for "
+                       "the Mamba-2 state: the one-shot prefill_slot serves it",
+    "dense_cache": "a NemotronHConfig's state lives in the paged pool's per-slot planes and its "
+                   "layers are one block of three kinds: forward_paged's, PagedDecodeEngine alone serves it",
+}
 _PLANES = "K and V planes by head: a latent cache has none"
 _LATENT_REFUSES = {
     "kv_quant": f"KV_QUANT re-stores {_PLANES}",
@@ -136,6 +151,11 @@ _PAGED_ONLY = {"dense_cache": "layers of more than one kind, a parallel block an
 @lru_cache(maxsize=256)  # configurations are few, frozen and hashable; the record is read-only
 def family(cfg) -> Family:
     """The record of ``cfg``'s family."""
+    if isinstance(cfg, nemotron_h.NemotronHConfig):  # a Mamba-2 state beside K/V, latent experts
+        hybrid = Count("hybrid", "hybrid_stats", nemotron_h.HYBRID_STATS)
+        routed = Count("moe", "moe_stats", tuple(f"moe.{n}" for n in llama.moe_stat_names(cfg)))
+        return Family("ssd", nemotron_h, nemotron_h.cache_spec(cfg), (hybrid, routed, ATTN),
+                      sambay.StateNotCarried, _SSD_REFUSES, n_real="always", one_head=True)
     if not isinstance(cfg, llama.LlamaConfig):  # a ``sambay.SambaYConfig``: K/V and a recurrent state
         hybrid = Count("hybrid", "hybrid_stats", sambay.HYBRID_STATS)
         return Family("hybrid", sambay, sambay.cache_spec(cfg), (hybrid, ATTN),

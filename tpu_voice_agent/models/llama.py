@@ -150,6 +150,10 @@ class LlamaConfig:
     swa_v_head_dim: int = 0
     swa_rope_theta: float = 0.0
 
+    # a routed expert's form (no field: every LlamaConfig's is the gated SwiGLU of
+    # three planes; ``models.nemotron_h``'s configuration names a two-plane one)
+    expert_form = "swiglu"
+
     def __post_init__(self):
         if self.layer_types and (len(self.layer_types) != self.n_layers or
                                  set(self.layer_types) - {"sliding", "full"}):
@@ -378,10 +382,10 @@ def quantize_params(params: dict) -> dict:
     """Weight-only symmetric int8, per-output-channel scales. Norms and the
     embedding table (a gather, already cheap) stay in their original dtype;
     every matmul weight becomes {"q": int8, "s": f32} resolved by _w()."""
-    if "layers" not in params:  # a models.sambay tree
-        from . import sambay
+    if "layers" not in params:  # another family's tree: models.sambay's, models.nemotron_h's
+        from . import nemotron_h, sambay
 
-        return sambay.quantize_params(params)
+        return (nemotron_h if "mamba" in params else sambay).quantize_params(params)
 
     quant = quantize_leaf
 
@@ -657,7 +661,12 @@ def _running_count(hot: jax.Array) -> jax.Array:
     return (within + before[:, None, :]).reshape(-1, E)[:A]
 
 
-def _moe_ffn_grouped(p, h, cfg: LlamaConfig):
+# the activation between a two-plane expert's up and down projections, by the
+# configuration's ``expert_form`` ("swiglu": the gated three-plane expert)
+EXPERT_ACTS = {"relu2": lambda u: jnp.square(jax.nn.relu(u)), "silu": jax.nn.silu}
+
+
+def _moe_ffn_grouped(p, h, cfg: LlamaConfig, lat=None, n_rows=None):
     """Grouped-matmul MoE FFN: assignments group by expert, each expert's run
     pads to a row-tile multiple, and ``ops.grouped_matmul`` streams one
     weight plane per expert that has rows — int8 as served — so FFN FLOPs
@@ -666,7 +675,15 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig):
     single-device path (a bare pallas_call under GSPMD would replicate its
     operands); a mesh keeps the dense dispatch (``cfg.moe_impl``). ``p`` holds one layer's
     expert leaves, or — from the layer scans — the stacked ones and the
-    layer's index under ``"layer"``. -> (out, ``_moe_stats``)."""
+    layer's index under ``"layer"``. The configuration says what an expert
+    is (``expert_form``: SwiGLU's three planes, or two with an activation of
+    ``EXPERT_ACTS`` between them) and the caller what is DISPATCHED: ``lat``
+    (B, T, w), one row a position, where the experts live at another width than
+    the router reads (a latent; the sum comes back at that width), else ``h``
+    itself; and with ``n_rows`` () that only the first ``n_rows`` of the B * T
+    rows are real: a filler row's picks fall on no expert — no run, no tile, no
+    weight fetch, as a pick held elsewhere — and ``assigned`` counts the real
+    rows' alone. -> (out, ``_moe_stats``)."""
     from ..ops.grouped_matmul import grouped_matmul
     from .moe import route_topk_flat
 
@@ -677,13 +694,16 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig):
     # the one-hot below: it counts in no run, pads nothing, names no tile
     # (no weight fetch), takes no row, and adds nothing in the combine. Its
     # gate stays what the router gave it (normalised over all K chosen)
-    H, share = cfg.n_held, cfg.n_held < E
+    H, share = cfg.n_held, cfg.n_held < E or n_rows is not None
     Tt = B * T
     A = Tt * K
     x2 = h.reshape(Tt, d)
     with jax.named_scope("router"):
         eids, gates = route_topk_flat(p["router"], x2, E, K, cfg.norm_topk,
                                       cfg.router_fn, **_router_kw(p, cfg))  # (Tt, K)
+    if lat is not None:
+        d = lat.shape[-1]
+        x2 = lat.reshape(Tt, d)
 
     with jax.named_scope("dispatch"):
         # compares and running counts over an (A, E) one-hot, no sort and
@@ -692,6 +712,8 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig):
         flat_e = eids.reshape(-1)  # assignment j = t*K + k
         if share:
             flat_e = flat_e - cfg.first_expert
+        if n_rows is not None:  # a filler row's picks: no column of the one-hot
+            flat_e = jnp.where(jnp.arange(A, dtype=jnp.int32) // K < n_rows, flat_e, -1)
         hot = flat_e[:, None] == jnp.arange(H, dtype=jnp.int32)[None, :]  # (A, H)
         tm = moe_row_tile(A, E)
         counts = jnp.sum(hot, axis=0, dtype=jnp.int32)
@@ -726,9 +748,13 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig):
 
     with jax.named_scope("experts"):
         li = p.get("layer")
-        gate_s = grouped_matmul(xs, p["moe_gate"], tile_expert, n_tiles, li, tm=tm)
-        up_s = grouped_matmul(xs, p["moe_up"], tile_expert, n_tiles, li, tm=tm)
-        act = (jax.nn.silu(gate_s.astype(jnp.float32)) * up_s.astype(jnp.float32)).astype(h.dtype)
+        if cfg.expert_form == "swiglu":
+            gate_s = grouped_matmul(xs, p["moe_gate"], tile_expert, n_tiles, li, tm=tm)
+            up_s = grouped_matmul(xs, p["moe_up"], tile_expert, n_tiles, li, tm=tm)
+            act = (jax.nn.silu(gate_s.astype(jnp.float32)) * up_s.astype(jnp.float32)).astype(h.dtype)
+        else:
+            up_s = grouped_matmul(xs, p["moe_up"], tile_expert, n_tiles, li, tm=tm)
+            act = EXPERT_ACTS[cfg.expert_form](up_s.astype(jnp.float32)).astype(h.dtype)
         down = grouped_matmul(act, p["moe_down"], tile_expert, n_tiles, li, tm=tm)  # (M_pad, d)
 
     with jax.named_scope("combine"):
@@ -739,10 +765,10 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig):
             rows = jnp.where(local.reshape(Tt, K, 1), rows, 0.0)
         out = jnp.sum(rows * gates[:, :, None], axis=1)
     return (out.astype(h.dtype).reshape(B, T, d),
-            _moe_stats(counts, ends[-1], A if share else None))
+            _moe_stats(counts, ends[-1], (A if n_rows is None else n_rows * K) if share else None))
 
 
-def _moe_ffn_dense(p, h, cfg: LlamaConfig):
+def _moe_ffn_dense(p, h, cfg: LlamaConfig, lat=None):
     """Dense-dispatch MoE FFN (models.moe.route_topk): expert choice becomes
     one-hot einsums with static shapes. EP sharding happens declaratively:
     the stacked (E, ...) expert weights shard E over the mesh's tp axis
@@ -781,12 +807,18 @@ def _moe_ffn_dense(p, h, cfg: LlamaConfig):
             held = slice(cfg.first_expert, cfg.first_expert + cfg.experts_held)
             assigned = jnp.sum(dispatch).astype(jnp.int32)
             dispatch, combine = dispatch[:, held], combine[:, held]
+    if lat is not None:
+        d = lat.shape[-1]
+        x2 = lat.reshape(B * T, d)
     with jax.named_scope("dispatch"):
         xe = jnp.einsum("tec,td->ecd", dispatch.astype(h.dtype), x2)  # (E, C, d)
     with jax.named_scope("experts"):
-        gate = _qe("ecd,edf->ecf", xe, p["moe_gate"])
-        up = _qe("ecd,edf->ecf", xe, p["moe_up"])
-        a = (jax.nn.silu(gate) * up).astype(h.dtype)
+        if cfg.expert_form == "swiglu":
+            gate = _qe("ecd,edf->ecf", xe, p["moe_gate"])
+            up = _qe("ecd,edf->ecf", xe, p["moe_up"])
+            a = (jax.nn.silu(gate) * up).astype(h.dtype)
+        else:
+            a = EXPERT_ACTS[cfg.expert_form](_qe("ecd,edf->ecf", xe, p["moe_up"])).astype(h.dtype)
         down = _qe("ecf,efd->ecd", a, p["moe_down"]).astype(h.dtype)
     with jax.named_scope("combine"):
         out = jnp.einsum("tec,ecd->td", combine.astype(h.dtype), down).reshape(B, T, d)
@@ -794,7 +826,7 @@ def _moe_ffn_dense(p, h, cfg: LlamaConfig):
     return out, _moe_stats(counts, cfg.n_held * C, assigned if cfg.experts_held else None)
 
 
-def _moe_ffn(p, h, cfg: LlamaConfig):
+def _moe_ffn(p, h, cfg: LlamaConfig, lat=None, n_rows=None):
     """Top-k routed expert FFN over (B, T, d) hidden states -> (out, stats).
     ``cfg.moe_impl`` names the dispatch; the engine that serves the model
     resolved "auto" where it was built (a single device takes the grouped
@@ -802,8 +834,8 @@ def _moe_ffn(p, h, cfg: LlamaConfig):
     tokens up and ties at a suffix prefill's 32-64 rows — a mesh the dense
     einsums, its experts sharded over tp)."""
     if cfg.moe_impl == "grouped":
-        return _moe_ffn_grouped(p, h, cfg)
-    return _moe_ffn_dense(p, h, cfg)
+        return _moe_ffn_grouped(p, h, cfg, lat, n_rows)
+    return _moe_ffn_dense(p, h, cfg, lat)  # (it computes every row: a filler's is unread)
 
 
 def _swiglu(p, h, names, cs=_identity_cs):

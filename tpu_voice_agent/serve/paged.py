@@ -32,6 +32,7 @@ import os
 import time
 from dataclasses import dataclass
 from functools import lru_cache, partial
+from math import prod
 from typing import Any
 
 import jax
@@ -280,6 +281,11 @@ def record_pool_gauges(alloc: "BlockAllocator", engine=None) -> None:
         m.set_gauge("paged.kv_quant_bits", float(engine.kv_quant_bits))
         m.set_gauge("paged.kv_bytes_per_block", float(bpb))
         m.set_gauge("paged.kv_bytes_per_token", float(bpb // engine.block_size))
+        # what a SLOT holds beside its blocks (a recurrent state, a convolution
+        # tail): the record's per-slot planes, whatever the family; 0 for K/V alone
+        m.set_gauge("paged.state_bytes_per_slot", float(sum(
+            prod(shape) * jnp.dtype(dtype).itemsize
+            for side in engine.family.cache["slot_planes"].values() for shape, dtype in side.values())))
         m.set_gauge("paged.kv_bytes_used", float(alloc.blocks_in_use * bpb))
         m.set_gauge("paged.kv_bytes_total", float(alloc.usable_blocks * bpb))
 
