@@ -286,18 +286,22 @@ def _chunk_program_shas(eng) -> list[str]:
 # layer in ``llama.forward_paged``); the compacted width (72 positions <= 96), the
 # hybrid's both widths and the latent model's both (taken on ISSUE 41's
 # parent, commit 4349cdf: it packs its MLPs alone, ``llama.packed_ffn`` on the
-# ``FfnPack`` the other models' two regions share) are unedited.
+# ``FfnPack`` the other models' two regions share) are unedited. ISSUE 48
+# re-derived all ten: every chunk program carries a third attention count
+# (``attn.common_query_rows``), and a plain model's 1 + W block is told
+# ``n_real`` at BOTH widths, for the block kernel's common pass to pack (the
+# grouped admission's and the one-row prefill's texts above did not move).
 CHUNK_SHA256 = {
-    "dense": ["8951780868d2d09f00e8f4257810c6d572d269e5bdee3f275b18d326bba3efd7",
-              "a83a1246db842dd9278de4755325c77eab35845297b5216c5ef486791938abaa"],
-    "routed": ["e5ea58bb8ee327cb1d68896070945d339e380e1f332944e244204bb5403944bc",
-               "09487a9d4027ee5490ba7d0e2544442000c57ee25acce4c9fc2fa995ff695c73"],
-    "hybrid": ["8a84063c101aa0386efdc89fb1599cba13fb411edfce7fe4b42fda5421b4c500",
-               "234168ec7de640450f7aa42f1701430ed236e1539a16d62dc2046323d7e095fb"],
-    "share": ["4aea2b264d78c130ac91ab51b91ab8d1abdc2b5312848381596fd56d592761d4",
-              "3aa59c4590c50e4b16c1693e05a42ecc9cc7c564820b6d869e1761f558e9c495"],
-    "latent": ["64a86c324bda9f11af6988b7b6cbee575cff285b76427c46600ee44a1c3be96f",
-               "e95bd6aebfe18613acd90f0196c29b679462a7fb2ed33089c7942d76cfefa65a"],
+    "dense": ["bd6960a6e5413477e9c715d36602d0ea9ee6872a882bd60eeee5173252803bc9",
+              "428563f0ebbff1c395cfa7e002d824eb6266d55bb320837ed9778deade59d0e6"],
+    "routed": ["1e3bef4a09480e68c0881380fc66b3721d7ec6095ddb37b5bc63850318ca5120",
+               "f93929afe0b3424a7145f32f7d88ebe5064b4df934dcd35faeeb4ecee781e9cd"],
+    "hybrid": ["0af0fa9ab62e74068db6bf412a61b12e2854e661fc22cedc9d0647e4f97d97fb",
+               "c336d19c2385d33baee94d0300f49fffc7627b95ca3d8804cbf35239af7a6c4e"],
+    "share": ["71ed6a17bfc260c579639cb4eba9753b92a38c95a47d644feb319a20aa8e1cd2",
+              "bf9f8de1d121b1a1d9ffd31364d5aa91756116ab3e8a2e37a9ff5600fd20b5dc"],
+    "latent": ["f791d7cacf4007111684766731421a27f85c588c79b36fc151680f09da7e382f",
+               "76cc5483bbd6bc6f9fda2a16a3e755a854e876ed2ddb43181d9065e14ab6639e"],
 }
 
 

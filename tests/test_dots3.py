@@ -709,12 +709,13 @@ def test_a_group_s_admission_is_the_per_slot_admissions(prompts):
 # ISSUE 44 changed the FULL-width chunk program alone and pinned the other three to ITS parent
 # (ee06ed6); ISSUE 45 moved all four — every program of the model writes a full layer's ONE row
 # [c | r] and gathers it once, straight out of the pool — and re-derived them: what a later PR
-# that leaves this model's programs alone must reproduce
+# that leaves this model's programs alone must reproduce. ISSUE 48 re-derived the COMPACTED chunk
+# program alone: its carry holds a third attention count (``ops.ATTN_STATS``), 0 for this model
 PARENT_SHA256 = {
     "group": "2df8668ef87d708bc17833bc3db3e38c8026e97d01e88443924dbbe67d3347fd",
     "block": "d2108056ef37eebcfa596cff0819a46729ac83034a677683d25e2f093b137089",
     "chunk": ["d78d902077560881fd9b398c6898fbcfc29a260620a640cf731741faa8a344c3",
-              "779d2ae47f3bf863c157a919f8a6265cb60ae7a07db6694352703e5634bf408c"],
+              "82bc26d3104e8213400aacc20727adcbd21ac91f7bf42b401620c872e9d13151"],
 }
 
 

@@ -265,18 +265,23 @@ def test_paged_attention_compiles_at_group_one(tpu_devices):
 @pytest.mark.parametrize("B,geom", [(32, LLAMA3_8B), (32, OLMOE), (8, LLAMA3_8B)],
                          ids=["parse_flood", "olmoe_flood", "parse_solo"])
 def test_paged_block_attention_common_pass_compiles_at_the_cells_shapes(tpu_devices, B, geom):
-    """The block kernel with its common pass (ISSUE 31) as the three cells run
-    it: (rows, 9 queries, q / kv heads of 128) = (32, 9, 32 / 8) Mistral's
-    full width, (32, 9, 16 / 16) OLMoE's, (8, 9, 32 / 8) the compacted width,
-    over the benchmark's 200-block pool and 12-column tables, with the write
-    mask handed down. Its dynamic grid, the VMEM it asks for beyond the
-    default (every row's statistics stay resident) and the dynamic sublane
-    slices are what interpret mode cannot refuse and Mosaic can."""
+    """The block kernel with its common pass (ISSUE 31) on the packed real
+    positions (ISSUE 48) as the three cells run it: (rows, 9 queries, q / kv
+    heads of 128) = (32, 9, 32 / 8) Mistral's full width, (32, 9, 16 / 16)
+    OLMoE's, (8, 9, 32 / 8) the compacted width, over the benchmark's 200-block
+    pool and 12-column tables, with the write mask and ``n_real`` handed down.
+    Its dynamic grid, the VMEM it asks for beyond the default (the queries,
+    their packed copy and its statistics stay resident), the sub-chunks'
+    dynamic sublane slices, a rider's queries written to and its state read
+    from ANY sublane offset are what interpret mode cannot refuse and Mosaic
+    can."""
     nq, nkv, hd, L = geom
     N, blocks = 200, 12
-    _compile(tpu_devices, ops.paged_block_attention, ((B, FF_T, nq, hd), BF16),
+    attend = lambda q, kp, vp, tables, pos, layer, live, n_real: ops.paged_block_attention(
+        q, kp, vp, tables, pos, layer, live, None, None, n_real, interpret=False)
+    _compile(tpu_devices, attend, ((B, FF_T, nq, hd), BF16),
              *_pool(N, geom), ((B, blocks), I32), ((B, FF_T), I32), ((), I32),
-             ((B,), jnp.bool_), interpret=False)
+             ((B,), jnp.bool_), ((B,), I32))
 
 
 def _conditionals(hlo: str) -> int:
@@ -472,22 +477,24 @@ def test_grouped_matmul_tiled_int8_stacked_compiles_at_command_a_plus_widths(tpu
 
 @pytest.mark.parametrize("window", [None, 4096], ids=["unbound", "window"])
 def test_paged_block_attention_compiles_at_144_query_rows_a_head(tpu_devices, window):
-    """(32 rows, 9 queries, 128 / 8 heads of 128): the rows' resident state is
-    94 MB, so the kernel walks two groups of 16, each with the split its
-    caller made (``row_group_splits``) — also behind a window, where a lone
-    split made the wrapper refuse."""
+    """(32 rows, 9 queries, 128 / 8 heads of 128): the queries of 32 rows,
+    their packed copy and its state are 94 MB (4608 query rows a head), so the kernel
+    walks two groups of 16, each with the split — and the packing of its rows'
+    real positions — its caller made (``row_group_splits``); also behind a
+    window, where a lone split made the wrapper refuse."""
     nq, nkv, hd, L = CMDAPLUS
     B, N, blocks, bs = 32, 200, 12, 128
 
-    def attend(q, kp, vp, tables, pos, layer, live):
-        splits = ops.row_group_splits((B, FF_T, nq, nkv, hd), tables, pos, live, bs, window=window)
+    def attend(q, kp, vp, tables, pos, layer, live, n_real):
+        splits = ops.row_group_splits((B, FF_T, nq, nkv, hd), tables, pos, live, bs, window=window,
+                                      n_real=n_real)
         assert len(splits) == 2
         return ops.paged_block_attention(q, kp, vp, tables, pos, layer, live, splits,
-                                         None if window is None else jnp.int32(window),
+                                         None if window is None else jnp.int32(window), n_real,
                                          interpret=False)
 
     _compile(tpu_devices, attend, ((B, FF_T, nq, hd), BF16), *_pool(N, CMDAPLUS),
-             ((B, blocks), I32), ((B, FF_T), I32), ((), I32), ((B,), jnp.bool_))
+             ((B, blocks), I32), ((B, FF_T), I32), ((), I32), ((B,), jnp.bool_), ((B,), I32))
 
 
 def _cmdaplus_engine(monkeypatch, **serving):

@@ -387,10 +387,12 @@ def _chunk_spy(monkeypatch) -> tuple[list[int], list[str]]:
 # a PR that changes the loop on purpose re-derives these (lower the first
 # ``decode_chunk`` dispatch as ``_chunk_spy`` does and hash it) and says so.
 # ISSUE 33 did: the confidence lanes' ``top_k`` over the vocabulary became
-# reductions (``engine._masked_conf``), in both variants.
+# reductions (``engine._masked_conf``), in both variants. ISSUE 48 did: the
+# attention counts are three (``attn.common_query_rows``) and the block kernel's
+# call holds the packing of the riders' real positions, in both variants.
 FULL_WIDTH_SHA256 = {
-    "dense": "401512baa13caf19f6fd9a666bd0901571ca3bc251b7120ffde235bae878d11a",
-    "routed": "ca00f47003dc18393fe2d3aacc8c0c0d25337d627fa32ca414a49faf9911f750",
+    "dense": "391c20da5d51f309e6ad45f708b3e356c9bcf2dad3070dfe6233f21759302134",
+    "routed": "8965054ebc383e1c0fb1c85fc74288b09479b25747cb16bd7474d73ee72ac37d",
 }
 
 

@@ -230,7 +230,9 @@ def test_paged_block_attention_common_pass_matches_the_plain_reference(case, gro
     assert int(split.n_common) == want_s
     assert (np.asarray(split.slot) < int(split.n_riders)).astype(int).tolist() == want_rides
     blocks = (np.asarray(q_pos).max(axis=1) // bs + 1)[rows]  # a live row attends these
-    assert np.asarray(split.counts).tolist() == [want_s * sum(want_rides), int(blocks.sum())]
+    # (the common pass is handed every position of a rider where ``n_real`` names none)
+    assert np.asarray(split.counts).tolist() == [want_s * sum(want_rides), int(blocks.sum()),
+                                                 T * sum(want_rides)]
     assert int(split.n_items) == want_s + int(blocks.sum()) - want_s * sum(want_rides)
 
     out = np.asarray(paged_block_attention(q, kp, vp, tables, q_pos, jnp.int32(1), live))
@@ -246,4 +248,99 @@ def test_paged_block_attention_common_pass_matches_the_plain_reference(case, gro
         jax.clear_caches()
         one_by_one = paged_block_attention(q, kp, vp, tables, q_pos, jnp.int32(1), live)
         np.testing.assert_allclose(np.asarray(one_by_one), ref, rtol=1e-5, atol=1e-5)
+        jax.clear_caches()  # the budget is read when the wrapper is traced
+
+
+# the common pass on the PACKED real positions (ISSUE 48): the same tables,
+# each row's ``n_real`` of its T = 9 positions real, the rest copies of its last
+# real one (query, position) as the chunk program's ``ff_body`` builds them
+_REAL_CASES = {
+    # name: (tables, first query position a row, live rows or None, n_real a row, window)
+    "ragged riders": ([_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0], _PREFIX + [7, 8, 0],
+                       _PREFIX + [9, 0, 0]], [60, 50, 70, 55], None, [0, 1, 2, 9], None),
+    "riders and a row with a different first block": (
+        [_PREFIX + [4, 5, 0], [9, 10, 11, 6, 0, 0], _PREFIX + [7, 8, 0]],
+        [60, 50, 70], None, [3, 2, 1], None),
+    "an idle row among live ones": (
+        [_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0], _PREFIX + [7, 8, 0]],
+        [60, 0, 70], [True, False, True], [2, 4, 9], None),
+    "no two rows agree": ([[1, 2, 3, 0, 0, 0], [4, 5, 6, 0, 0, 0], [7, 8, 9, 0, 0, 0]],
+                          [30, 20, 40], None, [1, 0, 5], None),
+    "every position real": ([_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0]], [60, 50], None,
+                            [9, 9], None),
+    "behind a window": ([_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0], _PREFIX + [7, 8, 0]],
+                        [60, 50, 70], None, [2, 0, 9], 24),
+    # Command A+'s shape in small: the rows' state passes the kernel's budget,
+    # so they go through in two groups of rows, each with a split of its own
+    "two groups of rows": ([_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0], _PREFIX + [7, 8, 0],
+                            _PREFIX + [9, 0, 0]], [60, 50, 70, 55], None, [1, 9, 0, 2], None),
+}
+
+
+@pytest.mark.parametrize("group", [4, 1, 16])
+@pytest.mark.parametrize("case", list(_REAL_CASES))
+def test_paged_block_attention_common_pass_takes_the_real_positions(case, group, monkeypatch):
+    """``n_real`` packs the riders' real positions for the common pass and
+    carries their state into the own pass: a real position's output is the
+    ``n_real=None`` call's BIT FOR BIT (a query row's dots do not depend on
+    which rows share its tile) and the plain reference's within tolerance; a
+    position behind them returns its row's last real one's output; a row with
+    none, or not live, returns zeros and disturbs nobody; ``common_query_rows``
+    counts the riders' real positions."""
+    import sys
+
+    from tpu_voice_agent.ops import (
+        common_block_split,
+        paged_block_attention,
+        paged_block_attention_reference,
+        row_group_splits,
+    )
+
+    tables, pos, live, n_real, window = _REAL_CASES[case]
+    L, N, bs, T, nkv, hd = 2, 12, 16, 9, 2, 32
+    B = len(tables)
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    n_real = np.asarray(n_real, np.int32)
+    t_of = np.minimum(np.arange(T)[None, :], np.maximum(n_real[:, None] - 1, 0))  # the copies
+    q = jax.random.normal(ks[0], (B, T, nkv * group, hd), jnp.float32)
+    q = jnp.take_along_axis(q, jnp.asarray(t_of)[:, :, None, None], axis=1)
+    kp = jax.random.normal(ks[1], (L, N, bs, nkv, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (L, N, bs, nkv, hd), jnp.float32)
+    tables = jnp.asarray(tables, jnp.int32)
+    q_pos = jnp.asarray(np.asarray(pos, np.int32)[:, None] + t_of)
+    live = None if live is None else jnp.asarray(live)
+    rows = (np.ones(B, bool) if live is None else np.asarray(live)) & (n_real > 0)
+    real = (np.arange(T)[None, :] < n_real[:, None]) & rows[:, None]
+
+    if case == "two groups of rows":
+        mod = sys.modules["tpu_voice_agent.ops.paged_attention"]
+        two_rows = nkv * T * group * (2 * hd * 4 + 2 * 4 * hd + 2 * 4 * 128) * 2
+        monkeypatch.setattr(mod, "_STATE_BYTES", two_rows)
+        jax.clear_caches()
+        shape = (B, T, nkv * group, nkv, hd)
+        made = lambda n: row_group_splits(shape, tables, q_pos, live, bs, itemsize=4, n_real=n)
+        assert len(made(None)) == 2
+    else:
+        made = lambda n: common_block_split(tables, q_pos, live, bs, window=window, n_real=n)
+    win = None if window is None else jnp.int32(window)
+    call = lambda n, split: np.asarray(paged_block_attention(
+        q, kp, vp, tables, q_pos, jnp.int32(1), live, split, win, n))
+    whole = call(None, made(None))
+    got = call(jnp.asarray(n_real), made(jnp.asarray(n_real)))
+    if window is None:  # the split is the wrapper's own where the caller hands none
+        np.testing.assert_array_equal(call(jnp.asarray(n_real), None), got)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got[real], whole[real])
+    np.testing.assert_array_equal(got, np.take_along_axis(got, t_of[:, :, None, None], axis=1))
+    assert (got[~rows] == 0).all()
+    if window is None:
+        ref = np.asarray(paged_block_attention_reference(q, kp, vp, tables, q_pos, 1))
+        np.testing.assert_allclose(got[rows], ref[rows], rtol=1e-5, atol=1e-5)
+    splits = made(jnp.asarray(n_real))
+    splits = splits if isinstance(splits, tuple) and not hasattr(splits, "counts") else (splits,)
+    rides = np.concatenate([np.asarray(s.slot) < int(s.n_riders) for s in splits])
+    counted = sum(int(s.counts[2]) for s in splits)
+    assert counted == int(n_real[rides].sum())
+    assert sum(int(s.counts[0]) for s in splits) == sum(int(s.n_common) * int(s.n_riders) for s in splits)
+    if case == "two groups of rows":
         jax.clear_caches()  # the budget is read when the wrapper is traced

@@ -454,8 +454,9 @@ def test_common_pass_counters_behind_the_batcher_match_the_tables(monkeypatch):
     """A ``test-tiny`` paged engine WITH its pinned prompt prefix, three rows
     behind the batcher through the Pallas block kernel: the tokens are the
     un-paged ``DecodeEngine``'s, and ``attn.common_row_blocks`` /
-    ``attn.row_blocks`` are what the tables and positions say, worked out
-    here by hand. Chunks of ONE forward, so a forward's positions are the
+    ``attn.row_blocks`` / ``attn.common_query_rows`` are what the tables and
+    positions say, worked out here by hand (the third: the riders' real
+    positions — the tokens the forward leaves them — over every layer's read). Chunks of ONE forward, so a forward's positions are the
     chunk's: a live row's queries run from ``pos`` to the position before
     the one it is left at. Two or more live rows hold the prefix's full
     blocks in common and nothing after them; one live row alone holds every
@@ -479,7 +480,7 @@ def test_common_pass_counters_behind_the_batcher_match_the_tables(monkeypatch):
     shared = install_prompt_prefix(paged) // bs
     assert shared >= 2
 
-    want, decode_chunk = np.zeros(2, np.int64), paged.decode_chunk
+    want, decode_chunk = np.zeros(3, np.int64), paged.decode_chunk
 
     def spy(cur, pos, fsm, active, *a, **kw):
         tables = np.asarray(paged.block_tables)
@@ -487,7 +488,8 @@ def test_common_pass_counters_behind_the_batcher_match_the_tables(monkeypatch):
         live, first, last = np.asarray(active), np.asarray(pos), np.asarray(res.pos) - 1
         assert int(res.fwds) == 1 and (tables[live, :shared] == tables[live][0, :shared]).all()
         common = shared if live.sum() > 1 else first[live][0] // bs
-        by_hand = [common * live.sum(), (last[live] // bs + 1).sum()]
+        handed = paged.cfg.n_layers * (last - first + 1)[live].sum() if common else 0
+        by_hand = [common * live.sum(), (last[live] // bs + 1).sum(), handed]
         assert np.asarray(res.counts["attn"]).tolist() == by_hand
         want[:] += by_hand
         return res
@@ -499,7 +501,9 @@ def test_common_pass_counters_behind_the_batcher_match_the_tables(monkeypatch):
     assert [r.token_ids for r in rp] == [r.token_ids for r in rd]
     assert all(r.error is None for r in rp)
     counters = fresh.snapshot()["counters"]
-    assert [counters["attn.common_row_blocks"], counters["attn.row_blocks"]] == want.tolist()
+    assert [counters["attn.common_row_blocks"], counters["attn.row_blocks"],
+            counters["attn.common_query_rows"]] == want.tolist()
+    assert want[2] < paged.cfg.n_layers * 9 * want[0] / shared / 2  # far under the blocks' whole width
     assert want[0] / want[1] > 0.6  # the prefix is most of what a row attends
 
 

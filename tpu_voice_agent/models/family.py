@@ -74,6 +74,8 @@ class Family:
     # behind the prefix), "always" (the prefix and every decode forward too), "" (a packed block
     # alone). One that is told picks an admission's attention path itself, by T, from the engine's
     # kernels; for the others the caller names the layout's ("xla" behind a prefix)
+    block_real: bool = False  # a 1 + W block forward is told them at EVERY width, packed or not:
+    # its attention kernel's common pass multiplies the real positions alone (``ops.paged_block_attention``)
     one_head: bool = False  # the head runs on the ONE position a row of a 1 + W block reads
     pack_rows: int = FFN_PACK_ROWS  # the packed width of a fast-forward block; 0: no packed branch
     scratch_prefix: bool = True  # the prompt prefix is prefilled through a scratch POOL
@@ -170,6 +172,6 @@ def family(cfg) -> Family:
                       mla.LatentCacheOnly, _LATENT_REFUSES, one_head=True)
     paged_only = bool(cfg.layer_types or cfg.parallel_block or cfg.tie_embeddings)
     return Family("plain", llama, llama.cache_spec(cfg), _counts(cfg), NotImplementedError,
-                  _PAGED_ONLY if paged_only else {}, one_head=bool(cfg.layer_types),
-                  scratch_prefix=paged_only)
+                  _PAGED_ONLY if paged_only else {}, block_real=True,
+                  one_head=bool(cfg.layer_types), scratch_prefix=paged_only)
 

@@ -1205,14 +1205,16 @@ def forward_paged(
     moe_stats: bool = False,  # routed models: also return the forward's
     # ``MOE_STATS`` summed over layers, (4,) int32 (the chunk loops carry them
     # out with their readback; no callback on the hot path)
-    attn_stats: bool = False,  # also return ``ops.ATTN_STATS``, (2,) int32:
-    # the row-blocks the block kernel's common pass took this forward and the
-    # row-blocks live rows attend in all (the chunk loops carry them likewise)
+    attn_stats: bool = False,  # also return ``ops.ATTN_STATS``, (3,) int32:
+    # the row-blocks the block kernel's common pass took this forward, the
+    # row-blocks live rows attend in all and the query positions that pass was
+    # handed (the chunk loops carry them likewise)
     n_real: jax.Array | None = None,  # (B,) int32: row b's real positions are
     # t < n_real[b] (None: all T of a live row). A model with a RECURRENT
     # state (models.sambay) advances it over those and no others; with
     # ``ffn_pack`` a LlamaConfig's position-wise work computes those and no
-    # others. Absent, the traced program is the one it was
+    # others, and the block kernel's common pass multiplies those and no
+    # others (``ops.paged_block_attention``)
     logit_pos: jax.Array | None = None,  # (B,) int32: the head runs on this
     # one position of each row, logits (B, 1, V) (that model, and one with
     # layers of more than one kind: the chunk loop's ``one_head``)
@@ -1314,6 +1316,10 @@ def forward_paged(
     if block_decode and kv_quant is None and mesh is None:
         from ..ops import common_block_split, row_group_splits
 
+        # with ``n_real`` the kernel's common pass multiplies the riders' real
+        # positions alone (where a rider's packed rows start is the split's), and
+        # a padded position — a copy of its row's last real one, writing the SAME
+        # K/V index — returns that one's output, as it did when it was computed
         with jax.named_scope("layer/attn/split"):
             if cfg.layer_types:
                 # this model's 144 query rows a K/V head pass what the kernel
@@ -1322,11 +1328,12 @@ def forward_paged(
                 shape = (B, T, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
                 size = params["embed"].dtype.itemsize  # the activations'
                 split = row_group_splits(shape, block_tables, positions, write_mask, bs,
-                                         itemsize=size)
-                win_split = {w: row_group_splits(shape, block_tables, positions, write_mask,
-                                                 bs, window=w, itemsize=size) for w in windows}
+                                         itemsize=size, n_real=n_real)
+                win_split = {w: row_group_splits(shape, block_tables, positions, write_mask, bs,
+                                                 window=w, itemsize=size, n_real=n_real)
+                             for w in windows}
             else:
-                split = common_block_split(block_tables, positions, write_mask, bs)
+                split = common_block_split(block_tables, positions, write_mask, bs, n_real=n_real)
 
     # rows of different dp groups may not share a packed axis: not under a mesh
     pack = None
@@ -1458,6 +1465,7 @@ def forward_paged(
                         mesh, q, kp, vp, block_tables, positions, li, write_mask,
                         **({"split": split} if window is None else
                            {"split": win_split[window], "window": jnp.int32(window)}),
+                        n_real=n_real,
                     ).reshape(B, T, -1)
                 else:
                     from ..ops import sharded_paged_block_attention_quant
@@ -1570,34 +1578,41 @@ def forward_paged(
         logits = cs(logits, "logits")
     extra = (jnp.sum(stats, axis=0),) if moe_stats else ()
     if attn_stats:
-        stats_of = lambda sp: _attn_stats(sp, block_decode and kv_quant is None, mesh,
-                                          block_tables, positions, write_mask, bs)
-        # layers behind a window that binds read other blocks: every layer's read
+        stats_of = lambda sp, **kw: _attn_stats(sp, block_decode and kv_quant is None, mesh,
+                                                block_tables, positions, write_mask, bs, **kw)
+        # layers behind a window that binds read other blocks: every layer's read.
+        # Layers of one kind read the same ones: the row-blocks of one read (what
+        # ``benchmark/lib/peaks.py`` builds its floors on), the query positions of all
         extra += (sum(stats_of(split if w is None else win_split.get(w)) for _, w in kinds)
-                  if windows else stats_of(split),)
+                  if windows else stats_of(split, reads=cfg.n_layers),)
     if pack is not None:
         extra += (pack.stats,)
     return (logits, k_pool, v_pool, k_scale, v_scale, *extra)
 
 
-def _attn_stats(split, two_pass: bool, mesh, block_tables, positions, live, bs: int):
-    """``ops.ATTN_STATS`` of one forward, (2,) int32. Through the two-pass
+def _attn_stats(split, two_pass: bool, mesh, block_tables, positions, live, bs: int,
+                reads: int = 1):
+    """``ops.ATTN_STATS`` of one forward, (3,) int32. Through the two-pass
     block kernel they are its split's own; under a mesh that is a split per
     dp group, as the kernel's wrapper derives it; on every other path no
-    block is common and live rows attend the blocks up to their frontier."""
+    block is common and live rows attend the blocks up to their frontier.
+    ``reads`` layers read alike: the query positions count every read, the
+    row-blocks stay one read's."""
     from ..ops import common_block_split
 
+    per_read = jnp.array([1, 1, reads], jnp.int32)
     if split is not None:  # one split, or one for each group of rows
-        return split.counts if hasattr(split, "counts") else sum(s.counts for s in split)
+        return per_read * (split.counts if hasattr(split, "counts") else sum(s.counts for s in split))
     B = positions.shape[0]
     live = jnp.ones((B,), bool) if live is None else live
     if two_pass:
         dp = mesh.shape.get("dp", 1)
         groups = lambda x: x.reshape(dp, B // dp, *x.shape[1:])
-        return jnp.sum(jax.vmap(lambda t, p, l: common_block_split(t, p, l, bs).counts)(
+        return per_read * jnp.sum(jax.vmap(lambda t, p, l: common_block_split(t, p, l, bs).counts)(
             groups(block_tables), groups(positions), groups(live)), axis=0)
     blocks = jnp.where(live, jnp.max(positions, axis=1) // bs + 1, 0)
-    return jnp.stack([jnp.zeros((), jnp.int32), jnp.sum(blocks).astype(jnp.int32)])
+    none = jnp.zeros((), jnp.int32)
+    return jnp.stack([none, jnp.sum(blocks).astype(jnp.int32), none])
 
 
 def param_count(cfg: LlamaConfig) -> int:

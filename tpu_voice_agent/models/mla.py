@@ -285,7 +285,8 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, c_pool, r_pool, b
         logits = _qe("btd,dv->btv", x, params["lm_head"])
     extra = (jnp.sum(stats, axis=0),) if moe_stats else ()
     if attn_stats or latent_stats:
-        counts = _attn_stats(split, False, None, block_tables, positions, write_mask, bs)
+        counts = _attn_stats(split, False, None, block_tables, positions, write_mask, bs,
+                             reads=cfg.n_layers)
         if attn_stats:
             extra += (counts,)
         if latent_stats:

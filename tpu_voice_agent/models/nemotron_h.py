@@ -396,7 +396,7 @@ def forward_paged(params, cfg: NemotronHConfig, tokens, positions, k_pool, v_poo
     split = None
     if block_decode and cfg.count("*"):
         with jax.named_scope("layer/attn/split"):
-            split = common_block_split(tables, positions, live, bs)
+            split = common_block_split(tables, positions, live, bs, n_real=n_real)
     # the real positions, packed: what every E layer runs on
     with jax.named_scope("layer/ffn/pack"):
         rows = row_tiles(n_real, T, ffn_pack or ADMIT_TILE)
@@ -446,7 +446,7 @@ def forward_paged(params, cfg: NemotronHConfig, tokens, positions, k_pool, v_poo
         with jax.named_scope("layer/attn/full"):
             if block_decode:
                 a = paged_block_attention(q, kp, vp, tables, positions, ai, live, split, None,
-                                          scale=scale, out_dtype=F32)
+                                          n_real, scale=scale, out_dtype=F32)
             else:
                 with jax.named_scope("kv_gather"):
                     tbl = tables[:, :nb]
@@ -491,8 +491,8 @@ def forward_paged(params, cfg: NemotronHConfig, tokens, positions, k_pool, v_poo
         extra += (st,)
     if attn_stats:
         held = jnp.sum(jnp.where(live, jnp.max(positions, axis=1) // bs + 1, 0))
-        common = split.counts[0] if split is not None else jnp.int32(0)
-        extra += (jnp.stack([na * common, na * held]).astype(jnp.int32),)
+        common, handed = split.counts[::2] if split is not None else (jnp.int32(0),) * 2
+        extra += (jnp.stack([na * common, na * held, na * handed]).astype(jnp.int32),)
     if ffn_pack:
         extra += (rows.stats,)
     return (logits, {"kv": kp, "conv": conv}, {"kv": vp, "ssm": ssm}, None, None, *extra)

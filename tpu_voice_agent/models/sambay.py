@@ -421,15 +421,16 @@ def forward_paged(params, cfg: SambaYConfig, tokens, positions, k_pool, v_pool, 
     split_full = split_win = None
     if block_decode:
         with jax.named_scope("layer/attn/split"):
-            split_full = common_block_split(tables, positions, live, bs)
-            split_win = common_block_split(tables, positions, live, bs, window=cfg.window)
+            split_full = common_block_split(tables, positions, live, bs, n_real=n_real)
+            split_win = common_block_split(tables, positions, live, bs, window=cfg.window,
+                                           n_real=n_real)
 
     def attend(q, kp, vp, plane, windowed: bool):
         if block_decode:
             return paged_block_attention(
                 q, kp, vp, tables, positions, plane, live,
                 split_win if windowed else split_full,
-                jnp.int32(cfg.window) if windowed else None, scale=scale, out_dtype=F32)
+                jnp.int32(cfg.window) if windowed else None, n_real, scale=scale, out_dtype=F32)
         with jax.named_scope("kv_gather"):
             tbl = tables[:, :nb]
             kl = kp[plane][tbl].reshape(B, nb * bs, G, w)
@@ -506,8 +507,8 @@ def forward_paged(params, cfg: SambaYConfig, tokens, positions, k_pool, v_pool, 
         # row-blocks over ALL attention reads of the forward: the windowed
         # layers walk theirs alone, the full layer and the cross layers ride
         # the common pass where the split has one
-        common = split_full.counts[0] if block_decode else jnp.int32(0)
+        common, handed = split_full.counts[::2] if block_decode else (jnp.int32(0),) * 2
         n_full = 1 + cfg.n_back
-        extra += (jnp.stack([n_full * common, n_full * held + full_plane * walked]
-                            ).astype(jnp.int32),)
+        extra += (jnp.stack([n_full * common, n_full * held + full_plane * walked,
+                             n_full * handed]).astype(jnp.int32),)
     return (logits, {"kv": kp, "conv": conv}, {"kv": vp, "ssm": ssm}, None, None, *extra)

@@ -527,20 +527,48 @@ def paged_attention_reference(
 # S the live rows hold in common and see whole, and writes ONE list of work
 # items, walked by a dynamic grid (a dead tile costs nothing):
 #   * the first S items are the COMMON pass: one pool block each, multiplied
-#     against the queries of every row that rides, as one (rows, hd) matrix
-#     per kv head — a K block is fetched, and its heads picked apart, once
-#     for all of them;
+#     against the queries of the rows that ride, as one (rows, hd) matrix per
+#     kv head — a K block is fetched, and its heads picked apart, once for all
+#     of them;
 #   * the rest are the OWN pass: (row, tile) pairs, each live row's tiles
 #     from its first own block (S if it rides, 0 if not) to its last query's.
-# The statistics (m, l, acc: float32) of every row stay in VMEM between the
-# two, so a riding row's own tiles go on from what the common pass left
-# instead of from (-inf, 0, 0): the online softmax across tiles, carried
-# across the passes. That IS the log-sum-exp merge (m = max(m1, m2),
+#
+# The two passes hold their queries in two layouts (ISSUE 48). The common pass
+# has no mask and no table of a row's, so nothing in it needs a row's queries
+# to be an aligned slice: it takes the riders' REAL positions (row b's are
+# ``t < n_real[b]``; of a fast-forward block's 9 about 1.4), packed — riders
+# in row order, ``group`` query rows a position — and walks the sub-chunks of
+# that list that hold one. Its time goes with the query rows it is handed (a
+# VPU-bound ~2.5 ns a (query row, kv head, block), PR 31), so it is handed
+# those whose output somebody reads. The own pass keeps a row's T * group query
+# rows (padded to whole sublane tiles) as ONE aligned block of the layout the
+# kernel always had — (nkv, rows * Rp, hd), riders first: its mask is per
+# position and its tiles the row's table's. That layout is the kernel's ONE
+# query operand, resident for the walk; the packed copy is the kernel's own
+# work (its first step moves each rider's block, cast up, to the row's packed
+# place in VMEM). So what XLA does around the call is what it was: a gather of
+# the packed rows outside cost 25-55 us a call (more than the pass it fed), and
+# a second reader of the queries changed how their producer was fused — the
+# hybrid's seeded plans turn on such a rounding (§6 of PERF.md, PR 48).
+#
+# The statistics (m, l, acc: float32) are CARRIED from one pass to the other,
+# not merged: when a rider's own pass starts, its real positions' rows of state
+# move from their packed place (a dynamic offset, any sublane) to the row's
+# block, and its own tiles go on from what the common pass left instead of
+# from (-inf, 0, 0) — every other query row of the block starts from nothing.
+# That IS the log-sum-exp merge (m = max(m1, m2),
 # l = e^(m1-m) l1 + e^(m2-m) l2, acc alike) with nothing written out between
 # them, in the order and arithmetic of the one-pass walk: block by block,
-# ascending. The division is a row's last act. S = 0 (under half of the live
+# ascending. The division is a row's last act. A query row's dot products do
+# not depend on which rows share its tile, so a real position's output is the
+# same bits whatever ``n_real`` packs beside it. S = 0 (under half of the live
 # rows agree, or a query inside the first block) is the old walk. A row that
-# is not live has no item and its output is zero.
+# is not live has no item and its output is zero; a position that is not real
+# returns its row's last real position's output (``llama.FfnPack.inv``'s rule,
+# a row's last act before it is written back): where a padded position is a
+# copy of that one and writes the same K/V index (``llama.forward_paged``) it
+# attended to exactly that before, and the next layer's two scatters there
+# must hold one value. A live row without a real position returns zeros.
 #
 # Operands: q, k, v and p go to both dots cast up to float32, as the one-pass
 # walk cast them: on the chip a float32 dot at the default precision is one
@@ -560,18 +588,25 @@ class BlockSplit(NamedTuple):
     item_block: jax.Array  # (max_blocks + B*max_blocks,) int32 — pool block
     item_row: jax.Array  # ... row (own items; rows in order)
     item_tile: jax.Array  # ... and table column of each item
-    slot: jax.Array  # (B,) int32 — the row's place in the kernel's query
-    # layout: riders first (the common pass multiplies those places alone),
-    # so a row rides — starts from the common pass — iff slot < n_riders
+    slot: jax.Array  # (B,) int32 — the row's place with the riders first, so
+    # a row rides — starts from the common pass — iff slot < n_riders (the
+    # latent kernel lays its queries out by it)
     order: jax.Array  # (B,) int32 — its inverse: the row at each place
     attended: jax.Array  # (B,) bool — the row has an own tile (it is live)
-    counts: jax.Array  # (2,) int32 — ATTN_STATS: row-blocks the common pass
-    # took, row-blocks live rows attend in all
+    counts: jax.Array  # (3,) int32 — ATTN_STATS
+    pack_start: jax.Array  # (B,) int32 — the riders' real positions packed, rows
+    # in order: a row's first slot ...
+    pack_n: jax.Array  # (B,) int32 — ... and how many it holds: a rider's real
+    # positions, 0 for a row that does not ride (``counts[2]`` in all)
+    n_real: jax.Array  # (B,) int32 — every row's real positions (T where the
+    # caller names none): a position behind them returns the last one's output
 
 
 # what a block forward counts (``forward_paged(attn_stats=True)``; the chunk
-# loops sum them, ``scheduler`` publishes them as ``attn.<name>``)
-ATTN_STATS = ("common_row_blocks", "row_blocks")
+# loops sum them, ``scheduler`` publishes them as ``attn.<name>``): row-blocks
+# the common pass took, row-blocks live rows attend in all, and the query
+# positions the common pass was handed (each summed over a forward's reads)
+ATTN_STATS = ("common_row_blocks", "row_blocks", "common_query_rows")
 
 
 def common_block_split(
@@ -581,6 +616,7 @@ def common_block_split(
     bs: int,
     window: int | None = None,  # a windowed layer: a query sees its last
     # ``window`` positions, its own among them
+    n_real: jax.Array | None = None,  # (B,) int32 — None: all T of every row
 ) -> BlockSplit:
     """The split, from what the kernel is handed and nothing else.
 
@@ -598,10 +634,15 @@ def common_block_split(
     Under a ``window`` no row rides (a block most rows hold in common lies
     before most rows' windows, or is cut by one: the common pass is the full
     layers') and a row's walk starts at the block that holds the first
-    position any of its queries sees."""
+    position any of its queries sees.
+
+    ``n_real`` moves no item: it says which of the riders' positions the
+    common pass is handed (``pack_start`` / ``pack_n``; every one has a slot,
+    so nothing has to fit)."""
     bt = block_tables.astype(jnp.int32)
     B, M = bt.shape
     qp = q_positions.astype(jnp.int32)
+    T = qp.shape[1]
     live = jnp.ones((B,), bool) if live is None else live.astype(bool)
     agree = (bt[:, :1] == bt[:, 0][None, :]) & live[:, None] & live[None, :]
     leader = jnp.argmax(jnp.sum(agree, axis=1))
@@ -629,10 +670,16 @@ def common_block_split(
     blocks = jnp.where(own < 0, lead[jnp.minimum(w, M - 1)], bt[rows, tiles])
     order = jnp.argsort(~rides, stable=True).astype(jnp.int32)
     slot = jnp.zeros((B,), jnp.int32).at[order].set(jnp.arange(B, dtype=jnp.int32))
-    counts = jnp.stack([S * jnp.sum(rides), jnp.sum(jnp.where(live, last + 1, 0))])
+    # the riders' real positions, packed: a row's slots start where the rows
+    # before it end
+    n_real = jnp.full((B,), T, jnp.int32) if n_real is None else jnp.clip(n_real.astype(jnp.int32), 0, T)
+    packed = jnp.where(rides, n_real, 0)
+    p_ends = jnp.cumsum(packed)
+    counts = jnp.stack([S * jnp.sum(rides), jnp.sum(jnp.where(live, last + 1, 0)), p_ends[-1]])
     i32 = lambda x: x.astype(jnp.int32)
     return BlockSplit(i32(S), i32(S + ends[-1]), i32(jnp.sum(rides)), i32(blocks),
-                      i32(rows), i32(tiles), slot, order, n > 0, i32(counts))
+                      i32(rows), i32(tiles), slot, order, n > 0, i32(counts),
+                      i32(p_ends - packed), i32(packed), n_real)
 
 
 def _softmax_tile(q, k, v, valid, m_prev, l_prev, acc_prev, scale: float):
@@ -654,63 +701,92 @@ def _softmax_tile(q, k, v, valid, m_prev, l_prev, acc_prev, scale: float):
 
 def _paged_block_kernel(
     qpos_ref,  # SMEM (B*T,)
-    meta_ref,  # SMEM (5,): [layer, S, items, riders, sub-chunks that hold one]
-    # (``windowed``: (6,), then the window — a query sees that many positions)
+    meta_ref,  # SMEM (4,): [layer, S, items, packed sub-chunks that hold a query row]
+    # (``windowed``: (5,), then the window — a query sees that many positions)
     block_ref,  # SMEM (max_blocks + B*max_blocks,): each item's pool block ...
     row_ref,  # ... row ...
     tile_ref,  # ... and table column
-    slot_ref,  # SMEM (B,): riders first
-    q_ref,  # (nkv, B*Rp, hd) — every row's queries, riders first
+    slot_ref,  # SMEM (B,): the row's place in the own pass's queries, riders first
+    pack_ref,  # SMEM (B,): the row's first packed query row ...
+    real_ref,  # ... and how many it holds there: a rider's real ones, 0 for a row that does not ride
+    nreal_ref,  # SMEM (B,): the row's real POSITIONS (t < it), a rider's or not
+    q_ref,  # (nkv, B*Rp, hd) — every row's queries, riders first (row b's at slot[b] * Rp)
     k_ref,  # (1, 1, bs, nkv, hd) — pool block block[w]
     v_ref,
-    o_ref,  # (nkv, B*Rp, hd) — rows in their own order
-    acc_ref,  # VMEM (nkv, B*Rp, hd) f32
-    m_ref,  # VMEM (nkv, B*Rp, 128) f32, a value across its lanes
+    o_ref,  # (nkv, Rp, hd) — row row[w]'s, rows in their own order
+    qc_ref,  # VMEM (nkv, Pq + Rp, hd) f32 — the riders' real positions' queries, packed
+    cacc_ref,  # VMEM (nkv, Pq + Rp, hd) f32 — the common pass's state, packed alike
+    cm_ref,  # VMEM (nkv, Pq + Rp, 128) f32, a value across its lanes
+    cl_ref,
+    acc_ref,  # VMEM (nkv, Rp, hd) f32 — the own pass's: one row's at a time
+    m_ref,  # VMEM (nkv, Rp, 128) f32
     l_ref,
-    kh_ref,  # VMEM (nkv, bs, hd) f32 — a common block's heads, picked apart once
-    vh_ref,
+    kf_ref,  # VMEM (bs, nkv, hd) f32 — a common block, cast up once for all its heads
+    vf_ref,
     *,
     scale: float,
     nkv: int,
     group: int,
     T: int,
     bs: int,
-    Rp: int,  # query rows a batch row holds in the layout (T*group, padded)
-    sub: int,  # query rows a sub-chunk of the common pass: whole batch rows
+    Rp: int,  # query rows a batch row holds in the own pass (T*group, padded)
+    sub: int,  # query rows a sub-chunk of the common pass
     windowed: bool = False,
 ):
     w = pl.program_id(0)
-    S, n, n_riders, n_sub = meta_ref[1], meta_ref[2], meta_ref[3], meta_ref[4]
+    S, n, n_sub = meta_ref[1], meta_ref[2], meta_ref[3]
     hd = acc_ref.shape[2]
 
-    def start(at, size):  # state from nothing
-        for h in range(nkv):
-            acc_ref[h, at, :] = jnp.zeros((size, hd), jnp.float32)
-            m_ref[h, at, :] = jnp.full((size, 128), _NEG_INF, jnp.float32)
-            l_ref[h, at, :] = jnp.zeros((size, 128), jnp.float32)
+    def advance(q, k, v, valid, refs, h, at):  # one head's rows ``at`` over one block
+        acc, m, l = refs
+        size = q.shape[0]
+        m_new, l_new, acc_new = _softmax_tile(q, k, v, valid, m[h, at, :1], l[h, at, :1],
+                                              acc[h, at, :], scale)
+        acc[h, at, :] = acc_new
+        m[h, at, :] = jnp.broadcast_to(m_new, (size, 128))
+        l[h, at, :] = jnp.broadcast_to(l_new, (size, 128))
 
-    def advance(h, at, size, k, v, valid):  # one head's rows ``at`` over one block
-        m, l, acc = _softmax_tile(q_ref[h, at, :], k, v, valid, m_ref[h, at, :1],
-                                  l_ref[h, at, :1], acc_ref[h, at, :], scale)
-        acc_ref[h, at, :] = acc
-        m_ref[h, at, :] = jnp.broadcast_to(m, (size, 128))
-        l_ref[h, at, :] = jnp.broadcast_to(l, (size, 128))
-
+    packed, own = (cacc_ref, cm_ref, cl_ref), (acc_ref, m_ref, l_ref)
     chunk = lambda i: pl.ds(pl.multiple_of(i * sub, sub), sub)
+    queries = lambda b: pl.ds(pl.multiple_of(slot_ref[b] * Rp, Rp), Rp)  # row b's, aligned
 
     @pl.when(w == 0)
     def _riders_start():
-        jax.lax.fori_loop(0, n_sub, lambda i, c: (start(chunk(i), sub), c)[1], 0)
+        def start(i, c):  # state from nothing, and no query a chunk's last rows may lack
+            for h in range(nkv):
+                qc_ref[h, chunk(i), :] = jnp.zeros((sub, hd), jnp.float32)
+                cacc_ref[h, chunk(i), :] = jnp.zeros((sub, hd), jnp.float32)
+                cm_ref[h, chunk(i), :] = jnp.full((sub, 128), _NEG_INF, jnp.float32)
+                cl_ref[h, chunk(i), :] = jnp.zeros((sub, 128), jnp.float32)
+            return c
+
+        jax.lax.fori_loop(0, n_sub, start, 0)
+
+        def pack(b, c):
+            # a rider's block goes to its packed place WHOLE (a dynamic offset, any
+            # sublane): the rows behind its real ones are the next rider's place,
+            # written after it — rows in order — or behind the last, never read
+            @pl.when(real_ref[b] > 0)
+            def _rider():
+                for h in range(nkv):
+                    qc_ref[h, pl.ds(pack_ref[b], Rp), :] = q_ref[h, queries(b), :].astype(jnp.float32)
+
+            return c
+
+        jax.lax.fori_loop(0, slot_ref.shape[0], pack, 0)
 
     @pl.when(w < S)
     def _common():  # every rider sees the whole block: no mask
-        for h in range(nkv):
-            kh_ref[h] = k_ref[0, 0, :, h].astype(jnp.float32)
-            vh_ref[h] = v_ref[0, 0, :, h].astype(jnp.float32)
+        # the block to float32 WHOLE, once: a head is then a strided read of
+        # 32-bit sublanes, where picking it out of the packed bf16 pairs costs
+        # ~0.2 us a (block, head) — most of this pass now that its rows are few
+        kf_ref[...] = k_ref[0, 0].astype(jnp.float32)
+        vf_ref[...] = v_ref[0, 0].astype(jnp.float32)
 
         def riders(i, c):  # the heads in line, so that one's dots hide under another's softmax
             for h in range(nkv):
-                advance(h, chunk(i), sub, kh_ref[h], vh_ref[h], None)
+                advance(qc_ref[h, chunk(i), :], kf_ref[:, h, :], vf_ref[:, h, :], None, packed, h,
+                        chunk(i))
             return c
 
         jax.lax.fori_loop(0, n_sub, riders, 0)
@@ -718,13 +794,20 @@ def _paged_block_kernel(
     @pl.when(jnp.logical_and(w >= S, w < n))
     def _own():
         b, j = row_ref[w], tile_ref[w]
-        at = pl.ds(pl.multiple_of(slot_ref[b] * Rp, Rp), Rp)
         first = jnp.logical_or(w == S, row_ref[jnp.maximum(w - 1, 0)] != b)
         last = jnp.logical_or(w == n - 1, row_ref[jnp.minimum(w + 1, row_ref.shape[0] - 1)] != b)
 
-        @pl.when(jnp.logical_and(first, slot_ref[b] >= n_riders))
-        def _row_start():  # a rider goes on from the common pass: the merge
-            start(at, Rp)
+        @pl.when(first)
+        def _row_start():
+            # a rider's REAL positions go on from what the common pass left them,
+            # every other query row from nothing: its own tiles alone (a value
+            # nobody reads, but one that is a number)
+            carried = jax.lax.broadcasted_iota(jnp.int32, (Rp, 1), 0) < real_ref[b]
+            was = pl.ds(pack_ref[b], Rp)
+            for h in range(nkv):
+                acc_ref[h] = jnp.where(carried, cacc_ref[h, was, :], 0.0)
+                m_ref[h] = jnp.where(carried, cm_ref[h, was, :], _NEG_INF)
+                l_ref[h] = jnp.where(carried, cl_ref[h, was, :], 0.0)
 
         k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (Rp, bs), 1)
         qpos_rows = jnp.zeros((Rp, 1), jnp.int32)  # padding rows stay at 0
@@ -734,66 +817,90 @@ def _paged_block_kernel(
                 qpos_ref[b * T + i], qpos_rows)
         valid = k_pos <= qpos_rows  # causal + frontier in one mask
         if windowed:  # the boundary block, masked per query position
-            valid = jnp.logical_and(valid, k_pos > qpos_rows - meta_ref[5])
+            valid = jnp.logical_and(valid, k_pos > qpos_rows - meta_ref[4])
         for h in range(nkv):
-            advance(h, at, Rp, k_ref[0, 0, :, h].astype(jnp.float32),
-                    v_ref[0, 0, :, h].astype(jnp.float32), valid)
+            advance(q_ref[h, queries(b), :], k_ref[0, 0, :, h].astype(jnp.float32),
+                    v_ref[0, 0, :, h].astype(jnp.float32), valid, own, h, slice(None))
 
         @pl.when(last)
         def _row_finish():
-            to = pl.ds(pl.multiple_of(b * Rp, Rp), Rp)
+            n_pos = nreal_ref[b]
             for h in range(nkv):
-                l = l_ref[h, at, :1]
-                o_ref[h, to, :] = (acc_ref[h, at, :] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+                l = l_ref[h, :, :1]
+                acc_ref[h] = acc_ref[h] / jnp.where(l == 0.0, 1.0, l)
+
+            @pl.when(n_pos < T)
+            def _behind_the_real_ones():
+                # a position behind its row's real ones returns the last real
+                # one's output (a copy of it, it attended to just that before
+                # the common pass left it out); ascending, so a source is never
+                # a row this loop wrote
+                for t in range(1, T):
+                    src = pl.ds(jnp.maximum(jnp.minimum(t, n_pos - 1), 0) * group, group)
+                    for h in range(nkv):
+                        acc_ref[h, t * group:(t + 1) * group, :] = acc_ref[h, src, :]
+
+            for h in range(nkv):  # a live row without a real position: zeros
+                o_ref[h] = jnp.where(n_pos > 0, acc_ref[h], 0.0).astype(o_ref.dtype)
 
 
-# the kernel keeps every row's queries, outputs and float32 statistics in
-# VMEM for the whole walk, which at (32 rows, 36 query rows, 8 kv heads) is
-# 32 MB: more than the 16 MB a kernel gets unasked, a quarter of a v5e's.
-# Wider batches than fit ``_STATE_BYTES`` go through the kernel in groups of
-# rows, each with a split of its own.
+# the kernel keeps every row's queries (the pipeline's two buffers), the packed
+# float32 copy of the riders' (every position has a slot) and the packed float32
+# statistics in VMEM for the whole walk: at (32 rows, 9 positions, 4 query rows a
+# position, 8 kv heads of 128) 1152 query rows a head, 23 MB — more than the
+# 16 MB a kernel gets unasked, a sixth of a v5e's. A row's own block (output,
+# statistics) is a row's: 0.5 MB. Wider batches than fit ``_STATE_BYTES`` go
+# through the kernel in groups of rows, each with a split of its own (16 query
+# rows a position: 4608 a head, 94 MB; two groups of 16 rows, 47 MB each).
 _VMEM_LIMIT = 64 << 20
 _STATE_BYTES = 48 << 20
 
 
-def _rows_that_fit(B: int, Rp: int, nkv: int, hd: int, itemsize: int,
-                   out_itemsize: int | None = None) -> int:
-    """The largest divisor of B whose rows' resident state (queries and
-    outputs, double-buffered; acc, m, l) stays inside ``_STATE_BYTES``."""
-    out_itemsize = itemsize if out_itemsize is None else out_itemsize
-    per_row = nkv * Rp * (2 * hd * (itemsize + out_itemsize) + 4 * hd + 2 * 4 * 128)
+def _rows_that_fit(B: int, R: int, nkv: int, hd: int, itemsize: int) -> int:
+    """The largest divisor of B whose rows' resident state — R = T * group
+    query rows a row: the queries, double-buffered; their packed float32 copy;
+    acc, m, l — stays inside ``_STATE_BYTES``."""
+    per_row = nkv * R * (2 * hd * itemsize + 2 * 4 * hd + 2 * 4 * 128)
     return max([c for c in range(1, B + 1) if B % c == 0 and c * per_row <= _STATE_BYTES],
                default=1)
 
 
 def _sub_rows(B: int) -> int:
-    """Batch rows a sub-chunk of the common pass holds: a quarter of the rows
-    (the largest divisor of B at or under it). Its dots stream that many
-    rows' queries past one head's K block, and only sub-chunks that hold a
-    rider run: larger is faster when every row rides (at 32 rows a quarter
-    is within 3 % of the whole), smaller when one of eight does (my chip
-    runs, PR 31)."""
+    """Batch rows' worth of queries a sub-chunk of the common pass holds: a
+    quarter of the rows' (the largest divisor of B at or under it). Its dots
+    stream that many query rows past one head's K block, and only sub-chunks
+    that hold one run: larger is faster when every position is real (at 32
+    rows a quarter is within 3 % of the whole), smaller when one of eight is
+    (my chip runs, PR 31). The block kernel counts them in PACKED rows, T *
+    group each (``_sub_query_rows``), the latent kernel in whole padded rows."""
     return max(c for c in range(1, max(B // 4, 1) + 1) if B % c == 0)
 
 
+def _sub_query_rows(B: int, R: int) -> int:
+    """Query rows a sub-chunk of the block kernel's common pass: ``_sub_rows``
+    rows' R = T * group, to whole tiles of the queries' packed sublanes."""
+    return -(-_sub_rows(B) * R // 16) * 16
+
+
 def _padded_query_rows(T: int, group: int) -> int:
-    """Query rows a batch row holds in the kernel's layout: T * group, padded
-    to whole sublane tiles so that a row is an aligned slice."""
+    """Query rows a batch row holds in the own pass's layout: T * group, padded
+    to whole sublane tiles so that a row is one aligned block."""
     return -(-T * group // 16) * 16
 
 
 def row_group_splits(shape: tuple[int, int, int, int, int], block_tables, q_positions, live,
                      bs: int, window: int | None = None, itemsize: int = 2,
-                     out_itemsize: int | None = None) -> tuple[BlockSplit, ...]:
+                     n_real: jax.Array | None = None) -> tuple[BlockSplit, ...]:
     """``common_block_split`` of each group of rows ``paged_block_attention``
     walks for queries of ``shape`` (B, T, nq, nkv, hd): one split where the
     rows' resident state fits the kernel whole, else one for each group — made
     by the caller once a forward, for all its layers (and, with ``window``,
     for the layers behind that window)."""
     B, T, nq, nkv, hd = shape
-    Bg = _rows_that_fit(B, _padded_query_rows(T, nq // nkv), nkv, hd, itemsize, out_itemsize)
-    return tuple(common_block_split(block_tables[g:g + Bg], q_positions[g:g + Bg],
-                                    None if live is None else live[g:g + Bg], bs, window)
+    Bg = _rows_that_fit(B, T * (nq // nkv), nkv, hd, itemsize)
+    cut = lambda x, g: None if x is None else x[g:g + Bg]
+    return tuple(common_block_split(block_tables[g:g + Bg], q_positions[g:g + Bg], cut(live, g),
+                                    bs, window, cut(n_real, g))
                  for g in range(0, B, Bg))
 
 
@@ -808,11 +915,16 @@ def paged_block_attention(
     layer: jax.Array,  # scalar int32
     live: jax.Array | None = None,  # (B,) bool — rows whose output is read
     split: BlockSplit | tuple | None = None,  # common_block_split of the
-    # three above, when the caller has it already (one forward, many layers);
-    # or ``row_group_splits``' tuple, one for each group of rows
+    # three above and ``n_real``, when the caller has it already (one forward,
+    # many layers); or ``row_group_splits``' tuple, one for each group of rows
     window: jax.Array | None = None,  # scalar int32: query i attends its last
     # ``window`` positions alone (a traced value, so that layers of one scan
     # may differ; ``split`` is then the caller's, made with that window)
+    n_real: jax.Array | None = None,  # (B,) int32: row b's real positions are
+    # t < n_real[b] (None: all T). The common pass multiplies those alone; a
+    # position behind them returns the row's last real one's output, a row
+    # without one zeros. Read where the wrapper makes the split; a caller's
+    # ``split`` was made with it
     *,
     scale: float | None = None,
     interpret: bool | None = None,
@@ -829,12 +941,10 @@ def paged_block_attention(
     group = nq // nkv
     scale = scale if scale is not None else hd**-0.5
     interpret = interpret if interpret is not None else on_cpu()
-    # the layout: (nkv, B * Rp, hd), riders first, a row's T*group query rows
-    # padded to whole sublane tiles so that a row is an aligned slice
     R = T * group
     Rp = _padded_query_rows(T, group)
     out_dtype = q.dtype if out_dtype is None else jnp.dtype(out_dtype)
-    Bg = _rows_that_fit(B, Rp, nkv, hd, q.dtype.itemsize, out_dtype.itemsize)
+    Bg = _rows_that_fit(B, R, nkv, hd, q.dtype.itemsize)
     # ``row_group_splits``' tuple, or nothing (one BlockSplit is a whole batch's)
     groups = None if split is None or isinstance(split, BlockSplit) else split
     if Bg < B:
@@ -843,39 +953,52 @@ def paged_block_attention(
         if groups is None and window is not None:
             raise NotImplementedError(
                 "a windowed layer's split is its caller's, made for one group of rows")
+        cut = lambda x, g: None if x is None else x[g:g + Bg]
         return jnp.concatenate([
             paged_block_attention(
                 q[g:g + Bg], k_pool, v_pool, block_tables[g:g + Bg], q_positions[g:g + Bg],
-                layer, None if live is None else live[g:g + Bg],
-                None if groups is None else groups[g // Bg], window, scale=scale,
-                interpret=interpret, out_dtype=out_dtype)
+                layer, cut(live, g), None if groups is None else groups[g // Bg], window,
+                cut(n_real, g), scale=scale, interpret=interpret, out_dtype=out_dtype)
             for g in range(0, B, Bg)])
     if groups is not None:
         (split,) = groups  # rows that fit whole: one group
     if split is None:
-        split = common_block_split(block_tables, q_positions, live, bs)
-    Bc = _sub_rows(B)
+        split = common_block_split(block_tables, q_positions, live, bs, n_real=n_real)
+    # the queries' layout is what it always was — (nkv, B * Rp, hd), riders
+    # first, a row's T * group query rows padded to whole sublane tiles so that a
+    # row is one aligned block — now resident for the whole walk: the kernel packs
+    # the riders' real positions out of it itself, into whole sub-chunks
+    sub = _sub_query_rows(B, R)
+    Pq = -(-B * R // sub) * sub
     qg = q.reshape(B, T, nkv, group, hd).transpose(2, 0, 1, 3, 4).reshape(nkv, B, R, hd)
     qg = jnp.pad(qg[:, split.order], ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
     qg = qg.reshape(nkv, B * Rp, hd)
-    whole = pl.BlockSpec((nkv, B * Rp, hd), lambda w, *_: (0, 0, 0))
     pool_spec = pl.BlockSpec(
         (1, 1, bs, nkv, hd), lambda w, qpos, meta, block, *_: (meta[0], block[w], 0, 0, 0))
     out = pl.pallas_call(
         functools.partial(_paged_block_kernel, scale=scale, nkv=nkv, group=group, T=T,
-                          bs=bs, Rp=Rp, sub=Bc * Rp,
+                          bs=bs, Rp=Rp, sub=sub,
                           **({} if window is None else {"windowed": True})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
+            num_scalar_prefetch=9,
             grid=(jnp.maximum(split.n_items, 1),),
-            in_specs=[whole, pool_spec, pool_spec],
-            out_specs=whole,
+            in_specs=[pl.BlockSpec((nkv, B * Rp, hd), lambda w, *_: (0, 0, 0)),
+                      pool_spec, pool_spec],
+            out_specs=pl.BlockSpec((nkv, Rp, hd),
+                                   lambda w, qpos, meta, block, row, *_: (0, row[w], 0)),
             scratch_shapes=[
-                pltpu.VMEM((nkv, B * Rp, hd), jnp.float32),
-                pltpu.VMEM((nkv, B * Rp, 128), jnp.float32),
-                pltpu.VMEM((nkv, B * Rp, 128), jnp.float32),
-                pltpu.VMEM((nkv, bs, hd), jnp.float32),
-                pltpu.VMEM((nkv, bs, hd), jnp.float32),
+                # a row's queries are written to, and its state read from, its
+                # packed place as a whole block, T * group rows padded: that
+                # much room behind the last slot
+                pltpu.VMEM((nkv, Pq + Rp, hd), jnp.float32),
+                pltpu.VMEM((nkv, Pq + Rp, hd), jnp.float32),
+                pltpu.VMEM((nkv, Pq + Rp, 128), jnp.float32),
+                pltpu.VMEM((nkv, Pq + Rp, 128), jnp.float32),
+                pltpu.VMEM((nkv, Rp, hd), jnp.float32),
+                pltpu.VMEM((nkv, Rp, 128), jnp.float32),
+                pltpu.VMEM((nkv, Rp, 128), jnp.float32),
+                pltpu.VMEM((bs, nkv, hd), jnp.float32),
+                pltpu.VMEM((bs, nkv, hd), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((nkv, B * Rp, hd), out_dtype),
@@ -884,9 +1007,10 @@ def paged_block_attention(
         name="paged_block_attention",
     )(q_positions.astype(jnp.int32).reshape(-1),
       jnp.stack([jnp.reshape(layer, ()).astype(jnp.int32), split.n_common, split.n_items,
-                 split.n_riders, -(-split.n_riders // Bc),
+                 -(-split.counts[2] * group // sub),
                  *(() if window is None else (jnp.reshape(window, ()).astype(jnp.int32),))]),
       split.item_block, split.item_row, split.item_tile, split.slot,
+      split.pack_start * group, split.pack_n * group, split.n_real,
       qg, k_pool, v_pool)
     # a row without an item was never written: zeros, not what the buffer held
     out = jnp.where(split.attended[None, :, None, None],
@@ -928,6 +1052,8 @@ def sharded_paged_block_attention(
     layer: jax.Array,
     live: jax.Array | None = None,  # (B,) bool
     split: BlockSplit | None = None,  # mesh=None only
+    window: jax.Array | None = None,  # ...
+    n_real: jax.Array | None = None,  # ... these two as well
     **kw,
 ) -> jax.Array:
     """paged_block_attention over a (dp, tp) mesh — same layout contract as
@@ -938,7 +1064,7 @@ def sharded_paged_block_attention(
     ``live``; ``split`` is the unmeshed caller's."""
     if mesh is None:
         return paged_block_attention(q, k_pool, v_pool, block_tables,
-                                     q_positions, layer, live, split, **kw)
+                                     q_positions, layer, live, split, window, n_real, **kw)
     from jax.sharding import PartitionSpec as P
 
     tp = mesh.shape.get("tp", 1)

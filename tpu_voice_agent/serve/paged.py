@@ -690,7 +690,7 @@ def paged_chunk_decode_loop(
             params, cfg, blk_tok, blk_pos, kp, vp,
             block_tables, rules=rules, attn_impl=kernels, write_mask=active,
             trash_idx=trash_idx, k_scale=ksc, v_scale=vsc, kv_quant=kv_quant,
-            **count_kw, **({"n_real": emitted} if told or packs else {}),
+            **count_kw, **({"n_real": emitted} if told or packs or fam.block_real else {}),
             **({"ffn_pack": ffn_pack} if packs else {}),
             **({"logit_pos": k} if fam.one_head else {}),
         )
