@@ -291,6 +291,10 @@ def _chunk_program_shas(eng) -> list[str]:
 # (``attn.common_query_rows``), and a plain model's 1 + W block is told
 # ``n_real`` at BOTH widths, for the block kernel's common pass to pack (the
 # grouped admission's and the one-row prefill's texts above did not move).
+# ISSUE 49 re-derived the latent model's COMPACTED width alone: its 1 + W block
+# is told ``n_real`` there too (``Family.block_real``), for the latent kernel
+# to pack — an argument this module's XLA engines do not read; the full width,
+# which was told already, is the text it was.
 CHUNK_SHA256 = {
     "dense": ["bd6960a6e5413477e9c715d36602d0ea9ee6872a882bd60eeee5173252803bc9",
               "428563f0ebbff1c395cfa7e002d824eb6266d55bb320837ed9778deade59d0e6"],
@@ -301,7 +305,7 @@ CHUNK_SHA256 = {
     "share": ["71ed6a17bfc260c579639cb4eba9753b92a38c95a47d644feb319a20aa8e1cd2",
               "bf9f8de1d121b1a1d9ffd31364d5aa91756116ab3e8a2e37a9ff5600fd20b5dc"],
     "latent": ["f791d7cacf4007111684766731421a27f85c588c79b36fc151680f09da7e382f",
-               "76cc5483bbd6bc6f9fda2a16a3e755a854e876ed2ddb43181d9065e14ab6639e"],
+               "d4c10d336feec5790dcb611f3872a45da8640b5506463a3305d45664658a9f98"],
 }
 
 
@@ -314,8 +318,9 @@ def test_the_latent_chunk_programs_are_the_parents():
     """A model with a latent cache (``models/mla.py``: the benchmark's
     ``moonlight-16b-a3b-int8`` at its rehearsal widths) packs its MLPs alone
     (``llama.packed_ffn``), on the ``FfnPack`` the other models pack both
-    regions with: at 32 slots its full-width chunk program holds that branch,
-    and both widths lower to the text ISSUE 41's parent lowers."""
+    regions with: at 32 slots its full-width chunk program holds that branch
+    and lowers to the text ISSUE 41's parent lowers; the compacted width to
+    ISSUE 49's (told ``n_real``)."""
     eng = _engine("latent")
     assert eng.latent and eng.compact_rows * 9 <= eng.ffn_pack_rows < SLOTS * 9
     assert _chunk_program_shas(eng) == CHUNK_SHA256["latent"]

@@ -54,7 +54,9 @@ def test_the_record_names_its_family_and_its_module(model):
     assert fam.one_head == (model != "dense" and model != "routed")
     assert fam.scratch_prefix == (model not in ("dense", "routed"))
     assert fam.n_real == {"hybrid": "always", "sparse": "admit"}.get(fam.name, "")
-    assert fam.block_real == (fam.name == "plain")  # the block kernel's callers' (``llama.forward_paged``)
+    # the callers of a kernel that packs the real positions: the block kernel's
+    # (``llama.forward_paged``) and the latent kernel's (``mla.forward_paged``)
+    assert fam.block_real == (fam.name in ("plain", "latent"))
 
 
 @pytest.mark.parametrize("model", MODELS)

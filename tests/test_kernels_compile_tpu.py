@@ -284,6 +284,28 @@ def test_paged_block_attention_common_pass_compiles_at_the_cells_shapes(tpu_devi
              ((B,), jnp.bool_), ((B,), I32))
 
 
+@pytest.mark.parametrize("B,T", [(32, FF_T), (8, FF_T), (32, 1)],
+                         ids=["moonlight_flood", "compacted", "a step"])
+def test_paged_latent_attention_packed_passes_compile_at_the_cells_shapes(tpu_devices, B, T):
+    """The latent kernel on the packed real positions (ISSUE 49) as
+    ``moonlight_flood`` runs it: (rows, 1 + 8 positions, 16 heads) queries of
+    512 + 64 over the cell's two planes and 12-column tables, with the write
+    mask and ``n_real`` handed down. The packed copy of both query halves and
+    its statistics beside the resident operands (42 MB at 32 rows, in ONE
+    group), a position's rows copied to a dynamic tile offset, the sub-chunks'
+    dynamic trip count and a rider's state read back from its packed place are
+    what interpret mode cannot refuse and Mosaic can."""
+    H, C, R, N, L = 16, 512, 64, 200, 17
+    from tpu_voice_agent.ops import latent_attention
+
+    assert latent_attention._rows_that_fit(B, T, H, C, R, 2) == B
+    attend = lambda qc, qr, cp, rp, tables, pos, layer, live, n_real: ops.paged_latent_attention(
+        qc, qr, cp, rp, tables, pos, layer, live, None, n_real, scale=192 ** -0.5, interpret=False)
+    _compile(tpu_devices, attend, ((B, T, H, C), BF16), ((B, T, H, R), BF16),
+             ((L, N, BLOCK, C), BF16), ((L, N, BLOCK, R), BF16), ((B, 12), I32), ((B, T), I32),
+             ((), I32), ((B,), jnp.bool_), ((B,), I32))
+
+
 def _conditionals(hlo: str) -> int:
     """``conditional`` instructions of a compiled program's text."""
     return hlo.count(" conditional(")
@@ -710,7 +732,9 @@ def test_the_moonlight_chunk_program_compiles_at_published_widths(tpu_devices, m
     beside them), int8 weights, the LATENT pool of 512 + 64 values a token a
     layer behind the latent kernel at 144 query rows a batch row, the
     163840-wide head on one position a row — at the compacted width, with the
-    MLPs packed into 96 rows (what the cell runs under load) and whole."""
+    MLPs packed into 96 rows (what the cell runs under load) and whole. Every
+    width is told its rows' real positions (``Family.block_real``), so each
+    lowers the latent kernel's packed passes (ISSUE 49)."""
     from tpu_voice_agent.serve import paged
 
     eng, s, params = _moonlight_engine(monkeypatch)

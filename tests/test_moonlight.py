@@ -175,6 +175,81 @@ def test_the_latent_kernel_matches_its_twin_behind_a_common_prefix_and_ragged_ow
             assert rel(got[:5], want[:5]) < 1e-4 and float(jnp.abs(got[5]).max()) == 0.0
 
 
+# the packed passes (ISSUE 49): six rows — four behind the same two leading
+# blocks (they ride), one with a table of its own, one idle — each with
+# ``n_real`` of its T positions real, the rest copies of its last real one
+# (query, position) as the chunk program's ``ff_body`` builds them
+_REAL_CASES = {
+    # name: (T, n_real a row, rows a group or None)
+    "one position, ragged": (1, [1, 0, 1, 1, 1, 1], None),
+    "one position, every one real": (1, [1, 1, 1, 1, 1, 1], None),
+    "a block, ragged": (9, [9, 0, 1, 4, 2, 3], None),
+    "a block, every position real": (9, [9, 9, 9, 9, 9, 9], None),
+    "a block, two groups of rows": (9, [2, 9, 0, 1, 3, 5], 3),
+}
+
+
+@pytest.mark.parametrize("case", list(_REAL_CASES))
+def test_the_latent_kernel_multiplies_the_real_positions_alone(case, monkeypatch):
+    """``n_real`` packs a row's real positions in both passes and carries the
+    riders' state from the common pass into the own one: a real position's
+    output is the ``n_real=None`` call's BIT FOR BIT (a query row's dots do
+    not depend on which rows share its tile) and the plain twin's within
+    tolerance; a position behind them returns its row's last real one's; a
+    live row without one, and the idle row, return zeros and disturb nobody;
+    ``common_query_rows`` counts the riders' real positions."""
+    import sys
+
+    from tpu_voice_agent.ops import (latent_row_splits, paged_latent_attention,
+                                     paged_latent_attention_reference)
+
+    T, n_real, rows_a_group = _REAL_CASES[case]
+    B, H, C, R, bs, L = 6, 4, 48, 16, 16, 3
+    ks = jax.random.split(jax.random.key(11), 4)
+    n_real = np.asarray(n_real, np.int32)
+    t_of = np.minimum(np.arange(T)[None, :], np.maximum(n_real[:, None] - 1, 0))  # the copies
+    copies = lambda q: jnp.take_along_axis(q, jnp.asarray(t_of)[:, :, None, None], axis=1)
+    c_pool = jax.random.normal(ks[0], (L, 40, bs, C), F32)
+    r_pool = jax.random.normal(ks[1], (L, 40, bs, R), F32)
+    q_c = copies(jax.random.normal(ks[2], (B, T, H, C), F32))
+    q_r = copies(jax.random.normal(ks[3], (B, T, H, R), F32))
+    tables = np.asarray([[1, 2, 10 + 4 * b, 11 + 4 * b, 12 + 4 * b, 13 + 4 * b] for b in range(B)], np.int32)
+    tables[4] = [30, 31, 32, 33, 34, 35]  # a row that shares nothing
+    first = np.asarray([40, 55, 33, 70, 50, 0])  # rides x 4, own table, idle
+    live = jnp.asarray([True, True, True, True, True, False])
+    pos = jnp.asarray(first[:, None] + t_of, jnp.int32)
+    tables = jnp.asarray(tables)
+    rows = np.asarray(live) & (n_real > 0)
+    real = (np.arange(T)[None, :] < n_real[:, None]) & rows[:, None]
+
+    if rows_a_group:  # the rows' state passes the kernel's budget: groups, each with a split of its own
+        mod = sys.modules["tpu_voice_agent.ops.latent_attention"]
+        monkeypatch.setattr(mod, "_rows_that_fit", lambda *a: rows_a_group)
+        jax.clear_caches()  # the budget is read when the wrapper is traced
+    made = lambda n: latent_row_splits((B, T, H, C, R), tables, pos, live, bs, 4, n)
+    assert len(made(None)) == (B // rows_a_group if rows_a_group else 1)
+    with jax.default_matmul_precision("highest"):
+        call = lambda n, split: np.asarray(paged_latent_attention(
+            q_c, q_r, c_pool, r_pool, tables, pos, jnp.int32(1), live, split, n, scale=0.125))
+        whole = call(None, made(None))
+        got = call(jnp.asarray(n_real), made(jnp.asarray(n_real)))
+        # the split is the wrapper's own where the caller hands none
+        np.testing.assert_array_equal(call(jnp.asarray(n_real), None), got)
+        want = np.asarray(paged_latent_attention_reference(q_c, q_r, c_pool, r_pool, tables, pos, 1,
+                                                           scale=0.125))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got[real], whole[real])
+    np.testing.assert_array_equal(got, np.take_along_axis(got, t_of[:, :, None, None], axis=1))
+    assert (got[~rows] == 0).all() and (whole[5] == 0).all()
+    assert rel(got[rows], want[rows]) < 1e-4 and rel(whole[:5], want[:5]) < 1e-4
+    splits = made(jnp.asarray(n_real))
+    rides = np.concatenate([np.asarray(s.slot) < int(s.n_riders) for s in splits])
+    assert rides[:4].all() or rows_a_group
+    assert sum(int(s.counts[2]) for s in splits) == int(n_real[rides].sum())
+    if rows_a_group:
+        jax.clear_caches()
+
+
 def test_the_pool_holds_576_values_a_token_a_layer():
     """At the PUBLISHED widths: two planes, a latent of 512 and one rotated
     key of 64 — 1152 B a token a layer in bf16, 19584 B over the 17 layers —

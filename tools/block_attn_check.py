@@ -1,12 +1,13 @@
 #!/usr/bin/env python3
-"""``ops.paged_block_attention`` alone, on the chip, at a cell's shapes: what
-its common pass costs at each number of rows, and — where the kernel takes
-``n_real`` (ISSUE 48) — that the outputs at the real positions are the
-``n_real=None`` call's bit for bit.
+"""``ops.paged_block_attention`` — or, ``--geom latent``, ``ops.paged_latent_attention``
+(two pools: a latent of 512 and ONE rotated key of 64 a position, 16 heads) —
+alone, on the chip, at a cell's shapes: what its common pass costs at each
+number of rows, and — where the kernel takes ``n_real`` (ISSUES 48, 49) — that
+the outputs at the real positions are the ``n_real=None`` call's bit for bit.
 
 A seeded pool, ``--rows`` rows behind ``--common`` blocks they all hold and see
 whole, each with two blocks of its own, a fast-forward block of 1 + 8 positions
-a row of which ``n_real`` (seeded, ~1.4 of 9 as the flood cells' are) are real
+(``--positions``) a row of which ``n_real`` (seeded, ~1.4 of 9 as the flood cells' are) are real
 and the rest copies of the row's last real one, as the chunk program's
 ``ff_body`` builds them. Three walls a row count, each the median of ``--reps``
 launches of ``--layers`` calls in one program: ``own`` (the rows' first blocks
@@ -16,7 +17,7 @@ handed down; absent where the kernel takes none, so the parent's tree runs this
 file too). ``whole - own`` is the common pass at the block's width,
 ``packed - own`` on the real positions.
 
-    python3 tools/block_attn_check.py [--geom mistral olmoe cmdaplus] [--rows 8 32] [--seed 7]
+    python3 tools/block_attn_check.py [--geom mistral olmoe cmdaplus latent] [--rows 8 32] [--seed 7]
 
 A line of JSON a geometry and row count, on stdout and appended to
 ``chiprun_out/block_attn_check.jsonl``; exit code 1 where a real position's
@@ -41,18 +42,36 @@ import numpy as np  # noqa: E402
 
 from tpu_voice_agent import ops  # noqa: E402
 
-# (q heads, kv heads, head_dim) of the cells that run the kernel
-GEOMS = {"mistral": (32, 8, 128), "olmoe": (16, 16, 128), "cmdaplus": (128, 8, 128)}
-BS, T, POOL, COLUMNS = 128, 9, 200, 12
-TAKES_N_REAL = "n_real" in inspect.signature(ops.paged_block_attention.__wrapped__).parameters
+# (q heads, kv heads, head_dim) of the cells that run the block kernel; "latent":
+# (heads, latent width, rotated width) of the one that runs the latent kernel
+# (``moonlight_flood``: 7 common blocks a row where the others hold 6)
+GEOMS = {"mistral": (32, 8, 128), "olmoe": (16, 16, 128), "cmdaplus": (128, 8, 128),
+         "latent": (16, 512, 64)}
+BS, POOL, COLUMNS = 128, 200, 12
 
 
-def case(rng, B: int, geom, common: int, layers: int, rides: bool):
-    nq, nkv, hd = geom
+def kernel(name: str):
+    """-> (the op, its keywords, whether it takes ``n_real``)."""
+    if name == "latent":
+        fn, kw = ops.paged_latent_attention, {"scale": (128 + 64) ** -0.5}
+    else:
+        fn, kw = ops.paged_block_attention, {}
+    return fn, kw, "n_real" in inspect.signature(fn.__wrapped__).parameters
+
+
+def case(rng, B: int, T: int, name: str, common: int, layers: int, rides: bool):
+    """-> (queries, pools, tables, positions, n_real): the op's operands in its order."""
     key = jax.random.PRNGKey(int(rng.integers(1 << 30)))
     kq, kk, kv = jax.random.split(key, 3)
-    pool = lambda k: jax.random.normal(k, (layers, POOL, BS, nkv, hd), jnp.bfloat16)
-    q = jax.random.normal(kq, (B, T, nq, hd), jnp.bfloat16)
+    normal = lambda k, *shape: jax.random.normal(k, shape, jnp.bfloat16)
+    if name == "latent":
+        H, C, R = GEOMS[name]
+        queries = (normal(kq, B, T, H, C), normal(jax.random.fold_in(kq, 1), B, T, H, R))
+        pools = (normal(kk, layers, POOL, BS, C), normal(kv, layers, POOL, BS, R))
+    else:
+        nq, nkv, hd = GEOMS[name]
+        queries = (normal(kq, B, T, nq, hd),)
+        pools = (normal(kk, layers, POOL, BS, nkv, hd), normal(kv, layers, POOL, BS, nkv, hd))
     tables = np.zeros((B, COLUMNS), np.int32)
     first = common if rides else 0
     tables[:, :first] = np.arange(first)[None, :]
@@ -60,25 +79,28 @@ def case(rng, B: int, geom, common: int, layers: int, rides: bool):
     n_real = np.minimum(rng.geometric(0.7, size=B), T).astype(np.int32)
     base = first * BS + rng.integers(40, 200, size=B)
     # a padded position is a copy of its row's last real one: its query, its position
-    t_of = last_real(n_real)
-    q = jnp.take_along_axis(q, jnp.asarray(t_of)[:, :, None, None], axis=1)
-    return (q, pool(kk), pool(kv), jnp.asarray(tables),
+    t_of = last_real(n_real, T)
+    queries = tuple(jnp.take_along_axis(q, jnp.asarray(t_of)[:, :, None, None], axis=1)
+                    for q in queries)
+    return (queries, pools, jnp.asarray(tables),
             jnp.asarray((base[:, None] + t_of).astype(np.int32)), jnp.asarray(n_real))
 
 
-def last_real(n_real) -> np.ndarray:
+def last_real(n_real, T: int) -> np.ndarray:
     """(B, T): t where position t is real, else the row's last real one."""
     return np.minimum(np.arange(T)[None, :], np.asarray(n_real)[:, None] - 1)
 
 
-def program(layers: int, with_n_real: bool):
+def program(name: str, layers: int, with_n_real: bool):
     """``layers`` calls in one program (the pool holds fewer planes: the index wraps)."""
-    def run(q, kp, vp, tables, positions, n_real):
+    fn, kw, _ = kernel(name)
+
+    def run(queries, pools, tables, positions, n_real):
         def layer(carry, li):
-            kw = {"n_real": n_real} if with_n_real else {}
-            out = ops.paged_block_attention(q, kp, vp, tables, positions, li % kp.shape[0], **kw)
+            out = fn(*queries, *pools, tables, positions, li % pools[0].shape[0], **kw,
+                     **({"n_real": n_real} if with_n_real else {}))
             return carry + out.astype(jnp.float32), None
-        return jax.lax.scan(layer, jnp.zeros(q.shape, jnp.float32),
+        return jax.lax.scan(layer, jnp.zeros(queries[0].shape, jnp.float32),
                             jnp.arange(layers, dtype=jnp.int32))[0]
     return jax.jit(run)
 
@@ -97,34 +119,38 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--geom", nargs="+", default=["mistral"], choices=sorted(GEOMS))
     ap.add_argument("--rows", nargs="+", type=int, default=[8, 32])
-    ap.add_argument("--common", type=int, default=6)
-    ap.add_argument("--layers", type=int, default=32)
+    ap.add_argument("--common", type=int, help="blocks every row holds (6; latent: 7)")
+    ap.add_argument("--positions", type=int, default=9, help="positions a row's block holds (T)")
+    ap.add_argument("--layers", type=int, help="calls a program (32; latent: 17)")
     ap.add_argument("--pool-layers", type=int, default=2)
     ap.add_argument("--reps", type=int, default=9)
     ap.add_argument("--seed", type=int, default=7)
     a = ap.parse_args()
+    T = a.positions
     dev = jax.devices()[0]
     out = Path("chiprun_out")
     out.mkdir(exist_ok=True)
     same = True
     for name in a.geom:
+        fn, kw, takes_n_real = kernel(name)
+        common = a.common or (7 if name == "latent" else 6)
+        layers = a.layers or (17 if name == "latent" else 32)
         for B in a.rows:
-            line = {"geom": name, "rows": B, "common": a.common, "layers": a.layers,
-                    "device": dev.device_kind, "takes_n_real": TAKES_N_REAL}
-            per_call = lambda ms: ms / a.layers
+            line = {"geom": name, "rows": B, "positions": T, "common": common, "layers": layers,
+                    "device": dev.device_kind, "takes_n_real": takes_n_real}
+            per_call = lambda ms: ms / layers
             for label, rides in (("own", False), ("whole", True)):
-                args = case(np.random.default_rng(a.seed), B, GEOMS[name], a.common,
-                            a.pool_layers, rides)
-                line[f"{label}_ms"] = per_call(wall_ms(program(a.layers, False), args, a.reps))
-            if TAKES_N_REAL:
-                line["packed_ms"] = per_call(wall_ms(program(a.layers, True), args, a.reps))
-                line["positions_real"] = int(jnp.sum(args[5]))
-                one = lambda kw: ops.paged_block_attention(*args[:5], jnp.int32(0), **kw)
+                args = case(np.random.default_rng(a.seed), B, T, name, common, a.pool_layers, rides)
+                line[f"{label}_ms"] = per_call(wall_ms(program(name, layers, False), args, a.reps))
+            if takes_n_real:
+                line["packed_ms"] = per_call(wall_ms(program(name, layers, True), args, a.reps))
+                line["positions_real"] = int(jnp.sum(args[4]))
+                one = lambda more: fn(*args[0], *args[1], *args[2:4], jnp.int32(0), **kw, **more)
                 ref = np.asarray(one({}), np.float32)
-                got = np.asarray(one({"n_real": args[5]}), np.float32)
-                real = np.arange(T)[None, :] < np.asarray(args[5])[:, None]
+                got = np.asarray(one({"n_real": args[4]}), np.float32)
+                real = np.arange(T)[None, :] < np.asarray(args[4])[:, None]
                 line["real_positions_bit_equal"] = bool(np.array_equal(ref[real], got[real]))
-                last = np.take_along_axis(got, last_real(args[5])[:, :, None, None], axis=1)
+                last = np.take_along_axis(got, last_real(args[4], T)[:, :, None, None], axis=1)
                 line["others_return_the_last_real"] = bool(np.array_equal(last, got))
                 same &= line["real_positions_bit_equal"] and line["others_return_the_last_real"]
             line["common_pass_ms"] = line["whole_ms"] - line["own_ms"]

@@ -75,7 +75,8 @@ class Family:
     # alone). One that is told picks an admission's attention path itself, by T, from the engine's
     # kernels; for the others the caller names the layout's ("xla" behind a prefix)
     block_real: bool = False  # a 1 + W block forward is told them at EVERY width, packed or not:
-    # its attention kernel's common pass multiplies the real positions alone (``ops.paged_block_attention``)
+    # its attention kernel multiplies the real positions alone (``ops.paged_block_attention``'s
+    # common pass, both passes of ``ops.paged_latent_attention``)
     one_head: bool = False  # the head runs on the ONE position a row of a 1 + W block reads
     pack_rows: int = FFN_PACK_ROWS  # the packed width of a fast-forward block; 0: no packed branch
     scratch_prefix: bool = True  # the prompt prefix is prefilled through a scratch POOL
@@ -169,7 +170,7 @@ def family(cfg) -> Family:
                       _LATENT_REFUSES, n_real="admit", one_head=True, prefix_whole_blocks=True)
     if cfg.kv_lora_rank:  # a latent and ONE rotated key a token a layer
         return Family("latent", mla, mla.cache_spec(cfg), _counts(cfg, mla.LATENT_STATS),
-                      mla.LatentCacheOnly, _LATENT_REFUSES, one_head=True)
+                      mla.LatentCacheOnly, _LATENT_REFUSES, block_real=True, one_head=True)
     paged_only = bool(cfg.layer_types or cfg.parallel_block or cfg.tie_embeddings)
     return Family("plain", llama, llama.cache_spec(cfg), _counts(cfg), NotImplementedError,
                   _PAGED_ONLY if paged_only else {}, block_real=True,

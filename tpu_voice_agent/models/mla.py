@@ -186,9 +186,11 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, c_pool, r_pool, b
 
     Attention: T <= ``MAX_BLOCK_DECODE_T`` under "pallas" — a decode step, a
     fast-forward block — goes through ``ops.paged_latent_attention`` (T = 1
-    too); a fresh block attends its own latents; everything else (a suffix
-    behind the cached prefix) gathers the row's covered blocks of BOTH planes
-    and attends in XLA, absorbed like the rest."""
+    too; told ``n_real`` it multiplies the real positions' query rows alone,
+    and a position behind them returns its row's last real one's); a fresh
+    block attends its own latents; everything else (a suffix behind the
+    cached prefix) gathers the row's covered blocks of BOTH planes and
+    attends in XLA, absorbed like the rest."""
     from ..ops.latent_attention import (latent_attention_reference, latent_row_splits,
                                         paged_latent_attention)
 
@@ -215,8 +217,10 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, c_pool, r_pool, b
     split = None
     if block_decode:
         with jax.named_scope("layer/attn/split"):
+            # ``n_real``: the kernel multiplies the real positions' query rows
+            # alone (a row outside ``write_mask`` has no item, whatever it names)
             split = latent_row_splits((B, T, H, C, dr), block_tables, positions, write_mask, bs,
-                                      params["embed"].dtype.itemsize)
+                                      params["embed"].dtype.itemsize, n_real)
 
     pack = None
     if ffn_pack and n_real is not None and B * T > ffn_pack:

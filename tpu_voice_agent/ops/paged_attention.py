@@ -872,7 +872,8 @@ def _sub_rows(B: int) -> int:
     that hold one run: larger is faster when every position is real (at 32
     rows a quarter is within 3 % of the whole), smaller when one of eight is
     (my chip runs, PR 31). The block kernel counts them in PACKED rows, T *
-    group each (``_sub_query_rows``), the latent kernel in whole padded rows."""
+    group each (``_sub_query_rows``); the latent kernel's sub-chunks are a
+    fixed count of packed query rows (``latent_attention._PACK_SUB``)."""
     return max(c for c in range(1, max(B // 4, 1) + 1) if B % c == 0)
 
 
