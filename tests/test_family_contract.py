@@ -184,7 +184,10 @@ def test_a_chunk_counts_what_the_record_names_in_its_order(model, catalog, monke
     names = [c.name for c in fam.counts] + ["ffn"] * bool(eng.ffn_pack_rows)
     assert [c.keyword for c in fam.counts] == {
         "dense": ["attn_stats"], "routed": ["moe_stats", "attn_stats"],
-        "hybrid": ["hybrid_stats", "attn_stats"], "share": ["moe_stats", "attn_stats"],
+        "hybrid": ["hybrid_stats", "attn_stats"],
+        # (this module's max_len of 256 passes the share model's rehearsal window: it BINDS,
+        # and a plain model whose window binds counts what its windowed layers walk)
+        "share": ["moe_stats", "attn_stats", "window_stats"],
         "latent": ["moe_stats", "attn_stats", "latent_stats"],
         "sparse": ["moe_stats", "attn_stats", "latent_stats"]}[model]
     if fam.name == "hybrid":

@@ -56,8 +56,12 @@ def _counts(cfg, latent: tuple[str, ...] = ()) -> tuple[Count, ...]:
     """A LlamaConfig's, in the order its chunk program carries them: the
     routed layers' expert rows, the attention row-blocks, a latent cache's reads."""
     routed = (Count("moe", "moe_stats", tuple(f"moe.{n}" for n in llama.moe_stat_names(cfg))),)
+    # K/V layers behind a window that BINDS at this ``max_seq_len``: what their walks read
+    windowed = not cfg.kv_lora_rank and llama.bound_window(cfg) is not None
     return (routed if cfg.n_experts else ()) + (ATTN,) + (
-        (Count("latent", "latent_stats", tuple(f"attn.{n}" for n in latent)),) if latent else ())
+        (Count("latent", "latent_stats", tuple(f"attn.{n}" for n in latent)),) if latent else ()) + (
+        (Count("window", "window_stats", tuple(f"attn.{n}" for n in llama.WINDOW_STATS)),)
+        if windowed else ())
 
 
 @dataclass(frozen=True)
@@ -146,9 +150,9 @@ _LATENT_REFUSES = {
     "dense_cache": f"a dense cache holds {_PLANES} (forward_paged alone runs it, "
                    "PagedDecodeEngine on one device serves it)",
 }
-_PAGED_ONLY = {"dense_cache": "layers of more than one kind, a parallel block and a tied head are "
-                              "forward_paged's: PagedDecodeEngine serves this model, the dense "
-                              "cache does not"}
+_PAGED_ONLY = {"dense_cache": "layers of more than one kind, a parallel block, a tied head and a "
+                              "router on the layer's input are forward_paged's: PagedDecodeEngine "
+                              "serves this model, the dense cache does not"}
 
 
 @lru_cache(maxsize=256)  # configurations are few, frozen and hashable; the record is read-only
@@ -171,8 +175,13 @@ def family(cfg) -> Family:
     if cfg.kv_lora_rank:  # a latent and ONE rotated key a token a layer
         return Family("latent", mla, mla.cache_spec(cfg), _counts(cfg, mla.LATENT_STATS),
                       mla.LatentCacheOnly, _LATENT_REFUSES, block_real=True, one_head=True)
-    paged_only = bool(cfg.layer_types or cfg.parallel_block or cfg.tie_embeddings)
+    paged_only = bool(cfg.layer_types or cfg.parallel_block or cfg.tie_embeddings
+                      or cfg.router_input == "layer")
+    refuses = dict(_PAGED_ONLY if paged_only else {})
+    if llama.bound_window(cfg) is not None:  # (the block kernel's meshed and quantised wrappers)
+        refuses.update({f: "a sliding window that binds: the wrappers of the kernels that serve "
+                           f"it take no window" for f in ("mesh", "kv_quant")})
     return Family("plain", llama, llama.cache_spec(cfg), _counts(cfg), NotImplementedError,
-                  _PAGED_ONLY if paged_only else {}, block_real=True,
+                  refuses, block_real=True,
                   one_head=bool(cfg.layer_types), scratch_prefix=paged_only)
 

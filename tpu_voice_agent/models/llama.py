@@ -30,6 +30,9 @@ from ..utils.compilewatch import watch_compiles
 
 # ---------------------------------------------------------------- config
 
+# the activation on the gate plane of a gated MLP (``LlamaConfig.gate_act``)
+GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
 
 @dataclass(frozen=True)
 class LlamaConfig:
@@ -149,12 +152,32 @@ class LlamaConfig:
     swa_qk_rope_dim: int = 0
     swa_v_head_dim: int = 0
     swa_rope_theta: float = 0.0
+    # ---- a router that reads the layer's INPUT and a ReGLU expert
+    # (``smallthinker``). Two properties of the MODEL, not knobs. What the
+    # router reads: "ffn" — the tensor the experts are given, behind attention
+    # (every model above) — or "layer": the residual x the layer is handed,
+    # before any norm. The picks are then a function of the layer's input:
+    # they are made in the FIRST position-wise region of a layer
+    # (``_route_ahead``) and carried across the attention call to the experts,
+    # which no longer route
+    router_input: str = "ffn"
+    # the activation on the gate plane of a gated (three-plane) MLP or expert:
+    # "silu" (SwiGLU) | "relu" (ReGLU: down(relu(gate h) * up h))
+    gate_act: str = "silu"
 
     # a routed expert's form (no field: every LlamaConfig's is the gated SwiGLU of
     # three planes; ``models.nemotron_h``'s configuration names a two-plane one)
     expert_form = "swiglu"
 
     def __post_init__(self):
+        if self.router_input not in ("ffn", "layer") or self.gate_act not in GATE_ACTS:
+            raise ValueError(f"router_input 'ffn' | 'layer' and gate_act one of {sorted(GATE_ACTS)}, "
+                             f"got {self.router_input!r}, {self.gate_act!r}")
+        if self.router_input == "layer" and (not self.n_experts or self.kv_lora_rank
+                                             or self.parallel_block):
+            raise NotImplementedError("a router on the layer's input: routed experts behind "
+                                      "forward_paged's own two regions (no latent forward, no "
+                                      "parallel block)")
         if self.layer_types and (len(self.layer_types) != self.n_layers or
                                  set(self.layer_types) - {"sliding", "full"}):
             raise ValueError(f"layer_types: one of 'sliding' | 'full' for each of "
@@ -577,6 +600,13 @@ MOE_STATS = ("assigned_rows", "padded_rows", "experts_touched", "load_max")
 MOE_SHARE_STATS = MOE_STATS + ("local_rows",)
 
 
+# what a forward of a model whose window BINDS counts beside ``ops.ATTN_STATS``
+# (published as ``attn.<name>``, as ``models.sambay`` publishes its own): the
+# row-blocks its windowed layers' walks read, and those the same rows hold up to
+# their frontier, each summed over the windowed layers
+WINDOW_STATS = ("window_blocks_walked", "window_blocks_held")
+
+
 def moe_stat_names(cfg) -> tuple[str, ...]:
     """What a routed forward of this model counts, in order."""
     return MOE_SHARE_STATS if cfg.experts_held else MOE_STATS
@@ -666,7 +696,38 @@ def _running_count(hot: jax.Array) -> jax.Array:
 EXPERT_ACTS = {"relu2": lambda u: jnp.square(jax.nn.relu(u)), "silu": jax.nn.silu}
 
 
-def _moe_ffn_grouped(p, h, cfg: LlamaConfig, lat=None, n_rows=None):
+def _gate_act(cfg):
+    """The activation on the gate plane of ``cfg``'s gated MLPs and experts
+    (another family's record that names none: SwiGLU's)."""
+    return GATE_ACTS[getattr(cfg, "gate_act", "silu")]
+
+
+def _route_ahead(p, x, cfg: LlamaConfig):
+    """A layer's picks from its INPUT ``x`` (B, T, d), the residual before any
+    norm (``router_input`` "layer") -> (eids (B, T, K) int32, gates (B, T, K)
+    float32), by the model's own rule (``route_topk_flat``: the same selection
+    as a router behind attention). Position-wise: a packed region runs it on
+    the real positions. The expert layer is handed them (``picks``) and does
+    not route. A scope of its own: ``layer/moe/route_ahead``."""
+    from .moe import route_topk_flat
+
+    B, T, d = x.shape
+    with jax.named_scope("layer/moe/route_ahead"):
+        eids, gates = route_topk_flat(p["router"], x.reshape(B * T, d), cfg.n_experts, cfg.top_k,
+                                      cfg.norm_topk, cfg.router_fn, **_router_kw(p, cfg))
+        return eids.reshape(B, T, -1), gates.reshape(B, T, -1)
+
+
+def _picks_or_refuse(cfg: LlamaConfig, picks):
+    """The expert layer of a model whose router reads the layer's input is
+    handed its picks; one that reads its own input is handed none."""
+    if (getattr(cfg, "router_input", "ffn") == "layer") != (picks is not None):
+        raise NotImplementedError(
+            "router_input 'layer': the caller routes ahead, on the layer's input "
+            "(llama.forward_paged does), and hands the expert layer its picks")
+
+
+def _moe_ffn_grouped(p, h, cfg: LlamaConfig, lat=None, n_rows=None, picks=None):
     """Grouped-matmul MoE FFN: assignments group by expert, each expert's run
     pads to a row-tile multiple, and ``ops.grouped_matmul`` streams one
     weight plane per expert that has rows — int8 as served — so FFN FLOPs
@@ -680,7 +741,9 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig, lat=None, n_rows=None):
     ``EXPERT_ACTS`` between them) and the caller what is DISPATCHED: ``lat``
     (B, T, w), one row a position, where the experts live at another width than
     the router reads (a latent; the sum comes back at that width), else ``h``
-    itself; and with ``n_rows`` () that only the first ``n_rows`` of the B * T
+    itself; with ``picks`` (eids, gates), each (B, T, K), that the router read
+    ANOTHER tensor, earlier in the layer (``_route_ahead``): nothing is routed
+    here; and with ``n_rows`` () that only the first ``n_rows`` of the B * T
     rows are real: a filler row's picks fall on no expert — no run, no tile, no
     weight fetch, as a pick held elsewhere — and ``assigned`` counts the real
     rows' alone. -> (out, ``_moe_stats``)."""
@@ -698,9 +761,12 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig, lat=None, n_rows=None):
     Tt = B * T
     A = Tt * K
     x2 = h.reshape(Tt, d)
-    with jax.named_scope("router"):
-        eids, gates = route_topk_flat(p["router"], x2, E, K, cfg.norm_topk,
-                                      cfg.router_fn, **_router_kw(p, cfg))  # (Tt, K)
+    if picks is not None:
+        eids, gates = picks[0].reshape(Tt, K), picks[1].reshape(Tt, K)
+    else:
+        with jax.named_scope("router"):
+            eids, gates = route_topk_flat(p["router"], x2, E, K, cfg.norm_topk,
+                                          cfg.router_fn, **_router_kw(p, cfg))  # (Tt, K)
     if lat is not None:
         d = lat.shape[-1]
         x2 = lat.reshape(Tt, d)
@@ -751,7 +817,8 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig, lat=None, n_rows=None):
         if cfg.expert_form == "swiglu":
             gate_s = grouped_matmul(xs, p["moe_gate"], tile_expert, n_tiles, li, tm=tm)
             up_s = grouped_matmul(xs, p["moe_up"], tile_expert, n_tiles, li, tm=tm)
-            act = (jax.nn.silu(gate_s.astype(jnp.float32)) * up_s.astype(jnp.float32)).astype(h.dtype)
+            act = (_gate_act(cfg)(gate_s.astype(jnp.float32))
+                   * up_s.astype(jnp.float32)).astype(h.dtype)
         else:
             up_s = grouped_matmul(xs, p["moe_up"], tile_expert, n_tiles, li, tm=tm)
             act = EXPERT_ACTS[cfg.expert_form](up_s.astype(jnp.float32)).astype(h.dtype)
@@ -768,7 +835,7 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig, lat=None, n_rows=None):
             _moe_stats(counts, ends[-1], (A if n_rows is None else n_rows * K) if share else None))
 
 
-def _moe_ffn_dense(p, h, cfg: LlamaConfig, lat=None):
+def _moe_ffn_dense(p, h, cfg: LlamaConfig, lat=None, picks=None):
     """Dense-dispatch MoE FFN (models.moe.route_topk): expert choice becomes
     one-hot einsums with static shapes. EP sharding happens declaratively:
     the stacked (E, ...) expert weights shard E over the mesh's tp axis
@@ -777,7 +844,7 @@ def _moe_ffn_dense(p, h, cfg: LlamaConfig, lat=None):
     capacity, so FLOPs and weight bytes are ∝ E whatever was routed: the
     meshed path, and the exact twin the grouped path is tested against.
     -> (out, ``_moe_stats``)."""
-    from .moe import moe_capacity, route_topk
+    from .moe import dispatch_topk, moe_capacity, route_topk
 
     B, T, d = h.shape
     x2 = h.reshape(B * T, d)
@@ -801,8 +868,12 @@ def _moe_ffn_dense(p, h, cfg: LlamaConfig, lat=None):
         )
     C = moe_capacity(B * T, cfg.n_experts, cfg.top_k, cf)
     with jax.named_scope("router"):
-        dispatch, combine = route_topk(p["router"], x2, cfg.n_experts, cfg.top_k, C,
-                                       cfg.norm_topk, cfg.router_fn, **_router_kw(p, cfg))
+        if picks is not None:  # routed ahead, on the layer's input: the slots alone
+            dispatch, combine = dispatch_topk(picks[0].reshape(B * T, -1),
+                                              picks[1].reshape(B * T, -1), cfg.n_experts, C)
+        else:
+            dispatch, combine = route_topk(p["router"], x2, cfg.n_experts, cfg.top_k, C,
+                                           cfg.norm_topk, cfg.router_fn, **_router_kw(p, cfg))
         if cfg.experts_held:  # a chip's share: the held experts' columns alone
             held = slice(cfg.first_expert, cfg.first_expert + cfg.experts_held)
             assigned = jnp.sum(dispatch).astype(jnp.int32)
@@ -816,7 +887,7 @@ def _moe_ffn_dense(p, h, cfg: LlamaConfig, lat=None):
         if cfg.expert_form == "swiglu":
             gate = _qe("ecd,edf->ecf", xe, p["moe_gate"])
             up = _qe("ecd,edf->ecf", xe, p["moe_up"])
-            a = (jax.nn.silu(gate) * up).astype(h.dtype)
+            a = (_gate_act(cfg)(gate) * up).astype(h.dtype)
         else:
             a = EXPERT_ACTS[cfg.expert_form](_qe("ecd,edf->ecf", xe, p["moe_up"])).astype(h.dtype)
         down = _qe("ecf,efd->ecd", a, p["moe_down"]).astype(h.dtype)
@@ -826,23 +897,25 @@ def _moe_ffn_dense(p, h, cfg: LlamaConfig, lat=None):
     return out, _moe_stats(counts, cfg.n_held * C, assigned if cfg.experts_held else None)
 
 
-def _moe_ffn(p, h, cfg: LlamaConfig, lat=None, n_rows=None):
+def _moe_ffn(p, h, cfg: LlamaConfig, lat=None, n_rows=None, picks=None):
     """Top-k routed expert FFN over (B, T, d) hidden states -> (out, stats).
     ``cfg.moe_impl`` names the dispatch; the engine that serves the model
     resolved "auto" where it was built (a single device takes the grouped
     kernel at every token count — PERF.md section 6, PR 28: it wins from 128
     tokens up and ties at a suffix prefill's 32-64 rows — a mesh the dense
     einsums, its experts sharded over tp)."""
+    _picks_or_refuse(cfg, picks)
     if cfg.moe_impl == "grouped":
-        return _moe_ffn_grouped(p, h, cfg, lat, n_rows)
-    return _moe_ffn_dense(p, h, cfg, lat)  # (it computes every row: a filler's is unread)
+        return _moe_ffn_grouped(p, h, cfg, lat, n_rows, picks)
+    return _moe_ffn_dense(p, h, cfg, lat, picks)  # (it computes every row: a filler's is unread)
 
 
-def _swiglu(p, h, names, cs=_identity_cs):
-    """down(silu(gate h) * up h) over the three leaves ``names``, float32."""
+def _swiglu(p, h, names, cs=_identity_cs, act="silu"):
+    """down(act(gate h) * up h) over the three leaves ``names``, float32
+    (``act``: the model's ``gate_act``; SwiGLU by default)."""
     gate = _qe("btd,df->btf", h, p[names[0]])
     up = _qe("btd,df->btf", h, p[names[1]])
-    act = cs((jax.nn.silu(gate) * up).astype(h.dtype), "ffn")
+    act = cs((GATE_ACTS[act](gate) * up).astype(h.dtype), "ffn")
     return _qe("btf,fd->btd", act, p[names[2]])
 
 
@@ -987,21 +1060,23 @@ def _layer_leaves(p: dict) -> dict:
             **{k: jax.tree.map(lambda a: a[p["layer"]], p[k]) for k in p["stacked"]}}
 
 
-def _ffn(p, h, cfg: LlamaConfig, cs=_identity_cs):
+def _ffn(p, h, cfg: LlamaConfig, cs=_identity_cs, picks=None):
     """A layer's MLP over its normed input (B, T, d) -> (y, the layer's
     ``_moe_stats`` or None): the dense SwiGLU, or the routed experts and the
     shared ones beside them. Position-wise: it asks nothing of B and T. Opens
     ``layer/ffn`` itself: a branch of a packed region puts its own names
     before it, and the scope paths the trace is read by stay whole. ``p``
     may hold leaves still STACKED over the layers (``_layer_leaves``): sliced
-    here, inside whichever branch runs."""
+    here, inside whichever branch runs. ``picks``: the layer's routing, made
+    ahead on its input (``_route_ahead``)."""
     with jax.named_scope("layer/ffn"):
         p = _layer_leaves(p)
         if cfg.n_experts > 0:
-            y, stats = _moe_ffn(p, h, cfg)
+            y, stats = _moe_ffn(p, h, cfg, picks=picks)
             if cfg.n_shared_experts:
                 with jax.named_scope("shared"):  # layer/ffn/shared
-                    shared = _swiglu(p, h, ("shared_gate", "shared_up", "shared_down"), cs)
+                    shared = _swiglu(p, h, ("shared_gate", "shared_up", "shared_down"), cs,
+                                     cfg.gate_act)
                     if cfg.shared_sum:  # the stacked SwiGLU IS their sum
                         y = y + shared.astype(y.dtype)
                     else:
@@ -1009,12 +1084,13 @@ def _ffn(p, h, cfg: LlamaConfig, cs=_identity_cs):
             return y, stats
         gate = _qe("btd,df->btf", h, p["w_gate"])
         up = _qe("btd,df->btf", h, p["w_up"])
-        act = (jax.nn.silu(gate) * up).astype(h.dtype)
+        act = (_gate_act(cfg)(gate) * up).astype(h.dtype)
         act = cs(act, "ffn")
         return _qe("btf,fd->btd", act, p["w_down"]).astype(h.dtype), None
 
 
-def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = False, u=None):
+def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = False, u=None,
+               picks=None):
     """Shared decoder-layer back half: output projection + residual, then
     the MLP (dense SwiGLU, or routed MoE when cfg.n_experts > 0) +
     residual. ``attn`` is (B, T, n_heads * head_dim). With ``moe_stats``
@@ -1022,7 +1098,8 @@ def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = 
     block (``u``: the layer's one normed input, which fed q/k/v too) adds
     both halves to the same residual: x + W_o attn + FFN(u). Position-wise:
     it asks nothing of B and T (``forward_paged`` runs it on a block's real
-    positions, packed)."""
+    positions, packed). ``picks``: the experts of a model whose router reads the
+    layer's input, chosen before attention (``_route_ahead``) on these rows."""
     with jax.named_scope("layer/attn_out"):
         attn = _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
         attn = cs(attn, "act")
@@ -1032,7 +1109,7 @@ def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = 
         raise NotImplementedError("a parallel block or shared experts around a dense MLP")
     with jax.named_scope("layer/ffn"):
         h = _norm(x, p["mlp_norm"], cfg) if u is None else u
-    y, stats = _ffn(p, h, cfg, cs)
+    y, stats = _ffn(p, h, cfg, cs, picks)
     with jax.named_scope("layer/ffn"):
         x = x + cs(y, "act") if u is None else x + attn + cs(y, "act")
     return (x, stats) if moe_stats else x
@@ -1170,7 +1247,8 @@ def forward(
 @watch_compiles("llama.forward_paged")
 @partial(jax.jit, static_argnames=("cfg", "rules", "attn_impl", "fresh_block",
                                    "gather_blocks", "kv_quant", "moe_stats",
-                                   "attn_stats", "hybrid_stats", "ffn_pack", "latent_stats"),
+                                   "attn_stats", "hybrid_stats", "ffn_pack", "latent_stats",
+                                   "window_stats"),
          donate_argnames=("k_pool", "v_pool", "k_scale", "v_scale"))
 def forward_paged(
     params: dict,
@@ -1227,6 +1305,8 @@ def forward_paged(
     # MLPs alone), and ``FFN_STATS`` (2,) int32 is returned LAST
     latent_stats: bool = False,  # a latent model only: also ``mla.LATENT_STATS``,
     # (2,) int32, after the attention row-blocks
+    window_stats: bool = False,  # a model whose window BINDS only (``bound_window``): also
+    # ``WINDOW_STATS``, (2,) int32, after the attention row-blocks
 ):
     """The paged twin of ``forward`` (parity-tested): sequences own
     non-contiguous pool blocks via per-row block tables (SURVEY.md §7
@@ -1262,7 +1342,7 @@ def forward_paged(
     if ffn_pack:
         fam.refuse("ffn_pack", NotImplementedError)
     asked = {"moe_stats": moe_stats, "attn_stats": attn_stats, "hybrid_stats": hybrid_stats,
-             "latent_stats": latent_stats}
+             "latent_stats": latent_stats, "window_stats": window_stats}
     counted = {c.keyword for c in fam.counts}
     if any(on and kw not in counted for kw, on in asked.items()):
         raise ValueError(f"a {fam.name} model's forward counts {sorted(counted)}: asked {asked}")
@@ -1354,6 +1434,10 @@ def forward_paged(
     stacked = () if pack is None else tuple(
         k for k in whole if not (cfg.moe_impl == "grouped" and k in _EXPERT_LEAVES))
 
+    # a router that reads the layer's input: the picks belong to the FIRST
+    # position-wise region and are carried across the attention call
+    ahead = cfg.router_input == "layer"
+
     def own_norm(p, x):
         """A parallel block's ONE normed input: q/k/v's and the MLP's."""
         if not cfg.parallel_block:
@@ -1370,6 +1454,7 @@ def forward_paged(
 
         if pack is None:
             u = own_norm(p, x)
+            picks = _route_ahead(p, x, cfg) if ahead else None
             q, k, v = _layer_qkv(p, x, cfg, cos, sin, cs, rotate=rotate, u=u)
         else:
             # a layer is position-wise but for its attention call and its K/V
@@ -1378,31 +1463,46 @@ def forward_paged(
             # one predicate — the output projection beside the MLP, not apart.
             # Only q/k/v are read back into the block, and only the attention
             # output is gathered
+            # picks made ahead stay in the layout of the branch that made them
+            # (the ONE predicate picks both regions' branches): a pair (the
+            # block's, the packed rows'), the other half zeros nobody reads
+            def no_picks(*rows):
+                return (jnp.zeros((*rows, cfg.top_k), jnp.int32),
+                        jnp.zeros((*rows, cfg.top_k), jnp.float32))
+
             def front(x, rope, hold=False):
                 pl = _layer_leaves(p)
+                picks = _route_ahead(pl, x, cfg) if ahead else None
                 q, k, v = _project_qkv(pl, x, cfg, cs, u=own_norm(pl, x))
                 if hold:
                     # buffers before they open into heads: a projection fused
                     # with that reshape wants every stacked plane transposed,
                     # and the whole branch then copies them all back, a layer
                     q, k, v = jax.lax.optimization_barrier((q, k, v))
-                return _rotate_heads(q, k, v, cfg, *rope, cs, rotate)
+                return _rotate_heads(q, k, v, cfg, *rope, cs, rotate), picks
 
             def front_rows(x, xp):
-                qkv = front(xp, rope_packed, hold=True)
+                qkv, picks = front(xp, rope_packed, hold=True)
                 with jax.named_scope("layer/attn_qkv/unpack"):
-                    return jax.tree.map(pack.block, qkv)
+                    qkv = jax.tree.map(pack.block, qkv)
+                return qkv if not ahead else (qkv, (no_picks(B, T), picks))
 
-            def back(x, attn):
+            def front_block(x, xp):
+                qkv, picks = front(x, (cos, sin))
+                return qkv if not ahead else (qkv, (picks, no_picks(1, ffn_pack)))
+
+            def back(x, attn, picks=None):
                 pl = _layer_leaves(p)
-                out = _layer_out(pl, x, attn, cfg, cs, moe_stats=moe_stats, u=own_norm(pl, x))
+                out = _layer_out(pl, x, attn, cfg, cs, moe_stats=moe_stats, u=own_norm(pl, x),
+                                 picks=picks)
                 return out if moe_stats else (out, None)
 
             x, xp = x
             # the conditional's own time (its operands' copies) is read with
             # the branches it chooses between
             with jax.named_scope("layer/attn_qkv"):
-                q, k, v = jax.lax.cond(pack.fits, front_rows, lambda x, xp: front(x, (cos, sin)), x, xp)
+                out = jax.lax.cond(pack.fits, front_rows, front_block, x, xp)
+            (q, k, v), picks = out if ahead else (out, (None, None))
 
         with jax.named_scope("layer/kv_write"):
             kp_flat = kp.reshape(L, N * bs, cfg.n_kv_heads, hdp)
@@ -1520,23 +1620,24 @@ def forward_paged(
                             vsc[li][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
                 attn = _attend(q, kl, vl, positions, kv_len_mask, window)
         if pack is None:
-            out = _layer_out(p, x, attn, cfg, cs, moe_stats=moe_stats, u=u)
+            out = _layer_out(p, x, attn, cfg, cs, moe_stats=moe_stats, u=u, picks=picks)
             x, stats = out if moe_stats else (out, None)
         else:
-            def back_rows(x, xp, attn):  # the new residual stays packed
+            def back_rows(x, xp, attn, *picks):  # the new residual stays packed
                 with jax.named_scope("layer/ffn/pack"):
                     rows = pack.rows(attn)
-                xp, st = back(xp, rows)
+                xp, st = back(xp, rows, picks[1] if ahead else None)
                 return (x, xp), st
 
-            def back_block(x, xp, attn):
-                x, st = back(x, attn)
+            def back_block(x, xp, attn, *picks):
+                x, st = back(x, attn, picks[0] if ahead else None)
                 return (x, xp), st
 
             # under a name no reader matches: ``layer/attn_out`` inside it
             # stays out of the MLP's time
             with jax.named_scope("layer/out"):
-                x, stats = jax.lax.cond(pack.fits, back_rows, back_block, x, xp, attn)
+                x, stats = jax.lax.cond(pack.fits, back_rows, back_block, x, xp, attn,
+                                        *(picks if ahead else ()))
         return (x, kp, vp, ksc, vsc), stats
 
     # layers of ONE kind are a scan over the stacked weights. Layers of more
@@ -1585,6 +1686,14 @@ def forward_paged(
         # ``benchmark/lib/peaks.py`` builds its floors on), the query positions of all
         extra += (sum(stats_of(split if w is None else win_split.get(w)) for _, w in kinds)
                   if windows else stats_of(split, reads=cfg.n_layers),)
+    if window_stats:
+        # what the layers behind a window walk of what their rows hold (the block
+        # kernel's own items; another path walks no block: zeros)
+        walked = held = jnp.zeros((), jnp.int32)
+        for _, w in kinds:
+            for sp in win_split.get(w, ()):
+                walked, held = walked + sp.n_items - sp.n_common, held + sp.counts[1]
+        extra += (jnp.stack([walked, held]).astype(jnp.int32),)
     if pack is not None:
         extra += (pack.stats,)
     return (logits, k_pool, v_pool, k_scale, v_scale, *extra)

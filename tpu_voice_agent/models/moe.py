@@ -115,3 +115,21 @@ def route_topk(router_w: jax.Array, x: jax.Array, n_experts: int, top_k: int,
     dispatch = slot_onehot * keep[..., None]
     combine = dispatch * kept_gate[..., None]
     return dispatch, combine if scale == 1.0 else combine * scale
+
+
+def dispatch_topk(eids: jax.Array, gates: jax.Array, n_experts: int,
+                  capacity: int) -> tuple[jax.Array, jax.Array]:
+    """The dense dispatch of picks made ELSEWHERE (a router that reads another
+    tensor than the experts: ``llama._route_ahead``): eids (T, K) int32 and
+    their finished gates (T, K) -> (dispatch (T, E, C) one-hot, combine
+    (T, E, C) gate-weighted), slots in token order as ``route_topk`` fills
+    them. The gates are used as given: serving is drop-free (``capacity``
+    covers every assignment), so no renormalisation over survivors arises."""
+    E, C = n_experts, capacity
+    hot = jax.nn.one_hot(eids, E, dtype=gates.dtype, axis=-1)  # (T, K, E)
+    chosen = jnp.sum(hot, axis=1) > 0.0  # (T, E)
+    gate = jnp.sum(hot * gates[:, :, None], axis=1)  # (T, E)
+    pos = jnp.cumsum(chosen.astype(jnp.int32), axis=0) - 1
+    keep = chosen & (pos < C)
+    dispatch = jax.nn.one_hot(jnp.where(keep, pos, C), C, dtype=gates.dtype) * keep[..., None]
+    return dispatch, dispatch * jnp.where(keep, gate, 0.0)[..., None]

@@ -63,6 +63,10 @@ from .engine import (
 )
 from .radix import RadixCache
 
+# the float32 attention scores ONE chunk of a chunked prefix prefill may hold a
+# layer (``PagedDecodeEngine._compute_prefix_kv``): what sizes the chunk
+PREFIX_SCORE_BYTES = 512 << 20
+
 SLOT_STATE_SPAN = REQUEST_SPAN + ".slot_state"
 
 
@@ -978,18 +982,34 @@ class PagedDecodeEngine(DecodeEngine):
         # the table's last column, where it has one: the scratch slot
         table = jnp.asarray([list(range(1, nb + 1)) + [0] * self._state_col], jnp.int32)
         kw = {"n_real": jnp.asarray([P], jnp.int32)} if fam.n_real == "always" else {}
-        # a prefix longer than the largest bucket (``prefix_whole_blocks``), in
+        # a prefix longer than the largest bucket (``_prefix_in_chunks``), in
         # chunks of it through the ONE scratch pool: each chunk attends the
-        # earlier ones through the model's own attention path, one program for
-        # all of them
-        chunk = min(bucket, self.prefill_buckets[-1]) if fam.prefix_whole_blocks else bucket
+        # earlier ones through the model's own attention path — a layer's own
+        # mask, window or none —, one program for all of them
+        whole = self._prefix_in_chunks(P)
+        chunk = min(bucket, self.prefill_buckets[-1]) if whole else bucket
+        if whole and fam.kv_by_head:
+            # K and V by head behind XLA's masks: a chunk's scores are heads x
+            # chunk x keys float32 a layer, BESIDE the resident model (0.94 GB at 28
+            # heads, 1024 positions, 8192 keys: a peak of 14.5 of 16 GB, my chip
+            # runs, PR 50). The chunk halves until they fit ``PREFIX_SCORE_BYTES``
+            while chunk > bs and self.cfg.n_heads * chunk * bucket * 4 > PREFIX_SCORE_BYTES:
+                chunk //= 2
+        if whole and fam.one_head and not fam.n_real:
+            # nobody reads a chunk's logits: the head on ONE position, not on every
+            # one of the chunk's (0.62 GB of float32 at a 151936-row vocabulary)
+            kw["logit_pos"] = jnp.zeros((1,), jnp.int32)
         for at in range(0, bucket, chunk):
             part = (tokens, positions) if chunk == bucket else (
                 tokens[:, at:at + chunk], positions[:, at:at + chunk])
             _, k, v, _, _ = forward_paged(self.params, self.cfg, *part, k, v, table,
-                                          attn_impl=self.kernels,
-                                          fresh_block=not fam.prefix_whole_blocks, **kw)
+                                          attn_impl=self.kernels, fresh_block=not whole, **kw)
         dense = lambda a: a[:, 1:].reshape(a.shape[0], 1, nb * bs, *a.shape[3:])[:, :, :P]
+        if whole:
+            # the chunks' workspace goes before the copies below are made: the host
+            # runs ahead of the device, and a buffer whose free is still pending
+            # counts beside every allocation made meanwhile
+            jax.block_until_ready((k, v))
         if slot["k"] or slot["v"]:  # the slot's planes: the snapshot; the blocks: the K/V planes
             self._prefix_state = {n: pool[n][:, 0] for pool, side in ((k, "k"), (v, "v"))
                                   for n in slot[side]}
@@ -1010,17 +1030,26 @@ class PagedDecodeEngine(DecodeEngine):
                 self.k_pool, self.v_pool, snapshot["conv"], snapshot["ssm"], jnp.int32(slot))
         get_metrics().inc("ssm.state_restores")
 
+    def _prefix_in_chunks(self, P: int) -> bool:
+        """The common prefix is cached in WHOLE blocks and prefilled in chunks of
+        the largest bucket: where the model's record says so (planes by layer
+        kind: no sub-block tail to scatter plane by plane into every admission's
+        first block), and for any model ``forward_paged`` alone runs whose prefix
+        PASSES the largest bucket — in one fresh block its scores would be
+        heads x P^2 float32 a layer. A prefix that fits a bucket is cached as it
+        always was."""
+        fam = self.family
+        return fam.prefix_whole_blocks or (fam.scratch_prefix and P > self.prefill_buckets[-1])
+
     def _cached_prefix_len(self, P: int) -> int:
-        """A model with planes by layer kind caches WHOLE blocks of the common
-        prefix (no sub-block tail to scatter plane by plane into every
-        admission's first block): the rest of it is prefilled with the suffix."""
-        return P // self.block_size * self.block_size if self.family.prefix_whole_blocks else P
+        """Whole blocks of the common prefix where it is cached in chunks: the
+        rest of it is prefilled with the suffix."""
+        return P // self.block_size * self.block_size if self._prefix_in_chunks(P) else P
 
     def _prefix_bucket(self, P: int) -> int:
-        """Such a model's prefix may pass the largest bucket: whole chunks of it."""
+        """Such a prefix may pass the largest bucket: whole chunks of it."""
         top = self.prefill_buckets[-1]
-        whole = self.family.prefix_whole_blocks and P > top
-        return -(-P // top) * top if whole else self._bucket(P)
+        return -(-P // top) * top if self._prefix_in_chunks(P) and P > top else self._bucket(P)
 
     def _prefill_kw(self, attn_impl: str, n_real) -> dict:
         """A prefill forward's arguments that follow the model's record: most
@@ -1056,6 +1085,12 @@ class PagedDecodeEngine(DecodeEngine):
         # (L, P, nkv, hd); a model with planes by layer kind: a tree of them
         pk = jax.tree.map(lambda a: a[:, 0], self.prefix_kv["k"])
         pv = jax.tree.map(lambda a: a[:, 0], self.prefix_kv["v"])
+        # the dense (L, 1, P, nkv, hd) copy goes NOW, not when the blocks are in
+        # the pool: beside a resident 12.5 GB an 8192-token prefix is 0.4 GB a
+        # copy, and four of them were alive at the install's peak (my chip runs,
+        # PR 50). ``_split_prefix`` only needs a non-None sentinel
+        jax.block_until_ready((pk, pv))  # ... and is GONE before the next copy is made
+        self.prefix_kv = {}
         head = lambda planes, n: jax.tree.map(lambda a: a[:, :n], planes)
         if full:
             for g in range(self.dp):
@@ -1071,11 +1106,8 @@ class PagedDecodeEngine(DecodeEngine):
             for g in range(self.dp):
                 self.radix[g].pin_root_chain(self.prefix_ids[: full * bs],
                                              self._prefix_blocks[g])
-        # the dense (L, 1, P, nkv, hd) prefix KV now lives in the pool (full
-        # blocks per dp group) + self._prefix_tail (remainder); keeping the
-        # dense copy would hold the prefix in HBM twice for the engine's
-        # lifetime. _split_prefix only needs a non-None sentinel.
-        self.prefix_kv = {}
+        # the prefix KV now lives in the pool (full blocks per dp group) +
+        # self._prefix_tail (remainder)
         return P
 
     # ------------------------------------------------------------ admission
