@@ -302,15 +302,24 @@ def _chunk_program_shas(eng) -> list[str]:
 # 4096, which cannot bind, and its programs are the texts they were. Every other
 # pin here, in ``tests/test_older_programs_pinned.py`` and in ``tests/test_dots3.py``
 # holds as it was: with ``router_input`` and ``gate_act`` at their defaults nothing moved.
+# ISSUE 51 re-derived the hybrid's two and the "share" model's two, each taken on
+# its parent's tree (dc7f00a) first, where all four held: the programs whose
+# window binds carry one more count (``attn.window_common_row_blocks``: fifth of
+# ``sambay.HYBRID_STATS``, third of ``llama.WINDOW_STATS`` — the row-blocks the
+# block kernel's common RANGE took off the rows' walks), and the hybrid's
+# ``attn.common_query_rows`` adds the positions handed to that range. This
+# module's engines attend through XLA: the kernel's own text is in none of them.
+# The dense, routed and latent kinds' six, the grouped admissions, the one-row
+# prefills and blocks of all five hold UNEDITED.
 CHUNK_SHA256 = {
     "dense": ["bd6960a6e5413477e9c715d36602d0ea9ee6872a882bd60eeee5173252803bc9",
               "428563f0ebbff1c395cfa7e002d824eb6266d55bb320837ed9778deade59d0e6"],
     "routed": ["1e3bef4a09480e68c0881380fc66b3721d7ec6095ddb37b5bc63850318ca5120",
                "f93929afe0b3424a7145f32f7d88ebe5064b4df934dcd35faeeb4ecee781e9cd"],
-    "hybrid": ["0af0fa9ab62e74068db6bf412a61b12e2854e661fc22cedc9d0647e4f97d97fb",
-               "c336d19c2385d33baee94d0300f49fffc7627b95ca3d8804cbf35239af7a6c4e"],
-    "share": ["bb64f53a4bdf874160de1a554fb73ebdbb596a930f076f84778c3e07ffcf75b5",
-              "e32f24277eed082c8ac2bc6e9e9ed369f84216712bbe82d61f0a6e749d7a56f2"],
+    "hybrid": ["d8e6303aa7d3956d4cc9684598bde03842352655e7b5b051c6af74480c05ca3f",
+               "506e4a4ad1c7a114c16d1b8ca9efb7b6433140de317fac4113b25bc70eadb251"],
+    "share": ["aac966f3fc026f0629d67d21e3f8fd2623878382bc2c20b39fb9f5d724ab4274",
+              "1861ec8d987fb1270ee5e093b253a5c4c8c852328b1635ef728cec379c4b8c69"],
     "latent": ["f791d7cacf4007111684766731421a27f85c588c79b36fc151680f09da7e382f",
                "d4c10d336feec5790dcb611f3872a45da8640b5506463a3305d45664658a9f98"],
 }

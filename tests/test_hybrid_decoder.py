@@ -194,6 +194,48 @@ def test_the_window_in_the_block_kernel(first):
     assert int(split.n_common) == 0 and int(split.n_items) == int((np.asarray(pos).max(1) // BS - lo + 1).sum())
 
 
+def test_the_windowed_layers_ride_a_common_range_and_count_it():
+    """Three rows of a 1 + 8 block behind five blocks they all name, a window
+    of 40 over blocks of 16: block 4 lies whole inside every row's window, so
+    the windowed layers read it once for the three (ISSUE 51) — the logits are
+    the XLA masks', and the forward counts the range under its own name: what
+    the rows attend (``window_blocks_walked``) and ``attn.row_blocks`` are what
+    they were, ``attn.common_row_blocks`` stays the full and cross layers', and
+    ``attn.common_query_rows`` counts the positions handed to the range too."""
+    from tpu_voice_agent.ops import ATTN_STATS
+
+    cfg = dataclasses.replace(CFG, window=40)
+    params = init_params(cfg, jax.random.key(0))
+    head = [1, 2, 3, 4, 5]
+    tables = jnp.asarray([head + [6, 9, 0], head + [7, 10, 1], head + [8, 11, 2]], jnp.int32)
+    n_real = jnp.asarray([3, 1, 9], jnp.int32)
+    pos = jnp.asarray([84, 82, 88])[:, None] + jnp.minimum(jnp.arange(9)[None], n_real[:, None] - 1)
+    toks = jax.random.randint(jax.random.key(2), (3, 9), 0, cfg.vocab_size)
+
+    def run(impl):
+        kp, vp = pools(cfg, F32)
+        kp["kv"] = jax.random.normal(jax.random.key(5), kp["kv"].shape, F32)
+        vp["kv"] = jax.random.normal(jax.random.key(6), vp["kv"].shape, F32)
+        with jax.default_matmul_precision("highest"):
+            return forward_paged(params, cfg, toks, pos, kp, vp, tables, attn_impl=impl, n_real=n_real,
+                                 hybrid_stats=True, attn_stats=True)
+
+    kernel, masks = run("pallas"), run("xla")
+    real = np.asarray(jnp.arange(9)[None] < n_real[:, None])
+    assert rel(np.asarray(kernel[0])[real], np.asarray(masks[0])[real]) < 1e-4
+    planes, reads = cfg.n_front - 1, 1 + cfg.n_back  # windowed layers; the full and the cross ones
+    hybrid = dict(zip(sambay.HYBRID_STATS, np.asarray(kernel[5]).tolist()))
+    # a row's boundary block is 2 or 3 (82 - 39 = 43, 88 - 39 = 49), its last 5 or 6
+    assert hybrid["attn.window_blocks_walked"] == planes * (4 + 4 + 4)
+    assert hybrid["attn.window_blocks_held"] == planes * (6 + 6 + 7)
+    assert hybrid["attn.window_common_row_blocks"] == planes * 3 * 1
+    assert np.asarray(masks[5]).tolist()[-1] == 0  # another path rides nothing
+    attn = dict(zip(ATTN_STATS, np.asarray(kernel[6]).tolist()))
+    assert attn["common_row_blocks"] == reads * 3 * 5  # the five blocks, the full layers' alone
+    assert attn["row_blocks"] == reads * 19 + planes * 12
+    assert attn["common_query_rows"] == (reads + planes) * int(n_real.sum())
+
+
 # ---------------------------------------------------------------- the engine
 
 
@@ -322,6 +364,7 @@ def test_the_batcher_publishes_the_state_and_window_counters(engine):
     assert d["ssm.positions"] == d["scheduler.forward_rows"] * 9 * n_ssm
     assert d["ssm.positions_advanced"] == d["scheduler.tokens_generated"] * n_ssm  # a token, a position
     assert 0 < d["attn.window_blocks_walked"] < d["attn.window_blocks_held"]
+    assert d.get("attn.window_common_row_blocks", 0.0) == 0  # the XLA masks walk no block
     assert d["ssm.state_restores"] == 3
 
 

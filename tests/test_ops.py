@@ -178,6 +178,7 @@ def test_engines_refuse_interpreted_pallas_unless_the_cpu_was_asked_for(monkeypa
 # 16-token blocks; three rows behind a shared prefix of blocks 1, 2, 3 unless
 # the case says otherwise; T = 9 queries a row starting at ``pos``.
 _PREFIX = [1, 2, 3]
+_HEAD = [1, 2, 3, 4, 5, 6]  # a longer one, of which a window leaves a RANGE in common
 _TWO_PASS_CASES = {
     # name: (tables, first query position a row, live rows or None, S, riders)
     "all rows ride": ([_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0], _PREFIX + [7, 8, 0]],
@@ -268,8 +269,34 @@ _REAL_CASES = {
                           [30, 20, 40], None, [1, 0, 5], None),
     "every position real": ([_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0]], [60, 50], None,
                             [9, 9], None),
-    "behind a window": ([_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0], _PREFIX + [7, 8, 0]],
-                        [60, 50, 70], None, [2, 0, 9], 24),
+    # behind a WINDOW (ISSUE 51): a head of six blocks, the rows' queries at positions
+    # 98-120, so the whole blocks inside EVERY rider's window are a common range
+    "behind a window": ([_HEAD + [7, 8], _HEAD + [9, 0], _HEAD + [10, 11]],
+                        [100, 98, 104], None, [2, 0, 9], 40),
+    "a range of three blocks behind a window": (
+        [_HEAD + [7, 8], _HEAD + [9, 0], _HEAD + [10, 11], _HEAD + [18, 0]],
+        [100, 98, 104, 99], None, [0, 1, 2, 9], 72),
+    "every position real behind a window": (
+        [_HEAD + [7, 8], _HEAD + [9, 0], _HEAD + [10, 11]], [100, 98, 104], None, [9, 9, 9], 56),
+    "an idle row behind a window": (
+        [_HEAD + [7, 8], _HEAD + [9, 0], _HEAD + [10, 11], _HEAD + [18, 0]],
+        [100, 0, 104, 99], [True, False, True, True], [2, 4, 9, 1], 56),
+    "a row with another first block behind a window": (
+        [_HEAD + [7, 8], [12, 13, 14, 15, 16, 17, 9, 0], _HEAD + [10, 11], _HEAD + [18, 0]],
+        [100, 98, 104, 99], None, [3, 2, 1, 9], 56),
+    "a query inside the first block behind a window": (
+        [_HEAD + [7, 8], _HEAD + [9, 0], _HEAD + [10, 11]], [100, 5, 104], None, [2, 9, 1], 56),
+    "no whole block inside the windows": (
+        [_HEAD + [7, 8], _HEAD + [9, 0], _HEAD + [10, 11]], [100, 98, 104], None, [2, 1, 9], 24),
+    "fewer than half the rows agree behind a window": (
+        [_HEAD + [7, 8], [12, 13, 14, 15, 16, 17, 9, 0], _HEAD + [10, 11],
+         [19, 20, 21, 22, 23, 0, 18, 0], [0, 2, 3, 4, 5, 6, 1, 0]],
+        [100, 98, 104, 99, 97], None, [3, 2, 1, 9, 4], 56),
+    "one position a row behind a window": (  # T = 1: a windowed layer's every step
+        [_HEAD + [7, 8], _HEAD + [9, 0], _HEAD + [10, 11]], [100, 98, 104], None, [1, 1, 1], 40, 1),
+    "two groups of rows behind a window": (
+        [_HEAD + [7, 8], _HEAD + [9, 0], _HEAD + [10, 11], _HEAD + [18, 0], _HEAD + [19, 20],
+         _HEAD + [21, 0]], [100, 98, 104, 99, 111, 97], None, [1, 9, 0, 2, 3, 1], 56),
     # Command A+'s shape in small: the rows' state passes the kernel's budget,
     # so they go through in two groups of rows, each with a split of its own
     "two groups of rows": ([_PREFIX + [4, 5, 0], _PREFIX + [6, 0, 0], _PREFIX + [7, 8, 0],
@@ -277,7 +304,7 @@ _REAL_CASES = {
 }
 
 
-@pytest.mark.parametrize("group", [4, 1, 16])
+@pytest.mark.parametrize("group", [4, 1, 16, 7])
 @pytest.mark.parametrize("case", list(_REAL_CASES))
 def test_paged_block_attention_common_pass_takes_the_real_positions(case, group, monkeypatch):
     """``n_real`` packs the riders' real positions for the common pass and
@@ -286,7 +313,18 @@ def test_paged_block_attention_common_pass_takes_the_real_positions(case, group,
     which rows share its tile) and the plain reference's within tolerance; a
     position behind them returns its row's last real one's output; a row with
     none, or not live, returns zeros and disturbs nobody; ``common_query_rows``
-    counts the riders' real positions."""
+    counts the riders' real positions.
+
+    Behind a WINDOW the common pass is a RANGE of columns between a rider's
+    low walk and its high one (ISSUE 51), each row's blocks still in ascending
+    order: a real position's output is, bit for bit, that of the same call
+    with the SAME K/V laid under block ids of each row's own — nobody rides:
+    the one walk a row made before — and the masked dense reference's within
+    tolerance. (The scale is a power of two there: in interpret mode the CPU's
+    compiler contracts ``dot * scale - m`` into ONE fused multiply-add in the
+    pass that has no mask between the two, which rounds as the masked pass does
+    only where ``dot * scale`` is exact. The chip has no such contraction:
+    ``tools/block_attn_check.py --window`` holds the same there at 128 ** -0.5.)"""
     import sys
 
     from tpu_voice_agent.ops import (
@@ -296,9 +334,13 @@ def test_paged_block_attention_common_pass_takes_the_real_positions(case, group,
         row_group_splits,
     )
 
-    tables, pos, live, n_real, window = _REAL_CASES[case]
-    L, N, bs, T, nkv, hd = 2, 12, 16, 9, 2, 32
-    B = len(tables)
+    tables, pos, live, n_real, window, *rest = _REAL_CASES[case]
+    (T,) = rest or (9,)
+    L, bs, nkv, hd = 2, 16, 2, 32
+    B, M = np.shape(tables)
+    own_ids = 24 + np.arange(B * M).reshape(B, M)  # behind every id a case names
+    N = 12 if window is None else 24 + B * M
+    scale = None if window is None else 0.125
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     n_real = np.asarray(n_real, np.int32)
     t_of = np.minimum(np.arange(T)[None, :], np.maximum(n_real[:, None] - 1, 0))  # the copies
@@ -312,19 +354,21 @@ def test_paged_block_attention_common_pass_takes_the_real_positions(case, group,
     rows = (np.ones(B, bool) if live is None else np.asarray(live)) & (n_real > 0)
     real = (np.arange(T)[None, :] < n_real[:, None]) & rows[:, None]
 
-    if case == "two groups of rows":
+    if "two groups of rows" in case:
         mod = sys.modules["tpu_voice_agent.ops.paged_attention"]
-        two_rows = nkv * T * group * (2 * hd * 4 + 2 * 4 * hd + 2 * 4 * 128) * 2
-        monkeypatch.setattr(mod, "_STATE_BYTES", two_rows)
+        half = nkv * T * group * (2 * hd * 4 + 2 * 4 * hd + 2 * 4 * 128) * (B // 2)
+        monkeypatch.setattr(mod, "_STATE_BYTES", half)
         jax.clear_caches()
         shape = (B, T, nkv * group, nkv, hd)
-        made = lambda n: row_group_splits(shape, tables, q_pos, live, bs, itemsize=4, n_real=n)
+        made = lambda n, tables=tables: row_group_splits(shape, tables, q_pos, live, bs, window=window,
+                                                         itemsize=4, n_real=n)
         assert len(made(None)) == 2
     else:
-        made = lambda n: common_block_split(tables, q_pos, live, bs, window=window, n_real=n)
+        made = lambda n, tables=tables: common_block_split(tables, q_pos, live, bs, window=window,
+                                                           n_real=n)
     win = None if window is None else jnp.int32(window)
-    call = lambda n, split: np.asarray(paged_block_attention(
-        q, kp, vp, tables, q_pos, jnp.int32(1), live, split, win, n))
+    call = lambda n, split, kp=kp, vp=vp, tables=tables: np.asarray(paged_block_attention(
+        q, kp, vp, tables, q_pos, jnp.int32(1), live, split, win, n, scale=scale))
     whole = call(None, made(None))
     got = call(jnp.asarray(n_real), made(jnp.asarray(n_real)))
     if window is None:  # the split is the wrapper's own where the caller hands none
@@ -335,12 +379,140 @@ def test_paged_block_attention_common_pass_takes_the_real_positions(case, group,
     assert (got[~rows] == 0).all()
     if window is None:
         ref = np.asarray(paged_block_attention_reference(q, kp, vp, tables, q_pos, 1))
-        np.testing.assert_allclose(got[rows], ref[rows], rtol=1e-5, atol=1e-5)
-    splits = made(jnp.asarray(n_real))
-    splits = splits if isinstance(splits, tuple) and not hasattr(splits, "counts") else (splits,)
+    else:
+        # the XLA attention of the model that brought the window: a row's blocks gathered,
+        # every query against every key, masked to the query's last ``window`` positions
+        from tpu_voice_agent.models.sambay import _attend
+
+        kl, vl = (p[1][tables].reshape(B, M * bs, nkv, hd) for p in (kp, vp))
+        ref = np.asarray(_attend(q, kl, vl, q_pos, window, scale))
+    np.testing.assert_allclose(got[rows], ref[rows], rtol=1e-5, atol=1e-5)
+    as_tuple = lambda x: x if isinstance(x, tuple) and not hasattr(x, "counts") else (x,)
+    splits = as_tuple(made(jnp.asarray(n_real)))
     rides = np.concatenate([np.asarray(s.slot) < int(s.n_riders) for s in splits])
     counted = sum(int(s.counts[2]) for s in splits)
     assert counted == int(n_real[rides].sum())
     assert sum(int(s.counts[0]) for s in splits) == sum(int(s.n_common) * int(s.n_riders) for s in splits)
-    if case == "two groups of rows":
+    if window is not None:
+        # the SAME K/V under ids of each row's own: no two rows agree, nobody rides
+        flat = np.asarray(tables).reshape(-1)
+        kp2, vp2 = (p.at[:, own_ids.reshape(-1)].set(p[:, flat]) for p in (kp, vp))
+        alone = made(jnp.asarray(n_real), jnp.asarray(own_ids, jnp.int32))
+        assert all(int(s.n_riders) == 0 and int(s.n_common) == 0 for s in as_tuple(alone))
+        walked = call(jnp.asarray(n_real), alone, kp2, vp2, jnp.asarray(own_ids, jnp.int32))
+        np.testing.assert_array_equal(got[real], walked[real])
+        # a query in the first block, no whole block, too few riders: no range
+        ranged = "inside" not in case and "fewer" not in case
+        assert all((int(s.n_common) > 0) == ranged for s in splits)
+        assert all(int(s.n_items) == int(a.n_items) - int(s.counts[0]) + int(s.n_common)
+                   for s, a in zip(splits, as_tuple(alone)))
+    if "two groups of rows" in case:
         jax.clear_caches()  # the budget is read when the wrapper is traced
+
+
+# what ``common_block_split(window=...)`` must find in the windowed cases above:
+# (S0, S1, the rows that ride), or None where it is the split without a range
+_RANGE_WANT = {
+    "behind a window": (5, 6, [1, 1, 1]),
+    "a range of three blocks behind a window": (3, 6, [1, 1, 1, 1]),
+    "every position real behind a window": (4, 6, [1, 1, 1]),
+    "an idle row behind a window": (4, 6, [1, 0, 1, 1]),
+    "a row with another first block behind a window": (4, 6, [1, 0, 1, 1]),
+    "a query inside the first block behind a window": None,
+    "no whole block inside the windows": None,
+    "fewer than half the rows agree behind a window": None,
+    "one position a row behind a window": (5, 6, [1, 1, 1]),
+}
+
+
+@pytest.mark.parametrize("case", list(_RANGE_WANT))
+def test_common_block_split_finds_the_common_range_behind_a_window(case):
+    """The split behind a window (ISSUE 51), item for item: the riders' LOW
+    walks from each one's boundary block to ``S0`` (rows in order, every rider
+    with one item at least), the range's ``S1 - S0`` blocks once, then every
+    live row's other blocks — a rider's from ``S1``, another row's whole
+    window; without a range (no whole block inside every rider's window, a
+    query inside the first block, fewer than half the live rows agreeing) one
+    walk a live row from its boundary block, as before. ``counts``: the
+    row-blocks the range took off the walks (a caller's
+    ``window_common_row_blocks``), those live rows hold, the riders' real
+    positions."""
+    from tpu_voice_agent.ops import common_block_split
+
+    tables, pos, live, n_real, window, *rest = _REAL_CASES[case]
+    (T,) = rest or (9,)
+    bs, tables, n_real = 16, np.asarray(tables), np.asarray(n_real, np.int32)
+    B = len(tables)
+    q_pos = np.asarray(pos)[:, None] + np.minimum(np.arange(T)[None, :], np.maximum(n_real[:, None] - 1, 0))
+    alive = np.ones(B, bool) if live is None else np.asarray(live)
+    S0, S1, rides = _RANGE_WANT[case] or (0, 0, [0] * B)
+    rides = np.asarray(rides, bool)
+    first = np.maximum(q_pos.min(axis=1) - (window - 1), 0) // bs
+    last = q_pos.max(axis=1) // bs
+    low = [(b, j) for b in np.flatnonzero(rides) for j in range(first[b], S0)]
+    own = [(b, j) for b in np.flatnonzero(alive) for j in range(S1 if rides[b] else first[b], last[b] + 1)]
+    leader = int(np.flatnonzero(rides)[0]) if rides.any() else 0
+
+    split = common_block_split(jnp.asarray(tables), jnp.asarray(q_pos, jnp.int32),
+                               None if live is None else jnp.asarray(live), bs, window=window,
+                               n_real=jnp.asarray(n_real))
+    n, n_low = int(split.n_items), int(split.n_low)
+    assert (int(split.n_common), n_low, n) == (S1 - S0, len(low), len(low) + S1 - S0 + len(own))
+    assert (np.asarray(split.slot) < int(split.n_riders)).tolist() == rides.tolist()
+    item_rows, item_tiles, item_blocks = (np.asarray(x)[:n] for x in
+                                          (split.item_row, split.item_tile, split.item_block))
+    walks = low + own
+    in_walks = np.r_[:n_low, n_low + S1 - S0:n]
+    assert list(zip(item_rows[in_walks], item_tiles[in_walks])) == walks
+    assert item_blocks[in_walks].tolist() == [tables[b, j] for b, j in walks]
+    assert item_tiles[n_low:n_low + S1 - S0].tolist() == list(range(S0, S1))
+    assert item_blocks[n_low:n_low + S1 - S0].tolist() == tables[leader, S0:S1].tolist()
+    assert all(sum(b == r for b, _ in low) >= 1 for r in np.flatnonzero(rides))
+    assert np.asarray(split.attended).tolist() == alive.tolist()
+    assert np.asarray(split.counts).tolist() == [(S1 - S0) * rides.sum(), (last + 1)[alive].sum(),
+                                                 n_real[rides].sum()]
+    if rides.any():  # a rider's real positions, packed in row order
+        assert np.asarray(split.pack_n).tolist() == np.where(rides, n_real, 0).tolist()
+        assert np.asarray(split.pack_start)[rides].tolist() == (
+            np.cumsum(np.where(rides, n_real, 0)) - n_real)[rides].tolist()
+
+
+# sha256 of the text ``paged_block_attention`` lowers to WITHOUT a window
+# (interpret mode, scope names in, Python frames out), taken on ISSUE 51's
+# parent (dc7f00a): the range of ISSUE 51 lives behind the kernel's static
+# ``windowed`` and ``common_block_split``'s ``window``, and every program without
+# a binding window keeps the text — and the chip's compiled executable — it had.
+# A PR that changes the unwindowed kernel on purpose re-derives them on its
+# parent's tree first and says so.
+_UNWINDOWED_SHA256 = {
+    "a block told its real positions": "0badcddf7b591b122b6c0822b6d192a0ad81d06d8ad1432ff8b1c27bf84e576a",
+    "one position a row, every row live": "c72d4005d89f1566d4841216debf01c5dd0b3a0acee37e47c9f775e6f3fdba34",
+}
+
+
+@pytest.mark.parametrize("case", list(_UNWINDOWED_SHA256))
+def test_the_unwindowed_block_kernel_lowers_to_the_text_it_had(case):
+    import hashlib
+
+    from tpu_voice_agent.ops import paged_block_attention
+
+    told = case == "a block told its real positions"
+    B, T, nq, nkv, hd, M = (4, 9, 8, 2, 32, 6) if told else (4, 1, 4, 4, 32, 6)
+    S = jax.ShapeDtypeStruct
+    args = [S((B, T, nq, hd), jnp.bfloat16), S((2, 12, 16, nkv, hd), jnp.bfloat16),
+            S((2, 12, 16, nkv, hd), jnp.bfloat16), S((B, M), jnp.int32), S((B, T), jnp.int32),
+            S((), jnp.int32)]
+    if told:
+        fn = lambda q, kp, vp, bt, qp, layer, live, n_real: paged_block_attention(
+            q, kp, vp, bt, qp, layer, live, None, None, n_real, interpret=True)
+        args += [S((B,), jnp.bool_), S((B,), jnp.int32)]
+    else:
+        fn = lambda q, kp, vp, bt, qp, layer: paged_block_attention(q, kp, vp, bt, qp, layer,
+                                                                    interpret=True)
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+    assert hashlib.sha256(text.encode()).hexdigest() == _UNWINDOWED_SHA256[case]

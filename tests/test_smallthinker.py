@@ -136,8 +136,9 @@ def test_the_packed_block_is_the_whole_block_on_its_real_positions(params, n_rea
             assert rel(packed[0][b, :n], whole[0][b, :n]) < 1e-4
     np.testing.assert_array_equal(np.asarray(packed[7]), np.asarray(whole[7]))  # the window's walk
     live = sum(n > 0 for n in n_real)
-    # 6 sliding layers; a live row holds 4 blocks (positions 40-48) and walks 3 (from 40 - 16 = 24)
-    assert np.asarray(whole[7]).tolist() == [6 * 3 * live, 6 * 4 * live]
+    # 6 sliding layers; a live row holds 4 blocks (positions 40-48) and walks 3 (from 40 - 16 = 24);
+    # the two rows' tables name no block in common: no range, nothing taken off their walks
+    assert np.asarray(whole[7]).tolist() == [6 * 3 * live, 6 * 4 * live, 0]
 
 
 def test_the_routers_picks_are_a_softmax_over_the_chosen_logits_by_both_readings(params):
@@ -348,9 +349,11 @@ def test_the_engine_serves_it_behind_the_batcher_at_both_chunk_widths(monkeypatc
     many = batcher.generate_many(prompts)
     assert all(r.error is None for r in solo + many)
     assert {c.rows for c in chunks} == {2, 8}
-    assert all(set(c.counts) >= {"moe", "attn", "window"} and c.counts["window"].shape == (2,) for c in chunks)
+    assert all(set(c.counts) >= {"moe", "attn", "window"} and c.counts["window"].shape == (3,) for c in chunks)
     assert many[0].token_ids == solo[0].token_ids
     counters = fresh.snapshot()["counters"]
     walked, held = counters["attn.window_blocks_walked"], counters["attn.window_blocks_held"]
     assert 0 < walked < 0.4 * held  # a window of 129 behind ~1060 positions: 2-3 blocks of 9
+    # (a block and one position: no block lies WHOLE inside every rider's window, so no common range)
+    assert counters.get("attn.window_common_row_blocks", 0) == 0
     assert counters["moe.assigned_rows"] > 0 and counters["attn.common_row_blocks"] > 0

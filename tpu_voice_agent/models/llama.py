@@ -602,9 +602,11 @@ MOE_SHARE_STATS = MOE_STATS + ("local_rows",)
 
 # what a forward of a model whose window BINDS counts beside ``ops.ATTN_STATS``
 # (published as ``attn.<name>``, as ``models.sambay`` publishes its own): the
-# row-blocks its windowed layers' walks read, and those the same rows hold up to
-# their frontier, each summed over the windowed layers
-WINDOW_STATS = ("window_blocks_walked", "window_blocks_held")
+# row-blocks inside its rows' windows (what a row attends in a windowed layer,
+# in a walk of its own or through the common range), those the same rows hold up
+# to their frontier, and those the common range took off the rows' own walks
+# (riders x the range's blocks), each summed over the windowed layers
+WINDOW_STATS = ("window_blocks_walked", "window_blocks_held", "window_common_row_blocks")
 
 
 def moe_stat_names(cfg) -> tuple[str, ...]:
@@ -1306,7 +1308,7 @@ def forward_paged(
     latent_stats: bool = False,  # a latent model only: also ``mla.LATENT_STATS``,
     # (2,) int32, after the attention row-blocks
     window_stats: bool = False,  # a model whose window BINDS only (``bound_window``): also
-    # ``WINDOW_STATS``, (2,) int32, after the attention row-blocks
+    # ``WINDOW_STATS``, (3,) int32, after the attention row-blocks
 ):
     """The paged twin of ``forward`` (parity-tested): sequences own
     non-contiguous pool blocks via per-row block tables (SURVEY.md §7
@@ -1684,16 +1686,21 @@ def forward_paged(
         # layers behind a window that binds read other blocks: every layer's read.
         # Layers of one kind read the same ones: the row-blocks of one read (what
         # ``benchmark/lib/peaks.py`` builds its floors on), the query positions of all
-        extra += (sum(stats_of(split if w is None else win_split.get(w)) for _, w in kinds)
+        # (a windowed layer's common RANGE is ``WINDOW_STATS``', not ``common_row_blocks``)
+        behind = jnp.array([0, 1, 1], jnp.int32)
+        extra += (sum(stats_of(split) if w is None else behind * stats_of(win_split.get(w))
+                      for _, w in kinds)
                   if windows else stats_of(split, reads=cfg.n_layers),)
     if window_stats:
-        # what the layers behind a window walk of what their rows hold (the block
-        # kernel's own items; another path walks no block: zeros)
-        walked = held = jnp.zeros((), jnp.int32)
+        # what the layers behind a window attend of what their rows hold (the block
+        # kernel's items, a range's block once for each of its riders; another path
+        # walks no block: zeros)
+        walked = held = ranged = jnp.zeros((), jnp.int32)
         for _, w in kinds:
             for sp in win_split.get(w, ()):
-                walked, held = walked + sp.n_items - sp.n_common, held + sp.counts[1]
-        extra += (jnp.stack([walked, held]).astype(jnp.int32),)
+                walked = walked + sp.n_items - sp.n_common + sp.counts[0]
+                held, ranged = held + sp.counts[1], ranged + sp.counts[0]
+        extra += (jnp.stack([walked, held, ranged]).astype(jnp.int32),)
     if pack is not None:
         extra += (pack.stats,)
     return (logits, k_pool, v_pool, k_scale, v_scale, *extra)

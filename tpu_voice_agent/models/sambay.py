@@ -72,7 +72,8 @@ _NO_WINDOW = 1 << 30
 # what a hybrid forward counts (summed over layers by the forward, over
 # forwards by the chunk loop; ``scheduler`` publishes each under its name)
 HYBRID_STATS = ("ssm.positions_advanced", "ssm.positions",
-                "attn.window_blocks_walked", "attn.window_blocks_held")
+                "attn.window_blocks_walked", "attn.window_blocks_held",
+                "attn.window_common_row_blocks")
 
 
 class StateNotCarried(ValueError):
@@ -376,7 +377,7 @@ def forward_paged(params, cfg: SambaYConfig, tokens, positions, k_pool, v_pool, 
     max_blocks + 1) with the state index last. ``logit_pos`` (B,): the head
     runs on that one position of each row, logits (B, 1, V) — the chunk
     program reads one row of a 1 + W block, and the head is 200 064 wide.
-    -> (logits, k_pool, v_pool, None, None), then ``HYBRID_STATS`` (4,) with
+    -> (logits, k_pool, v_pool, None, None), then ``HYBRID_STATS`` (5,) with
     ``hybrid_stats``, then ``ops.ATTN_STATS`` summed over the attention
     layers with ``attn_stats``.
 
@@ -500,15 +501,22 @@ def forward_paged(params, cfg: SambaYConfig, tokens, positions, k_pool, v_pool, 
         else:
             logits = jnp.einsum("btd,vd->btv", x, params["embed"], preferred_element_type=F32)
     extra = ()
+    # what the two splits' common passes took (row-blocks, positions handed): the full and the
+    # cross layers' leading blocks; the windowed layers' range, off the rows' own walks
+    nothing = (jnp.int32(0),) * 2
+    common, handed = split_full.counts[::2] if block_decode else nothing
+    ranged, win_handed = split_win.counts[::2] if block_decode else nothing
     if hybrid_stats:
         extra += (jnp.stack([cfg.n_front * jnp.sum(n_real), jnp.int32(cfg.n_front * B * T),
-                             full_plane * walked, full_plane * held]).astype(jnp.int32),)
+                             full_plane * walked, full_plane * held,
+                             full_plane * ranged]).astype(jnp.int32),)
     if attn_stats:
         # row-blocks over ALL attention reads of the forward: the windowed
-        # layers walk theirs alone, the full layer and the cross layers ride
-        # the common pass where the split has one
-        common, handed = split_full.counts[::2] if block_decode else (jnp.int32(0),) * 2
+        # layers attend what lies inside their rows' windows (a common range of it
+        # once for its riders: ``HYBRID_STATS``' to count), the full layer and the
+        # cross layers ride the common pass where the split has one; the positions
+        # handed to a common pass are every read's
         n_full = 1 + cfg.n_back
         extra += (jnp.stack([n_full * common, n_full * held + full_plane * walked,
-                             n_full * handed]).astype(jnp.int32),)
+                             n_full * handed + full_plane * win_handed]).astype(jnp.int32),)
     return (logits, {"kv": kp, "conv": conv}, {"kv": vp, "ssm": ssm}, None, None, *extra)

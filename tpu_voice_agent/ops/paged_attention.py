@@ -570,12 +570,35 @@ def paged_attention_reference(
 # attended to exactly that before, and the next layer's two scatters there
 # must hold one value. A live row without a real position returns zeros.
 #
+# Behind a WINDOW (a sliding layer: ``window``, the kernel's static
+# ``windowed``) the blocks most rows hold in common lie partly BEFORE the rows'
+# windows, and a row's boundary block is cut by its own: what the riders can
+# read once is a RANGE of columns [S0, S1) — from the first block that every
+# query of every rider sees whole (``j * bs > max(qpos) - window``, and past every
+# rider's boundary block) to the unwindowed pass's end (ISSUE 51). The item list
+# then has three runs and the kernel three phases, each row's blocks still
+# visited in ASCENDING order with the one walk's arithmetic: the riders' LOW
+# walks, (row, column) from a row's boundary block to S0, masked by the window
+# per query position, state from nothing — at a rider's last low item its block
+# of state goes WHOLE to its packed place, as its queries went (rows in order,
+# the next rider's overwrites the tail); the range's blocks, the common pass's
+# body over the packed real positions, going on from that state; then every live
+# row's other blocks — a rider's from S1, carried as above, another row's whole
+# window in one walk. Without a range (``S1 <= S0``, too few riders) that last
+# run is all there is: one walk a live row, from the block that holds the first
+# position any of its queries sees. 32 rows at ~8300 behind one 8192-token head
+# and a window of 4096 walk ~33 blocks a row of which 30 are the range: 1056
+# items become 127, 1.33 ms a call 0.33 (my chip run, PR 51); a range of ONE
+# block still pays at 32 rows and at 8 (``tools/block_attn_check.py --window``).
+#
 # Operands: q, k, v and p go to both dots cast up to float32, as the one-pass
 # walk cast them: on the chip a float32 dot at the default precision is one
 # bf16 pass of the MXU, so that is what the pool's bf16 values cost, and bf16
 # operands measured 5-12 % SLOWER here (my chip runs, PR 31). With the order
-# unchanged the outputs equal the one-pass kernel's bit for bit, on the chip
-# and in interpret mode alike.
+# unchanged the outputs equal the one-pass kernel's bit for bit on the chip
+# (``tools/block_attn_check.py``). In interpret mode they do where ``scale`` is
+# a power of two: XLA's CPU compiler contracts ``dot * scale - m`` into one fused
+# multiply-add in the pass that has no mask between the two (PR 51).
 
 
 class BlockSplit(NamedTuple):
@@ -583,6 +606,7 @@ class BlockSplit(NamedTuple):
     it: tables do not move inside a forward, positions do between them)."""
 
     n_common: jax.Array  # () int32 — S, leading table columns of the common pass
+    # (behind a window: the columns of its range, ``S1 - S0``)
     n_items: jax.Array  # () int32 — S + the own pass's (row, tile) pairs
     n_riders: jax.Array  # () int32
     item_block: jax.Array  # (max_blocks + B*max_blocks,) int32 — pool block
@@ -600,6 +624,9 @@ class BlockSplit(NamedTuple):
     # positions, 0 for a row that does not ride (``counts[2]`` in all)
     n_real: jax.Array  # (B,) int32 — every row's real positions (T where the
     # caller names none): a position behind them returns the last one's output
+    n_low: jax.Array | None = None  # () int32 — behind a window: the riders' LOW
+    # items, before the common blocks (which are then a RANGE of columns,
+    # ``n_common`` of them from ``item_tile[n_low]``); None without a window
 
 
 # what a block forward counts (``forward_paged(attn_stats=True)``; the chunk
@@ -607,6 +634,32 @@ class BlockSplit(NamedTuple):
 # the common pass took, row-blocks live rows attend in all, and the query
 # positions the common pass was handed (each summed over a forward's reads)
 ATTN_STATS = ("common_row_blocks", "row_blocks", "common_query_rows")
+
+
+def _riders(bt: jax.Array, live: jax.Array):
+    """-> (the leader's row of the tables, the live rows that hold its first
+    block, the columns on which every one of them holds the leader's id): the
+    leader is the lowest row of the largest set of live rows that agree on
+    their first block."""
+    agree = (bt[:, :1] == bt[:, 0][None, :]) & live[:, None] & live[None, :]
+    leader = jnp.argmax(jnp.sum(agree, axis=1))
+    lead = bt[leader]
+    cand = agree[leader]  # none when nothing is live
+    same = jnp.all((bt == lead[None, :]) | ~cand[:, None], axis=0)  # (M,)
+    return lead, cand, same
+
+
+def _riders_places(rides: jax.Array, n_real: jax.Array | None, T: int):
+    """-> (``order``, ``slot``: the rows with the riders first, and its inverse;
+    every row's real positions; the riders' — 0 for a row that does not ride —
+    and where they end, packed in row order: a row's slots start where the rows
+    before it end)."""
+    B = rides.shape[0]
+    order = jnp.argsort(~rides, stable=True).astype(jnp.int32)
+    slot = jnp.zeros((B,), jnp.int32).at[order].set(jnp.arange(B, dtype=jnp.int32))
+    n_real = jnp.full((B,), T, jnp.int32) if n_real is None else jnp.clip(n_real.astype(jnp.int32), 0, T)
+    packed = jnp.where(rides, n_real, 0)
+    return order, slot, n_real, packed, jnp.cumsum(packed)
 
 
 def common_block_split(
@@ -631,34 +684,27 @@ def common_block_split(
     this forward writes (a row writes at its query positions, all of them at
     or past S*bs).
 
-    Under a ``window`` no row rides (a block most rows hold in common lies
-    before most rows' windows, or is cut by one: the common pass is the full
-    layers') and a row's walk starts at the block that holds the first
-    position any of its queries sees.
+    Under a ``window`` a row's walk starts at the block that holds the first
+    position any of its queries sees, and what the riders read once is a RANGE
+    of columns (``_window_block_split``).
 
     ``n_real`` moves no item: it says which of the riders' positions the
     common pass is handed (``pack_start`` / ``pack_n``; every one has a slot,
     so nothing has to fit)."""
+    if window is not None:
+        return _window_block_split(block_tables, q_positions, live, bs, window, n_real)
     bt = block_tables.astype(jnp.int32)
     B, M = bt.shape
     qp = q_positions.astype(jnp.int32)
     T = qp.shape[1]
     live = jnp.ones((B,), bool) if live is None else live.astype(bool)
-    agree = (bt[:, :1] == bt[:, 0][None, :]) & live[:, None] & live[None, :]
-    leader = jnp.argmax(jnp.sum(agree, axis=1))
-    lead = bt[leader]
-    cand = agree[leader]  # none when nothing is live
-    same = jnp.all((bt == lead[None, :]) | ~cand[:, None], axis=0)  # (M,)
+    lead, cand, same = _riders(bt, live)
     s_agree = jnp.sum(jnp.cumprod(same.astype(jnp.int32)))
     s_pos = jnp.min(jnp.where(cand, jnp.min(qp, axis=1) // bs, M))
     S = jnp.where(2 * jnp.sum(cand) >= jnp.maximum(jnp.sum(live), 1),
                   jnp.minimum(s_agree, s_pos), 0)
-    if window is not None:
-        S = jnp.zeros_like(S)
     rides = cand & (S > 0)
     first = jnp.where(rides, S, 0)
-    if window is not None:
-        first = jnp.maximum(jnp.min(qp, axis=1) - (window - 1), 0) // bs
     last = jnp.minimum(jnp.max(qp, axis=1) // bs, M - 1)
     n = jnp.where(live, last - first + 1, 0)
     ends = jnp.cumsum(n)
@@ -668,18 +714,75 @@ def common_block_split(
     rows = jnp.clip(jnp.sum(own[:, None] >= ends[None, :], axis=1), 0, B - 1)
     tiles = jnp.clip(first[rows] + own - (ends - n)[rows], 0, M - 1)
     blocks = jnp.where(own < 0, lead[jnp.minimum(w, M - 1)], bt[rows, tiles])
-    order = jnp.argsort(~rides, stable=True).astype(jnp.int32)
-    slot = jnp.zeros((B,), jnp.int32).at[order].set(jnp.arange(B, dtype=jnp.int32))
-    # the riders' real positions, packed: a row's slots start where the rows
-    # before it end
-    n_real = jnp.full((B,), T, jnp.int32) if n_real is None else jnp.clip(n_real.astype(jnp.int32), 0, T)
-    packed = jnp.where(rides, n_real, 0)
-    p_ends = jnp.cumsum(packed)
+    order, slot, n_real, packed, p_ends = _riders_places(rides, n_real, T)
     counts = jnp.stack([S * jnp.sum(rides), jnp.sum(jnp.where(live, last + 1, 0)), p_ends[-1]])
     i32 = lambda x: x.astype(jnp.int32)
     return BlockSplit(i32(S), i32(S + ends[-1]), i32(jnp.sum(rides)), i32(blocks),
                       i32(rows), i32(tiles), slot, order, n > 0, i32(counts),
                       i32(p_ends - packed), i32(packed), n_real)
+
+
+def _window_block_split(block_tables, q_positions, live, bs: int, window: int, n_real) -> BlockSplit:
+    """``common_block_split`` behind a ``window``: what the riders read once is
+    a RANGE ``[S0, S1)`` of table columns.
+
+    The riders are the unwindowed split's, and so is ``S1``: the leading
+    columns on which every rider holds the leader's id and that end at or
+    before the smallest query position of any rider. ``S0`` is the first
+    column whose block every query of every rider sees WHOLE —
+    ``j * bs > max(qpos) - window`` over the riders — and that lies past every
+    rider's boundary block (the one that holds the first position any of its
+    queries sees), so a rider's state always starts in a walk of its own.
+    Where ``S1 <= S0`` nobody rides. Items: the riders' LOW walks, (row,
+    column) for the columns from a row's boundary block to ``S0``, rows in
+    order; the range's blocks, once; then every live row's other blocks, rows
+    in order — a rider's from ``S1`` to its last query's, another row's whole
+    window. ``n_common`` is ``S1 - S0``, ``n_low`` the items before the
+    range, ``item_tile[n_low]`` is ``S0``, and ``counts[0]`` the row-blocks
+    the range took off the riders' walks (no full layer's: a caller publishes
+    them under another name than ``ATTN_STATS[0]``)."""
+    bt = block_tables.astype(jnp.int32)
+    B, M = bt.shape
+    qp = q_positions.astype(jnp.int32)
+    T = qp.shape[1]
+    live = jnp.ones((B,), bool) if live is None else live.astype(bool)
+    lead, cand, same = _riders(bt, live)
+    qmin, qmax = jnp.min(qp, axis=1), jnp.max(qp, axis=1)
+    first = jnp.maximum(qmin - (window - 1), 0) // bs  # a row's boundary block
+    last = jnp.minimum(qmax // bs, M - 1)
+    S1 = jnp.minimum(jnp.sum(jnp.cumprod(same.astype(jnp.int32))),
+                     jnp.min(jnp.where(cand, qmin // bs, M)))
+    whole = -(-jnp.maximum(jnp.max(jnp.where(cand, qmax, 0)) - window + 1, 0) // bs)
+    S0 = jnp.maximum(whole, jnp.max(jnp.where(cand, first + 1, 0)))
+    taken = (2 * jnp.sum(cand) >= jnp.maximum(jnp.sum(live), 1)) & (S1 > S0)
+    R = jnp.where(taken, S1 - S0, 0)
+    rides = cand & taken
+    n_low = jnp.where(rides, S0 - first, 0)
+    high = jnp.where(rides, S1, first)
+    n_high = jnp.where(live, last - high + 1, 0)
+    low_ends, high_ends = jnp.cumsum(n_low), jnp.cumsum(n_high)
+    L = low_ends[-1]
+    w = jnp.arange(M + B * M, dtype=jnp.int32)
+
+    def run(at, ends, n, start):  # the (row, column) of item ``at`` of a run of walks, rows in order
+        rows = jnp.clip(jnp.sum(at[:, None] >= ends[None, :], axis=1), 0, B - 1)
+        return rows, jnp.clip(start[rows] + at - (ends - n)[rows], 0, M - 1)
+
+    low_rows, low_tiles = run(w, low_ends, n_low, first)
+    # a range item names the row of the first item behind the range: its output
+    # block is the one the pipeline holds next
+    high_rows, high_tiles = run(jnp.maximum(w - L - R, 0), high_ends, n_high, high)
+    in_low, in_range = w < L, (w >= L) & (w < L + R)
+    rows = jnp.where(in_low, low_rows, high_rows)
+    tiles = jnp.where(in_low, low_tiles,
+                      jnp.where(in_range, jnp.minimum(S0 + w - L, M - 1), high_tiles))
+    blocks = jnp.where(in_range, lead[tiles], bt[rows, tiles])
+    order, slot, n_real, packed, p_ends = _riders_places(rides, n_real, T)
+    counts = jnp.stack([R * jnp.sum(rides), jnp.sum(jnp.where(live, last + 1, 0)), p_ends[-1]])
+    i32 = lambda x: x.astype(jnp.int32)
+    return BlockSplit(i32(R), i32(L + R + high_ends[-1]), i32(jnp.sum(rides)), i32(blocks),
+                      i32(rows), i32(tiles), slot, order, n_high > 0, i32(counts),
+                      i32(p_ends - packed), i32(packed), n_real, i32(L))
 
 
 def _softmax_tile(q, k, v, valid, m_prev, l_prev, acc_prev, scale: float):
@@ -702,7 +805,8 @@ def _softmax_tile(q, k, v, valid, m_prev, l_prev, acc_prev, scale: float):
 def _paged_block_kernel(
     qpos_ref,  # SMEM (B*T,)
     meta_ref,  # SMEM (4,): [layer, S, items, packed sub-chunks that hold a query row]
-    # (``windowed``: (5,), then the window — a query sees that many positions)
+    # (``windowed``: (6,), then the window — a query sees that many positions — and
+    # the riders' low items, which stand before the S common blocks)
     block_ref,  # SMEM (max_blocks + B*max_blocks,): each item's pool block ...
     row_ref,  # ... row ...
     tile_ref,  # ... and table column
@@ -736,6 +840,11 @@ def _paged_block_kernel(
     w = pl.program_id(0)
     S, n, n_sub = meta_ref[1], meta_ref[2], meta_ref[3]
     hd = acc_ref.shape[2]
+    if windowed:
+        # the common blocks are a RANGE of columns, items [lo, hi): the riders'
+        # low walks stand before it, every live row's other blocks behind it
+        lo = meta_ref[5]
+        hi = lo + S
 
     def advance(q, k, v, valid, refs, h, at):  # one head's rows ``at`` over one block
         acc, m, l = refs
@@ -775,7 +884,7 @@ def _paged_block_kernel(
 
         jax.lax.fori_loop(0, slot_ref.shape[0], pack, 0)
 
-    @pl.when(w < S)
+    @pl.when(jnp.logical_and(w >= lo, w < hi) if windowed else w < S)
     def _common():  # every rider sees the whole block: no mask
         # the block to float32 WHOLE, once: a head is then a strided read of
         # 32-bit sublanes, where picking it out of the packed bf16 pairs costs
@@ -791,11 +900,18 @@ def _paged_block_kernel(
 
         jax.lax.fori_loop(0, n_sub, riders, 0)
 
-    @pl.when(jnp.logical_and(w >= S, w < n))
+    @pl.when(jnp.logical_and(jnp.logical_or(w < lo, w >= hi), w < n) if windowed
+             else jnp.logical_and(w >= S, w < n))
     def _own():
         b, j = row_ref[w], tile_ref[w]
-        first = jnp.logical_or(w == S, row_ref[jnp.maximum(w - 1, 0)] != b)
-        last = jnp.logical_or(w == n - 1, row_ref[jnp.minimum(w + 1, row_ref.shape[0] - 1)] != b)
+        row_was = lambda: row_ref[jnp.maximum(w - 1, 0)] != b
+        row_next = lambda: row_ref[jnp.minimum(w + 1, row_ref.shape[0] - 1)] != b
+        if windowed:  # a rider walks twice: up to the range, and on from behind it
+            first = (w == 0) | (w == hi) | row_was()
+            last = (w == lo - 1) | (w == n - 1) | row_next()
+        else:
+            first = jnp.logical_or(w == S, row_was())
+            last = jnp.logical_or(w == n - 1, row_next())
 
         @pl.when(first)
         def _row_start():
@@ -803,6 +919,8 @@ def _paged_block_kernel(
             # every other query row from nothing: its own tiles alone (a value
             # nobody reads, but one that is a number)
             carried = jax.lax.broadcasted_iota(jnp.int32, (Rp, 1), 0) < real_ref[b]
+            if windowed:  # a low walk starts from nothing, as the row's one walk did
+                carried = jnp.logical_and(carried, w >= hi)
             was = pl.ds(pack_ref[b], Rp)
             for h in range(nkv):
                 acc_ref[h] = jnp.where(carried, cacc_ref[h, was, :], 0.0)
@@ -822,7 +940,19 @@ def _paged_block_kernel(
             advance(q_ref[h, queries(b), :], k_ref[0, 0, :, h].astype(jnp.float32),
                     v_ref[0, 0, :, h].astype(jnp.float32), valid, own, h, slice(None))
 
-        @pl.when(last)
+        if windowed:
+            @pl.when(last & (w < lo) & (real_ref[b] > 0))
+            def _to_the_range():
+                # a rider's low walk ends: its state goes to its packed place WHOLE,
+                # as its queries went (``_riders_start``): the rows behind its real
+                # ones are the next rider's place, whose low walk ends after this one
+                was = pl.ds(pack_ref[b], Rp)
+                for h in range(nkv):
+                    cacc_ref[h, was, :] = acc_ref[h]
+                    cm_ref[h, was, :] = m_ref[h]
+                    cl_ref[h, was, :] = l_ref[h]
+
+        @pl.when(jnp.logical_and(last, w >= lo) if windowed else last)
         def _row_finish():
             n_pos = nreal_ref[b]
             for h in range(nkv):
@@ -1009,7 +1139,8 @@ def paged_block_attention(
     )(q_positions.astype(jnp.int32).reshape(-1),
       jnp.stack([jnp.reshape(layer, ()).astype(jnp.int32), split.n_common, split.n_items,
                  -(-split.counts[2] * group // sub),
-                 *(() if window is None else (jnp.reshape(window, ()).astype(jnp.int32),))]),
+                 *(() if window is None else (jnp.reshape(window, ()).astype(jnp.int32),
+                                              split.n_low))]),
       split.item_block, split.item_row, split.item_tile, split.slot,
       split.pack_start * group, split.pack_n * group, split.n_real,
       qg, k_pool, v_pool)
