@@ -15,6 +15,7 @@ import json
 import pathlib
 import subprocess
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -178,12 +179,16 @@ def test_cpu_clocks_tile_as_the_wall_does():
     """``cpu_ms`` and ``others_cpu_ms`` are read on the boundaries the wall is
     read on and tiled the same way: a nested staged span's CPU is its own
     stage's and is carved out of its parent ONCE, and a thread cannot burn more
-    CPU than wall (the three clocks are read in turn: half a millisecond)."""
+    CPU than wall. Held as IDENTITIES and orders, with a tolerance that is a
+    share of the step's own wall (ISSUE 52): how much CPU a spinning thread is
+    GIVEN on a shared box is the box's to say — milliseconds against a constant
+    went red in the driver's runs (ROADMAP D7)."""
     import time
 
     from tpu_voice_agent.utils.steplog import PREFILL_STAGE_SPAN, span
 
     log = StepLog(max_steps=8, enabled=True)
+    own0 = time.thread_time_ns()
     t = log.timer()
     t.stage("sched.admit")
     _spin(0.02)
@@ -194,17 +199,28 @@ def test_cpu_clocks_tile_as_the_wall_does():
     t.stage("sched.readback")
     _spin(0.01)
     rec = t.finish()
+    own = (time.thread_time_ns() - own0) / 1e6  # what this thread burned around the whole step
     st, cpu, others = rec["stages"], rec["cpu_ms"], rec["others_cpu_ms"]
+    wall = rec["wall_ms"]
     assert set(st) == set(cpu) == set(others) == {"admit", "prefill", "decode", "readback"}
-    assert sum(cpu.values()) <= rec["wall_ms"] + 0.5
+    # the wall tiles exactly (rounding alone), whatever the machine did to the thread
+    assert abs(sum(st.values()) - wall) <= 5e-4 * (len(st) + 1)
+    # a spin never ends before its time, a sleep neither: the stages hold them
+    assert st["admit"] >= 19.9 and st["prefill"] >= 29.9 and st["decode"] >= 19.9 and st["readback"] >= 9.9
+    # a thread burns no more CPU than wall, in any stage and in all (the three
+    # clocks are read in turn: a hundredth of the step between them)
+    tol = 0.01 * wall
+    assert sum(cpu.values()) <= wall + tol
     for k in st:
-        assert cpu[k] <= st[k] + 0.5, (k, cpu, st)
-    # carved once: the 30 ms of the nested span are ``prefill`` on every clock
-    # and NOT ``admit`` (uncarved it would read 50: the margin is for a thread
-    # the machine ran late, which only lowers its CPU)
-    assert 19.9 <= st["admit"] and cpu["admit"] <= st["admit"] + 0.5 < 45.0
-    assert cpu["prefill"] <= st["prefill"] + 0.5 and cpu["prefill"] >= 15.0
-    assert cpu["decode"] <= 5.0  # asleep: no CPU of its own
+        assert cpu[k] <= st[k] + tol, (k, cpu, st)
+    # carved ONCE: the nested span's CPU is ``prefill``'s and not also
+    # ``admit``'s — counted twice, the stages would sum past what the thread
+    # burned around the whole step (its timer's own work included), and a
+    # stage left out would fall short of it by that stage
+    assert sum(cpu.values()) <= own + tol
+    assert sum(cpu.values()) >= own - tol - 0.5 * min(cpu["admit"], cpu["prefill"])
+    # orders: the stage that slept burned the least, and a small share of its wall
+    assert cpu["decode"] < min(cpu["admit"], cpu["prefill"]) and cpu["decode"] <= 0.25 * st["decode"]
     assert EVERY_RECORD <= set(rec)
 
 
@@ -241,16 +257,24 @@ def test_off_cpu_and_others_cpu_tell_three_causes_apart(held_by):
             other.join(timeout=10)
     assert not other.is_alive()
     wall, cpu, others = rec["stages"]["admit"], rec["cpu_ms"]["admit"], rec["others_cpu_ms"]["admit"]
+    # Time off the CPU that the MACHINE explains — the thread runnable and not
+    # run (``run_delay_ms``, ISSUE 52) — is none of the three causes: on a
+    # shared box a spinning thread is given what is left, and that went red as
+    # "its own work off the CPU" in the driver's runs (ROADMAP D7). It is taken
+    # off only where it can EXCUSE — the share that must be small, the loss
+    # another thread's CPU must answer for — never off a share that must be
+    # large (a thread woken for the interpreter waits for a CPU too)
     off = wall - cpu
+    delay = rec.get("run_delay_ms", 0.0)
     if held_by == "another_thread":
         # two threads share one interpreter: each runs about half the time (a
         # loaded machine gives both less; the other thread's share stays a
-        # third of what this one lost or more)
-        assert off >= 0.2 * wall and others >= 0.3 * off, rec
+        # third of what this one lost TO IT or more)
+        assert off >= 0.2 * wall and others >= 0.3 * (off - delay), rec
     elif held_by == "a_sleep":
         assert off >= 0.9 * wall and others <= 0.15 * off, rec
     else:
-        assert off <= 0.35 * wall and others <= 0.35 * wall, rec
+        assert off - delay <= 0.35 * wall and others <= 0.35 * wall, rec
 
 
 @pytest.mark.parametrize("where", ["another_thread", "own_thread", "the_gap"])
@@ -557,8 +581,16 @@ def test_steplog_off_is_token_identical(scope_engine):
     # nothing happened), tiled as the stages are; the one that was off nothing
     steps = log.steps()
     assert steps and all(s["seq"] < len(steps) for s in steps)
+    from tpu_voice_agent.utils import machine
+
+    has = {k for k, there in machine.counters().sources().items() if there}
     for rec in steps:
         assert EVERY_RECORD <= set(rec), EVERY_RECORD - set(rec)
+        # the machine's side (ISSUE 52): each counter the machine has, and no key
+        # for one it lacks
+        assert "stall_dump_n" not in rec and "stall" not in rec  # no watchdog, no sampler armed
+        assert set(machine.MACHINE_KEYS) & set(rec) == has
+        assert all(rec[k] is not None and rec[k] >= 0 for k in has)
         assert set(rec["cpu_ms"]) == set(rec["others_cpu_ms"]) == set(rec["stages"])
         assert sum(rec["cpu_ms"].values()) <= rec["wall_ms"] + 0.5
         if rec.get("forwards"):
@@ -610,8 +642,14 @@ def test_admissions_tile_their_request_and_count_admitted(scope_engine):
     # ``prefill_ms`` is timed INSIDE the stage's span: the stage holds it, and
     # it is most of the stage (0.975-0.9999 of it in 12 runs beside this suite
     # under six workers, where "within 5 %" either way failed)
+    # — of the stage the thread was RUN for: on a loaded box a thread taken off
+    # the CPU between the span's clock and the engine's own stretched one
+    # step's stage to three times its call (PR 52's runs), so the time the
+    # machine kept the thread runnable and not run is taken off the stage
+    # (``run_delay_ms``, the machine's own word for it: nothing where it has none)
     staged = sum(s["stages"].get("prefill", 0.0) for s in steps)
-    assert 0.8 * staged <= sum(r.prefill_ms for r in res) <= staged + 1e-3
+    kept_waiting = sum(s.get("run_delay_ms", 0.0) for s in steps if s.get("admissions"))
+    assert 0.8 * (staged - kept_waiting) <= sum(r.prefill_ms for r in res) <= staged + 1e-3
     for a in adm:
         assert {f"{p}_ms" for p in ADMISSION_PARTS if p != "bookkeeping"} <= set(a)
         assert a["prompt_tokens"] > 0 and a["cached_tokens"] == 0 and a["rid"] >= 0
@@ -738,21 +776,35 @@ def test_queue_wait_grows_when_slots_are_busy(scope_engine):
     m = get_metrics()
     s0, n0 = m.counter_state()[1].get("scheduler.queue_wait", (0.0, 0))
     bat = _batcher(scope_engine)  # two slots
+    import time
+
+    t_submit = time.time_ns()
     res = bat.generate_many(["turn on the lights", "play some jazz",
                              "dim the bedroom lights", "what time is it"])
     assert all(r.error is None for r in res)
     waits = [r.queue_ms for r in res]
-    assert max(waits[:2]) < 50.0  # admitted by the first step
-    # the last two wait for a slot: at least one whole decode chunk
-    chunk_ms = min(s["wall_ms"] for s in get_steplog().steps() if s.get("tokens"))
+    # orders and counts, no wait against a constant (ISSUE 52; a loaded box's
+    # first admission alone outlasted the 50 ms this held the second to): the
+    # first two are admitted by the FIRST step, in order ...
+    steps = get_steplog().steps()
+    by_step = [[round(a["queue_ms"], 3) for a in s.get("admissions", [])] for s in steps]
+    assert by_step[0] == [round(w, 3) for w in waits[:2]]
+    # ... within it: neither waited longer than from its submit to that step's end
+    assert max(waits[:2]) <= steps[0]["wall_ms"] + (steps[0]["t0_ns"] - t_submit) / 1e6
+    # the last two wait for a slot, in later steps: at least one whole decode chunk
+    assert sorted(w for ws in by_step[1:] for w in ws) == sorted(round(w, 3) for w in waits[2:])
+    chunk_ms = min(s["wall_ms"] for s in steps if s.get("tokens"))
     assert min(waits[2:]) > max(waits[:2]) and min(waits[2:]) >= 0.5 * chunk_ms
     s1, n1 = m.counter_state()[1]["scheduler.queue_wait"]
     assert n1 - n0 == 4 and s1 - s0 == pytest.approx(sum(waits), rel=1e-6)
     assert "scheduler.admissions" not in m.counter_state()[0]  # one copy, not two
-    # an idle batcher admits at once
+    # an idle batcher admits at once: in the first step it runs, behind nobody
+    get_steplog().clear()
     bat2 = _batcher(scope_engine)
     (r,) = bat2.generate_many(["stop"])
-    assert r.queue_ms < 50.0
+    first = get_steplog().steps()[0]
+    assert [round(a["queue_ms"], 3) for a in first["admissions"]] == [round(r.queue_ms, 3)]
+    assert r.queue_ms < min(waits[2:])  # no slot to wait for
 
 
 def test_profiler_capture_holds_the_step_and_its_admissions(scope_engine, tmp_path):
@@ -1215,8 +1267,10 @@ def test_a_long_step_is_photographed_once_and_nothing_restarts(scope_engine, mon
     """The chaos drill ``stall_step`` (2 s of sleep at the top of a step) under
     the watchdog at its default threshold (30 s: no restart): ONE ``stall``
     snapshot, taken when the step was a second old, whose batcher thread
-    stands in the drill's ``sleep``, on the record of the step that follows;
-    the watchdog's own lateness rides the same records."""
+    stands in the drill's ``sleep``, on the record of the step that slept
+    (the drill sleeps inside the step's timer since ISSUE 52); the watchdog
+    woke on time all along, so the sampler it holds armed never fired
+    (``dump_n`` 0); the watchdog's own lateness rides the same records."""
     from tpu_voice_agent.serve.colocate import ColocatedServing
     from tpu_voice_agent.utils import chaos
 
@@ -1239,15 +1293,17 @@ def test_a_long_step_is_photographed_once_and_nothing_restarts(scope_engine, mon
     assert get_metrics().counter_state()[0].get("engine.restarts", 0.0) == restarts
     steps = log.steps()
     stalls = [s["stall"] for s in steps if "stall" in s]
-    assert len(stalls) == 1 and "stall" in steps[0]  # the record that follows the sleep
+    assert len(stalls) == 1 and "stall" in steps[0]  # the record of the step that slept
     (snap,) = stalls
-    assert 1000.0 <= snap["age_ms"] < 2000.0 and snap["gc_open_ms"] is None
-    assert snap["batcher"] == "colocate" and snap["late_ms"] < 500.0
+    slept = steps[0]["wall_ms"]  # the drill's sleep and a tiny model's step
+    assert 1000.0 <= snap["age_ms"] < slept and snap["gc_open_ms"] is None
+    assert snap["batcher"] == "colocate" and snap["late_ms"] < 0.25 * slept
     (batcher,) = [t for t in snap["threads"] if t["name"] == "colocate"]
     assert batcher["frames"][0].startswith("scheduler.py:") and batcher["frames"][0].endswith(" step")
     assert any(f.endswith(" _tick") for f in batcher["frames"]) and len(batcher["frames"]) <= 6
     assert {t["name"] for t in snap["threads"]} >= {"colocate", "colocate-watchdog", "MainThread"}
-    assert snap["open_spans"] == []  # the drill sleeps before the step's timer opens
+    assert snap["open_spans"] == []  # the drill sleeps before the step's first stage opens
+    assert snap["dump_n"] == 0 == steps[0]["stall_dump_n"] and "dump_at_ms" not in snap
     assert "stall_pending" not in log.dump()  # folded: it is the record's now
     assert steps[0]["gap_ms"] == 0.0 and all("watchdog_late_ms" in s for s in steps)
     assert "host.watchdog_late" in get_metrics().counter_state()[1]
@@ -1301,3 +1357,297 @@ def test_one_rid_from_submit_through_admission_to_delivery(scope_engine, tmp_pat
     for step in spans["sched.step"]:
         assert sum(inside(step, tick) for tick in spans["sched.tick"]) == 1
     assert len(spans["sched.admit.head"]) >= 2 and spans["sched.harvest"]
+
+
+# ---------------------------------------------------- the machine's side of a step (ISSUE 52)
+
+
+def _line_of(frame: str, module) -> str:
+    """The source line a dump's frame (``file.py:N func``) names, in ``module``."""
+    import linecache
+
+    return linecache.getline(module.__file__, int(frame.split(":")[1].split()[0]))
+
+
+@pytest.mark.parametrize("cause", ["hold", "hold_walked", "sleep"])
+def test_a_long_step_names_its_cause(cause, scope_engine, monkeypatch):
+    """Steps of two seconds behind the real serving loop and its watchdog, told
+    apart by their records. ``hold``: a helper thread inside a native call that
+    KEEPS the interpreter (``host_wait_check.drill_hold``) — every Python
+    thread stands still, the watchdog oversleeps by most of the hold, and the
+    stamp it holds armed, which needs no interpreter, is made WHILE the hold
+    lasts — a time and no thread's frames: nobody's state is touched.
+    ``hold_walked``: the same with ``machine._Sampler.frames`` set, as
+    the drill on the chip sets it — every thread's frames, the helper's among
+    them. ``sleep``: the chaos drill ``stall_step`` — the batcher's own thread
+    sleeps, everybody else runs, the watchdog is on time, photographs the
+    batcher at the line that sleeps and keeps the stamp from firing. Every
+    bound is relative to the drill's own stamps or the step's own wall."""
+    import host_wait_check
+
+    from tpu_voice_agent.serve import scheduler
+    from tpu_voice_agent.serve.colocate import SAMPLE_LATE_S, ColocatedServing
+    from tpu_voice_agent.utils import chaos, machine
+
+    monkeypatch.setenv("CHAOS_STALL_S", "2.0")
+    log = get_steplog()
+    bat = _batcher(scope_engine, max_new_tokens=32)
+    assert bat.generate_many(["go back"])[0].token_ids  # compiled before the drill
+    log.clear()
+    drilled: dict = {}
+    if cause == "sleep":
+        chaos.configure("stall_step@1")
+    else:
+        inner = bat._step
+
+        def held_step(timer, epoch):  # inside the step's timer, once
+            if not drilled:
+                # the walk is armed for the drill alone, as the tool arms it: two
+                # wakes of the watchdog before the hold, while no thread runs
+                # JAX's Python for it to walk into (``machine._Sampler``)
+                stamp = machine.sampler()
+                stamp.frames = cause == "hold_walked"
+                time.sleep(0.25)
+                try:
+                    drilled.update(host_wait_check.drill_hold())
+                finally:
+                    stamp.frames = False
+            return inner(timer, epoch)
+
+        monkeypatch.setattr(bat, "_step", held_step)
+    co = ColocatedServing(None, bat)
+    co.start_watchdog(interval_s=0.1)  # it holds the switch armed before the first step opens
+    co.start()
+    try:
+        res = co.submit_parse("scroll down").result(timeout=120)
+    finally:
+        chaos.reset()
+        co.stop()
+    assert res.error is None and co.stats.restarts == 0
+    (rec,) = [s for s in log.steps() if "stall" in s]
+    stall, wall = rec["stall"], rec["wall_ms"]
+    assert rec["stall_dump_n"] == stall["dump_n"] and stall["batcher"] == "colocate"
+    threads = stall.get("threads", [])  # (the photograph's or the walk's)
+    assert all(len(t["frames"]) <= 6 for t in threads)
+    batcher = next((t for t in threads if t["name"] == "colocate"), None)
+    if cause == "sleep":
+        # the batcher in ``time.sleep``, photographed by a watchdog that was on
+        # time — and so kept the stamp from firing
+        assert stall["dump_n"] == 0 and "dump_at_ms" not in stall
+        assert batcher["frames"][0].endswith(" step")
+        assert "time.sleep(" in _line_of(batcher["frames"][0], scheduler)
+        assert rec["watchdog_late_ms"] < 0.5 * wall and stall["late_ms"] < 0.5 * wall
+        assert 1000.0 <= stall["age_ms"] <= wall
+        return
+    held = (drilled["end_ns"] - drilled["begin_ns"]) / 1e6
+    begin_at, end_at = ((drilled[k] - rec["t0_ns"]) / 1e6 for k in ("begin_ns", "end_ns"))
+    assert not drilled["alive"] and held >= 2000.0
+    # made once, when the watchdog had not woken for what it armed the switch
+    # with at its last wake (within the interval before the hold; a file's
+    # mtime is a tick coarse) — WHILE the interpreter was held
+    assert stall["dump_n"] == 1 and stall["after_ms"] == 1e3 * (0.1 + SAMPLE_LATE_S)
+    assert begin_at + stall["after_ms"] - 100.0 - 20.0 <= stall["dump_at_ms"] <= end_at - 500.0
+    # every Python thread stood still: the watchdog overslept most of the hold ...
+    assert rec["watchdog_late_ms"] >= 0.5 * held
+    # ... and the batcher's thread was not waiting for a CPU
+    assert rec.get("run_delay_ms", 0.0) <= 0.25 * held
+    if cause == "hold":
+        # a time alone; the frames are the watchdog's photograph's, if it still
+        # found the step open: taken late, under the interpreter's lock, when
+        # the hold was over
+        assert set(stall) - {"dump_n", "after_ms", "dump_at_ms", "batcher"} <= {
+            "threads", "age_ms", "late_ms", "gc_open_ms", "open_spans"}
+        if "age_ms" in stall:
+            assert stall["late_ms"] >= 0.5 * held and stall["age_ms"] >= end_at - 1.0
+    else:
+        # the thread that held it, by its frame (it is gone when the step closes:
+        # the dump's ident finds no name any more)
+        (helper,) = [t for t in threads if t["frames"] and t["frames"][0].endswith(" hold")]
+        assert helper["name"] == str(drilled["ident"])
+        assert "usleep(" in _line_of(helper["frames"][0], host_wait_check)
+        assert any(f.endswith(" drill_hold") for f in batcher["frames"])  # it waits for the helper
+
+
+def test_a_stopped_process_is_sampled_when_it_runs_again(tmp_path):
+    """``SIGSTOP``, then ``SIGCONT`` two seconds later, from a child
+    (``host_wait_check.drill_stop``): the whole process is not run, the stamp
+    that needs no interpreter with it — it is made when the process runs
+    again, NOT when its time ran out, though the watchdog wakes at that same
+    moment and reaches for the timer. In a process of its own: a stopped test
+    process reads as a stopped job to a shell."""
+    script = tmp_path / "stopped.py"
+    script.write_text(
+        "import json, sys, time\n"
+        f"sys.path[:0] = [{str(ROOT)!r}, {str(ROOT / 'tools')!r}]\n"
+        "import host_wait_check\n"
+        "from tpu_voice_agent.utils.steplog import StepLog\n"
+        "from tpu_voice_agent.serve.colocate import ColocatedServing\n"
+        "co = ColocatedServing(None, None)\n"  # its watchdog alone: it holds the switch
+        "co.start_watchdog(interval_s=0.25)\n"
+        "time.sleep(0.6)\n"
+        "t = StepLog(max_steps=8, enabled=True, sampler=True).timer()\n"
+        "t.stage('sched.admit')\n"
+        "drilled = host_wait_check.drill_stop()\n"
+        "print(json.dumps({'drill': drilled, 'rec': t.finish()}))\n")
+    proc = subprocess.run([sys.executable, str(script)], capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = json.loads(proc.stdout.splitlines()[-1])
+    drilled, rec = out["drill"], out["rec"]
+    if "refused" in drilled:
+        pytest.skip(f"no process may stop this one here: {drilled['refused']}")
+    stall = rec["stall"]
+    cont_at = (drilled["end_ns"] - rec["t0_ns"]) / 1e6
+    assert drilled["end_ns"] - drilled["begin_ns"] >= 2e9 and rec["wall_ms"] >= cont_at
+    assert stall["dump_n"] == 1 == rec["stall_dump_n"] and "threads" not in stall
+    # not before the SIGCONT (a file's mtime is a tick coarse): the stamp stood still too
+    assert cont_at - 20.0 <= stall["dump_at_ms"] <= rec["wall_ms"]
+    assert stall["after_ms"] == 750.0 and rec["watchdog_late_ms"] >= 1000.0
+
+
+@pytest.mark.parametrize("lacking,keys", [
+    ("schedstat", ["run_delay_ms"]), ("cpu.stat", ["throttled_ms"]),
+    ("a kernel that counts nothing", ["majflt"]),
+    ("every source", ["run_delay_ms", "majflt", "throttled_ms"])])
+def test_a_source_the_machine_lacks_leaves_its_key_out(lacking, keys, monkeypatch):
+    """A machine without a source (its probe fails — or, as the benchmark's
+    machines do, its kernel answers ``getrusage`` and counts NOTHING, not even
+    the switch a sleep is): the key is in NO record — never ``None``, never a
+    made-up 0.0 that would say "no delay" — the other counters stay, and the
+    reader ``step_fields`` finds nothing to read."""
+    import types
+
+    from benchmark.readers import step_fields
+    from tpu_voice_agent.utils import machine
+
+    there = {k for k, has in machine.counters().sources().items() if has}
+    if "majflt" in keys:
+        zeros = types.SimpleNamespace(ru_minflt=0, ru_majflt=0, ru_nvcsw=0, ru_nivcsw=0)
+        monkeypatch.setattr(machine, "resource", types.SimpleNamespace(
+            RUSAGE_SELF=0, RUSAGE_THREAD=1, getrusage=lambda who: zeros))
+    if len(keys) > 1 or "majflt" not in keys:
+        real = machine._open
+        gone = ("schedstat", "cpu.stat") if len(keys) > 1 else (lacking,)
+        monkeypatch.setattr(machine, "_open", lambda path: -1 if any(g in path for g in gone) else real(path))
+    monkeypatch.setattr(machine, "_MACHINE", None)  # probed anew, behind the patch
+    log = StepLog(max_steps=8, enabled=True)
+    recs = [log.timer().finish() for _ in range(3)]
+    assert not any(machine.counters().sources()[k] for k in keys)
+    for rec in recs:
+        assert not set(keys) & set(rec) and not any(v is None for v in rec.values())
+        assert there - set(keys) <= set(rec)  # the sources it has stay
+    for key in keys:
+        assert step_fields.read({"steps": recs}, what=key, stat="sum", per="steps") is None
+    for key in there - set(keys):
+        assert step_fields.read({"steps": recs}, what=key, stat="sum") >= 0
+
+
+def test_a_ledger_that_is_off_arms_nothing_and_opens_no_file(monkeypatch):
+    """``StepLog(enabled=False)`` reads no ``/proc`` and opens no file, and a
+    watchdog over a ledger that is off arms no timer and takes no signal; a
+    ledger that is on reads the counters and still opens no file (the process
+    has ONE sampler, and only a watchdog arms it)."""
+    from tpu_voice_agent.serve.colocate import ColocatedServing
+    from tpu_voice_agent.utils import machine
+
+    opened = []
+    monkeypatch.setattr(machine, "_MACHINE", None)
+    monkeypatch.setattr(machine, "_SAMPLER", None)
+    monkeypatch.setattr(machine, "_open", lambda path: opened.append(path) or -1)
+    monkeypatch.setattr(machine.tempfile, "TemporaryFile", lambda *a, **kw: pytest.fail("opened a file"))
+    monkeypatch.setattr(machine.tempfile, "TemporaryDirectory", lambda *a, **kw: pytest.fail("made a directory"))
+    monkeypatch.setattr(machine.faulthandler, "dump_traceback_later", lambda *a, **kw: pytest.fail("armed the walk"))
+    monkeypatch.setattr(machine.ctypes, "CDLL", lambda *a, **kw: pytest.fail("reached for a timer"))
+    rec = StepLog(max_steps=8, enabled=False, sampler=True).timer().finish()
+    assert machine._MACHINE is None and machine._SAMPLER is None and not opened
+    assert not set(machine.MACHINE_KEYS) & set(rec) and "stall_dump_n" not in rec
+    monkeypatch.setattr(get_steplog(), "enabled", False)
+    co = ColocatedServing(None, None)
+    co.start_watchdog(interval_s=0.02)
+    time.sleep(0.1)
+    co.stop()
+    assert machine._SAMPLER is None and not opened
+    rec = StepLog(max_steps=8, enabled=True, sampler=True).timer().finish()
+    assert machine._SAMPLER is None and "stall_dump_n" not in rec
+    assert opened and machine._MACHINE is not None  # on: it looked for the counters
+
+
+def test_the_sampler_never_fires_in_ordinary_steps(scope_engine):
+    """Armed anew at every wake of the watchdog and never fired: over twenty
+    ordinary steps behind the real serving loop nothing is written, no record
+    holds a ``stall`` or a ``stall_dump_n``, and a watchdog that stops leaves
+    nothing armed — nor does one that recovers a dead loop hold it meanwhile."""
+    import os
+
+    from tpu_voice_agent.serve.colocate import ColocatedServing
+    from tpu_voice_agent.utils import machine
+
+    log = get_steplog()
+    bat = _batcher(scope_engine, max_new_tokens=32)
+    assert bat.generate_many(["go back"])[0].token_ids  # compiled before the count
+    log.clear()
+    co = ColocatedServing(None, bat)
+    co.start_watchdog(interval_s=0.05)
+    co.start()
+    try:
+        while len(log.steps()) < 20:
+            futs = [co.submit_parse(t) for t in ("scroll down", "play some jazz", "what time is it")]
+            assert all(f.result(timeout=120).error is None for f in futs)
+        assert machine.armed_sampler() is not None
+    finally:
+        co.stop()
+    steps = log.steps()
+    short = [s for s in steps if s["wall_ms"] < 900.0 * log.stall_after_s()]
+    assert len(short) >= 20 or len(short) == len(steps)
+    assert not any("stall_dump_n" in s or "stall" in s for s in short)
+    stamp = machine.sampler()
+    assert machine.armed_sampler() is None and not stamp._left()  # the stopped watchdog let go of it
+    if max(s["watchdog_late_ms"] for s in steps) < 400.0:  # (it woke in time throughout)
+        assert os.listdir(stamp._dir.name) == []
+        assert stamp.file is None or os.fstat(stamp.file.fileno()).st_size == 0  # (a walk's, of a test before)
+
+
+def test_the_os_counters_bracket_a_step_and_a_new_thread_probes_anew():
+    """The counters are read at a step's two ends: counts are whole numbers,
+    times are milliseconds, nothing is negative, and a key the machine has no
+    source for is not there to ask for. A thread's own delay comes from the
+    file THAT thread opened: a loop the watchdog restarts is a new thread with
+    a file of its own, which closes with it."""
+    import os
+    import threading
+
+    from tpu_voice_agent.utils import machine
+
+    def a_step() -> dict:
+        t = StepLog(max_steps=8, enabled=True).timer()
+        t.stage("sched.admit")
+        time.sleep(0.05)
+        return t.finish()
+
+    rec = a_step()
+    has = {k for k, there in machine.counters().sources().items() if there}
+    assert set(machine.MACHINE_KEYS) & set(rec) == has
+    assert all(rec[k] >= 0 for k in has)
+    if "majflt" in has:
+        assert isinstance(rec["majflt"], int)
+    if "run_delay_ms" in has:  # a thread that slept was not kept waiting for its whole step
+        assert rec["run_delay_ms"] < rec["wall_ms"]
+    assert "stall_dump_n" not in rec  # an ad-hoc ledger arms no sampler
+    seen: dict = {}
+
+    def restarted_loop() -> None:
+        seen["rec"] = a_step()
+        seen["fd"] = machine.counters()._own.file.fd
+
+    th = threading.Thread(target=restarted_loop)
+    th.start()
+    th.join(timeout=60)
+    assert not th.is_alive() and set(seen["rec"]) ^ set(rec) <= {"gc"}  # (a collection falls where it falls)
+    mine = machine.counters()._own.file.fd
+    if "run_delay_ms" in has:
+        assert seen["fd"] >= 0 and seen["fd"] != mine
+        os.fstat(mine)  # this thread's is open; the other's closed with its thread
+        try:  # (its number may be somebody else's file by now)
+            target = os.readlink(f"/proc/self/fd/{seen['fd']}")
+        except OSError:
+            target = ""
+        assert not target.endswith("/schedstat")

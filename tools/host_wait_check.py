@@ -22,9 +22,24 @@ that stretch are summarised apart (the steps under the profiler's own start
 and stop are left out of both): traced and untraced readings of one process,
 side by side. Last, what the
 instrumentation costs where it runs: nanoseconds a ``gc.callbacks`` pair and
-a reading of the three clocks.
+a reading of the three clocks, and (ISSUE 52) of each of the OS's counters a
+record carries and of arming the stamp that needs no interpreter (the watchdog
+does that once a wake) — with which of those sources this machine has.
 
-    python3 tools/host_wait_check.py --workload parse_flood [--seed 7] [--seconds 20] [--profile-s 3]
+``--drill hold|stop`` (ISSUE 52) stalls the live stack for ``DRILL_S`` in the
+middle of the window, in one of the two ways a long step comes about, and
+prints the drill's own stamps beside the record of the step it fell into:
+``hold`` — a helper thread inside a native call that KEEPS the interpreter
+(``ctypes.PyDLL(None).usleep``): every Python thread stands still, the Python
+watchdog oversleeps, the switch it holds armed fires while the hold lasts
+(``stall.dump_at_ms`` about ``after_ms`` past the watchdog's last wake); for
+the drill's few seconds the sampler walks every thread's frames
+(``machine._Sampler.frames``), so the helper's frame is among
+``stall.threads``; ``stop`` — a child process sends this one ``SIGSTOP`` and,
+later, ``SIGCONT``: the stamp stands still with the rest (``dump_at_ms`` not
+before the ``SIGCONT``) and holds the frames of the one thread it reached.
+
+    python3 tools/host_wait_check.py --workload parse_flood [--seed 7] [--seconds 20] [--profile-s 3] [--drill hold]
 
 One cell a process (each fills most of the chip). A line of JSON a run, on
 stdout and appended to ``chiprun_out/host_wait_check.jsonl``. With
@@ -38,9 +53,24 @@ import json
 import os
 import statistics
 import sys
+import threading
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DRILL_S = 2.0  # past the sampler's least timeout (a second)
+# the child of ``drill_stop``: stops its parent, lets it run again, says when
+_STOPPER = """
+import os, signal, sys, time
+pid, seconds = int(sys.argv[1]), float(sys.argv[2])
+os.kill(pid, signal.SIGSTOP)
+try:
+    stopped = time.time_ns()
+    time.sleep(seconds)
+finally:
+    cont = time.time_ns()
+    os.kill(pid, signal.SIGCONT)
+print(stopped, cont)
+"""
 
 
 def med(values) -> float | None:
@@ -57,6 +87,7 @@ def mean(values) -> float | None:
 
 def summary(steps: list[dict], seconds: float) -> dict:
     """The new keys over ``steps`` (records of steps that ran a chunk)."""
+    from tpu_voice_agent.utils.machine import MACHINE_KEYS
     from tpu_voice_agent.utils.steplog import STAGES
 
     if not steps:
@@ -82,6 +113,12 @@ def summary(steps: list[dict], seconds: float) -> dict:
         "watchdog_late_ms_max": max(s["watchdog_late_ms"] for s in steps),
         "stalls": sum(1 for s in steps if "stall" in s),
     }
+    # the machine's side (ISSUE 52): a step's mean of each counter the machine
+    # has — a key it lacks is in no record, and not in this summary either
+    for k in (*MACHINE_KEYS, "stall_dump_n"):
+        have = [s[k] for s in steps if k in s]
+        if have:
+            out[k + "_per_step"], out[k + "_max"] = round(sum(have) / len(have), 4), max(have)
     # every stage on the three clocks, MEANS: wall, this thread's CPU, the others' CPU
     out["stages_mean"] = {k: [mean(s["stages"][k] for s in steps if k in s["stages"]),
                               mean(s["cpu_ms"][k] for s in steps if k in s["cpu_ms"]),
@@ -98,10 +135,75 @@ def summary(steps: list[dict], seconds: float) -> dict:
     return out
 
 
+def drill_hold(seconds: float = DRILL_S) -> dict:
+    """A helper thread inside a native call that KEEPS the interpreter for
+    ``seconds`` (``PyDLL`` releases no lock around the call): the process runs,
+    and no Python thread of it does. Returns the hold's own stamps
+    (``time.time_ns``) and the helper's ident, by which a dump names it once
+    the thread is gone."""
+    import ctypes
+
+    usleep = ctypes.PyDLL(None).usleep
+    usleep.argtypes, usleep.restype = [ctypes.c_uint], ctypes.c_int
+    out: dict = {"drill": "hold"}
+
+    def hold() -> None:
+        out.update(ident=threading.get_ident(), begin_ns=time.time_ns())
+        usleep(int(seconds * 1e6))
+        out["end_ns"] = time.time_ns()
+
+    th = threading.Thread(target=hold, name="drill-hold")
+    th.start()
+    th.join(timeout=seconds + 60)
+    out["alive"] = th.is_alive()
+    return out
+
+
+def drill_stop(seconds: float = DRILL_S) -> dict:
+    """A child process sends THIS process ``SIGSTOP`` and, ``seconds`` later,
+    ``SIGCONT``: the whole process is not run, its sampler with it. Returns the
+    child's stamps of both signals (``time.time_ns``), or ``refused`` where the
+    machine lets no process stop this one."""
+    import subprocess
+
+    child = subprocess.run([sys.executable, "-c", _STOPPER, str(os.getpid()), str(seconds)],
+                           capture_output=True, text=True, timeout=seconds + 60)
+    if child.returncode != 0:
+        return {"drill": "stop", "refused": child.stderr.strip()[-300:]}
+    stopped, cont = (int(v) for v in child.stdout.split())
+    return {"drill": "stop", "begin_ns": stopped, "end_ns": cont}
+
+
 def instrument_cost(n: int = 20000) -> dict:
     """Nanoseconds a ``gc.callbacks`` pair (stamp + annotation + event) and a
-    reading of the three clocks, in this process, with the profiler off."""
-    from tpu_voice_agent.utils import steplog
+    reading of the three clocks, in this process, with the profiler off; and
+    (ISSUE 52) of what a step's two ends read of the machine, source by source
+    as ``utils/machine.py`` reads them, of arming the stamp that needs no
+    interpreter (the watchdog's, once a wake) and arming + cancelling it, and
+    of the whole of a step's open and close."""
+    from tpu_voice_agent.utils import machine, steplog
+
+    def ns_each(fn, reps: int) -> float:
+        t0 = time.perf_counter_ns()
+        for _ in range(reps):
+            fn()
+        return round((time.perf_counter_ns() - t0) / reps, 1)
+
+    counters, stamp = machine.counters(), machine.sampler()
+    cost = {"machine_read_ns": ns_each(counters.read, 2000)}
+    for source in (counters.run_delay_ms, counters.majflt, counters.throttled_ms):
+        if source() is not None:  # a source the machine lacks has no cost, and no key
+            cost[source.__name__ + "_read_ns"] = ns_each(source, 2000)
+    if stamp is not None:
+        held = stamp.owner  # the serving watchdog: it arms the switch anew within its interval
+        cost["sampler_arm_ns"] = ns_each(lambda: stamp.arm(cost, 60.0), 2000)
+        cost["sampler_arm_cancel_ns"] = ns_each(lambda: (stamp.arm(cost, 60.0), stamp.cancel(cost)), 2000)
+        if held is not None:
+            stamp.arm(held, 60.0)
+    log = steplog.StepLog(max_steps=8, enabled=True, sampler=True)
+    cost["step_open_close_ns"] = ns_each(lambda: log.timer().finish(), 1000)
+    bare = steplog.StepLog(max_steps=8, enabled=False)
+    cost["step_open_close_ledger_off_ns"] = ns_each(lambda: bare.timer().finish(), 1000)
 
     info = {"generation": 0, "collected": 0, "uncollectable": 0}
     steplog._install_gc()
@@ -115,7 +217,7 @@ def instrument_cost(n: int = 20000) -> dict:
     for _ in range(n):
         steplog._clocks()
     return {"gc_callback_pair_ns": round(pair, 1),
-            "three_clocks_ns": round((time.perf_counter_ns() - t0) / n, 1)}
+            "three_clocks_ns": round((time.perf_counter_ns() - t0) / n, 1), **cost}
 
 
 def main() -> int:
@@ -125,6 +227,8 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, default=20.0)
     ap.add_argument("--profile-s", type=float, default=0.0,
                     help="profile this many seconds in the middle of the window")
+    ap.add_argument("--drill", choices=("hold", "stop"),
+                    help=f"stall the stack for {DRILL_S} s in the middle of the window")
     args = ap.parse_args()
     os.chdir(ROOT)
     sys.path.insert(0, ROOT)
@@ -166,11 +270,31 @@ def main() -> int:
                             max(0.0, (args.seconds - args.profile_s) / 2), args.profile_s)
             tracer.start()
         edges: dict = {}
+        drilled: dict = {}
+
+        def drill() -> None:
+            from tpu_voice_agent.utils import machine
+
+            time.sleep(args.seconds / 2 - 1.5)
+            # ``hold`` names the thread that holds the interpreter: every
+            # thread's frames, for these few seconds alone (the watchdog arms
+            # the switch anew at each wake, half a second apart)
+            stamp = machine.armed_sampler()
+            if stamp is not None:
+                stamp.frames = args.drill == "hold"
+            time.sleep(1.5)
+            try:
+                drilled.update((drill_hold if args.drill == "hold" else drill_stop)())
+            finally:
+                if stamp is not None:
+                    stamp.frames = False
 
         def on_event(msg: dict) -> None:
             edges[msg["ev"]] = (msg["t"], get_metrics().counter_state()[0])
             if msg["ev"] == "window_start" and tracer is not None:
                 tracer.go.set()
+            if msg["ev"] == "window_start" and args.drill:
+                threading.Thread(target=drill, name="drill", daemon=True).start()
 
         client.command(dict(gen, cmd="run"), on_event)
         if tracer is not None:
@@ -200,6 +324,18 @@ def main() -> int:
             out["profiled"] = summary(inside, sum(s["wall_ms"] + s["gap_ms"] for s in inside) / 1e3)
         if outside:
             out["longest_step"] = max(outside, key=lambda s: s["wall_ms"])
+        from tpu_voice_agent.utils import machine
+
+        out["machine_sources"] = machine.counters().sources()  # as THIS thread finds them
+        if args.drill:
+            # the drill's stamps on the clock of the step it fell into (ms from
+            # that step's start, as ``stall.dump_at_ms`` is), beside its record
+            hit = next((s for s in steps if "begin_ns" in drilled
+                        and s["t0_ns"] <= drilled["begin_ns"] <= s["t1_ns"]), None)
+            if hit is not None:
+                drilled.update(begin_at_ms=round((drilled["begin_ns"] - hit["t0_ns"]) / 1e6, 3),
+                               end_at_ms=round((drilled["end_ns"] - hit["t0_ns"]) / 1e6, 3))
+            out["drill"], out["drilled_step"] = drilled, hit
         with open(os.path.join(ROOT, "chiprun_out", f"host_wait_records_{args.workload}.jsonl"), "w") as f:
             f.writelines(json.dumps(s) + "\n" for s in steps)  # every record of the window
         out["cost"] = instrument_cost()

@@ -115,6 +115,12 @@ def render_step(rec: dict, width: int = 48, max_wall_ms: float | None = None) ->
         line += (f"\n       ⏸ stall snapshot at {stall.get('age_ms', 0.0):.0f} ms "
                  f"(late {stall.get('late_ms', 0.0):.1f}, gc open {stall.get('gc_open_ms')}): "
                  f"spans {stall.get('open_spans')}; batcher {' < '.join(frames[:3])}")
+        if "dump_n" in stall:  # the stamp's half (ISSUE 52): when it fired, and who else stood where
+            others = [f"{t.get('name')}: {t['frames'][0]}" for t in stall.get("threads", [])
+                      if t.get("name") != stall.get("batcher") and t.get("frames")]
+            line += (f"\n         sampled {stall['dump_n']}x at {stall.get('dump_at_ms')} ms of the step; "
+                     f"run delay {rec.get('run_delay_ms')} throttled {rec.get('throttled_ms')} "
+                     f"majflt {rec.get('majflt')}; {' | '.join(others[:4])}")
     for ev in rec.get("events") or []:
         flag = "POST-FENCE " if ev.get("post_fence") else ""
         line += (f"\n       ⚡ {flag}compile {ev.get('site')} "
@@ -166,8 +172,13 @@ def _synthetic_ring() -> dict:
                                 "frames": ["scheduler.py:1297 _step", "scheduler.py:1060 step"]}]}},
         {"seq": 2, "wall_ms": 96.0, "occupancy": 3, "tokens": 24,
          "stages": {"decode": 88.0, "readback": 6.0, "release": 2.0}},
+        {"seq": 3, "wall_ms": 400.0, "stages": {"readback": 400.0}, "run_delay_ms": 0.4,
+         "majflt": 0, "watchdog_late_ms": 290.0, "stall_dump_n": 1,
+         "stall": {"batcher": "colocate", "dump_n": 1, "dump_at_ms": 101.9,
+                   "threads": [{"name": "colocate", "frames": ["scheduler.py:1301 _step"]},
+                               {"name": "drill-hold", "frames": ["host_wait_check.py:120 hold"]}]}},
     ]
-    return {"enabled": True, "max_steps": 256, "recorded": 3, "steps": steps}
+    return {"enabled": True, "max_steps": 256, "recorded": 4, "steps": steps}
 
 
 def rows_of(txt: str) -> list[str]:
@@ -177,17 +188,20 @@ def rows_of(txt: str) -> list[str]:
 def self_test() -> int:
     body = _synthetic_ring()
     txt = render_timeline(body, width=40)
-    assert "step ledger: 3 of 3" in txt, txt
+    assert "step ledger: 4 of 4" in txt, txt
     assert "POST-FENCE compile engine.chunk_decode_loop" in txt, txt
     assert "⚡" in txt and "1 compile stall(s)" in txt, txt
     assert "occ 3" in txt and "tok 24" in txt and "fwd 8" in txt, txt
     assert "head 0.4 gap 3.2 off-cpu 84.5 (others 3.3) gc 2x 1.5 max 1.2" in txt, txt
     assert "stall snapshot at 1030 ms" in txt and "scheduler.py:1297 _step <" in txt, txt
+    # the sampler's half of a stall: when it fired, and the thread that held the interpreter
+    assert "sampled 1x at 101.9 ms of the step; run delay 0.4 throttled None majflt 0; " \
+           "drill-hold: host_wait_check.py:120 hold" in txt, txt
     assert "off-cpu" not in rows_of(txt)[2]  # a record of an older ledger renders as before
     # the bar scales against the window's longest step: the 412 ms step's
     # bar must be strictly longer than the 96 ms step's
     rows = rows_of(txt)
-    assert len(rows) == 3, rows
+    assert len(rows) == 4, rows
     w0 = rows[0].split("|")[1]
     w2 = rows[2].split("|")[1]
     assert len(w0.rstrip()) > len(w2.rstrip()), (w0, w2)
@@ -205,7 +219,7 @@ def self_test() -> int:
 
     with tempfile.NamedTemporaryFile("w", suffix=".json", delete=False) as f:
         _json.dump({"frozen": True, "steplog": body}, f)
-    assert load_dump(f.name)["recorded"] == 3
+    assert load_dump(f.name)["recorded"] == 4
     print(txt)
     print("stepview self-test ok")
     return 0

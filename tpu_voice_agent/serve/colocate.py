@@ -34,6 +34,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from ..utils import machine
 from ..utils.steplog import (
     TICK_SPAN,
     annotation,
@@ -45,6 +46,12 @@ from ..utils.steplog import (
 from .engine import GenerationResult
 from .scheduler import ContinuousBatcher
 from .stt import SpeechEngine, TranscribeResult
+
+
+# how late a wake of the watchdog must come before the stamp that needs no
+# interpreter fires (``_watch``): a thread that only sleeps comes this late
+# where every Python thread stands still, and hardly ever otherwise
+SAMPLE_LATE_S = 0.5
 
 
 @dataclass
@@ -406,9 +413,18 @@ class ColocatedServing:
         Long before that, and with no restart (ISSUE 36): its own LATENESS —
         how far each ``sleep(interval_s)`` overslept, histogram
         ``host.watchdog_late`` and ``watchdog_late_ms`` on the next step
-        record — and, once a step, a SNAPSHOT of what holds the batcher's
-        thread when the step is older than three median steps and a second
-        (``StepLog.stall_snapshot``: the record's ``stall``).
+        record — and, once a step, a SNAPSHOT of a step older than three
+        median steps and a second (``StepLog.stall_snapshot``: every
+        thread's frames, the open spans, an open collection, its own
+        lateness). This thread needs the interpreter, so where every Python
+        thread stands still it comes when the step is over. For that case it
+        holds a DEAD MAN'S SWITCH (ISSUE 52): before every sleep it arms the
+        process's one stamp that needs no interpreter
+        (``utils/machine._Sampler``), which fires only if this thread then
+        wakes ``SAMPLE_LATE_S`` late — on time behind a held interpreter, when
+        the process runs again where the machine froze it: the long step's
+        record tells the two apart by it (``stall.dump_at_ms``). It is let go
+        before a recovery, whose own work may outlast it.
         """
         if self._watchdog is not None:
             return
@@ -421,6 +437,8 @@ class ColocatedServing:
         from ..utils import get_metrics
 
         get_metrics().inc("engine.restarts", 0.0)
+        if get_steplog().enabled and (sampler := machine.sampler()) is not None:
+            sampler.arm(self, interval_s + SAMPLE_LATE_S)
         self._watchdog = threading.Thread(
             target=self._watch, args=(interval_s, stall_s),
             name="colocate-watchdog", daemon=True)
@@ -457,9 +475,14 @@ class ColocatedServing:
         log = logging.getLogger("tpu_voice_agent.colocate")
         steplog = get_steplog()
         late_ms, snapped = 0.0, None  # the last sleep's lateness; the step photographed
+        # the dead man's switch: held from ``start_watchdog`` on (a step that
+        # opens before this thread first runs finds it armed)
+        sampler = machine.sampler() if steplog.enabled else None
         while True:
             with self._work:
                 if self._stop:
+                    if sampler is not None:
+                        sampler.cancel(self)  # a stopped watchdog holds no switch
                     return
                 dead = self._thread is not None and not self._thread.is_alive()
                 t0, worker = self._step_t0, self._thread
@@ -477,6 +500,11 @@ class ColocatedServing:
                           batcher_frames=" < ".join(
                               f for t in snap["threads"] if t["name"] == snap["batcher"]
                               for f in t["frames"]))
+            if sampler is not None and (dead or stalled):
+                # a recovery (a freeze of the flight recorder, a warm restart,
+                # lock waits) may outlast the switch while Python runs: what
+                # fired then would be the next long step's to misread
+                sampler.cancel(self)
             if dead:
                 log.error("colocate worker died; failing inflight work and "
                           "restarting the serving loop")
@@ -523,6 +551,10 @@ class ColocatedServing:
                 for fut in futs:
                     self._set_future(fut, exc=exc)
                 self._restart_worker(exc, reset_batcher=False)
+            if sampler is not None:
+                # at every wake (one system call: the timer is the kernel's): it
+                # fires where THIS thread wakes SAMPLE_LATE_S late or later
+                sampler.arm(self, interval_s + SAMPLE_LATE_S)
             t_sleep = time.perf_counter_ns()
             time.sleep(interval_s)
             late_ns = max(0, time.perf_counter_ns() - t_sleep - int(interval_s * 1e9))

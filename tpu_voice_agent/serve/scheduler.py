@@ -1039,14 +1039,6 @@ class ContinuousBatcher:
         from ..utils.steplog import get_steplog
 
         epoch = self._epoch
-        if chaos_fire("stall_step"):
-            # chaos drill for the stalled-step watchdog: sleep as if the
-            # dispatch wedged. On wake, a bumped epoch means the watchdog
-            # already warm-restarted the world — this step must vanish.
-            time.sleep(float(os.environ.get("CHAOS_STALL_S", "2.0")))
-            if epoch != self._epoch:
-                return None
-
         # the step ledger (ISSUE 9): one StepTimer per scheduler step, a
         # ``sched.step`` on the profiler's trace whose four contiguous
         # stage spans tile the chunk wall. Host timing only — record()
@@ -1054,6 +1046,15 @@ class ContinuousBatcher:
         # byte-identical either way.
         timer = get_steplog().timer()
         try:
+            if chaos_fire("stall_step"):
+                # chaos drill for the stalled-step watchdog: sleep as if the
+                # dispatch wedged — INSIDE the step's timer since ISSUE 52, so
+                # that its sampler sees the sleep. On wake, a bumped epoch means
+                # the watchdog already warm-restarted the world — this step
+                # must vanish.
+                time.sleep(float(os.environ.get("CHAOS_STALL_S", "2.0")))
+                if epoch != self._epoch:
+                    return None
             return self._step(timer, epoch)
         finally:
             timer.close()  # a step that raised or returned early

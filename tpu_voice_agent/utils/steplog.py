@@ -97,6 +97,23 @@ the watchdog's lateness (``watchdog_late_ms``) and its ``stall`` snapshot
 (``StepLog.stall_snapshot``). The scalar keys are on every record, 0.0 where
 nothing happened; ``gc`` and ``stall`` only where they hold something.
 
+The MACHINE's side of a step (ISSUE 52). Where every Python thread stands
+still the watchdog stands still too, so a STAMP that needs no interpreter is
+taken for it: ``machine._Sampler``, a kernel timer and a C signal handler, a
+dead man's switch the watchdog arms anew at every wake. A step whose wall
+passes three median steps (``stall_after_s``) reads its file where it closes,
+and its ``stall`` gains ``dump_n`` (flat too: ``stall_dump_n``) and, of a
+stamp, ``after_ms``, ``dump_at_ms`` and the frames of the one thread the signal
+reached (``threads``) — well before the step's end: the process ran and its
+interpreter was held; about the stall's END: the whole process was not run.
+It walks no other thread's frames: that killed the process (``_Sampler``
+says how, and how a drill still names the thread that holds the interpreter).
+And the OS's counters, read at a step's two ends beside the clocks
+(``utils/machine.py``, ``MACHINE_KEYS``): ``run_delay_ms`` (the thread runnable
+and not run), ``majflt`` (the process's major faults), ``throttled_ms`` (its
+cgroup's CPU quota). A source the machine lacks leaves its key OUT of every
+record — never 0.0, which would say "no delay".
+
 Surfaces: ``engine.step.*`` histograms/gauges in the metrics registry,
 ``GET /debug/steplog`` on the brain, a ``steplog`` section folded into
 flight-recorder freezes, the ``tools/stepview.py`` timeline, and the
@@ -116,6 +133,8 @@ import sys
 import threading
 import time
 from collections import deque
+
+from . import machine
 
 # the tiling stage order (stepview renders bars in this order)
 STAGES = ("admit", "prefill", "draft", "decode", "readback", "release")
@@ -255,7 +274,11 @@ class StepLog:
     on, cheap to feed, immutable dumps on read)."""
 
     def __init__(self, max_steps: int | None = None,
-                 enabled: bool | None = None):
+                 enabled: bool | None = None, sampler: bool = False):
+        """``sampler``: this ledger's long steps read the file of the process's
+        ONE ``machine._Sampler`` (the global ledger's do, where a watchdog
+        holds it armed; a test's own only if told)."""
+        self.sampler = sampler
         self.max_steps = max_steps if max_steps is not None \
             else int(os.environ.get("STEPLOG_STEPS", "256"))
         self.enabled = enabled if enabled is not None \
@@ -264,6 +287,7 @@ class StepLog:
         self._steps: list[dict] = []
         self._seq = 0
         self._current: StepTimer | None = None  # the open step, for the watchdog
+        self._stall_after_s = 1.0  # ``stall_after_s()``, kept by ``record``
         # where the last recorded step ended: (wall, thread CPU, process CPU, thread)
         self._last_end: tuple[int, int, int, int] | None = None
 
@@ -288,6 +312,9 @@ class StepLog:
             self._steps.append(rec)
             if len(self._steps) > self.max_steps:
                 del self._steps[: len(self._steps) - self.max_steps]
+            if rec["seq"] < 16 or rec["seq"] % 16 == 0:  # the median of 256 walls moves slowly
+                walls = sorted(s["wall_ms"] for s in self._steps)
+                self._stall_after_s = max(1.0, 3e-3 * walls[len(walls) // 2])
         m = get_metrics()
         m.observe_ms("engine.step.wall", rec["wall_ms"])
         for stage, ms in rec["stages"].items():
@@ -331,23 +358,30 @@ class StepLog:
             self._steps.clear()
             self._seq = 0
             self._last_end = None
+            self._stall_after_s = 1.0
 
     # ------------------------------------------------------------ stalls
 
     def stall_after_s(self) -> float:
-        """How old an open step must be before the watchdog photographs it:
-        three times the ring's median step, and at least a second."""
-        with self._lock:
-            walls = sorted(s["wall_ms"] for s in self._steps)
-        return max(1.0, 3e-3 * walls[len(walls) // 2]) if walls else 1.0
+        """How old an open step must be before the watchdog photographs it and
+        its close reads the sampler's file: three times the ring's median step,
+        and at least a second. Read where every step closes and at every wake
+        of the watchdog, so ``record`` keeps it (it sorted the ring's walls
+        wherever it was asked)."""
+        return self._stall_after_s
 
     def stall_snapshot(self, batcher: str, age_s: float, late_ms: float) -> dict:
         """What holds the batcher's thread, photographed from another thread
         (the watchdog's) while a step is ``age_s`` old: every thread's name and
         top six frames, whether a collection is open and since when, the names
-        of the step's open spans, and how late the photograph itself came.
-        Pushed onto the event ring, so that the step's record carries it as
-        ``stall`` when (if) the step closes; ``dump`` shows it until then."""
+        of the step's open spans, and how late the photograph itself came — a
+        watchdog that stood still with the rest comes when the step is over,
+        and THEN what counts is WHEN the stamp that needs no interpreter was
+        written (``machine._Sampler``: the other half of the one ``stall`` of
+        the step's record; its ``threads`` replace these). The frames are
+        walked HERE, under the interpreter's lock, where they cannot move.
+        Pushed onto the event ring, so that the record carries it when (if)
+        the step closes; ``dump`` shows it until then."""
         names = {t.ident: t.name for t in threading.enumerate()}
         threads = []
         for ident, frame in sys._current_frames().items():
@@ -517,6 +551,14 @@ class StepTimer:
         log._current = self
         self._step = annotation("sched.step", step_num=log.next_seq(), step=True)
         self._step.__enter__()
+        # the machine's side, just outside the wall: the OS's counters at this end
+        self._m0 = machine.counters().read() if log.enabled else None
+        # the sampler some watchdog of this process holds armed, if this
+        # ledger's long steps read its file; and what the file held
+        self._sampler = machine.armed_sampler() if log.enabled and log.sampler else None
+        self.dump: dict | None = None
+        # one pair of the wall clock and ``perf_counter_ns``: a file's mtime
+        # (``dump_at_ms``) is mapped onto the step's clock through it
         self.t0_ns = time.time_ns()
         self.t0, self.c0, self.p0 = _clocks()
         self._t_end: tuple[int, int, int] | None = None  # where the last stage closed
@@ -573,6 +615,11 @@ class StepTimer:
             self._head = None
         self._step.__exit__(None, None, None)
         self._step = None
+        if self._sampler is not None and \
+                time.perf_counter_ns() - self.t0 >= 1e9 * self._log.stall_after_s():
+            # read at the close of a LONG step, and only then: the file is
+            # empty unless the watchdog woke late (``machine._Sampler``)
+            self.dump = self._sampler.take(self.t0_ns)
         if self._log._current is self:
             self._log._current = None
         if getattr(_ACTIVE, "timer", None) is self:
@@ -586,6 +633,7 @@ class StepTimer:
         # must not show up as unaccounted step time — with it excluded the
         # stages tile the wall by construction
         now, t1_ns = _clocks(), time.time_ns()
+        m1 = machine.counters().read() if self._m0 is not None else ()
         if self._stage is not None:
             self._close_stage(now)
         end = self._t_end if self.stages else now
@@ -616,6 +664,11 @@ class StepTimer:
         _fold_events(rec, _take_events() if log.enabled else (), self.t0, self.ident)
         if log.enabled:
             log._last_end = (*end, self.ident)
+        rec.update({k: round(b - a, 3) for k, a, b in zip(machine.MACHINE_KEYS, self._m0 or (), m1)
+                    if a is not None and b is not None})
+        if self.dump is not None:  # beside the watchdog's photograph, where there is one
+            rec["stall_dump_n"] = self.dump["dump_n"]
+            rec.setdefault("stall", {"batcher": threading.current_thread().name}).update(self.dump)
         if self.admissions:
             rec["admissions"] = self.admissions
         rec.update({k: v for k, v in meta.items() if v is not None})
@@ -641,7 +694,7 @@ def annotation(name: str, step: bool = False, **attrs):
     return (StepTraceAnnotation if step else TraceAnnotation)(name, **attrs)
 
 
-_GLOBAL_STEPLOG = StepLog()
+_GLOBAL_STEPLOG = StepLog(sampler=True)
 
 
 def get_steplog() -> StepLog:
