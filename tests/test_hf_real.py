@@ -197,6 +197,123 @@ class TestHFTokenizer:
         assert any(tok.id_to_tok[i].startswith("<0x") for i in ids)
 
 
+def _hf_prompt_cuts(rng):
+    """Heads and continuations cut out of rendered prompts, anywhere."""
+    for text in ("search for wireless headphones", "open the 2nd result, then don't wait"):
+        p = render_prompt(text, {"last_query": "red  shoes"})
+        for _ in range(40):
+            k = rng.randrange(len(p) + 1)
+            yield p[:k], p[k:k + rng.randrange(0, 80)]
+
+
+def _hf_recut_by_the_regex(rng):
+    """What the pre-tokenizer's look-ahead can still re-cut at the head's end:
+    a run of spaces that loses its last one to the next word, a contraction
+    completed by the continuation, a word, a number and a run of punctuation
+    that go on."""
+    for head, more in (("it'l", "l do"), ("we'", "ve gone"), ("a  ", "b"), ("a   ", " b"), ("a \n", "\nb"),
+                       ("page 12", "34 of"), ("wait..", ".!"), ("click the butt", "on now"), ("a ", "'s"),
+                       ("x'", "s"), ("tab\t", "\t\tend"), ("end ", ""), ("end  ", "  ")):
+        for lead in ("", "open the settings menu and turn on dark mode, then "):
+            yield lead + head, more
+
+
+def _hf_added_token_across_the_cut(rng):
+    """An added token that begins in the head and ends behind it closes the
+    segment before it, so the head's last pre-tokens re-cut."""
+    special = "<|end_of_text|>"
+    for k in range(len(special) + 1):
+        for lead in ("go back  ", "a", "it'll be  ", special + " then  ", ""):
+            yield lead + special[:k], special[k:] + " and on"
+    yield "a" + special, "b"
+    yield special + special, special
+    # (and one that is the head of a longer one, where the vocabulary has such)
+    yield "go back " + special, "! and on"
+    yield special, "!"
+
+
+def _hf_multibyte(rng):
+    alphabet = "é ü → “q” 漢字 😀 naïve 12 a'll  "
+    for _ in range(80):
+        a = "".join(rng.choice(alphabet) for _ in range(rng.randrange(0, 40)))
+        b = "".join(rng.choice(alphabet) for _ in range(rng.randrange(0, 24)))
+        yield a, b
+
+
+def _hf_short_or_no_continuation(rng):
+    p = render_prompt("go back", {})
+    for k in range(0, 24):
+        yield p[:k], p[k:k + 30]
+        yield p[len(p) - k:], ""
+
+
+HF_KINDS = {f.__name__[4:]: f for f in (
+    _hf_prompt_cuts, _hf_recut_by_the_regex, _hf_added_token_across_the_cut, _hf_multibyte,
+    _hf_short_or_no_continuation)}
+
+
+def _bytelevel_variant(path, added: str) -> HFTokenizer:
+    """The fixture's vocabulary with its added tokens as they are (``as_is``),
+    with none (``none``: the regex's own look-ahead is then all that is left
+    open; the contractions it cuts are merged there as a trained vocabulary
+    merges them, so a re-cut one changes ids) or with one more that an added
+    token is the head of (``nested``)."""
+    tok = load_hf_tokenizer(path)
+    if added == "as_is":
+        return tok
+    extra = {} if added == "none" else {**tok.added, "<|end_of_text|>!": tok.vocab_size}
+    vocab = {t: i for t, i in tok.vocab.items() if t not in tok.added or t in extra or i == tok.eos_id}
+    merges = sorted(tok.ranks, key=tok.ranks.get)
+    for tail in ("ll", "re", "ve", "s", "t"):
+        merges.append(("'", tail))
+        vocab["'" + tail] = max(vocab.values()) + 1
+    return HFTokenizer(vocab=vocab, merges=merges, kind="byte_level", added=extra, eos="<|end_of_text|>")
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("kind", sorted(HF_KINDS))
+@pytest.mark.parametrize("added", ["as_is", "none", "nested"])
+def test_bytelevel_stable_prefix_and_the_rest_are_the_whole_encoding(added, kind, seed, bytelevel_tokenizer_json):
+    """``stable_prefix``'s promise (``grammar.tokenizer.Tokenizer``'s, held by
+    ``tests/test_stable_prefix.py`` for the in-tree vocabulary) for byte-level
+    BPE: whole pre-tokens, less what the regex and the added tokens can still
+    re-cut — ``ids + encode(rest) == encode(whole)``, id for id."""
+    import random
+
+    tok = _bytelevel_variant(bytelevel_tokenizer_json, added)
+    pairs = list(HF_KINDS[kind](random.Random(f"{kind}/{seed}")))
+    assert len(pairs) >= 16
+    kept = 0
+    for head, more in pairs:
+        ids, n = tok.stable_prefix(head)
+        whole = (head + more).encode()
+        assert ids + tok.encode(whole[n:]) == tok.encode(head + more), (head[-40:], more[:40])
+        assert tok.encode(head)[:len(ids)] == ids and n <= len(head.encode())
+        whole[:n].decode()  # the cut lies between characters
+        kept += len(ids)
+    assert kept > 0  # it does promise something
+
+
+def test_bytelevel_stable_prefix_keeps_all_but_the_last_pretokens(bytelevel_tokenizer_json):
+    """The head of a prompt keeps nearly all of its ids: what the added
+    tokens' length and the regex's one character of look-ahead leave open is
+    its last two dozen bytes."""
+    tok = load_hf_tokenizer(bytelevel_tokenizer_json)
+    head = render_prompt("sample", {})
+    ids, n = tok.stable_prefix(head)
+    assert len(head.encode()) - 40 < n < len(head.encode())
+    assert len(ids) > len(tok.encode(head)) - 16
+
+
+def test_sentencepiece_promises_nothing_stable(sp_tokenizer_json):
+    """Merges run over a whole segment there: no id of a head is safe from
+    what follows, so nothing is kept and every prompt is encoded whole."""
+    tok = load_hf_tokenizer(sp_tokenizer_json)
+    for head in ("", "the cat", render_prompt("go back", {})):
+        assert tok.stable_prefix(head) == ([], 0)
+    assert tok.encode("the cat".encode()) == tok.encode("the cat")
+
+
 class TestVocabSizedFSM:
     def test_fsm_over_hf_vocab_walks_grammar(self, bytelevel_tokenizer_json):
         tok = load_hf_tokenizer(bytelevel_tokenizer_json)

@@ -597,6 +597,7 @@ def test_a_member_that_fails_in_its_host_half_fails_alone(fault):
     from tpu_voice_agent.utils import chaos
 
     eng = _grouping()
+    want = _per_slot_plans(4)  # before this batcher holds slots: it decodes on the same engine
     prompts = _group_prompts(4)
     if fault == "oversized":
         prompts[1] = prompts[1] + " and then scroll down" * 200
@@ -608,7 +609,6 @@ def test_a_member_that_fails_in_its_host_half_fails_alone(fault):
         _, (calls, rows, batched) = _admit_counts(bat.step)
     finally:
         chaos.reset()
-    want = _per_slot_plans(4)
     if fault == "pool_exhausted":
         # the first is launched alone when the second breaks the loop off
         assert (calls, rows, batched) == (1, 1, 0) and [r for r, _ in bat.pending] == rids[1:]
@@ -662,3 +662,128 @@ def test_what_the_grouped_path_does_not_take_goes_through_prefill_slot(case, mon
     bat.reset()
     if case != "radix":  # whose tree keeps the finished requests' chains
         assert eng.allocator.blocks_in_use == len(eng._prefix_blocks[0])
+
+
+# ---------------------------------------------------------------- the head's ids, kept (ISSUE 53)
+
+SITE_TOKENS = 145  # the rehearsal's: 879 + 145 = 1024, eight whole blocks
+
+
+@functools.lru_cache(maxsize=None)
+def _two_slots():
+    """A dense engine whose top bucket holds the prompt head with the
+    rehearsal's site context in it. Shared: a test installs the head it needs."""
+    from tpu_voice_agent.serve import DecodeEngine
+
+    return DecodeEngine(preset="test-tiny", max_len=2048, batch_slots=2, prefill_buckets=(64, 1024))
+
+
+@pytest.fixture()
+def site():
+    """``site(tokens)`` puts a site context of so many tokens into the
+    process's prompt head (the cells' own text, seed 60); gone behind the test."""
+    from benchmark.builders.dots3_stack import site_context_text
+    from tpu_voice_agent.services import prompts
+
+    yield lambda tokens: prompts.set_site_context(site_context_text(_two_slots().tokenizer, tokens, 60))
+    prompts.set_site_context("")
+
+
+@pytest.mark.parametrize("site_tokens", [0, SITE_TOKENS], ids=["bare-head", "site-context"])
+def test_encode_prompt_is_the_tokenizers_whole_walk_id_for_id(site_tokens, site):
+    """Every text of the cells' corpus, behind the bare head and behind a site
+    context: the memo's ids and the walk behind them are ``encode(prompt,
+    bos=True)``, and all but the head's last pieces came from the memo."""
+    from benchmark.lib.corpus import texts
+    from tpu_voice_agent.services.brain import install_prompt_prefix
+    from tpu_voice_agent.services.prompts import render_prompt
+
+    eng = _two_slots()
+    site(site_tokens)
+    assert install_prompt_prefix(eng) == 879 + site_tokens
+    head, kept, tail = eng._head
+    assert head.endswith('<|user|>\n{"text":"') and kept[:len(eng.prefix_ids) - 8] == eng.prefix_ids[:-8]
+    assert 0 < len(tail) < 28 and len(eng.prefix_ids) - 8 < len(kept) <= len(eng.tokenizer.encode(head, bos=True))
+    for text in texts(64):
+        for context in ({}, {"last_query": "red shoes", "page": 2}):
+            prompt = render_prompt(text, context)
+            assert eng.encode_prompt(prompt) == (eng.tokenizer.encode(prompt, bos=True), len(kept))
+
+
+def test_a_prompt_without_the_head_and_a_list_of_ids_pass_through(site, tiny_batch_engine):
+    """What does not start with the head's text is encoded whole — a bare
+    text, a ``feed_prefix`` partial cut inside the head, a prompt that leaves
+    the head a character early — and ids come back as they went in."""
+    from tpu_voice_agent.services.brain import install_prompt_prefix
+    from tpu_voice_agent.services.prompts import render_prompt
+
+    eng = _two_slots()
+    site(0)
+    install_prompt_prefix(eng)
+    tok, head = eng.tokenizer, eng._head[0]
+    for prompt in ("go back", head[:len(head) // 2], head[:-1], head[:-3] + "x" + head[-2:] + 'go back"}', ""):
+        assert eng.encode_prompt(prompt) == (tok.encode(prompt, bos=True), 0)
+    ids = tok.encode(render_prompt("go back", {}), bos=True)
+    assert eng.encode_prompt(ids) == (ids, 0) == eng.encode_prompt(tuple(ids))
+    # the head's text alone: all of the memo, the walk over its last bytes
+    assert eng.encode_prompt(head) == (tok.encode(head, bos=True), len(eng._head[1]))
+    # and an engine that was told no head keeps none
+    assert tiny_batch_engine._head is None
+    assert tiny_batch_engine.encode_prompt(render_prompt("go back", {})) == (ids, 0)
+
+
+def test_the_memo_follows_the_head_that_set_prompt_prefix_is_given(site):
+    """``set_site_context`` + ``set_prompt_prefix`` again replace the head's
+    text and ids together with ``prefix_ids``; between the two a prompt is
+    rendered with a head the engine does not hold, and is encoded whole."""
+    from tpu_voice_agent.services.brain import install_prompt_prefix
+    from tpu_voice_agent.services.prompts import render_prompt
+
+    eng = _two_slots()
+    tok = eng.tokenizer
+
+    def reused(text="scroll down"):
+        prompt = render_prompt(text, {})
+        ids, n = eng.encode_prompt(prompt)
+        assert ids == tok.encode(prompt, bos=True)
+        return n
+
+    site(0)
+    install_prompt_prefix(eng)
+    bare = reused()
+    assert 850 < bare == len(eng._head[1]) <= 879 + 8
+    site(SITE_TOKENS)
+    assert reused() == 0  # the new head's prompts miss the old head's text
+    install_prompt_prefix(eng)
+    assert reused() == len(eng._head[1]) == bare + SITE_TOKENS and len(eng.prefix_ids) == 1024
+    site(0)
+    assert reused() == 0
+    install_prompt_prefix(eng)
+    assert reused() == bare
+
+
+def test_an_admissions_entry_says_how_many_ids_the_memo_gave():
+    """``head_ids_reused`` in the step ledger's entry and ``admit.head_ids_reused``
+    in the registry: the memo's ids for a prompt behind the head (grouped or
+    not), 0 for one that is not; the parts still tile the request."""
+    from tpu_voice_agent.utils import get_metrics
+    from tpu_voice_agent.utils.steplog import ADMISSION_PARTS, get_steplog
+
+    eng = _grouping()
+    kept = len(eng._head[1])
+    assert 850 < kept <= 879 + 8
+    bat = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=16)
+    hits = [bat.submit(p) for p in _group_prompts(3)]
+    misses = [bat.submit(t) for t in GROUP_TEXTS[:2]] + [bat.submit(eng.tokenizer.encode(GROUP_TEXTS[2], bos=True))]
+    before = get_metrics().counter_state()[0].get("admit.head_ids_reused", 0.0)
+    seq = (get_steplog().last() or {"seq": -1})["seq"]
+    bat.run_until_done()
+    assert get_metrics().counter_state()[0]["admit.head_ids_reused"] - before == 3 * kept
+    entries = {a["rid"]: a for s in get_steplog().steps() if s["seq"] > seq for a in s.get("admissions", [])}
+    assert set(entries) == set(hits + misses) and all(bat.results[r].error is None for r in entries)
+    for rid, a in entries.items():
+        assert a["head_ids_reused"] == (kept if rid in hits else 0) <= a["prompt_tokens"]
+        assert (a["cached_tokens"] == 879) == (rid in hits)  # ``_split_prefix`` matched the memo's ids
+        assert "tokenize_ms" in a
+        assert sum(a.get(f"{p}_ms", 0.0) for p in ADMISSION_PARTS) <= a["request_ms"] + 1e-3, a
+    bat.reset()

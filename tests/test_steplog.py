@@ -653,6 +653,7 @@ def test_admissions_tile_their_request_and_count_admitted(scope_engine):
     for a in adm:
         assert {f"{p}_ms" for p in ADMISSION_PARTS if p != "bookkeeping"} <= set(a)
         assert a["prompt_tokens"] > 0 and a["cached_tokens"] == 0 and a["rid"] >= 0
+        assert a["head_ids_reused"] == 0  # no head was installed: every id was walked
     _assert_parts_tile(adm)
     # the result carries the request's own queue wait
     assert sorted(round(r.queue_ms, 3) for r in res) == sorted(

@@ -9,7 +9,8 @@ PROFILED program. This serves one cell of ``BENCHMARK.json`` as
 ``benchmark/run.py`` does — its configuration through ``benchmark/builders``,
 its traffic file through the load generator's process — for ``--seconds``,
 and prints the medians of the ring's new keys over the steps of the window:
-the head of a step (``head_ms``, its off-CPU share, others' CPU in it), the
+the head of a step (``head_ms``, its off-CPU share, others' CPU in it; what
+its admissions spent tokenizing and how many ids the head's memo gave them), the
 gap before a step, the lock wait and the collections a step, every stage's
 wall / CPU / others' CPU (``others_cpu_ms["readback"]`` with one client is
 the runtime's floor), the wake latency of an answer, collections a second,
@@ -94,6 +95,7 @@ def summary(steps: list[dict], seconds: float) -> dict:
         return {"steps": 0}
     adm = [s for s in steps if s.get("admitted") and "head_ms" in s]
     head = sum(s["head_ms"] for s in adm)
+    admissions = [a for s in adm for a in s.get("admissions", [])]
     out = {
         "steps": len(steps), "wall_ms": med(s["wall_ms"] for s in steps),
         "head_ms": med(s["head_ms"] for s in adm),
@@ -102,6 +104,13 @@ def summary(steps: list[dict], seconds: float) -> dict:
         "head_ms_mean": mean(s["head_ms"] for s in adm),
         "head_cpu_ms_mean": mean(s["head_cpu_ms"] for s in adm),
         "head_others_cpu_ms_mean": mean(s["head_others_cpu_ms"] for s in adm),
+        # what the head holds of tokenizing (ISSUE 53): an admission's walk, a step's
+        # admissions' walks together, and the ids an admission took from the engine's
+        # memo of the prompt head (a program that keeps none writes no such key)
+        "tokenize_ms": med(a["tokenize_ms"] for a in admissions if "tokenize_ms" in a),
+        "tokenize_ms_per_step_mean": mean(sum(a.get("tokenize_ms", 0.0) for a in s.get("admissions", []))
+                                          for s in adm),
+        "head_ids_reused": med(a["head_ids_reused"] for a in admissions if "head_ids_reused" in a),
         "gap_ms": med(s["gap_ms"] for s in adm), "gap_cpu_ms_mean": mean(s["gap_cpu_ms"] for s in adm),
         "gap_others_cpu_ms_mean": mean(s["gap_others_cpu_ms"] for s in adm),
         "lock_wait_ms_per_step": round(sum(s["lock_wait_ms"] for s in steps) / len(steps), 4),

@@ -175,6 +175,7 @@ class HFTokenizer:
             if specials
             else None
         )
+        self._longest_special = len(specials[0]) if specials else 0
         self._b2u = _byte_to_unicode()
 
     # ------------------------------------------------------------ encode
@@ -193,14 +194,17 @@ class HFTokenizer:
                     ids.append(bt)
         return ids
 
+    def _encode_pretoken(self, piece: str) -> list[int]:
+        """Byte-level: one pre-token's bytes, remapped and merged."""
+        return self._encode_word(tuple(self._b2u[b] for b in piece.encode()))
+
     def _encode_segment(self, text: str) -> list[int]:
         if not text:
             return []
         if self.kind == "byte_level":
             ids: list[int] = []
             for m in _PRETOK.finditer(text):
-                mapped = "".join(self._b2u[b] for b in m.group(0).encode())
-                ids.extend(self._encode_word(tuple(mapped)))
+                ids.extend(self._encode_pretoken(m.group(0)))
             return ids
         # sentencepiece: the Prepend normalizer applies to EVERY non-special
         # segment (HF runs normalization per split piece, so text following
@@ -210,7 +214,10 @@ class HFTokenizer:
             norm = self.prepend + norm
         return self._encode_word(tuple(norm))
 
-    def encode(self, text: str, bos: bool = False, eos: bool = False) -> list[int]:
+    def encode(self, text: str | bytes, bos: bool = False, eos: bool = False) -> list[int]:
+        if isinstance(text, bytes):
+            # what lies behind a ``stable_prefix``: cut between characters
+            text = text.decode()
         ids: list[int] = [self.bos_id] if bos else []
         if self._special_split is not None:
             for part in self._special_split.split(text):
@@ -223,6 +230,40 @@ class HFTokenizer:
         if eos:
             ids.append(self.eos_id)
         return ids
+
+    def stable_prefix(self, text: str) -> tuple[list[int], int]:
+        """The ids of ``encode(text)`` that no continuation of ``text`` can
+        change, and the bytes they cover (``grammar.tokenizer.Tokenizer.
+        stable_prefix`` has the contract). Byte-level BPE merges inside one
+        pre-token, so whole pre-tokens stand — less the last ones that more
+        text can still re-cut: ``_PRETOK`` reads at most one character past a
+        match's end (the look-ahead of ``\\s+(?!\\S)``, the third character of
+        ``'ll``), and an added token may begin in the last characters and be
+        completed behind them, which ends the segment there. A
+        sentencepiece-style vocabulary merges over a whole segment: it
+        promises nothing, and nothing is stable."""
+        if self.kind != "byte_level":
+            return [], 0
+        n = len(text)
+        S = self._longest_special
+        last_end = n - max(S - 1, 0) - 2  # a pre-token that ends later may move
+        parts = self._special_split.split(text) if S else [text]
+        ids: list[int] = []
+        pos = end = 0
+        for part in parts:
+            if part in self.added:
+                if pos + S > n:  # a longer added token may still match here
+                    break
+                ids.append(self.added[part])
+                pos = end = pos + len(part)
+                continue
+            for m in _PRETOK.finditer(part):
+                if pos + m.end() > last_end:
+                    return ids, len(text[:end].encode())
+                ids.extend(self._encode_pretoken(m.group(0)))
+                end = pos + m.end()
+            pos += len(part)
+        return ids, len(text[:end].encode())
 
     # ------------------------------------------------------------ decode
 

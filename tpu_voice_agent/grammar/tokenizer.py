@@ -85,8 +85,9 @@ class Tokenizer:
 
     Exposes the interface every tokenizer in the framework satisfies
     (grammar.hf_tokenizer.HFTokenizer is the real-checkpoint twin):
-    ``encode/decode/token_bytes/byte_pieces``, ``vocab_size`` and the
-    instance special ids ``pad_id/bos_id/eos_id`` (engines must use these,
+    ``encode/decode/token_bytes/byte_pieces``, ``stable_prefix`` (what no
+    continuation of a text can change: the engine keeps the prompt head's ids
+    by it), ``vocab_size`` and the instance special ids ``pad_id/bos_id/eos_id`` (engines must use these,
     never the module constants — real checkpoints place them elsewhere).
     """
 
@@ -104,6 +105,7 @@ class Tokenizer:
             for b in piece:
                 node = node.setdefault(b, {})
             node[-1] = idx + len(SPECIALS)
+        self._longest = max(len(p) for p in pieces)
 
     @classmethod
     def build(
@@ -128,12 +130,24 @@ class Tokenizer:
                 add(piece)
         return cls(pieces)
 
-    def encode(self, text: str, bos: bool = False, eos: bool = False) -> list[int]:
-        data = text.encode()
-        ids: list[int] = [BOS_ID] if bos else []
+    def encode(self, text: str | bytes, bos: bool = False, eos: bool = False) -> list[int]:
+        """Ids of ``text``. Bytes are walked as they are: what lies behind a
+        ``stable_prefix`` may start inside a multi-byte character."""
+        data = text if isinstance(text, bytes) else text.encode()
+        ids, _ = self._walk(data, len(data))
+        if bos:
+            ids.insert(0, BOS_ID)
+        if eos:
+            ids.append(EOS_ID)
+        return ids
+
+    def _walk(self, data: bytes, stop: int) -> tuple[list[int], int]:
+        """The greedy longest-match walk over ``data``: the ids of the tokens
+        that start before byte ``stop``, and the byte behind the last of them."""
+        ids: list[int] = []
         i = 0
         n = len(data)
-        while i < n:
+        while i < stop:
             node = self._trie
             best_id = None
             best_len = 0
@@ -150,9 +164,16 @@ class Tokenizer:
                 best_len = 1
             ids.append(best_id)
             i += best_len
-        if eos:
-            ids.append(EOS_ID)
-        return ids
+        return ids, i
+
+    def stable_prefix(self, text: str) -> tuple[list[int], int]:
+        """The ids of ``encode(text)`` that no continuation of ``text`` can
+        change, and the bytes they cover: a token that starts at byte i is
+        decided by bytes i .. i + longest piece - 1, so it stands where those
+        all lie inside ``text``. For every ``more``:
+        ``ids + encode((text + more).encode()[n_bytes:]) == encode(text + more)``."""
+        data = text.encode()
+        return self._walk(data, len(data) - self._longest + 1)
 
     def decode(self, ids: list[int]) -> str:
         out = b"".join(self.token_bytes(i) for i in ids)

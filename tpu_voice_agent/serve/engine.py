@@ -13,6 +13,7 @@ Replaces the reference's OpenAI chat.completions call (apps/brain/src/llm.ts:
 
 from __future__ import annotations
 
+import os
 import time
 from typing import Any
 from dataclasses import dataclass, replace, field
@@ -873,6 +874,10 @@ class DecodeEngine:
         # shared-prefix cache: token ids + their precomputed KV (L,1,P,nkv,hd)
         self.prefix_ids: list[int] = []
         self.prefix_kv: dict | None = None
+        # the prompt head's text, the ids of it that no suffix can change (BOS
+        # first) and the head's bytes behind them: ``encode_prompt``'s memo,
+        # replaced together with the two above
+        self._head: tuple[str, list[int], bytes] | None = None
         # speculative decoding (serve.spec): built LAST — the decoder reads
         # engine tables/cache geometry, and a draft-model drafter allocates
         # its own KV against batch_slots/max_len. Layout subclasses whose
@@ -998,6 +1003,7 @@ class DecodeEngine:
         if len(sample_prompts) < 2:
             raise ValueError("need >= 2 sample prompts to locate the shared prefix")
         encs = [self.tokenizer.encode(p, bos=True) for p in sample_prompts]
+        self._head = self._stable_head(sample_prompts, encs)
         P = 0
         shortest = min(len(e) for e in encs)
         while P < shortest and all(e[P] == encs[0][P] for e in encs):
@@ -1015,6 +1021,40 @@ class DecodeEngine:
             jnp.asarray(tokens), jnp.asarray(positions), P, bucket)
         self.prefix_ids = ids
         return P
+
+    def _stable_head(self, sample_prompts, encs) -> tuple | None:
+        """``encode_prompt``'s memo from the samples: their common STRING
+        prefix is the head's text, and what the tokenizer promises of it
+        (``stable_prefix``) is kept. None where it promises nothing: every
+        prompt is then encoded whole."""
+        stable_prefix = getattr(self.tokenizer, "stable_prefix", None)
+        if stable_prefix is None:
+            return None
+        head = os.path.commonprefix(sample_prompts)
+        ids, n_bytes = stable_prefix(head)
+        if not ids:
+            return None
+        ids = [self.tokenizer.bos_id] + ids
+        if any(e[:len(ids)] != ids for e in encs):
+            raise ValueError(
+                f"{type(self.tokenizer).__name__}.stable_prefix broke its promise: "
+                f"its {len(ids)} ids are not the head of a sample prompt's encoding")
+        return head, ids, head.encode()[n_bytes:]
+
+    def encode_prompt(self, prompt) -> tuple[list[int], int]:
+        """A rendered prompt's ids (BOS first), and how many of them came
+        from the head's memo — THE one place a prompt becomes ids. A ``str``
+        that starts with the head's text takes the memo's ids and the
+        tokenizer's walk from the memo's last byte on (id for id what the
+        whole walk gives: ``stable_prefix``'s promise); any other ``str`` is
+        encoded whole; a list of ids is itself."""
+        if not isinstance(prompt, str):
+            return [int(t) for t in prompt], 0
+        memo = self._head  # read once: ``set_prompt_prefix`` may replace it on another thread
+        if memo is not None and prompt.startswith(memo[0]):
+            head, ids, tail = memo
+            return ids + self.tokenizer.encode(tail + prompt[len(head):].encode()), len(ids)
+        return self.tokenizer.encode(prompt, bos=True), 0
 
     def _cached_prefix_len(self, P: int) -> int:
         """How much of the common token prefix is cached (all of it)."""
@@ -1251,7 +1291,7 @@ class DecodeEngine:
                 "single-request generate() requires batch_slots=1; batched decode "
                 "is driven by the continuous-batching scheduler (serve.scheduler)"
             )
-        ids = self.tokenizer.encode(prompt, bos=True)
+        ids, _ = self.encode_prompt(prompt)
         return self.prefill_slot(ids, 0), len(ids)
 
     def _admit_first_token(self, prompt: str, temperature: float,

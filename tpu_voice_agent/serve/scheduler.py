@@ -622,10 +622,10 @@ class ContinuousBatcher:
                         slot, self.tenancy.resolve(self._tenant.get(rid)))
             t0 = time.perf_counter()
             with span(f"{REQUEST_SPAN}.tokenize"):
-                ids = (eng.tokenizer.encode(prompt, bos=True)
-                       if isinstance(prompt, str) else [int(t) for t in prompt])
+                ids, reused = eng.encode_prompt(prompt)
             n = len(ids)
-            req.set(prompt_tokens=n)
+            req.set(prompt_tokens=n, head_ids_reused=reused)
+            get_metrics().inc("admit.head_ids_reused", float(reused))
             C = self._prefill_chunk
             if C > 0 and n > C:
                 with span(ALLOC_SPAN):
@@ -888,8 +888,7 @@ class ContinuousBatcher:
             return {"ok": False, "reason": "no_slot"}
         if self.tenancy is not None:
             eng.set_slot_ns(slot, self.tenancy.resolve(tenant))
-        ids = (eng.tokenizer.encode(prompt, bos=True)
-               if isinstance(prompt, str) else [int(t) for t in prompt])
+        ids, _ = eng.encode_prompt(prompt)
         try:
             eng.prefill_slot(ids, slot)
         except PoolExhausted:
@@ -957,8 +956,7 @@ class ContinuousBatcher:
             return {"ok": False, "reason": "no_slot"}
         if self.tenancy is not None:
             eng.set_slot_ns(slot, self.tenancy.resolve(tenant))
-        ids = (eng.tokenizer.encode(prompt, bos=True)
-               if isinstance(prompt, str) else [int(t) for t in prompt])
+        ids, _ = eng.encode_prompt(prompt)
         bs = eng.block_size
         pb = len(eng._prefix_blocks[0])
         ship_cap = (len(ids) - 1) // bs

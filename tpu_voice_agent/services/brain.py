@@ -172,10 +172,16 @@ class SessionTranscripts:
     N's prompt would silently stop extending turn N-1's).
     """
 
-    def __init__(self, tokenizer, max_sessions: int | None = None):
+    def __init__(self, tokenizer, max_sessions: int | None = None,
+                 encode_prompt=None):
         from collections import OrderedDict
 
         self.tokenizer = tokenizer
+        # how a rendered prompt becomes ids: the serving engine's
+        # ``encode_prompt`` (the head's ids are kept there), else the
+        # tokenizer's whole walk
+        self._encode_prompt = encode_prompt or (
+            lambda prompt: (tokenizer.encode(prompt, bos=True), 0))
         self.max_sessions = max_sessions if max_sessions is not None else int(
             os.environ.get("RADIX_SESSIONS", "256"))
         self._hist: "OrderedDict[str, list[int]]" = OrderedDict()
@@ -202,7 +208,7 @@ class SessionTranscripts:
 
     def record(self, session_id: str, prompt, generated_ids: list[int]) -> None:
         """Commit a finished turn: the next prompt extends prompt+output."""
-        ids = (self.tokenizer.encode(prompt, bos=True)
+        ids = (self._encode_prompt(prompt)[0]
                if isinstance(prompt, str) else list(prompt))
         with self._lock:
             self._hist[session_id] = ids + [int(t) for t in generated_ids]
@@ -270,7 +276,8 @@ class BatchedEngineParser:
         # keeps the exact pre-radix parse(text, context) contract
         self.wants_session = session_aware
         self.supports_speculation = True
-        self.transcripts = (SessionTranscripts(engine.tokenizer)
+        self.transcripts = (SessionTranscripts(engine.tokenizer,
+                                               encode_prompt=engine.encode_prompt)
                             if session_aware else None)
         # sid -> two-phase spec turn; LRU-capped like the transcripts — a
         # session that speculates and then disconnects must not leak its
@@ -516,7 +523,7 @@ class BatchedEngineParser:
         leave the decode budget's headroom before max_len."""
         eng = self.engine
         limit = min(eng.prefill_buckets[-1], eng.max_len - self.max_new_tokens)
-        n = (len(eng.tokenizer.encode(prompt, bos=True))
+        n = (len(eng.encode_prompt(prompt)[0])
              if isinstance(prompt, str) else len(prompt))
         return n > limit
 

@@ -65,7 +65,7 @@ class TPUSummarizer:
         prompt = None
         for cap in (4000, 2000, 1000, 500, 240, 100, 40):
             prompt = render_summarize_prompt(title, body, max_body_chars=cap)
-            if len(engine.tokenizer.encode(prompt, bos=True)) <= limit:
+            if len(engine.encode_prompt(prompt)[0]) <= limit:
                 break
         else:
             # even the smallest cap overflows (sub-word-bucket engine):

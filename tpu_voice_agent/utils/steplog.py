@@ -44,7 +44,10 @@ duration into the step's record. The step itself is a
                                      launch (the first ``.prefill_call`` to
                                      enter, else ``sched.decode_dispatch``)
         sched.admit.request          one admission; attrs rid, queue_ms,
-                                     prompt_tokens, cached_tokens
+                                     prompt_tokens, head_ids_reused (of
+                                     them, the ids the engine's memo of the
+                                     prompt head gave: ``encode_prompt``),
+                                     cached_tokens
           .tokenize .alloc .first_token_call .slot_state .bookkeeping
                                      its parts (``ADMISSION_PARTS``), in
                                      code order
