@@ -14,8 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tests.test_admit_group import _model
-from tpu_voice_agent.models import dots3, llama, mla, sambay
+from tests.test_admit_group import _model as _older_model
+from tpu_voice_agent.models import dots3, llama, mla, olmo_hybrid, sambay
 from tpu_voice_agent.models.family import FFN, family
 from tpu_voice_agent.serve import ContinuousBatcher, DecodeEngine, PagedDecodeEngine
 from tpu_voice_agent.serve.spec import SpecConfig
@@ -26,6 +26,16 @@ MODELS = ("dense", "routed", "hybrid", "share", "latent", "sparse")
 FAMILY = {"dense": "plain", "routed": "plain", "hybrid": "hybrid", "share": "plain",
           "latent": "latent", "sparse": "sparse"}
 SLOTS, BS, BLOCKS = 8, 128, 24
+
+
+def _model(model: str) -> dict:
+    """``tests/test_admit_group.py``'s six, and the family that came behind them
+    (a delta-rule state: ``models/olmo_hybrid.py``, its own test preset)."""
+    if model != "gdn":
+        return _older_model(model)
+    from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
+
+    return dict(cfg=olmo_hybrid.PRESETS["olmo-hybrid-test"], tokenizer=default_tokenizer())
 
 
 def _engine(model: str, cls=PagedDecodeEngine, **over):
@@ -209,3 +219,66 @@ def test_a_chunk_counts_what_the_record_names_in_its_order(model, catalog, monke
         for metric, v in zip(metrics, np.asarray(values)):
             assert catalog(metric), metric
             assert after.get(metric, 0.0) - before.get(metric, 0.0) == float(v), metric
+
+
+# ---------------------------------------------------------------- a third state-carrying family
+
+
+def test_the_gdn_record_is_read_like_any_other():
+    """The record of a family that came behind ISSUE 46's six (a delta-rule
+    matrix state beside K/V): the engine builds, compiles and counts from it
+    with no line of its own."""
+    from tpu_voice_agent.ops import gated_delta
+
+    cfg = _cfg("gdn")
+    fam = family(cfg)
+    assert fam.name == "gdn" and fam.module is olmo_hybrid and fam.error is sambay.StateNotCarried
+    assert set(fam.refuses) <= set(FEATURES) and fam.cache == olmo_hybrid.cache_spec(cfg)
+    assert [c.keyword for c in fam.counts] == ["hybrid_stats", "attn_stats"]
+    assert fam.count("hybrid").metrics == olmo_hybrid.HYBRID_STATS
+    assert (fam.n_real, fam.one_head, fam.block_real, fam.pack_rows, fam.scratch_prefix) == (
+        "always", True, False, 96, True)
+    eng = _engine("gdn")
+    spec = eng.family.cache
+    assert eng.family is family(eng.cfg) and eng._cache_spec is spec and spec["state_column"]
+    assert (eng.hybrid, eng.latent, eng.sparse) == (False, False, False) and eng.ffn_pack_rows == 96
+    for side, pool in (("k", eng.k_pool), ("v", eng.v_pool)):
+        want = {n: ((p[0], BLOCKS, BS, *p[1:]), jnp.bfloat16) for n, p in spec["planes"][side].items()}
+        want.update({n: ((p[0], SLOTS, *p[1:]), dt) for n, (p, dt) in spec["slot_planes"][side].items()})
+        assert {n: (a.shape, a.dtype) for n, a in pool.items()} == want
+    c = eng.cfg
+    assert eng.v_pool["gdn"].shape[2:] == gated_delta.plane_shape(c.gdn_heads, c.gdn_key_dim, c.gdn_value_dim)
+    assert eng.block_tables.shape == (SLOTS, eng.max_blocks + 1)
+    assert np.asarray(eng.block_tables)[:, -1].tolist() == list(range(SLOTS))
+    assert eng.kv_bytes_per_block == BS * fam.token_bytes
+
+
+@pytest.mark.parametrize("feature", FEATURES)
+def test_what_the_gdn_table_refuses_is_refused_by_type_and_nothing_else_is(feature):
+    fam = family(_cfg("gdn"))
+    if feature not in fam.refuses:  # ffn_pack: its position-wise regions always run packed
+        assert feature == "ffn_pack" and _enter(feature, "gdn") == 96
+        return
+    if feature == "chunked_prefill":
+        assert _enter(feature, "gdn") is None
+        return
+    with pytest.raises(sambay.StateNotCarried, match=f"^{feature}: .*(delta-rule|OlmoHybridConfig)"):
+        _enter(feature, "gdn")
+
+
+def test_a_gdn_chunk_counts_what_the_record_names_in_its_order(catalog):
+    from tpu_voice_agent.utils import get_metrics
+
+    eng = _engine("gdn", init_weights=True)
+    eng.ffn_pack_rows = 8  # under the compacted width's 2 x 9 positions
+    before = dict(get_metrics().counter_state()[0])
+    bat = ContinuousBatcher(eng, chunk_steps=2, max_new_tokens=8)
+    bat.submit("go back")
+    res = bat.step()
+    assert list(res.counts) == ["hybrid", "attn", "ffn"]
+    after = get_metrics().counter_state()[0]
+    for count in (*eng.family.counts, FFN):
+        assert res.counts[count.name].shape == (len(count.metrics),)
+        for name, n in zip(count.metrics, np.asarray(res.counts[count.name]).tolist()):
+            assert catalog(name), f"{name} is not in docs/OBSERVABILITY.md"
+            assert after.get(name, 0.0) - before.get(name, 0.0) == n

@@ -460,9 +460,10 @@ def check_catalog(reg: dict[str, dict[str, list[str]]],
 # What a chunk program counts (``models/family.py`` ``Count``): ONE loop of the
 # batcher adds each vector to the counters its record names, built from the
 # modules' own tuples (``llama.moe_stat_names``, ``ops.ATTN_STATS``,
-# ``mla.LATENT_STATS``, ``sambay.HYBRID_STATS``, ``llama.FFN_STATS``) — no
-# ``inc("...")`` a name, so their families are registered where ``Count`` is defined
-COUNTED = ("moe.*", "attn.*", "ssm.*", "ffn.*")
+# ``mla.LATENT_STATS``, ``sambay.HYBRID_STATS``, ``olmo_hybrid.HYBRID_STATS``,
+# ``llama.FFN_STATS``) — no ``inc("...")`` a name, so their families are
+# registered where ``Count`` is defined
+COUNTED = ("moe.*", "attn.*", "ssm.*", "gdn.*", "ffn.*")
 
 
 def scan_source(root: pathlib.Path) -> dict[str, dict[str, list[str]]]:

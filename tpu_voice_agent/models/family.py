@@ -27,7 +27,7 @@ from types import ModuleType
 from typing import Mapping, NamedTuple
 
 from ..ops import ATTN_STATS
-from . import dots3, llama, mla, nemotron_h, sambay
+from . import dots3, llama, mla, nemotron_h, olmo_hybrid, sambay
 
 # the rows the position-wise regions of a fast-forward block are packed into
 # (ISSUE 37: the MLPs; ISSUE 41: q/k/v and the output projection with them):
@@ -140,6 +140,21 @@ _SSD_REFUSES = {
     "dense_cache": "a NemotronHConfig's state lives in the paged pool's per-slot planes and its "
                    "layers are one block of three kinds: forward_paged's, PagedDecodeEngine alone serves it",
 }
+_GDN = ("K/V blocks alone, without the delta-rule state (2.2 MB a layer a request, float32) and the "
+        "convolution tail that go with them: not with an OlmoHybridConfig")
+_GDN_REFUSES = {
+    "kv_quant": f"KV_QUANT re-stores {_GDN}",
+    "radix": f"radix reuse hands a slot cached {_GDN}",
+    "mesh": f"a mesh shards a LlamaConfig's weights and {_GDN}",
+    "spec": "a rejected draft cannot be un-advanced (a delta-rule write READS the state it "
+            f"overwrites): a verify step rolls back (overwrite-before-attend) {_GDN}",
+    "handoff": f"a handoff ships and adopts {_GDN}",
+    "chunked_prefill": "the cursor of a chunked admission carries no count of real positions for "
+                       "the delta-rule state: the one-shot prefill_slot serves it",
+    "dense_cache": "an OlmoHybridConfig's state lives in the paged pool's per-slot planes and its "
+                   "layers are of two kinds under a reordered norm: forward_paged's, "
+                   "PagedDecodeEngine alone serves it",
+}
 _PLANES = "K and V planes by head: a latent cache has none"
 _LATENT_REFUSES = {
     "kv_quant": f"KV_QUANT re-stores {_PLANES}",
@@ -155,9 +170,19 @@ _PAGED_ONLY = {"dense_cache": "layers of more than one kind, a parallel block, a
                               "serves this model, the dense cache does not"}
 
 
+def tree_owner(params: dict) -> ModuleType:
+    """The module whose parameter tree ``params`` is, of the families that keep
+    a tree of their own (each names the key only its tree has, ``TREE_ROOT``)."""
+    return next(m for m in (sambay, nemotron_h, olmo_hybrid) if m.TREE_ROOT in params)
+
+
 @lru_cache(maxsize=256)  # configurations are few, frozen and hashable; the record is read-only
 def family(cfg) -> Family:
     """The record of ``cfg``'s family."""
+    if isinstance(cfg, olmo_hybrid.OlmoHybridConfig):  # a delta-rule matrix state beside K/V
+        hybrid = Count("hybrid", "hybrid_stats", olmo_hybrid.HYBRID_STATS)
+        return Family("gdn", olmo_hybrid, olmo_hybrid.cache_spec(cfg), (hybrid, ATTN),
+                      sambay.StateNotCarried, _GDN_REFUSES, n_real="always", one_head=True)
     if isinstance(cfg, nemotron_h.NemotronHConfig):  # a Mamba-2 state beside K/V, latent experts
         hybrid = Count("hybrid", "hybrid_stats", nemotron_h.HYBRID_STATS)
         routed = Count("moe", "moe_stats", tuple(f"moe.{n}" for n in llama.moe_stat_names(cfg)))

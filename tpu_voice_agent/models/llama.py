@@ -405,10 +405,10 @@ def quantize_params(params: dict) -> dict:
     """Weight-only symmetric int8, per-output-channel scales. Norms and the
     embedding table (a gather, already cheap) stay in their original dtype;
     every matmul weight becomes {"q": int8, "s": f32} resolved by _w()."""
-    if "layers" not in params:  # another family's tree: models.sambay's, models.nemotron_h's
-        from . import nemotron_h, sambay
+    if "layers" not in params:  # another family's tree: the module that owns it quantises it
+        from .family import tree_owner
 
-        return (nemotron_h if "mamba" in params else sambay).quantize_params(params)
+        return tree_owner(params).quantize_params(params)
 
     quant = quantize_leaf
 

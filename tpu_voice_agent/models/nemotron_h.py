@@ -71,6 +71,9 @@ F32 = jnp.float32
 # (each moves its 4.19 MB once in and once out: the scan's floor)
 HYBRID_STATS = ("ssm.positions_advanced", "ssm.positions", "ssm.state_rows_moved")
 
+# the key that only this family's parameter tree has (``family.tree_owner``)
+TREE_ROOT = "mamba"
+
 # packed rows a walk of the E layers takes where the caller names no width (an
 # admission: a group's (4, 64) block whole; the prefix's chunk in four). Every
 # tile of ~100 real positions x 22 picks touches nearly all 128 held experts,

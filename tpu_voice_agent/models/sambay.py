@@ -153,6 +153,8 @@ def cache_spec(cfg: SambaYConfig) -> dict:
 # ---------------------------------------------------------------- params
 
 _INT8 = ("in_proj", "out_proj", "wqkv", "wq", "wo", "w1", "w2")
+# the key that only this family's parameter tree has (``family.tree_owner``)
+TREE_ROOT = "front"
 
 
 def _mix_shapes(cfg: SambaYConfig, kind: str) -> dict:

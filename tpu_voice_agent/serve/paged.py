@@ -367,11 +367,19 @@ def _scatter_blocks(k_pool, v_pool, src_k, src_v, dst_idx):
 
 @watch_compiles("paged._restore_state")
 @partial(jax.jit, donate_argnames=("k_pool", "v_pool"))
-def _restore_state(k_pool, v_pool, conv, ssm, slot):
-    """A slot's recurrent state <- a snapshot (n_layers, ...) of it: the
-    convolution tails in ``k_pool``, the float32 states in ``v_pool``."""
-    return ({**k_pool, "conv": k_pool["conv"].at[:, slot].set(conv)},
-            {**v_pool, "ssm": v_pool["ssm"].at[:, slot].set(ssm)})
+def _restore_state(k_pool, v_pool, k_slot, v_slot, slot):
+    """A slot's planes <- a snapshot of them, {plane: (n_layers, ...)} a pool,
+    whatever the family's record names them (``cache_spec``'s ``slot_planes``:
+    a convolution tail in ``k_pool`` and a float32 state in ``v_pool`` for the
+    three families that keep one)."""
+    put = lambda pool, snap: {**pool, **{n: pool[n].at[:, slot].set(a) for n, a in snap.items()}}
+    return put(k_pool, k_slot), put(v_pool, v_slot)
+
+
+def _by_pool(k_pool, v_pool, snapshot: dict) -> tuple[dict, dict]:
+    """A snapshot {plane: array} as ``_restore_state`` takes it: the planes the
+    k pool holds and those the v pool holds."""
+    return tuple({n: a for n, a in snapshot.items() if n in pool} for pool in (k_pool, v_pool))
 
 
 @watch_compiles("paged._set_table_rows")
@@ -411,8 +419,8 @@ def forward_paged_first_tokens(params, cfg, tokens, positions, k_pool, v_pool, t
         k_pool, v_pool = _scatter_blocks(k_pool, v_pool, tile(tail["k"]), tile(tail["v"]), dst)
     if snapshot is not None:
         rep = lambda x: jnp.repeat(x[:, None], A, axis=1)
-        k_pool, v_pool = _restore_state(k_pool, v_pool, rep(snapshot["conv"]),
-                                        rep(snapshot["ssm"]), restore_at)
+        k_pool, v_pool = _restore_state(
+            k_pool, v_pool, *_by_pool(k_pool, v_pool, jax.tree.map(rep, snapshot)), restore_at)
     logits, k_pool, v_pool, _, _ = forward_paged(
         params, cfg, tokens, positions, k_pool, v_pool, rows, rules=rules,
         attn_impl=attn_impl, write_mask=live, logit_pos=last, n_real=n_real,
@@ -1024,10 +1032,12 @@ class PagedDecodeEngine(DecodeEngine):
 
         with span(STATE_RESTORE_SPAN):
             if snapshot is None:
-                snapshot = {"conv": jnp.zeros_like(self.k_pool["conv"][:, 0]),
-                            "ssm": jnp.zeros_like(self.v_pool["ssm"][:, 0])}
+                slot_planes = self._cache_spec["slot_planes"]
+                snapshot = {n: jnp.zeros_like(pool[n][:, 0])
+                            for pool, side in ((self.k_pool, "k"), (self.v_pool, "v"))
+                            for n in slot_planes[side]}
             self.k_pool, self.v_pool = _restore_state(
-                self.k_pool, self.v_pool, snapshot["conv"], snapshot["ssm"], jnp.int32(slot))
+                self.k_pool, self.v_pool, *_by_pool(self.k_pool, self.v_pool, snapshot), jnp.int32(slot))
         get_metrics().inc("ssm.state_restores")
 
     def _prefix_in_chunks(self, P: int) -> bool:

@@ -151,7 +151,8 @@ REQUEST_SPAN = "sched.admit.request"
 ALLOC_SPAN = REQUEST_SPAN + ".alloc"
 PREFILL_CALL_SPAN = REQUEST_SPAN + ".prefill_call"
 FIRST_TOKEN_SPAN = REQUEST_SPAN + ".first_token_call"
-# a model with a recurrent state (models.sambay) alone: the copy of the
+# a model whose family's record gives a SLOT planes of its own (a recurrent
+# state of any kind: ``models.family``'s ``slot_planes``) alone: the copy of the
 # prefix's state snapshot into the slot, inside ``.alloc`` and taken out of
 # it like ``.prefill_call`` (``state_restore_ms`` in the admission's entry;
 # no other model's admission has the key, so it is no ``ADMISSION_PARTS``)
