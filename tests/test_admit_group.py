@@ -310,17 +310,26 @@ def _chunk_program_shas(eng) -> list[str]:
 # ``attn.common_query_rows`` adds the positions handed to that range. This
 # module's engines attend through XLA: the kernel's own text is in none of them.
 # The dense, routed and latent kinds' six, the grouped admissions, the one-row
-# prefills and blocks of all five hold UNEDITED.
+# prefills and blocks of all five hold UNEDITED. ISSUE 56 re-derived the FULL
+# width of the routed, the "share" and the latent kinds, each held on its
+# parent's tree (0971bae) first: a packed region tells its routed block how
+# many of its rows are real (``FfnPack.n_rows`` -> ``_moe_ffn_grouped(n_rows=…)``:
+# one ``where`` on the picks, and the mask a share's absent pick already had),
+# so a filler row goes to no expert. The dense and the hybrid kinds' four, the
+# three compacted widths (72 positions <= 96: nothing packs), the grouped
+# admissions, the one-row prefills and blocks of all five, ``tests/test_olmoe.py``'s
+# two (2 slots), ``tests/test_ffn_pack.py``'s three (no ``n_real``) and every pin
+# of ``dots3``, ``nemotron_h`` and ``olmo_hybrid`` hold UNEDITED.
 CHUNK_SHA256 = {
     "dense": ["bd6960a6e5413477e9c715d36602d0ea9ee6872a882bd60eeee5173252803bc9",
               "428563f0ebbff1c395cfa7e002d824eb6266d55bb320837ed9778deade59d0e6"],
-    "routed": ["1e3bef4a09480e68c0881380fc66b3721d7ec6095ddb37b5bc63850318ca5120",
+    "routed": ["fcba4f5d8cbab2b63ebd7d9c0cf3fdd9fc6d95889d7d6961e28cc402de53ff38",
                "f93929afe0b3424a7145f32f7d88ebe5064b4df934dcd35faeeb4ecee781e9cd"],
     "hybrid": ["d8e6303aa7d3956d4cc9684598bde03842352655e7b5b051c6af74480c05ca3f",
                "506e4a4ad1c7a114c16d1b8ca9efb7b6433140de317fac4113b25bc70eadb251"],
-    "share": ["aac966f3fc026f0629d67d21e3f8fd2623878382bc2c20b39fb9f5d724ab4274",
+    "share": ["ba851e38e55c751b8a0b7f1b780f249ca8c5d17c3528e0937b96eb72c55cb8c3",
               "1861ec8d987fb1270ee5e093b253a5c4c8c852328b1635ef728cec379c4b8c69"],
-    "latent": ["f791d7cacf4007111684766731421a27f85c588c79b36fc151680f09da7e382f",
+    "latent": ["3a81e8b6c4f77a510f8bc362c7e4ef85135f7f72c5aff9a3ae09b6f52115ca41",
                "d4c10d336feec5790dcb611f3872a45da8640b5506463a3305d45664658a9f98"],
 }
 
@@ -335,8 +344,9 @@ def test_the_latent_chunk_programs_are_the_parents():
     ``moonlight-16b-a3b-int8`` at its rehearsal widths) packs its MLPs alone
     (``llama.packed_ffn``), on the ``FfnPack`` the other models pack both
     regions with: at 32 slots its full-width chunk program holds that branch
-    and lowers to the text ISSUE 41's parent lowers; the compacted width to
-    ISSUE 49's (told ``n_real``)."""
+    and lowers to ISSUE 56's text (the branch tells its experts ``n_rows``;
+    ISSUE 41's parent's until then); the compacted width to ISSUE 49's (told
+    ``n_real``)."""
     eng = _engine("latent")
     assert eng.latent and eng.compact_rows * 9 <= eng.ffn_pack_rows < SLOTS * 9
     assert _chunk_program_shas(eng) == CHUNK_SHA256["latent"]

@@ -14,6 +14,7 @@ branches of the same program; a call without ``n_real`` is the parent's
 program, text for text."""
 
 import dataclasses
+import functools
 import hashlib
 
 import jax
@@ -51,15 +52,21 @@ def _engine(model: str, prefix: bool = False, float32: bool = False) -> PagedDec
             vocab_size=1024, dim=128, n_layers=2, n_heads=4, n_kv_heads=4, ffn_dim=64,
             max_seq_len=1536, n_experts=8, top_k=2, capacity_factor=4.0, norm_topk=False,
             qk_norm=True), **kw)
-    else:  # "share": layers of two kinds, a parallel block, shared + held experts, a tied head
+    else:
+        # "share": layers of two kinds, a parallel block, shared + held experts, a tied head;
+        # "ahead": a router that reads the layer's input, a window that binds; "latent": a latent
+        # cache, leading dense layers, its MLPs packed alone — each at its file's rehearsal widths
         import json
         from pathlib import Path
 
-        from benchmark.builders import cohere2moe_stack, parse_stack
+        from benchmark.builders import cohere2moe_stack, moonlight_stack, parse_stack, smallthinker_stack
 
-        conf = json.loads((Path(__file__).parents[1]
-                           / "benchmark/configs/command-a-plus-05-2026-int8.json").read_text())
-        cfg = cohere2moe_stack.llama_config(*parse_stack.as_run(conf, True))
+        name, stack = {"share": ("command-a-plus-05-2026-int8", cohere2moe_stack),
+                       "ahead": ("smallthinker-21b-a3b-int8", smallthinker_stack),
+                       "latent": ("moonlight-16b-a3b-int8", moonlight_stack)}[model]
+        conf = json.loads((Path(__file__).parents[1] / f"benchmark/configs/{name}.json").read_text())
+        run, serving = parse_stack.as_run(conf, True)
+        cfg = stack.llama_config(run, {**serving, "site_context_tokens": 0})  # (no site context left behind)
         eng = PagedDecodeEngine(cfg=dataclasses.replace(cfg, max_seq_len=1536), quant=None, **kw)
     if float32:  # weights and pools: a served plan then turns on no rounding
         eng.params, eng.k_pool, eng.v_pool = _float32(eng.params), _float32(eng.k_pool), _float32(eng.v_pool)
@@ -74,14 +81,14 @@ def _rel(got, want) -> float:
 
 
 def _block(eng, n_real, seed: int = 3):
-    """A (SLOTS, 1 + W) block as ``ff_body`` builds it — row b's positions
+    """A (rows, 1 + W) block as ``ff_body`` builds it — row b's positions
     past its ``n_real[b]`` hold copies of its last real one; a row of 0 is
     idle (write mask off, parked at position 0) — over pools of seeded K/V,
     each row behind 200 positions of its own blocks. -> the call's
     arguments, the pools as fresh copies (they are donated)."""
     rng = np.random.default_rng(seed)
     n_real = np.asarray(n_real, np.int32)
-    B, T, bs = SLOTS, 1 + W, eng.block_size
+    B, T, bs = len(n_real), 1 + W, eng.block_size
     live = n_real > 0
     iw = np.minimum(np.arange(T)[None, :], np.maximum(n_real[:, None] - 1, 0))
     tokens = np.take_along_axis(rng.integers(3, 600, size=(B, T)), iw, axis=1)
@@ -173,7 +180,7 @@ def test_a_packed_region_hands_every_position_its_rows():
     T, P = 1 + W, 32
     x = jax.random.normal(jax.random.PRNGKey(0), (SLOTS, T, 6), jnp.float32)
     table = jnp.arange(SLOTS * T, dtype=jnp.float32).reshape(SLOTS, T, 1)
-    region = lambda x: (x * 2, jnp.float32(3))
+    region = lambda x, n_rows=None: (x * 2, jnp.float32(3))  # (told the packed rows that are real)
     whole = x * 2 + table
     for n_real in (FITS, OVERFLOWS):
         pack = llama.ffn_pack_index(jnp.asarray(n_real, jnp.int32), T, P)
@@ -208,6 +215,127 @@ def test_a_padded_position_reads_its_rows_last_real_slot():
     over = llama.ffn_pack_index(jnp.asarray(OVERFLOWS, jnp.int32), T, 32)
     assert not bool(over.fits) and np.asarray(over.stats).tolist() == [0, SLOTS * T]
     assert ((0 <= np.asarray(over.inv)) & (np.asarray(over.inv) < 32)).all()
+
+
+# ---------------------------------------------------------------- the filler (ISSUE 56)
+
+ROUTED = ["routed", "share", "ahead", "latent"]
+CELL_PACK = FFN_PACK_ROWS  # 96 slots, as the cells serve; 12 rows x 9 positions = 108 are wider
+REAL = {1: [0, 0, 1] + [0] * 9,  # k real positions in all, 96 - k slots of filler behind them
+        40: [1 + W, 1, 5, 0, 3, 1 + W, 2, 1, 4, 0, 5, 1],
+        96: [1 + W] * 10 + [6, 0]}
+TOO_MANY = [1 + W] * 11 + [5]  # 104 > 96: the whole-width branches
+
+
+class _Programs:
+    """One routed engine's ``forward_paged`` over a 12-row block with
+    ``moe_stats``, each variant under a jit of its OWN, traced while its
+    patches stand on ``models.llama`` (the module's jit would hand back
+    whichever variant it traced first for these shapes): ``told`` — this
+    tree's program at P = 96; ``parent`` — the same with ``n_rows`` WITHHELD
+    from the routed block, the packed path as ISSUE 56's parent ran it;
+    ``alone(k)`` — packed at P = k slots, so no slot is filler, at P = 96's
+    row tile: the k real rows dispatched alone."""
+
+    def __init__(self, model: str):
+        self.model, self.eng = model, _engine(model)
+        cfg = self.eng.cfg
+        self.names = llama.moe_stat_names(cfg)
+        self.routed_layers = cfg.n_layers - cfg.first_dense_layers
+        moe_ffn, tile = llama._moe_ffn, llama.moe_row_tile(CELL_PACK * cfg.top_k, cfg.n_experts)
+        self.told = self._variant(CELL_PACK)
+        self.parent = self._variant(CELL_PACK, _moe_ffn=lambda p, h, cfg, lat=None, n_rows=None, picks=None:
+                                    moe_ffn(p, h, cfg, lat, None, picks))
+        self.alone = functools.cache(lambda k: self.parent if k == CELL_PACK else self._variant(
+            k, moe_row_tile=lambda assignments, n_experts: tile))
+        self._runs = {}
+
+    def _variant(self, P: int, **patches):
+        fwd, cfg = forward_paged.__wrapped__.__wrapped__, self.eng.cfg
+
+        @jax.jit
+        def program(params, tokens, positions, k_pool, v_pool, tables, n_real, rest):
+            return fwd(params, cfg, tokens, positions, k_pool, v_pool, tables, attn_impl="xla",
+                       n_real=n_real, ffn_pack=P, moe_stats=True, **rest)
+
+        def run(n_real):
+            (params, _, tokens, positions), pools, tables, kw, _ = _block(self.eng, n_real)
+            rest = {k: v for k, v in kw.items() if k != "attn_impl"}
+            with pytest.MonkeyPatch.context() as mp:
+                for name, value in patches.items():
+                    mp.setattr(llama, name, value)
+                return program(params, tokens, positions, *pools(), tables,
+                               jnp.asarray(n_real, jnp.int32), rest)
+        return run
+
+    def run(self, which: str, n_real) -> dict:
+        """{"logits", "pools", "moe" (by name), "ffn"} of one variant over one block, run once."""
+        key = (which, tuple(n_real))
+        if key not in self._runs:
+            variant = self.alone(sum(n_real)) if which == "alone" else getattr(self, which)
+            out = variant(n_real)
+            live = np.asarray(n_real) > 0
+            real = np.arange(1 + W)[None, :] < np.asarray(n_real)[:, None]
+            logits = np.asarray(out[0], np.float32)
+            self._runs[key] = {
+                "logits": logits[live, 0] if self.eng.cfg.layer_types else logits[real],
+                # the pools whole but the trash block (0: idle rows park there whatever they hold)
+                "pools": [a[:, 1:] for pool in out[1:3] for a in _flat(pool)],
+                "moe": dict(zip(self.names, map(int, out[5]), strict=True)), "ffn": np.asarray(out[6]).tolist()}
+        return self._runs[key]
+
+
+@pytest.fixture(scope="module", params=ROUTED)
+def progs(request):
+    return _Programs(request.param)
+
+
+@pytest.mark.parametrize("k", sorted(REAL))
+def test_a_packed_blocks_filler_goes_to_no_expert(progs, k):
+    """k real positions and 96 - k slots of filler through ``forward_paged``
+    with ``moe_stats``: the rows its routed layers computed, the experts with
+    a row and the busiest expert's rows are those of the k real rows
+    dispatched ALONE, and ``assigned_rows`` is k x K a routed layer — with
+    picks the layer routes itself, picks made ahead on its input, a chip's
+    share (its fifth count too) and a latent model's MLPs packed alone."""
+    told, alone = progs.run("told", REAL[k]), progs.run("alone", REAL[k])
+    assert sum(REAL[k]) == k and told["ffn"] == [1, CELL_PACK] and alone["ffn"] == [1, k]
+    assert told["moe"] == alone["moe"]
+    assert told["moe"]["assigned_rows"] == k * progs.eng.cfg.top_k * progs.routed_layers
+    assert 0 < told["moe"]["load_max"] <= k * progs.routed_layers
+    assert np.array_equal(np.argmax(told["logits"], -1), np.argmax(alone["logits"], -1))
+    withheld = progs.run("parent", REAL[k])["moe"]  # the filler routed: every slot's K picks
+    assert withheld["assigned_rows"] == CELL_PACK * progs.eng.cfg.top_k * progs.routed_layers
+    assert k == CELL_PACK or withheld["load_max"] > told["moe"]["load_max"]
+
+
+@pytest.mark.parametrize("k", sorted(REAL))
+def test_the_real_positions_are_the_parents_bit_for_bit(progs, k):
+    """Told ``n_rows`` or not, the real positions' logits and every K/V
+    write outside the trash block are the SAME BITS, in the engines' bfloat16:
+    a real row keeps its rank in its expert's run (the filler ranked behind
+    it) and the kernel's row does not depend on its tile-mates."""
+    told, parent = progs.run("told", REAL[k]), progs.run("parent", REAL[k])
+    assert told["logits"].size and np.abs(told["logits"]).max() > 0
+    assert np.array_equal(told["logits"], parent["logits"])
+    assert len(told["pools"]) == len(parent["pools"]) >= 2
+    for ours, theirs in zip(told["pools"], parent["pools"]):
+        assert np.array_equal(ours, theirs)
+
+
+def test_the_whole_width_branch_counts_what_it_counted(progs):
+    """More real positions than slots: the whole-width branches of the same
+    program run — counts of the packed branch's SHAPE (four; a share's five),
+    the numbers and the bits the parent's program returned: every position
+    of the block assigned, filler or not."""
+    told, parent = progs.run("told", TOO_MANY), progs.run("parent", TOO_MANY)
+    assert told["ffn"] == [0, len(TOO_MANY) * (1 + W)]
+    assert list(told["moe"]) == list(progs.names) == list(progs.run("told", REAL[40])["moe"])
+    assert told["moe"] == parent["moe"]
+    assert told["moe"]["assigned_rows"] == len(TOO_MANY) * (1 + W) * progs.eng.cfg.top_k * progs.routed_layers
+    assert np.array_equal(told["logits"], parent["logits"])
+    for ours, theirs in zip(told["pools"], parent["pools"]):
+        assert np.array_equal(ours, theirs)
 
 
 # sha256 of the lowered text (scope names in, Python frames out: what the

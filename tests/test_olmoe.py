@@ -491,6 +491,51 @@ def test_row_tile_follows_the_assignment_count():
     assert tile(24 * 2, 4) == 16 and tile(10 ** 6, 8) == 128
 
 
+@pytest.mark.parametrize("k", [1, 40, 96])
+@pytest.mark.parametrize("kind", ["routed", "share", "picks"])
+def test_a_filler_row_goes_to_no_expert(kind, k):
+    """ISSUE 56: 96 rows of which the first k are real and the rest copies of
+    ONE row (a packed region's filler, ``FfnPack.n_rows``), through the grouped
+    dispatch told ``n_rows``: the counts — assignments, rows computed, experts
+    with a row, the busiest — and the real rows' outputs are those of the k
+    rows dispatched ALONE (16 experts: the row tile is 16 at every count
+    here); a filler row's routed output is zero; a model that holds all its
+    experts keeps FOUR counts, a share its five. Not told, the filler's picks
+    fill tiles of their own and the real rows read what they read, bit for bit."""
+    P, K = 96, 2
+    cfg = olmoe_cfg(16, K, moe_impl="grouped", **{
+        "routed": {}, "share": dict(experts_held=4, first_expert=4),
+        "picks": dict(router_input="layer")}[kind])
+    assert {llama.moe_row_tile(n * K, 16) for n in (1, 40, 96)} == {16}
+    p = jax.tree.map(lambda a: a[0], seeded_params(cfg)["layers"])
+    h = jax.random.normal(jax.random.PRNGKey(5), (1, P, cfg.dim), jnp.float32)
+    h = h.at[:, k:].set(h[:, k - 1])
+    picks = None
+    if kind == "picks":  # routed AHEAD, on another tensor than the experts read
+        x = jax.random.normal(jax.random.PRNGKey(6), h.shape, jnp.float32).at[:, k:].set(3.0)
+        picks = llama._route_ahead(p, x, cfg)
+    cut = lambda t, n: None if t is None else tuple(a[:, :n] for a in t)
+    ffn = jax.jit(lambda h, picks, n: llama._moe_ffn(p, h, cfg, n_rows=n, picks=picks))
+    told, told_stats = ffn(h, picks, jnp.int32(k))
+    alone, alone_stats = ffn(h[:, :k], cut(picks, k), None)
+    whole, whole_stats = ffn(h, picks, None)
+    names = llama.moe_stat_names(cfg)
+    assert told_stats.shape == alone_stats.shape == whole_stats.shape == (len(names),)
+    assert len(names) == (5 if kind == "share" else 4)
+    got, want, filled = (dict(zip(names, map(int, st))) for st in (told_stats, alone_stats, whole_stats))
+    assert got == want and got["assigned_rows"] == k * K
+    # bit for bit beside the program of the same shapes; the k rows alone are a
+    # program of another (one row: a matrix-vector product), the last bit its own
+    assert np.array_equal(told[:, :k], whole[:, :k]) and np.allclose(told[:, :k], alone, rtol=1e-5, atol=1e-6)
+    assert float(jnp.abs(told[:, k:]).max(initial=0.0)) == 0.0
+    if kind != "share":  # (a share's filler may pick experts held elsewhere)
+        assert got["padded_rows"] > 0 and float(jnp.abs(told[:, :k]).max()) > 0
+        # not told: the P - k copies ride their K experts — whole tiles of one row
+        assert filled["assigned_rows"] == P * K and filled["load_max"] >= P - k
+        assert k == P or (filled["load_max"] > got["load_max"]
+                          and filled["padded_rows"] >= got["padded_rows"] + (P - k) // 16 * 16)
+
+
 # ---------------------------------------------------------------- checkpoints
 
 

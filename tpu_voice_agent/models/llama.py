@@ -746,9 +746,10 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig, lat=None, n_rows=None, picks=None):
     itself; with ``picks`` (eids, gates), each (B, T, K), that the router read
     ANOTHER tensor, earlier in the layer (``_route_ahead``): nothing is routed
     here; and with ``n_rows`` () that only the first ``n_rows`` of the B * T
-    rows are real: a filler row's picks fall on no expert — no run, no tile, no
-    weight fetch, as a pick held elsewhere — and ``assigned`` counts the real
-    rows' alone. -> (out, ``_moe_stats``)."""
+    rows are real (a packed region's: ``FfnPack.n_rows``): a filler row's picks
+    fall on no expert — no run, no tile, no weight fetch, as a pick held
+    elsewhere — and every count is the real rows' alone. -> (out,
+    ``_moe_stats``: a share's five, else four, whatever ``n_rows``)."""
     from ..ops.grouped_matmul import grouped_matmul
     from .moe import route_topk_flat
 
@@ -758,8 +759,10 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig, lat=None, n_rows=None, picks=None):
     # onward. An assignment to an expert held elsewhere matches no column of
     # the one-hot below: it counts in no run, pads nothing, names no tile
     # (no weight fetch), takes no row, and adds nothing in the combine. Its
-    # gate stays what the router gave it (normalised over all K chosen)
-    H, share = cfg.n_held, cfg.n_held < E or n_rows is not None
+    # gate stays what the router gave it (normalised over all K chosen).
+    # ``absent``: some pick may match no column — a share's, a filler row's
+    H, share = cfg.n_held, cfg.n_held < E
+    absent = share or n_rows is not None
     Tt = B * T
     A = Tt * K
     x2 = h.reshape(Tt, d)
@@ -799,8 +802,8 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig, lat=None, n_rows=None, picks=None):
         # rows move by GATHER (an int32 scatter builds the index): row r of
         # the padded layout reads token row_tok[r], padding reads a zero row
         row_tok = jnp.full((n_static * tm,), Tt, jnp.int32)
-        if share:
-            local = jnp.any(hot, axis=1)  # (A,) the expert is held here
+        if absent:
+            local = jnp.any(hot, axis=1)  # (A,) the expert is held here, the row real
             row_tok = row_tok.at[jnp.where(local, dest, n_static * tm)].set(
                 jnp.arange(A, dtype=jnp.int32) // K, mode="drop")
         else:
@@ -830,7 +833,7 @@ def _moe_ffn_grouped(p, h, cfg: LlamaConfig, lat=None, n_rows=None, picks=None):
         # assignment j sits at row dest[j]; a token's K rows are gathered
         # and summed under its gates (no scatter-add of (A, d) rows)
         rows = down[dest].reshape(Tt, K, d).astype(jnp.float32)
-        if share:  # an absent pick's ``dest`` is 0: some other row, or one never written
+        if absent:  # an absent pick's ``dest`` is 0: some other row, or one never written
             rows = jnp.where(local.reshape(Tt, K, 1), rows, 0.0)
         out = jnp.sum(rows * gates[:, :, None], axis=1)
     return (out.astype(h.dtype).reshape(B, T, d),
@@ -938,11 +941,14 @@ class FfnPack(NamedTuple):
     one and writes the same K/V index, so the values must stay equal — and
     any slot for a row with none (its writes are parked, its logits unread).
     ``fits``: all real positions have a slot; where they do not, the forward
-    runs at its full width, as it always did."""
+    runs at its full width, as it always did. ``n_rows``: the real positions —
+    rows in order, so where they fit the first ``n_rows`` slots hold them and
+    every slot behind is filler, which a routed block sends to no expert."""
 
     idx: jax.Array
     inv: jax.Array
     fits: jax.Array
+    n_rows: jax.Array
 
     @property
     def stats(self) -> jax.Array:
@@ -974,7 +980,8 @@ def ffn_pack_index(n_real: jax.Array, T: int, P: int) -> FfnPack:
     slot = jnp.arange(P, dtype=jnp.int32)
     row = jnp.minimum(jnp.sum(ends[None, :] <= slot[:, None], axis=1, dtype=jnp.int32), B - 1)
     idx = row * T + jnp.clip(slot - starts[row], 0, T - 1)  # past the last real slot: unread
-    return FfnPack(idx, inv, ends[-1] <= P)
+    n_rows = ends[-1]
+    return FfnPack(idx, inv, n_rows <= P, n_rows)
 
 
 class RowTiles(NamedTuple):
@@ -1032,9 +1039,10 @@ def row_tiles(n_real: jax.Array, T: int, tile: int) -> RowTiles:
 def packed_ffn(ffn, h: jax.Array, pack: FfnPack | None):
     """``ffn(h)`` -> (y, stats) over the (B, T, d) block ``h``, or — with a
     ``pack`` — over its real positions alone where they fit: gather them to
-    (1, P, d), the same ``ffn``, and every position reads its slot back. One
-    branch a forward (``pack.fits`` is made once, before the layers). A
-    latent model's (``models.mla``), which packs its MLPs alone;
+    (1, P, d), the same ``ffn`` told how many of those rows are real
+    (``n_rows``: its experts take no filler), and every position reads its slot
+    back. One branch a forward (``pack.fits`` is made once, before the layers).
+    A latent model's (``models.mla``), which packs its MLPs alone;
     ``forward_paged`` here packs both position-wise regions of a layer."""
     if pack is None:
         return ffn(h)
@@ -1042,7 +1050,7 @@ def packed_ffn(ffn, h: jax.Array, pack: FfnPack | None):
     def packed(h):
         with jax.named_scope("layer/ffn/pack"):
             hp = pack.rows(h)
-        y, stats = ffn(hp)
+        y, stats = ffn(hp, n_rows=pack.n_rows)
         with jax.named_scope("layer/ffn/unpack"):
             return pack.block(y), stats
 
@@ -1062,7 +1070,7 @@ def _layer_leaves(p: dict) -> dict:
             **{k: jax.tree.map(lambda a: a[p["layer"]], p[k]) for k in p["stacked"]}}
 
 
-def _ffn(p, h, cfg: LlamaConfig, cs=_identity_cs, picks=None):
+def _ffn(p, h, cfg: LlamaConfig, cs=_identity_cs, picks=None, n_rows=None):
     """A layer's MLP over its normed input (B, T, d) -> (y, the layer's
     ``_moe_stats`` or None): the dense SwiGLU, or the routed experts and the
     shared ones beside them. Position-wise: it asks nothing of B and T. Opens
@@ -1070,11 +1078,13 @@ def _ffn(p, h, cfg: LlamaConfig, cs=_identity_cs, picks=None):
     before it, and the scope paths the trace is read by stay whole. ``p``
     may hold leaves still STACKED over the layers (``_layer_leaves``): sliced
     here, inside whichever branch runs. ``picks``: the layer's routing, made
-    ahead on its input (``_route_ahead``)."""
+    ahead on its input (``_route_ahead``). ``n_rows``: only the first ``n_rows``
+    rows are real (a packed region's filler behind them): the routed experts
+    take those alone; the dense and the shared MLPs compute every row."""
     with jax.named_scope("layer/ffn"):
         p = _layer_leaves(p)
         if cfg.n_experts > 0:
-            y, stats = _moe_ffn(p, h, cfg, picks=picks)
+            y, stats = _moe_ffn(p, h, cfg, n_rows=n_rows, picks=picks)
             if cfg.n_shared_experts:
                 with jax.named_scope("shared"):  # layer/ffn/shared
                     shared = _swiglu(p, h, ("shared_gate", "shared_up", "shared_down"), cs,
@@ -1092,7 +1102,7 @@ def _ffn(p, h, cfg: LlamaConfig, cs=_identity_cs, picks=None):
 
 
 def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = False, u=None,
-               picks=None):
+               picks=None, n_rows=None):
     """Shared decoder-layer back half: output projection + residual, then
     the MLP (dense SwiGLU, or routed MoE when cfg.n_experts > 0) +
     residual. ``attn`` is (B, T, n_heads * head_dim). With ``moe_stats``
@@ -1100,8 +1110,9 @@ def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = 
     block (``u``: the layer's one normed input, which fed q/k/v too) adds
     both halves to the same residual: x + W_o attn + FFN(u). Position-wise:
     it asks nothing of B and T (``forward_paged`` runs it on a block's real
-    positions, packed). ``picks``: the experts of a model whose router reads the
-    layer's input, chosen before attention (``_route_ahead``) on these rows."""
+    positions, packed, and says how many of the rows are: ``n_rows``, ``_ffn``'s).
+    ``picks``: the experts of a model whose router reads the layer's input,
+    chosen before attention (``_route_ahead``) on these rows."""
     with jax.named_scope("layer/attn_out"):
         attn = _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
         attn = cs(attn, "act")
@@ -1111,7 +1122,7 @@ def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = 
         raise NotImplementedError("a parallel block or shared experts around a dense MLP")
     with jax.named_scope("layer/ffn"):
         h = _norm(x, p["mlp_norm"], cfg) if u is None else u
-    y, stats = _ffn(p, h, cfg, cs, picks)
+    y, stats = _ffn(p, h, cfg, cs, picks, n_rows)
     with jax.named_scope("layer/ffn"):
         x = x + cs(y, "act") if u is None else x + attn + cs(y, "act")
     return (x, stats) if moe_stats else x
@@ -1493,10 +1504,10 @@ def forward_paged(
                 qkv, picks = front(x, (cos, sin))
                 return qkv if not ahead else (qkv, (picks, no_picks(1, ffn_pack)))
 
-            def back(x, attn, picks=None):
+            def back(x, attn, picks=None, n_rows=None):
                 pl = _layer_leaves(p)
                 out = _layer_out(pl, x, attn, cfg, cs, moe_stats=moe_stats, u=own_norm(pl, x),
-                                 picks=picks)
+                                 picks=picks, n_rows=n_rows)
                 return out if moe_stats else (out, None)
 
             x, xp = x
@@ -1628,7 +1639,7 @@ def forward_paged(
             def back_rows(x, xp, attn, *picks):  # the new residual stays packed
                 with jax.named_scope("layer/ffn/pack"):
                     rows = pack.rows(attn)
-                xp, st = back(xp, rows, picks[1] if ahead else None)
+                xp, st = back(xp, rows, picks[1] if ahead else None, pack.n_rows)
                 return (x, xp), st
 
             def back_block(x, xp, attn, *picks):

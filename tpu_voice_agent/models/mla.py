@@ -163,8 +163,9 @@ def latent_out(p, a, cfg: LlamaConfig, dtype):
         return o.astype(dtype).reshape(*a.shape[:2], -1)
 
 
-def _dense_ffn(p, h, cfg: LlamaConfig):
-    """A leading layer's SwiGLU at ``dense_ffn_dim`` -> (y, no routed stats)."""
+def _dense_ffn(p, h, cfg: LlamaConfig, n_rows=None):
+    """A leading layer's SwiGLU at ``dense_ffn_dim`` -> (y, no routed stats);
+    every row it is handed, real or filler (``n_rows`` is the experts')."""
     with jax.named_scope("layer/ffn"), jax.named_scope("dense"):
         return _swiglu(p, h, ("w_gate", "w_up", "w_down")).astype(h.dtype), None
 
