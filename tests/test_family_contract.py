@@ -31,10 +31,13 @@ SLOTS, BS, BLOCKS = 8, 128, 24
 def _model(model: str) -> dict:
     """``tests/test_admit_group.py``'s six, and the family that came behind them
     (a delta-rule state: ``models/olmo_hybrid.py``, its own test preset)."""
-    if model != "gdn":
+    if model not in ("gdn", "looped"):
         return _older_model(model)
     from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
 
+    if model == "looped":  # layers that run twice a token, under a sandwich norm and an exit gate
+        return dict(cfg=llama.LlamaConfig(dim=128, n_layers=2, n_heads=4, n_kv_heads=4, ffn_dim=256,
+                                          ut_steps=2, sandwich_norm=True), tokenizer=default_tokenizer())
     return dict(cfg=olmo_hybrid.PRESETS["olmo-hybrid-test"], tokenizer=default_tokenizer())
 
 
@@ -91,15 +94,15 @@ def test_the_engine_builds_the_pools_the_record_states(model):
     assert eng.kv_bytes_per_block == BS * eng.family.token_bytes
     from tpu_voice_agent.utils import hbmledger
 
-    # the plan counts BLOCK planes, a dense decoder's arithmetic where they are K/V by
-    # head (a hybrid model's as if every layer wrote them, its slots' planes not at all:
-    # ROADMAP D5, what is left) and the record's planes where they are not
+    # the plan counts the record's BLOCK planes (since PR 57 a hybrid model's too: the layers
+    # that write K/V at the heads they hold, not every layer; its slots' planes not at all:
+    # ROADMAP D5, what is left) — a dense decoder's arithmetic where they are K/V by head
     by_head = model not in ("latent", "sparse")
     cfg = eng.cfg
-    want = (2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * 2 * BS if by_head
-            else eng.kv_bytes_per_block)
+    if model in ("dense", "routed", "share"):
+        assert eng.kv_bytes_per_block == 2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * 2 * BS
     assert eng.family.kv_by_head == by_head
-    assert hbmledger.engine_hbm_plan(eng)["kv_pool_bytes"] == BLOCKS * want
+    assert hbmledger.engine_hbm_plan(eng)["kv_pool_bytes"] == BLOCKS * eng.kv_bytes_per_block
 
 
 def _enter(feature: str, model: str):
@@ -282,3 +285,63 @@ def test_a_gdn_chunk_counts_what_the_record_names_in_its_order(catalog):
         for name, n in zip(count.metrics, np.asarray(res.counts[count.name]).tolist()):
             assert catalog(name), f"{name} is not in docs/OBSERVABILITY.md"
             assert after.get(name, 0.0) - before.get(name, 0.0) == n
+
+
+# ---------------------------------------------------------------- layers that run several times a token
+
+
+def test_the_looped_record_is_the_plain_familys_with_a_plane_for_every_pass():
+    """A ``LlamaConfig`` whose layers run ``ut_steps`` times (ISSUE 57): no family of
+    its own — the plain record with ``ut_steps`` x ``n_layers`` planes, one more count
+    and its own refusals; the engine sizes pool, bytes and plan from the record."""
+    from tpu_voice_agent.utils import hbmledger
+
+    cfg = _cfg("looped")
+    fam = family(cfg)
+    assert fam.name == "plain" and fam.module is llama and fam.error is NotImplementedError
+    assert set(fam.refuses) == set(FEATURES) - {"ffn_pack"} and fam.cache == llama.cache_spec(cfg)
+    assert [c.keyword for c in fam.counts] == ["attn_stats", "loop_stats"]
+    assert fam.count("loop").metrics == tuple(f"loop.{n}" for n in llama.LOOP_STATS)
+    assert (fam.n_real, fam.one_head, fam.block_real, fam.pack_rows, fam.scratch_prefix) == (
+        "", True, True, 96, True)
+    eng = _engine("looped")
+    L = cfg.ut_steps * cfg.n_layers
+    assert eng.family is family(eng.cfg) and fam.cache["planes"]["k"]["kv"][0] == L == 4
+    assert eng.k_pool.shape == eng.v_pool.shape == (L, BLOCKS, BS, cfg.n_kv_heads, cfg.head_dim)
+    assert eng.kv_bytes_per_block == BS * fam.token_bytes == BS * 2 * 2 * L * cfg.n_kv_heads * cfg.head_dim
+    assert hbmledger.engine_hbm_plan(eng)["kv_pool_bytes"] == BLOCKS * eng.kv_bytes_per_block
+    # with the fields at their defaults the record is the one it was
+    plain = family(_cfg("dense"))
+    assert [c.keyword for c in plain.counts] == ["attn_stats"] and not plain.refuses
+
+
+@pytest.mark.parametrize("feature", FEATURES)
+def test_what_the_looped_table_refuses_is_refused_by_type_and_nothing_else_is(feature):
+    fam = family(_cfg("looped"))
+    if feature not in fam.refuses:  # ffn_pack: the packed regions run inside the passes
+        assert feature == "ffn_pack" and _enter(feature, "looped") == 96
+        return
+    if feature == "chunked_prefill":
+        assert _enter(feature, "looped") is None
+        return
+    with pytest.raises(NotImplementedError, match=f"^{feature}: .*pass"):
+        _enter(feature, "looped")
+
+
+def test_a_looped_chunk_counts_what_the_record_names_in_its_order(catalog):
+    from tpu_voice_agent.utils import get_metrics
+
+    eng = _engine("looped", init_weights=True)
+    eng.ffn_pack_rows = 8  # under the compacted width's 2 x 9 positions
+    before = dict(get_metrics().counter_state()[0])
+    bat = ContinuousBatcher(eng, chunk_steps=2, max_new_tokens=8)
+    bat.submit("go back")
+    res = bat.step()
+    assert list(res.counts) == ["attn", "loop", "ffn"]
+    after = get_metrics().counter_state()[0]
+    for count in (*eng.family.counts, FFN):
+        assert res.counts[count.name].shape == (len(count.metrics),)
+        for name, n in zip(count.metrics, np.asarray(res.counts[count.name]).tolist()):
+            assert catalog(name), f"{name} is not in docs/OBSERVABILITY.md"
+            assert after.get(name, 0.0) - before.get(name, 0.0) == n
+    assert np.asarray(res.counts["loop"])[0] == eng.cfg.ut_steps * int(res.fwds)

@@ -61,7 +61,10 @@ def _counts(cfg, latent: tuple[str, ...] = ()) -> tuple[Count, ...]:
     return (routed if cfg.n_experts else ()) + (ATTN,) + (
         (Count("latent", "latent_stats", tuple(f"attn.{n}" for n in latent)),) if latent else ()) + (
         (Count("window", "window_stats", tuple(f"attn.{n}" for n in llama.WINDOW_STATS)),)
-        if windowed else ())
+        if windowed else ()) + (
+        # layers that run more than once: the passes, and where the exit gate's selection fell
+        (Count("loop", "loop_stats", tuple(f"loop.{n}" for n in llama.LOOP_STATS)),)
+        if cfg.ut_steps > 1 else ())
 
 
 @dataclass(frozen=True)
@@ -165,9 +168,29 @@ _LATENT_REFUSES = {
     "dense_cache": f"a dense cache holds {_PLANES} (forward_paged alone runs it, "
                    "PagedDecodeEngine on one device serves it)",
 }
-_PAGED_ONLY = {"dense_cache": "layers of more than one kind, a parallel block, a tied head and a "
-                              "router on the layer's input are forward_paged's: PagedDecodeEngine "
+_PAGED_ONLY = {"dense_cache": "layers of more than one kind, a parallel block, a tied head, a sandwich "
+                              "norm and a router on the layer's input are forward_paged's: PagedDecodeEngine "
                               "serves this model, the dense cache does not"}
+
+
+_LOOPED = ("a K/V plane for every (pass, layer) — ut_steps x n_layers of them — over n_layers "
+           "layers of weights")
+# what no CPU test drives at ut_steps > 1 is refused, not assumed: each names what it would
+# have to learn of the planes
+_LOOPED_REFUSES = {
+    "dense_cache": f"{_LOOPED}: the dense cache and llama.forward hold one plane a layer and run "
+                   "the layers once; forward_paged's loop of passes alone runs this model, "
+                   "PagedDecodeEngine serves it",
+    "mesh": f"a mesh shards a pool of n_layers planes by its rules and runs the layers once: {_LOOPED} "
+            "is not placed by parallel.mesh",
+    "kv_quant": f"KV_QUANT's scale planes and quantising scatters are untested over {_LOOPED}",
+    "radix": f"radix reuse adopts and evicts chains of blocks whose cost it counts a layer: untested over {_LOOPED}",
+    "spec": "a verify step rolls back by overwriting K/V before it is attended, in every plane of every "
+            f"pass, and a draft model shares no pass: untested over {_LOOPED}",
+    "handoff": f"a handoff ships and adopts blocks sized by n_layers: untested over {_LOOPED}",
+    "chunked_prefill": "a chunked admission's cursor is untested over the loop of passes: the one-shot "
+                       "prefill_slot serves it",
+}
 
 
 def tree_owner(params: dict) -> ModuleType:
@@ -200,13 +223,14 @@ def family(cfg) -> Family:
     if cfg.kv_lora_rank:  # a latent and ONE rotated key a token a layer
         return Family("latent", mla, mla.cache_spec(cfg), _counts(cfg, mla.LATENT_STATS),
                       mla.LatentCacheOnly, _LATENT_REFUSES, block_real=True, one_head=True)
+    looped = cfg.ut_steps > 1
     paged_only = bool(cfg.layer_types or cfg.parallel_block or cfg.tie_embeddings
-                      or cfg.router_input == "layer")
-    refuses = dict(_PAGED_ONLY if paged_only else {})
+                      or cfg.router_input == "layer" or looped or cfg.sandwich_norm)
+    refuses = dict(_LOOPED_REFUSES if looped else _PAGED_ONLY if paged_only else {})
     if llama.bound_window(cfg) is not None:  # (the block kernel's meshed and quantised wrappers)
         refuses.update({f: "a sliding window that binds: the wrappers of the kernels that serve "
                            f"it take no window" for f in ("mesh", "kv_quant")})
     return Family("plain", llama, llama.cache_spec(cfg), _counts(cfg), NotImplementedError,
                   refuses, block_real=True,
-                  one_head=bool(cfg.layer_types), scratch_prefix=paged_only)
+                  one_head=bool(cfg.layer_types) or looped, scratch_prefix=paged_only)
 

@@ -164,12 +164,35 @@ class LlamaConfig:
     # the activation on the gate plane of a gated (three-plane) MLP or expert:
     # "silu" (SwiGLU) | "relu" (ReGLU: down(relu(gate h) * up h))
     gate_act: str = "silu"
+    # ---- LOOPED layers under a sandwich norm, behind an exit gate (``ouro``).
+    # Three properties of the MODEL, not knobs. ``ut_steps`` U > 1: the SAME
+    # ``n_layers`` layers run U times a token; every (pass, layer) keeps K/V of
+    # its own — plane ``u * n_layers + l`` of a pool of U * ``n_layers`` planes
+    # (``cache_spec``) — the model's final norm closes EVERY pass (its output is
+    # the next pass's input), and an exit gate (``params["exit_gate"]``: d -> 1,
+    # a sigmoid) says after each pass how much of the remaining probability
+    # leaves there: the head reads the state of the first pass at which the
+    # cumulated probability reaches ``exit_threshold`` (the last pass if none
+    # does; at 1.0 that is the last wherever the sigmoid stays under 1). Every
+    # pass is computed for every position, as the published forward does
+    ut_steps: int = 1
+    exit_threshold: float = 1.0
+    # a norm on each sub-layer's OUTPUT beside the one on its input:
+    # x + N(Attn(N(x))), then x + N(MLP(N(x))) (``attn_post_norm``, ``mlp_post_norm``)
+    sandwich_norm: bool = False
 
     # a routed expert's form (no field: every LlamaConfig's is the gated SwiGLU of
     # three planes; ``models.nemotron_h``'s configuration names a two-plane one)
     expert_form = "swiglu"
 
     def __post_init__(self):
+        if self.ut_steps < 1:
+            raise ValueError(f"ut_steps {self.ut_steps}: the layers run at least once")
+        if (self.ut_steps > 1 or self.sandwich_norm) and (
+                self.n_experts or self.kv_lora_rank or self.layer_types or self.parallel_block
+                or self.tie_embeddings or self.qk_norm):
+            raise NotImplementedError("looped layers and the sandwich norm: around a dense block "
+                                      "of one kind with an untied head (forward_paged's scan)")
         if self.router_input not in ("ffn", "layer") or self.gate_act not in GATE_ACTS:
             raise ValueError(f"router_input 'ffn' | 'layer' and gate_act one of {sorted(GATE_ACTS)}, "
                              f"got {self.router_input!r}, {self.gate_act!r}")
@@ -277,8 +300,9 @@ def cache_planes(k: dict, v: dict, *, by_name: bool = False, slot_k=None, slot_v
 
 
 def cache_spec(cfg: LlamaConfig) -> dict:
-    """K and V planes by head, every layer's."""
-    kv = {"kv": (cfg.n_layers, cfg.n_kv_heads, cfg.head_dim)}
+    """K and V planes by head, every layer's — of every pass, where the layers
+    run more than once (plane ``u * n_layers + l``)."""
+    kv = {"kv": (cfg.ut_steps * cfg.n_layers, cfg.n_kv_heads, cfg.head_dim)}
     return cache_planes(kv, kv)
 
 
@@ -312,6 +336,8 @@ def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
         layers["mlp_norm"] = norm_init(L, d)
     if cfg.qk_norm:
         layers.update({"q_norm": norm_init(L, nq * hd), "k_norm": norm_init(L, nkv * hd)})
+    if cfg.sandwich_norm:
+        layers.update({"attn_post_norm": norm_init(L, d), "mlp_post_norm": norm_init(L, d)})
     if cfg.n_shared_experts:
         # the shared experts side by side: ONE SwiGLU of n_shared * f columns
         # IS the sum of theirs (the mean is taken where it is added)
@@ -342,6 +368,10 @@ def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     }
     if not cfg.tie_embeddings:  # tied: the head IS the embedding
         params["lm_head"] = w_init(k_head, d, cfg.vocab_size)
+    if cfg.ut_steps > 1:  # float32, never quantised: d + 1 numbers
+        params["exit_gate"] = {
+            "w": jax.random.normal(jax.random.fold_in(k_head, 1), (d,), jnp.float32) * d ** -0.5,
+            "b": jnp.zeros((), jnp.float32)}
     return params
 
 
@@ -424,6 +454,7 @@ def quantize_params(params: dict) -> dict:
         # attention leaves stacked by layer KIND (models.dots3)
         **{k: layers(params[k]) for k in ("attn_full", "attn_swa") if k in params},
         "final_norm": params["final_norm"],
+        **({"exit_gate": params["exit_gate"]} if "exit_gate" in params else {}),
         # a tied head: an int8 copy of the embedding, a scale a vocabulary row
         "lm_head": quant(_w(params["lm_head"]) if "lm_head" in params else params["embed"].T),
     }
@@ -1115,6 +1146,8 @@ def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = 
     chosen before attention (``_route_ahead``) on these rows."""
     with jax.named_scope("layer/attn_out"):
         attn = _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
+        if cfg.sandwich_norm:  # the norm on the sub-layer's OUTPUT
+            attn = _norm(attn, p["attn_post_norm"], cfg)
         attn = cs(attn, "act")
         if u is None:
             x = x + attn
@@ -1124,8 +1157,61 @@ def _layer_out(p, x, attn, cfg: LlamaConfig, cs=_identity_cs, moe_stats: bool = 
         h = _norm(x, p["mlp_norm"], cfg) if u is None else u
     y, stats = _ffn(p, h, cfg, cs, picks, n_rows)
     with jax.named_scope("layer/ffn"):
+        if cfg.sandwich_norm:
+            y = _norm(y, p["mlp_post_norm"], cfg)
         x = x + cs(y, "act") if u is None else x + attn + cs(y, "act")
     return (x, stats) if moe_stats else x
+
+
+# ---------------------------------------------------------------- looped layers
+
+# what a forward of a model whose layers run more than once counts (published as
+# ``loop.<name>``): the passes it ran, the positions its head read (live rows'
+# real ones) and, of those, the positions whose selected pass is the LAST
+LOOP_STATS = ("passes", "exit_rows", "exit_last")
+
+
+class LoopExit(NamedTuple):
+    """The exit gate's books over the positions the head reads, carried from
+    pass to pass: the selected pass's state so far, the exit probability
+    cumulated, the probability that is left (the product of 1 - lambda), the
+    pass selected (-1: none yet) and the passes run."""
+
+    state: jax.Array  # (B, R, d)
+    cum: jax.Array  # (B, R) float32
+    left: jax.Array  # (B, R) float32
+    step: jax.Array  # (B, R) int32
+    passes: jax.Array  # () int32
+
+
+def pass_planes(u, cfg: LlamaConfig):
+    """The first K/V plane of pass ``u``: every (pass, layer) keeps K/V of its own."""
+    return u * cfg.n_layers
+
+
+def _close_pass(params, cfg: LlamaConfig, x, ex: LoopExit, u, read):
+    """What closes pass ``u`` of a looped model: the model's final norm on every
+    position — its output is the next pass's input — and, on the positions the
+    head reads (``read``: (B, T, d) -> (B, R, d)), the exit gate and the published
+    selection in float32: lambda_u = sigmoid(s_u . w + b); p_u = lambda_u x what is
+    left (the LAST pass takes all that is left); the head reads the state of the
+    first pass at which the cumulated p reaches ``exit_threshold``, the last pass's
+    if none does. ``x``: (B, T, d), or a packed forward's pair (the block, the
+    packed rows) — one of the two is stale and both are normed.
+    -> (the next pass's input, the books)."""
+    with jax.named_scope("loop/exit"):
+        x = jax.tree.map(lambda a: _norm(a, params["final_norm"], cfg).astype(a.dtype), x)
+        s = read(x)
+        gate = params["exit_gate"]
+        lam = jax.nn.sigmoid(jnp.einsum("brd,d->br", s.astype(jnp.float32),
+                                        gate["w"].astype(jnp.float32),
+                                        precision=jax.lax.Precision.HIGHEST)
+                             + gate["b"].astype(jnp.float32))
+        last = u == cfg.ut_steps - 1
+        cum = ex.cum + jnp.where(last, ex.left, lam * ex.left)
+        take = (ex.step < 0) & ((cum >= cfg.exit_threshold) | last)
+        return x, LoopExit(jnp.where(take[..., None], s, ex.state), cum, ex.left * (1.0 - lam),
+                           jnp.where(take, u, ex.step).astype(jnp.int32), ex.passes + 1)
 
 
 # ---------------------------------------------------------------- forward
@@ -1261,7 +1347,7 @@ def forward(
 @partial(jax.jit, static_argnames=("cfg", "rules", "attn_impl", "fresh_block",
                                    "gather_blocks", "kv_quant", "moe_stats",
                                    "attn_stats", "hybrid_stats", "ffn_pack", "latent_stats",
-                                   "window_stats"),
+                                   "window_stats", "loop_stats"),
          donate_argnames=("k_pool", "v_pool", "k_scale", "v_scale"))
 def forward_paged(
     params: dict,
@@ -1320,6 +1406,8 @@ def forward_paged(
     # (2,) int32, after the attention row-blocks
     window_stats: bool = False,  # a model whose window BINDS only (``bound_window``): also
     # ``WINDOW_STATS``, (3,) int32, after the attention row-blocks
+    loop_stats: bool = False,  # a model whose layers run more than once only (``ut_steps``):
+    # also ``LOOP_STATS``, (3,) int32, behind those
 ):
     """The paged twin of ``forward`` (parity-tested): sequences own
     non-contiguous pool blocks via per-row block tables (SURVEY.md §7
@@ -1355,7 +1443,8 @@ def forward_paged(
     if ffn_pack:
         fam.refuse("ffn_pack", NotImplementedError)
     asked = {"moe_stats": moe_stats, "attn_stats": attn_stats, "hybrid_stats": hybrid_stats,
-             "latent_stats": latent_stats, "window_stats": window_stats}
+             "latent_stats": latent_stats, "window_stats": window_stats,
+             "loop_stats": loop_stats}
     counted = {c.keyword for c in fam.counts}
     if any(on and kw not in counted for kw, on in asked.items()):
         raise ValueError(f"a {fam.name} model's forward counts {sorted(counted)}: asked {asked}")
@@ -1458,10 +1547,13 @@ def forward_paged(
         with jax.named_scope("layer/attn_qkv"):
             return _norm(x, p["attn_norm"], cfg)
 
-    def layer(carry, layer_in, kind=(True, None)):
+    def layer(carry, layer_in, kind=(True, None), base=None):
         x, kp, vp, ksc, vsc = carry
         p, li = layer_in
         rotate, window = kind
+        # the K/V plane this layer writes and attends: its own index, or — a looped
+        # model's — its index behind the pass's first plane (``base``)
+        plane = li if base is None else base + li
         if whole:
             p = {**p, **whole, "layer": li, **({"stacked": stacked} if stacked else {})}
 
@@ -1525,11 +1617,11 @@ def forward_paged(
                 # flat view XLA relaid the whole pool out around a one-row
                 # scatter in the unrolled layers — a 16x padded copy, 6.25 GB
                 # at these widths (my chip run, PR 34; PR 32 met the same)
-                kp = kp.at[li, flat_idx // bs, flat_idx % bs].set(k.astype(kp.dtype))
-                vp = vp.at[li, flat_idx // bs, flat_idx % bs].set(v.astype(vp.dtype))
+                kp = kp.at[plane, flat_idx // bs, flat_idx % bs].set(k.astype(kp.dtype))
+                vp = vp.at[plane, flat_idx // bs, flat_idx % bs].set(v.astype(vp.dtype))
             elif kv_quant is None:
-                kp = kp_flat.at[li, flat_idx].set(k.astype(kp.dtype)).reshape(kp.shape)
-                vp = vp_flat.at[li, flat_idx].set(v.astype(vp.dtype)).reshape(vp.shape)
+                kp = kp_flat.at[plane, flat_idx].set(k.astype(kp.dtype)).reshape(kp.shape)
+                vp = vp_flat.at[plane, flat_idx].set(v.astype(vp.dtype)).reshape(vp.shape)
             else:
                 from ..ops.kvquant import quantize_kv
 
@@ -1539,12 +1631,12 @@ def forward_paged(
                 # scales by construction)
                 qk, sk = quantize_kv(k, kv_quant)
                 qv, sv = quantize_kv(v, kv_quant)
-                kp = kp_flat.at[li, flat_idx].set(qk).reshape(kp.shape)
-                vp = vp_flat.at[li, flat_idx].set(qv).reshape(vp.shape)
+                kp = kp_flat.at[plane, flat_idx].set(qk).reshape(kp.shape)
+                vp = vp_flat.at[plane, flat_idx].set(qv).reshape(vp.shape)
                 ksc_flat = ksc.reshape(L, N * bs, cfg.n_kv_heads)
                 vsc_flat = vsc.reshape(L, N * bs, cfg.n_kv_heads)
-                ksc = ksc_flat.at[li, flat_idx].set(sk).reshape(ksc.shape)
-                vsc = vsc_flat.at[li, flat_idx].set(sv).reshape(vsc.shape)
+                ksc = ksc_flat.at[plane, flat_idx].set(sk).reshape(ksc.shape)
+                vsc = vsc_flat.at[plane, flat_idx].set(sv).reshape(vsc.shape)
 
         # this model's layers say their kind: layer/attn/{window,full}
         with jax.named_scope("layer/attn" + ("" if not cfg.layer_types else
@@ -1554,7 +1646,7 @@ def forward_paged(
                     from ..ops import sharded_paged_attention
 
                     attn = sharded_paged_attention(
-                        mesh, q[:, 0], kp, vp, block_tables, frontier + 1, li
+                        mesh, q[:, 0], kp, vp, block_tables, frontier + 1, plane
                     ).reshape(B, T, -1)
                 else:
                     from ..ops import sharded_paged_attention_quant
@@ -1564,7 +1656,7 @@ def forward_paged(
                     # bytes cross HBM and fp KV never materializes
                     attn = sharded_paged_attention_quant(
                         mesh, q[:, 0], kp, vp, ksc, vsc, block_tables,
-                        frontier + 1, li, bits=bits,
+                        frontier + 1, plane, bits=bits,
                     ).reshape(B, T, -1)
             elif block_decode:
                 # the paged twin of the dense frontier-read block kernel — T
@@ -1575,7 +1667,7 @@ def forward_paged(
                     from ..ops import sharded_paged_block_attention
 
                     attn = sharded_paged_block_attention(
-                        mesh, q, kp, vp, block_tables, positions, li, write_mask,
+                        mesh, q, kp, vp, block_tables, positions, plane, write_mask,
                         **({"split": split} if window is None else
                            {"split": win_split[window], "window": jnp.int32(window)}),
                         n_real=n_real,
@@ -1584,7 +1676,7 @@ def forward_paged(
                     from ..ops import sharded_paged_block_attention_quant
 
                     attn = sharded_paged_block_attention_quant(
-                        mesh, q, kp, vp, ksc, vsc, block_tables, positions, li,
+                        mesh, q, kp, vp, ksc, vsc, block_tables, positions, plane,
                         bits=bits,
                     ).reshape(B, T, -1)
             elif fresh_block and T > 1 and window is None:
@@ -1620,17 +1712,17 @@ def forward_paged(
                 with jax.named_scope("kv_gather"):
                     tbl = block_tables[:, :nb]
                     if kv_quant is None:
-                        kl = kp[li][tbl].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-                        vl = vp[li][tbl].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+                        kl = kp[plane][tbl].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+                        vl = vp[plane][tbl].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
                     else:
                         from ..ops.kvquant import dequantize_kv
 
                         kl = dequantize_kv(
-                            kp[li][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
-                            ksc[li][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
+                            kp[plane][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
+                            ksc[plane][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
                         vl = dequantize_kv(
-                            vp[li][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
-                            vsc[li][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
+                            vp[plane][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
+                            vsc[plane][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
                 attn = _attend(q, kl, vl, positions, kv_len_mask, window)
         if pack is None:
             out = _layer_out(p, x, attn, cfg, cs, moe_stats=moe_stats, u=u, picks=picks)
@@ -1659,8 +1751,36 @@ def forward_paged(
     # scan over periods of the pattern, the period's four layers unrolled in
     # its body, made XLA copy every period's slice of every leaf out — 13.6 ms
     # of a 32.6 ms forward (my chip run, PR 34)
+    def unpacked(x):  # a packed forward's pair (the block, the packed rows) -> every position its slot
+        return x if pack is None else jnp.where(pack.fits, pack.block(x[1]), x[0])
+
     with jax.named_scope("layers"):
-        if len(set(kinds)) == 1:
+        if cfg.ut_steps > 1:
+            # the SAME scanned weights ``ut_steps`` times: a scan of passes around
+            # the scan of layers — ONE layer body in the program's text, not
+            # ut_steps x n_layers of them — each pass on K/V planes of its own,
+            # closed by the model's norm and the exit gate
+            def read(x):  # what the head reads of a pass's state
+                return unpacked(x) if logit_pos is None else jnp.take_along_axis(
+                    unpacked(x), logit_pos[:, None, None], axis=1)
+
+            def one_pass(carry, u):
+                (x, *pools), ex = carry
+                (x, *pools), _ = jax.lax.scan(
+                    partial(layer, kind=kinds[0], base=pass_planes(u, cfg)), (x, *pools),
+                    (scanned, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+                x, ex = _close_pass(params, cfg, x, ex, u, read)
+                return ((x, *pools), ex), None
+
+            R = T if logit_pos is None else 1
+            books = LoopExit(jnp.zeros((B, R, cfg.dim), params["embed"].dtype),
+                             jnp.zeros((B, R), jnp.float32), jnp.ones((B, R), jnp.float32),
+                             jnp.full((B, R), -1, jnp.int32), jnp.zeros((), jnp.int32))
+            ((_, k_pool, v_pool, k_scale, v_scale), books), _ = jax.lax.scan(
+                one_pass, ((x, k_pool, v_pool, k_scale, v_scale), books),
+                jnp.arange(cfg.ut_steps, dtype=jnp.int32))
+            stats = None
+        elif len(set(kinds)) == 1:
             (x, k_pool, v_pool, k_scale, v_scale), stats = jax.lax.scan(
                 partial(layer, kind=kinds[0]),
                 (x, k_pool, v_pool, k_scale, v_scale),
@@ -1674,13 +1794,16 @@ def forward_paged(
             x, k_pool, v_pool, k_scale, v_scale = carry
             stats = jnp.stack(per_layer) if moe_stats else None
 
-    if pack is not None:
-        with jax.named_scope("layer/out/unpack"):  # once a forward: every position its slot
-            x = jnp.where(pack.fits, pack.block(x[1]), x[0])
-    with jax.named_scope("final_norm"):
-        if logit_pos is not None:  # the head on the one position a row reads
-            x = jnp.take_along_axis(x, logit_pos[:, None, None], axis=1)
-        x = _norm(x, params["final_norm"], cfg)
+    if cfg.ut_steps > 1:
+        x = books.state  # the selected pass's state, normed where its pass closed
+    else:
+        if pack is not None:
+            with jax.named_scope("layer/out/unpack"):  # once a forward
+                x = unpacked(x)
+        with jax.named_scope("final_norm"):
+            if logit_pos is not None:  # the head on the one position a row reads
+                x = jnp.take_along_axis(x, logit_pos[:, None, None], axis=1)
+            x = _norm(x, params["final_norm"], cfg)
     with jax.named_scope("lm_head"):
         if "lm_head" in params:
             logits = _qe("btd,dv->btv", x, params["lm_head"])
@@ -1701,7 +1824,7 @@ def forward_paged(
         behind = jnp.array([0, 1, 1], jnp.int32)
         extra += (sum(stats_of(split) if w is None else behind * stats_of(win_split.get(w))
                       for _, w in kinds)
-                  if windows else stats_of(split, reads=cfg.n_layers),)
+                  if windows else stats_of(split, reads=cfg.ut_steps * cfg.n_layers),)
     if window_stats:
         # what the layers behind a window attend of what their rows hold (the block
         # kernel's items, a range's block once for each of its riders; another path
@@ -1712,6 +1835,13 @@ def forward_paged(
                 walked = walked + sp.n_items - sp.n_common + sp.counts[0]
                 held, ranged = held + sp.counts[1], ranged + sp.counts[0]
         extra += (jnp.stack([walked, held, ranged]).astype(jnp.int32),)
+    if loop_stats:
+        # the positions the head read: a live row's one (``logit_pos``), else its real ones
+        valid = jnp.ones(books.step.shape, bool) if write_mask is None else write_mask[:, None]
+        if logit_pos is None and n_real is not None:
+            valid = valid & (jnp.arange(T, dtype=jnp.int32)[None, :] < n_real[:, None])
+        extra += (jnp.stack([books.passes, jnp.sum(valid),
+                             jnp.sum(valid & (books.step == cfg.ut_steps - 1))]).astype(jnp.int32),)
     if pack is not None:
         extra += (pack.stats,)
     return (logits, k_pool, v_pool, k_scale, v_scale, *extra)
@@ -1747,11 +1877,12 @@ def param_count(cfg: LlamaConfig) -> int:
     shared experts, a tied head once."""
     d, f, hd = cfg.dim, cfg.ffn_dim, cfg.head_dim
     per_layer = d * (cfg.n_heads * hd) + 2 * d * (cfg.n_kv_heads * hd) + (cfg.n_heads * hd) * d
-    norms = d if cfg.parallel_block else 2 * d
+    norms = (d if cfg.parallel_block else 2 * d) + (2 * d if cfg.sandwich_norm else 0)
     if cfg.n_experts > 0:
         per_layer += (cfg.n_held + cfg.n_shared_experts) * 3 * d * f + d * cfg.n_experts + norms
     else:
         per_layer += 3 * d * f + norms
     if cfg.qk_norm:
         per_layer += (cfg.n_heads + cfg.n_kv_heads) * hd
-    return cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2) + cfg.n_layers * per_layer + d
+    gate = d + 1 if cfg.ut_steps > 1 else 0  # the exit gate; looped layers are held ONCE
+    return cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2) + cfg.n_layers * per_layer + d + gate

@@ -285,6 +285,10 @@ def record_pool_gauges(alloc: "BlockAllocator", engine=None) -> None:
         m.set_gauge("paged.kv_quant_bits", float(engine.kv_quant_bits))
         m.set_gauge("paged.kv_bytes_per_block", float(bpb))
         m.set_gauge("paged.kv_bytes_per_token", float(bpb // engine.block_size))
+        # the K planes a token is written to, from the record's spec: a layer's —
+        # or, where the layers run more than once, one for every (pass, layer)
+        m.set_gauge("paged.kv_planes", float(sum(
+            p[0] for p in engine.family.cache["planes"]["k"].values())))
         # what a SLOT holds beside its blocks (a recurrent state, a convolution
         # tail): the record's per-slot planes, whatever the family; 0 for K/V alone
         m.set_gauge("paged.state_bytes_per_slot", float(sum(
@@ -1022,7 +1026,14 @@ class PagedDecodeEngine(DecodeEngine):
             self._prefix_state = {n: pool[n][:, 0] for pool, side in ((k, "k"), (v, "v"))
                                   for n in slot[side]}
             k, v = k["kv"], v["kv"]
-        return {"k": jax.tree.map(dense, k), "v": jax.tree.map(dense, v)}
+        # ONE pool at a time: its dense copy made and its scratch blocks gone before
+        # the other's is — beside a resident 12.2 GB a scratch pool of 192 planes is
+        # 0.9 GB a side and its copies as much again (16.43 of ~16.9 GB with both
+        # alive at once: my chip run, PR 57)
+        scratch = {"k": k, "v": v}
+        del k, v
+        return {side: jax.block_until_ready(jax.tree.map(dense, scratch.pop(side)))
+                for side in ("k", "v")}
 
     def _restore_slot_state(self, slot: int, snapshot: dict | None) -> None:
         """Before the admission chain of a model whose slots hold planes of

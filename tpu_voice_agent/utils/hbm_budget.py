@@ -69,7 +69,8 @@ def pp_tp_hbm_per_chip(
     ``costmodel.decode_step_bytes`` and, from the live tree, ``hbmledger``)."""
     from ..models.family import family
 
-    if cfg.n_experts or family(cfg).cache["state_column"]:  # a request's per-slot state: not sized here
+    fam = family(cfg)
+    if cfg.n_experts or fam.cache["state_column"]:  # a request's per-slot state: not sized here
         raise ValueError(f"hbm_budget sizes dense decoders on the pp x tp layout; a "
                          f"{type(cfg).__name__} with {cfg.n_experts} experts "
                          f"is not one (utils.hbmledger plans from the engine's own tree)")
@@ -83,12 +84,16 @@ def pp_tp_hbm_per_chip(
     layer_weights = layers_per_chip * per_layer_matmul * wbytes // tp
     scales = (layers_per_chip * per_layer_out_channels * 4 // tp
               if quant == "int8" else 0)
-    norms = layers_per_chip * 2 * d * 2  # bf16, replicated within stage
+    norms = layers_per_chip * (4 if cfg.sandwich_norm else 2) * d * 2  # bf16, replicated within stage
 
     embed = V * d * 2  # bf16, replicated
     lm_head = V * d * wbytes + (V * 4 if quant == "int8" else 0)  # replicated
 
-    kv_cache = 2 * layers_per_chip * batch_slots * max_len * (nkv // max(tp, 1) or 1) * hd * 2
+    # the K/V planes are the family record's, not the layer count: a model whose
+    # layers run more than once keeps a plane for every (pass, layer) over
+    # n_layers layers of weights (a stage holds its layers' planes of every pass)
+    planes_per_chip = (fam.cache["planes"]["k"]["kv"][0] if fam.kv_by_head else L) // pp
+    kv_cache = 2 * planes_per_chip * batch_slots * max_len * (nkv // max(tp, 1) or 1) * hd * 2
 
     # activation high-water mark: the per-slot prefill block dominates
     # (B=1, T=prefill_bucket): x + q/k/v + gate/up at f32 einsum outputs

@@ -70,6 +70,10 @@ def engine_hbm_plan(engine) -> dict:
         weights += out_ch * 4
     weights += V * d * 2  # embed: replicated bf16 (a gather — unquantized)
     weights += (L * 2 * d + d) * 2  # attn/mlp norms + final norm, bf16
+    if getattr(cfg, "sandwich_norm", False):
+        weights += L * 2 * d * 2  # the norms on the two sub-layers' OUTPUTS, bf16
+    if getattr(cfg, "ut_steps", 1) > 1:
+        weights += (d + 1) * 4  # the exit gate, float32; looped layers are held ONCE
 
     pool_blocks = getattr(getattr(engine, "allocator", None), "n_blocks", None)
     if pool_blocks is not None:
@@ -79,12 +83,18 @@ def engine_hbm_plan(engine) -> dict:
         # flagging a phantom 2-4x drift against a bf16-assumed plan
         from ..ops.kvquant import kv_block_bytes
 
-        kv = pool_blocks * kv_block_bytes(
-            L, engine.block_size, nkv, hd, getattr(engine, "kv_quant", None))
-        if not engine.family.kv_by_head:  # a latent cache: the planes its record names
+        # the PLANES are the family record's (the layers that write K/V, at the
+        # heads they hold — or, where the layers run more than once, one for every
+        # (pass, layer)); the weights above are the configuration's n_layers
+        fam = engine.family
+        if fam.kv_by_head:
+            planes, heads, width = fam.cache["planes"]["k"]["kv"]
+            kv = pool_blocks * kv_block_bytes(
+                planes, engine.block_size, heads, width, getattr(engine, "kv_quant", None))
+        else:  # a latent cache: the planes its record names
             kv = pool_blocks * engine.kv_bytes_per_block
     else:
-        kv = 2 * L * engine.batch_slots * engine.max_len * nkv * hd * 2
+        kv = 2 * L * engine.batch_slots * engine.max_len * nkv * hd * 2  # (no looped model: paged only)
         P = len(getattr(engine, "prefix_ids", ()) or ())
         if P and getattr(engine, "prefix_kv", None):
             kv += 2 * L * P * nkv * hd * 2  # dense prefix KV lives beside
