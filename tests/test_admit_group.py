@@ -215,12 +215,16 @@ def _one_row_prefill_text(eng) -> str:
 # lone admission still run these programs (what the entry points' compile
 # cache keys on, so a chip run LOADS the parent's executables). A PR that
 # changes ``forward_paged`` on purpose re-derives them (``_one_row_prefill_text``
-# on its parent's tree) and says so.
+# on its parent's tree) and says so. ISSUE 58 re-derived all four, each held on
+# its parent's tree (40ebd89) first: the covered blocks leave the pool in ONE
+# gather on (plane, block) (``llama.gather_row_blocks``) where a ``dynamic_slice``
+# of the whole plane stood before the gather, for K and for V — the only ops
+# that moved, in every text.
 ONE_ROW_SHA256 = {
-    "dense": "8a98dfcdaf06a51af0d8ba6e251b3d4bbef0d08663e2d0d18b7e14998a2e0dad",
-    "routed": "473ec8f7c6f0a6f79571e5feac550de838d34c0c77a4a69d892a5146a369e069",
-    "hybrid": "e832eabb33a04f121e95d85f69364d2ec5755eaabcb989af3041fce3347c14e2",
-    "share": "9e53bc71334fa837c8d1f8821e84b6f39cff8594a6516bdb4ca4183f888fe2a0",
+    "dense": "7789f597b44d748b9751077b6eec66621d98eb2cb87f5f91e64f7ee59ef6cfd0",
+    "routed": "f176598f04ae3a7504e54e58372a0705d163e2b27d82252dcaff72fd24b48f6e",
+    "hybrid": "8fdb094261566b504ae8da5a16de56ee31e2bf6752073a89348f4df54630dbf0",
+    "share": "63d2fdff4fdcd21ca6838c1322a69c22ba596a1b689cfd0efaf9b341e7d8dc32",
 }
 
 
@@ -319,18 +323,24 @@ def _chunk_program_shas(eng) -> list[str]:
 # three compacted widths (72 positions <= 96: nothing packs), the grouped
 # admissions, the one-row prefills and blocks of all five, ``tests/test_olmoe.py``'s
 # two (2 slots), ``tests/test_ffn_pack.py``'s three (no ``n_real``) and every pin
-# of ``dots3``, ``nemotron_h`` and ``olmo_hybrid`` hold UNEDITED.
+# of ``dots3``, ``nemotron_h`` and ``olmo_hybrid`` hold UNEDITED. ISSUE 58
+# re-derived all ten, each held on its parent's tree (40ebd89) first: this
+# module's engines attend through XLA, so their 1 + W block runs the branch an
+# admission runs (``kv_gather``), and that branch gathers (plane, block) out of
+# the pool in one op (``llama.gather_row_blocks``) where it sliced the plane
+# first. The chip's chunk programs go through the block kernel and hold no
+# such branch: theirs are the texts they were.
 CHUNK_SHA256 = {
-    "dense": ["bd6960a6e5413477e9c715d36602d0ea9ee6872a882bd60eeee5173252803bc9",
-              "428563f0ebbff1c395cfa7e002d824eb6266d55bb320837ed9778deade59d0e6"],
-    "routed": ["fcba4f5d8cbab2b63ebd7d9c0cf3fdd9fc6d95889d7d6961e28cc402de53ff38",
-               "f93929afe0b3424a7145f32f7d88ebe5064b4df934dcd35faeeb4ecee781e9cd"],
-    "hybrid": ["d8e6303aa7d3956d4cc9684598bde03842352655e7b5b051c6af74480c05ca3f",
-               "506e4a4ad1c7a114c16d1b8ca9efb7b6433140de317fac4113b25bc70eadb251"],
-    "share": ["ba851e38e55c751b8a0b7f1b780f249ca8c5d17c3528e0937b96eb72c55cb8c3",
-              "1861ec8d987fb1270ee5e093b253a5c4c8c852328b1635ef728cec379c4b8c69"],
-    "latent": ["3a81e8b6c4f77a510f8bc362c7e4ef85135f7f72c5aff9a3ae09b6f52115ca41",
-               "d4c10d336feec5790dcb611f3872a45da8640b5506463a3305d45664658a9f98"],
+    "dense": ["8c977f5a74057290497eb2416cab94954488d2829ca691e8d5de332e3592e73d",
+              "96ec54fc1aa63c2419c581ec802265b18bb4255a87b0d59999c51e23adaeedf4"],
+    "routed": ["a02dd35107a78c8cc6de92ff4e536b8bda89217eb0bfb1da078fc03742365feb",
+               "9721b92392c343728006147d4bf7ee918c041770022e932d4f983d759ab50dfe"],
+    "hybrid": ["eb147389e2bcda48ff01dfe6aa1d8be0c17e916d31a65cd1e2fc05b04100a32b",
+               "0b541fb49f406c239682ad77af65260bd3cb0e7cdaac4fc528a7829474995141"],
+    "share": ["5b6b550b7df812a9f70cc9057f953ee4ad8e2dc0c10098865a9be9df4312a720",
+              "988a45e19c81f0f36ef9d761bb4d75192eadcdef569e00dc6b8198a4eb5d19a5"],
+    "latent": ["f5666531c61fdd1447878cbb75e1c9dc4101423bb499baff0023c56e1bbd8203",
+               "4a12a86248e72b461cc40c639e9fec3c9838c5983a74c3cb3837037885ea2fd1"],
 }
 
 

@@ -342,11 +342,14 @@ def test_the_whole_width_branch_counts_what_it_counted(progs):
 # compile cache keys on) of ``forward_paged`` over ``_block`` WITHOUT
 # ``n_real`` as the PARENT of ISSUE 37 (commit 0cf90c6) lowers it: admission,
 # refcheck, spec decode's verify block, the T = 1 body and the compacted
-# chunk trace the program they traced.
+# chunk trace the program they traced. ISSUE 58 re-derived the three (they held
+# on its parent's tree, 40ebd89): these engines attend through XLA, where the
+# block's covered blocks leave the pool in one gather on (plane, block)
+# (``llama.gather_row_blocks``), no slice of the plane before it.
 PARENT_SHA256 = {
-    "dense": "f5ccf0b0e2a3d3d0cd3dbbfdde9f981abb9078119a1d186e4932a61d258e446f",
-    "routed": "e0474155d53199451149d5d107c38a607d23bae4d56c4be9b0852d02eae5432a",
-    "share": "d933de84b9bc016d6f2f94db045ca85cf193000d15de3cd8482a05dbeeab82fc",
+    "dense": "1c301076c905b2691dc2572b37945f4bb7e67bb9c637ae10081f752fd2e1c4bb",
+    "routed": "269d76deac3efea05ff0d2ceedb459c713ff55eb26d89dbe52a020205c44c9c9",
+    "share": "36a2205ead8a66041d701bba162e8bd615aefc5f2186eedf8f4ffa2a2d471c70",
 }
 
 

@@ -11,7 +11,13 @@ widths of all five and the one-row prefill of the other four
 ``serve/paged.py`` and a site context in ``services/prompts.py``: every older
 program's text — what the entry points' compile cache keys on, so a chip run
 LOADS the parent's executables — is what it was. A PR that changes one on
-purpose re-derives its hash on its parent's tree (``_texts``) and says so."""
+purpose re-derives its hash on its parent's tree (``_texts``) and says so.
+ISSUE 58 re-derived all eleven, each held on its parent's tree (40ebd89)
+first: an admission's covered blocks leave the pool in ONE gather on (plane,
+block) (``llama.gather_row_blocks``) where a ``dynamic_slice`` of the whole
+plane stood before the gather — and these engines attend through XLA, so
+their one-row 1 + 8 block runs that branch too (the chip's goes through the
+block kernel)."""
 
 import functools
 import hashlib
@@ -75,20 +81,20 @@ def _sha(text: str) -> str:
 
 
 GROUP_SHA256 = {
-    "dense": "22524698c3b1d71ff728c7215eb149dd0a3d0e94a46f76a5d517727e8f6a78dd",
-    "routed": "dafb520f8c61e940d2c3fd99155e60efdf610f50e78d99ef001a0c95c4e485b9",
-    "hybrid": "549545176fb0542421bbfdfcac9839965c769cc463b478a5cd58ad3f4577d798",
-    "share": "4e8b2a20d506addf970297f60d3b9576be8ce6cbbefb13d3bedb30c4c1887cd9",
-    "latent": "ea989309dd32bf9ff8f862c7ab15cbf11e4a540cd6db009b5e4908257635fefd",
+    "dense": "b7cdce3619d59bf1fdd9c6bf3c2a9653b268b3ab86aa7bdbf53e6fb925dd7a15",
+    "routed": "6e7f92cfd707cc485c6f106fa8d4b89d94b7ae8df10f98a40ceea7576f1277bc",
+    "hybrid": "acb9d1c6bbc52cc7157db51550b120329d948f43e10e05b0ac0a4ff48b91a7e9",
+    "share": "04e2351e128feae97bec1ce962c0027b92e8a738024fc5dad0f403c1f1ccf4c5",
+    "latent": "b34710488aaf21d0cc9a9638ffe07d5deae53fc59bf6853817a490429580eef6",
 }
 BLOCK_SHA256 = {
-    "dense": "d5e2511ae1f7682872fb2f1bc36a8884ce8fe15c8283a3358a4fe8163c3891a6",
-    "routed": "fea6fbfba597ad7171319ba803eda797c1880c522df8937dc5386ccc5108ad1f",
-    "hybrid": "7a327bad66726f8026c9369822b58b55ed741bfe6943bfcef0514079bdc254c8",
-    "share": "e07fe19a48f64fc9fd8307bcebe9da9fa36beea0b80d04ac4a582102dc3e70c5",
-    "latent": "56a77e8468c62c1ce0fb5d3031b31876229e267a2d2718028c9311aa0f1d9e8b",
+    "dense": "daf78869c42d72060244ed54c0cdc0cb47c57940008225a03ba69c5061d01110",
+    "routed": "f106b93f4903e3e79e50b1191524f9e663d8fcdb6b3239bcc47343d598c402e7",
+    "hybrid": "45fa4e5f3ecf39c2df110c0a00aca72bb35776c91c0c486633e4da5a9125191b",
+    "share": "55d2571d1caf47c0d156ccca20ec77e167c8bb707176117d63ceb6ae8ce3285f",
+    "latent": "d2c4f887cbe6ee5f91e5f466aacd017b445e6dde8761f7d5140c2129f7e6b214",
 }
-ONE_ROW_SHA256 = {"latent": "7d601f093f6b2931a6512bea9f217c01b62629c049d05e4867ff1017c543303f"}
+ONE_ROW_SHA256 = {"latent": "7cec90a9b8a7060bfa96be805a21d6bfc0fc64cafda675e51fe6354f00462cf5"}
 
 
 @functools.lru_cache(maxsize=None)

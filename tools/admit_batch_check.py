@@ -46,7 +46,7 @@ def build_engine(conf: dict, rehearsal: bool, prefix: bool = True):
     import jax
 
     from benchmark.builders import (cohere2moe_stack, dots3_stack, moonlight_stack, olmoe_stack,
-                                    parse_stack, sambay_stack, smallthinker_stack)
+                                    ouro_stack, parse_stack, sambay_stack, smallthinker_stack)
     from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
     from tpu_voice_agent.serve import PagedDecodeEngine
     from tpu_voice_agent.services.brain import install_prompt_prefix
@@ -61,6 +61,7 @@ def build_engine(conf: dict, rehearsal: bool, prefix: bool = True):
         "moonlight_stack": (moonlight_stack.llama_config, moonlight_stack.make_params),
         "dots3_stack": (dots3_stack.llama_config, dots3_stack.make_params),
         "smallthinker_stack": (smallthinker_stack.llama_config, smallthinker_stack.make_params),
+        "ouro_stack": (ouro_stack.llama_config, ouro_stack.make_params),
     }[conf["builder"]]
     eng = PagedDecodeEngine(
         cfg=llama_config(m, s), tokenizer=default_tokenizer(), quant=s["quant"],

@@ -306,6 +306,16 @@ def cache_spec(cfg: LlamaConfig) -> dict:
     return cache_planes(kv, kv)
 
 
+def gather_row_blocks(pool: jax.Array, plane, tbl: jax.Array) -> jax.Array:
+    """The blocks ``tbl`` (rows, blocks) names in plane ``plane`` of a pool (planes, N, bs,
+    ...), as (rows, blocks, bs, ...): ONE gather on (plane, block) in the pool as it is
+    shaped. ``pool[plane][tbl]`` slices the WHOLE plane first — a ``dynamic_slice`` at a
+    scan's index, a static one in an unrolled layer — and XLA writes that plane out in HBM
+    before it gathers from it: 0.21 s of ``ouro_flood``'s traced stretch, 0.10 s of
+    ``parse_flood``'s (ledger, PR 57), 10 of ``olmohybrid_flood``'s 51 ms call (PR 58)."""
+    return pool[plane, tbl]
+
+
 def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     """Random init. Layer weights are stacked on a leading n_layers axis."""
     from .family import family  # the ONE hand-off to the sibling families (they import this module)
@@ -1711,18 +1721,17 @@ def forward_paged(
                 # COVERED blocks to a contiguous view once per layer
                 with jax.named_scope("kv_gather"):
                     tbl = block_tables[:, :nb]
+
+                    def covered(pool):  # values (B, S, heads, width) or scales (B, S, heads)
+                        return gather_row_blocks(pool, plane, tbl).reshape(B, S, *pool.shape[3:])
+
                     if kv_quant is None:
-                        kl = kp[plane][tbl].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-                        vl = vp[plane][tbl].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+                        kl, vl = covered(kp), covered(vp)
                     else:
                         from ..ops.kvquant import dequantize_kv
 
-                        kl = dequantize_kv(
-                            kp[plane][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
-                            ksc[plane][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
-                        vl = dequantize_kv(
-                            vp[plane][tbl].reshape(B, S, cfg.n_kv_heads, hdp),
-                            vsc[plane][tbl].reshape(B, S, cfg.n_kv_heads), kv_quant)
+                        kl = dequantize_kv(covered(kp), covered(ksc), kv_quant)
+                        vl = dequantize_kv(covered(vp), covered(vsc), kv_quant)
                 attn = _attend(q, kl, vl, positions, kv_len_mask, window)
         if pack is None:
             out = _layer_out(p, x, attn, cfg, cs, moe_stats=moe_stats, u=u, picks=picks)

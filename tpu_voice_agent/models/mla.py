@@ -44,7 +44,7 @@ import jax.numpy as jnp
 
 from .llama import (MAX_BLOCK_DECODE_T, LlamaConfig, _attn_stats, _ffn, _qe, _scan_and_whole,
                     _swiglu, _EXPERT_LEAVES, apply_rope_interleaved, cache_planes, ffn_pack_index,
-                    packed_ffn, rms_norm, rope_tables)
+                    gather_row_blocks, packed_ffn, rms_norm, rope_tables)
 
 F32 = jnp.float32
 
@@ -246,8 +246,8 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, c_pool, r_pool, b
             else:
                 with jax.named_scope("kv_gather"):
                     tbl = block_tables[:, :nb]
-                    cl = cp[li][tbl].reshape(B, nb * bs, C)
-                    rl = rp[li][tbl].reshape(B, nb * bs, dr)
+                    cl = gather_row_blocks(cp, li, tbl).reshape(B, nb * bs, C)
+                    rl = gather_row_blocks(rp, li, tbl).reshape(B, nb * bs, dr)
                 a = latent_attention_reference(q_c, q_r, cl, rl, positions, scale=scale)
         attn = latent_out(p, a, cfg, x.dtype)
         with jax.named_scope("layer/attn_out"):

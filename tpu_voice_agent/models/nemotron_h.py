@@ -60,8 +60,8 @@ from dataclasses import dataclass, replace
 import jax
 import jax.numpy as jnp
 
-from .llama import (EXPERT_ACTS, MAX_BLOCK_DECODE_T, _moe_ffn, _qe, cache_planes, moe_stat_names,
-                    quantize_leaf, rms_norm, row_tiles)
+from .llama import (EXPERT_ACTS, MAX_BLOCK_DECODE_T, _moe_ffn, _qe, cache_planes, gather_row_blocks,
+                    moe_stat_names, quantize_leaf, rms_norm, row_tiles)
 from .sambay import _NO_WINDOW, StateNotCarried, _attend  # noqa: F401  (the family's error class)
 
 F32 = jnp.float32
@@ -453,8 +453,8 @@ def forward_paged(params, cfg: NemotronHConfig, tokens, positions, k_pool, v_poo
             else:
                 with jax.named_scope("kv_gather"):
                     tbl = tables[:, :nb]
-                    kl = kp[ai][tbl].reshape(B, nb * bs, nkv, hd)
-                    vl = vp[ai][tbl].reshape(B, nb * bs, nkv, hd)
+                    kl = gather_row_blocks(kp, ai, tbl).reshape(B, nb * bs, nkv, hd)
+                    vl = gather_row_blocks(vp, ai, tbl).reshape(B, nb * bs, nkv, hd)
                 a = _attend(q, kl, vl, positions, _NO_WINDOW, scale)
         with jax.named_scope("layer/attn_out"):
             out = _qe("bth,hd->btd", a.astype(x.dtype).reshape(B, T, nq * hd), p["wo"])

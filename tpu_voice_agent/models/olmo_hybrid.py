@@ -68,8 +68,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.gated_delta import plane_shape
-from .llama import (MAX_BLOCK_DECODE_T, _qe, _swiglu, cache_planes, ffn_pack_index, quantize_leaf,
-                    rms_norm)
+from .llama import (MAX_BLOCK_DECODE_T, _qe, _swiglu, cache_planes, ffn_pack_index, gather_row_blocks,
+                    quantize_leaf, rms_norm)
 from .sambay import _NO_WINDOW, StateNotCarried, _attend  # noqa: F401  (the family's error class)
 
 F32 = jnp.float32
@@ -445,8 +445,8 @@ def forward_paged(params, cfg: OlmoHybridConfig, tokens, positions, k_pool, v_po
             else:
                 with jax.named_scope("kv_gather"):
                     tbl = tables[:, :nb]
-                    kl = kp[ai][tbl].reshape(B, nb * bs, -1, hd)[:, :, :nkv]
-                    vl = vp[ai][tbl].reshape(B, nb * bs, -1, hd)[:, :, :nkv]
+                    kl = gather_row_blocks(kp, ai, tbl).reshape(B, nb * bs, -1, hd)[:, :, :nkv]
+                    vl = gather_row_blocks(vp, ai, tbl).reshape(B, nb * bs, -1, hd)[:, :, :nkv]
                 a = _attend(q, kl, vl, positions, _NO_WINDOW, scale)
         x = rowwise(out_and_mlp(params["attn"], ai, "layer/attn_out"),
                     (x, a.astype(dtype).reshape(B, T, nq * hd)), "layer/rows")

@@ -64,7 +64,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from .llama import MAX_BLOCK_DECODE_T, _qe, cache_planes, quantize_leaf
+from .llama import MAX_BLOCK_DECODE_T, _qe, cache_planes, gather_row_blocks, quantize_leaf
 
 F32 = jnp.float32
 _NO_WINDOW = 1 << 30
@@ -436,8 +436,8 @@ def forward_paged(params, cfg: SambaYConfig, tokens, positions, k_pool, v_pool, 
                 jnp.int32(cfg.window) if windowed else None, n_real, scale=scale, out_dtype=F32)
         with jax.named_scope("kv_gather"):
             tbl = tables[:, :nb]
-            kl = kp[plane][tbl].reshape(B, nb * bs, G, w)
-            vl = vp[plane][tbl].reshape(B, nb * bs, G, w)
+            kl = gather_row_blocks(kp, plane, tbl).reshape(B, nb * bs, G, w)
+            vl = gather_row_blocks(vp, plane, tbl).reshape(B, nb * bs, G, w)
         return _attend(q, kl, vl, positions, cfg.window if windowed else _NO_WINDOW, scale)
 
     def period(x, kp, vp, conv, ssm, p, i, windowed: bool):
