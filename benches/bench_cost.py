@@ -103,8 +103,7 @@ def main() -> None:
                    if prefill_total else 0.0)
     log(f"split: prefill {prefill_frac:.1%} of total flops "
         f"({cached_frac:.1%} of prefill served from cache), decode "
-        f"{1 - prefill_frac:.1%}; wasted drafts "
-        f"{totals['wasted_draft_flops']} flops")
+        f"{1 - prefill_frac:.1%}")
 
     # ---- capacity differential: alternating on/off rounds so machine
     # drift cancels instead of masquerading as metering overhead; the

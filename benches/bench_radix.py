@@ -28,8 +28,7 @@ the same bytes into ~2×/~4× the pool blocks, reported as
 max_len worst-case sequences the budget admits — 0/1/2 at the tight
 budget) with hit rate and eviction churn per tier — the doubled pool
 must RAISE reuse (int8 hit rate below bf16 fails the bench; measured:
-churn 4 → 0 evictions at the same bytes). The ≥ 1.9× serving-dims
-capacity bar is gated in bench_spec's ``kvq_pool_capacity_*`` rows.
+churn 4 → 0 evictions at the same bytes).
 
 Knobs: BENCH_RADIX_SESSIONS (default 4), BENCH_RADIX_TURNS (default 4),
 BENCH_RADIX_TOKENS (default 48), BENCH_RADIX_BLOCK (default 64 — finer
@@ -269,8 +268,7 @@ def main() -> None:
         }
     # the capacity multiple this engine actually realized (test-tiny's
     # head_dim 32 pays proportionally more scale overhead than serving
-    # dims — the >= 1.9x serving-dims bar is gated in bench_spec's
-    # kvq_pool_capacity_* rows; this row benchdiff-gates against drift)
+    # dims; this row benchdiff-gates against drift)
     cap8 = kvq_section["int8"]["pool_blocks"] / kvq_section["off"]["pool_blocks"]
     row("kvq_radix_pool_capacity_int8", cap8, "x")
     # a thinner-but-lossier tier must not COST reuse on the same workload

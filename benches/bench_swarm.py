@@ -13,22 +13,9 @@ Emits the standard one-JSON-row-per-metric contract plus a
 ``BENCH_swarm_<ts>.json`` artifact whose ``swarm`` section run_all.py
 merges into the combined snapshot (incl. ``--quick`` at trimmed N).
 
-The engine-backed section (ISSUE 8, BENCH_SWARM_SPEC=1 default) re-runs
-the capacity search against a REAL paged+radix test-tiny engine behind the
-continuous batcher — once spec-off, once with SPEC_ENABLE-equivalent
-speculation on — and gates on the ratio: capacity at SLO with speculative
-decode must not fall below the spec-off engine plane (the host-side
-draft/verify loop must buy steps, not capacity). The gate is ENFORCED:
-ratio < 0.75 (one-session probe noise at quick-scale integer capacities
-is tolerated) or an unservable spec-off plane exits non-zero, failing the
-run_all table. SLO thresholds are
-widened for the tiny-real-model CPU harness exactly like bench_chaos; the
-verdict is the RATIO under identical thresholds.
-
 Knobs: BENCH_SWARM_MAX_N (default 192), BENCH_SWARM_UTTERANCES (6),
 BENCH_SWARM_THINK_S (0.05), BENCH_SWARM_BRAIN_INFLIGHT (8),
-BENCH_SWARM_EXEC_INFLIGHT (8), BENCH_SWARM_SPEC (1),
-BENCH_SWARM_ENGINE_MAX_N (8), BENCH_SWARM_ENGINE_SLOTS (4).
+BENCH_SWARM_EXEC_INFLIGHT (8).
 """
 
 from __future__ import annotations
@@ -46,45 +33,6 @@ from common import _ROOT, emit, log, snapshot_observability  # noqa: E402
 
 sys.path.insert(0, str(Path(_ROOT) / "tools"))
 import swarm  # noqa: E402
-
-
-def _engine_parser(slots: int, spec: bool):
-    """The compound serving plane under capacity test: paged + radix
-    test-tiny behind the continuous batcher (bench_chaos's system-under-
-    drill), optionally with speculative decoding stacked on (ISSUE 8)."""
-    from tpu_voice_agent.serve import PagedDecodeEngine, SpecConfig
-    from tpu_voice_agent.services.brain import (
-        BatchedEngineParser,
-        install_prompt_prefix,
-    )
-
-    eng = PagedDecodeEngine(
-        preset="test-tiny", max_len=2048, batch_slots=slots,
-        prefill_buckets=(128, 256, 512, 1024, 2048), radix_enable=True,
-        spec=SpecConfig(k=4, drafter="fsm,prompt") if spec else None)
-    install_prompt_prefix(eng)
-    return BatchedEngineParser(eng, chunk_steps=16, session_aware=True)
-
-
-def _engine_capacity(label: str, max_n: int, utterances: int,
-                     slots: int, spec: bool) -> dict:
-    import tempfile
-
-    tmp = tempfile.mkdtemp(prefix=f"bench_swarm_{label}_")
-    parser = _engine_parser(slots, spec)
-    urls, servers = swarm.build_local_stack(
-        tmp, brain_inflight=8, exec_inflight=8, parser=parser,
-        parse_timeout_s=20.0)
-    try:
-        log(f"[{label}] binary-searching engine-backed capacity up to "
-            f"{max_n} sessions (spec={'on' if spec else 'off'})")
-        return swarm.binary_search_capacity(
-            urls["voice"], max_n=max_n, sample_urls=list(urls.values()),
-            utterances=utterances, think_s=0.05)
-    finally:
-        for srv in servers:
-            srv.__exit__(None, None, None)
-        parser.close()
 
 
 def main() -> None:
@@ -142,45 +90,6 @@ def main() -> None:
         emit("swarm_error_rate_at_capacity", slo_at_cap["error_rate"], "fraction")
     emit("swarm_probes", float(len(result["probes"])), "runs")
 
-    # ------------------------------------------- engine-backed spec gate
-    engine_section: dict = {}
-    if os.environ.get("BENCH_SWARM_SPEC", "1") == "1":
-        engine_max_n = int(os.environ.get("BENCH_SWARM_ENGINE_MAX_N", "8"))
-        engine_slots = int(os.environ.get("BENCH_SWARM_ENGINE_SLOTS", "4"))
-        # widened CPU-harness SLO for the tiny REAL model (bench_chaos's
-        # discipline: identical thresholds both runs, the verdict is the
-        # ratio); operators can pin their own
-        os.environ.setdefault("SLO_TARGET_P50_MS", "8000")
-        os.environ.setdefault("SLO_TARGET_P99_MS", "30000")
-        plain = _engine_capacity("engine", engine_max_n, utterances,
-                                 engine_slots, spec=False)
-        spec = _engine_capacity("engine+spec", engine_max_n, utterances,
-                                engine_slots, spec=True)
-        cap_plain = plain["capacity_sessions"]
-        cap_spec = spec["capacity_sessions"]
-        ratio = cap_spec / cap_plain if cap_plain else 0.0
-        log(f"engine-backed capacity: spec-off {cap_plain}, spec-on "
-            f"{cap_spec} sessions (ratio {ratio:.2f}; the gate: speculation "
-            "must not cost capacity)")
-        # ENFORCED gate (like bench_spec's identity gate): capacities are
-        # integer session counts from a binary search, so at quick-scale N
-        # one session of probe noise is possible — the hard floor is 0.75,
-        # and a spec-off plane that cannot serve at all fails outright
-        if cap_plain == 0 or ratio < 0.75:
-            log(f"SPEC CAPACITY GATE FAILED: spec-on/{'off' if cap_plain else 'OFF=0'} "
-                f"ratio {ratio:.2f} < 0.75")
-            sys.exit(1)
-        emit("swarm_capacity_engine_sessions", float(cap_plain), "sessions")
-        emit("swarm_capacity_engine_spec_sessions", float(cap_spec),
-             "sessions", vs_baseline=ratio)
-        engine_section = {
-            "engine_capacity_sessions": cap_plain,
-            "engine_spec_capacity_sessions": cap_spec,
-            "spec_capacity_ratio": round(ratio, 3),
-            "engine_at_capacity": plain.get("at_capacity"),
-            "engine_spec_at_capacity": spec.get("at_capacity"),
-        }
-
     art_dir = Path(_ROOT) / "bench_artifacts"
     art_dir.mkdir(exist_ok=True)
     stamp = datetime.datetime.now().strftime("%Y%m%d_%H%M%S")
@@ -199,7 +108,6 @@ def main() -> None:
             "knee": knee,
             "first_saturated": first,
             "flight_recorder": flight,
-            **engine_section,
         },
         **obs,
     }, indent=1))

@@ -59,29 +59,6 @@ def percentile(xs, q) -> float:
     return float(np.percentile(xs, q))
 
 
-def snapshot_spec() -> dict:
-    """The speculative-decoding verdict for a BENCH_* artifact, shaped like
-    the SLO section bench_faults embeds: the process-local spec.* counters
-    and gauges (serve.spec registers them) plus the derived accept rate.
-    In-process benches call it after their spec runs; {} when speculation
-    never ran — observability must never fail a bench."""
-    from tpu_voice_agent.utils import get_metrics
-
-    snap = get_metrics().snapshot()
-    drafted = snap["counters"].get("spec.drafted_tokens", 0.0)
-    accepted = snap["counters"].get("spec.accepted_tokens", 0.0)
-    steps = snap["counters"].get("spec.verify_steps", 0.0)
-    if steps <= 0:
-        return {}
-    return {
-        "drafted_tokens": drafted,
-        "accepted_tokens": accepted,
-        "verify_steps": steps,
-        "accept_rate": (accepted / drafted) if drafted else 0.0,
-        "tokens_per_step": snap["gauges"].get("spec.tokens_per_step"),
-    }
-
-
 def snapshot_observability(service_url: str, timeout_s: float = 5.0) -> dict:
     """One service's SLO verdict + per-stage latency decomposition, shaped
     for embedding in a BENCH_* artifact (``{"slo": ..., "stage_latency_ms":

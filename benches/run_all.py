@@ -26,7 +26,6 @@ from pathlib import Path
 
 BENCHES = ["bench_batch.py", "bench_stt.py", "bench_grounding.py",
            "bench_quality.py", "bench_quality_online.py", "bench_faults.py",
-           "bench_spec.py",
            "bench_radix.py", "bench_swarm.py", "bench_chaos.py",
            "bench_steplog.py", "bench_router.py", "bench_handoff.py",
            "bench_fleet.py", "bench_autopilot.py", "bench_cost.py",
@@ -35,9 +34,7 @@ BENCHES = ["bench_batch.py", "bench_stt.py", "bench_grounding.py",
 # --quick: the fast subset (quality rows always run — they skip cleanly
 # when no checkpoint is configured; the heavy latency benches are dropped;
 # the fault drill stays — it is service-level, no model, seconds on CPU;
-# the spec bench stays at a reduced utterance/token budget — tiny model,
-# and the accept-rate verdict belongs in every quick artifact; the STT
-# bench stays at trimmed stream counts/seconds so the multi-stream
+# the STT bench stays at trimmed stream counts/seconds so the multi-stream
 # capacity number lands in every combined artifact; the radix bench runs
 # UNTRIMMED — the tiny model makes its full 4-session x 4-turn workload
 # ~30 s on CPU, and the turn-2+ prefill-collapse verdict is a mean over
@@ -93,7 +90,7 @@ BENCHES = ["bench_batch.py", "bench_stt.py", "bench_grounding.py",
 # identity, or leaks blocks on the prefill-kill drill must fail the
 # quick table as well
 QUICK_BENCHES = ["bench_quality.py", "bench_quality_online.py",
-                 "bench_faults.py", "bench_spec.py",
+                 "bench_faults.py",
                  "bench_stt.py", "bench_radix.py", "bench_swarm.py",
                  "bench_chaos.py", "bench_steplog.py", "bench_router.py",
                  "bench_handoff.py", "bench_fleet.py", "bench_autopilot.py",
@@ -103,11 +100,8 @@ QUICK_BENCHES = ["bench_quality.py", "bench_quality_online.py",
 QUICK_ENV = {"EVAL_BACKEND": "rule",
              "BENCH_QO_MAX_N": "4", "BENCH_QO_UTTERANCES": "2",
              "BENCH_QO_DETECT_TIMEOUT_S": "30",
-             "BENCH_SPEC_UTTERANCES": "3", "BENCH_SPEC_TOKENS": "96",
-             "BENCH_SPEC_PAGED_SESSIONS": "2", "BENCH_SPEC_PAGED_TURNS": "2",
              "BENCH_STT_SECONDS": "4", "BENCH_STT_STREAMS": "1,4",
              "BENCH_SWARM_MAX_N": "8", "BENCH_SWARM_UTTERANCES": "3",
-             "BENCH_SWARM_ENGINE_MAX_N": "4",
              "BENCH_CHAOS_MAX_N": "4", "BENCH_CHAOS_UTTERANCES": "2",
              "BENCH_STEPLOG_SESSIONS": "6", "BENCH_STEPLOG_ROUNDS": "2",
              "BENCH_ROUTER_MAX_N": "6", "BENCH_ROUTER_UTTERANCES": "2",
@@ -213,7 +207,7 @@ def main() -> None:
             if body.get("bench") == name.removesuffix(".py"):
                 entry["artifact"] = art.name
                 for key in ("slo", "stage_latency_ms", "runtime_gauges",
-                            "spec", "stt", "radix", "swarm", "chaos",
+                            "stt", "radix", "swarm", "chaos",
                             "steplog", "engine_step", "xla", "hbm",
                             "router", "kv_quant", "handoff", "fleet",
                             "quality", "autopilot", "cost", "tenancy",

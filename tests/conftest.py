@@ -42,7 +42,7 @@ import pytest  # noqa: E402
 # a habit). Fast tier = the service/contract/unit tests plus the shared
 # session-scoped engines: `pytest -m "not slow"` (< ~3 min on CPU). Slow
 # tier = compile-heavy mesh/parity/model tests, auto-marked per module here
-# (one central list instead of 19 scattered pytestmark lines). The plain
+# (one central list instead of scattered pytestmark lines). The plain
 # `pytest tests/` still runs EVERYTHING — the driver's green bar covers
 # both tiers.
 SLOW_MODULES = {
@@ -50,16 +50,11 @@ SLOW_MODULES = {
     "test_ckpt",
     "test_colocate",
     "test_expert",
-    "test_fastforward",
     "test_hf_real",
-    "test_llama",
     "test_longctx",
-    "test_moe_llama",
     "test_multihost",
     "test_ops_sharded",
-    "test_paged",
     "test_pipeline",
-    "test_prefix",
     "test_qwen2vl",
     "test_races",
     "test_ring",

@@ -252,7 +252,7 @@ def test_knob_accessors_fall_back_to_declared_defaults():
     assert knobs.get("STEPLOG_ENABLE") == "1"  # declared default
     assert knobs.knob_bool("STEPLOG_ENABLE") is True
     assert knobs.knob_bool("STEPLOG_ENABLE", default=False) is False  # override
-    assert knobs.knob_bool("SPEC_ENABLE") is False  # declared default None
+    assert knobs.knob_bool("RADIX_ENABLE") is False  # declared default None
     assert knobs.knob_int("STEPLOG_STEPS") == 256
     with pytest.raises(KeyError):
         knobs.get("NOT_A_DECLARED_KNOB")

@@ -20,7 +20,7 @@ import urllib.request
 
 import pytest
 
-from tpu_voice_agent.serve import DecodeEngine, PagedDecodeEngine, SpecConfig
+from tpu_voice_agent.serve import DecodeEngine, PagedDecodeEngine
 from tpu_voice_agent.serve.scheduler import ContinuousBatcher
 from tpu_voice_agent.services.brain import (
     SessionTranscripts,
@@ -37,7 +37,6 @@ from tpu_voice_agent.utils.costmodel import (
     llm_attn_flops_per_ctx,
     llm_token_flops,
     prefill_flops,
-    spec_verify_flops,
     whisper_decoder_flops,
     whisper_encoder_flops,
     zero_ledger,
@@ -97,12 +96,10 @@ def test_prefill_split_exact_partition(tiny_cfg):
     assert model.prefill_split(0, 0) == (0, 0)
 
 
-def test_decode_and_spec_verify_flops(tiny_cfg):
+def test_decode_flops(tiny_cfg):
     tok = llm_token_flops(tiny_cfg)
     att = llm_attn_flops_per_ctx(tiny_cfg)
     assert decode_flops(tiny_cfg, 3, 100) == 3 * (tok + 100 * att)
-    # a verify forward computes 1 + K positions whether drafts survive
-    assert spec_verify_flops(tiny_cfg, 200, 4) == decode_flops(tiny_cfg, 5, 200)
     model = CostModel(tiny_cfg)
     fl, by = model.decode_row(2, 50)
     assert fl == decode_flops(tiny_cfg, 2, 50)
@@ -171,7 +168,6 @@ def test_dense_conservation_exact(tiny_batch_engine):
     t = b.costs.totals
     assert t["prefill_flops"] > 0 and t["decode_flops"] > 0
     assert t["decode_bytes"] > 0 and t["kv_block_us"] > 0
-    assert t["wasted_draft_flops"] == 0  # no drafts on the plain loop
     assert t["prefill_cached_flops"] == 0  # dense engine, no prefix cache
     # the meter reconciled measured walls into live gauges + counters
     snap = get_metrics().snapshot()
@@ -218,13 +214,12 @@ def test_cost_lanes_token_identity_and_quiet_sentinel(tiny_batch_engine,
 @pytest.mark.parametrize("tier", [None, "int8", "int4"])
 def test_paged_mixed_batch_conservation(tier):
     """The acceptance drill: ONE meter over a mixed workload — radix warm
-    hits, spec accepts/rejects, a chaos-poisoned row, a mid-decode
+    hits, a chaos-poisoned row, a mid-decode
     cancellation — reconciles exactly, errored rows still billing the
     work they spent before eviction."""
     eng = PagedDecodeEngine(
         preset="test-tiny", max_len=2048, batch_slots=2,
-        prefill_buckets=BUCKETS, radix_enable=True,
-        spec=SpecConfig(k=4, drafter="fsm,prompt"), kv_quant=tier or "off")
+        prefill_buckets=BUCKETS, radix_enable=True, kv_quant=tier or "off")
     install_prompt_prefix(eng)
     b = ContinuousBatcher(eng, chunk_steps=8, max_new_tokens=MAXTOK)
     assert b.costs is not None
@@ -280,10 +275,6 @@ def test_paged_mixed_batch_conservation(tier):
     # EXACT reconciliation over every request this meter ever saw
     _assert_conserved(b, seen)
     t = b.costs.totals
-    # spec ran: drafts were paid for, rejected ones show up as waste — a
-    # subset of decode_flops, never more
-    assert eng.spec.stats()["accepted"] > 0
-    assert 0 <= t["wasted_draft_flops"] <= t["decode_flops"]
     # paged rows hold real block-time (owned + shared x chunk walls)
     assert t["kv_block_us"] > 0
 

@@ -772,12 +772,11 @@ def test_the_full_width_chunk_program_walks_tiles_and_branches_nowhere(engine_of
     assert count(packed, "while") == count(whole, "while") + 2 * eng.cfg.n_layers
 
 
-@pytest.mark.parametrize("what", ["radix", "spec", "kv_quant", "handoff", "mesh", "dense_engine", "dense_forward"])
+@pytest.mark.parametrize("what", ["radix", "kv_quant", "handoff", "mesh", "dense_engine", "dense_forward"])
 def test_what_moves_k_and_v_planes_refuses_the_planes_by_kind_by_type(what):
     """ONE typed error, where each is built or called — its message names the
     index plane."""
     from tpu_voice_agent.serve import DecodeEngine
-    from tpu_voice_agent.serve.spec import SpecConfig
 
     if what == "dense_forward":
         params = init_params(CFG, jax.random.key(0), F32)
@@ -788,8 +787,6 @@ def test_what_moves_k_and_v_planes_refuses_the_planes_by_kind_by_type(what):
     with pytest.raises(mla.LatentCacheOnly):
         if what == "radix":
             _engine(radix_enable=True)
-        elif what == "spec":
-            _engine(spec=SpecConfig(k=2))
         elif what == "kv_quant":
             _engine(kv_quant="int8")
         elif what == "handoff":

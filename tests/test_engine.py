@@ -157,3 +157,14 @@ class TestQuantizedEngine:
         b = meshed.generate(prompt, max_new_tokens=160)
         assert a.error is None and b.error is None
         assert a.token_ids == b.token_ids
+
+
+def test_generation_result_zero_duration_guard():
+    from tpu_voice_agent.serve import GenerationResult
+
+    r = GenerationResult(text="", token_ids=[1], prefill_ms=0.0,
+                         decode_ms=0.0, steps=1, finished=True)
+    assert r.tokens_per_s == 0.0
+    r2 = GenerationResult(text="", token_ids=[1], prefill_ms=0.0,
+                          decode_ms=-1.0, steps=1, finished=True)
+    assert r2.tokens_per_s == 0.0

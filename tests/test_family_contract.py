@@ -18,11 +18,12 @@ from tests.test_admit_group import _model as _older_model
 from tpu_voice_agent.models import dots3, llama, mla, olmo_hybrid, sambay
 from tpu_voice_agent.models.family import FFN, family
 from tpu_voice_agent.serve import ContinuousBatcher, DecodeEngine, PagedDecodeEngine
-from tpu_voice_agent.serve.spec import SpecConfig
 
-FEATURES = ("kv_quant", "radix", "mesh", "spec", "handoff", "chunked_prefill", "dense_cache",
+FEATURES = ("kv_quant", "radix", "mesh", "handoff", "chunked_prefill", "dense_cache",
             "ffn_pack")  # what a serving plane may ask of a family: a record refuses some, by name
 MODELS = ("dense", "routed", "hybrid", "share", "latent", "sparse")
+# the families that came behind ISSUE 46's six (``_model``), and what each one's refusals name
+LATER = {"gdn": "(delta-rule|OlmoHybridConfig)", "looped": "pass"}
 FAMILY = {"dense": "plain", "routed": "plain", "hybrid": "hybrid", "share": "plain",
           "latent": "latent", "sparse": "sparse"}
 SLOTS, BS, BLOCKS = 8, 128, 24
@@ -116,8 +117,6 @@ def _enter(feature: str, model: str):
         from tpu_voice_agent.parallel import make_mesh
 
         return _engine(model, mesh=make_mesh(dp=2, tp=1, devices=jax.devices()[:2])).dp
-    if feature == "spec":
-        return _engine(model, spec=SpecConfig(k=2, drafter="fsm")).spec
     if feature == "handoff":
         return _engine(model).gather_chain_kv([1])
     if feature == "chunked_prefill":
@@ -130,11 +129,14 @@ def _enter(feature: str, model: str):
 
 
 @pytest.mark.parametrize("feature", FEATURES)
-@pytest.mark.parametrize("model", MODELS)
+@pytest.mark.parametrize("model", MODELS + tuple(LATER))
 def test_what_the_table_refuses_is_refused_by_type_and_nothing_else_is(model, feature):
     fam = family(_cfg(model))
     if feature not in fam.refuses:
-        assert _enter(feature, model) is not None  # built, or entered
+        got = _enter(feature, model)
+        assert got is not None  # built, or entered
+        # the later two refuse all but ffn_pack: their position-wise regions run packed
+        assert model not in LATER or (feature, got) == ("ffn_pack", 96)
         return
     if feature == "chunked_prefill":  # declined, not raised: the caller's one-shot fallback serves it
         assert _enter(feature, model) is None
@@ -146,7 +148,7 @@ def test_what_the_table_refuses_is_refused_by_type_and_nothing_else_is(model, fe
             llama.forward_paged(None, cfg, S((2, 9), jnp.int32), S((2, 9), jnp.int32), None, None,
                                 S((2, 3), jnp.int32), ffn_pack=8)
         return
-    with pytest.raises(fam.error, match=f"^{feature}: "):
+    with pytest.raises(fam.error, match=f"^{feature}: .*{LATER.get(model, '')}"):
         _enter(feature, model)
     if feature == "dense_cache":  # and the dense forward itself: not implemented there, by the same table
         with pytest.raises(NotImplementedError, match="^dense_cache: .*forward_paged"):
@@ -256,19 +258,6 @@ def test_the_gdn_record_is_read_like_any_other():
     assert eng.kv_bytes_per_block == BS * fam.token_bytes
 
 
-@pytest.mark.parametrize("feature", FEATURES)
-def test_what_the_gdn_table_refuses_is_refused_by_type_and_nothing_else_is(feature):
-    fam = family(_cfg("gdn"))
-    if feature not in fam.refuses:  # ffn_pack: its position-wise regions always run packed
-        assert feature == "ffn_pack" and _enter(feature, "gdn") == 96
-        return
-    if feature == "chunked_prefill":
-        assert _enter(feature, "gdn") is None
-        return
-    with pytest.raises(sambay.StateNotCarried, match=f"^{feature}: .*(delta-rule|OlmoHybridConfig)"):
-        _enter(feature, "gdn")
-
-
 def test_a_gdn_chunk_counts_what_the_record_names_in_its_order(catalog):
     from tpu_voice_agent.utils import get_metrics
 
@@ -313,19 +302,6 @@ def test_the_looped_record_is_the_plain_familys_with_a_plane_for_every_pass():
     # with the fields at their defaults the record is the one it was
     plain = family(_cfg("dense"))
     assert [c.keyword for c in plain.counts] == ["attn_stats"] and not plain.refuses
-
-
-@pytest.mark.parametrize("feature", FEATURES)
-def test_what_the_looped_table_refuses_is_refused_by_type_and_nothing_else_is(feature):
-    fam = family(_cfg("looped"))
-    if feature not in fam.refuses:  # ffn_pack: the packed regions run inside the passes
-        assert feature == "ffn_pack" and _enter(feature, "looped") == 96
-        return
-    if feature == "chunked_prefill":
-        assert _enter(feature, "looped") is None
-        return
-    with pytest.raises(NotImplementedError, match=f"^{feature}: .*pass"):
-        _enter(feature, "looped")
 
 
 def test_a_looped_chunk_counts_what_the_record_names_in_its_order(catalog):

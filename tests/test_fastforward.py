@@ -247,16 +247,46 @@ def test_batched_ff_paged_matches_dense(request):
         assert d.token_ids == p.token_ids, (d.text[:80], p.text[:80])
 
 
+def _same_stream_or_a_near_tie(eng, prompt, x_ids, y_ids):
+    """Two kernels' streams for one prompt are the same tokens, or part at a
+    decision the reference cannot separate: both picks within ONE bfloat16
+    step of the largest legal logit of the float32-accumulating XLA forward
+    over what the streams share. Past such a decision the two are different
+    requests and nothing more is compared."""
+    import jax
+
+    from tpu_voice_agent.grammar.fsm import fsm_row
+    from tpu_voice_agent.models.llama import forward, init_kv_cache
+
+    k = next((i for i, (a, b) in enumerate(zip(x_ids, y_ids)) if a != b), None)
+    if k is None:
+        assert x_ids == y_ids  # one is no prefix of the other
+        return
+    ids = eng.tokenizer.encode(prompt, bos=True) + x_ids[:k]
+    lg, _ = forward(eng.params, eng.cfg, jnp.asarray(ids, jnp.int32)[None], jnp.arange(len(ids))[None],
+                    init_kv_cache(eng.cfg, 1, eng.max_len), None, attn_impl="xla")
+    lg = np.asarray(jax.device_get(lg[0, -1]), np.float32)
+    legal = np.asarray(fsm_row(eng.tables, jnp.asarray([eng.fsm.walk(x_ids[:k])])))[0] >= 0
+    assert legal[x_ids[k]] and legal[y_ids[k]]
+    top = lg[legal].max()
+    step = np.abs(lg[legal]).max() * 2.0 ** -7  # bfloat16 keeps 8 bits of a value
+    assert top - lg[x_ids[k]] <= step and top - lg[y_ids[k]] <= step, (
+        k, x_ids[k], y_ids[k], top, lg[x_ids[k]], lg[y_ids[k]], step)
+
+
 def test_batched_ff_paged_pallas_matches_dense_pallas():
     """Layout parity inside the pallas kernel family: the paged frontier-
-    read block kernel must be token-identical to the DENSE block kernel at
-    batch width (same weights, same streaming-softmax algorithm — only the
-    KV layout differs, and layout must never change the stream).
+    read block kernel against the DENSE block kernel at batch width (same
+    weights; each layout prefills and walks the keys through its own path).
+    Layout must never change the stream but at a near-tie: with random tiny
+    weights two legal tokens can lie closer than a bfloat16 rounding (the
+    third decision of a free string here: 0.002-0.004 apart at ~2.0, and the
+    XLA forward picks either by the dtype it is given), and there the two
+    kernels may part (``_same_stream_or_a_near_tie``). Both stay valid.
 
     Pallas-vs-XLA token identity is deliberately NOT asserted on this pair:
     flash-style streaming softmax and the one-shot XLA softmax differ in
-    reduction order, and with random tiny weights a near-tie argmax can
-    legitimately flip (the kernel itself is pinned to the jnp reference by
+    reduction order (the kernel itself is pinned to the jnp reference by
     allclose in test_paged/test_ops)."""
     import jax
     import jax.numpy as jnp
@@ -280,10 +310,13 @@ def test_batched_ff_paged_pallas_matches_dense_pallas():
     )]
     rd = ContinuousBatcher(dense, chunk_steps=8, max_new_tokens=160).generate_many(prompts)
     rp = ContinuousBatcher(paged, chunk_steps=8, max_new_tokens=160).generate_many(prompts)
-    for x, y in zip(rd, rp):
+    same = 0
+    for prompt, x, y in zip(prompts, rd, rp):
         assert x.error is None and y.error is None
-        assert paged.fsm.walk(y.token_ids) >= 0
-        assert x.token_ids == y.token_ids, (x.text[:80], y.text[:80])
+        assert paged.fsm.walk(y.token_ids) >= 0 and dense.fsm.walk(x.token_ids) >= 0
+        _same_stream_or_a_near_tie(dense, prompt, x.token_ids, y.token_ids)
+        same += x.token_ids == y.token_ids
+    assert same >= 1  # near-ties are the exception: a kernel that is wrong parts every stream
 
 
 def test_batched_ff_pp_matches_dense():

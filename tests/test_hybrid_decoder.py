@@ -368,14 +368,11 @@ def test_the_batcher_publishes_the_state_and_window_counters(engine):
     assert d["ssm.state_restores"] == 3
 
 
-@pytest.mark.parametrize("what", ["radix", "kv_quant", "spec", "mesh", "handoff"])
+@pytest.mark.parametrize("what", ["radix", "kv_quant", "mesh", "handoff"])
 def test_what_moves_blocks_alone_refuses_this_configuration(what, engine):
-    """Radix reuse, a quantised K/V tier, speculative decoding, a mesh and
-    the warm handoff each move, re-store, roll back or shard K/V blocks
-    alone: with a model whose requests hold a recurrent state they refuse
+    """Radix reuse, a quantised K/V tier, a mesh and the warm handoff each
+    move, re-store or shard K/V blocks alone: with a model whose requests hold a recurrent state they refuse
     with a typed error where they are built or called, and never run wrong."""
-    from tpu_voice_agent.serve.spec import SpecConfig
-
     refused = pytest.raises(sambay.StateNotCarried)
     if what == "handoff":
         with refused:
@@ -384,7 +381,6 @@ def test_what_moves_blocks_alone_refuses_this_configuration(what, engine):
             engine.adopt_chain_kv(np.zeros((4, 1, 128, 1, 32)), np.zeros((4, 1, 128, 1, 32)))
         return
     kw = {"radix": {"radix_enable": True}, "kv_quant": {"kv_quant": "int8"},
-          "spec": {"spec": SpecConfig(k=4)},
           "mesh": {"mesh": jax.sharding.Mesh(np.array(jax.devices()[:2]).reshape(1, 2), ("dp", "tp"))}}[what]
     with refused:
         _engine(**kw)

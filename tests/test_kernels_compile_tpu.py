@@ -170,10 +170,6 @@ def test_llama_dense_kernels_compile(tpu_devices, geom):
     _compile(tpu_devices, ops.decode_block_attention_layer,
              ((B, FF_T, nq, hd), BF16), *stacked, ((B, FF_T), I32), ((), I32),
              interpret=False)
-    # the (B, 1+K) speculative verify block rides the same kernel at K=4
-    _compile(tpu_devices, ops.decode_block_attention_layer,
-             ((B, 5, nq, hd), BF16), *stacked, ((B, 5), I32), ((), I32),
-             interpret=False)
 
 
 @pytest.mark.slow
@@ -207,12 +203,6 @@ def test_quantized_kv_kernels_compile(tpu_devices, geom, bits):
              ((B, S, nkv, hdp), I8), ((B, S, nkv, hdp), I8),
              ((B, S, nkv), BF16), ((B, S, nkv), BF16), lens,
              bits=bits, interpret=False)
-
-
-@pytest.mark.slow
-def test_masked_argmax_block_compiles(tpu_devices):
-    _compile(tpu_devices, ops.masked_argmax_block, ((4, 5, VOCAB), F32),
-             ((4, 5), I32), ((FSM_STATES, VOCAB), jnp.bool_), interpret=False)
 
 
 @pytest.mark.slow
@@ -872,6 +862,3 @@ def test_sharded_kernels_compile_on_2x2(tpu_devices):
        [((B, VOCAB), F32), ((B,), I32), ((FSM_STATES, VOCAB), jnp.bool_),
         ((FSM_STATES, FSM_CLASSES), I32), ((VOCAB,), I32)],
        [P("dp", None), P("dp"), rep, rep, rep], interpret=False)
-    go(ops.sharded_masked_argmax_block,
-       [((B, 5, VOCAB), F32), ((B, 5), I32), ((FSM_STATES, VOCAB), jnp.bool_)],
-       [P("dp", None, None), P("dp", None), rep], interpret=False)

@@ -5,10 +5,9 @@ The storage contract (ops/kvquant.py): the paged pool stores per-(position,
 kv_head) scaled int8 (or packed int4) values, quantized ONCE at write time
 (deterministic rowwise math shared by the in-forward scatter and the host
 prefix/tail scatter), with the bf16 scale planes pool-indexed by block id —
-so radix sharing, spec rollback, and the warm-restart reserve path all
-carry scales with the block for free. ``KV_QUANT`` unset keeps the bf16
-pool byte-identical, differentially tested like ``RADIX_ENABLE`` /
-``SPEC_ENABLE`` before it.
+so radix sharing and the warm-restart reserve path carry scales with the
+block for free. ``KV_QUANT`` unset keeps the bf16 pool byte-identical,
+differentially tested like ``RADIX_ENABLE`` before it.
 
 The accuracy contract is the golden differential (evals/golden.py
 ``kv_quant_differential``): int8 token-identical on the golden set with the
@@ -16,10 +15,8 @@ distilled checkpoint, int4 held to a pinned intent-type-agreement floor,
 both grammar-valid always.
 
 The fused decode tail (ops/grammar_mask.py): grammar mask + argmax + FSM
-advance in ONE Pallas call (``masked_argmax_advance``), and the spec
-verify block's per-position masked argmax in one call
-(``masked_argmax_block``) — parity-tested against the XLA reference path
-they replace.
+advance in ONE Pallas call (``masked_argmax_advance``) — parity-tested
+against the XLA reference path it replaces.
 """
 
 import jax
@@ -28,7 +25,7 @@ import numpy as np
 import pytest
 
 from tpu_voice_agent.grammar.fsm import fsm_advance
-from tpu_voice_agent.serve import DecodeEngine, PagedDecodeEngine, SpecConfig
+from tpu_voice_agent.serve import DecodeEngine, PagedDecodeEngine
 from tpu_voice_agent.serve.scheduler import ContinuousBatcher
 from tpu_voice_agent.services.brain import (
     SessionTranscripts,
@@ -47,10 +44,10 @@ PROMPT_TEXTS = ["search for usb hubs", "scroll down"]
 MAXTOK = 48
 
 
-def _paged(kv_quant, radix=False, spec=None, **kw):
+def _paged(kv_quant, radix=False, **kw):
     eng = PagedDecodeEngine(
         preset="test-tiny", max_len=2048, batch_slots=2,
-        prefill_buckets=BUCKETS, radix_enable=radix, spec=spec,
+        prefill_buckets=BUCKETS, radix_enable=radix,
         kv_quant=kv_quant, **kw)
     install_prompt_prefix(eng)
     return eng
@@ -281,24 +278,6 @@ def test_masked_argmax_advance_fuses_mask_argmax_and_fsm(tiny_tables):
     assert (np.asarray(nxt)[live] == np.asarray(chain_nxt)[live]).all()
 
 
-def test_masked_argmax_block_per_position_states(tiny_tables):
-    """The spec verify tail: every (row, position) masked at its OWN state
-    in one call == the sequential per-position reference loop."""
-    from tpu_voice_agent.ops import masked_argmax_block, masked_argmax_reference
-
-    tables, V = tiny_tables
-    S = tables.dense_mask.shape[0]
-    B, T = 3, 5
-    logits = jax.random.normal(jax.random.PRNGKey(13), (B, T, V), jnp.float32)
-    states = jax.random.randint(jax.random.PRNGKey(14), (B, T), 0, S)
-    states = states.at[1, 3].set(-1)  # dead positions clamp to state 0
-    out = masked_argmax_block(logits, states, tables.dense_mask)
-    for i in range(T):
-        ref = masked_argmax_reference(
-            logits[:, i, :], jnp.maximum(states[:, i], 0), tables.dense_mask)
-        assert (np.asarray(out[:, i]) == np.asarray(ref)).all()
-
-
 # ------------------------------------------------------------ engine gating
 
 
@@ -375,18 +354,6 @@ def test_int8_radix_warm_cold_identity(eng_int8):
     warm2 = _play_session(warm_eng)
     for c, w in zip(cold, warm2):
         assert c.token_ids == w.token_ids
-
-
-def test_int8_spec_paged_identity(eng_int8, prompts, int8_baseline):
-    """Spec verify/rollback is block-granular over the quantized pool
-    unchanged: int8+spec == int8 plain, with drafts actually landing."""
-    eng = _paged("int8", spec=SpecConfig(k=4, drafter="fsm,prompt"))
-    res = _run(eng, prompts)
-    for ref, r in zip(int8_baseline, res):
-        assert r.error is None
-        assert r.token_ids == ref.token_ids
-        assert r.forwards > 0
-    assert eng.spec.stats()["accepted"] > 0
 
 
 def test_int8_chaos_nan_quarantines_alone(eng_int8, prompts, int8_baseline):

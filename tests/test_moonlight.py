@@ -405,12 +405,11 @@ def _pick_logits(logits, state, slots, ns):
     return logits[:, 0, :]
 
 
-@pytest.mark.parametrize("what", ["radix", "spec", "kv_quant", "handoff", "mesh", "dense_engine",
+@pytest.mark.parametrize("what", ["radix", "kv_quant", "handoff", "mesh", "dense_engine",
                                   "dense_forward", "pipeline"])
 def test_what_moves_k_and_v_planes_refuses_a_latent_cache_by_type(what):
     """ONE typed error, where each is built or called."""
     from tpu_voice_agent.serve import DecodeEngine
-    from tpu_voice_agent.serve.spec import SpecConfig
 
     if what == "dense_forward":  # ``paged_only``'s refusal, as every such model's
         params = init_params(CFG, jax.random.key(0), F32)
@@ -421,8 +420,6 @@ def test_what_moves_k_and_v_planes_refuses_a_latent_cache_by_type(what):
     with pytest.raises(mla.LatentCacheOnly):
         if what == "radix":
             _engine(radix_enable=True)
-        elif what == "spec":
-            _engine(spec=SpecConfig(k=2))
         elif what == "kv_quant":
             _engine(kv_quant="int8")
         elif what == "handoff":

@@ -691,14 +691,9 @@ def test_metrics_name_collision_lint_clean_on_repo():
     assert reg, "lint found no registrations — scanner broke"
     collisions = metrics_lint.find_collisions(reg)
     assert collisions == [], f"metric name(s) registered under two types: {collisions}"
-    # the speculative-decoding gauges/counters (serve.spec) are registered
-    # where the lint can see them — a rename there must show up here
-    for name, kind in (("spec.drafted_tokens", "counter"),
-                       ("spec.accepted_tokens", "counter"),
-                       ("spec.verify_steps", "counter"),
-                       ("spec.accept_rate", "gauge"),
-                       ("spec.tokens_per_step", "gauge"),
-                       ("scheduler.forwards", "counter"),
+    # the multi-token-step counters are registered where the lint can see
+    # them — a rename there must show up here
+    for name, kind in (("scheduler.forwards", "counter"),
                        ("scheduler.tokens_per_forward", "gauge")):
         assert list(reg[name]) == [kind], name
 

@@ -409,11 +409,9 @@ def test_the_batcher_publishes_the_state_counters(engine):
                                                     + (c.d_conv - 1) * c.conv_dim * 2)
 
 
-@pytest.mark.parametrize("what", ["radix", "kv_quant", "spec", "mesh", "handoff", "chunked_prefill",
-                                  "dense_cache"])
+@pytest.mark.parametrize("what", ["radix", "kv_quant", "mesh", "handoff", "chunked_prefill", "dense_cache"])
 def test_every_refusal_raises_its_reason(what, engine):
     from tpu_voice_agent.serve import DecodeEngine
-    from tpu_voice_agent.serve.spec import SpecConfig
 
     eng, _ = engine
     fam = eng.family
@@ -431,7 +429,6 @@ def test_every_refusal_raises_its_reason(what, engine):
             DecodeEngine(cfg=eng.cfg, tokenizer=eng.tokenizer, max_len=256, init_weights=False)
     else:
         kw = {"radix": {"radix_enable": True}, "kv_quant": {"kv_quant": "int8"},
-              "spec": {"spec": SpecConfig(k=4)},
               "mesh": {"mesh": jax.sharding.Mesh(np.array(jax.devices()[:2]).reshape(1, 2), ("dp", "tp"))}}[what]
         with pytest.raises(oh.StateNotCarried):
             _engine(**kw)

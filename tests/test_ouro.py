@@ -367,11 +367,9 @@ def test_the_batcher_publishes_the_loops_counters_and_the_planes(engine):
     assert abs(hbm_report(eng)["drift"]) < 0.01
 
 
-@pytest.mark.parametrize("what", ["radix", "kv_quant", "spec", "mesh", "handoff", "chunked_prefill",
-                                  "dense_cache"])
+@pytest.mark.parametrize("what", ["radix", "kv_quant", "mesh", "handoff", "chunked_prefill", "dense_cache"])
 def test_every_refusal_raises_its_reason(what, engine):
     from tpu_voice_agent.serve import DecodeEngine
-    from tpu_voice_agent.serve.spec import SpecConfig
 
     eng, _ = engine
     fam = eng.family
@@ -391,7 +389,6 @@ def test_every_refusal_raises_its_reason(what, engine):
             llama.forward(None, eng.cfg, TOKS[:, :4], jnp.arange(4)[None], None)
     else:
         kw = {"radix": {"radix_enable": True}, "kv_quant": {"kv_quant": "int8"},
-              "spec": {"spec": SpecConfig(k=4)},
               "mesh": {"mesh": jax.sharding.Mesh(np.array(jax.devices()[:2]).reshape(1, 2), ("dp", "tp"))}}[what]
         with pytest.raises(NotImplementedError):
             _engine(**kw)

@@ -20,7 +20,6 @@ import pytest
 from tpu_voice_agent.serve.engine import DecodeEngine
 from tpu_voice_agent.serve.paged import PagedDecodeEngine
 from tpu_voice_agent.serve.scheduler import ContinuousBatcher
-from tpu_voice_agent.serve.spec import SpecConfig
 from tpu_voice_agent.utils import chaos as chaos_mod
 from tpu_voice_agent.utils.quality import (
     GoldenCanary,
@@ -75,20 +74,6 @@ def test_token_identity_paged_radix():
     off = _run(_paged(False, radix_enable=True, fast_forward=4))
     assert [r.token_ids for r in on] == [r.token_ids for r in off]
     assert all(r.quality is not None for r in on)
-
-
-def test_token_identity_spec_verify():
-    """Spec-verify plane (dense + paged): the verify steps carry the same
-    readback contract; acceptance/rollback boundaries are untouched."""
-    on = _run(_dense(True, spec=SpecConfig(k=3)))
-    off = _run(_dense(False, spec=SpecConfig(k=3)))
-    assert [r.token_ids for r in on] == [r.token_ids for r in off]
-    pon = _run(_paged(True, radix_enable=True, spec=SpecConfig(k=3)))
-    poff = _run(_paged(False, radix_enable=True, spec=SpecConfig(k=3)))
-    assert [r.token_ids for r in pon] == [r.token_ids for r in poff]
-    # the spec plane still reports per-request quality AND speculation
-    assert all(r.quality is not None for r in pon)
-    assert any(r.spec_accepted > 0 for r in pon)
 
 
 def test_zero_postfence_recompiles_with_lanes_on():
@@ -191,41 +176,6 @@ def test_conf_lanes_select_what_the_sort_gave(case, V):
         assert np.all(margin == QUALITY_MARGIN_CAP)
     else:  # a tie at the maximum, a dead row, a poisoned row
         assert np.all(margin == 0.0) and (case == "tie_at_max" or np.all(ent == 0.0))
-
-
-def test_conf_lanes_of_the_spec_verify_tail_are_the_sorted_ones(monkeypatch):
-    """The verify tail calls ``_masked_conf`` directly, once a verified
-    position, on the masked logits its greedy pick built: the block's lanes
-    are those the parent's sort folds, bit for bit."""
-    import jax.numpy as jnp
-
-    from tpu_voice_agent.serve import spec as spec_mod
-
-    eng = _dense(True, spec=SpecConfig(k=3))
-    B, K, V = 2, 3, eng.cfg.vocab_size
-    rng = np.random.default_rng(33)
-    logits = jnp.asarray((rng.standard_normal((B, 1 + K, V)) * 3).astype(np.float32))
-    start = jnp.full((B,), eng.fsm.start, jnp.int32)
-    first = np.asarray(spec_mod.fsm_row(eng.tables, start))[0]
-    cur = jnp.full((B,), int(np.flatnonzero(first >= 0)[0]), jnp.int32)
-    state = jnp.asarray(np.full((B,), first[int(cur[0])]), jnp.int32)
-
-    def lanes():
-        none = jnp.full((B, K), -1, jnp.int32)
-        out = spec_mod._verify_commit(
-            logits, cur, jnp.full((B,), 8, jnp.int32), state, jnp.ones((B,), bool),
-            jnp.zeros((B,), jnp.int32), jnp.full((B,), 16, jnp.int32), none,
-            jnp.zeros((B,), jnp.int32), cur, jnp.tile(cur[:, None], (1, 1 + K)), eng.tables,
-            eng.byte_len_table, jnp.int32(4096), eng.logit_mask, K, eng.eos_id, eng.pad_id, 256,
-            quality_lanes=True)
-        return out[-1]
-
-    got = lanes()
-    monkeypatch.setattr(spec_mod, "_masked_conf", _sorted_conf)
-    want = lanes()
-    assert int(np.asarray(got[4]).sum()) == B  # one verified decision a row: the bonus
-    for g, w in zip(got, want):
-        assert np.array_equal(_bits(g), _bits(w)), (np.asarray(g), np.asarray(w))
 
 
 # ------------------------------------------------------------ quality SLO

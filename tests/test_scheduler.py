@@ -345,7 +345,7 @@ def test_slots_of_two_dp_groups_never_share_a_compacted_program():
 # ------------------------------------------------- the seam (ISSUE 30)
 #
 # ``decode_chunk`` hands its chunk back as ONE value, a ``ChunkResult``, under
-# one signature in all four implementations; nothing of a chunk is left on
+# one signature in all three implementations; nothing of a chunk is left on
 # the engine, and the batcher writes nothing there.
 
 @functools.lru_cache(maxsize=None)
@@ -353,18 +353,17 @@ def _implementer(kind: str):
     """A 2-slot engine of each ``decode_chunk`` implementation; the
     confidence lanes off on one of them, so both kinds of ``conf`` are met."""
     from tpu_voice_agent.parallel.pipeline import pp_tp_mesh
-    from tpu_voice_agent.serve import DecodeEngine, PagedDecodeEngine, PPDecodeEngine, SpecConfig
+    from tpu_voice_agent.serve import DecodeEngine, PagedDecodeEngine, PPDecodeEngine
 
     kw = dict(preset="test-tiny", max_len=512, batch_slots=2, prefill_buckets=(64, 128))
     if kind == "dense":
         return DecodeEngine(quality_lanes=False, **kw)
     if kind == "pp":
         return PPDecodeEngine(mesh=pp_tp_mesh(2, 1), **kw)
-    spec = SpecConfig(k=4, drafter="fsm") if kind == "spec-over-paged" else None
-    return PagedDecodeEngine(radix_enable=False, spec=spec, **kw)
+    return PagedDecodeEngine(radix_enable=False, **kw)
 
 
-@pytest.mark.parametrize("kind", ["dense", "paged", "pp", "spec-over-paged"])
+@pytest.mark.parametrize("kind", ["dense", "paged", "pp"])
 def test_every_decode_chunk_returns_one_record_under_one_signature(kind, monkeypatch):
     import inspect
 
@@ -376,11 +375,10 @@ def test_every_decode_chunk_returns_one_record_under_one_signature(kind, monkeyp
 
     eng = _implementer(kind)
     B = eng.batch_slots
-    chunker = eng.spec if kind == "spec-over-paged" else eng
-    assert inspect.signature(type(chunker).decode_chunk) == inspect.signature(DecodeEngine.decode_chunk)
+    assert inspect.signature(type(eng).decode_chunk) == inspect.signature(DecodeEngine.decode_chunk)
 
     # the batcher reads a chunk back with ONE device_get, counted from the
-    # moment ``decode_chunk`` returned (the spec decoder pays its own inside)
+    # moment ``decode_chunk`` returned
     gets, device_get, decode_chunk = [], jax.device_get, eng.decode_chunk
     monkeypatch.setattr(jax, "device_get", lambda x: gets.append(1) or device_get(x))
     monkeypatch.setattr(eng, "decode_chunk", lambda *a, **kw: (decode_chunk(*a, **kw), gets.clear())[0])
@@ -402,11 +400,6 @@ def test_every_decode_chunk_returns_one_record_under_one_signature(kind, monkeyp
         else:
             assert res.conf is None
         assert "moe" not in res.counts  # a dense model's chunk program has no such output
-        counts = (res.row_fwds, res.row_accepts, res.row_drafted)
-        if kind == "spec-over-paged":  # host counts a row: the live one rode every verify step
-            assert all(c.shape == (B,) and c.dtype == np.int64 for c in counts) and res.row_fwds[0] == res.fwds
-        else:
-            assert counts == (None, None, None)
 
     check(res, eng.compact_rows if kind == "paged" else B)  # one live row of two: the compacted width
     # the same call by hand, with both keywords: the chaos mask is THIS chunk's
@@ -518,7 +511,7 @@ GROUP_TEXTS = ["search for laptops under 1000",
 @functools.lru_cache(maxsize=None)
 def _grouping(**kw):
     """A paged engine that groups admissions: 32 slots (``admit_rows`` 4)
-    behind the 879-token prompt prefix; with ``kw`` (radix, spec) one that
+    behind the 879-token prompt prefix; with ``kw`` (radix) one that
     does not. Shared: every test leaves it with no slot held."""
     from tpu_voice_agent.serve.paged import PagedDecodeEngine
     from tpu_voice_agent.services.brain import install_prompt_prefix
@@ -627,19 +620,16 @@ def test_a_member_that_fails_in_its_host_half_fails_alone(fault):
     assert eng.allocator.blocks_in_use == len(eng._prefix_blocks[0])
 
 
-@pytest.mark.parametrize("case", ["lone-waiter", "no-prefix-match", "radix", "spec", "chunked"])
+@pytest.mark.parametrize("case", ["lone-waiter", "no-prefix-match", "radix", "chunked"])
 def test_what_the_grouped_path_does_not_take_goes_through_prefill_slot(case, monkeypatch):
     """One request waiting; prompts that do not start with the cached prefix;
-    an engine with radix reuse or spec decode on (``admit_rows`` 0); and
+    an engine with radix reuse on (``admit_rows`` 0); and
     admissions that PREFILL_CHUNK_TOKENS chunks: none reaches ``admit_group``,
     all are admitted, a slot at a time."""
-    from tpu_voice_agent.serve.spec import SpecConfig
-
     if case == "chunked":
         monkeypatch.setenv("PREFILL_CHUNK_TOKENS", "512")
-    eng = _grouping(**{"radix": {"radix_enable": True},
-                       "spec": {"spec": SpecConfig(k=4, drafter="fsm")}}.get(case, {}))
-    assert eng.admit_rows == (0 if case in ("radix", "spec") else 4)
+    eng = _grouping(**({"radix_enable": True} if case == "radix" else {}))
+    assert eng.admit_rows == (0 if case == "radix" else 4)
     grouped, per_slot, chunked = [], [], []
     for name, seen in (("admit_group", grouped), ("prefill_slot", per_slot),
                        ("begin_chunked_prefill", chunked)):

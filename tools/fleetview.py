@@ -247,8 +247,7 @@ def render_costs(costs_fan: dict, series: dict[str, list[dict]],
                 f"{eng.get('chunks', 0)}")
             lines.append(
                 f"  flops {total:.3g} — decode {dec:.0%}, prefill cache "
-                f"hit {cached:.0%}, wasted drafts "
-                f"{t.get('wasted_draft_flops', 0):.3g}; kv "
+                f"hit {cached:.0%}; kv "
                 f"{t.get('kv_block_us', 0) / 1e6:.3g} block-s")
             for sess in body.get("top_sessions") or []:
                 fl = (sess.get("prefill_flops", 0)
@@ -517,7 +516,7 @@ def self_test() -> int:
         "service": "brain", "enabled": True,
         "totals": {"prefill_flops": 8e9, "prefill_cached_flops": 2e9,
                    "decode_flops": 30e9, "decode_bytes": 5e9,
-                   "wasted_draft_flops": 1e9, "kv_block_us": 4_000_000},
+                   "kv_block_us": 4_000_000},
         "engine": {"weights_stream_bytes": 9e9, "fwds": 900, "chunks": 60},
         "mfu": 0.31, "mbu": 0.62, "mfu_prefill": 0.4,
         "top_sessions": [{"session": "s-big", "prefill_flops": 6e9,

@@ -90,19 +90,8 @@ PINNED: dict[str, str] = {
     "scheduler.cancelled": "counter",
     "scheduler.shed_expired": "counter",
     "engine.restarts": "counter",
-    # speculative decoding over the paged/radix plane (ISSUE 8, serve/
-    # spec.py + serve/scheduler.py, docs/PERF.md "Speculative decoding"):
-    # after PR 8 these names carry PAGED-plane traffic too — accept_rate /
-    # tokens_per_step are the drafter-health dials bench_spec gates on,
     # tokens_per_forward is the scheduler's multi-token-step denominator
-    # (forwards counts dispatches, never accepted tokens), trace_records
-    # counts SPEC_TRACE_SINK lines feeding train.distill draft retraining
-    "spec.accept_rate": "gauge",
-    "spec.tokens_per_step": "gauge",
-    "spec.drafted_tokens": "counter",
-    "spec.accepted_tokens": "counter",
-    "spec.verify_steps": "counter",
-    "spec.trace_records": "counter",
+    # (forwards counts dispatches, never emitted tokens)
     "scheduler.tokens_per_forward": "gauge",
     "scheduler.forwards": "counter",
     "scheduler.forward_rows": "counter",  # ISSUE 29: forwards x the chunk's width

@@ -4,8 +4,8 @@
 The scheduler records every chunk's wall-time decomposition into a bounded
 ring (utils/steplog.py) served at ``GET /debug/steplog`` on the brain and
 folded into flight-recorder freezes. This tool renders that ring as a text
-timeline: one gantt row per step, the six tiling stages (admit / prefill /
-draft / decode / readback / release) as proportional bar segments, batch
+timeline: one gantt row per step, the five tiling stages (admit / prefill /
+decode / readback / release) as proportional bar segments, batch
 occupancy + token counts — and, where the record holds them, the head of
 the step, the gap before it, time off the CPU and collections — in the
 margin, a stall snapshot's batcher frames, and any compile-sentinel events
@@ -41,7 +41,6 @@ DEFAULT_BRAIN = "http://127.0.0.1:8090"
 STAGE_GLYPHS = (
     ("admit", "a"),
     ("prefill", "P"),
-    ("draft", "d"),
     ("decode", "█"),
     ("readback", "r"),
     ("release", "·"),
@@ -93,8 +92,6 @@ def render_step(rec: dict, width: int = 48, max_wall_ms: float | None = None) ->
         meta.append(f"tok {rec['tokens']}")
     if rec.get("forwards"):
         meta.append(f"fwd {rec['forwards']}")
-    if rec.get("accepted"):
-        meta.append(f"acc {rec['accepted']}")
     # what held the thread (ISSUE 36), where the record says: the head of the
     # step, the gap before it, time off the CPU beside others' CPU, collections
     if "head_ms" in rec:
@@ -156,12 +153,12 @@ def _synthetic_ring() -> dict:
          "events": [{"site": "engine.chunk_decode_loop", "ms": 310.0,
                      "shape": "int32[4]", "post_fence": True}]},
         {"seq": 1, "wall_ms": 101.0, "occupancy": 3, "tokens": 24,
-         "forwards": 8, "accepted": 16,
-         "stages": {"admit": 0.5, "draft": 12.0, "decode": 80.0,
+         "forwards": 8,
+         "stages": {"admit": 0.5, "prefill": 12.0, "decode": 80.0,
                     "readback": 6.0, "release": 2.5},
-         "cpu_ms": {"admit": 0.4, "draft": 11.0, "decode": 3.0,
+         "cpu_ms": {"admit": 0.4, "prefill": 11.0, "decode": 3.0,
                     "readback": 0.1, "release": 2.0},
-         "others_cpu_ms": {"admit": 0.1, "draft": 0.5, "decode": 2.0,
+         "others_cpu_ms": {"admit": 0.1, "prefill": 0.5, "decode": 2.0,
                            "readback": 0.3, "release": 0.4},
          "head_ms": 0.4, "gap_ms": 3.2, "lock_wait_ms": 0.0,
          "gc_n": 2, "gc_ms": 1.5, "gc_max_ms": 1.2, "watchdog_late_ms": 0.1,
@@ -206,7 +203,7 @@ def self_test() -> int:
     w2 = rows[2].split("|")[1]
     assert len(w0.rstrip()) > len(w2.rstrip()), (w0, w2)
     # every recorded stage appears as its glyph somewhere in the bars
-    assert "P" in w0 and "█" in w0 and "d" in rows[1].split("|")[1]
+    assert "P" in w0 and "█" in w0 and "r" in rows[1].split("|")[1]
     # stage tiling sanity on the synthetic data itself (the ledger's
     # ≥95%-accounted contract, held by the real scheduler tests too)
     for s in body["steps"]:

@@ -147,8 +147,6 @@ class TokenFSM:
         # kept for forced_tables(): byte-expanded transitions + piece trie
         self._trans_b = trans_b
         self._trie = trie
-        # lookahead(): canonical forced chain per state, computed on demand
-        self._lookahead_cache: dict[int, list[int]] = {}
         self._forced_arr: tuple[np.ndarray, np.ndarray] | None = None
 
     # ------------------------------------------------------------ dense views
@@ -205,9 +203,8 @@ class TokenFSM:
 
     def _tile_run(self, run: list[int], width: int) -> list[int]:
         """Greedy-longest canonical tokenization of a byte run over the
-        vocab trie (first id of a piece = canonical). THE one copy of the
-        canonical-tiling convention — forced_tables and lookahead must
-        stay bit-identical or draft acceptance quietly degrades."""
+        vocab trie (first id of a piece = canonical): ``forced_tables``'
+        canonical-tiling convention."""
         toks, i = [], 0
         while i < len(run) and len(toks) < width:
             node, best, j = self._trie, None, i
@@ -240,29 +237,6 @@ class TokenFSM:
             ff_tokens[s, : len(toks)] = toks
             ff_len[s] = len(toks)
         return ff_tokens, ff_len
-
-    def lookahead(self, state: int, width: int) -> list[int]:
-        """Draft tokens along the forced byte path from ``state`` (the
-        speculative-decoding host API; serve.spec FSMDrafter).
-
-        Unlike ``forced_tables`` — whose chains are *forced* onto the
-        stream without sampling — lookahead tokens are only PROPOSALS: the
-        verify pass checks them against the target model's greedy choice,
-        so the canonical (greedy-longest) tokenization here is a guess the
-        model is free to reject in favor of a different tiling of the same
-        bytes. Returns up to ``width`` token ids; [] when ``state`` is not
-        byte-forced (a free choice point) or is dead/accepting. Chains are
-        cached per state (full length) and sliced per call."""
-        if state < 0 or state >= self.num_states or width <= 0:
-            return []
-        chain = self._lookahead_cache.get(state)
-        if chain is None:
-            # tile the WHOLE forced run (bounded by the 4096-byte run cap),
-            # so the cache serves any draft width without silent truncation
-            run = self._forced_run(state)
-            chain = self._tile_run(run, len(run))
-            self._lookahead_cache[state] = chain
-        return chain[:width]
 
     # ------------------------------------------------------------ device tables
 

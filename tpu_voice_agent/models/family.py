@@ -120,7 +120,6 @@ _HYBRID_REFUSES = {
     "kv_quant": f"KV_QUANT re-stores {_STATE}",
     "radix": f"radix reuse hands a slot cached {_STATE}",
     "mesh": f"a mesh shards weights of a LlamaConfig's layout and {_STATE}",
-    "spec": f"speculative decoding rolls back (overwrite-before-attend) {_STATE}",
     "handoff": f"a handoff ships and adopts {_STATE}",
     "chunked_prefill": "the cursor of a chunked admission carries no count of real positions "
                        "for the recurrent state: the one-shot prefill_slot serves it",
@@ -135,8 +134,6 @@ _SSD_REFUSES = {
     "radix": f"radix reuse hands a slot cached {_SSD}",
     "mesh": "a mesh shards a LlamaConfig's weights and would exchange latent rows between the chips "
             f"that share an expert layer, which nothing here does; it moves {_SSD}",
-    "spec": "a rejected draft cannot be un-advanced: a verify step rolls back (overwrite-before-"
-            f"attend) {_SSD}",
     "handoff": f"a handoff ships and adopts {_SSD}",
     "chunked_prefill": "the cursor of a chunked admission carries no count of real positions for "
                        "the Mamba-2 state: the one-shot prefill_slot serves it",
@@ -149,8 +146,6 @@ _GDN_REFUSES = {
     "kv_quant": f"KV_QUANT re-stores {_GDN}",
     "radix": f"radix reuse hands a slot cached {_GDN}",
     "mesh": f"a mesh shards a LlamaConfig's weights and {_GDN}",
-    "spec": "a rejected draft cannot be un-advanced (a delta-rule write READS the state it "
-            f"overwrites): a verify step rolls back (overwrite-before-attend) {_GDN}",
     "handoff": f"a handoff ships and adopts {_GDN}",
     "chunked_prefill": "the cursor of a chunked admission carries no count of real positions for "
                        "the delta-rule state: the one-shot prefill_slot serves it",
@@ -163,7 +158,6 @@ _LATENT_REFUSES = {
     "kv_quant": f"KV_QUANT re-stores {_PLANES}",
     "radix": f"radix reuse hands a slot cached {_PLANES}",
     "mesh": f"a mesh shards {_PLANES}",
-    "spec": f"a verify step rolls back {_PLANES}",
     "handoff": f"a handoff ships and adopts {_PLANES}",
     "dense_cache": f"a dense cache holds {_PLANES} (forward_paged alone runs it, "
                    "PagedDecodeEngine on one device serves it)",
@@ -185,8 +179,6 @@ _LOOPED_REFUSES = {
             "is not placed by parallel.mesh",
     "kv_quant": f"KV_QUANT's scale planes and quantising scatters are untested over {_LOOPED}",
     "radix": f"radix reuse adopts and evicts chains of blocks whose cost it counts a layer: untested over {_LOOPED}",
-    "spec": "a verify step rolls back by overwriting K/V before it is attended, in every plane of every "
-            f"pass, and a draft model shares no pass: untested over {_LOOPED}",
     "handoff": f"a handoff ships and adopts blocks sized by n_layers: untested over {_LOOPED}",
     "chunked_prefill": "a chunked admission's cursor is untested over the loop of passes: the one-shot "
                        "prefill_slot serves it",

@@ -259,10 +259,6 @@ MAX_BLOCK_DECODE_T = 16
 
 PRESETS: dict[str, LlamaConfig] = {
     "test-tiny": LlamaConfig(dim=128, n_layers=2, n_heads=4, n_kv_heads=2, ffn_dim=256, max_seq_len=256),
-    # speculative-decoding draft model (serve.spec): a fraction of even the
-    # test-tiny step cost, so K draft forwards stay cheap next to one
-    # target verify forward
-    "draft-tiny": LlamaConfig(dim=64, n_layers=1, n_heads=2, n_kv_heads=1, ffn_dim=128, max_seq_len=256),
     "tinyllama-1.1b": LlamaConfig(dim=2048, n_layers=22, n_heads=32, n_kv_heads=4, ffn_dim=5632),
     "llama3-8b": LlamaConfig(
         dim=4096, n_layers=32, n_heads=32, n_kv_heads=8, ffn_dim=14336, rope_theta=500_000.0, max_seq_len=8192
@@ -1486,8 +1482,8 @@ def forward_paged(
                 else trash_idx.astype(jnp.int32))
         flat_idx = jnp.where(write_mask[:, None], flat_idx, park[:, None])
 
-    # the small mid-sequence block (a grammar fast-forward chain step, a
-    # speculative verify step) attends through the paged block kernel. Which
+    # the small mid-sequence block (a grammar fast-forward chain step)
+    # attends through the paged block kernel. Which
     # leading blocks the live rows hold in common is read HERE, once a
     # forward, from the tables, the positions and the write mask: tables do
     # not move between layers. Under a mesh each dp group pins its own prefix

@@ -50,11 +50,9 @@ from .grammar_mask import (
     masked_argmax,
     masked_argmax_advance,
     masked_argmax_advance_reference,
-    masked_argmax_block,
     masked_argmax_reference,
     sharded_masked_argmax,
     sharded_masked_argmax_advance,
-    sharded_masked_argmax_block,
 )
 from .grouped_matmul import grouped_matmul, grouped_matmul_reference
 from .kvquant import (
@@ -120,11 +118,9 @@ __all__ = [
     "masked_argmax",
     "masked_argmax_advance",
     "masked_argmax_advance_reference",
-    "masked_argmax_block",
     "masked_argmax_reference",
     "sharded_masked_argmax",
     "sharded_masked_argmax_advance",
-    "sharded_masked_argmax_block",
     "dequantize_kv",
     "kv_block_bytes",
     "kv_quant_bits",

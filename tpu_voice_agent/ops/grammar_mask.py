@@ -148,21 +148,13 @@ def masked_argmax_reference(
 
 # ------------------------------------------------------- fused decode tail
 #
-# ISSUE 12: the per-step sampling tail was mask -> argmax (this kernel) ->
-# a separate two-gather FSM advance; and the speculative verify step ran
-# K+1 SEQUENTIAL (B, V) mask+argmax rounds in XLA. The two entries below
-# finish the fusion:
-#
-# - ``masked_argmax_advance``: mask + argmax + FSM advance in ONE kernel.
-#   The col_id class tiles stream beside the logits tiles, the kernel
-#   tracks the argmax position's class, and the (1, 1, C) row of the
-#   compressed transition table — fetched by the row's own state, the
-#   same scalar-prefetch trick as the mask tiles — yields the next state
-#   at finish. Nothing V-sized ever leaves the kernel.
-# - ``masked_argmax_block``: every verify position of a (B, 1+K) spec
-#   block masked at its OWN state and argmaxed in ONE pallas_call (the
-#   grid folds positions into rows), replacing the K+1-round XLA loop in
-#   serve.spec._verify_commit.
+# ISSUE 12: the per-step sampling tail was mask -> argmax (the kernel
+# above) -> a separate two-gather FSM advance. ``masked_argmax_advance``
+# is mask + argmax + FSM advance in ONE kernel. The col_id class tiles
+# stream beside the logits tiles, the kernel tracks the argmax position's
+# class, and the (1, 1, C) row of the compressed transition table — fetched
+# by the row's own state, the same scalar-prefetch trick as the mask tiles —
+# yields the next state at finish. Nothing V-sized ever leaves the kernel.
 
 
 def _argmax_advance_kernel(
@@ -319,53 +311,3 @@ def masked_argmax_advance_reference(
     state = jnp.maximum(fsm_state, 0)
     tok = masked_argmax_reference(logits, state, mask_table)
     return tok, table[state, col_id[tok]]
-
-
-# analyze: ok[jit-sentinel] -- kernel wrapper traced inline by the watched engine/stt loops, never a serving dispatch entry point
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def masked_argmax_block(
-    logits: jax.Array,  # (B, T, V) float — one verify block per row
-    fsm_state: jax.Array,  # (B, T) int32 — each position's OWN state
-    mask_table: jax.Array,  # (n_states, V) bool
-    *,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """Per-position masked argmax for a whole speculative verify block in
-    ONE pallas_call: positions fold into grid rows, each streaming the mask
-    tiles of its own FSM state. Returns (B, T) int32. Dead (negative)
-    states are clamped to 0 — serve.spec._verify_commit proves their
-    positions sit strictly past the first draft mismatch, so the clamped
-    garbage can never affect acceptance or the bonus pick."""
-    B, T, V = logits.shape
-    out = masked_argmax(
-        logits.reshape(B * T, V),
-        jnp.maximum(fsm_state.reshape(B * T), 0),
-        mask_table,
-        interpret=interpret,
-    )
-    return out.reshape(B, T)
-
-
-def sharded_masked_argmax_block(
-    mesh,
-    logits: jax.Array,  # (B, T, V)
-    fsm_state: jax.Array,  # (B, T)
-    mask_table: jax.Array,  # (n_states, V) bool — replicated
-    **kw,
-) -> jax.Array:
-    """masked_argmax_block over a (dp, tp) mesh (batch over dp, table
-    replicated; ``mesh=None`` falls through)."""
-    if mesh is None:
-        return masked_argmax_block(logits, fsm_state, mask_table, **kw)
-    from jax.sharding import PartitionSpec as P
-
-    dp = mesh.shape.get("dp", 1)
-    dp_ax = "dp" if (dp > 1 and logits.shape[0] % dp == 0) else None
-    fn = jax.shard_map(
-        functools.partial(masked_argmax_block, **kw),
-        mesh=mesh,
-        in_specs=(P(dp_ax, None, None), P(dp_ax, None), P(None, None)),
-        out_specs=P(dp_ax, None),
-        check_vma=False,
-    )
-    return fn(logits, fsm_state, mask_table)

@@ -15,9 +15,9 @@ Layout contract (every reader/writer goes through these helpers):
   dot the two halves separately and never materialize the unpacked tensor.
 - scales: one bf16 scale per (position, kv_head), stored block-major in a
   ``(L, N, bs, nkv)`` plane indexed exactly like the pool. Scales are
-  pool-indexed by block id, so radix chains, the warm-restart ``reserve``
-  path, and spec rollback all share/adopt them with zero extra
-  bookkeeping — "scales travel with the block". Per-position granularity
+  pool-indexed by block id, so radix chains and the warm-restart
+  ``reserve`` path share/adopt them with zero extra bookkeeping —
+  "scales travel with the block". Per-position granularity
   (finer than one scale per whole block) is what makes quantize-on-write
   exact and deterministic under the incremental decode write pattern: a
   token's row is quantized once, at write time, independent of every

@@ -16,7 +16,7 @@ decode_attention_layer's stacked-cache plane).
 The pool is layer-stacked (L, N, bs, nkv, hd) with the layer index in the
 scalars, so the decode loop's scan body never slices a per-layer pool.
 
-The (B, T > 1) BLOCK kernel (grammar fast-forward, speculative verify) does
+The (B, T > 1) BLOCK kernel (grammar fast-forward) does
 not let each row walk its own table: the leading blocks that live rows hold
 in common — the shared prompt prefix — are read ONCE, against every row's
 queries, and only a row's own blocks are read per row; see "block decode"
@@ -1315,7 +1315,7 @@ def paged_block_attention_quant(
     interpret: bool | None = None,
 ) -> jax.Array:
     """``paged_block_attention`` over the quantized pool (grammar ff chain
-    and speculative verify steps): per-query frontiers, fused dequant."""
+    steps): per-query frontiers, fused dequant."""
     B, T, nq, hd = q.shape
     bs, nkv = k_pool.shape[2], k_pool.shape[3]
     max_blocks = block_tables.shape[1]

@@ -6,24 +6,12 @@ from .planner import LongSessionPlanner, PlannerSession
 from .radix import RadixCache
 from .pp_engine import PPDecodeEngine
 from .scheduler import ContinuousBatcher
-from .spec import (
-    ChainDrafter,
-    DraftModelDrafter,
-    FSMDrafter,
-    PromptLookupDrafter,
-    SpecConfig,
-    SpecDecoder,
-    spec_from_env,
-)
 
 __all__ = [
     "BlockAllocator",
-    "ChainDrafter",
     "ColocatedServing",
     "ContinuousBatcher",
     "DecodeEngine",
-    "DraftModelDrafter",
-    "FSMDrafter",
     "GenerationResult",
     "GroundingEngine",
     "GroundingResult",
@@ -32,8 +20,4 @@ __all__ = [
     "PPDecodeEngine",
     "PlannerSession",
     "RadixCache",
-    "PromptLookupDrafter",
-    "SpecConfig",
-    "SpecDecoder",
-    "spec_from_env",
 ]

@@ -519,20 +519,8 @@ class ColocatedServing:
                 self.stats.restarts += 1
                 from ..utils.tracing import get_flight_recorder
 
-                # name the decode plane in the dump: a speculative chunk is
-                # a HOST-driven loop of verify dispatches (per-step
-                # readbacks, host drafters), so its stall signature differs
-                # from a single wedged device dispatch — the first thing an
-                # operator triaging the flight dump needs to know. The warm
-                # restart below also bumps the SpecDecoder's generation
-                # fence (engine.warm_restart -> spec.reset()), so the
-                # wedged thread stops dispatching verify steps against the
-                # restarted engine if it ever wakes.
-                spec_plane = getattr(self.batcher.engine, "spec", None)
                 get_flight_recorder().trigger(
-                    "engine.stall",
-                    detail=f"step stalled >{stall_s}s"
-                    + (" (speculative chunk)" if spec_plane is not None else ""))
+                    "engine.stall", detail=f"step stalled >{stall_s}s")
                 # ordering: epoch fence up (batcher.reset) BEFORE the warm
                 # restart, both before the fresh loop spawns — the wedged
                 # thread is abandoned, and if it ever wakes its step

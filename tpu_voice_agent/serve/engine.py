@@ -54,21 +54,16 @@ class GenerationResult:
     prefill_ms: float
     decode_ms: float
     steps: int  # EMITTED tokens — under multi-token stepping (grammar
-    # fast-forward, speculative decoding) this counts accepted output
-    # tokens, never verify/forward dispatches (those are `forwards`)
+    # fast-forward) this counts accepted output tokens, never forward
+    # dispatches (those are `forwards`)
     finished: bool  # True only if EOS was reached (truncation => False)
     error: str | None = None  # per-request failure (e.g. prompt too long)
     forwards: int = 0  # decode forward dispatches (< steps under grammar
-    # fast-forward / speculative decoding, where one forward emits several
-    # accepted tokens)
+    # fast-forward, where one forward emits several accepted tokens)
     cached_tokens: int = 0  # prompt tokens served from cached KV at
     # admission (static prefix cache or radix chain hit) — prefill_ms
     # covers only the COMPUTED suffix, so the two together describe the
     # admission honestly (conflating them was the old prefill_ms bug)
-    spec_accepted: int = 0  # draft tokens accepted by verify passes this
-    # request rode (speculative decoding; 0 = no drafts landed or spec
-    # off) — steps = spec_accepted + bonus/plain tokens, so per-request
-    # accept effectiveness is (steps - spec_accepted) vs forwards
     prompt_tokens: int = 0  # prompt length in tokens — with cached_tokens
     # it yields the outstanding-prefill measurement the voice service's
     # endpoint gauge needs (ISSUE 15 satellite)
@@ -81,16 +76,14 @@ class GenerationResult:
     # was sampled (utils.quality.conf_summary builds it)
     cost: dict | None = None  # per-request resource ledger (ISSUE 17):
     # utils.costmodel.LEDGER_KEYS ints (prefill FLOPs split cached vs
-    # computed, decode FLOPs + KV bytes, wasted-draft FLOPs, KV
-    # block-microseconds held) — None when COST_ENABLE=0 or the request
-    # ran outside the continuous batcher. Errored/evicted rows still
+    # computed, decode FLOPs + KV bytes, KV block-microseconds held) —
+    # None when COST_ENABLE=0 or the request ran outside the continuous batcher. Errored/evicted rows still
     # carry the cost they spent before dying (the ledger conserves).
 
     @property
     def tokens_per_s(self) -> float:
-        # zero/negative-duration guard: a fully fast-forwarded or
-        # speculation-saturated generation can finish inside timer
-        # resolution — report 0 rather than raise/inf
+        # zero/negative-duration guard: a fully fast-forwarded generation
+        # can finish inside timer resolution — report 0 rather than raise/inf
         return self.steps / (self.decode_ms / 1e3) if self.decode_ms > 0 else 0.0
 
 
@@ -100,9 +93,7 @@ class ChunkResult:
     one chunk's record while it dispatches the next, and nothing of a chunk
     is left on the engine. A field that is None is an empty pytree leaf, so
     ONE ``jax.device_get`` over the fields a caller wants reads them all in
-    one transfer, with no placeholder for what this engine does not report.
-    The spec decoder's ``out``/``n``/``eos``/``fwds``/``poison``/``conf``
-    are host values already (its per-step readbacks paid for them)."""
+    one transfer, with no placeholder for what this engine does not report."""
 
     # the batcher's per-slot state after the chunk, and what it emitted
     out: Any  # (B, cap) emitted token ids, pad-filled
@@ -116,7 +107,7 @@ class ChunkResult:
     tokens_left: Any
     fwds: Any  # forward dispatches of the chunk: the denominator that keeps
     # tokens-per-forward truthful when one forward emits several tokens
-    # (grammar fast-forward, speculative decoding)
+    # (grammar fast-forward)
     poison: Any  # (B,) per-row fault code the quarantine evicts on:
     # 0 ok / 1 non-finite logits / 2 grammar dead state
     rows: int  # the width the chunk was dispatched at: ``batch_slots``,
@@ -130,10 +121,6 @@ class ChunkResult:
     # from an engine whose loop counts nothing
     ffn_rows: int = 0  # the rows an UNPACKED forward's MLPs compute (width x
     # positions a row); 0: this engine does not say
-    # the spec decoder's per-row host counts; None on the plain loops
-    row_fwds: Any = None  # verify steps the row took part in
-    row_accepts: Any = None  # draft tokens accepted
-    row_drafted: Any = None  # draft tokens proposed
 
 
 def _mask_sample_advance(logits, fsm_state, tables: DeviceFSM, key, temperature,
@@ -195,9 +182,8 @@ def _conf_stats(raw, state, tables: DeviceFSM, constrained: bool, logit_mask):
     quality observatory's intent lanes (ISSUE 15): top1−top2 margin of the
     masked logits, entropy of the masked softmax, and the forced flag
     (grammar leaves a single legal token). THE one copy shared by the
-    dense/paged chunk loops and the spec verify commit (jit-inlined at
-    every call site). Pure readback arithmetic over values the loops
-    already computed — nothing feeds back into sampling, so tokens are
+    dense and paged chunk loops (jit-inlined at every call site). Pure
+    readback arithmetic over values the loops already computed — nothing feeds back into sampling, so tokens are
     identical with the lanes on or off (tests/test_quality.py holds that
     differentially per plane)."""
     lg = raw.astype(jnp.float32)
@@ -215,9 +201,7 @@ def _conf_stats(raw, state, tables: DeviceFSM, constrained: bool, logit_mask):
 
 def _masked_conf(lg, nlegal):
     """The reduction half of ``_conf_stats`` over ALREADY-masked f32
-    logits — the spec verify tail calls this directly on the per-position
-    masked logits it builds anyway (re-deriving the mask per position
-    would double the verify tail's vocab work).
+    logits.
 
     The margin's top-2 is SELECTED by reductions, never sorted:
     ``lax.top_k`` lowers on the TPU to a sort of the whole row (7.9 ms a
@@ -247,7 +231,7 @@ def _masked_conf(lg, nlegal):
 def _conf_accumulate(conf, ok, margin, ent, forced_one, forced_extra=None):
     """Fold one decision into the per-row conf lanes ``(margin_sum,
     margin_min, entropy_sum, forced, decisions)``. ``forced_extra`` adds
-    grammar-forced chain tokens (ff / spec positions count elsewhere)."""
+    grammar-forced chain tokens (ff positions count elsewhere)."""
     msum, mmin, esum, forced, cnt = conf
     msum = msum + jnp.where(ok, margin, 0.0)
     mmin = jnp.where(ok, jnp.minimum(mmin, margin), mmin)
@@ -402,10 +386,9 @@ def chain_block(iw, cur, chain, k, active, pad_id, pos):
     """Block tokens/positions for a (B, 1+W) chain step: ``[cur,
     chain_0..k-1]`` with the tail duplicating the last valid (token,
     position) — duplicate (token, position) scatter writes are idempotent
-    on the cache, so padding never scribbles junk over live KV. THE one
-    copy of this construction, shared by the grammar fast-forward loop and
-    the speculative verify step (serve.spec): returns (step_tok, blk_tok,
-    blk_pos)."""
+    on the cache, so padding never scribbles junk over live KV. The
+    grammar fast-forward loops' one copy of this construction: returns
+    (step_tok, blk_tok, blk_pos)."""
     ci = jnp.clip(iw - 1, 0, jnp.maximum(k[:, None] - 1, 0))
     chain_tok = jnp.take_along_axis(chain, ci, axis=1)
     step_tok = jnp.where(active, cur, pad_id)
@@ -419,11 +402,9 @@ def chain_block(iw, cur, chain, k, active, pad_id, pos):
 def chain_byte_cap(k, chain, cur_tok, nbytes, byte_len_table, byte_budget):
     """Cap a chain length so its cumulative bytes still fit after
     ``cur_tok``'s: the plain path overshoots the byte budget by at most
-    one token (stop is checked after the add), so chain/draft tokens may
-    only be taken while they still fit. The ff loop and the speculative
-    verify step MUST share this contract exactly — truncation boundaries
-    are part of the token-identity guarantee (tests/test_spec.py
-    byte-budget parity). Returns (capped k, per-token cumulative bytes)."""
+    one token (stop is checked after the add), so chain tokens may only
+    be taken while they still fit. The dense and paged ff loops share this
+    contract. Returns (capped k, per-token cumulative bytes)."""
     chain_bytes = jnp.cumsum(
         jnp.where(chain >= 0, byte_len_table[jnp.maximum(chain, 0)], 0), axis=1)
     rem = (byte_budget - nbytes - byte_len_table[jnp.maximum(cur_tok, 0)])[:, None]
@@ -710,10 +691,6 @@ class DecodeEngine:
         # a (B, 1+W) forward whose attention runs the Pallas frontier-read
         # block kernel (ops.decode_block_attention) under kernels="pallas",
         # so the chain tokens ride the weight read nearly free at any B
-        spec=None,  # serve.spec.SpecConfig | None — speculative decoding
-        # (draft K + one-pass verify). None keeps the decode path
-        # byte-identical to pre-speculation; greedy constrained decode
-        # routes through SpecDecoder when set (spec supersedes ff there)
         quality_lanes: bool | None = None,  # ISSUE 15 confidence lanes in
         # the decode loops (margin/entropy/forced readbacks). None reads
         # QUALITY_ENABLE; tokens are identical on or off — the flag only
@@ -748,8 +725,6 @@ class DecodeEngine:
             fam.refuse("dense_cache")
         if mesh is not None:
             fam.refuse("mesh")
-        if spec is not None and getattr(spec, "k", 0):
-            fam.refuse("spec")
         if base.n_experts > 0 and base.moe_impl == "auto":
             # THE dispatch choice of a routed model, made once, here, from
             # where the engine runs: the grouped-matmul kernel on a single
@@ -878,22 +853,6 @@ class DecodeEngine:
         # first) and the head's bytes behind them: ``encode_prompt``'s memo,
         # replaced together with the two above
         self._head: tuple[str, list[int], bytes] | None = None
-        # speculative decoding (serve.spec): built LAST — the decoder reads
-        # engine tables/cache geometry, and a draft-model drafter allocates
-        # its own KV against batch_slots/max_len. Layout subclasses whose
-        # KV surface does not exist yet at this point (the paged engine's
-        # pool/allocator) defer via _spec_cfg and call _build_spec once
-        # their surface is up; the pp engine refuses spec at construction.
-        self.spec = None
-        self._spec_cfg = spec if (spec is not None and getattr(spec, "k", 0)) \
-            else None
-        if self._spec_cfg is not None and self._alloc_dense_cache:
-            self._build_spec()
-
-    def _build_spec(self) -> None:
-        from .spec import SpecDecoder
-
-        self.spec = SpecDecoder(self, self._spec_cfg)
 
     # ------------------------------------------------------------ helpers
 
@@ -1111,11 +1070,6 @@ class DecodeEngine:
                 # tokenizer/shape fault at the top of admission
                 raise ChaosError("chaos: injected prefill exception")
             self.release_slot(slot)  # a finished request may still own resources
-            if self.spec is not None:
-                # admission hook: the spec decoder keeps the host-side token
-                # context its drafters read (and the draft model prefills
-                # its own cache line for this slot)
-                self.spec.on_admit(slot, list(ids))
             n = len(ids)
             suffix = self._split_prefix(ids)
             if suffix is not None:
@@ -1180,14 +1134,10 @@ class DecodeEngine:
         configured the chunk takes (B, 1+W) grammar-chain steps — the
         round-3 single-request restriction is lifted by the frontier-read
         block-attention kernel (each row reads its own context, not the
-        cache capacity, even at batch width). With speculation configured
-        (serve.spec) greedy chunks route through the SpecDecoder —
-        draft-K-verify-once steps, token-identical to this loop by
-        construction; non-greedy chunks keep the plain path (temperature
-        speculation would need rejection sampling).
+        cache capacity, even at batch width).
 
-        THE CONTRACT of every layout's ``decode_chunk`` (dense, paged, pp,
-        and the SpecDecoder behind them): one signature, one ``ChunkResult``
+        THE CONTRACT of every layout's ``decode_chunk`` (dense, paged,
+        pp): one signature, one ``ChunkResult``
         back, nothing of the chunk left on the engine. ``live`` is the
         caller's host mirror of ``active`` (a superset of it), from which a
         layout with a compacted width chooses the chunk program's width;
@@ -1199,11 +1149,6 @@ class DecodeEngine:
         dispatch is only clamped back to each row's actual frontier there
         (the clamp cannot live in here: ``pos`` is a device array
         mid-async-dispatch, and a host read would stall the chain)."""
-        if self.spec is not None and greedy:
-            return self.spec.decode_chunk(
-                cur, pos, fsm, active, nbytes, tokens_left, key,
-                temperature, byte_budget, chunk_steps, greedy,
-                nan_inject=nan_inject)
         out, n, eos, self.cache, cur, pos, fsm, active, nbytes, left, fwds, \
             pois, conf = (
                 chunk_decode_loop(
@@ -1254,8 +1199,6 @@ class DecodeEngine:
         scheduler passes via ``generated_ids`` into its tree first).
         ``ok=False`` marks an errored/cancelled request: resources are
         still freed, but layout subclasses must never cache its chain."""
-        if self.spec is not None:
-            self.spec.on_release(slot, ok=ok)
 
     def warm_restart(self) -> None:
         """Rebuild device decode state after a wedged/corrupt step, REUSING
@@ -1273,11 +1216,6 @@ class DecodeEngine:
                     out_shardings=kv_sh)()
             else:
                 self.cache = init_kv_cache(self.cfg, self.batch_slots, self.max_len)
-        if self.spec is not None:
-            # drop per-slot host contexts + drafter state and bump the
-            # generation fence: a decode_chunk wedged mid-flight must stop
-            # dispatching verify steps against the restarted engine
-            self.spec.reset()
         # re-arm the recompilation sentinel's warmup fence: the restart
         # reuses compiled programs, so any NEW trace after it means the
         # rebuilt mutable state came back with an unexpected shape — the
@@ -1293,25 +1231,6 @@ class DecodeEngine:
             )
         ids, _ = self.encode_prompt(prompt)
         return self.prefill_slot(ids, 0), len(ids)
-
-    def _admit_first_token(self, prompt: str, temperature: float,
-                           greedy: bool = True, constrained: bool = True):
-        """Single-request admission: prefill slot 0 + sample the first
-        token. THE one copy of the prologue shared by generate() and the
-        speculative path (prefill bucketing / first-token masking must
-        never diverge between them). Returns (tok0, fsm0, prompt_len,
-        prefill_ms) — prefill_ms is dispatch-side (no block), matching
-        generate()'s sync discipline."""
-        t0 = time.perf_counter()
-        last_logits, n = self._prefill(prompt)
-        fsm_state = jnp.full((1,), self.fsm.start, dtype=jnp.int32)
-        self._rng, k0 = jax.random.split(self._rng)
-        tok0, fsm0 = _first_token(
-            last_logits, fsm_state, self.tables, k0,
-            jnp.float32(temperature), greedy=greedy, constrained=constrained,
-            kernels=self.kernels, rules=self.rules, logit_mask=self.logit_mask,
-        )
-        return tok0, fsm0, n, (time.perf_counter() - t0) * 1e3
 
     def generate(
         self,
@@ -1333,13 +1252,16 @@ class DecodeEngine:
         # generate pays exactly ONE combined device_get at the end and
         # never blocks mid-flight. prefill_ms is therefore dispatch-side
         # (enqueue) time; the total latency is what's real.
-        if (self.spec is not None and constrained and greedy
-                and not ignore_eos):
-            # speculative greedy path: host-driven draft/verify steps
-            # (token-identical to the loop below by construction)
-            return self._generate_spec(prompt, max_new_tokens, byte_budget)
-        tok0, fsm0, n, prefill_ms = self._admit_first_token(
-            prompt, temperature, greedy=greedy, constrained=constrained)
+        t0 = time.perf_counter()
+        last_logits, n = self._prefill(prompt)
+        fsm_state = jnp.full((1,), self.fsm.start, dtype=jnp.int32)
+        self._rng, k0 = jax.random.split(self._rng)
+        tok0, fsm0 = _first_token(
+            last_logits, fsm_state, self.tables, k0,
+            jnp.float32(temperature), greedy=greedy, constrained=constrained,
+            kernels=self.kernels, rules=self.rules, logit_mask=self.logit_mask,
+        )
+        prefill_ms = (time.perf_counter() - t0) * 1e3
 
         t1 = time.perf_counter()
         self._rng, key = jax.random.split(self._rng)
@@ -1395,86 +1317,6 @@ class DecodeEngine:
                    "poisoned: " + ("non-finite logits" if pois == 1
                                    else "grammar dead state")),
             forwards=int(fwds_h),
-            prompt_tokens=n,
-            quality=quality,
-        )
-
-    def _generate_spec(
-        self,
-        prompt: str,
-        max_new_tokens: int,
-        byte_budget: int,
-    ) -> GenerationResult:
-        """Single-request speculative greedy generation: the same admission
-        as generate() (_admit_first_token), then chunks of draft-K/
-        verify-once steps through the SpecDecoder (serve.spec). Each verify
-        step emits 1..K+1 accepted tokens; ``steps`` counts the tokens,
-        ``forwards`` the verify dispatches."""
-        tok0, fsm0, n, prefill_ms = self._admit_first_token(prompt, 0.0)
-
-        t1 = time.perf_counter()
-        cur = tok0
-        pos = jnp.full((1,), n, dtype=jnp.int32)
-        fsm = fsm0
-        active = tok0 != self.eos_id
-        nbytes = jnp.zeros((1,), jnp.int32)
-        left = jnp.full((1,), max_new_tokens, dtype=jnp.int32)
-        out_ids: list[int] = []
-        finished = False
-        forwards = 0
-        pois = 0
-        conf_acc = None
-        while True:
-            res = self.decode_chunk(cur, pos, fsm, active, nbytes, left, None,
-                                    0.0, byte_budget, chunk_steps=32,
-                                    greedy=True)
-            cur, pos, fsm, active, nbytes, left = (
-                res.cur, res.pos, res.fsm, res.active, res.nbytes,
-                res.tokens_left)
-            out_h, n_h, act_h, eos_h = jax.device_get(
-                (res.out, res.n, active, res.eos))
-            out_ids.extend(int(t) for t in np.asarray(out_h)[0, : int(n_h[0])])
-            finished = finished or bool(eos_h[0])
-            forwards += res.fwds
-            if res.conf is not None:
-                # per-chunk conf lanes (host arrays from the spec decoder):
-                # one fold rule, utils.quality.conf_fold
-                from ..utils.quality import conf_fold
-
-                conf_acc = conf_fold(conf_acc, res.conf)
-            # the verify step carries the same per-row fault codes as the
-            # chunk loops — surface them as the typed error generate() does
-            if int(res.poison[0]) > 0:
-                pois = int(res.poison[0])
-                break
-            if not bool(np.asarray(act_h)[0]):
-                break
-        decode_ms = (time.perf_counter() - t1) * 1e3
-
-        from ..utils import get_metrics
-
-        m = get_metrics()
-        m.inc("engine.requests")
-        m.inc("engine.tokens_generated", len(out_ids))
-        m.observe_ms("engine.prefill", prefill_ms)
-        m.observe_ms("engine.decode", decode_ms)
-
-        quality = None
-        if conf_acc is not None:
-            from ..utils.quality import conf_summary
-
-            quality = conf_summary([x[0] for x in conf_acc], len(out_ids))
-        return GenerationResult(
-            text=self.tokenizer.decode(out_ids),
-            token_ids=out_ids,
-            prefill_ms=prefill_ms,
-            decode_ms=decode_ms,
-            steps=len(out_ids),
-            finished=finished,
-            error=(None if pois == 0 else
-                   "poisoned: " + ("non-finite logits" if pois == 1
-                                   else "grammar dead state")),
-            forwards=forwards,
             prompt_tokens=n,
             quality=quality,
         )

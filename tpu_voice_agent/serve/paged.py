@@ -532,14 +532,7 @@ def paged_chunk_decode_loop(
     (pad, 0 emitted, ``eos0``, no poison, ``_conf_init``), so the caller sees
     the full width's shapes. The pool is shared and addressed through the
     tables, so nothing of it is gathered. The engine picks the width from
-    the batcher's live count (``PagedDecodeEngine.decode_chunk``).
-
-    The batched VERIFY mode of this chunk path (speculative decoding,
-    ISSUE 8) lives in serve.spec.paged_spec_verify_step: drafting is
-    host-side so verify steps cannot run inside this lax.while_loop — the
-    SpecDecoder substitutes for the whole loop behind decode_chunk, one
-    (B, 1+K) forward_paged per step with the same write_mask/trash-block
-    discipline, per-row accept lengths, and the same per-row poison codes."""
+    the batcher's live count (``PagedDecodeEngine.decode_chunk``)."""
     if rows_idx is not None:
         with jax.named_scope("rows_gather"):
             full = (cur, pos, fsm_state, active, nbytes, tokens_left)
@@ -809,7 +802,7 @@ class PagedDecodeEngine(DecodeEngine):
         # per-(position, head) scaled values (ops.kvquant) — half/quarter
         # the HBM bytes per block, so a fixed pool budget holds ~2x/~4x the
         # blocks. Unset keeps the bf16 pool byte-identical, differentially
-        # tested like RADIX_ENABLE/SPEC_ENABLE before it.
+        # tested like RADIX_ENABLE before it.
         if kv_quant is None:
             kv_quant = os.environ.get("KV_QUANT") or None
         if kv_quant in ("", "off"):
@@ -819,8 +812,8 @@ class PagedDecodeEngine(DecodeEngine):
         self.kv_quant = kv_quant
         if radix_enable is None:
             radix_enable = os.environ.get("RADIX_ENABLE") == "1"
-        # what the model's kind refuses of a paged engine (a mesh and a verify
-        # step: the parent's constructor, from the same table)
+        # what the model's kind refuses of a paged engine (a mesh: the
+        # parent's constructor, from the same table)
         if kv_quant:
             fam.refuse("kv_quant")
         if radix_enable:
@@ -899,12 +892,6 @@ class PagedDecodeEngine(DecodeEngine):
         # yet; reconcile would clamp _next_pos against the row's parked
         # device position)
         self._mid_prefill: set[int] = set()
-        # speculative decoding (ISSUE 8): deferred from the parent ctor —
-        # the SpecDecoder reads the paged surface (pool/tables/trash) that
-        # only exists now. Greedy batched chunks route through it; rejected
-        # draft positions roll back on COW-owned blocks (spec.py docstring)
-        if self._spec_cfg is not None:
-            self._build_spec()
 
     def _placed_zeros(self, shape, dtype, scale: bool = False):
         """A plane of zeros where this engine keeps it: under KV_QUANT a block
@@ -1315,14 +1302,6 @@ class PagedDecodeEngine(DecodeEngine):
         # show up as served-from-cache in the radix gauges
         with span(ALLOC_SPAN):
             self.radix[g].record_hit(P)
-            if self.spec is not None:
-                # drafter seeding on the warm path (the miss fallback hooks
-                # on_admit inside super().prefill_slot): the drafters get
-                # the FULL cached prompt ids, so prompt-lookup drafting sees
-                # the whole multi-turn transcript from a warm turn's first
-                # verify step — the radix admission feeds the drafter, not
-                # just the KV
-                self.spec.on_admit(slot, ids)
             m = len(suffix)
             tokens = np.full((1, bucket), self.pad_id, dtype=np.int32)
             tokens[0, :m] = suffix
@@ -1359,11 +1338,10 @@ class PagedDecodeEngine(DecodeEngine):
         while a group of TWO must still cost less than two one-row calls
         (PERF.md section 6, PR 35, has the chip's numbers at 4 and at 8).
         0 = never: a mesh (slots of different dp groups share no batch
-        axis), radix reuse (an admission's chain is its own), spec decode (a
-        drafter is seeded per admission), ``KV_QUANT`` — all of which
-        ``prefill_slot`` serves as it did."""
+        axis), radix reuse (an admission's chain is its own), ``KV_QUANT`` —
+        all of which ``prefill_slot`` serves as it did."""
         A = self.batch_slots // 8
-        on = (A >= 2 and self.dp == 1 and self.radix is None and self.spec is None
+        on = (A >= 2 and self.dp == 1 and self.radix is None
               and self.kv_quant is None and bool(self.prefix_ids))
         return A if on else 0
 
@@ -1643,9 +1621,6 @@ class PagedDecodeEngine(DecodeEngine):
         self._last_prefill_compute_ms = cur.total_ms
         self._last_cached_tokens = cur.P
         self._slot_ids[slot] = cur.ids
-        if self.spec is not None:
-            # drafter seeding at admission, same hook as the one-shot paths
-            self.spec.on_admit(slot, cur.ids)
         r = len(cur.suffix) - start
         with span(FIRST_TOKEN_SPAN):
             return logits[:, r - 1, :]
@@ -1727,19 +1702,6 @@ class PagedDecodeEngine(DecodeEngine):
         caller's ``reconcile_coverage`` clamps back: a driver that skips it
         compounds the claim toward max_len per slot — recreating the dense
         footprint this engine exists to avoid."""
-        if self.spec is not None and greedy:
-            # speculative batched verify mode (ISSUE 8): chunks become
-            # draft-K/verify-once steps through the SpecDecoder, each ONE
-            # (B, 1+K) forward_paged — token-identical to this loop by
-            # construction, stacking on radix warm prefills. The decoder
-            # claims block coverage per verify step via spec_grow (growth
-            # here would over-claim chunk_steps*(1+K) positions at once);
-            # reconcile_coverage still clamps after the chunk. Only the
-            # plain chunk loop counts anything: its ``counts`` stay empty
-            return self.spec.decode_chunk(
-                cur, pos, fsm, active, nbytes, tokens_left, key,
-                temperature, byte_budget, chunk_steps, greedy,
-                nan_inject=nan_inject)
         # a fast-forward chunk can emit up to (1+W) tokens per step — the
         # table must cover the worst case BEFORE dispatch (a mid-chunk
         # write past the covered blocks would scribble on the pool). The
@@ -1810,32 +1772,6 @@ class PagedDecodeEngine(DecodeEngine):
             conf=conf if self.quality_lanes else None,
             counts=dict(zip(names, counts, strict=True)), ffn_rows=width)
 
-    def spec_grow(self, span: int, active=None) -> list[int]:
-        """Claim block coverage for one speculative verify step (cur + K
-        draft writes) — the spec twin of decode_chunk's pre-dispatch
-        claim, paced per verify step because the SpecDecoder pays a host
-        readback each step anyway (and reconciles ``_next_pos`` to the
-        actual frontier after it, so the worst-case claim never compounds
-        across steps). ``active`` restricts the claim to rows still
-        decoding: a slot that finished mid-chunk stays engine-owned until
-        the scheduler's post-chunk release and must not keep bleeding the
-        pool. Returns the slots whose pool claim FAILED (after radix
-        eviction): the caller truncates those rows alone at their covered
-        frontier while batch-mates keep decoding — the same per-request
-        isolation as the plain chunk's ladder."""
-        starved = []
-        for b in range(self.batch_slots):
-            if b in self._mid_prefill:
-                continue  # chunked admission underway — not decoding
-            if self._slot_owned[b] and (active is None or active[b]):
-                try:
-                    self._grow(b, self._next_pos[b] + span + 1)
-                except PoolExhausted:
-                    starved.append(b)
-                    continue
-                self._next_pos[b] = min(self._next_pos[b] + span, self.max_len)
-        return starved
-
     def set_slot_ns(self, slot: int, ns: str | None) -> None:
         """Install the tenant radix namespace for the slot's NEXT admission
         (the scheduler calls this right before ``prefill_slot``; the
@@ -1864,11 +1800,6 @@ class PagedDecodeEngine(DecodeEngine):
                 # later session as a warm prefix. Under pool pressure
                 # (_radix_may_admit) insertion is denied too — caching must
                 # yield to live admissions before live admissions shed.
-                # ``generated_ids`` is the scheduler's ACCEPTED token stream
-                # — under speculation, rejected draft KV only ever lives at
-                # positions PAST len(prompt+accepted), i.e. in the partial
-                # tail block insert() already refuses to adopt, so zero
-                # radix-cached blocks can contain a rejected draft token.
                 ids = self._slot_ids[slot] + [int(t) for t in generated_ids]
                 blocks = self._slot_shared[slot] + self._slot_owned[slot]
                 self.radix[self._group(slot)].insert(ids, blocks, ns=ns)
@@ -1879,9 +1810,6 @@ class PagedDecodeEngine(DecodeEngine):
             self._covered[slot] = 0
             self._next_pos[slot] = 0
         self._slot_ids[slot] = None
-        # parent hook: the spec decoder drops the slot's host context /
-        # drafter state (and writes its SPEC_TRACE_SINK record on ok)
-        super().release_slot(slot, generated_ids, ok=ok)
 
     def _radix_may_admit(self, group: int) -> bool:
         """Pool-pressure gate on session-cache admission (degradation stage
@@ -2004,11 +1932,6 @@ class PagedDecodeEngine(DecodeEngine):
         self._mid_prefill.clear()
         self.block_tables = self._fresh_tables()
         self._pressure_until = 0.0
-        if self.spec is not None:
-            # per-slot host contexts + drafter state are slot bookkeeping
-            # too; the generation fence stops a wedged decode_chunk from
-            # dispatching further verify steps against the fresh world
-            self.spec.reset()
         # re-arm the recompilation sentinel (see the dense twin): the
         # rebuilt tables/allocator must come back at the old shapes — a
         # post-restart retrace is an alertable event, not background noise

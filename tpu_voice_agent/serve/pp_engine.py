@@ -120,21 +120,7 @@ class PPDecodeEngine(DecodeEngine):
         # cache traffic as a (B, 1) step and the chain tokens ride free.
         # Fewer steps also means fewer S-tick fill-drain traversals, the
         # pp-specific overhead.
-        spec=None,  # serve.spec.SpecConfig — REFUSED (typed, below): this
-        # layout has no rollback story for rejected draft positions
     ):
-        if spec is not None and getattr(spec, "k", 0):
-            # clear typed refusal (the brain factory passes SPEC_ENABLE
-            # through instead of warn+ignoring it): the dense layout rolls
-            # back by position rewind, the paged layout by overwriting
-            # COW-owned draft blocks — the staged pp cache (batch at axis
-            # 2, layers stage-sliced over pp) supports neither, so a
-            # rejected draft would leave unrollable KV in every stage
-            raise ValueError(
-                "speculative decoding is not supported on the pp layout: "
-                "the staged pipeline cache has no per-position rollback "
-                "story; unset SPEC_ENABLE or serve speculation on the "
-                "dense or paged engines")
         if mesh is None or "pp" not in mesh.shape:
             raise ValueError("PPDecodeEngine needs a mesh with a 'pp' axis "
                              "(parallel.pipeline.pp_tp_mesh)")
@@ -226,7 +212,7 @@ class PPDecodeEngine(DecodeEngine):
                 batch_slots: int = 1,
                 prefill_buckets: tuple[int, ...] = (128, 256, 512, 1024, 2048),
                 dtype=jnp.bfloat16, quant: str | None = None,
-                fast_forward: int = 0, spec=None,
+                fast_forward: int = 0,
                 **_ignored) -> "PPDecodeEngine":
         """Serve a real HF checkpoint through the pp×tp pipeline (the 70B
         import path; same loader as DecodeEngine.from_hf). Pass
@@ -242,8 +228,7 @@ class PPDecodeEngine(DecodeEngine):
         tok = load_hf_tokenizer(model_dir)
         eng = cls(cfg=cfg, mesh=mesh, max_len=max_len, batch_slots=batch_slots,
                   prefill_buckets=prefill_buckets, tokenizer=tok,
-                  init_weights=False, quant=quant, fast_forward=fast_forward,
-                  spec=spec)
+                  init_weights=False, quant=quant, fast_forward=fast_forward)
         eng.load_params(llama_from_hf_state(model_dir, cfg, dtype=dtype))
         return eng
 

@@ -312,27 +312,6 @@ class RadixCache:
 
     # ------------------------------------------------------------ stats
 
-    def chains(self) -> list[list[int]]:
-        """Every root→leaf token-id chain currently cached (debug/test
-        surface). The speculative-decoding containment tests walk this to
-        assert no cached chain ever contains a rejected draft token: each
-        chain must be a prefix of some request's accepted prompt+generated
-        stream (serve.spec — rejected drafts live only past the accepted
-        frontier, in the partial tail ``insert`` refuses to adopt)."""
-        out: list[list[int]] = []
-
-        def walk(node: RadixNode, ids: list[int]) -> None:
-            if not node.children:
-                if ids:
-                    out.append(list(ids))
-                return
-            for child in node.children.values():
-                kt = child.key[1] if child.ns is not None else child.key
-                walk(child, ids + list(kt))
-
-        walk(self.root, [])
-        return out
-
     def reclaimable_blocks(self) -> int:
         """Blocks the eviction ladder could hand back under pressure:
         unpinned nodes whose block the tree solely owns (refcount == 1).

@@ -141,10 +141,6 @@ class _Slot:
     cached_tokens: int = 0  # prompt tokens served from cached KV (static
     # prefix / radix chain) at admission
     queue_ms: float = 0.0  # submit() -> popped from pending by step()
-    forwards: int = 0  # decode forward dispatches this request rode (spec
-    # engines report per-row participation; 0 = engine doesn't split it)
-    spec_accepts: int = 0  # draft tokens accepted for this request (spec
-    # engines report per-row accept counts on the same widened readback)
     eos: bool = False
     # ISSUE 15 conf lanes accumulated across chunks (engines report per-row
     # margin/entropy/forced/decision lanes on the same combined readback)
@@ -792,8 +788,7 @@ class ContinuousBatcher:
             computed, cached = self.costs.model.prefill_split(
                 n, sl.cached_tokens)
             sl.cost = dict.fromkeys(
-                ("decode_flops", "decode_bytes", "wasted_draft_flops",
-                 "kv_block_us"), 0)
+                ("decode_flops", "decode_bytes", "kv_block_us"), 0)
             sl.cost["prefill_flops"] = computed
             sl.cost["prefill_cached_flops"] = cached
             self.costs.fold_prefill(computed, cached, sl.prefill_ms)
@@ -1318,10 +1313,9 @@ class ContinuousBatcher:
         # frontier (the ff worst-case claim must not compound per chunk)
         eng.reconcile_coverage(pos_h)
 
-        # ACCEPTED/emitted tokens, never verify steps or forward dispatches:
-        # `n` is the per-row emitted count in every engine layout (plain,
-        # ff, speculative), so the tokens/s EMA below stays truthful when
-        # one forward emits several tokens
+        # EMITTED tokens, never forward dispatches: `n` is the per-row
+        # emitted count in every engine layout (plain, ff), so the tokens/s
+        # EMA below stays truthful when one forward emits several tokens
         m.inc("scheduler.tokens_generated", float(n_h.sum()))
         m.inc("scheduler.chunks")
         if fwds_h > 0:
@@ -1376,27 +1370,18 @@ class ContinuousBatcher:
         except Exception:
             pass
 
-        # widened spec readbacks (ISSUE 8): per-row verify participation
-        # and accept counts — host arrays the SpecDecoder already paid the
-        # transfer for, folded into per-REQUEST accounting so batched
-        # results carry an honest ``forwards`` (steps/forwards IS the
-        # request's speculation multiplier) and ``spec_accepted``
-        row_fwds, row_accepts = res.row_fwds, res.row_accepts
         # ISSUE 15 conf lanes: per-row (margin_sum, margin_min, entropy_sum,
         # forced, decisions) folded into per-request accounting so finished
         # results carry an honest quality vector
         conf_arr = None if conf_h is None else [np.asarray(x) for x in conf_h]
 
         # cost fold (ISSUE 17): one per-row ledger dict per chunk, computed
-        # from readbacks already paid for. Positions computed: spec rows
-        # pay 1 + drafted per verify forward (worst-case verify cost —
-        # rejected drafts included, the hardware did the work); plain rows
-        # pay one position per emitted token (grammar fast-forward writes
-        # each forced token's KV through the same per-position compute).
+        # from readbacks already paid for. A row pays one position per
+        # emitted token (grammar fast-forward writes each forced token's KV
+        # through the same per-position compute).
         # KV block-time: paged rows hold owned + shared blocks for the
         # chunk wall; dense rows hold 1 "block" (their whole KV line).
         costs = self.costs
-        row_drafted = res.row_drafted
         chunk_us = int(round(chunk_s * 1e6))
         chunk_flops = 0
         chunk_kv_bytes = 0
@@ -1420,24 +1405,12 @@ class ContinuousBatcher:
             if costs is not None and sl.cost is not None:
                 # fold BEFORE the poison branch: an evicted row's spent
                 # chunk cost must ride out on its error result
-                if row_fwds is not None and row_drafted is not None:
-                    positions = int(row_fwds[b]) + int(row_drafted[b])
-                else:
-                    positions = int(n_h[b])
-                fl, by = costs.model.decode_row(positions, int(pos_h[b]))
-                wasted = 0
-                if row_drafted is not None and row_accepts is not None:
-                    w_pos = max(0, int(row_drafted[b]) - int(row_accepts[b]))
-                    if w_pos:
-                        wasted = costs.model.decode_row(
-                            w_pos, int(pos_h[b]))[0]
+                fl, by = costs.model.decode_row(int(n_h[b]), int(pos_h[b]))
                 kv_us = chunk_us * eng.slot_block_count(b)
                 sl.cost["decode_flops"] += fl
                 sl.cost["decode_bytes"] += by
-                sl.cost["wasted_draft_flops"] += wasted
                 sl.cost["kv_block_us"] += kv_us
                 costs.fold_row({"decode_flops": fl, "decode_bytes": by,
-                                "wasted_draft_flops": wasted,
                                 "kv_block_us": kv_us})
                 chunk_flops += fl
                 chunk_kv_bytes += by
@@ -1459,10 +1432,6 @@ class ContinuousBatcher:
                                               detail=reason)
                 continue
             sl.token_ids.extend(int(t) for t in out_h[b, : n_h[b]])
-            if row_fwds is not None:
-                sl.forwards += int(row_fwds[b])
-            if row_accepts is not None:
-                sl.spec_accepts += int(row_accepts[b])
             if conf_arr is not None:
                 sl.conf_msum += float(conf_arr[0][b])
                 sl.conf_mmin = min(sl.conf_mmin, float(conf_arr[1][b]))
@@ -1487,8 +1456,6 @@ class ContinuousBatcher:
                     steps=len(sl.token_ids),  # accepted tokens, not forwards
                     finished=bool(eos_h[b]),
                     cached_tokens=sl.cached_tokens,
-                    forwards=sl.forwards,
-                    spec_accepted=sl.spec_accepts,
                     prompt_tokens=sl.prompt_len,
                     queue_ms=sl.queue_ms,
                     quality=conf_summary(
@@ -1513,10 +1480,7 @@ class ContinuousBatcher:
                 self.engine.release_slot(b, generated_ids=sl.token_ids)
 
         # close the ledger entry: everything after the readback (commit,
-        # release/radix-insert, gauge exports, HBM tick) is "release"; the
-        # drafter's host share (``sched.decode.draft`` around the spec
-        # drafter) was taken out of the decode stage it ran inside, so the
-        # six stages still tile the wall
+        # release/radix-insert, gauge exports, HBM tick) is "release"
         # roofline reconciliation (ISSUE 17): the chunk's analytic FLOPs /
         # KV bytes against the measured chunk wall -> engine.mfu /
         # engine.mbu gauges + cost.* counters (weights stream per forward
@@ -1532,8 +1496,6 @@ class ContinuousBatcher:
             tokens=int(n_h.sum()),
             admitted=n_admitted or None,
             forwards=fwds_h,
-            accepted=(int(np.sum(row_accepts)) if row_accepts is not None
-                      else None),
         )
         return res
 

@@ -1820,13 +1820,7 @@ def make_parser_from_env() -> IntentParser:
     session-aware — multi-turn prompts become strict token extensions that
     the tree admits with O(new utterance) prefill. RADIX_MAX_NODES caps the
     tree, RADIX_SESSIONS the host transcript LRU (docs/PERF.md "Session KV
-    reuse"). Unset keeps the stateless path byte-identical.
-    SPEC_ENABLE=1 turns on grammar-aware speculative decoding on the dense
-    AND paged engine layouts (SPEC_K / SPEC_DRAFTER / SPEC_DRAFT_MODEL /
-    SPEC_TRACE_SINK — serve.spec); on paged it runs inside the batched
-    chunk path and compounds with radix warm prefills (ISSUE 8). The pp
-    layout refuses it with a typed error at boot (no rollback story on the
-    staged cache). Greedy output stays token-identical either way."""
+    reuse"). Unset keeps the stateless path byte-identical."""
     import logging
 
     log = logging.getLogger("tpu_voice_agent.brain")
@@ -1838,9 +1832,6 @@ def make_parser_from_env() -> IntentParser:
     paged = os.environ.get("BRAIN_PAGED") == "1"
     quant = os.environ.get("BRAIN_QUANT") or None
     moe = "grouped" if os.environ.get("BRAIN_MOE") == "grouped" else None
-    from ..serve import spec_from_env
-
-    spec = spec_from_env()  # None unless SPEC_ENABLE=1
 
     def warn_unused(backend_name: str, **knobs) -> None:
         for name, val in knobs.items():
@@ -1854,21 +1845,18 @@ def make_parser_from_env() -> IntentParser:
 
         if paged:
             # classmethod polymorphism: from_hf builds cls(...), so the
-            # paged engine loads checkpoints through the same loader.
-            # SPEC_ENABLE just turns on here (ISSUE 8): spec decode runs
-            # inside the paged chunk path, compounding with radix reuse
+            # paged engine loads checkpoints through the same loader
             pool = int(os.environ.get("BRAIN_POOL_BLOCKS", "0")) or None
             eng = PagedDecodeEngine.from_hf(
                 model_dir, quant=quant, batch_slots=max(slots, 1),
-                moe_impl=moe, pool_blocks=pool, spec=spec)
+                moe_impl=moe, pool_blocks=pool)
             return _wrap_batched(eng)
         return _wrap_engine(DecodeEngine.from_hf(model_dir, quant=quant,
                                                  batch_slots=slots, fast_forward=ff,
-                                                 moe_impl=moe, spec=spec))
+                                                 moe_impl=moe))
     backend = os.environ.get("BRAIN_BACKEND", "rule")
     if backend == "rule":
-        warn_unused("rule", BRAIN_PAGED=paged, BRAIN_QUANT=quant, BRAIN_MOE=moe,
-                    SPEC_ENABLE=spec)
+        warn_unused("rule", BRAIN_PAGED=paged, BRAIN_QUANT=quant, BRAIN_MOE=moe)
         return RuleBasedParser()
     if backend.startswith("distilled"):
         # the in-tree trained intent checkpoint through the real constrained
@@ -1885,7 +1873,7 @@ def make_parser_from_env() -> IntentParser:
         if loaded is None:
             raise ValueError(f"no distilled intent checkpoint at {path} "
                              "(run python -m tpu_voice_agent.train.make_tiny_ckpts)")
-        return distill.intent_engine_from(*loaded, spec=spec)
+        return distill.intent_engine_from(*loaded)
     if backend.startswith("engine"):
         from ..serve import DecodeEngine, PagedDecodeEngine
 
@@ -1902,15 +1890,13 @@ def make_parser_from_env() -> IntentParser:
         if paged:
             # paged KV pool behind the batcher: HBM tracks live tokens, the
             # shared prompt prefix is stored once, BRAIN_POOL_BLOCKS sizes
-            # the pool (default: dense worst case). SPEC_ENABLE composes
-            # (ISSUE 8): greedy chunks become draft-K/verify-once steps on
-            # the paged layout, stacking with radix warm prefills
+            # the pool (default: dense worst case)
             pool = int(os.environ.get("BRAIN_POOL_BLOCKS", "0")) or None
             return _wrap_batched(PagedDecodeEngine(
                 preset=preset, cfg=cfg, batch_slots=max(slots, 1),
-                pool_blocks=pool, quant=quant, fast_forward=ff, spec=spec))
+                pool_blocks=pool, quant=quant, fast_forward=ff))
         return _wrap_engine(DecodeEngine(preset=preset, cfg=cfg, batch_slots=slots,
-                                         fast_forward=ff, quant=quant, spec=spec))
+                                         fast_forward=ff, quant=quant))
     if backend.startswith("pp"):
         # TP×PP pipelined engine (the 70B planner serving layout): layers
         # pipeline over pp, each stage tensor-parallel over tp.
@@ -1932,13 +1918,9 @@ def make_parser_from_env() -> IntentParser:
         # fill-drain bubble where the dense/paged layouts ride it free.
         # CPU measured the opposite (+14%), so the knob stays available.
         ppff = int(os.environ.get("BRAIN_FF", "0"))  # analyze: ok[env-knob] -- deliberate per-backend default: ff measured HURTING the staged pp layout (see comment above); every other backend keeps the declared default 8
-        # spec passes THROUGH: the engine refuses it with a clear typed
-        # error (no rollback story on the staged cache) instead of the old
-        # warn+ignore — an operator who set SPEC_ENABLE on the pp backend
-        # finds out at boot, not by silently missing the speedup
         return _wrap_batched(PPDecodeEngine(preset=preset, mesh=pp_tp_mesh(pp, tp),
                                             batch_slots=slots, quant=quant,
-                                            fast_forward=ppff, spec=spec))
+                                            fast_forward=ppff))
     if backend.startswith("planner-distilled"):
         # the in-tree trained intent checkpoint behind the SESSION-KEYED
         # planner: multi-turn transcripts with the distilled short prompt
@@ -1952,7 +1934,7 @@ def make_parser_from_env() -> IntentParser:
         from ..train import distill
 
         warn_unused("planner-distilled", BRAIN_PAGED=paged, BRAIN_QUANT=quant,
-                    BRAIN_MOE=moe, SPEC_ENABLE=spec)
+                    BRAIN_MOE=moe)
         path = (backend.split(":", 1)[1] if ":" in backend
                 else os.path.join("checkpoints", distill.INTENT_CKPT))
         loaded = distill.load_ckpt_path(path, LlamaConfig)
@@ -1978,8 +1960,7 @@ def make_parser_from_env() -> IntentParser:
         from ..parallel.ring import sp_mesh
         from ..serve import LongSessionPlanner
 
-        warn_unused("planner", BRAIN_PAGED=paged, BRAIN_QUANT=quant, BRAIN_MOE=moe,
-                    SPEC_ENABLE=spec)
+        warn_unused("planner", BRAIN_PAGED=paged, BRAIN_QUANT=quant, BRAIN_MOE=moe)
         preset = backend.split(":", 1)[1] if ":" in backend else "tinyllama-1.1b"
         sp = int(os.environ.get("BRAIN_SP", "0")) or len(jax.devices())
         return PlannerParser(LongSessionPlanner(preset=preset, mesh=sp_mesh(sp)))

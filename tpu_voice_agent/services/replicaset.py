@@ -100,8 +100,8 @@ FLEET_SIGNALS: tuple[tuple[str, str, str, str, float], ...] = (
     # engine.step decode wall this window — the device-plane symptom of
     # recompiles / jit-cache thrash (step ledger histogram)
     ("decode_ms", "hist", "engine.step.decode", "high", 2.0),
-    # speculation health: a replica whose drafts stopped landing decodes
-    # token-by-token while its peers emit multiples per forward
+    # fast-forward health: a replica whose forced chains stopped landing
+    # decodes token-by-token while its peers emit multiples per forward
     ("tokens_per_forward", "gauge", "scheduler.tokens_per_forward", "low", 0.25),
     # KV pool pressure: one replica evict-thrashing while peers are half
     # empty is a placement pathology, not fleet load

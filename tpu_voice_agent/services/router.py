@@ -1,8 +1,8 @@
 """Replicated brain tier: a session-affine router over N brain replicas.
 
 Everything before this PR was one brain process — a single point of failure
-holding every piece of warm state (radix chains, session transcripts, spec
-drafter seeds). This service is the *replica* fault domain: an HTTP tier
+holding every piece of warm state (radix chains, session transcripts).
+This service is the *replica* fault domain: an HTTP tier
 that exposes the existing brain contract (``POST /parse``, ``GET /health``,
 ``GET /metrics``, ``/debug/*`` fan-out, ``POST /admin/drain``) in front of
 ``BRAIN_REPLICAS=url,url,...``, so the voice service just points

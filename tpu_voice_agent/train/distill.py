@@ -646,152 +646,18 @@ def train_intent_model(
     return cfg, params, stats
 
 
-def intent_engine_from(cfg, params, max_new_tokens: int = 300, spec=None):
+def intent_engine_from(cfg, params, max_new_tokens: int = 300):
     """Serving engine + parser over trained weights: the REAL constrained
     decode path (grammar FSM, prefix cache machinery) with the distilled
-    short prompt instead of the few-shot prefix. ``spec`` (serve.spec
-    SpecConfig) turns on speculative decoding for the distilled engine —
-    brain plumbs SPEC_ENABLE through here."""
+    short prompt instead of the few-shot prefix."""
     from ..serve import DecodeEngine
     from ..services.brain import EngineParser
 
     eng = DecodeEngine(cfg=replace(cfg, max_seq_len=512), max_len=512,
-                       prefill_buckets=(64, 128), init_weights=False,
-                       spec=spec)
+                       prefill_buckets=(64, 128), init_weights=False)
     eng.load_params(jax.device_put(params))
     return EngineParser(eng, max_new_tokens=max_new_tokens,
                         render=distilled_prompt)
-
-
-# ------------------------------------------------------------ draft traces
-
-
-def load_spec_trace(path: str) -> list[dict]:
-    """Parse a ``SPEC_TRACE_SINK`` JSONL file (serve.spec SpecDecoder
-    appends one record per cleanly released speculative request:
-    prompt/generated ids + drafted/accepted counts). Malformed or partial
-    lines are skipped — the sink appends from a serving process that may
-    be killed mid-write, and a torn tail line must not poison retraining."""
-    out: list[dict] = []
-    with open(path) as f:
-        for line in f:
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                rec = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if rec.get("prompt_ids") and rec.get("generated_ids"):
-                out.append(rec)
-    return out
-
-
-def build_draft_batches_from_trace(records, tokenizer, seq_len: int = 256,
-                                   batch: int = 8, seed: int = 0):
-    """Draft-trace records -> fixed (B, T) (tokens, targets, loss_mask)
-    arrays for ``step.loss_fn_targets``, loss on the GENERATED span (plus
-    one EOS termination position): the drafter's job is to predict the
-    target's accepted stream given the live context — exactly what the
-    trace captured in production, including the multi-turn radix-warm
-    prompts the synthetic corpus never renders. Contexts longer than
-    ``seq_len`` keep their RIGHT-most window (drafting conditions on
-    recent context; the deep prompt head is conditioning, not labels) —
-    unlike ``build_intent_batches`` nothing is dropped, because production
-    prompts routinely exceed any training window."""
-    rng = np.random.default_rng(seed)
-    rows = []
-    for rec in records:
-        p = [int(t) for t in rec["prompt_ids"]]
-        g = [int(t) for t in rec["generated_ids"]]
-        ids = p + g + [tokenizer.eos_id]
-        gen_start = len(p)
-        if len(ids) > seq_len:
-            off = len(ids) - seq_len
-            ids = ids[off:]
-            gen_start = max(gen_start - off, 1)  # keep >= 1 context position
-        T = len(ids)
-        toks = ids + [tokenizer.pad_id] * (seq_len - T)
-        tgts = ids[1:] + [tokenizer.pad_id] * (seq_len - T + 1)
-        mask = [0.0] * seq_len
-        for i in range(gen_start - 1, T - 1):
-            mask[i] = 1.0  # position i predicts ids[i+1]: gen span + EOS
-        rows.append((toks, tgts, mask))
-    rng.shuffle(rows)
-    toks = np.asarray([r[0] for r in rows], np.int32)
-    tgts = np.asarray([r[1] for r in rows], np.int32)
-    masks = np.asarray([r[2] for r in rows], np.float32)
-    n = (len(rows) // batch) * batch
-    return (toks[:n].reshape(-1, batch, seq_len),
-            tgts[:n].reshape(-1, batch, seq_len),
-            masks[:n].reshape(-1, batch, seq_len))
-
-
-DRAFT_CKPT = "draft-tiny-trace"
-
-
-def train_draft_from_trace(path: str, steps: int = 400, batch: int = 8,
-                           seq_len: int = 256, lr: float = 3e-3,
-                           seed: int = 0, log=None):
-    """Retrain the ``draft-tiny`` speculation drafter on production draft
-    traces (the ROADMAP's accept-rate flywheel: serve with
-    ``SPEC_TRACE_SINK`` set, retrain here, point ``SPEC_DRAFT_MODEL`` at
-    ``save_ckpt(root, DRAFT_CKPT, ...)``'s output). The student is the
-    draft-tiny preset at the serving tokenizer's vocab — the width
-    ``DraftModelDrafter`` pads/validates against the target. Returns
-    (cfg, params, stats)."""
-    import optax
-
-    from ..grammar.intent_grammar import build_intent_fsm
-    from ..models.llama import PRESETS, init_params
-    from .step import loss_fn_targets
-
-    tokenizer, _ = build_intent_fsm()
-    records = load_spec_trace(path)
-    if not records:
-        raise ValueError(f"no usable draft-trace records in {path} "
-                         "(serve with SPEC_TRACE_SINK=<path> first)")
-    toks_e, tgts_e, masks_e = build_draft_batches_from_trace(
-        records, tokenizer, seq_len=seq_len, batch=batch, seed=seed)
-    if toks_e.shape[0] == 0:
-        raise ValueError(
-            f"{len(records)} trace records fill no ({batch}, {seq_len}) "
-            "batch; lower batch or collect more traffic")
-    cfg = replace(PRESETS["draft-tiny"], vocab_size=tokenizer.vocab_size,
-                  max_seq_len=seq_len)
-    params = jax.jit(partial(init_params, cfg, dtype=jnp.float32))(
-        jax.random.PRNGKey(seed))
-
-    warmup = min(50, max(1, steps // 4))
-    sched = optax.warmup_cosine_decay_schedule(0.0, lr, warmup, steps, lr * 0.05)
-    optimizer = optax.adamw(sched, weight_decay=0.01)
-    opt_state = optimizer.init(params)
-
-    # analyze: ok[jit-sentinel] -- offline training step, not a serving dispatch — the recompile sentinel guards the serving plane
-    @jax.jit
-    def step_fn(params, opt_state, tokens, targets, loss_mask):
-        loss, grads = jax.value_and_grad(loss_fn_targets)(
-            params, cfg, tokens, targets, loss_mask)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    t0 = time.perf_counter()
-    first = None
-    for s in range(steps):
-        b = s % toks_e.shape[0]
-        params, opt_state, loss = step_fn(
-            params, opt_state, jnp.asarray(toks_e[b]), jnp.asarray(tgts_e[b]),
-            jnp.asarray(masks_e[b]))
-        if s == 0:
-            first = float(loss)
-        if log and (s % 100 == 0 or s == steps - 1):
-            log(f"draft trace train step {s}/{steps} loss {float(loss):.4f}")
-    stats = {"steps": steps, "records": len(records),
-             "batches": int(toks_e.shape[0]),
-             "first_loss": first, "final_loss": float(loss),
-             "train_s": round(time.perf_counter() - t0, 1)}
-    return cfg, params, stats
 
 
 # ------------------------------------------------------------ whisper train

@@ -115,8 +115,8 @@ class CompileWatcher:
         from . import get_metrics, log_event
 
         # expected-compile allowlist: site prefixes the operator has
-        # declared legitimately lazy (XLA_EXPECTED_COMPILES="stt.,spec._draft"
-        # — e.g. a drafter model loaded on first use). Still counted and
+        # declared legitimately lazy (XLA_EXPECTED_COMPILES="stt."
+        # — e.g. an STT model loaded on first use). Still counted and
         # ringed as compiles, but never flagged post-fence: the alert is
         # for SURPRISE traces only. Read per event (compiles are rare) so
         # tests and live operators can tune it without a restart.

@@ -12,8 +12,7 @@ all three:
   dimensions, MoE-aware: only ``top_k`` experts are active per token),
   KV bytes read/written per step (KV_QUANT-aware via the
   ``ops.kvquant`` per-(position, head) layout — the single
-  byte-accounting source), spec verify-step worst-case cost (1 + K
-  positions per verify forward), and the Whisper encoder/decoder cost
+  byte-accounting source), and the Whisper encoder/decoder cost
   (mirrors ``models.whisper.param_count``'s weight walk). Config
   arithmetic only; no device reads, ever.
 - **Exact conservation** — every quantity is a Python ``int``. The
@@ -39,13 +38,10 @@ Ledger keys (all ints):
 - ``prefill_flops`` — prompt positions actually computed at admission
 - ``prefill_cached_flops`` — FLOPs the prefix/radix cache avoided
   (computed + cached == the full cold-prompt cost, exactly)
-- ``decode_flops`` — every decode position computed for the row,
-  INCLUDING rejected speculative drafts (the hardware did the work)
+- ``decode_flops`` — every decode position computed for the row
 - ``decode_bytes`` — KV bytes read + written for those positions
   (weights stream per *dispatch*, batch-shared, and is metered
   engine-side — see ``CostMeter.engine``)
-- ``wasted_draft_flops`` — the rejected-draft subset of
-  ``decode_flops`` (drafted − accepted positions; 0 on plain paths)
 - ``kv_block_us`` — KV block-microseconds held (paged: owned + shared
   blocks x chunk wall; dense: 1 "block" == the slot's KV line)
 
@@ -64,7 +60,7 @@ from . import get_metrics
 from .knobs import knob_bool, knob_float, knob_int
 
 LEDGER_KEYS = ("prefill_flops", "prefill_cached_flops", "decode_flops",
-               "decode_bytes", "wasted_draft_flops", "kv_block_us")
+               "decode_bytes", "kv_block_us")
 
 
 def zero_ledger() -> dict:
@@ -82,7 +78,7 @@ def decode_step_bytes(cfg, batch: int, context_tokens: int,
     for the whole batch; each live slot reads its attended KV. KV bytes
     follow the ops.kvquant per-(position, head) layout, so the ratio
     between tiers IS the modeled decode-stage speedup the bench kv_quant
-    rows report (benches/bench_spec.py). Hoisted from utils/hbmledger
+    rows report. Hoisted from utils/hbmledger
     (ISSUE 17) so byte accounting has one source of truth beside the
     FLOP model."""
     from ..ops.kvquant import KV_QUANT_VBYTES, KV_SCALE_BYTES
@@ -178,12 +174,6 @@ def decode_flops(cfg, n_positions: int, ctx: int) -> int:
     conservation is unaffected)."""
     return int(n_positions * (llm_token_flops(cfg)
                               + ctx * llm_attn_flops_per_ctx(cfg)))
-
-
-def spec_verify_flops(cfg, ctx: int, k: int) -> int:
-    """Worst-case cost of ONE speculative verify forward: 1 + K
-    positions computed whether or not the drafts survive."""
-    return decode_flops(cfg, 1 + k, ctx)
 
 
 # ---------------------------------------------------------- Whisper model

@@ -21,8 +21,7 @@ This module closes the loop:
   ``hbm.{weights,kv_pool,workspace,free}_bytes`` gauges plus
   ``hbm.plan_drift`` — (measured − planned) ÷ planned over the accountable
   parts. Drift past ``HBM_DRIFT_WARN`` (default 0.15) is the "your mental
-  model of HBM is wrong" alarm: a leaked cache, a double-resident prefix,
-  an unplanned drafter model.
+  model of HBM is wrong" alarm: a leaked cache, a double-resident prefix.
 
 Everything degrades gracefully off-TPU: the ledger is exactly as useful on
 the CPU harness (allocator-tracked bytes, zero workspace) as the tests

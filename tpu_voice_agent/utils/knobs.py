@@ -164,13 +164,8 @@ declare("ARTIFACTS_DIR", ".artifacts", "executor screenshot/DOM artifact root", 
 declare("UPLOADS_DIR", ".uploads", "executor file-upload staging dir", table=RESILIENCE)
 
 # ================================================================== perf
-# docs/PERF.md — speculation, radix KV reuse, STT batching, engine config
+# docs/PERF.md — radix KV reuse, STT batching, engine config
 
-declare("SPEC_ENABLE", None, "1 builds the SpecDecoder (unset keeps the plain decode path)", table=PERF)
-declare("SPEC_K", "4", "draft width — each verify step emits 1..K+1 tokens", table=PERF)
-declare("SPEC_DRAFTER", "fsm,prompt", "drafter chain: fsm | prompt | model, first non-empty proposal wins", table=PERF)
-declare("SPEC_DRAFT_MODEL", None, "orbax checkpoint dir for the model drafter", table=PERF)
-declare("SPEC_TRACE_SINK", None, "JSONL path for per-request speculation traces (drafter retraining)", table=PERF)
 declare("KV_QUANT", None, "paged KV pool storage tier: int8 | int4 (unset = bf16, byte-identical path)", table=PERF)
 declare("RADIX_ENABLE", None, "1 builds the radix KV session cache", table=PERF)
 declare("RADIX_MAX_NODES", "4096", "radix tree size cap per dp group", table=PERF)

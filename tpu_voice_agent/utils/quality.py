@@ -15,10 +15,9 @@ SLO-gated signal on every utterance:
   Exported as ``stt.confidence_mean`` / ``stt.confidence_min`` /
   ``stt.confidence_repetition`` and fed here by the voice service per
   final transcript.
-- **Intent confidence** — the grammar-constrained decode tail (dense,
-  paged, and spec-verify planes share one readback contract,
-  ``ChunkResult.conf``) reports masked-logit margin and entropy per accepted
-  decision plus the grammar-forced-token fraction; the brain feeds them
+- **Intent confidence** — the grammar-constrained decode tail (the dense
+  and paged planes share one readback contract, ``ChunkResult.conf``)
+  reports masked-logit margin and entropy per accepted decision plus the grammar-forced-token fraction; the brain feeds them
   here per parse, with degraded/downgraded parses counted structurally.
 - **Execution feedback** — executor action verdicts become weak labels
   per intent type (``quality.exec_success_rate``), closing the loop the
@@ -348,21 +347,6 @@ def make_quality_handler(monitor: QualityMonitor):
         return web.json_response(monitor.state())
 
     return quality_ep
-
-
-def conf_fold(acc, new):
-    """Fold one chunk/step's host-side conf lanes into an accumulator —
-    THE one spelling of the (margin_sum, margin_min, entropy_sum, forced,
-    decisions) merge rule (sums add, mins min, counts add), shared by the
-    spec decoder's per-step accumulation and the single-request spec
-    generate's per-chunk one. ``acc=None`` starts a fresh accumulator."""
-    import numpy as np
-
-    new = [np.asarray(x) for x in new]
-    if acc is None:
-        return new
-    return [acc[0] + new[0], np.minimum(acc[1], new[1]), acc[2] + new[2],
-            acc[3] + new[3], acc[4] + new[4]]
 
 
 def conf_summary(conf_h, steps: int) -> dict | None:

@@ -11,18 +11,14 @@ with the step's wall-time decomposition —
               timed — the two host→device copies of the staged suffix, on
               the paged layout the block allocation and prefix-tail
               scatter, and the DISPATCH of the jitted forward)
-    draft     host drafter share of the chunk (``sched.decode.draft``
-              around the spec drafter; carved out of decode)
-    decode    the decode_chunk dispatch wall — for spec engines this is the
-              whole host-driven draft/verify loop (per-step readbacks
-              included), minus the carved drafter share
+    decode    the decode_chunk dispatch wall
     readback  the scheduler's one combined device_get (host sync — on the
               plain async-dispatch path this is where device compute time
               surfaces to the host)
     release   post-readback commit: result assembly, release_slot /
               radix-insert, gauge exports, HBM ledger tick
 
-— plus batch occupancy, accepted-token and forward counts, each admission's
+— plus batch occupancy, emitted-token and forward counts, each admission's
 parts and queue wait (``admissions``), and any compile events the
 recompilation sentinel (utils/compilewatch.py) caught during the step
 ("compile stall": the step that paid a trace shows it).
@@ -67,7 +63,6 @@ duration into the step's record. The step itself is a
                                      evenly over the members' ledger entries,
                                      which gain ``rows``
       sched.decode_dispatch          stage ``decode``
-        sched.decode.draft           stage ``draft``
       sched.readback                 stage ``readback``
       sched.release                  stage ``release``
     sched.wait_for_work, sched.harvest   serve/colocate.py, between steps
@@ -78,7 +73,7 @@ duration into the step's record. The step itself is a
 
 The four ``stage()`` spans are contiguous (one clock reading closes one and
 opens the next), and a staged span nested in another is subtracted from it,
-so the six stages TILE the step wall by construction: ``sum(stages) ≈
+so the five stages TILE the step wall by construction: ``sum(stages) ≈
 wall``.
 
 WHY the thread was slow there (ISSUE 36). Every stage boundary is read on
@@ -140,10 +135,9 @@ from collections import deque
 from . import machine
 
 # the tiling stage order (stepview renders bars in this order)
-STAGES = ("admit", "prefill", "draft", "decode", "readback", "release")
+STAGES = ("admit", "prefill", "decode", "readback", "release")
 # the spans that ARE stages
 SPAN_STAGE = {"sched.admit": "admit", "sched.admit.prefill": "prefill",
-              "sched.decode.draft": "draft",
               "sched.decode_dispatch": "decode", "sched.readback": "readback",
               "sched.release": "release"}
 PREFILL_STAGE_SPAN = "sched.admit.prefill"
@@ -458,9 +452,9 @@ class _Span:
         timer = self.timer
         timer._open.remove(self)
         if self.stage is not None:
-            # a staged span inside another (a prefill call inside admit, the
-            # drafter inside decode) is that stage's time and not its
-            # parent's: the stages tile the wall, on all three clocks
+            # a staged span inside another (a prefill call inside admit) is
+            # that stage's time and not its parent's: the stages tile the
+            # wall, on all three clocks
             cpu, proc = c - self.c0, p - self.p0
             for up in reversed(timer._open):
                 if up.stage is not None:
@@ -681,7 +675,7 @@ class StepTimer:
 
 
 def span(name: str, **attrs):
-    """The span primitive for code below the scheduler (engine, drafter):
+    """The span primitive for code below the scheduler (the engine):
     part of the thread's open step when there is one, a bare
     TraceAnnotation when there is none (a direct ``engine.generate``)."""
     timer = getattr(_ACTIVE, "timer", None)
