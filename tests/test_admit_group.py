@@ -219,12 +219,17 @@ def _one_row_prefill_text(eng) -> str:
 # its parent's tree (40ebd89) first: the covered blocks leave the pool in ONE
 # gather on (plane, block) (``llama.gather_row_blocks``) where a ``dynamic_slice``
 # of the whole plane stood before the gather, for K and for V — the only ops
-# that moved, in every text.
+# that moved, in every text. ISSUE 60 re-derived all four (each held by the
+# driver's run of its parent's tree, adb1d6a): the K/V write is
+# ``llama.write_rows`` — told no real positions (the dense, routed and "share"
+# kinds' admissions) the SAME pair of scatters, K's and V's issued before the
+# reshapes back (the "share" kind's (block, offset) made once a forward, not a
+# layer); the hybrid's admission is told and walks tiles of its real rows.
 ONE_ROW_SHA256 = {
-    "dense": "7789f597b44d748b9751077b6eec66621d98eb2cb87f5f91e64f7ee59ef6cfd0",
-    "routed": "f176598f04ae3a7504e54e58372a0705d163e2b27d82252dcaff72fd24b48f6e",
-    "hybrid": "8fdb094261566b504ae8da5a16de56ee31e2bf6752073a89348f4df54630dbf0",
-    "share": "63d2fdff4fdcd21ca6838c1322a69c22ba596a1b689cfd0efaf9b341e7d8dc32",
+    "dense": "ead99bde7608d5c63381a639ff0449781ef6e6df8a9d6cdedb5cd87e715494ad",
+    "routed": "888881fc064f8dda83f27a6bfc880a0ba5015543c71399297a5282209792c55e",
+    "hybrid": "ee3c33c14a3cd7bed5fa393bfc3c280e7e8eea64f42ecb225c4f9b2146b7aee9",
+    "share": "61edb0c62eb00a1690472a813c135b1a1afefbba03ffbcfd4e554ce5fa7ddf21",
 }
 
 
@@ -329,18 +334,21 @@ def _chunk_program_shas(eng) -> list[str]:
 # admission runs (``kv_gather``), and that branch gathers (plane, block) out of
 # the pool in one op (``llama.gather_row_blocks``) where it sliced the plane
 # first. The chip's chunk programs go through the block kernel and hold no
-# such branch: theirs are the texts they were.
+# such branch: theirs are the texts they were. ISSUE 60 re-derived all ten
+# (each held by the driver's run of its parent's tree, adb1d6a): every chunk
+# program carries one more count (``kv.rows_written``) and its 1 + W block's
+# K/V write walks tiles of the real rows (``llama.write_rows``).
 CHUNK_SHA256 = {
-    "dense": ["8c977f5a74057290497eb2416cab94954488d2829ca691e8d5de332e3592e73d",
-              "96ec54fc1aa63c2419c581ec802265b18bb4255a87b0d59999c51e23adaeedf4"],
-    "routed": ["a02dd35107a78c8cc6de92ff4e536b8bda89217eb0bfb1da078fc03742365feb",
-               "9721b92392c343728006147d4bf7ee918c041770022e932d4f983d759ab50dfe"],
-    "hybrid": ["eb147389e2bcda48ff01dfe6aa1d8be0c17e916d31a65cd1e2fc05b04100a32b",
-               "0b541fb49f406c239682ad77af65260bd3cb0e7cdaac4fc528a7829474995141"],
-    "share": ["5b6b550b7df812a9f70cc9057f953ee4ad8e2dc0c10098865a9be9df4312a720",
-              "988a45e19c81f0f36ef9d761bb4d75192eadcdef569e00dc6b8198a4eb5d19a5"],
-    "latent": ["f5666531c61fdd1447878cbb75e1c9dc4101423bb499baff0023c56e1bbd8203",
-               "4a12a86248e72b461cc40c639e9fec3c9838c5983a74c3cb3837037885ea2fd1"],
+    "dense": ["715920e5f63af8fd588662720307e6b544fe9ea0ea6f4a87e402948e61f51bae",
+              "e9f530c32a04113bbbcea4210d685b8f3170ddd3bed80a78bc909aeed7649bf4"],
+    "routed": ["80badec7520045d49ec39c94e779929c4ee54c4152ad0ab07600c83f46ca6981",
+               "04e819b74bcd38686278fd5a231c9d5aad0ae2ab38d70de56c3ad0d2022d86ec"],
+    "hybrid": ["61590178b262c90714d9afbf5c356332061ca1b720523e97f82847d2f0cc3a25",
+               "16eafb49e588d50fd58dbae07e95a887cad025ce3ec17bb738c6ee1a7dbdf7e0"],
+    "share": ["fe8ea32f9f7ffc84cc6bc7c14daee28cfa86428517c389046cafd8ba9d228a39",
+              "a68822cc133b6a92415367512c1674a84c1a6321857f2cb5d21f914680e227ce"],
+    "latent": ["69e9841980a745b3f23c75e02409575c8d1efeeb8051368c8b0780e5a7bbaf0f",
+               "3fd94d97143fc35e06d8e372168848af25524ba575eb9ffddfb325d613eb036f"],
 }
 
 

@@ -122,7 +122,7 @@ def test_the_configuration_keeps_the_published_widths_and_names_its_cut():
     assert fam.token_bytes == 3 * 1408 + 6 * 2176 == 17280
     assert dots3.layer_plan(full)[:3] == (("full", 0), ("full", 1), ("sliding", 0))
     assert fam.count("latent").metrics == tuple(f"attn.{n}" for n in mla.LATENT_STATS + dots3.SPARSE_STATS)
-    assert [c.name for c in family(llama.PRESETS["test-tiny"]).counts] == ["attn"]
+    assert [c.name for c in family(llama.PRESETS["test-tiny"]).counts] == ["attn", "kv"]
     # the rehearsal: every mechanism present, selection and window binding
     assert (CFG.layer_types, CFG.first_dense_layers) == (("full", "sliding", "full", "sliding", "sliding"), 2)
     assert (CFG.n_heads, CFG.swa_n_heads, CFG.kv_lora_rank, CFG.swa_kv_lora_rank) == (4, 2, 48, 40)

@@ -198,12 +198,13 @@ def test_a_chunk_counts_what_the_record_names_in_its_order(model, catalog, monke
     fam = eng.family
     names = [c.name for c in fam.counts] + ["ffn"] * bool(eng.ffn_pack_rows)
     assert [c.keyword for c in fam.counts] == {
-        "dense": ["attn_stats"], "routed": ["moe_stats", "attn_stats"],
-        "hybrid": ["hybrid_stats", "attn_stats"],
+        "dense": ["attn_stats", "kv_stats"], "routed": ["moe_stats", "attn_stats", "kv_stats"],
+        "hybrid": ["hybrid_stats", "attn_stats", "kv_stats"],
         # (this module's max_len of 256 passes the share model's rehearsal window: it BINDS,
         # and a plain model whose window binds counts what its windowed layers walk)
-        "share": ["moe_stats", "attn_stats", "window_stats"],
-        "latent": ["moe_stats", "attn_stats", "latent_stats"],
+        "share": ["moe_stats", "attn_stats", "window_stats", "kv_stats"],
+        "latent": ["moe_stats", "attn_stats", "latent_stats", "kv_stats"],
+        # (its site writes planes by layer kind, not through ``llama.write_rows``: no count of them)
         "sparse": ["moe_stats", "attn_stats", "latent_stats"]}[model]
     if fam.name == "hybrid":
         assert fam.count("hybrid").metrics == sambay.HYBRID_STATS
@@ -239,7 +240,7 @@ def test_the_gdn_record_is_read_like_any_other():
     fam = family(cfg)
     assert fam.name == "gdn" and fam.module is olmo_hybrid and fam.error is sambay.StateNotCarried
     assert set(fam.refuses) <= set(FEATURES) and fam.cache == olmo_hybrid.cache_spec(cfg)
-    assert [c.keyword for c in fam.counts] == ["hybrid_stats", "attn_stats"]
+    assert [c.keyword for c in fam.counts] == ["hybrid_stats", "attn_stats", "kv_stats"]
     assert fam.count("hybrid").metrics == olmo_hybrid.HYBRID_STATS
     assert (fam.n_real, fam.one_head, fam.block_real, fam.pack_rows, fam.scratch_prefix) == (
         "always", True, False, 96, True)
@@ -267,7 +268,7 @@ def test_a_gdn_chunk_counts_what_the_record_names_in_its_order(catalog):
     bat = ContinuousBatcher(eng, chunk_steps=2, max_new_tokens=8)
     bat.submit("go back")
     res = bat.step()
-    assert list(res.counts) == ["hybrid", "attn", "ffn"]
+    assert list(res.counts) == ["hybrid", "attn", "kv", "ffn"]
     after = get_metrics().counter_state()[0]
     for count in (*eng.family.counts, FFN):
         assert res.counts[count.name].shape == (len(count.metrics),)
@@ -289,7 +290,7 @@ def test_the_looped_record_is_the_plain_familys_with_a_plane_for_every_pass():
     fam = family(cfg)
     assert fam.name == "plain" and fam.module is llama and fam.error is NotImplementedError
     assert set(fam.refuses) == set(FEATURES) - {"ffn_pack"} and fam.cache == llama.cache_spec(cfg)
-    assert [c.keyword for c in fam.counts] == ["attn_stats", "loop_stats"]
+    assert [c.keyword for c in fam.counts] == ["attn_stats", "loop_stats", "kv_stats"]
     assert fam.count("loop").metrics == tuple(f"loop.{n}" for n in llama.LOOP_STATS)
     assert (fam.n_real, fam.one_head, fam.block_real, fam.pack_rows, fam.scratch_prefix) == (
         "", True, True, 96, True)
@@ -301,7 +302,7 @@ def test_the_looped_record_is_the_plain_familys_with_a_plane_for_every_pass():
     assert hbmledger.engine_hbm_plan(eng)["kv_pool_bytes"] == BLOCKS * eng.kv_bytes_per_block
     # with the fields at their defaults the record is the one it was
     plain = family(_cfg("dense"))
-    assert [c.keyword for c in plain.counts] == ["attn_stats"] and not plain.refuses
+    assert [c.keyword for c in plain.counts] == ["attn_stats", "kv_stats"] and not plain.refuses
 
 
 def test_a_looped_chunk_counts_what_the_record_names_in_its_order(catalog):
@@ -313,7 +314,7 @@ def test_a_looped_chunk_counts_what_the_record_names_in_its_order(catalog):
     bat = ContinuousBatcher(eng, chunk_steps=2, max_new_tokens=8)
     bat.submit("go back")
     res = bat.step()
-    assert list(res.counts) == ["attn", "loop", "ffn"]
+    assert list(res.counts) == ["attn", "loop", "kv", "ffn"]
     after = get_metrics().counter_state()[0]
     for count in (*eng.family.counts, FFN):
         assert res.counts[count.name].shape == (len(count.metrics),)

@@ -345,11 +345,14 @@ def test_the_whole_width_branch_counts_what_it_counted(progs):
 # chunk trace the program they traced. ISSUE 58 re-derived the three (they held
 # on its parent's tree, 40ebd89): these engines attend through XLA, where the
 # block's covered blocks leave the pool in one gather on (plane, block)
-# (``llama.gather_row_blocks``), no slice of the plane before it.
+# (``llama.gather_row_blocks``), no slice of the plane before it. ISSUE 60
+# re-derived the three (they held in the driver's run of its parent's tree,
+# adb1d6a): without ``n_real`` the K/V write is the same pair of scatters,
+# issued through ``llama.write_rows`` (both before the reshapes back).
 PARENT_SHA256 = {
-    "dense": "1c301076c905b2691dc2572b37945f4bb7e67bb9c637ae10081f752fd2e1c4bb",
-    "routed": "269d76deac3efea05ff0d2ceedb459c713ff55eb26d89dbe52a020205c44c9c9",
-    "share": "36a2205ead8a66041d701bba162e8bd615aefc5f2186eedf8f4ffa2a2d471c70",
+    "dense": "d756397fb03ec282da89c59974ff84a51bd22aa22c33ef4124d7ca3f9a59986c",
+    "routed": "e942cbc59ecb3c90ed8fca7ad7a3b512a61e1d850db901099fb418763078e8e0",
+    "share": "53a63a11ed81c63de41a82fa84702910c708c6bf0a3e1309428299e37edb5939",
 }
 
 

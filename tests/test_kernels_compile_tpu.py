@@ -350,12 +350,26 @@ def _chunk_program_at_published_widths(tpu_devices, monkeypatch, config, compact
     return compiled.as_text(), eng, routed
 
 
+def _the_kv_write_alone_holds_a_planes_shape(text: str, eng, blocks: int = 200):
+    """Of the compiled chunk program's ops, only the K/V write's in-place scatters (and the
+    fusions they are the roots of) produce a K/V plane's or the pool's shape: the walk over
+    tiles of the real rows (ISSUE 60) carries the pools through its ``while`` in place — no
+    ``copy``, no slice of a plane (PRs 34, 58)."""
+    from tools.kv_write_check import shaped_ops
+
+    cfg = eng.cfg
+    pool = (cfg.n_layers, blocks, eng.block_size, cfg.n_kv_heads, cfg.head_dim)
+    found = shaped_ops(text, pool)
+    assert found and set(found) <= {"fusion", "scatter"}, found
+
+
 @pytest.mark.parametrize("config", ["mistral-7b-v0.1-int8", "olmoe-1b-7b-0125-int8"])
 def test_the_compacted_chunk_program_compiles_at_published_widths(tpu_devices, monkeypatch, config):
     text, eng, routed = _chunk_program_at_published_widths(tpu_devices, monkeypatch, config, True)
     B, R = eng.batch_slots, eng.compact_rows
     assert text.count("tpu_custom_call") == (4 if routed else 1)  # block attention (+ gate, up, down)
     assert f"bf16[{R},9," in text and f"bf16[{B},9," not in text  # the forwards run at R rows
+    _the_kv_write_alone_holds_a_planes_shape(text, eng)
     assert R * 9 <= eng.ffn_pack_rows and "conditional" not in text  # nothing to pack at this width
 
 
@@ -372,6 +386,7 @@ def test_the_packed_chunk_program_compiles_at_published_widths(tpu_devices, monk
     assert (B, P) == (32, 96) and _conditionals(text) == 2
     assert text.count("tpu_custom_call") == (7 if routed else 1)  # block attention (+ 3 a branch)
     assert f"bf16[{B},9," in text and f"bf16[{P}," in text  # both branches
+    _the_kv_write_alone_holds_a_planes_shape(text, eng)
 
 
 def _hybrid_engine(monkeypatch):
@@ -579,6 +594,7 @@ def test_the_command_a_plus_chunk_program_compiles_at_published_widths(tpu_devic
     assert _conditionals(text) == (8 * 2 if width == "packed" else 0)
     # the head runs on one position a row
     assert f"f32[{n},32768]" in text and f"{n},9,32768]" not in text and f"[{9 * n},32768]" not in text
+    _the_kv_write_alone_holds_a_planes_shape(text, eng, s["pool_blocks"])
 
 
 @pytest.mark.parametrize("bucket,fresh", [(64, False), (1024, True), (1, False), (9, False)],

@@ -358,7 +358,7 @@ def test_the_engine_serves_it_behind_the_batcher_at_both_chunk_widths(monkeypatc
     many = batcher.generate_many([render_prompt(t, {}) for t in texts])
     assert all(r.error is None for r in solo + many)
     assert {c.rows for c in chunks} == {2, 8}  # one live row rides the compacted width, four the full one
-    assert all([(k, v.shape) for k, v in c.counts.items()] == [("moe", (4,)), ("attn", (3,)), ("latent", (2,))] for c in chunks)
+    assert all([(k, v.shape) for k, v in c.counts.items()] == [("moe", (4,)), ("attn", (3,)), ("latent", (2,)), ("kv", (1,))] for c in chunks)
     assert many[0].token_ids == solo[0].token_ids  # the same plan at either width
     counters = fresh.snapshot()["counters"]
     assert counters["moe.assigned_rows"] > 0 and counters["attn.row_blocks"] > 0

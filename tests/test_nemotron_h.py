@@ -324,7 +324,7 @@ def test_the_record_is_the_new_familys(engine):
     eng, _ = engine
     fam = family(eng.cfg)
     assert fam is eng.family and fam.name == "ssd" and fam.module is nh
-    assert [c.name for c in fam.counts] == ["hybrid", "moe", "attn"]
+    assert [c.name for c in fam.counts] == ["hybrid", "moe", "attn", "kv"]
     assert fam.count("hybrid").metrics == nh.HYBRID_STATS and fam.count("moe").metrics[-1] == "moe.local_rows"
     assert fam.n_real == "always" and fam.one_head and fam.pack_rows == 96 and fam.scratch_prefix
     assert fam.cache["state_column"] and set(fam.cache["slot_planes"]["v"]) == {"ssm"}

@@ -17,7 +17,11 @@ first: an admission's covered blocks leave the pool in ONE gather on (plane,
 block) (``llama.gather_row_blocks``) where a ``dynamic_slice`` of the whole
 plane stood before the gather — and these engines attend through XLA, so
 their one-row 1 + 8 block runs that branch too (the chip's goes through the
-block kernel)."""
+block kernel). ISSUE 60 re-derived all eleven (each held by the driver's run
+of its parent's tree, adb1d6a): the K/V write is ``llama.write_rows`` — the
+same pair of scatters where a forward is told no real positions (K's and V's
+issued together), a walk over tiles of the real rows in the hybrid's grouped
+admission, which is told them."""
 
 import functools
 import hashlib
@@ -81,17 +85,17 @@ def _sha(text: str) -> str:
 
 
 GROUP_SHA256 = {
-    "dense": "b7cdce3619d59bf1fdd9c6bf3c2a9653b268b3ab86aa7bdbf53e6fb925dd7a15",
-    "routed": "6e7f92cfd707cc485c6f106fa8d4b89d94b7ae8df10f98a40ceea7576f1277bc",
-    "hybrid": "acb9d1c6bbc52cc7157db51550b120329d948f43e10e05b0ac0a4ff48b91a7e9",
-    "share": "04e2351e128feae97bec1ce962c0027b92e8a738024fc5dad0f403c1f1ccf4c5",
+    "dense": "c660f9a40d0d714a91e7beb5617252f950cd9137c1911cacb016d3e1a5201101",
+    "routed": "1b2e18edc494b58ef6cdef0e8e25e8b10911d4439765c76cd53291ef3bbd3002",
+    "hybrid": "51f724bfea7173126b0134b89c889d01b73f97296cba0a426fef800b99a5ceef",
+    "share": "2878bedbdf5f64da0436085de675d8f1e1d78029c07f0c444df92e3707cc9f76",
     "latent": "b34710488aaf21d0cc9a9638ffe07d5deae53fc59bf6853817a490429580eef6",
 }
 BLOCK_SHA256 = {
-    "dense": "daf78869c42d72060244ed54c0cdc0cb47c57940008225a03ba69c5061d01110",
-    "routed": "f106b93f4903e3e79e50b1191524f9e663d8fcdb6b3239bcc47343d598c402e7",
+    "dense": "7c2107aa05c775a5f7582d54891620c03341f58b8cb357d002e6a2cac79bb4dc",
+    "routed": "ba97552f1c33d99614cfe71299bd5a430eff18f61c908edad4c815f2eb17bd25",
     "hybrid": "45fa4e5f3ecf39c2df110c0a00aca72bb35776c91c0c486633e4da5a9125191b",
-    "share": "55d2571d1caf47c0d156ccca20ec77e167c8bb707176117d63ceb6ae8ce3285f",
+    "share": "73d794ebad9363ea10114239e70c980dd103084a89a31b8d87f5e761f22aac55",
     "latent": "d2c4f887cbe6ee5f91e5f466aacd017b445e6dde8761f7d5140c2129f7e6b214",
 }
 ONE_ROW_SHA256 = {"latent": "7cec90a9b8a7060bfa96be805a21d6bfc0fc64cafda675e51fe6354f00462cf5"}

@@ -296,7 +296,7 @@ def test_the_record_is_the_new_familys(engine):
     eng, _ = engine
     fam = family(eng.cfg)
     assert fam is eng.family and fam.name == "gdn" and fam.module is oh
-    assert [c.name for c in fam.counts] == ["hybrid", "attn"]
+    assert [c.name for c in fam.counts] == ["hybrid", "attn", "kv"]
     assert fam.count("hybrid").metrics == oh.HYBRID_STATS
     assert fam.n_real == "always" and fam.one_head and fam.pack_rows == 96 and fam.scratch_prefix
     assert fam.cache["state_column"] and set(fam.cache["slot_planes"]["v"]) == {"gdn"}

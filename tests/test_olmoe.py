@@ -392,10 +392,12 @@ def _chunk_spy(monkeypatch) -> tuple[list[int], list[str]]:
 # call holds the packing of the riders' real positions, in both variants.
 # ISSUE 58 did (both held on its parent's tree, 40ebd89): through XLA the T = 1
 # body's covered blocks leave the pool in one gather on (plane, block)
-# (``llama.gather_row_blocks``), no slice of the plane before it.
+# (``llama.gather_row_blocks``), no slice of the plane before it. ISSUE 60 did (both
+# held by the driver's run of its parent's tree, adb1d6a): one more carry and output
+# in both variants (``kv.rows_written``), and the K/V write is ``llama.write_rows``.
 FULL_WIDTH_SHA256 = {
-    "dense": "e0685f007a7bc3d48968cc202042e582c1dac1e1098b97e05380d22353636db7",
-    "routed": "de51d0bf81d383c49ff26fb18409de067d82c227ecbdbc602a78f4892e560af6",
+    "dense": "c12030c20f145e09c325f2bdef769349df23de80fab822bb2fe092b1c3bd0bc3",
+    "routed": "b7b1e3784b480cfe1dfe62ff6d5fcda072976ea9c92c6cb5bf9cf716e6b0edca",
 }
 
 
@@ -405,8 +407,8 @@ def test_the_fence_around_the_dense_path(model, monkeypatch):
     block (ISSUE 28: PR 27 was refused for a slower DENSE cell). A dense
     ``test-tiny`` engine behind the batcher: no ``moe.*`` metric of any kind
     is registered, every chunk's record has ``moe`` None, the chunk program returns
-    17 values (16 until ISSUE 31 added the attention row-block counts to both
-    variants), and the tokens are those of the un-paged
+    18 values (16 until ISSUE 31 added the attention row-block counts to both
+    variants, 17 until ISSUE 60 added the rows the K/V write moved), and the tokens are those of the un-paged
     ``DecodeEngine`` (whose loop this block never touched). The routed
     variant of the same program returns one more, and the four counters
     rise. Since ISSUE 29 the fence holds the compacted width out too: at the
@@ -446,11 +448,11 @@ def test_the_fence_around_the_dense_path(model, monkeypatch):
     moe_names = sorted(k for part in snap.values() if isinstance(part, dict) for k in part
                        if str(k).startswith("moe."))
     if model == "routed":
-        assert set(arity) == {18} and all(c.counts["moe"].shape == (4,) for c in chunks)
+        assert set(arity) == {19} and all(c.counts["moe"].shape == (4,) for c in chunks)
         assert moe_names == sorted(f"moe.{n}" for n in llama.MOE_STATS)
         assert all(snap["counters"][k] > 0 for k in moe_names)
         return
-    assert set(arity) == {17} and moe_names == []
+    assert set(arity) == {18} and moe_names == []
     assert all("moe" not in c.counts for c in chunks)
     assert {k for k in vars(eng) if k.startswith("_last_")} <= {"_last_prefill_compute_ms", "_last_cached_tokens"}
     plain = DecodeEngine(preset="test-tiny", max_len=1536, prefill_buckets=(128, 256, 1024))

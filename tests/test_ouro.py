@@ -106,7 +106,7 @@ def test_the_configuration_keeps_every_published_width_and_reduces_nothing():
     assert (full.ut_steps, full.sandwich_norm, full.exit_threshold, full.rope_theta) == (4, True, 1.0, 1e6)
     fam = family(full)
     assert fam.name == "plain" and fam.module is llama and fam.scratch_prefix and fam.one_head
-    assert [c.name for c in fam.counts] == ["attn", "loop"]
+    assert [c.name for c in fam.counts] == ["attn", "loop", "kv"]
     assert fam.count("loop").metrics == ("loop.passes", "loop.exit_rows", "loop.exit_last")
     # 192 planes, 1.5 MiB a token, 201.3 MB a block; the weights' layers are counted ONCE
     assert fam.cache["planes"]["k"]["kv"] == (192, 16, 128) and fam.token_bytes == 1_572_864

@@ -55,7 +55,7 @@ def test_the_chunk_program_compiles_at_published_widths(chip, monkeypatch, width
     eng, s, params = _engine(monkeypatch)
     B, R, cfg = eng.batch_slots, eng.compact_rows, eng.cfg
     assert (B, R) == (8, 2) and eng.family.name == "plain" and eng.admit_rows == 0
-    assert [c.name for c in eng.family.counts] == ["attn", "loop"]
+    assert [c.name for c in eng.family.counts] == ["attn", "loop", "kv"]
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     shapes = lambda tree: jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), tree)
     k_pool, v_pool = paged.build_pools(eng._cache_spec, s["pool_blocks"], eng.block_size, B, zeros=S)
@@ -77,6 +77,12 @@ def test_the_chunk_program_compiles_at_published_widths(chip, monkeypatch, width
     assert mem.temp_size_in_bytes < 1 << 28
     # weights 2.77 GB + the pool 9.46 GB, donated: arguments and outputs alias
     assert 12.2e9 < mem.argument_size_in_bytes < 12.35e9 and mem.alias_size_in_bytes > 9.4e9
+    # of the pool's or a plane's shape: the K/V write's in-place scatters alone — the walk
+    # over tiles of the real rows (ISSUE 60) carries the pools through its ``while`` in place
+    from tools.kv_write_check import shaped_ops
+
+    found = shaped_ops(text, k_pool.shape)
+    assert found and set(found) <= {"fusion", "scatter"}, found
 
 
 @pytest.mark.parametrize("rows,bucket,blocks,fresh", [(1, 64, 12, False), (1, 128, 12, False),

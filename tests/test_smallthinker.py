@@ -95,7 +95,7 @@ def test_the_configuration_keeps_the_published_widths_and_names_its_cut():
     assert llama.bound_window(full) == 4096  # max_len 8832 passes it: the window binds
     fam = family(full)
     assert fam.name == "plain" and fam.scratch_prefix and fam.one_head and fam.block_real
-    assert [c.name for c in fam.counts] == ["moe", "attn", "window"]
+    assert [c.name for c in fam.counts] == ["moe", "attn", "window", "kv"]
     assert {"mesh", "kv_quant", "dense_cache"} <= set(fam.refuses)
     # the arithmetic of the cut (benchmark/lib/peaks_smallthinker.py has the rest)
     assert llama.param_count(full) == 24 * (20_971_520 + 377_487_360 + 2560 * 64 + 2 * 2560) + 2 * 151936 * 2560 + 2560

@@ -84,7 +84,7 @@ def test_the_chunk_program_compiles_at_published_widths(chip, monkeypatch, width
     eng, s, params = _engine(monkeypatch)
     B, R, cfg = eng.batch_slots, eng.compact_rows, eng.cfg
     assert (B, R) == (32, 8) and eng.family.name == "plain" and eng.ffn_pack_rows == 96
-    assert [c.name for c in eng.family.counts] == ["moe", "attn", "window"]
+    assert [c.name for c in eng.family.counts] == ["moe", "attn", "window", "kv"]
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     shapes = lambda tree: jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype), tree)
     k_pool, v_pool = paged.build_pools(eng._cache_spec, s["pool_blocks"], eng.block_size, B, zeros=S)

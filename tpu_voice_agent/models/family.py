@@ -48,13 +48,16 @@ class Count(NamedTuple):
 
 
 ATTN = Count("attn", "attn_stats", tuple(f"attn.{n}" for n in ATTN_STATS))
+# the rows a forward's cache writes moved (``llama.write_rows``), behind a family's own counts
+KV = Count("kv", "kv_stats", tuple(f"kv.{n}" for n in llama.KV_STATS))
 # a program whose position-wise regions may run packed, LAST (``ffn_pack`` = the rows)
 FFN = Count("ffn", "ffn_pack", tuple(f"ffn.{n}" for n in llama.FFN_STATS))
 
 
-def _counts(cfg, latent: tuple[str, ...] = ()) -> tuple[Count, ...]:
+def _counts(cfg, latent: tuple[str, ...] = (), kv: bool = True) -> tuple[Count, ...]:
     """A LlamaConfig's, in the order its chunk program carries them: the
-    routed layers' expert rows, the attention row-blocks, a latent cache's reads."""
+    routed layers' expert rows, the attention row-blocks, a latent cache's reads,
+    the rows its cache writes moved (``kv``: its module writes through ``write_rows``)."""
     routed = (Count("moe", "moe_stats", tuple(f"moe.{n}" for n in llama.moe_stat_names(cfg))),)
     # K/V layers behind a window that BINDS at this ``max_seq_len``: what their walks read
     windowed = not cfg.kv_lora_rank and llama.bound_window(cfg) is not None
@@ -64,7 +67,7 @@ def _counts(cfg, latent: tuple[str, ...] = ()) -> tuple[Count, ...]:
         if windowed else ()) + (
         # layers that run more than once: the passes, and where the exit gate's selection fell
         (Count("loop", "loop_stats", tuple(f"loop.{n}" for n in llama.LOOP_STATS)),)
-        if cfg.ut_steps > 1 else ())
+        if cfg.ut_steps > 1 else ()) + ((KV,) if kv else ())
 
 
 @dataclass(frozen=True)
@@ -196,21 +199,21 @@ def family(cfg) -> Family:
     """The record of ``cfg``'s family."""
     if isinstance(cfg, olmo_hybrid.OlmoHybridConfig):  # a delta-rule matrix state beside K/V
         hybrid = Count("hybrid", "hybrid_stats", olmo_hybrid.HYBRID_STATS)
-        return Family("gdn", olmo_hybrid, olmo_hybrid.cache_spec(cfg), (hybrid, ATTN),
+        return Family("gdn", olmo_hybrid, olmo_hybrid.cache_spec(cfg), (hybrid, ATTN, KV),
                       sambay.StateNotCarried, _GDN_REFUSES, n_real="always", one_head=True)
     if isinstance(cfg, nemotron_h.NemotronHConfig):  # a Mamba-2 state beside K/V, latent experts
         hybrid = Count("hybrid", "hybrid_stats", nemotron_h.HYBRID_STATS)
         routed = Count("moe", "moe_stats", tuple(f"moe.{n}" for n in llama.moe_stat_names(cfg)))
-        return Family("ssd", nemotron_h, nemotron_h.cache_spec(cfg), (hybrid, routed, ATTN),
+        return Family("ssd", nemotron_h, nemotron_h.cache_spec(cfg), (hybrid, routed, ATTN, KV),
                       sambay.StateNotCarried, _SSD_REFUSES, n_real="always", one_head=True)
     if not isinstance(cfg, llama.LlamaConfig):  # a ``sambay.SambaYConfig``: K/V and a recurrent state
         hybrid = Count("hybrid", "hybrid_stats", sambay.HYBRID_STATS)
-        return Family("hybrid", sambay, sambay.cache_spec(cfg), (hybrid, ATTN),
+        return Family("hybrid", sambay, sambay.cache_spec(cfg), (hybrid, ATTN, KV),
                       sambay.StateNotCarried, _HYBRID_REFUSES, n_real="always",
                       one_head=True, pack_rows=0)
     if cfg.index_topk:  # learned sparse attention over a latent cache, planes by layer kind
         return Family("sparse", dots3, dots3.cache_spec(cfg),
-                      _counts(cfg, mla.LATENT_STATS + dots3.SPARSE_STATS), mla.LatentCacheOnly,
+                      _counts(cfg, mla.LATENT_STATS + dots3.SPARSE_STATS, kv=False), mla.LatentCacheOnly,
                       _LATENT_REFUSES, n_real="admit", one_head=True, prefix_whole_blocks=True)
     if cfg.kv_lora_rank:  # a latent and ONE rotated key a token a layer
         return Family("latent", mla, mla.cache_spec(cfg), _counts(cfg, mla.LATENT_STATS),

@@ -968,6 +968,13 @@ def _swiglu(p, h, names, cs=_identity_cs, act="silu"):
 FFN_STATS = ("forwards_packed", "rows")
 
 
+# what every forward that writes K/V (or a latent cache's two planes) counts, published as
+# ``kv.<name>``: the rows ``write_rows`` moved into each of the two pools, summed over the
+# cache layers — ``B * T`` a layer where every position is written, the walk's tiles where
+# the forward is told its rows' real positions
+KV_STATS = ("rows_written",)
+
+
 class FfnPack(NamedTuple):
     """The real positions of a (B, T) block, packed: built ONCE a forward
     from ``n_real`` (row b's real positions are ``t < n_real[b]``), used by
@@ -1071,6 +1078,63 @@ def row_tiles(n_real: jax.Array, T: int, tile: int) -> RowTiles:
     last = jnp.maximum(n_pos - 1, 0)
     idx = order.idx[jnp.minimum(jnp.arange(P, dtype=jnp.int32), last)]
     return RowTiles(idx, order.inv, jnp.maximum(-(-n_pos // tile), 1), last, min(tile, P))
+
+
+def write_tile(rows: int) -> int:
+    """The rows a step of ``write_rows``' walk moves, of a block of ``rows`` = B * T
+    positions: a static function of the shape. A fast-forward block holds 1.4-1.7 real
+    positions of 9 a live row, so a sixth of the block (48 of 288, 16 of 72) holds a
+    forward's real rows in most forwards and the chunk's first forward walks a few. A
+    step costs ~1.3 us and ~0.18 us a row (a gather and a scatter, K and V, at 8 x 128)
+    where the scatter of all 288 rows costs 51: one tile 9.7 us, all six 57 (my chip
+    runs, PR 60; a tile that does not divide the block walks its last rows twice: 64 of
+    288 costs 61 at six tiles' work in five). Where the tile is the whole block nothing
+    walks."""
+    return min(rows, max(16, -(-rows // 48) * 8))
+
+
+def write_walk(n_real: jax.Array | None, T: int, where: tuple) -> tuple[RowTiles | None, tuple]:
+    """Once a forward, for ``write_rows``: the (B, T) block's real positions (row b's
+    are ``t < n_real[b]``; a row that is not live has none) in tiles, and ``where`` —
+    the pool indices of every position, (B, T) each — in the tiles' packed order: a
+    slot behind the last real one names that one's index again. A forward that is
+    told no real positions, and a block no larger than a tile, keep the scatter:
+    (None, ``where``)."""
+    if n_real is None or write_tile(n_real.shape[0] * T) >= n_real.shape[0] * T:
+        return None, where
+    tiles = row_tiles(n_real, T, write_tile(n_real.shape[0] * T))
+    return tiles, tuple(w.reshape(-1)[tiles.idx] for w in where)
+
+
+def rows_written(tiles: RowTiles | None, positions: jax.Array) -> jax.Array:
+    """``kv.rows_written`` of ONE cache layer: the rows ``write_rows`` moves into
+    each of its two pools — the walk's tiles, or every position of the block."""
+    return jnp.int32(positions.size) if tiles is None else tiles.n_tiles * tiles.tile
+
+
+def write_rows(pool_a: jax.Array, pool_b: jax.Array, plane, a: jax.Array, b: jax.Array,
+               where: tuple, tiles: RowTiles | None = None) -> tuple[jax.Array, jax.Array]:
+    """A layer's cache write: the block's rows ``a`` / ``b`` (B, T, ...) into plane
+    ``plane`` of their pools at ``where`` — the index arrays behind the plane, in the
+    pool's own indexing (``(idx,)`` on a flat view, ``(block, offset)`` on a pool as
+    it is shaped). Without ``tiles`` every position is written, ``where`` (B, T) each:
+    ONE scatter a pool, which costs by the ROW on the chip (~0.1 us: 25-31 us at 288
+    rows, 8 at 72; ledger, PR 59), whatever the row holds. With ``tiles``
+    (``write_walk``: ``where`` in their packed order) the write walks the tiles that
+    hold real positions and moves nothing else: a position behind a row's real ones
+    only wrote the last real one's index again, a row that is not live only its
+    trash slot. The two pools are the loop's carry — a ``while``'s carry aliases in
+    place; a conditional around a pool copies it out."""
+    if tiles is None:
+        return pool_a.at[(plane, *where)].set(a), pool_b.at[(plane, *where)].set(b)
+    a, b = (r.reshape(-1, *r.shape[2:]) for r in (a, b))
+
+    def step(i, pools):
+        src = tiles.cut(tiles.idx, i)
+        at = (plane, *(tiles.cut(w, i) for w in where))
+        return tuple(p.at[at].set(r[src]) for p, r in zip(pools, (a, b)))
+
+    return jax.lax.fori_loop(0, tiles.n_tiles, step, (pool_a, pool_b))
 
 
 def packed_ffn(ffn, h: jax.Array, pack: FfnPack | None):
@@ -1353,7 +1417,7 @@ def forward(
 @partial(jax.jit, static_argnames=("cfg", "rules", "attn_impl", "fresh_block",
                                    "gather_blocks", "kv_quant", "moe_stats",
                                    "attn_stats", "hybrid_stats", "ffn_pack", "latent_stats",
-                                   "window_stats", "loop_stats"),
+                                   "window_stats", "loop_stats", "kv_stats"),
          donate_argnames=("k_pool", "v_pool", "k_scale", "v_scale"))
 def forward_paged(
     params: dict,
@@ -1414,6 +1478,7 @@ def forward_paged(
     # ``WINDOW_STATS``, (3,) int32, after the attention row-blocks
     loop_stats: bool = False,  # a model whose layers run more than once only (``ut_steps``):
     # also ``LOOP_STATS``, (3,) int32, behind those
+    kv_stats: bool = False,  # also ``KV_STATS``, (1,) int32, behind those (before ``FFN_STATS``)
 ):
     """The paged twin of ``forward`` (parity-tested): sequences own
     non-contiguous pool blocks via per-row block tables (SURVEY.md §7
@@ -1450,7 +1515,7 @@ def forward_paged(
         fam.refuse("ffn_pack", NotImplementedError)
     asked = {"moe_stats": moe_stats, "attn_stats": attn_stats, "hybrid_stats": hybrid_stats,
              "latent_stats": latent_stats, "window_stats": window_stats,
-             "loop_stats": loop_stats}
+             "loop_stats": loop_stats, "kv_stats": kv_stats}
     counted = {c.keyword for c in fam.counts}
     if any(on and kw not in counted for kw, on in asked.items()):
         raise ValueError(f"a {fam.name} model's forward counts {sorted(counted)}: asked {asked}")
@@ -1524,10 +1589,17 @@ def forward_paged(
                 split = common_block_split(block_tables, positions, write_mask, bs, n_real=n_real)
 
     # rows of different dp groups may not share a packed axis: not under a mesh
+    live = None  # the real positions a row holds, told off a mesh: none of a row that is not live
+    if n_real is not None and rules is None:
+        live = n_real if write_mask is None else jnp.where(write_mask, n_real, 0)
+    # where a position's K and V land, in the pool's indexing at the site; told the real
+    # positions, the write walks tiles of them (``write_rows``): their indices, once a forward
+    write_at = (flat_idx // bs, flat_idx % bs) if cfg.layer_types else (flat_idx,)
+    with jax.named_scope("layer/kv_write"):
+        write_tiles, write_at = write_walk(live if kv_quant is None else None, T, write_at)
     pack = None
-    if ffn_pack and n_real is not None and rules is None and B * T > ffn_pack:
+    if ffn_pack and live is not None and B * T > ffn_pack:
         with jax.named_scope("layer/ffn/pack"):
-            live = n_real if write_mask is None else jnp.where(write_mask, n_real, 0)
             pack = ffn_pack_index(live, T, ffn_pack)
         # once a forward: the packed slots' angles, and the residual — which
         # then STAYS packed from layer to layer where the positions fit (the
@@ -1618,16 +1690,15 @@ def forward_paged(
         with jax.named_scope("layer/kv_write"):
             kp_flat = kp.reshape(L, N * bs, cfg.n_kv_heads, hdp)
             vp_flat = vp.reshape(L, N * bs, cfg.n_kv_heads, hdp)
-            if cfg.layer_types and kv_quant is None:
-                # the pool indexed AS IT IS SHAPED, (block, offset): through the
-                # flat view XLA relaid the whole pool out around a one-row
-                # scatter in the unrolled layers — a 16x padded copy, 6.25 GB
-                # at these widths (my chip run, PR 34; PR 32 met the same)
-                kp = kp.at[plane, flat_idx // bs, flat_idx % bs].set(k.astype(kp.dtype))
-                vp = vp.at[plane, flat_idx // bs, flat_idx % bs].set(v.astype(vp.dtype))
-            elif kv_quant is None:
-                kp = kp_flat.at[plane, flat_idx].set(k.astype(kp.dtype)).reshape(kp.shape)
-                vp = vp_flat.at[plane, flat_idx].set(v.astype(vp.dtype)).reshape(vp.shape)
+            if kv_quant is None:
+                # the unrolled layers index the pool AS IT IS SHAPED, (block, offset):
+                # through the flat view XLA relaid the whole pool out around a one-row
+                # scatter there — a 16x padded copy, 6.25 GB at these widths (my chip
+                # run, PR 34; PR 32 met the same)
+                pools = (kp, vp) if cfg.layer_types else (kp_flat, vp_flat)
+                pools = write_rows(*pools, plane, k.astype(kp.dtype), v.astype(vp.dtype),
+                                   write_at, write_tiles)
+                kp, vp = (pl.reshape(kp.shape) for pl in pools)
             else:
                 from ..ops.kvquant import quantize_kv
 
@@ -1847,6 +1918,8 @@ def forward_paged(
             valid = valid & (jnp.arange(T, dtype=jnp.int32)[None, :] < n_real[:, None])
         extra += (jnp.stack([books.passes, jnp.sum(valid),
                              jnp.sum(valid & (books.step == cfg.ut_steps - 1))]).astype(jnp.int32),)
+    if kv_stats:
+        extra += (cfg.ut_steps * cfg.n_layers * rows_written(write_tiles, positions)[None],)
     if pack is not None:
         extra += (pack.stats,)
     return (logits, k_pool, v_pool, k_scale, v_scale, *extra)

@@ -44,7 +44,8 @@ import jax.numpy as jnp
 
 from .llama import (MAX_BLOCK_DECODE_T, LlamaConfig, _attn_stats, _ffn, _qe, _scan_and_whole,
                     _swiglu, _EXPERT_LEAVES, apply_rope_interleaved, cache_planes, ffn_pack_index,
-                    gather_row_blocks, packed_ffn, rms_norm, rope_tables)
+                    gather_row_blocks, packed_ffn, rms_norm, rope_tables, rows_written, write_rows,
+                    write_walk)
 
 F32 = jnp.float32
 
@@ -177,13 +178,14 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, c_pool, r_pool, b
                   attn_impl: str = "pallas", write_mask=None, trash_idx=None,
                   fresh_block: bool = False, gather_blocks: int | None = None, n_real=None,
                   logit_pos=None, moe_stats: bool = False, attn_stats: bool = False,
-                  latent_stats: bool = False, ffn_pack: int = 0):
+                  latent_stats: bool = False, kv_stats: bool = False, ffn_pack: int = 0):
     """``llama.forward_paged`` for a latent model: ``c_pool`` (L, N, bs, C)
     and ``r_pool`` (L, N, bs, dr) in the places of ``k_pool`` / ``v_pool``.
     -> (logits, c_pool, r_pool, None, None), then with ``moe_stats`` the
     routed layers' ``llama.MOE_STATS``, with ``attn_stats`` ``ops.ATTN_STATS``
     (a LAYER's read, as every ``LlamaConfig``'s), with ``latent_stats``
-    ``LATENT_STATS`` over all layers, with a packed MLP ``llama.FFN_STATS``.
+    ``LATENT_STATS`` over all layers, with ``kv_stats`` ``llama.KV_STATS`` (the latent and
+    the rotated key are its two pools), with a packed MLP ``llama.FFN_STATS``.
 
     Attention: T <= ``MAX_BLOCK_DECODE_T`` under "pallas" — a decode step, a
     fast-forward block — goes through ``ops.paged_latent_attention`` (T = 1
@@ -213,6 +215,12 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, c_pool, r_pool, b
         park = jnp.zeros((B,), jnp.int32) if trash_idx is None else trash_idx.astype(jnp.int32)
         blk = jnp.where(write_mask[:, None], blk, park[:, None] // bs)
         off = jnp.where(write_mask[:, None], off, park[:, None] % bs)
+    # told its rows' real positions, the write walks tiles of them (``llama.write_rows``)
+    live = None
+    if n_real is not None:
+        live = n_real if write_mask is None else jnp.where(write_mask, n_real, 0)
+    with jax.named_scope("layer/kv_write"):
+        write_tiles, write_at = write_walk(live, T, (blk, off))
 
     block_decode = attn_impl == "pallas" and not fresh_block and T <= MAX_BLOCK_DECODE_T
     split = None
@@ -224,17 +232,16 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, c_pool, r_pool, b
                                       params["embed"].dtype.itemsize, n_real)
 
     pack = None
-    if ffn_pack and n_real is not None and B * T > ffn_pack:
+    if ffn_pack and live is not None and B * T > ffn_pack:
         with jax.named_scope("layer/ffn/pack"):
-            live = n_real if write_mask is None else jnp.where(write_mask, n_real, 0)
             pack = ffn_pack_index(live, T, ffn_pack)
 
     def layer(carry, p, li, ffn):
         x, cp, rp = carry
         q_c, q_r, c, r = latent_qkv(p, x, cfg, cos, sin)
         with jax.named_scope("layer/kv_write"):
-            cp = cp.at[li, blk, off].set(c.astype(cp.dtype))
-            rp = rp.at[li, blk, off].set(r.astype(rp.dtype))
+            cp, rp = write_rows(cp, rp, li, c.astype(cp.dtype), r.astype(rp.dtype),
+                                write_at, write_tiles)
         with jax.named_scope("layer/attn"):
             if block_decode:
                 with jax.named_scope("latent"):
@@ -300,6 +307,8 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, c_pool, r_pool, b
             n_read = (sum(s.n_items for s in split) if split is not None else counts[1])
             extra += (jnp.stack([cfg.n_layers * n_read * bs,
                                  cfg.n_layers * jnp.sum(alive) * T * H]).astype(jnp.int32),)
+    if kv_stats:
+        extra += (cfg.n_layers * rows_written(write_tiles, positions)[None],)
     if pack is not None:
         extra += (pack.stats,)
     return (logits, c_pool, r_pool, None, None, *extra)

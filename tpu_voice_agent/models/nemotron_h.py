@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 
 from .llama import (EXPERT_ACTS, MAX_BLOCK_DECODE_T, _moe_ffn, _qe, cache_planes, gather_row_blocks,
-                    moe_stat_names, quantize_leaf, rms_norm, row_tiles)
+                    moe_stat_names, quantize_leaf, rms_norm, row_tiles, rows_written, write_rows, write_walk)
 from .sambay import _NO_WINDOW, StateNotCarried, _attend  # noqa: F401  (the family's error class)
 
 F32 = jnp.float32
@@ -361,14 +361,15 @@ def forward_paged(params, cfg: NemotronHConfig, tokens, positions, k_pool, v_poo
                   attn_impl: str = "pallas", write_mask=None, trash_idx=None,
                   fresh_block: bool = False, gather_blocks: int | None = None, n_real=None,
                   logit_pos=None, ffn_pack: int = 0, hybrid_stats: bool = False,
-                  moe_stats: bool = False, attn_stats: bool = False, fault: str | None = None):
+                  moe_stats: bool = False, attn_stats: bool = False, kv_stats: bool = False,
+                  fault: str | None = None):
     """``models.llama.forward_paged`` for this model (``fresh_block`` is a
     promise this forward does not need): ``k_pool`` / ``v_pool`` the pytrees
     of the module docstring, ``block_tables`` (B, max_blocks + 1) with the
     state index last; ``logit_pos`` (B,): the head on that one position a row.
     -> (logits, k_pool, v_pool, None, None), then in the family's order:
     ``HYBRID_STATS`` (3,), the routed layers' ``llama.MOE_SHARE_STATS`` (or
-    ``MOE_STATS``), ``ops.ATTN_STATS``, and LAST with ``ffn_pack``
+    ``MOE_STATS``), ``ops.ATTN_STATS``, ``llama.KV_STATS``, and LAST with ``ffn_pack``
     ``llama.FFN_STATS``. ``fault`` PLANTS one (``FAULTS``); None everywhere else."""
     from ..ops import common_block_split, paged_block_attention
 
@@ -380,7 +381,8 @@ def forward_paged(params, cfg: NemotronHConfig, tokens, positions, k_pool, v_poo
     tables, sidx = block_tables[:, :-1].astype(jnp.int32), block_tables[:, -1].astype(jnp.int32)
     M = tables.shape[1]
     live = jnp.ones((B,), bool) if write_mask is None else write_mask
-    n_real = jnp.where(live, T if n_real is None else n_real, 0).astype(jnp.int32)
+    told = n_real is not None
+    n_real = jnp.where(live, n_real if told else T, 0).astype(jnp.int32)
     real = jnp.arange(T)[None, :] < n_real[:, None]
     nb = gather_blocks if gather_blocks is not None else M
     hd, nq, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
@@ -396,6 +398,9 @@ def forward_paged(params, cfg: NemotronHConfig, tokens, positions, k_pool, v_poo
     park = jnp.zeros((B,), jnp.int32) if trash_idx is None else trash_idx.astype(jnp.int32)
     w_blk = jnp.where(real, blk, park[:, None] // bs)
     w_off = jnp.where(real, positions % bs, park[:, None] % bs)
+    # told its rows' real positions, the write walks tiles of them (``llama.write_rows``)
+    with jax.named_scope("layer/kv_write"):
+        write_tiles, write_at = write_walk(n_real if told else None, T, (w_blk, w_off))
     split = None
     if block_decode and cfg.count("*"):
         with jax.named_scope("layer/attn/split"):
@@ -444,8 +449,7 @@ def forward_paged(params, cfg: NemotronHConfig, tokens, positions, k_pool, v_poo
             k = _qe("btd,dh->bth", u, p["wk"]).astype(kp.dtype).reshape(B, T, nkv, hd)
             v = _qe("btd,dh->bth", u, p["wv"]).astype(vp.dtype).reshape(B, T, nkv, hd)
         with jax.named_scope("layer/kv_write"):
-            kp = kp.at[ai, w_blk, w_off].set(k)
-            vp = vp.at[ai, w_blk, w_off].set(v)
+            kp, vp = write_rows(kp, vp, ai, k, v, write_at, write_tiles)
         with jax.named_scope("layer/attn/full"):
             if block_decode:
                 a = paged_block_attention(q, kp, vp, tables, positions, ai, live, split, None,
@@ -496,6 +500,8 @@ def forward_paged(params, cfg: NemotronHConfig, tokens, positions, k_pool, v_poo
         held = jnp.sum(jnp.where(live, jnp.max(positions, axis=1) // bs + 1, 0))
         common, handed = split.counts[::2] if split is not None else (jnp.int32(0),) * 2
         extra += (jnp.stack([na * common, na * held, na * handed]).astype(jnp.int32),)
+    if kv_stats:
+        extra += (na * rows_written(write_tiles, positions)[None],)
     if ffn_pack:
         extra += (rows.stats,)
     return (logits, {"kv": kp, "conv": conv}, {"kv": vp, "ssm": ssm}, None, None, *extra)

@@ -69,7 +69,7 @@ import jax.numpy as jnp
 
 from ..ops.gated_delta import plane_shape
 from .llama import (MAX_BLOCK_DECODE_T, _qe, _swiglu, cache_planes, ffn_pack_index, gather_row_blocks,
-                    quantize_leaf, rms_norm)
+                    quantize_leaf, rms_norm, rows_written, write_rows, write_walk)
 from .sambay import _NO_WINDOW, StateNotCarried, _attend  # noqa: F401  (the family's error class)
 
 F32 = jnp.float32
@@ -310,13 +310,13 @@ def forward_paged(params, cfg: OlmoHybridConfig, tokens, positions, k_pool, v_po
                   attn_impl: str = "pallas", write_mask=None, trash_idx=None,
                   fresh_block: bool = False, gather_blocks: int | None = None, n_real=None,
                   logit_pos=None, ffn_pack: int = 0, hybrid_stats: bool = False,
-                  attn_stats: bool = False, fault: str | None = None):
+                  attn_stats: bool = False, kv_stats: bool = False, fault: str | None = None):
     """``models.llama.forward_paged`` for this model (``fresh_block`` is a
     promise this forward does not need): ``k_pool`` / ``v_pool`` the pytrees
     of the module docstring, ``block_tables`` (B, max_blocks + 1) with the
     state index last; ``logit_pos`` (B,): the head on that one position a row.
     -> (logits, k_pool, v_pool, None, None), then in the family's order:
-    ``HYBRID_STATS`` (3,), ``ops.ATTN_STATS``, and LAST with ``ffn_pack``
+    ``HYBRID_STATS`` (3,), ``ops.ATTN_STATS``, ``llama.KV_STATS``, and LAST with ``ffn_pack``
     ``llama.FFN_STATS``. ``fault`` PLANTS one (``FAULTS``); None everywhere else."""
     from ..ops import common_block_split, paged_block_attention
 
@@ -328,7 +328,8 @@ def forward_paged(params, cfg: OlmoHybridConfig, tokens, positions, k_pool, v_po
     tables, sidx = block_tables[:, :-1].astype(jnp.int32), block_tables[:, -1].astype(jnp.int32)
     M = tables.shape[1]
     live = jnp.ones((B,), bool) if write_mask is None else write_mask
-    n_real = jnp.where(live, T if n_real is None else n_real, 0).astype(jnp.int32)
+    told = n_real is not None
+    n_real = jnp.where(live, n_real if told else T, 0).astype(jnp.int32)
     real = jnp.arange(T)[None, :] < n_real[:, None]
     nb = gather_blocks if gather_blocks is not None else M
     hd, nq, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
@@ -346,6 +347,9 @@ def forward_paged(params, cfg: OlmoHybridConfig, tokens, positions, k_pool, v_po
     park = jnp.zeros((B,), jnp.int32) if trash_idx is None else trash_idx.astype(jnp.int32)
     w_blk = jnp.where(real, blk, park[:, None] // bs)
     w_off = jnp.where(real, positions % bs, park[:, None] % bs)
+    # told its rows' real positions, the write walks tiles of them (``llama.write_rows``)
+    with jax.named_scope("layer/kv_write"):
+        write_tiles, write_at = write_walk(n_real if told else None, T, (w_blk, w_off))
     split = None
     if block_decode and cfg.count("F"):
         with jax.named_scope("layer/attn/split"):
@@ -435,8 +439,7 @@ def forward_paged(params, cfg: OlmoHybridConfig, tokens, positions, k_pool, v_po
                 q, k = _rope_half(q, positions), _rope_half(k, positions)
         held = ((0, 0), (0, 0), (0, cfg.kv_heads_held - nkv), (0, 0))  # the planes' heads of zeros
         with jax.named_scope("layer/kv_write"):
-            kp = kp.at[ai, w_blk, w_off].set(jnp.pad(k, held))
-            vp = vp.at[ai, w_blk, w_off].set(jnp.pad(v, held))
+            kp, vp = write_rows(kp, vp, ai, jnp.pad(k, held), jnp.pad(v, held), write_at, write_tiles)
         with jax.named_scope("layer/attn/full"):
             if block_decode:  # a group of query heads of zeros for each K/V head of zeros
                 qh = jnp.pad(q, ((0, 0), (0, 0), (0, (cfg.kv_heads_held - nkv) * (nq // nkv)), (0, 0)))
@@ -480,6 +483,8 @@ def forward_paged(params, cfg: OlmoHybridConfig, tokens, positions, k_pool, v_po
         held = jnp.sum(jnp.where(live, jnp.max(positions, axis=1) // bs + 1, 0))
         common, handed = split.counts[::2] if split is not None else (jnp.int32(0),) * 2
         extra += (jnp.stack([nf_all * common, nf_all * held, nf_all * handed]).astype(jnp.int32),)
+    if kv_stats:
+        extra += (nf_all * rows_written(write_tiles, positions)[None],)
     if ffn_pack:
         extra += ((pack.stats if pack is not None else jnp.asarray([0, P], jnp.int32)),)
     return (logits, {"kv": kp, "tail": tails}, {"kv": vp, "gdn": states}, None, None, *extra)

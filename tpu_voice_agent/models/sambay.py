@@ -64,7 +64,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from .llama import MAX_BLOCK_DECODE_T, _qe, cache_planes, gather_row_blocks, quantize_leaf
+from .llama import (MAX_BLOCK_DECODE_T, _qe, cache_planes, gather_row_blocks, quantize_leaf, rows_written,
+                    write_rows, write_walk)
 
 F32 = jnp.float32
 _NO_WINDOW = 1 << 30
@@ -370,7 +371,7 @@ def forward_paged(params, cfg: SambaYConfig, tokens, positions, k_pool, v_pool, 
                   attn_impl: str = "pallas", write_mask=None, trash_idx=None,
                   fresh_block: bool = False, gather_blocks: int | None = None, n_real=None,
                   logit_pos=None, ffn_pack: int = 0, hybrid_stats: bool = False,
-                  attn_stats: bool = False):
+                  attn_stats: bool = False, kv_stats: bool = False):
     """``models.llama.forward_paged`` for this model (it hands on to the
     family's module, with the keywords every family takes: ``fresh_block`` is
     a promise this forward does not need, ``ffn_pack`` one its family's table
@@ -381,7 +382,8 @@ def forward_paged(params, cfg: SambaYConfig, tokens, positions, k_pool, v_pool, 
     program reads one row of a 1 + W block, and the head is 200 064 wide.
     -> (logits, k_pool, v_pool, None, None), then ``HYBRID_STATS`` (5,) with
     ``hybrid_stats``, then ``ops.ATTN_STATS`` summed over the attention
-    layers with ``attn_stats``.
+    layers with ``attn_stats``, then ``llama.KV_STATS`` over the ``n_front`` K/V
+    planes with ``kv_stats``.
 
     Attention: T <= ``MAX_BLOCK_DECODE_T`` under "pallas" goes through
     ``ops.paged_block_attention`` (T = 1 too), everything else gathers the
@@ -395,7 +397,8 @@ def forward_paged(params, cfg: SambaYConfig, tokens, positions, k_pool, v_pool, 
     tables, sidx = block_tables[:, :-1].astype(jnp.int32), block_tables[:, -1].astype(jnp.int32)
     M = tables.shape[1]
     live = jnp.ones((B,), bool) if write_mask is None else write_mask
-    n_real = jnp.where(live, T if n_real is None else n_real, 0).astype(jnp.int32)
+    told = n_real is not None
+    n_real = jnp.where(live, n_real if told else T, 0).astype(jnp.int32)
     real = jnp.arange(T)[None, :] < n_real[:, None]
     nb = gather_blocks if gather_blocks is not None else M
     scale = cfg.head_dim ** -0.5
@@ -415,6 +418,9 @@ def forward_paged(params, cfg: SambaYConfig, tokens, positions, k_pool, v_pool, 
     park = jnp.zeros((B,), jnp.int32) if trash_idx is None else trash_idx.astype(jnp.int32)
     w_blk = jnp.where(real, blk, park[:, None] // bs)
     w_off = jnp.where(real, positions % bs, park[:, None] % bs)
+    # told its rows' real positions, the write walks tiles of them (``llama.write_rows``)
+    with jax.named_scope("layer/kv_write"):
+        write_tiles, write_at = write_walk(n_real if told else None, T, (w_blk, w_off))
 
     # what the windowed layers walk, and what they would without a window
     qmin, qmax = jnp.min(positions, axis=1), jnp.max(positions, axis=1)
@@ -454,8 +460,7 @@ def forward_paged(params, cfg: SambaYConfig, tokens, positions, k_pool, v_pool, 
             q = pack_q(qkv[..., :nq], cfg)
             k, v = pack_kv(qkv[..., nq:nq + nkv], cfg), pack_kv(qkv[..., nq + nkv:], cfg)
         with jax.named_scope("layer/kv_write"):
-            kp = kp.at[i, w_blk, w_off].set(k.astype(kp.dtype))
-            vp = vp.at[i, w_blk, w_off].set(v.astype(vp.dtype))
+            kp, vp = write_rows(kp, vp, i, k.astype(kp.dtype), v.astype(vp.dtype), write_at, write_tiles)
         with jax.named_scope("layer/attn/window" if windowed else "layer/attn/full"):
             a = attend(q, kp, vp, i, windowed)
         with jax.named_scope("layer/attn_out"):
@@ -521,4 +526,6 @@ def forward_paged(params, cfg: SambaYConfig, tokens, positions, k_pool, v_pool, 
         n_full = 1 + cfg.n_back
         extra += (jnp.stack([n_full * common, n_full * held + full_plane * walked,
                              n_full * handed + full_plane * win_handed]).astype(jnp.int32),)
+    if kv_stats:
+        extra += (cfg.n_front * rows_written(write_tiles, positions)[None],)
     return (logits, {"kv": kp, "conv": conv}, {"kv": vp, "ssm": ssm}, None, None, *extra)
