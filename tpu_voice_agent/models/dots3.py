@@ -72,6 +72,16 @@ plane where a full layer's r would live apart. All ride ONE block table.
 Parameters: ``attn_full`` / ``attn_swa`` stack the attention leaves by kind,
 ``dense_layers`` / ``layers`` the feed-forward ones and the norms
 (``models.mla``'s).
+
+A selection CARRIED across layers (``glm_moe_dsa``: GLM-5.2, ``LlamaConfig.indexer_types``; no
+gate, no rescale, no sliding layer): a "shared" layer is a full layer WITHOUT an indexer — a kind
+of its own here, ``attn_shared`` its leaves (no W_qI / W_kI / W_w), ``k_pool["shared"]`` its rows
+[c | r] (no index key cached) — that attends S_l[t] = S_f(l)[t], the keys the nearest full layer f
+before it selected. A full layer's tiles hand their ``top_k`` on as they make it: the chosen keys'
+sequence positions and their pool blocks, (P, K) each, in the packed order of the forward's
+positions (``layer/attn/carry``); a shared layer's tiles cut theirs out of that, gather THEIR OWN
+plane's rows at those coordinates and attend them through the same kernel. Nothing else differs:
+one ``latent_qkv``, one indexer path, one select-gather, one kernel call.
 """
 
 from __future__ import annotations
@@ -97,6 +107,9 @@ F32 = jnp.float32
 # could see and the keys they attended, cached positions the sliding layers'
 # window gathers read
 SPARSE_STATS = ("index_keys_scored", "keys_visible", "keys_selected", "window_keys_read")
+# behind them where a selection is carried (``indexer_types``): (real position, layer) pairs whose
+# layer scored and selected, and pairs whose layer attended the set an earlier layer chose
+CARRY_STATS = ("selections_made", "selections_carried")
 INDEX_NORM_EPS = 1e-6
 
 
@@ -111,22 +124,40 @@ class Kind(NamedTuple):
     C: int
     theta: float
     window: int | None
-    indexed: bool
+    indexed: bool  # runs an indexer (and caches an index key)
+    stack: str = "attn_full"  # the kind's attention leaves in the parameter tree
+
+    @property
+    def selected(self) -> bool:
+        """Attends a selected key set (its own indexer's, or one carried to it)."""
+        return self.window is None
+
+
+def layer_kinds(cfg: LlamaConfig) -> tuple[str, ...]:
+    """The kind of every layer: "full" | "sliding", and "shared" where
+    ``indexer_types`` says a full layer runs no indexer."""
+    return tuple("shared" if i == "shared" else t
+                 for t, i in zip(cfg.layer_types, cfg.indexer_types or cfg.layer_types))
 
 
 def kinds(cfg: LlamaConfig) -> dict[str, Kind]:
-    return {"full": Kind(cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim,
-                         cfg.q_lora_rank, cfg.kv_lora_rank, cfg.rope_theta, None, True),
-            "sliding": Kind(cfg.swa_n_heads, cfg.swa_qk_nope_dim, cfg.swa_qk_rope_dim,
-                            cfg.swa_v_head_dim, cfg.swa_q_lora_rank or cfg.q_lora_rank,
-                            cfg.swa_kv_lora_rank, cfg.swa_rope_theta, cfg.sliding_window, False)}
+    """The sizes of the kinds this model has layers of."""
+    full = Kind(cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim,
+                cfg.q_lora_rank, cfg.kv_lora_rank, cfg.rope_theta, None, True)
+    every = {"full": full,
+             "sliding": Kind(cfg.swa_n_heads, cfg.swa_qk_nope_dim, cfg.swa_qk_rope_dim,
+                             cfg.swa_v_head_dim, cfg.swa_q_lora_rank or cfg.q_lora_rank,
+                             cfg.swa_kv_lora_rank, cfg.swa_rope_theta, cfg.sliding_window, False,
+                             "attn_swa"),
+             "shared": full._replace(indexed=False, stack="attn_shared")}
+    return {t: k for t, k in every.items() if t == "full" or t in layer_kinds(cfg)}
 
 
 def layer_plan(cfg: LlamaConfig) -> tuple[tuple[str, int], ...]:
     """(kind, index among the layers of that kind) of every layer."""
-    seen = {"full": 0, "sliding": 0}
+    seen = {"full": 0, "sliding": 0, "shared": 0}
     plan = []
-    for t in cfg.layer_types:
+    for t in layer_kinds(cfg):
         plan.append((t, seen[t]))
         seen[t] += 1
     return tuple(plan)
@@ -135,14 +166,17 @@ def layer_plan(cfg: LlamaConfig) -> tuple[tuple[str, int], ...]:
 def cache_spec(cfg: LlamaConfig) -> dict:
     """What a token holds in the pool, by layer KIND: each pool's planes as
     (layers of the kind, width) — a full layer's ONE row [c | r] and its index
-    key, a sliding layer's c and r apart."""
+    key, a sliding layer's c and r apart, a shared layer's ONE row [c | r] and
+    no index key."""
     k = kinds(cfg)
-    n = {t: cfg.layer_types.count(t) for t in ("full", "sliding")}
+    n = {t: layer_kinds(cfg).count(t) for t in ("full", "sliding", "shared")}
     planes = {"k": {"kv": (n["full"], k["full"].C + k["full"].dr), "idx": (n["full"], cfg.index_head_dim)},
               "v": {}}
     if n["sliding"]:
         planes["k"]["swa"] = (n["sliding"], k["sliding"].C)
         planes["v"]["swa"] = (n["sliding"], k["sliding"].dr)
+    if n["shared"]:
+        planes["k"]["shared"] = (n["shared"], k["shared"].C + k["shared"].dr)
     return cache_planes(planes["k"], planes["v"], by_name=True)
 
 
@@ -203,10 +237,10 @@ def init_params(cfg: LlamaConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
     if n_dense:
         params["dense_layers"] = {**stack(k_dense, n_dense, {
             "w_gate": (d, fd), "w_up": (d, fd), "w_down": (fd, d)}), **norms(n_dense)}
-    for kind, kk in (("full", k_full), ("sliding", k_swa)):
-        L = cfg.layer_types.count(kind)
+    for kind, kk in (("full", k_full), ("sliding", k_swa), ("shared", jax.random.fold_in(k_full, 1))):
+        L = layer_kinds(cfg).count(kind)
         if L:
-            params["attn_" + ("swa" if kind == "sliding" else kind)] = {
+            params[kinds(cfg)[kind].stack] = {
                 **stack(kk, L, attn_shapes(cfg, kind)), **attn_norms(cfg, kind, L, dtype)}
     return params
 
@@ -318,8 +352,8 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
     ``ops.ATTN_STATS``, with ``latent_stats`` ``LATENT_STATS + SPARSE_STATS`` over all
     layers, where the block is walked packed (``ffn_pack`` under its B * T
     positions, with ``n_real``) ``llama.FFN_STATS``. ``fault`` PLANTS one, for
-    the comparison's limit to be set against (``FAULTS``); None everywhere
-    else."""
+    the comparison's limit to be set against (``FAULTS``, ``CARRY_FAULTS``); None
+    everywhere else."""
     from ..ops import sparse_latent as sl
 
     pallas = attn_impl == "pallas"
@@ -327,12 +361,13 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
     twin = sl.gathered_latent_attention_reference
     attend_full = sl.sparse_latent_attention if pallas else sl.sparse_latent_attention_reference
     attend_window = sl.window_latent_attention if pallas else twin
-    if fault is not None and fault not in FAULTS:
-        raise ValueError(f"fault {fault!r}: one of {FAULTS}")
+    if fault is not None and fault not in FAULTS + CARRY_FAULTS:
+        raise ValueError(f"fault {fault!r}: one of {FAULTS + CARRY_FAULTS}")
 
     B, T = tokens.shape
     kvp, ip = k_pool["kv"], k_pool["idx"]
     cps, rps = k_pool.get("swa"), v_pool.get("swa")
+    skv = k_pool.get("shared")
     N, bs = kvp.shape[1], kvp.shape[2]
     ncols = block_tables.shape[1]
     nb = min(gather_blocks, ncols) if gather_blocks is not None else ncols
@@ -345,7 +380,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
 
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
-        rope = {t: rope_tables(positions, k.dr, k.theta) for t, k in kd.items()}
+        rope = {t: rope_tables(positions, k.dr, k.theta) for t, k in kd.items() if t != "shared"}
     blk = jnp.take_along_axis(block_tables, positions // bs, axis=1)
     off = positions % bs
     if write_mask is not None:
@@ -385,28 +420,49 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         # and the blocks that hold their window
         Tq = 8 if T > MAX_BLOCK_DECODE_T and T % 8 == 0 else T
         G = P // Tq
-        pos_g = positions.reshape(G, Tq)
-        tbl_g = jnp.repeat(block_tables, T // Tq, axis=0)
-        window = kd["sliding"].window if fault != "no_window" else None
-        reach = (window - 1) if window else ncols * bs
-        WB = min(-(-(reach + Tq - 1) // bs) + 1, ncols)
-        first = jnp.maximum(jnp.min(pos_g, axis=1) - reach, 0) // bs  # (G,)
-        cols = first[:, None] + jnp.arange(WB, dtype=jnp.int32)[None, :]
-        wblk = jnp.take_along_axis(tbl_g, jnp.minimum(cols, ncols - 1), axis=1)  # (G, WB)
-        kpos_w = jnp.where(cols[:, :, None] < ncols,
-                           cols[:, :, None] * bs + jnp.arange(bs, dtype=jnp.int32), -1)
-        kpos_w = kpos_w.reshape(G, WB * bs)
-        hi_w = jnp.repeat(pos_g, kd["sliding"].H, axis=1)  # (G, Tq * Hs), position-major
-        lo_w = jnp.maximum(hi_w - reach, 0)
+        WB = 0
+        if "sliding" in kd:
+            pos_g = positions.reshape(G, Tq)
+            tbl_g = jnp.repeat(block_tables, T // Tq, axis=0)
+            window = kd["sliding"].window if fault != "no_window" else None
+            reach = (window - 1) if window else ncols * bs
+            WB = min(-(-(reach + Tq - 1) // bs) + 1, ncols)
+            first = jnp.maximum(jnp.min(pos_g, axis=1) - reach, 0) // bs  # (G,)
+            cols = first[:, None] + jnp.arange(WB, dtype=jnp.int32)[None, :]
+            wblk = jnp.take_along_axis(tbl_g, jnp.minimum(cols, ncols - 1), axis=1)  # (G, WB)
+            kpos_w = jnp.where(cols[:, :, None] < ncols,
+                               cols[:, :, None] * bs + jnp.arange(bs, dtype=jnp.int32), -1)
+            kpos_w = kpos_w.reshape(G, WB * bs)
+            hi_w = jnp.repeat(pos_g, kd["sliding"].H, axis=1)  # (G, Tq * Hs), position-major
+            lo_w = jnp.maximum(hi_w - reach, 0)
 
-    def full_attention(li, qc_f, qr_f, qi_f, wi_f, kvp, ip, out):
+    k_sel = kd["full"]
+    scale = (k_sel.dn + k_sel.dr) ** -0.5
+    cut_tile = lambda a, i: jax.lax.dynamic_slice_in_dim(a, i * tile, tile)
+
+    def attend_chosen(i, li, ps, qc_f, qr_f, plane, sel, sblk, out):
+        """Tile ``i`` (positions ``ps``) of a selected layer behind its selection:
+        ONE row a chosen key, [c | r], out of the layer's own plane, and the kernel."""
+        with jax.named_scope("layer/attn/select"):
+            # straight out of the pool (a layer's plane sliced first is an HBM copy a tile behind a
+            # ``while`` that carries the pools)
+            kv_sel = plane[li, sblk, sel % bs]
+        with jax.named_scope("layer/attn/full"):
+            a = attend_full(cut_tile(qc_f, i), cut_tile(qr_f, i), kv_sel, sel,
+                            jnp.zeros((tile, k_sel.H), jnp.int32),
+                            jnp.broadcast_to(ps[:, None], (tile, k_sel.H)), scale=scale)
+        return jax.lax.dynamic_update_slice_in_dim(out, a, i * tile, 0)
+
+    def full_attention(li, qc_f, qr_f, qi_f, wi_f, kvp, ip, out, chosen=None):
         """The full layers' attention over (P, ...) queries in ``idx``'s
-        order, the real positions first, into ``out`` (P, H, C), tile by tile."""
-        k = kd["full"]
-        scale = (k.dn + k.dr) ** -0.5
+        order, the real positions first, into ``out`` (P, H, C), tile by tile.
+        ``chosen`` (a model whose shared layers take this layer's selection):
+        the (P, K) buffers its tiles leave their chosen keys' sequence positions
+        and pool blocks in -> (out, chosen)."""
 
-        def one_tile(i, out):
-            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, i * tile, tile)
+        def one_tile(i, carry):
+            out = carry if chosen is None else carry[0]
+            cut = lambda a: cut_tile(a, i)
             ps, tb = cut(pos_of), cut(tbl_of)
             with jax.named_scope("layer/attn/index"):
                 with jax.named_scope("scores"):
@@ -425,14 +481,25 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
                 # than the rows it names)
                 col = (sel // bs)[:, :, None] == jnp.arange(nb, dtype=jnp.int32)
                 sblk = jnp.sum(jnp.where(col, tb[:, None, :], 0), axis=-1)
-                # ONE row a chosen key, [c | r], straight out of the pool (a layer's plane
-                # sliced first is an HBM copy a tile behind a ``while`` that carries the pools)
-                kv_sel = kvp[li, sblk, sel % bs]
-            with jax.named_scope("layer/attn/full"):
-                a = attend_full(cut(qc_f), cut(qr_f), kv_sel, sel,
-                                jnp.zeros((tile, k.H), jnp.int32),
-                                jnp.broadcast_to(ps[:, None], (tile, k.H)), scale=scale)
-            return jax.lax.dynamic_update_slice_in_dim(out, a, i * tile, 0)
+            out = attend_chosen(i, li, ps, qc_f, qr_f, kvp, sel, sblk, out)
+            if chosen is None:
+                return out
+            with jax.named_scope("layer/attn/carry"):  # handed on to the shared layers behind
+                return out, tuple(jax.lax.dynamic_update_slice_in_dim(buf, v, i * tile, 0)
+                                  for buf, v in zip(carry[1], (sel, sblk)))
+
+        return jax.lax.fori_loop(0, n_tiles, one_tile, out if chosen is None else (out, chosen))
+
+    def shared_attention(li, qc_f, qr_f, plane, chosen, out):
+        """A shared layer's attention: the same tiles over the set the nearest
+        full layer before it left in ``chosen``, gathered out of ITS OWN plane."""
+        if fault == "other_row":  # a position reads its neighbour's selection
+            chosen = tuple(jnp.roll(buf, 1, axis=0) for buf in chosen)
+
+        def one_tile(i, out):
+            with jax.named_scope("layer/attn/carry"):
+                sel, sblk = (cut_tile(buf, i) for buf in chosen)
+            return attend_chosen(i, li, cut_tile(pos_of, i), qc_f, qr_f, plane, sel, sblk, out)
 
         return jax.lax.fori_loop(0, n_tiles, one_tile, out)
 
@@ -474,7 +541,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         ``layer/ffn/pack`` — so that a reader of the region counts it."""
         named = jax.named_scope if scope else (lambda name: nullcontext())
         with named(scope):
-            attn_p = jax.tree.map(lambda a: a[ki], params["attn_full" if k.indexed else "attn_swa"])
+            attn_p = jax.tree.map(lambda a: a[ki], params[k.stack])
         with named("layer/ffn/pack"):
             if L < n_dense:
                 ffn_p = jax.tree.map(lambda a: a[L], params["dense_layers"])
@@ -491,9 +558,12 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         if fault == "no_gate":
             gate = None
         qi, ki, wi = index or (None, None, None)
+        if fault == "no_index_rope" and k.indexed:  # the index key cached as it was before its rotation
+            ki = _rope_head(ki[:, :, None, :], cos, -sin, k.dr)[:, :, 0]
         with jax.named_scope("layer/kv_write"):
-            # a full layer's position writes ONE row [c | r] and its index key, a sliding one's c and r
-            written = (sl.key_row(c, r), ki) if k.indexed else (c, r)
+            # a full layer's position writes ONE row [c | r] and its index key, a shared one's that row
+            # alone, a sliding one's c and r
+            written = (sl.key_row(c, r), ki)[:len(planes)] if k.selected else (c, r)
             planes = tuple(pl.at[li, blk, off].set(v.astype(pl.dtype))
                            for pl, v in zip(planes, written))
         return {"c": q_c, "r": q_r, "gate": gate, "i": qi, "w": wi}, planes
@@ -501,7 +571,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
     def attend_block(k: Kind, li, q_c, q_r, planes):
         """What takes a row's (b, t) block of queries: a sliding layer's
         window, and the planted fault ``no_selection``."""
-        if not k.indexed:
+        if not k.selected:
             return window_attention(li, q_c, q_r, *planes)
         return dense_attention(li, q_c, q_r, planes[0])
 
@@ -509,6 +579,8 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         """A layer behind its attention, position-wise over (b, t, d) ->
         (the new residual, the MLP's routed counts or None)."""
         attn = latent_out(p, a, k, gate, x.dtype)
+        if fault == "short_value":  # a value head as wide as its key: the columns past dn dropped
+            attn = attn.reshape(*attn.shape[:2], k.H, k.dv).at[..., k.dn:].set(0).reshape(attn.shape)
         with jax.named_scope("layer/attn_out"):
             x = x + _qe("bth,hd->btd", attn, p["wo"]).astype(x.dtype)
         with jax.named_scope("layer/ffn"):
@@ -517,22 +589,41 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         with jax.named_scope("layer/ffn"):
             return x + y, st
 
-    pools = {"full": (kvp, ip), "sliding": (cps, rps)}
+    pools = {"full": (kvp, ip), "sliding": (cps, rps), "shared": (skv,)}
+    carries = "shared" in kd  # a full layer's selection is an input of the shared layers behind it
+    # a selected layer attends every key: the fault planted in all of them, or in the shared ones
+    every_key = lambda k: fault == "no_selection" or (fault == "shared_all_keys" and not k.indexed)
 
-    def block_layer(L, kind, ki, x):
-        """A layer over the (B, T) block as it stands."""
+    def selected_attention(k: Kind, li, q, planes, out, chosen):
+        """A selected layer's attention over queries in ``idx``'s order ->
+        (out, the selection it made or took: what the next shared layer takes)."""
+        if not k.indexed:
+            return shared_attention(li, q["c"], q["r"], planes[0], chosen, out), chosen
+        if not carries:
+            return full_attention(li, *(q[n] for n in "criw"), *planes, out), None
+        made = chosen if chosen is not None else tuple(jnp.zeros((P, K), jnp.int32) for _ in range(2))
+        out, made = full_attention(li, *(q[n] for n in "criw"), *planes, out, made)
+        # (planted: every shared layer takes the FIRST full layer's set, not the nearest's)
+        return out, chosen if fault == "first_selection" and chosen is not None else made
+
+    def block_layer(L, kind, ki, x, held):
+        """A layer over the (B, T) block as it stands. ``held``: the selection
+        a full layer hands the shared layers behind it, where the model has any.
+        -> (x, the MLP's routed counts or None, ``held``)."""
         k, li = kd[kind], jnp.int32(ki)
         p, ffn = leaves(L, k, ki)
         q, pools[kind] = front(p, k, li, x, *rope[kind], blk, off, pools[kind])
         with jax.named_scope("layer/attn"):
-            if k.indexed and fault != "no_selection":
-                order_of = lambda a: a.reshape(P, *a.shape[2:])[idx]
-                a = full_attention(li, *(order_of(q[n]) for n in "criw"), *pools[kind],
-                                   jnp.zeros((P, k.H, k.C), x.dtype))
+            if k.selected and not every_key(k):
+                order_of = lambda a: a if a is None else a.reshape(P, *a.shape[2:])[idx]
+                a, chosen = selected_attention(
+                    k, li, {n: order_of(q[n]) for n in "criw"}, pools[kind],
+                    jnp.zeros((P, k.H, k.C), x.dtype), held.get("chosen"))
+                held = {"chosen": chosen}
                 a = a[inv].reshape(B, T, k.H, k.C)
             else:
                 a = attend_block(k, li, q["c"], q["r"], pools[kind])
-        return back(p, ffn, k, x, a, q["gate"])
+        return (*back(p, ffn, k, x, a, q["gate"]), held)
 
     def walked_layer(L, kind, ki, x, held):
         """A layer over the PACKED residual x (P, d): ONE copy of its
@@ -543,11 +634,12 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         the packed arrays, a plane sliced, rows put back — runs under the
         region's own name (``layer/attn_qkv/pack`` and ``/unpack``,
         ``layer/attn_out/pack``, ``layer/ffn/pack`` and ``/unpack``).
-        ``held``: the walks' buffers by slot, a kind's queries and the full
-        layers' output, handed on from layer to layer (none zeroed a layer).
+        ``held``: the walks' buffers by slot, a kind's queries, the selected
+        layers' output and the selection a full layer made, handed on from layer
+        to layer (none zeroed a layer).
         -> (x, the MLP's routed counts or None, ``held``)."""
         k, li = kd[kind], jnp.int32(ki)
-        blockwise = not k.indexed or fault == "no_selection"  # attention takes a row's (t, head) group
+        blockwise = not k.selected or every_key(k)  # attention takes a row's (t, head) group
 
         def front_rows(i, planes):
             p = leaves(L, k, ki, "layer/attn_qkv/pack")[0]
@@ -571,7 +663,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         if kind not in held:
             like = jax.eval_shape(lambda: front_rows(0, pools[kind])[0])
             held = {**held, kind: jax.tree.map(lambda v: jnp.zeros((P, *v.shape[1:]), v.dtype), like)}
-            if k.indexed:
+            if k.selected and "out" not in held:
                 held["out"] = jnp.zeros((P, k.H, k.C), x.dtype)
         with jax.named_scope("layer/front"):  # (the walk's ``while`` itself: a name no reader matches)
             q, pools[kind] = jax.lax.fori_loop(0, rows.n_tiles, front_tile, (held[kind], pools[kind]))
@@ -586,8 +678,8 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
                 # before a walk's tile (of ``ffn_pack``) does: a slot behind the last real one reads
                 # THAT one's output, or its residual — and from the next layer on the latent it
                 # writes to that position's cache index — would be another's
-                a = full_attention(li, *(q[n] for n in "criw"), *pools[kind], held["out"])
-                held = {**held, "out": a}
+                a, chosen = selected_attention(k, li, q, pools[kind], held["out"], held.get("chosen"))
+                held = {**held, "out": a, "chosen": chosen}
                 a_rows = lambda i: a[rows.slots(i)]
 
         def back_tile(i, carry):
@@ -614,17 +706,18 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
             slots = lambda a: a.reshape(P, *a.shape[2:])[rows.idx]
             x, blk, off = slots(x), slots(blk), slots(off)
             rope = {t: tuple(slots(a) for a in cs) for t, cs in rope.items()}
+    if "shared" in kd:  # a full layer's sizes: its angles
+        rope["shared"] = rope["full"]
     stats, held = [], {}
+    a_layer = block_layer if rows is None else walked_layer
     for L, (kind, ki) in enumerate(layer_plan(cfg)):
-        if rows is None:
-            x, st = block_layer(L, kind, ki, x)
-        else:
-            x, st, held = walked_layer(L, kind, ki, x, held)
+        x, st, held = a_layer(L, kind, ki, x, held)
         if st is not None:
             stats.append(st)
 
     kvp, ip = pools["full"]
     cps, rps = pools["sliding"]
+    skv, = pools["shared"]
     if rows is not None:
         with jax.named_scope("layer/out/unpack"):  # once a forward: the positions the head reads
             x = x[rows.inv if logit_pos is None else
@@ -636,24 +729,28 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     with jax.named_scope("lm_head"):
         logits = _qe("btd,dv->btv", x, params["lm_head"])
-    k_pool = {**k_pool, "kv": kvp, "idx": ip, **({} if cps is None else {"swa": cps})}
+    k_pool = {**k_pool, "kv": kvp, "idx": ip, **({} if cps is None else {"swa": cps}),
+              **({} if skv is None else {"shared": skv})}
     v_pool = {**v_pool, **({} if rps is None else {"swa": rps})}
     extra = (sum(stats),) if moe_stats else ()
     if attn_stats or latent_stats:
         if attn_stats:
             extra += (_attn_stats(None, False, None, block_tables, positions, write_mask, bs),)
         if latent_stats:
-            n_full, n_swa = (cfg.layer_types.count(t) for t in ("full", "sliding"))
+            n_full, n_swa, n_shared = (layer_kinds(cfg).count(t) for t in ("full", "sliding", "shared"))
+            n_sel = n_full + n_shared  # layers that gather and attend a selection
             n_alive = jnp.sum(alive).astype(jnp.int32)
             scored = n_tiles * tile
             seen = jnp.sum(jnp.where(real, pos_of + 1, 0))
             chosen = jnp.sum(jnp.where(real, jnp.minimum(pos_of + 1, K), 0))
             win = n_alive * (T // Tq) * WB * bs
+            heads_swa = kd["sliding"].H if n_swa else 0
             extra += (jnp.stack([
-                n_full * scored * K + n_swa * win,
-                n_alive * T * (n_full * kd["full"].H + n_swa * kd["sliding"].H),
-                n_full * scored * (N * bs), n_full * seen, n_full * chosen,
-                n_swa * win]).astype(jnp.int32),)
+                n_sel * scored * K + n_swa * win,
+                n_alive * T * (n_sel * kd["full"].H + n_swa * heads_swa),
+                n_full * scored * (N * bs), n_sel * seen, n_sel * chosen,
+                n_swa * win, *((n_full * n_pos, n_shared * n_pos) if cfg.indexer_types else ())
+            ]).astype(jnp.int32),)
     if rows is not None:
         extra += (rows.stats,)
     return (logits, k_pool, v_pool, None, None, *extra)
@@ -664,3 +761,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
 # attention over every key, the first ``index_topk`` keys instead of the
 # best, no window, no gate, no rescale
 FAULTS = ("no_selection", "first_keys", "no_window", "no_gate", "no_rescale")
+# of a selection carried across layers: a shared layer over every key, every shared layer on the
+# FIRST full layer's set, a position on its neighbour's set, the index key cached unrotated, a
+# value head cut to its key's width (dv > dn there)
+CARRY_FAULTS = ("shared_all_keys", "first_selection", "other_row", "no_index_rope", "short_value")

@@ -212,8 +212,11 @@ def family(cfg) -> Family:
                       sambay.StateNotCarried, _HYBRID_REFUSES, n_real="always",
                       one_head=True, pack_rows=0)
     if cfg.index_topk:  # learned sparse attention over a latent cache, planes by layer kind
+        # (where shared layers take a full layer's selection, what says one was carried)
+        carried = dots3.CARRY_STATS if cfg.indexer_types else ()
         return Family("sparse", dots3, dots3.cache_spec(cfg),
-                      _counts(cfg, mla.LATENT_STATS + dots3.SPARSE_STATS, kv=False), mla.LatentCacheOnly,
+                      _counts(cfg, mla.LATENT_STATS + dots3.SPARSE_STATS + carried, kv=False),
+                      mla.LatentCacheOnly,
                       _LATENT_REFUSES, n_real="admit", one_head=True, prefix_whole_blocks=True)
     if cfg.kv_lora_rank:  # a latent and ONE rotated key a token a layer
         return Family("latent", mla, mla.cache_spec(cfg), _counts(cfg, mla.LATENT_STATS),

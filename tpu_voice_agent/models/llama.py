@@ -138,6 +138,12 @@ class LlamaConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # which layers RUN an indexer (``glm_moe_dsa``'s ``indexer_types``), one of "full" | "shared"
+    # for each layer: a "full" layer scores and selects as above; a "shared" one has NO indexer
+    # and caches NO index key — it attends, over its OWN latents, the keys the nearest "full"
+    # layer before it selected (the selection is carried from layer to layer). () = every
+    # "full" entry of ``layer_types`` runs its own
+    indexer_types: tuple[str, ...] = ()
     # the query is compressed too: h W_qa -> RMSNorm -> W_qb (0: one matrix)
     q_lora_rank: int = 0
     # the normed compressed query and latent are rescaled by (dim / rank)^0.5
@@ -213,14 +219,25 @@ class LlamaConfig:
                              "layer_types, index_n_heads and an index_head_dim that holds the "
                              "rotated width")
         if self.kv_lora_rank and self.layer_types and not self.index_topk:
-            raise NotImplementedError("layer kinds inside a latent model are models.dots3's "
-                                      "forward's: one with an indexer (index_topk)")
+            raise NotImplementedError("layer kinds inside a latent model are the selected-latent "
+                                      "forward's (models.dots3): one with an indexer (index_topk)")
         if (self.q_lora_rank or self.attn_gate or self.lora_rescale) and not self.index_topk:
             raise NotImplementedError("a compressed query, a gate a head and the rank rescale "
-                                      "are models.dots3's forward's (index_topk)")
+                                      "are the selected-latent forward's (models.dots3: index_topk)")
         if self.index_topk and not self.q_lora_rank:
-            raise NotImplementedError("models.dots3's indexer reads the compressed query: "
+            raise NotImplementedError("the indexer (models.dots3) reads the compressed query: "
                                       "a q_lora_rank")
+        if self.indexer_types and not (self.index_topk and len(self.indexer_types) == self.n_layers
+                                       and not set(self.indexer_types) - {"full", "shared"}):
+            raise ValueError(f"indexer_types: one of 'full' | 'shared' for each of {self.n_layers} "
+                             f"layers behind an indexer (index_topk), got {self.indexer_types}")
+        if self.indexer_types and self.indexer_types[0] != "full":
+            raise ValueError("a 'shared' layer attends the keys the nearest 'full' layer before it "
+                             "selected: the first layer runs an indexer")
+        if self.indexer_types and set(self.layer_types) != {"full"}:
+            raise NotImplementedError("a selection carried across layers: between latent layers that "
+                                      "are all selected (layer_types 'full'); none carries one past "
+                                      "a sliding layer")
         if self.index_topk and "sliding" in self.layer_types and not (
                 self.swa_n_heads and self.swa_kv_lora_rank and self.swa_qk_nope_dim
                 and self.swa_qk_rope_dim and self.swa_v_head_dim and self.swa_rope_theta):
@@ -458,7 +475,7 @@ def quantize_params(params: dict) -> dict:
         # a latent model's leading dense layers, stacked apart (models.mla)
         **({"dense_layers": layers(params["dense_layers"])} if "dense_layers" in params else {}),
         # attention leaves stacked by layer KIND (models.dots3)
-        **{k: layers(params[k]) for k in ("attn_full", "attn_swa") if k in params},
+        **{k: layers(params[k]) for k in ("attn_full", "attn_swa", "attn_shared") if k in params},
         "final_norm": params["final_norm"],
         **({"exit_gate": params["exit_gate"]} if "exit_gate" in params else {}),
         # a tied head: an int8 copy of the embedding, a scale a vocabulary row
