@@ -175,6 +175,8 @@ def test_a_ragged_block_of_two_rows_behind_chunks_is_the_reference():
     assert stats["keys_visible"] == 2 * (34 + 35 + 36 + 34 + 35) and stats["keys_selected"] == 2 * 5 * 16
     assert stats["index_keys_scored"] == 2 * 6 * N * BS  # one tile of the block's 6 slots, the whole plane
     assert stats["window_keys_read"] > 0
+    # one tile pass a full layer, and the rule walks 40 keys of table behind top-16 at 4 heads
+    assert (stats["selected_tiles"], stats["selected_tiles_walked"]) == (2, 2)
 
 
 @functools.lru_cache(maxsize=None)
@@ -319,15 +321,50 @@ def test_the_bias_selects_and_the_index_key_is_layer_normed(served_f32):
     assert rel(got, ref.logits(moved, MODEL, SAMPLE)) > 1e-3
 
 
+FETCHES = ("walked", "gathered")
+
+
+@pytest.fixture
+def fetch(request, monkeypatch):
+    """The rule bound to one of its answers: a selected layer's keys WALKED under the mask, or
+    GATHERED — for a forward traced anew under it (a jit of the test's own: the module's hands
+    back what it traced first)."""
+    monkeypatch.setattr(sl, "walks", lambda keys, topk, heads: request.param == "walked")
+    return request.param
+
+
+def _prefill(params, cfg, impl="xla", fault=None, **kw):
+    kp, vp = pools(cfg, F32)
+    return jax.jit(functools.partial(dots3.forward_paged, attn_impl=impl, fault=fault, **kw),
+                   static_argnums=1)(params, cfg, TOKS, jnp.arange(50)[None], kp, vp, TABLE)
+
+
+@pytest.mark.parametrize("fetch", FETCHES, indirect=True)
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_served_forward_is_the_same_walked_and_gathered(served_f32, impl, fetch):
+    """ISSUE 62: one softmax over one key set, two ways to fetch it — a prefill
+    of 50 (selection and window binding) under either answer of the rule, kernels
+    and twins, is the forward the module serves (whichever the rule picked for
+    it), and counts its tile passes as walked or not."""
+    params, sound = served_f32
+    with jax.default_matmul_precision("highest"):
+        out = _prefill(params, CFG, impl, latent_stats=True)
+    assert rel(out[0][0], sound) < 1e-4
+    stats = dict(zip(mla.LATENT_STATS + dots3.SPARSE_STATS, np.asarray(out[-1]).tolist()))
+    tiles = 2 * 5  # two full layers, 50 positions in tiles of 10
+    assert (stats["selected_tiles"], stats["selected_tiles_walked"]) == (tiles, tiles * (fetch == "walked"))
+    assert stats["keys_selected"] == 2 * sum(min(t + 1, 16) for t in range(50))
+
+
+@pytest.mark.parametrize("fetch", FETCHES, indirect=True)
 @pytest.mark.parametrize("fault", dots3.FAULTS)
-def test_each_planted_fault_moves_the_served_logits(served_f32, fault):
+def test_each_planted_fault_moves_the_served_logits(served_f32, fault, fetch):
     """``dots3.forward_paged(fault=...)``: what the comparison's limit is set
     against on the chip — planted in the SERVED program, each departs from
-    the sound one at float32 by far more than rounding."""
+    the sound one at float32 by far more than rounding, whichever way the
+    selected keys are fetched."""
     params, sound = served_f32
-    kp, vp = pools(CFG, F32)
-    prefill = lambda fault: jax.jit(functools.partial(dots3.forward_paged, attn_impl="xla", fault=fault),
-                                    static_argnums=1)(params, CFG, TOKS, jnp.arange(50)[None], kp, vp, TABLE)
+    prefill = lambda fault: _prefill(params, CFG, fault=fault)
     with jax.default_matmul_precision("highest"):
         out = prefill(fault)
         if fault == dots3.FAULTS[0]:
@@ -405,6 +442,176 @@ def test_the_gathered_kernel_on_one_key_tile_matches_its_twin_on_the_split_rows(
     for other in (rows[..., :-1], jnp.pad(rows, ((0, 0), (0, 0), (0, 8)))):
         with pytest.raises(ValueError, match="no rows"):
             sl.sparse_latent_attention(q_c, q_r, other, kpos, lo, hi, scale=0.125)
+
+
+def _walked_case(H: int, nb: int, common: int, seed: int, filler: int = 0, tile: int = 4, K: int = 12):
+    """A tile of ``tile`` slots (C = 48, R = 16, blocks of 8) whose tables hold the
+    same ``common`` leading blocks and blocks of their own behind them; slot 0 sees
+    FEWER keys than K, the others stand anywhere behind the common blocks; the
+    last ``filler`` slots repeat the last real one (query, table, position), as
+    ``llama.ffn_pack_index`` fills a tile. -> (q_c, q_r, plane stack, layer,
+    tables, positions, the scores the selection is made from)."""
+    C, R, bs, n = 48, 16, 8, 60
+    ks = jax.random.split(jax.random.key(seed), 5)
+    q_c, q_r = jax.random.normal(ks[0], (tile, H, C), F32), jax.random.normal(ks[1], (tile, H, R), F32)
+    plane = jax.random.normal(ks[2], (3, n, bs, C + R), F32)
+    tables = np.zeros((tile, nb), np.int32)
+    tables[:, :common] = 7 + 2 * np.arange(common)[None, :]  # no run of ids: a walk reads them by name
+    tables[:, common:] = (30 + np.arange(tile * (nb - common)).reshape(tile, nb - common)) % n
+    pos = np.array(jax.random.randint(ks[3], (tile,), min(common * bs, nb * bs - 1), nb * bs), np.int32)
+    pos[0] = K - 5
+    real = tile - filler
+    take = np.minimum(np.arange(tile), real - 1)
+    q_c, q_r, tables, pos = q_c[take], q_r[take], jnp.asarray(tables[take]), jnp.asarray(pos[take])
+    mine = jax.random.normal(ks[4], (tile, nb * bs), F32)[take]
+    mine = jnp.where(jnp.arange(nb * bs)[None, :] <= pos[:, None], mine, -jnp.inf)
+    return q_c, q_r, plane, jnp.int32(1), tables, pos, mine
+
+
+# (heads, table columns, columns every slot holds): none, some, all in common; a common
+# run that fills no whole item (``_WALK_COLS`` = 4: 4 + 2); heads past a sublane tile
+WALKED = [(4, 5, 0), (4, 5, 3), (4, 5, 5), (6, 9, 6), (20, 3, 2)]
+
+
+@pytest.mark.parametrize("filler", [0, 2])
+@pytest.mark.parametrize("H,nb,common", WALKED)
+def test_the_walked_kernel_matches_its_twin_and_the_gathered_one(H, nb, common, filler):
+    """ISSUE 62: a tile's blocks walked straight out of the pool under the
+    selection as a mask — interpret mode against the jnp twin, against the
+    GATHERED kernel over ``lax.top_k``'s rows (the same softmax over the same
+    set, fetched the other way) and one (slot, head) by hand; a slot that sees
+    fewer keys than K; filler slots return what the slot they repeat returns."""
+    K, bs = 12, 8
+    q_c, q_r, plane, li, tables, pos, mine = _walked_case(H, nb, common, seed=11 + common, filler=filler)
+    seq = jnp.arange(nb * bs)[None, :]
+    chosen = sl.chosen_mask(mine, K) & (seq <= pos[:, None])
+    assert int(chosen[0].sum()) == K - 4 and all(int(n) == K for n in chosen[1:].sum(axis=1))
+    split = sl.walk_split(tables, pos, 4, bs)
+    with jax.default_matmul_precision("highest"):
+        got = sl.walked_latent_attention(q_c, q_r, plane, li, chosen, tables,
+                                         jax.tree.map(lambda a: a[0], split), scale=0.125)
+        want = sl.walked_latent_attention_reference(q_c, q_r, plane, li, chosen, tables, scale=0.125)
+        _, sel = jax.lax.top_k(mine, K)
+        rows = plane[1, jnp.take_along_axis(tables, sel // bs, axis=1), sel % bs]
+        gathered = sl.sparse_latent_attention(q_c, q_r, rows, sel, jnp.zeros((4, H), jnp.int32),
+                                              jnp.broadcast_to(pos[:, None], (4, H)), scale=0.125)
+    assert got.shape == (4, H, 48) and rel(got, want) < 1e-4 and rel(got, gathered) < 1e-4
+    p, h = 2, H - 1
+    keys = np.asarray(plane[1][tables[p]]).reshape(nb * bs, 64)
+    s = np.concatenate([np.asarray(q_c[p, h]), np.asarray(q_r[p, h])]) @ keys.T * 0.125
+    w = np.where(np.asarray(chosen[p]), np.exp(s - s[np.asarray(chosen[p])].max()), 0.0)
+    np.testing.assert_allclose(got[p, h], (w / w.sum()) @ keys[:, :48], rtol=2e-4, atol=2e-5)
+    if filler:
+        assert np.array_equal(got[-1], got[4 - filler - 1]) and np.array_equal(got[-2], got[4 - filler - 1])
+    with pytest.raises(ValueError, match="no rows"):
+        sl.walked_latent_attention(q_c, q_r, plane[..., :-1], li, chosen, tables,
+                                   jax.tree.map(lambda a: a[0], split), scale=0.125)
+
+
+@pytest.mark.parametrize("H,nb,common", WALKED)
+def test_a_tile_s_items_read_every_block_a_slot_sees_once(H, nb, common):
+    """``walk_split``: a (slot, column) a REAL slot's position reaches is read by
+    exactly ONE item — a common item's where every slot that sees the column
+    names the same block there, else an own item of that slot — under the block
+    id the slot's table names; tiles are split apart (two here: the second one's
+    slots all see little); a slot that is not real (the second tile's last: an
+    idle row's table of zeros, a position past everything) has no item and parts
+    no column the others hold in common."""
+    bs, tile, NK = 8, 4, sl._WALK_COLS
+    _, _, _, _, tables, pos, _ = _walked_case(H, nb, common, seed=5)
+    tables = jnp.concatenate([tables, tables.at[-1].set(0)])
+    pos = jnp.concatenate([pos, jnp.minimum(pos, 9).at[-1].set(nb * bs - 1)])
+    real = jnp.arange(2 * tile) < 2 * tile - 1
+    split = sl.walk_split(tables, pos, tile, bs, real)
+    for t in range(2):
+        at = slice(t * tile, (t + 1) * tile)
+        tb, ps, live = np.asarray(tables[at]), np.asarray(pos[at]), np.asarray(real[at])
+        groups, n_c, keys = int(split.n_common[t]), int(split.n_columns[t]), np.asarray(split.keys[t])
+        read = np.zeros((tile, nb), int)
+        for w in range(int(split.n_items[t])):
+            held = min(n_c - w * NK, NK) if w < groups else 1
+            for j in range(NK):
+                slot, col = (int(v) for v in sl.walk_entry(w, j, groups, n_c, keys, tile, nb))
+                assert col * bs <= ps[slot] and live[slot]  # the slot whose table names the block sees it
+                if j < held:
+                    read[np.flatnonzero(live) if w < groups else [slot], col] += 1
+                else:  # a column the item does not hold repeats one it does: the same block again, masked
+                    assert (slot, col) == tuple(int(v) for v in sl.walk_entry(w, held - 1, groups, n_c, keys, tile, nb))
+        sees = (np.arange(nb)[None, :] * bs <= ps[:, None]) & live[:, None]
+        same = np.array([len({tb[p, c] for p in range(tile) if sees[p, c]}) <= 1 for c in range(nb)])
+        # (a common column is read for the real slots that do not reach it too: their mask is empty there)
+        assert np.array_equal(read, np.where(same[None, :] & sees.any(axis=0)[None, :], live[:, None], sees))
+        assert groups == -(-int((same & sees.any(axis=0)).sum()) // NK)
+        if common >= 2 and t == 1:  # two columns, in common again
+            assert int(split.n_items[t]) == groups == 1
+
+
+TOP_K_ROWS = {
+    "distinct": lambda k: jax.random.normal(k, (6, 40), F32),
+    "ties_at_the_kth": lambda k: jnp.round(jax.random.normal(k, (6, 40), F32) * 1.5) / 2,
+    "all_equal": lambda k: jnp.zeros((6, 40), F32),
+    "fewer_finite_than_k": lambda k: jnp.where(jnp.arange(40)[None, :] <= jnp.asarray([[0], [3], [10], [11], [12], [39]]),
+                                              jnp.round(jax.random.normal(k, (6, 40), F32)), -jnp.inf),
+}
+
+
+@pytest.mark.parametrize("rows", sorted(TOP_K_ROWS))
+def test_the_membership_mask_is_top_k_s_index_set_ties_included(rows):
+    """``top_k_members``: compares alone, and EXACTLY the set ``lax.top_k``
+    returns — where values tie at the k-th (it takes the lower indices; 0.0 before
+    -0.0, which is no tie to it), where a whole row ties, and where fewer than k are finite (it takes -inf keys, lowest
+    index first: the caller's ``s <= position`` cuts them again)."""
+    k = 12
+    mine = TOP_K_ROWS[rows](jax.random.key(3))
+    vals, sel = jax.lax.top_k(mine, k)
+    if rows != "distinct":  # the k-th value IS tied with one left out, in some row
+        assert bool(((mine == vals[:, -1:]).sum(axis=1) > (vals == vals[:, -1:]).sum(axis=1)).any())
+    if rows == "ties_at_the_kth":
+        assert bool(jnp.signbit(jnp.where(mine == 0, mine, 1.0)).any())  # zeros of both signs among them
+    want = np.zeros(mine.shape, bool)
+    np.put_along_axis(want, np.asarray(sel), True, axis=1)
+    assert np.array_equal(sl.top_k_members(mine, vals, sel), want)
+    assert np.array_equal(sl.chosen_mask(mine, k), want)
+
+
+def test_the_rule_reads_shapes_alone_and_walks_a_few_times_index_topk():
+    """``walks(keys, topk, heads)``: the cells' 8832 positions of table behind
+    top-2048 walk at 64 heads, a 128 k context gathers (60 x the FLOPs) at either
+    head count — and nothing but its three arguments and the readings beside it
+    decides: no flag, no environment variable, no name."""
+    import inspect
+
+    assert sl.walks(8832, 2048, 64) and not sl.walks(131072, 2048, 64) and not sl.walks(131072, 2048, 128)
+    assert sl.walks(2048, 2048, 128) and sl.walks(56, 16, 4)  # nothing to leave out: every key is chosen
+    assert list(inspect.signature(sl.walks).parameters) == ["keys", "topk", "heads"]
+    assert set(sl.walks.__code__.co_names) <= {"_WALKED_KEY_HEAD_NS", "_GATHER_ROW_NS", "_GATHERED_KEY_HEAD_NS"}
+    # the cost of the walk grows with the table, the gather's does not
+    flips = [k for k in range(2048, 200000, 128) if sl.walks(k, 2048, 64) != sl.walks(k + 128, 2048, 64)]
+    assert len(flips) == 1
+
+
+def test_the_check_tool_holds_the_two_fetches_together_at_toy_shapes_on_the_cpu(tmp_path):
+    """``tools/selected_attn_check.py`` (the stop rule's instrument, the source of
+    the rule's constants) in interpret mode: gather + gathered kernel against the
+    walk and its twin, the members' mask against ``top_k``'s scatter — exit code
+    0, a line a head count, the rule's verdict, and NO time from a CPU."""
+    import os
+    import subprocess
+    import sys
+
+    root = Path(__file__).parents[1]
+    done = subprocess.run([sys.executable, str(root / "tools/selected_attn_check.py"), "--heads", "4", "8",
+                           "--keys", "640", "--common", "3", "--topk", "200", "--tile", "4"],
+                          capture_output=True, text=True, cwd=tmp_path, timeout=600,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert done.returncode == 0, done.stderr[-2000:]
+    lines = [json.loads(ln) for ln in done.stdout.splitlines() if ln.startswith("{")]
+    assert [ln["heads"] for ln in lines] == [4, 8]
+    for ln in lines:
+        assert ln["members_are_top_k_s"] and ln["rule_walks"] and ln["largest_difference"] < 2e-2
+        assert (ln["keys"], ln["topk"], ln["common_items"]) == (640, 200, 1)
+        assert not [k for k in ln if k.endswith("_us") or k == "readings_ns"]
+    assert (tmp_path / "chiprun_out/selected_attn_check.jsonl").read_text().count("\n") == 2
 
 
 def test_a_full_layer_s_row_in_the_pool_is_the_latent_beside_its_rotated_key():
@@ -615,7 +822,7 @@ def test_the_engine_serves_it_behind_the_batcher_at_both_chunk_widths(monkeypatc
     many = batcher.generate_many(prompts)
     assert all(r.error is None for r in solo + many)
     assert {c.rows for c in chunks} == {2, 8}
-    assert all(c.counts["moe"].shape == (len(llama.moe_stat_names(eng.cfg)),) and c.counts["latent"].shape == (6,) for c in chunks)
+    assert all(c.counts["moe"].shape == (len(llama.moe_stat_names(eng.cfg)),) and c.counts["latent"].shape == (8,) for c in chunks)
     assert many[0].token_ids == solo[0].token_ids  # the same plan at either width
     counters = fresh.snapshot()["counters"]
     assert counters["moe.assigned_rows"] > counters["moe.local_rows"] > 0
@@ -623,6 +830,10 @@ def test_the_engine_serves_it_behind_the_batcher_at_both_chunk_widths(monkeypatc
     assert 0 < chosen < 0.3 * visible  # 256 of ~1050 keys a position
     assert counters["attn.index_keys_scored"] >= visible and counters["attn.window_keys_read"] > 0
     assert counters["attn.latent_query_rows"] > 0 and counters["attn.latent_keys_read"] > 0
+    # what says the walk engaged: every tile pass took the path the rule picks at these shapes
+    walked = sl.walks(eng.block_tables.shape[1] * eng.block_size, eng.cfg.index_topk, eng.cfg.n_heads)
+    assert counters["attn.selected_tiles"] > 0
+    assert counters["attn.selected_tiles_walked"] == walked * counters["attn.selected_tiles"]
 
 
 def test_the_chunk_loop_gives_the_same_plans_walked_and_whole(monkeypatch, prompts):
@@ -710,12 +921,16 @@ def test_a_group_s_admission_is_the_per_slot_admissions(prompts):
 # (ee06ed6); ISSUE 45 moved all four — every program of the model writes a full layer's ONE row
 # [c | r] and gathers it once, straight out of the pool — and re-derived them: what a later PR
 # that leaves this model's programs alone must reproduce. ISSUE 48 re-derived the COMPACTED chunk
-# program alone: its carry holds a third attention count (``ops.ATTN_STATS``), 0 for this model
+# program alone: its carry holds a third attention count (``ops.ATTN_STATS``), 0 for this model.
+# ISSUE 62 moved all four and re-derived them ONCE: at these widths the rule (``sl.walks``: twelve
+# columns of table behind top-256 at 4 heads) WALKS — the members' mask, ``walk_split`` once a
+# forward and the walked kernel where the gather stood — and every program carries two more
+# counts (``selected_tiles`` / ``selected_tiles_walked``)
 PARENT_SHA256 = {
-    "group": "2df8668ef87d708bc17833bc3db3e38c8026e97d01e88443924dbbe67d3347fd",
-    "block": "d2108056ef37eebcfa596cff0819a46729ac83034a677683d25e2f093b137089",
-    "chunk": ["d78d902077560881fd9b398c6898fbcfc29a260620a640cf731741faa8a344c3",
-              "82bc26d3104e8213400aacc20727adcbd21ac91f7bf42b401620c872e9d13151"],
+    "group": "fea82c9023b771927167d337e586e5a4fc15b50316b3e04c9b2a70fe440e6411",
+    "block": "1bf7bb9ca74cdf8e85d0b4cfe5aff6bfafa8f93689bd87f4d68ec9175be0bf89",
+    "chunk": ["65f7ad5fb8c63961d324d67fd109cc26bcc767e91939601fbf7b03d51e1b786c",
+              "17097aef3fa03611ec4b88e55d45aed5d9af955385f55081907f7d07456317b8"],
 }
 
 

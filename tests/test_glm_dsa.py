@@ -184,6 +184,8 @@ def test_a_ragged_block_of_two_rows_behind_chunks_is_the_reference():
     # ... chosen in TWO: the indexer scores two planes, three layers are handed a set
     assert stats["index_keys_scored"] == 2 * 6 * N * BS
     assert (stats["selections_made"], stats["selections_carried"]) == (2 * 5, 3 * 5)
+    # one tile pass a selected layer, and the rule walks 40 keys of table behind top-16 at 4 heads
+    assert (stats["selected_tiles"], stats["selected_tiles_walked"]) == (5, 5)
     assert stats["window_keys_read"] == 0 and stats["latent_query_rows"] == 2 * 3 * 5 * CFG.n_heads
 
 
@@ -295,17 +297,57 @@ def test_the_bias_selects(served_f32):
     assert rel(got, ref.logits(zero, MODEL, SAMPLE)) > 1e-3
 
 
+FETCHES = ("walked", "gathered")
+
+
+@pytest.fixture
+def fetch(request, monkeypatch):
+    """The rule bound to one of its answers: a selected layer's keys WALKED under the mask, or
+    GATHERED — for a forward traced anew under it (a jit of the test's own: the module's hands
+    back what it traced first)."""
+    from tpu_voice_agent.ops import sparse_latent as sl
+
+    monkeypatch.setattr(sl, "walks", lambda keys, topk, heads: request.param == "walked")
+    return request.param
+
+
+def _prefill(params, cfg, impl="xla", fault=None, **kw):
+    kp, vp = pools(cfg, F32)
+    return jax.jit(functools.partial(dots3.forward_paged, attn_impl=impl, fault=fault, **kw),
+                   static_argnums=1)(params, cfg, TOKS, jnp.arange(50)[None], kp, vp, TABLE)
+
+
+@pytest.mark.parametrize("fetch", FETCHES, indirect=True)
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_served_forward_is_the_same_walked_and_gathered(served_f32, impl, fetch):
+    """ISSUE 62: one softmax over one key set, two ways to fetch it — a prefill
+    of 50 under either answer of the rule, kernels and twins, is the forward the
+    module serves; the selection is CARRIED to the shared layers as the fetch
+    takes it (the members' mask, or the chosen keys' positions and blocks), and
+    every count but the walked tile passes is the same."""
+    params, sound = served_f32
+    with jax.default_matmul_precision("highest"):
+        out = _prefill(params, CFG, impl, latent_stats=True)
+    assert rel(out[0][0], sound) < 1e-4
+    stats = dict(zip(STATS, np.asarray(out[-1]).tolist()))
+    tiles = 5 * 5  # five selected layers, 50 positions in tiles of 10
+    assert (stats["selected_tiles"], stats["selected_tiles_walked"]) == (tiles, tiles * (fetch == "walked"))
+    assert stats["keys_selected"] == 5 * sum(min(t + 1, 16) for t in range(50))
+    assert (stats["selections_made"], stats["selections_carried"]) == (2 * 50, 3 * 50)
+
+
+@pytest.mark.parametrize("fetch", FETCHES, indirect=True)
 @pytest.mark.parametrize("fault", ("no_selection", "first_keys") + dots3.CARRY_FAULTS)
-def test_each_planted_fault_moves_the_served_logits(served_f32, fault):
+def test_each_planted_fault_moves_the_served_logits(served_f32, fault, fetch):
     """``dots3.forward_paged(fault=...)``: what the comparison's limit is set
     against on the chip (``benchmark/tools/indexshare_check.py``) — planted in
     the SERVED program, each departs from the sound one at float32 by far more
-    than rounding; ``first_selection`` and ``shared_all_keys`` land where the
-    reference's matching departure does."""
+    than rounding, whichever way the selected keys are fetched (``other_row``
+    rolls the members' mask where it rolled the chosen positions);
+    ``first_selection`` and ``shared_all_keys`` land where the reference's
+    matching departure does."""
     params, sound = served_f32
-    kp, vp = pools(CFG, F32)
-    prefill = lambda fault: jax.jit(functools.partial(dots3.forward_paged, attn_impl="xla", fault=fault),
-                                    static_argnums=1)(params, CFG, TOKS, jnp.arange(50)[None], kp, vp, TABLE)
+    prefill = lambda fault: _prefill(params, CFG, fault=fault)
     with jax.default_matmul_precision("highest"):
         out = prefill(fault)
         if fault == "no_selection":
@@ -481,6 +523,12 @@ def test_the_engine_serves_it_behind_the_batcher_at_both_chunk_widths(monkeypatc
     assert 0 < chosen < 0.3 * visible  # 256 of ~1050 keys a position
     made, carried = counters["attn.selections_made"], counters["attn.selections_carried"]
     assert made > 0 and carried * 2 == made * 3  # F S S F S: three layers in five are handed a set
+    # what says the walk engaged: every tile pass took the path the rule picks at these shapes
+    from tpu_voice_agent.ops import sparse_latent as sl
+
+    walked = sl.walks(eng.block_tables.shape[1] * eng.block_size, eng.cfg.index_topk, eng.cfg.n_heads)
+    assert counters["attn.selected_tiles"] > 0
+    assert counters["attn.selected_tiles_walked"] == walked * counters["attn.selected_tiles"]
     # the indexer scores TWO planes a forward: scored / (made / 2 positions) stays the pool's size
     assert counters["attn.index_keys_scored"] >= visible * 2 / 5 and counters["attn.window_keys_read"] == 0
 

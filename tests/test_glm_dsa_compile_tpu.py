@@ -86,7 +86,8 @@ def test_the_glm_chunk_program_compiles_at_published_widths(tpu_devices, monkeyp
         pad_id=eng.pad_id, max_len=eng.max_len, kv_quant=None, quality_lanes=eng.quality_lanes).compile()
     text = compiled.as_text()
     n = R if width == "compact" else B
-    for kernel in ("indexer_scores", "sparse_latent_attention", "grouped_matmul"):
+    # (the cell's 8832 keys of table behind top-2048 WALK: the walked kernel under the scope's name)
+    for kernel in ("indexer_scores", "sparse_latent_attention", "walked_latent_attention", "grouped_matmul"):
         assert kernel in text, kernel
     assert "window_latent_attention" not in text and "conditional" not in text
     # the head on one position a row; no key or value of a cached position is ever decompressed

@@ -838,6 +838,28 @@ def test_the_gathered_latent_kernel_compiles_at_published_widths(tpu_devices, na
     assert name in compiled.as_text()
 
 
+@pytest.mark.parametrize("H,tile", [(64, 16), (128, 16), (64, 12)], ids=["glm-5.2", "dots3-note", "compact"])
+def test_the_walked_latent_kernel_compiles_at_published_widths(tpu_devices, H, tile):
+    """ISSUE 62: a tile's 16 (the compacted width's 12) slots x 64 | 128 heads
+    as query rows [q_c | q_r] of 512 + 64, walking the 69 columns of their
+    tables block by block out of the cell's 264-block plane stack under the
+    members' mask — the layer and the block ids (read out of the tile's table by
+    the items' keys) in the index maps, a dynamic grid over the tile's items,
+    four common columns an item."""
+    from tpu_voice_agent.ops import sparse_latent as sl
+
+    nb = 69
+
+    def walk(q_c, q_r, plane, layer, chosen, tables, *split):
+        return sl.walked_latent_attention(q_c, q_r, plane, layer, chosen, tables, sl.WalkSplit(*split),
+                                          scale=0.07, interpret=False)
+
+    compiled = _compile(tpu_devices, walk, ((tile, H, 512), BF16), ((tile, H, 64), BF16),
+                        ((8, 264, 128, 576), BF16), ((), I32), ((tile, nb * 128), jnp.bool_), ((tile, nb), I32),
+                        ((), I32), ((), I32), ((), I32), (((1 + tile) * nb,), I32))
+    assert "walked_latent_attention" in compiled.as_text()
+
+
 @pytest.mark.slow
 def test_sharded_kernels_compile_on_2x2(tpu_devices):
     """The shard_map variants the dp x tp serving mesh traces (batch over

@@ -37,11 +37,18 @@ over a key set gathered for its queries:
 - a full layer's positions, the REAL ones first (``llama.ffn_pack_index``),
   go tile by tile (16 slots) — as many tiles as hold real positions — through
   ``ops.indexer_scores`` (every pool block scored once for all of them), a
-  read of each position's own blocks out of that by its table, ``top_k``, ONE
-  gather of the chosen keys' rows [c | r] straight out of the pool (one row a
-  key where two planes paid for two; what a gathered row costs on the chip,
-  and the step it takes past 1 KB, is in PERF.md section 6, PR 45), and the
-  kernel with a position's H heads as a group over that one tile of keys;
+  read of each position's own blocks out of that by its table, ``top_k``, and
+  the chosen keys fetched one of TWO ways, by the shapes of the program alone
+  (``ops.sparse_latent.walks``: the keys a row's table spans, ``index_topk``,
+  the heads — no flag): where the context is a few times ``index_topk`` the
+  tile WALKS its rows' table columns block by block straight out of the pool
+  under the selection as a membership mask (``walked_latent_attention``: a
+  column every slot holds is read once for all of them; every visible key is
+  scored and an unchosen one masked — the same softmax over the same set, and
+  no gather, which the chip charges ~16 ns a ROW: PERF.md section 6, PR 62);
+  everywhere else ONE gather of the chosen keys' rows [c | r] (one row a key
+  where two planes paid for two: PERF.md section 6, PR 45) and the kernel with
+  a position's H heads as a group over that one tile of keys;
 - a sliding layer's rows gather the few blocks that hold their window and
   the kernel takes a row's T x H queries as a group (a prefill wider than
   ``MAX_BLOCK_DECODE_T`` is cut into rows of 8 positions first).
@@ -77,11 +84,12 @@ A selection CARRIED across layers (``glm_moe_dsa``: GLM-5.2, ``LlamaConfig.index
 gate, no rescale, no sliding layer): a "shared" layer is a full layer WITHOUT an indexer — a kind
 of its own here, ``attn_shared`` its leaves (no W_qI / W_kI / W_w), ``k_pool["shared"]`` its rows
 [c | r] (no index key cached) — that attends S_l[t] = S_f(l)[t], the keys the nearest full layer f
-before it selected. A full layer's tiles hand their ``top_k`` on as they make it: the chosen keys'
-sequence positions and their pool blocks, (P, K) each, in the packed order of the forward's
-positions (``layer/attn/carry``); a shared layer's tiles cut theirs out of that, gather THEIR OWN
+before it selected. A full layer's tiles hand their ``top_k`` on as they make it, in the form the
+fetch takes it — gathered: the chosen keys' sequence positions and their pool blocks, (P, K) each;
+walked: ``top_k``'s members, ONE (P, nb * bs) mask — in the packed order of the forward's
+positions (``layer/attn/carry``); a shared layer's tiles cut theirs out of that, fetch THEIR OWN
 plane's rows at those coordinates and attend them through the same kernel. Nothing else differs:
-one ``latent_qkv``, one indexer path, one select-gather, one kernel call.
+one ``latent_qkv``, one indexer path, one ``attend_chosen``.
 """
 
 from __future__ import annotations
@@ -106,7 +114,10 @@ F32 = jnp.float32
 # scored (positions x the plane), keys the real positions of full layers
 # could see and the keys they attended, cached positions the sliding layers'
 # window gathers read
-SPARSE_STATS = ("index_keys_scored", "keys_visible", "keys_selected", "window_keys_read")
+SPARSE_STATS = ("index_keys_scored", "keys_visible", "keys_selected", "window_keys_read",
+                # tile passes of selected attention (selected layers x tiles that hold a real
+                # position), and those that WALKED their rows' blocks under the selection as a mask
+                "selected_tiles", "selected_tiles_walked")
 # behind them where a selection is carried (``indexer_types``): (real position, layer) pairs whose
 # layer scored and selected, and pairs whose layer attended the set an earlier layer chose
 CARRY_STATS = ("selections_made", "selections_carried")
@@ -331,9 +342,12 @@ def _position_tile(P: int) -> int:
     """Positions a pass of a full layer's attention takes: the largest
     divisor of P up to 16 (a fast-forward block of 32 rows, a suffix group
     and the prefix's chunks: 16; the compacted width's 72: 12). Small,
-    because a pass GATHERS ``index_topk`` rows of the cache for every slot
-    of its tile, real or not, and the gather is most of a full layer's
-    attention: ~45 real positions of a block's 288 take three tiles."""
+    because a pass pays for every slot of its tile, real or not — a GATHERED
+    pass ``index_topk`` rows of the cache a slot, a WALKED one the slot's heads
+    as query rows of every key block: ~45 real positions of a block's 288
+    take three tiles. (Sized when every pass gathered; a walked pass's fixed
+    part — its items' steps, its slots' own blocks — would be paid once in a
+    wider tile: PERF.md section 7, "Open after PR 62".)"""
     return max(t for t in range(1, min(P, 16) + 1) if P % t == 0)
 
 
@@ -360,6 +374,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
     index_fn = sl.indexer_scores if pallas else sl.indexer_scores_reference
     twin = sl.gathered_latent_attention_reference
     attend_full = sl.sparse_latent_attention if pallas else sl.sparse_latent_attention_reference
+    attend_walk = sl.walked_latent_attention if pallas else sl.walked_latent_attention_reference
     attend_window = sl.window_latent_attention if pallas else twin
     if fault is not None and fault not in FAULTS + CARRY_FAULTS:
         raise ValueError(f"fault {fault!r}: one of {FAULTS + CARRY_FAULTS}")
@@ -401,6 +416,10 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
     # ---- a full layer's geometry, once a forward: the real positions first
     K = min(cfg.index_topk, nb * bs)
     tile = _position_tile(P)
+    # how a selected layer fetches its chosen keys — by the shapes of THIS program alone
+    # (``ops.sparse_latent.walks``): its rows' blocks walked whole under the selection as a mask,
+    # or one gathered row a chosen key
+    walk = sl.walks(nb * bs, K, kd["full"].H)
     with jax.named_scope("layer/attn/split"):
         if live_n is not None:
             # every position has a slot: it always fits
@@ -415,6 +434,8 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         pos_of = positions.reshape(-1)[idx]  # (P,)
         tbl_of = block_tables[idx // T, :nb]  # (P, nb)
         real = jnp.arange(P) < n_pos if live_n is not None else jnp.repeat(alive, T)[idx]
+        # a walked tile's work: the kernel's items (its twin reads by none)
+        items = sl.walk_split(tbl_of, pos_of, tile, bs, real) if walk and pallas else None
 
         # ---- a sliding layer's: rows of at most MAX_BLOCK_DECODE_T positions
         # and the blocks that hold their window
@@ -440,9 +461,20 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
     scale = (k_sel.dn + k_sel.dr) ** -0.5
     cut_tile = lambda a, i: jax.lax.dynamic_slice_in_dim(a, i * tile, tile)
 
-    def attend_chosen(i, li, ps, qc_f, qr_f, plane, sel, sblk, out):
-        """Tile ``i`` (positions ``ps``) of a selected layer behind its selection:
-        ONE row a chosen key, [c | r], out of the layer's own plane, and the kernel."""
+    def attend_chosen(i, li, ps, qc_f, qr_f, plane, picked, out):
+        """Tile ``i`` (positions ``ps``) of a selected layer behind its selection ``picked``, in
+        the form its fetch takes it. Gathered, (sel, sblk): ONE row a chosen key, [c | r], out of
+        the layer's own plane, and the kernel over them. Walked, (members,): the tile's blocks
+        straight out of the pool, a key admitted iff the position chose it and may see it."""
+        if walk:
+            with jax.named_scope("layer/attn/select"):
+                seen = picked[0] & (jnp.arange(nb * bs, dtype=jnp.int32)[None, :] <= ps[:, None])
+                work = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), items)
+            with jax.named_scope("layer/attn/full"):
+                a = attend_walk(cut_tile(qc_f, i), cut_tile(qr_f, i), plane, li, seen, cut_tile(tbl_of, i),
+                                work, scale=scale)
+            return jax.lax.dynamic_update_slice_in_dim(out, a, i * tile, 0)
+        sel, sblk = picked
         with jax.named_scope("layer/attn/select"):
             # straight out of the pool (a layer's plane sliced first is an HBM copy a tile behind a
             # ``while`` that carries the pools)
@@ -457,8 +489,9 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
         """The full layers' attention over (P, ...) queries in ``idx``'s
         order, the real positions first, into ``out`` (P, H, C), tile by tile.
         ``chosen`` (a model whose shared layers take this layer's selection):
-        the (P, K) buffers its tiles leave their chosen keys' sequence positions
-        and pool blocks in -> (out, chosen)."""
+        the buffers its tiles leave their selection in, as ``attend_chosen`` takes
+        it — gathered, the chosen keys' sequence positions and pool blocks, (P, K)
+        each; walked, ``top_k``'s members, (P, nb * bs) -> (out, chosen)."""
 
         def one_tile(i, carry):
             out = carry if chosen is None else carry[0]
@@ -474,32 +507,35 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
                         mine = -seq.astype(F32) + jnp.zeros_like(mine)
                     mine = jnp.where(seq <= ps[:, None], mine, -jnp.inf)
                 with jax.named_scope("top_k"):
-                    _, sel = jax.lax.top_k(mine, K)  # (tile, K) sequence positions
+                    vals, sel = jax.lax.top_k(mine, K)  # (tile, K) sequence positions
             with jax.named_scope("layer/attn/select"):
-                # the block of each chosen key out of the slot's table: a compare
-                # and a sum over its few columns (a gather of scalars costs more
-                # than the rows it names)
-                col = (sel // bs)[:, :, None] == jnp.arange(nb, dtype=jnp.int32)
-                sblk = jnp.sum(jnp.where(col, tb[:, None, :], 0), axis=-1)
-            out = attend_chosen(i, li, ps, qc_f, qr_f, kvp, sel, sblk, out)
+                if walk:  # the same set as a membership mask: compares, no scatter
+                    picked = (sl.top_k_members(mine, vals, sel),)
+                else:
+                    # the block of each chosen key out of the slot's table: a compare
+                    # and a sum over its few columns (a gather of scalars costs more
+                    # than the rows it names)
+                    col = (sel // bs)[:, :, None] == jnp.arange(nb, dtype=jnp.int32)
+                    picked = (sel, jnp.sum(jnp.where(col, tb[:, None, :], 0), axis=-1))
+            out = attend_chosen(i, li, ps, qc_f, qr_f, kvp, picked, out)
             if chosen is None:
                 return out
             with jax.named_scope("layer/attn/carry"):  # handed on to the shared layers behind
                 return out, tuple(jax.lax.dynamic_update_slice_in_dim(buf, v, i * tile, 0)
-                                  for buf, v in zip(carry[1], (sel, sblk)))
+                                  for buf, v in zip(carry[1], picked))
 
         return jax.lax.fori_loop(0, n_tiles, one_tile, out if chosen is None else (out, chosen))
 
     def shared_attention(li, qc_f, qr_f, plane, chosen, out):
         """A shared layer's attention: the same tiles over the set the nearest
-        full layer before it left in ``chosen``, gathered out of ITS OWN plane."""
+        full layer before it left in ``chosen``, fetched out of ITS OWN plane."""
         if fault == "other_row":  # a position reads its neighbour's selection
             chosen = tuple(jnp.roll(buf, 1, axis=0) for buf in chosen)
 
         def one_tile(i, out):
             with jax.named_scope("layer/attn/carry"):
-                sel, sblk = (cut_tile(buf, i) for buf in chosen)
-            return attend_chosen(i, li, cut_tile(pos_of, i), qc_f, qr_f, plane, sel, sblk, out)
+                picked = tuple(cut_tile(buf, i) for buf in chosen)
+            return attend_chosen(i, li, cut_tile(pos_of, i), qc_f, qr_f, plane, picked, out)
 
         return jax.lax.fori_loop(0, n_tiles, one_tile, out)
 
@@ -601,7 +637,8 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
             return shared_attention(li, q["c"], q["r"], planes[0], chosen, out), chosen
         if not carries:
             return full_attention(li, *(q[n] for n in "criw"), *planes, out), None
-        made = chosen if chosen is not None else tuple(jnp.zeros((P, K), jnp.int32) for _ in range(2))
+        made = chosen if chosen is not None else (
+            (jnp.zeros((P, nb * bs), bool),) if walk else tuple(jnp.zeros((P, K), jnp.int32) for _ in range(2)))
         out, made = full_attention(li, *(q[n] for n in "criw"), *planes, out, made)
         # (planted: every shared layer takes the FIRST full layer's set, not the nearest's)
         return out, chosen if fault == "first_selection" and chosen is not None else made
@@ -749,7 +786,8 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
                 n_sel * scored * K + n_swa * win,
                 n_alive * T * (n_sel * kd["full"].H + n_swa * heads_swa),
                 n_full * scored * (N * bs), n_sel * seen, n_sel * chosen,
-                n_swa * win, *((n_full * n_pos, n_shared * n_pos) if cfg.indexer_types else ())
+                n_swa * win, n_sel * n_tiles, n_sel * n_tiles * walk,
+                *((n_full * n_pos, n_shared * n_pos) if cfg.indexer_types else ())
             ]).astype(jnp.int32),)
     if rows is not None:
         extra += (rows.stats,)
