@@ -177,6 +177,7 @@ def test_a_ragged_block_of_two_rows_behind_chunks_is_the_reference():
     assert stats["window_keys_read"] > 0
     # one tile pass a full layer, and the rule walks 40 keys of table behind top-16 at 4 heads
     assert (stats["selected_tiles"], stats["selected_tiles_walked"]) == (2, 2)
+    assert stats["selections_thresholded"] == 0  # the twins' ``chosen_mask`` sorts: no kernel ran
 
 
 @functools.lru_cache(maxsize=None)
@@ -353,6 +354,8 @@ def test_the_served_forward_is_the_same_walked_and_gathered(served_f32, impl, fe
     stats = dict(zip(mla.LATENT_STATS + dots3.SPARSE_STATS, np.asarray(out[-1]).tolist()))
     tiles = 2 * 5  # two full layers, 50 positions in tiles of 10
     assert (stats["selected_tiles"], stats["selected_tiles_walked"]) == (tiles, tiles * (fetch == "walked"))
+    # (ISSUE 63) the threshold select makes a walked tile's set where the kernels are on, ``lax.top_k`` elsewhere
+    assert stats["selections_thresholded"] == tiles * (fetch == "walked" and impl == "pallas")
     assert stats["keys_selected"] == 2 * sum(min(t + 1, 16) for t in range(50))
 
 
@@ -546,25 +549,38 @@ def test_a_tile_s_items_read_every_block_a_slot_sees_once(H, nb, common):
             assert int(split.n_items[t]) == groups == 1
 
 
+_ROUNDED = lambda k, shape=(6, 40): jnp.round(jax.random.normal(k, shape, F32))
+_ENDS = lambda ends, n: jnp.arange(n)[None, :] <= jnp.asarray(ends)[:, None]
+# name -> (scores of a key, k)
 TOP_K_ROWS = {
-    "distinct": lambda k: jax.random.normal(k, (6, 40), F32),
-    "ties_at_the_kth": lambda k: jnp.round(jax.random.normal(k, (6, 40), F32) * 1.5) / 2,
-    "all_equal": lambda k: jnp.zeros((6, 40), F32),
-    "fewer_finite_than_k": lambda k: jnp.where(jnp.arange(40)[None, :] <= jnp.asarray([[0], [3], [10], [11], [12], [39]]),
-                                              jnp.round(jax.random.normal(k, (6, 40), F32)), -jnp.inf),
+    "distinct": (lambda k: jax.random.normal(k, (6, 40), F32), 12),
+    "ties_at_the_kth": (lambda k: jnp.round(jax.random.normal(k, (6, 40), F32) * 1.5) / 2, 12),
+    "all_equal": (lambda k: jnp.zeros((6, 40), F32), 12),
+    "fewer_finite_than_k": (lambda k: jnp.where(_ENDS([0, 3, 10, 11, 12, 39], 40), _ROUNDED(k), -jnp.inf), 12),
+    # ISSUE 63: what the threshold select must take as ``top_k`` does
+    "k_is_every_key": (_ROUNDED, 40),
+    "k_is_one": (_ROUNDED, 1),
+    "nans_of_both_signs": (lambda k: _ROUNDED(k).at[jnp.arange(6), jnp.arange(6) * 3].set(jnp.nan)
+                           .at[2, 7::4].set(-jnp.nan).at[4, :30].set(jnp.nan), 12),
+    "a_row_all_minus_inf": (lambda k: _ROUNDED(k).at[1].set(-jnp.inf).at[4].set(-jnp.inf), 12),
+    "the_cells_8832_keys": (lambda k: jnp.where(_ENDS([5, 2047, 2048, 8300, 8831, 4000], 8832),
+                                                 _ROUNDED(k, (6, 8832)) / 4, -jnp.inf), 2048),
 }
 
 
 @pytest.mark.parametrize("rows", sorted(TOP_K_ROWS))
 def test_the_membership_mask_is_top_k_s_index_set_ties_included(rows):
-    """``top_k_members``: compares alone, and EXACTLY the set ``lax.top_k``
+    """``top_k_members`` (compares alone) and ``threshold_members`` (ISSUE 63: no sorted row — the
+    kernel in interpret mode, and its steps as XLA alone): EXACTLY the set ``lax.top_k``
     returns — where values tie at the k-th (it takes the lower indices; 0.0 before
-    -0.0, which is no tie to it), where a whole row ties, and where fewer than k are finite (it takes -inf keys, lowest
-    index first: the caller's ``s <= position`` cuts them again)."""
-    k = 12
-    mine = TOP_K_ROWS[rows](jax.random.key(3))
+    -0.0, which is no tie to it), where a whole row ties, where fewer than k are finite (it takes -inf keys, lowest
+    index first: the caller's ``s <= position`` cuts them again), where k is every key or one, where
+    a row holds NaNs (above +inf, or below -inf by their sign) and at a width that is no power of two."""
+    make, k = TOP_K_ROWS[rows]
+    mine = make(jax.random.key(3))
     vals, sel = jax.lax.top_k(mine, k)
-    if rows != "distinct":  # the k-th value IS tied with one left out, in some row
+    if rows in ("ties_at_the_kth", "all_equal", "fewer_finite_than_k", "the_cells_8832_keys"):
+        # the k-th value IS tied with one left out, in some row
         assert bool(((mine == vals[:, -1:]).sum(axis=1) > (vals == vals[:, -1:]).sum(axis=1)).any())
     if rows == "ties_at_the_kth":
         assert bool(jnp.signbit(jnp.where(mine == 0, mine, 1.0)).any())  # zeros of both signs among them
@@ -572,6 +588,10 @@ def test_the_membership_mask_is_top_k_s_index_set_ties_included(rows):
     np.put_along_axis(want, np.asarray(sel), True, axis=1)
     assert np.array_equal(sl.top_k_members(mine, vals, sel), want)
     assert np.array_equal(sl.chosen_mask(mine, k), want)
+    assert np.array_equal(sl.threshold_members(mine, k), want)
+    if k < mine.shape[1]:
+        steps = jax.jit(lambda m: sl._select_members(lambda: sl._total_order(m), lambda v: lambda: v, k, unroll=True))
+        assert np.array_equal(np.asarray(steps(mine)) != 0, want)
 
 
 def test_the_rule_reads_shapes_alone_and_walks_a_few_times_index_topk():
@@ -612,6 +632,26 @@ def test_the_check_tool_holds_the_two_fetches_together_at_toy_shapes_on_the_cpu(
         assert (ln["keys"], ln["topk"], ln["common_items"]) == (640, 200, 1)
         assert not [k for k in ln if k.endswith("_us") or k == "readings_ns"]
     assert (tmp_path / "chiprun_out/selected_attn_check.jsonl").read_text().count("\n") == 2
+
+
+def test_the_check_tool_s_select_reading_holds_each_form_to_top_k_s_set_on_the_cpu(tmp_path):
+    """``tools/selected_attn_check.py --select`` (ISSUE 63's stop rule) in interpret mode: ``lax.top_k`` +
+    ``top_k_members``, the threshold select as XLA alone and as the kernel — each mask a scatter of
+    ``top_k``'s indices on seeded planes and the planted rows, exit code 0, ONE line, no time from a CPU."""
+    import os
+    import subprocess
+    import sys
+
+    tool = Path(__file__).parents[1] / "tools/selected_attn_check.py"
+    done = subprocess.run([sys.executable, str(tool), "--select", "--keys", "640", "--topk", "200", "--tile", "4",
+                           "--passes", "2"], capture_output=True, text=True, cwd=tmp_path, timeout=600,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert done.returncode == 0, done.stderr[-2000:]
+    line, = [json.loads(ln) for ln in done.stdout.splitlines() if ln.startswith("{")]
+    assert line["select"] and (line["tile"], line["keys"], line["topk"]) == (4, 640, 200)
+    assert all(line[f"{form}_is_top_k_s"] for form in ("top_k", "select_xla", "select_kernel"))
+    assert not [k for k in line if k.endswith("_us") or "_over_" in k]
+    assert (tmp_path / "chiprun_out/selected_attn_check.jsonl").read_text().count("\n") == 1
 
 
 def test_a_full_layer_s_row_in_the_pool_is_the_latent_beside_its_rotated_key():
@@ -822,7 +862,7 @@ def test_the_engine_serves_it_behind_the_batcher_at_both_chunk_widths(monkeypatc
     many = batcher.generate_many(prompts)
     assert all(r.error is None for r in solo + many)
     assert {c.rows for c in chunks} == {2, 8}
-    assert all(c.counts["moe"].shape == (len(llama.moe_stat_names(eng.cfg)),) and c.counts["latent"].shape == (8,) for c in chunks)
+    assert all(c.counts["moe"].shape == (len(llama.moe_stat_names(eng.cfg)),) and c.counts["latent"].shape == (9,) for c in chunks)
     assert many[0].token_ids == solo[0].token_ids  # the same plan at either width
     counters = fresh.snapshot()["counters"]
     assert counters["moe.assigned_rows"] > counters["moe.local_rows"] > 0
@@ -834,6 +874,8 @@ def test_the_engine_serves_it_behind_the_batcher_at_both_chunk_widths(monkeypatc
     walked = sl.walks(eng.block_tables.shape[1] * eng.block_size, eng.cfg.index_topk, eng.cfg.n_heads)
     assert counters["attn.selected_tiles"] > 0
     assert counters["attn.selected_tiles_walked"] == walked * counters["attn.selected_tiles"]
+    # ... and the threshold select with it (ISSUE 63): every tile pass of this model selects for itself
+    assert counters["attn.selections_thresholded"] == counters["attn.selected_tiles_walked"]
 
 
 def test_the_chunk_loop_gives_the_same_plans_walked_and_whole(monkeypatch, prompts):
@@ -925,12 +967,15 @@ def test_a_group_s_admission_is_the_per_slot_admissions(prompts):
 # ISSUE 62 moved all four and re-derived them ONCE: at these widths the rule (``sl.walks``: twelve
 # columns of table behind top-256 at 4 heads) WALKS — the members' mask, ``walk_split`` once a
 # forward and the walked kernel where the gather stood — and every program carries two more
-# counts (``selected_tiles`` / ``selected_tiles_walked``)
+# counts (``selected_tiles`` / ``selected_tiles_walked``). ISSUE 63 moved all four again, ONCE: a
+# walked tile's set is made by ``threshold_members`` (in interpret mode here: its compare-and-count
+# steps stand in the text) where ``lax.top_k`` sorted, and the carry holds a ninth count
+# (``selections_thresholded``)
 PARENT_SHA256 = {
-    "group": "fea82c9023b771927167d337e586e5a4fc15b50316b3e04c9b2a70fe440e6411",
-    "block": "1bf7bb9ca74cdf8e85d0b4cfe5aff6bfafa8f93689bd87f4d68ec9175be0bf89",
-    "chunk": ["65f7ad5fb8c63961d324d67fd109cc26bcc767e91939601fbf7b03d51e1b786c",
-              "17097aef3fa03611ec4b88e55d45aed5d9af955385f55081907f7d07456317b8"],
+    "group": "351a01c88efc4b25e69e04feea74736679677f7edf43c4ea10e90b49ad0a460d",
+    "block": "887b0527f8ce358515c76e7e0dc25f3dac42101db3514a25670b418819f48e90",
+    "chunk": ["618e45882ec4eae9ca761493215e18620c8b58d93886d0786b3b57841bb5b0f9",
+              "ee74fc10a2668cba133f05d64121aa9b8717a391df69884308e3f55931463213"],
 }
 
 

@@ -92,8 +92,8 @@ def test_the_dots3_chunk_program_compiles_at_published_widths(tpu_devices, monke
     text = compiled.as_text()
     n = R if width == "compact" else B
     # (the cell's 8832 keys of table behind top-2048 WALK at 128 heads too: the walked kernel under the scope's name)
-    for kernel in ("indexer_scores", "sparse_latent_attention", "walked_latent_attention", "window_latent_attention",
-                   "grouped_matmul"):
+    for kernel in ("indexer_scores", "threshold_members", "sparse_latent_attention", "walked_latent_attention",
+                   "window_latent_attention", "grouped_matmul"):
         assert kernel in text, kernel
     # ISSUE 44: the packed width walks ONE copy of a layer's position-wise code in tiles of 96 packed
     # rows (two ``while`` a layer) — no predicate, no whole-width twin: the parent of ISSUE 44 (ee06ed6)

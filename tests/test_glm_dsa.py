@@ -334,6 +334,41 @@ def test_the_served_forward_is_the_same_walked_and_gathered(served_f32, impl, fe
     assert (stats["selected_tiles"], stats["selected_tiles_walked"]) == (tiles, tiles * (fetch == "walked"))
     assert stats["keys_selected"] == 5 * sum(min(t + 1, 16) for t in range(50))
     assert (stats["selections_made"], stats["selections_carried"]) == (2 * 50, 3 * 50)
+    # (ISSUE 63) two indexed layers' tiles select by threshold where they walk with the kernels on
+    assert stats["selections_thresholded"] == 2 * 5 * (fetch == "walked" and impl == "pallas")
+
+
+def test_a_walked_forward_through_the_threshold_select_is_the_one_through_top_k_bit_for_bit(served_f32, monkeypatch):
+    """ISSUE 63: the SAME set by another way to it. A walked prefill of 50 with the kernels on,
+    its indexed layers' tiles selecting through ``threshold_members`` (no sorted row), against the
+    parent's path — ``lax.top_k`` + ``top_k_members`` bound in its place: every mask a full layer
+    hands on (what the three shared layers behind it attend: ``layer/attn/carry``) is the same mask,
+    and the logits and every cached row are the same BITS."""
+    from tpu_voice_agent.ops import sparse_latent as sl
+
+    params, _ = served_f32
+    monkeypatch.setattr(sl, "walks", lambda keys, topk, heads: True)
+
+    def prefill_through(members):
+        masks = []
+
+        def recorded(mine, k):
+            made = members(mine, k)
+            jax.debug.callback(lambda m: masks.append(np.asarray(m)), made, ordered=True)
+            return made
+
+        monkeypatch.setattr(sl, "threshold_members", recorded)
+        with jax.default_matmul_precision("highest"):
+            out = jax.block_until_ready(_prefill(params, CFG, "pallas"))
+        jax.effects_barrier()
+        return out, masks
+
+    (new, new_masks), (old, old_masks) = prefill_through(sl.threshold_members), prefill_through(sl.chosen_mask)
+    assert len(new_masks) == len(old_masks) == 2 * 5  # two indexed layers, 50 positions in tiles of 10
+    assert all(m.dtype == bool and m.shape == (10, TABLE.shape[1] * BS) and 0 < m.sum() < m.size for m in new_masks)
+    assert all(np.array_equal(a, b) for a, b in zip(new_masks, old_masks))
+    assert np.array_equal(new[0], old[0])
+    assert all(np.array_equal(a, b) for a, b in zip(jax.tree.leaves(new[1:3]), jax.tree.leaves(old[1:3])))
 
 
 @pytest.mark.parametrize("fetch", FETCHES, indirect=True)
@@ -529,6 +564,8 @@ def test_the_engine_serves_it_behind_the_batcher_at_both_chunk_widths(monkeypatc
     walked = sl.walks(eng.block_tables.shape[1] * eng.block_size, eng.cfg.index_topk, eng.cfg.n_heads)
     assert counters["attn.selected_tiles"] > 0
     assert counters["attn.selected_tiles_walked"] == walked * counters["attn.selected_tiles"]
+    # ... and the threshold select in the two layers in five that select (ISSUE 63)
+    assert counters["attn.selections_thresholded"] * 5 == counters["attn.selected_tiles_walked"] * 2
     # the indexer scores TWO planes a forward: scored / (made / 2 positions) stays the pool's size
     assert counters["attn.index_keys_scored"] >= visible * 2 / 5 and counters["attn.window_keys_read"] == 0
 

@@ -7,6 +7,8 @@ that head through the scratch pool and the comparison's one-row block.
 ``tests/test_dots3_compile_tpu.py``'s pattern, in a file of its own so that the
 test run spreads these eight-layer programs over another worker."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -87,8 +89,11 @@ def test_the_glm_chunk_program_compiles_at_published_widths(tpu_devices, monkeyp
     text = compiled.as_text()
     n = R if width == "compact" else B
     # (the cell's 8832 keys of table behind top-2048 WALK: the walked kernel under the scope's name)
-    for kernel in ("indexer_scores", "sparse_latent_attention", "walked_latent_attention", "grouped_matmul"):
+    for kernel in ("indexer_scores", "threshold_members", "sparse_latent_attention", "walked_latent_attention",
+                   "grouped_matmul"):
         assert kernel in text, kernel
+    # (ISSUE 63) a walked tile's selection sorts no row of scores: the k-th score and its last tie, counted
+    assert not re.search(r"f32\[\d+,8832\]\S* sort\(", text)
     assert "window_latent_attention" not in text and "conditional" not in text
     # the head on one position a row; no key or value of a cached position is ever decompressed
     assert f"f32[{n},19360]" in text and f"{n},9,19360]" not in text
@@ -119,4 +124,7 @@ def test_the_glm_prefills_compile_at_published_widths(tpu_devices, monkeypatch, 
         S((rows, cols), I32), attn_impl="pallas", n_real=n_real).compile()
     text = compiled.as_text()
     assert "indexer_scores" in text and "sparse_latent_attention" in text
+    # (ISSUE 63) the select runs where a program chooses FEWER keys than its table spans: the head's chunks
+    # behind a 64-column table choose 2048 of 8192, the suffixes and the block 2048 of 8832
+    assert "threshold_members" in text and not re.search(r"f32\[\d+,\d+\]\S* sort\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

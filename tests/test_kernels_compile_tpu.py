@@ -860,6 +860,19 @@ def test_the_walked_latent_kernel_compiles_at_published_widths(tpu_devices, H, t
     assert "walked_latent_attention" in compiled.as_text()
 
 
+@pytest.mark.parametrize("tile,keys,k", [(16, 8832, 2048), (12, 8832, 2048), (16, 1152, 1024)],
+                         ids=["block", "compact", "head-chunk"])
+def test_the_threshold_select_compiles_at_published_widths(tpu_devices, tile, keys, k):
+    """ISSUE 63: a walked tile's selection — top-2048 of the 8832 positions its rows' tables span
+    (16 slots; the compacted width's 12, padded to 16 inside), top-1024 of a head chunk's 1152 — as
+    ONE kernel over the tile's keys whole in VMEM, and no sort in the program around it."""
+    from tpu_voice_agent.ops import sparse_latent as sl
+
+    compiled = _compile(tpu_devices, sl.threshold_members, ((tile, keys), F32), k=k, interpret=False)
+    text = compiled.as_text()
+    assert "threshold_members" in text and " sort(" not in text
+
+
 @pytest.mark.slow
 def test_sharded_kernels_compile_on_2x2(tpu_devices):
     """The shard_map variants the dp x tp serving mesh traces (batch over

@@ -45,7 +45,7 @@ def build_engine(conf: dict, rehearsal: bool, prefix: bool = True):
     (and, for a check that seeds the pool itself, less the cached ``prefix``)."""
     import jax
 
-    from benchmark.builders import (cohere2moe_stack, dots3_stack, moonlight_stack, olmoe_stack,
+    from benchmark.builders import (cohere2moe_stack, dots3_stack, glm_dsa_stack, moonlight_stack, olmoe_stack,
                                     ouro_stack, parse_stack, sambay_stack, smallthinker_stack)
     from tpu_voice_agent.grammar.intent_grammar import default_tokenizer
     from tpu_voice_agent.serve import PagedDecodeEngine
@@ -60,6 +60,7 @@ def build_engine(conf: dict, rehearsal: bool, prefix: bool = True):
         "cohere2moe_stack": (cohere2moe_stack.llama_config, cohere2moe_stack.make_params),
         "moonlight_stack": (moonlight_stack.llama_config, moonlight_stack.make_params),
         "dots3_stack": (dots3_stack.llama_config, dots3_stack.make_params),
+        "glm_dsa_stack": (glm_dsa_stack.llama_config, glm_dsa_stack.make_params),
         "smallthinker_stack": (smallthinker_stack.llama_config, smallthinker_stack.make_params),
         "ouro_stack": (ouro_stack.llama_config, ouro_stack.make_params),
     }[conf["builder"]]

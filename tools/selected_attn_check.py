@@ -29,6 +29,18 @@ columns an item, ``_WALK_SUB`` query rows a step). On the CPU (interpret
 mode: pass small shapes) it checks that the two paths agree and prints no time.
 Exit code 1 where they differ by more than the pool dtype's rounding, or where the
 membership mask (``top_k_members``) is not ``lax.top_k``'s index set on this device.
+
+``--select`` reads the SELECTION alone instead (ISSUE 63; the stop rule's instrument): us a
+tile, at ``--tile`` / ``--keys`` / ``--topk``, of the three ways to a walked tile's members'
+mask — ``top_k`` (``lax.top_k``, a full sort of every row, + ``top_k_members``), ``select_xla``
+(``ops.sparse_latent``'s exact threshold select as XLA alone, its compare-and-count steps
+unrolled) and ``select_kernel`` (``threshold_members``: the same steps over the tile's keys
+resident in VMEM) — over ``--passes`` distinct score planes cut at ``seq <= position`` as the
+cells cut them, in one program each (``program_us``: the same program with no selection in it —
+what every reading holds of the scan's own; the ``_net`` ratios are taken without it); and
+whether each form's mask IS a scatter of ``top_k``'s indices on this device, on those planes and
+on planted rows (ties at the k-th, zeros of both signs, rows that run out, a row all -inf, NaNs
+of both signs). One line of JSON, exit code 1 on a difference.
 """
 
 from __future__ import annotations
@@ -79,15 +91,60 @@ def case(seed: int, tile: int, H: int, keys: int, common: int, topk: int, planes
     return q_c, q_r, plane, tables, pos, sel, sblk, chosen, split
 
 
-def members_are_top_k_s(seed: int, tile: int, keys: int, topk: int) -> bool:
-    """On THIS device: ``top_k_members`` against a scatter of ``lax.top_k``'s indices, on scores
-    with ties at the k-th value (zeros of both signs among them) and rows that run out."""
+def planted(seed: int, tile: int, keys: int) -> jax.Array:
+    """(tile, keys) scores with ties at the k-th value (zeros of both signs among them) and rows
+    that run out."""
     mine = jnp.round(jax.random.normal(jax.random.PRNGKey(seed % (1 << 31)), (tile, keys), jnp.float32) * 1.5) / 2
     ends = jnp.linspace(1, keys - 1, tile).astype(jnp.int32)[:, None]
-    mine = jnp.where(jnp.arange(keys)[None, :] <= ends, mine, -jnp.inf)
-    vals, sel = jax.lax.top_k(mine, min(topk, keys))
-    want = jnp.zeros(mine.shape, bool).at[jnp.arange(tile)[:, None], sel].set(True)
-    return bool(jnp.array_equal(sl.top_k_members(mine, vals, sel), want))
+    return jnp.where(jnp.arange(keys)[None, :] <= ends, mine, -jnp.inf)
+
+
+def top_k_s_set(mine: jax.Array, k: int) -> jax.Array:
+    """A scatter of ``lax.top_k``'s indices: the yardstick every mask is held to."""
+    sel = jax.lax.top_k(mine, k)[1]
+    return jnp.zeros(mine.shape, bool).at[jnp.arange(mine.shape[0])[:, None], sel].set(True)
+
+
+def members_are_top_k_s(seed: int, tile: int, keys: int, topk: int) -> bool:
+    """On THIS device: ``top_k_members`` against a scatter of ``lax.top_k``'s indices."""
+    mine, k = planted(seed, tile, keys), min(topk, keys)
+    return bool(jnp.array_equal(sl.chosen_mask(mine, k), top_k_s_set(mine, k)))
+
+
+# the three ways to a walked tile's members' mask, (tile, S) float32 scores -> (tile, S) bool
+SELECTS = {
+    "top_k": sl.chosen_mask,
+    "select_xla": lambda mine, k: sl._select_members(
+        lambda: sl._total_order(mine), lambda v: (lambda: v), k, unroll=True) != 0,
+    "select_kernel": sl.threshold_members,
+}
+
+
+def select_reading(a, timed: bool) -> dict:
+    """``--select``: each form's us a tile and whether its mask is ``top_k``'s set."""
+    k = min(a.topk, a.keys)
+    kp, ks = jax.random.split(jax.random.PRNGKey(a.seed % (1 << 31)))
+    pos = jax.random.randint(kp, (a.passes, a.tile, 1), max(a.keys - 700, 0), a.keys)  # 8-40 + a few past the head
+    scores = jnp.where(jnp.arange(a.keys)[None, None, :] <= pos,
+                       jax.random.normal(ks, (a.passes, a.tile, a.keys), jnp.float32), -jnp.inf)
+    rows = planted(a.seed, a.tile, a.keys)
+    odd = rows.at[0].set(-jnp.inf).at[1, ::3].set(jnp.nan).at[1, 1::7].set(-jnp.nan).at[2, : a.keys // 2].set(jnp.nan)
+    line = {"select": True, "tile": a.tile, "keys": a.keys, "topk": k, "passes": a.passes,
+            "device": jax.devices()[0].device_kind}
+    time_of = lambda form: jax.jit(lambda planes: jax.lax.scan(
+        lambda n, mine: (n + form(mine, k).astype(jnp.int32), None), jnp.zeros(planes.shape[1:], jnp.int32), planes)[0])
+    held = [(mine, top_k_s_set(mine, k)) for mine in (*scores, rows, odd)]
+    for name, form in SELECTS.items():
+        line[f"{name}_is_top_k_s"] = all(bool(jnp.array_equal(form(mine, k), want)) for mine, want in held)
+        if timed:
+            line[f"{name}_us"] = wall_us(time_of(form), (scores,), a.reps, a.passes)
+    if timed:
+        # the program's own: the pass with NO selection in it (a plane cut out of the stack, one compare, the sum)
+        own = line["program_us"] = wall_us(time_of(lambda mine, k: mine > 0.0), (scores,), a.reps, a.passes)
+        for name in list(SELECTS)[1:]:
+            line[f"{name}_over_top_k"] = line[f"{name}_us"] / line["top_k_us"]
+            line[f"{name}_over_top_k_net"] = (line[f"{name}_us"] - own) / (line["top_k_us"] - own)
+    return line
 
 
 def gather_pass(q_c, q_r, plane, li, pos, sel, sblk):
@@ -132,12 +189,25 @@ def main() -> int:
     ap.add_argument("--planes", type=int, default=8)
     ap.add_argument("--reps", type=int, default=9)
     ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--select", action="store_true",
+                    help="read the selection alone: us a tile of top_k + top_k_members and of each form of "
+                         "the threshold select, and whether each mask is top_k's set on this device")
     ap.add_argument("--try", dest="tries", nargs="+", default=[], metavar="COLS:SUB",
                     help="read the walk at these widths too (_WALK_COLS:_WALK_SUB)")
     a = ap.parse_args()
     timed = jax.devices()[0].platform != "cpu"
     out = Path("chiprun_out")
     out.mkdir(exist_ok=True)
+
+    def report(line):
+        print(json.dumps(line), flush=True)
+        with (out / "selected_attn_check.jsonl").open("a") as f:
+            f.write(json.dumps(line) + "\n")
+
+    if a.select:
+        line = select_reading(a, timed)
+        report(line)
+        return 0 if all(line[f"{name}_is_top_k_s"] for name in SELECTS) else 1
     agree = members = members_are_top_k_s(a.seed, a.tile, a.keys, a.topk)
     for H in a.heads:
         q_c, q_r, plane, tables, pos, sel, sblk, chosen, split = case(
@@ -182,9 +252,7 @@ def main() -> int:
                 "gather_row": slot_ns(line["gather_us"] - line["gathered_kernel_us"]) / K,
                 "gathered_key_head": slot_ns(line["gathered_kernel_us"]) / (K * H),
                 "walked_key_head": slot_ns(line["walk_us"]) / (keys * H)}
-        print(json.dumps(line), flush=True)
-        with (out / "selected_attn_check.jsonl").open("a") as f:
-            f.write(json.dumps(line) + "\n")
+        report(line)
     return 0 if agree else 1
 
 

@@ -37,18 +37,24 @@ over a key set gathered for its queries:
 - a full layer's positions, the REAL ones first (``llama.ffn_pack_index``),
   go tile by tile (16 slots) — as many tiles as hold real positions — through
   ``ops.indexer_scores`` (every pool block scored once for all of them), a
-  read of each position's own blocks out of that by its table, ``top_k``, and
-  the chosen keys fetched one of TWO ways, by the shapes of the program alone
-  (``ops.sparse_latent.walks``: the keys a row's table spans, ``index_topk``,
-  the heads — no flag): where the context is a few times ``index_topk`` the
-  tile WALKS its rows' table columns block by block straight out of the pool
-  under the selection as a membership mask (``walked_latent_attention``: a
-  column every slot holds is read once for all of them; every visible key is
-  scored and an unchosen one masked — the same softmax over the same set, and
-  no gather, which the chip charges ~16 ns a ROW: PERF.md section 6, PR 62);
-  everywhere else ONE gather of the chosen keys' rows [c | r] (one row a key
-  where two planes paid for two: PERF.md section 6, PR 45) and the kernel with
-  a position's H heads as a group over that one tile of keys;
+  read of each position's own blocks out of that by its table, the selection
+  (``top_k``'s index set), and the chosen keys fetched one of TWO ways, by the
+  shapes of the program alone (``ops.sparse_latent.walks``: the keys a row's
+  table spans, ``index_topk``, the heads — no flag): where the context is a few
+  times ``index_topk`` the tile WALKS its rows' table columns block by block
+  straight out of the pool under the selection as a membership mask
+  (``walked_latent_attention``: a column every slot holds is read once for all
+  of them; every visible key is scored and an unchosen one masked — the same
+  softmax over the same set, and no gather, which the chip charges ~16 ns a
+  ROW: PERF.md section 6, PR 62) and the mask is made with NOTHING SORTED
+  (``threshold_members``: the k-th score in ``top_k``'s order and the last tie
+  it takes, by compare-and-count steps over the tile's keys in VMEM — ~11 us a
+  tile of 16 where ``lax.top_k``'s full sort of (16, 8832) + the compares were
+  ~141: PERF.md section 6, PR 63); everywhere else ``lax.top_k`` — the branch
+  that SORTS: it needs the indices — and ONE gather of the chosen keys' rows
+  [c | r] (one row a key where two planes paid for two: PERF.md section 6, PR
+  45) and the kernel with a position's H heads as a group over that one tile
+  of keys;
 - a sliding layer's rows gather the few blocks that hold their window and
   the kernel takes a row's T x H queries as a group (a prefill wider than
   ``MAX_BLOCK_DECODE_T`` is cut into rows of 8 positions first).
@@ -84,9 +90,10 @@ A selection CARRIED across layers (``glm_moe_dsa``: GLM-5.2, ``LlamaConfig.index
 gate, no rescale, no sliding layer): a "shared" layer is a full layer WITHOUT an indexer — a kind
 of its own here, ``attn_shared`` its leaves (no W_qI / W_kI / W_w), ``k_pool["shared"]`` its rows
 [c | r] (no index key cached) — that attends S_l[t] = S_f(l)[t], the keys the nearest full layer f
-before it selected. A full layer's tiles hand their ``top_k`` on as they make it, in the form the
-fetch takes it — gathered: the chosen keys' sequence positions and their pool blocks, (P, K) each;
-walked: ``top_k``'s members, ONE (P, nb * bs) mask — in the packed order of the forward's
+before it selected. A full layer's tiles hand their selection on as they make it, in the form the
+fetch takes it — gathered: ``lax.top_k``'s chosen keys' sequence positions and their pool blocks, (P, K)
+each; walked: the same set's members, ONE (P, nb * bs) mask, made by the threshold select with no sort
+(``threshold_members``) — in the packed order of the forward's
 positions (``layer/attn/carry``); a shared layer's tiles cut theirs out of that, fetch THEIR OWN
 plane's rows at those coordinates and attend them through the same kernel. Nothing else differs:
 one ``latent_qkv``, one indexer path, one ``attend_chosen``.
@@ -117,7 +124,10 @@ F32 = jnp.float32
 SPARSE_STATS = ("index_keys_scored", "keys_visible", "keys_selected", "window_keys_read",
                 # tile passes of selected attention (selected layers x tiles that hold a real
                 # position), and those that WALKED their rows' blocks under the selection as a mask
-                "selected_tiles", "selected_tiles_walked")
+                "selected_tiles", "selected_tiles_walked",
+                # tile passes of INDEXED layers whose selection the threshold select made
+                # (``ops.sparse_latent.threshold_members``: no sorted row), not ``lax.top_k``
+                "selections_thresholded")
 # behind them where a selection is carried (``indexer_types``): (real position, layer) pairs whose
 # layer scored and selected, and pairs whose layer attended the set an earlier layer chose
 CARRY_STATS = ("selections_made", "selections_carried")
@@ -375,6 +385,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
     twin = sl.gathered_latent_attention_reference
     attend_full = sl.sparse_latent_attention if pallas else sl.sparse_latent_attention_reference
     attend_walk = sl.walked_latent_attention if pallas else sl.walked_latent_attention_reference
+    members_of = sl.threshold_members if pallas else sl.chosen_mask
     attend_window = sl.window_latent_attention if pallas else twin
     if fault is not None and fault not in FAULTS + CARRY_FAULTS:
         raise ValueError(f"fault {fault!r}: one of {FAULTS + CARRY_FAULTS}")
@@ -507,11 +518,14 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
                         mine = -seq.astype(F32) + jnp.zeros_like(mine)
                     mine = jnp.where(seq <= ps[:, None], mine, -jnp.inf)
                 with jax.named_scope("top_k"):
-                    vals, sel = jax.lax.top_k(mine, K)  # (tile, K) sequence positions
-            with jax.named_scope("layer/attn/select"):
-                if walk:  # the same set as a membership mask: compares, no scatter
-                    picked = (sl.top_k_members(mine, vals, sel),)
-                else:
+                    if walk:
+                        # ``top_k``'s set as a membership mask and NOTHING sorted: the mask wants the
+                        # k-th score and its last tie, two scalars a row (ISSUE 63)
+                        picked = (members_of(mine, K),)
+                    else:
+                        sel = jax.lax.top_k(mine, K)[1]  # (tile, K) sequence positions
+            if not walk:
+                with jax.named_scope("layer/attn/select"):
                     # the block of each chosen key out of the slot's table: a compare
                     # and a sum over its few columns (a gather of scalars costs more
                     # than the rows it names)
@@ -787,6 +801,7 @@ def forward_paged(params, cfg: LlamaConfig, tokens, positions, k_pool, v_pool, b
                 n_alive * T * (n_sel * kd["full"].H + n_swa * heads_swa),
                 n_full * scored * (N * bs), n_sel * seen, n_sel * chosen,
                 n_swa * win, n_sel * n_tiles, n_sel * n_tiles * walk,
+                n_full * n_tiles * (walk and pallas and K < nb * bs),
                 *((n_full * n_pos, n_shared * n_pos) if cfg.indexer_types else ())
             ]).astype(jnp.int32),)
     if rows is not None:

@@ -42,6 +42,12 @@ and the positions, once a forward; a dynamic grid walks it). It does
 ``nb * bs / K`` times the gathered kernel's FLOPs on ``tile`` times its query
 rows a key block, and no gather, which the chip charges by the ROW (~15 ns of
 1152 B): ``walks`` says, from the shapes alone, which fetch is the cheaper.
+
+``threshold_members`` — a walked tile's selection (ISSUE 63): ``lax.top_k``'s index set as the
+membership mask itself, from the k-th largest score and the last tie ``top_k`` takes — bisection on
+the keys' bits, compare-and-count over the tile's keys whole in VMEM — where ``lax.top_k`` sorts
+every row and the mask reads two scalars of it. ``chosen_mask`` (``lax.top_k`` +
+``top_k_members``) is its twin; the gather branch, which needs the indices, keeps the sort.
 """
 
 from __future__ import annotations
@@ -282,8 +288,88 @@ def top_k_members(mine: jax.Array, vals: jax.Array, sel: jax.Array) -> jax.Array
 
 
 def chosen_mask(mine: jax.Array, k: int) -> jax.Array:
-    """``top_k_members`` of ``lax.top_k(mine, k)``."""
+    """``top_k_members`` of ``lax.top_k(mine, k)``: the twin of ``threshold_members`` (what a walked
+    tile runs with the kernels off), and the yardstick of the tests and the tool."""
     return top_k_members(mine, *jax.lax.top_k(mine, k))
+
+
+# WHICH BRANCH SORTS. ``lax.top_k`` lowers to a FULL sort of every row ((16, 8832) float32 with its
+# indices: 123-136 us a tile in the cells' device traces — ledger, PR 62), and ``top_k_members`` reads two
+# scalars a row of the result. The gather branch keeps it: it fetches by the sorted indices. The walk
+# branch wants the mask alone, and makes it below by an exact k-th order statistic — the same set bit
+# for bit. READINGS (``tools/selected_attn_check.py --select`` on the chip, PR 63: a tile of 16 behind
+# top-2048 of 8832 keys, us a tile inside a program whose own share is 26): ``lax.top_k`` +
+# ``top_k_members`` 161.9; the steps below as XLA alone, unrolled (93 fusions) 89.7; as ONE kernel over
+# the keys resident in VMEM 36.5 — ~11 us of its own against ~136. PERF.md section 6, PR 63.
+
+_INT_MIN = -(1 << 31)
+
+
+def _count(hit: jax.Array) -> jax.Array:
+    """(P, S) bool -> (P, 1) int32: the hits a row."""
+    return jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
+
+
+def _largest(bits: int, rows: int, holds, unroll: bool) -> jax.Array:
+    """-> (rows, 1) int32: a row's largest t of ``bits`` bits with ``holds(t)`` (rows, 1) bool — true
+    of 0 and of no t past a false one — made bit by bit from the top one down: ``bits`` calls."""
+    def step(i, t):
+        cand = t | jnp.left_shift(jnp.int32(1), bits - 1 - i)
+        return jnp.where(holds(cand), cand, t)
+
+    return jax.lax.fori_loop(0, bits, step, jnp.zeros((rows, 1), jnp.int32), unroll=unroll)
+
+
+def _select_members(keys, hold, k: int, unroll: bool) -> jax.Array:
+    """``top_k_members``' mask, as (P, S) int32, with NO sorted row: ``keys()`` -> (P, S) int32 in
+    ``_total_order`` (read anew at every use: a kernel's block stays where it is), k < S. The k-th
+    largest key a row is the largest value that k keys reach — 32 compare-and-count steps, its bits
+    from the sign down (t holds them offset: t ^ INT_MIN is the key) —; the last tie ``top_k`` takes
+    is the position of the ``need``-th key EQUAL to it, need = k - the keys above — the largest
+    position with fewer than ``need`` such keys before it, ceil(log2 S) steps more over ``spot``
+    (a tied key's position, S elsewhere; ``hold(value)`` keeps it and returns its reader)."""
+    P, S = keys().shape
+    kth = _largest(32, P, lambda t: _count(keys() >= (t ^ _INT_MIN)) >= k, unroll) ^ _INT_MIN
+    need = k - _count(keys() > kth)
+    seq = jax.lax.broadcasted_iota(jnp.int32, (P, S), 1)
+    spot = hold(jnp.where(keys() == kth, seq, S))
+    last_tie = _largest((S - 1).bit_length(), P, lambda t: _count(spot() < t) < need, unroll)
+    return ((keys() > kth) | (spot() <= last_tie)).astype(jnp.int32)
+
+
+def _threshold_kernel(k_ref, o_ref, *, k: int):
+    """keys (P, S) int32 -> members (P, S) int32, both whole in VMEM; ``spot`` lives in the output's
+    block until the members overwrite it."""
+    def hold(v):
+        o_ref[...] = v
+        return lambda: o_ref[...]
+
+    o_ref[...] = _select_members(lambda: k_ref[...], hold, k, unroll=False)
+
+
+# analyze: ok[jit-sentinel] -- kernel wrapper traced inline by the watched engine loops, never a serving dispatch entry point
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def threshold_members(mine: jax.Array, k: int, *, interpret: bool | None = None) -> jax.Array:
+    """``chosen_mask(mine, k)`` bit for bit — ``lax.top_k``'s index set of (P, S) float32 scores as
+    a (P, S) bool mask: ties in its order, -0.0 below 0.0, -inf keys where fewer than k are finite,
+    NaNs where its total order puts them — by an exact k-th order statistic (``_select_members``)
+    where ``lax.top_k`` SORTS every row whole and a walked tile reads two scalars a row of the result.
+    One call, the tile's keys resident in VMEM (565 KB at (16, 8832)); k >= S chooses everything."""
+    P, S = mine.shape
+    if k >= S:
+        return jnp.ones((P, S), bool)
+    Pp, Sp = -(-P // 8) * 8, -(-S // 128) * 128
+    # (a padding key stands below every real one of its value: behind them, and k <= S never reaches it)
+    keys = jnp.pad(_total_order(mine), ((0, Pp - P), (0, Sp - S)), constant_values=_INT_MIN)
+    call = pl.pallas_call(
+        functools.partial(_threshold_kernel, k=k),
+        out_shape=jax.ShapeDtypeStruct((Pp, Sp), jnp.int32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret if interpret is not None else on_cpu(),
+        name="threshold_members",
+    )
+    with jax.named_scope("threshold_members"):  # the kernel alone, by its name, in the device trace
+        return call(keys)[:P, :S] != 0
 
 
 class WalkSplit(NamedTuple):
