@@ -450,9 +450,9 @@ def check_catalog(reg: dict[str, dict[str, list[str]]],
 # batcher adds each vector to the counters its record names, built from the
 # modules' own tuples (``llama.moe_stat_names``, ``ops.ATTN_STATS``,
 # ``mla.LATENT_STATS``, ``sambay.HYBRID_STATS``, ``olmo_hybrid.HYBRID_STATS``,
-# ``llama.FFN_STATS``, ``llama.LOOP_STATS``, ``llama.KV_STATS``) — no ``inc("...")`` a name,
-# so their families are registered where ``Count`` is defined
-COUNTED = ("moe.*", "attn.*", "ssm.*", "gdn.*", "ffn.*", "loop.*", "kv.*")
+# ``lfm2.HYBRID_STATS``, ``llama.FFN_STATS``, ``llama.LOOP_STATS``, ``llama.KV_STATS``) — no
+# ``inc("...")`` a name, so their families are registered where ``Count`` is defined
+COUNTED = ("moe.*", "attn.*", "ssm.*", "gdn.*", "conv.*", "ffn.*", "loop.*", "kv.*")
 
 
 def scan_source(root: pathlib.Path) -> dict[str, dict[str, list[str]]]:

@@ -27,7 +27,7 @@ from types import ModuleType
 from typing import Mapping, NamedTuple
 
 from ..ops import ATTN_STATS
-from . import dots3, llama, mla, nemotron_h, olmo_hybrid, sambay
+from . import dots3, lfm2, llama, mla, nemotron_h, olmo_hybrid, sambay
 
 # the rows the position-wise regions of a fast-forward block are packed into
 # (ISSUE 37: the MLPs; ISSUE 41: q/k/v and the output projection with them):
@@ -117,45 +117,48 @@ class Family:
         return 2 * sum(prod(p) for side in self.cache["planes"].values() for p in side.values())
 
 
-_STATE = ("K/V blocks alone, without the recurrent state that goes with them: not with a "
-          "SambaYConfig")
-_HYBRID_REFUSES = {
-    "kv_quant": f"KV_QUANT re-stores {_STATE}",
-    "radix": f"radix reuse hands a slot cached {_STATE}",
-    "mesh": f"a mesh shards weights of a LlamaConfig's layout and {_STATE}",
-    "handoff": f"a handoff ships and adopts {_STATE}",
-    "chunked_prefill": "the cursor of a chunked admission carries no count of real positions "
-                       "for the recurrent state: the one-shot prefill_slot serves it",
-    "dense_cache": "the state of a SambaYConfig's requests lives in the paged pool's per-slot "
-                   "planes, forward_paged's: PagedDecodeEngine alone serves it",
-    "ffn_pack": "models.sambay's MLPs have no packed branch (ROADMAP S3 (e))",
-}
-_SSD = ("K/V blocks alone, without the Mamba-2 state (4 MB a layer a request) and the convolution "
-        "tail that go with them: not with a NemotronHConfig")
-_SSD_REFUSES = {
-    "kv_quant": f"KV_QUANT re-stores {_SSD}",
-    "radix": f"radix reuse hands a slot cached {_SSD}",
-    "mesh": "a mesh shards a LlamaConfig's weights and would exchange latent rows between the chips "
-            f"that share an expert layer, which nothing here does; it moves {_SSD}",
-    "handoff": f"a handoff ships and adopts {_SSD}",
-    "chunked_prefill": "the cursor of a chunked admission carries no count of real positions for "
-                       "the Mamba-2 state: the one-shot prefill_slot serves it",
-    "dense_cache": "a NemotronHConfig's state lives in the paged pool's per-slot planes and its "
-                   "layers are one block of three kinds: forward_paged's, PagedDecodeEngine alone serves it",
-}
-_GDN = ("K/V blocks alone, without the delta-rule state (2.2 MB a layer a request, float32) and the "
-        "convolution tail that go with them: not with an OlmoHybridConfig")
-_GDN_REFUSES = {
-    "kv_quant": f"KV_QUANT re-stores {_GDN}",
-    "radix": f"radix reuse hands a slot cached {_GDN}",
-    "mesh": f"a mesh shards a LlamaConfig's weights and {_GDN}",
-    "handoff": f"a handoff ships and adopts {_GDN}",
-    "chunked_prefill": "the cursor of a chunked admission carries no count of real positions for "
-                       "the delta-rule state: the one-shot prefill_slot serves it",
-    "dense_cache": "an OlmoHybridConfig's state lives in the paged pool's per-slot planes and its "
-                   "layers are of two kinds under a reordered norm: forward_paged's, "
-                   "PagedDecodeEngine alone serves it",
-}
+def _state_refuses(state: str, held: str, config: str, dense_cache: str,
+                   mesh: str = "a mesh shards a LlamaConfig's weights and", **more: str) -> dict[str, str]:
+    """What a family whose requests hold a per-slot state beside their K/V blocks
+    refuses, and why: ``state`` names it, ``held`` says what goes with the blocks,
+    ``config`` whose it is; ``dense_cache`` why ``forward_paged`` alone runs the
+    model; ``mesh`` what a mesh would do before it moved the blocks alone. One
+    line a family: the six reasons are the same six, for another noun."""
+    alone = f"K/V blocks alone, without {held} with them: not with {config}"
+    return {"kv_quant": f"KV_QUANT re-stores {alone}",
+            "radix": f"radix reuse hands a slot cached {alone}",
+            "mesh": f"{mesh} {alone}",
+            "handoff": f"a handoff ships and adopts {alone}",
+            "chunked_prefill": "the cursor of a chunked admission carries no count of real positions "
+                               f"for {state}: the one-shot prefill_slot serves it",
+            "dense_cache": dense_cache, **more}
+
+
+_HYBRID_REFUSES = _state_refuses(
+    "the recurrent state", "the recurrent state that goes", "a SambaYConfig",
+    "the state of a SambaYConfig's requests lives in the paged pool's per-slot planes, "
+    "forward_paged's: PagedDecodeEngine alone serves it",
+    mesh="a mesh shards weights of a LlamaConfig's layout and",
+    ffn_pack="models.sambay's MLPs have no packed branch (ROADMAP S3 (e))")
+_SSD_REFUSES = _state_refuses(
+    "the Mamba-2 state", "the Mamba-2 state (4 MB a layer a request) and the convolution tail that go",
+    "a NemotronHConfig",
+    "a NemotronHConfig's state lives in the paged pool's per-slot planes and its layers are one "
+    "block of three kinds: forward_paged's, PagedDecodeEngine alone serves it",
+    mesh="a mesh shards a LlamaConfig's weights and would exchange latent rows between the chips "
+         "that share an expert layer, which nothing here does; it moves")
+_GDN_REFUSES = _state_refuses(
+    "the delta-rule state",
+    "the delta-rule state (2.2 MB a layer a request, float32) and the convolution tail that go",
+    "an OlmoHybridConfig",
+    "an OlmoHybridConfig's state lives in the paged pool's per-slot planes and its layers are of "
+    "two kinds under a reordered norm: forward_paged's, PagedDecodeEngine alone serves it")
+# a tail alone: no recurrence, nothing scanned — 8 KB a layer a request at the published sizes
+_CONV_REFUSES = _state_refuses(
+    "the convolution tail", "the convolution tail (two gated inputs a layer a request) that goes",
+    "an Lfm2Config",
+    "an Lfm2Config's tails live in the paged pool's per-slot planes and its layers are of two "
+    "mixer kinds over dense and routed MLPs: forward_paged's, PagedDecodeEngine alone serves it")
 _PLANES = "K and V planes by head: a latent cache has none"
 _LATENT_REFUSES = {
     "kv_quant": f"KV_QUANT re-stores {_PLANES}",
@@ -191,12 +194,17 @@ _LOOPED_REFUSES = {
 def tree_owner(params: dict) -> ModuleType:
     """The module whose parameter tree ``params`` is, of the families that keep
     a tree of their own (each names the key only its tree has, ``TREE_ROOT``)."""
-    return next(m for m in (sambay, nemotron_h, olmo_hybrid) if m.TREE_ROOT in params)
+    return next(m for m in (sambay, nemotron_h, olmo_hybrid, lfm2) if m.TREE_ROOT in params)
 
 
 @lru_cache(maxsize=256)  # configurations are few, frozen and hashable; the record is read-only
 def family(cfg) -> Family:
     """The record of ``cfg``'s family."""
+    if isinstance(cfg, lfm2.Lfm2Config):  # a convolution's tail beside K/V, no recurrence; routed experts
+        hybrid = Count("hybrid", "hybrid_stats", lfm2.HYBRID_STATS)
+        routed = Count("moe", "moe_stats", tuple(f"moe.{n}" for n in llama.moe_stat_names(cfg)))
+        return Family("conv", lfm2, lfm2.cache_spec(cfg), (hybrid, routed, ATTN, KV),
+                      sambay.StateNotCarried, _CONV_REFUSES, n_real="always", one_head=True)
     if isinstance(cfg, olmo_hybrid.OlmoHybridConfig):  # a delta-rule matrix state beside K/V
         hybrid = Count("hybrid", "hybrid_stats", olmo_hybrid.HYBRID_STATS)
         return Family("gdn", olmo_hybrid, olmo_hybrid.cache_spec(cfg), (hybrid, ATTN, KV),

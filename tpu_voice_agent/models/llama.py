@@ -1154,6 +1154,22 @@ def write_rows(pool_a: jax.Array, pool_b: jax.Array, plane, a: jax.Array, b: jax
     return jax.lax.fori_loop(0, tiles.n_tiles, step, (pool_a, pool_b))
 
 
+def conv_window(tail: jax.Array, x: jax.Array, n_real: jax.Array, taps):
+    """What a causal convolution carries between forwards, for every family that
+    keeps one (``models.sambay``, ``nemotron_h``, ``olmo_hybrid``, ``lfm2``).
+    ``tail`` (B, K-1, w): a row's inputs before position 0; ``x`` (B, T, w): this
+    block's; ``taps``: the convolution itself, handed the padded inputs (B,
+    K-1+T, w) — the filter, bias and activation are the model's. -> (what
+    ``taps`` gives, the K - 1 inputs before position ``n_real`` of each row: the
+    NEW tail — the old one for a row that stays, part old and part new for a row
+    that advances by fewer than K - 1)."""
+    xp = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    out = taps(xp)
+    new_tail = jnp.take_along_axis(
+        xp, (n_real[:, None] + jnp.arange(tail.shape[1])[None, :])[:, :, None], axis=1)
+    return out, new_tail
+
+
 def packed_ffn(ffn, h: jax.Array, pack: FfnPack | None):
     """``ffn(h)`` -> (y, stats) over the (B, T, d) block ``h``, or — with a
     ``pack`` — over its real positions alone where they fit: gather them to

@@ -60,7 +60,7 @@ from dataclasses import dataclass, replace
 import jax
 import jax.numpy as jnp
 
-from .llama import (EXPERT_ACTS, MAX_BLOCK_DECODE_T, _moe_ffn, _qe, cache_planes, gather_row_blocks,
+from .llama import (EXPERT_ACTS, MAX_BLOCK_DECODE_T, _moe_ffn, _qe, cache_planes, conv_window, gather_row_blocks,
                     moe_stat_names, quantize_leaf, rms_norm, row_tiles, rows_written, write_rows, write_walk)
 from .sambay import _NO_WINDOW, StateNotCarried, _attend  # noqa: F401  (the family's error class)
 
@@ -307,12 +307,10 @@ def mamba_mix(p, u, tail, planes, sidx, li, n_real, cfg: NemotronHConfig, scan_i
         zxd = _qe("btd,de->bte", u, p["in_proj"])
         z, xbc, dt = zxd[..., :di], zxd[..., di:di + cd].astype(u.dtype), zxd[..., di + cd:]
     with jax.named_scope("layer/ssm/conv"):
-        xp = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)  # (B, K-1+T, cd)
-        conv = sum(xp[:, j:j + T].astype(F32) * p["conv_w"][j].astype(F32) for j in range(K))
-        xbc = jax.nn.silu(conv + p["conv_b"].astype(F32))
-        # the inputs before position n_real: the old tail for a row that stays
-        new_tail = jnp.take_along_axis(
-            xp, (n_real[:, None] + jnp.arange(K - 1)[None, :])[:, :, None], axis=1)
+        taps = lambda xp: jax.nn.silu(  # over (B, K-1+T, cd)
+            sum(xp[:, j:j + T].astype(F32) * p["conv_w"][j].astype(F32) for j in range(K))
+            + p["conv_b"].astype(F32))
+        xbc, new_tail = conv_window(tail, xbc, n_real, taps)
     with jax.named_scope("layer/ssm/scan"):
         x = xbc[..., :di].reshape(B, T, H, P)
         bm = xbc[..., di:di + G * N].reshape(B, T, G, N)

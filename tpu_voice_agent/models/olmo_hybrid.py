@@ -68,7 +68,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.gated_delta import plane_shape
-from .llama import (MAX_BLOCK_DECODE_T, _qe, _swiglu, cache_planes, ffn_pack_index, gather_row_blocks,
+from .llama import (MAX_BLOCK_DECODE_T, _qe, _swiglu, cache_planes, conv_window, ffn_pack_index, gather_row_blocks,
                     quantize_leaf, rms_norm, rows_written, write_rows, write_walk)
 from .sambay import _NO_WINDOW, StateNotCarried, _attend  # noqa: F401  (the family's error class)
 
@@ -274,11 +274,9 @@ def gdn_mix(p, proj, ab, tail, planes, sidx, li, n_real, cfg: OlmoHybridConfig, 
     kd, cd = cfg.key_dim, cfg.conv_dim
     with jax.named_scope("layer/gdn/conv"):
         qkv, gate = proj[..., :cd], proj[..., cd:]
-        xp = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)  # (B, K-1+T, cd)
-        qkv = jax.nn.silu(sum(xp[:, j:j + T].astype(F32) * p["conv_w"][j].astype(F32) for j in range(K)))
-        # the inputs before position n_real: the old tail for a row that stays
-        new_tail = jnp.take_along_axis(
-            xp, (n_real[:, None] + jnp.arange(K - 1)[None, :])[:, :, None], axis=1)
+        taps = lambda xp: jax.nn.silu(  # over (B, K-1+T, cd)
+            sum(xp[:, j:j + T].astype(F32) * p["conv_w"][j].astype(F32) for j in range(K)))
+        qkv, new_tail = conv_window(tail, qkv, n_real, taps)
     with jax.named_scope("layer/gdn/scan"):
         q = qkv[..., :kd].reshape(B, T, H, dk)
         k = qkv[..., kd:2 * kd].reshape(B, T, H, dk)

@@ -64,8 +64,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from .llama import (MAX_BLOCK_DECODE_T, _qe, cache_planes, gather_row_blocks, quantize_leaf, rows_written,
-                    write_rows, write_walk)
+from .llama import (MAX_BLOCK_DECODE_T, _qe, cache_planes, conv_window, gather_row_blocks, quantize_leaf,
+                    rows_written, write_rows, write_walk)
 
 F32 = jnp.float32
 _NO_WINDOW = 1 << 30
@@ -284,12 +284,10 @@ def ssm_mix(p, u, tail, planes, sidx, li, n_real, cfg, scan_impl: str):
         xz = _qe("btd,de->bte", u, p["in_proj"])
         x, z = xz[..., :di].astype(u.dtype), xz[..., di:]
     with jax.named_scope("layer/ssm/conv"):
-        xp = jnp.concatenate([tail.astype(x.dtype), x], axis=1)  # (B, K-1+T, di)
-        conv = sum(xp[:, j:j + T].astype(F32) * p["conv_w"][j].astype(F32) for j in range(K))
-        x = jax.nn.silu(conv + p["conv_b"].astype(F32))
-        # the inputs before position n_real: the old tail for a row that stays
-        new_tail = jnp.take_along_axis(
-            xp, (n_real[:, None] + jnp.arange(K - 1)[None, :])[:, :, None], axis=1)
+        taps = lambda xp: jax.nn.silu(  # over (B, K-1+T, di)
+            sum(xp[:, j:j + T].astype(F32) * p["conv_w"][j].astype(F32) for j in range(K))
+            + p["conv_b"].astype(F32))
+        x, new_tail = conv_window(tail, x, n_real, taps)
     with jax.named_scope("layer/ssm/scan"):
         dbc = jnp.einsum("bte,er->btr", x.astype(u.dtype), p["x_proj"],
                          preferred_element_type=F32)
